@@ -8,14 +8,6 @@ import sys
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
-def setup_platform() -> None:
-    """Honor JAX_PLATFORMS even when a preloaded accelerator plugin would
-    otherwise win platform selection.  Call before any jax backend use."""
-    from mpit_tpu.utils.platform import honor_jax_platforms
-
-    honor_jax_platforms()
-
-
 def log(*a) -> None:
     print(*a, file=sys.stderr, flush=True)
 

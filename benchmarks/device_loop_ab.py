@@ -22,7 +22,7 @@ Two modes, selected by ``MPIT_AB_MODE``:
 
 - ``flagship``: the PR-8-era host-epoch-loop vs ``lax.while_loop``
   comparison on the mesh_launch flagship config (kept for the
-  ``time_to_target_s`` flip decision, docs/NORTHSTAR_r5.md).
+  ``time_to_target_s`` flip decision).
 
 Env (dplane mode): MPIT_AB_MB (payload MB per client, default 64),
 MPIT_AB_ROUNDS (default 5), MPIT_AB_REPS (default 3), MPIT_AB_DEVICES
@@ -37,7 +37,7 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
-from _common import emit_json, log, setup_platform  # noqa: E402
+from _common import emit_json, log  # noqa: E402
 
 MODE = os.environ.get("MPIT_AB_MODE", "dplane")
 N_DEV = int(os.environ.get("MPIT_AB_DEVICES", "8"))
@@ -50,7 +50,6 @@ if MODE == "dplane":
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
     ensure_cpu_device_headroom(N_DEV)
 
-setup_platform()
 
 REPS = int(os.environ.get("MPIT_AB_REPS", "3"))
 TARGET = float(os.environ.get("MPIT_AB_TARGET", "0.02"))

@@ -1,8 +1,8 @@
 """Flash forward block-size sweep — the 65-70% MFU push (round-5 task).
 
 Round 4 bisected the forward's remaining gap to the online-softmax
-state update (docs/KERNEL_BENCH.md §0): the stripped kernel runs at 92%
-of bf16 peak, adding the (m, l) scratch chain drops it to ~60%.  The
+state update (builder run, July 2026): the stripped kernel ran at 92%
+of bf16 peak, adding the (m, l) scratch chain dropped it to ~60%.  The
 state update runs ONCE PER KV BLOCK, so larger block_k amortizes it —
 this sweep walks (block_q, block_k) combos under a raised 64 MB VMEM
 budget (``MPIT_FA_VMEM_MB``, set below; the stock 16 MB budget rejects
@@ -20,9 +20,7 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
-from _common import emit_json, log as _log, setup_platform  # noqa: E402
-
-setup_platform()
+from _common import emit_json, log as _log  # noqa: E402
 
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
@@ -39,8 +37,8 @@ B, H, D = 1, 8, 128
 # amortization candidates.  Prior data (docs/tpu_compile_notes.md §2,
 # 100 MB VMEM budget): BIGGER block_q is slower (2048x1024 = 97 vs
 # 1024x1024 = 102 TFLOP/s — less double-buffering overlap), but
-# bk-heavy combos (1024x2048, 512x2048) — the serialization lever of
-# KERNEL_BENCH §0.5 — were never measured.  The whole sweep runs under
+# bk-heavy combos (1024x2048, 512x2048) — the serialization lever —
+# were never measured.  The whole sweep runs under
 # MPIT_FA_VMEM_MB=64 (set below; perf-neutral per the same note), with
 # (1024, 1024) re-measured under it as the in-sweep control.
 COMBOS = [(1024, 1024), (1024, 2048), (2048, 1024), (1536, 1536),

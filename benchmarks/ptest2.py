@@ -28,10 +28,7 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
-from _common import join_checked, log as _log, setup_platform, shm_gang  # noqa: E402
-
-setup_platform()
-
+from _common import join_checked, log as _log, shm_gang  # noqa: E402
 
 MB = float(os.environ.get("MPIT_BENCH_MB", "16"))
 ROUNDS = int(os.environ.get("MPIT_BENCH_ROUNDS", "20"))

@@ -57,9 +57,12 @@ _LITTLE = sys.byteorder == "little"
 # scale, and ctypes releases the GIL for the call.  Results are
 # bit-identical to the numpy paths (build.py pins -ffp-contract=off;
 # parity-tested in tests/test_codec.py), so the numpy code stays as the
-# fallback (no g++ on the host, MPIT_PS_CODEC_NATIVE=0) and the oracle.
+# oracle and as the path MPIT_PS_CODEC_NATIVE=0 or a big-endian host
+# selects.  A build that FAILS raises: the shm transport needs the same
+# library, so a gang that runs at all has it, and a codec gone quietly
+# serial would only show up as a slower run.
 _NATIVE_ENV = "MPIT_PS_CODEC_NATIVE"
-_native_lib: Optional[object] = None  # None: untried; False: unavailable
+_native_lib: Optional[object] = None  # None: untried; False: switched off
 
 
 def _native():
@@ -68,13 +71,10 @@ def _native():
         if os.environ.get(_NATIVE_ENV, "1") == "0" or not _LITTLE:
             _native_lib = False
         else:
-            try:
-                from mpit_tpu.comm.native import build
-                from mpit_tpu.comm.native._bindings import NativeTransportLib
+            from mpit_tpu.comm.native import build
+            from mpit_tpu.comm.native._bindings import NativeTransportLib
 
-                _native_lib = NativeTransportLib(build.ensure_built())
-            except Exception:  # no g++ / unwritable tree: numpy fallback
-                _native_lib = False
+            _native_lib = NativeTransportLib(build.ensure_built())
     return _native_lib or None
 
 #: int8 per-block absmax granularity.  4 bytes of scale per 1024 codes

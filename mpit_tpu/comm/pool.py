@@ -24,7 +24,7 @@ Determinism is the design center, not an afterthought:
   bitwise equality is asserted per kernel x codec x chunk geometry x
   thread count by tests/test_pool.py.
 * **Serial is the same bytes, not a different path.**  With
-  ``MPIT_POOL_THREADS=0`` (or no compiled library) every submit runs
+  ``MPIT_POOL_THREADS=0`` (or the library switched off) every submit runs
   the kernel inline through the exact code the call site used before
   the pool existed, and returns an already-completed job.
 
@@ -148,7 +148,8 @@ def _done_job() -> Job:
 
 class WorkerPool:
     """One native worker pool plus the serial fallback that replaces it
-    byte-for-byte when ``threads == 0`` or the library is absent."""
+    byte-for-byte when ``threads == 0`` or the library is switched off
+    (``MPIT_PS_CODEC_NATIVE=0``; a failed build raises, comm/codec.py)."""
 
     def __init__(self, threads: Optional[int] = None):
         self.requested = configured_threads() if threads is None else threads
@@ -158,7 +159,7 @@ class WorkerPool:
         self._closed = False
         self._busy_sampled = 0.0
         if self.requested > 0:
-            lib = _load_native()
+            lib = codec_mod._native()
             if lib is not None:
                 self._lib = lib
                 self._pool = lib.mt_pool_start(self.requested)
@@ -301,7 +302,7 @@ class WorkerPool:
 
     def xor_sync(self, a: np.ndarray, b: np.ndarray,
                  out: np.ndarray) -> None:
-        lib = self._lib if self._lib is not None else _load_native()
+        lib = self._lib if self._lib is not None else codec_mod._native()
         if lib is not None:
             lib.mt_xor_bytes(a, b, out, int(a.nbytes))
         else:
@@ -313,7 +314,7 @@ class WorkerPool:
         """Single-pass fused fold when native is available; the numpy
         fallback keeps the identical association order (copyto then one
         ``+=`` per child, sorted caller-side), so both are bit-equal."""
-        lib = self._lib if self._lib is not None else _load_native()
+        lib = self._lib if self._lib is not None else codec_mod._native()
         if lib is not None and children:
             lib.mt_fold_f32(own, _child_ptrs(children), len(children),
                             out, int(own.size))
@@ -424,36 +425,6 @@ def _child_ptrs(children: Sequence[np.ndarray]) -> np.ndarray:
     (i.e. fold) order.  The native submit copies it again into the job,
     so its lifetime only needs to span the submit call."""
     return np.array([c.ctypes.data for c in children], dtype=np.uint64)
-
-
-_native_lib: Optional[object] = None  # None: untried; False: unavailable
-
-
-def _load_native():
-    """Shared native library, or None (no compiler / big-endian /
-    disabled): the pool then stays serial and tier-1 stays green.  A
-    stale .so fails the bindings' version-stamp check loudly; that
-    message is surfaced once via the module logger, never swallowed."""
-    global _native_lib
-    if _native_lib is None:
-        if os.environ.get(codec_mod._NATIVE_ENV, "1") == "0" \
-                or not codec_mod._LITTLE:
-            _native_lib = False
-        else:
-            try:
-                from mpit_tpu.comm.native import build
-                from mpit_tpu.comm.native._bindings import NativeTransportLib
-
-                _native_lib = NativeTransportLib(build.ensure_built())
-            except RuntimeError as exc:  # version-stamp mismatch: loud
-                from mpit_tpu.utils.logging import get_logger
-
-                get_logger("pool").warning(
-                    "native library unavailable (serial fallback): %s", exc)
-                _native_lib = False
-            except Exception:  # no g++ / unwritable tree: quiet fallback
-                _native_lib = False
-    return _native_lib or None
 
 
 _GLOBAL: Optional[WorkerPool] = None

@@ -32,13 +32,19 @@ class LmModel(NamedTuple):
     vocab: int
 
 
-def _resolve_flash(use_flash: Optional[bool]) -> bool:
-    """Default: the pallas kernel on TPU, the jnp reference elsewhere
-    (the reference path differentiates without a recompute pass, which
-    is the right trade on CPU gangs like the CI smoke)."""
-    if use_flash is not None:
-        return bool(use_flash)
-    return jax.default_backend() == "tpu"
+def _resolve_attn(use_flash: Optional[bool]):
+    """``use_flash`` None: the pallas kernel on TPU, the jnp reference
+    elsewhere (the reference path differentiates without a recompute
+    pass, which is the right trade on CPU gangs like the CI smoke).
+    True pins the kernel AND pins it compiled (``interpret=False``): a
+    run that asked for flash gets Mosaic's kernel or an error at
+    lowering, never the interpreter because the process quietly came up
+    on another backend.  False pins the reference."""
+    if use_flash is None:
+        return default_attn(causal=True,
+                            use_flash=jax.default_backend() == "tpu")
+    return default_attn(causal=True, use_flash=bool(use_flash),
+                        interpret=False if use_flash else None)
 
 
 def build(*, vocab: int = 256, d_model: int = 64, n_heads: int = 4,
@@ -51,7 +57,7 @@ def build(*, vocab: int = 256, d_model: int = 64, n_heads: int = 4,
     module = TinyDecoder(
         vocab=vocab, d_model=d_model, n_heads=n_heads, n_layers=n_layers,
         max_len=seq_len,
-        attn_fn=default_attn(causal=True, use_flash=_resolve_flash(use_flash)),
+        attn_fn=_resolve_attn(use_flash),
     )
     sample = jnp.zeros((1, seq_len), jnp.int32)
     fm = flatten_module(module, jax.random.PRNGKey(seed), sample)

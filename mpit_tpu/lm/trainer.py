@@ -45,7 +45,9 @@ LM_DEFAULTS = Config(
     n_heads=4,
     n_layers=2,
     seq_len=128,
-    use_flash=-1,  # -1 auto (flash on TPU, jnp reference elsewhere); 0/1 pin
+    # -1 auto (flash on TPU, jnp reference elsewhere) | 0 reference |
+    # 1 the Mosaic-compiled kernel or an error (lm/model.py _resolve_attn)
+    use_flash=-1,
     # optimizer (the MnistTrainer knob names, so launch configs carry over)
     opt="downpour",  # sgd|msgd|downpour|eamsgd|easgd|rmsprop|adam|adamax|
     #                  adagrad|adadelta (rule names are server-stateful)
@@ -163,9 +165,18 @@ class LmTrainer:
         if hasattr(opt, "start"):
             with self.tm.phase("start"):
                 self.w = opt.start(self.w)
+        mosaic_calls = None
+        if cfg.use_flash > 0:
+            # Evidence that the step's attention is the compiled kernel:
+            # a Pallas TPU kernel lowers to a ``tpu_custom_call``; an
+            # interpreted or reference attention has none.
+            mosaic_calls = jax.jit(self._vgf).lower(
+                self.w, jnp.asarray(self.stream.batch_at(0))
+            ).as_text().count("tpu_custom_call")
         history = []
         tokens_total = 0
         train_s = 0.0  # feval incl. blocking sync — the tokens/sec base
+        first_step_s = None  # the step that holds the compile
         window_losses = []
         with profiler_trace(cfg.get("profile_dir", "")):
             for step in range(cfg.steps):
@@ -174,6 +185,8 @@ class LmTrainer:
                 with self.tm.phase("feval"):
                     self.w, loss = opt.step(self.w, tokens)
                 train_s += time.monotonic() - t0
+                if first_step_s is None:
+                    first_step_s = train_s
                 tokens_total += tokens_per_step
                 window_losses.append(loss)
                 self._m_tokens.inc(tokens_per_step)
@@ -215,6 +228,8 @@ class LmTrainer:
             "tokens_total": tokens_total,
             "tokens_per_s": tokens_per_s,
             "train_seconds": train_s,
+            "first_step_seconds": first_step_s,
+            "mosaic_calls": mosaic_calls,
             "elapsed": self.tm.elapsed(),
             "timers": dict(self.tm.total),
             "steps": cfg.steps,

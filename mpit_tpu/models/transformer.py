@@ -28,14 +28,18 @@ from mpit_tpu.ops.flash_attention import attention_reference, flash_attention
 AttnFn = Callable[[jnp.ndarray, jnp.ndarray, jnp.ndarray], jnp.ndarray]
 
 
-def default_attn(causal: bool = True, use_flash: bool = True) -> AttnFn:
+def default_attn(causal: bool = True, use_flash: bool = True,
+                 interpret: Optional[bool] = None) -> AttnFn:
     """Single-device attention over (B, L, H, D): flash kernel or the jnp
-    reference (the latter differentiates without a recompute pass)."""
+    reference (the latter differentiates without a recompute pass).
+    ``interpret`` reaches ``pallas_call``: None interprets everywhere
+    but on a TPU (ops/tiles.py), False pins the Mosaic-compiled kernel."""
 
     def fn(q, k, v):
         qh, kh, vh = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
         if use_flash:
-            out = flash_attention(qh, kh, vh, causal=causal)
+            out = flash_attention(qh, kh, vh, causal=causal,
+                                  interpret=interpret)
         else:
             out = attention_reference(qh, kh, vh, causal=causal)
         return out.transpose(0, 2, 1, 3)

@@ -48,11 +48,6 @@ from mpit_tpu.ops.tiles import (
 
 NEG_INF = float("-inf")
 
-# jax renamed TPUCompilerParams -> CompilerParams across versions; accept
-# either so the kernels run on both sides of the rename.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams")
-
 # In-kernel running-max sentinel.  A FINITE very-negative value instead
 # of -inf: every `isneginf` guard in the hot loop disappears (exp of
 # (-1e30 - x) underflows to exactly 0, which is what the guards
@@ -90,7 +85,7 @@ def _fa_compiler_params(vmem_mb_auto: float = 0.0):
         kwargs["vmem_limit_bytes"] = int(vmem_mb * 2**20)
     if os.environ.get("MPIT_FA_DIMSEM", "1") != "0":
         kwargs["dimension_semantics"] = ("parallel", "arbitrary")
-    return _CompilerParams(**kwargs) if kwargs else None
+    return pltpu.CompilerParams(**kwargs) if kwargs else None
 
 
 def _vmem_auto(bq: int, bk: int) -> float:
@@ -321,11 +316,12 @@ def _fa_kernel(qoff_ref, kvoff_ref, kvlen_ref, q_ref, k_ref, v_ref, o_ref,
 
 
 def _default_blocks(dtype) -> Tuple[int, int]:
-    """Dtype-aware default tiles, chosen by on-chip sweep
-    (docs/KERNEL_BENCH.md): 1024x1024 for <=2-byte inputs (2.7x faster
-    than the old 256x512); 512x512 for f32 — the f32 backward at
-    1024-blocks sits at the scoped-VMEM edge and crashes the TPU
-    compiler inside larger programs (docs/tpu_compile_notes.md)."""
+    """Dtype-aware default tiles, from a July 2026 on-chip sweep the
+    ledger has not reproduced: 1024x1024 for <=2-byte inputs (2.7x
+    faster than the old 256x512 there); 512x512 for f32 — the f32
+    backward at 1024-blocks sits at the scoped-VMEM edge and crashes
+    the TPU compiler inside larger programs
+    (docs/tpu_compile_notes.md)."""
     return (1024, 1024) if jnp.dtype(dtype).itemsize <= 2 else (512, 512)
 
 
@@ -338,16 +334,19 @@ def _tile_dims(lq, lk, d, block_q, block_k, sm_scale, dtype,
     forward slices outputs back to true lq, and LSE/delta are per-row).
     ``block_q``/``block_k`` of None resolve to the dtype default.
 
-    ``fwd_long_bq`` (forward only): at Lq >= 16384 bf16 the 3-rep
-    on-chip A/B measured block_q=2048 faster than 1024 (16k: 4.90 vs
-    5.07 ms; 32k: 18.41 vs 19.00 ms, 60.6% MFU) while at 8k it is ~3%
-    slower (docs/KERNEL_BENCH.md §0.5), so the default grows with the
-    sequence.  MPIT_FA_LONG_BQ=0 pins the flat 1024 default.
+    Both length-aware defaults below came from July 2026 on-chip
+    sweeps the ledger has not reproduced.
+
+    ``fwd_long_bq`` (forward only): at Lq >= 16384 bf16 a 3-rep A/B
+    measured block_q=2048 faster than 1024 (16k: 4.90 vs 5.07 ms; 32k:
+    18.41 vs 19.00 ms) while at 8k it was ~3% slower, so the default
+    grows with the sequence.  MPIT_FA_LONG_BQ=0 pins the flat 1024
+    default.
 
     ``bwd_long_bk`` (backward, fused schedule only — callers pass the
     resolved ``fused`` flag): at Lk >= 32768 bf16 the 32k sweep
     measured block_k=2048 the clear backward winner (fwd+bwd 74.0 ->
-    63-67 ms; KERNEL_BENCH §0.5): fewer, wider kv blocks halve the
+    63-67 ms): fewer, wider kv blocks halve the
     fused schedule's dQ-partials transient (4 GB -> 2 GB on the bench
     shape, re-admitting the fused path under the auto budget) on top of
     the wider tile's intrinsic win over the 4 GB fused variant.  The
@@ -818,12 +817,12 @@ def _use_fused_bwd(q_shape, k_shape, d, dtype, sm_scale, block_q, block_k):
     fused only while its dQ-partials transient — (n_kv_blocks, Lq, D)
     f32 *per vmapped (batch, head) program, all live at once* — fits
     ``MPIT_FA_FUSED_BWD_MAX_MB`` (default 2048).  The fused sweep saves
-    2 of 7 matmuls per tile pair; the round-5 on-chip A/B
-    (docs/KERNEL_BENCH.md §0.6) measured it faster at every length
-    (-5.5% at 8k, -5.7% at 16k, -7.0% at 32k on the B=1 H=8 D=128
-    bench shape).  The budget admits the 1 GB transient at 16k and
-    refuses 4 GB; at 32k the length-aware bwd bk=2048 default (§0.5
-    sweep: fwd+bwd 74 -> 63-67 ms) halves the transient to exactly
+    2 of 7 matmuls per tile pair; a July 2026 on-chip A/B the ledger
+    has not reproduced measured it faster at every length (-5.5% at
+    8k, -5.7% at 16k, -7.0% at 32k on the B=1 H=8 D=128 bench shape).
+    The budget admits the 1 GB transient at 16k and refuses 4 GB; at
+    32k the length-aware bwd bk=2048 default halves the transient to
+    exactly
     2048 MB, so the bench shape now runs FUSED at 32k by default —
     shave ``MPIT_FA_FUSED_BWD_MAX_MB`` (or set
     ``MPIT_FA_LONG_BK_BWD=0``) to force the two-kernel schedule when a
@@ -946,11 +945,10 @@ def flash_attention(
     """Flash attention over ``(..., L, D)`` with global-offset causal
     masking.  Leading axes are batched (vmapped); offsets may be traced.
 
-    Default blocks are 1024x1024 (measured 2.7-3x faster than 256x512
-    on TPU v5e, docs/KERNEL_BENCH.md), growing to 2048x1024 at
-    L >= 16384 where the on-chip A/B measured it ~3% faster still
-    (§0.5; MPIT_FA_LONG_BQ=0 pins 1024 — the kernel auto-raises its
-    scoped-VMEM budget for the bigger score tile).  ``_tile_dims``
+    Default blocks are 1024x1024, growing to 2048x1024 at L >= 16384
+    (defaults from a July 2026 sweep on a v5e the ledger has not
+    reproduced; MPIT_FA_LONG_BQ=0 pins 1024 — the kernel auto-raises
+    its scoped-VMEM budget for the bigger score tile).  ``_tile_dims``
     clamps blocks for short sequences, so the default is safe at any L.
 
     ``precision``: MXU input precision for the two block matmuls (e.g.

@@ -17,7 +17,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from mpit_tpu.parallel.collective import shard_map  # version shim
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec
 
 from mpit_tpu.ops.fused_update import fused_nesterov_commit

@@ -20,7 +20,7 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from mpit_tpu.parallel.collective import shard_map  # version shim
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 

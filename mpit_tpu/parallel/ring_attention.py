@@ -42,7 +42,7 @@ from typing import Callable, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from mpit_tpu.parallel.collective import shard_map  # version shim
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from mpit_tpu.ops.flash_attention import (

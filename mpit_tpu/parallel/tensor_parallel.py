@@ -24,7 +24,7 @@ from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
-from mpit_tpu.parallel.collective import shard_map  # version shim
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 

@@ -213,13 +213,6 @@ def main(argv: Optional[List[str]] = None) -> None:
     if "--child" in argv:
         _child_main()
         return
-    # Honor JAX_PLATFORMS for the in-process np=1 path too (gang children
-    # already do): environments that pre-import an accelerator plugin
-    # otherwise ignore the env var and a CPU-intended run lands on the
-    # accelerator.
-    from mpit_tpu.utils.platform import honor_jax_platforms
-
-    honor_jax_platforms()
     cfg = BICNN_LAUNCH_DEFAULTS.parse_args(argv)
     # Fail fast in the parent: a bad optimizer name or role split discovered
     # only inside a child would strand its gang peers in the stop protocol.
@@ -244,19 +237,27 @@ def main(argv: Optional[List[str]] = None) -> None:
             )
     effective = min(int(cfg.np), int(cfg.maxrank) + 1)
     tester_flags = resolve_tester_flags(cfg)  # validate even for np=1
+    trainers = [0]  # maxrank=0 parks all but a local rank 0
     if effective > 1:
-        assign_roles(
+        _sranks, trainers, _tester, _tranks = assign_roles(
             effective, int(cfg.master_freq), *tester_flags,
             str(cfg.valid_mode),
         )
     t0 = time.monotonic()
     if int(cfg.np) == 1:
-        result = run_rank(0, 1, cfg, transport=None)
+        from mpit_tpu.utils.platform import device_report, enable_compile_cache
+
+        enable_compile_cache()
+        result = {**run_rank(0, 1, cfg, transport=None), **device_report()}
         print(json.dumps({"rank0": _summarize(result)}, indent=2))
     else:
-        from mpit_tpu.train.gang import launch_gang
+        from mpit_tpu.train.gang import assign_devices, launch_gang
 
-        results = launch_gang("mpit_tpu.train.bicnn_launch", cfg)
+        # A chip for every client (the tester is one); servers and
+        # parked ranks stay on the host.
+        results = launch_gang(
+            "mpit_tpu.train.bicnn_launch", cfg,
+            env_overrides=assign_devices(int(cfg.np), trainers))
         print(json.dumps(
             {str(r): _summarize(res) for r, res in sorted(results.items())},
             indent=2,

@@ -17,9 +17,50 @@ import subprocess
 import sys
 import tempfile
 import time
-from typing import Any, Dict, Optional
+from typing import Any, Dict, Optional, Sequence
 
 from mpit_tpu.utils.config import Config
+from mpit_tpu.utils.platform import count_local_chips, cpu_pinned
+
+# Importing this module (and the launchers built on it) initialises no
+# jax backend: the gang parent must stay off the chips its workers own.
+
+
+def assign_devices(
+    size: int, chip_owners: Sequence[int],
+) -> Dict[int, Dict[str, str]]:
+    """Per-rank device environment, applied BEFORE a child imports jax:
+    each rank of ``chip_owners`` (workers, a tester) gets a chip of its
+    own and sees no other; every other rank (servers, controller,
+    readers, cells, spares) is pinned to ``JAX_PLATFORMS=cpu`` and never
+    initialises the TPU backend — a chip belongs to one process.  The
+    map is a pure function of the roles, so a supervisor restart hands a
+    worker the chip its predecessor had.
+
+    Asking for more owners than the host has chips fails here, in the
+    parent, before any spawn.  A parent whose own environment pins the
+    CPU (``JAX_PLATFORMS=cpu``: tests, CI) assigns nothing — every rank
+    inherits it."""
+    if cpu_pinned():
+        return {}
+    chips = count_local_chips()
+    if len(chip_owners) > chips:
+        raise ValueError(
+            f"ranks {list(chip_owners)} each need a TPU chip of their own "
+            f"but this host has {chips}; start fewer workers, or set "
+            f"JAX_PLATFORMS=cpu to run the whole gang on the host")
+    env = {r: {"JAX_PLATFORMS": "cpu"} for r in range(size)}
+    for chip, rank in enumerate(chip_owners):
+        env[rank] = {
+            # tpu first: jax raises at backend start-up when the chip
+            # cannot be had, where an unset variable would quietly carry
+            # on on the CPU.
+            "JAX_PLATFORMS": "tpu,cpu",
+            "TPU_VISIBLE_CHIPS": str(chip),
+            "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_BOUNDS": "1,1,1",
+        }
+    return env
 
 
 def child_transport(cfg: Config, rank: int, size: int):
@@ -100,6 +141,11 @@ def spawn_rank(
     restarted incarnation continues the same rank log.  ``cfg`` is
     serialized per call, so a restart may carry a modified config
     (barrier off, resume on) without touching its gang-mates."""
+    # The parent builds the native library (a no-op once current), so
+    # children find it there instead of racing N compilers onto one path.
+    from mpit_tpu.comm.native.build import ensure_built
+
+    ensure_built()
     logpath = os.path.join(logdir, f"rank{rank}.log")
     resultpath = os.path.join(logdir, f"rank{rank}.result.json")
     env = {
@@ -207,23 +253,35 @@ def launch_gang(
 
 
 def child_env() -> tuple[int, int, Config]:
-    """(rank, size, cfg) from the gang environment, for ``--child`` mains.
+    """(rank, size, cfg) from the gang environment, for ``--child`` mains
+    — the one start-up path of every gang rank, so it also turns on the
+    compile cache and holds a chip owner to its chip: a rank whose
+    environment names a chip (:func:`assign_devices`) and whose jax did
+    not come up on the TPU raises here, before its role starts."""
+    from mpit_tpu.utils.platform import enable_compile_cache
 
-    Also applies the child's JAX_PLATFORMS assignment — a preloaded
-    accelerator plugin would otherwise override the env var and every
-    rank would contend for the same chip."""
-    from mpit_tpu.utils.platform import honor_jax_platforms
-
-    honor_jax_platforms()
+    enable_compile_cache()
     rank = int(os.environ["MPIT_RANK"])
     size = int(os.environ["MPIT_SIZE"])
     cfg = Config(**json.loads(os.environ["MPIT_CFG"]))
+    if "TPU_VISIBLE_CHIPS" in os.environ:
+        import jax
+
+        if jax.default_backend() != "tpu":
+            raise RuntimeError(
+                f"rank {rank} was assigned TPU chip "
+                f"{os.environ['TPU_VISIBLE_CHIPS']} but jax came up on "
+                f"{jax.default_backend()!r}")
     return rank, size, cfg
 
 
 def write_result(result: Dict[str, Any]) -> None:
-    """Results travel over a dedicated file, not stdout: log lines from
+    """Report one rank's result, stamped with the device it ran on.
+    Results travel over a dedicated file, not stdout: log lines from
     library threads could interleave with (and corrupt) a stdout protocol."""
+    from mpit_tpu.utils.platform import device_report
+
+    result = {**result, **device_report()}
     result_file = os.environ.get("MPIT_RESULT_FILE")
     if result_file:
         with open(result_file, "w") as fh:

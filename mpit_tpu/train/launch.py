@@ -44,9 +44,10 @@ LAUNCH_DEFAULTS = TRAINER_DEFAULTS.merged(
     ring_mb=64,
     namespace="",
     # Per-rank device assignment (the reference's AGPU map,
-    # mlaunch.lua:56-62): inherit | cpu | workers_accel (one compute rank
-    # — tester else first client — owns the accelerator, rest CPU).
-    device_policy="inherit",
+    # mlaunch.lua:56-62): workers_accel (each worker, and the tester,
+    # owns one TPU chip; every host role is pinned to the CPU backend —
+    # train/gang.py assign_devices) | cpu (every rank on the host).
+    device_policy="workers_accel",
     # Gang wire: shm (one host) | tcp (cross-host; tcp_addrs = one
     # host:port per rank, comma-separated — the hostfile analog).
     transport="shm",
@@ -214,7 +215,9 @@ LAUNCH_DEFAULTS = TRAINER_DEFAULTS.merged(
     lm_seq=128,
     lm_steps=200,
     lm_eval_every=50,
-    lm_use_flash=-1,  # -1 auto (flash on TPU) | 0 jnp reference | 1 flash
+    # -1 auto (flash on TPU) | 0 jnp reference | 1 the Pallas kernel
+    # compiled by Mosaic or an error (never interpreted — chip runs pin 1)
+    lm_use_flash=-1,
     lm_weights="",
     # Device-resident data plane (mpit_tpu.dplane; docs/DEVICE.md):
     # servers hold shard + optimizer state as (mesh-sharded) HBM arrays
@@ -932,46 +935,33 @@ def _child_main() -> None:
     from mpit_tpu.obs import maybe_write_rank_trace
 
     maybe_write_rank_trace(rank, role=str(result.get("role", "")))
-    import jax
-
-    result.setdefault("platform", jax.default_backend())
     write_result(result)
 
 
 def device_env_overrides(cfg: Config, size: int) -> Dict[int, Dict[str, str]]:
-    """Per-rank JAX_PLATFORMS assignment from cfg.device_policy."""
-    policy = cfg.get("device_policy", "inherit")
-    if policy == "inherit":
-        return {}
+    """Per-rank device environment from cfg.device_policy."""
+    policy = cfg.get("device_policy", "workers_accel")
     if policy == "cpu":
         return {r: {"JAX_PLATFORMS": "cpu"} for r in range(size)}
-    if policy == "workers_accel":
-        # Single-accelerator hosts: exactly ONE rank may own the chip
-        # (libtpu holds an exclusive lock) — the tester if present, else
-        # the first client; every other rank is forced to CPU.  Multi-chip
-        # hosts should pass per-rank visible-device env via launch_gang's
-        # env_overrides instead.  Under shardctl the last rank is the
-        # controller (a pure host role, never the accelerator owner);
-        # under --elastic the split runs over the initial membership
-        # (spare joiner slots are host roles).
-        role_size = int(cfg.get("elastic_np0", 0) or 0) or size
-        role_size = role_size - 1 if (bool(cfg.get("shardctl", False))
-                                      or bool(cfg.get("elastic", False))) \
-            else role_size
-        # readers and replica cells are host roles
-        role_size -= int(cfg.get("serve_readers", 0) or 0)
-        role_size -= int(cfg.get("cells", 0) or 0)
-        sranks, cranks, tester = assign_roles(
-            role_size, int(cfg.get("master_freq", 2)),
-            str(cfg.get("tester", "none"))
-        )
-        accel_rank = tester if tester is not None else cranks[0]
-        return {
-            r: {"JAX_PLATFORMS": "cpu"} for r in range(size) if r != accel_rank
-        }
-    raise ValueError(
-        f"device_policy must be inherit|cpu|workers_accel, got {policy!r}"
-    )
+    if policy != "workers_accel":
+        raise ValueError(
+            f"device_policy must be workers_accel|cpu, got {policy!r}")
+    from mpit_tpu.train.gang import assign_devices
+
+    # The chip owners are the ranks that train or test.  Under shardctl
+    # the last rank is the controller; under --elastic the split runs
+    # over the initial membership (spare joiner slots are servers);
+    # readers and replica cells sit past the role ranks — host roles all.
+    role_size = int(cfg.get("elastic_np0", 0) or 0) or size
+    if bool(cfg.get("shardctl", False)) or bool(cfg.get("elastic", False)):
+        role_size -= 1
+    role_size -= int(cfg.get("serve_readers", 0) or 0)
+    role_size -= int(cfg.get("cells", 0) or 0)
+    _sranks, cranks, tester = assign_roles(
+        role_size, int(cfg.get("master_freq", 2)),
+        str(cfg.get("tester", "none")))
+    return assign_devices(
+        size, cranks + ([tester] if tester is not None else []))
 
 
 def launch_processes(cfg: Config, timeout: float = 3600.0) -> Dict[int, Dict[str, Any]]:
@@ -1089,18 +1079,15 @@ def main(argv: Optional[List[str]] = None) -> None:
     if "--child" in argv:
         _child_main()
         return
-    # Honor JAX_PLATFORMS for the in-process np=1 path (gang children
-    # already do via train.gang).
-    from mpit_tpu.utils.platform import honor_jax_platforms
-
-    honor_jax_platforms()
     cfg = LAUNCH_DEFAULTS.parse_args(argv)
     t0 = time.monotonic()
     if int(cfg.np) == 1:
         from mpit_tpu.obs import maybe_start_statusd
+        from mpit_tpu.utils.platform import device_report, enable_compile_cache
 
+        enable_compile_cache()
         maybe_start_statusd(0, role="local")
-        result = run_rank(0, 1, cfg, transport=None)
+        result = {**run_rank(0, 1, cfg, transport=None), **device_report()}
         from mpit_tpu.obs import maybe_merge_rank_traces, maybe_write_rank_trace
 
         maybe_write_rank_trace(0, role=str(result.get("role", "")))
@@ -1121,7 +1108,10 @@ def _summarize(result: Dict[str, Any]) -> Dict[str, Any]:
     keep = {"role", "final_test_err", "time_to_target", "elapsed",
             "grads_applied", "params_served", "best_test_err",
             "reads", "monotone", "busy_honored",
-            "final_loss", "final_eval_loss", "tokens_per_s", "tokens_total"}
+            "final_loss", "final_eval_loss", "tokens_per_s", "tokens_total",
+            "steps", "train_seconds", "first_step_seconds", "mosaic_calls",
+            "platform", "device_kind", "device_count", "device_ids",
+            "chip_nodes"}
     return {k: v for k, v in result.items() if k in keep}
 
 

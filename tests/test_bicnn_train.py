@@ -347,7 +347,7 @@ class TestTopologies:
 def test_docqa_real_corpus_learns_above_chance():
     """BiCNN on the committed REAL corpus (stdlib docstrings): pool size
     is 20, chance = 5%; the recorded full run (8 epochs, 200 filters)
-    reaches 58-66% (docs/NORTHSTAR_r4.md) — this bounded version must
+    reached 58-66% (builder run, round 4) — this bounded version must
     clear 8x chance."""
     from mpit_tpu.data.qa import DOCQA_EMBEDDING_DIM, docqa_paths
     from mpit_tpu.data.qa import load_qa

@@ -276,3 +276,32 @@ class TestMultiProcess:
             assert peer.wait(60) == 0
         finally:
             main.close()
+
+
+class TestBuild:
+    """comm/native/build.py decides from content, not mtimes."""
+
+    def test_rebuilds_on_hash_mismatch_though_library_is_newer(
+            self, monkeypatch):
+        from mpit_tpu.comm.native import build
+
+        lib = build.ensure_built()
+        assert build.STAMP.read_text().strip() == build.source_hash()
+        calls = []
+        real_run = subprocess.run
+        monkeypatch.setattr(
+            build.subprocess, "run",
+            lambda cmd, **kw: calls.append(cmd) or real_run(cmd, **kw))
+        build.ensure_built()
+        assert not calls  # current: no compiler run
+        # A library from other flags/another CPU: newer than the source,
+        # recorded hash differs -> rebuilt, via a temporary name.
+        build.STAMP.write_text("0" * 64 + "\n")
+        os.utime(lib)  # mtime now >= the source's
+        assert lib.stat().st_mtime >= build.SRC.stat().st_mtime
+        build.ensure_built()
+        (cmd,) = calls
+        out = cmd[cmd.index("-o") + 1]
+        assert out != str(lib) and os.path.dirname(out) == str(lib.parent)
+        assert not os.path.exists(out)  # renamed into place
+        assert build.STAMP.read_text().strip() == build.source_hash()

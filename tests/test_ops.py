@@ -188,7 +188,8 @@ def test_flash_grad_matches_reference(rng, fa_backward_path):
 
 
 def test_fwd_long_bq_block_routing(monkeypatch):
-    """Length-aware forward default (KERNEL_BENCH §0.5 A/B): block_q
+    """Length-aware forward default (from a July 2026 sweep the ledger
+    has not reproduced): block_q
     grows to 2048 at Lq >= 16384 bf16 — forward only, explicit blocks
     and the env kill-switch win, f32 keeps its 512 default."""
     from mpit_tpu.ops.flash_attention import _tile_dims
@@ -212,7 +213,8 @@ def test_fwd_long_bq_block_routing(monkeypatch):
 
 def test_bwd_long_bk_block_routing(monkeypatch):
     """Backward default block_k grows to 2048 at Lk >= 32768 bf16 (the
-    32k sweep's winner, KERNEL_BENCH §0.5) — and the fused-schedule gate
+    winner of a July 2026 32k sweep the ledger has not reproduced) —
+    and the fused-schedule gate
     resolves the SAME bk, so its dQ-partials transient estimate matches
     the schedule that actually runs (2 GB at 32k on the bench shape,
     admitted by the 2048 MB budget)."""
@@ -309,8 +311,8 @@ def test_fused_bwd_auto_gate(monkeypatch):
     assert _use_fused_bwd(*args) is False
     monkeypatch.delenv("MPIT_FA_FUSED_BWD_MAX_MB", raising=False)
     # 16k, 8 heads: 16 * 16384 * 128 * 4 x 8 = 1 GB — admitted by the
-    # round-5 budget (the on-chip A/B measured fused 5.7% faster here;
-    # KERNEL_BENCH §0.6).
+    # round-5 budget (a July 2026 A/B the ledger has not reproduced
+    # measured fused 5.7% faster here).
     args16 = ((1, 8, 16384, 128), (1, 8, 16384, 128), 128, jnp.bfloat16,
               None, None, None)
     assert _use_fused_bwd(*args16) is True

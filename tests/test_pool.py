@@ -16,9 +16,9 @@ Two suites for the ISSUE 17 seam:
   exit, any submit after close raises :class:`PoolClosedError` loudly
   (serial pools included), and 32 open/close cycles leak no OS thread.
 
-The parity suite needs the compiled library; without a toolchain it
-skips (the serial fallback is then the only path, and tier-1 stays
-green by construction).
+The parity suite needs the compiled library; it skips only where the
+library is switched off (MPIT_PS_CODEC_NATIVE=0) — a failed build
+raises at collection.
 """
 
 import numpy as np
@@ -27,11 +27,11 @@ import pytest
 from mpit_tpu.comm import codec as codec_mod
 from mpit_tpu.comm import pool as pool_mod
 
-HAVE_NATIVE = pool_mod._load_native() is not None
+HAVE_NATIVE = codec_mod._native() is not None
 
 pooled = pytest.mark.skipif(
     not HAVE_NATIVE,
-    reason="native pool library unavailable (serial fallback only)")
+    reason="native library switched off (MPIT_PS_CODEC_NATIVE=0)")
 
 BLOCK = codec_mod.BLOCK
 #: one BLOCK-aligned shard, one tailed (size % BLOCK != 0) shard

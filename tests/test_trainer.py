@@ -176,23 +176,103 @@ class TestTopologies:
 
 
 class TestDevicePolicy:
-    def test_overrides_shapes(self):
+    """The per-rank device assignment the chip run depends on
+    (train/gang.py assign_devices): a chip for each worker, host roles
+    pinned to the CPU backend, all decided in the parent."""
+
+    @staticmethod
+    def _host(monkeypatch, chips, platforms=None):
+        from mpit_tpu.train import gang
+
+        monkeypatch.setattr(gang, "count_local_chips", lambda: chips)
+        if platforms is None:
+            monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+        else:
+            monkeypatch.setenv("JAX_PLATFORMS", platforms)
+
+    def test_one_chip_one_worker(self, monkeypatch):
         from mpit_tpu.train.launch import LAUNCH_DEFAULTS, device_env_overrides
 
-        cfg = LAUNCH_DEFAULTS.merged(np=4)
-        assert device_env_overrides(cfg, 4) == {}
-        cfg = cfg.merged(device_policy="cpu")
+        self._host(monkeypatch, chips=1, platforms="tpu,cpu")
+        ov = device_env_overrides(LAUNCH_DEFAULTS.merged(np=3), 3)
+        # master_freq=2: servers 0 and 2 are host roles, worker 1 owns
+        # the chip and sees no other.
+        assert ov[0] == ov[2] == {"JAX_PLATFORMS": "cpu"}
+        assert ov[1]["TPU_VISIBLE_CHIPS"] == "0"
+        assert ov[1]["JAX_PLATFORMS"].split(",")[0] == "tpu"
+        assert ov[1]["TPU_PROCESS_BOUNDS"] == "1,1,1"
+        assert ov[1]["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1"
+
+    def test_four_chips_four_workers(self, monkeypatch):
+        from mpit_tpu.train.launch import LAUNCH_DEFAULTS, device_env_overrides
+
+        self._host(monkeypatch, chips=4)
+        cfg = LAUNCH_DEFAULTS.merged(np=6, master_freq=3)
+        ov = device_env_overrides(cfg, 6)
+        assert ov[0] == ov[3] == {"JAX_PLATFORMS": "cpu"}
+        chips = [ov[r]["TPU_VISIBLE_CHIPS"] for r in (1, 2, 4, 5)]
+        assert sorted(chips) == ["0", "1", "2", "3"]
+        # a pure function of the roles: a supervisor restart re-reads
+        # the same map, so a worker gets its predecessor's chip
+        assert device_env_overrides(cfg, 6) == ov
+
+    def test_tester_owns_a_chip_host_roles_do_not(self, monkeypatch):
+        from mpit_tpu.train.launch import LAUNCH_DEFAULTS, device_env_overrides
+
+        self._host(monkeypatch, chips=2)
+        # ranks 0..3 split (workers 1 and 3), 4 = controller
+        cfg = LAUNCH_DEFAULTS.merged(np=5, shardctl=True)
+        ov = device_env_overrides(cfg, 5)
+        assert [r for r in ov if "TPU_VISIBLE_CHIPS" in ov[r]] == [1, 3]
+        assert ov[4] == {"JAX_PLATFORMS": "cpu"}
+        cfg = LAUNCH_DEFAULTS.merged(np=3, tester="last")
+        ov = device_env_overrides(cfg, 3)
+        assert {r for r in ov if "TPU_VISIBLE_CHIPS" in ov[r]} == {1, 2}
+
+    def test_more_workers_than_chips_fails_in_parent(self, monkeypatch):
+        from mpit_tpu.train.launch import LAUNCH_DEFAULTS, launch_processes
+
+        self._host(monkeypatch, chips=1)
+        cfg = LAUNCH_DEFAULTS.merged(np=4, opt="downpour")
+        spawned = []
+        monkeypatch.setattr("mpit_tpu.train.gang.spawn_rank",
+                            lambda *a, **k: spawned.append(a))
+        with pytest.raises(ValueError, match=r"\[1, 3\].*has 1"):
+            launch_processes(cfg, timeout=5)
+        assert not spawned
+
+    def test_cpu_parent_only_inherits(self, monkeypatch):
+        from mpit_tpu.train.launch import LAUNCH_DEFAULTS, device_env_overrides
+
+        # chips or not: a parent pinned to the CPU assigns nothing
+        self._host(monkeypatch, chips=4, platforms="cpu")
+        assert device_env_overrides(LAUNCH_DEFAULTS.merged(np=4), 4) == {}
+
+    def test_cpu_policy_and_unknown_policy(self, monkeypatch):
+        from mpit_tpu.train.launch import LAUNCH_DEFAULTS, device_env_overrides
+
+        self._host(monkeypatch, chips=4)
+        cfg = LAUNCH_DEFAULTS.merged(np=4, device_policy="cpu")
         ov = device_env_overrides(cfg, 4)
         assert set(ov) == {0, 1, 2, 3}
         assert all(v == {"JAX_PLATFORMS": "cpu"} for v in ov.values())
-        cfg = cfg.merged(device_policy="workers_accel")
-        ov = device_env_overrides(cfg, 4)
-        # master_freq=2: even ranks are servers; of the clients {1, 3}
-        # only the first keeps the accelerator -> all but rank 1 forced.
-        assert set(ov) == {0, 2, 3}
-        import pytest as _pytest
-        with _pytest.raises(ValueError, match="device_policy"):
-            device_env_overrides(cfg.merged(device_policy="gpu4"), 4)
+        with pytest.raises(ValueError, match="device_policy"):
+            device_env_overrides(cfg.merged(device_policy="inherit"), 4)
+
+    def test_bicnn_gang_goes_through_the_same_assignment(self, monkeypatch):
+        from mpit_tpu.train import bicnn_launch, gang
+
+        self._host(monkeypatch, chips=2)
+        seen = {}
+        monkeypatch.setattr(
+            gang, "launch_gang",
+            lambda module, cfg, env_overrides=None: seen.update(
+                env=env_overrides) or {})
+        bicnn_launch.main(["--np", "4", "--optimization", "downpour",
+                           "--valid_mode", "none"])
+        assert {r for r, e in seen["env"].items()
+                if "TPU_VISIBLE_CHIPS" in e} == {1, 3}
+        assert seen["env"][0] == seen["env"][2] == {"JAX_PLATFORMS": "cpu"}
 
     @pytest.mark.slow
     def test_gang_applies_policy(self, monkeypatch):
@@ -211,6 +291,40 @@ class TestDevicePolicy:
         results = launch_processes(cfg, timeout=600)
         assert set(results) == {0, 1}
         assert all(r.get("platform") == "cpu" for r in results.values())
+
+
+class TestChipSmoke:
+    def test_refuses_on_cpu_without_spawning(self):
+        """JAX_PLATFORMS=cpu: non-zero, one line, no result line — and it
+        returns before anything could have been spawned or compiled."""
+        import os
+        import subprocess
+        import sys
+
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        out = subprocess.run(
+            [sys.executable, "chip_smoke.py"], cwd=repo,
+            env={**os.environ, "JAX_PLATFORMS": "cpu"},
+            capture_output=True, text=True, timeout=60)
+        assert out.returncode != 0
+        assert out.stdout == ""
+        assert len(out.stderr.strip().splitlines()) == 1
+
+    def test_launchers_import_no_backend(self):
+        """The gang parent must stay off jax: a parent that initialised a
+        backend would hold the chip its worker needs."""
+        import os
+        import subprocess
+        import sys
+
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        code = (
+            "import chip_smoke, mpit_tpu.train.launch, "
+            "mpit_tpu.train.bicnn_launch, mpit_tpu.train.gang\n"
+            "from jax._src import xla_bridge\n"
+            "assert not xla_bridge.backends_are_initialized()\n")
+        subprocess.run([sys.executable, "-c", code], cwd=repo, check=True,
+                       timeout=120)
 
 
 @pytest.mark.slow

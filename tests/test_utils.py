@@ -112,3 +112,58 @@ class TestCheckpoint:
         np.testing.assert_array_equal(
             w2.astype(np.float32), w.astype(np.float32)
         )
+
+
+class TestCompileCache:
+    """utils/platform.enable_compile_cache: one function, and the cache
+    can be placed from outside."""
+
+    @pytest.fixture
+    def cache_dir_config(self):
+        import jax
+
+        before = jax.config.jax_compilation_cache_dir
+        yield
+        jax.config.update("jax_compilation_cache_dir", before)
+
+    def test_env_dir_is_left_to_jax(self, monkeypatch, tmp_path,
+                                    cache_dir_config):
+        import jax
+
+        from mpit_tpu.utils.platform import enable_compile_cache
+
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        jax.config.update("jax_compilation_cache_dir", "sentinel")
+        assert enable_compile_cache() == str(tmp_path)
+        # no directory set in code: jax reads the variable itself
+        assert jax.config.jax_compilation_cache_dir == "sentinel"
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
+
+    def test_default_is_the_checkout(self, monkeypatch, cache_dir_config):
+        import pathlib
+
+        import jax
+
+        from mpit_tpu.utils.platform import enable_compile_cache
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        want = str(pathlib.Path(__file__).resolve().parents[1] / ".jax_cache")
+        assert enable_compile_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want
+
+
+class TestChipNodes:
+    def test_chip_node_pattern(self):
+        from mpit_tpu.utils.platform import _CHIP_NODE
+
+        assert _CHIP_NODE.match("/dev/vfio/2")
+        assert _CHIP_NODE.match("/dev/accel0")
+        assert not _CHIP_NODE.match("/dev/vfio/vfio")
+        assert not _CHIP_NODE.match("/dev/vfio/2/x")
+
+    def test_cpu_process_holds_none(self):
+        from mpit_tpu.utils.platform import device_report
+
+        rep = device_report()
+        assert rep["platform"] == "cpu" and rep["chip_nodes"] == []
+        assert rep["device_count"] == len(rep["device_ids"])

@@ -26,9 +26,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import numpy as np
 
 from benchmarks._common import emit_json, log as _log
-from mpit_tpu.utils.platform import honor_jax_platforms
-
-honor_jax_platforms()
 
 SEEDS = [int(s) for s in os.environ.get("MPIT_ACC_SEEDS", "0,1,2").split(",")]
 LEGS = os.environ.get("MPIT_ACC_LEGS", "docqa,flagship").split(",")
@@ -44,7 +41,7 @@ def _stats(xs):
 
 
 def leg_docqa() -> dict:
-    """The NORTHSTAR_r4 docqa config (real stdlib-docstring corpus),
+    """The round-4 docqa config (real stdlib-docstring corpus),
     per seed: sgd, 8 epochs, 200 filters."""
     from mpit_tpu.train.bicnn import BICNN_DEFAULTS, BiCNNTrainer
 
