@@ -47,6 +47,12 @@ def _resolve_attn(use_flash: Optional[bool]):
                         interpret=False if use_flash else None)
 
 
+def vocab_kw(vocab: int) -> dict:
+    """``build``'s vocabulary keyword for a ``--lm_vocab`` value: 0
+    leaves ``build``'s own keyword default in force."""
+    return {"vocab": int(vocab)} if vocab else {}
+
+
 def build(*, vocab: int = 256, d_model: int = 64, n_heads: int = 4,
           n_layers: int = 2, seq_len: int = 128, seed: int = 0,
           use_flash: Optional[bool] = None) -> LmModel:
@@ -66,8 +72,9 @@ def build(*, vocab: int = 256, d_model: int = 64, n_heads: int = 4,
         # tokens: (B, seq_len + 1) int32 — packed, every cell real.
         inputs, targets = tokens[:, :-1], tokens[:, 1:]
         logp = fm.apply_flat(w, inputs)  # (B, L, V) log-probs
-        nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)
-        return jnp.mean(nll)
+        with jax.named_scope("head_loss"):
+            nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)
+            return jnp.mean(nll)
 
     return LmModel(module=module, flat=fm, loss=loss,
                    value_and_grad=jax.value_and_grad(loss),

@@ -32,7 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from mpit_tpu.lm.data import PackedStream
-from mpit_tpu.lm.model import build
+from mpit_tpu.lm.model import build, vocab_kw
 from mpit_tpu.obs import PhaseTimers, get_registry, profiler_trace
 from mpit_tpu.optim import EAMSGD, MSGD, Downpour, RuleShell
 from mpit_tpu.optim.msgd import MSGDConfig
@@ -40,11 +40,15 @@ from mpit_tpu.utils.config import Config
 from mpit_tpu.utils.logging import get_logger
 
 LM_DEFAULTS = Config(
-    # model (vocab is pinned to the byte stream's 256)
+    # model
     d_model=64,
     n_heads=4,
     n_layers=2,
     seq_len=128,
+    # rows of the token table and the head (--lm_vocab); 0 = build's own
+    # keyword default, the byte stream's 256, whose ids index the first
+    # rows of a larger table
+    vocab=0,
     # -1 auto (flash on TPU, jnp reference elsewhere) | 0 reference |
     # 1 the Mosaic-compiled kernel or an error (lm/model.py _resolve_attn)
     use_flash=-1,
@@ -91,6 +95,7 @@ class LmTrainer:
         self.model = build(
             d_model=cfg.d_model, n_heads=cfg.n_heads, n_layers=cfg.n_layers,
             seq_len=cfg.seq_len, seed=cfg.seed, use_flash=use_flash,
+            **vocab_kw(cfg.vocab),
         )
         dtype = jnp.dtype(cfg.dtype)
         self.w = self.model.flat.w0.astype(dtype)
@@ -209,7 +214,9 @@ class LmTrainer:
                     self.log.info(
                         "step %d avg_loss %.5f eval_loss %.5f tok/s %.0f",
                         step, avg_loss, ev, tps)
-        sync_time = getattr(opt, "dusync", 0.0)
+        # the optimizer's seconds at the ParamClientAPI boundary (its
+        # round.exchange phases; a plain timer there with obs off)
+        sync_time = getattr(opt, "sync_seconds", 0.0)
         self.tm.add("sync", sync_time)
         # feval net of blocking sync, like MnistTrainer — but tokens/sec
         # keeps the sync in its denominator (a stalled worker earns no

@@ -21,6 +21,7 @@ from __future__ import annotations
 from typing import Callable, Optional
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 
 from mpit_tpu.ops.flash_attention import attention_reference, flash_attention
@@ -59,19 +60,24 @@ class DecoderBlock(nn.Module):
         head = self.d_model // self.n_heads
         attn = self.attn_fn if self.attn_fn is not None else default_attn()
 
-        h = nn.LayerNorm()(x)
-        qkv = nn.Dense(3 * self.d_model, use_bias=False)(h)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        q = q.reshape(b, l, self.n_heads, head)
-        k = k.reshape(b, l, self.n_heads, head)
-        v = v.reshape(b, l, self.n_heads, head)
-        x = x + nn.Dense(self.d_model, use_bias=False)(
-            attn(q, k, v).reshape(b, l, self.d_model)
-        )
+        # The scopes name the model's layers on the device operations
+        # of a profiler trace (forward and backward alike); they are
+        # metadata and change no program.
+        with jax.named_scope("attn"):
+            h = nn.LayerNorm()(x)
+            qkv = nn.Dense(3 * self.d_model, use_bias=False)(h)
+            q, k, v = jnp.split(qkv, 3, axis=-1)
+            q = q.reshape(b, l, self.n_heads, head)
+            k = k.reshape(b, l, self.n_heads, head)
+            v = v.reshape(b, l, self.n_heads, head)
+            x = x + nn.Dense(self.d_model, use_bias=False)(
+                attn(q, k, v).reshape(b, l, self.d_model)
+            )
 
-        h = nn.LayerNorm()(x)
-        h = nn.gelu(nn.Dense(self.mlp_ratio * self.d_model)(h))
-        return x + nn.Dense(self.d_model)(h)
+        with jax.named_scope("mlp"):
+            h = nn.LayerNorm()(x)
+            h = nn.gelu(nn.Dense(self.mlp_ratio * self.d_model)(h))
+            return x + nn.Dense(self.d_model)(h)
 
 
 class TinyDecoder(nn.Module):
@@ -94,14 +100,16 @@ class TinyDecoder(nn.Module):
             # Fail at trace time: out-of-range position gathers clamp
             # under jit and would silently reuse the last embedding row.
             raise ValueError(f"sequence length {l} > max_len {self.max_len}")
-        x = nn.Embed(self.vocab, self.d_model)(tokens)
-        pos = nn.Embed(self.max_len, self.d_model)(jnp.arange(l))
-        x = x + pos[None, :, :]
+        with jax.named_scope("embed"):
+            x = nn.Embed(self.vocab, self.d_model)(tokens)
+            pos = nn.Embed(self.max_len, self.d_model)(jnp.arange(l))
+            x = x + pos[None, :, :]
         for _ in range(self.n_layers):
             x = DecoderBlock(
                 d_model=self.d_model, n_heads=self.n_heads,
                 attn_fn=self.attn_fn,
             )(x)
-        x = nn.LayerNorm()(x)
-        logits = nn.Dense(self.vocab, use_bias=False)(x)
-        return nn.log_softmax(logits)
+        with jax.named_scope("head_loss"):  # lm/model.py's NLL joins it
+            x = nn.LayerNorm()(x)
+            logits = nn.Dense(self.vocab, use_bias=False)(x)
+            return nn.log_softmax(logits)

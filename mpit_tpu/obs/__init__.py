@@ -18,7 +18,12 @@ counters.  This package is the one place the stack reports through:
   start/end, per-phase marks (encode → send → ack on the client,
   apply → ack on the server), its ``[epoch, seq]`` identity and an
   outcome, so a straggling or retried op is attributable to a phase
-  and a peer.  Scheduler task lifecycles record alongside.
+  and a peer.  Scheduler task lifecycles record alongside.  A worker's
+  sync round is one ``round`` span whose phases tile it (d2h, the
+  exchange, h2d), every op carries its per-channel ordinal so both
+  halves join without the framed wire, and the round's
+  ``mpit.round`` profiler annotation anchors the spans on a device
+  trace's clock.
 - :mod:`mpit_tpu.obs.trace` — a **Chrome trace-event exporter**: spans
   plus task lifecycles dump as trace JSON (one pid per rank, one tid
   per op channel / task), merged across ranks by the gang launcher at

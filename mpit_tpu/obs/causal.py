@@ -14,7 +14,14 @@ connects.  This module is the offline joiner that connects them:
    seq)`` — into a *causal chain*.  A retried op contributes one client
    span (its attempts segmented by the ``backoff`` marks) and every
    server span its frames produced (the apply plus any dup re-acks).
-3. **Align clocks.**  Cross-rank subtractions use the per-pair offset:
+   An op of the unframed wire has no such identity; its halves join on
+   the per-channel ordinal ``n`` both recorders count (obs/spans.py):
+   ``(op, client rank, server rank, "ord", n)``, exact because the
+   channels are strictly sequential and nothing is retried there.
+3. **Align clocks.**  Ranks whose trace parts name the same
+   ``clock_id`` (one host, one ``time.monotonic``) differ by their
+   recorded ``epoch_offset``s exactly.  Otherwise cross-rank
+   subtractions use the per-pair offset:
    primarily the FLAG_TIMING estimator state embedded in
    ``otherData.clock`` (obs/clock.py), falling back to the same
    minimum-RTT estimate derived from the joined span pairs themselves
@@ -59,6 +66,9 @@ from mpit_tpu.obs.clock import PeerClock
 #: whatever clock error the uncertainty bound absorbs).
 PHASES = ("encode", "send_queue", "wire", "stream", "server_queue",
           "apply", "ack_wire", "retry", "client_wait")
+
+#: stands in for the epoch in the key of a chain joined by ordinal
+ORDINAL = "ord"
 
 #: ops the joiner considers (framed PS data ops; MIGRATE spans carry no
 #: [epoch, seq] and are not point-to-point client ops).
@@ -155,7 +165,10 @@ def _chain_key(span: Span):
     a = span.args
     epoch, seq = a.get("epoch"), a.get("seq")
     if epoch is None or seq is None:
-        return None
+        # The unframed wire: the per-channel ordinal is the identity.
+        if a.get("n") is None or "shard" in a:
+            return None
+        epoch, seq = ORDINAL, a["n"]
     if span.side == "client":
         client = a.get("rank", span.pid)
         server = (("shard", a["shard"]) if "shard" in a
@@ -296,6 +309,25 @@ def _server_rank(chain: Chain):
     return val if kind == "srv" else None
 
 
+def shared_clock_offsets(other_data: dict) -> Dict[Tuple[int, int], float]:
+    """(rank a, rank b) -> exported clock of b minus exported clock of
+    a in µs, for every pair of ranks whose parts name one ``clock_id``:
+    they read one monotonic clock, so their exported timestamps differ
+    by their epoch offsets and by nothing else."""
+    ranks = []
+    for rank, info in (other_data.get("ranks") or {}).items():
+        if isinstance(info, dict) and "epoch_offset" in info \
+                and info.get("clock_id"):
+            try:
+                ranks.append((int(rank), info["clock_id"],
+                              float(info["epoch_offset"])))
+            except (TypeError, ValueError):
+                continue
+    return {(a, b): (off_b - off_a) * 1e6
+            for a, id_a, off_a in ranks for b, id_b, off_b in ranks
+            if a != b and id_a == id_b}
+
+
 def recorded_offsets(other_data: dict) -> Dict[Tuple[int, int], dict]:
     """(client, server) -> estimate from the trace's embedded
     FLAG_TIMING estimator state (otherData.clock, obs/clock.py)."""
@@ -323,6 +355,7 @@ class OffsetTable:
     ones otherwise."""
 
     def __init__(self, chains: List[Chain], other_data: dict):
+        self.shared = shared_clock_offsets(other_data)
         self.recorded = recorded_offsets(other_data)
         self.derived = derive_offsets(chains)
 
@@ -330,6 +363,9 @@ class OffsetTable:
         """(offset_us, uncertainty_us, source) — offset is server minus
         client; unknown pairs fall back to (0, inf) so their phases are
         reported but never counted as violations."""
+        shared = self.shared.get((client, server))
+        if shared is not None:
+            return shared, 0.0, "monotonic"
         est = self.recorded.get((client, server))
         if est is not None:
             return (float(est["offset_us"]), float(est["uncertainty_us"]),
@@ -537,7 +573,11 @@ def streaming_overlap(chain: Chain,
     if chunks < 2:
         return None
     flush = client.mark_ts("flush")
-    first_apply = server.mark_ts("apply", last=False)
+    # a chunk's fold-in begins at its ``copy`` mark (``apply`` in traces
+    # from before the server split that phase)
+    first_apply = server.mark_ts("copy", last=False)
+    if first_apply is None:
+        first_apply = server.mark_ts("apply", last=False)
     if flush is None or first_apply is None:
         return None
     offset, unc, source = offsets.lookup(

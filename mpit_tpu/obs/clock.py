@@ -61,6 +61,20 @@ def epoch_offset() -> float:
     return _EPOCH_OFFSET
 
 
+def clock_id() -> str:
+    """Which monotonic clock this process reads: the host's boot id.
+    Two ranks with the same id share ``time.monotonic`` exactly, so the
+    difference of their epoch offsets is their whole clock offset (the
+    gangs of this repo run on one host; obs/causal.py uses it)."""
+    try:
+        with open("/proc/sys/kernel/random/boot_id") as fh:
+            return fh.read().strip()
+    except OSError:
+        import socket
+
+        return socket.gethostname()
+
+
 def wall_us() -> int:
     """Wall-clock microseconds on the process time base — the stamp the
     FLAG_TIMING wire carries (int64-friendly)."""

@@ -15,6 +15,22 @@ kinds:
 - **Task lifecycles**: the cooperative scheduler records each task's
   spawn→completion window and terminal state — service loops, pumps,
   and reapers show up as rows in the exported trace.
+- **Round spans** (:meth:`SpanRecorder.round`): the parent of one sync
+  round of a worker, phase-marked by the optimizer shell
+  (``wait_backward`` → ``d2h`` → ``stage`` → ``exchange`` → ``h2d`` →
+  ``telemetry``) so its children tile it.  While it is open, every
+  client op span this thread begins carries ``round=k``; the span also
+  enters one ``jax.profiler.TraceAnnotation("mpit.round", round=k,
+  mono_ns=...)``, whose ``mono_ns`` is the span's own begin stamp: the
+  pair (profiler timestamp, monotonic timestamp) that puts the spans
+  and a device trace on one clock (docs/OBSERVABILITY.md, *One
+  clock*).
+
+Every op span carries ``n``, its ordinal on its channel (``tid``).  The
+channels are strictly sequential on both sides, so the client half and
+the server half of one op share (client rank, server rank, op, ``n``)
+even on the unframed wire, where no ``[epoch, seq]`` identity exists;
+obs/causal.py joins on it.
 
 The recorder owns every clock read.  Role files (``ps/``, ``ft/``,
 ``comm/``) never call ``time.monotonic()`` to measure — the MT-O4xx
@@ -30,9 +46,10 @@ skew applies, which is fine at the phase granularity traced here).
 
 from __future__ import annotations
 
+import queue
 import threading
 import time
-from typing import Dict, List, Optional, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 from mpit_tpu.obs import clock as _clock
 from mpit_tpu.obs import flight as _flight
@@ -53,6 +70,9 @@ class NullSpan:
 
     def end(self, outcome: str = "ok", **kw) -> None:
         pass
+
+    def phase_seconds(self, phase: str) -> float:
+        return 0.0
 
 
 NULL_SPAN = NullSpan()
@@ -89,6 +109,14 @@ class OpSpan:
         if self.cpu0 is not None:
             self.cpu_marks.append(self._rec._prof.cpu_now())
 
+    def phase_seconds(self, phase: str) -> float:
+        """Seconds this (ended) span spent in the phases named
+        ``phase``: each runs from its mark to the next mark or the
+        span's end."""
+        ends = [t for _p, t in self.marks[1:]] + [self.t1]
+        return sum(end - t for (p, t), end in zip(self.marks, ends)
+                   if p == phase)
+
     def note(self, **kw) -> None:
         """Attach args discovered mid-op (e.g. seq assigned after the
         encode, retry counts)."""
@@ -105,6 +133,69 @@ class OpSpan:
         if kw:
             self.args.update(kw)
         self._rec._finish(self)
+
+
+class RoundSpan(OpSpan):
+    """The parent span of one sync round (:meth:`SpanRecorder.round`)."""
+
+    __slots__ = ("_annotation",)
+
+    def __init__(self, rec: "SpanRecorder", tid: str,
+                 args: Dict[str, object], phase: str):
+        super().__init__(rec, "round", tid, args)
+        # The first phase begins with the span, so the phases tile it.
+        self.marks.append((phase, self.t0))
+        if self.cpu0 is not None:
+            self.cpu_marks.append(self.cpu0)
+        rec._ctx.round = args["round"]
+        self._annotation = None
+        try:
+            from jax.profiler import TraceAnnotation
+        except ImportError:  # a jax-free reader of this package
+            return
+        # The anchor: the annotation's profiler timestamp against this
+        # span's own monotonic begin stamp.  A no-op outside a profiler
+        # session.
+        self._annotation = TraceAnnotation(
+            "mpit.round", round=args["round"],
+            mono_ns=int(round(self.t0 * 1e9)))
+        self._annotation.__enter__()
+
+    def end(self, outcome: str = "ok", **kw) -> None:
+        if self.t1 is not None:
+            return
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
+        self._rec._ctx.round = None
+        super().end(outcome, **kw)
+
+
+class _ReadyWaiter(threading.Thread):
+    """Ends spans when the device result they wait for is ready, so that
+    the role thread that dispatched the work never blocks on it.  Exists
+    only while recording (:meth:`SpanRecorder.end_when_ready` starts it
+    on first use).  Results are waited for in the order handed over,
+    which is the order the backend runs them in: the ``exec`` mark of an
+    item is stamped when the item before it became ready."""
+
+    def __init__(self) -> None:
+        super().__init__(name="obs-ready-waiter", daemon=True)
+        self.items: "queue.SimpleQueue" = queue.SimpleQueue()
+        self.done = 0  # spans ended; this thread is the only writer
+
+    def run(self) -> None:
+        import jax
+
+        while True:
+            span, result = self.items.get()
+            span.mark("exec")
+            try:
+                jax.block_until_ready(result)
+                outcome = "ready"
+            except Exception:  # a donated or deleted result: no stamp
+                outcome = "lost"
+            span.end(outcome)
+            self.done += 1
 
 
 class SpanRecorder:
@@ -136,6 +227,52 @@ class SpanRecorder:
         #: served by the /status introspection endpoint (obs/statusd.py)
         #: and attached to flight-recorder dumps.
         self._open: Dict[int, OpSpan] = {}
+        #: spans begun per channel: the next op's ordinal ``n``
+        self._ordinals: Dict[str, int] = {}
+        #: the sync round this thread is in (``round``), if any
+        self._ctx = threading.local()
+        self._waiter: Optional[_ReadyWaiter] = None
+        self._handed = 0  # spans given to the waiter
+
+    def _begin(self, span: OpSpan) -> OpSpan:
+        n = self._ordinals.get(span.tid, 0)
+        self._ordinals[span.tid] = n + 1
+        span.args["n"] = n
+        self._open[id(span)] = span
+        return span
+
+    def round(self, k: int, phase: str, rank: object = None) -> OpSpan:
+        """Begin the parent span of sync round ``k`` of worker ``rank``
+        in its first phase ``phase`` (see the module docstring).  The
+        caller marks the later phases and ends the span on the thread
+        that began it."""
+        prefix = f"r{rank}:" if rank is not None else ""
+        return self._begin(RoundSpan(
+            self, f"{prefix}round",
+            {"round": int(k), "rank": rank, "side": "worker"}, phase))
+
+    def end_when_ready(self, span: OpSpan, result: Any) -> None:
+        """Hand ``span`` to the waiter thread, which marks ``exec`` when
+        the result handed over before this one is ready and ends the
+        span when ``result`` is.  The caller must not touch the span
+        again."""
+        span.cpu0 = None  # another thread ends it: no CPU attribution
+        with self._hist_lock:
+            if self._waiter is None:
+                self._waiter = _ReadyWaiter()
+                self._waiter.start()
+            self._handed += 1
+        self._waiter.items.put((span, result))
+
+    def drain(self, timeout: float = 10.0) -> bool:
+        """Wait until every span handed to :meth:`end_when_ready` has
+        ended (the exporter calls this before it reads ``spans``)."""
+        deadline = time.monotonic() + timeout
+        while self._waiter is not None and self._waiter.done < self._handed:
+            if time.monotonic() > deadline:
+                return False
+            time.sleep(0.005)
+        return True
 
     def op(self, name: str, peer: object = "?", side: str = "client",
            **args) -> OpSpan:
@@ -151,9 +288,11 @@ class SpanRecorder:
         args["side"] = side
         rank = args.get("rank")
         prefix = f"r{rank}:" if rank is not None else ""
-        span = OpSpan(self, name, f"{prefix}{side}:{peer}:{name}", args)
-        self._open[id(span)] = span
-        return span
+        current = getattr(self._ctx, "round", None)
+        if current is not None and side == "client":
+            args["round"] = current
+        return self._begin(
+            OpSpan(self, name, f"{prefix}{side}:{peer}:{name}", args))
 
     def open_ops(self) -> List[Dict[str, object]]:
         """Snapshot of the in-flight ops: identity args, current phase,
@@ -221,6 +360,15 @@ class NullRecorder:
     def op(self, name: str, peer: object = "?", side: str = "client",
            **args) -> NullSpan:
         return NULL_SPAN
+
+    def round(self, k: int, phase: str, rank: object = None) -> NullSpan:
+        return NULL_SPAN
+
+    def end_when_ready(self, span, result) -> None:
+        pass
+
+    def drain(self, timeout: float = 10.0) -> bool:
+        return True
 
     def open_ops(self) -> list:
         return []

@@ -13,8 +13,20 @@ readable by Perfetto (https://ui.perfetto.dev) and chrome://tracing:
   ``GRAD.send``, ...); task lifecycles emit one ``X`` each;
 - timestamps are wall-clock microseconds (monotonic span times shifted
   by the recorder's captured epoch offset), so per-rank part files
-  merge onto a single timeline, and a concurrently captured
-  ``jax.profiler`` trace (also wall-anchored) lines up beside it.
+  merge onto a single timeline.  ``otherData.ranks[<rank>]`` carries
+  that rank's ``epoch_offset`` (seconds) and ``clock_id``: subtracting
+  the offset takes an exported timestamp back to the rank's monotonic
+  clock, and ranks with one ``clock_id`` (one host) share that clock
+  exactly.
+- a concurrently captured ``jax.profiler`` trace is on the profiler's
+  own clock, not on this one.  The two are joined explicitly: every
+  ``round`` span enters a ``TraceAnnotation("mpit.round", round=k,
+  mono_ns=<the span's monotonic begin>)``, so each traced round holds
+  one (profiler timestamp, monotonic timestamp) pair.  A reader maps
+  monotonic stamps onto the profiler's timeline with the pair of the
+  nearest round and checks the drift between the first pair and the
+  last (``chipbench/layers/spantree.py`` does; docs/OBSERVABILITY.md,
+  *One clock*).
 
 Flow: each rank writes ``$MPIT_OBS_TRACE.rank<N>.json`` at exit
 (:func:`maybe_write_rank_trace`, called from the launch child mains);
@@ -129,11 +141,15 @@ def write_rank_trace(path: str, rank: int, role: str = "",
     rec = recorder if recorder is not None else _spans.get_recorder()
     reg = registry if registry is not None else _metrics.get_registry()
     label = f"rank {rank}" + (f" ({role})" if role else "")
+    rec.drain()  # spans that end when a device result is ready
     obj = {
         "traceEvents": chrome_events(rec, pid=rank, label=label),
         "displayTimeUnit": "ms",
         "otherData": {
-            "ranks": {str(rank): {"role": role, "metrics": reg.snapshot()}},
+            "ranks": {str(rank): {
+                "role": role, "metrics": reg.snapshot(),
+                "epoch_offset": rec.epoch_offset,
+                "clock_id": _clock.clock_id()}},
             # Per-peer clock-offset estimates (obs/clock.py): the causal
             # joiner aligns ranks from these instead of re-deriving
             # offsets from span pairs (obs/causal.py).

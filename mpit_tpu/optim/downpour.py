@@ -31,15 +31,15 @@ params are quantized too; su>1 local moves run on the exact local ``w``.
 
 from __future__ import annotations
 
-import time
 from typing import Any, Callable, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from mpit_tpu.obs import get_registry
+from mpit_tpu.obs import get_recorder, get_registry
 from mpit_tpu.optim.client_api import ParamClientAPI
+from mpit_tpu.optim.sync import push_pull
 
 
 class Downpour:
@@ -60,14 +60,17 @@ class Downpour:
         self.pc = pclient
         self.su = su
         self.k = 0
-        self.dusync = 0.0  # blocking-sync seconds (reference state.dusync)
+        self.rounds = 0  # sync rounds done: the ``round`` of the spans
+        #: seconds inside ``round.exchange`` (the reference's blocking-sync
+        #: seconds, at the ParamClientAPI boundary): from the round spans
+        #: while recording, from a plain timer there with obs off
+        self.sync_seconds = 0.0
         self._started = False
         # Training telemetry (mpit_tpu.obs): loss + shipped-update norm
-        # gauges, written only on sync rounds (where host copies already
-        # happen, so no extra device sync) and only when obs is enabled
-        # (the norm is an O(n) host reduction).
+        # gauges, written only on sync rounds and only when obs is
+        # enabled, under the round's ``telemetry`` phase (optim/sync.py).
         _reg = get_registry()
-        self._obs = _reg.enabled
+        self._spans = get_recorder()
         self._m_loss = _reg.gauge("mpit_train_loss", opt="downpour")
         self._m_unorm = _reg.gauge("mpit_train_update_norm", opt="downpour")
 
@@ -90,34 +93,19 @@ class Downpour:
         self._started = True
         return w
 
-    def _sync(self, payload: jnp.ndarray) -> jnp.ndarray:
-        """Ship ``payload`` as the grad, fetch fresh params, time the wait."""
-        np.copyto(self.grad_host, np.asarray(payload))
-        if self._obs:
-            self._m_unorm.set(float(np.linalg.norm(self.grad_host)))
-        self.pc.async_send_grad()
-        self.pc.async_recv_param()
-        t0 = time.monotonic()
-        self.pc.wait()
-        self.dusync += time.monotonic() - t0
-        return jnp.asarray(self.w_host)
-
     def step(self, w: jnp.ndarray, *fn_args: Any) -> Tuple[jnp.ndarray, jnp.ndarray]:
         assert self._started, "call start(w) first"
         k = jnp.asarray(self.k, jnp.int32)
         loss, dfdx, accum, w_local = self._local(w, self.accum, k, *fn_args)
 
-        synced = self.su == 1 or self.k % self.su == 0
         if self.su == 1:
-            w = self._sync(dfdx)
+            w = push_pull(self, dfdx, loss)
         elif self.k % self.su == 0:
-            w = self._sync(accum)
+            w = push_pull(self, accum, loss)
             self.accum = jnp.zeros_like(accum)
         else:
             self.accum = accum
             w = w_local  # move locally between syncs (reference :44)
-        if self._obs and synced:
-            self._m_loss.set(float(loss))
 
         self.k += 1
         return w, loss

@@ -39,9 +39,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from mpit_tpu.obs import get_registry
+from mpit_tpu.obs import get_recorder, get_registry
 from mpit_tpu.optim.client_api import ParamClientAPI
 from mpit_tpu.optim.msgd import MSGDConfig, msgd_commit, msgd_init, msgd_lookahead
+from mpit_tpu.optim.sync import shipped_norm
 
 
 class EAMSGD:
@@ -63,15 +64,19 @@ class EAMSGD:
         self.pc = pclient
         self.su = su
         self.mva = mva
-        self.dusync = 0.0
+        self.rounds = 0  # sync rounds done: the ``round`` of the spans
+        #: seconds inside ``round.exchange`` (the pull and its wait, the
+        #: push and its ping): from the round spans while recording, from
+        #: a plain timer at the same boundaries with obs off
+        self.sync_seconds = 0.0
         self._started = False
         # Training telemetry (mpit_tpu.obs): the elastic distance
         # ||w - w*|| is EASGD's own convergence signal — the exploration
-        # radius the mva force is pulling back.  Derived from the sug
-        # host mirror on sync rounds only, and only when obs is enabled
-        # (it is an O(n) host reduction).
+        # radius the mva force is pulling back.  Reduced on the device
+        # from sug on sync rounds only, and only when obs is enabled;
+        # read back under the round's ``telemetry`` phase.
         _reg = get_registry()
-        self._obs = _reg.enabled
+        self._spans = get_recorder()
         self._m_dist = _reg.gauge("mpit_train_elastic_distance", opt="eamsgd")
         self._m_unorm = _reg.gauge("mpit_train_update_norm", opt="eamsgd")
         # Local rule = msgd without the momentum ramp (reference :24-45).
@@ -119,27 +124,53 @@ class EAMSGD:
         sync_round = self._steps % self.su == 0
         w_retracted = None
         if sync_round:
+            # The round's span tree (docs/OBSERVABILITY.md): the same
+            # phases as optim/sync.py's push-and-pull round, in this
+            # rule's order: pull, elastic force, push.  The fences and
+            # the telemetry exist only while recording.
+            rec = self._spans
+            span = rec.round(self.rounds, "exchange",
+                             rank=getattr(self.pc, "rank", None))
+            # obs off: a plain timer at the exchange phases' boundaries
+            plain = not rec.enabled
+            t0 = time.monotonic() if plain else 0.0
             self.pc.async_recv_param()  # center_host <- w*
-            t0 = time.monotonic()
             self.pc.wait()  # completes this recv and any prior send
-            self.dusync += time.monotonic() - t0
+            if plain:
+                self.sync_seconds += time.monotonic() - t0
+            span.mark("h2d")
+            center = jnp.asarray(self.center_host)
+            if rec.enabled:
+                jax.block_until_ready(center)
+                span.mark("wait_backward")  # here: the elastic force
             if self._use_fused_elastic:
                 # One sweep computes sug and the retracted w together.
-                w_retracted, sug = self._elastic_retract(
-                    w, jnp.asarray(self.center_host)
-                )
+                w_retracted, sug = self._elastic_retract(w, center)
             else:
-                sug = self._elastic(w, jnp.asarray(self.center_host))
-            np.copyto(self.sug_host, np.asarray(sug))
-            if self._obs:
+                sug = self._elastic(w, center)
+            unorm = None
+            if rec.enabled:
+                jax.block_until_ready(sug)
+                unorm = shipped_norm(sug)  # read under telemetry
+            span.mark("d2h")
+            host = np.asarray(sug)
+            span.mark("stage")
+            np.copyto(self.sug_host, host)
+            span.mark("exchange")
+            t0 = time.monotonic() if plain else 0.0
+            self.pc.async_send_grad()  # server: w* += sug
+            self.pc.ping()  # overlap I/O with local compute (reference :63)
+            if plain:
+                self.sync_seconds += time.monotonic() - t0
+            if rec.enabled:
+                span.mark("telemetry")
                 # sug = mva * (w - w*): one norm serves both gauges.
-                unorm = float(np.linalg.norm(self.sug_host))
+                unorm = float(unorm)
                 self._m_unorm.set(unorm)
                 self._m_dist.set(unorm / self.mva)
-            self.pc.async_send_grad()  # server: w* += sug
-            t0 = time.monotonic()
-            self.pc.ping()  # overlap I/O with local compute (reference :63)
-            self.dusync += time.monotonic() - t0
+            span.end()
+            self.sync_seconds += span.phase_seconds("exchange")  # 0.0 if off
+            self.rounds += 1
 
         if self._skip_local:
             loss = jnp.zeros(())

@@ -115,9 +115,11 @@ def msgd_step(
     ``value_and_grad_fn(w, *fn_args) -> (loss, grad)`` is the feval closure
     analog (reference goot.lua:101-126).  Pure; jit the caller.
     """
-    w_la, state = msgd_lookahead(w, state, cfg)
+    with jax.named_scope("update"):
+        w_la, state = msgd_lookahead(w, state, cfg)
     loss, grad = value_and_grad_fn(w_la, *fn_args)
-    w_new, state = msgd_commit(w_la, grad, state, cfg)
+    with jax.named_scope("update"):
+        w_new, state = msgd_commit(w_la, grad, state, cfg)
     return w_new, state, loss
 
 

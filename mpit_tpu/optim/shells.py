@@ -30,17 +30,17 @@ step; pick ``none``/``bf16`` there if the tester must match exactly.
 
 from __future__ import annotations
 
-import time
 from typing import Any, Callable, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from mpit_tpu.obs import get_registry
+from mpit_tpu.obs import get_recorder, get_registry
 from mpit_tpu.optim import rules as rules_mod
 from mpit_tpu.optim.client_api import ParamClientAPI
 from mpit_tpu.optim.msgd import MSGDConfig, msgd_init, msgd_step
+from mpit_tpu.optim.sync import push_pull
 
 
 class RuleShell:
@@ -67,13 +67,18 @@ class RuleShell:
         self.su = su
         self.mode = mode
         self.k = 0
-        self.dusync = 0.0
+        self.rounds = 0  # sync rounds done: the ``round`` of the spans
+        #: seconds inside ``round.exchange`` (first ``async_*`` call to
+        #: the return of ``wait``): from the round spans while recording,
+        #: from a plain timer at the same boundary with obs off
+        self.sync_seconds = 0.0
         self._started = False
         # Training telemetry (mpit_tpu.obs): loss + shipped-update norm,
-        # written on sync rounds only and only when obs is enabled (the
-        # norm is an O(n) host reduction over the grad mirror).
+        # written on sync rounds only and only when obs is enabled, under
+        # the round's ``telemetry`` phase (optim/sync.py: the norm is
+        # reduced on the device, off the round's critical path).
         _reg = get_registry()
-        self._obs = _reg.enabled
+        self._spans = get_recorder()
         self._m_loss = _reg.gauge("mpit_train_loss", opt=f"rule-{mode}")
         self._m_unorm = _reg.gauge("mpit_train_update_norm",
                                    opt=f"rule-{mode}")
@@ -105,31 +110,16 @@ class RuleShell:
         self._started = True
         return w
 
-    def _sync(self, payload: jnp.ndarray) -> jnp.ndarray:
-        np.copyto(self.grad_host, np.asarray(payload))
-        if self._obs:
-            self._m_unorm.set(float(np.linalg.norm(self.grad_host)))
-        self.pc.async_send_grad()
-        self.pc.async_recv_param()
-        t0 = time.monotonic()
-        self.pc.wait()
-        self.dusync += time.monotonic() - t0
-        return jnp.asarray(self.w_host)
-
     def step(self, w: jnp.ndarray, *fn_args: Any) -> Tuple[jnp.ndarray, jnp.ndarray]:
         assert self._started, "call start(w) first"
-        if self._obs and (self.su == 1 or self.k % self.su == 0):
-            synced_loss = True
-        else:
-            synced_loss = False
         if self.mode == "global":
             loss, g = self._vgf(w, *fn_args)
             if self.su == 1:
-                w = self._sync(g)
+                w = push_pull(self, g, loss)
             else:
                 self.accum = self.accum + g
                 if self.k % self.su == 0:
-                    w = self._sync(self.accum)
+                    w = push_pull(self, self.accum, loss)
                     self.accum = jnp.zeros_like(self.accum)
                 # else: params do not move between syncs (reference :41)
         else:  # local-mode RMSProp
@@ -137,15 +127,13 @@ class RuleShell:
                 w, self.accum, self.rstate, *fn_args
             )
             if self.su == 1:
-                w = self._sync(update)
+                w = push_pull(self, update, loss)
             elif self.k % self.su == 0:
-                w = self._sync(accum)
+                w = push_pull(self, accum, loss)
                 self.accum = jnp.zeros_like(accum)
             else:
                 self.accum = accum
                 w = w + update  # move locally (reference :63)
-        if synced_loss:
-            self._m_loss.set(float(loss))
         self.k += 1
         return w, loss
 

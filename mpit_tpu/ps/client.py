@@ -957,6 +957,7 @@ class ParamClient:
         wire = self._grad_wire.get(srank)
         span.mark("encode")
         payload = self._encode(view, wire, residual=self._residual.get(srank))
+        span.note(bytes=payload.nbytes)
         if not self.ft.framed:
             span.mark("send")
             yield from aio_send(self.transport, payload, srank, tags.GRAD,
@@ -993,6 +994,7 @@ class ParamClient:
                               rank=self.rank)
         out = self.param[shard.offset : shard.end]
         wire = self._param_wire.get(srank)
+        span.note(bytes=(out if wire is None else wire).nbytes)
         if not self.ft.framed:
             span.mark("send")
             yield from aio_send(self.transport, tags.EMPTY, srank,
@@ -1135,7 +1137,8 @@ class ParamClient:
                     else None)
         seq = self._next_seq(srank, tag)
         nchunks = len(spans_)
-        span.note(epoch=self.ft.epoch, seq=seq, chunks=nchunks)
+        span.note(epoch=self.ft.epoch, seq=seq, chunks=nchunks,
+                  bytes=view.nbytes)
         span.mark("encode")
         pool = comm_pool.get_pool()
         jobs: Dict[int, object] = {}
@@ -1309,7 +1312,7 @@ class ParamClient:
         out = self.param[shard.offset: shard.end]
         seq = self._next_seq(srank, tags.PARAM_REQ)
         span.note(epoch=self.ft.epoch, seq=seq,
-                  chunks=len(self._chunk_spans[srank]))
+                  chunks=len(self._chunk_spans[srank]), bytes=out.nbytes)
         spans_ = self._chunk_spans[srank]
         frame = self._param_rx[srank]
         req = (timed_frame(self.ft.epoch, seq, 0) if self._timing

@@ -112,6 +112,7 @@ from mpit_tpu.ft import (
 from mpit_tpu.dplane import exchange as _dpexchange
 from mpit_tpu.dplane import hbm as _dphbm
 from mpit_tpu.obs import (
+    NULL_SPAN,
     get_flight,
     get_recorder,
     obs_enabled,
@@ -515,6 +516,31 @@ class ParamServer:
 
             return contextlib.nullcontext()
         return jax.default_device(self._device)
+
+    def _exec_span(self, crank: int, grad_span):
+        """The ``apply_exec`` span of the GRAD op ``grad_span``: begun
+        at the dispatch of the jitted apply and handed to the recorder's
+        waiter, which ends it when the result is ready (obs/spans.py);
+        its phases are ``queued`` (behind the apply dispatched before
+        it) and ``exec``.  ``grad_n`` is the GRAD span's ordinal: a dup
+        or stale frame opens a GRAD span and no apply, so the two
+        ordinals may part."""
+        if not self._spans.enabled:
+            return NULL_SPAN
+        span = self._spans.op("apply_exec", peer=crank, side="server",
+                              rank=self.rank, grad_n=grad_span.args["n"])
+        span.mark("queued")
+        return span
+
+    def _await_apply(self, span) -> None:
+        """While recording: wait for a pending apply under a phase of
+        its own (``wait_apply``) before the PARAM span's ``snapshot``,
+        which would otherwise hide that wait inside its host copy.  The
+        snapshot blocks on the same result a moment later, so nothing
+        is served later for it."""
+        if self._spans.enabled:
+            span.mark("wait_apply")
+            jax.block_until_ready(self.param)
 
     # -- codec + FT negotiation ---------------------------------------------
 
@@ -1146,28 +1172,27 @@ class ParamServer:
 
     def _apply_chunk(self, crank: int, codec: "codec_mod.Codec",
                      body: np.ndarray, lo: int, hi: int,
-                     commit: bool) -> None:
+                     commit: bool, span=NULL_SPAN) -> None:
         """Decode+apply one GRAD chunk — fused into one XLA call when
         that matches the unchunked rounding (:meth:`_chunk_fused_ok`);
         the version commits once per op (on the final chunk), so the
-        snapshot cache and diff stream keep op-granular versions."""
+        snapshot cache and diff stream keep op-granular versions.
+        ``span`` is the op's GRAD span: ``copy`` covers the owned copy
+        (or host decode) of the chunk, ``dispatch`` the jitted call."""
         csize = hi - lo
         fused = codec.identity or self._chunk_fused_ok()
+        span.mark("copy")
         if self._hbm is not None:
             if codec.identity:
                 payload: Any = self._chunk_owned(body.view(self.dtype))
-                self._hbm.apply_wire_chunk(codec, payload, lo, csize,
-                                           commit=commit)
             elif fused:
-                self._hbm.apply_wire_chunk(
-                    codec,
-                    [self._chunk_owned(v)
-                     for v in codec.split_wire(body, csize)],
-                    lo, csize, commit=commit)
+                payload = [self._chunk_owned(v)
+                           for v in codec.split_wire(body, csize)]
             else:
-                self._hbm.apply_wire_chunk(
-                    None, self._chunk_decoded(crank, codec, body, csize),
-                    lo, csize, commit=commit)
+                payload = self._chunk_decoded(crank, codec, body, csize)
+            span.mark("dispatch")
+            self._hbm.apply_wire_chunk(codec if fused else None, payload,
+                                       lo, csize, commit=commit)
             self.param = self._hbm.param
             self.rule_state = self._hbm.rule_state
             return
@@ -1175,15 +1200,14 @@ class ParamServer:
             if codec.identity:
                 grad_in: Any = jnp.asarray(
                     self._chunk_owned(body.view(self.dtype)))
-                apply_fn = self._chunk_apply_for(codec, csize)
             elif fused:
                 grad_in = [jnp.asarray(self._chunk_owned(v))
                            for v in codec.split_wire(body, csize)]
-                apply_fn = self._chunk_apply_for(codec, csize)
             else:
                 grad_in = jnp.asarray(
                     self._chunk_decoded(crank, codec, body, csize))
-                apply_fn = self._chunk_apply_for(None, csize)
+            apply_fn = self._chunk_apply_for(codec if fused else None, csize)
+            span.mark("dispatch")
             self.param, self.rule_state = apply_fn(
                 self.param, grad_in, self.rule_state, np.int32(lo))
 
@@ -1204,6 +1228,7 @@ class ParamServer:
         spans_ = chunk_spans(self.size, self._chunk[crank])
         cur: "Optional[Tuple[int, int]]" = None
         span = None
+        exec_span = NULL_SPAN
         while self.live.on:
             got = yield from aio_recv(
                 self.transport, crank, tags.GRAD, live=self.live,
@@ -1212,6 +1237,7 @@ class ParamServer:
             if got is None:
                 if span is not None:
                     span.end("aborted")
+                    exec_span.end("aborted")
                 return
             epoch, seq, idx, cnt = unpack_chunk_header(rxbuf)
             t_tx = t_recv = 0
@@ -1241,19 +1267,26 @@ class ParamServer:
                     # The client abandoned an op mid-stream (teardown
                     # races only — the pump never overlaps ops).
                     span.end("aborted")
+                    exec_span.end("aborted")
                 cur = (epoch, seq)
                 span = self._spans.op("GRAD", peer=crank, side="server",
                                       rank=self.rank)
                 span.note(epoch=epoch, seq=seq, chunks=cnt)
+                # one apply_exec per op: first chunk's dispatch to the
+                # last chunk's result
+                exec_span = self._exec_span(crank, span)
             lo, hi = spans_[idx]
-            span.mark("apply")
             body = rxbuf[chdr: chdr + self._chunk_body_for(codec, hi - lo)]
-            self._apply_chunk(crank, codec, body, lo, hi, commit=done)
+            self._apply_chunk(crank, codec, body, lo, hi, commit=done,
+                              span=span)
             if done:
+                self._spans.end_when_ready(exec_span, self.param)
                 self._m_grads.inc()
                 self._committed()
             if not self.live.on:
                 span.end("aborted")
+                if not done:
+                    exec_span.end("aborted")
                 span, cur = None, None
                 continue
             span.mark("ack")
@@ -1279,6 +1312,7 @@ class ParamServer:
         spans_ = chunk_spans(self.size, self._chunk[crank])
         full = min(self._chunk[crank], self.size)
         stride = chunk_stride(chdr, self._chunk_body_for(codec, full))
+        self._await_apply(span)
         span.mark("snapshot")
         wire = self._snapshot_wire(codec)
         wire_u8 = wire.view(np.uint8) if wire.dtype != np.uint8 else wire
@@ -1606,8 +1640,10 @@ class ParamServer:
             span = self._spans.op("PARAM", peer=crank, side="server",
                                   rank=self.rank)
             if not framed:
+                self._await_apply(span)
                 span.mark("snapshot")
                 snapshot = self._snapshot_wire(codec)
+                span.note(bytes=snapshot.nbytes)
                 span.mark("send")
                 yield from aio_send(
                     self.transport, snapshot, crank, tags.PARAM,
@@ -1629,9 +1665,11 @@ class ParamServer:
                 yield from self._serve_param_chunks(
                     crank, codec, epoch, seq, req, t_recv, gen, span)
                 continue
+            self._await_apply(span)
             span.mark("snapshot")
             hdr = self._reply_hdr_for(crank)
             wire = self._snapshot_wire(codec)
+            span.note(bytes=wire.nbytes)
             wire_u8 = wire.view(np.uint8) if wire.dtype != np.uint8 else wire
             reply = self._param_send.get(crank)
             if reply is None or len(reply) != hdr + len(wire_u8):
@@ -2029,6 +2067,8 @@ class ParamServer:
                                   reply_live),
                 name=f"serve_busy:{crank}")
             return
+        # No ``wait_apply`` split here: serving cells reuse this method
+        # verbatim (cells/cell.py) and have no apply to wait for.
         span.mark("snapshot")
         wire = self._snapshot_wire(codec)
         header = self._serve_ok_header(epoch, seq)
@@ -2137,7 +2177,8 @@ class ParamServer:
                     staleness = self._snap_version - unpack_version(gbuf)
                     span.note(staleness=staleness)
                     self._stale_hist(crank).observe(staleness)
-            span.mark("apply")
+            span.note(bytes=gbuf.nbytes)
+            span.mark("copy")
             # The apply's operands are owned copies of the rx views
             # (:meth:`_chunk_owned` — `ps-grad-apply-owned`, MT-D901).
             # The GRAD_ACK below does NOT serialize buffer reuse: the
@@ -2146,15 +2187,22 @@ class ParamServer:
             # next GRAD landing in ``gbuf`` would race the in-flight
             # execution (visible as wrong applied bytes whenever the
             # backend queue is backed up, e.g. first-call compiles).
+            # Hence two phases and a span of its own: ``copy`` (the
+            # owned copy and ``jnp.asarray``), ``dispatch`` (the call
+            # returns when the apply is enqueued), and ``apply_exec``,
+            # which the recorder's waiter ends when the result is ready
+            # — after the ack, which it does not delay.
             if self._hbm is not None:
                 # Device-resident path: the slot's donated fused
                 # decode+apply — same math, same operand order as the
                 # legacy jit below, so both runs stay bitwise equal.
-                self._hbm.apply_wire(
-                    codec,
+                owned: Any = (
                     self._chunk_owned(data if data is not None else gbuf)
                     if parts is None
                     else [self._chunk_owned(v) for v in parts])
+                span.mark("dispatch")
+                exec_span = self._exec_span(crank, span)
+                self._hbm.apply_wire(codec, owned)
                 self.param = self._hbm.param
                 self.rule_state = self._hbm.rule_state
             else:
@@ -2165,9 +2213,12 @@ class ParamServer:
                     else:
                         grad_in = [jnp.asarray(self._chunk_owned(v))
                                    for v in parts]
+                    span.mark("dispatch")
+                    exec_span = self._exec_span(crank, span)
                     self.param, self.rule_state = apply_fn(
                         self.param, grad_in, self.rule_state
                     )
+            self._spans.end_when_ready(exec_span, self.param)
             self._m_grads.inc()
             self._committed()
             if not self.live.on:

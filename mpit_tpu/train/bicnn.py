@@ -652,7 +652,7 @@ class BiCNNTrainer:
                 "epoch %d done, for %.2f seconds", epoch, history[-1]["seconds"]
             )
         accs = self.test3()
-        sync = getattr(opt, "dusync", 0.0)
+        sync = getattr(opt, "sync_seconds", 0.0)
         self.tm.add("sync", sync)
         if hasattr(opt, "stop"):
             with self.tm.phase("stop"):

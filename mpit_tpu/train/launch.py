@@ -213,6 +213,9 @@ LAUNCH_DEFAULTS = TRAINER_DEFAULTS.merged(
     lm_heads=4,
     lm_layers=2,
     lm_seq=128,
+    # rows of the token table and the head; 0 = lm.model.build's own
+    # keyword default (the byte stream's 256)
+    lm_vocab=0,
     lm_steps=200,
     lm_eval_every=50,
     # -1 auto (flash on TPU) | 0 jnp reference | 1 the Pallas kernel
@@ -330,6 +333,7 @@ def lm_trainer_cfg(cfg: Config) -> Config:
         n_heads=int(cfg.get("lm_heads", 4)),
         n_layers=int(cfg.get("lm_layers", 2)),
         seq_len=int(cfg.get("lm_seq", 128)),
+        vocab=int(cfg.get("lm_vocab", 0)),
         steps=int(cfg.get("lm_steps", 200)),
         eval_every=int(cfg.get("lm_eval_every", 50)),
         use_flash=int(cfg.get("lm_use_flash", -1)),
@@ -347,13 +351,14 @@ def lm_layout(cfg: Config, n_servers: int):
     targets; empty keeps balanced targets (still boundary-aligned, so
     it differs from the raw equal split)."""
     from mpit_tpu.lm import build, plan
+    from mpit_tpu.lm.model import vocab_kw
 
     tcfg = lm_trainer_cfg(cfg)
     # Param *shapes* don't depend on the attention implementation, so
     # layout derivation never touches the accelerator kernels.
     model = build(d_model=tcfg.d_model, n_heads=tcfg.n_heads,
                   n_layers=tcfg.n_layers, seq_len=tcfg.seq_len,
-                  seed=tcfg.seed, use_flash=False)
+                  seed=tcfg.seed, use_flash=False, **vocab_kw(tcfg.vocab))
     params = model.flat.unravel(model.flat.w0)
     spec = str(cfg.get("lm_weights", "") or "")
     weights = ([float(x) for x in spec.split(",") if x.strip() != ""]
@@ -380,11 +385,13 @@ def _serve_vec_len(cfg: Config, rank: int) -> int:
     full = TRAINER_DEFAULTS.merged(cfg.to_dict())
     if int(cfg.get("lm", 0)):
         from mpit_tpu.lm import build
+        from mpit_tpu.lm.model import vocab_kw
 
         tcfg = lm_trainer_cfg(cfg)
         model = build(d_model=tcfg.d_model, n_heads=tcfg.n_heads,
                       n_layers=tcfg.n_layers, seq_len=tcfg.seq_len,
-                      seed=tcfg.seed, use_flash=False)
+                      seed=tcfg.seed, use_flash=False,
+                      **vocab_kw(tcfg.vocab))
         return int(model.flat.size)
     x_train = load_mnist(side=full.side)[0][0]
     if full.model == "cnn":

@@ -184,7 +184,9 @@ class MnistTrainer:
             if h["test_err"] <= cfg.target_test_err:
                 time_to_target = h["at"]
                 break
-        sync_time = getattr(opt, "dusync", 0.0)
+        # the optimizer's seconds at the ParamClientAPI boundary (its
+        # round.exchange phases; a plain timer there with obs off)
+        sync_time = getattr(opt, "sync_seconds", 0.0)
         self.tm.add("sync", sync_time)
         # The blocking-sync seconds accrued inside opt.step were measured
         # under the 'feval' phase too; report feval net of sync so the

@@ -327,3 +327,22 @@ class TestLmTrainer:
         cfg = self.CFG.merged({"opt": "nope"})
         with pytest.raises(ValueError, match="unknown optimizer"):
             LmTrainer(cfg).run()
+
+
+@pytest.mark.parametrize("lm_vocab, rows", [(0, 256), (320, 320)])
+def test_lm_vocab_reaches_every_build(lm_vocab, rows):
+    """``--lm_vocab`` sizes the token table and the head in the trainer,
+    in the gang's layout and in a reader's vector alike; 0 leaves
+    ``lm.model.build``'s own keyword default in force."""
+    from mpit_tpu.lm import LmTrainer
+    from mpit_tpu.train import launch
+
+    cfg = launch.LAUNCH_DEFAULTS.merged(lm=1, lm_d_model=32, lm_heads=2,
+                                        lm_layers=1, lm_seq=16, opt="sgd",
+                                        lm_vocab=lm_vocab, lm_use_flash=0)
+    trainer = LmTrainer(launch.lm_trainer_cfg(cfg))
+    assert trainer.model.vocab == rows
+    size = int(trainer.model.flat.size)
+    assert launch._serve_vec_len(cfg, rank=0) == size
+    layout = launch.lm_layout(cfg, n_servers=2)
+    assert sum(shard.size for shard in layout) == size

@@ -1,0 +1,546 @@
+"""The sync round's span tree (PR 23): the shells' ``round`` spans and
+their phases, the ordinal join on the unframed wire, ``apply_exec``
+against the GRAD ack, the obs-off path, the anchor onto a profiler
+timeline, and the benchmark's readers of all of it on a hand-worked
+fixture.  In-process thread gangs and constructed fixtures only.
+"""
+
+import contextlib
+import json
+import threading
+import time
+
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from chipbench import spec as spec_mod
+from chipbench.layers import spantree
+from mpit_tpu import obs
+from mpit_tpu.comm.local import LocalRouter
+from mpit_tpu.obs import causal
+from mpit_tpu.obs import trace as obs_trace
+from mpit_tpu.optim.downpour import Downpour
+from mpit_tpu.optim.easgd import EAMSGD
+from mpit_tpu.optim.shells import RuleShell
+from mpit_tpu.ps import ParamClient, ParamServer
+
+SIZE = 64
+TARGET = jnp.linspace(-1.0, 1.0, SIZE)
+
+
+def quad(w, target):
+    """Loss and gradient of 0.5 |w - target|^2."""
+    d = w - target
+    return 0.5 * jnp.sum(d * d), d
+
+
+@pytest.fixture
+def obs_on():
+    obs.configure(enabled=True, reset=True)
+    try:
+        yield obs.get_recorder()
+    finally:
+        obs.configure(enabled=None, reset=True)
+
+
+@contextlib.contextmanager
+def gang(nservers, nclients, rule="add"):
+    """Servers on threads, clients driven by the caller, unframed wire."""
+    n = nservers + nclients
+    router = LocalRouter(n)
+    sranks, cranks = list(range(nservers)), list(range(nservers, n))
+    servers = [ParamServer(r, cranks, router.endpoint(r), rule=rule)
+               for r in sranks]
+    threads = [threading.Thread(target=s.start, daemon=True)
+               for s in servers]
+    for t in threads:
+        t.start()
+    clients = [ParamClient(r, sranks, router.endpoint(r),
+                           seed_servers=(r == cranks[0])) for r in cranks]
+    try:
+        yield servers, clients
+    finally:
+        for s in servers:
+            s.live.stop()
+        for t in threads:
+            t.join(10)
+            assert not t.is_alive(), "server thread did not stop"
+
+
+OPTIMIZERS = {
+    "rule-su1": lambda pc: RuleShell(quad, pc, su=1),
+    "rule-su2": lambda pc: RuleShell(quad, pc, su=2),
+    "downpour": lambda pc: Downpour(quad, pc, lr=0.1, su=1),
+    "easgd": lambda pc: EAMSGD(quad, pc, lr=0.1, mva=0.2, su=1),
+}
+
+
+def phase_spans(span):
+    """[(phase, begin, end)] of a recorder span."""
+    ends = [t for _p, t in span.marks[1:]] + [span.t1]
+    return [(p, t, e) for (p, t), e in zip(span.marks, ends)]
+
+
+@pytest.mark.parametrize("name", sorted(OPTIMIZERS))
+def test_round_children_tile_and_ops_carry_the_round(obs_on, name):
+    rec = obs_on
+    steps = 3
+    with gang(2, 1) as (_servers, (pc,)):
+        opt = OPTIMIZERS[name](pc)
+        w = opt.start(jnp.zeros(SIZE))
+        for _ in range(steps * getattr(opt, "su", 1)):
+            w, _loss = opt.step(w, TARGET)
+        opt.stop()
+    rounds = [s for s in rec.spans if s.name == "round"]
+    assert [s.args["round"] for s in rounds] == list(range(steps))
+    assert opt.rounds == steps
+    for span in rounds:
+        parts = phase_spans(span)
+        # the children tile the parent: the first begins with it, each
+        # begins where the one before ends, the last ends with it
+        assert parts[0][1] == span.t0 and parts[-1][2] == span.t1
+        assert all(a[2] == b[1] for a, b in zip(parts, parts[1:]))
+        assert {"wait_backward", "d2h", "stage", "exchange", "h2d",
+                "telemetry"} == {p for p, _b, _e in parts}
+        # the client ops of the round carry its number and begin inside
+        # one of its exchange phases, one GRAD and one PARAM per server
+        ops = [s for s in rec.spans if s.args.get("side") == "client"
+               and s.args.get("round") == span.args["round"]]
+        assert sorted(s.name for s in ops) == ["GRAD"] * 2 + ["PARAM"] * 2
+        exchanges = [(b, e) for p, b, e in parts if p == "exchange"]
+        assert all(any(b <= s.t0 <= e for b, e in exchanges) for s in ops)
+    # the exported trace nests the phases under the B/E pair by name
+    events = obs_trace.chrome_events(rec, pid=0)
+    names = {e["name"] for e in events if e.get("cat") == "ps_phase"}
+    assert {"round.d2h", "round.exchange", "round.h2d"} <= names
+    # one definition of the sync time: the sum of the exchange phases
+    want = sum(e - b for s in rounds for p, b, e in phase_spans(s)
+               if p == "exchange")
+    assert opt.sync_seconds == pytest.approx(want) and want > 0
+
+
+class CountingClient:
+    """The optimizer tests' in-process plain-add server, one shard."""
+
+    def start(self, param, grad):
+        self.param, self.grad = param, grad
+        self.center = param.copy()
+
+    reset = start
+
+    def async_send_grad(self):
+        self.center += self.grad
+
+    def async_recv_param(self):
+        np.copyto(self.param, self.center)
+
+    def ping(self):
+        pass
+
+    wait = stop = ping
+
+
+@pytest.mark.parametrize("name", sorted(OPTIMIZERS))
+def test_obs_off_makes_no_span_and_reads_only_the_plain_timer(monkeypatch,
+                                                              name):
+    """With obs off a round creates no span, takes no fence and reads
+    the clock only for ``sync_seconds``: one ``time.monotonic`` pair
+    around each exchange (EASGD's round has two), which times the
+    client's wait as the round span's ``exchange`` phase would."""
+    nap = 0.01
+
+    class SlowClient(CountingClient):
+        def wait(self):
+            time.sleep(nap)
+
+    obs.configure(enabled=False, reset=True)
+    try:
+        opt = OPTIMIZERS[name](SlowClient())
+        w = opt.start(jnp.zeros(SIZE))
+        for _ in range(2 * getattr(opt, "su", 1)):  # compile everything
+            w, _loss = opt.step(w, TARGET)
+        before = opt.sync_seconds
+        reads = []
+        for clock in ("monotonic", "monotonic_ns", "time", "perf_counter"):
+            real = getattr(time, clock)
+            monkeypatch.setattr(
+                time, clock,
+                lambda real=real, clock=clock: reads.append(clock) or real())
+        for _ in range(2 * getattr(opt, "su", 1)):
+            w, _loss = opt.step(w, TARGET)
+        monkeypatch.undo()
+        exchanges = 2 if name == "easgd" else 1  # a round
+        assert reads == ["monotonic"] * (2 * exchanges * 2)
+        assert opt._spans is obs.NULL_RECORDER
+        assert obs.get_recorder().spans == ()
+        assert opt.rounds == 4
+        assert 2 * nap <= opt.sync_seconds - before < 2 * nap + 0.5
+    finally:
+        obs.configure(enabled=None, reset=True)
+
+
+def test_client_and_server_spans_join_by_ordinal_unframed(obs_on):
+    rec = obs_on
+    rounds = 3
+    with gang(2, 2) as (_servers, clients):
+        bufs = [(np.zeros(SIZE, np.float32), np.zeros(SIZE, np.float32))
+                for _ in clients]
+        starters = [threading.Thread(target=c.start, args=b, daemon=True)
+                    for c, b in zip(clients, bufs)]
+        for t in starters:
+            t.start()
+        for t in starters:
+            t.join(30)
+        for _ in range(rounds):
+            for c in clients:
+                c.grad[:] = 1.0
+                c.async_send_grad()
+                c.async_recv_param()
+                c.wait()
+        for c in clients:
+            c.stop()
+    spans = causal.extract_spans(obs_trace.chrome_events(rec, pid=0))
+    assert not any("seq" in s.args for s in spans)  # no wire identity
+    chains, _unkeyed = causal.join_spans(spans)
+    for op in ("GRAD", "PARAM"):
+        mine = [c for c in chains if c.op == op]
+        # one chain per (client, server, round), every one with both halves
+        assert len(mine) == 2 * 2 * rounds
+        assert all(c.joined and c.key[3] == causal.ORDINAL for c in mine)
+        assert sorted({c.key[4] for c in mine}) == list(range(rounds))
+        for chain in mine:
+            client, server = chain.client, chain.server
+            assert client.args["n"] == server.args["n"] == chain.key[4]
+            assert client.args["rank"] == server.args["peer"]
+            assert client.args["peer"] == server.args["rank"]
+            # one process, one clock: the server's half begins after the
+            # client sent and before the client's half ends
+            assert client.mark_ts("send", last=False) <= server.t0 \
+                <= client.t1
+    grads = [s for s in spans if s.name == "GRAD"]
+    assert all(s.args["bytes"] == SIZE // 2 * 4 for s in grads)
+    execs = [s for s in spans if s.name == "apply_exec"]
+    assert len(execs) == 2 * 2 * rounds
+    assert all([p for p, _t, _d in s.phases] == ["queued", "exec"]
+               and s.outcome == "ready" for s in execs)
+
+
+@pytest.mark.parametrize("ids, want", [
+    (("boot-a", "boot-a"), {(1, 0): 150e6, (0, 1): -150e6}),
+    (("boot-a", "boot-b"), {}),
+])
+def test_ranks_on_one_clock_differ_by_their_epoch_offsets(ids, want):
+    other = {"ranks": {
+        "1": {"epoch_offset": 100.0, "clock_id": ids[0]},
+        "0": {"epoch_offset": 250.0, "clock_id": ids[1]},
+        "7": {"role": "from a program that records no offset"}}}
+    assert causal.shared_clock_offsets(other) == want
+    table = causal.OffsetTable([], other)
+    if want:
+        assert table.lookup(1, 0) == (150e6, 0.0, "monotonic")
+    else:
+        assert table.lookup(1, 0)[2] == "none"
+
+
+class SlowResult:
+    """A stubbed apply's result: ready ``delay`` seconds after it is
+    first waited for."""
+
+    def __init__(self, value, delay):
+        self.value, self.delay = value, delay
+
+    def block_until_ready(self):
+        time.sleep(self.delay)
+        return self
+
+    def __array__(self, dtype=None, copy=None):
+        return np.asarray(self.value, dtype)
+
+
+@pytest.mark.parametrize("delay", [0.3])
+def test_apply_exec_ends_after_the_ack_and_does_not_delay_it(obs_on, delay):
+    rec = obs_on
+    with gang(1, 1) as ((server,), (pc,)):
+        server._apply_for = lambda codec: (
+            lambda param, grad, state: (
+                SlowResult(np.asarray(param) + np.asarray(grad), delay),
+                state))
+        pc.start(np.zeros(SIZE, np.float32), np.zeros(SIZE, np.float32))
+        pc.grad[:] = 1.0
+        pc.async_send_grad()
+        pc.wait()  # returns at the ack
+        acked = time.monotonic()
+        assert rec.drain(timeout=10)
+        pc.stop()
+    grad_client = next(s for s in rec.spans if s.name == "GRAD"
+                       and s.args["side"] == "client")
+    grad_server = next(s for s in rec.spans if s.name == "GRAD"
+                       and s.args["side"] == "server")
+    exec_span = next(s for s in rec.spans if s.name == "apply_exec")
+    # the ack did not wait for the apply, recording or not
+    assert grad_client.t1 - grad_client.t0 < delay / 2
+    assert grad_client.t1 <= acked
+    assert [p for p, _t in grad_server.marks] == ["copy", "dispatch", "ack"]
+    # the apply's execution ends after the ack, in a span of its own
+    assert exec_span.t1 > grad_server.t1 and exec_span.t1 > grad_client.t1
+    assert exec_span.t1 - exec_span.t0 >= delay
+    assert exec_span.phase_seconds("exec") >= delay
+    assert exec_span.args["grad_n"] == grad_server.args["n"] == 0
+    assert exec_span.outcome == "ready"
+
+
+# -- a hand-made profiler trace and the benchmark's readers -------------------
+#
+# One traced round.  Profiler clock (ns): the window is [0, 1_000_000]
+# (bench.batch then bench.dispatch); the chip runs the step's program
+# jit_loss over [50k, 950k] with three operations, [50k, 150k] under
+# head_loss, [150k, 200k] under attn and [900k, 950k] under the transpose
+# of head_loss, and then another program, jit_shipped_norm over
+# [955k, 975k], whose one operation [960k, 970k] also sits under a scope
+# called head_loss and must not count: busy 210k, idle 790k in the gaps
+# [0, 50k], [200k, 900k], [950k, 960k] and [970k, 1000k].  The
+# ``mpit.round`` annotation begins at 200_000 ns with mono_ns
+# 5_000_200_000, so the monotonic clock is the profiler's plus 5 s.  The
+# round (monotonic ms after 5 s): wait_backward 0.20-0.25, d2h 0.25-0.30,
+# stage 0.30-0.35, exchange 0.35-0.85, h2d 0.85-0.90, telemetry
+# 0.90-0.95; the client's GRAD 0.35-0.60 (send mark at 0.36) and PARAM
+# 0.35-0.80, 500 bytes each; the server's GRAD begins at 0.40 and copies
+# until 0.48; its apply_exec is queued 0.50-0.52 and runs 0.52-0.75.
+# Leaves cover [200k, 800k] and [850k, 950k] of the profiler's timeline,
+# so 50k of the middle gap, the first gap and the last two are unnamed:
+# 140k of 790k, 17.72%.  Per MB of the 500 bytes: GRAD op 0.25 ms, PARAM
+# op 0.45, the server's copy 0.08, apply_exec 0.23, each times 2000.
+
+WORKER, SERVER = 1, 0
+EPOCH_OFFSET = {WORKER: 100.0, SERVER: 250.0}
+HAND_WORKED = {
+    "shell_round_ms_p50": 0.5,
+    "d2h_ms_p50": 0.1,
+    "h2d_ms_p50": 0.05,
+    "param_op_ms_p50": 0.45,
+    "grad_queue_ms_p50": 0.04,
+    "server_apply_ms_p50": 0.23,
+    "idle_unnamed_pct": 100 * 140 / 790,
+    "head_loss_ms_per_step": 0.15,
+}
+
+
+def varint(n):
+    out = bytearray()
+    while True:
+        out.append((n & 0x7F) | (0x80 if n > 0x7F else 0))
+        n >>= 7
+        if not n:
+            return bytes(out)
+
+
+def field(number, value):
+    """One protobuf field: a varint for an int, else length-delimited."""
+    if isinstance(value, int):
+        return varint(number << 3) + varint(value)
+    data = value.encode() if isinstance(value, str) else value
+    return varint(number << 3 | 2) + varint(len(data)) + data
+
+
+def xplane(name, lines, stat_names, metadata_stats=None):
+    """An XPlane: ``lines`` is {line name: [(event name, start_ns,
+    duration_ns, {stat: int})]}; ``metadata_stats`` {event name: {stat:
+    str}} puts string stats on the events' metadata."""
+    stat_id = {s: i + 1 for i, s in enumerate(stat_names)}
+    names = sorted({ev[0] for evs in lines.values() for ev in evs})
+    meta_id = {n: i + 1 for i, n in enumerate(names)}
+    body = field(2, name)
+    for k, (line, events) in enumerate(lines.items()):
+        packed = field(1, k + 1) + field(2, line) + field(3, 0)
+        for ev_name, start, dur, stats in events:
+            event = (field(1, meta_id[ev_name]) + field(2, start * 1000)
+                     + field(3, dur * 1000))
+            for stat, value in stats.items():
+                event += field(4, field(1, stat_id[stat]) + field(4, value))
+            packed += field(4, event)
+        body += field(3, packed)
+    for n, i in meta_id.items():
+        meta = field(1, i) + field(2, n)
+        for stat, text in (metadata_stats or {}).get(n, {}).items():
+            meta += field(5, field(1, stat_id[stat]) + field(5, text))
+        body += field(4, field(1, i) + field(2, meta))
+    for stat, i in stat_id.items():
+        body += field(5, field(1, i) + field(2, field(1, i) + field(2, stat)))
+    return field(1, body)
+
+
+@pytest.fixture
+def traced_run(tmp_path):
+    """The ``run`` a reader is given, for the trace described above."""
+    ops = {"%fusion.1 = f32[8]{0} fusion()": "jit(loss)/head_loss/dot_general:",
+           "%fusion.2 = f32[8]{0} fusion()":
+               "jit(loss)/DecoderBlock_0/attn/dot_general:",
+           "%fusion.3 = f32[8]{0} fusion()":
+               "jit(loss)/transpose(jvp(head_loss))/mul:",
+           "%fusion.4 = f32[]{} fusion()":
+               "jit(shipped_norm)/head_loss/reduce_sum:"}
+    a, b, c, d = ops
+    space = xplane(
+        "/device:TPU:0",
+        {"XLA Ops": [(a, 50_000, 100_000, {}), (b, 150_000, 50_000, {}),
+                     (c, 900_000, 50_000, {}), (d, 960_000, 10_000, {})],
+         "XLA Modules": [("jit_loss(1)", 50_000, 900_000, {}),
+                         ("jit_shipped_norm(2)", 955_000, 20_000, {})]},
+        ["tf_op"], {n: {"tf_op": s} for n, s in ops.items()})
+    space += xplane(
+        "/host:CPU",
+        {"python": [("bench.batch", 0, 100_000, {}),
+                    ("bench.dispatch", 100_000, 900_000, {}),
+                    ("mpit.round", 200_000, 750_000,
+                     {"round": 4, "mono_ns": 5_000_200_000})]},
+        ["round", "mono_ns"])
+    trace_dir = tmp_path / "device_trace" / "plugins" / "profile" / "t"
+    trace_dir.mkdir(parents=True)
+    (trace_dir / "host.xplane.pb").write_bytes(space)
+
+    events = []
+
+    def span(pid, tid, name, begin_ms, phases, end_ms, **args):
+        us = lambda ms: (5.0 + ms / 1e3 + EPOCH_OFFSET[pid]) * 1e6
+        events.append({"ph": "B", "cat": "ps_op", "name": name, "pid": pid,
+                       "tid": tid, "ts": us(begin_ms), "args": args})
+        marks = phases + [("", end_ms)]
+        for (phase, at), (_next, until) in zip(marks, marks[1:]):
+            events.append({"ph": "X", "cat": "ps_phase", "pid": pid,
+                           "tid": tid, "name": f"{name}.{phase}",
+                           "ts": us(at), "dur": us(until) - us(at)})
+        events.append({"ph": "E", "cat": "ps_op", "name": name, "pid": pid,
+                       "tid": tid, "ts": us(end_ms),
+                       "args": {"outcome": "ok"}})
+
+    span(WORKER, 1, "round", 0.20,
+         [("wait_backward", 0.20), ("d2h", 0.25), ("stage", 0.30),
+          ("exchange", 0.35), ("h2d", 0.85), ("telemetry", 0.90)], 0.95,
+         side="worker", round=4, rank=WORKER, n=4)
+    span(WORKER, 2, "GRAD", 0.35,
+         [("encode", 0.35), ("send", 0.36), ("ack", 0.38)], 0.60,
+         side="client", rank=WORKER, peer=SERVER, round=4, n=4, bytes=500)
+    span(WORKER, 3, "PARAM", 0.35, [("send", 0.35), ("recv", 0.37)], 0.80,
+         side="client", rank=WORKER, peer=SERVER, round=4, n=4, bytes=500)
+    span(SERVER, 1, "GRAD", 0.40,
+         [("copy", 0.40), ("dispatch", 0.48), ("ack", 0.50)], 0.55,
+         side="server", rank=SERVER, peer=WORKER, n=4, bytes=500)
+    span(SERVER, 2, "apply_exec", 0.50, [("queued", 0.50), ("exec", 0.52)],
+         0.75, side="server", rank=SERVER, peer=WORKER, n=4, grad_n=4)
+    events.sort(key=lambda e: e["ts"])
+    obs_path = tmp_path / "obs_trace.json"
+    obs_path.write_text(json.dumps({
+        "traceEvents": events,
+        "otherData": {"ranks": {
+            str(r): {"epoch_offset": off, "clock_id": "one-host"}
+            for r, off in EPOCH_OFFSET.items()}}}))
+    return {
+        "obs_trace": str(obs_path),
+        "results": {WORKER: {"chipbench": {"marks": {}}},
+                    SERVER: {"chipbench": {"marks": {}}}},
+        "summary": {"window": [5.0, 5.001], "worker_ranks": [WORKER]},
+        "reduction": {"step_module": "jit_loss", "step_module_runs": 1},
+    }
+
+
+def reader(name):
+    root = spec_mod.ROOT
+    return spec_mod.load_reader(root, spec_mod.load_bench(root), name)
+
+
+@pytest.mark.parametrize("name", sorted(HAND_WORKED))
+def test_reader_gives_the_hand_worked_value(traced_run, name):
+    assert reader(name)(traced_run) == pytest.approx(HAND_WORKED[name],
+                                                     rel=1e-6)
+
+
+@pytest.mark.parametrize("name", sorted(HAND_WORKED))
+def test_reader_gives_none_on_a_run_without_spans(tmp_path, name):
+    """A program that predates the spans (PR 23's parent): a merged
+    trace with client op spans and nothing else, no device trace."""
+    events = [
+        {"ph": "B", "cat": "ps_op", "name": "GRAD", "pid": WORKER, "tid": 1,
+         "ts": 105.0004e6, "args": {"side": "client", "peer": SERVER}},
+        {"ph": "E", "cat": "ps_op", "name": "GRAD", "pid": WORKER, "tid": 1,
+         "ts": 105.0006e6, "args": {"outcome": "ok"}}]
+    path = tmp_path / "obs_trace.json"
+    path.write_text(json.dumps({"traceEvents": events}))
+    run = {"obs_trace": str(path),
+           "results": {WORKER: {"chipbench": {"marks": {
+               "epoch_offset": 100.0}}}},
+           "summary": {"window": [5.0, 5.001], "worker_ranks": [WORKER]},
+           "reduction": {"step_module": "jit_loss", "step_module_runs": 4}}
+    assert reader(name)(run) is None
+    assert reader(name)({**run, "obs_trace": None}) is None
+
+
+def test_readers_print_the_parts_per_mb_and_the_step_by_scope(traced_run,
+                                                              capsys):
+    """``bytes``, ``grad_n``, ``queued`` and the scopes other than
+    ``head_loss`` are read here: the lines two readers print before
+    their result."""
+    tree = spantree.load(traced_run)
+    assert spantree.per_mb(tree) == pytest.approx({
+        "GRAD op": 500.0, "PARAM op": 900.0, "server copy": 160.0,
+        "apply_exec": 460.0, "queued ms": 0.02})
+    ((client, server, applied), (param, pserver, none)) = sorted(
+        spantree.round_ops(tree), key=lambda m: m[0].name)
+    assert (client.name, server.side, applied.name) == (
+        "GRAD", "server", "apply_exec")
+    assert param.name == "PARAM" and pserver is None and none is None
+    assert spantree.scope_ms_per_step(traced_run) == pytest.approx({
+        "step": 0.9, "head_loss": 0.15, "attn": 0.05})
+    reader("server_apply_ms_p50")(traced_run)
+    reader("head_loss_ms_per_step")(traced_run)
+    out = capsys.readouterr().out
+    assert "ms per MB of the spans' own bytes: GRAD op 500.000" in out
+    assert "by scope: step 0.900, head_loss 0.150, attn 0.050" in out
+
+
+def test_one_name_under_two_stacks_is_ambiguous_not_misattributed(tmp_path):
+    """Two programs that own an operation of the same HLO text: the
+    plane's metadata then holds the name twice, and neither stack is
+    taken.  Another plane's metadata does not count at all."""
+    def plane(name, stacks):
+        body = field(2, name)
+        for i, (op, stack) in enumerate(stacks):
+            meta = field(1, i + 1) + field(2, op) + field(
+                5, field(1, 1) + field(5, stack))
+            body += field(4, field(1, i + 1) + field(2, meta))
+        body += field(5, field(1, 1) + field(2, field(1, 1)
+                                             + field(2, "tf_op")))
+        return field(1, body)
+
+    path = tmp_path / "two.xplane.pb"
+    path.write_bytes(
+        plane("/device:TPU:0", [("%add.1", "jit(loss)/head_loss/add:"),
+                                ("%add.1", "jit(shipped_norm)/add:"),
+                                ("%mul.2", "jit(loss)/mlp/mul:")])
+        + plane("/device:TPU:1", [("%mul.2", "jit(loss)/attn/mul:")]))
+    assert spantree.op_scopes(str(path), "/device:TPU:0") == {
+        "%add.1": spantree.AMBIGUOUS, "%mul.2": "jit(loss)/mlp/mul:"}
+    assert spantree.op_scopes(str(path), "/device:TPU:1") == {
+        "%mul.2": "jit(loss)/attn/mul:"}
+
+
+def test_anchor_maps_monotonic_stamps_to_the_microsecond(traced_run):
+    path = spantree.xplane_path(traced_run)
+    rows = spantree.anchors(path)
+    assert rows == [(4, 200_000.0, 5_000_200_000.0)]
+    tree = spantree.load(traced_run)
+    (span,) = tree.rounds()
+    # the round span's begin is the annotation's begin on the profiler's
+    # clock, and every phase boundary lands where the fixture put it
+    begin = spantree.to_profiler_ns(rows, tree.mono(span, span.t0))
+    assert abs(begin - 200_000.0) < 1_000
+    ends = [spantree.to_profiler_ns(rows, tree.mono(span, ts + dur))
+            for _p, ts, dur in span.phases]
+    want = [250_000, 300_000, 350_000, 850_000, 900_000, 950_000]
+    assert all(abs(a - b) < 1_000 for a, b in zip(ends, want))
+    # two rounds 3 s apart whose offsets differ by 2 us: the nearest
+    # round's offset is used, and the drift is reported
+    two = rows + [(5, 3_000_202_000.0, 8_000_200_000.0)]
+    assert spantree.to_profiler_ns(two, 7.9) == pytest.approx(
+        2_900_002_000.0, abs=1)
+    assert spantree.drift_us(two) == pytest.approx(2.0)
