@@ -214,9 +214,14 @@ SINKS: Tuple[OwnedSink, ...] = (
     OwnedSink(
         "ps-grad-apply-owned-legacy", "ps/server.py", "apply_fn", 1,
         fn="_recv_grad",
-        doc="unframed GRAD apply, legacy host path: same aliasing "
-            "contract — jnp.asarray zero-copy-aliases aligned host "
-            "memory while the async apply is still reading it."),
+        doc="GRAD apply, host path: same aliasing contract, kept by "
+            "rotation instead of by copy.  The apply (which donates "
+            "arguments 0 and 2, never this one) reads the receive frame "
+            "where it landed — jnp.asarray aliases its 64-byte-aligned "
+            "payload — so the operand must come from _GradFrames.lend(): "
+            "the lent frame is received into again only once the token "
+            "of the apply that read it is ready (writable()), two ops "
+            "later at the earliest; the other frame takes the next GRAD."),
     OwnedSink(
         "pool-client-decode-owned", "ps/client.py", "submit_decode", 1,
         receiver="pool",

@@ -15,8 +15,9 @@ OwnedSink/OwnedPath/DonatedSlot rows in
 mpit_tpu.analysis.disciplines):
 
 - **OWNED** — freshly allocated or explicitly copied: ``_chunk_owned``,
-  ``device_copy``, ``np.array/empty/zeros/...``, ``.copy()``, or a
-  same-file helper all of whose returns classify OWNED.
+  ``device_copy``, ``np.array/empty/zeros/...``, ``.copy()``, a receive
+  frame lent out of its rotation (``_GradFrames.lend``), or a same-file
+  helper all of whose returns classify OWNED.
 - **UNOWNED** — a view into memory someone else recycles:
   ``as_bytes_view``, ``frombuffer``, ``memoryview``, ``split_wire``,
   or ``.view()`` of a non-owned base.
@@ -48,9 +49,11 @@ register_rules({
 
 OWNED, UNOWNED, UNKNOWN = "owned", "unowned", "unknown"
 
-#: calls that hand back freshly owned memory.
+#: calls that hand back freshly owned memory — or, ``lend``, memory
+#: that is out of the receive rotation until the apply handed it has
+#: run (ps/server.py ``_GradFrames``): owned for as long as it is read.
 _OWNING_CALLS = {
-    "_chunk_owned", "device_copy", "_device_copy", "copy", "deepcopy",
+    "_chunk_owned", "lend", "device_copy", "_device_copy", "copy", "deepcopy",
     "empty", "zeros", "ones", "full", "array", "arange", "concatenate",
     "stack", "empty_like", "zeros_like", "ones_like", "full_like",
     "frombuffer_copy", "tobytes",

@@ -254,7 +254,10 @@ class SpanRecorder:
     def end_when_ready(self, span: OpSpan, result: Any) -> None:
         """Hand ``span`` to the waiter thread, which marks ``exec`` when
         the result handed over before this one is ready and ends the
-        span when ``result`` is.  The caller must not touch the span
+        span when ``result`` is.  ``result`` must be something no later
+        computation donates (the server hands over its apply's token,
+        not the shard): a deleted array cannot be waited on, and its
+        span ends ``lost`` at once.  The caller must not touch the span
         again."""
         span.cpu0 = None  # another thread ends it: no CPU attribution
         with self._hist_lock:

@@ -130,6 +130,67 @@ from mpit_tpu.shardctl.shardmap import ShardMap
 from mpit_tpu.utils.logging import get_logger
 
 
+#: XLA:CPU takes host memory under ``jnp.asarray`` as it stands only on
+#: this boundary; anything else it copies into a buffer of its own.
+_XLA_CPU_ALIGN = 64
+
+_shard_head = jax.jit(lambda param: param[:1])
+
+
+class _GradFrames:
+    """One client's GRAD receive staging on the unchunked path: two
+    frames, received into in turn.  The host apply reads a frame's
+    payload where it landed — the payload starts on the boundary
+    XLA:CPU aliases, so :meth:`lend` moves nothing — and the
+    `ps-grad-apply-owned` contract (jax never reads memory the wire is
+    writing) holds by rotation instead of by copy: the frame lent to an
+    apply goes out of use (:meth:`lent_until`) until that apply has run
+    (:meth:`writable`).  The GRAD ack does not serialize this: the
+    apply is only dispatched when the ack goes out."""
+
+    def __init__(self, hdr: int, nbytes: int,
+                 views_of: Callable[[np.ndarray], Any]):
+        self._frames, self._views = [], []
+        for _ in range(2):
+            raw = np.zeros(hdr + nbytes + _XLA_CPU_ALIGN, np.uint8)
+            start = -(raw.ctypes.data + hdr) % _XLA_CPU_ALIGN
+            frame = raw[start:start + hdr + nbytes]
+            self._frames.append(frame)
+            self._views.append(views_of(frame[hdr:]))
+        self._readers: List[Any] = [None, None]  # the apply reading each
+        self._cur = 0
+
+    @property
+    def frame(self) -> np.ndarray:
+        """The frame the next GRAD is received into."""
+        return self._frames[self._cur]
+
+    @property
+    def views(self) -> Any:
+        """Its payload as the codec cuts it: one typed view, or the
+        wire parts."""
+        return self._views[self._cur]
+
+    def writable(self) -> bool:
+        """Whether the apply that last read :attr:`frame` has run."""
+        reader = self._readers[self._cur]
+        if reader is not None and not reader.is_ready():
+            return False
+        self._readers[self._cur] = None
+        return True
+
+    def lend(self) -> Any:
+        """The payload as the jitted apply's operand (call it where the
+        arrays shall live)."""
+        return jax.tree.map(jnp.asarray, self.views)
+
+    def lent_until(self, token) -> None:
+        """``token`` is ready when the apply that got :meth:`lend`'s
+        operand has run; receive into the other frame meanwhile."""
+        self._readers[self._cur] = token
+        self._cur ^= 1
+
+
 class ParamServer:
     def __init__(
         self,
@@ -223,7 +284,7 @@ class ParamServer:
         self.size = -1
         self.param: Optional[jnp.ndarray] = None  # device-resident shard
         self.rule_state = None
-        self.grad_bufs: Dict[int, np.ndarray] = {}  # host recv staging, per client
+        self.grad_bufs: Dict[int, _GradFrames] = {}  # host recv staging, per client
         # Codec negotiation state (INIT v2).  codec=None adopts whatever
         # each client announces (per-pair negotiation — mixed-codec
         # gangs are legal); an explicit name validates every
@@ -233,8 +294,6 @@ class ParamServer:
             codec_mod.get(codec)
         self._codec_pin = codec or None
         self._codecs: Dict[int, codec_mod.Codec] = {}
-        self._grad_views: Dict[int, List[np.ndarray]] = {}
-        self._grad_data: Dict[int, np.ndarray] = {}  # identity typed view
         self._push_bufs: Dict[int, np.ndarray] = {}
         self._push_host: Dict[int, np.ndarray] = {}
         self._apply_cache: Dict[str, Callable] = {}
@@ -331,6 +390,7 @@ class ParamServer:
         self._spans = get_recorder()
         _m, _r = self.metrics, rank
         self._m_grads = _m.counter("mpit_ps_grads_applied_total", rank=_r)
+        self._m_inplace = _m.counter("mpit_ps_apply_inplace_total", rank=_r)
         self._m_served = _m.counter("mpit_ps_params_served_total", rank=_r)
         self._m_dups = _m.counter("mpit_ps_dup_ops_total", rank=_r)
         self._m_stale = _m.counter("mpit_ps_stale_drops_total", rank=_r)
@@ -462,6 +522,13 @@ class ParamServer:
         self._m_grads.value = int(v)  # checkpoint restore continuity
 
     @property
+    def apply_inplace(self) -> int:
+        """Host applies whose donation engaged: the new shard stands
+        where the old one stood.  The rest fell back to fresh outputs
+        because something still held a view of the shard."""
+        return int(self._m_inplace.value)
+
+    @property
     def params_served(self) -> int:
         return int(self._m_served.value)
 
@@ -531,6 +598,15 @@ class ParamServer:
                               rank=self.rank, grad_n=grad_span.args["n"])
         span.mark("queued")
         return span
+
+    def _apply_token(self):
+        """One element of the shard as an array of its own: ready when
+        the apply that produced ``self.param`` has run, and an output
+        no apply donates, so it outlives the shard it was cut from — a
+        later donated apply deletes that.  What waits for an apply
+        (the recorder's waiter, a lent receive frame) waits on this."""
+        with self._dev_ctx():
+            return _shard_head(self.param)
 
     def _await_apply(self, span) -> None:
         """While recording: wait for a pending apply under a phase of
@@ -643,7 +719,7 @@ class ParamServer:
             else:
                 with self._dev_ctx():
                     self.param = jnp.zeros((size,), dtype=self.dtype)
-                    self.rule_state = self.rule.init(self.param)
+                    self.rule_state = self._init_state(self.param)
         else:
             # All clients must agree on this server's shard (reference :87-88).
             assert (self.offset, self.size) == (offset, size), (
@@ -710,13 +786,6 @@ class ParamServer:
                 "would not be bitwise-equal to the whole-shard apply. "
                 "Use a splittable rule (add/rmsprop/adadelta) or turn "
                 "chunking off (docs/PROTOCOL.md §12.5)")
-        if self._hbm is None and self.rule_state:
-            # The chunk applies DONATE param + state (in-place slice
-            # updates; §12.3), and rule inits may alias several leaves
-            # to one zeros buffer (rmsprop) — donating one buffer
-            # twice is an XLA error.  Break the aliasing now (the
-            # dplane slot does the same at construction).
-            self.rule_state = _dphbm.dedupe_state(self.rule_state)
 
     def _negotiate_v4(self, crank: int, raw: np.ndarray) -> "codec_mod.Codec":
         """INIT v4: codec + FT posture + the versioned shard map.  The
@@ -810,13 +879,11 @@ class ParamServer:
             return {k: jnp.asarray(v) for k, v in state.items()}
 
     def _init_state(self, param):
-        """Fresh rule state for ``param``.  Donated applies (dplane)
-        need the aliased zeros_like leaves some rules share broken
-        apart — donating one buffer twice is an XLA error."""
-        state = self.rule.init(param)
-        if self._dp_cfg is not None and self._dp_cfg.donate:
-            state = _dphbm.dedupe_state(state)
-        return state
+        """Fresh rule state for ``param``.  The applies donate it, so
+        the one zeros_like array some rules hand to several leaves
+        (adam's m and v) is broken apart — donating one buffer twice
+        is an XLA error."""
+        return _dphbm.dedupe_state(self.rule.init(param))
 
     def _hdr_for(self, crank: int) -> int:
         """Header size of this client's data frames (GRAD/PARAM_PUSH)."""
@@ -873,8 +940,7 @@ class ParamServer:
             timing = self._timing.get(crank, False)
             stride = self._chunk_stride_for(crank, codec)
             self._codecs[crank] = codec
-            for store in (self._grad_views, self._grad_data,
-                          self.grad_bufs, self._push_bufs,
+            for store in (self.grad_bufs, self._push_bufs,
                           self._push_host, self._param_send,
                           self._chunk_asm):
                 store.pop(crank, None)
@@ -889,8 +955,6 @@ class ParamServer:
             return
         hdr = self._hdr_for(crank)
         self._codecs[crank] = codec
-        self._grad_views.pop(crank, None)
-        self._grad_data.pop(crank, None)
         self._push_bufs.pop(crank, None)
         self._push_host.pop(crank, None)
         self._param_send.pop(crank, None)
@@ -898,14 +962,13 @@ class ParamServer:
         self._chunk_rx_push.pop(crank, None)
         self._chunk_asm.pop(crank, None)
         if codec.identity:
-            buf = np.zeros(hdr + self.size * np.dtype(self.dtype).itemsize,
-                           np.uint8)
-            self.grad_bufs[crank] = buf
-            self._grad_data[crank] = buf[hdr:].view(self.dtype)
+            self.grad_bufs[crank] = _GradFrames(
+                hdr, self.size * np.dtype(self.dtype).itemsize,
+                lambda payload: payload.view(self.dtype))
         else:
-            buf = np.zeros(hdr + codec.wire_nbytes(self.size), np.uint8)
-            self.grad_bufs[crank] = buf
-            self._grad_views[crank] = codec.split_wire(buf[hdr:], self.size)
+            self.grad_bufs[crank] = _GradFrames(
+                hdr, codec.wire_nbytes(self.size),
+                lambda payload: codec.split_wire(payload, self.size))
         timing = self._timing.get(crank, False)
         if hdr:
             self._ack_send[crank] = np.zeros(
@@ -917,29 +980,35 @@ class ParamServer:
     def _release_client(self, crank: int) -> None:
         """Drop an evicted client's staging (its shard registration's
         per-client footprint); the shard itself is shared state."""
-        for store in (self.grad_bufs, self._grad_views, self._grad_data,
-                      self._push_bufs, self._push_host, self._param_send,
-                      self._codecs, self._ack_send, self._req_buf,
-                      self._hb_buf, self._chunk_rx, self._chunk_rx_push,
-                      self._chunk_asm):
+        for store in (self.grad_bufs, self._push_bufs, self._push_host,
+                      self._param_send, self._codecs, self._ack_send,
+                      self._req_buf, self._hb_buf, self._chunk_rx,
+                      self._chunk_rx_push, self._chunk_asm):
             store.pop(crank, None)
 
     def _apply_for(self, codec: "codec_mod.Codec") -> Callable:
         """The jitted shard update for one codec: frame decode fused with
         ``rule.apply`` into a single XLA program (one call per grad, same
-        as the fp32 path)."""
+        as the fp32 path).  Param and rule state are DONATED (never the
+        gradient): the update then sweeps the shard where it stands
+        instead of writing every output into a fresh whole-shard
+        allocation.  Best-effort and numerics-neutral, as in
+        :meth:`_chunk_apply_for`: while a zero-copy view of the shard is
+        alive (a pull in flight, a checkpoint) jax declines by itself
+        and allocates, and the view keeps its bytes —
+        ``mpit_ps_apply_inplace_total`` says how often it engaged."""
         fn = self._apply_cache.get(codec.name)
         if fn is None:
             rule_apply = self.rule.apply
             if codec.identity:
-                fn = jax.jit(rule_apply)
+                fn = jax.jit(rule_apply, donate_argnums=(0, 2))
             else:
                 size = self.size
 
                 def _decode_apply(param, parts, state):
                     return rule_apply(param, codec.decode_parts(parts, size), state)
 
-                fn = jax.jit(_decode_apply)
+                fn = jax.jit(_decode_apply, donate_argnums=(0, 2))
             self._apply_cache[codec.name] = fn
         return fn
 
@@ -995,6 +1064,17 @@ class ParamServer:
             self._snap_version = self._hbm.version
         else:
             self._snap_version += 1
+
+    def _release_snapshot(self) -> None:
+        """Let go of the cached host view and frames of the version the
+        next apply replaces: the identity codec serves a zero-copy view
+        of the shard, and a view still held when the donated apply is
+        dispatched makes jax decline the donation.  Nothing is lost — a
+        version that is about to be stale never hits again.  A view in
+        flight elsewhere (a reply task, a cell's frame history) keeps
+        pinning its buffer; that apply allocates, as every apply did."""
+        self._snap_host = None
+        self._snap_wire.clear()
 
     def _snapshot_wire(self, codec: "codec_mod.Codec") -> np.ndarray:
         """The current version's PARAM frame for ``codec``, cached: N
@@ -1280,7 +1360,9 @@ class ParamServer:
             self._apply_chunk(crank, codec, body, lo, hi, commit=done,
                               span=span)
             if done:
-                self._spans.end_when_ready(exec_span, self.param)
+                if self._spans.enabled:
+                    self._spans.end_when_ready(exec_span,
+                                               self._apply_token())
                 self._m_grads.inc()
                 self._committed()
             if not self.live.on:
@@ -1649,6 +1731,10 @@ class ParamServer:
                     self.transport, snapshot, crank, tags.PARAM,
                     live=self.live, abort=self._svc_abort(crank, gen),
                 )
+                # This loop now waits for the next request; a view of
+                # the shard left bound here would pin it through the
+                # next apply (:meth:`_release_snapshot`).
+                del snapshot
                 self._m_served.inc()
                 span.end("served")
                 continue
@@ -1681,6 +1767,7 @@ class ParamServer:
                 # client's next gradient will echo (staleness telemetry).
                 pack_version(reply, self._snap_version)
             reply[hdr:] = wire_u8
+            del wire, wire_u8  # copied: do not pin the shard (as above)
             span.mark("send")
             if timing:
                 # The reply's timing tail (§6.7): echoed request stamp,
@@ -2133,11 +2220,15 @@ class ParamServer:
         framed = self._framed.get(crank, False)
         timing = self._timing.get(crank, False)
         hdr = self._hdr_for(crank)
-        gbuf = self.grad_bufs[crank]
-        parts = self._grad_views.get(crank)
-        data = self._grad_data.get(crank)
+        frames = self.grad_bufs[crank]
         apply_fn = self._apply_for(codec)
         while self.live.on:
+            # The frame about to be received into was lent to the apply
+            # two ops back.  It has run, unless pushes outpace applies
+            # with no pull between them (a pull waits for its apply).
+            while not frames.writable():
+                yield EXEC
+            gbuf = frames.frame
             got = yield from aio_recv(
                 self.transport, crank, tags.GRAD, live=self.live, out=gbuf,
                 abort=self._svc_abort(crank, gen),
@@ -2179,46 +2270,57 @@ class ParamServer:
                     self._stale_hist(crank).observe(staleness)
             span.note(bytes=gbuf.nbytes)
             span.mark("copy")
-            # The apply's operands are owned copies of the rx views
-            # (:meth:`_chunk_owned` — `ps-grad-apply-owned`, MT-D901).
             # The GRAD_ACK below does NOT serialize buffer reuse: the
             # jitted apply only *dispatches* before the ack goes out,
             # and jax zero-copy-aliases aligned host arrays, so the
-            # next GRAD landing in ``gbuf`` would race the in-flight
-            # execution (visible as wrong applied bytes whenever the
+            # next GRAD landing in the memory the apply was handed
+            # would race the in-flight execution (`ps-grad-apply-owned`,
+            # MT-D901; visible as wrong applied bytes whenever the
             # backend queue is backed up, e.g. first-call compiles).
-            # Hence two phases and a span of its own: ``copy`` (the
-            # owned copy and ``jnp.asarray``), ``dispatch`` (the call
-            # returns when the apply is enqueued), and ``apply_exec``,
-            # which the recorder's waiter ends when the result is ready
-            # — after the ack, which it does not delay.
+            # Hence the operand is an owned copy (device path) or a
+            # frame out of rotation until its apply has run (host path,
+            # :class:`_GradFrames`), and the op has two phases and a
+            # span of its own: ``copy`` (making the operand),
+            # ``dispatch`` (the call returns when the apply is
+            # enqueued), and ``apply_exec``, which the recorder's waiter
+            # ends when the apply has run — after the ack, which it
+            # does not delay.
             if self._hbm is not None:
                 # Device-resident path: the slot's donated fused
                 # decode+apply — same math, same operand order as the
-                # legacy jit below, so both runs stay bitwise equal.
-                owned: Any = (
-                    self._chunk_owned(data if data is not None else gbuf)
-                    if parts is None
-                    else [self._chunk_owned(v) for v in parts])
+                # host jit below, so both runs stay bitwise equal.
+                views = frames.views
+                owned: Any = (self._chunk_owned(views) if codec.identity
+                              else [self._chunk_owned(v) for v in views])
                 span.mark("dispatch")
                 exec_span = self._exec_span(crank, span)
                 self._hbm.apply_wire(codec, owned)
                 self.param = self._hbm.param
                 self.rule_state = self._hbm.rule_state
+                token = (self._apply_token() if self._spans.enabled
+                         else None)
             else:
+                self._release_snapshot()
                 with self._dev_ctx():
-                    if parts is None:
-                        grad_in: Any = jnp.asarray(self._chunk_owned(
-                            data if data is not None else gbuf))
-                    else:
-                        grad_in = [jnp.asarray(self._chunk_owned(v))
-                                   for v in parts]
+                    grad_in = frames.lend()
                     span.mark("dispatch")
                     exec_span = self._exec_span(crank, span)
+                    # Whether the donation engaged is the shard's
+                    # address before and after.  Reading it does not
+                    # wait for the apply; a declined one pays here for
+                    # the allocation of its outputs, nothing else: what
+                    # pins the shard is a view taken of it ready, so
+                    # nothing is queued ahead of this apply.
+                    stood_at = self.param.unsafe_buffer_pointer()
                     self.param, self.rule_state = apply_fn(
                         self.param, grad_in, self.rule_state
                     )
-            self._spans.end_when_ready(exec_span, self.param)
+                    inplace = self.param.unsafe_buffer_pointer() == stood_at
+                    token = self._apply_token()
+                frames.lent_until(token)
+                self._m_inplace.inc(int(inplace))
+                exec_span.note(inplace=int(inplace))
+            self._spans.end_when_ready(exec_span, token)
             self._m_grads.inc()
             self._committed()
             if not self.live.on:
@@ -3020,7 +3122,7 @@ class ParamServer:
                         k: _dphbm.device_copy(jnp.asarray(v))
                         for k, v in state.items()}
                 else:  # stateless rule (plain add) or legacy checkpoint
-                    self.rule_state = self.rule.init(self.param)
+                    self.rule_state = self._init_state(self.param)
         for crank_s, info in (meta.get("clients") or {}).items():
             crank = int(crank_s)
             if crank not in self.cranks:

@@ -827,6 +827,7 @@ def run_rank(
         return {
             "role": "server",
             "grads_applied": server.grads_applied,
+            "apply_inplace": server.apply_inplace,
             "params_served": server.params_served,
             "ckpts_written": server.ckpts_written,
         }
@@ -1113,7 +1114,8 @@ def main(argv: Optional[List[str]] = None) -> None:
 
 def _summarize(result: Dict[str, Any]) -> Dict[str, Any]:
     keep = {"role", "final_test_err", "time_to_target", "elapsed",
-            "grads_applied", "params_served", "best_test_err",
+            "grads_applied", "apply_inplace", "params_served",
+            "best_test_err",
             "reads", "monotone", "busy_honored",
             "final_loss", "final_eval_loss", "tokens_per_s", "tokens_total",
             "steps", "train_seconds", "first_step_seconds", "mosaic_calls",

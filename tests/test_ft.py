@@ -530,6 +530,11 @@ class TestHeartbeatLeaseEviction:
                                    daemon=True)
         starter.start()
         join_all([starter], timeout=10)
+        # start() returns when the INIT is delivered, not when the
+        # server's listener has taken it
+        deadline = time.monotonic() + 10
+        while servers[0].rejoins < 1 and time.monotonic() < deadline:
+            time.sleep(0.005)
         assert servers[0].rejoins == 1
         c2b.async_recv_param()
         c2b.wait()

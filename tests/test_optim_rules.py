@@ -177,6 +177,22 @@ class TestRegistry:
         with pytest.raises(ValueError):
             rules.state_slots("nope")
 
+    @pytest.mark.parametrize("name", sorted(rules.names()))
+    def test_server_side_state_has_pairwise_distinct_buffers(self, name):
+        # The host server's apply donates the shard and every slot: one
+        # zeros array under two slots (adam's m and v as init hands them
+        # out) would be donated twice, which XLA refuses.
+        from mpit_tpu.comm.local import LocalRouter
+        from mpit_tpu.ps import ParamServer
+
+        server = ParamServer(0, [1], LocalRouter(2).endpoint(0), rule=name)
+        server._negotiate(1, np.asarray([0, 64, 0], np.int64).tobytes())
+        held = [server.param, *server.rule_state.values()]
+        assert sorted(server.rule_state) == sorted(
+            rules.make(name).init(server.param))
+        where = [a.unsafe_buffer_pointer() for a in held]
+        assert len(set(where)) == len(where), name
+
 
 def quadratic_vgf(w, target):
     """loss = 0.5*||w-target||², grad = w-target."""

@@ -10,15 +10,18 @@ import contextlib
 import threading
 import time
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from mpit_tpu import obs
+from mpit_tpu.comm import codec as codec_mod
 from mpit_tpu.comm.local import LocalRouter
 from mpit_tpu.optim import rules
 from mpit_tpu.optim.downpour import Downpour
 from mpit_tpu.optim.shells import SingleWorker
-from mpit_tpu.ps import ParamClient, ParamServer, Shard, shard_layout
+from mpit_tpu.ps import ParamClient, ParamServer, Shard, shard_layout, tags
 
 
 class TestShardLayout:
@@ -626,3 +629,191 @@ class TestServerCheckpointResume:
         assert server2.grads_applied == 1  # counter restored from meta
         client2.stop()
         join_all([t])
+
+
+class WireClient:
+    """The unframed wire written by hand against one host server (rank
+    0) from rank 1: the test decides every frame the server sees, so the
+    same frames can go through a plain loop of the rule."""
+
+    def __init__(self, rule, codec_name, size):
+        self.codec = codec_mod.get(codec_name)
+        self.size = size
+        router = LocalRouter(2)
+        self.ep = router.endpoint(1)
+        self.server = ParamServer(0, [1], router.endpoint(0), rule=rule)
+        self.thread = threading.Thread(target=self.server.start, daemon=True)
+        self.thread.start()
+        self.ep.send(np.asarray([0, size, self.codec.wire_id], np.int64),
+                     0, tags.INIT)
+
+    def frame(self, x):
+        if self.codec.identity:
+            return x.astype(np.float32).view(np.uint8)
+        wire = np.empty(self.codec.wire_nbytes(self.size), np.uint8)
+        self.codec.encode_into(x.astype(np.float32), wire)
+        return wire
+
+    def operand(self, frame):
+        """What the jitted apply is handed for ``frame``."""
+        if self.codec.identity:
+            return jnp.asarray(frame.view(np.float32))
+        return [jnp.asarray(v)
+                for v in self.codec.split_wire(frame, self.size)]
+
+    def seed(self, frame):
+        self.ep.send(frame, 0, tags.PARAM_PUSH)
+        self.ep.recv(0, tags.PARAM_PUSH_ACK)
+
+    def push(self, frame):
+        self.ep.send(frame, 0, tags.GRAD)
+        self.ep.recv(0, tags.GRAD_ACK)
+
+    def pull(self):
+        self.ep.send(tags.EMPTY, 0, tags.PARAM_REQ)
+        return np.frombuffer(self.ep.recv(0, tags.PARAM), np.uint8)
+
+    def stop(self):
+        self.ep.send(tags.EMPTY, 0, tags.STOP)
+        join_all([self.thread])
+
+    def close(self):
+        self.server.live.stop()
+        self.thread.join(5)
+
+
+@pytest.fixture
+def obs_on():
+    obs.configure(enabled=True, reset=True)
+    try:
+        yield obs.get_recorder()
+    finally:
+        obs.configure(enabled=None, reset=True)
+
+
+class TestDonatedApply:
+    """The unchunked host apply donates the shard and the rule's slots
+    (ISSUE 24): same bytes as an undonated loop, in place whenever
+    nothing holds a view of the shard, a fresh output when something
+    does."""
+
+    SIZE = 1000  # not a multiple of the int8 block
+
+    @pytest.mark.parametrize("codec_name", ["none", "int8"])
+    @pytest.mark.parametrize("rule_name", sorted(rules.names()))
+    def test_five_grads_bit_equal_to_undonated_loop(self, rng, rule_name,
+                                                    codec_name):
+        size = self.SIZE
+        wc = WireClient(rule_name, codec_name, size)
+        try:
+            seed = wc.frame(rng.normal(size=size))
+            frames = [wc.frame(rng.normal(size=size)) for _ in range(5)]
+            wc.seed(seed)
+            for frame in frames:
+                wc.push(frame)
+            wc.stop()
+        finally:
+            wc.close()
+        server, codec = wc.server, wc.codec
+
+        rule = rules.make(rule_name)
+        if codec.identity:
+            p0 = seed.view(np.float32)
+            plain = jax.jit(rule.apply)
+        else:
+            p0 = np.empty(size, np.float32)
+            codec.decode_into(seed, p0)
+            plain = jax.jit(lambda p, parts, s: rule.apply(
+                p, codec.decode_parts(parts, size), s))
+        p = jnp.asarray(p0)
+        state = rule.init(p)
+        for frame in frames:
+            p, state = plain(p, wc.operand(frame), state)
+
+        assert server.grads_applied == 5
+        assert np.array_equal(np.asarray(server.param), np.asarray(p))
+        assert sorted(server.rule_state) == sorted(state)
+        for name, leaf in state.items():
+            assert np.array_equal(np.asarray(server.rule_state[name]),
+                                  np.asarray(leaf)), name
+        assert server.apply_inplace in (4, 5)
+        assert server.metrics.counter(
+            "mpit_ps_apply_inplace_total", rank=0).value \
+            == server.apply_inplace
+
+    def test_held_snapshot_view_keeps_its_bytes_and_the_apply_allocates(
+            self, rng):
+        size = self.SIZE
+        wc = WireClient("adam", "none", size)
+        try:
+            server = wc.server
+            seed = wc.frame(rng.normal(size=size))
+            frames = [wc.frame(rng.normal(size=size)) for _ in range(3)]
+            wc.seed(seed)
+            wc.push(frames[0])
+            first = wc.pull().view(np.float32)  # waits for the apply
+            assert server.apply_inplace == 1
+            # what a reader's reply task holds while its send is in
+            # flight: the zero-copy view of the shard the cache serves
+            view = server._snapshot_wire(wc.codec)
+            assert not view.flags.owndata
+            assert np.array_equal(view, first)
+            wc.push(frames[1])
+            second = wc.pull().view(np.float32)
+            assert np.array_equal(view, first)  # its bytes stayed
+            assert server.apply_inplace == 1  # declined, and counted so
+            del view
+            wc.push(frames[2])
+            third = wc.pull().view(np.float32)
+            assert server.apply_inplace == 2  # engages again
+            wc.stop()
+        finally:
+            wc.close()
+        rule = rules.make("adam")
+        plain = jax.jit(rule.apply)
+        p = jnp.asarray(seed.view(np.float32))
+        state = rule.init(p)
+        for frame, got in zip(frames, (first, second, third)):
+            p, state = plain(p, wc.operand(frame), state)
+            assert np.array_equal(got, np.asarray(p))
+        assert server.grads_applied == 3
+
+    def test_two_worker_traced_gang_loses_no_apply_span(self, obs_on, rng):
+        """Pushes of two workers back to back with no pull between
+        them: a later donated apply deletes the shard an earlier one
+        produced while the recorder's waiter may still be waiting on
+        it.  The waiter is handed the apply's token, not the shard."""
+        rec = obs_on
+        size, pushes = 1 << 22, 3
+        with launch(1, 2, rule="adam") as (servers, clients, threads):
+            starts = [threading.Thread(
+                target=c.start, daemon=True,
+                args=(np.zeros(size, np.float32), np.zeros(size, np.float32)))
+                for c in clients]  # concurrently: the server waits for both
+            for t in starts:
+                t.start()
+            for t in starts:
+                t.join(30)
+                assert not t.is_alive(), "client start hung"
+            for k in range(pushes):
+                for c in clients:
+                    c.grad[:] = k + 1.0
+                    c.async_send_grad()
+                for c in clients:
+                    c.wait()
+            for c in clients:
+                c.async_recv_param()
+                c.wait()
+            assert rec.drain(timeout=30)
+            for c in clients:
+                c.stop()
+            join_all(threads)
+            server = servers[0]
+        execs = [s for s in rec.spans if s.name == "apply_exec"]
+        assert len(execs) == server.grads_applied == 2 * pushes
+        assert all(s.outcome == "ready" for s in execs)
+        assert all([p for p, _t in s.marks] == ["queued", "exec"]
+                   for s in execs)
+        assert sum(s.args["inplace"] for s in execs) == server.apply_inplace
+        assert server.apply_inplace >= 2 * pushes - 1
+        np.testing.assert_array_equal(clients[0].param, clients[1].param)

@@ -254,6 +254,9 @@ class SlowResult:
         time.sleep(self.delay)
         return self
 
+    def unsafe_buffer_pointer(self):  # a fresh output, never in place
+        return id(self)
+
     def __array__(self, dtype=None, copy=None):
         return np.asarray(self.value, dtype)
 
@@ -266,6 +269,8 @@ def test_apply_exec_ends_after_the_ack_and_does_not_delay_it(obs_on, delay):
             lambda param, grad, state: (
                 SlowResult(np.asarray(param) + np.asarray(grad), delay),
                 state))
+        # the stub's result stands for its own token: what is waited on
+        server._apply_token = lambda: server.param
         pc.start(np.zeros(SIZE, np.float32), np.zeros(SIZE, np.float32))
         pc.grad[:] = 1.0
         pc.async_send_grad()
