@@ -39,20 +39,6 @@ COMPILE_EVENTS = (
 now = time.monotonic  # one clock for every process of the host
 
 
-def set_vocab(vocab: int) -> None:
-    """The configuration's ``vocab_size`` in a program that has no
-    ``--lm_vocab``: ``LmTrainer``, ``launch.lm_layout`` and
-    ``launch._serve_vec_len`` all call ``lm.model.build`` without a
-    vocabulary and get its keyword default, the byte stream's 256.  The
-    benchmark sets that default in its own process, so the token table,
-    the head, the softmax and the exchanged vector have the published
-    size; the stream's byte ids index the first 256 rows.  PERF.md
-    section 7 lists the switch that replaces this."""
-    from mpit_tpu.lm import model
-
-    model.build.__kwdefaults__["vocab"] = int(vocab)
-
-
 class CompileLog:
     """When this process traced, lowered or compiled a program: the
     window may hold none of it."""
@@ -143,9 +129,12 @@ class WorkerLoop:
             for s, k in ((seed, 0), (seed, 7), (seed + 31, 2)))
 
     def _reference_check(self, tr: Any, tokens: Any) -> Dict[str, Any]:
-        """The system's loss and gradient against the plain reference on
-        one seeded sequence at the cell's widths, on the seeded initial
-        weights; and the count of Mosaic calls in the lowered step.  The
+        """The system's loss and gradient against the configuration's
+        plain reference (``reference/<module>.py``, by the key
+        ``reference`` of the configuration's file, which the module is
+        handed whole) on one seeded sequence at the cell's widths, on
+        the seeded initial weights; and the count of Mosaic calls in the
+        lowered step.  The
         system takes the sequence repeated over the cell's batch, so one
         trace and one lowering of the very program the window runs serve
         both (each costs seconds at these depths), and the mean over
@@ -158,19 +147,21 @@ class WorkerLoop:
         import jax
         import jax.numpy as jnp
 
-        from chipbench.reference import gpt_plain
+        from chipbench import compare, spec as spec_mod
 
-        cfg = tr.cfg
+        config = self.spec["config"]
+        reference = spec_mod.load_named(self.spec["bench_dir"], "reference",
+                                        config)
         w0 = tr.model.flat.w0
         row = tokens[:1]
         tiled = jnp.tile(row, (tokens.shape[0], 1))
         lowered = jax.jit(tr._vgf).lower(w0, tiled)
         mosaic_calls = lowered.as_text().count("tpu_custom_call")
-        ref_loss, ref_grad = gpt_plain.loss_and_grad_flat(
-            w0, tr.model.flat.unravel, row, int(cfg.n_heads),
-            int(cfg.n_layers))
+        ref_loss, ref_grad = reference.loss_and_grad_flat(
+            w0, tr.model.flat.unravel, row, config)
         sys_loss, sys_grad = lowered.compile()(w0, tiled)
-        out = gpt_plain.compare(sys_loss, sys_grad, ref_loss, ref_grad)
+        out = compare.compare(sys_loss, sys_grad, ref_loss, ref_grad,
+                              reference)
         out["mosaic_calls"] = mosaic_calls
         return out
 
@@ -303,7 +294,8 @@ class WorkerLoop:
 
             found = glob.glob(os.path.join(
                 trace_dir, "plugins", "profile", "*", "*.xplane.pb"))
-            reduction = (reduce_trace(found[0], spec["step_module"]) if found
+            reduction = (reduce_trace(found[0], spec["step_module"],
+                                      spec["config"]["scopes"]) if found
                          else {"ok": False, "why": "no .xplane.pb written"})
         return {
             "steps": state["k"],
@@ -329,7 +321,7 @@ class WorkerLoop:
                     + int(stats.get("peak_bytes_reserved", 0)),
                     int(stats.get("bytes_limit", 1 << 62))),
                 "memory_stats": {k: int(v) for k, v in stats.items()},
-                "n_layers": int(cfg.n_layers),
+                "vector_len": int(tr.model.flat.w0.size),
             },
         }
 
@@ -338,9 +330,6 @@ def main() -> None:
     spec = json.loads(os.environ[SPEC_ENV])
     marks: Dict[str, float] = {}
     import jax
-
-    if spec.get("vocab_size"):
-        set_vocab(spec["vocab_size"])  # every rank: the cut is derived from it
 
     marks["jax_imported"] = now()
     compiles = CompileLog()

@@ -1,21 +1,20 @@
 """L1 kernels: the least time the chip's peaks allow the micro-step's
-attention (``chipbench/flops.py`` from shapes, ``chipbench/peaks.json``)
-over the device time its Mosaic calls took.  The line the runner prints
-before the result says which peak binds."""
+attention (FLOPs and bytes of the ``attn`` kernel family from the
+configuration's arithmetic, ``chipbench/arithmetic/<module>.py``
+``kernels``; peaks from ``chipbench/peaks.json``) over the device time
+the Mosaic calls under that family's scope took
+(``layers/flash_ms_per_step.py``).  The line printed before the result
+says which peak binds."""
 
 from chipbench import flops
 
 
 def read(run):
-    red = run["reduction"]
-    if not red.get("step_module_runs") or not red.get("mosaic_calls") \
-            or run["peaks"] is None:
+    found = flops.kernel_family(run, "attn")
+    if found is None or run["peaks"] is None:
         return None
-    cell = run["cell"]
-    need_flops, need_bytes = flops.flash_step_cost(
-        cell.config, int(cell.traffic["batch"]))
-    seconds = red["mosaic_s"] / red["step_module_runs"]
-    share, bound = flops.roofline(need_flops, need_bytes, seconds,
+    kernel, seconds = found
+    share, bound = flops.roofline(kernel["flops"], kernel["bytes"], seconds,
                                   run["peaks"])
     print(f"chipbench: flash roofline is bound by {bound}", flush=True)
     return share

@@ -5,7 +5,8 @@ backward: ``jax.named_scope`` in ``models/transformer.py`` and
 ``lm/model.py``), among the operations the first worker's chip ran
 inside the step's own program in the traced window.  A fusion counts
 under the scope of its root operation.  A line before the result gives
-the same for every scope of the model (``spantree.SCOPES``), what no
+the same for every scope of the model (``scopes`` in the
+configuration's file), what no
 scope names, and what could not be told apart."""
 
 from chipbench.layers import spantree

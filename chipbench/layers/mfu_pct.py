@@ -1,5 +1,6 @@
 """Device: forward+backward FLOPs per token from shapes, nothing
-recomputed (``chipbench/flops.py``), times this run's ``tokens_per_s``,
+recomputed (the configuration's own arithmetic,
+``chipbench/arithmetic/<module>.py``), times this run's ``tokens_per_s``,
 over chips times the published bf16 peak (``chipbench/peaks.json``).
 This is the traced run's rate, which tracing slows a little; the
 untraced run prints its own on an earlier line."""
@@ -11,5 +12,5 @@ def read(run):
     if run["peaks"] is None:
         return None
     summary = run["summary"]
-    return flops.mfu_pct(run["cell"].config, summary["tokens_per_s"],
+    return flops.mfu_pct(run["cell"], summary["tokens_per_s"],
                          len(summary["worker_ranks"]), run["peaks"])

@@ -29,18 +29,13 @@ import glob
 import json
 import os
 import pathlib
-import re
 import statistics
 from typing import Any, Dict, List, Optional, Tuple
 
-from chipbench import reduce as reduce_mod
+from chipbench import reduce as reduce_mod, spec as spec_mod
 
 Interval = Tuple[float, float]
 CACHE_KEY = "_spantree"  # on the run dict: one parse for all readers
-SCOPE_STAT = "tf_op"  # the metadata stat that holds an operation's name stack
-#: the model's layers as ``jax.named_scope`` names them in the program
-SCOPES = ("embed", "attn", "mlp", "head_loss", "update")
-_SCOPE = re.compile(r"\b(%s)\b" % "|".join(SCOPES))
 MB = 1e6  # bytes
 
 
@@ -284,96 +279,23 @@ def device_idle(run: Dict[str, Any]) -> Optional[List[Interval]]:
 
 # -- the model's layer names on the device operations ---------------------------
 #
-# ``jax.profiler.ProfileData`` gives an event's own stats but not those of
-# its metadata, and the name ``jax.named_scope`` gave an operation
-# (``jit(loss)/.../head_loss/dot_general``) is a stat of the event's
-# metadata in the device plane.  So the plane's metadata is read straight
-# from the file: the few fields of ``XSpace`` that are needed, decoded by
-# hand (tsl/profiler/protobuf/xplane.proto; only varints and
-# length-delimited fields occur in them).
+# The name stack of an operation is read by ``chipbench/reduce.py``
+# (:func:`chipbench.reduce.op_scopes`), which books the Mosaic kernels by
+# it as well; the names stay reachable here for the readers.
+
+AMBIGUOUS = reduce_mod.AMBIGUOUS
+op_scopes = reduce_mod.op_scopes
 
 
-def _varint(buf: bytes, at: int) -> Tuple[int, int]:
-    value, shift = 0, 0
-    while True:
-        byte = buf[at]
-        at += 1
-        value |= (byte & 0x7F) << shift
-        shift += 7
-        if byte < 0x80:
-            return value, at
-
-
-def _fields(buf: bytes):
-    """(field number, wire type, value) of one protobuf message."""
-    at, end = 0, len(buf)
-    while at < end:
-        key, at = _varint(buf, at)
-        number, wire = key >> 3, key & 7
-        if wire == 0:
-            value, at = _varint(buf, at)
-        elif wire == 2:
-            size, at = _varint(buf, at)
-            value, at = buf[at:at + size], at + size
-        elif wire == 1:
-            value, at = buf[at:at + 8], at + 8
-        elif wire == 5:
-            value, at = buf[at:at + 4], at + 4
-        else:
-            raise ValueError(f"wire type {wire} in an xplane message")
-        yield number, wire, value
-
-
-AMBIGUOUS = "?"  # an event name that two name stacks claim in one plane
-
-
-def op_scopes(path: str, plane_name: str) -> Dict[str, str]:
-    """``{event name: name stack}`` for the operations of the device
-    plane ``plane_name``: an ``XLA Ops`` event's name (its HLO text) to
-    the name jax gave the operation, scopes included.  A name that two
-    metadata entries of the plane give different stacks (the same HLO
-    text in two programs) maps to :data:`AMBIGUOUS`.  Empty where the
-    plane holds no such stat."""
-    with open(path, "rb") as fh:
-        space = memoryview(fh.read())
-    out: Dict[str, str] = {}
-    for number, wire, plane in _fields(space):
-        if number != 1 or wire != 2:
-            continue
-        name, metadata, stat_names = "", [], {}
-        for f, w, value in _fields(plane):
-            if f == 2 and w == 2:
-                name = bytes(value).decode("utf-8", "replace")
-            elif f == 4 and w == 2:
-                metadata.append(value)
-            elif f == 5 and w == 2:
-                entry = dict((n, v) for n, _w, v in _fields(value))
-                meta = dict((n, v) for n, _w, v in _fields(entry.get(2, b"")))
-                stat_names[meta.get(1, entry.get(1))] = \
-                    bytes(meta.get(2, b"")).decode("utf-8", "replace")
-        if name != plane_name:
-            continue
-        for entry in metadata:
-            event_name, stack = "", ""
-            for f, w, value in _fields(entry):
-                if f != 2 or w != 2:
-                    continue
-                for g, gw, part in _fields(value):
-                    if g == 2 and gw == 2:
-                        event_name = bytes(part).decode("utf-8", "replace")
-                    elif g == 5 and gw == 2:
-                        stat = dict((n, v) for n, _w, v in _fields(part))
-                        if stat_names.get(stat.get(1)) == SCOPE_STAT:
-                            text = stat.get(5)
-                            if text is None and 7 in stat:  # a reference
-                                text = stat_names.get(stat[7], "").encode()
-                            stack = bytes(text or b"").decode(
-                                "utf-8", "replace")
-            if event_name and stack:
-                known = out.setdefault(event_name, stack)
-                if known != stack:
-                    out[event_name] = AMBIGUOUS
-    return out
+def model_scopes(run: Dict[str, Any]) -> List[str]:
+    """The scopes this run's device time is booked under: those its
+    cell's configuration lists (``scopes`` in the configuration's file);
+    a run that names no cell, as a test's hand-made one, is read under
+    every scope any committed configuration lists."""
+    cell = run.get("cell")
+    if cell is not None:
+        return list(cell.config["scopes"])
+    return spec_mod.all_scopes()
 
 
 def scope_ms_per_step(run: Dict[str, Any]) -> Optional[Dict[str, float]]:
@@ -381,8 +303,9 @@ def scope_ms_per_step(run: Dict[str, Any]) -> Optional[Dict[str, float]]:
     operations of the first chip that ran inside a run of the step's
     program (the ``XLA Modules`` events of ``reduction.step_module`` in
     the traced window, so another program's operations never count),
-    each under the innermost of :data:`SCOPES` in its name stack; the
-    rest under ``unscoped``, names of :data:`AMBIGUOUS` stack under
+    each under the innermost of the configuration's scopes
+    (:func:`model_scopes`) in its name stack; the rest under
+    ``unscoped``, names of :data:`AMBIGUOUS` stack under
     ``ambiguous``, and the programs' own time under ``step``.  None
     without a device trace or a run of the step's program."""
     chip = traced_chip(run)
@@ -396,13 +319,14 @@ def scope_ms_per_step(run: Dict[str, Any]) -> Optional[Dict[str, float]]:
         return None
     starts = [s for s, _e in steps]
     scopes = op_scopes(xplane_path(run), chip["plane"])
+    pattern = reduce_mod.scope_pattern(model_scopes(run))
     total: Dict[str, float] = {"step": sum(e - s for s, e in steps)}
     for name, start, dur in chip["ops"]:
         at = bisect.bisect_right(starts, start) - 1
         if at < 0 or start >= steps[at][1]:
             continue
         stack = scopes.get(name, "")
-        found = _SCOPE.findall(stack)
+        found = pattern.findall(stack) if pattern else []
         key = ("ambiguous" if stack == AMBIGUOUS
                else found[-1] if found else "unscoped")
         total[key] = total.get(key, 0.0) + dur

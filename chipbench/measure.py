@@ -169,10 +169,15 @@ def summarise(results: Dict[int, Dict[str, Any]], t_command: float,
 
 
 def correctness(results: Dict[int, Dict[str, Any]], summary: Dict[str, Any],
-                platform: str) -> List[str]:
+                platform: str, least_mosaic_calls: int,
+                vector_len: int) -> List[str]:
     """Every reason the run is not ``correct``; empty means it is.
     ``platform`` is what a worker must report: ``tpu``, or ``cpu`` in the
-    self-check's rehearsal, which prints no metric."""
+    self-check's rehearsal, which prints no metric.  From the
+    configuration's arithmetic: ``least_mosaic_calls``, the fewest
+    ``tpu_custom_call``s the lowered step may hold, summed over the
+    block's kernel families, and ``vector_len``, the parameters it says
+    are exchanged, which the program's flat vector must have exactly."""
     why: List[str] = []
     ranks = summary["worker_ranks"]
     lo, hi = summary["window"]
@@ -194,11 +199,15 @@ def correctness(results: Dict[int, Dict[str, Any]], summary: Dict[str, Any],
                     why.append(f"worker {rank} holds chip nodes "
                                f"{res.get('chip_nodes')}, not one")
                 nodes += res.get("chip_nodes", [])
-                if (res.get("mosaic_calls") or 0) < 2 * w["n_layers"]:
+                if (res.get("mosaic_calls") or 0) < least_mosaic_calls:
                     why.append(
                         f"worker {rank}: {res.get('mosaic_calls')} "
-                        f"tpu_custom_calls in the lowered step, under "
-                        f"2 x {w['n_layers']} layers")
+                        f"tpu_custom_calls in the lowered step, under the "
+                        f"{least_mosaic_calls} its kernel families need")
+            if w["vector_len"] != vector_len:
+                why.append(f"worker {rank}: the program's vector has "
+                           f"{w['vector_len']} elements, the "
+                           f"configuration's arithmetic says {vector_len}")
             if not w["stream_ok"]:
                 why.append(f"worker {rank}: the copied stream differs from "
                            "the program's")

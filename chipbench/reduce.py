@@ -1,7 +1,8 @@
 """From a profiler trace (``.xplane.pb``) to numbers: the device's busy
 union and idle share over the traced window, time per device operation,
-time of the Mosaic kernels, time of the step's program, and the idle
-gaps named by what the host was doing.  Kept with the benchmark so that
+time of the Mosaic kernels, all together and under each of the model's
+scopes, time of the step's program, and the idle gaps named by what the
+host was doing.  Kept with the benchmark so that
 every PR computes the same number in the same way; checked by the
 self-check against ``chipbench/fixtures/steps4.xplane.pb``.
 
@@ -129,14 +130,133 @@ def _host_activity(annotations: Sequence[Tuple[str, float, float]],
     return inner[0]
 
 
-def reduce_trace(path: str, step_module: str) -> Dict[str, Any]:
+# -- the model's layer names on the device operations ---------------------------
+#
+# ``jax.profiler.ProfileData`` gives an event's own stats but not those of
+# its metadata, and the name ``jax.named_scope`` gave an operation
+# (``jit(loss)/.../head_loss/dot_general``) is a stat of the event's
+# metadata in the device plane.  So the plane's metadata is read straight
+# from the file: the few fields of ``XSpace`` that are needed, decoded by
+# hand (tsl/profiler/protobuf/xplane.proto; only varints and
+# length-delimited fields occur in them).
+
+SCOPE_STAT = "tf_op"  # the metadata stat that holds an operation's name stack
+AMBIGUOUS = "?"  # an event name that two name stacks claim in one plane
+
+
+def scope_pattern(scopes: Sequence[str]) -> Optional["re.Pattern[str]"]:
+    """Finds the model's scopes, as the configuration's file lists them,
+    in a name stack (``transpose(jvp(attn))`` holds ``attn``); None for
+    no scopes."""
+    if not scopes:
+        return None
+    return re.compile(r"\b(%s)\b" % "|".join(re.escape(s) for s in scopes))
+
+
+def _varint(buf: bytes, at: int) -> Tuple[int, int]:
+    value, shift = 0, 0
+    while True:
+        byte = buf[at]
+        at += 1
+        value |= (byte & 0x7F) << shift
+        shift += 7
+        if byte < 0x80:
+            return value, at
+
+
+def _fields(buf: bytes):
+    """(field number, wire type, value) of one protobuf message."""
+    at, end = 0, len(buf)
+    while at < end:
+        key, at = _varint(buf, at)
+        number, wire = key >> 3, key & 7
+        if wire == 0:
+            value, at = _varint(buf, at)
+        elif wire == 2:
+            size, at = _varint(buf, at)
+            value, at = buf[at:at + size], at + size
+        elif wire == 1:
+            value, at = buf[at:at + 8], at + 8
+        elif wire == 5:
+            value, at = buf[at:at + 4], at + 4
+        else:
+            raise ValueError(f"wire type {wire} in an xplane message")
+        yield number, wire, value
+
+
+
+
+def op_scopes(path: str, plane_name: str) -> Dict[str, str]:
+    """``{event name: name stack}`` for the operations of the device
+    plane ``plane_name``: an ``XLA Ops`` event's name (its HLO text) to
+    the name jax gave the operation, scopes included.  A name that two
+    metadata entries of the plane give different stacks (the same HLO
+    text in two programs) maps to :data:`AMBIGUOUS`.  Empty where the
+    plane holds no such stat."""
+    with open(path, "rb") as fh:
+        space = memoryview(fh.read())
+    out: Dict[str, str] = {}
+    for number, wire, plane in _fields(space):
+        if number != 1 or wire != 2:
+            continue
+        name, metadata, stat_names = "", [], {}
+        for f, w, value in _fields(plane):
+            if f == 2 and w == 2:
+                name = bytes(value).decode("utf-8", "replace")
+            elif f == 4 and w == 2:
+                metadata.append(value)
+            elif f == 5 and w == 2:
+                entry = dict((n, v) for n, _w, v in _fields(value))
+                meta = dict((n, v) for n, _w, v in _fields(entry.get(2, b"")))
+                stat_names[meta.get(1, entry.get(1))] = \
+                    bytes(meta.get(2, b"")).decode("utf-8", "replace")
+        if name != plane_name:
+            continue
+        for entry in metadata:
+            event_name, stack = "", ""
+            for f, w, value in _fields(entry):
+                if f != 2 or w != 2:
+                    continue
+                for g, gw, part in _fields(value):
+                    if g == 2 and gw == 2:
+                        event_name = bytes(part).decode("utf-8", "replace")
+                    elif g == 5 and gw == 2:
+                        stat = dict((n, v) for n, _w, v in _fields(part))
+                        if stat_names.get(stat.get(1)) == SCOPE_STAT:
+                            text = stat.get(5)
+                            if text is None and 7 in stat:  # a reference
+                                text = stat_names.get(stat[7], "").encode()
+                            stack = bytes(text or b"").decode(
+                                "utf-8", "replace")
+            if event_name and stack:
+                known = out.setdefault(event_name, stack)
+                if known != stack:
+                    out[event_name] = AMBIGUOUS
+    return out
+
+
+
+def reduce_trace(path: str, step_module: str,
+                 scopes: Sequence[str] = ()) -> Dict[str, Any]:
     """Everything the per-layer readers and ``breakdown`` take from one
     trace.  Seconds unless the key says otherwise.  With several chips
     in the trace, busy time is averaged over them.  ``step_module`` is
     the name of the micro-step's program as the mix's file gives it
     (``jit_loss``); a trace that holds no run of it gives no step time,
-    and ``modules`` says which programs it does hold."""
+    and ``modules`` says which programs it does hold.
+
+    ``scopes`` are the model's, from the configuration's file.  Beside
+    ``mosaic_s`` and ``mosaic_calls``, every Mosaic kernel together,
+    ``mosaic_by_scope`` has ``{scope: [calls, seconds]}`` of the Mosaic
+    calls whose name stack (:func:`op_scopes`) holds exactly that one
+    scope: a kernel family's time is the time under its scope
+    (``arithmetic/<module>.py`` ``kernels``), so one block's kernels are
+    not booked as another's.  A call whose stack holds none of the
+    scopes, or two of them, or whose name two stacks claim, counts for no
+    family: ``mosaic_no_family`` has ``[label, calls, seconds]`` of
+    those."""
     events = load(path)
+    pattern = scope_pattern(scopes)
     annotations = events["annotations"]
     if not annotations or not events["chips"]:
         return {"ok": False, "why": "no device plane or no bench.* "
@@ -149,10 +269,13 @@ def reduce_trace(path: str, step_module: str) -> Dict[str, Any]:
     op_count: Dict[str, int] = collections.Counter()
     mosaic_ns = 0.0
     mosaic_calls = 0
+    by_scope: Dict[str, List[float]] = {}  # scope -> [calls, ns]
+    no_family: Dict[str, List[float]] = {}  # label -> [calls, ns]
     module_ns: Dict[str, List[float]] = collections.defaultdict(list)
     gap_by_activity: Dict[str, float] = collections.Counter()
     longest_gap_ns = 0.0
-    for chip in events["chips"].values():
+    for plane_name, chip in events["chips"].items():
+        stacks = op_scopes(path, plane_name) if pattern else {}
         spans = clip([(s, s + d) for _n, s, d in chip["ops"]], lo, hi)
         busy = union(spans)
         busy_s.append(sum(e - s for s, e in busy) / 1e9)
@@ -165,6 +288,14 @@ def reduce_trace(path: str, step_module: str) -> Dict[str, Any]:
             if MOSAIC_MARK in name:
                 mosaic_ns += dur
                 mosaic_calls += 1
+                stack = stacks.get(name, "")
+                found = (set(pattern.findall(stack))
+                         if pattern and stack != AMBIGUOUS else set())
+                row = (by_scope.setdefault(found.pop(), [0, 0.0])
+                       if len(found) == 1
+                       else no_family.setdefault(label, [0, 0.0]))
+                row[0] += 1
+                row[1] += dur
         for name, start, dur in chip["modules"]:
             if lo <= start and start + dur <= hi:
                 module_ns[module_short_name(name)].append(dur)
@@ -198,6 +329,10 @@ def reduce_trace(path: str, step_module: str) -> Dict[str, Any]:
                                if step_runs else None),
         "mosaic_s": mosaic_ns / 1e9 / n_chips,
         "mosaic_calls": mosaic_calls,
+        "mosaic_by_scope": {scope: [int(calls), ns / 1e9 / n_chips]
+                            for scope, (calls, ns) in by_scope.items()},
+        "mosaic_no_family": [[label, int(calls), ns / 1e9 / n_chips]
+                             for label, (calls, ns) in no_family.items()],
         "longest_gap_s": longest_gap_ns / 1e9,
         "device_ops": top({f"{k} x{op_count[k]}": v / n_chips
                            for k, v in op_time.items()}),
@@ -209,4 +344,5 @@ if __name__ == "__main__":
     import json
     import sys
 
-    print(json.dumps(reduce_trace(sys.argv[1], sys.argv[2]), indent=1))
+    print(json.dumps(reduce_trace(sys.argv[1], sys.argv[2], sys.argv[3:]),
+                     indent=1))

@@ -1,8 +1,14 @@
-"""The plain reference of the block the cells train: forward, loss and
-gradient in straightforward ``jax.numpy``, float32, every matrix product
-at ``default_matmul_precision("highest")``, dense masked attention, no
-kernel, no flat vector, no parameter server.  It decides the reference
-part of ``correct``.
+"""The plain reference of the GPT-2 block: what a configuration with
+``"reference": "gpt_plain"`` is held to.  Forward, loss and gradient in
+straightforward ``jax.numpy``, float32, every matrix product at
+``default_matmul_precision("highest")``, dense masked attention, no
+kernel, no parameter server.  ``chipbench/spec.py`` finds it by the
+configuration's key and has the contract of such a module
+(``loss_and_grad_flat``, ``LOSS_TOL_NATS``, ``GRAD_REL_TOL``);
+``chipbench/compare.py`` is the comparison every reference is held by,
+and decides the reference part of ``correct``.  The sizes it needs
+(``n_head``, ``n_layer``) it reads from the configuration's file, which
+it is handed as a dict.
 
 The block is GPT-2's, which Cerebras-GPT (arXiv:2304.03208, section 2.1;
 ``model_type`` ``gpt2``) uses unchanged: token plus learned position
@@ -112,44 +118,17 @@ def loss(params: Dict[str, Any], tokens: jnp.ndarray, n_head: int,
     return -jnp.mean(picked)
 
 
-_value_and_grad = jax.jit(jax.value_and_grad(loss), static_argnums=(2, 3))
-
-
-def loss_and_grad(params: Dict[str, Any], tokens: jnp.ndarray, n_head: int,
-                  n_layer: int) -> Tuple[jnp.ndarray, Dict[str, Any]]:
-    """Reference loss and gradient pytree at full float32 precision."""
-    with jax.default_matmul_precision("highest"):
-        return _value_and_grad(params, tokens, n_head, n_layer)
-
-
 def loss_and_grad_flat(w: jnp.ndarray, unravel: Any, tokens: jnp.ndarray,
-                       n_head: int, n_layer: int
+                       config: Dict[str, Any]
                        ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """The same from the program's flat vector to a flat gradient, in one
-    jitted program, so that no pytree of the model's size outlives it.
+    """The contract's entry: from the program's flat vector to the loss
+    and a flat gradient, in one jitted program, so that no pytree of the
+    model's size outlives it; ``config`` is the configuration's file.
     The tokens are an argument, never a constant of the program: a
     constant would make every seed a new program for the compile cache
     (85 s of every set-up at 111m, my chip run, PR 22)."""
+    n_head, n_layer = int(config["n_head"]), int(config["n_layer"])
     fn = jax.jit(jax.value_and_grad(
         lambda flat, tok: loss(unravel(flat), tok, n_head, n_layer)))
     with jax.default_matmul_precision("highest"):
         return fn(w, tokens)
-
-
-@jax.jit
-def _relative_error(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
-    return jnp.sqrt(jnp.sum(jnp.square(a - b)) / jnp.sum(jnp.square(b)))
-
-
-def compare(sys_loss: float, sys_grad: jnp.ndarray, ref_loss: float,
-            ref_grad: jnp.ndarray) -> Dict[str, Any]:
-    """The comparison that decides the reference part of ``correct``:
-    flat gradients, relative error in the 2-norm (one fused reduction,
-    no vector of the model's size beside the two)."""
-    loss_err = abs(float(sys_loss) - float(ref_loss))
-    grad_err = float(_relative_error(sys_grad, ref_grad))
-    return {
-        "loss_sys": float(sys_loss), "loss_ref": float(ref_loss),
-        "loss_abs_err": loss_err, "grad_rel_err": grad_err,
-        "ok": bool(loss_err <= LOSS_TOL_NATS and grad_err <= GRAD_REL_TOL),
-    }

@@ -38,24 +38,21 @@ def compile_cell(name: str, batch_override: int = 0) -> None:
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
-    from chipbench import flops, spec as spec_mod
-    from chipbench.child import set_vocab
-    from mpit_tpu.lm import build
-    from mpit_tpu.models.transformer import TinyDecoder, default_attn
+    from chipbench import flops, run as runner, spec as spec_mod
+    from mpit_tpu.models.transformer import default_attn
     from mpit_tpu.optim.msgd import MSGDConfig, msgd_step
 
     cell = spec_mod.load_cell(name)
-    c, mix = cell.config, cell.traffic
-    set_vocab(c["vocab_size"])
-    batch, seq = batch_override or int(mix["batch"]), int(c["n_positions"])
-    # parameter structure from the program's own builder (reference
-    # attention: shapes do not depend on the attention, lm_layout does
-    # the same), then the same loss over the Mosaic-pinned attention
-    shapes = build(d_model=c["n_embd"], n_heads=c["n_head"],
-                   n_layers=c["n_layer"], seq_len=seq, seed=1, use_flash=False)
-    module = TinyDecoder(
-        vocab=c["vocab_size"], d_model=c["n_embd"], n_heads=c["n_head"],
-        n_layers=c["n_layer"], max_len=seq,
+    mix = cell.traffic
+    batch = batch_override or int(mix["batch"])
+    seq = int(runner.launch_config(cell, 1).lm_seq)
+    # the model from the program's own builder, by the cell's launch
+    # config, with the reference attention (its initialisation runs the
+    # model, here on the CPU; shapes do not depend on the attention,
+    # lm_layout does the same), then the same loss over that module with
+    # the Mosaic-pinned attention in its place
+    shapes = runner.build_model(cell, seed=1, lm_use_flash=0)
+    module = shapes.module.clone(
         attn_fn=default_attn(causal=True, use_flash=True, interpret=False))
 
     def loss(w, tokens):
@@ -106,8 +103,10 @@ def compile_cell(name: str, batch_override: int = 0) -> None:
     print(f"  resident beside the program: {beside / 1e9:.3f} GB; together "
           f"{(total + beside) / 1e9:.3f} GB of "
           f"{flops.load_peaks('TPU v5 lite')['hbm_bytes'] / 1e9:.1f} GB")
-    print(f"  tpu_custom_call in the compiled step: {calls} "
-          f"(2 x {c['n_layer']} layers = {2 * c['n_layer']} for attention)")
+    least = {family: kernel["least_calls"] for family, kernel in
+             cell.arithmetic().kernels(cell.config, batch).items()}
+    print(f"  tpu_custom_call in the compiled step: {calls} (the "
+          f"configuration's kernel families need at least {least})")
 
 
 def main(argv) -> int:

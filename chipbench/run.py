@@ -121,7 +121,8 @@ def launch_config(cell: spec_mod.Cell, seed: int) -> Any:
     launcher switch for each of its sizes, by the size's key), the mix's
     ``launcher`` switches, batch, rate and period, and the run's seed.  A
     configuration whose block needs another switch names it in its own
-    file."""
+    file (the vocabulary is such a size: ``lm_vocab`` from the
+    configuration's own key)."""
     from mpit_tpu.train.launch import LAUNCH_DEFAULTS
 
     config, mix = cell.config, cell.traffic
@@ -136,6 +137,20 @@ def launch_config(cell: spec_mod.Cell, seed: int) -> Any:
         **{**config.get("launcher", {}), **sizes, **mix["launcher"]},
         batch=mix["batch"], lr=mix["lr"], su=mix["su"], seed=seed,
         lm_eval_every=0, lm_steps=0)
+
+
+def build_model(cell: spec_mod.Cell, seed: int, **switches: Any) -> Any:
+    """The cell's model as the program's own trainer builds it from the
+    launch config (``train/launch.py`` ``lm_trainer_cfg``, ``LmTrainer``:
+    what a worker rank does), with launcher ``switches`` laid over it.
+    For the by-hand tools and the self-check, which need the flat vector
+    and the loss without a gang; whatever block the configuration's
+    switches select is the block they get."""
+    from mpit_tpu.lm import LmTrainer
+    from mpit_tpu.train.launch import lm_trainer_cfg
+
+    cfg = launch_config(cell, seed).merged(**switches)
+    return LmTrainer(lm_trainer_cfg(cfg)).model
 
 
 def device_env(cfg: Any) -> Dict[int, Dict[str, str]]:
@@ -172,7 +187,10 @@ def run_gang(cell: spec_mod.Cell, seed: int, seconds: float, trace: bool,
         "token_budget": int(mix["token_budget"]),
         "warmup_rounds": int(mix["warmup_rounds"]),
         "step_module": mix["step_module"],
-        "vocab_size": cell.config.get("vocab_size"),
+        # the configuration as this run has it (the self-check's is
+        # shrunk in memory) and where its reference module is found
+        "config": cell.config,
+        "bench_dir": str(spec_mod.bench_dir(cell.root, cell.bench)),
         "run_dir": str(run_dir), "worker_ranks": workers,
     })
     obs_trace = run_dir / "obs_trace.json"
@@ -235,7 +253,12 @@ def run_cell(cell: spec_mod.Cell, seed: int, seconds: float, trace: bool,
     first = results[summary["worker_ranks"][0]]
     kind = first.get("device_kind", "")
     peaks = flops.load_peaks(kind) if platform == "tpu" else None
-    why_not = measure.correctness(results, summary, platform)
+    arithmetic = cell.arithmetic()
+    families = arithmetic.kernels(cell.config, int(cell.traffic["batch"]))
+    vector_len = int(arithmetic.param_count(cell.config))
+    why_not = measure.correctness(
+        results, summary, platform,
+        sum(int(k["least_calls"]) for k in families.values()), vector_len)
     for reason in why_not:
         say(f"NOT CORRECT: {reason}")
 
@@ -255,9 +278,19 @@ def run_cell(cell: spec_mod.Cell, seed: int, seconds: float, trace: bool,
     say(f"learning: first loss {summary['first_loss']:.4f}, at the budget "
         f"{summary['loss_at_budget']:.4f}, the mix wants "
         f"{summary['min_learning_nats']:.4f} nats between them")
-    say(f"reference check: loss {reference['loss_sys']:.6f} against "
-        f"{reference['loss_ref']:.6f} (|d| {reference['loss_abs_err']:.2e}),"
-        f" gradient relative error {reference['grad_rel_err']:.3e}")
+    say(f"reference check ({cell.config['reference']}): loss "
+        f"{reference['loss_sys']:.6f} against {reference['loss_ref']:.6f} "
+        f"(|d| {reference['loss_abs_err']:.2e}, limit "
+        f"{reference['loss_tol']:.2e}), gradient relative error "
+        f"{reference['grad_rel_err']:.3e} (limit "
+        f"{reference['grad_tol']:.2e})")
+    say(f"exchanged vector: {first['chipbench_worker']['vector_len']} "
+        f"elements in the program, {vector_len} by the configuration's "
+        f"arithmetic ({cell.config['arithmetic']}), "
+        f"{vector_len * flops.F32 / 1e6:.1f} MB; tpu_custom_calls in the "
+        f"lowered step {first.get('mosaic_calls')}, its kernel families "
+        "need " + json.dumps({f: k["least_calls"]
+                              for f, k in families.items()}))
     say("set-up parts of the first worker (s): " + json.dumps(
         setup_parts(gang["t_spawn"], first["chipbench"]["marks"])))
     compiled = [c for c in first["chipbench"]["compiles"]
@@ -268,7 +301,7 @@ def run_cell(cell: spec_mod.Cell, seed: int, seconds: float, trace: bool,
         [round(row[3], 4) for row in first["chipbench_worker"]["step_rows"]]))
     say(f"device memory_stats: {first['chipbench_worker']['memory_stats']}")
     if peaks is not None and not trace:
-        mfu = flops.mfu_pct(cell.config, summary["tokens_per_s"],
+        mfu = flops.mfu_pct(cell, summary["tokens_per_s"],
                             len(summary["worker_ranks"]), peaks)
         say(f"mfu_pct of this untraced run: {mfu:.3f}")
 
@@ -297,6 +330,14 @@ def run_cell(cell: spec_mod.Cell, seed: int, seconds: float, trace: bool,
            "peaks": peaks, "first_worker": first}
     say("programs in the trace, runs and total ms: "
         + json.dumps(reduction.get("modules")))
+    say("Mosaic kernels in the traced window by model scope [calls, s]: "
+        + json.dumps(reduction.get("mosaic_by_scope"))
+        + f"; all of them {reduction.get('mosaic_calls')} calls, "
+        f"{reduction.get('mosaic_s')} s")
+    if reduction.get("mosaic_no_family"):
+        say("Mosaic calls under no scope of the configuration or under two, "
+            "counted for no kernel family [label, calls, s]: "
+            + json.dumps(reduction["mosaic_no_family"]))
     line["metrics"] = per_layer(cell, run)
     device["busy_s"] = reduction["busy_s"]
     device["window_s"] = reduction["window_s"]

@@ -3,22 +3,33 @@
 Run it before any chip call.  It prints no metric and names the CPU in
 its output; nothing it measures is a device number.  It checks:
 
-1. ``BENCHMARK.json``'s names and units against the contract's rules;
-2. ``chipbench/flops.py`` against hand-worked cases;
+1. ``BENCHMARK.json``'s names and units against the contract's rules,
+   and that every configuration's file names a reference and an
+   arithmetic module that load;
+2. each configuration's arithmetic module (``chipbench/arithmetic/``)
+   against its own hand-worked cases, and ``chipbench/flops.py``'s
+   roofline and peaks;
 3. the copied stream against the program's;
 4. the trace reduction against ``chipbench/fixtures/steps4.xplane.pb``
-   and the numbers beside it, and ``tokens_per_s`` on a hand-made run
-   with a stalled round;
-5. every cell at a tiny size (``n_embd`` 64, 2 layers, sequence 128,
-   reference attention, ``device_policy=cpu``, a 6 s window) end to end
-   through the runner's own code, and the last line's keys, names and
-   units; one cell traced, its readers fed the fixture's reduction;
-6. three steps of the one-worker PS mix against plain Adam on the plain
-   reference;
-7. that a further cell, a third configuration, a new mix (four workers
-   on two servers, the gang no committed cell runs) and a new per-layer
-   metric need only new files and entries (throw-away files under a
-   temporary directory).
+   and the numbers beside it (its sixteen Mosaic calls carry no scope
+   and are booked for no family), against the hand-made scoped trace of
+   ``chipbench/fixtures/handmade.py`` (Mosaic calls and seconds under
+   each scope, a call under two counted for neither), and
+   ``tokens_per_s`` on a hand-made run with a stalled round;
+5. every cell at its configuration's own small size (the ``tiny``
+   overrides of its file; reference attention, ``device_policy=cpu``, a
+   6 s window) end to end through the runner's own code, and the last
+   line's keys, names and units; one cell traced, its readers fed the
+   hand-made trace's reduction;
+6. three steps of the one-worker PS mix against plain Adam on the
+   configuration's plain reference, the model built by the program's own
+   builder from the cell's launch config;
+7. that a further cell needs only new files and entries (throw-away
+   files under a temporary directory): a configuration whose file shares
+   no key with the committed ones, with a reference module, an
+   arithmetic module, a scope more and a kernel family of its own, a
+   new mix (four workers on two servers, the gang no committed cell
+   runs) and new per-layer metrics, one of them the family's reader.
 
 ``--quick`` stops after step 4 (no gang is started).
 """
@@ -39,10 +50,8 @@ os.environ["JAX_PLATFORMS"] = "cpu"  # before anything imports jax
 from chipbench import flops, run as runner, spec as spec_mod
 
 FIXTURES = pathlib.Path(__file__).resolve().parent / "fixtures"
-TINY = {"n_embd": 64, "n_head": 4, "n_layer": 2, "n_inner": 256,
-        "n_positions": 128, "vocab_size": 320}  # not the stream's 256
 TINY_BATCH = 2
-TINY_BUDGET = 24 * TINY_BATCH * TINY["n_positions"]  # 24 updates' tokens
+TINY_UPDATES = 24  # the tiny budget, in updates
 TINY_LR = {"adam": 3e-3, "msgd": 0.1}
 WINDOW_S = 6.0  # the su 8 cell needs 192 micro-steps to its tiny budget
 FAILURES: List[str] = []
@@ -71,43 +80,42 @@ def check_names() -> None:
                    if spec_mod.load_reader(loaded.root, bench, m["name"]) is None]
         check(not missing, f"{cell['name']}: a reader for every per-layer "
               f"metric {missing}")
+        check(callable(loaded.reference().loss_and_grad_flat)
+              and callable(loaded.arithmetic().kernels),
+              f"{cell['name']}: its configuration's reference "
+              f"{loaded.config['reference']!r} and arithmetic "
+              f"{loaded.config['arithmetic']!r} load by name")
 
 
 def check_flops() -> None:
-    c111 = spec_mod.load_cell("c111m-local").config
-    c13 = spec_mod.load_cell("c1.3b-ps1w-su8").config
-    # By hand, cerebras-gpt-111m: a layer's matrices 4 x 768^2 + 2 x 768 x
-    # 3072 = 7,077,888 weights, x6 = 42,467,328 FLOPs a token; attention
-    # 12 x 768 x 2049 / 2 = 9,441,792; ten layers 519,091,200; the head
-    # 6 x 768 x 50257 = 231,584,256; 750,675,456 in all.  At 6 x 2048
-    # tokens a micro-step: 9.224 TFLOP.
-    check(flops.train_flops_per_token(c111) == 750_675_456,
-          "flops per token of cerebras-gpt-111m, by hand 750,675,456")
-    check(close(flops.train_flops_per_token(c111) * 12288, 9.224e12, 1e-3),
-          "a micro-step of cerebras-gpt-111m at batch 6 is 9.22 TFLOP")
-    # Parameters: tables 50257 x 768 + 2048 x 768 = 40,170,240; a layer
-    # 3,072 + 2,359,296 + 2,362,368 + 2,360,064 = 7,084,800, ten of them;
-    # final LayerNorm 1,536; head 38,597,376: 149,617,152, a 598.5 MB
-    # vector.
-    check(flops.param_count(c111) == 149_617_152,
-          "parameters of cerebras-gpt-111m, by hand 149,617,152")
-    check(flops.exchange_bytes_per_round(c111) == 2 * 4 * 149_617_152,
-          "bytes per exchange round: the vector out and back")
-    # cerebras-gpt-1.3b-d4: tables 102,926,336 + 4,194,304; a layer 8,192
-    # + 16,777,216 + 16,785,408 + 16,779,264 = 50,350,080, four of them;
-    # final LayerNorm 4,096; head 102,926,336.
-    check(flops.param_count(c13) == 411_451_392,
-          "parameters of cerebras-gpt-1.3b-d4, by hand 411,451,392")
-    # Flash, one layer of 111m at batch 8: pairs 8 x 12 x 2048 x 2049 / 2
-    # = 201,424,896; forward 4 x 64 = 256 FLOPs a pair: 51.56 GFLOP;
-    # backward 640 a pair: 128.9 GFLOP.  q, k, v, o are 8 x 12 x 2048 x 64
-    # x 4 B = 50.33 MB each.
-    cost = flops.flash_call_cost(c111, 8)
-    check(cost["fwd"][0] == 256 * 201_424_896 and
-          cost["bwd"][0] == 640 * 201_424_896,
-          "flash FLOPs per call of cerebras-gpt-111m at batch 8")
-    check(close(cost["fwd"][1], 4 * 50_331_648 + 786_432, 1e-9),
-          "flash forward bytes: q, k, v in, o and the row sums out")
+    """Each arithmetic module a configuration names, on its own
+    hand-worked cases; then the committed configurations through it and
+    ``chipbench/flops.py``."""
+    bench = spec_mod.load_bench()
+    seen, worked = set(), set()
+    for entry in bench["workloads"]:
+        cell = spec_mod.load_cell(entry["name"])
+        if cell.config_name in seen:
+            continue
+        seen.add(cell.config_name)
+        arithmetic = cell.arithmetic()
+        cases = ([] if cell.config["arithmetic"] in worked
+                 else arithmetic.hand_worked())
+        worked.add(cell.config["arithmetic"])
+        for what, got, want in cases:
+            check(got == want, f"arithmetic {cell.config['arithmetic']}: "
+                  f"{what}, by hand {want} (got {got})")
+        n = arithmetic.param_count(cell.config)
+        check(flops.exchange_bytes_per_round(cell) == 2 * 4 * n,
+              f"{cell.config_name}: {n} parameters, a {4 * n / 1e6:.1f} MB "
+              "vector out and back in an exchange round")
+        families = arithmetic.kernels(cell.config, int(cell.traffic["batch"]))
+        check(all(k["scope"] in cell.config["scopes"] and k["flops"] > 0
+                  and k["bytes"] > 0 and k["least_calls"] > 0
+                  for k in families.values()),
+              f"{cell.config_name}: every kernel family {sorted(families)} "
+              "has a scope of the configuration, FLOPs, bytes and a least "
+              "count of calls")
     share, bound = flops.roofline(197e12, 1.0, 2.0, flops.load_peaks("TPU v5 lite"))
     check(close(share, 50.0, 1e-9) and bound == "compute",
           "roofline: 197 TFLOP in 2 s on a v5e is 50%, bound by compute")
@@ -183,18 +191,54 @@ def check_reduction() -> Dict[str, Any]:
           f"reduction: most idle time under {want['longest_idle_owner']}")
     check(red["device_ops"][0][0].startswith(want["top_op_prefix"]),
           f"reduction: top device op {want['top_op_prefix']}")
+    check(red["mosaic_by_scope"] == {} and
+          sum(row[1] for row in red["mosaic_no_family"]) == red["mosaic_calls"],
+          "reduction of the fixture: its Mosaic calls carry no scope and "
+          "are booked for no kernel family")
+    return scoped_reduction(check_it=True)
+
+
+def scoped_reduction(check_it: bool = False) -> Dict[str, Any]:
+    """The reduction of the hand-made scoped trace
+    (``fixtures/handmade.py``), which is also what the traced rehearsals
+    hand the readers in place of a device trace."""
+    from chipbench.fixtures import handmade
+    from chipbench.reduce import reduce_trace
+
+    with tempfile.TemporaryDirectory() as tmp:
+        path = handmade.write_scoped(pathlib.Path(tmp) / "scoped.xplane.pb")
+        red = reduce_trace(str(path), "jit_loss", handmade.SCOPES)
+    for key, want in handmade.SCOPED_EXPECTED.items() if check_it else ():
+        got = red.get(key)
+        check(_same(got, want), f"reduction of the hand-made scoped trace: "
+              f"{key} = {want} (got {got})")
     return red
+
+
+def _same(got: Any, want: Any) -> bool:
+    if isinstance(want, float):
+        return isinstance(got, (int, float)) and close(got, want, 1e-9)
+    if isinstance(want, dict):
+        return isinstance(got, dict) and set(got) == set(want) and all(
+            _same(got[k], v) for k, v in want.items())
+    if isinstance(want, list):
+        return isinstance(got, list) and len(got) == len(want) and all(
+            _same(a, b) for a, b in zip(got, want))
+    return got == want
 
 
 # -- 5..7: gangs on the CPU --------------------------------------------------
 
 
 def tiny(cell: spec_mod.Cell) -> spec_mod.Cell:
-    """The cell at rehearsal size: the same files, smaller numbers."""
-    cell.config.update(TINY)
+    """The cell at rehearsal size: the same files, the configuration's
+    own small sizes (``tiny`` in its file) laid over its keys."""
+    cell.config.update(cell.config["tiny"])
     cell.traffic["launcher"].update(device_policy="cpu", lm_use_flash=0)
+    seq = int(runner.launch_config(cell, 0).lm_seq)
     cell.traffic.update(batch=TINY_BATCH,
-                        token_budget=TINY_BUDGET * int(cell.traffic["su"]),
+                        token_budget=(TINY_UPDATES * TINY_BATCH * seq
+                                      * int(cell.traffic["su"])),
                         lr=TINY_LR[cell.traffic["launcher"]["opt"]],
                         min_learning_nats=0.2)
     return cell
@@ -229,39 +273,33 @@ def rehearse(cell: spec_mod.Cell, traced: bool = False,
 
 
 def check_trajectory(out: Dict[str, Any], cell: spec_mod.Cell) -> None:
-    """Three steps of plain Adam on the plain reference against the
-    one-worker PS run's first three losses: same seeded weights, same
-    batches, the server's rule written out plainly."""
-    import jax
+    """Three steps of plain Adam on the configuration's plain reference
+    against the one-worker PS run's first three losses: same seeded
+    weights (the program's own builder, from the cell's launch config),
+    same batches, the server's rule written out plainly on the flat
+    vector.  ``cell`` is the rehearsed one, at its small size."""
     import jax.numpy as jnp
 
-    from chipbench.child import set_vocab
-    from chipbench.reference import gpt_plain
     from chipbench.traffic.packed_bytes import packed_batch
-    from mpit_tpu.lm import build
 
-    set_vocab(cell.config["vocab_size"])
     worker_rank = out["summary"]["worker_ranks"][0]
     rows = out["gang"]["results"][worker_rank]["chipbench_worker"]["step_rows"]
-    c, lr = cell.config, cell.traffic["lr"]
-    model = build(d_model=c["n_embd"], n_heads=c["n_head"],
-                  n_layers=c["n_layer"], seq_len=c["n_positions"], seed=3,
-                  use_flash=False)
-    params = model.flat.unravel(model.flat.w0)
-    zeros = jax.tree_util.tree_map(jnp.zeros_like, params)
-    m, v = zeros, zeros
+    lr, reference = cell.traffic["lr"], cell.reference()
+    flat = runner.build_model(cell, seed=3).flat
+    seq = int(runner.launch_config(cell, 3).lm_seq)
+    w = flat.w0
+    m = v = jnp.zeros_like(w)
     losses = []
     for t in range(1, 4):
-        tokens = jnp.asarray(packed_batch(
-            3 + worker_rank, t - 1, TINY_BATCH, c["n_positions"]))
-        loss, g = gpt_plain.loss_and_grad(params, tokens, c["n_head"],
-                                          c["n_layer"])
+        tokens = jnp.asarray(packed_batch(3 + worker_rank, t - 1,
+                                          TINY_BATCH, seq))
+        loss, g = reference.loss_and_grad_flat(w, flat.unravel, tokens,
+                                               cell.config)
         losses.append(float(loss))
-        m = jax.tree_util.tree_map(lambda a, b: 0.9 * a + 0.1 * b, m, g)
-        v = jax.tree_util.tree_map(lambda a, b: 0.999 * a + 0.001 * b * b, v, g)
+        m = 0.9 * m + 0.1 * g
+        v = 0.999 * v + 0.001 * g * g
         lr_t = lr * math.sqrt(1 - 0.999 ** t) / (1 - 0.9 ** t)
-        params = jax.tree_util.tree_map(
-            lambda p, a, b: p - lr_t * a / (jnp.sqrt(b) + 1e-8), params, m, v)
+        w = w - lr_t * m / (jnp.sqrt(v) + 1e-8)
     got = [rows[k][3] for k in range(3)]
     check(all(abs(a - b) < 2e-4 for a, b in zip(got, losses)),
           f"three steps of the PS run {got} follow plain Adam on the plain "
@@ -269,49 +307,68 @@ def check_trajectory(out: Dict[str, Any], cell: spec_mod.Cell) -> None:
 
 
 def check_extension() -> None:
-    """A further cell with a third configuration, a new mix (the gang of
-    four workers on two servers, which no committed cell runs) and a new
-    per-layer metric, from new files and entries alone."""
+    """A further cell from new files and entries alone: the files under
+    ``fixtures/extension`` laid into a copy of the benchmark's directory
+    (a configuration none of whose size keys the committed ones have,
+    its reference and arithmetic modules, two readers), a new mix
+    written here (four workers on two servers, which no committed cell
+    runs), and the entries that name them.  The kernel family the
+    arithmetic adds, ``commit`` under the scope ``update``, is read by
+    its own reader from the hand-made trace's reduction: 40 us in two
+    micro-steps, 0.02 ms a step."""
     root = spec_mod.ROOT
     with tempfile.TemporaryDirectory(dir=root / runner.RUNS_DIR) as tmp:
         tmp_root = pathlib.Path(tmp)
         shutil.copytree(root / "chipbench", tmp_root / "chipbench",
                         ignore=shutil.ignore_patterns("__pycache__", "fixtures"))
+        shutil.copytree(FIXTURES / "extension", tmp_root / "chipbench",
+                        dirs_exist_ok=True,
+                        ignore=shutil.ignore_patterns("__pycache__"))
         bench = spec_mod.load_bench()
         bench["configs"].append({
-            "name": "third", "source": "https://example.org/third",
-            "file": "chipbench/configs/third.json", "reduced": [],
+            "name": "other", "source": "https://example.org/other",
+            "file": "chipbench/configs/other.json", "reduced": [],
             "why": "throw-away"})
         bench["workloads"].append({
-            "name": "extra", "config": "third", "traffic": "new-mix",
+            "name": "extra", "config": "other", "traffic": "new-mix",
             "chips": 1, "why": "throw-away"})
-        bench["per_layer"].append({
+        bench["per_layer"] += [{
             "name": "rounds_counted", "unit": "rounds", "better": "higher",
             "source": "program_counter", "layer": "L3 shell + client",
-            "moves": "tokens_per_s", "workloads": ["extra"]})
+            "moves": "tokens_per_s", "workloads": ["extra"]}, {
+            "name": "commit_ms_per_step", "unit": "ms", "better": "lower",
+            "source": "device_trace", "layer": "L1 kernels",
+            "moves": "tokens_per_s", "workloads": ["extra"]}]
         with open(tmp_root / "BENCHMARK.json", "w") as fh:
             json.dump(bench, fh)
-        base = spec_mod.load_cell("c111m-ps1w-su1")
-        with open(tmp_root / "chipbench/configs/third.json", "w") as fh:
-            json.dump({**base.config, **TINY, "n_layer": 1}, fh)
-        mix = base.traffic  # four workers on two servers: ranks 0 and 3 serve
-        mix["launcher"].update(np=6, master_freq=3)
+        mix = spec_mod.load_cell("c111m-ps1w-su1").traffic
+        mix["launcher"].update(np=6, master_freq=3)  # ranks 0 and 3 serve
         mix["warmup_rounds"] = 10
         with open(tmp_root / "chipbench/traffic/new-mix.json", "w") as fh:
             json.dump(mix, fh)
-        with open(tmp_root / "chipbench/layers/rounds_counted.py", "w") as fh:
-            fh.write("def read(run):\n    return float(sum(run['summary']"
-                     "['rounds_in_window'].values()))\n")
         check(not spec_mod.check_names(bench), "the extended BENCHMARK.json's names")
         cell = spec_mod.load_cell("extra", root=tmp_root)
+        committed = {key for w in spec_mod.load_bench()["workloads"]
+                     for key in spec_mod.load_cell(w["name"]).config["tiny"]}
+        check(not committed & set(cell.config),
+              f"the new configuration has none of the committed ones' size "
+              f"keys {sorted(committed)}")
+        for what, got, want in cell.arithmetic().hand_worked():
+            check(got == want, f"arithmetic other: {what}, by hand {want} "
+                  f"(got {got})")
         out = runner.run_cell(tiny(cell), seed=4, seconds=WINDOW_S, trace=True,
                               platform="cpu",
-                              stand_in_reduction=fixture_reduction())
+                              stand_in_reduction=scoped_reduction())
         check_line(cell, out["line"], traced=True)
-        check("rounds_counted" in out["line"]["metrics"],
-              "a further cell, a third configuration, a new mix of four "
-              "workers and a new per-layer metric ran from new files and "
-              "entries alone")
+        metrics = out["line"]["metrics"]
+        check("rounds_counted" in metrics and
+              close(metrics.get("commit_ms_per_step", {}).get("value", 0.0),
+                    0.02, 1e-9),
+              "a further cell ran from new files and entries alone: a "
+              "configuration with its own keys, reference, arithmetic, "
+              "scopes, kernel family and small size, a new mix of four "
+              "workers, and two new per-layer metrics, one of them the "
+              f"family's own reader {sorted(metrics)}")
         check(len(out["summary"]["worker_ranks"]) == 4,
               "the new mix ran four workers on two servers")
 
@@ -332,8 +389,11 @@ def main(argv: List[str]) -> int:
             out = rehearse(cell)
             if (cell.traffic["launcher"]["np"], cell.traffic["su"]) == (3, 1):
                 check_trajectory(out, cell)
-                rehearse(spec_mod.load_cell(entry["name"]), traced=True,
-                         reduction=reduction)
+                traced = rehearse(spec_mod.load_cell(entry["name"]),
+                                  traced=True, reduction=reduction)
+                check("flash_ms_per_step" in traced["line"]["metrics"],
+                      f"{cell.name}, traced: the attn family's reader found "
+                      "the hand-made trace's calls under its scope")
         check_extension()
     if FAILURES:
         print(f"selfcheck[cpu]: {len(FAILURES)} FAILED:", flush=True)

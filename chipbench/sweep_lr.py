@@ -26,8 +26,8 @@ def main(argv) -> int:
     for lr in (float(x) for x in argv[3:]):
         cell = spec_mod.load_cell(name)
         cell.traffic["lr"] = lr
-        cell.traffic["token_budget"] = 4 * cell.traffic["batch"] * \
-            cell.config["n_positions"]  # any budget every run reaches
+        cell.traffic["token_budget"] = 4 * cell.traffic["batch"] * int(
+            runner.launch_config(cell, seed).lm_seq)  # every run reaches it
         try:
             out = runner.run_cell(cell, seed, seconds, trace=False)
         except measure.RunFailed as exc:
