@@ -19,7 +19,10 @@ vector should fall on *parameter boundaries*, not arbitrary offsets —
 a shard that splits a weight matrix splits its quantization blocks and
 its optimizer-state locality with it.  :func:`flat_segments` renders the
 pytree as an ordered segment table, :func:`aligned_cut` cuts the vector
-at segment boundaries as close to balanced as the boundaries allow, and
+at segment boundaries as close to balanced as the boundaries allow (a
+*stacked* leaf, one matrix per expert on its leading axis, may also be
+cut between two experts: an expert's weights and their optimizer slots
+still move as a unit), and
 :func:`plan_shard_map` lifts that cut into a versioned
 :class:`~mpit_tpu.shardctl.shardmap.ShardMap` — the layout source for
 shardctl gangs (``ParamClient(shard_map=...)``).
@@ -212,27 +215,45 @@ def shard_tree(tree: Any, shardings: Any) -> Any:
 
 
 class Segment(NamedTuple):
-    """One leaf's extent inside the raveled flat vector."""
+    """One leaf's extent inside the raveled flat vector.  ``unit`` > 0
+    marks a stacked leaf that may be cut at every multiple of ``unit``
+    elements from its offset (one expert's matrix); 0 is a leaf that is
+    never split."""
 
     name: str
     offset: int
     size: int
+    unit: int = 0
 
     @property
     def end(self) -> int:
         return self.offset + self.size
 
+    def boundaries(self) -> List[int]:
+        """Where a cut may fall from this segment's begin up to, not
+        including, its end."""
+        return list(range(self.offset, self.end, self.unit or self.size))
 
-def flat_segments(tree: Any, sep: str = "/") -> List[Segment]:
+
+def flat_segments(tree: Any, sep: str = "/",
+                  stacked: Optional[str] = None) -> List[Segment]:
     """The ordered segment table of ``ravel_pytree(tree)``: one entry
-    per leaf, contiguous, in tree-leaves order (the order ravel uses)."""
+    per leaf, contiguous, in tree-leaves order (the order ravel uses).
+    ``stacked`` is a pattern (``re.search`` on the leaf's path name) of
+    the leaves that hold one matrix per expert on their leading axis:
+    such a segment gets ``unit`` = the elements of one expert's matrix
+    and may be cut between experts."""
     segments: List[Segment] = []
     offset = 0
     flat, _ = jax.tree_util.tree_flatten_with_path(tree)
     for path, leaf in flat:
-        size = int(np.prod(np.shape(leaf))) if np.shape(leaf) else 1
+        shape = np.shape(leaf)
+        size = int(np.prod(shape)) if shape else 1
         name = sep.join(_key_str(k) for k in path)
-        segments.append(Segment(name, offset, size))
+        unit = 0
+        if stacked and len(shape) > 1 and re.search(stacked, name):
+            unit = size // shape[0]
+        segments.append(Segment(name, offset, size, unit))
         offset += size
     return segments
 
@@ -240,8 +261,9 @@ def flat_segments(tree: Any, sep: str = "/") -> List[Segment]:
 def aligned_cut(plong: int, segments: Sequence[Segment], n: int,
                 weights: Optional[Sequence[float]] = None):
     """Cut ``[0, plong)`` into ``n`` contiguous shards whose interior
-    boundaries fall on segment boundaries, each as close to the equal
-    cut ``i*plong/n`` as the boundaries allow.
+    boundaries fall on segment boundaries (or, inside a stacked segment,
+    on a multiple of its ``unit``: between two experts), each as close
+    to the equal cut ``i*plong/n`` as the boundaries allow.
 
     ``weights`` (optional, one positive number per shard) replaces the
     equal targets with cumulative-fraction targets
@@ -253,9 +275,10 @@ def aligned_cut(plong: int, segments: Sequence[Segment], n: int,
     when server budgets differ.
 
     Invariants (property-tested): shards tile ``[0, plong)``, every
-    shard is nonempty, every interior cut is some segment's offset, and
-    the result is a pure function of its arguments.  Raises when fewer
-    segments than shards exist — an element-level cut would split a
+    shard is nonempty, every interior cut is some segment's offset (or
+    an expert boundary of a stacked one), and the result is a pure
+    function of its arguments.  Raises when fewer boundaries than cuts
+    exist — an element-level cut would split a
     parameter, which is exactly what alignment is for (fall back to
     :func:`mpit_tpu.ps.sharding.shard_layout` deliberately instead).
     """
@@ -288,12 +311,14 @@ def aligned_cut(plong: int, segments: Sequence[Segment], n: int,
         pos = s.end
     if pos != plong:
         raise ValueError(f"segments cover {pos} of {plong} elements")
-    if len(segs) < n:
+    # interior candidates: every segment's begin, and inside a stacked
+    # segment every expert's begin
+    boundaries = [b for s in segs for b in s.boundaries()][1:]
+    if len(boundaries) < n - 1:
         raise ValueError(
             f"cannot align {n} shards on {len(segs)} segments — an "
             "aligned cut never splits a parameter (use shard_layout for "
             "element-level cuts)")
-    boundaries = [s.offset for s in segs[1:]]  # interior candidates
     cuts: List[int] = []
     lo = 0
     for i in range(1, n):
@@ -311,7 +336,8 @@ def aligned_cut(plong: int, segments: Sequence[Segment], n: int,
 
 def plan_shard_map(tree: Any, server_ranks: Sequence[int], *,
                    sep: str = "/", shards_per_server: int = 1,
-                   weights: Optional[Sequence[float]] = None):
+                   weights: Optional[Sequence[float]] = None,
+                   stacked: Optional[str] = None):
     """A version-0 :class:`~mpit_tpu.shardctl.shardmap.ShardMap` whose
     cut is segment-aligned — the partition engine acting as shardctl's
     layout source.  ``shards_per_server`` over-partitions (the §9.1
@@ -325,7 +351,7 @@ def plan_shard_map(tree: Any, server_ranks: Sequence[int], *,
     if not ranks:
         raise ValueError("need at least one server rank")
     k = max(int(shards_per_server), 1)
-    segments = flat_segments(tree, sep=sep)
+    segments = flat_segments(tree, sep=sep, stacked=stacked)
     plong = segments[-1].end
     cut_weights = None
     if weights is not None:

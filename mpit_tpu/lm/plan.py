@@ -6,8 +6,9 @@ artifacts the PS stack consumes:
 
 - :meth:`LmPlan.layout` — a **static weighted aligned cut**: one
   contiguous :class:`~mpit_tpu.ps.sharding.Shard` per server, every
-  interior boundary on a parameter boundary, targets skewed by
-  per-server weights.  Passed to ``ParamClient(layout=...)`` /
+  interior boundary on a parameter boundary (or, inside a stacked
+  expert leaf, between two experts: :data:`STACKED_LEAVES`), targets
+  skewed by per-server weights.  Passed to ``ParamClient(layout=...)`` /
   ``ReaderClient(layout=...)`` it replaces the equal split while
   keeping the whole static feature lattice (chunked streaming, int8
   EF, staleness, agg tree) negotiable — the flagship composition.
@@ -53,7 +54,23 @@ PARTITION_RULES = [
     # optimizer slots resolve as scalars before any rule is consulted)
     (r"Dense_\d+/bias", P()),
     (r"LayerNorm_\d+/(scale|bias)", P()),
+    # the OLMoE block (models/transformer.py OlmoeDecoder): token table
+    # and head by rows / output features, the stacked experts by expert,
+    # attention and router matrices by output features, norms whole
+    # (an optimizer slot's path ends in the slot's name: /m, /v)
+    (r"(^|/)embed(/|$)", P("mdl", None)),
+    (r"(^|/)head(/|$)", P(None, "mdl")),
+    (r"experts_(gate|up|down)(/|$)", P("mdl", None, None)),
+    (r"OlmoeBlock_\d+/(w[qkvo]|router)(/|$)", P(None, "mdl")),
+    (r"(attn|mlp|q|k|final)_norm(/|$)", P()),
 ]
+
+#: Leaves that hold one matrix per expert on their leading axis: the
+#: cut may fall between two experts inside them (an expert's weights
+#: and its optimizer slots still move as a unit).  One OLMoE layer has
+#: three of 134M elements beside two of 103M (table and head): on leaf
+#: boundaries alone a two-server cut is lopsided 1.2 to 1.6 times.
+STACKED_LEAVES = r"experts_(gate|up|down)$"
 
 
 def audit_rules(tree: Any, rules=None, *, sep: str = "/") -> Dict[str, int]:
@@ -121,7 +138,7 @@ def plan(params: Any, n_servers: int, *, rule: str = "add",
     *heterogeneous server budgets*)."""
     if n_servers < 1:
         raise ValueError("need at least one server")
-    segments = flat_segments(params, sep=sep)
+    segments = flat_segments(params, sep=sep, stacked=STACKED_LEAVES)
     plong = segments[-1].end
     weights = ([float(w) for w in server_weights]
                if server_weights is not None else None)
@@ -131,5 +148,6 @@ def plan(params: Any, n_servers: int, *, rule: str = "add",
 
 
 __all__ = [
-    "PARTITION_RULES", "LmPlan", "audit_rules", "plan", "plan_shard_map",
+    "PARTITION_RULES", "STACKED_LEAVES", "LmPlan", "audit_rules", "plan",
+    "plan_shard_map",
 ]

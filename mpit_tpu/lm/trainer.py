@@ -32,7 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from mpit_tpu.lm.data import PackedStream
-from mpit_tpu.lm.model import build, vocab_kw
+from mpit_tpu.lm.model import build, build_kw
 from mpit_tpu.obs import PhaseTimers, get_registry, profiler_trace
 from mpit_tpu.optim import EAMSGD, MSGD, Downpour, RuleShell
 from mpit_tpu.optim.msgd import MSGDConfig
@@ -40,7 +40,8 @@ from mpit_tpu.utils.config import Config
 from mpit_tpu.utils.logging import get_logger
 
 LM_DEFAULTS = Config(
-    # model
+    # model: the block (lm/model.py ARCHS) and its sizes
+    arch="gpt2",
     d_model=64,
     n_heads=4,
     n_layers=2,
@@ -49,6 +50,13 @@ LM_DEFAULTS = Config(
     # keyword default, the byte stream's 256, whose ids index the first
     # rows of a larger table
     vocab=0,
+    # olmoe's own sizes: experts, experts a token, one expert's width,
+    # the rotary base and the RMSNorm epsilon
+    n_experts=8,
+    experts_per_tok=2,
+    expert_width=32,
+    rope_theta=10000.0,
+    norm_eps=1e-5,
     # -1 auto (flash on TPU, jnp reference elsewhere) | 0 reference |
     # 1 the Mosaic-compiled kernel or an error (lm/model.py _resolve_attn)
     use_flash=-1,
@@ -92,11 +100,7 @@ class LmTrainer:
         self.tm = PhaseTimers()
 
         use_flash = None if cfg.use_flash < 0 else bool(cfg.use_flash)
-        self.model = build(
-            d_model=cfg.d_model, n_heads=cfg.n_heads, n_layers=cfg.n_layers,
-            seq_len=cfg.seq_len, seed=cfg.seed, use_flash=use_flash,
-            **vocab_kw(cfg.vocab),
-        )
+        self.model = build(use_flash=use_flash, **build_kw(cfg))
         dtype = jnp.dtype(cfg.dtype)
         self.w = self.model.flat.w0.astype(dtype)
         self._vgf = self.model.value_and_grad
@@ -147,7 +151,10 @@ class LmTrainer:
                           mva=cfg.mva, su=cfg.su)
         # Server-stateful rules: the launcher configures the matching
         # server rule; the client ships raw gradients.
-        return RuleShell(self._vgf, self.pc, su=cfg.su, mode="global")
+        # a block with telemetry of its own returns it beside the loss
+        stats_step = self.model.value_grad_stats
+        return RuleShell(stats_step or self._vgf, self.pc, su=cfg.su,
+                         mode="global", has_aux=stats_step is not None)
 
     # -- evaluation -----------------------------------------------------------
 
@@ -170,6 +177,10 @@ class LmTrainer:
         if hasattr(opt, "start"):
             with self.tm.phase("start"):
                 self.w = opt.start(self.w)
+        # the seeded vector now lives in self.w and the optimizer's
+        # mirrors; the model's own reference would keep a whole vector
+        # resident on the device for the rest of the run
+        self.model.flat.w0 = None
         mosaic_calls = None
         if cfg.use_flash > 0:
             # Evidence that the step's attention is the compiled kernel:
@@ -237,6 +248,10 @@ class LmTrainer:
             "train_seconds": train_s,
             "first_step_seconds": first_step_s,
             "mosaic_calls": mosaic_calls,
+            # the block's own statistics of the last sync round, one
+            # entry a layer (olmoe: moe_load_max_over_mean); empty with
+            # obs off or a block that has none
+            **getattr(opt, "stats_last", {}),
             "elapsed": self.tm.elapsed(),
             "timers": dict(self.tm.total),
             "steps": cfg.steps,

@@ -14,10 +14,16 @@ long-context showcase built on the framework's own kernels:
 - MXU-friendly sizing: model/head dims in multiples of 8, all matmuls
   batched over (B, L);
 - pre-LN blocks, learned positional embeddings, causal by default.
+
+Two blocks: :class:`TinyDecoder` (GPT-2's: LayerNorm, learned
+positions, GELU MLP) and :class:`OlmoeDecoder` (OLMoE's: RMSNorm,
+rotary positions, query/key norm, top-k of E gated experts), chosen by
+``lm/model.py`` ``build(arch=...)``.
 """
 
 from __future__ import annotations
 
+from functools import partial
 from typing import Callable, Optional
 
 import flax.linen as nn
@@ -25,24 +31,30 @@ import jax
 import jax.numpy as jnp
 
 from mpit_tpu.ops.flash_attention import attention_reference, flash_attention
+from mpit_tpu.parallel import moe
 
 AttnFn = Callable[[jnp.ndarray, jnp.ndarray, jnp.ndarray], jnp.ndarray]
 
 
 def default_attn(causal: bool = True, use_flash: bool = True,
-                 interpret: Optional[bool] = None) -> AttnFn:
+                 interpret: Optional[bool] = None,
+                 precision: Optional[str] = None) -> AttnFn:
     """Single-device attention over (B, L, H, D): flash kernel or the jnp
     reference (the latter differentiates without a recompute pass).
     ``interpret`` reaches ``pallas_call``: None interprets everywhere
-    but on a TPU (ops/tiles.py), False pins the Mosaic-compiled kernel."""
+    but on a TPU (ops/tiles.py), False pins the Mosaic-compiled kernel.
+    ``precision`` is the MXU input precision of the two attention
+    products, forward and backward (``"highest"``: float32 inputs);
+    None is the backend's default, one bf16 pass on a TPU."""
 
     def fn(q, k, v):
         qh, kh, vh = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
         if use_flash:
             out = flash_attention(qh, kh, vh, causal=causal,
-                                  interpret=interpret)
+                                  interpret=interpret, precision=precision)
         else:
-            out = attention_reference(qh, kh, vh, causal=causal)
+            with jax.default_matmul_precision(precision or "default"):
+                out = attention_reference(qh, kh, vh, causal=causal)
         return out.transpose(0, 2, 1, 3)
 
     return fn
@@ -112,4 +124,148 @@ class TinyDecoder(nn.Module):
         with jax.named_scope("head_loss"):  # lm/model.py's NLL joins it
             x = nn.LayerNorm()(x)
             logits = nn.Dense(self.vocab, use_bias=False)(x)
+            return nn.log_softmax(logits)
+
+
+# ---------------------------------------------------------------------------
+# The sparse-expert block (OLMoE: Muennighoff et al., arXiv:2409.02060;
+# ``model_type`` ``olmoe``).  RMSNorm, rotary positions, RMSNorm on the
+# projected queries and keys, bias-free projections, a router and top-k
+# of E SiLU-gated experts by sorted dropless dispatch
+# (``parallel/moe.py``), an untied head.  Parameters are float32 and
+# named by hand, the experts stacked on a leading expert axis (which
+# ``lm/plan.py`` may cut between experts).  The plain float32 reference
+# it is held to is ``lm/olmoe_reference.py``; that file shares no code
+# with this one.
+# ---------------------------------------------------------------------------
+
+_INIT = nn.initializers.normal(stddev=0.02)
+# The router's product runs in float32 at full precision: it is a
+# thousandth of the step's FLOPs, and one bf16 pass here flips top-k
+# membership wherever two experts' probabilities are close.
+ROUTER_PRECISION = jax.lax.Precision.HIGHEST
+# The attention path runs above the one-pass default: the four
+# projections at three bf16 passes, the flash kernel's two products on
+# float32 inputs (``default_attn``'s ``precision``, which ``lm/model.py``
+# hands the block's attention).  With the query/key norm a head's q and k
+# have norm sqrt(128), the scores are O(10), and one bf16 pass of q and
+# k is percents off in the attention probabilities: against the float32
+# reference 1.4-1.8% of the gradient's norm at published widths, where
+# everything else in one pass adds 0.25% (v5e, PERF.md section 6, PR
+# 26: projections alone 0.8-1.3%, kernel alone 1.4-1.6%, both 0.23-0.27%
+# for 23 ms of a 139 ms step).
+ATTN_PRECISION = jax.lax.Precision.HIGH
+ATTN_KERNEL_PRECISION = "highest"
+
+
+def rms_norm(x: jnp.ndarray, weight: jnp.ndarray, eps: float) -> jnp.ndarray:
+    """``x / rms(x) * weight`` over the last axis, in float32."""
+    x = x.astype(jnp.float32)
+    var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
+    return x * jax.lax.rsqrt(var + eps) * weight
+
+
+def rope(x: jnp.ndarray, theta: float) -> jnp.ndarray:
+    """Rotary position embedding over the full head width of ``x (B, L,
+    H, D)``, rotate-half convention: with the head split into halves
+    ``(x1, x2)`` and angles ``pos * theta^(-2i/D)``, ``(x1 cos - x2 sin,
+    x2 cos + x1 sin)``.  Angles in float32."""
+    _, l, _, d = x.shape
+    inv_freq = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    angles = jnp.arange(l, dtype=jnp.float32)[:, None] * inv_freq[None, :]
+    cos = jnp.cos(angles)[None, :, None, :]
+    sin = jnp.sin(angles)[None, :, None, :]
+    x1, x2 = jnp.split(x, 2, axis=-1)
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+
+
+class OlmoeBlock(nn.Module):
+    d_model: int
+    n_heads: int
+    n_experts: int
+    experts_per_tok: int
+    expert_width: int
+    rope_theta: float = 10000.0
+    norm_eps: float = 1e-5
+    attn_fn: Optional[AttnFn] = None
+
+    @nn.compact
+    def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
+        b, l, d = x.shape
+        head = d // self.n_heads
+        e, f = self.n_experts, self.expert_width
+        attn = self.attn_fn if self.attn_fn is not None else default_attn()
+        ones = nn.initializers.ones
+
+        with jax.named_scope("attn"):
+            h = rms_norm(x, self.param("attn_norm", ones, (d,)), self.norm_eps)
+            project = partial(jnp.matmul, precision=ATTN_PRECISION)
+            q = project(h, self.param("wq", _INIT, (d, d)))
+            k = project(h, self.param("wk", _INIT, (d, d)))
+            v = project(h, self.param("wv", _INIT, (d, d)))
+            # OLMoE normalises the projected queries and keys over their
+            # whole width, before the split into heads
+            q = rms_norm(q, self.param("q_norm", ones, (d,)), self.norm_eps)
+            k = rms_norm(k, self.param("k_norm", ones, (d,)), self.norm_eps)
+            q = rope(q.reshape(b, l, self.n_heads, head), self.rope_theta)
+            k = rope(k.reshape(b, l, self.n_heads, head), self.rope_theta)
+            v = v.reshape(b, l, self.n_heads, head)
+            x = x + project(attn(q, k, v).reshape(b, l, d),
+                            self.param("wo", _INIT, (d, d)))
+
+        with jax.named_scope("router"):
+            h = rms_norm(x, self.param("mlp_norm", ones, (d,)),
+                         self.norm_eps).reshape(b * l, d)
+            logits = jnp.matmul(h, self.param("router", _INIT, (d, e)),
+                                precision=ROUTER_PRECISION)
+            probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+            weights, experts = moe.route_top_k(probs, self.experts_per_tok)
+            # routing imbalance, for telemetry: read only where the
+            # caller makes ``intermediates`` mutable (lm/model.py stats)
+            self.sow("intermediates", "moe_load",
+                     moe.load_max_over_mean(experts, e))
+
+        wg = self.param("experts_gate", _INIT, (e, d, f))
+        wu = self.param("experts_up", _INIT, (e, d, f))
+        wd = self.param("experts_down", _INIT, (e, f, d))
+        y = moe.dispatch_top_k(
+            h, weights, experts, e,
+            lambda rows, sizes: moe.swiglu_experts(rows, sizes, wg, wu, wd))
+        return x + y.reshape(b, l, d)
+
+
+class OlmoeDecoder(nn.Module):
+    """Causal LM of :class:`OlmoeBlock` layers: a token table (no
+    position table: the positions are rotary), the blocks, a final
+    RMSNorm and an untied head; returns log-probabilities like
+    :class:`TinyDecoder`, so ``lm/model.py`` closes the same loss over
+    either."""
+
+    vocab: int = 256
+    d_model: int = 64
+    n_heads: int = 4
+    n_layers: int = 2
+    n_experts: int = 8
+    experts_per_tok: int = 2
+    expert_width: int = 32
+    rope_theta: float = 10000.0
+    norm_eps: float = 1e-5
+    attn_fn: Optional[AttnFn] = None
+
+    @nn.compact
+    def __call__(self, tokens: jnp.ndarray) -> jnp.ndarray:
+        d = self.d_model
+        with jax.named_scope("embed"):
+            x = self.param("embed", _INIT, (self.vocab, d))[tokens]
+        for _ in range(self.n_layers):
+            x = OlmoeBlock(
+                d_model=d, n_heads=self.n_heads, n_experts=self.n_experts,
+                experts_per_tok=self.experts_per_tok,
+                expert_width=self.expert_width, rope_theta=self.rope_theta,
+                norm_eps=self.norm_eps, attn_fn=self.attn_fn,
+            )(x)
+        with jax.named_scope("head_loss"):  # lm/model.py's NLL joins it
+            x = rms_norm(x, self.param("final_norm", nn.initializers.ones,
+                                       (d,)), self.norm_eps)
+            logits = x @ self.param("head", _INIT, (d, self.vocab))
             return nn.log_softmax(logits)

@@ -43,6 +43,15 @@ from mpit_tpu.optim.msgd import MSGDConfig, msgd_init, msgd_step
 from mpit_tpu.optim.sync import push_pull
 
 
+def host_mirror(w: jnp.ndarray) -> np.ndarray:
+    """A writable, dtype-preserving host copy of ``w`` that leaves no
+    second one behind: ``np.array(w)`` alone would cache a read-only
+    host copy on ``w`` itself for as long as ``w`` lives (the seeded
+    vector lives as long as the model object: a dead whole vector of
+    host memory).  The cache lands on a device copy that dies here."""
+    return np.array(jnp.copy(w))
+
+
 class RuleShell:
     """Accumulate-and-ship client for server-side optimizer rules."""
 
@@ -53,6 +62,11 @@ class RuleShell:
         *,
         su: int = 1,
         mode: str = "global",
+        # global mode: the step returns ``((loss, stats), grad)``,
+        # ``stats`` the model's own {name: device array with one entry a
+        # layer} (lm/model.py ``value_grad_stats``): fetched on sync
+        # rounds while obs records, never with obs off (optim/sync.py)
+        has_aux: bool = False,
         # 'local'-mode RMSProp hyperparameters (reference optim-rmsprop.lua):
         lr: float = 1e-2,
         decay: float = 0.95,
@@ -82,6 +96,8 @@ class RuleShell:
         self._m_loss = _reg.gauge("mpit_train_loss", opt=f"rule-{mode}")
         self._m_unorm = _reg.gauge("mpit_train_update_norm",
                                    opt=f"rule-{mode}")
+        self._has_aux = has_aux
+        self.stats_last: dict = {}  # name -> the last recorded round's values
         if mode == "global":
             self._vgf = jax.jit(value_and_grad_fn)
 
@@ -101,9 +117,12 @@ class RuleShell:
             self._rule = rule
 
     def start(self, w: jnp.ndarray) -> jnp.ndarray:
-        self.w_host = np.array(w)  # dtype-preserving host mirror
+        self.w_host = host_mirror(w)
         self.grad_host = np.zeros_like(self.w_host)
-        self.accum = jnp.zeros_like(w)
+        # the accumulator is a whole vector on the device: only where
+        # something accumulates (at su 1 in global mode nothing does)
+        self.accum = (jnp.zeros_like(w)
+                      if self.su > 1 or self.mode == "local" else None)
         if self.mode == "local":
             self.rstate = self._rule.init(w)
         self.pc.start(self.w_host, self.grad_host)
@@ -114,12 +133,14 @@ class RuleShell:
         assert self._started, "call start(w) first"
         if self.mode == "global":
             loss, g = self._vgf(w, *fn_args)
+            loss, stats = loss if self._has_aux else (loss, None)
             if self.su == 1:
-                w = push_pull(self, g, loss)
+                # g is ours alone: its buffer goes once it is on the host
+                w = push_pull(self, g, loss, consume=True, stats=stats)
             else:
                 self.accum = self.accum + g
                 if self.k % self.su == 0:
-                    w = push_pull(self, self.accum, loss)
+                    w = push_pull(self, self.accum, loss, stats=stats)
                     self.accum = jnp.zeros_like(self.accum)
                 # else: params do not move between syncs (reference :41)
         else:  # local-mode RMSProp
@@ -178,7 +199,7 @@ class SingleWorker:
 
     def start(self, w: jnp.ndarray) -> jnp.ndarray:
         self.state = self._init_fn(w)
-        self.w_host = np.array(w)  # dtype-preserving host mirror
+        self.w_host = host_mirror(w)
         self.grad_host = np.zeros_like(self.w_host)
         self.pc.start(self.w_host, self.grad_host)
         self._started = True

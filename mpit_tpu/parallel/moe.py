@@ -1,7 +1,23 @@
-"""Expert parallelism: a Switch-style top-1 MoE layer over an ``ep``
-mesh axis.
+"""Mixture of experts: sorted dropless top-k dispatch on one device
+(:func:`dispatch_top_k`, what the OLMoE block of
+``models/transformer.py`` runs), the dense float32 oracle it is tested
+against (:func:`moe_dense_reference`), and a Switch-style top-1 layer
+over an ``ep`` mesh axis (:func:`ep_moe`, with its own oracle
+:func:`moe_reference`).
 
-Not in the reference (SURVEY §2: EP absent).  TPU-native shape:
+**Sorted dropless dispatch.**  The ``T x k`` (token, expert) assignments
+are flattened and sorted by expert, the tokens' rows gathered in that
+order, and each expert matrix applied to its own run of rows by one
+grouped product over all experts (:func:`grouped_dot`: the rows' FLOPs
+only, ``k / E`` of the dense form's).  The group sizes are the router's counts and sum to
+exactly ``k T``, a static row count: no capacity, so no token ever loses
+an expert however uneven the routing.  The results are scaled by the
+router's weights and summed back per token.  Both permutations are
+gathers in both directions (a permutation's transpose is its inverse),
+so the backward pass has no scatter.
+
+**``ep_moe``** (not in the reference, SURVEY §2: EP absent).  TPU-native
+shape:
 
 - experts' MLP weights are stacked on a leading expert axis and sharded
   over ``ep`` — each device owns ``E/n`` experts in HBM;
@@ -16,12 +32,214 @@ Not in the reference (SURVEY §2: EP absent).  TPU-native shape:
 
 from __future__ import annotations
 
-from typing import Callable
+from functools import partial
+from typing import Callable, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
+
+
+def route_top_k(probs: jnp.ndarray, k: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """The ``k`` largest router probabilities of each row and their
+    experts, ``(T, k)`` each, **not renormalised**.  Ties break towards
+    the lower expert index (``jax.lax.top_k`` puts the lower index
+    first)."""
+    return jax.lax.top_k(probs, k)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _spread(x, order, inverse, k):
+    """Row ``order[i] // k`` of ``x`` for every sorted assignment ``i``:
+    the gather of the dispatch.  ``inverse`` is ``order``'s inverse
+    permutation."""
+    return x[order // k]
+
+
+def _spread_fwd(x, order, inverse, k):
+    return x[order // k], inverse
+
+
+def _spread_bwd(k, inverse, g):
+    # Unsort, then sum a token's k rows: a gather where the plain
+    # transpose of ``x[order // k]`` would be a scatter-add.
+    back = g[inverse].reshape(-1, k, g.shape[-1]).sum(axis=1)
+    return back, None, None
+
+
+_spread.defvjp(_spread_fwd, _spread_bwd)
+
+
+@jax.custom_vjp
+def _unsort(y, order, inverse):
+    """``y`` back in assignment order (row ``t * k + j`` is token ``t``'s
+    ``j``-th expert)."""
+    return y[inverse]
+
+
+def _unsort_fwd(y, order, inverse):
+    return y[inverse], order
+
+
+def _unsort_bwd(order, g):
+    return g[order], None, None
+
+
+_unsort.defvjp(_unsort_fwd, _unsort_bwd)
+
+
+def sort_by_expert(experts: jnp.ndarray, n_experts: int):
+    """``(order, inverse, group_sizes)`` of the flattened ``(T, k)``
+    assignments: ``order`` sorts them by expert (stable, so a token's
+    rows keep their order inside a group), ``inverse`` undoes it,
+    ``group_sizes[e]`` counts expert ``e``'s rows and sums to ``T k``."""
+    flat = experts.reshape(-1)
+    order = jnp.argsort(flat, stable=True)
+    inverse = jnp.argsort(order)
+    group_sizes = jnp.bincount(flat, length=n_experts).astype(jnp.int32)
+    return order, inverse, group_sizes
+
+
+# -- the grouped product --------------------------------------------------------
+#
+# ``rows (m, k)`` sorted by group times ``w (E, k, n)``, group ``e``'s run
+# of ``group_sizes[e]`` rows against ``w[e]``.  Two implementations of
+# the same arithmetic (operands rounded to bf16 on a TPU, float32
+# accumulation, float32 results):
+#
+# - ``jax.lax.ragged_dot``, native on the TPU backend and exact float32
+#   on the CPU;
+# - :func:`pallas_grouped_dot`: the megablox grouped matmul kernels that
+#   ship with jax (``jax.experimental.pallas.ops.tpu.megablox``), with a
+#   vjp of our own that keeps every result float32 (the library's
+#   returns a bf16 operand's gradient in bf16).  On the v5e at OLMoE's
+#   shapes (32,768 rows x 2048 x 1024, 64 groups) it is 1.5 times
+#   ``ragged_dot`` forward and backward (PERF.md section 6, PR 26).  TPU
+#   only, shapes in whole tiles only (:func:`pallas_fits`).
+
+GMM_TILE_M = 256   # rows a tile; the row count must be a multiple
+GMM_TILE = 1024    # columns a tile, on both matrix dimensions
+
+
+def pallas_fits(m: int, k: int, n: int) -> bool:
+    """Whether the Pallas kernels take ``(m, k) x (E, k, n)``: whole row
+    tiles, and each matrix dimension one tile or whole tiles of 1024."""
+    return m % GMM_TILE_M == 0 and all(
+        x % 128 == 0 and (x <= GMM_TILE or x % GMM_TILE == 0)
+        for x in (k, n))
+
+
+def _gmm_tiling(k: int, n: int) -> Tuple[int, int, int]:
+    return GMM_TILE_M, min(k, GMM_TILE), min(n, GMM_TILE)
+
+
+def _megablox():
+    # the package's ``__init__`` rebinds the name ``gmm`` to a function,
+    # so the module of the kernels is reached by its full name
+    import importlib
+
+    return importlib.import_module(
+        "jax.experimental.pallas.ops.tpu.megablox.gmm")
+
+
+@jax.custom_vjp
+def pallas_grouped_dot(rows, w, group_sizes):
+    """The grouped product by the megablox kernels: three Mosaic calls
+    forward and backward (the product, its rows' gradient, its weights'
+    gradient), float32 results.  Shapes :func:`pallas_fits` takes."""
+    mb = _megablox()
+    return mb.gmm(rows.astype(jnp.bfloat16), w.astype(jnp.bfloat16),
+                  group_sizes, jnp.float32,
+                  _gmm_tiling(w.shape[1], w.shape[2]))
+
+
+def _pallas_grouped_dot_fwd(rows, w, group_sizes):
+    return pallas_grouped_dot(rows, w, group_sizes), (rows, w, group_sizes)
+
+
+def _pallas_grouped_dot_bwd(res, g):
+    mb = _megablox()
+    rows, w, group_sizes = res
+    g16 = g.astype(jnp.bfloat16)
+    k, n = w.shape[1], w.shape[2]
+    d_rows = mb.gmm(g16, w.astype(jnp.bfloat16), group_sizes, jnp.float32,
+                    _gmm_tiling(n, k), transpose_rhs=True)
+    d_w = mb.tgmm(rows.astype(jnp.bfloat16).swapaxes(0, 1), g16,
+                  group_sizes, jnp.float32, _gmm_tiling(k, n),
+                  num_actual_groups=w.shape[0])
+    return d_rows, d_w, None
+
+
+pallas_grouped_dot.defvjp(_pallas_grouped_dot_fwd, _pallas_grouped_dot_bwd)
+
+
+def grouped_dot(rows: jnp.ndarray, w: jnp.ndarray,
+                group_sizes: jnp.ndarray) -> jnp.ndarray:
+    """The grouped product: :func:`pallas_grouped_dot` on a TPU where the
+    shapes are whole tiles, ``jax.lax.ragged_dot`` everywhere else.  On
+    the chip the other path is never silent: the benchmark counts the
+    step's Mosaic calls against the ``experts`` kernel family
+    (``chipbench/arithmetic/olmoe.py``), and a run without them is not
+    ``correct``."""
+    if (jax.default_backend() == "tpu"
+            and pallas_fits(rows.shape[0], w.shape[1], w.shape[2])):
+        return pallas_grouped_dot(rows, w, group_sizes)
+    return jax.lax.ragged_dot(rows, w, group_sizes)
+
+
+def swiglu_experts(rows: jnp.ndarray, group_sizes: jnp.ndarray,
+                   wg: jnp.ndarray, wu: jnp.ndarray,
+                   wd: jnp.ndarray) -> jnp.ndarray:
+    """``(SiLU(rows Wg_e) * (rows Wu_e)) Wd_e`` for rows sorted by
+    expert: three grouped products (:func:`grouped_dot`) over ``wg, wu
+    (E, d, f)`` and ``wd (E, f, d)``, no bias."""
+    gate = grouped_dot(rows, wg, group_sizes)
+    up = grouped_dot(rows, wu, group_sizes)
+    return grouped_dot(jax.nn.silu(gate) * up, wd, group_sizes)
+
+
+def dispatch_top_k(x: jnp.ndarray, weights: jnp.ndarray,
+                   experts: jnp.ndarray, n_experts: int,
+                   expert_fn: Callable[[jnp.ndarray, jnp.ndarray],
+                                       jnp.ndarray]) -> jnp.ndarray:
+    """``sum_j weights[t, j] * expert_{experts[t, j]}(x[t])`` for every
+    token, dropless.  ``x (T, d)``; ``weights``, ``experts (T, k)``;
+    ``expert_fn(rows, group_sizes)`` maps the ``T k`` rows sorted by
+    expert to their outputs (:func:`swiglu_experts`).  The sort, the
+    gathers and the weighted sum run under the scope ``dispatch``, the
+    experts under ``experts`` (flat, never nested: a trace books an
+    operation under the one scope of its name stack)."""
+    t, k = experts.shape
+    with jax.named_scope("dispatch"):
+        order, inverse, group_sizes = sort_by_expert(experts, n_experts)
+        rows = _spread(x, order, inverse, k)
+    with jax.named_scope("experts"):
+        out = expert_fn(rows, group_sizes)
+    with jax.named_scope("dispatch"):
+        out = _unsort(out, order, inverse).reshape(t, k, -1)
+        return jnp.einsum("tkd,tk->td", out, weights.astype(out.dtype))
+
+
+def load_max_over_mean(experts: jnp.ndarray, n_experts: int) -> jnp.ndarray:
+    """Routing imbalance: the busiest expert's assignment count over the
+    mean count (1 is even; ``E / k`` is every token on the same k)."""
+    counts = jnp.bincount(experts.reshape(-1), length=n_experts)
+    return jnp.max(counts) * n_experts / experts.size
+
+
+def moe_dense_reference(x, probs, wg, wu, wd, k: int):
+    """The dense float32 oracle of :func:`dispatch_top_k` with
+    :func:`swiglu_experts`: every token through every expert, masked by
+    its top-``k`` router weights (not renormalised).  ``E / k`` times the
+    expert FLOPs; for tests."""
+    weights, experts = route_top_k(probs, k)
+    mask = jnp.zeros_like(probs).at[
+        jnp.arange(probs.shape[0])[:, None], experts].set(weights)
+    hidden = jax.nn.silu(jnp.einsum("td,edf->etf", x, wg)) \
+        * jnp.einsum("td,edf->etf", x, wu)
+    return jnp.einsum("etd,te->td", jnp.einsum("etf,efd->etd", hidden, wd),
+                      mask)
 
 
 def ep_moe(

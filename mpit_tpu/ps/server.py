@@ -1695,6 +1695,13 @@ class ParamServer:
                 )
             span.end("applied")
             if once:
+                # The one-shot seed is in ``self.param`` (a copy of its
+                # own): the whole-shard staging is never received into
+                # again, and kept it is a dead shard of host memory for
+                # the rest of the run (1.25 GB a server at OLMoE's one
+                # layer: PERF.md section 6, PR 26).
+                self._push_bufs.pop(crank, None)
+                self._push_host.pop(crank, None)
                 return
 
     def _send_param(self, crank: int, gen: int = 0):

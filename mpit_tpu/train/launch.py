@@ -209,6 +209,15 @@ LAUNCH_DEFAULTS = TRAINER_DEFAULTS.merged(
     # the equal split — lm_weights skews it ("3,1" = server 0 aims at
     # 3/4 of the vector), empty = balanced cut on parameter boundaries.
     lm=0,
+    # the block: gpt2 (LayerNorm, learned positions, GELU MLP) | olmoe
+    # (RMSNorm, rotary positions, q/k norm, top-k of E gated experts by
+    # sorted dropless dispatch); the lm_experts.. knobs are olmoe's
+    lm_arch="gpt2",
+    lm_experts=8,
+    lm_experts_per_tok=2,
+    lm_expert_width=32,
+    lm_rope_theta=10000.0,
+    lm_norm_eps=1e-5,
     lm_d_model=64,
     lm_heads=4,
     lm_layers=2,
@@ -329,6 +338,12 @@ def lm_trainer_cfg(cfg: Config) -> Config:
     launch config: shared optimizer/loop knobs carried over verbatim,
     lm_* knobs mapped onto the trainer's names."""
     return Config(
+        arch=str(cfg.get("lm_arch", "gpt2")),
+        n_experts=int(cfg.get("lm_experts", 8)),
+        experts_per_tok=int(cfg.get("lm_experts_per_tok", 2)),
+        expert_width=int(cfg.get("lm_expert_width", 32)),
+        rope_theta=float(cfg.get("lm_rope_theta", 10000.0)),
+        norm_eps=float(cfg.get("lm_norm_eps", 1e-5)),
         d_model=int(cfg.get("lm_d_model", 64)),
         n_heads=int(cfg.get("lm_heads", 4)),
         n_layers=int(cfg.get("lm_layers", 2)),
@@ -351,14 +366,11 @@ def lm_layout(cfg: Config, n_servers: int):
     targets; empty keeps balanced targets (still boundary-aligned, so
     it differs from the raw equal split)."""
     from mpit_tpu.lm import build, plan
-    from mpit_tpu.lm.model import vocab_kw
+    from mpit_tpu.lm.model import build_kw
 
-    tcfg = lm_trainer_cfg(cfg)
     # Param *shapes* don't depend on the attention implementation, so
     # layout derivation never touches the accelerator kernels.
-    model = build(d_model=tcfg.d_model, n_heads=tcfg.n_heads,
-                  n_layers=tcfg.n_layers, seq_len=tcfg.seq_len,
-                  seed=tcfg.seed, use_flash=False, **vocab_kw(tcfg.vocab))
+    model = build(use_flash=False, **build_kw(lm_trainer_cfg(cfg)))
     params = model.flat.unravel(model.flat.w0)
     spec = str(cfg.get("lm_weights", "") or "")
     weights = ([float(x) for x in spec.split(",") if x.strip() != ""]
@@ -385,13 +397,9 @@ def _serve_vec_len(cfg: Config, rank: int) -> int:
     full = TRAINER_DEFAULTS.merged(cfg.to_dict())
     if int(cfg.get("lm", 0)):
         from mpit_tpu.lm import build
-        from mpit_tpu.lm.model import vocab_kw
+        from mpit_tpu.lm.model import build_kw
 
-        tcfg = lm_trainer_cfg(cfg)
-        model = build(d_model=tcfg.d_model, n_heads=tcfg.n_heads,
-                      n_layers=tcfg.n_layers, seq_len=tcfg.seq_len,
-                      seed=tcfg.seed, use_flash=False,
-                      **vocab_kw(tcfg.vocab))
+        model = build(use_flash=False, **build_kw(lm_trainer_cfg(cfg)))
         return int(model.flat.size)
     x_train = load_mnist(side=full.side)[0][0]
     if full.model == "cnn":
@@ -1119,6 +1127,7 @@ def _summarize(result: Dict[str, Any]) -> Dict[str, Any]:
             "reads", "monotone", "busy_honored",
             "final_loss", "final_eval_loss", "tokens_per_s", "tokens_total",
             "steps", "train_seconds", "first_step_seconds", "mosaic_calls",
+            "moe_load_max_over_mean",
             "platform", "device_kind", "device_count", "device_ids",
             "chip_nodes"}
     return {k: v for k, v in result.items() if k in keep}
