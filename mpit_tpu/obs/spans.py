@@ -267,6 +267,13 @@ class SpanRecorder:
             self._handed += 1
         self._waiter.items.put((span, result))
 
+    def clock(self) -> float:
+        """The clock the spans are stamped with, for a wait that has to
+        stay outside every span (the client's shard gate measures
+        ``gated_ms`` before its GRAD span opens).  The null recorder
+        answers 0.0 and reads no clock."""
+        return time.monotonic()
+
     def drain(self, timeout: float = 10.0) -> bool:
         """Wait until every span handed to :meth:`end_when_ready` has
         ended (the exporter calls this before it reads ``spans``)."""
@@ -369,6 +376,9 @@ class NullRecorder:
 
     def end_when_ready(self, span, result) -> None:
         pass
+
+    def clock(self) -> float:
+        return 0.0
 
     def drain(self, timeout: float = 10.0) -> bool:
         return True

@@ -9,6 +9,14 @@ The real implementation is :class:`mpit_tpu.ps.client.ParamClient`; optimizer
 unit tests substitute an in-process simulator.  Buffers are 1-D numpy arrays
 the client slices per server shard (numpy views = the zero-copy analog of
 ``torch.Storage(grad, offset, size)``, reference pclient.lua:50-52).
+
+Two optional extensions: ``sync_device`` (:class:`DeviceSyncAPI`, below)
+and ``stream_shards(staged, landed)``, by which a client tells the sync
+round how the vector is cut and takes its per-shard gate and sink
+(described on :meth:`mpit_tpu.ps.client.ParamClient.stream_shards`; used
+by :mod:`mpit_tpu.optim.sync`, which tests for it by name, because
+``isinstance`` on a protocol does not see through a front that forwards
+with ``__getattr__``).
 """
 
 from __future__ import annotations
@@ -55,3 +63,4 @@ class DeviceSyncAPI(ParamClientAPI, Protocol):
     universal fallback."""
 
     def sync_device(self, update, *, pull: bool = True): ...
+

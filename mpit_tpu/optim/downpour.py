@@ -39,7 +39,7 @@ import numpy as np
 
 from mpit_tpu.obs import get_recorder, get_registry
 from mpit_tpu.optim.client_api import ParamClientAPI
-from mpit_tpu.optim.sync import push_pull
+from mpit_tpu.optim.sync import attach, push_pull
 
 
 class Downpour:
@@ -90,6 +90,7 @@ class Downpour:
         self.grad_host = np.zeros_like(self.w_host)
         self.accum = jnp.zeros_like(w)
         self.pc.start(self.w_host, self.grad_host)
+        attach(self)  # the round streams where the client says how it is cut
         self._started = True
         return w
 
@@ -113,3 +114,4 @@ class Downpour:
     def stop(self) -> None:
         if self._started:
             self.pc.stop()
+            self._stream.close()  # the round's copying thread

@@ -40,7 +40,7 @@ from mpit_tpu.obs import get_recorder, get_registry
 from mpit_tpu.optim import rules as rules_mod
 from mpit_tpu.optim.client_api import ParamClientAPI
 from mpit_tpu.optim.msgd import MSGDConfig, msgd_init, msgd_step
-from mpit_tpu.optim.sync import push_pull
+from mpit_tpu.optim.sync import attach, push_pull
 
 
 def host_mirror(w: jnp.ndarray) -> np.ndarray:
@@ -126,6 +126,7 @@ class RuleShell:
         if self.mode == "local":
             self.rstate = self._rule.init(w)
         self.pc.start(self.w_host, self.grad_host)
+        attach(self)  # the round streams where the client says how it is cut
         self._started = True
         return w
 
@@ -161,6 +162,7 @@ class RuleShell:
     def stop(self) -> None:
         if self._started:
             self.pc.stop()
+            self._stream.close()  # the round's copying thread
 
 
 class SingleWorker:

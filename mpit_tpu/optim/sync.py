@@ -9,19 +9,54 @@ that round, recorded as one ``round`` span whose phases tile it
 ``wait_backward`` → ``d2h`` → ``stage`` → ``exchange`` → ``h2d`` →
 ``telemetry``
 
+**The round moves in pieces, and shard by shard** where the client says
+how the vector is cut (``stream_shards``, an optional extension of
+``ParamClientAPI`` that a :class:`~mpit_tpu.ps.client.ParamClient` not
+under shardctl has).
+Three resources take part in a round and none needs the others' turn:
+the chip's DMA engine (d2h, h2d), the client's thread (its copies into
+and out of the transport) and the servers.  So the payload leaves the
+device in pieces of :data:`PIECE_BYTES`, in the client's shard order,
+a few in flight at a time, each staged into its slice of ``grad_host``
+by the stream's thread as it lands; shard ``s``'s GRAD op begins once
+shard ``s`` is whole in the mirror (the client asks the *gate*,
+:meth:`ShardStream.staged`, before it touches the slice) while the
+pieces of shard ``s + 1`` are still crossing; and when server ``s``'s
+PARAM op completes the client calls the *sink*,
+:meth:`ShardStream.landed`, and the same thread sends that slice of
+``w_host`` back up, piece by piece into one donated device buffer,
+while the other servers' PARAM ops are still receiving.  Every server
+sees the frames it saw, in the order it saw them.  No whole-vector host
+array is made on the way up, and the pieces land in memory the
+allocator hands out again (a host array of the whole vector is fresh
+pages every round, which cost more than the copy: most of what the
+pieces gain, they gain without any overlap).  So there is one round,
+not two.  A client that gives no cut (the tests' simulators, shardctl,
+the device plane's front) is one shard, the whole vector, with no hook
+on it: its payload goes down in the same pieces, the three calls run
+once the vector is whole in the mirror, and the shell sinks the shard
+itself after ``wait``.  With one shard nothing moves beside anything
+(``shards_streamed`` 0); the pieces are what is left of the gain.
+
 With obs off every span site is a call on ``NULL_SPAN``: no fence is
 taken and no telemetry is computed, and the only clock reads are the
 two of a plain timer around the exchange, which keeps
 ``sync_seconds`` the same quantity at the same boundary whether obs is
 on (the ``exchange`` phase of the span) or off.  While recording, two
-fences split what would otherwise hide inside a host copy
-(``np.asarray(payload)`` waits for the backward *and* copies; the
-transfer behind ``jnp.asarray(w_host)`` completes after the call
-returns), and the update norm is reduced on the device, off the round's
-critical path, and read back as one scalar under ``telemetry``; the
-model's own statistics (``stats``: a sparse-expert block's routing
-imbalance, an auxiliary output of the step) are read there too, and
-never with obs off.
+fences split what would otherwise hide inside a host copy (the first
+d2h waits for the backward *and* copies; the transfers of the h2d
+complete after the calls return), and the update norm is reduced on the
+device, off the round's critical path, and read back as one scalar
+under ``telemetry``; the model's own statistics (``stats``: a
+sparse-expert block's routing imbalance, an auxiliary output of the
+step) are read there too, and never with obs off.
+
+The phases name what the worker *waited for with nothing else going
+on*: ``d2h`` until the first piece is on the host,
+``stage`` from there until the first shard is whole in the mirror,
+``exchange`` from the first ``async_*`` call to the return of ``wait``
+(the later shards' d2h and the earlier shards' h2d run inside it),
+``h2d`` from there until the parameters are whole on the device.
 
 EASGD's round has the same parts in another order (pull, then push) and
 marks them itself (:mod:`mpit_tpu.optim.easgd`).
@@ -29,14 +64,31 @@ marks them itself (:mod:`mpit_tpu.optim.easgd`).
 
 from __future__ import annotations
 
+import queue
+import threading
 import time
-from typing import Any, Dict, Optional
+from collections import deque
+from functools import partial
+from typing import Any, Dict, List, NamedTuple, Optional, Set, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from mpit_tpu.obs import get_registry
+
+#: A piece of the vector on its way down or up.  Small enough that the
+#: host arrays the d2h lands in come back from the allocator's free
+#: lists: from 32 MB on glibc maps fresh pages for every one.  The d2h
+#: of a 598 MB vector into the mirror takes 0.21-0.22 s whole (and a
+#: 0.032 s stage), 0.30-0.45 s in pieces of 32-64 MB and 0.094 s in
+#: pieces of 8 MB (PERF.md section 6, PR 27).  Large enough that a
+#: 2.5 GB vector is a few hundred of them.
+PIECE_BYTES = 8 << 20
+#: Pieces cut from the payload and on their way to the host at once:
+#: what the device holds beside the payload, and enough that the DMA
+#: engine never waits for the host (2 is slower, 8 no faster).
+IN_FLIGHT = 4
 
 
 @jax.jit
@@ -45,14 +97,249 @@ def shipped_norm(x: jnp.ndarray) -> jnp.ndarray:
     return jnp.sqrt(jnp.sum(jnp.square(x.astype(jnp.float32))))
 
 
+@partial(jax.jit, static_argnames="size")
+def _cut(x: jnp.ndarray, start: int, *, size: int) -> jnp.ndarray:
+    return jax.lax.dynamic_slice(x, (start,), (size,))
+
+
+@partial(jax.jit, donate_argnums=0)
+def _paste(whole: jnp.ndarray, piece: jnp.ndarray, start: int) -> jnp.ndarray:
+    return jax.lax.dynamic_update_slice(whole, piece, (start,))
+
+
+def _exchange(opt: Any, span: Any) -> None:
+    """The round at the ``ParamClientAPI`` boundary: the three calls,
+    once each, and with obs off the plain timer around them."""
+    span.mark("exchange")
+    plain = not opt._spans.enabled  # obs off: a plain timer at this boundary
+    t0 = time.monotonic() if plain else 0.0
+    opt.pc.async_send_grad()
+    opt.pc.async_recv_param()
+    opt.pc.wait()
+    if plain:
+        opt.sync_seconds += time.monotonic() - t0
+    span.mark("h2d")
+
+
+class _Whole(NamedTuple):
+    """The cut of a client that gives none: one shard, all of it."""
+
+    offset: int
+    end: int
+
+
+class _Copies:
+    """The copies of one round, run off the client's thread (on the
+    stream's): every piece of the payload to its place in ``grad_host``
+    (setting a shard's flag when its last piece is there), then each
+    shard of ``w_host`` back to the device as it is sunk.  The waits
+    for the DMA engine and the host copies release the interpreter
+    lock, so the client's thread keeps pumping beside them."""
+
+    def __init__(self, stream: "ShardStream", payload: jnp.ndarray,
+                 consume: bool):
+        self.stream = stream
+        self.payload = payload
+        self.consume = consume
+        self.first_piece = threading.Event()
+        self.staged = [threading.Event() for _ in stream.cut]
+        self.landed: "queue.SimpleQueue[Optional[int]]" = queue.SimpleQueue()
+        self.sunk: Set[int] = set()  # shards handed to ``landed``
+        self.w: Optional[jnp.ndarray] = None
+        self.error: Optional[BaseException] = None
+        self.quit = False  # the round failed: stop copying
+        self.done = threading.Event()
+
+    def run(self) -> None:
+        try:
+            self._stage()
+            self._upload()
+        except BaseException as exc:  # noqa: BLE001 — ``check`` raises it
+            self.error = exc
+        finally:
+            self.payload = None
+            self.first_piece.set()
+            for flag in self.staged:
+                flag.set()  # after ``error``: a waiter sees both
+            self.done.set()
+
+    def check(self) -> None:
+        """Raise what stopped the copies, if anything did."""
+        if self.error is not None:
+            raise RuntimeError(
+                "the round's copying thread failed") from self.error
+
+    def sink(self, shard: int) -> None:
+        self.sunk.add(shard)
+        self.landed.put(shard)
+
+    def _stage(self) -> None:
+        stream, payload = self.stream, self.payload
+        todo = iter(stream.pieces)
+        flight: deque = deque()
+
+        def issue() -> None:
+            piece = next(todo, None)
+            if piece is not None:
+                _shard, lo, hi = piece
+                part = _cut(payload, lo, size=hi - lo)
+                part.copy_to_host_async()
+                flight.append((piece, part))
+
+        for _ in range(IN_FLIGHT):
+            issue()
+        while flight and not self.quit:
+            (shard, lo, hi), part = flight.popleft()
+            host = np.asarray(part)
+            self.first_piece.set()
+            np.copyto(stream.grad_host[lo:hi], host)
+            del host  # on the CPU backend a view of the buffer freed next
+            part.delete()
+            issue()
+            if hi == stream.cut[shard].end:
+                self.staged[shard].set()
+        if self.consume and not flight:  # every cut has run
+            payload.delete()
+
+    def _upload(self) -> None:
+        stream = self.stream
+        # On the CPU backend a put may alias host memory, and the
+        # mirror is overwritten by the next round's PARAM.
+        private = jax.default_backend() == "cpu"
+        while True:
+            shard = self.landed.get()
+            if shard is None or self.quit:
+                return
+            if self.w is None:
+                self.w = jnp.zeros(stream.w_host.shape, stream.w_host.dtype)
+            for lo, hi in stream.parts[shard]:
+                view = stream.w_host[lo:hi]
+                part = jax.device_put(view.copy() if private else view)
+                self.w = _paste(self.w, part, lo)
+
+
+class ShardStream:
+    """A shell's side of the round: the cut it moves by, the gate and
+    the sink it offers the client once (:func:`attach`), and the one
+    thread that makes every round's copies, from the first round until
+    :meth:`close` (a thread a round would leave each round's host
+    pieces behind in an allocator arena of its own).  Between rounds the
+    gate is open and the sink does nothing, so ops issued outside
+    :func:`push_pull` run as before."""
+
+    def __init__(self, grad_host: np.ndarray, w_host: np.ndarray):
+        self.grad_host, self.w_host = grad_host, w_host
+        self.cut: List[Any] = []
+        self.parts: List[List[Tuple[int, int]]] = []  # (lo, hi), by shard
+        self.pieces: List[Tuple[int, int, int]] = []  # (shard, lo, hi), all
+        self.shards_streamed = 0  # shards that move beside each other
+        self.m_streamed: Any = None  # mpit_round_streamed_total
+        self._index: Dict[int, int] = {}  # a shard's offset -> its number
+        self._worker: Optional[_Copies] = None  # this round's, in a round
+        self._rounds: "queue.SimpleQueue[Optional[_Copies]]" = (
+            queue.SimpleQueue())
+        self._thread: Optional[threading.Thread] = None
+
+    def bind(self, cut: List[Any]) -> None:
+        """Take the cut; a piece never crosses a shard."""
+        step = max(PIECE_BYTES // self.grad_host.dtype.itemsize, 1)
+        self.cut = list(cut)
+        self._index = {shard.offset: i for i, shard in enumerate(cut)}
+        self.parts = [
+            [(lo, min(lo + step, shard.end))
+             for lo in range(shard.offset, shard.end, step)]
+            for shard in cut]
+        self.pieces = [(i, lo, hi) for i, parts in enumerate(self.parts)
+                       for lo, hi in parts]
+        self.shards_streamed = len(cut) if len(cut) > 1 else 0
+
+    # -- the hooks (on the client's thread; neither blocks) ------------------
+
+    def staged(self, shard: Any) -> bool:
+        worker = self._worker
+        if worker is None:
+            return True
+        if not worker.staged[self._index[shard.offset]].is_set():
+            return False
+        worker.check()
+        return True
+
+    def landed(self, shard: Any) -> None:
+        worker = self._worker
+        if worker is not None:
+            worker.sink(self._index[shard.offset])
+
+    # -- the thread, and one round -------------------------------------------
+
+    def _serve(self) -> None:
+        while (copies := self._rounds.get()) is not None:
+            copies.run()
+
+    def close(self) -> None:
+        """End the thread (a shell's ``stop``); a later round starts
+        another."""
+        if self._thread is not None:
+            self._rounds.put(None)
+            self._thread.join()
+            self._thread = None
+
+    def round(self, opt: Any, span: Any, payload: jnp.ndarray,
+              consume: bool) -> jnp.ndarray:
+        span.mark("d2h")
+        if self._thread is None:
+            self._thread = threading.Thread(
+                target=self._serve, name="mpit-round-stream", daemon=True)
+            self._thread.start()
+        worker = self._worker = _Copies(self, payload, consume)
+        self._rounds.put(worker)
+        try:
+            worker.first_piece.wait()
+            span.mark("stage")
+            worker.staged[0].wait()
+            worker.check()  # nothing half staged leaves ungated
+            _exchange(opt, span)
+            # What the client did not sink goes up now: it took no
+            # hooks, or a read was aborted at shutdown.
+            for shard in range(len(self.cut)):
+                if shard not in worker.sunk:
+                    worker.sink(shard)
+        except BaseException:
+            worker.quit = True  # no copy after a failed exchange
+            raise
+        finally:
+            worker.landed.put(None)
+            worker.done.wait()
+            self._worker = None
+        worker.check()
+        return worker.w
+
+
+def attach(opt: Any) -> None:
+    """Called by a shell's ``start`` once its client has started: bind
+    the round's stream, ``opt._stream``, to the client's cut and give
+    the client the gate and the sink, if it takes them
+    (``stream_shards``); if not, to one shard with no hook on it."""
+    opt.rounds_streamed = 0  # rounds in which two or more shards streamed
+    stream = opt._stream = ShardStream(opt.grad_host, opt.w_host)
+    # Tested by name: ``isinstance`` on a protocol looks past
+    # ``__getattr__``, so it would not see the extension behind a front
+    # that forwards to its client (the benchmark's timing proxy).
+    install = getattr(opt.pc, "stream_shards", None)
+    cut = install(stream.staged, stream.landed) if install else None
+    stream.bind(cut or [_Whole(0, opt.grad_host.size)])
+    stream.m_streamed = get_registry().counter(
+        "mpit_round_streamed_total", rank=getattr(opt.pc, "rank", None))
+
+
 def push_pull(opt: Any, payload: jnp.ndarray,
               loss: Optional[jnp.ndarray] = None, *, consume: bool = False,
               stats: Optional[Dict[str, jnp.ndarray]] = None,
               ) -> jnp.ndarray:
     """Ship ``payload`` as the gradient and fetch fresh parameters.
     ``opt`` is the shell: its ``pc``, ``grad_host``, ``w_host``,
-    ``rounds``, ``sync_seconds``, its recorder ``_spans`` and its gauges
-    ``_m_unorm`` and ``_m_loss``.  ``consume``: the payload is the
+    ``rounds``, ``sync_seconds``, its recorder ``_spans``, its gauges
+    ``_m_unorm`` and ``_m_loss`` and the stream :func:`attach` bound,
+    ``_stream``.  ``consume``: the payload is the
     caller's to give up (a gradient nobody else holds); its device
     buffer is freed once it is staged on the host, so the round's h2d
     does not find a dead whole vector still resident.  ``stats``: the
@@ -67,23 +354,13 @@ def push_pull(opt: Any, payload: jnp.ndarray,
     if rec.enabled:
         jax.block_until_ready(payload)
         unorm = shipped_norm(payload)  # dispatched; read under telemetry
-    span.mark("d2h")
-    host = np.asarray(payload)
-    span.mark("stage")
-    np.copyto(opt.grad_host, host)
-    if consume:
-        del host  # on the CPU backend a view of the buffer freed next
-        payload.delete()
-    span.mark("exchange")
-    plain = not rec.enabled  # obs off: a plain timer at this boundary
-    t0 = time.monotonic() if plain else 0.0
-    opt.pc.async_send_grad()
-    opt.pc.async_recv_param()
-    opt.pc.wait()
-    if plain:
-        opt.sync_seconds += time.monotonic() - t0
-    span.mark("h2d")
-    w = jnp.asarray(opt.w_host)
+    stream = opt._stream
+    w = stream.round(opt, span, payload, consume)
+    span.note(pieces=len(stream.pieces),
+              shards_streamed=stream.shards_streamed)
+    if stream.shards_streamed:
+        opt.rounds_streamed += 1
+        stream.m_streamed.inc()
     if rec.enabled:
         jax.block_until_ready(w)
         span.mark("telemetry")

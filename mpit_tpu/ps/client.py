@@ -49,7 +49,7 @@ from __future__ import annotations
 
 import time
 from collections import deque
-from typing import Deque, Dict, Generator, List, Optional, Tuple
+from typing import Callable, Deque, Dict, Generator, List, Optional, Tuple
 
 import numpy as np
 
@@ -301,6 +301,11 @@ class ParamClient:
         self._opq: Dict[int, Deque[Tuple[Generator, str]]] = {}
         self._pump_live: Dict[int, bool] = {}
         self._pump_task: Dict[int, Optional[object]] = {}
+        # The streamed round's per-shard gate and sink (stream_shards):
+        # None, and no instruction beyond the test for it, unless a
+        # shell installed them.
+        self._staged: Optional[Callable[[Shard], bool]] = None
+        self._landed: Optional[Callable[[Shard], None]] = None
 
     # -- lifecycle ----------------------------------------------------------
 
@@ -946,13 +951,20 @@ class ParamClient:
         (reference pclient.lua:48-58).  Non-identity codecs encode into
         the per-server staging frame at ship time; the int8 residual is
         folded in and refreshed by the same pass.  Framed mode stamps
-        [epoch, seq] and retries the staged bytes on deadline."""
+        [epoch, seq] and retries the staged bytes on deadline.  Gated
+        (:meth:`stream_shards`): nothing here reads the slice before
+        the shell has staged it, and the wait lies before the span."""
+        gated_ms = None
+        if self._staged is not None:
+            gated_ms = yield from self._gate(shard)
         if self._chunked:
             yield from self._chunked_write(srank, shard, tags.GRAD,
-                                           tags.GRAD_ACK, "GRAD")
+                                           tags.GRAD_ACK, "GRAD", gated_ms)
             return
         span = self._spans.op("GRAD", peer=srank, side="client",
                               rank=self.rank)
+        if gated_ms is not None:
+            span.note(gated_ms=gated_ms)
         view = self.grad[shard.offset : shard.end]
         wire = self._grad_wire.get(srank)
         span.mark("encode")
@@ -983,13 +995,21 @@ class ParamClient:
         )
 
     def _recv_param(self, srank: int, shard: Shard):
+        """Read this server's shard into the param slice and, once the
+        slice is whole (decoded, where a codec or the framed wire is
+        on), hand it to the shell's sink (:meth:`stream_shards`)."""
+        whole = yield from (self._chunked_read(srank, shard)
+                            if self._chunked
+                            else self._read_shard(srank, shard))
+        if whole and self._landed is not None:
+            self._landed(shard)
+
+    def _read_shard(self, srank: int, shard: Shard):
         """Request-to-read header, then receive into the param slice
         (reference pclient.lua:72-82) — via the wire staging frame when
         the codec is not identity.  Framed mode seq-tags the request and
-        discards snapshot frames that echo an earlier request."""
-        if self._chunked:
-            yield from self._chunked_read(srank, shard)
-            return
+        discards snapshot frames that echo an earlier request.  Returns
+        True once the slice is whole."""
         span = self._spans.op("PARAM", peer=srank, side="client",
                               rank=self.rank)
         out = self.param[shard.offset : shard.end]
@@ -1010,7 +1030,7 @@ class ParamClient:
                 span.mark("decode")
                 self.codec.decode_into(wire, out)
             span.end("ok" if got is not None else "aborted")
-            return
+            return got is not None
         seq = self._next_seq(srank, tags.PARAM_REQ)
         span.note(epoch=self.ft.epoch, seq=seq)
         wire = self._param_rx[srank]
@@ -1062,7 +1082,7 @@ class ParamClient:
                         span.mark("decode")
                         self._decode_framed(wire, out)
                         span.end("ok")
-                        return
+                        return True
                     # stale snapshot (earlier request's duplicate): drop
             except DeadlineExceeded as exc:
                 last = exc
@@ -1113,7 +1133,8 @@ class ParamClient:
     # -- pipelined streaming transfers (FLAG_CHUNKED, PROTOCOL.md §12) -------
 
     def _chunked_write(self, srank: int, shard: Shard, tag: int,
-                       ack_tag: int, what: str):
+                       ack_tag: int, what: str,
+                       gated_ms: Optional[float] = None):
         """One streamed shard write: the body ships as K independent
         chunk frames, each encoded into its own staging slot and posted
         *without* waiting — the transport moves chunk k while this
@@ -1126,6 +1147,8 @@ class ParamClient:
         any retry pattern."""
         span = self._spans.op(what, peer=srank, side="client",
                               rank=self.rank)
+        if gated_ms is not None:
+            span.note(gated_ms=gated_ms)
         spans_ = self._chunk_spans[srank]
         stride = self._chunk_stride[srank]
         staging = (self._grad_wire if tag == tags.GRAD
@@ -1306,7 +1329,8 @@ class ParamClient:
         the assembly restarts whenever a newer version appears (a
         retried request re-served at the head), so the delivered vector
         is always a single committed version (§12.4).  FIFO channels
-        guarantee no stale-version chunk arrives after a newer one."""
+        guarantee no stale-version chunk arrives after a newer one.
+        Returns True once the slice is whole."""
         span = self._spans.op("PARAM", peer=srank, side="client",
                               rank=self.rank)
         out = self.param[shard.offset: shard.end]
@@ -1411,7 +1435,7 @@ class ParamClient:
                                 while not job.done():
                                     yield EXEC
                         span.end("ok")
-                        return
+                        return True
             except DeadlineExceeded as exc:
                 last = exc
         span.end("exhausted")
@@ -1453,6 +1477,41 @@ class ParamClient:
         if not residuals:
             return 0.0
         return float(np.sqrt(sum(float(np.dot(r, r)) for r in residuals)))
+
+    # -- the streamed round's gate and sink (optim/sync.py) ------------------
+
+    def stream_shards(
+        self,
+        staged: Callable[[Shard], bool],
+        landed: Callable[[Shard], None],
+    ) -> Optional[List[Shard]]:
+        """An optional extension of ``ParamClientAPI``
+        (optim/client_api.py; shells test for it by name): install a
+        shell's per-shard gate and sink and return the cut they will be
+        called with, one shard a server in channel order.  From
+        then on a GRAD op asks ``staged(shard)`` before it touches its
+        slice of ``grad``, yielding to the other channels until the
+        answer is True, and a PARAM op calls ``landed(shard)`` once its
+        slice of ``param`` is whole.  Both run on this client's thread
+        and must not block.  The wire does not change: each server still
+        sees its GRAD and then its PARAM request, in that order
+        (docs/PROTOCOL.md §1, pairing rules).  Under shardctl the ops
+        address shards that move between owners, not channels: nothing
+        is installed and None is returned, and the shell moves the
+        vector as one shard."""
+        if self._sc:
+            return None
+        self._staged, self._landed = staged, landed
+        return list(self.shards)
+
+    def _gate(self, shard: Shard):
+        """Yield until the shell has staged ``shard``; returns the
+        milliseconds that took by the recorder's clock (0.0 with obs
+        off), which the GRAD span opened next carries as ``gated_ms``."""
+        t0 = self._spans.clock()
+        while not self._staged(shard):
+            yield EXEC
+        return (self._spans.clock() - t0) * 1e3
 
     # -- public async API (reference pclient.lua:84-109) --------------------
 

@@ -892,7 +892,9 @@ def run_rank(
     else:
         trainer = MnistTrainer(cfg, pclient=pclient, data=data, rank=rank)
     log.info("worker with servers %s", sranks)
-    return {"role": "worker", **trainer.run()}
+    return {"role": "worker", **trainer.run(),
+            "rounds_streamed": getattr(trainer.optimizer,
+                                       "rounds_streamed", 0)}
 
 
 # -- process-mode launcher (the mpirun analog) -------------------------------
@@ -1126,7 +1128,8 @@ def _summarize(result: Dict[str, Any]) -> Dict[str, Any]:
             "best_test_err",
             "reads", "monotone", "busy_honored",
             "final_loss", "final_eval_loss", "tokens_per_s", "tokens_total",
-            "steps", "train_seconds", "first_step_seconds", "mosaic_calls",
+            "steps", "rounds_streamed", "train_seconds",
+            "first_step_seconds", "mosaic_calls",
             "moe_load_max_over_mean",
             "platform", "device_kind", "device_count", "device_ids",
             "chip_nodes"}

@@ -180,6 +180,120 @@ def test_obs_off_makes_no_span_and_reads_only_the_plain_timer(monkeypatch,
         obs.configure(enabled=None, reset=True)
 
 
+# -- the streamed round (PR 27): the same tree, what its phases mean ----------
+
+
+@pytest.mark.parametrize("nservers, streamed", [(1, 0), (2, 2)])
+def test_the_six_phases_tile_a_streamed_round(obs_on, monkeypatch, nservers,
+                                              streamed):
+    from mpit_tpu.optim import sync
+
+    monkeypatch.setattr(sync, "PIECE_BYTES", 10 * 4)  # several a shard
+    rec = obs_on
+    with gang(nservers, 1) as (_servers, (pc,)):
+        opt = RuleShell(quad, pc, su=1)
+        w = opt.start(jnp.zeros(SIZE))
+        for _ in range(3):
+            w, _loss = opt.step(w, TARGET)
+        opt.stop()
+    rounds = [s for s in rec.spans if s.name == "round"]
+    assert len(rounds) == 3 and opt.rounds_streamed == (3 if streamed else 0)
+    for span in rounds:
+        parts = phase_spans(span)
+        assert [p for p, _b, _e in parts] == [
+            "wait_backward", "d2h", "stage", "exchange", "h2d", "telemetry"]
+        assert parts[0][1] == span.t0 and parts[-1][2] == span.t1
+        assert all(a[2] == b[1] for a, b in zip(parts, parts[1:]))
+        assert span.args["shards_streamed"] == streamed
+        assert span.args["pieces"] == -(-SIZE // nservers // 10) * nservers
+        # the ops still begin inside the exchange, and carry the round
+        (exchange,) = [(b, e) for p, b, e in parts if p == "exchange"]
+        ops = [s for s in rec.spans if s.args.get("side") == "client"
+               and s.args.get("round") == span.args["round"]]
+        assert sorted(s.name for s in ops) == (
+            ["GRAD"] * nservers + ["PARAM"] * nservers)
+        assert all(exchange[0] <= s.t0 and s.t1 <= exchange[1] for s in ops)
+        assert all("gated_ms" in s.args for s in ops if s.name == "GRAD")
+    want = sum(e - b for s in rounds for p, b, e in phase_spans(s)
+               if p == "exchange")
+    assert opt.sync_seconds == pytest.approx(want) and want > 0
+
+
+def test_gated_ms_lies_outside_the_grad_span(obs_on):
+    """The wait for a shard's staging is measured before its GRAD span
+    opens: the span keeps meaning the wire and the server."""
+    rec = obs_on
+    hold = 0.25
+    with gang(2, 1) as (_servers, (pc,)):
+        opt = RuleShell(quad, pc, su=1)
+        w = opt.start(jnp.zeros(SIZE))
+        stream = opt._stream
+        second = stream.cut[1]
+        asked = []
+
+        def slow_gate(shard):
+            if shard.offset != second.offset:
+                return stream.staged(shard)
+            asked.append(time.monotonic())
+            return stream.staged(shard) and asked[-1] - asked[0] >= hold
+
+        pc.stream_shards(slow_gate, stream.landed)
+        w, _loss = opt.step(w, TARGET)
+        opt.stop()
+    grads = {s.args["peer"]: s for s in rec.spans
+             if s.name == "GRAD" and s.args["side"] == "client"}
+    held, free = grads[1], grads[0]
+    assert held.args["gated_ms"] >= 1e3 * hold
+    assert held.t0 >= asked[0] + hold          # it opened after the wait
+    assert held.t1 - held.t0 < hold / 2        # and does not contain it
+    assert free.args["gated_ms"] < 1e3 * hold / 2
+    # the round's exchange does contain it: the worker waited there
+    (span,) = [s for s in rec.spans if s.name == "round"]
+    assert span.phase_seconds("exchange") >= hold
+
+
+def test_obs_off_a_streamed_round_reads_the_clock_twice_and_fences_nothing(
+        monkeypatch):
+    import jax
+
+    obs.configure(enabled=False, reset=True)
+    try:
+        with gang(2, 1) as (_servers, (pc,)):
+            opt = RuleShell(quad, pc, su=1)
+            w = opt.start(jnp.zeros(SIZE))
+            for _ in range(2):  # compile everything
+                w, _loss = opt.step(w, TARGET)
+            assert opt._stream is not None and opt.rounds_streamed == 2
+            me = threading.current_thread()
+            reads, fences = [], []
+            for clock in ("monotonic", "monotonic_ns", "time",
+                          "perf_counter"):
+                real = getattr(time, clock)
+
+                def counted(real=real, clock=clock):
+                    if threading.current_thread() is me:  # not the servers
+                        reads.append(clock)
+                    return real()
+
+                monkeypatch.setattr(time, clock, counted)
+            real_fence = jax.block_until_ready
+            monkeypatch.setattr(
+                jax, "block_until_ready",
+                lambda x: fences.append(1) or real_fence(x))
+            before = opt.sync_seconds
+            for _ in range(2):
+                w, _loss = opt.step(w, TARGET)
+            monkeypatch.undo()
+            assert reads == ["monotonic"] * 4  # a pair a round
+            assert fences == []
+            assert opt._spans is obs.NULL_RECORDER
+            assert obs.get_recorder().spans == ()
+            assert opt.rounds_streamed == 4 and opt.sync_seconds > before
+            opt.stop()
+    finally:
+        obs.configure(enabled=None, reset=True)
+
+
 def test_client_and_server_spans_join_by_ordinal_unframed(obs_on):
     rec = obs_on
     rounds = 3

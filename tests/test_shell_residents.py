@@ -85,6 +85,55 @@ def test_at_su_2_the_accumulator_outlives_its_round():
     assert not opt.accum.is_deleted()
 
 
+def test_the_device_pieces_of_a_consumed_payload_are_gone_after_the_round(
+        monkeypatch):
+    """A streamed round cuts the payload into pieces on the device:
+    each is given up once it is on the host, and the payload once all
+    are."""
+    import threading
+
+    from mpit_tpu.comm.local import LocalRouter
+    from mpit_tpu.optim import sync
+    from mpit_tpu.ps import ParamClient, ParamServer
+
+    monkeypatch.setattr(sync, "PIECE_BYTES", 5 * 4)
+    cuts, parts, held = [], [], []
+    real_cut, real_paste = sync._cut, sync._paste
+    monkeypatch.setattr(
+        sync, "_cut",
+        lambda x, start, *, size: cuts.append(
+            real_cut(x, start, size=size)) or cuts[-1])
+    monkeypatch.setattr(
+        sync, "_paste",
+        lambda whole, piece, start: parts.append(piece) or real_paste(
+            whole, piece, start))
+    router = LocalRouter(3)
+    servers = [ParamServer(r, [2], router.endpoint(r)) for r in (0, 1)]
+    threads = [threading.Thread(target=s.start, daemon=True)
+               for s in servers]
+    for t in threads:
+        t.start()
+    try:
+        pc = ParamClient(2, [0, 1], router.endpoint(2), seed_servers=True)
+        opt = RuleShell(quad, pc, su=1)
+        opt._vgf = lambda w, t: held.append(quad(w, t)) or held[-1]
+        w = opt.start(jnp.zeros(SIZE))
+        w, _loss = opt.step(w, TARGET)
+        assert opt.rounds_streamed == 1
+        assert len(cuts) == len(parts) == len(opt._stream.pieces) == 8
+        assert all(piece.is_deleted() for piece in cuts)
+        assert held[0][1].is_deleted()  # the gradient itself
+        # plain add of the raw gradient, whole on the device again
+        np.testing.assert_allclose(w, -np.asarray(TARGET))
+        del parts[:]
+        opt.stop()
+    finally:
+        for s in servers:
+            s.live.stop()
+        for t in threads:
+            t.join(10)
+
+
 class Unreadable:
     """A statistic that fails the test if anything fetches it."""
 
