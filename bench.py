@@ -107,8 +107,8 @@ def bench_train() -> dict:
     # device_loop=1: the whole train-to-target runs as ONE lax.while_loop
     # device program (on-device shuffle + epoch scan + eval + early
     # exit), so time_to_target measures the device, not per-epoch host
-    # round trips — the default came from a July 2026 A/B
-    # (benchmarks/device_loop_ab.py) the ledger has not reproduced.  The
+    # round trips — the default came from a July 2026 A/B on a
+    # forced-host-device CPU that the ledger has no counterpart of.  The
     # steady-throughput leg is mode-independent (same compiled epoch
     # scan either way).  MPIT_BENCH_DEVICE_LOOP=0 restores the
     # host-loop measurement.

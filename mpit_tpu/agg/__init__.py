@@ -1,8 +1,8 @@
 """mpit_tpu.agg — hierarchical quantized aggregation under the PS model.
 
-BENCH_r09/BENCH_r15 pinned GRAD as wire-bound: once chunked streaming
-(§12) put the single-link path at the link floor, the next order of
-magnitude has to come from sending *fewer bytes upstream*.  This
+GRAD is wire-bound: once chunked streaming (§12) put the single-link
+path at the link floor, the next order of magnitude has to come from
+sending *fewer bytes upstream*.  This
 package embeds a collective pre-reduction stage under the parameter-
 server model (the MXNET-MPI direction, PAPERS.md 1802.06949): N
 gradients become one before the server ever sees them.

@@ -706,7 +706,7 @@ int64_t mt_api_version(void) { return 17001; }
 // -- worker-pool data plane --------------------------------------------------
 //
 // A persistent native thread pool so chunk encode/decode/XOR/fold runs off
-// the Python critical thread (the GIL cap recorded by BENCH_r15/r16).  Jobs
+// the Python critical thread (otherwise one interpreter lock caps it).  Jobs
 // are pure: owned input pointers -> owned output pointers, all regions
 // disjoint per job, per-block int8 EF state (the residual slice) carried in
 // the job.  Completion order therefore never influences byte content; the

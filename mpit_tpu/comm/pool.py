@@ -1,8 +1,8 @@
 """Worker-pool submission seam for the chunk data plane.
 
-The GIL cap recorded by BENCH_r15/r16: every per-chunk encode, decode,
-XOR delta and tree fold ran serially on the one Python thread, so chunk
-k's CPU work could never overlap chunk k+1's wire time.  This module is
+The GIL cap: every per-chunk encode, decode, XOR delta and tree fold
+ran serially on the one Python thread, so chunk k's CPU work could
+never overlap chunk k+1's wire time.  This module is
 the narrow seam between the protocol code and the native worker pool in
 ``comm/native/transport.cpp`` (mt_pool_*): call sites submit pure kernel
 jobs and collect them in submission order; the pool runs them GIL-free
@@ -448,14 +448,21 @@ def current_pool() -> Optional[WorkerPool]:
     return _GLOBAL
 
 
-def configure(threads: Optional[int]) -> WorkerPool:
-    """Replace the process-wide pool (tests, bench A/B legs).  Closes
-    the previous one so its workers never leak across configurations."""
+def close() -> None:
+    """Close the process-wide pool and leave none: the next
+    :func:`get_pool` builds one."""
     global _GLOBAL
     with _GLOBAL_MU:
         old, _GLOBAL = _GLOBAL, None
     if old is not None:
         old.close()
+
+
+def configure(threads: Optional[int]) -> WorkerPool:
+    """Replace the process-wide pool (tests).  Closes the previous one
+    so its workers never leak across configurations."""
+    global _GLOBAL
+    close()
     with _GLOBAL_MU:
         _GLOBAL = WorkerPool(threads)
         _register_status(_GLOBAL)

@@ -10,8 +10,8 @@ three properties the MNIST loader cannot give it:
   (``np.random.Philox(key=[seed, step])``), so any process that knows
   the seed can materialize step ``k`` without replaying steps
   ``0..k-1``.  This is what makes supervisor restarts and the
-  fault-free bitwise-envelope gates (tools/lm_smoke.py,
-  ``MPIT_BENCH_LM``) possible: a restarted worker resumes mid-stream
+  fault-free bitwise-envelope gates (tools/lm_smoke.py) possible: a
+  restarted worker resumes mid-stream
   and sees the *identical* batch the dead incarnation would have.
 - **learnable structure**: documents are modular arithmetic walks —
   ``tok[i] = (start + i * stride) % 256`` with the stride drawn from a

@@ -317,7 +317,7 @@ class Scenario:
         OPERATIONS.md §2.3).  ``soak`` crosses >= 5 traffic shapes;
         ``smoke`` is the CI short form (one shape change + one
         preemption wave, then a quiet tail so the scale-down shows);
-        ``bench`` is the ptest A/B's bursty leg."""
+        ``bench`` is one bursty leg."""
         if name == "soak":
             spec = (
                 f"seed={seed},writers=2,readers=3;"

@@ -27,8 +27,7 @@ the attribution plane that makes those answerable:
   wall × threads), the per-phase on-CPU vs off-CPU split (non-negative
   and sums-to-wall by the same clamped construction as the causal
   decomposition), the encode-while-wire fraction of chunked streams,
-  and a top-tasks-by-CPU table.  ptest attaches the same figures to
-  recorded boundaries under ``MPIT_BENCH_PROFILE=1`` (BENCH_r17).
+  and a top-tasks-by-CPU table.
 
 Enablement: ``MPIT_OBS_PROFILE`` truthy (which implies obs, like a
 trace request does), or :func:`configure` for tests.  Profiling stays
@@ -209,8 +208,8 @@ def get_profiler():
 
 
 def configure(enabled: Optional[bool] = None, reset: bool = False) -> None:
-    """Programmatic profiling enablement (tests, ptest's in-process agg
-    legs).  ``enabled=None`` returns control to the environment."""
+    """Programmatic profiling enablement (tests).  ``enabled=None``
+    returns control to the environment."""
     global _FORCED, _GLOBAL
     _FORCED = enabled
     if reset:

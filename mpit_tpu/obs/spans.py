@@ -49,6 +49,7 @@ from __future__ import annotations
 import queue
 import threading
 import time
+import weakref
 from typing import Any, Dict, List, Optional, Tuple
 
 from mpit_tpu.obs import clock as _clock
@@ -174,9 +175,12 @@ class _ReadyWaiter(threading.Thread):
     """Ends spans when the device result they wait for is ready, so that
     the role thread that dispatched the work never blocks on it.  Exists
     only while recording (:meth:`SpanRecorder.end_when_ready` starts it
-    on first use).  Results are waited for in the order handed over,
-    which is the order the backend runs them in: the ``exec`` mark of an
-    item is stamped when the item before it became ready."""
+    on first use) and ends with the recorder that started it
+    (:meth:`SpanRecorder.close`, or the recorder's collection: a None
+    behind what was handed over).  Results are waited for in the order
+    handed over, which is the order the backend runs them in: the
+    ``exec`` mark of an item is stamped when the item before it became
+    ready."""
 
     def __init__(self) -> None:
         super().__init__(name="obs-ready-waiter", daemon=True)
@@ -186,8 +190,8 @@ class _ReadyWaiter(threading.Thread):
     def run(self) -> None:
         import jax
 
-        while True:
-            span, result = self.items.get()
+        while (item := self.items.get()) is not None:
+            span, result = item
             span.mark("exec")
             try:
                 jax.block_until_ready(result)
@@ -196,6 +200,7 @@ class _ReadyWaiter(threading.Thread):
                 outcome = "lost"
             span.end(outcome)
             self.done += 1
+            del item, span, result  # a span holds its recorder
 
 
 class SpanRecorder:
@@ -232,6 +237,7 @@ class SpanRecorder:
         #: the sync round this thread is in (``round``), if any
         self._ctx = threading.local()
         self._waiter: Optional[_ReadyWaiter] = None
+        self._end_waiter: Optional[weakref.finalize] = None
         self._handed = 0  # spans given to the waiter
 
     def _begin(self, span: OpSpan) -> OpSpan:
@@ -263,9 +269,17 @@ class SpanRecorder:
         with self._hist_lock:
             if self._waiter is None:
                 self._waiter = _ReadyWaiter()
+                self._end_waiter = weakref.finalize(
+                    self, self._waiter.items.put, None)
                 self._waiter.start()
             self._handed += 1
         self._waiter.items.put((span, result))
+
+    def close(self) -> None:
+        """End the waiter thread once what was handed over has drained
+        (a recorder that is replaced: :func:`reset`)."""
+        if self._end_waiter is not None:
+            self._end_waiter()
 
     def clock(self) -> float:
         """The clock the spans are stamped with, for a wait that has to
@@ -414,6 +428,9 @@ def get_recorder():
 
 
 def reset() -> None:
-    """Drop the global recorder (tests; called by obs.configure)."""
+    """Drop the global recorder (tests; called by obs.configure) and
+    end its waiter thread."""
     global _GLOBAL
-    _GLOBAL = None
+    old, _GLOBAL = _GLOBAL, None
+    if old is not None:
+        old.close()

@@ -66,8 +66,8 @@ def _fa_compiler_params(vmem_mb_auto: float = 0.0):
 
     ``MPIT_FA_VMEM_MB`` raises the scoped-VMEM budget from the 16 MB
     default — required to even compile block combos whose f32 score
-    tile exceeds ~4 MB (e.g. block_k=2048 sweeps,
-    benchmarks/flash_block_sweep.py); the 100 MB-budget sweep data in
+    tile exceeds ~4 MB (e.g. block_k=2048 sweeps); the 100 MB-budget
+    sweep data in
     docs/tpu_compile_notes.md §2 shows the raise itself is perf-neutral
     for the default tiles.  ``vmem_mb_auto`` is the caller's computed
     floor for configs that cannot compile under the stock budget (the

@@ -67,6 +67,7 @@ from __future__ import annotations
 import queue
 import threading
 import time
+import weakref
 from collections import deque
 from functools import partial
 from typing import Any, Dict, List, NamedTuple, Optional, Set, Tuple
@@ -218,6 +219,15 @@ class _Copies:
                 self.w = _paste(self.w, part, lo)
 
 
+def _serve(rounds: "queue.SimpleQueue[Optional[_Copies]]") -> None:
+    """The stream's thread: each round's copies in turn, until a None.
+    It holds the queue and, for the length of a round, that round's
+    copies; never the stream, which can therefore be collected."""
+    while (copies := rounds.get()) is not None:
+        copies.run()
+        del copies  # or the last round's would hold the stream here
+
+
 class ShardStream:
     """A shell's side of the round: the cut it moves by, the gate and
     the sink it offers the client once (:func:`attach`), and the one
@@ -225,7 +235,9 @@ class ShardStream:
     :meth:`close` (a thread a round would leave each round's host
     pieces behind in an allocator arena of its own).  Between rounds the
     gate is open and the sink does nothing, so ops issued outside
-    :func:`push_pull` run as before."""
+    :func:`push_pull` run as before.  The thread ends with the stream:
+    at :meth:`close`, or when the last owner (the shell, and a client
+    that took the gate and the sink) lets go of it unclosed."""
 
     def __init__(self, grad_host: np.ndarray, w_host: np.ndarray):
         self.grad_host, self.w_host = grad_host, w_host
@@ -239,6 +251,8 @@ class ShardStream:
         self._rounds: "queue.SimpleQueue[Optional[_Copies]]" = (
             queue.SimpleQueue())
         self._thread: Optional[threading.Thread] = None
+        self._serve = partial(_serve, self._rounds)  # the thread's target
+        weakref.finalize(self, self._rounds.put, None)
 
     def bind(self, cut: List[Any]) -> None:
         """Take the cut; a piece never crosses a shard."""
@@ -270,10 +284,6 @@ class ShardStream:
             worker.sink(self._index[shard.offset])
 
     # -- the thread, and one round -------------------------------------------
-
-    def _serve(self) -> None:
-        while (copies := self._rounds.get()) is not None:
-            copies.run()
 
     def close(self) -> None:
         """End the thread (a shell's ``stop``); a later round starts

@@ -111,8 +111,9 @@ def _device_loop_train(*, cfg, trainer, state, eval_params, err_fn, mesh,
 
     bench.py defaults to device_loop=1 for the headline
     time_to_target_s (MPIT_BENCH_DEVICE_LOOP=0 restores the host loop);
-    that default came from a July 2026 A/B (benchmarks/
-    device_loop_ab.py) the ledger has not reproduced.
+    that default came from a July 2026 A/B on a forced-host-device
+    CPU whose script and record are gone; the ledger has no
+    counterpart.
 
     Trade-offs (why the host loop remains the general default): the shuffle is
     jax.random rather than the host path's numpy rng (equally random,

@@ -326,6 +326,11 @@ class _Gang:
         assert not self.sth.is_alive(), "server never stopped"
 
     def close(self):
+        """Whatever the test did (it may have failed half way), nothing
+        of the gang serves on after it."""
+        self.server.live.stop()
+        for cell in self.cells.values():
+            cell.shutdown()
         for r, t in self.tr.items():
             t.close()
 
@@ -507,6 +512,12 @@ class TestFabric:
             gang.client.stop()
         finally:
             gang.close()
+        # A killed process has no thread; the stand-in has one that
+        # polls a torn transport for ever, and the server waits out the
+        # victim's lease (ttl 10 s): ``close`` ends both.
+        for thread in (*gang.cth.values(), gang.sth):
+            thread.join(60)
+            assert not thread.is_alive(), "the gang outlived the test"
 
     def test_goodbye_retire_reroutes_readers(self):
         """Graceful cell retirement (the autoscale drain verb): readers

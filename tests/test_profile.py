@@ -28,6 +28,7 @@ import pytest
 
 from mpit_tpu import obs
 from mpit_tpu.aio import Scheduler
+from mpit_tpu.comm import pool as comm_pool
 from mpit_tpu.obs import causal as obs_causal
 from mpit_tpu.obs import flight as obs_flight
 from mpit_tpu.obs import metrics as obs_metrics
@@ -48,6 +49,15 @@ def prof_on():
         yield obs_profile.get_profiler()
     finally:
         obs.configure(enabled=None, reset=True)
+
+
+@pytest.fixture
+def no_pool():
+    """The sampler's tracks depend on whether the process has a pool
+    (any client with a codec builds one): ask for none."""
+    comm_pool.close()
+    yield
+    comm_pool.close()
 
 
 def burn_task(rounds=40, width=4000):
@@ -183,7 +193,25 @@ def _sampled_trace(tmp_path, prof, rank, n=4):
 
 
 class TestCounterTracks:
-    def test_round_trip_validates(self, prof_on, tmp_path):
+    def test_the_pools_tracks_come_with_it_and_go_at_its_close(
+            self, prof_on, no_pool):
+        prof = prof_on
+        prof._interval = 0.0
+        pool = comm_pool.configure(2)
+        for i in range(2):  # utilization is a difference of two samples
+            prof.sample(i)
+        assert {track for _, track, _ in prof.samples} == (
+            {"sched_runq", "task_cpu"} if pool.serial
+            else set(obs_profile.TRACKS))
+        comm_pool.close()
+        assert comm_pool.current_pool() is None
+        prof.samples.clear()
+        prof.sample(2)
+        assert {track for _, track, _ in prof.samples} == {
+            "sched_runq", "task_cpu"}
+        assert comm_pool.current_pool() is None  # observed, not built
+
+    def test_round_trip_validates(self, prof_on, no_pool, tmp_path):
         path = _sampled_trace(tmp_path, prof_on, rank=0)
         stats = obs_trace.validate_trace(path)
         assert stats["counters"] >= 8  # 2 tracks x 4 samples

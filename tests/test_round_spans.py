@@ -6,6 +6,7 @@ fixture.  In-process thread gangs and constructed fixtures only.
 """
 
 import contextlib
+import gc
 import json
 import threading
 import time
@@ -407,6 +408,49 @@ def test_apply_exec_ends_after_the_ack_and_does_not_delay_it(obs_on, delay):
     assert exec_span.phase_seconds("exec") >= delay
     assert exec_span.args["grad_n"] == grad_server.args["n"] == 0
     assert exec_span.outcome == "ready"
+
+
+def waiters():
+    return {t for t in threading.enumerate()
+            if t.name == "obs-ready-waiter"}
+
+
+def test_a_replaced_recorder_ends_its_waiter_once_it_has_drained():
+    """``while True: items.get()`` left one thread for every recorder
+    ever made."""
+    before, recorders = waiters(), []
+    try:
+        for _ in range(10):
+            obs.configure(enabled=True, reset=True)
+            rec = obs.get_recorder()
+            rec.end_when_ready(rec.op("apply_exec", side="server"),
+                               SlowResult(0, 0.05))
+            recorders.append(rec)
+        mine = waiters() - before
+        for rec in recorders:  # what was handed over has drained
+            assert rec.drain(timeout=10)
+            assert [s.outcome for s in rec.spans] == ["ready"]
+        deadline = time.monotonic() + 10
+        while len(waiters() - before) > 1 and time.monotonic() < deadline:
+            time.sleep(0.01)
+        assert len(waiters() - before) <= 1  # the recorder that stands
+    finally:
+        obs.configure(enabled=None, reset=True)
+    for thread in mine:
+        thread.join(10)
+    assert not waiters() - before
+
+
+def test_a_dropped_recorder_takes_its_waiter_with_it():
+    before = waiters()
+    rec = obs.SpanRecorder()  # nobody's global, never closed
+    rec.end_when_ready(rec.op("apply_exec", side="server"), jnp.ones(3))
+    assert rec.drain(timeout=10)
+    (thread,) = waiters() - before
+    del rec
+    gc.collect()  # its spans hold it
+    thread.join(10)
+    assert not thread.is_alive()
 
 
 # -- a hand-made profiler trace and the benchmark's readers -------------------

@@ -10,9 +10,11 @@ a time limit of its own.
 """
 
 import contextlib
+import gc
 import signal
 import threading
 import time
+import weakref
 
 import jax.numpy as jnp
 import numpy as np
@@ -368,6 +370,42 @@ def test_a_simulator_is_one_shard_in_pieces_that_the_shell_sinks(obs_on):
     assert len(stream_threads()) == 1  # one for all rounds, until stop
     opt.stop()
     assert not stream_threads()
+
+
+def test_a_shell_dropped_unstopped_takes_its_thread_and_mirrors_with_it():
+    """What made the count depend on which file ran before this one:
+    the thread used to hold the stream, and the stream the mirrors, for
+    the life of the process."""
+    opt = RuleShell(quad, Simulator(), su=1)
+    w = opt.start(jnp.zeros(SIZE) + 0.25)
+    w, _loss = opt.step(w, TARGET)
+    (thread,) = stream_threads()
+    mirrors = [weakref.ref(opt.grad_host), weakref.ref(opt.w_host)]
+    del opt  # no stop
+    gc.collect()
+    thread.join(10)
+    assert not thread.is_alive() and not stream_threads()
+    assert [m() for m in mirrors] == [None, None]
+
+
+def test_a_client_that_took_the_hooks_owns_the_stream_with_the_shell():
+    with gang(2) as (_servers, pc):
+        opt = RuleShell(quad, pc, su=1)
+        w = opt.start(jnp.zeros(SIZE) + 0.25)
+        w, _loss = opt.step(w, TARGET)
+        (thread,) = stream_threads()
+        stream = weakref.ref(opt._stream)
+        del opt
+        gc.collect()
+        # the gate and the sink are the stream's: it serves on
+        assert stream() is not None and thread.is_alive()
+        pc.async_send_grad()  # between rounds the gate is open
+        pc.wait()
+        pc.stop()
+    del pc, _servers
+    gc.collect()
+    thread.join(10)
+    assert stream() is None and not stream_threads()
 
 
 def test_one_server_goes_in_pieces_and_streams_nothing(obs_on):

@@ -17,7 +17,7 @@ on the in-process router, twice:
    controller and **nobody calling /scale**.
 
 Every serving member runs under the **member-capacity throttle**
-(BENCH_r11's model): each shard op blocks its rank for
+(a fixed reply capacity a member): each shard op blocks its rank for
 ``shard_bytes / member_mbs`` wall-seconds, so a member is a
 fixed-capacity resource, reader pressure shows up as queueing in the
 pooled ``mpit_ps_op_seconds`` p99, and adding/draining members moves
@@ -109,7 +109,7 @@ FT_KW = dict(op_deadline_s=10.0, max_retries=10,
 
 
 def _throttle_member(server, rank, mbs, factors):
-    """BENCH_r11's member-capacity model at the per-shard-op seam: the
+    """The member-capacity model at the per-shard-op seam: the
     slot busy-timer wraps dedup->apply->ack (GRAD) and snapshot->send
     (PARAM), so one blocking sleep per op serializes this rank's
     service exactly the way a fixed-capacity member would.  ``factors``
