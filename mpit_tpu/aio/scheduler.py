@@ -375,36 +375,63 @@ def aio_recv(
     out: Optional[Any] = None,
     deadline: Optional[float] = None,
     abort: Optional[Callable[[], bool]] = None,
+    request: Optional[Generator[str, None, Any]] = None,
 ) -> Generator[str, None, Any]:
-    """Nonblocking receive: probe until a matching message exists, then post
-    the receive and poll it to completion.  Returns the payload.
+    """Nonblocking receive.  Returns the payload, or None if it gave up.
 
-    Mirrors reference init.lua:67-102 (Iprobe poll -> Irecv -> Test poll,
-    cancel-on-shutdown).  ``out``, when given, is a preallocated buffer the
-    transport fills (the zero-copy analog of receiving into a tensor shard).
+    With ``out``, a preallocated buffer of the message's size, the receive
+    is posted at once and polled to completion: a transport that can lands
+    the message in ``out`` as it arrives (``comm/shm.py``), with no pass
+    over it afterwards.  Without ``out`` (acks, headers, anything whose
+    size is not known) it is the reference's idiom, init.lua:67-102:
+    Iprobe poll -> Irecv -> Test poll.
+
+    ``request`` is the send that asks the peer for this message (an
+    ``aio_send``, unstarted).  It runs here, once the receive is posted:
+    an answer that is on its way before its receive is known is
+    assembled elsewhere and copied, and a quick peer beats a slow
+    requester often enough to matter (the round's PARAM did in one pull
+    of ten when the request went first: PERF.md section 6, PR 29).
 
     ``deadline`` (absolute monotonic seconds) raises
-    :class:`DeadlineExceeded` from the probe loop if no matching message
-    arrives in time.  ``abort`` returning True gives up and returns None
-    (lease eviction / generation change).  Both are checked only while
-    *probing*: once a matching message exists the recv is posted and
-    drained to completion — cancelling a posted receive could strand or
-    destroy a message another service generation still needs.
+    :class:`DeadlineExceeded` if the message is not whole in time;
+    ``abort`` returning True, or the live flag dropping, gives up and
+    returns None (lease eviction / generation change / shutdown).  All
+    three are checked until the message is whole.  Giving up cancels the
+    posted receive — also when the generator is closed or dropped — and
+    every transport keeps a message whole for the next receiver when a
+    receive it had begun to fill is cancelled, so no service generation
+    strands or tears a message its successor still needs.
     """
-    while not transport.iprobe(src, tag):
+
+    def gave_up() -> bool:
         if live is not None and not live.io:
-            return None
+            return True
         if abort is not None and abort():
-            return None
+            return True
         if deadline is not None and time.monotonic() > deadline:
             raise DeadlineExceeded("recv", src, tag, time.monotonic() - deadline)
-        yield EXEC
+        return False
+
+    if out is None:
+        yield from request or ()
+        while not transport.iprobe(src, tag):
+            if gave_up():
+                return None
+            yield EXEC
     handle = transport.irecv(src, tag, out=out)
-    while not transport.test(handle):
-        if live is not None and not live.io:
+    whole = False
+    try:
+        if out is not None:
+            yield from request or ()
+        while not transport.test(handle):
+            if gave_up():
+                return None
+            yield EXEC
+        whole = True
+    finally:
+        if not whole:
             transport.cancel(handle)
-            return None
-        yield EXEC
     payload = transport.payload(handle)
     if cb is not None:
         cb(payload)

@@ -51,7 +51,7 @@ def _as_pointer(obj):
 #: lockstep with transport.cpp (see kApiVersion comment there) whenever
 #: the exported C surface changes, so a stale .so fails loudly with a
 #: rebuild message instead of a confusing missing-symbol AttributeError.
-MT_API_VERSION = 17001
+MT_API_VERSION = 17002
 
 
 class NativeTransportLib:
@@ -125,6 +125,8 @@ class NativeTransportLib:
         self._lib.mt_recv_size.argtypes = [ctypes.c_void_p, ctypes.c_int64]
         self._lib.mt_release.restype = None
         self._lib.mt_release.argtypes = [ctypes.c_void_p, ctypes.c_int64]
+        self._lib.mt_rx_bytes.restype = ctypes.c_uint64
+        self._lib.mt_rx_bytes.argtypes = [ctypes.c_void_p, ctypes.c_int32]
         self._lib.mt_test.restype = ctypes.c_int32
         self._lib.mt_test.argtypes = [ctypes.c_void_p, ctypes.c_int64]
         self._lib.mt_time.restype = ctypes.c_double
@@ -256,6 +258,10 @@ class NativeTransportLib:
     def mt_release(self, ctx, handle):
         """Drop a completed handle's bookkeeping."""
         return self._lib.mt_release(ctx, handle)
+
+    def mt_rx_bytes(self, ctx, which):
+        """Bytes of the messages received whole so far: which == 0, those that landed in the buffer of a receive posted before they arrived; 1, those assembled in a buffer of the transport's own."""
+        return self._lib.mt_rx_bytes(ctx, which)
 
     def mt_test(self, ctx, handle):
         """Advance progress; 1 complete, 0 pending, -1 unknown/cancelled, -2 size mismatch."""

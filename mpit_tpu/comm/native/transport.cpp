@@ -16,6 +16,20 @@
 //  * Message assembly, (rank, tag) matching, and handle state live in
 //    process-local memory — the ring is purely a mailbox, so a receiver
 //    polling one tag never head-of-line-blocks other tags.
+//  * A receive lands where it was asked for when it was posted before
+//    its message opened: the drain that reads a message's first chunk
+//    binds it to the oldest receive posted for (src, tag), if that
+//    receive's buffer is exactly the message's size and nothing is queued
+//    for the channel ahead of it, and every chunk then goes from the ring
+//    into that buffer (two copies end to end: sender into ring, ring into
+//    the caller's memory).  Everything else is assembled in a buffer of
+//    the transport's own and copied out by the mt_test that takes it
+//    (three copies): no receive posted yet, a receive posted while the
+//    message was already arriving, a buffer of another size (mt_test then
+//    reports -2 and the message stays), a message queued ahead.
+//    Cancelling a bound receive moves what has landed into an assembly
+//    buffer, where the rest follows: the message stays whole for the next
+//    receive and the cancelled buffer is not written again.
 //  * Per-destination FIFO send queues give MPI-style non-overtaking order
 //    between any (src, dst) pair.
 //  * All progress happens inside mt_iprobe/mt_test calls from the caller's
@@ -98,6 +112,7 @@ struct Partial {
   uint64_t filled = 0;  // bytes assembled so far (chunks arrive in order)
   uint32_t seen = 0;
   int32_t tag = 0;
+  int64_t bound = 0;  // receive whose buffer the chunks land in; 0: buf
   Buffer buf;
 };
 
@@ -126,6 +141,8 @@ struct RecvOp {
   uint8_t* out = nullptr;
   uint64_t cap = 0;
   uint64_t size = 0;
+  uint64_t msg_id = 0;  // the sender's message landing in `out`, once bound
+  bool bound = false;
   bool done = false;
   bool cancelled = false;
   bool size_mismatch = false;
@@ -146,6 +163,10 @@ struct Ctx {
   std::vector<Buffer> buf_cache;  // recycled big message buffers
   int64_t next_handle = 1;
   uint64_t next_msg_id = 1;
+  // Bytes of the messages that became whole in a caller's buffer, and in
+  // an assembly buffer (mt_rx_bytes).
+  uint64_t rx_direct_bytes = 0;
+  uint64_t rx_assembled_bytes = 0;
   std::string last_error;
 };
 
@@ -281,10 +302,47 @@ void lock_ring(RingHeader* hdr) {
   }
 }
 
-// Drain the own inbox: move complete chunks into partial/ready maps.
-// Payload bytes go straight from the ring into their final message
-// buffer — one copy, into uninitialized storage (the old vector path
-// value-initialized every byte and copied multi-chunk payloads twice).
+// The receive a message of `total` bytes from (src, tag) that opens now
+// lands in: the oldest one posted and not yet matched, if its buffer is
+// exactly that size and no whole message waits on the channel ahead of it
+// (a half-assembled one cannot: a sender's messages arrive one at a time).
+// Handles count up, so the map's order is the order of posting.
+RecvOp* posted_recv(Ctx* ctx, int src, int tag, uint64_t total,
+                    int64_t* handle) {
+  auto box = ctx->ready.find({src, tag});
+  if (box != ctx->ready.end() && !box->second.empty()) return nullptr;
+  for (auto& [h, op] : ctx->recvs) {
+    if (op.src != src || op.tag != tag || op.bound || op.done) continue;
+    if (op.cap != total) return nullptr;
+    *handle = h;
+    return &op;
+  }
+  return nullptr;
+}
+
+// A sender places one message at a time, so the first chunk of a new one
+// says that whatever it left unfinished (a send cancelled part-way, a
+// peer that died and came back) will never be finished: a receive bound
+// to it goes back to waiting, an assembly buffer is recycled.
+void abandon_partials(Ctx* ctx, int src) {
+  auto it = ctx->partial.lower_bound({src, 0});
+  while (it != ctx->partial.end() && it->first.first == src) {
+    Partial& part = it->second;
+    if (part.bound != 0) {
+      RecvOp& op = ctx->recvs.at(part.bound);
+      op.bound = false;
+      op.msg_id = 0;
+    } else {
+      recycle_buffer(ctx, std::move(part.buf));
+    }
+    it = ctx->partial.erase(it);
+  }
+}
+
+// Drain the own inbox.  Payload bytes go from the ring into the buffer of
+// the receive the message is bound to, or else into its assembly buffer —
+// one copy either way, into uninitialized storage; an assembled message
+// pays a second one when mt_test hands it over.
 void drain_inbox(Ctx* ctx) {
   Ring& ring = ctx->own;
   lock_ring(ring.hdr);
@@ -294,25 +352,58 @@ void drain_inbox(Ctx* ctx) {
     ChunkHeader ch;
     circ_read(ring, tail, &ch, sizeof(ch));
     tail += sizeof(ch);
+    // A first chunk opens a message: it lands in the receive posted for
+    // it, if there is one, and in an assembly buffer otherwise.
+    RecvOp* op = nullptr;
+    int64_t handle = 0;
+    if (ch.chunk_idx == 0) {
+      abandon_partials(ctx, ch.src);
+      op = posted_recv(ctx, ch.src, ch.tag, ch.total_bytes, &handle);
+    }
     if (ch.chunk_bytes == ch.total_bytes) {  // complete in one chunk
-      Buffer buf = alloc_buffer(ctx, ch.total_bytes);
-      if (ch.chunk_bytes > 0) circ_read(ring, tail, buf.data.get(), ch.chunk_bytes);
-      ctx->ready[{ch.src, ch.tag}].push_back(Message{std::move(buf)});
+      if (op != nullptr) {
+        if (ch.chunk_bytes > 0) circ_read(ring, tail, op->out, ch.chunk_bytes);
+        op->size = ch.total_bytes;
+        op->bound = true;
+        op->done = true;
+        ctx->rx_direct_bytes += ch.total_bytes;
+      } else {
+        Buffer buf = alloc_buffer(ctx, ch.total_bytes);
+        if (ch.chunk_bytes > 0) circ_read(ring, tail, buf.data.get(), ch.chunk_bytes);
+        ctx->ready[{ch.src, ch.tag}].push_back(Message{std::move(buf)});
+        ctx->rx_assembled_bytes += ch.total_bytes;
+      }
     } else {
       auto key = std::make_pair(ch.src, ch.msg_id);
       Partial& part = ctx->partial[key];
       if (part.seen == 0) {
         part.total = ch.total_bytes;
         part.tag = ch.tag;
-        part.buf = alloc_buffer(ctx, ch.total_bytes);
+        if (op != nullptr) {
+          op->bound = true;
+          op->msg_id = ch.msg_id;
+          part.bound = handle;
+        } else {
+          part.buf = alloc_buffer(ctx, ch.total_bytes);
+        }
+      } else if (part.bound != 0) {
+        op = &ctx->recvs.at(part.bound);
       }
+      uint8_t* dst = op != nullptr ? op->out : part.buf.data.get();
       uint64_t n = ch.chunk_bytes;  // clamp defensively; completion is byte-based
       if (part.filled + n > part.total) n = part.total - part.filled;
-      if (n > 0) circ_read(ring, tail, part.buf.data.get() + part.filled, n);
+      if (n > 0) circ_read(ring, tail, dst + part.filled, n);
       part.filled += ch.chunk_bytes;
       part.seen++;
       if (part.filled >= part.total) {
-        ctx->ready[{ch.src, part.tag}].push_back(Message{std::move(part.buf)});
+        if (op != nullptr) {
+          op->size = part.total;
+          op->done = true;
+          ctx->rx_direct_bytes += part.total;
+        } else {
+          ctx->ready[{ch.src, part.tag}].push_back(Message{std::move(part.buf)});
+          ctx->rx_assembled_bytes += part.total;
+        }
         ctx->partial.erase(key);
       }
     }
@@ -320,6 +411,29 @@ void drain_inbox(Ctx* ctx) {
   }
   ring.hdr->tail = tail;
   pthread_mutex_unlock(&ring.hdr->mutex);
+}
+
+// Take a receive off the books.  One that a message is bound to hands the
+// message back first: what has landed in its buffer is copied into an
+// assembly buffer, where the rest of the chunks follow (or, if the message
+// is whole and was never collected, to the head of its channel's queue),
+// so the next receive finds the message whole and nothing writes to this
+// one's buffer again.
+void drop_recv(Ctx* ctx, std::map<int64_t, RecvOp>::iterator it) {
+  RecvOp& op = it->second;
+  if (op.bound) {
+    Buffer buf = alloc_buffer(ctx, op.cap);
+    if (op.done) {
+      if (op.cap > 0) std::memcpy(buf.data.get(), op.out, op.cap);
+      ctx->ready[{op.src, op.tag}].push_front(Message{std::move(buf)});
+    } else {
+      Partial& part = ctx->partial.at({op.src, op.msg_id});
+      if (part.filled > 0) std::memcpy(buf.data.get(), op.out, part.filled);
+      part.buf = std::move(buf);
+      part.bound = 0;
+    }
+  }
+  ctx->recvs.erase(it);
 }
 
 // Try to place more chunks of the front send op for each destination.
@@ -503,6 +617,7 @@ int mt_test(void* vctx, int64_t handle) {
     RecvOp& op = rit->second;
     if (op.cancelled) return -1;
     if (op.done) return 1;
+    if (op.bound) return 0;  // its message is landing in op.out
     auto box = ctx->ready.find({op.src, op.tag});
     if (box == ctx->ready.end() || box->second.empty()) return 0;
     Message& msg = box->second.front();
@@ -540,13 +655,22 @@ void mt_cancel(void* vctx, int64_t handle) {
     return;
   }
   auto rit = ctx->recvs.find(handle);
-  if (rit != ctx->recvs.end()) ctx->recvs.erase(rit);
+  if (rit != ctx->recvs.end()) drop_recv(ctx, rit);
 }
 
+// Forget a handle whose completion the caller has seen.
 void mt_release(void* vctx, int64_t handle) {
   auto* ctx = static_cast<Ctx*>(vctx);
   ctx->recvs.erase(handle);
   ctx->sends.erase(handle);
+}
+
+// Bytes of the messages received whole so far: which == 0, those that
+// landed in the buffer of a receive posted before they arrived; 1, those
+// assembled in a buffer of the transport's own.
+uint64_t mt_rx_bytes(void* vctx, int32_t which) {
+  auto* ctx = static_cast<Ctx*>(vctx);
+  return which == 0 ? ctx->rx_direct_bytes : ctx->rx_assembled_bytes;
 }
 
 // Monotonic wall clock in seconds (the MPI_Wtime analog,
@@ -699,7 +823,7 @@ void mt_fold_f32(const void* vown, const void* vptrs, int32_t nchildren,
 // generated _bindings.py refuses a stale .so (loud rebuild message)
 // instead of failing with a confusing missing-symbol AttributeError.
 // Keep in sync with MT_API_VERSION in gen_bindings.py.
-int64_t mt_api_version(void) { return 17001; }
+int64_t mt_api_version(void) { return 17002; }
 
 }  // extern "C"
 

@@ -9,10 +9,23 @@ the asynchronous one-sided PS semantics XLA collectives can't express
 (SURVEY.md section 7 "hard parts").
 
 Zero-copy discipline: sends pass the numpy buffer's raw pointer to C and
-the Handle holds the array reference until completion; receives land
-directly in the caller's buffer.  Completed native handles are freed
-test-once style (like MPI requests); the Python Handle caches completion
-so repeated ``test`` stays idempotent.
+the Handle holds the array reference until completion.  A receive posted
+with a buffer *before* its message's first chunk is drained lands in that
+buffer straight from the ring, chunk by chunk, from whichever call makes
+progress (any ``test``, ``iprobe`` or ``isend`` of this endpoint): the
+buffer belongs to the transport from ``irecv`` until ``test`` is true or
+``cancel`` returns, and the endpoint keeps it alive that long.  Every
+other receive is assembled in a buffer of the library's own and copied
+out by the ``test`` that takes it: one posted after a probe (``out``
+absent, the chunked client, the serving tier), one posted while its
+message was already arriving, one behind a message queued on its channel.
+A buffer of the wrong size raises the size mismatch and the message stays.
+``cancel`` of a receive whose message has begun to land moves what has
+landed into an assembly buffer, the rest follows it there, and the next
+receive gets the message whole; the cancelled buffer is not written again.
+``rx_path_bytes`` says how many bytes went which way.  Completed native
+handles are freed test-once style (like MPI requests); the Python Handle
+caches completion so repeated ``test`` stays idempotent.
 """
 
 from __future__ import annotations
@@ -69,6 +82,19 @@ class ShmTransport(Transport):
         self._m_rx_bytes = [_reg.counter("mpit_shm_rx_bytes_total",
                                          rank=rank, peer=r)
                             for r in range(nranks)]
+        # Which way the received bytes went: the native side counts, and
+        # with obs on the counters follow it whenever a receive completes.
+        self._m_rx_paths = {
+            "rx_direct_bytes": _reg.counter(
+                "mpit_shm_rx_direct_bytes_total", rank=rank),
+            "rx_assembled_bytes": _reg.counter(
+                "mpit_shm_rx_assembled_bytes_total", rank=rank),
+        } if _reg.enabled else {}
+        self._rx_counted = dict.fromkeys(self._m_rx_paths, 0)
+        # Posted receives, by native handle: their buffers are written from
+        # the drain, so they live until the receive is done or cancelled
+        # even if the caller lets go of the Handle.
+        self._posted: dict = {}
         atexit.register(self.close)
 
     # -- Transport ----------------------------------------------------------
@@ -111,6 +137,7 @@ class ShmTransport(Transport):
         native = self.lib.mt_irecv(self._ctx, src, tag, out, nbytes)
         if native < 0:
             raise ValueError(f"irecv from invalid rank {src}")
+        self._posted[native] = out
         return Handle(kind="recv", peer=src, tag=tag, out=out, native_id=native)
 
     def iprobe(self, src: int, tag: int) -> bool:
@@ -122,6 +149,7 @@ class ShmTransport(Transport):
         code = self.lib.mt_test(self._ctx, handle.native_id)
         if code == 0:
             return False
+        self._posted.pop(handle.native_id, None)  # every other code is final
         if code == 1:
             handle.done = True
             if handle.kind == "recv" and handle.meta.get("as_bytes"):
@@ -132,6 +160,11 @@ class ShmTransport(Transport):
                 self._m_rx_msgs[handle.peer].inc()
                 self._m_rx_bytes[handle.peer].inc(
                     int(getattr(out, "nbytes", None) or len(out or b"")))
+                if self._m_rx_paths:
+                    now = self.rx_path_bytes()
+                    for key, counter in self._m_rx_paths.items():
+                        counter.inc(now[key] - self._rx_counted[key])
+                    self._rx_counted = now
             if handle.kind == "send":
                 handle.buf = None  # release ownership back to the caller
             self.lib.mt_release(self._ctx, handle.native_id)
@@ -152,8 +185,18 @@ class ShmTransport(Transport):
     def cancel(self, handle: Handle) -> None:
         if not handle.done:
             self.lib.mt_cancel(self._ctx, handle.native_id)
+            self._posted.pop(handle.native_id, None)
         handle.cancelled = True
         handle.buf = None
+
+    def rx_path_bytes(self) -> dict:
+        """Bytes of the messages received whole so far, by the way they
+        took: into the buffer of a receive posted before they arrived, or
+        through an assembly buffer and one more copy."""
+        return {
+            "rx_direct_bytes": int(self.lib.mt_rx_bytes(self._ctx, 0)),
+            "rx_assembled_bytes": int(self.lib.mt_rx_bytes(self._ctx, 1)),
+        }
 
     def close(self) -> None:
         if not self._closed and self._ctx:

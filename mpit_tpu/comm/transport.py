@@ -109,6 +109,11 @@ class Transport(abc.ABC):
             raise RuntimeError("payload requested before completion")
         return handle.out if handle.out is not None else handle.payload
 
+    def rx_path_bytes(self) -> dict:
+        """Rank-result fields saying which way the received bytes went,
+        where a transport has more than one (``comm/shm.py``)."""
+        return {}
+
     def close(self) -> None:  # pragma: no cover - backends override
         pass
 

@@ -1017,14 +1017,21 @@ class ParamClient:
         span.note(bytes=(out if wire is None else wire).nbytes)
         if not self.ft.framed:
             span.mark("send")
-            yield from aio_send(self.transport, tags.EMPTY, srank,
-                                tags.PARAM_REQ, live=self.live,
-                                deadline=self._op_deadline())
-            span.mark("recv")
+            request = aio_send(self.transport, tags.EMPTY, srank,
+                               tags.PARAM_REQ, live=self.live,
+                               deadline=self._op_deadline())
+
+            def ask():
+                yield from request
+                span.mark("recv")
+
+            # ``aio_recv`` posts the receive and only then lets the
+            # request leave, so the shard lands in its place however
+            # quick the server is.
             got = yield from aio_recv(
                 self.transport, srank, tags.PARAM, live=self.live,
                 out=out if wire is None else wire,
-                deadline=self._op_deadline(),
+                deadline=self._op_deadline(), request=ask(),
             )
             if got is not None and wire is not None:
                 span.mark("decode")

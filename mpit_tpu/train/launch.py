@@ -610,7 +610,17 @@ def run_rank(
     transport: Any,
     data: Any = None,
 ) -> Dict[str, Any]:
-    """Run one rank's role to completion; returns its result dict."""
+    """Run one rank's role to completion; returns its result dict, with
+    the transport's word on which way the bytes it received went
+    (``rx_direct_bytes`` / ``rx_assembled_bytes`` on the shm wire)."""
+    result = _run_role(rank, size, cfg, transport, data)
+    if transport is not None:
+        result = {**result, **transport.rx_path_bytes()}
+    return result
+
+
+def _run_role(rank: int, size: int, cfg: Config, transport: Any,
+              data: Any) -> Dict[str, Any]:
     log = get_logger("launch", rank)
     if size == 1:
         if bool(cfg.get("resume", False)):
@@ -1128,7 +1138,8 @@ def _summarize(result: Dict[str, Any]) -> Dict[str, Any]:
             "best_test_err",
             "reads", "monotone", "busy_honored",
             "final_loss", "final_eval_loss", "tokens_per_s", "tokens_total",
-            "steps", "rounds_streamed", "train_seconds",
+            "steps", "rounds_streamed", "rx_direct_bytes",
+            "rx_assembled_bytes", "train_seconds",
             "first_step_seconds", "mosaic_calls",
             "moe_load_max_over_mean",
             "platform", "device_kind", "device_count", "device_ids",

@@ -5,10 +5,15 @@ init.lua scheduler round-robin, cancel-on-shutdown) but as real assertions
 rather than eyeballed prints (SURVEY.md section 4).
 """
 
+import os
+import time
+
+import numpy as np
 import pytest
 
 from mpit_tpu.aio import (
     DONE,
+    DeadlineExceeded,
     EXEC,
     LiveFlag,
     Queue,
@@ -229,3 +234,113 @@ class TestAioTransfers:
         sched.wait()
         assert task.state == DONE
         assert task.result is None
+
+
+class TestAioRecvPostsEarly:
+    """``aio_recv`` with ``out`` posts its receive before the message
+    arrives, on a real shm endpoint pair; the message is five rings long,
+    so "part-way" is a state the test can hold."""
+
+    RING = 1 << 20
+    BIG = 5 << 20
+
+    @pytest.fixture
+    def wire(self):
+        from mpit_tpu.comm.shm import ShmTransport
+
+        ns = f"t_aio_{os.getpid()}"
+        a, b = (ShmTransport(ns, r, 2, ring_bytes=self.RING) for r in (0, 1))
+        data = np.random.default_rng(0).integers(0, 256, self.BIG,
+                                                 dtype=np.uint8)
+        yield a, b, data
+        a.close()
+        b.close()
+
+    def test_completes_for_a_message_that_arrives_after_the_post(self, wire):
+        a, b, data = wire
+        out = np.zeros_like(data)
+        sched = Scheduler()
+        recv = sched.spawn(aio_recv(b, 0, 4, out=out))
+        for _ in range(3):
+            sched.ping()
+        assert recv.state != DONE  # posted, nothing has arrived
+        sched.spawn(aio_send(a, data, 1, 4))
+        sched.wait()
+        assert recv.result is out
+        np.testing.assert_array_equal(out, data)
+        assert b.rx_path_bytes() == {"rx_direct_bytes": self.BIG,
+                                     "rx_assembled_bytes": 0}
+
+    @pytest.mark.parametrize("how", ["abort", "deadline", "live", "closed"])
+    def test_giving_up_part_way_neither_loses_nor_tears(self, wire, how):
+        a, b, data = wire
+        out = np.zeros_like(data)
+        sched = Scheduler()
+        live = LiveFlag()
+        gone = []
+        recv = sched.spawn(aio_recv(
+            b, 0, 4, out=out, live=live,
+            abort=lambda: how == "abort" and bool(gone),
+            deadline=time.monotonic() + 0.2 if how == "deadline" else None))
+        hs = a.isend(data, 1, 4)
+        sched.ping()  # drains the first ring into ``out``
+        assert out.any() and recv.state != DONE and not a.test(hs)
+        gone.append(True)
+        if how == "live":
+            live.io = False
+        if how == "deadline":
+            time.sleep(0.25)
+        if how == "closed":
+            recv.gen.close()  # a generator dropped without a word
+        elif how == "deadline":
+            with pytest.raises(TaskError) as failed:
+                sched.wait()
+            assert isinstance(failed.value.cause, DeadlineExceeded)
+        else:
+            sched.wait()
+            assert recv.state == DONE and recv.result is None
+        out[:] = 0
+        # The next receive gets the message whole, bit for bit, and the
+        # buffer that was given up is not written again.
+        again = np.zeros_like(data)
+        sched = Scheduler()
+        nxt = sched.spawn(aio_recv(b, 0, 4, out=again))
+        while not (a.test(hs) and nxt.state == DONE):
+            sched.ping()
+        np.testing.assert_array_equal(again, data)
+        assert not out.any()
+
+    @pytest.mark.parametrize("with_out", [True, False])
+    def test_request_leaves_once_the_receive_is_posted(self, with_out):
+        """With ``out`` the receive is posted before the request that the
+        message answers is sent; without it there is nothing to post
+        yet, and the request goes first."""
+        from mpit_tpu.comm.local import LocalRouter, LocalTransport
+
+        calls = []
+
+        class Recording(LocalTransport):
+            def isend(self, data, dst, tag):
+                calls.append("isend")
+                return super().isend(data, dst, tag)
+
+            def irecv(self, src, tag, out=None):
+                calls.append("irecv")
+                return super().irecv(src, tag, out=out)
+
+        router = LocalRouter(2)
+        asker, peer = Recording(router, 0), router.endpoint(1)
+        out = np.zeros(4, np.uint8) if with_out else None
+        sched = Scheduler()
+        recv = sched.spawn(aio_recv(
+            asker, 1, 5, out=out, request=aio_send(asker, b"", 1, 4)))
+
+        def answer():
+            yield from aio_recv(peer, 0, 4)
+            yield from aio_send(peer, np.arange(4, dtype=np.uint8), 0, 5)
+
+        sched.spawn(answer())
+        sched.wait()
+        assert calls == (["irecv", "isend"] if with_out
+                         else ["isend", "irecv"])
+        assert bytes(recv.result) == bytes(range(4))

@@ -243,6 +243,253 @@ class TestShmCancelAndProbe:
             b.close()
 
 
+def spin(*steps, limit=10**6):
+    """Poll every side until all steps are true: a sender finishes only
+    as the receiver drains (messages here are several rings long)."""
+    spins = 0
+    while not all([step() for step in steps]):
+        spins += 1
+        assert spins < limit
+
+
+def noise(seed, nbytes):
+    return np.random.default_rng(seed).integers(0, 256, nbytes, dtype=np.uint8)
+
+
+class TestPostedRecvLandsInPlace:
+    """A receive posted with a buffer before its message arrives is bound
+    when the message's first chunk is drained, and the chunks go from the
+    ring into that buffer; everything else is assembled and copied out by
+    the test that takes it (transport.cpp ``posted_recv``).  Messages are
+    five 1 MB rings long unless a case says otherwise."""
+
+    RING = 1 << 20
+    BIG = 5 << 20
+
+    def pair(self, name, nranks=2):
+        ns = f"t_pr_{name}_{os.getpid()}"
+        return [ShmTransport(ns, r, nranks, ring_bytes=self.RING)
+                for r in range(nranks)]
+
+    def test_posted_before_arrival_lands_direct(self):
+        a, b = self.pair("direct")
+        try:
+            data = noise(1, self.BIG)
+            out = np.zeros_like(data)
+            hr = b.irecv(0, 4, out=out)
+            assert not b.test(hr)
+            hs = a.isend(data, 1, 4)
+            spin(lambda: a.test(hs), lambda: b.test(hr))
+            np.testing.assert_array_equal(out, data)
+            assert b.rx_path_bytes() == {"rx_direct_bytes": self.BIG,
+                                         "rx_assembled_bytes": 0}
+        finally:
+            a.close()
+            b.close()
+
+    def test_small_and_empty_messages_land_direct_too(self):
+        """No size threshold: what decides is whether a buffer waits."""
+        a, b = self.pair("small")
+        try:
+            out = np.zeros(3, np.float32)
+            hr = b.irecv(0, 4, out=out)
+            he = b.irecv(0, 5, out=bytearray())
+            a.send(np.asarray([1, 2, 3], np.float32), 1, 4)
+            a.send(b"", 1, 5)
+            spin(lambda: b.test(hr), lambda: b.test(he))
+            np.testing.assert_array_equal(out, [1, 2, 3])
+            assert b.rx_path_bytes() == {"rx_direct_bytes": 12,
+                                         "rx_assembled_bytes": 0}
+        finally:
+            a.close()
+            b.close()
+
+    @pytest.mark.parametrize("when", ["whole", "half_way"])
+    def test_posted_late_is_assembled(self, when):
+        """Posted after the message was assembled, or half-way through
+        its arrival: bit-equal, by the assembly buffer."""
+        a, b = self.pair(f"late_{when}")
+        try:
+            data = noise(2, self.BIG)
+            hs = a.isend(data, 1, 4)
+            if when == "whole":
+                spin(lambda: a.test(hs), lambda: b.iprobe(0, 4))
+            else:
+                assert not b.iprobe(0, 4)  # drains the first ring
+                assert not a.test(hs)
+            out = np.zeros_like(data)
+            hr = b.irecv(0, 4, out=out)
+            spin(lambda: a.test(hs), lambda: b.test(hr))
+            np.testing.assert_array_equal(out, data)
+            assert b.rx_path_bytes() == {"rx_direct_bytes": 0,
+                                         "rx_assembled_bytes": self.BIG}
+        finally:
+            a.close()
+            b.close()
+
+    def test_two_posted_take_two_messages_in_order(self):
+        a, b = self.pair("order")
+        try:
+            first, second = noise(3, self.BIG), noise(4, self.BIG)
+            out1, out2 = np.zeros_like(first), np.zeros_like(second)
+            h1 = b.irecv(0, 4, out=out1)
+            h2 = b.irecv(0, 4, out=out2)
+            s1 = a.isend(first, 1, 4)
+            s2 = a.isend(second, 1, 4)
+            # Polling the younger receive first must not hand it the
+            # older message: the match is made in the order of posting.
+            spin(lambda: a.test(s1), lambda: a.test(s2),
+                 lambda: b.test(h2), lambda: b.test(h1))
+            np.testing.assert_array_equal(out1, first)
+            np.testing.assert_array_equal(out2, second)
+            assert b.rx_path_bytes()["rx_direct_bytes"] == 2 * self.BIG
+        finally:
+            a.close()
+            b.close()
+
+    def test_message_queued_ahead_keeps_the_order(self):
+        """A whole message waits on the channel when the receives are
+        posted: the first receive takes it, and the message that arrives
+        next is not bound past it."""
+        a, b = self.pair("ahead")
+        try:
+            first, second = noise(5, 1 << 16), noise(6, 1 << 16)
+            a.send(first, 1, 4)
+            while not b.iprobe(0, 4):
+                pass
+            out1, out2 = np.zeros_like(first), np.zeros_like(second)
+            h1 = b.irecv(0, 4, out=out1)
+            h2 = b.irecv(0, 4, out=out2)
+            a.send(second, 1, 4)
+            spin(lambda: b.test(h1), lambda: b.test(h2))
+            np.testing.assert_array_equal(out1, first)
+            np.testing.assert_array_equal(out2, second)
+        finally:
+            a.close()
+            b.close()
+
+    def test_other_tag_and_other_source_do_not_disturb(self):
+        a, b, c = self.pair("beside", nranks=3)
+        try:
+            data = noise(7, self.BIG)
+            other_tag, other_src = noise(8, self.BIG), noise(9, self.BIG)
+            out = np.zeros_like(data)
+            hr = b.irecv(0, 4, out=out)
+            sends = [(a, a.isend(other_tag, 1, 5)), (c, c.isend(other_src, 1, 4)),
+                     (a, a.isend(data, 1, 4))]
+            spin(lambda: b.test(hr),
+                 *[lambda t=t, h=h: t.test(h) for t, h in sends],
+                 lambda: b.iprobe(0, 5), lambda: b.iprobe(2, 4))
+            np.testing.assert_array_equal(out, data)
+            got_tag, got_src = np.zeros_like(data), np.zeros_like(data)
+            b.recv(0, 5, out=got_tag)
+            b.recv(2, 4, out=got_src)
+            np.testing.assert_array_equal(got_tag, other_tag)
+            np.testing.assert_array_equal(got_src, other_src)
+            assert b.rx_path_bytes() == {"rx_direct_bytes": self.BIG,
+                                         "rx_assembled_bytes": 2 * self.BIG}
+        finally:
+            for t in (a, b, c):
+                t.close()
+
+    @pytest.mark.parametrize("when", ["part_way", "whole_uncollected",
+                                      "one_chunk_uncollected"])
+    def test_cancel_leaves_the_message_whole(self, when):
+        """Cancelled with one ring of the message in its buffer, or with
+        all of it there (five rings, or one chunk) and no ``test`` having
+        said so: the next receive gets the message, and the cancelled
+        buffer is left alone."""
+        a, b = self.pair(f"cancel_{when}")
+        try:
+            data = noise(10, 1000 if when.startswith("one_chunk") else self.BIG)
+            out = np.zeros_like(data)
+            hr = b.irecv(0, 4, out=out)
+            hs = a.isend(data, 1, 4)
+            if when == "part_way":
+                assert not b.test(hr)  # one ring of it has landed
+                landed = int(np.flatnonzero(out)[-1]) + 1
+                assert 0 < landed < self.BIG
+            else:  # b makes progress through calls about another channel
+                spin(lambda: a.test(hs), lambda: not b.iprobe(0, 9)
+                     and b.rx_path_bytes()["rx_direct_bytes"] == data.nbytes)
+                landed = data.nbytes
+            np.testing.assert_array_equal(out[:landed], data[:landed])
+            b.cancel(hr)
+            out[:] = 0
+            again = np.zeros_like(data)
+            hr2 = b.irecv(0, 4, out=again)
+            spin(lambda: a.test(hs), lambda: b.test(hr2))
+            np.testing.assert_array_equal(again, data)
+            assert not out.any()  # nothing wrote to it after the cancel
+            assert hr.cancelled and not b.test(hr)
+            if when == "part_way":
+                assert b.rx_path_bytes() == {"rx_direct_bytes": 0,
+                                             "rx_assembled_bytes": self.BIG}
+        finally:
+            a.close()
+            b.close()
+
+    def test_send_cancelled_part_way_frees_the_bound_recv(self):
+        """The sender gives a message up half-placed (a framed op's
+        deadline) and sends it again: the receive bound to the torn one
+        takes the retry, whole."""
+        a, b = self.pair("torn")
+        try:
+            torn, retry = noise(11, self.BIG), noise(12, self.BIG)
+            out = np.zeros_like(retry)
+            hr = b.irecv(0, 4, out=out)
+            hs = a.isend(torn, 1, 4)
+            assert not b.test(hr) and not a.test(hs)
+            a.cancel(hs)
+            hs = a.isend(retry, 1, 4)
+            spin(lambda: a.test(hs), lambda: b.test(hr))
+            np.testing.assert_array_equal(out, retry)
+            assert b.rx_path_bytes()["rx_direct_bytes"] == self.BIG
+        finally:
+            a.close()
+            b.close()
+
+    def test_wrong_size_posted_early_raises_and_message_survives(self):
+        a, b = self.pair("size")
+        try:
+            data = noise(13, self.BIG)
+            small = np.zeros(self.BIG - 4, np.uint8)
+            hr = b.irecv(0, 4, out=small)
+            hs = a.isend(data, 1, 4)
+            with pytest.raises(ValueError, match="size mismatch"):
+                spin(lambda: a.test(hs) and False, lambda: b.test(hr))
+            assert not small.any()
+            out = np.zeros_like(data)
+            hr2 = b.irecv(0, 4, out=out)
+            spin(lambda: a.test(hs), lambda: b.test(hr2))
+            np.testing.assert_array_equal(out, data)
+        finally:
+            a.close()
+            b.close()
+
+    def test_dropped_handle_keeps_its_buffer_alive(self):
+        """The drain writes to a posted buffer whoever still holds the
+        Handle, so the endpoint holds it until done or cancelled."""
+        import gc
+        import weakref
+
+        a, b = self.pair("alive")
+        try:
+            out = np.zeros(self.BIG, np.uint8)
+            ref = weakref.ref(out)
+            b.irecv(0, 4, out=out)
+            del out
+            gc.collect()
+            assert ref() is not None
+            hs = a.isend(noise(14, self.BIG), 1, 4)
+            spin(lambda: a.test(hs),
+                 lambda: not b.iprobe(0, 9)  # any call of b's makes progress
+                 and b.rx_path_bytes()["rx_direct_bytes"] == self.BIG)
+        finally:
+            a.close()
+            b.close()
+
+
 ECHO_PEER = textwrap.dedent(
     """
     import sys, numpy as np

@@ -7,6 +7,7 @@ Topology helpers run each server's blocking event loop on its own thread
 """
 
 import contextlib
+import os
 import threading
 import time
 
@@ -18,6 +19,7 @@ import pytest
 from mpit_tpu import obs
 from mpit_tpu.comm import codec as codec_mod
 from mpit_tpu.comm.local import LocalRouter
+from mpit_tpu.comm.transport import Handle
 from mpit_tpu.optim import rules
 from mpit_tpu.optim.downpour import Downpour
 from mpit_tpu.optim.shells import SingleWorker
@@ -817,3 +819,100 @@ class TestDonatedApply:
         assert sum(s.args["inplace"] for s in execs) == server.apply_inplace
         assert server.apply_inplace >= 2 * pushes - 1
         np.testing.assert_array_equal(clients[0].param, clients[1].param)
+
+
+class PostsLate:
+    """Mixin over a transport: a receive is posted only once its message
+    is whole — the probe-then-post order, which the shm wire serves from
+    an assembly buffer with one more copy."""
+
+    def irecv(self, src, tag, out=None):
+        if out is None:
+            return super().irecv(src, tag)
+        return Handle(kind="recv", peer=src, tag=tag, out=out,
+                      meta={"posted": None})
+
+    def test(self, handle):
+        if "posted" not in handle.meta:
+            return super().test(handle)
+        if handle.meta["posted"] is None:
+            if handle.cancelled or not self.iprobe(handle.peer, handle.tag):
+                return False
+            handle.meta["posted"] = super().irecv(handle.peer, handle.tag,
+                                                  out=handle.out)
+        handle.done = super().test(handle.meta["posted"])
+        return handle.done
+
+    def cancel(self, handle):
+        posted = handle.meta.get("posted", handle)
+        if posted is not None:
+            super().cancel(posted)
+        handle.cancelled = True
+
+
+class TestShmWireLandsInPlace:
+    """Unchunked rounds over the shm wire (ISSUE 29): the server's GRAD
+    receive and the client's PARAM receive are posted before their
+    messages arrive, so the shards land in the frame and in the parameter
+    slice straight from the ring — and the bytes are those of a wire that
+    assembles every message first."""
+
+    SIZE = 600_000       # two shards of 1.2 MB ...
+    RING = 256 << 10     # ... through 256 KB rings
+    ROUNDS = 3
+
+    def run_rounds(self, name, transport_cls):
+        ns = f"t_psw_{name}_{os.getpid()}"
+        wires = [transport_cls(ns, r, 3, ring_bytes=self.RING)
+                 for r in range(3)]
+        servers = [ParamServer(r, [2], wires[r],
+                               rule=rules.make("adam", lr=1e-2))
+                   for r in (0, 1)]
+        threads = [threading.Thread(target=s.start, daemon=True)
+                   for s in servers]
+        for t in threads:
+            t.start()
+        client = ParamClient(2, [0, 1], wires[2], seed_servers=True)
+        rng = np.random.default_rng(29)
+        param = rng.normal(size=self.SIZE).astype(np.float32)
+        grad = np.zeros_like(param)
+        try:
+            client.start(param, grad)
+            for _ in range(self.ROUNDS):
+                grad[:] = rng.normal(size=self.SIZE).astype(np.float32)
+                client.async_send_grad()
+                client.async_recv_param()
+                client.wait()
+            client.stop()
+            join_all(threads)
+            assert [s.grads_applied for s in servers] == [self.ROUNDS] * 2
+            return param, [w.rx_path_bytes() for w in wires]
+        finally:
+            for s in servers:
+                s.live.stop()
+            for t in threads:
+                t.join(5)
+            for w in wires:
+                w.close()
+
+    def test_rounds_land_direct_and_equal_the_assembled_wire(self):
+        from mpit_tpu.comm.shm import ShmTransport
+
+        class LateShm(PostsLate, ShmTransport):
+            pass
+
+        direct, paths = self.run_rounds("direct", ShmTransport)
+        late, late_paths = self.run_rounds("late", LateShm)
+        assert np.array_equal(direct, late)
+        shard = self.SIZE // 2 * 4
+        for rank, rx in enumerate(paths):
+            moved = self.ROUNDS * (shard if rank < 2 else 2 * shard)
+            # Every GRAD (servers) and PARAM (client) of every round ...
+            assert rx["rx_direct_bytes"] >= moved, (rank, rx)
+            # ... and what was assembled is acks, headers, INIT, and on a
+            # server the one-shot seed if it beat its receive there.
+            seed = shard if rank < 2 else 0
+            assert rx["rx_assembled_bytes"] < seed + 4096, (rank, rx)
+        for rx in late_paths:
+            assert rx["rx_direct_bytes"] == 0
+            assert rx["rx_assembled_bytes"] >= self.ROUNDS * shard
