@@ -2,7 +2,8 @@
 
 Assembles one of the decoders of :mod:`mpit_tpu.models.transformer`
 (``arch``: ``gpt2`` is :class:`TinyDecoder`, ``olmoe``
-:class:`OlmoeDecoder`; either's attention is the ``ops/`` flash kernel
+:class:`OlmoeDecoder`, ``mellum`` :class:`MellumDecoder`; each one's
+attention is the ``ops/`` flash kernel
 on TPU and the jnp reference — which differentiates without a recompute
 pass — elsewhere) into the flat-vector calling convention the parameter server shards: a
 :class:`~mpit_tpu.models.flat.FlatModel` plus a next-token NLL over
@@ -21,12 +22,18 @@ import jax.numpy as jnp
 from mpit_tpu.models.flat import FlatModel, flatten_module
 from mpit_tpu.models import transformer
 from mpit_tpu.models.transformer import (
+    MellumDecoder,
     OlmoeDecoder,
     TinyDecoder,
     default_attn,
 )
 
-ARCHS = ("gpt2", "olmoe")
+ARCHS = ("gpt2", "olmoe", "mellum")
+# what a sparse block ``sow``s a layer, and the name of each in the
+# step's telemetry (``value_grad_stats``), on the round span and as the
+# gauge ``mpit_<name>``
+MOE_STATS = {"moe_load": "moe_load_max_over_mean",
+             "moe_held": "moe_held_rows_share"}
 
 
 class LmModel(NamedTuple):
@@ -38,10 +45,12 @@ class LmModel(NamedTuple):
     value_and_grad: Callable[..., Any]        # (w, tokens) -> (loss, grad)
     seq_len: int
     vocab: int
-    #: olmoe: (w, tokens) -> ((loss, {name: device array}), grad), the
-    #: same step with the block's own telemetry as an auxiliary output
-    #: (``moe_load_max_over_mean``, one number a layer), which the shell
-    #: fetches only while obs is on; None for a block that has none
+    #: olmoe, mellum: (w, tokens) -> ((loss, {name: device array}),
+    #: grad), the same step with the block's own telemetry as an
+    #: auxiliary output (``moe_load_max_over_mean`` and, from a block
+    #: that holds a share of its experts, ``moe_held_rows_share``, one
+    #: number a layer each), which the optimizer fetches only while obs
+    #: is on; None for a block that has none
     value_grad_stats: Optional[Callable[..., Any]] = None
 
 
@@ -63,15 +72,21 @@ def _resolve_attn(use_flash: Optional[bool],
                         precision=precision)
 
 
+# mellum's own sizes (``build``'s keywords, ``LM_DEFAULTS``' names)
+MELLUM_KEYS = ("kv_heads", "head_dim", "experts_first", "experts_held",
+               "window", "full_every", "yarn_factor", "yarn_orig",
+               "yarn_beta_fast", "yarn_beta_slow", "yarn_attn_factor")
+
+
 def build_kw(cfg: Any) -> dict:
     """``build``'s keywords for a trainer config (``LM_DEFAULTS``'
-    names): every size of either block and the seed; the attention is
+    names): every size of every block and the seed; the attention is
     the caller's to choose.  ``vocab`` 0 leaves ``build``'s own default
     in force (the byte stream's 256)."""
     kw = {key: cfg[key] for key in (
         "arch", "d_model", "n_heads", "n_layers", "seq_len", "seed",
         "n_experts", "experts_per_tok", "expert_width", "rope_theta",
-        "norm_eps")}
+        "norm_eps", *MELLUM_KEYS)}
     if int(cfg.vocab):
         kw["vocab"] = int(cfg.vocab)
     return kw
@@ -82,20 +97,49 @@ def build(*, arch: str = "gpt2", vocab: int = 256, d_model: int = 64,
           seed: int = 0, use_flash: Optional[bool] = None,
           n_experts: int = 8, experts_per_tok: int = 2,
           expert_width: int = 32, rope_theta: float = 10000.0,
-          norm_eps: float = 1e-5) -> LmModel:
+          norm_eps: float = 1e-5, kv_heads: int = 0, head_dim: int = 0,
+          experts_first: int = 0, experts_held: int = 0, window: int = 0,
+          full_every: int = 4, yarn_factor: float = 0.0,
+          yarn_orig: int = 0, yarn_beta_fast: float = 32.0,
+          yarn_beta_slow: float = 1.0,
+          yarn_attn_factor: float = 1.0) -> LmModel:
     """Build the decoder, flatten its params, and close over the
     next-token NLL.  ``arch`` chooses the block; the expert, rotary and
-    norm sizes are ``olmoe``'s alone.  For ``gpt2`` ``max_len`` is
-    pinned to ``seq_len`` — the packed stream always fills full
-    sequences, and an exact fit keeps the position table out of the
-    sharding slack (``olmoe``'s positions are rotary: no table)."""
+    norm sizes are the sparse blocks' alone, and those from
+    ``kv_heads`` on ``mellum``'s (``kv_heads`` 0: as many as query
+    heads; ``head_dim`` 0: ``d_model / n_heads``; ``experts_held`` 0:
+    all ``n_experts``, else the contiguous share from ``experts_first``
+    that this chip holds of a router ``n_experts`` wide; ``window`` 0:
+    every layer full; ``yarn_factor`` 0: the plain rotary table on the
+    full layers too).  For ``gpt2`` ``max_len`` is pinned to ``seq_len``
+    — the packed stream always fills full sequences, and an exact fit
+    keeps the position table out of the sharding slack (the other
+    blocks' positions are rotary: no table)."""
     if arch not in ARCHS:
         raise ValueError(f"unknown LM arch {arch!r}; have {ARCHS}")
-    if arch == "olmoe":
+    if arch == "mellum":
+        held = experts_held or n_experts
+        if experts_first + held > n_experts:
+            raise ValueError(f"experts {experts_first}.."
+                             f"{experts_first + held - 1} held of {n_experts}")
+        attn_fn = _resolve_attn(use_flash)
+        yarn = (float(yarn_factor), int(yarn_orig), float(yarn_beta_fast),
+                float(yarn_beta_slow), float(yarn_attn_factor)
+                ) if yarn_factor else None
+        module: Any = MellumDecoder(
+            vocab=vocab, d_model=d_model, n_heads=n_heads,
+            kv_heads=kv_heads or n_heads,
+            head_dim=head_dim or d_model // n_heads, n_layers=n_layers,
+            n_experts=n_experts, experts_per_tok=experts_per_tok,
+            expert_width=expert_width, experts_first=experts_first,
+            experts_held=experts_held, window=window,
+            full_every=full_every, rope_theta=rope_theta, yarn=yarn,
+            norm_eps=norm_eps, attn_fn=attn_fn)
+    elif arch == "olmoe":
         # read at build time: the probe of the reference's tolerances
         # tries other precisions (chipbench/reference/probe_olmoe.py)
         attn_fn = _resolve_attn(use_flash, transformer.ATTN_KERNEL_PRECISION)
-        module: Any = OlmoeDecoder(
+        module = OlmoeDecoder(
             vocab=vocab, d_model=d_model, n_heads=n_heads,
             n_layers=n_layers, n_experts=n_experts,
             experts_per_tok=experts_per_tok, expert_width=expert_width,
@@ -105,7 +149,13 @@ def build(*, arch: str = "gpt2", vocab: int = 256, d_model: int = 64,
             vocab=vocab, d_model=d_model, n_heads=n_heads,
             n_layers=n_layers, max_len=seq_len,
             attn_fn=_resolve_attn(use_flash))
-    sample = jnp.zeros((1, seq_len), jnp.int32)
+    # Initialisation runs the model on the sample.  No parameter's shape
+    # or value depends on the sample's length (rotary positions, a key
+    # per parameter's path), and mellum trains at sequences at which a
+    # host role's forward pass with the materialised reference attention
+    # takes minutes and tens of GB (lm_layout on a server rank), so its
+    # sample is short; the older blocks keep the sample they had.
+    sample = jnp.zeros((1, 16 if arch == "mellum" else seq_len), jnp.int32)
     fm = flatten_module(module, jax.random.PRNGKey(seed), sample)
 
     def mean_nll(logp, targets):
@@ -120,15 +170,19 @@ def build(*, arch: str = "gpt2", vocab: int = 256, d_model: int = 64,
         return mean_nll(logp, targets)
 
     value_grad_stats = None
-    if arch == "olmoe":
+    if arch in ("olmoe", "mellum"):
         def loss_and_load(w, tokens):
             # the same loss with ``intermediates`` collected: the
-            # routers' loads, which the forward pass has already counted
+            # routers' counts, which the forward pass has already made
             logp, state = fm.apply_flat(w, tokens[:, :-1],
                                         mutable=["intermediates"])
-            loads = jax.tree_util.tree_leaves(state["intermediates"])
+            blocks = state["intermediates"]
+            layers = sorted(blocks, key=lambda name: int(
+                name.rsplit("_", 1)[1]))
             return mean_nll(logp, tokens[:, 1:]), {
-                "moe_load_max_over_mean": jnp.stack(loads)}
+                name: jnp.stack([blocks[layer][sown][0] for layer in layers])
+                for sown, name in MOE_STATS.items()
+                if sown in blocks[layers[0]]}
 
         value_grad_stats = jax.value_and_grad(loss_and_load, has_aux=True)
 

@@ -57,6 +57,21 @@ LM_DEFAULTS = Config(
     expert_width=32,
     rope_theta=10000.0,
     norm_eps=1e-5,
+    # mellum's own sizes (lm/model.py build): KV heads and the heads'
+    # width (0: as olmoe's), the share of the experts held (0: all), the
+    # sliding window (0: none) and which layers are full, YaRN on those
+    # (factor 0: none)
+    kv_heads=0,
+    head_dim=0,
+    experts_first=0,
+    experts_held=0,
+    window=0,
+    full_every=4,
+    yarn_factor=0.0,
+    yarn_orig=0,
+    yarn_beta_fast=32.0,
+    yarn_beta_slow=1.0,
+    yarn_attn_factor=1.0,
     # -1 auto (flash on TPU, jnp reference elsewhere) | 0 reference |
     # 1 the Mosaic-compiled kernel or an error (lm/model.py _resolve_attn)
     use_flash=-1,
@@ -136,7 +151,11 @@ class LmTrainer:
             mcfg = MSGDConfig(lr=cfg.lr, lrd=cfg.lrd, lrp=cfg.lrp,
                               mom=cfg.mom, mommax=cfg.mommax,
                               momdecay=cfg.momdecay, l2wd=cfg.l2wd)
-            return MSGD(mcfg, self._vgf)
+            # a block with telemetry of its own returns it beside the
+            # loss, here as under the shells below
+            stats_step = self.model.value_grad_stats
+            return MSGD(mcfg, stats_step or self._vgf,
+                        has_aux=stats_step is not None)
         if self.pc is None:
             raise ValueError(
                 f"optimizer {name!r} needs a parameter client "
@@ -248,9 +267,9 @@ class LmTrainer:
             "train_seconds": train_s,
             "first_step_seconds": first_step_s,
             "mosaic_calls": mosaic_calls,
-            # the block's own statistics of the last sync round, one
-            # entry a layer (olmoe: moe_load_max_over_mean); empty with
-            # obs off or a block that has none
+            # the block's own statistics of the last recorded round (a
+            # local run's step), one entry a layer (lm/model.py
+            # MOE_STATS); empty with obs off or a block that has none
             **getattr(opt, "stats_last", {}),
             "elapsed": self.tm.elapsed(),
             "timers": dict(self.tm.total),

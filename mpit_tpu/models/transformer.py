@@ -15,10 +15,13 @@ long-context showcase built on the framework's own kernels:
   batched over (B, L);
 - pre-LN blocks, learned positional embeddings, causal by default.
 
-Two blocks: :class:`TinyDecoder` (GPT-2's: LayerNorm, learned
-positions, GELU MLP) and :class:`OlmoeDecoder` (OLMoE's: RMSNorm,
-rotary positions, query/key norm, top-k of E gated experts), chosen by
-``lm/model.py`` ``build(arch=...)``.
+Three blocks: :class:`TinyDecoder` (GPT-2's: LayerNorm, learned
+positions, GELU MLP), :class:`OlmoeDecoder` (OLMoE's: RMSNorm, rotary
+positions, query/key norm, top-k of E gated experts) and
+:class:`MellumDecoder` (Mellum 2's: grouped KV heads of their own
+width, sliding-window and full attention mixed by layer with a rotary
+table per layer type, top-k renormalised, and a chip's share of the
+experts), chosen by ``lm/model.py`` ``build(arch=...)``.
 """
 
 from __future__ import annotations
@@ -26,35 +29,49 @@ from __future__ import annotations
 from functools import partial
 from typing import Callable, Optional
 
+import math
+
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from mpit_tpu.ops.flash_attention import attention_reference, flash_attention
 from mpit_tpu.parallel import moe
 
-AttnFn = Callable[[jnp.ndarray, jnp.ndarray, jnp.ndarray], jnp.ndarray]
+#: ``fn(q, k, v, window=None) -> out``.  A block that never passes a
+#: window may be handed a callable of three arguments (ring attention).
+AttnFn = Callable[..., jnp.ndarray]
 
 
 def default_attn(causal: bool = True, use_flash: bool = True,
                  interpret: Optional[bool] = None,
                  precision: Optional[str] = None) -> AttnFn:
-    """Single-device attention over (B, L, H, D): flash kernel or the jnp
+    """Single-device attention ``fn(q, k, v, window=None)`` over ``q (B,
+    L, Hq, D)`` and ``k, v (B, L, Hkv, D)``: flash kernel or the jnp
     reference (the latter differentiates without a recompute pass).
+    Fewer KV heads than query heads are grouped (query head ``g`` on KV
+    head ``g // (Hq // Hkv)``) and ``window`` is the sliding causal
+    window, both as ``ops/flash_attention.py`` has them: one callable
+    serves a model's full and windowed layers.
     ``interpret`` reaches ``pallas_call``: None interprets everywhere
     but on a TPU (ops/tiles.py), False pins the Mosaic-compiled kernel.
     ``precision`` is the MXU input precision of the two attention
     products, forward and backward (``"highest"``: float32 inputs);
     None is the backend's default, one bf16 pass on a TPU."""
 
-    def fn(q, k, v):
+    def fn(q, k, v, window=None):
         qh, kh, vh = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
+        # the window is a keyword only where there is one: the plain
+        # causal call is the call it was
+        kw = {} if window is None else {"window": window}
         if use_flash:
             out = flash_attention(qh, kh, vh, causal=causal,
-                                  interpret=interpret, precision=precision)
+                                  interpret=interpret, precision=precision,
+                                  **kw)
         else:
             with jax.default_matmul_precision(precision or "default"):
-                out = attention_reference(qh, kh, vh, causal=causal)
+                out = attention_reference(qh, kh, vh, causal=causal, **kw)
         return out.transpose(0, 2, 1, 3)
 
     return fn
@@ -262,6 +279,219 @@ class OlmoeDecoder(nn.Module):
                 d_model=d, n_heads=self.n_heads, n_experts=self.n_experts,
                 experts_per_tok=self.experts_per_tok,
                 expert_width=self.expert_width, rope_theta=self.rope_theta,
+                norm_eps=self.norm_eps, attn_fn=self.attn_fn,
+            )(x)
+        with jax.named_scope("head_loss"):  # lm/model.py's NLL joins it
+            x = rms_norm(x, self.param("final_norm", nn.initializers.ones,
+                                       (d,)), self.norm_eps)
+            logits = x @ self.param("head", _INIT, (d, self.vocab))
+            return nn.log_softmax(logits)
+
+
+# ---------------------------------------------------------------------------
+# The windowed sparse-expert block (Mellum 2, JetBrains; ``model_type``
+# ``mellum``; the configuration's keys are those of its ``config.json``).
+# Grouped KV heads of a width of their own (not ``d_model / heads``),
+# sliding-window attention on most layers and full attention on every
+# ``full_every``-th with a rotary table per layer type (YaRN on the full
+# layers), top-k of E experts with the k weights renormalised, every
+# layer sparse, and **a share of the experts**: the layer is told which
+# contiguous range it holds (``experts_first``, ``experts_held``), routes
+# over all ``n_experts`` and computes its own experts' part
+# (``parallel/moe.py``, *A share of the experts*).  The plain float32
+# reference it is held to is ``lm/mellum_reference.py``; that file
+# shares no code with this one.
+# ---------------------------------------------------------------------------
+
+#: The token table's own scale: what keeps the routing of a share of
+#: the experts even.  At std 0.02 a token's row is small beside the
+#: attention's output, which is nearly the same at every position, so
+#: every token's router input points the same way and the seeded routing
+#: collapses onto a few experts (OLMoE's does: PERF.md section 6, PR
+#: 26); with a share of the experts held that would make the work
+#: anything from nothing to everything by the seed.  At std 1 the seeded
+#: routing follows token identity in the first layer, but the later
+#: layers already read a max-over-mean of 2.2-2.6, and momentum SGD at
+#: any of the rates tried collapses them within 20-50 steps: what the
+#: first steps learn (putting down the head's unused rows) is one
+#: direction added to every position of the residual stream.  At std 8
+#: the token's own row stays the largest thing in the stream for the
+#: hundreds of steps a run makes: both counters hold still through the
+#: window (v5e, PERF.md section 6, PR 30).  The first operation on the
+#: stream is an RMSNorm, so the scale says only how large a token's row
+#: is beside what the layers add to it.
+MELLUM_EMBED_INIT = nn.initializers.normal(stddev=8.0)
+
+
+def yarn_inv_freq(head: int, theta: float, factor: float, original: int,
+                  beta_fast: float, beta_slow: float) -> np.ndarray:
+    """YaRN's per-pair inverse frequencies (Peng et al., arXiv:2309.00071,
+    as Hugging Face's ``_compute_yarn_parameters`` has them): pair ``j``
+    of ``head / 2`` keeps ``theta^(-2j/head)`` where it turns more than
+    ``beta_fast`` times over the original context, takes it divided by
+    ``factor`` where it turns less than ``beta_slow`` times, and a linear
+    blend between."""
+    def pair_of(turns):  # the pair that makes ``turns`` rotations
+        return (head * math.log(original / (turns * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(pair_of(beta_fast)), 0)
+    high = min(math.ceil(pair_of(beta_slow)), head - 1)
+    j = np.arange(head // 2, dtype=np.float64)
+    ramp = np.clip((j - low) / max(high - low, 1e-3), 0.0, 1.0)
+    plain = theta ** (-2.0 * j / head)
+    return ((1.0 - ramp) * plain + ramp * plain / factor).astype(np.float32)
+
+
+def rope_by(x: jnp.ndarray, inv_freq: np.ndarray,
+            scale: float = 1.0) -> jnp.ndarray:
+    """:func:`rope` with the inverse frequencies given and ``cos``,
+    ``sin`` multiplied by ``scale`` (YaRN's ``attention_factor``)."""
+    l = x.shape[1]
+    angles = (jnp.arange(l, dtype=jnp.float32)[:, None]
+              * jnp.asarray(inv_freq)[None, :])
+    cos = (jnp.cos(angles) * scale)[None, :, None, :]
+    sin = (jnp.sin(angles) * scale)[None, :, None, :]
+    x1, x2 = jnp.split(x, 2, axis=-1)
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+
+
+class MellumBlock(nn.Module):
+    d_model: int
+    n_heads: int
+    kv_heads: int
+    head_dim: int
+    n_experts: int           # the router's width: every expert there is
+    experts_per_tok: int
+    expert_width: int
+    experts_first: int = 0   # the share held here: a contiguous range
+    experts_held: int = 0    # 0: all of them
+    window: int = 0          # 0: a full-attention layer
+    rope_theta: float = 500000.0
+    yarn: Optional[tuple] = None  # full layers: (factor, original,
+    #                               beta_fast, beta_slow, attention_factor)
+    norm_eps: float = 1e-6
+    attn_fn: Optional[AttnFn] = None
+
+    @nn.compact
+    def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
+        b, l, d = x.shape
+        hq, hkv, hd = self.n_heads, self.kv_heads, self.head_dim
+        e, f = self.n_experts, self.expert_width
+        held = self.experts_held or e
+        first = self.experts_first if held < e else None
+        attn = self.attn_fn if self.attn_fn is not None else default_attn()
+        ones = nn.initializers.ones
+        if self.window or self.yarn is None:  # the plain rotary table
+            inv_freq, scale = (self.rope_theta ** (
+                -np.arange(0, hd, 2, dtype=np.float64) / hd)
+            ).astype(np.float32), 1.0
+        else:
+            inv_freq = yarn_inv_freq(hd, self.rope_theta, *self.yarn[:4])
+            scale = float(self.yarn[4])
+
+        # a windowed layer's operations go under a scope of their own,
+        # so that a trace tells the two kernels' time apart; flat, like
+        # every scope of the model
+        with jax.named_scope("attn_window" if self.window else "attn"):
+            # The attention path runs at the backend's default, one bf16
+            # pass: without a query/key norm the scores are O(1), and on
+            # the v5e the whole path above it (OLMoE's ATTN_PRECISION and
+            # the kernel on float32 inputs) moved the gradient's error
+            # against the float32 reference from 0.073% to 0.067% for
+            # three times the attention's time (PERF.md section 6, PR 30).
+            h = rms_norm(x, self.param("attn_norm", ones, (d,)), self.norm_eps)
+            q = h @ self.param("wq", _INIT, (d, hq * hd))
+            k = h @ self.param("wk", _INIT, (d, hkv * hd))
+            v = h @ self.param("wv", _INIT, (d, hkv * hd))
+            q = rope_by(q.reshape(b, l, hq, hd), inv_freq, scale)
+            k = rope_by(k.reshape(b, l, hkv, hd), inv_freq, scale)
+            v = v.reshape(b, l, hkv, hd)
+            out = (attn(q, k, v, window=self.window) if self.window
+                   else attn(q, k, v))
+            x = x + out.reshape(b, l, hq * hd) @ self.param(
+                "wo", _INIT, (hq * hd, d))
+
+        norm = self.param("mlp_norm", ones, (d,))
+        router = self.param("router", _INIT, (d, e))
+        wg = self.param("experts_gate", _INIT, (held, d, f))
+        wu = self.param("experts_up", _INIT, (held, d, f))
+        wd = self.param("experts_down", _INIT, (held, f, d))
+        k_tok, eps = self.experts_per_tok, self.norm_eps
+
+        # The dropless dispatch has k T rows whatever the share holds;
+        # kept for the backward pass in every layer they would not fit
+        # beside the model at the sequence this block trains at, so the
+        # branch is computed again there and one layer's rows live at a
+        # time.
+        @jax.checkpoint
+        def sparse(x, norm, router, wg, wu, wd):
+            with jax.named_scope("router"):
+                h = rms_norm(x, norm, eps).reshape(b * l, d)
+                logits = jnp.matmul(h, router, precision=ROUTER_PRECISION)
+                probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+                # top-k over all the experts, the k weights renormalised
+                # over the chosen k whether held here or not
+                weights, experts = moe.route_top_k(probs, k_tok,
+                                                   renormalise=True)
+                stats = (moe.load_max_over_mean(experts, e),
+                         moe.held_rows_share(experts, self.experts_first,
+                                             held))
+            y = moe.dispatch_top_k(
+                h, weights, experts, e,
+                lambda rows, sizes: moe.swiglu_experts(
+                    rows, sizes, wg, wu, wd, first))
+            return y.reshape(b, l, d), stats
+
+        y, (load, share) = sparse(x, norm, router, wg, wu, wd)
+        # telemetry, read only where the caller makes ``intermediates``
+        # mutable (lm/model.py stats)
+        self.sow("intermediates", "moe_load", load)
+        self.sow("intermediates", "moe_held", share)
+        return x + y
+
+
+class MellumDecoder(nn.Module):
+    """Causal LM of :class:`MellumBlock` layers: a token table, the
+    blocks (layer ``i`` is a full-attention layer iff ``(i + 1) %
+    full_every == 0``, the others slide a window), a final RMSNorm and
+    an untied head; returns log-probabilities like the other decoders."""
+
+    vocab: int = 256
+    d_model: int = 64
+    n_heads: int = 4
+    kv_heads: int = 2
+    head_dim: int = 32
+    n_layers: int = 4
+    n_experts: int = 8
+    experts_per_tok: int = 2
+    expert_width: int = 32
+    experts_first: int = 0
+    experts_held: int = 0
+    window: int = 16
+    full_every: int = 4
+    rope_theta: float = 500000.0
+    yarn: Optional[tuple] = None
+    norm_eps: float = 1e-6
+    attn_fn: Optional[AttnFn] = None
+
+    @nn.compact
+    def __call__(self, tokens: jnp.ndarray) -> jnp.ndarray:
+        d = self.d_model
+        with jax.named_scope("embed"):
+            x = self.param("embed", MELLUM_EMBED_INIT,
+                           (self.vocab, d))[tokens]
+        for i in range(self.n_layers):
+            full = self.full_every and (i + 1) % self.full_every == 0
+            x = MellumBlock(
+                d_model=d, n_heads=self.n_heads, kv_heads=self.kv_heads,
+                head_dim=self.head_dim, n_experts=self.n_experts,
+                experts_per_tok=self.experts_per_tok,
+                expert_width=self.expert_width,
+                experts_first=self.experts_first,
+                experts_held=self.experts_held,
+                window=0 if full else self.window,
+                rope_theta=self.rope_theta, yarn=self.yarn,
                 norm_eps=self.norm_eps, attn_fn=self.attn_fn,
             )(x)
         with jax.named_scope("head_loss"):  # lm/model.py's NLL joins it

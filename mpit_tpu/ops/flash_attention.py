@@ -17,6 +17,14 @@ showcase the rebuild adds on top of capability parity.  Design:
   attends a local Q chunk against a remote KV chunk
   (:mod:`mpit_tpu.parallel.ring_attention`).
 - ``kv_len`` masks padded keys so inputs need not be block-multiples.
+- **Grouped KV heads** by the index map: a group's query heads are
+  folded into the kernel's rows over the one KV head they share, so
+  ``k`` and ``v`` are read where they lie (no repeat is materialised)
+  and ``dk``, ``dv`` are summed over the group in the backward kernel's
+  own accumulator.  The head counts travel in the shapes.
+- **A sliding window** (``window``, causal): a block wholly outside
+  ``[i - window + 1, i]`` is skipped like a block above the diagonal,
+  forward and in both backward kernels (:func:`_block_bounds`).
 
 :func:`flash_attention` is the user op (normalized output, custom VJP:
 pallas backward in the standard flash schedule — P is recomputed
@@ -117,14 +125,42 @@ def _long_blocks_fit_vmem(bq: int, bk: int) -> bool:
 # ---------------------------------------------------------------------------
 
 
-def _mask(sh_q: int, sh_k: int, q_offset, kv_offset, kv_len, causal: bool):
-    """Boolean (Lq, Lk) validity mask in *global* coordinates."""
+def _mask(sh_q: int, sh_k: int, q_offset, kv_offset, kv_len, causal: bool,
+          window: int | None = None):
+    """Boolean (Lq, Lk) validity mask in *global* coordinates.  With a
+    ``window`` (causal only) query ``i`` sees key ``j`` iff ``0 <= i - j
+    < window``: its own position and the ``window - 1`` before it."""
     qi = q_offset + jnp.arange(sh_q)[:, None]
     kj = kv_offset + jnp.arange(sh_k)[None, :]
     valid = (kj - kv_offset) < kv_len
     if causal:
         valid = valid & (qi >= kj)
+    if window is not None:
+        valid = valid & (qi - kj < window)
     return valid
+
+
+def _check_window(window, causal: bool) -> int | None:
+    """A window is the sliding causal one: it bounds how far *back* a
+    query looks, so without ``causal`` it would be half a mask."""
+    if window is None:
+        return None
+    if not causal or int(window) < 1:
+        raise ValueError("window needs causal=True and window >= 1")
+    return int(window)
+
+
+def _group_queries(q, k):
+    """Grouped KV heads travel in the shapes: ``q (..., Hq, Lq, D)``
+    over ``k (..., Hkv, Lk, D)`` with ``Hq = G * Hkv`` is returned as
+    ``(..., Hkv, G, Lq, D)`` (query head ``g`` attends KV head ``g //
+    G``), one rank above ``k``; equal heads come back as they are."""
+    if q.ndim < 3 or q.shape[-3] == k.shape[-3]:
+        return q
+    hq, hkv = q.shape[-3], k.shape[-3]
+    if hq % hkv:
+        raise ValueError(f"{hq} query heads over {hkv} KV heads")
+    return q.reshape(*q.shape[:-3], hkv, hq // hkv, *q.shape[-2:])
 
 
 def attention_reference(
@@ -136,13 +172,20 @@ def attention_reference(
     sm_scale: float | None = None,
     q_offset=0,
     kv_offset=0,
+    window: int | None = None,
 ) -> jnp.ndarray:
     """Plain softmax attention over the last two axes; leading axes batch.
-    Rows with no valid key return zeros (matches the ring/partial path)."""
+    Rows with no valid key return zeros (matches the ring/partial path).
+    ``window`` as in :func:`_mask`; fewer KV heads than query heads
+    (axis -3) are repeated over their groups, materialised."""
+    window = _check_window(window, causal)
+    if q.ndim >= 3 and q.shape[-3] != k.shape[-3]:
+        groups = q.shape[-3] // k.shape[-3]
+        k, v = (jnp.repeat(x, groups, axis=-3) for x in (k, v))
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(q.shape[-1])
     s = jnp.einsum("...qd,...kd->...qk", q, k).astype(jnp.float32) * scale
     valid = _mask(q.shape[-2], k.shape[-2], q_offset, kv_offset,
-                  k.shape[-2], causal)
+                  k.shape[-2], causal, window)
     s = jnp.where(valid, s, NEG_INF)
     m = jnp.max(s, axis=-1, keepdims=True)
     m_safe = jnp.where(jnp.isneginf(m), 0.0, m)
@@ -203,14 +246,25 @@ def finalize_partials(acc, l, dtype=jnp.float32):
 # ---------------------------------------------------------------------------
 
 
+def _q_row0(qoff_ref, i, block_q, q_blocks):
+    """Global position of the first query row of q block ``i``.  With
+    grouped KV heads the query heads of a group lie one after another
+    along the rows, ``q_blocks`` blocks each, so the position starts
+    over at every head; ``q_blocks`` None is the equal-heads program,
+    with no modulus in it."""
+    if q_blocks is not None:
+        i = i % q_blocks
+    return qoff_ref[0, 0] + i * block_q
+
+
 def _block_bounds(qoff_ref, kvoff_ref, kvlen_ref, i, j, *, causal,
-                  block_q, block_k):
+                  block_q, block_k, window=None, q_blocks=None):
     """(live, full) triage for the (i, j) tile — the ONE copy of the
-    off-by-one-sensitive causal boundary rule, shared by forward and
-    both backward kernels: dead blocks skip everything, full blocks take
-    the mask-free fast path, edge (diagonal / kv_len-straddling) blocks
-    mask."""
-    q_lo = qoff_ref[0, 0] + i * block_q
+    off-by-one-sensitive causal and window boundary rules, shared by
+    forward and both backward kernels: dead blocks skip everything, full
+    blocks take the mask-free fast path, edge (diagonal / window-edge /
+    kv_len-straddling) blocks mask."""
+    q_lo = _q_row0(qoff_ref, i, block_q, q_blocks)
     k_hi_local = (j + 1) * block_k  # exclusive
     live = j * block_k < kvlen_ref[0, 0]
     full = k_hi_local <= kvlen_ref[0, 0]
@@ -222,11 +276,31 @@ def _block_bounds(qoff_ref, kvoff_ref, kvlen_ref, i, j, *, causal,
         full = jnp.logical_and(
             full, q_lo >= kvoff_ref[0, 0] + k_hi_local - 1
         )
+    if window is not None:
+        # live: the block's last key is inside the first row's window;
+        # full: even its first key is inside the last row's
+        k_max = kvoff_ref[0, 0] + k_hi_local - 1
+        live = jnp.logical_and(live, q_lo - k_max < window)
+        full = jnp.logical_and(
+            full,
+            q_lo + (block_q - 1) - (kvoff_ref[0, 0] + j * block_k) < window)
     return live, full
 
 
+def _valid(qi, kj_local, kvoff_ref, kvlen_ref, causal, window):
+    """Elementwise validity of an edge block: the mask of
+    :func:`_mask` on a tile's global query rows and local key columns."""
+    valid = kj_local < kvlen_ref[0, 0]
+    if causal:
+        valid = valid & (qi >= kvoff_ref[0, 0] + kj_local)
+    if window is not None:
+        valid = valid & (qi - (kvoff_ref[0, 0] + kj_local) < window)
+    return valid
+
+
 def _fa_kernel(qoff_ref, kvoff_ref, kvlen_ref, q_ref, k_ref, v_ref, o_ref,
-               *rest, causal, scale, block_q, block_k, partial, precision):
+               *rest, causal, scale, block_q, block_k, partial, precision,
+               window=None, q_blocks=None):
     if partial:
         m_out, l_out, acc_scr, m_scr, l_scr = rest
     else:
@@ -246,8 +320,9 @@ def _fa_kernel(qoff_ref, kvoff_ref, kvlen_ref, q_ref, k_ref, v_ref, o_ref,
     live, full = _block_bounds(
         qoff_ref, kvoff_ref, kvlen_ref, i, j,
         causal=causal, block_q=block_q, block_k=block_k,
+        window=window, q_blocks=q_blocks,
     )
-    q_lo = qoff_ref[0, 0] + i * block_q
+    q_lo = _q_row0(qoff_ref, i, block_q, q_blocks)
 
     def _block(masked):
         s = jax.lax.dot_general(
@@ -260,9 +335,8 @@ def _fa_kernel(qoff_ref, kvoff_ref, kvlen_ref, q_ref, k_ref, v_ref, o_ref,
                   + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0))
             kj_local = (j * block_k
                         + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1))
-            valid = kj_local < kvlen_ref[0, 0]
-            if causal:
-                valid = valid & (qi >= kvoff_ref[0, 0] + kj_local)
+            valid = _valid(qi, kj_local, kvoff_ref, kvlen_ref, causal,
+                           window)
             s = jnp.where(valid, s, _BIG_NEG)
 
         # Finite sentinel algebra: m_new >= any valid score, so
@@ -389,20 +463,53 @@ def _lse_of(m, l):
     return m + jnp.log(jnp.where(l == 0.0, 1.0, l))
 
 
+def _fold(x, lq_p, d_p):
+    """``(G, lq, d)`` padded to ``(G, lq_p, d_p)`` and laid out as ``(G
+    * lq_p, d_p)`` rows, head after head (see :func:`_q_row0`); a plain
+    ``(lq, d)`` is only padded."""
+    if x.ndim == 2:
+        return jnp.pad(x, ((0, lq_p - x.shape[0]), (0, d_p - x.shape[1])))
+    g, lq, d = x.shape
+    return jnp.pad(x, ((0, 0), (0, lq_p - lq), (0, d_p - d))).reshape(
+        g * lq_p, d_p)
+
+
+def _unfold(x, like, lq_p):
+    """The inverse of :func:`_fold` on a kernel's row output: back to
+    ``like``'s leading shape, true rows and true width."""
+    if like.ndim == 2:
+        return x[:like.shape[0], :like.shape[1]]
+    g, lq, d = like.shape
+    return x.reshape(g, lq_p, -1)[:, :lq, :d]
+
+
+def _unfold_stat(x, like, lq_p):
+    """Lane 0 of a ``(rows, LANE)`` row statistic, unfolded as
+    :func:`_unfold` does the rows."""
+    if like.ndim == 2:
+        return x[:like.shape[0], 0]
+    return x[:, 0].reshape(like.shape[0], lq_p)[:, :like.shape[1]]
+
+
 def _fa_2d(q, k, v, q_offset, kv_offset, *, causal, sm_scale, block_q,
-           block_k, interpret, partial=False, precision=None):
+           block_k, interpret, partial=False, precision=None, window=None):
     """Core call on (Lq, D) x (Lk, D); pads to tiles.  Returns the
     normalized (Lq, D) output, or with ``partial`` the unnormalized
-    ``(acc, m, l)`` triple (f32) for cross-chunk merging."""
-    lq, d = q.shape
+    ``(acc, m, l)`` triple (f32) for cross-chunk merging.  ``q`` of
+    ``(G, Lq, D)`` is a group of query heads over the one KV head: its
+    heads are folded into the rows, so ``k`` and ``v`` are read where
+    they lie and never repeated."""
+    lq, d = q.shape[-2:]
     lk = k.shape[0]
+    groups = q.shape[0] if q.ndim == 3 else 1
     scale, bq, bk, lq_p, lk_p, d_p = _tile_dims(
         lq, lk, d, block_q, block_k, sm_scale, q.dtype, fwd_long_bq=True
     )
-    qp = jnp.pad(q, ((0, lq_p - lq), (0, d_p - d)))
+    qp = _fold(q, lq_p, d_p)
     kp = jnp.pad(k, ((0, lk_p - lk), (0, d_p - d)))
     vp = jnp.pad(v, ((0, lk_p - lk), (0, d_p - d)))
-    grid = (lq_p // bq, lk_p // bk)
+    rows = groups * lq_p
+    grid = (rows // bq, lk_p // bk)
     vmem_auto = _vmem_auto(bq, bk)
 
     sspec = pl.BlockSpec((1, 1), lambda i, j: (0, 0), memory_space=pltpu.SMEM)
@@ -411,17 +518,24 @@ def _fa_2d(q, k, v, q_offset, kv_offset, *, causal, sm_scale, block_q,
     if partial:
         out_specs = (qspec, rowspec, rowspec)
         out_shape = (
-            jax.ShapeDtypeStruct((lq_p, d_p), jnp.float32),
-            jax.ShapeDtypeStruct((lq_p, LANE), jnp.float32),
-            jax.ShapeDtypeStruct((lq_p, LANE), jnp.float32),
+            jax.ShapeDtypeStruct((rows, d_p), jnp.float32),
+            jax.ShapeDtypeStruct((rows, LANE), jnp.float32),
+            jax.ShapeDtypeStruct((rows, LANE), jnp.float32),
         )
     else:
         out_specs = qspec
-        out_shape = jax.ShapeDtypeStruct((lq_p, d_p), q.dtype)
+        out_shape = jax.ShapeDtypeStruct((rows, d_p), q.dtype)
+    # the window and the fold are keywords of the kernel only where they
+    # are in force: the causal equal-heads call is the program it was
+    extra = {}
+    if window is not None:
+        extra["window"] = window
+    if groups > 1:
+        extra["q_blocks"] = lq_p // bq
     res = pl.pallas_call(
         functools.partial(
             _fa_kernel, causal=causal, scale=scale, block_q=bq, block_k=bk,
-            partial=partial, precision=precision,
+            partial=partial, precision=precision, **extra,
         ),
         grid=grid,
         in_specs=[
@@ -446,8 +560,9 @@ def _fa_2d(q, k, v, q_offset, kv_offset, *, causal, sm_scale, block_q,
     )
     if partial:
         acc, m, l = res
-        return acc[:lq, :d], m[:lq, 0], l[:lq, 0]
-    return res[:lq, :d]
+        return (_unfold(acc, q, lq_p), _unfold_stat(m, q, lq_p),
+                _unfold_stat(l, q, lq_p))
+    return _unfold(res, q, lq_p)
 
 
 def flash_attention_partial(
@@ -463,18 +578,20 @@ def flash_attention_partial(
     block_k: int | None = None,
     interpret: bool | None = None,
     precision: str | None = None,
+    window: int | None = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Pallas twin of :func:`block_attention_partial`: unnormalized
     ``(acc, m, l)`` over ``(..., L, D)``.  Forward-only — ring attention
     pairs it with :func:`flash_attention_bwd_pair` under a custom VJP at
     the ring level
-    (:mod:`mpit_tpu.parallel.ring_attention`)."""
+    (:mod:`mpit_tpu.parallel.ring_attention`).  ``q`` one rank above
+    ``k`` is grouped (:func:`_group_queries`)."""
     f = lambda q2, k2, v2: _fa_2d(
         q2, k2, v2, q_offset, kv_offset, causal=causal, sm_scale=sm_scale,
         block_q=block_q, block_k=block_k, interpret=interpret, partial=True,
-        precision=precision,
+        precision=precision, window=window,
     )
-    for _ in range(q.ndim - 2):
+    for _ in range(k.ndim - 2):
         f = jax.vmap(f)
     return f(q, k, v)
 
@@ -497,7 +614,8 @@ def flash_attention_partial(
 
 def _bwd_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
               qoff_ref, kvoff_ref, kvlen_ref, i, j, *,
-              causal, scale, block_q, block_k, precision, masked):
+              causal, scale, block_q, block_k, precision, masked,
+              window=None, q_blocks=None):
     """Shared block math: recompute P and dS for the (i, j) tile.
     Matmul inputs stay in their native dtype (bf16 runs the MXU at full
     rate); softmax/derivative algebra is f32.  ``masked=False`` is the
@@ -509,13 +627,11 @@ def _bwd_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     ) * scale  # (block_q, block_k) f32
 
     if masked:
-        qi = (qoff_ref[0, 0] + i * block_q
+        qi = (_q_row0(qoff_ref, i, block_q, q_blocks)
               + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0))
         kj_local = (j * block_k
                     + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1))
-        valid = kj_local < kvlen_ref[0, 0]
-        if causal:
-            valid = valid & (qi >= kvoff_ref[0, 0] + kj_local)
+        valid = _valid(qi, kj_local, kvoff_ref, kvlen_ref, causal, window)
         # exp(s - lse) is only read where valid; all-masked rows have
         # lse = -inf and no valid element, so the inf branch is never
         # taken.
@@ -534,7 +650,8 @@ def _bwd_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _fa_bwd_dq_kernel(qoff_ref, kvoff_ref, kvlen_ref, q_ref, do_ref,
                       lse_ref, delta_ref, k_ref, v_ref, dq_ref, dq_scr, *,
-                      causal, scale, block_q, block_k, precision):
+                      causal, scale, block_q, block_k, precision,
+                      window=None, q_blocks=None):
     i, j = pl.program_id(0), pl.program_id(1)
     nj = pl.num_programs(1)
 
@@ -545,6 +662,7 @@ def _fa_bwd_dq_kernel(qoff_ref, kvoff_ref, kvlen_ref, q_ref, do_ref,
     live, full = _block_bounds(
         qoff_ref, kvoff_ref, kvlen_ref, i, j,
         causal=causal, block_q=block_q, block_k=block_k,
+        window=window, q_blocks=q_blocks,
     )
 
     def _block(masked):
@@ -553,6 +671,7 @@ def _fa_bwd_dq_kernel(qoff_ref, kvoff_ref, kvlen_ref, q_ref, do_ref,
             qoff_ref, kvoff_ref, kvlen_ref, i, j,
             causal=causal, scale=scale, block_q=block_q, block_k=block_k,
             precision=precision, masked=masked,
+            window=window, q_blocks=q_blocks,
         )
         dq_scr[:] = dq_scr[:] + scale * jax.lax.dot_general(
             ds.astype(k_ref.dtype), k_ref[:], (((1,), (0,)), ((), ())),
@@ -575,7 +694,8 @@ def _fa_bwd_dq_kernel(qoff_ref, kvoff_ref, kvlen_ref, q_ref, do_ref,
 def _fa_bwd_dkdv_kernel(qoff_ref, kvoff_ref, kvlen_ref, k_ref, v_ref,
                         q_ref, do_ref, lse_ref, delta_ref,
                         dk_ref, dv_ref, dk_scr, dv_scr, *,
-                        causal, scale, block_q, block_k, precision):
+                        causal, scale, block_q, block_k, precision,
+                        window=None, q_blocks=None):
     j, i = pl.program_id(0), pl.program_id(1)  # kv outer, q inner
     ni = pl.num_programs(1)
 
@@ -587,6 +707,7 @@ def _fa_bwd_dkdv_kernel(qoff_ref, kvoff_ref, kvlen_ref, k_ref, v_ref,
     live, full = _block_bounds(
         qoff_ref, kvoff_ref, kvlen_ref, i, j,
         causal=causal, block_q=block_q, block_k=block_k,
+        window=window, q_blocks=q_blocks,
     )
 
     def _block(masked):
@@ -595,6 +716,7 @@ def _fa_bwd_dkdv_kernel(qoff_ref, kvoff_ref, kvlen_ref, k_ref, v_ref,
             qoff_ref, kvoff_ref, kvlen_ref, i, j,
             causal=causal, scale=scale, block_q=block_q, block_k=block_k,
             precision=precision, masked=masked,
+            window=window, q_blocks=q_blocks,
         )
         dv_scr[:] = dv_scr[:] + jax.lax.dot_general(
             p.astype(do_ref.dtype), do_ref[:], (((0,), (0,)), ((), ())),
@@ -622,7 +744,8 @@ def _fa_bwd_dkdv_kernel(qoff_ref, kvoff_ref, kvlen_ref, k_ref, v_ref,
 def _fa_bwd_fused_kernel(qoff_ref, kvoff_ref, kvlen_ref, k_ref, v_ref,
                          q_ref, do_ref, lse_ref, delta_ref,
                          dk_ref, dv_ref, dqp_ref, dk_scr, dv_scr, *,
-                         causal, scale, block_q, block_k, precision):
+                         causal, scale, block_q, block_k, precision,
+                         window=None, q_blocks=None):
     """Single-sweep backward: grid (kv outer, q inner) producing dK/dV
     (accumulated in VMEM scratch) AND the dQ contribution of this kv
     block (written once per program into a (n_kv_blocks, Lq, D) partial
@@ -639,6 +762,7 @@ def _fa_bwd_fused_kernel(qoff_ref, kvoff_ref, kvlen_ref, k_ref, v_ref,
     live, full = _block_bounds(
         qoff_ref, kvoff_ref, kvlen_ref, i, j,
         causal=causal, block_q=block_q, block_k=block_k,
+        window=window, q_blocks=q_blocks,
     )
 
     def _block(masked):
@@ -647,6 +771,7 @@ def _fa_bwd_fused_kernel(qoff_ref, kvoff_ref, kvlen_ref, k_ref, v_ref,
             qoff_ref, kvoff_ref, kvlen_ref, i, j,
             causal=causal, scale=scale, block_q=block_q, block_k=block_k,
             precision=precision, masked=masked,
+            window=window, q_blocks=q_blocks,
         )
         dv_scr[:] = dv_scr[:] + jax.lax.dot_general(
             p.astype(do_ref.dtype), do_ref[:], (((0,), (0,)), ((), ())),
@@ -683,22 +808,29 @@ def _fa_bwd_fused_kernel(qoff_ref, kvoff_ref, kvlen_ref, k_ref, v_ref,
 
 def _rows_to_lanes(x, length_p):
     """(L,) f32 row stats -> (L_p, LANE) with the value broadcast across
-    lanes (the layout the kernels read back as ``ref[:, :1]``)."""
-    xp = jnp.pad(x.astype(jnp.float32), (0, length_p - x.shape[0]))
-    return jnp.broadcast_to(xp[:, None], (length_p, LANE))
+    lanes (the layout the kernels read back as ``ref[:, :1]``); a
+    group's (G, L) -> (G * L_p, LANE), as :func:`_fold` lays the rows."""
+    pad = [(0, 0)] * (x.ndim - 1) + [(0, length_p - x.shape[-1])]
+    xp = jnp.pad(x.astype(jnp.float32), pad).reshape(-1)  # folded heads
+    return jnp.broadcast_to(xp[:, None], (xp.shape[0], LANE))
 
 
 def _fa_2d_bwd(q, k, v, do, lse, delta, q_offset, kv_offset, *, causal,
                sm_scale, block_q, block_k, interpret, precision,
-               fused=True):
+               fused=True, window=None):
     """Backward core on (Lq, D) x (Lk, D): returns (dq, dk, dv).
 
     ``lse``/``delta`` are per-q-row f32 vectors (log-sum-exp from the
     forward; rowsum(dO*O)).  Padded q rows carry dO = 0 so their P/dS
     contribute nothing; padded k rows are masked by ``kv_len``.
+    ``q``, ``do`` of ``(G, Lq, D)`` (and ``lse``, ``delta`` of ``(G,
+    Lq)``) are a group of query heads over the one KV head, folded into
+    the rows as in the forward: ``dk`` and ``dv`` are then summed over
+    the group's heads inside the kernel, in the same VMEM accumulator.
     """
-    lq, d = q.shape
+    lq, d = q.shape[-2:]
     lk = k.shape[0]
+    groups = q.shape[0] if q.ndim == 3 else 1
     # bwd_long_bk only under the fused schedule: the 32k sweep measured
     # the win THERE (the halved dQ-partials transient is most of it);
     # the two-kernel schedule with bk=2048 is unmeasured, so the
@@ -707,12 +839,13 @@ def _fa_2d_bwd(q, k, v, do, lse, delta, q_offset, kv_offset, *, causal,
     scale, bq, bk, lq_p, lk_p, d_p = _tile_dims(
         lq, lk, d, block_q, block_k, sm_scale, q.dtype, bwd_long_bk=fused
     )
-    qp = jnp.pad(q, ((0, lq_p - lq), (0, d_p - d)))
+    qp = _fold(q, lq_p, d_p)
     kp = jnp.pad(k, ((0, lk_p - lk), (0, d_p - d)))
     vp = jnp.pad(v, ((0, lk_p - lk), (0, d_p - d)))
-    dop = jnp.pad(do, ((0, lq_p - lq), (0, d_p - d)))
+    dop = _fold(do, lq_p, d_p)
     lse_r = _rows_to_lanes(lse, lq_p)
     delta_r = _rows_to_lanes(delta, lq_p)
+    rows = groups * lq_p
     vmem_auto = _vmem_auto(bq, bk)
 
     sspec = pl.BlockSpec((1, 1), lambda i, j: (0, 0), memory_space=pltpu.SMEM)
@@ -723,6 +856,10 @@ def _fa_2d_bwd(q, k, v, do, lse, delta, q_offset, kv_offset, *, causal,
     )
     kw = dict(causal=causal, scale=scale, block_q=bq, block_k=bk,
               precision=precision)
+    if window is not None:  # keywords only where in force, as forward
+        kw["window"] = window
+    if groups > 1:
+        kw["q_blocks"] = lq_p // bq
     interp = _interpret(interpret)
 
     if fused:
@@ -748,14 +885,14 @@ def _fa_2d_bwd(q, k, v, do, lse, delta, q_offset, kv_offset, *, causal,
                                memory_space=pltpu.VMEM)
         dk, dv, dq_part = pl.pallas_call(
             functools.partial(_fa_bwd_fused_kernel, **kw),
-            grid=(nj, lq_p // bq),
+            grid=(nj, rows // bq),
             in_specs=[sspec, sspec, sspec, kvrow2, kvrow2, qrow2, qrow2,
                       qstat2, qstat2],
             out_specs=(kvrow2, kvrow2, dqpspec),
             out_shape=(
                 jax.ShapeDtypeStruct((lk_p, d_p), k.dtype),
                 jax.ShapeDtypeStruct((lk_p, d_p), v.dtype),
-                jax.ShapeDtypeStruct((nj, lq_p, d_p), jnp.float32),
+                jax.ShapeDtypeStruct((nj, rows, d_p), jnp.float32),
             ),
             scratch_shapes=[
                 pltpu.VMEM((bk, d_p), jnp.float32),
@@ -765,7 +902,7 @@ def _fa_2d_bwd(q, k, v, do, lse, delta, q_offset, kv_offset, *, causal,
             compiler_params=_fa_compiler_params(vmem_auto),
         )(*scalars, kp, vp, qp, dop, lse_r, delta_r)
         dq = jnp.sum(dq_part, axis=0).astype(q.dtype)
-        return dq[:lq, :d], dk[:lk, :d], dv[:lk, :d]
+        return _unfold(dq, q, lq_p), dk[:lk, :d], dv[:lk, :d]
 
     # Two-kernel fallback (fused=False).
     # Kernel 1: dQ — q rows outer, kv blocks inner.
@@ -774,10 +911,10 @@ def _fa_2d_bwd(q, k, v, do, lse, delta, q_offset, kv_offset, *, causal,
     kvrow = pl.BlockSpec((bk, d_p), lambda i, j: (j, 0), memory_space=pltpu.VMEM)
     dq = pl.pallas_call(
         functools.partial(_fa_bwd_dq_kernel, **kw),
-        grid=(lq_p // bq, lk_p // bk),
+        grid=(rows // bq, lk_p // bk),
         in_specs=[sspec, sspec, sspec, qrow, qrow, qstat, qstat, kvrow, kvrow],
         out_specs=qrow,
-        out_shape=jax.ShapeDtypeStruct((lq_p, d_p), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((rows, d_p), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d_p), jnp.float32)],
         interpret=interp,
         compiler_params=_fa_compiler_params(vmem_auto),
@@ -789,7 +926,7 @@ def _fa_2d_bwd(q, k, v, do, lse, delta, q_offset, kv_offset, *, causal,
     qstat2 = pl.BlockSpec((bq, LANE), lambda j, i: (i, 0), memory_space=pltpu.VMEM)
     dk, dv = pl.pallas_call(
         functools.partial(_fa_bwd_dkdv_kernel, **kw),
-        grid=(lk_p // bk, lq_p // bq),
+        grid=(lk_p // bk, rows // bq),
         in_specs=[sspec, sspec, sspec, kvrow2, kvrow2, qrow2, qrow2,
                   qstat2, qstat2],
         out_specs=(kvrow2, kvrow2),
@@ -805,10 +942,11 @@ def _fa_2d_bwd(q, k, v, do, lse, delta, q_offset, kv_offset, *, causal,
         compiler_params=_fa_compiler_params(vmem_auto),
     )(*scalars, kp, vp, qp, dop, lse_r, delta_r)
 
-    return dq[:lq, :d], dk[:lk, :d], dv[:lk, :d]
+    return _unfold(dq, q, lq_p), dk[:lk, :d], dv[:lk, :d]
 
 
-def _use_fused_bwd(q_shape, k_shape, d, dtype, sm_scale, block_q, block_k):
+def _use_fused_bwd(q_shape, k_shape, d, dtype, sm_scale, block_q, block_k,
+                   window=None):
     """Backward-schedule choice (the ONE decision point, made where the
     full vmapped batch shape is visible).
 
@@ -833,7 +971,15 @@ def _use_fused_bwd(q_shape, k_shape, d, dtype, sm_scale, block_q, block_k):
     batched array and let the op vmap internally (as the model zoo
     does); wrapping the op in an OUTER ``jax.vmap`` batches the
     custom-vjp rules per example, so this gate sees a batch of 1 and
-    undercounts the transient by the outer batch factor."""
+    undercounts the transient by the outer batch factor.
+
+    With a ``window``, ``auto`` is the two-kernel schedule: the fused
+    sweep owns a dQ-partial slot for every (kv block, q block) pair and
+    must zero the dead ones, and under a window most pairs are dead
+    (at 8k with window 1024 and 512-blocks 47 of 256 are live), so the
+    transient would be mostly zeros written and summed; the two-kernel
+    schedule skips a dead pair outright.  Fused under a window is
+    UNMEASURED (``MPIT_FA_FUSED_BWD=1`` still forces it)."""
     mode = os.environ.get("MPIT_FA_FUSED_BWD", "auto") or "auto"
     if mode == "0":
         return False
@@ -846,6 +992,8 @@ def _use_fused_bwd(q_shape, k_shape, d, dtype, sm_scale, block_q, block_k):
         raise ValueError(
             f"MPIT_FA_FUSED_BWD={mode!r}: expected '0', '1', or 'auto'"
         )
+    if window is not None:
+        return False
     lq, lk = q_shape[-2], k_shape[-2]
     # bwd_long_bk: the gate must see the SAME bk the executed backward
     # resolves (_fa_2d_bwd), or the transient estimate is for a
@@ -864,31 +1012,35 @@ def _use_fused_bwd(q_shape, k_shape, d, dtype, sm_scale, block_q, block_k):
 def flash_attention_bwd_pair(q, k, v, do, lse, *, causal=False, sm_scale=None,
                              q_offset=0, kv_offset=0, delta=None, o=None,
                              block_q=None, block_k=None, interpret=None,
-                             precision=None):
+                             precision=None, window=None):
     """Pallas flash backward for one (Q chunk, KV chunk) pair over
     ``(..., L, D)``: returns ``(dq, dk, dv)`` given the forward's row
     ``lse`` (shape ``(..., Lq)``) and either ``delta = rowsum(dO*O)`` or
     ``o`` to compute it from.  This is the per-ring-step backward op of
     :mod:`mpit_tpu.parallel.ring_attention` — O(block) extra memory.
+    ``q``, ``do`` (and ``lse``) one rank above ``k`` are grouped
+    (:func:`_group_queries`).
     """
     if delta is None:
         if o is None:
             raise ValueError("flash_attention_bwd_pair needs delta or o")
         delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), -1)
     fused = _use_fused_bwd(q.shape, k.shape, q.shape[-1], q.dtype,
-                           sm_scale, block_q, block_k)
+                           sm_scale, block_q, block_k, window)
     f = lambda q2, k2, v2, do2, lse2, delta2: _fa_2d_bwd(
         q2, k2, v2, do2, lse2, delta2, q_offset, kv_offset, causal=causal,
         sm_scale=sm_scale, block_q=block_q, block_k=block_k,
         interpret=interpret, precision=precision, fused=fused,
+        window=window,
     )
-    for _ in range(q.ndim - 2):
+    for _ in range(k.ndim - 2):
         f = jax.vmap(f)
     return f(q, k, v, do, lse, delta)
 
 
 @functools.lru_cache(maxsize=64)
-def _make_flash(causal, sm_scale, block_q, block_k, interpret, precision):
+def _make_flash(causal, sm_scale, block_q, block_k, interpret, precision,
+                window=None):
     """Differentiable flash op for fixed static config: pallas forward,
     pallas backward (flash schedule, O(block) memory — the forward's
     partial outputs provide the LSE residual)."""
@@ -898,9 +1050,9 @@ def _make_flash(causal, sm_scale, block_q, block_k, interpret, precision):
         f = lambda q2, k2, v2: _fa_2d(
             q2, k2, v2, q_offset, kv_offset, causal=causal,
             sm_scale=sm_scale, block_q=block_q, block_k=block_k,
-            interpret=interpret, precision=precision,
+            interpret=interpret, precision=precision, window=window,
         )
-        for _ in range(q.ndim - 2):
+        for _ in range(k.ndim - 2):
             f = jax.vmap(f)
         return f(q, k, v)
 
@@ -908,7 +1060,7 @@ def _make_flash(causal, sm_scale, block_q, block_k, interpret, precision):
         acc, m, l = flash_attention_partial(
             q, k, v, causal=causal, sm_scale=sm_scale, q_offset=q_offset,
             kv_offset=kv_offset, block_q=block_q, block_k=block_k,
-            interpret=interpret, precision=precision,
+            interpret=interpret, precision=precision, window=window,
         )
         o = finalize_partials(acc, l, dtype=q.dtype)
         lse = _lse_of(m, l)
@@ -920,7 +1072,7 @@ def _make_flash(causal, sm_scale, block_q, block_k, interpret, precision):
             q, k, v, g, lse, causal=causal, sm_scale=sm_scale,
             q_offset=q_offset, kv_offset=kv_offset, o=o,
             block_q=block_q, block_k=block_k, interpret=interpret,
-            precision=precision,
+            precision=precision, window=window,
         )
         return dq, dk, dv, None, None
 
@@ -941,9 +1093,23 @@ def flash_attention(
     block_k: int | None = None,
     interpret: bool | None = None,
     precision: str | None = None,
+    window: int | None = None,
 ) -> jnp.ndarray:
     """Flash attention over ``(..., L, D)`` with global-offset causal
     masking.  Leading axes are batched (vmapped); offsets may be traced.
+
+    **Grouped KV heads** travel in the shapes: ``q (..., Hq, L, D)``
+    over ``k, v (..., Hkv, L, D)`` with ``Hq`` a multiple of ``Hkv``;
+    query head ``g`` attends KV head ``g // (Hq // Hkv)``.  A group's
+    query heads are folded into the kernel's rows, so ``k`` and ``v``
+    are read where they lie (no repeat is materialised) and ``dk``,
+    ``dv`` are summed over the group inside the backward kernel.
+
+    ``window`` (causal only): query ``i`` sees key ``j`` iff ``0 <= i -
+    j < window``.  A block wholly outside the window is skipped like a
+    block above the diagonal, forward and backward
+    (:func:`_block_bounds`); the backward is then the two-kernel
+    schedule (:func:`_use_fused_bwd`).
 
     Default blocks are 1024x1024, growing to 2048x1024 at L >= 16384
     (defaults from a July 2026 sweep on a v5e the ledger has not
@@ -958,10 +1124,12 @@ def flash_attention(
     # it must be a static float, not a traced value (float() rejects
     # tracers with a clear error instead of leaking per-trace cache
     # entries).
+    window = _check_window(window, causal)
     fa = _make_flash(bool(causal),
                      None if sm_scale is None else float(sm_scale),
                      None if block_q is None else int(block_q),
                      None if block_k is None else int(block_k),
-                     _interpret(interpret), precision)
-    return fa(q, k, v, jnp.asarray(q_offset, jnp.int32),
-              jnp.asarray(kv_offset, jnp.int32))
+                     _interpret(interpret), precision, window)
+    out = fa(_group_queries(q, k), k, v, jnp.asarray(q_offset, jnp.int32),
+             jnp.asarray(kv_offset, jnp.int32))
+    return out.reshape(q.shape)

@@ -25,6 +25,7 @@ from typing import Any, Callable, NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 
+from mpit_tpu.obs import get_recorder
 from mpit_tpu.ops.fused_update import fused_enabled as _fused_enabled
 
 
@@ -127,15 +128,42 @@ class MSGD:
     """Object wrapper with the same lifecycle as the comm-aware optimizers,
     for uniform dispatch in trainers (reference goot.lua:66-89 dispatch)."""
 
-    def __init__(self, cfg: MSGDConfig, value_and_grad_fn: Callable[..., Tuple[jnp.ndarray, Any]]):
+    def __init__(self, cfg: MSGDConfig,
+                 value_and_grad_fn: Callable[..., Tuple[jnp.ndarray, Any]],
+                 has_aux: bool = False):
+        """``has_aux``: the step returns ``((loss, stats), grad)``,
+        ``stats`` the model's own {name: device array with one entry a
+        layer} (lm/model.py ``value_grad_stats``), as the parameter
+        server's shells take it (optim/shells.py).  Each step is then a
+        ``round`` span while obs records (phases ``step`` and
+        ``telemetry``), with the statistics noted on it, set on their
+        gauges and kept as ``stats_last``; with obs off they are never
+        fetched and no span exists."""
         self.cfg = cfg
         self._step = jax.jit(
             lambda w, state, *args: msgd_step(value_and_grad_fn, w, state, cfg, *args)
         )
         self.state: dict | None = None
+        self._has_aux = has_aux
+        self._spans = get_recorder()
+        self.rounds = 0  # steps done: the ``round`` of the spans
+        self.stats_last: dict = {}  # name -> the last recorded step's values
 
     def step(self, w: Any, *fn_args: Any) -> Tuple[Any, jnp.ndarray]:
         if self.state is None:
             self.state = msgd_init(w)
-        w, self.state, loss = self._step(w, self.state, *fn_args)
+        if not self._has_aux:
+            w, self.state, loss = self._step(w, self.state, *fn_args)
+            return w, loss
+        rec = self._spans
+        span = rec.round(self.rounds, "step")
+        w, self.state, (loss, stats) = self._step(w, self.state, *fn_args)
+        if rec.enabled:
+            from mpit_tpu.optim.sync import note_stats
+
+            jax.block_until_ready(w)
+            span.mark("telemetry")
+            note_stats(self, span, stats)
+        span.end()
+        self.rounds += 1
         return w, loss

@@ -341,6 +341,22 @@ def attach(opt: Any) -> None:
         "mpit_round_streamed_total", rank=getattr(opt.pc, "rank", None))
 
 
+def note_stats(opt: Any, span: Any, stats: Dict[str, jnp.ndarray]) -> None:
+    """The model's own statistics of one step (device arrays with one
+    entry a layer), fetched and recorded: each noted on ``span`` by its
+    name, set on the gauge ``mpit_<name>`` by layer and kept as
+    ``opt.stats_last`` for the rank result.  For callers that are
+    recording only: the fetch waits for the step."""
+    opt.stats_last = {
+        name: [float(x) for x in np.ravel(np.asarray(value))]
+        for name, value in stats.items()}
+    span.note(**opt.stats_last)
+    gauge = get_registry().gauge
+    for name, per_layer in opt.stats_last.items():
+        for layer, value in enumerate(per_layer):
+            gauge(f"mpit_{name}", layer=layer).set(value)
+
+
 def push_pull(opt: Any, payload: jnp.ndarray,
               loss: Optional[jnp.ndarray] = None, *, consume: bool = False,
               stats: Optional[Dict[str, jnp.ndarray]] = None,
@@ -378,14 +394,7 @@ def push_pull(opt: Any, payload: jnp.ndarray,
         if loss is not None:
             opt._m_loss.set(float(loss))
         if stats:
-            opt.stats_last = {
-                name: [float(x) for x in np.ravel(np.asarray(value))]
-                for name, value in stats.items()}
-            span.note(**opt.stats_last)
-            gauge = get_registry().gauge
-            for name, per_layer in opt.stats_last.items():
-                for layer, value in enumerate(per_layer):
-                    gauge(f"mpit_{name}", layer=layer).set(value)
+            note_stats(opt, span, stats)
     span.end()
     opt.sync_seconds += span.phase_seconds("exchange")  # 0.0 if off
     opt.rounds += 1

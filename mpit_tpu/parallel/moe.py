@@ -9,12 +9,24 @@ over an ``ep`` mesh axis (:func:`ep_moe`, with its own oracle
 are flattened and sorted by expert, the tokens' rows gathered in that
 order, and each expert matrix applied to its own run of rows by one
 grouped product over all experts (:func:`grouped_dot`: the rows' FLOPs
-only, ``k / E`` of the dense form's).  The group sizes are the router's counts and sum to
-exactly ``k T``, a static row count: no capacity, so no token ever loses
-an expert however uneven the routing.  The results are scaled by the
-router's weights and summed back per token.  Both permutations are
-gathers in both directions (a permutation's transpose is its inverse),
-so the backward pass has no scatter.
+only, ``k / E`` of the dense form's).  The group sizes are the router's
+counts over all ``E`` experts and the row count is ``k T``, static: no
+capacity, so no token ever loses an expert however uneven the routing.
+The results are scaled by the router's weights and summed back per
+token.  Both permutations are gathers in both directions (a
+permutation's transpose is its inverse), so the backward pass has no
+scatter.
+
+**A share of the experts** (``held``).  Where the experts are spread
+over chips, one chip's layer is told which contiguous range it holds
+(``held = (first, count)``) and is handed those experts' matrices only.
+The router still scores and ranks all ``E``; the sort still orders all
+``k T`` rows by expert, so the held experts' rows are one run of them;
+the grouped product starts at group ``first`` and visits ``count``
+groups, so the rows of absent experts are computed by nobody, read as
+zero and pass zero back (:func:`grouped_dot`).  With everything held the
+groups sum to exactly ``k T``; with a share, to less.  Nothing here
+stands in for the absent chips or the exchange between them.
 
 **``ep_moe``** (not in the reference, SURVEY §2: EP absent).  TPU-native
 shape:
@@ -33,7 +45,7 @@ shape:
 from __future__ import annotations
 
 from functools import partial
-from typing import Callable, Tuple
+from typing import Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -41,12 +53,17 @@ from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
-def route_top_k(probs: jnp.ndarray, k: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
+def route_top_k(probs: jnp.ndarray, k: int, renormalise: bool = False
+                ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """The ``k`` largest router probabilities of each row and their
-    experts, ``(T, k)`` each, **not renormalised**.  Ties break towards
-    the lower expert index (``jax.lax.top_k`` puts the lower index
-    first)."""
-    return jax.lax.top_k(probs, k)
+    experts, ``(T, k)`` each; with ``renormalise`` the ``k`` weights are
+    divided by their sum (``norm_topk_prob``), else left as they are.
+    Ties break towards the lower expert index (``jax.lax.top_k`` puts
+    the lower index first)."""
+    weights, experts = jax.lax.top_k(probs, k)
+    if renormalise:
+        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    return weights, experts
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(3,))
@@ -119,19 +136,28 @@ def sort_by_expert(experts: jnp.ndarray, n_experts: int):
 #   only, shapes in whole tiles only (:func:`pallas_fits`).
 
 GMM_TILE_M = 256   # rows a tile; the row count must be a multiple
-GMM_TILE = 1024    # columns a tile, on both matrix dimensions
+GMM_TILE = 1024    # the most columns a tile takes, on both dimensions
+
+
+def _gmm_tile(x: int) -> int:
+    """The tile of a matrix dimension ``x``, a multiple of 128: the
+    whole of it up to :data:`GMM_TILE`, else the largest multiple of
+    128 that divides it and is no more than :data:`GMM_TILE` (1024 for
+    2048; 768 for 2304)."""
+    if x <= GMM_TILE:
+        return x
+    return max(t for t in range(128, GMM_TILE + 1, 128) if x % t == 0)
 
 
 def pallas_fits(m: int, k: int, n: int) -> bool:
     """Whether the Pallas kernels take ``(m, k) x (E, k, n)``: whole row
-    tiles, and each matrix dimension one tile or whole tiles of 1024."""
-    return m % GMM_TILE_M == 0 and all(
-        x % 128 == 0 and (x <= GMM_TILE or x % GMM_TILE == 0)
-        for x in (k, n))
+    tiles, and each matrix dimension a multiple of 128 (so whole tiles
+    of :func:`_gmm_tile`)."""
+    return m % GMM_TILE_M == 0 and k % 128 == 0 and n % 128 == 0
 
 
 def _gmm_tiling(k: int, n: int) -> Tuple[int, int, int]:
-    return GMM_TILE_M, min(k, GMM_TILE), min(n, GMM_TILE)
+    return GMM_TILE_M, _gmm_tile(k), _gmm_tile(n)
 
 
 def _megablox():
@@ -143,60 +169,100 @@ def _megablox():
         "jax.experimental.pallas.ops.tpu.megablox.gmm")
 
 
-@jax.custom_vjp
-def pallas_grouped_dot(rows, w, group_sizes):
+def _held_rows(group_sizes: jnp.ndarray, first: int, count: int,
+               m: int) -> jnp.ndarray:
+    """``(m, 1)`` bool: which of the rows sorted by expert belong to the
+    groups ``first .. first + count - 1``."""
+    ends = jnp.cumsum(group_sizes)
+    lo = ends[first] - group_sizes[first]
+    hi = ends[first + count - 1]
+    row = jnp.arange(m)[:, None]
+    return (row >= lo) & (row < hi)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(3,))
+def pallas_grouped_dot(rows, w, group_sizes, first=None):
     """The grouped product by the megablox kernels: three Mosaic calls
     forward and backward (the product, its rows' gradient, its weights'
-    gradient), float32 results.  Shapes :func:`pallas_fits` takes."""
+    gradient), float32 results.  Shapes :func:`pallas_fits` takes.
+    ``first`` None: ``w`` holds every group of ``group_sizes``.  An
+    int: ``w`` holds the ``w.shape[0]`` groups from ``first`` on (the
+    kernels' ``group_offset``); they visit those groups' row tiles only,
+    and the rows they never wrote are set to zero here, forward and in
+    the rows' gradient."""
     mb = _megablox()
-    return mb.gmm(rows.astype(jnp.bfloat16), w.astype(jnp.bfloat16),
-                  group_sizes, jnp.float32,
-                  _gmm_tiling(w.shape[1], w.shape[2]))
+    out = mb.gmm(rows.astype(jnp.bfloat16), w.astype(jnp.bfloat16),
+                 group_sizes, jnp.float32,
+                 _gmm_tiling(w.shape[1], w.shape[2]),
+                 **_offset(first))
+    return _zero_unheld(out, group_sizes, first, w.shape[0])
 
 
-def _pallas_grouped_dot_fwd(rows, w, group_sizes):
-    return pallas_grouped_dot(rows, w, group_sizes), (rows, w, group_sizes)
+def _offset(first):
+    return {} if first is None else {
+        "group_offset": jnp.asarray(first, jnp.int32)}
 
 
-def _pallas_grouped_dot_bwd(res, g):
+def _zero_unheld(x, group_sizes, first, count):
+    if first is None:
+        return x
+    return jnp.where(_held_rows(group_sizes, first, count, x.shape[0]),
+                     x, 0.0)
+
+
+def _pallas_grouped_dot_fwd(rows, w, group_sizes, first):
+    return (pallas_grouped_dot(rows, w, group_sizes, first),
+            (rows, w, group_sizes))
+
+
+def _pallas_grouped_dot_bwd(first, res, g):
     mb = _megablox()
     rows, w, group_sizes = res
     g16 = g.astype(jnp.bfloat16)
     k, n = w.shape[1], w.shape[2]
     d_rows = mb.gmm(g16, w.astype(jnp.bfloat16), group_sizes, jnp.float32,
-                    _gmm_tiling(n, k), transpose_rhs=True)
+                    _gmm_tiling(n, k), transpose_rhs=True, **_offset(first))
     d_w = mb.tgmm(rows.astype(jnp.bfloat16).swapaxes(0, 1), g16,
                   group_sizes, jnp.float32, _gmm_tiling(k, n),
-                  num_actual_groups=w.shape[0])
-    return d_rows, d_w, None
+                  num_actual_groups=w.shape[0], **_offset(first))
+    return _zero_unheld(d_rows, group_sizes, first, w.shape[0]), d_w, None
 
 
 pallas_grouped_dot.defvjp(_pallas_grouped_dot_fwd, _pallas_grouped_dot_bwd)
 
 
 def grouped_dot(rows: jnp.ndarray, w: jnp.ndarray,
-                group_sizes: jnp.ndarray) -> jnp.ndarray:
+                group_sizes: jnp.ndarray,
+                first: Optional[int] = None) -> jnp.ndarray:
     """The grouped product: :func:`pallas_grouped_dot` on a TPU where the
     shapes are whole tiles, ``jax.lax.ragged_dot`` everywhere else.  On
     the chip the other path is never silent: the benchmark counts the
     step's Mosaic calls against the ``experts`` kernel family
-    (``chipbench/arithmetic/olmoe.py``), and a run without them is not
-    ``correct``."""
+    (``chipbench/arithmetic/<module>.py``), and a run without them is
+    not ``correct``.  ``first``: ``w`` holds the ``w.shape[0]`` groups of
+    ``group_sizes`` from ``first`` on, and the other groups' rows come
+    back zero (the module's docstring, *A share of the experts*);
+    ``ragged_dot`` gets there by zero matrices in the absent groups'
+    places, which is fine where it runs (tests and tiny sizes)."""
     if (jax.default_backend() == "tpu"
             and pallas_fits(rows.shape[0], w.shape[1], w.shape[2])):
-        return pallas_grouped_dot(rows, w, group_sizes)
+        return pallas_grouped_dot(rows, w, group_sizes, first)
+    if first is not None:
+        w = jnp.zeros((group_sizes.shape[0],) + w.shape[1:], w.dtype).at[
+            first:first + w.shape[0]].set(w)
     return jax.lax.ragged_dot(rows, w, group_sizes)
 
 
 def swiglu_experts(rows: jnp.ndarray, group_sizes: jnp.ndarray,
-                   wg: jnp.ndarray, wu: jnp.ndarray,
-                   wd: jnp.ndarray) -> jnp.ndarray:
+                   wg: jnp.ndarray, wu: jnp.ndarray, wd: jnp.ndarray,
+                   first: Optional[int] = None) -> jnp.ndarray:
     """``(SiLU(rows Wg_e) * (rows Wu_e)) Wd_e`` for rows sorted by
     expert: three grouped products (:func:`grouped_dot`) over ``wg, wu
-    (E, d, f)`` and ``wd (E, f, d)``, no bias."""
-    gate = grouped_dot(rows, wg, group_sizes)
-    up = grouped_dot(rows, wu, group_sizes)
-    return grouped_dot(jax.nn.silu(gate) * up, wd, group_sizes)
+    (E, d, f)`` and ``wd (E, f, d)``, no bias; with ``first``, over the
+    held experts' matrices only."""
+    gate = grouped_dot(rows, wg, group_sizes, first)
+    up = grouped_dot(rows, wu, group_sizes, first)
+    return grouped_dot(jax.nn.silu(gate) * up, wd, group_sizes, first)
 
 
 def dispatch_top_k(x: jnp.ndarray, weights: jnp.ndarray,
@@ -206,7 +272,9 @@ def dispatch_top_k(x: jnp.ndarray, weights: jnp.ndarray,
     """``sum_j weights[t, j] * expert_{experts[t, j]}(x[t])`` for every
     token, dropless.  ``x (T, d)``; ``weights``, ``experts (T, k)``;
     ``expert_fn(rows, group_sizes)`` maps the ``T k`` rows sorted by
-    expert to their outputs (:func:`swiglu_experts`).  The sort, the
+    expert to their outputs (:func:`swiglu_experts`; one that holds a
+    share of the experts returns zero rows for the others, so those
+    assignments add nothing, forward and backward).  The sort, the
     gathers and the weighted sum run under the scope ``dispatch``, the
     experts under ``experts`` (flat, never nested: a trace books an
     operation under the one scope of its name stack)."""
@@ -226,6 +294,15 @@ def load_max_over_mean(experts: jnp.ndarray, n_experts: int) -> jnp.ndarray:
     mean count (1 is even; ``E / k`` is every token on the same k)."""
     counts = jnp.bincount(experts.reshape(-1), length=n_experts)
     return jnp.max(counts) * n_experts / experts.size
+
+
+def held_rows_share(experts: jnp.ndarray, first: int,
+                    count: int) -> jnp.ndarray:
+    """The share of the ``T k`` assignments that land on the held
+    experts ``first .. first + count - 1`` (uniform routing gives
+    ``count / E``)."""
+    held = (experts >= first) & (experts < first + count)
+    return jnp.mean(held.astype(jnp.float32))
 
 
 def moe_dense_reference(x, probs, wg, wu, wd, k: int):

@@ -211,13 +211,28 @@ LAUNCH_DEFAULTS = TRAINER_DEFAULTS.merged(
     lm=0,
     # the block: gpt2 (LayerNorm, learned positions, GELU MLP) | olmoe
     # (RMSNorm, rotary positions, q/k norm, top-k of E gated experts by
-    # sorted dropless dispatch); the lm_experts.. knobs are olmoe's
+    # sorted dropless dispatch) | mellum (grouped KV heads of their own
+    # width, window and full attention mixed, top-k renormalised, a
+    # share of the experts).  lm_experts .. lm_norm_eps are the sparse
+    # blocks'; lm_kv_heads .. lm_yarn_attn_factor are mellum's own
+    # (lm/model.py build has each one's meaning and what 0 stands for)
     lm_arch="gpt2",
     lm_experts=8,
     lm_experts_per_tok=2,
     lm_expert_width=32,
     lm_rope_theta=10000.0,
     lm_norm_eps=1e-5,
+    lm_kv_heads=0,
+    lm_head_dim=0,
+    lm_experts_first=0,
+    lm_experts_held=0,
+    lm_window=0,
+    lm_full_every=4,
+    lm_yarn_factor=0.0,
+    lm_yarn_orig=0,
+    lm_yarn_beta_fast=32.0,
+    lm_yarn_beta_slow=1.0,
+    lm_yarn_attn_factor=1.0,
     lm_d_model=64,
     lm_heads=4,
     lm_layers=2,
@@ -337,7 +352,12 @@ def lm_trainer_cfg(cfg: Config) -> Config:
     """The :data:`mpit_tpu.lm.trainer.LM_DEFAULTS`-shaped config for one
     launch config: shared optimizer/loop knobs carried over verbatim,
     lm_* knobs mapped onto the trainer's names."""
+    from mpit_tpu.lm.model import MELLUM_KEYS
+    from mpit_tpu.lm.trainer import LM_DEFAULTS
+
     return Config(
+        **{key: type(LM_DEFAULTS[key])(cfg.get(f"lm_{key}", LM_DEFAULTS[key]))
+           for key in MELLUM_KEYS},
         arch=str(cfg.get("lm_arch", "gpt2")),
         n_experts=int(cfg.get("lm_experts", 8)),
         experts_per_tok=int(cfg.get("lm_experts_per_tok", 2)),

@@ -54,7 +54,7 @@ def test_scopes_come_from_the_cells_configuration():
 def test_every_cells_vocabulary_reaches_the_program_through_the_launcher():
     from chipbench import run as runner
 
-    want = {"gpt2": 50257, "olmoe": 50304}
+    want = {"gpt2": 50257, "olmoe": 50304, "mellum": 12288}
     for name in CELLS:
         cell = spec_mod.load_cell(name)
         cfg = runner.launch_config(cell, seed=5)
@@ -72,7 +72,7 @@ def test_scopes_come_from_every_committed_configuration():
     # lists, in order of first mention
     assert spantree.model_scopes({}) == [
         "embed", "attn", "mlp", "head_loss", "update", "router", "dispatch",
-        "experts"]
+        "experts", "attn_window"]
 
 
 def olmoe_cases():
@@ -198,3 +198,157 @@ def test_the_benchmarks_copy_of_the_reference_is_the_programs_to_the_bit():
     assert np.array_equal(np.asarray(copy_grad), np.asarray(grad))
     assert cell.reference().GRAD_REL_TOL == 6.0e-3
     assert not hasattr(olmoe_reference, "GRAD_REL_TOL")
+
+
+# -- the Mellum configuration (PR 30) ---------------------------------------------
+
+MELLUM_CELL = "mellum2-l4e8-local"
+
+
+def mellum_cases():
+    return spec_mod.load_cell(MELLUM_CELL).arithmetic().hand_worked()
+
+
+@pytest.mark.parametrize("what,got,want", mellum_cases(),
+                         ids=[c[0] for c in mellum_cases()])
+def test_mellum_arithmetic_by_hand(what, got, want):
+    assert got == want, what
+
+
+def test_mellum_file_has_the_catalogs_keys_and_states_its_cuts():
+    """Every number of the catalog's entry under its own key (the
+    model-configs guide's ``architectures.jsonl``, read where it is
+    installed; the hand-copied numbers below where it is not), nested
+    groups whole; only ``reduced`` differs, no width among it, each cut
+    at the guide's floor with the published count beside it."""
+    import pathlib
+
+    cell = spec_mod.load_cell(MELLUM_CELL)
+    config = cell.config
+    catalog = {
+        "attention_bias": False, "head_dim": 128, "hidden_act": "silu",
+        "hidden_size": 2304, "intermediate_size": 7168,
+        "max_position_embeddings": 131072, "max_window_layers": 0,
+        "model_type": "mellum", "moe_intermediate_size": 896,
+        "norm_topk_prob": True, "num_attention_heads": 32,
+        "num_experts": 64, "num_experts_per_tok": 8,
+        "num_hidden_layers": 28, "num_key_value_heads": 4,
+        "rms_norm_eps": 1e-06, "sliding_window": 1024,
+        "tie_word_embeddings": False, "vocab_size": 98304,
+        "use_sliding_window": True}
+    path = pathlib.Path(
+        "/opt/skills/guides/model-configs/architectures.jsonl")
+    if path.exists():
+        rows = [json.loads(line) for line in path.read_text().splitlines()]
+        entry = next(r for r in rows if r["name"].startswith("Mellum2-12B"))
+        assert {k: v for k, v in entry["config"].items()
+                if not isinstance(v, (list, dict))} == catalog
+        assert entry["source_url"] == config["source"]
+        catalog = entry["config"]
+    differ = sorted(k for k, v in catalog.items() if config.get(k, "?") != v)
+    assert differ == sorted(config["reduced"]) == [
+        "num_experts", "num_hidden_layers", "vocab_size"]
+    assert config["published"] == {k: catalog[k] for k in config["reduced"]}
+    assert (config["num_hidden_layers"], config["num_experts"],
+            config["vocab_size"]) == (4, 8, 98304 // 8)   # the floors
+    assert config["router_experts"] == 64   # the router keeps its width
+    entry = next(c for c in cell.bench["configs"]
+                 if c["name"] == cell.config_name)
+    assert sorted(entry["reduced"]) == differ
+    assert cell.chips == 1
+    assert {"embed", "attn", "attn_window", "router", "dispatch", "experts",
+            "head_loss", "update"} == set(config["scopes"])
+    assert all(key in config for key in config["launcher_from"].values())
+    assert all(key in config for key in config["tiny"])
+
+
+def test_mellums_readers_find_nothing_in_a_run_without_the_block():
+    """What the parent's traced run hands them: a cell whose
+    configuration lists no such scope and holds no share, a program that
+    recorded no such counter, no merged trace: None, no raise."""
+    cell = spec_mod.load_cell("olmoe-l1-ps1w-su1")
+    run = {"cell": cell, "reduction": {"step_module": "jit_loss"},
+           "obs_trace": None, "peaks": None, "results": {},
+           "summary": {"worker_ranks": [1], "window": [0.0, 1.0]}}
+    for name in ("attn_window_ms_per_step", "attn_window_roofline",
+                 "held_experts_ms_per_step", "held_experts_roofline",
+                 "held_rows_share_pct"):
+        reader = spec_mod.load_reader(cell.root, cell.bench, name)
+        assert reader is not None and reader(dict(run)) is None
+
+
+def test_mellums_readers_read_a_hand_made_run(monkeypatch):
+    """The five readers on a reduction and a span tree made by hand:
+    three rounds with shares of 10%, 15% and 15% a layer, of which the
+    device trace holds the last two; 30 ms of Mosaic calls under
+    ``attn_window`` and 20 under ``experts`` over two runs of the
+    step."""
+    from chipbench import flops
+    from chipbench.layers import held_rows_share_pct, spantree
+
+    cell = spec_mod.load_cell(MELLUM_CELL)
+
+    class Round:
+        def __init__(self, k, share):
+            self.args = {"round": k, "moe_held_rows_share": [share] * 4}
+
+    class Tree:
+        def rounds(self):
+            return [Round(7, 0.10), Round(8, 0.15), Round(9, 0.15)]
+
+    monkeypatch.setattr(spantree, "xplane_path", lambda run: "a.xplane.pb")
+    monkeypatch.setattr(spantree, "anchors",
+                        lambda path: [(8, 0.0, 0.0), (9, 1.0, 1.0)])
+
+    run = {"cell": cell, "peaks": flops.load_peaks("TPU v5 lite"),
+           spantree.CACHE_KEY: Tree(),
+           "reduction": {"step_module_runs": 2, "mosaic_by_scope": {
+               "attn_window": (18, 0.060), "experts": (96, 0.040)}}}
+
+    def read(name):
+        return spec_mod.load_reader(cell.root, cell.bench, name)(run)
+
+    assert held_rows_share_pct.rounds_mean(run) == pytest.approx(
+        [0.10, 0.15, 0.15])
+    assert held_rows_share_pct.rounds_mean(run, {7}) == pytest.approx([0.10])
+    assert read("held_rows_share_pct") == pytest.approx(15.0)
+    assert read("attn_window_ms_per_step") == pytest.approx(30.0)
+    kernels = cell.arithmetic().kernels(cell.config, 1)
+    window = kernels["attn_window"]
+    assert read("attn_window_roofline") == pytest.approx(
+        100 * window["flops"] / 197e12 / 0.030)
+    # the experts' rows at 15 / 12.5 of the uniform expectation, by the
+    # traced rounds
+    cost = cell.arithmetic().experts_cost(cell.config, 1)
+    assert read("held_experts_roofline") == pytest.approx(
+        100 * max(cost["flops"] * 1.2 / 197e12,
+                  (cost["bytes"] + 0.2 * cost["rows_bytes"]) / 819e9) / 0.020)
+
+
+def test_the_benchmarks_copy_of_mellums_reference_is_the_programs_to_the_bit():
+    """``chipbench/reference/mellum_plain.py`` is a copy of
+    ``mpit_tpu/lm/mellum_reference.py``: the same loss and flat gradient,
+    bit for bit, at the tiny size, given the same share; only the
+    benchmark's copy carries tolerances."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from chipbench import run as runner
+    from chipbench.traffic.packed_bytes import packed_batch
+    from mpit_tpu.lm import mellum_reference
+
+    cell = spec_mod.load_cell(MELLUM_CELL)
+    cell.config.update(cell.config["tiny"])
+    cell.traffic["launcher"].update(device_policy="cpu", lm_use_flash=0)
+    flat = runner.build_model(cell, seed=7).flat
+    assert int(flat.w0.size) == cell.arithmetic().param_count(cell.config)
+    tokens = jnp.asarray(packed_batch(7, 0, 2, cell.config["train_seq"]))
+    copy_loss, copy_grad = cell.reference().loss_and_grad_flat(
+        flat.w0, flat.unravel, tokens, cell.config)
+    loss, grad = mellum_reference.loss_and_grad_flat(
+        flat.w0, flat.unravel, tokens, cell.config)
+    assert float(copy_loss) == float(loss)
+    assert np.array_equal(np.asarray(copy_grad), np.asarray(grad))
+    assert not hasattr(mellum_reference, "GRAD_REL_TOL")
+    assert 0 < cell.reference().LOSS_TOL_NATS < 1e-2
+    assert 0 < cell.reference().GRAD_REL_TOL < 1e-1

@@ -139,6 +139,9 @@ def test_the_grouped_product_is_each_groups_rows_times_its_matrix(sizes):
 @pytest.mark.parametrize("m,k,n,fits", [
     (32768, 2048, 1024, True), (32768, 1024, 2048, True),
     (256, 128, 128, True), (512, 64, 32, False), (100, 2048, 1024, False),
-    (256, 1536, 1024, False)])
+    # since PR 30 a dimension over one tile takes the largest multiple of
+    # 128 that divides it (768 for 1536 and for Mellum's 2304)
+    (256, 1536, 1024, True), (65536, 2304, 896, True),
+    (256, 2300, 896, False)])
 def test_pallas_takes_whole_tiles_only(m, k, n, fits):
     assert moe.pallas_fits(m, k, n) is fits
