@@ -51,7 +51,7 @@ def _as_pointer(obj):
 #: lockstep with transport.cpp (see kApiVersion comment there) whenever
 #: the exported C surface changes, so a stale .so fails loudly with a
 #: rebuild message instead of a confusing missing-symbol AttributeError.
-MT_API_VERSION = 17002
+MT_API_VERSION = 17003
 
 
 class NativeTransportLib:
@@ -125,6 +125,8 @@ class NativeTransportLib:
         self._lib.mt_recv_size.argtypes = [ctypes.c_void_p, ctypes.c_int64]
         self._lib.mt_release.restype = None
         self._lib.mt_release.argtypes = [ctypes.c_void_p, ctypes.c_int64]
+        self._lib.mt_ring_counts.restype = ctypes.c_uint64
+        self._lib.mt_ring_counts.argtypes = [ctypes.c_void_p, ctypes.c_int32]
         self._lib.mt_rx_bytes.restype = ctypes.c_uint64
         self._lib.mt_rx_bytes.argtypes = [ctypes.c_void_p, ctypes.c_int32]
         self._lib.mt_test.restype = ctypes.c_int32
@@ -258,6 +260,10 @@ class NativeTransportLib:
     def mt_release(self, ctx, handle):
         """Drop a completed handle's bookkeeping."""
         return self._lib.mt_release(ctx, handle)
+
+    def mt_ring_counts(self, ctx, which):
+        """How the rings were used so far: which == 0, chunks this endpoint placed in its peers' rings; 1, placements a full ring refused (the sender waited for the owner); 2, chunks copied out of the own rings; 3, those of them during whose copy the ring's head moved (sender and owner were copying at the same time)."""
+        return self._lib.mt_ring_counts(ctx, which)
 
     def mt_rx_bytes(self, ctx, which):
         """Bytes of the messages received whole so far: which == 0, those that landed in the buffer of a receive posted before they arrived; 1, those assembled in a buffer of the transport's own."""

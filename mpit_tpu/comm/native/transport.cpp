@@ -8,11 +8,23 @@
 // paths ride XLA collectives over ICI/DCN and are not this file's job.
 //
 // Design (deliberately not an MPI clone):
-//  * One POSIX shm ring buffer per rank (its inbox).  Senders append
-//    variable-size chunks under a process-shared mutex; only the owner
-//    drains.  Chunking bounds ring residency so messages larger than the
-//    ring (the reference ships 640 MB parameter vectors, ptest.lua:3)
-//    stream through a small ring without deadlock.
+//  * One POSIX shm segment per rank (its inbox), and in it one ring per
+//    sending rank: a ring has exactly one producer and one consumer.  The
+//    sender alone writes `head`, the owner alone writes `tail`, each
+//    published with release and read with acquire, and there is no lock:
+//    the sender copies a chunk's header and payload into the ring and then
+//    publishes `head`; the owner copies a chunk out and then publishes
+//    `tail`, chunk by chunk, so both copy at the same time and a sender
+//    sees room as soon as one chunk has left.  Chunking bounds ring
+//    residency so messages larger than the ring (the reference ships
+//    640 MB parameter vectors, ptest.lua:3) stream through a small ring
+//    without deadlock.  A sender that dies inside a chunk has published
+//    nothing; its next incarnation takes `head` from the segment and the
+//    first chunk of its next message drops what it left half-sent
+//    (abandon_partials).  An owner that comes back recreates its segment,
+//    and a sender stalled on the old one maps the new (kStallRemapThreshold).
+//    The segment is nranks rings of ring_bytes each, but tmpfs backs only
+//    the pages that were touched: a pair that never talks costs nothing.
 //  * Message assembly, (rank, tag) matching, and handle state live in
 //    process-local memory — the ring is purely a mailbox, so a receiver
 //    polling one tag never head-of-line-blocks other tags.
@@ -56,7 +68,6 @@
 #include <vector>
 
 #include <fcntl.h>
-#include <pthread.h>
 #include <sys/mman.h>
 #include <sys/stat.h>
 #include <time.h>
@@ -64,16 +75,28 @@
 
 namespace {
 
-constexpr uint64_t kReadyMagic = 0x4d50495454505531ull;  // "MPITTPU1"
+constexpr uint64_t kReadyMagic = 0x4d50495454505532ull;  // "MPITTPU2"
 constexpr uint64_t kMaxChunk = 1ull << 22;               // 4 MB
 
-struct RingHeader {
+// Start of a segment.  The ring indices follow at kIndexOffset, one per
+// sending rank, and the rings' data areas from data_offset(nrings) on.
+struct SegmentHeader {
   std::atomic<uint64_t> ready;  // kReadyMagic once initialized
-  pthread_mutex_t mutex;        // process-shared
-  uint64_t capacity;            // data-area bytes
-  uint64_t head;                // absolute bytes written (mod capacity)
-  uint64_t tail;                // absolute bytes consumed
+  uint64_t nrings;              // one ring per sending rank
+  uint64_t capacity;            // data bytes of each ring
 };
+
+// Each index on a cache line of its own: the two sides poll each other's.
+struct RingIndex {
+  alignas(64) std::atomic<uint64_t> head;  // absolute bytes written: the sender's
+  alignas(64) std::atomic<uint64_t> tail;  // absolute bytes consumed: the owner's
+};
+
+constexpr uint64_t kIndexOffset = 64;
+
+uint64_t data_offset(uint64_t nrings) {
+  return (kIndexOffset + nrings * sizeof(RingIndex) + 4095) & ~4095ull;
+}
 
 struct ChunkHeader {
   int32_t src;
@@ -85,11 +108,33 @@ struct ChunkHeader {
   uint64_t total_bytes;
 };
 
-struct Ring {
-  RingHeader* hdr = nullptr;
-  uint8_t* data = nullptr;
+struct Segment {
+  SegmentHeader* hdr = nullptr;
   size_t map_bytes = 0;
 };
+
+// The ring of one (sender, owner) pair inside the owner's segment.
+struct Ring {
+  RingIndex* idx;
+  uint8_t* data;
+  uint64_t capacity;
+};
+
+Ring ring_at(const Segment& seg, int src) {
+  auto* base = reinterpret_cast<uint8_t*>(seg.hdr);
+  uint64_t cap = seg.hdr->capacity;
+  return Ring{reinterpret_cast<RingIndex*>(base + kIndexOffset) + src,
+              base + data_offset(seg.hdr->nrings) + (uint64_t)src * cap, cap};
+}
+
+// A chunk and its header take at most a quarter of the ring, so the sender
+// can be copying the next one in while the owner copies this one out.
+uint64_t max_chunk(uint64_t capacity) {
+  uint64_t fit = capacity / 4 > sizeof(ChunkHeader)
+                     ? capacity / 4 - sizeof(ChunkHeader)
+                     : 1;
+  return kMaxChunk < fit ? kMaxChunk : fit;
+}
 
 // Message payload storage: a plain heap buffer, deliberately NOT a
 // std::vector — vector's value-initialization would memset every byte
@@ -127,10 +172,10 @@ struct SendOp {
   uint32_t next_chunk = 0;
   bool done = false;
   bool cancelled = false;
-  uint32_t stalls = 0;  // consecutive zero-progress pump attempts
+  uint32_t stalls = 0;  // consecutive pump passes that found the ring full
 };
 
-// After this many consecutive zero-progress attempts on a full peer ring,
+// After this many consecutive passes that placed nothing in a full peer ring,
 // suspect a stale mapping (peer crashed and recreated its segment) and
 // remap.  Normal backpressure resets the counter on any progress.
 constexpr uint32_t kStallRemapThreshold = 4096;
@@ -153,8 +198,8 @@ struct Ctx {
   int rank = -1;
   int nranks = 0;
   uint64_t ring_bytes = 0;
-  Ring own;
-  std::vector<Ring> peers;  // lazily opened inboxes of other ranks
+  Segment own;
+  std::vector<Segment> peers;  // lazily opened inboxes of other ranks
   std::map<std::pair<int, int>, std::deque<Message>> ready;      // (src,tag)
   std::map<std::pair<int, uint64_t>, Partial> partial;           // (src,msg_id)
   std::map<int64_t, SendOp> sends;
@@ -167,6 +212,14 @@ struct Ctx {
   // an assembly buffer (mt_rx_bytes).
   uint64_t rx_direct_bytes = 0;
   uint64_t rx_assembled_bytes = 0;
+  // Chunks placed in a peer's ring, placements refused by a full ring (the
+  // sender waited for the owner), chunks copied out of an own ring, and
+  // those of them during whose copy the ring's head moved (the sender was
+  // copying into the ring at the same time) (mt_ring_counts).
+  uint64_t tx_chunks = 0;
+  uint64_t tx_ring_full = 0;
+  uint64_t rx_chunks = 0;
+  uint64_t rx_overlap_chunks = 0;
   std::string last_error;
 };
 
@@ -208,15 +261,16 @@ std::string shm_name(const std::string& ns, int rank) {
   return "/mt_" + ns + "_r" + std::to_string(rank);
 }
 
-bool map_ring(const std::string& name, uint64_t ring_bytes, bool create,
-              Ring* out, std::string* err) {
+bool map_segment(const std::string& name, uint64_t nrings, uint64_t ring_bytes,
+                 bool create, Segment* out, std::string* err) {
   int flags = create ? (O_CREAT | O_RDWR) : O_RDWR;
   int fd = shm_open(name.c_str(), flags, 0600);
   if (fd < 0) {
     if (err) *err = "shm_open " + name + ": " + std::strerror(errno);
     return false;
   }
-  size_t total = sizeof(RingHeader) + ring_bytes;
+  // A new file is a hole from end to end: every index reads zero.
+  size_t total = data_offset(nrings) + nrings * ring_bytes;
   if (create && ftruncate(fd, (off_t)total) != 0) {
     if (err) *err = "ftruncate " + name + ": " + std::strerror(errno);
     close(fd);
@@ -225,7 +279,7 @@ bool map_ring(const std::string& name, uint64_t ring_bytes, bool create,
   if (!create) {
     // The creator sizes the segment; wait for a nonzero size.
     struct stat st;
-    if (fstat(fd, &st) != 0 || (size_t)st.st_size < sizeof(RingHeader)) {
+    if (fstat(fd, &st) != 0 || (size_t)st.st_size < data_offset(nrings)) {
       close(fd);
       if (err) *err = "peer segment not sized yet";
       return false;
@@ -238,16 +292,14 @@ bool map_ring(const std::string& name, uint64_t ring_bytes, bool create,
     if (err) *err = "mmap " + name + ": " + std::strerror(errno);
     return false;
   }
-  out->hdr = reinterpret_cast<RingHeader*>(mem);
-  out->data = reinterpret_cast<uint8_t*>(mem) + sizeof(RingHeader);
+  out->hdr = reinterpret_cast<SegmentHeader*>(mem);
   out->map_bytes = total;
   return true;
 }
 
-void circ_write(Ring& ring, uint64_t pos, const void* src, uint64_t n) {
-  uint64_t cap = ring.hdr->capacity;
-  uint64_t off = pos % cap;
-  uint64_t first = (off + n <= cap) ? n : cap - off;
+void circ_write(const Ring& ring, uint64_t pos, const void* src, uint64_t n) {
+  uint64_t off = pos % ring.capacity;
+  uint64_t first = (off + n <= ring.capacity) ? n : ring.capacity - off;
   std::memcpy(ring.data + off, src, first);
   if (first < n) {
     std::memcpy(ring.data, reinterpret_cast<const uint8_t*>(src) + first,
@@ -255,51 +307,43 @@ void circ_write(Ring& ring, uint64_t pos, const void* src, uint64_t n) {
   }
 }
 
-void circ_read(Ring& ring, uint64_t pos, void* dst, uint64_t n) {
-  uint64_t cap = ring.hdr->capacity;
-  uint64_t off = pos % cap;
-  uint64_t first = (off + n <= cap) ? n : cap - off;
+void circ_read(const Ring& ring, uint64_t pos, void* dst, uint64_t n) {
+  uint64_t off = pos % ring.capacity;
+  uint64_t first = (off + n <= ring.capacity) ? n : ring.capacity - off;
   std::memcpy(dst, ring.data + off, first);
   if (first < n) {
     std::memcpy(reinterpret_cast<uint8_t*>(dst) + first, ring.data, n - first);
   }
 }
 
-Ring* peer_ring(Ctx* ctx, int dst) {
-  if (dst < 0 || dst >= ctx->nranks) return nullptr;
-  Ring& ring = ctx->peers[dst];
-  if (ring.hdr == nullptr) {
-    std::string err;
-    if (!map_ring(shm_name(ctx->ns, dst), ctx->ring_bytes, /*create=*/false,
-                  &ring, &err)) {
-      return nullptr;  // peer not up yet; caller retries on next progress
-    }
+void unmap_peer(Ctx* ctx, int dst) {
+  Segment& seg = ctx->peers[dst];
+  if (seg.hdr != nullptr) {
+    munmap(seg.hdr, seg.map_bytes);
+    seg = Segment{};
   }
-  if (ring.hdr->ready.load(std::memory_order_acquire) != kReadyMagic) {
+}
+
+// The segment of rank `dst`, once its owner has made it ready for a gang
+// of this shape; nullptr until then (the caller retries on next progress).
+Segment* peer_segment(Ctx* ctx, int dst) {
+  if (dst < 0 || dst >= ctx->nranks) return nullptr;
+  Segment& seg = ctx->peers[dst];
+  if (seg.hdr == nullptr &&
+      !map_segment(shm_name(ctx->ns, dst), (uint64_t)ctx->nranks,
+                   ctx->ring_bytes, /*create=*/false, &seg, nullptr)) {
+    return nullptr;  // peer not up yet
+  }
+  if (seg.hdr->ready.load(std::memory_order_acquire) != kReadyMagic) {
     return nullptr;
   }
-  return &ring;
-}
-
-void unmap_peer(Ctx* ctx, int dst) {
-  Ring& ring = ctx->peers[dst];
-  if (ring.hdr != nullptr) {
-    munmap(ring.hdr, ring.map_bytes);
-    ring = Ring{};
+  if (seg.hdr->nrings != (uint64_t)ctx->nranks ||
+      seg.map_bytes <
+          data_offset(seg.hdr->nrings) + seg.hdr->nrings * seg.hdr->capacity) {
+    unmap_peer(ctx, dst);  // a gang of another shape left it behind
+    return nullptr;
   }
-}
-
-// Robust lock: if the previous holder died mid-critical-section, take
-// ownership, mark the mutex consistent, and reset the ring indices (the
-// in-flight bytes are garbage after a crash; post-crash message loss is the
-// accepted semantic — the PS protocol's acks surface it to the caller).
-void lock_ring(RingHeader* hdr) {
-  int rc = pthread_mutex_lock(&hdr->mutex);
-  if (rc == EOWNERDEAD) {
-    hdr->head = 0;
-    hdr->tail = 0;
-    pthread_mutex_consistent(&hdr->mutex);
-  }
+  return &seg;
 }
 
 // The receive a message of `total` bytes from (src, tag) that opens now
@@ -339,16 +383,18 @@ void abandon_partials(Ctx* ctx, int src) {
   }
 }
 
-// Drain the own inbox.  Payload bytes go from the ring into the buffer of
-// the receive the message is bound to, or else into its assembly buffer —
-// one copy either way, into uninitialized storage; an assembled message
-// pays a second one when mt_test hands it over.
-void drain_inbox(Ctx* ctx) {
-  Ring& ring = ctx->own;
-  lock_ring(ring.hdr);
-  uint64_t head = ring.hdr->head;
-  uint64_t tail = ring.hdr->tail;
-  while (tail < head) {
+// Drain one ring of the own inbox, as far as its head stood when the pass
+// began (a sender copying in beside the drain could otherwise hold the
+// thread here for a whole message).  Payload bytes go from the ring into
+// the buffer of the receive the message is bound to, or else into its
+// assembly buffer — one copy either way, into uninitialized storage, with
+// no lock held; an assembled message pays a second one when mt_test hands
+// it over.  `tail` is published after every chunk.
+void drain_ring(Ctx* ctx, const Ring& ring) {
+  uint64_t tail = ring.idx->tail.load(std::memory_order_relaxed);
+  const uint64_t limit = ring.idx->head.load(std::memory_order_acquire);
+  while (tail < limit) {
+    const uint64_t head_before = ring.idx->head.load(std::memory_order_acquire);
     ChunkHeader ch;
     circ_read(ring, tail, &ch, sizeof(ch));
     tail += sizeof(ch);
@@ -408,9 +454,18 @@ void drain_inbox(Ctx* ctx) {
       }
     }
     tail += ch.chunk_bytes;
+    ring.idx->tail.store(tail, std::memory_order_release);
+    ctx->rx_chunks++;
+    if (ring.idx->head.load(std::memory_order_acquire) != head_before) {
+      ctx->rx_overlap_chunks++;
+    }
   }
-  ring.hdr->tail = tail;
-  pthread_mutex_unlock(&ring.hdr->mutex);
+}
+
+void drain_inbox(Ctx* ctx) {
+  for (int src = 0; src < ctx->nranks; ++src) {
+    drain_ring(ctx, ring_at(ctx->own, src));
+  }
 }
 
 // Take a receive off the books.  One that a message is bound to hands the
@@ -436,9 +491,13 @@ void drop_recv(Ctx* ctx, std::map<int64_t, RecvOp>::iterator it) {
   ctx->recvs.erase(it);
 }
 
-// Try to place more chunks of the front send op for each destination.
+// Place more chunks of the front send ops of each destination, at most one
+// ring's worth of bytes a destination and pass: with the owner draining
+// beside it the ring may never fill, and the caller's thread has its other
+// destinations, its inbox and its deadlines to look at.
 void pump_sends(Ctx* ctx) {
   for (auto& [dst, queue] : ctx->send_q) {
+    uint64_t budget = UINT64_MAX;  // the ring's capacity, once it is mapped
     while (!queue.empty()) {
       int64_t handle = queue.front();
       auto it = ctx->sends.find(handle);
@@ -447,51 +506,49 @@ void pump_sends(Ctx* ctx) {
         continue;
       }
       SendOp& op = it->second;
-      Ring* ring = peer_ring(ctx, dst);
-      if (ring == nullptr) break;  // destination not up yet
-      // A chunk must fit in the destination ring with its header; cap at
-      // half the ring so two senders can interleave without livelock.
-      uint64_t ring_cap = ring->hdr->capacity;
-      uint64_t fit_max = ring_cap > 2 * sizeof(ChunkHeader)
-                             ? (ring_cap - 2 * sizeof(ChunkHeader)) / 2
-                             : 1;
-      uint64_t max_chunk = kMaxChunk < fit_max ? kMaxChunk : fit_max;
-      bool progressed = true;
-      while (!op.done && progressed) {
-        progressed = false;
+      Segment* seg = peer_segment(ctx, dst);
+      if (seg == nullptr) break;  // destination not up yet
+      const Ring ring = ring_at(*seg, ctx->rank);
+      if (budget > ring.capacity) budget = ring.capacity;
+      const uint64_t chunk_max = max_chunk(ring.capacity);
+      uint64_t head = ring.idx->head.load(std::memory_order_relaxed);
+      bool full = false;
+      while (!op.done) {
         uint64_t remaining = op.len - op.written;
-        uint64_t chunk = remaining < max_chunk ? remaining : max_chunk;
+        uint64_t chunk = remaining < chunk_max ? remaining : chunk_max;
         uint64_t need = sizeof(ChunkHeader) + chunk;
-        lock_ring(ring->hdr);
-        uint64_t used = ring->hdr->head - ring->hdr->tail;
-        uint64_t free_bytes = ring->hdr->capacity - used;
-        if (free_bytes >= need) {
-          ChunkHeader ch;
-          ch.src = ctx->rank;
-          ch.tag = op.tag;
-          ch.msg_id = op.msg_id;
-          ch.chunk_idx = op.next_chunk;
-          ch.nchunks = 0;  // informational; completion is byte-based
-          ch.chunk_bytes = chunk;
-          ch.total_bytes = op.len;
-          circ_write(*ring, ring->hdr->head, &ch, sizeof(ch));
-          if (chunk > 0) {
-            circ_write(*ring, ring->hdr->head + sizeof(ch), op.data + op.written,
-                       chunk);
-          }
-          ring->hdr->head += need;
-          op.written += chunk;
-          op.next_chunk++;
-          op.stalls = 0;
-          progressed = true;
-          if (op.written >= op.len) op.done = true;
+        if (need > budget) break;
+        uint64_t used = head - ring.idx->tail.load(std::memory_order_acquire);
+        if (ring.capacity - used < need) {
+          ctx->tx_ring_full++;
+          full = true;
+          break;
         }
-        pthread_mutex_unlock(&ring->hdr->mutex);
+        ChunkHeader ch;
+        ch.src = ctx->rank;
+        ch.tag = op.tag;
+        ch.msg_id = op.msg_id;
+        ch.chunk_idx = op.next_chunk;
+        ch.nchunks = 0;  // informational; completion is byte-based
+        ch.chunk_bytes = chunk;
+        ch.total_bytes = op.len;
+        circ_write(ring, head, &ch, sizeof(ch));
+        if (chunk > 0) {
+          circ_write(ring, head + sizeof(ch), op.data + op.written, chunk);
+        }
+        head += need;
+        ring.idx->head.store(head, std::memory_order_release);
+        budget -= need;
+        ctx->tx_chunks++;
+        op.written += chunk;
+        op.next_chunk++;
+        op.stalls = 0;
+        if (op.written >= op.len) op.done = true;
       }
       if (!op.done) {
-        // Zero progress with a full ring: count stalls; past the threshold
-        // assume a stale mapping (peer recreated its segment) and remap.
-        if (++op.stalls >= kStallRemapThreshold) {
+        // A full ring pass after pass: past the threshold assume a stale
+        // mapping (the peer recreated its segment) and remap.
+        if (full && ++op.stalls >= kStallRemapThreshold) {
           op.stalls = 0;
           unmap_peer(ctx, dst);
         }
@@ -521,20 +578,14 @@ void* mt_init(const char* ns, int rank, int nranks, uint64_t ring_bytes) {
   std::string name = shm_name(ctx->ns, rank);
   shm_unlink(name.c_str());  // clear any stale segment from a crashed run
   std::string err;
-  if (!map_ring(name, ring_bytes, /*create=*/true, &ctx->own, &err)) {
+  if (!map_segment(name, (uint64_t)nranks, ring_bytes, /*create=*/true,
+                   &ctx->own, &err)) {
     std::fprintf(stderr, "mt_init: %s\n", err.c_str());
     delete ctx;
     return nullptr;
   }
-  pthread_mutexattr_t attr;
-  pthread_mutexattr_init(&attr);
-  pthread_mutexattr_setpshared(&attr, PTHREAD_PROCESS_SHARED);
-  pthread_mutexattr_setrobust(&attr, PTHREAD_MUTEX_ROBUST);
-  pthread_mutex_init(&ctx->own.hdr->mutex, &attr);
-  pthread_mutexattr_destroy(&attr);
+  ctx->own.hdr->nrings = (uint64_t)nranks;
   ctx->own.hdr->capacity = ring_bytes;
-  ctx->own.hdr->head = 0;
-  ctx->own.hdr->tail = 0;
   ctx->own.hdr->ready.store(kReadyMagic, std::memory_order_release);
   return ctx;
 }
@@ -546,9 +597,7 @@ void mt_finalize(void* vctx) {
     munmap(ctx->own.hdr, ctx->own.map_bytes);
     shm_unlink(shm_name(ctx->ns, ctx->rank).c_str());
   }
-  for (Ring& ring : ctx->peers) {
-    if (ring.hdr != nullptr) munmap(ring.hdr, ring.map_bytes);
-  }
+  for (int dst = 0; dst < ctx->nranks; ++dst) unmap_peer(ctx, dst);
   delete ctx;
 }
 
@@ -671,6 +720,18 @@ void mt_release(void* vctx, int64_t handle) {
 uint64_t mt_rx_bytes(void* vctx, int32_t which) {
   auto* ctx = static_cast<Ctx*>(vctx);
   return which == 0 ? ctx->rx_direct_bytes : ctx->rx_assembled_bytes;
+}
+
+// How the rings were used so far: which == 0, chunks this endpoint placed
+// in its peers' rings; 1, placements a full ring refused (the sender waited
+// for the owner); 2, chunks copied out of the own rings; 3, those of them
+// during whose copy the ring's head moved: sender and owner were copying
+// at the same time.
+uint64_t mt_ring_counts(void* vctx, int32_t which) {
+  auto* ctx = static_cast<Ctx*>(vctx);
+  const uint64_t counts[] = {ctx->tx_chunks, ctx->tx_ring_full, ctx->rx_chunks,
+                             ctx->rx_overlap_chunks};
+  return which >= 0 && which < 4 ? counts[which] : 0;
 }
 
 // Monotonic wall clock in seconds (the MPI_Wtime analog,
@@ -823,7 +884,7 @@ void mt_fold_f32(const void* vown, const void* vptrs, int32_t nchildren,
 // generated _bindings.py refuses a stale .so (loud rebuild message)
 // instead of failing with a confusing missing-symbol AttributeError.
 // Keep in sync with MT_API_VERSION in gen_bindings.py.
-int64_t mt_api_version(void) { return 17002; }
+int64_t mt_api_version(void) { return 17003; }
 
 }  // extern "C"
 

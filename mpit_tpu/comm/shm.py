@@ -8,6 +8,18 @@ role topologies — the deployment shape the reference exercises with
 the asynchronous one-sided PS semantics XLA collectives can't express
 (SURVEY.md section 7 "hard parts").
 
+The wire: every rank owns one shm segment, its inbox, and in it one
+ring per sending rank (``ring_bytes`` each; tmpfs backs only the pages a
+pair has touched).  A ring has one producer and one consumer and no lock:
+the sender alone writes its ``head``, the owner alone its ``tail``, the
+sender copies a chunk in and then publishes ``head``, the owner copies it
+out and then publishes ``tail``, chunk by chunk, so the two copy at the
+same time, and several senders into one inbox never wait for each other.
+A sender killed inside a chunk has published nothing; what it left
+half-sent is dropped when its next incarnation's first message opens.
+``ring_counters`` says how often a sender found its ring full and how
+often the two sides were copying at once.
+
 Zero-copy discipline: sends pass the numpy buffer's raw pointer to C and
 the Handle holds the array reference until completion.  A receive posted
 with a buffer *before* its message's first chunk is drained lands in that
@@ -82,15 +94,14 @@ class ShmTransport(Transport):
         self._m_rx_bytes = [_reg.counter("mpit_shm_rx_bytes_total",
                                          rank=rank, peer=r)
                             for r in range(nranks)]
-        # Which way the received bytes went: the native side counts, and
-        # with obs on the counters follow it whenever a receive completes.
-        self._m_rx_paths = {
-            "rx_direct_bytes": _reg.counter(
-                "mpit_shm_rx_direct_bytes_total", rank=rank),
-            "rx_assembled_bytes": _reg.counter(
-                "mpit_shm_rx_assembled_bytes_total", rank=rank),
+        # Which way the received bytes went and how the rings were used:
+        # the native side counts, and with obs on the counters follow it
+        # whenever a transfer completes.
+        self._m_native = {
+            key: _reg.counter(f"mpit_shm_{key}_total", rank=rank)
+            for key in self.wire_counts()
         } if _reg.enabled else {}
-        self._rx_counted = dict.fromkeys(self._m_rx_paths, 0)
+        self._native_counted = dict.fromkeys(self._m_native, 0)
         # Posted receives, by native handle: their buffers are written from
         # the drain, so they live until the receive is done or cancelled
         # even if the caller lets go of the Handle.
@@ -160,13 +171,13 @@ class ShmTransport(Transport):
                 self._m_rx_msgs[handle.peer].inc()
                 self._m_rx_bytes[handle.peer].inc(
                     int(getattr(out, "nbytes", None) or len(out or b"")))
-                if self._m_rx_paths:
-                    now = self.rx_path_bytes()
-                    for key, counter in self._m_rx_paths.items():
-                        counter.inc(now[key] - self._rx_counted[key])
-                    self._rx_counted = now
             if handle.kind == "send":
                 handle.buf = None  # release ownership back to the caller
+            if self._m_native:
+                now = self.wire_counts()
+                for key, counter in self._m_native.items():
+                    counter.inc(now[key] - self._native_counted[key])
+                self._native_counted = now
             self.lib.mt_release(self._ctx, handle.native_id)
             return True
         if code == -2:
@@ -197,6 +208,20 @@ class ShmTransport(Transport):
             "rx_direct_bytes": int(self.lib.mt_rx_bytes(self._ctx, 0)),
             "rx_assembled_bytes": int(self.lib.mt_rx_bytes(self._ctx, 1)),
         }
+
+    def ring_counters(self) -> dict:
+        """How the rings were used so far: chunks this endpoint placed in
+        its peers' rings and placements a full ring refused (it waited for
+        the owner's drain); chunks it copied out of its own rings and those
+        of them during whose copy the sender moved the ring's head (both
+        sides were copying at once)."""
+        return {key: int(self.lib.mt_ring_counts(self._ctx, which))
+                for which, key in enumerate((
+                    "tx_chunks", "tx_ring_full", "rx_chunks",
+                    "rx_overlap_chunks"))}
+
+    def wire_counts(self) -> dict:
+        return {**self.rx_path_bytes(), **self.ring_counters()}
 
     def close(self) -> None:
         if not self._closed and self._ctx:

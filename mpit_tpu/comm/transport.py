@@ -89,9 +89,11 @@ class Transport(abc.ABC):
         is buffered, implementations should raise ``RuntimeError`` rather
         than return ``False`` — the schedulers' probe-then-recv loops
         (aio/scheduler.py) would otherwise poll a drained channel forever.
-        TcpTransport implements this; ShmTransport relies on its
-        EOWNERDEAD remap to resurrect the peer instead, so a probe there
-        keeps returning ``False`` while recovery is in progress.
+        TcpTransport implements this; ShmTransport relies on a restarted
+        peer carrying on in place instead (a dead sender has published
+        nothing half-written; a sender stalled on a dead owner's segment
+        maps the new one), so a probe there keeps returning ``False``
+        while recovery is in progress.
         """
 
     @abc.abstractmethod
@@ -109,9 +111,10 @@ class Transport(abc.ABC):
             raise RuntimeError("payload requested before completion")
         return handle.out if handle.out is not None else handle.payload
 
-    def rx_path_bytes(self) -> dict:
-        """Rank-result fields saying which way the received bytes went,
-        where a transport has more than one (``comm/shm.py``)."""
+    def wire_counts(self) -> dict:
+        """Rank-result fields a transport counts about its own wire, where
+        it has any (``comm/shm.py``: which way the received bytes went and
+        how its rings were used)."""
         return {}
 
     def close(self) -> None:  # pragma: no cover - backends override

@@ -204,7 +204,7 @@ def _child_main() -> None:
     maybe_start_statusd(rank)
     transport = child_transport(cfg, rank, size)
     result = {**run_rank(rank, size, cfg, transport),
-              **transport.rx_path_bytes()}
+              **transport.wire_counts()}
     transport.close()
     write_result(result)
 

@@ -632,10 +632,12 @@ def run_rank(
 ) -> Dict[str, Any]:
     """Run one rank's role to completion; returns its result dict, with
     the transport's word on which way the bytes it received went
-    (``rx_direct_bytes`` / ``rx_assembled_bytes`` on the shm wire)."""
+    (``rx_direct_bytes`` / ``rx_assembled_bytes`` on the shm wire) and on
+    how its rings were used (``tx_chunks``, ``tx_ring_full``, ``rx_chunks``,
+    ``rx_overlap_chunks``)."""
     result = _run_role(rank, size, cfg, transport, data)
     if transport is not None:
-        result = {**result, **transport.rx_path_bytes()}
+        result = {**result, **transport.wire_counts()}
     return result
 
 
@@ -1159,7 +1161,8 @@ def _summarize(result: Dict[str, Any]) -> Dict[str, Any]:
             "reads", "monotone", "busy_honored",
             "final_loss", "final_eval_loss", "tokens_per_s", "tokens_total",
             "steps", "rounds_streamed", "rx_direct_bytes",
-            "rx_assembled_bytes", "train_seconds",
+            "rx_assembled_bytes", "tx_chunks", "tx_ring_full", "rx_chunks",
+            "rx_overlap_chunks", "train_seconds",
             "first_step_seconds", "mosaic_calls",
             "moe_load_max_over_mean",
             "platform", "device_kind", "device_count", "device_ids",

@@ -490,6 +490,229 @@ class TestPostedRecvLandsInPlace:
             b.close()
 
 
+SEND_PEER = textwrap.dedent(
+    """
+    import sys, numpy as np
+    sys.path.insert(0, {repo!r})
+    from mpit_tpu.comm.shm import ShmTransport
+    t = ShmTransport({ns!r}, {rank}, {nranks}, ring_bytes={ring})
+    data = np.random.default_rng({seed}).integers(0, 256, {nbytes}, dtype=np.uint8)
+    t.send(data, {dst}, {tag})
+    t.close()
+    """
+)
+
+
+def send_peer(**fields):
+    """A process that sends one seeded message and leaves."""
+    return subprocess.Popen(
+        [sys.executable, "-c", SEND_PEER.format(repo=REPO, **fields)],
+        env={**os.environ, "JAX_PLATFORMS": "cpu"})
+
+
+@pytest.mark.parametrize("nranks,ring", [(3, 1 << 20), (4, 300_007),
+                                         (6, 1 << 16)])
+class TestPairRings:
+    """One ring a (sender, owner) pair in the owner's segment, no lock:
+    the sender alone moves ``head``, the owner alone ``tail``, chunk by
+    chunk (transport.cpp ``drain_ring`` / ``pump_sends``).  A chunk and its
+    header take a quarter of the ring, so 300,007 leaves 3 bytes over and
+    every lap's chunks lie elsewhere and straddle the ring's end."""
+
+    def gang(self, name, nranks, ring):
+        ns = f"t_ring_{name}_{nranks}_{os.getpid()}"
+        return ns, [ShmTransport(ns, r, nranks, ring_bytes=ring)
+                    for r in range(nranks)]
+
+    def test_two_producers_interleave_whole_and_in_order(self, nranks, ring):
+        """Three messages each from two senders, every one several rings
+        long, all in flight at once: each arrives whole, and a pair's in
+        the order they were sent."""
+        _, wires = self.gang("two", nranks, ring)
+        try:
+            owner, senders = wires[0], (1, nranks - 1)
+            sizes = [3 * ring + 17, 2 * ring + 1, 5 * ring - 3]
+            sent = {src: [noise(10 * src + k, n) for k, n in enumerate(sizes)]
+                    for src in senders}
+            outs = {src: [np.zeros_like(m) for m in sent[src]]
+                    for src in senders}
+            recvs = [owner.irecv(src, 4, out=out)
+                     for src in senders for out in outs[src]]
+            sends = [(wires[src], wires[src].isend(m, 0, 4))
+                     for k in range(len(sizes)) for src in senders
+                     for m in [sent[src][k]]]
+            spin(*[lambda t=t, h=h: t.test(h) for t, h in sends],
+                 *[lambda h=h: owner.test(h) for h in recvs])
+            for src in senders:
+                for out, msg in zip(outs[src], sent[src]):
+                    np.testing.assert_array_equal(out, msg)
+            assert owner.rx_path_bytes() == {
+                "rx_direct_bytes": 2 * sum(sizes), "rx_assembled_bytes": 0}
+        finally:
+            for t in wires:
+                t.close()
+
+    def test_message_of_many_rings_streams_through(self, nranks, ring):
+        """Forty rings and a bit, then a second message that starts where
+        the first left off: the indices wrap many times, chunks straddle
+        the ring's end, and a sender never gets more than a ring ahead."""
+        _, wires = self.gang("long", nranks, ring)
+        try:
+            a, b = wires[1], wires[0]
+            for seed, nbytes in ((20, 40 * ring + 12_345), (21, 3 * ring + 1)):
+                data = noise(seed, nbytes)
+                out = np.zeros_like(data)
+                hr = b.irecv(1, 4, out=out)
+                hs = a.isend(data, 0, 4)
+                assert not a.test(hs)  # a ring's worth is out, no more
+                assert a.ring_counters()["tx_ring_full"] > 0
+                spin(lambda: a.test(hs), lambda: b.test(hr))
+                np.testing.assert_array_equal(out, data)
+            assert (b.ring_counters()["rx_chunks"]
+                    == a.ring_counters()["tx_chunks"] >= 4 * 43)
+        finally:
+            for t in wires:
+                t.close()
+
+    def test_send_to_self(self, nranks, ring):
+        _, wires = self.gang("self", nranks, ring)
+        try:
+            t = wires[nranks - 1]
+            small, big = noise(30, 100), noise(31, 3 * ring + 5)
+            out_small, out_big = np.zeros_like(small), np.zeros_like(big)
+            recvs = [t.irecv(t.rank, 4, out=out_small),
+                     t.irecv(t.rank, 4, out=out_big)]
+            sends = [t.isend(small, t.rank, 4), t.isend(big, t.rank, 4)]
+            spin(*[lambda h=h: t.test(h) for h in sends + recvs])
+            np.testing.assert_array_equal(out_small, small)
+            np.testing.assert_array_equal(out_big, big)
+        finally:
+            for t in wires:
+                t.close()
+
+    def test_killed_producer_leaves_the_inbox_usable(self, nranks, ring):
+        """A sender process dies with its message part-way in (what it had
+        published is landed, what it was copying was never published): the
+        other sender's message arrives beside it, and the dead one's next
+        incarnation carries on from the ring's head and its first message
+        takes the receive the torn one was bound to."""
+        ns, wires = self.gang("kill", nranks, ring)
+        wires.pop(1).close()  # rank 1 lives in processes of its own
+        try:
+            owner, other = wires[0], wires[-1]
+            nbytes = 8 * ring
+            peer = dict(ns=ns, rank=1, nranks=nranks, ring=ring, dst=0, tag=4,
+                        nbytes=nbytes)
+            out = np.zeros(nbytes, np.uint8)
+            hr = owner.irecv(1, 4, out=out)
+            doomed = send_peer(seed=40, **peer)
+            spin(lambda: owner.test(hr) or out[2 * ring] != 0
+                 or out[2 * ring + 1] != 0, limit=10**8)
+            assert not owner.test(hr)
+            doomed.kill()
+            doomed.wait(60)
+            beside = noise(41, 2 * ring)
+            got = np.zeros_like(beside)
+            hb = owner.irecv(other.rank, 4, out=got)
+            hs = other.isend(beside, 0, 4)
+            spin(lambda: other.test(hs), lambda: owner.test(hb))
+            np.testing.assert_array_equal(got, beside)
+            assert not owner.test(hr)  # the torn message never completes
+            again = send_peer(seed=42, **peer)
+            spin(lambda: owner.test(hr), limit=10**8)
+            assert again.wait(60) == 0
+            np.testing.assert_array_equal(out, noise(42, nbytes))
+        finally:
+            for t in wires:
+                t.close()
+
+    def test_counters_say_who_waited_and_who_overlapped(self, nranks, ring):
+        _, wires = self.gang("count", nranks, ring)
+        try:
+            a, b = wires[1], wires[0]
+            zero = dict.fromkeys(("tx_chunks", "tx_ring_full", "rx_chunks",
+                                  "rx_overlap_chunks"), 0)
+            assert a.ring_counters() == b.ring_counters() == zero
+            # One-chunk messages to a receiver that has nothing else to do:
+            # ten chunks each side, no ring ever full, and no chunk copied
+            # out while the sender copied in (it is the same thread here).
+            for k in range(10):
+                a.send(noise(50 + k, 1000), 0, 4)
+                b.recv(1, 4, out=np.zeros(1000, np.uint8))
+            assert a.ring_counters() == {**zero, "tx_chunks": 10}
+            assert b.ring_counters() == {**zero, "rx_chunks": 10}
+            # A receiver that sleeps: the sender finds the ring full.
+            data = noise(60, 2 * ring)
+            hs = a.isend(data, 0, 4)
+            for _ in range(3):
+                assert not a.test(hs)
+            assert a.ring_counters()["tx_ring_full"] >= 3
+            assert b.ring_counters()["rx_chunks"] == 10
+            out = np.zeros_like(data)
+            hr = b.irecv(1, 4, out=out)
+            spin(lambda: a.test(hs), lambda: b.test(hr))
+            np.testing.assert_array_equal(out, data)
+            assert b.ring_counters()["rx_overlap_chunks"] == 0
+        finally:
+            for t in wires:
+                t.close()
+
+    def test_a_pair_that_never_talks_costs_nothing(self, nranks, ring):
+        """A segment is ``nranks`` rings, but tmpfs backs only the pages
+        that were touched: rank 1 talks to ranks 0 and 2 and they answer,
+        so two rings' worth at rank 1 and one each at the other two."""
+        ns, wires = self.gang("sparse", nranks, ring)
+        try:
+            hub = wires[1]
+            data = noise(70, 2 * ring)
+            for peer in (wires[0], wires[2]):
+                for src, dst in ((hub, peer), (peer, hub)):
+                    out = np.zeros_like(data)
+                    hr = dst.irecv(src.rank, 4, out=out)
+                    hs = src.isend(data, dst.rank, 4)
+                    spin(lambda: src.test(hs), lambda: dst.test(hr))
+            slack = 64 << 10  # the indices' page and the rings' edges
+            for rank, touched in ((0, 1), (1, 2), (2, 1)):
+                used = os.stat(f"/dev/shm/mt_{ns}_r{rank}").st_blocks * 512
+                assert touched * ring <= used + slack, (rank, used)
+                assert used <= touched * ring + slack, (rank, used)
+        finally:
+            for t in wires:
+                t.close()
+
+
+OVERLAP_RING = 1 << 20
+OVERLAP_BYTES = 96 * OVERLAP_RING
+
+
+class TestCopiesOverlap:
+    def test_sender_and_receiver_copy_at_the_same_time(self):
+        """One sender process, one receiver, one message of 96 rings: a
+        chunk counts as overlapped if the sender published another while
+        the receiver was copying it out, which a ring-wide lock round both
+        copies makes impossible (nought then, by construction).  Alone on
+        the eight-core CPU host 378-379 of the 385 chunks overlap (six
+        runs), beside ten spinning processes 286-380; a quarter is the
+        bound, so that a sender kept off its core for most of the message
+        still passes beside the suite's other workers, and a lock does
+        not."""
+        ns = f"t_ovl_{os.getpid()}"
+        b = ShmTransport(ns, 0, 2, ring_bytes=OVERLAP_RING)
+        try:
+            out = np.zeros(OVERLAP_BYTES, np.uint8)
+            hr = b.irecv(1, 4, out=out)
+            peer = send_peer(ns=ns, rank=1, nranks=2, ring=OVERLAP_RING,
+                             dst=0, tag=4, nbytes=OVERLAP_BYTES, seed=80)
+            spin(lambda: b.test(hr), limit=10**9)
+            assert peer.wait(60) == 0
+            np.testing.assert_array_equal(out, noise(80, OVERLAP_BYTES))
+            counts = b.ring_counters()
+            assert counts["rx_chunks"] == 4 * 96 + 1
+            assert counts["rx_overlap_chunks"] >= counts["rx_chunks"] // 4, counts
+        finally:
+            b.close()
+
+
 ECHO_PEER = textwrap.dedent(
     """
     import sys, numpy as np

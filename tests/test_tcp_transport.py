@@ -526,7 +526,7 @@ def test_cross_process_kill_and_resume(tmp_path):
     """A rank process dies hard (no goodbye) mid-gang and a replacement
     process rebinds its address: the surviving rank's queued frames reach
     the replacement and traffic resumes — the TCP analog of the shm
-    transport's EOWNERDEAD remap."""
+    transport's stale-segment remap."""
     addrs, socks = allocate_local_addresses(2)
     for s in socks:  # children rebind their own listeners
         s.close()
