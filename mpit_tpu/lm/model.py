@@ -2,8 +2,8 @@
 
 Assembles one of the decoders of :mod:`mpit_tpu.models.transformer`
 (``arch``: ``gpt2`` is :class:`TinyDecoder`, ``olmoe``
-:class:`OlmoeDecoder`, ``mellum`` :class:`MellumDecoder`; each one's
-attention is the ``ops/`` flash kernel
+:class:`OlmoeDecoder`, ``mellum`` :class:`MellumDecoder`, ``lfm2``
+:class:`Lfm2Decoder`; each one's attention is the ``ops/`` flash kernel
 on TPU and the jnp reference — which differentiates without a recompute
 pass — elsewhere) into the flat-vector calling convention the parameter server shards: a
 :class:`~mpit_tpu.models.flat.FlatModel` plus a next-token NLL over
@@ -22,18 +22,20 @@ import jax.numpy as jnp
 from mpit_tpu.models.flat import FlatModel, flatten_module
 from mpit_tpu.models import transformer
 from mpit_tpu.models.transformer import (
+    Lfm2Decoder,
     MellumDecoder,
     OlmoeDecoder,
     TinyDecoder,
     default_attn,
 )
 
-ARCHS = ("gpt2", "olmoe", "mellum")
-# what a sparse block ``sow``s a layer, and the name of each in the
-# step's telemetry (``value_grad_stats``), on the round span and as the
-# gauge ``mpit_<name>``
+ARCHS = ("gpt2", "olmoe", "mellum", "lfm2")
+# what a sparse layer ``sow``s, and the name of each in the step's
+# telemetry (``value_grad_stats``), on the round span and as the gauge
+# ``mpit_<name>``
 MOE_STATS = {"moe_load": "moe_load_max_over_mean",
-             "moe_held": "moe_held_rows_share"}
+             "moe_held": "moe_held_rows_share",
+             "moe_flips": "moe_bias_flips_share"}
 
 
 class LmModel(NamedTuple):
@@ -45,12 +47,13 @@ class LmModel(NamedTuple):
     value_and_grad: Callable[..., Any]        # (w, tokens) -> (loss, grad)
     seq_len: int
     vocab: int
-    #: olmoe, mellum: (w, tokens) -> ((loss, {name: device array}),
-    #: grad), the same step with the block's own telemetry as an
-    #: auxiliary output (``moe_load_max_over_mean`` and, from a block
-    #: that holds a share of its experts, ``moe_held_rows_share``, one
-    #: number a layer each), which the optimizer fetches only while obs
-    #: is on; None for a block that has none
+    #: olmoe, mellum, lfm2: (w, tokens) -> ((loss, {name: device
+    #: array}), grad), the same step with the block's own telemetry as
+    #: an auxiliary output (``moe_load_max_over_mean``; from a block
+    #: that holds a share of its experts ``moe_held_rows_share``; from
+    #: one whose router has a selection bias ``moe_bias_flips_share``;
+    #: one number a sparse layer each), which the optimizer fetches only
+    #: while obs is on; None for a block that has none
     value_grad_stats: Optional[Callable[..., Any]] = None
 
 
@@ -72,10 +75,14 @@ def _resolve_attn(use_flash: Optional[bool],
                         precision=precision)
 
 
-# mellum's own sizes (``build``'s keywords, ``LM_DEFAULTS``' names)
+# mellum's own sizes (``build``'s keywords, ``LM_DEFAULTS``' names);
+# lfm2 shares the first four
 MELLUM_KEYS = ("kv_heads", "head_dim", "experts_first", "experts_held",
                "window", "full_every", "yarn_factor", "yarn_orig",
                "yarn_beta_fast", "yarn_beta_slow", "yarn_attn_factor")
+# lfm2's own
+LFM2_KEYS = ("layer_types", "dense_layers", "dense_width", "conv_kernel",
+             "route_scale")
 
 
 def build_kw(cfg: Any) -> dict:
@@ -86,7 +93,7 @@ def build_kw(cfg: Any) -> dict:
     kw = {key: cfg[key] for key in (
         "arch", "d_model", "n_heads", "n_layers", "seq_len", "seed",
         "n_experts", "experts_per_tok", "expert_width", "rope_theta",
-        "norm_eps", *MELLUM_KEYS)}
+        "norm_eps", *MELLUM_KEYS, *LFM2_KEYS)}
     if int(cfg.vocab):
         kw["vocab"] = int(cfg.vocab)
     return kw
@@ -102,7 +109,9 @@ def build(*, arch: str = "gpt2", vocab: int = 256, d_model: int = 64,
           full_every: int = 4, yarn_factor: float = 0.0,
           yarn_orig: int = 0, yarn_beta_fast: float = 32.0,
           yarn_beta_slow: float = 1.0,
-          yarn_attn_factor: float = 1.0) -> LmModel:
+          yarn_attn_factor: float = 1.0, layer_types: str = "",
+          dense_layers: int = 0, dense_width: int = 0,
+          conv_kernel: int = 3, route_scale: float = 1.0) -> LmModel:
     """Build the decoder, flatten its params, and close over the
     next-token NLL.  ``arch`` chooses the block; the expert, rotary and
     norm sizes are the sparse blocks' alone, and those from
@@ -111,22 +120,45 @@ def build(*, arch: str = "gpt2", vocab: int = 256, d_model: int = 64,
     all ``n_experts``, else the contiguous share from ``experts_first``
     that this chip holds of a router ``n_experts`` wide; ``window`` 0:
     every layer full; ``yarn_factor`` 0: the plain rotary table on the
-    full layers too).  For ``gpt2`` ``max_len`` is pinned to ``seq_len``
+    full layers too).  ``lfm2`` takes ``kv_heads``, ``head_dim`` and the
+    share as ``mellum`` does, and from ``layer_types`` on its own: the
+    token mixer of each of the ``n_layers`` layers held here, ``conv``
+    or ``full_attention``, comma-separated; how many of them, the
+    first, have the dense MLP of ``dense_width`` and not the sparse one;
+    the short convolution's taps; the router's
+    ``routed_scaling_factor``.  For ``gpt2`` ``max_len`` is pinned to ``seq_len``
     — the packed stream always fills full sequences, and an exact fit
     keeps the position table out of the sharding slack (the other
     blocks' positions are rotary: no table)."""
     if arch not in ARCHS:
         raise ValueError(f"unknown LM arch {arch!r}; have {ARCHS}")
-    if arch == "mellum":
+    if arch in ("mellum", "lfm2"):
         held = experts_held or n_experts
         if experts_first + held > n_experts:
             raise ValueError(f"experts {experts_first}.."
                              f"{experts_first + held - 1} held of {n_experts}")
         attn_fn = _resolve_attn(use_flash)
+    if arch == "lfm2":
+        kinds = tuple(kind.strip() for kind in layer_types.split(",")
+                      if kind.strip())
+        if len(kinds) != n_layers:
+            raise ValueError(f"layer_types names {len(kinds)} layers "
+                             f"({layer_types!r}), n_layers is {n_layers}")
+        module: Any = Lfm2Decoder(
+            vocab=vocab, d_model=d_model, n_heads=n_heads,
+            kv_heads=kv_heads or n_heads,
+            head_dim=head_dim or d_model // n_heads, layer_types=kinds,
+            dense_layers=dense_layers, dense_width=dense_width,
+            n_experts=n_experts, experts_per_tok=experts_per_tok,
+            expert_width=expert_width, experts_first=experts_first,
+            experts_held=experts_held, conv_kernel=conv_kernel,
+            route_scale=float(route_scale), rope_theta=rope_theta,
+            norm_eps=norm_eps, attn_fn=attn_fn)
+    elif arch == "mellum":
         yarn = (float(yarn_factor), int(yarn_orig), float(yarn_beta_fast),
                 float(yarn_beta_slow), float(yarn_attn_factor)
                 ) if yarn_factor else None
-        module: Any = MellumDecoder(
+        module = MellumDecoder(
             vocab=vocab, d_model=d_model, n_heads=n_heads,
             kv_heads=kv_heads or n_heads,
             head_dim=head_dim or d_model // n_heads, n_layers=n_layers,
@@ -154,8 +186,10 @@ def build(*, arch: str = "gpt2", vocab: int = 256, d_model: int = 64,
     # per parameter's path), and mellum trains at sequences at which a
     # host role's forward pass with the materialised reference attention
     # takes minutes and tens of GB (lm_layout on a server rank), so its
-    # sample is short; the older blocks keep the sample they had.
-    sample = jnp.zeros((1, 16 if arch == "mellum" else seq_len), jnp.int32)
+    # sample is short, and lfm2's with it; the older blocks keep the
+    # sample they had.
+    short = arch in ("mellum", "lfm2")
+    sample = jnp.zeros((1, 16 if short else seq_len), jnp.int32)
     fm = flatten_module(module, jax.random.PRNGKey(seed), sample)
 
     def mean_nll(logp, targets):
@@ -170,10 +204,11 @@ def build(*, arch: str = "gpt2", vocab: int = 256, d_model: int = 64,
         return mean_nll(logp, targets)
 
     value_grad_stats = None
-    if arch in ("olmoe", "mellum"):
+    if arch in ("olmoe", "mellum", "lfm2"):
         def loss_and_load(w, tokens):
             # the same loss with ``intermediates`` collected: the
             # routers' counts, which the forward pass has already made
+            # (a layer with a dense MLP sows none and is not among them)
             logp, state = fm.apply_flat(w, tokens[:, :-1],
                                         mutable=["intermediates"])
             blocks = state["intermediates"]

@@ -72,6 +72,16 @@ LM_DEFAULTS = Config(
     yarn_beta_fast=32.0,
     yarn_beta_slow=1.0,
     yarn_attn_factor=1.0,
+    # lfm2's own sizes (lm/model.py build; it shares kv_heads, head_dim
+    # and the share with mellum): each held layer's token mixer, "conv"
+    # or "full_attention", comma-separated; how many leading layers have
+    # the dense MLP and its width; the short convolution's taps; the
+    # router's scale
+    layer_types="",
+    dense_layers=0,
+    dense_width=0,
+    conv_kernel=3,
+    route_scale=1.0,
     # -1 auto (flash on TPU, jnp reference elsewhere) | 0 reference |
     # 1 the Mosaic-compiled kernel or an error (lm/model.py _resolve_attn)
     use_flash=-1,
@@ -117,6 +127,9 @@ class LmTrainer:
         use_flash = None if cfg.use_flash < 0 else bool(cfg.use_flash)
         self.model = build(use_flash=use_flash, **build_kw(cfg))
         dtype = jnp.dtype(cfg.dtype)
+        # an alias of the model's seeded vector where the dtype is its
+        # own; the local step donates what it is handed from its second
+        # call on, never this (optim/msgd.py MSGD.step)
         self.w = self.model.flat.w0.astype(dtype)
         self._vgf = self.model.value_and_grad
         self._loss = jax.jit(self.model.loss)

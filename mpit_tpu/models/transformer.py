@@ -15,13 +15,18 @@ long-context showcase built on the framework's own kernels:
   batched over (B, L);
 - pre-LN blocks, learned positional embeddings, causal by default.
 
-Three blocks: :class:`TinyDecoder` (GPT-2's: LayerNorm, learned
+Four decoders: :class:`TinyDecoder` (GPT-2's: LayerNorm, learned
 positions, GELU MLP), :class:`OlmoeDecoder` (OLMoE's: RMSNorm, rotary
-positions, query/key norm, top-k of E gated experts) and
+positions, query/key norm, top-k of E gated experts),
 :class:`MellumDecoder` (Mellum 2's: grouped KV heads of their own
 width, sliding-window and full attention mixed by layer with a rotary
 table per layer type, top-k renormalised, and a chip's share of the
-experts), chosen by ``lm/model.py`` ``build(arch=...)``.
+experts) and :class:`Lfm2Decoder` (LFM2's: a layer's token mixer a
+gated short convolution or grouped-head attention with a per-head
+query/key norm, its MLP dense or a share of sparse experts behind a
+sigmoid router with a selection bias, both read from the
+configuration layer by layer), chosen by ``lm/model.py``
+``build(arch=...)``.
 """
 
 from __future__ import annotations
@@ -37,6 +42,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from mpit_tpu.ops.flash_attention import attention_reference, flash_attention
+from mpit_tpu.ops.short_conv import causal_depthwise_conv
 from mpit_tpu.parallel import moe
 
 #: ``fn(q, k, v, window=None) -> out``.  A block that never passes a
@@ -356,6 +362,68 @@ def rope_by(x: jnp.ndarray, inv_freq: np.ndarray,
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
+def grouped_attention(h: jnp.ndarray, wq: jnp.ndarray, wk: jnp.ndarray,
+                      wv: jnp.ndarray, wo: jnp.ndarray, *, heads: int,
+                      kv_heads: int, head_dim: int, inv_freq: np.ndarray,
+                      attn: AttnFn, scale: float = 1.0, window: int = 0,
+                      qk_norm: Optional[tuple] = None) -> jnp.ndarray:
+    """Attention over grouped KV heads of their own width on the normed
+    stream ``h (B, L, d)``, projected back to ``(B, L, d)``: bias-free
+    projections to ``heads`` query and ``kv_heads`` key and value heads
+    of ``head_dim``, rotary positions by ``inv_freq`` (:func:`rope_by`),
+    ``attn`` with the ``window`` where there is one.  ``qk_norm``
+    ``(q weight, k weight, eps)``: an RMSNorm over each head's width on
+    the queries and the keys, before the rotary embedding.  Every
+    product at the backend's default precision, one bf16 pass on a TPU:
+    Mellum's scores are O(1) without a norm, and LFM2's with its per-head
+    norm read the same gradient error against the float32 reference with
+    the path raised as OLMoE's is (0.159% both ways on the v5e, PERF.md
+    section 6, PR 32)."""
+    b, l, _ = h.shape
+    q, k, v = h @ wq, h @ wk, h @ wv
+
+    def heads_of(x, count, weight):
+        x = x.reshape(b, l, count, head_dim)
+        if qk_norm is not None:
+            x = rms_norm(x, weight, qk_norm[2])
+        return rope_by(x, inv_freq, scale)
+
+    q = heads_of(q, heads, qk_norm and qk_norm[0])
+    k = heads_of(k, kv_heads, qk_norm and qk_norm[1])
+    v = v.reshape(b, l, kv_heads, head_dim)
+    out = attn(q, k, v, window=window) if window else attn(q, k, v)
+    return out.reshape(b, l, heads * head_dim) @ wo
+
+
+def sparse_mlp(x: jnp.ndarray, norm: jnp.ndarray, router: jnp.ndarray,
+               experts: tuple, *, route: Callable, eps: float,
+               n_experts: int, first: int, held: int):
+    """The sparse MLP of a block that holds ``held`` of its ``n_experts``
+    experts from ``first`` on, on the stream ``x (B, L, d)``: RMSNorm,
+    the router's product over all the experts in float32 at full
+    precision, ``route(logits) -> (weights, chosen, extra statistics)``
+    (the block's own scoring: a softmax or a sigmoid with a selection
+    bias, over all ``n_experts`` whether held or not), and the held
+    experts' part of the weighted sum by the sorted dropless dispatch
+    (``parallel/moe.py``).  Returns the branch's output and its
+    statistics: the load's max over mean, the held rows' share, then
+    ``route``'s own.  Pure in its arguments, so a block wraps it in
+    ``jax.checkpoint``."""
+    b, l, d = x.shape
+    wg, wu, wd = experts
+    with jax.named_scope("router"):
+        h = rms_norm(x, norm, eps).reshape(b * l, d)
+        logits = jnp.matmul(h, router, precision=ROUTER_PRECISION)
+        weights, chosen, extra = route(logits.astype(jnp.float32))
+        stats = (moe.load_max_over_mean(chosen, n_experts),
+                 moe.held_rows_share(chosen, first, held), *extra)
+    y = moe.dispatch_top_k(
+        h, weights, chosen, n_experts,
+        lambda rows, sizes: moe.swiglu_experts(
+            rows, sizes, wg, wu, wd, first if held < n_experts else None))
+    return y.reshape(b, l, d), stats
+
+
 class MellumBlock(nn.Module):
     d_model: int
     n_heads: int
@@ -379,7 +447,6 @@ class MellumBlock(nn.Module):
         hq, hkv, hd = self.n_heads, self.kv_heads, self.head_dim
         e, f = self.n_experts, self.expert_width
         held = self.experts_held or e
-        first = self.experts_first if held < e else None
         attn = self.attn_fn if self.attn_fn is not None else default_attn()
         ones = nn.initializers.ones
         if self.window or self.yarn is None:  # the plain rotary table
@@ -401,16 +468,13 @@ class MellumBlock(nn.Module):
             # against the float32 reference from 0.073% to 0.067% for
             # three times the attention's time (PERF.md section 6, PR 30).
             h = rms_norm(x, self.param("attn_norm", ones, (d,)), self.norm_eps)
-            q = h @ self.param("wq", _INIT, (d, hq * hd))
-            k = h @ self.param("wk", _INIT, (d, hkv * hd))
-            v = h @ self.param("wv", _INIT, (d, hkv * hd))
-            q = rope_by(q.reshape(b, l, hq, hd), inv_freq, scale)
-            k = rope_by(k.reshape(b, l, hkv, hd), inv_freq, scale)
-            v = v.reshape(b, l, hkv, hd)
-            out = (attn(q, k, v, window=self.window) if self.window
-                   else attn(q, k, v))
-            x = x + out.reshape(b, l, hq * hd) @ self.param(
-                "wo", _INIT, (hq * hd, d))
+            x = x + grouped_attention(
+                h, self.param("wq", _INIT, (d, hq * hd)),
+                self.param("wk", _INIT, (d, hkv * hd)),
+                self.param("wv", _INIT, (d, hkv * hd)),
+                self.param("wo", _INIT, (hq * hd, d)),
+                heads=hq, kv_heads=hkv, head_dim=hd, inv_freq=inv_freq,
+                scale=scale, attn=attn, window=self.window)
 
         norm = self.param("mlp_norm", ones, (d,))
         router = self.param("router", _INIT, (d, e))
@@ -426,22 +490,14 @@ class MellumBlock(nn.Module):
         # time.
         @jax.checkpoint
         def sparse(x, norm, router, wg, wu, wd):
-            with jax.named_scope("router"):
-                h = rms_norm(x, norm, eps).reshape(b * l, d)
-                logits = jnp.matmul(h, router, precision=ROUTER_PRECISION)
-                probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-                # top-k over all the experts, the k weights renormalised
-                # over the chosen k whether held here or not
-                weights, experts = moe.route_top_k(probs, k_tok,
-                                                   renormalise=True)
-                stats = (moe.load_max_over_mean(experts, e),
-                         moe.held_rows_share(experts, self.experts_first,
-                                             held))
-            y = moe.dispatch_top_k(
-                h, weights, experts, e,
-                lambda rows, sizes: moe.swiglu_experts(
-                    rows, sizes, wg, wu, wd, first))
-            return y.reshape(b, l, d), stats
+            # top-k over all the experts, the k weights renormalised
+            # over the chosen k whether held here or not
+            return sparse_mlp(
+                x, norm, router, (wg, wu, wd), eps=eps, n_experts=e,
+                first=self.experts_first, held=held,
+                route=lambda logits: (*moe.route_top_k(
+                    jax.nn.softmax(logits, axis=-1), k_tok,
+                    renormalise=True), ()))
 
         y, (load, share) = sparse(x, norm, router, wg, wu, wd)
         # telemetry, read only where the caller makes ``intermediates``
@@ -493,6 +549,209 @@ class MellumDecoder(nn.Module):
                 window=0 if full else self.window,
                 rope_theta=self.rope_theta, yarn=self.yarn,
                 norm_eps=self.norm_eps, attn_fn=self.attn_fn,
+            )(x)
+        with jax.named_scope("head_loss"):  # lm/model.py's NLL joins it
+            x = rms_norm(x, self.param("final_norm", nn.initializers.ones,
+                                       (d,)), self.norm_eps)
+            logits = x @ self.param("head", _INIT, (d, self.vocab))
+            return nn.log_softmax(logits)
+
+
+# ---------------------------------------------------------------------------
+# The hybrid block (LFM2, Liquid AI; ``model_type`` ``lfm2_moe``; the
+# configuration's keys are those of its ``config.json``).  Every layer
+# is ``x = x + op(RMSNorm(x))`` then ``x = x + ffn(RMSNorm(x))``, and
+# both halves are values read from the configuration layer by layer:
+# the token mixer ``op`` is a **gated short convolution**
+# (``layer_types[i] == "conv"``: no attention, no positions) or
+# full causal attention over grouped KV heads with an RMSNorm over each
+# head's width on the queries and the keys; the MLP ``ffn`` is dense
+# (SiLU-gated, ``dense_width`` wide: the leading ``num_dense_layers``)
+# or sparse: a **sigmoid** router over all ``n_experts`` with a
+# **selection bias** (it enters the choice of the top-k and not their
+# weights), the k scores divided by their sum plus 1e-6 and scaled, and
+# this chip's share of the experts, as Mellum's.  The plain float32
+# reference it is held to is ``chipbench/reference/lfm2_plain.py``,
+# which shares no code with this file (tests/test_lfm2.py).
+# ---------------------------------------------------------------------------
+
+#: The short convolution's taps are seeded at the scale of torch's own
+#: ``Conv1d`` default for one input channel and three taps (uniform
+#: within 1 / sqrt 3: std 1/3), not at 0.02: the operator's output is
+#: then of the size of its gated input, as an attention layer's is of
+#: its values.  At 0.02 a conv layer adds a thousandth of the stream,
+#: its parameters' gradients are lost in the norm of the whole, and the
+#: reference check could not tell a reversed convolution from a right
+#: one (2e-5 of the gradient's norm at the tiny size).
+LFM2_TAPS_INIT = nn.initializers.normal(stddev=1.0 / 3.0)
+#: the kinds of token mixer ``layer_types`` may name
+LFM2_MIXERS = ("conv", "full_attention")
+#: ``norm_topk_prob``'s guard against an all-zero top-k, as published
+LFM2_ROUTE_EPS = 1e-6
+
+
+class Lfm2Block(nn.Module):
+    d_model: int
+    mixer: str               # of LFM2_MIXERS
+    sparse: bool             # the MLP: sparse experts, else dense
+    n_heads: int
+    kv_heads: int
+    head_dim: int
+    dense_width: int
+    n_experts: int           # the router's width: every expert there is
+    experts_per_tok: int
+    expert_width: int
+    experts_first: int = 0   # the share held here: a contiguous range
+    experts_held: int = 0    # 0: all of them
+    conv_kernel: int = 3
+    route_scale: float = 1.0
+    rope_theta: float = 1000000.0
+    norm_eps: float = 1e-5
+    attn_fn: Optional[AttnFn] = None
+
+    @nn.compact
+    def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
+        if self.mixer not in LFM2_MIXERS:
+            raise ValueError(f"layer type {self.mixer!r}; have {LFM2_MIXERS}")
+        d, eps = self.d_model, self.norm_eps
+        ones = nn.initializers.ones
+        op_norm = self.param("op_norm", ones, (d,))
+        x = (self.short_conv if self.mixer == "conv"
+             else self.attention)(x, op_norm)
+        ffn_norm = self.param("ffn_norm", ones, (d,))
+        if not self.sparse:
+            with jax.named_scope("mlp"):
+                h = rms_norm(x, ffn_norm, eps)
+                w1 = self.param("w1", _INIT, (d, self.dense_width))
+                w3 = self.param("w3", _INIT, (d, self.dense_width))
+                w2 = self.param("w2", _INIT, (self.dense_width, d))
+                return x + (jax.nn.silu(h @ w1) * (h @ w3)) @ w2
+        return x + self.sparse_experts(x, ffn_norm)
+
+    def short_conv(self, x, norm):
+        """``x + (C * conv(B * z)) W_out`` with ``[B, C, z] = RMSNorm(x)
+        W_in``: two products under the scope ``conv``, and between them
+        the two gates and the depthwise causal convolution
+        (``ops/short_conv.py``) under ``conv_mix``, which are elementwise
+        and bound by memory; no bias anywhere (``conv_bias`` false)."""
+        d = self.d_model
+        w_in = self.param("conv_in", _INIT, (d, 3 * d))
+        taps = self.param("conv_taps", LFM2_TAPS_INIT,
+                          (self.conv_kernel, d))
+        w_out = self.param("conv_out", _INIT, (d, d))
+        with jax.named_scope("conv"):
+            bcz = rms_norm(x, norm, self.norm_eps) @ w_in
+        with jax.named_scope("conv_mix"):
+            gate_in, gate_out, z = jnp.split(bcz, 3, axis=-1)
+            y = gate_out * causal_depthwise_conv(gate_in * z, taps)
+        with jax.named_scope("conv"):
+            return x + y @ w_out
+
+    def attention(self, x, norm):
+        d, hq, hkv, hd = (self.d_model, self.n_heads, self.kv_heads,
+                          self.head_dim)
+        attn = self.attn_fn if self.attn_fn is not None else default_attn()
+        inv_freq = (self.rope_theta ** (
+            -np.arange(0, hd, 2, dtype=np.float64) / hd)).astype(np.float32)
+        ones = nn.initializers.ones
+        with jax.named_scope("attn"):
+            return x + grouped_attention(
+                rms_norm(x, norm, self.norm_eps),
+                self.param("wq", _INIT, (d, hq * hd)),
+                self.param("wk", _INIT, (d, hkv * hd)),
+                self.param("wv", _INIT, (d, hkv * hd)),
+                self.param("wo", _INIT, (hq * hd, d)),
+                heads=hq, kv_heads=hkv, head_dim=hd, inv_freq=inv_freq,
+                attn=attn, qk_norm=(self.param("q_norm", ones, (hd,)),
+                                    self.param("k_norm", ones, (hd,)),
+                                    self.norm_eps))
+
+    def sparse_experts(self, x, norm):
+        d, e, f = self.d_model, self.n_experts, self.expert_width
+        held = self.experts_held or e
+        router = self.param("router", _INIT, (d, e))
+        # the selection bias: part of the vector, seeded away from zero
+        # so that the selection it changes is exercised; no gradient
+        # reaches it and no rule here updates it (the published
+        # balancing has no rate in the configuration)
+        bias = self.param("router_bias", _INIT, (e,))
+        wg = self.param("experts_gate", _INIT, (held, d, f))
+        wu = self.param("experts_up", _INIT, (held, d, f))
+        wd = self.param("experts_down", _INIT, (held, f, d))
+
+        # recomputed in the backward pass, as Mellum's and for its
+        # reason: the dropless dispatch's k T rows a layer
+        @jax.checkpoint
+        def sparse(x, norm, router, bias, wg, wu, wd):
+            def route(logits):
+                scores = jax.nn.sigmoid(logits)
+                weights, chosen = moe.route_top_k(
+                    scores, self.experts_per_tok, renormalise=True,
+                    bias=bias, eps=LFM2_ROUTE_EPS, scale=self.route_scale)
+                return weights, chosen, (
+                    moe.bias_flips_share(scores, chosen),)
+
+            return sparse_mlp(
+                x, norm, router, (wg, wu, wd), route=route,
+                eps=self.norm_eps, n_experts=e, first=self.experts_first,
+                held=held)
+
+        y, (load, share, flips) = sparse(x, norm, router, bias, wg, wu, wd)
+        # telemetry, read only where the caller makes ``intermediates``
+        # mutable (lm/model.py stats)
+        self.sow("intermediates", "moe_load", load)
+        self.sow("intermediates", "moe_held", share)
+        self.sow("intermediates", "moe_flips", flips)
+        return y
+
+
+class Lfm2Decoder(nn.Module):
+    """Causal LM of :class:`Lfm2Block` layers: a token table (at
+    :data:`MELLUM_EMBED_INIT`'s scale, for the same reason: a share of
+    the experts is held), the blocks, a final RMSNorm and an untied
+    head; returns log-probabilities like the other decoders.  Layer
+    ``i``'s token mixer is ``layer_types[i]`` and its MLP is dense iff
+    ``i < dense_layers``: the layers held here are a run of the
+    published model's, so both are given for that run."""
+
+    vocab: int = 256
+    d_model: int = 64
+    n_heads: int = 4
+    kv_heads: int = 2
+    head_dim: int = 16
+    layer_types: tuple = ("conv", "full_attention", "conv", "conv")
+    dense_layers: int = 1
+    dense_width: int = 128
+    n_experts: int = 8
+    experts_per_tok: int = 2
+    expert_width: int = 32
+    experts_first: int = 0
+    experts_held: int = 0
+    conv_kernel: int = 3
+    route_scale: float = 1.0
+    rope_theta: float = 1000000.0
+    norm_eps: float = 1e-5
+    attn_fn: Optional[AttnFn] = None
+
+    @nn.compact
+    def __call__(self, tokens: jnp.ndarray) -> jnp.ndarray:
+        d = self.d_model
+        with jax.named_scope("embed"):
+            x = self.param("embed", MELLUM_EMBED_INIT,
+                           (self.vocab, d))[tokens]
+        for i, mixer in enumerate(self.layer_types):
+            x = Lfm2Block(
+                d_model=d, mixer=mixer, sparse=i >= self.dense_layers,
+                n_heads=self.n_heads, kv_heads=self.kv_heads,
+                head_dim=self.head_dim, dense_width=self.dense_width,
+                n_experts=self.n_experts,
+                experts_per_tok=self.experts_per_tok,
+                expert_width=self.expert_width,
+                experts_first=self.experts_first,
+                experts_held=self.experts_held,
+                conv_kernel=self.conv_kernel, route_scale=self.route_scale,
+                rope_theta=self.rope_theta, norm_eps=self.norm_eps,
+                attn_fn=self.attn_fn,
             )(x)
         with jax.named_scope("head_loss"):  # lm/model.py's NLL joins it
             x = rms_norm(x, self.param("final_norm", nn.initializers.ones,

@@ -124,15 +124,26 @@ def fused_nesterov_commit(
     retract right after its localupdate)."""
     n = w.shape[0]
     br = block_rows_for(n)
-    w2, _ = as_rows(w, br)
-    vt2, _ = as_rows(vt, br)
-    g2, _ = as_rows(g, br)
-    grid = (w2.shape[0] // br,)
+    if n % LANE == 0:
+        # Whole lanes: the vector is its own (rows, 128) view and no
+        # padded copy of it is made, which is what lets a caller's
+        # donated ``w`` and ``vt`` be updated where they lie (a pad
+        # copies each operand whole and the slice back each result).
+        # The grid's last block may overhang the rows: what it reads
+        # past the end is unspecified and what it writes there is
+        # dropped, and the kernel is elementwise.
+        def rows(x):
+            return x.reshape(n // LANE, LANE)
+    else:
+        def rows(x):
+            return as_rows(x, br)[0]
+    w2, vt2, g2 = rows(w), rows(vt), rows(g)
+    grid = (pl.cdiv(w2.shape[0], br),)
     retract = sug is not None
     operands = [_scalar(clr, w2.dtype), w2, vt2, g2]
     in_specs = [_scalar_spec(), _row_spec(br), _row_spec(br), _row_spec(br)]
     if retract:
-        operands.append(as_rows(sug, br)[0])
+        operands.append(rows(sug))
         in_specs.append(_row_spec(br))
     w_new, vt_new = pl.pallas_call(
         functools.partial(_nesterov_kernel, l2wd=float(l2wd), retract=retract),

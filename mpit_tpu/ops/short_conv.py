@@ -1,0 +1,25 @@
+"""The short causal depthwise convolution of a gated-convolution token
+mixer (LFM2's ``conv`` layers: ``models/transformer.py`` ``Lfm2Block``).
+
+``c[t] = sum_j taps[j] * u[t - (K - 1) + j]`` per channel, ``u`` zero
+before the sequence: the last tap multiplies the current position and
+nothing later is seen (torch's ``Conv1d(groups=d, padding=K - 1)`` cut
+to the sequence's length).  ``K`` is a handful (3), so the convolution
+is ``K`` shifted elementwise products that XLA fuses with the gates
+around it; its transpose is the same shifts the other way, so the
+backward pass has no scatter.  No state crosses sequences: a packed
+grid's rows are whole sequences.
+"""
+
+from __future__ import annotations
+
+import jax.numpy as jnp
+
+
+def causal_depthwise_conv(u: jnp.ndarray, taps: jnp.ndarray) -> jnp.ndarray:
+    """``u (B, L, d)`` convolved along ``L`` with ``taps (K, d)``, one
+    filter a channel, causal (the module's docstring has the index
+    convention); any ``L``, shorter than ``K`` included."""
+    k, length = taps.shape[0], u.shape[1]
+    padded = jnp.pad(u, ((0, 0), (k - 1, 0), (0, 0)))
+    return sum(taps[j] * padded[:, j:j + length] for j in range(k))

@@ -114,7 +114,9 @@ def msgd_step(
     """One full msgd step: lookahead -> grad at displaced w -> commit.
 
     ``value_and_grad_fn(w, *fn_args) -> (loss, grad)`` is the feval closure
-    analog (reference goot.lua:101-126).  Pure; jit the caller.
+    analog (reference goot.lua:101-126).  Pure; jit the caller
+    (:class:`MSGD` does, and donates ``w`` and ``state`` to the jitted
+    step: there the caller's arrays are consumed).
     """
     with jax.named_scope("update"):
         w_la, state = msgd_lookahead(w, state, cfg)
@@ -140,8 +142,13 @@ class MSGD:
         gauges and kept as ``stats_last``; with obs off they are never
         fetched and no span exists."""
         self.cfg = cfg
+        # ``w`` and ``state`` are donated: the step writes the new
+        # vector and momentum where the old ones lay, so it holds each
+        # once and not in and out, and the ``w`` handed to :meth:`step`
+        # is consumed.  Not the first call's: see :meth:`step`.
         self._step = jax.jit(
-            lambda w, state, *args: msgd_step(value_and_grad_fn, w, state, cfg, *args)
+            lambda w, state, *args: msgd_step(value_and_grad_fn, w, state, cfg, *args),
+            donate_argnums=(0, 1),
         )
         self.state: dict | None = None
         self._has_aux = has_aux
@@ -150,8 +157,15 @@ class MSGD:
         self.stats_last: dict = {}  # name -> the last recorded step's values
 
     def step(self, w: Any, *fn_args: Any) -> Tuple[Any, jnp.ndarray]:
+        """One step; returns the new ``w`` and the loss.  The ``w``
+        passed in is donated to the step and unreadable afterwards:
+        keep only what this returns.  The first call's is the
+        exception: a trainer starts from its model's seeded vector
+        (``flat.w0``, which ``LmTrainer.w`` aliases and the benchmark
+        reads again after warm-up), so that call steps on a copy."""
         if self.state is None:
             self.state = msgd_init(w)
+            w = jax.tree_util.tree_map(jnp.copy, w)
         if not self._has_aux:
             w, self.state, loss = self._step(w, self.state, *fn_args)
             return w, loss

@@ -53,17 +53,40 @@ from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
-def route_top_k(probs: jnp.ndarray, k: int, renormalise: bool = False
-                ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """The ``k`` largest router probabilities of each row and their
-    experts, ``(T, k)`` each; with ``renormalise`` the ``k`` weights are
-    divided by their sum (``norm_topk_prob``), else left as they are.
-    Ties break towards the lower expert index (``jax.lax.top_k`` puts
-    the lower index first)."""
-    weights, experts = jax.lax.top_k(probs, k)
+def route_top_k(scores: jnp.ndarray, k: int, renormalise: bool = False, *,
+                bias: Optional[jnp.ndarray] = None, eps: float = 0.0,
+                scale: float = 1.0) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """The ``k`` chosen experts of each row of the router's ``scores (T,
+    E)`` (a softmax's probabilities, or a sigmoid's scores) and their
+    weights, ``(T, k)`` each.  Chosen are the ``k`` largest scores or,
+    with a ``bias (E,)``, the ``k`` largest of ``scores + bias``: the
+    bias enters the selection only, the weights are the chosen experts'
+    scores without it, so no gradient reaches it.  With ``renormalise``
+    the ``k`` weights are divided by their sum plus ``eps``
+    (``norm_topk_prob``), else left as they are; then multiplied by
+    ``scale`` (``routed_scaling_factor``).  Ties break towards the lower
+    expert index (``jax.lax.top_k`` puts the lower index first)."""
+    if bias is None:
+        weights, experts = jax.lax.top_k(scores, k)
+    else:
+        _, experts = jax.lax.top_k(scores + bias, k)
+        weights = jnp.take_along_axis(scores, experts, axis=-1)
     if renormalise:
-        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+        total = jnp.sum(weights, axis=-1, keepdims=True)
+        weights = weights / (total + eps if eps else total)
+    if scale != 1.0:
+        weights = weights * scale
     return weights, experts
+
+
+def bias_flips_share(scores: jnp.ndarray, experts: jnp.ndarray
+                     ) -> jnp.ndarray:
+    """What a selection bias changes: the share of the ``T k`` chosen
+    ``experts`` that are not among the ``k`` largest of ``scores``
+    alone (0: the bias moves no choice)."""
+    _, plain = jax.lax.top_k(scores, experts.shape[-1])
+    kept = jnp.any(experts[:, :, None] == plain[:, None, :], axis=-1)
+    return 1.0 - jnp.mean(kept.astype(jnp.float32))
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(3,))

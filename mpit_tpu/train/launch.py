@@ -213,8 +213,12 @@ LAUNCH_DEFAULTS = TRAINER_DEFAULTS.merged(
     # (RMSNorm, rotary positions, q/k norm, top-k of E gated experts by
     # sorted dropless dispatch) | mellum (grouped KV heads of their own
     # width, window and full attention mixed, top-k renormalised, a
-    # share of the experts).  lm_experts .. lm_norm_eps are the sparse
-    # blocks'; lm_kv_heads .. lm_yarn_attn_factor are mellum's own
+    # share of the experts) | lfm2 (a gated short convolution or
+    # grouped-head attention with a per-head q/k norm by layer, a dense
+    # or a sparse MLP by layer, a sigmoid router with a selection bias).
+    # lm_experts .. lm_norm_eps are the sparse blocks'; lm_kv_heads ..
+    # lm_yarn_attn_factor are mellum's own, of which lfm2 takes the
+    # first four; lm_layer_types .. lm_route_scale are lfm2's own
     # (lm/model.py build has each one's meaning and what 0 stands for)
     lm_arch="gpt2",
     lm_experts=8,
@@ -233,6 +237,11 @@ LAUNCH_DEFAULTS = TRAINER_DEFAULTS.merged(
     lm_yarn_beta_fast=32.0,
     lm_yarn_beta_slow=1.0,
     lm_yarn_attn_factor=1.0,
+    lm_layer_types="",
+    lm_dense_layers=0,
+    lm_dense_width=0,
+    lm_conv_kernel=3,
+    lm_route_scale=1.0,
     lm_d_model=64,
     lm_heads=4,
     lm_layers=2,
@@ -352,12 +361,12 @@ def lm_trainer_cfg(cfg: Config) -> Config:
     """The :data:`mpit_tpu.lm.trainer.LM_DEFAULTS`-shaped config for one
     launch config: shared optimizer/loop knobs carried over verbatim,
     lm_* knobs mapped onto the trainer's names."""
-    from mpit_tpu.lm.model import MELLUM_KEYS
+    from mpit_tpu.lm.model import LFM2_KEYS, MELLUM_KEYS
     from mpit_tpu.lm.trainer import LM_DEFAULTS
 
     return Config(
         **{key: type(LM_DEFAULTS[key])(cfg.get(f"lm_{key}", LM_DEFAULTS[key]))
-           for key in MELLUM_KEYS},
+           for key in MELLUM_KEYS + LFM2_KEYS},
         arch=str(cfg.get("lm_arch", "gpt2")),
         n_experts=int(cfg.get("lm_experts", 8)),
         experts_per_tok=int(cfg.get("lm_experts_per_tok", 2)),

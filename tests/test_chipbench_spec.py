@@ -54,7 +54,7 @@ def test_scopes_come_from_the_cells_configuration():
 def test_every_cells_vocabulary_reaches_the_program_through_the_launcher():
     from chipbench import run as runner
 
-    want = {"gpt2": 50257, "olmoe": 50304, "mellum": 12288}
+    want = {"gpt2": 50257, "olmoe": 50304, "mellum": 12288, "lfm2_moe": 8192}
     for name in CELLS:
         cell = spec_mod.load_cell(name)
         cfg = runner.launch_config(cell, seed=5)
@@ -72,7 +72,7 @@ def test_scopes_come_from_every_committed_configuration():
     # lists, in order of first mention
     assert spantree.model_scopes({}) == [
         "embed", "attn", "mlp", "head_loss", "update", "router", "dispatch",
-        "experts", "attn_window"]
+        "experts", "attn_window", "conv", "conv_mix"]
 
 
 def olmoe_cases():
@@ -352,3 +352,153 @@ def test_the_benchmarks_copy_of_mellums_reference_is_the_programs_to_the_bit():
     assert not hasattr(mellum_reference, "GRAD_REL_TOL")
     assert 0 < cell.reference().LOSS_TOL_NATS < 1e-2
     assert 0 < cell.reference().GRAD_REL_TOL < 1e-1
+
+
+# -- the LFM2 configuration (PR 32) -----------------------------------------------
+
+LFM2_CELL = "lfm2-l5e8-local"
+
+
+def lfm2_cases():
+    return spec_mod.load_cell(LFM2_CELL).arithmetic().hand_worked()
+
+
+@pytest.mark.parametrize("what,got,want", lfm2_cases(),
+                         ids=[c[0] for c in lfm2_cases()])
+def test_lfm2_arithmetic_by_hand(what, got, want):
+    assert got == want, what
+
+
+def test_lfm2_file_has_the_catalogs_keys_and_states_its_cuts():
+    """Every number of the catalog's entry under its own key (the
+    model-configs guide's ``architectures.jsonl``, read where it is
+    installed; the hand-copied numbers below where it is not), nested
+    groups and ``layer_types`` whole; only ``reduced`` differs, no width
+    among it, each cut at the guide's floor with the published count
+    beside it, and a key for which layers are here."""
+    import pathlib
+
+    cell = spec_mod.load_cell(LFM2_CELL)
+    config = cell.config
+    catalog = {
+        "conv_L_cache": 3, "conv_bias": False, "hidden_size": 2048,
+        "intermediate_size": 11776, "max_position_embeddings": 128000,
+        "model_type": "lfm2_moe", "moe_intermediate_size": 1536,
+        "norm_eps": 1e-05, "norm_topk_prob": True,
+        "num_attention_heads": 32, "num_dense_layers": 2,
+        "num_experts": 64, "num_experts_per_tok": 4,
+        "num_hidden_layers": 40, "num_key_value_heads": 8,
+        "routed_scaling_factor": 1, "use_expert_bias": True,
+        "vocab_size": 65536}
+    path = pathlib.Path(
+        "/opt/skills/guides/model-configs/architectures.jsonl")
+    if path.exists():
+        rows = [json.loads(line) for line in path.read_text().splitlines()]
+        entry = next(r for r in rows if r["name"] == "LFM2-24B-A2B")
+        assert {k: v for k, v in entry["config"].items()
+                if not isinstance(v, (list, dict))} == catalog
+        assert entry["source_url"] == config["source"]
+        catalog = entry["config"]
+        assert config["layer_types"] == catalog["layer_types"]
+        assert config["rope_parameters"] == catalog["rope_parameters"]
+    differ = sorted(k for k, v in catalog.items() if config.get(k, "?") != v)
+    assert differ == sorted(config["reduced"]) == [
+        "num_experts", "num_hidden_layers", "vocab_size"]
+    assert config["published"] == {k: catalog[k] for k in config["reduced"]}
+    # the floors: the dense layer and a whole period of four after it,
+    # 8 experts, an eighth of the vocabulary
+    assert (config["num_hidden_layers"], config["num_experts"],
+            config["vocab_size"]) == (5, 8, 65536 // 8)
+    assert config["router_experts"] == 64   # the router keeps its width
+    assert (config["first_layer"], config["dense_layers_here"]) == (1, 1)
+    entry = next(c for c in cell.bench["configs"]
+                 if c["name"] == cell.config_name)
+    assert sorted(entry["reduced"]) == differ
+    assert entry["source"] == config["source"]
+    assert cell.chips == 1
+    assert ["embed", "conv", "conv_mix", "attn", "mlp", "router", "dispatch",
+            "experts", "head_loss", "update"] == config["scopes"]
+    assert all(key in config for key in config["launcher_from"].values())
+    assert all(key in config for key in config["tiny"])
+    assert len(config["assumed"]) >= 6 and config["deployment"]
+
+
+def test_lfm2s_readers_find_nothing_in_a_run_without_the_block():
+    """What the parent's traced run hands them: a cell whose
+    configuration lists no such scope, whose arithmetic has no such
+    cost, a program that recorded no such counter, no merged trace:
+    None, no raise."""
+    for name in ("mellum2-l4e8-local", "c111m-local"):
+        cell = spec_mod.load_cell(name)
+        run = {"cell": cell, "reduction": {"step_module": "jit__lambda"},
+               "obs_trace": None, "peaks": None, "results": {},
+               "summary": {"worker_ranks": [0], "window": [0.0, 1.0]}}
+        for metric in ("conv_ms_per_step", "conv_mix_ms_per_step",
+                       "conv_mix_roofline", "router_bias_flips_pct"):
+            reader = spec_mod.load_reader(cell.root, cell.bench, metric)
+            assert reader is not None and reader(dict(run)) is None
+
+
+def test_lfm2s_readers_read_a_hand_made_run(monkeypatch):
+    """The four readers, and the five appended ones, on a scope table
+    and a span tree made by hand: 30 ms under ``conv`` and 12 under
+    ``conv_mix`` a step; three rounds whose four sparse layers flip 2%,
+    4% and 4% of their choices."""
+    from chipbench import flops
+    from chipbench.layers import spantree
+
+    cell = spec_mod.load_cell(LFM2_CELL)
+
+    class Round:
+        def __init__(self, k, flips):
+            self.args = {"round": k, "moe_bias_flips_share": [flips] * 4,
+                         "moe_held_rows_share": [0.125] * 4,
+                         "moe_load_max_over_mean": [1.5, 1.4, 1.9, 1.6]}
+
+    class Tree:
+        def rounds(self):
+            return [Round(7, 0.02), Round(8, 0.04), Round(9, 0.04)]
+
+    table = {"step": 250.0, "conv": 30.0, "conv_mix": 12.0, "router": 5.0,
+             "dispatch": 35.0, "experts": 40.0, "head_loss": 11.0}
+    monkeypatch.setattr(spantree, "scope_ms_per_step", lambda run: table)
+    monkeypatch.setattr(spantree, "xplane_path", lambda run: None)
+    run = {"cell": cell, "peaks": flops.load_peaks("TPU v5 lite"),
+           spantree.CACHE_KEY: Tree(),
+           "reduction": {"step_module_runs": 2, "mosaic_by_scope": {
+               "attn": (4, 0.040), "experts": (96, 0.060)}}}
+
+    def read(name):
+        return spec_mod.load_reader(cell.root, cell.bench, name)(run)
+
+    assert read("conv_ms_per_step") == pytest.approx(42.0)
+    assert read("conv_mix_ms_per_step") == pytest.approx(12.0)
+    cost = cell.arithmetic().conv_mix_cost(cell.config, 1)
+    assert cost["bytes"] == 4 * 4 * (8192 * 22528 + 18_432)
+    assert read("conv_mix_roofline") == pytest.approx(
+        100 * cost["bytes"] / 819e9 / 0.012)
+    assert read("router_bias_flips_pct") == pytest.approx(4.0)
+    assert read("dispatch_ms_per_step") == pytest.approx(40.0)
+    assert read("held_experts_ms_per_step") == pytest.approx(40.0)
+    assert read("held_rows_share_pct") == pytest.approx(12.5)
+    assert read("expert_load_max_over_mean") == pytest.approx(1.9)
+    assert read("flash_ms_per_step") == pytest.approx(20.0)
+    experts = cell.arithmetic().experts_cost(cell.config, 1)
+    assert read("held_experts_roofline") == pytest.approx(
+        100 * max(experts["flops"] / 197e12, experts["bytes"] / 819e9)
+        / 0.030)
+    # every metric the cell lists has a reader
+    for metric in cell.metrics("per_layer"):
+        assert spec_mod.load_reader(cell.root, cell.bench,
+                                    metric["name"]) is not None
+
+
+def test_the_parent_fails_the_new_cell_at_once():
+    """A checkout without the cell's entry ends the command with a
+    ``SpecError``, before any gang: what the driver's trial of the new
+    cell on the parent needs."""
+    bench = spec_mod.load_bench()
+    names = [w["name"] for w in bench["workloads"]]
+    assert names[-1] == LFM2_CELL and len(names) == 6
+    with pytest.raises(spec_mod.SpecError, match="no workload"):
+        spec_mod.load_cell("lfm2-l5e8-locals")

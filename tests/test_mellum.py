@@ -254,8 +254,11 @@ def test_the_table_is_at_std_8_and_the_rest_at_002(case):
 
 # -- the flash kernel: grouped KV heads and the window ------------------------------
 
-# (query heads, KV heads, sequence, window); blocks are 64 x 128
+# (query heads, KV heads, sequence, window[, head width: 32]); blocks
+# are 64 x 128
 FLASH_CASES = {
+    # LFM2's attention layer (PR 32): 32 query over 8 KV heads of 64
+    "grouped 32 on 8, heads of 64": (32, 8, 256, None, 64),
     "grouped 4 on 1": (4, 1, 256, None),
     "grouped 8 on 2": (8, 2, 256, None),
     "window ends inside a block": (4, 4, 256, 100),
@@ -274,12 +277,13 @@ def test_flash_kernel_equals_the_reference(what, schedule, monkeypatch):
     ``attention_reference`` with the same two arguments; under a window
     ``auto`` is the two-kernel backward and ``1`` forces the fused."""
     monkeypatch.setenv("MPIT_FA_FUSED_BWD", schedule)
-    hq, hkv, seq, window = FLASH_CASES[what]
+    hq, hkv, seq, window, *width = FLASH_CASES[what]
+    head = width[0] if width else 32
     keys = jax.random.split(jax.random.PRNGKey(hq * 1000 + seq), 4)
-    q = jax.random.normal(keys[0], (2, hq, seq, 32))
-    k = jax.random.normal(keys[1], (2, hkv, seq, 32))
-    v = jax.random.normal(keys[2], (2, hkv, seq, 32))
-    g = jax.random.normal(keys[3], (2, hq, seq, 32))
+    q = jax.random.normal(keys[0], (2, hq, seq, head))
+    k = jax.random.normal(keys[1], (2, hkv, seq, head))
+    v = jax.random.normal(keys[2], (2, hkv, seq, head))
+    g = jax.random.normal(keys[3], (2, hq, seq, head))
 
     def kernel(q, k, v):
         return jnp.sum(g * flash_attention(
@@ -433,10 +437,13 @@ def test_route_top_k_renormalises_over_the_chosen():
     (1024, 2048, (256, 1024, 1024)),
     (2304, 896, (256, 768, 896)),       # Mellum's: 768 divides 2304
     (896, 2304, (256, 896, 768)),
+    (2048, 1536, (256, 1024, 768)),     # LFM2's: 768 divides 1536
+    (1536, 2048, (256, 768, 1024)),
     (128, 128, (256, 128, 128)),
 ])
 def test_the_grouped_products_tiling(k, n, tiling):
     assert moe.pallas_fits(65536, k, n)
+    assert moe.pallas_fits(32768, k, n)   # LFM2's 4 T rows at 8192
     assert moe._gmm_tiling(k, n) == tiling
     assert k % tiling[1] == 0 and n % tiling[2] == 0
 

@@ -1,7 +1,10 @@
 """Compiles for a *described* v5e, no chip attached (the
 on-chip-measurement guide, section 2): the Pallas grouped product of
-``parallel/moe.py`` at OLMoE's published shapes, forward and backward.
-What interpret mode cannot show: that the tiles fit the chip's fast
+``parallel/moe.py`` at OLMoE's published shapes, forward and backward;
+the flash kernel at LFM2's attention shape (32 query over 8 KV heads of
+64 at 8192); and the msgd commit over LFM2's vector, whose length is
+whole lanes and no whole number of blocks, with ``w`` and ``vt``
+donated.  What interpret mode cannot show: that the tiles fit the chip's fast
 memory and the kernels lower.  A compile that passes is not a chip run
 and says nothing about time.
 
@@ -47,3 +50,43 @@ def test_the_pallas_grouped_product_compiles_at_published_shapes(one_chip, k, n)
     assert moe.pallas_fits(ROWS, k, n)
     # float32 results: the weights' gradient is never rounded to bf16
     assert "f32[64,%d,%d]" % (k, n) in text
+
+
+def test_flash_attention_compiles_at_32_over_8_heads_of_64(one_chip):
+    """LFM2's attention layer (PR 32): a group's four query heads folded
+    into the kernel's rows, the head width padded to the lanes; forward
+    and the backward kernels lower for the chip, k and v at the KV
+    heads' size."""
+    from mpit_tpu.ops.flash_attention import flash_attention
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, causal=True,
+                                       interpret=False) ** 2)
+
+    q = jax.ShapeDtypeStruct((1, 32, 8192, 64), jnp.float32,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, 8, 8192, 64), jnp.float32,
+                              sharding=one_chip)
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv).compile()
+    calls = compiled.as_text().count('custom_call_target="tpu_custom_call"')
+    assert calls >= 2   # forward, and the fused or the two backward kernels
+    dq, dk, dv = compiled.output_shardings
+
+
+def test_the_donated_commit_keeps_no_copy_of_lfm2s_vector(one_chip):
+    """486,062,464 elements are whole lanes and 14,833.45 blocks: the
+    commit sweeps the vector where it lies with an overhanging last
+    block, so with ``w`` and ``vt`` donated the program holds no third
+    vector (a padded copy of each operand would be three)."""
+    from mpit_tpu.ops.fused_update import fused_nesterov_commit
+
+    n = 486_062_464
+    vec = jax.ShapeDtypeStruct((n,), jnp.float32, sharding=one_chip)
+    compiled = jax.jit(
+        lambda w, vt, g: fused_nesterov_commit(w, vt, g, 0.03,
+                                               interpret=False),
+        donate_argnums=(0, 1)).lower(vec, vec, vec).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * 4 * n   # both, to the tile
+    assert mem.temp_size_in_bytes < 4 * n // 8
