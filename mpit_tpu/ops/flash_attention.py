@@ -23,8 +23,19 @@ showcase the rebuild adds on top of capability parity.  Design:
   and ``dk``, ``dv`` are summed over the group in the backward kernel's
   own accumulator.  The head counts travel in the shapes.
 - **A sliding window** (``window``, causal): a block wholly outside
-  ``[i - window + 1, i]`` is skipped like a block above the diagonal,
-  forward and in both backward kernels (:func:`_block_bounds`).
+  ``[i - window + 1, i]`` is dead like a block above the diagonal.
+- **The grids walk live blocks only** (:class:`_Walk`): for an outer
+  block the live inner blocks are one range ``[lo, hi]``, worked out
+  from the offsets, traced or not, once a call (:func:`_prefetch`) and
+  prefetched with the three scalars (``PrefetchScalarGridSpec``), so
+  the index maps read it: inner step ``t`` visits block ``lo + t``.
+  Under a window the inner axis is the static bound on a range's length
+  (4 where a row has 16 blocks, at window 1024 on 512-blocks); under
+  plain causal masking it stays the row, and a step past the range names
+  the block already held.  A dead block is neither fetched nor computed,
+  forward and in every backward kernel; one function holds the bounds
+  for index maps and bodies alike.  :func:`flash_step_counts` says how
+  many steps a call visits and how many are live.
 
 :func:`flash_attention` is the user op (normalized output, custom VJP:
 pallas backward in the standard flash schedule — P is recomputed
@@ -40,6 +51,7 @@ O(block_q·block_k) scratch, never the (Lq, Lk) score matrix).
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import math
 import os
@@ -47,9 +59,11 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from mpit_tpu.obs.metrics import get_registry
 from mpit_tpu.ops.tiles import (
     LANE, round_up as _round_up, use_interpret as _interpret,
 )
@@ -246,83 +260,271 @@ def finalize_partials(acc, l, dtype=jnp.float32):
 # ---------------------------------------------------------------------------
 
 
-def _q_row0(qoff_ref, i, block_q, q_blocks):
-    """Global position of the first query row of q block ``i``.  With
-    grouped KV heads the query heads of a group lie one after another
-    along the rows, ``q_blocks`` blocks each, so the position starts
-    over at every head; ``q_blocks`` None is the equal-heads program,
-    with no modulus in it."""
-    if q_blocks is not None:
-        i = i % q_blocks
-    return qoff_ref[0, 0] + i * block_q
+def _binds(prim, swap=False):
+    def op(self, other):
+        x, y = self.v, other.v if isinstance(other, _Int) else other
+        return _Int(prim(y, x) if swap else prim(x, y))
+    return op
 
 
-def _block_bounds(qoff_ref, kvoff_ref, kvlen_ref, i, j, *, causal,
-                  block_q, block_k, window=None, q_blocks=None):
-    """(live, full) triage for the (i, j) tile — the ONE copy of the
-    off-by-one-sensitive causal and window boundary rules, shared by
-    forward and both backward kernels: dead blocks skip everything, full
-    blocks take the mask-free fast path, edge (diagonal / window-edge /
-    kv_len-straddling) blocks mask."""
-    q_lo = _q_row0(qoff_ref, i, block_q, q_blocks)
-    k_hi_local = (j + 1) * block_k  # exclusive
-    live = j * block_k < kvlen_ref[0, 0]
-    full = k_hi_local <= kvlen_ref[0, 0]
-    if causal:
-        q_max = q_lo + (block_q - 1)
-        k_min = kvoff_ref[0, 0] + j * block_k
-        live = jnp.logical_and(live, q_max >= k_min)
-        # fully live: even the block's last key is <= the first query row
-        full = jnp.logical_and(
-            full, q_lo >= kvoff_ref[0, 0] + k_hi_local - 1
-        )
-    if window is not None:
-        # live: the block's last key is inside the first row's window;
-        # full: even its first key is inside the last row's
-        k_max = kvoff_ref[0, 0] + k_hi_local - 1
-        live = jnp.logical_and(live, q_lo - k_max < window)
-        full = jnp.logical_and(
-            full,
-            q_lo + (block_q - 1) - (kvoff_ref[0, 0] + j * block_k) < window)
-    return live, full
+class _Int:
+    """A traced integer (or boolean) whose operators bind ``lax``
+    primitives directly.  ``jnp``'s operators on a tracer each go
+    through a jitted ufunc, a nested trace an operation: written with
+    them, the walk's arithmetic added seconds to every start-up of a
+    ten-layer model, compile cache warm or not (PERF.md section 6,
+    PR 33).  Also the ``xp`` of
+    :meth:`_Walk.live_range` for traced operands, beside ``numpy`` for
+    concrete ones.  Division truncates where ``//`` floors: the walk
+    divides a negative number only where it discards the result."""
+
+    __slots__ = ("v",)
+
+    def __init__(self, v):
+        self.v = v
+
+    __add__ = __radd__ = _binds(jax.lax.add)
+    __sub__ = _binds(jax.lax.sub)
+    __rsub__ = _binds(jax.lax.sub, swap=True)
+    __mul__ = __rmul__ = _binds(jax.lax.mul)
+    __floordiv__ = _binds(jax.lax.div)
+    __mod__ = _binds(jax.lax.rem)
+    __lt__ = _binds(jax.lax.lt)
+    __le__ = _binds(jax.lax.le)
+    __ge__ = _binds(jax.lax.ge)
+    __gt__ = _binds(jax.lax.gt)
+    __or__ = __ror__ = _binds(jax.lax.bitwise_or)
+    __and__ = __rand__ = _binds(jax.lax.bitwise_and)
+    minimum = staticmethod(lambda a, b: _binds(jax.lax.min)(_Int.of(a), b))
+    maximum = staticmethod(lambda a, b: _binds(jax.lax.max)(_Int.of(a), b))
+
+    @staticmethod
+    def of(x):
+        return x if isinstance(x, _Int) else _Int(x)
+
+    @staticmethod
+    def where(cond, a, b):
+        """``lax.select`` takes three arrays of one shape."""
+        cond, a, b = (_Int.of(x).v for x in (cond, a, b))
+        shape = np.broadcast_shapes(*map(np.shape, (cond, a, b)))
+
+        def whole(x, dtype):
+            x = x if isinstance(x, jax.Array) else np.asarray(x, dtype)
+            return x if x.shape == shape else jax.lax.broadcast(x, shape)
+
+        return _Int(jax.lax.select(whole(cond, bool), whole(a, np.int32),
+                                   whole(b, np.int32)))
 
 
-def _valid(qi, kj_local, kvoff_ref, kvlen_ref, causal, window):
-    """Elementwise validity of an edge block: the mask of
-    :func:`_mask` on a tile's global query rows and local key columns."""
-    valid = kj_local < kvlen_ref[0, 0]
-    if causal:
-        valid = valid & (qi >= kvoff_ref[0, 0] + kj_local)
-    if window is not None:
-        valid = valid & (qi - (kvoff_ref[0, 0] + kj_local) < window)
+@dataclasses.dataclass(frozen=True)
+class _Walk:
+    """The live-block walk of one kernel call: which inner blocks a grid
+    row visits, and the ONE copy of the off-by-one-sensitive causal,
+    window and ``kv_len`` boundary rules, shared by the index maps (what
+    is fetched) and the kernel bodies (what is computed) of the forward
+    and every backward kernel: both read the ranges that
+    :func:`_prefetch` makes with :meth:`live_range`.
+
+    The outer grid axis is the q blocks (forward, dQ) or, with
+    ``kv_outer``, the kv blocks (dK/dV, fused); the inner axis walks the
+    other side.  For an outer block the inner blocks in which the mask
+    has any true entry are one contiguous range ``[lo, hi]``
+    (:meth:`live_range`); inner step ``t`` visits block ``lo + t``, and
+    a step past ``hi`` names block ``hi`` again, which the pipeline
+    already holds and does not fetch, and runs no product.  Under a
+    ``window`` a range is never longer than :attr:`extent`, a static
+    bound far below the row's length, and that is the inner axis; under
+    plain causal masking a range can be the whole row, the extent stays
+    and the dead steps are the clamped ones.  So a block above the
+    diagonal, outside the window or beyond ``kv_len`` is never fetched.
+
+    With grouped KV heads (``groups`` > 1) the group's query heads lie
+    one after another along the rows, ``q_blocks`` blocks each
+    (:func:`_fold`): a q-outer row starts its positions over at every
+    head, and a kv-outer row walks its range once a head, ``extent``
+    steps each."""
+
+    kv_outer: bool
+    causal: bool
+    window: int | None
+    block_q: int
+    block_k: int
+    q_blocks: int   # q blocks of one head
+    kv_blocks: int
+    groups: int = 1
+
+    @property
+    def inner_blocks(self) -> int:
+        return self.q_blocks if self.kv_outer else self.kv_blocks
+
+    @property
+    def extent(self) -> int:
+        """Inner steps of one range: the static bound on its length.
+        ``window + b_outer - 1`` consecutive positions see an outer
+        block, and ``n`` of them touch at most ``ceil((n - 1) / b) + 1``
+        inner blocks, whatever the offsets."""
+        if self.window is None:
+            return self.inner_blocks
+        b_outer, b_inner = ((self.block_k, self.block_q) if self.kv_outer
+                            else (self.block_q, self.block_k))
+        return min(self.inner_blocks,
+                   -(-(self.window + b_outer - 2) // b_inner) + 1)
+
+    @property
+    def grid(self) -> Tuple[int, int]:
+        if self.kv_outer:
+            return self.kv_blocks, self.groups * self.extent
+        return self.groups * self.q_blocks, self.extent
+
+    def live_range(self, outer, q_off, kv_off, kv_len, xp=_Int):
+        """``(lo, hi)``: the inner blocks of outer block ``outer`` in
+        which :func:`_mask` has any true entry; ``hi < lo`` when there
+        is none (all keys masked: a ring step wholly above the diagonal,
+        ``kv_len`` 0).  A q block's rows see the keys from its first
+        row's window start to its last row's own position; a kv block's
+        keys are seen by the rows from its first key's position to its
+        last key's window end: the same rule read from either side,
+        shifted to the inner side's local index and clipped to its
+        length.  Integer arithmetic on whatever ``xp`` computes with:
+        traced :class:`_Int` scalars in an index map or a body (or a
+        traced vector of outer blocks, :func:`_sum_visited`), numpy
+        arrays of outer blocks for the trace-time step counts and the
+        tests."""
+        bq, bk = self.block_q, self.block_k
+        lo_pos = hi_pos = None
+        if self.kv_outer:
+            first = kv_off + outer * bk
+            last = kv_off + xp.minimum((outer + 1) * bk, kv_len) - 1
+            if self.causal:
+                lo_pos = first - q_off
+            if self.window is not None:
+                hi_pos = last + (self.window - 1) - q_off
+            inner_len, b_inner = self.q_blocks * bq, bq
+        else:
+            head_i = outer % self.q_blocks if self.groups > 1 else outer
+            first = q_off + head_i * bq
+            last = first + (bq - 1)
+            if self.window is not None:
+                lo_pos = first - (self.window - 1) - kv_off
+            if self.causal:
+                hi_pos = last - kv_off
+            inner_len, b_inner = kv_len, bk
+        lo_pos = 0 if lo_pos is None else xp.maximum(lo_pos, 0)
+        hi_pos = (inner_len - 1 if hi_pos is None
+                  else xp.minimum(hi_pos, inner_len - 1))
+        empty = hi_pos < lo_pos
+        if self.kv_outer:  # a kv block wholly beyond kv_len has no key
+            empty = empty | (last < first)
+        lo = lo_pos // b_inner
+        return lo, xp.where(empty, lo - 1, hi_pos // b_inner)
+
+    def fetch(self, outer, t, lo_ref, hi_ref, *_scalars):
+        """Index-map half: the inner block held at inner step ``t`` (a
+        folded row block under ``kv_outer``): the walk's block, held at
+        the range's end past it; an empty range holds any valid block.
+        The ranges are the prefetched ones (:func:`_prefetch`)."""
+        t, lo, hi = _Int(t), _Int(lo_ref[outer]), _Int(hi_ref[outer])
+        head = 0
+        if self.kv_outer and self.groups > 1:
+            head, t = t // self.extent * self.q_blocks, t % self.extent
+        held = _Int.minimum(_Int.maximum(_Int.minimum(lo + t, hi), 0),
+                            self.inner_blocks - 1)
+        return (head + held).v
+
+    def tile(self, lo_ref, hi_ref, qoff_ref, kvoff_ref, kvlen_ref):
+        """Body half, for this grid step: ``(q_lo, j, live, full, first,
+        last)``: the global position of the tile's first query row, its
+        kv block, the triage (dead tiles skip everything, full ones take
+        the mask-free fast path, edge ones (diagonal, window edge,
+        ``kv_len``-straddling) mask) and whether the step is the
+        walk's first or last (init, finalize)."""
+        outer, t = pl.program_id(0), _Int(pl.program_id(1))
+        first, last = t <= 0, t >= pl.num_programs(1) - 1
+        q_off, kv_off, kv_len = (_Int(ref[0]) for ref in
+                                 (qoff_ref, kvoff_ref, kvlen_ref))
+        lo, hi, outer = _Int(lo_ref[outer]), _Int(hi_ref[outer]), _Int(outer)
+        if self.kv_outer:
+            if self.groups > 1:
+                t = t % self.extent
+            head_i, j = lo + t, outer
+            live = head_i <= hi
+        else:
+            head_i = outer % self.q_blocks if self.groups > 1 else outer
+            j = lo + t
+            live = j <= hi
+        bq, bk = self.block_q, self.block_k
+        q_lo = q_off + head_i * bq
+        k_end = (j + 1) * bk  # local, exclusive
+        full = k_end <= kv_len
+        if self.causal:
+            # even the block's last key is <= the first query row
+            full = full & (q_lo >= kv_off + k_end - 1)
+        if self.window is not None:
+            # even its first key is inside the last row's window
+            full = full & (q_lo + (bq - 1) - (kv_off + j * bk) < self.window)
+        return tuple(x.v for x in (q_lo, j, live, full, first, last))
+
+    def steps(self, q_off=0, kv_off=0, kv_len=None) -> dict:
+        """Grid steps of one call: ``visited`` (the grid's shape),
+        ``live`` (tiles computed) and ``rect`` (the whole rectangle, the
+        grid before the walk), from :meth:`live_range` on concrete
+        offsets."""
+        kv_len = self.kv_blocks * self.block_k if kv_len is None else kv_len
+        outer = np.arange(self.grid[0])
+        lo, hi = self.live_range(outer, int(q_off), int(kv_off),
+                                 int(kv_len), xp=np)
+        # a rule that does not depend on the outer block gives scalars
+        live = int(np.broadcast_to(np.maximum(hi - lo + 1, 0),
+                                   outer.shape).sum())
+        return {
+            "visited": self.grid[0] * self.grid[1],
+            "live": live * (self.groups if self.kv_outer else 1),
+            "rect": self.groups * self.q_blocks * self.kv_blocks,
+        }
+
+
+def _valid(q_lo, j, kvoff_ref, kvlen_ref, shape, walk):
+    """Elementwise validity of an edge tile: the mask of :func:`_mask`
+    on its global query rows (from ``q_lo``) and the local key columns
+    of kv block ``j``."""
+    qi = q_lo + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    kj_local = (j * walk.block_k
+                + jax.lax.broadcasted_iota(jnp.int32, shape, 1))
+    valid = kj_local < kvlen_ref[0]
+    if walk.causal:
+        valid = valid & (qi >= kvoff_ref[0] + kj_local)
+    if walk.window is not None:
+        valid = valid & (qi - (kvoff_ref[0] + kj_local) < walk.window)
     return valid
 
 
-def _fa_kernel(qoff_ref, kvoff_ref, kvlen_ref, q_ref, k_ref, v_ref, o_ref,
-               *rest, causal, scale, block_q, block_k, partial, precision,
-               window=None, q_blocks=None):
+def _run_tile(live, full, block):
+    """The triage of :meth:`_Walk.tile` carried out: a dead tile runs
+    nothing, a full one ``block(masked=False)``, an edge one the masked
+    form."""
+    pl.when(jnp.logical_and(live, full))(
+        functools.partial(block, masked=False))
+    pl.when(jnp.logical_and(live, jnp.logical_not(full)))(
+        functools.partial(block, masked=True))
+
+
+def _fa_kernel(lo_ref, hi_ref, qoff_ref, kvoff_ref, kvlen_ref, q_ref, k_ref,
+               v_ref, o_ref, *rest, walk, scale, partial, precision):
     if partial:
         m_out, l_out, acc_scr, m_scr, l_scr = rest
     else:
         acc_scr, m_scr, l_scr = rest
-    i, j = pl.program_id(0), pl.program_id(1)
-    nj = pl.num_programs(1)
+    # Block triage (see _Walk.tile): shaving the mask passes on interior
+    # blocks is a direct win because the per-tile cost is the VPU's
+    # dependent chain, not the MXU.
+    q_lo, j, live, full, first, last = walk.tile(
+        lo_ref, hi_ref, qoff_ref, kvoff_ref, kvlen_ref)
 
-    @pl.when(j == 0)
+    @pl.when(first)
     def _init():
         acc_scr[:] = jnp.zeros_like(acc_scr)
         m_scr[:] = jnp.full_like(m_scr, _BIG_NEG)
         l_scr[:] = jnp.zeros_like(l_scr)
-
-    # Block triage (see _block_bounds): shaving the mask passes on
-    # interior blocks is a direct win because the per-tile cost is the
-    # VPU's dependent chain, not the MXU.
-    live, full = _block_bounds(
-        qoff_ref, kvoff_ref, kvlen_ref, i, j,
-        causal=causal, block_q=block_q, block_k=block_k,
-        window=window, q_blocks=q_blocks,
-    )
-    q_lo = _q_row0(qoff_ref, i, block_q, q_blocks)
 
     def _block(masked):
         s = jax.lax.dot_general(
@@ -331,12 +533,7 @@ def _fa_kernel(qoff_ref, kvoff_ref, kvlen_ref, q_ref, k_ref, v_ref, o_ref,
         ) * scale  # (block_q, block_k) f32
 
         if masked:
-            qi = (q_lo
-                  + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0))
-            kj_local = (j * block_k
-                        + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1))
-            valid = _valid(qi, kj_local, kvoff_ref, kvlen_ref, causal,
-                           window)
+            valid = _valid(q_lo, j, kvoff_ref, kvlen_ref, s.shape, walk)
             s = jnp.where(valid, s, _BIG_NEG)
 
         # Finite sentinel algebra: m_new >= any valid score, so
@@ -365,15 +562,11 @@ def _fa_kernel(qoff_ref, kvoff_ref, kvlen_ref, q_ref, k_ref, v_ref, o_ref,
         m_scr[:, :1] = m_new
         l_scr[:, :1] = l_new
 
-    @pl.when(jnp.logical_and(live, full))
-    def _fast():
-        _block(masked=False)
+    _run_tile(live, full, _block)
 
-    @pl.when(jnp.logical_and(live, jnp.logical_not(full)))
-    def _edge():
-        _block(masked=True)
-
-    @pl.when(j == nj - 1)
+    # A row with an empty range runs no tile and still ends here: zeros,
+    # m = -inf and l = 0, as the partials' public contract says.
+    @pl.when(last)
     def _finalize():
         if partial:
             o_ref[:] = acc_scr[:]
@@ -491,6 +684,48 @@ def _unfold_stat(x, like, lq_p):
     return x[:, 0].reshape(like.shape[0], lq_p)[:, :like.shape[1]]
 
 
+def _walk_specs(walk, d_p):
+    """Block specs of one walk, each a function of the block's width:
+    ``held`` for an operand of the outer side, which stays while the
+    inner axis runs, ``walked`` for one of the inner side, fetched as
+    :meth:`_Walk.fetch` says.  The index maps take what is prefetched
+    (:func:`_prefetch`) after the grid indices."""
+    b_outer, b_inner = ((walk.block_k, walk.block_q) if walk.kv_outer
+                        else (walk.block_q, walk.block_k))
+
+    def held(width=d_p):
+        return pl.BlockSpec((b_outer, width), lambda o, t, *s: (o, 0),
+                            memory_space=pltpu.VMEM)
+
+    def walked(width=d_p):
+        return pl.BlockSpec((b_inner, width),
+                            lambda o, t, *s: (walk.fetch(o, t, *s), 0),
+                            memory_space=pltpu.VMEM)
+
+    return held, walked
+
+
+def _prefetch(walk, q_offset, kv_offset, kv_len):
+    """What every kernel prefetches (SMEM, ahead of the grid, so that
+    the index maps read it, traced or not): each outer block's live
+    range, ``lo`` and ``hi`` by :meth:`_Walk.live_range` on the offsets
+    as they are, and the three scalars the bodies' masks read.  The
+    ranges are worked out here, once a call and in a few vector
+    operations, and not in every index map: an index map is traced once
+    an operand, again a ``vmap`` level and again at lowering, and with
+    the bounds' arithmetic inside it that was a tenth of a ten-layer
+    model's warm start-up (PERF.md section 6, PR 33)."""
+    q_off, kv_off, kv_len = (jnp.asarray(x, jnp.int32)
+                             for x in (q_offset, kv_offset, kv_len))
+    n_outer = walk.grid[0]
+    lo, hi = walk.live_range(_Int(jnp.arange(n_outer, dtype=jnp.int32)),
+                             _Int(q_off), _Int(kv_off), _Int(kv_len))
+    rows = lambda x: jnp.broadcast_to(
+        jnp.asarray(_Int.of(x).v, jnp.int32), (n_outer,))
+    return (rows(lo), rows(hi), q_off.reshape(1), kv_off.reshape(1),
+            kv_len.reshape(1))
+
+
 def _fa_2d(q, k, v, q_offset, kv_offset, *, causal, sm_scale, block_q,
            block_k, interpret, partial=False, precision=None, window=None):
     """Core call on (Lq, D) x (Lk, D); pads to tiles.  Returns the
@@ -509,55 +744,39 @@ def _fa_2d(q, k, v, q_offset, kv_offset, *, causal, sm_scale, block_q,
     kp = jnp.pad(k, ((0, lk_p - lk), (0, d_p - d)))
     vp = jnp.pad(v, ((0, lk_p - lk), (0, d_p - d)))
     rows = groups * lq_p
-    grid = (rows // bq, lk_p // bk)
-    vmem_auto = _vmem_auto(bq, bk)
-
-    sspec = pl.BlockSpec((1, 1), lambda i, j: (0, 0), memory_space=pltpu.SMEM)
-    qspec = pl.BlockSpec((bq, d_p), lambda i, j: (i, 0), memory_space=pltpu.VMEM)
-    rowspec = pl.BlockSpec((bq, LANE), lambda i, j: (i, 0), memory_space=pltpu.VMEM)
+    walk = _Walk(False, causal, window, bq, bk, lq_p // bq, lk_p // bk,
+                 groups)
+    held, walked = _walk_specs(walk, d_p)
     if partial:
-        out_specs = (qspec, rowspec, rowspec)
+        out_specs = (held(), held(LANE), held(LANE))
         out_shape = (
             jax.ShapeDtypeStruct((rows, d_p), jnp.float32),
             jax.ShapeDtypeStruct((rows, LANE), jnp.float32),
             jax.ShapeDtypeStruct((rows, LANE), jnp.float32),
         )
     else:
-        out_specs = qspec
+        out_specs = held()
         out_shape = jax.ShapeDtypeStruct((rows, d_p), q.dtype)
-    # the window and the fold are keywords of the kernel only where they
-    # are in force: the causal equal-heads call is the program it was
-    extra = {}
-    if window is not None:
-        extra["window"] = window
-    if groups > 1:
-        extra["q_blocks"] = lq_p // bq
     res = pl.pallas_call(
         functools.partial(
-            _fa_kernel, causal=causal, scale=scale, block_q=bq, block_k=bk,
-            partial=partial, precision=precision, **extra,
+            _fa_kernel, walk=walk, scale=scale, partial=partial,
+            precision=precision,
         ),
-        grid=grid,
-        in_specs=[
-            sspec, sspec, sspec, qspec,
-            pl.BlockSpec((bk, d_p), lambda i, j: (j, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((bk, d_p), lambda i, j: (j, 0), memory_space=pltpu.VMEM),
-        ],
-        out_specs=out_specs,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,
+            grid=walk.grid,
+            in_specs=[held(), walked(), walked()],
+            out_specs=out_specs,
+            scratch_shapes=[
+                pltpu.VMEM((bq, d_p), jnp.float32),
+                pltpu.VMEM((bq, LANE), jnp.float32),
+                pltpu.VMEM((bq, LANE), jnp.float32),
+            ],
+        ),
         out_shape=out_shape,
-        scratch_shapes=[
-            pltpu.VMEM((bq, d_p), jnp.float32),
-            pltpu.VMEM((bq, LANE), jnp.float32),
-            pltpu.VMEM((bq, LANE), jnp.float32),
-        ],
         interpret=_interpret(interpret),
-        compiler_params=_fa_compiler_params(vmem_auto),
-    )(
-        jnp.asarray(q_offset, jnp.int32).reshape(1, 1),
-        jnp.asarray(kv_offset, jnp.int32).reshape(1, 1),
-        jnp.asarray(lk, jnp.int32).reshape(1, 1),
-        qp, kp, vp,
-    )
+        compiler_params=_fa_compiler_params(_vmem_auto(bq, bk)),
+    )(*_prefetch(walk, q_offset, kv_offset, lk), qp, kp, vp)
     if partial:
         acc, m, l = res
         return (_unfold(acc, q, lq_p), _unfold_stat(m, q, lq_p),
@@ -586,6 +805,9 @@ def flash_attention_partial(
     the ring level
     (:mod:`mpit_tpu.parallel.ring_attention`).  ``q`` one rank above
     ``k`` is grouped (:func:`_group_queries`)."""
+    _note_steps(("fwd",), q, k, causal=causal, window=window,
+                block_q=block_q, block_k=block_k, q_offset=q_offset,
+                kv_offset=kv_offset)
     f = lambda q2, k2, v2: _fa_2d(
         q2, k2, v2, q_offset, kv_offset, causal=causal, sm_scale=sm_scale,
         block_q=block_q, block_k=block_k, interpret=interpret, partial=True,
@@ -613,10 +835,10 @@ def flash_attention_partial(
 
 
 def _bwd_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-              qoff_ref, kvoff_ref, kvlen_ref, i, j, *,
-              causal, scale, block_q, block_k, precision, masked,
-              window=None, q_blocks=None):
-    """Shared block math: recompute P and dS for the (i, j) tile.
+              kvoff_ref, kvlen_ref, q_lo, j, *, walk, scale, precision,
+              masked):
+    """Shared block math: recompute P and dS for the tile whose first
+    query row is at ``q_lo`` over kv block ``j``.
     Matmul inputs stay in their native dtype (bf16 runs the MXU at full
     rate); softmax/derivative algebra is f32.  ``masked=False`` is the
     interior-block fast path: every element is valid by construction, so
@@ -627,11 +849,7 @@ def _bwd_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     ) * scale  # (block_q, block_k) f32
 
     if masked:
-        qi = (_q_row0(qoff_ref, i, block_q, q_blocks)
-              + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0))
-        kj_local = (j * block_k
-                    + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1))
-        valid = _valid(qi, kj_local, kvoff_ref, kvlen_ref, causal, window)
+        valid = _valid(q_lo, j, kvoff_ref, kvlen_ref, s.shape, walk)
         # exp(s - lse) is only read where valid; all-masked rows have
         # lse = -inf and no valid element, so the inf branch is never
         # taken.
@@ -648,75 +866,65 @@ def _bwd_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     return p, ds
 
 
-def _fa_bwd_dq_kernel(qoff_ref, kvoff_ref, kvlen_ref, q_ref, do_ref,
-                      lse_ref, delta_ref, k_ref, v_ref, dq_ref, dq_scr, *,
-                      causal, scale, block_q, block_k, precision,
-                      window=None, q_blocks=None):
-    i, j = pl.program_id(0), pl.program_id(1)
-    nj = pl.num_programs(1)
+def _fa_bwd_dq_kernel(lo_ref, hi_ref, qoff_ref, kvoff_ref, kvlen_ref, q_ref,
+                      do_ref, lse_ref, delta_ref, k_ref, v_ref, dq_ref,
+                      dq_scr, *,
+                      walk, scale, precision):
+    q_lo, j, live, full, first, last = walk.tile(
+        lo_ref, hi_ref, qoff_ref, kvoff_ref, kvlen_ref)
 
-    @pl.when(j == 0)
+    @pl.when(first)
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
-
-    live, full = _block_bounds(
-        qoff_ref, kvoff_ref, kvlen_ref, i, j,
-        causal=causal, block_q=block_q, block_k=block_k,
-        window=window, q_blocks=q_blocks,
-    )
 
     def _block(masked):
         _, ds = _bwd_p_ds(
             q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-            qoff_ref, kvoff_ref, kvlen_ref, i, j,
-            causal=causal, scale=scale, block_q=block_q, block_k=block_k,
-            precision=precision, masked=masked,
-            window=window, q_blocks=q_blocks,
+            kvoff_ref, kvlen_ref, q_lo, j,
+            walk=walk, scale=scale, precision=precision, masked=masked,
         )
         dq_scr[:] = dq_scr[:] + scale * jax.lax.dot_general(
             ds.astype(k_ref.dtype), k_ref[:], (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32, precision=precision,
         )
 
-    @pl.when(jnp.logical_and(live, full))
-    def _fast():
-        _block(masked=False)
+    _run_tile(live, full, _block)
 
-    @pl.when(jnp.logical_and(live, jnp.logical_not(full)))
-    def _edge():
-        _block(masked=True)
-
-    @pl.when(j == nj - 1)
+    @pl.when(last)
     def _finalize():
         dq_ref[:] = dq_scr[:].astype(dq_ref.dtype)
 
 
-def _fa_bwd_dkdv_kernel(qoff_ref, kvoff_ref, kvlen_ref, k_ref, v_ref,
-                        q_ref, do_ref, lse_ref, delta_ref,
-                        dk_ref, dv_ref, dk_scr, dv_scr, *,
-                        causal, scale, block_q, block_k, precision,
-                        window=None, q_blocks=None):
-    j, i = pl.program_id(0), pl.program_id(1)  # kv outer, q inner
-    ni = pl.num_programs(1)
+def _fa_bwd_kv_kernel(lo_ref, hi_ref, qoff_ref, kvoff_ref, kvlen_ref, k_ref,
+                      v_ref, q_ref, do_ref, lse_ref, delta_ref,
+                      dk_ref, dv_ref, *rest, walk, scale, precision,
+                      fused):
+    """The kv-outer backward sweep: dK/dV accumulated in VMEM scratch
+    over the q blocks of the kv block's live range (over every head of a
+    group in turn).  The two-kernel schedule's second kernel as it is;
+    with ``fused`` the single sweep, which ALSO writes the dQ
+    contribution of each live tile, once, into its slot of a
+    (n_kv_blocks, Lq, D) partial that the caller sums: the separate dQ
+    kernel's s/P/dS recomputation folds away, 5 matmuls per tile pair
+    instead of 7.  A dead pair's slot is never visited and holds
+    garbage: the caller sums the visited ones only."""
+    if fused:
+        dqp_ref, dk_scr, dv_scr = rest
+    else:
+        dk_scr, dv_scr = rest
+    q_lo, j, live, full, first, last = walk.tile(
+        lo_ref, hi_ref, qoff_ref, kvoff_ref, kvlen_ref)
 
-    @pl.when(i == 0)
+    @pl.when(first)
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    live, full = _block_bounds(
-        qoff_ref, kvoff_ref, kvlen_ref, i, j,
-        causal=causal, block_q=block_q, block_k=block_k,
-        window=window, q_blocks=q_blocks,
-    )
-
     def _block(masked):
         p, ds = _bwd_p_ds(
             q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-            qoff_ref, kvoff_ref, kvlen_ref, i, j,
-            causal=causal, scale=scale, block_q=block_q, block_k=block_k,
-            precision=precision, masked=masked,
-            window=window, q_blocks=q_blocks,
+            kvoff_ref, kvlen_ref, q_lo, j,
+            walk=walk, scale=scale, precision=precision, masked=masked,
         )
         dv_scr[:] = dv_scr[:] + jax.lax.dot_general(
             p.astype(do_ref.dtype), do_ref[:], (((0,), (0,)), ((), ())),
@@ -726,81 +934,15 @@ def _fa_bwd_dkdv_kernel(qoff_ref, kvoff_ref, kvlen_ref, k_ref, v_ref,
             ds.astype(q_ref.dtype), q_ref[:], (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32, precision=precision,
         )
+        if fused:
+            dqp_ref[0] = scale * jax.lax.dot_general(
+                ds.astype(k_ref.dtype), k_ref[:], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32, precision=precision,
+            )
 
-    @pl.when(jnp.logical_and(live, full))
-    def _fast():
-        _block(masked=False)
+    _run_tile(live, full, _block)
 
-    @pl.when(jnp.logical_and(live, jnp.logical_not(full)))
-    def _edge():
-        _block(masked=True)
-
-    @pl.when(i == ni - 1)
-    def _finalize():
-        dk_ref[:] = dk_scr[:].astype(dk_ref.dtype)
-        dv_ref[:] = dv_scr[:].astype(dv_ref.dtype)
-
-
-def _fa_bwd_fused_kernel(qoff_ref, kvoff_ref, kvlen_ref, k_ref, v_ref,
-                         q_ref, do_ref, lse_ref, delta_ref,
-                         dk_ref, dv_ref, dqp_ref, dk_scr, dv_scr, *,
-                         causal, scale, block_q, block_k, precision,
-                         window=None, q_blocks=None):
-    """Single-sweep backward: grid (kv outer, q inner) producing dK/dV
-    (accumulated in VMEM scratch) AND the dQ contribution of this kv
-    block (written once per program into a (n_kv_blocks, Lq, D) partial
-    that the caller sums).  Folds the separate dq kernel's s/P/dS
-    recomputation away: 5 matmuls per tile pair instead of 7."""
-    j, i = pl.program_id(0), pl.program_id(1)  # kv outer, q inner
-    ni = pl.num_programs(1)
-
-    @pl.when(i == 0)
-    def _init():
-        dk_scr[:] = jnp.zeros_like(dk_scr)
-        dv_scr[:] = jnp.zeros_like(dv_scr)
-
-    live, full = _block_bounds(
-        qoff_ref, kvoff_ref, kvlen_ref, i, j,
-        causal=causal, block_q=block_q, block_k=block_k,
-        window=window, q_blocks=q_blocks,
-    )
-
-    def _block(masked):
-        p, ds = _bwd_p_ds(
-            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-            qoff_ref, kvoff_ref, kvlen_ref, i, j,
-            causal=causal, scale=scale, block_q=block_q, block_k=block_k,
-            precision=precision, masked=masked,
-            window=window, q_blocks=q_blocks,
-        )
-        dv_scr[:] = dv_scr[:] + jax.lax.dot_general(
-            p.astype(do_ref.dtype), do_ref[:], (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32, precision=precision,
-        )
-        dk_scr[:] = dk_scr[:] + scale * jax.lax.dot_general(
-            ds.astype(q_ref.dtype), q_ref[:], (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32, precision=precision,
-        )
-        dqp_ref[0] = scale * jax.lax.dot_general(
-            ds.astype(k_ref.dtype), k_ref[:], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32, precision=precision,
-        )
-
-    @pl.when(jnp.logical_and(live, full))
-    def _fast():
-        _block(masked=False)
-
-    @pl.when(jnp.logical_and(live, jnp.logical_not(full)))
-    def _edge():
-        _block(masked=True)
-
-    # Dead blocks still own their dq-partial slot: zero it (unwritten
-    # output blocks hold garbage).
-    @pl.when(jnp.logical_not(live))
-    def _dead():
-        dqp_ref[0] = jnp.zeros_like(dqp_ref[0])
-
-    @pl.when(i == ni - 1)
+    @pl.when(last)
     def _finalize():
         dk_ref[:] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[:] = dv_scr[:].astype(dv_ref.dtype)
@@ -813,6 +955,24 @@ def _rows_to_lanes(x, length_p):
     pad = [(0, 0)] * (x.ndim - 1) + [(0, length_p - x.shape[-1])]
     xp = jnp.pad(x.astype(jnp.float32), pad).reshape(-1)  # folded heads
     return jnp.broadcast_to(xp[:, None], (xp.shape[0], LANE))
+
+
+def _sum_visited(dq_part, walk, q_offset, kv_offset, kv_len):
+    """The fused sweep's dQ: the sum over kv blocks of the partial
+    slots a live tile wrote.  Slot ``(j, i)`` was visited iff ``j`` lies
+    in q block ``i``'s live range (the q-outer reading of the same
+    rule); the others were never written and may hold anything, so they
+    are selected away, not multiplied by zero."""
+    across = dataclasses.replace(walk, kv_outer=False, groups=1)
+    lo, hi = across.live_range(_Int(jnp.arange(walk.q_blocks)),
+                               _Int(q_offset), _Int(kv_offset), kv_len)
+    j = jnp.arange(walk.kv_blocks)[:, None]
+    visited = (j >= _Int.of(lo).v) & (j <= hi.v)  # (kv_blocks, q_blocks)
+    nj, rows, d_p = dq_part.shape
+    part = dq_part.reshape(nj, walk.groups, walk.q_blocks, walk.block_q, d_p)
+    return jnp.sum(
+        jnp.where(visited[:, None, :, None, None], part, 0.0), axis=0
+    ).reshape(rows, d_p)
 
 
 def _fa_2d_bwd(q, k, v, do, lse, delta, q_offset, kv_offset, *, causal,
@@ -846,101 +1006,71 @@ def _fa_2d_bwd(q, k, v, do, lse, delta, q_offset, kv_offset, *, causal,
     lse_r = _rows_to_lanes(lse, lq_p)
     delta_r = _rows_to_lanes(delta, lq_p)
     rows = groups * lq_p
-    vmem_auto = _vmem_auto(bq, bk)
+    kw = dict(scale=scale, precision=precision)
+    call = dict(interpret=_interpret(interpret),
+                compiler_params=_fa_compiler_params(_vmem_auto(bq, bk)))
 
-    sspec = pl.BlockSpec((1, 1), lambda i, j: (0, 0), memory_space=pltpu.SMEM)
-    scalars = (
-        jnp.asarray(q_offset, jnp.int32).reshape(1, 1),
-        jnp.asarray(kv_offset, jnp.int32).reshape(1, 1),
-        jnp.asarray(lk, jnp.int32).reshape(1, 1),
-    )
-    kw = dict(causal=causal, scale=scale, block_q=bq, block_k=bk,
-              precision=precision)
-    if window is not None:  # keywords only where in force, as forward
-        kw["window"] = window
-    if groups > 1:
-        kw["q_blocks"] = lq_p // bq
-    interp = _interpret(interpret)
-
+    # dK/dV, and under the fused schedule dQ's partials: kv blocks
+    # outer, each walking the q blocks of its live range.  Fused is 5
+    # matmuls per tile pair vs the two-kernel schedule's 7; its partial
+    # buffer costs n_kv_blocks * Lq * D * 4 bytes of transient HBM per
+    # (B, H) program (128 MB at L=16k, 512 MB at 32k with 1024-wide kv
+    # blocks — x batch*heads live at once under vmap) and one XLA
+    # reduction.  Fused-vs-two-kernel selection (incl. the vmapped-batch
+    # HBM budget) lives in _use_fused_bwd; this function only executes
+    # the chosen schedule.
+    walk = _Walk(True, causal, window, bq, bk, lq_p // bq, lk_p // bk,
+                 groups)
+    held, walked = _walk_specs(walk, d_p)
+    out_specs = [held(), held()]
+    out_shape = [jax.ShapeDtypeStruct((lk_p, d_p), k.dtype),
+                 jax.ShapeDtypeStruct((lk_p, d_p), v.dtype)]
     if fused:
-        # Fused single sweep: dK/dV accumulate in VMEM, dQ leaves as
-        # per-kv-block partials — (n_kv_blocks, Lq, D) f32, each block
-        # written exactly once — summed here.  5 matmuls per tile pair
-        # vs the two-kernel schedule's 7; the partial buffer costs
-        # n_kv_blocks * Lq * D * 4 bytes of transient HBM per (B, H)
-        # program (128 MB at L=16k, 512 MB at 32k with 1024-wide kv
-        # blocks — x batch*heads live at once under vmap) and one XLA
-        # reduction.
-        # Fused-vs-two-kernel selection (incl. the vmapped-batch HBM
-        # budget) lives in _use_fused_bwd; this function only executes
-        # the chosen schedule.
-        nj = lk_p // bk
-        kvrow2 = pl.BlockSpec((bk, d_p), lambda j, i: (j, 0),
-                              memory_space=pltpu.VMEM)
-        qrow2 = pl.BlockSpec((bq, d_p), lambda j, i: (i, 0),
-                             memory_space=pltpu.VMEM)
-        qstat2 = pl.BlockSpec((bq, LANE), lambda j, i: (i, 0),
-                              memory_space=pltpu.VMEM)
-        dqpspec = pl.BlockSpec((1, bq, d_p), lambda j, i: (j, i, 0),
-                               memory_space=pltpu.VMEM)
-        dk, dv, dq_part = pl.pallas_call(
-            functools.partial(_fa_bwd_fused_kernel, **kw),
-            grid=(nj, rows // bq),
-            in_specs=[sspec, sspec, sspec, kvrow2, kvrow2, qrow2, qrow2,
-                      qstat2, qstat2],
-            out_specs=(kvrow2, kvrow2, dqpspec),
-            out_shape=(
-                jax.ShapeDtypeStruct((lk_p, d_p), k.dtype),
-                jax.ShapeDtypeStruct((lk_p, d_p), v.dtype),
-                jax.ShapeDtypeStruct((nj, rows, d_p), jnp.float32),
-            ),
+        out_specs.append(pl.BlockSpec(
+            (1, bq, d_p), lambda j, t, *s: (j, walk.fetch(j, t, *s), 0),
+            memory_space=pltpu.VMEM))
+        out_shape.append(
+            jax.ShapeDtypeStruct((walk.kv_blocks, rows, d_p), jnp.float32))
+    dk, dv, *dq_part = pl.pallas_call(
+        functools.partial(_fa_bwd_kv_kernel, walk=walk, fused=fused, **kw),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,
+            grid=walk.grid,
+            in_specs=[held(), held(), walked(), walked(), walked(LANE),
+                      walked(LANE)],
+            out_specs=tuple(out_specs),
             scratch_shapes=[
                 pltpu.VMEM((bk, d_p), jnp.float32),
                 pltpu.VMEM((bk, d_p), jnp.float32),
             ],
-            interpret=interp,
-            compiler_params=_fa_compiler_params(vmem_auto),
-        )(*scalars, kp, vp, qp, dop, lse_r, delta_r)
-        dq = jnp.sum(dq_part, axis=0).astype(q.dtype)
-        return _unfold(dq, q, lq_p), dk[:lk, :d], dv[:lk, :d]
-
-    # Two-kernel fallback (fused=False).
-    # Kernel 1: dQ — q rows outer, kv blocks inner.
-    qrow = pl.BlockSpec((bq, d_p), lambda i, j: (i, 0), memory_space=pltpu.VMEM)
-    qstat = pl.BlockSpec((bq, LANE), lambda i, j: (i, 0), memory_space=pltpu.VMEM)
-    kvrow = pl.BlockSpec((bk, d_p), lambda i, j: (j, 0), memory_space=pltpu.VMEM)
-    dq = pl.pallas_call(
-        functools.partial(_fa_bwd_dq_kernel, **kw),
-        grid=(rows // bq, lk_p // bk),
-        in_specs=[sspec, sspec, sspec, qrow, qrow, qstat, qstat, kvrow, kvrow],
-        out_specs=qrow,
-        out_shape=jax.ShapeDtypeStruct((rows, d_p), q.dtype),
-        scratch_shapes=[pltpu.VMEM((bq, d_p), jnp.float32)],
-        interpret=interp,
-        compiler_params=_fa_compiler_params(vmem_auto),
-    )(*scalars, qp, dop, lse_r, delta_r, kp, vp)
-
-    # Kernel 2: dK/dV — kv blocks outer, q rows inner.
-    kvrow2 = pl.BlockSpec((bk, d_p), lambda j, i: (j, 0), memory_space=pltpu.VMEM)
-    qrow2 = pl.BlockSpec((bq, d_p), lambda j, i: (i, 0), memory_space=pltpu.VMEM)
-    qstat2 = pl.BlockSpec((bq, LANE), lambda j, i: (i, 0), memory_space=pltpu.VMEM)
-    dk, dv = pl.pallas_call(
-        functools.partial(_fa_bwd_dkdv_kernel, **kw),
-        grid=(lk_p // bk, rows // bq),
-        in_specs=[sspec, sspec, sspec, kvrow2, kvrow2, qrow2, qrow2,
-                  qstat2, qstat2],
-        out_specs=(kvrow2, kvrow2),
-        out_shape=(
-            jax.ShapeDtypeStruct((lk_p, d_p), k.dtype),
-            jax.ShapeDtypeStruct((lk_p, d_p), v.dtype),
         ),
-        scratch_shapes=[
-            pltpu.VMEM((bk, d_p), jnp.float32),
-            pltpu.VMEM((bk, d_p), jnp.float32),
-        ],
-        interpret=interp,
-        compiler_params=_fa_compiler_params(vmem_auto),
-    )(*scalars, kp, vp, qp, dop, lse_r, delta_r)
+        out_shape=tuple(out_shape),
+        **call,
+    )(*_prefetch(walk, q_offset, kv_offset, lk), kp, vp, qp, dop, lse_r,
+      delta_r)
+
+    if fused:
+        dq = _sum_visited(dq_part[0], walk, q_offset, kv_offset,
+                          lk).astype(q.dtype)
+    else:
+        # The two-kernel schedule's dQ: q rows outer, each walking the
+        # kv blocks of its live range.
+        walk = dataclasses.replace(walk, kv_outer=False)
+        held, walked = _walk_specs(walk, d_p)
+        dq = pl.pallas_call(
+            functools.partial(_fa_bwd_dq_kernel, walk=walk, **kw),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=5,
+                grid=walk.grid,
+                in_specs=[held(), held(), held(LANE), held(LANE), walked(),
+                          walked()],
+                out_specs=held(),
+                scratch_shapes=[pltpu.VMEM((bq, d_p), jnp.float32)],
+            ),
+            out_shape=jax.ShapeDtypeStruct((rows, d_p), q.dtype),
+            **call,
+        )(*_prefetch(walk, q_offset, kv_offset, lk), qp, dop, lse_r,
+          delta_r, kp, vp)
 
     return _unfold(dq, q, lq_p), dk[:lk, :d], dv[:lk, :d]
 
@@ -974,12 +1104,16 @@ def _use_fused_bwd(q_shape, k_shape, d, dtype, sm_scale, block_q, block_k,
     undercounts the transient by the outer batch factor.
 
     With a ``window``, ``auto`` is the two-kernel schedule: the fused
-    sweep owns a dQ-partial slot for every (kv block, q block) pair and
-    must zero the dead ones, and under a window most pairs are dead
-    (at 8k with window 1024 and 512-blocks 47 of 256 are live), so the
-    transient would be mostly zeros written and summed; the two-kernel
-    schedule skips a dead pair outright.  Fused under a window is
-    UNMEASURED (``MPIT_FA_FUSED_BWD=1`` still forces it)."""
+    sweep's partial has a slot for every (kv block, q block) pair, and
+    under a window most pairs are dead (at 8k with window 1024 and
+    512-blocks 45 of 256 are live).  A dead slot is no longer visited or
+    zeroed (:class:`_Walk`), but the caller's sum still reads the whole
+    transient to select the live slots (:func:`_sum_visited`), five
+    sixths of it unwritten; the two-kernel schedule reads nothing it
+    does not need.  Fused under a window, with the partials laid out by
+    a pair's place in its range so that the transient shrinks with the
+    window, is UNMEASURED (``MPIT_FA_FUSED_BWD=1`` still forces the
+    fused sweep, on the slot layout)."""
     mode = os.environ.get("MPIT_FA_FUSED_BWD", "auto") or "auto"
     if mode == "0":
         return False
@@ -1009,6 +1143,61 @@ def _use_fused_bwd(q_shape, k_shape, d, dtype, sm_scale, block_q, block_k,
     return transient_mb <= budget
 
 
+_KERNEL_WALKS = {  # kernel -> (kv_outer, the _tile_dims flag it resolves with)
+    "fwd": (False, "fwd_long_bq"),
+    "dq": (False, None),
+    "dkdv": (True, None),
+    "fused": (True, "bwd_long_bk"),
+}
+
+
+def flash_step_counts(kernel, q_shape, k_shape, dtype, *, causal=False,
+                      window=None, block_q=None, block_k=None, q_offset=0,
+                      kv_offset=0):
+    """Grid steps of one kernel call over ``q_shape`` x ``k_shape`` (as
+    :func:`flash_attention` takes them, or ``q`` already grouped one rank
+    above ``k``): ``{"visited", "live", "rect"}``, summed over the
+    leading (batch, KV head) axes.  ``kernel`` is ``fwd``, ``dq``,
+    ``dkdv`` or ``fused``.  ``visited`` is what the grid's shape says,
+    ``live`` the tiles that run a product, ``rect`` the whole rectangle
+    the grid was before it walked live ranges; live over visited is the
+    walk's hit share.  The program's own numbers: the same
+    :class:`_Walk` the kernels lower with, on concrete offsets (an
+    offset that is traced counts as 0)."""
+    kv_outer, long_flag = _KERNEL_WALKS[kernel]
+    q_shape, k_shape = tuple(q_shape), tuple(k_shape)
+    if len(q_shape) == len(k_shape) and len(q_shape) >= 3:
+        groups = q_shape[-3] // k_shape[-3]
+    else:
+        groups = q_shape[-3] if len(q_shape) > len(k_shape) else 1
+    lq, d = q_shape[-2:]
+    lk = k_shape[-2]
+    _, bq, bk, lq_p, lk_p, _ = _tile_dims(
+        lq, lk, d, block_q, block_k, None, dtype,
+        **({long_flag: True} if long_flag else {}))
+    walk = _Walk(kv_outer, causal, window, bq, bk, lq_p // bq, lk_p // bk,
+                 groups)
+    concrete = lambda x: 0 if isinstance(x, jax.core.Tracer) else int(x)
+    one = walk.steps(concrete(q_offset), concrete(kv_offset), lk)
+    calls = math.prod(k_shape[:-2])
+    return {name: n * calls for name, n in one.items()}
+
+
+def _note_steps(kernels, q, k, **kw):
+    """While obs records: the step counts of the kernels this lowering
+    holds, on ``mpit_fa_steps_{visited,live,rect}_total`` by ``kernel``.
+    Runs where a call is traced, once a lowering, never in the step;
+    with obs off the registry is the null one and nothing is counted."""
+    registry = get_registry()
+    if not registry.enabled:
+        return
+    for kernel in kernels:
+        counts = flash_step_counts(kernel, q.shape, k.shape, q.dtype, **kw)
+        for name, n in counts.items():
+            registry.counter(f"mpit_fa_steps_{name}_total",
+                             kernel=kernel).inc(n)
+
+
 def flash_attention_bwd_pair(q, k, v, do, lse, *, causal=False, sm_scale=None,
                              q_offset=0, kv_offset=0, delta=None, o=None,
                              block_q=None, block_k=None, interpret=None,
@@ -1027,6 +1216,9 @@ def flash_attention_bwd_pair(q, k, v, do, lse, *, causal=False, sm_scale=None,
         delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), -1)
     fused = _use_fused_bwd(q.shape, k.shape, q.shape[-1], q.dtype,
                            sm_scale, block_q, block_k, window)
+    _note_steps(("fused",) if fused else ("dq", "dkdv"), q, k,
+                causal=causal, window=window, block_q=block_q,
+                block_k=block_k, q_offset=q_offset, kv_offset=kv_offset)
     f = lambda q2, k2, v2, do2, lse2, delta2: _fa_2d_bwd(
         q2, k2, v2, do2, lse2, delta2, q_offset, kv_offset, causal=causal,
         sm_scale=sm_scale, block_q=block_q, block_k=block_k,
@@ -1047,6 +1239,8 @@ def _make_flash(causal, sm_scale, block_q, block_k, interpret, precision,
 
     @jax.custom_vjp
     def fa(q, k, v, q_offset, kv_offset):
+        _note_steps(("fwd",), q, k, causal=causal, window=window,
+                    block_q=block_q, block_k=block_k)
         f = lambda q2, k2, v2: _fa_2d(
             q2, k2, v2, q_offset, kv_offset, causal=causal,
             sm_scale=sm_scale, block_q=block_q, block_k=block_k,
@@ -1106,10 +1300,12 @@ def flash_attention(
     ``dv`` are summed over the group inside the backward kernel.
 
     ``window`` (causal only): query ``i`` sees key ``j`` iff ``0 <= i -
-    j < window``.  A block wholly outside the window is skipped like a
-    block above the diagonal, forward and backward
-    (:func:`_block_bounds`); the backward is then the two-kernel
-    schedule (:func:`_use_fused_bwd`).
+    j < window``.  A block wholly outside the window, like one above
+    the diagonal or beyond the keys' length, is neither fetched nor
+    visited, forward and backward: the grids walk each row's live range
+    (:class:`_Walk`), whose length under a window is bounded by the
+    window, not the sequence.  The backward under a window is the
+    two-kernel schedule (:func:`_use_fused_bwd`).
 
     Default blocks are 1024x1024, growing to 2048x1024 at L >= 16384
     (defaults from a July 2026 sweep on a v5e the ledger has not

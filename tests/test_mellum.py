@@ -302,6 +302,92 @@ def test_flash_kernel_equals_the_reference(what, schedule, monkeypatch):
         np.testing.assert_allclose(a, b, atol=2e-4, rtol=2e-4)
 
 
+# -- the live-block walk's own numbers (PR 33) ----------------------------------
+
+# q shape, k shape (float32, so 512 x 512 blocks), window -> visited, live, rect
+STEP_SHAPES = {
+    # a sliding layer: 16 x 16 block pairs a head, 45 of them inside the
+    # window of 1024 (1 + 2 + 14 x 3), 32 heads; the inner axis is the
+    # window's static bound, 4, times the 128 x 4 outer blocks
+    "mellum, window 1024": ((1, 32, 8192, 128), (1, 4, 8192, 128), 1024,
+                            (2048, 1440, 8192)),
+    # its full layer: 136 of 256 pairs on or under the diagonal
+    "mellum, full": ((1, 32, 8192, 128), (1, 4, 8192, 128), None,
+                     (8192, 4352, 8192)),
+    "lfm2": ((1, 32, 8192, 64), (1, 8, 8192, 64), None, (8192, 4352, 8192)),
+    # 72 (batch, head) programs of 4 x 4 blocks, 10 live
+    "cerebras-gpt-111m": ((6, 12, 2048, 64), (6, 12, 2048, 64), None,
+                          (1152, 720, 1152)),
+}
+
+
+@pytest.mark.parametrize("kernel", ["fwd", "dq", "dkdv", "fused"])
+@pytest.mark.parametrize("shape", sorted(STEP_SHAPES))
+def test_the_walks_step_counts_at_published_shapes(shape, kernel):
+    """The plain function's numbers are the program's own (the walk the
+    kernels lower with): under a window the grid visits the window's
+    static bound a row, not the row; under plain causal masking it still
+    visits the rectangle, and the dead steps are the clamped ones."""
+    from mpit_tpu.ops.flash_attention import flash_step_counts
+
+    q_shape, k_shape, window, (visited, live, rect) = STEP_SHAPES[shape]
+    counts = flash_step_counts(kernel, q_shape, k_shape, jnp.float32,
+                               causal=True, window=window)
+    assert counts == {"visited": visited, "live": live, "rect": rect}
+    if window is not None:  # the static bound times the outer blocks
+        bound = -(-(window + 512 - 2) // 512) + 1
+        heads_kv, groups = k_shape[1], q_shape[1] // k_shape[1]
+        outer = 16 if kernel in ("dkdv", "fused") else 16 * groups
+        per_row = bound * (groups if kernel in ("dkdv", "fused") else 1)
+        assert visited == heads_kv * outer * per_row
+
+
+def _fa_counters():
+    return {name: value for name, value
+            in obs.get_registry().snapshot().items()
+            if name.startswith("mpit_fa_steps_")}
+
+
+def test_the_step_counters_move_at_lowering_only(obs_on):
+    """``mpit_fa_steps_*_total`` by ``kernel``: counted where a call is
+    traced, once a lowering; running the compiled step again counts
+    nothing, and a window's backward is the two kernels."""
+    q = jnp.ones((1, 8, 256, 32))
+    k = jnp.ones((1, 2, 256, 32))
+
+    @jax.jit
+    def step(q, k, v):
+        return jax.grad(lambda q: jnp.sum(flash_attention(
+            q, k, v, causal=True, window=100, block_q=64, block_k=128,
+            interpret=True)))(q)
+
+    assert _fa_counters() == {}
+    step(q, k, k)
+    first = _fa_counters()
+    # 2 KV heads x (4 heads x 4 q blocks) x 2 kv blocks a rectangle; a
+    # window of 100 over 64 x 128 blocks keeps both kv blocks in a row's
+    # bound and 2 + 4 of a head's 8 pairs live
+    for kernel in ("fwd", "dq", "dkdv"):
+        assert first[f'mpit_fa_steps_rect_total{{kernel="{kernel}"}}'] == 64
+        assert first[f'mpit_fa_steps_visited_total{{kernel="{kernel}"}}'] == 64
+        assert first[f'mpit_fa_steps_live_total{{kernel="{kernel}"}}'] == 48
+    assert not any("fused" in name for name in first)
+    step(q + 1.0, k, k)
+    assert _fa_counters() == first
+
+
+def test_with_obs_off_a_lowering_touches_no_registry(monkeypatch):
+    def no_counter(*_a, **_k):
+        raise AssertionError("a counter was asked for with obs off")
+
+    assert not obs.obs_enabled()
+    monkeypatch.setattr(obs.NullRegistry, "counter", no_counter)
+    q = jnp.ones((1, 2, 128, 32))
+    jax.jit(jax.grad(lambda q: jnp.sum(flash_attention(
+        q, q, q, causal=True, block_q=64, block_k=128,
+        interpret=True)))).lower(q)
+
+
 def test_a_window_needs_causal():
     q = jnp.zeros((1, 2, 16, 8))
     with pytest.raises(ValueError, match="causal"):

@@ -410,6 +410,220 @@ def test_flash_bwd_ragged_offset_pair(rng, fa_backward_path):
         )
 
 
+# -- the live-block walk (PR 33) ----------------------------------------------
+#
+# One function, ``_Walk.live_range``, says which inner blocks a grid row
+# visits; the index maps and the kernels' bodies both read it.  The tests
+# hold it to the mask itself, and the kernels to the reference on the
+# same grid of cases.
+
+# (causal, window, block_q, block_k, (lq, lk))
+WALK_MASKS = {
+    "plain": (False, None, 16, 32, (128, 128)),
+    "causal": (True, None, 16, 32, (128, 128)),
+    "window inside a block": (True, 7, 16, 32, (128, 128)),
+    "window of two blocks": (True, 64, 16, 32, (128, 128)),
+    "window beyond the sequence": (True, 10_000, 16, 32, (128, 128)),
+    "causal, ragged": (True, None, 16, 32, (100, 77)),
+    "window, ragged": (True, 40, 16, 32, (77, 100)),
+    "plain, ragged": (False, None, 16, 32, (100, 77)),
+    "window 1024 on 512-blocks": (True, 1024, 512, 512, (2048, 2048)),
+}
+# (q_offset, kv_offset): as a ring step has them
+WALK_OFFSETS = {
+    "no offsets": (0, 0),
+    "q ahead": (96, 0),
+    "q ahead, off the blocks": (75, 11),
+    "equal": (48, 48),
+    "q behind": (0, 96),
+    "q wholly behind": (0, 4096),   # a ring step above the diagonal
+}
+
+
+def _walk_of(mask, groups, kv_outer):
+    from mpit_tpu.ops.flash_attention import _Walk
+    from mpit_tpu.ops.tiles import round_up
+
+    causal, window, bq, bk, (lq, lk) = WALK_MASKS[mask]
+    lq_p, lk_p = round_up(lq, bq), round_up(lk, bk)
+    return _Walk(kv_outer, causal, window, bq, bk, lq_p // bq, lk_p // bk,
+                 groups), lq_p, lk_p, lk
+
+
+@pytest.mark.parametrize("kv_outer", [False, True], ids=["q-outer", "kv-outer"])
+@pytest.mark.parametrize("groups", [1, 4, 8])
+@pytest.mark.parametrize("offsets", sorted(WALK_OFFSETS))
+@pytest.mark.parametrize("mask", sorted(WALK_MASKS))
+def test_live_range_is_the_blocks_in_which_the_mask_has_an_entry(
+        mask, offsets, groups, kv_outer):
+    """Brute force: for every outer block, ``[lo, hi]`` is exactly the
+    inner blocks whose tile of ``_mask`` has any true entry, it is never
+    longer than the walk's extent, and the index map's block is the
+    walk's while live and a block already held after."""
+    from mpit_tpu.ops.flash_attention import _mask
+
+    walk, lq_p, lk_p, lk = _walk_of(mask, groups, kv_outer)
+    q_off, kv_off = WALK_OFFSETS[offsets]
+    valid = np.broadcast_to(
+        _mask(lq_p, lk_p, q_off, kv_off, lk, walk.causal, walk.window),
+        (lq_p, lk_p))
+    tiles = valid.reshape(walk.q_blocks, walk.block_q,
+                          walk.kv_blocks, walk.block_k).any(axis=(1, 3))
+    n_outer = walk.kv_blocks if kv_outer else groups * walk.q_blocks
+    outers = np.arange(n_outer)
+    _, lo, hi = np.broadcast_arrays(outers, *walk.live_range(
+        outers, q_off, kv_off, lk, xp=np))
+    refs = [x.astype(np.int32) for x in (lo, hi)]   # the prefetched ranges
+    for outer in range(n_outer):
+        row = tiles[:, outer] if kv_outer else tiles[outer % walk.q_blocks]
+        live = np.flatnonzero(row)
+        if live.size == 0:
+            assert hi[outer] < lo[outer], (outer, lo[outer], hi[outer])
+            continue
+        assert (lo[outer], hi[outer]) == (live[0], live[-1]), outer
+        assert live.size == hi[outer] - lo[outer] + 1 <= walk.extent
+        for t in range(walk.grid[1]):
+            head, u = divmod(t, walk.extent) if kv_outer else (0, t)
+            want = head * walk.q_blocks + min(lo[outer] + u, hi[outer])
+            assert int(walk.fetch(outer, t, *refs)) == want, (outer, t)
+    steps = walk.steps(q_off, kv_off, lk)
+    assert steps["live"] == groups * int(tiles.sum())
+    assert steps["visited"] == walk.grid[0] * walk.grid[1] <= steps["rect"]
+
+
+@pytest.mark.parametrize("kv_outer", [False, True], ids=["q-outer", "kv-outer"])
+def test_the_walks_arithmetic_traces_as_plain_primitives(kv_outer):
+    """An index map is traced once an operand, again a ``vmap`` level
+    and again at lowering; with ``jnp``'s operators every operation of
+    it was a nested jitted ufunc, which cost a ten-layer model seconds
+    of every start-up (PERF.md section 6, PR 33).  So the index map
+    only reads its row's prefetched range, and that and the ranges'
+    own arithmetic bind plain primitives."""
+    walk, _, _, lk = _walk_of("window, ragged", 4, kv_outer)
+
+    class Ref:  # stands in for an SMEM ref: reading it is one load
+        def __init__(self, value):
+            self.value = value
+
+        def __getitem__(self, _index):
+            return self.value
+
+    def index_map(outer, t, lo, hi):
+        return walk.fetch(outer, t, Ref(lo), Ref(hi))
+
+    def ranges(outer, q_off, kv_off):
+        from mpit_tpu.ops.flash_attention import _Int
+
+        lo, hi = walk.live_range(_Int(outer), _Int(q_off), _Int(kv_off), lk)
+        return _Int.of(lo).v, hi.v
+
+    one = jnp.int32(1)
+    text = (str(jax.make_jaxpr(index_map)(one, one, one, one))
+            + str(jax.make_jaxpr(ranges)(jnp.arange(4), one, one)))
+    assert "pjit" not in text and "select_n" in text
+
+
+# (causal, window) x the offsets above, on 64 x 128 blocks over ragged
+# lengths; "q wholly behind" leaves every row's range empty
+KERNEL_MASKS = {
+    "causal": None,
+    "window inside a block": 40,
+    "window on a block edge": 128,
+    "window beyond the sequence": 1000,
+}
+
+
+@pytest.mark.parametrize("fa_backward_path", ["1", "0"], indirect=True,
+                         ids=["fused-bwd", "two-kernel-bwd"])
+@pytest.mark.parametrize("groups", [1, 4])
+@pytest.mark.parametrize("offsets", sorted(WALK_OFFSETS))
+@pytest.mark.parametrize("mask", sorted(KERNEL_MASKS))
+def test_walked_kernels_equal_the_reference(mask, offsets, groups,
+                                            fa_backward_path):
+    """Forward, ``lse`` and all three gradients of the interpreted
+    kernels against ``attention_reference`` on the walk's grid of cases,
+    under both backward schedules.  The interpreter fills what no step
+    writes with NaN, so a summed unvisited dQ slot or a row that never
+    reached its finalize would show."""
+    from mpit_tpu.ops.flash_attention import (
+        _lse_of, _mask, flash_attention_partial)
+
+    window = KERNEL_MASKS[mask]
+    q_off, kv_off = WALK_OFFSETS[offsets]
+    lq, lk, hkv, d = 150, 200, 2, 16
+    keys = jax.random.split(jax.random.PRNGKey(groups * 100 + lq), 4)
+    q = jax.random.normal(keys[0], (hkv * groups, lq, d))
+    k = jax.random.normal(keys[1], (hkv, lk, d))
+    v = jax.random.normal(keys[2], (hkv, lk, d))
+    g = jax.random.normal(keys[3], q.shape)
+    kw = dict(causal=True, window=window, q_offset=q_off, kv_offset=kv_off)
+
+    def kernel(q, k, v):
+        return jnp.sum(g * flash_attention(
+            q, k, v, block_q=64, block_k=128, interpret=True,
+            precision="highest", **kw))
+
+    def plain(q, k, v):
+        return jnp.sum(g * attention_reference(q, k, v, **kw))
+
+    with jax.default_matmul_precision("highest"):
+        got = jax.value_and_grad(kernel, (0, 1, 2))(q, k, v)
+        want = jax.value_and_grad(plain, (0, 1, 2))(q, k, v)
+        acc, m, l = flash_attention_partial(
+            q.reshape(hkv, groups, lq, d), k, v, block_q=64, block_k=128,
+            interpret=True, precision="highest", **kw)
+        s = jnp.einsum("hgqd,hkd->hgqk", q.reshape(hkv, groups, lq, d),
+                       k) / np.sqrt(d)
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(a, b, atol=2e-4, rtol=2e-4)
+    valid = _mask(lq, lk, q_off, kv_off, lk, True, window)
+    lse = jax.nn.logsumexp(jnp.where(valid, s, -jnp.inf), axis=-1)
+    np.testing.assert_allclose(_lse_of(m, l), lse, atol=2e-4, rtol=2e-4)
+    dead = ~np.asarray(valid).any(axis=-1)   # rows that see no key
+    assert dead.all() == (offsets == "q wholly behind")
+    assert dead.any() or offsets != "q behind"
+    # the partials' public contract on such rows: zeros, -inf and 0
+    assert np.all(np.asarray(acc)[..., dead, :] == 0.0)
+    assert np.all(np.isneginf(np.asarray(m)[..., dead]))
+    assert np.all(np.asarray(l)[..., dead] == 0.0)
+
+
+@pytest.mark.parametrize("fa_backward_path", ["1", "0"], indirect=True,
+                         ids=["fused-bwd", "two-kernel-bwd"])
+@pytest.mark.parametrize("window", [None, 40], ids=["causal", "window"])
+def test_traced_offsets_give_what_concrete_ones_give(window,
+                                                     fa_backward_path):
+    """A ring step's offsets are traced (``axis_index`` under
+    ``shard_map``): the prefetched scalars steer the same index maps,
+    and the numbers are the concrete call's, bit for bit."""
+    from mpit_tpu.ops.flash_attention import (
+        _lse_of, flash_attention_bwd_pair, flash_attention_partial)
+
+    keys = jax.random.split(jax.random.PRNGKey(5), 4)
+    q = jax.random.normal(keys[0], (2, 4, 150, 16))   # 4 heads over 1
+    k = jax.random.normal(keys[1], (2, 200, 16))
+    v = jax.random.normal(keys[2], (2, 200, 16))
+    do = jax.random.normal(keys[3], q.shape)
+
+    def pair(q_offset, kv_offset):
+        kw = dict(causal=True, window=window, q_offset=q_offset,
+                  kv_offset=kv_offset, block_q=64, block_k=128,
+                  interpret=True)
+        acc, m, l = flash_attention_partial(q, k, v, **kw)
+        o = finalize_partials(acc, l)
+        return (acc, m, l) + flash_attention_bwd_pair(
+            q, k, v, do, _lse_of(m, l), o=o, **kw)
+
+    import functools
+
+    for offsets in [(0, 0), (140, 75), (0, 130)]:
+        concrete = jax.jit(functools.partial(pair, *offsets))()
+        traced = jax.jit(pair)(*(jnp.int32(x) for x in offsets))
+        for a, b in zip(traced, concrete):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
 def test_flash_bwd_no_quadratic_intermediate():
     """The backward must never materialize an (Lq, Lk) array — the memory
     property flash attention exists for (VERDICT r2 missing-item #2).

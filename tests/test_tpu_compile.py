@@ -1,8 +1,9 @@
 """Compiles for a *described* v5e, no chip attached (the
 on-chip-measurement guide, section 2): the Pallas grouped product of
 ``parallel/moe.py`` at OLMoE's published shapes, forward and backward;
-the flash kernel at LFM2's attention shape (32 query over 8 KV heads of
-64 at 8192); and the msgd commit over LFM2's vector, whose length is
+the flash kernels at LFM2's attention shape (32 query over 8 KV heads of
+64 at 8192) and at Mellum's sliding layer's (32 over 4 heads of 128
+under a window of 1024); and the msgd commit over LFM2's vector, whose length is
 whole lanes and no whole number of blocks, with ``w`` and ``vt``
 donated.  What interpret mode cannot show: that the tiles fit the chip's fast
 memory and the kernels lower.  A compile that passes is not a chip run
@@ -52,25 +53,33 @@ def test_the_pallas_grouped_product_compiles_at_published_shapes(one_chip, k, n)
     assert "f32[64,%d,%d]" % (k, n) in text
 
 
-def test_flash_attention_compiles_at_32_over_8_heads_of_64(one_chip):
+@pytest.mark.parametrize("kv_heads,width,window", [(8, 64, None),
+                                                   (4, 128, 1024)],
+                         ids=["32_over_8_heads_of_64",
+                              "32_over_4_heads_of_128_window_1024"])
+def test_flash_attention_compiles_at(one_chip, kv_heads, width, window):
     """LFM2's attention layer (PR 32): a group's four query heads folded
-    into the kernel's rows, the head width padded to the lanes; forward
-    and the backward kernels lower for the chip, k and v at the KV
-    heads' size."""
+    into the kernel's rows, the head width padded to the lanes; and
+    Mellum's sliding layer at its published shape (PR 33): a group of
+    eight under a window of 1024, the inner grid axis the window's
+    static bound and the index maps reading the prefetched offsets.
+    Forward and the backward kernels lower for the chip, k and v at the
+    KV heads' size."""
     from mpit_tpu.ops.flash_attention import flash_attention
 
     def loss(q, k, v):
-        return jnp.sum(flash_attention(q, k, v, causal=True,
+        return jnp.sum(flash_attention(q, k, v, causal=True, window=window,
                                        interpret=False) ** 2)
 
-    q = jax.ShapeDtypeStruct((1, 32, 8192, 64), jnp.float32,
+    q = jax.ShapeDtypeStruct((1, 32, 8192, width), jnp.float32,
                              sharding=one_chip)
-    kv = jax.ShapeDtypeStruct((1, 8, 8192, 64), jnp.float32,
+    kv = jax.ShapeDtypeStruct((1, kv_heads, 8192, width), jnp.float32,
                               sharding=one_chip)
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         q, kv, kv).compile()
     calls = compiled.as_text().count('custom_call_target="tpu_custom_call"')
-    assert calls >= 2   # forward, and the fused or the two backward kernels
+    # forward, and the fused (no window) or the two backward kernels
+    assert calls >= (2 if window is None else 3)
     dq, dk, dv = compiled.output_shardings
 
 
