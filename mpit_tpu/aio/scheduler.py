@@ -41,10 +41,10 @@ IDLE_USEC = float(os.environ.get("MPIT_AIO_IDLE_USEC", "200"))
 # accumulated this many seconds of idle backoff without completing a
 # single task, the scheduler dumps its live task table plus the flight
 # recorder's recent events — a hang produces a postmortem instead of
-# nothing.  Counted in *idle-backoff* seconds (no extra clock reads on
-# the hot path): a pass that completes a task resets the budget, so a
-# healthy-but-busy gang never trips it.  Active only when obs is
-# enabled; 0 disables.
+# nothing.  Counted in *idle-backoff* seconds as the span recorder
+# measured them (with obs off nothing is measured and nothing counted):
+# a pass that completes a task resets the budget, so a healthy-but-busy
+# gang never trips it.  Active only when obs is enabled; 0 disables.
 STALL_S = float(os.environ.get("MPIT_OBS_STALL_S", "60"))
 
 # Task signals (reference init.lua:21-25).  INIT/OK are retained for state
@@ -161,6 +161,9 @@ class Scheduler:
         self.stall_s = STALL_S if stall_s is None else float(stall_s)
         self._idle_accum = 0.0
         self._stall_dumped = False
+        #: seconds inside the back-off sleeps, as the recorder measured
+        #: them: 0.0 and no clock read while it does not record
+        self.sleep_s = 0.0
         _reg = _obs_metrics.get_registry()
         self._m_steps = _reg.counter("mpit_aio_steps_total")
         self._m_idle = _reg.counter("mpit_aio_idle_seconds_total")
@@ -215,10 +218,12 @@ class Scheduler:
             self._stall_dumped = False
         elif self.idle_usec > 0 and self.queue:
             # Full pass, nothing finished: yield the core (see IDLE_USEC)
-            # instead of burning it on iprobe spins.
-            time.sleep(self.idle_usec * 1e-6)
-            self._m_idle.inc(self.idle_usec * 1e-6)
-            self._idle_accum += self.idle_usec * 1e-6
+            # instead of burning it on iprobe spins.  What the sleep took
+            # is the recorder's to say: 0.0 with obs off.
+            slept = self._rec.sleep(self.idle_usec * 1e-6)
+            self.sleep_s += slept
+            self._m_idle.inc(slept)
+            self._idle_accum += slept
             if (self._flight.enabled and self.stall_s > 0
                     and not self._stall_dumped
                     and self._idle_accum >= self.stall_s):

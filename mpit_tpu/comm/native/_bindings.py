@@ -51,7 +51,7 @@ def _as_pointer(obj):
 #: lockstep with transport.cpp (see kApiVersion comment there) whenever
 #: the exported C surface changes, so a stale .so fails loudly with a
 #: rebuild message instead of a confusing missing-symbol AttributeError.
-MT_API_VERSION = 17003
+MT_API_VERSION = 17004
 
 
 class NativeTransportLib:
@@ -99,6 +99,8 @@ class NativeTransportLib:
         self._lib.mt_isend.argtypes = [ctypes.c_void_p, ctypes.c_int32, ctypes.c_int32, ctypes.c_void_p, ctypes.c_uint64]
         self._lib.mt_nranks.restype = ctypes.c_int32
         self._lib.mt_nranks.argtypes = [ctypes.c_void_p]
+        self._lib.mt_op_timing.restype = ctypes.c_int32
+        self._lib.mt_op_timing.argtypes = [ctypes.c_void_p, ctypes.c_int64, ctypes.c_void_p]
         self._lib.mt_pool_busy_seconds.restype = ctypes.c_double
         self._lib.mt_pool_busy_seconds.argtypes = [ctypes.c_void_p]
         self._lib.mt_pool_close.restype = None
@@ -129,10 +131,14 @@ class NativeTransportLib:
         self._lib.mt_ring_counts.argtypes = [ctypes.c_void_p, ctypes.c_int32]
         self._lib.mt_rx_bytes.restype = ctypes.c_uint64
         self._lib.mt_rx_bytes.argtypes = [ctypes.c_void_p, ctypes.c_int32]
+        self._lib.mt_set_timing.restype = None
+        self._lib.mt_set_timing.argtypes = [ctypes.c_void_p, ctypes.c_int32]
         self._lib.mt_test.restype = ctypes.c_int32
         self._lib.mt_test.argtypes = [ctypes.c_void_p, ctypes.c_int64]
         self._lib.mt_time.restype = ctypes.c_double
         self._lib.mt_time.argtypes = []
+        self._lib.mt_wire_ns.restype = ctypes.c_uint64
+        self._lib.mt_wire_ns.argtypes = [ctypes.c_void_p, ctypes.c_int32]
         self._lib.mt_xor_bytes.restype = None
         self._lib.mt_xor_bytes.argtypes = [ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int64]
 
@@ -205,6 +211,11 @@ class NativeTransportLib:
         """World size."""
         return self._lib.mt_nranks(ctx)
 
+    def mt_op_timing(self, ctx, handle, out):
+        """The record of a finished op, after mt_test saw it done and before mt_release: 12 u64 words into out and 12 returned, or 0 without a record (timing off). [0] 1 send / 2 receive, [1] msg_id, [2] t_first ns, [3] t_done ns, [4] copy_ns, [5] blocked_ns (send) or starved_ns (receive), [6] away_ns, [7] chunks, [8] refused placements (send) or overlapped chunks (receive), [9] receive landed in its posted buffer, [10] receive's t_first_pub ns, [11] bytes."""
+        out, _out_len = _as_pointer(out)
+        return self._lib.mt_op_timing(ctx, handle, out)
+
     def mt_pool_busy_seconds(self, pool):
         """Cumulative worker seconds spent inside kernels (feeds mpit_pool_busy_seconds)."""
         return self._lib.mt_pool_busy_seconds(pool)
@@ -269,6 +280,10 @@ class NativeTransportLib:
         """Bytes of the messages received whole so far: which == 0, those that landed in the buffer of a receive posted before they arrived; 1, those assembled in a buffer of the transport's own."""
         return self._lib.mt_rx_bytes(ctx, which)
 
+    def mt_set_timing(self, ctx, on):
+        """The one switch of the wire's timing: on, every message keeps a record of where its time went (mt_op_timing), every chunk carries the instant it was published and the endpoint its totals (mt_wire_ns); off (the default) the message path reads no clock."""
+        return self._lib.mt_set_timing(ctx, on)
+
     def mt_test(self, ctx, handle):
         """Advance progress; 1 complete, 0 pending, -1 unknown/cancelled, -2 size mismatch."""
         return self._lib.mt_test(ctx, handle)
@@ -276,6 +291,10 @@ class NativeTransportLib:
     def mt_time(self):
         """Monotonic seconds (MPI_Wtime analog, reference mpifuncs.c:2500)."""
         return self._lib.mt_time()
+
+    def mt_wire_ns(self, ctx, which):
+        """The endpoint's totals while timing, ns, cumulative: which == 0, inside the copies into peers' rings; 1, inside the copies out of the own rings and the memcpy that hands an assembled message over; 2, inside progress() and that memcpy (less the two copies: the cost of polling)."""
+        return self._lib.mt_wire_ns(ctx, which)
 
     def mt_xor_bytes(self, a, b, out, n):
         """out[i] = a[i] ^ b[i] over n bytes (cells DELTA frame XOR), word-at-a-time. Bit-identical to np.bitwise_xor."""

@@ -47,6 +47,14 @@
 //  * All progress happens inside mt_iprobe/mt_test calls from the caller's
 //    cooperative scheduler — single-threaded per process, like the
 //    reference's coroutine polling (reference init.lua:147-185).
+//  * Where a message's time went is kept only while the endpoint's one
+//    switch is on (mt_set_timing; comm/shm.py sets it from the span
+//    recorder): a record a message on each end (TxTiming, RxTiming), the
+//    instant a chunk was published in its header (`pub_ns`), and three
+//    endpoint totals (mt_wire_ns).  Off, no clock is read on the message
+//    path, `pub_ns` is 0 and mt_op_timing gives nothing.  Both ends read
+//    CLOCK_MONOTONIC of one host, so the owner's subtraction from a
+//    sender's stamp is exact.
 //
 // Exported C API (ctypes bindings are generated from specs/*.json by
 // gen_bindings.py, mirroring the reference's readspec.py codegen).
@@ -106,6 +114,7 @@ struct ChunkHeader {
   uint32_t nchunks;
   uint64_t chunk_bytes;
   uint64_t total_bytes;
+  uint64_t pub_ns;      // CLOCK_MONOTONIC at publication; 0: sender not timing
 };
 
 struct Segment {
@@ -148,8 +157,78 @@ struct Buffer {
   uint64_t cap = 0;  // allocation size
 };
 
+uint64_t now_ns() {
+  struct timespec ts;
+  clock_gettime(CLOCK_MONOTONIC, &ts);
+  return (uint64_t)ts.tv_sec * 1000000000ull + (uint64_t)ts.tv_nsec;
+}
+
+uint64_t since(uint64_t later, uint64_t earlier) {
+  return later > earlier ? later - earlier : 0;
+}
+
+// Where a sent message's time went, first attempt to place a chunk to last
+// chunk published.  What is neither `copy_ns` nor `blocked_ns` of that is
+// the sender's time away: the ring had room and its thread was elsewhere.
+struct TxTiming {
+  uint64_t t_first = 0;
+  uint64_t t_done = 0;
+  uint64_t copy_ns = 0;     // inside circ_write
+  uint64_t blocked_ns = 0;  // a refused placement to the next accepted one
+  uint64_t t_refused = 0;   // the refusal still waited out; 0: none
+  uint32_t refused = 0;     // the polls, as tx_ring_full counts them
+  uint32_t chunks = 0;
+};
+
+// Where a received message's time went, first chunk published to message
+// whole where it was asked for: `copy_ns` + `starved_ns` + `away_ns`.
+struct RxTiming {
+  uint64_t msg_id = 0;
+  uint64_t t_first_pub = 0;  // the sender's stamp on the first chunk
+  uint64_t t_first = 0;      // copy-out of the first chunk begins
+  uint64_t t_done = 0;
+  uint64_t last_end = 0;     // the copy before this one ended
+  uint64_t copy_ns = 0;      // inside circ_read, and the hand-over memcpy
+  uint64_t starved_ns = 0;   // ring empty, message partial: the sender's
+  uint64_t away_ns = 0;      // a chunk lay published and was not being copied
+  uint32_t chunks = 0;
+  uint32_t overlap_chunks = 0;
+
+  // One chunk copied out between `t_start` and `t_end`.
+  void chunk(const ChunkHeader& ch, uint64_t t_start, uint64_t t_end,
+             bool overlapped) {
+    // A sender that keeps no time says nothing of when it published.
+    uint64_t pub = ch.pub_ns != 0 && ch.pub_ns < t_start ? ch.pub_ns : t_start;
+    if (chunks == 0) {
+      msg_id = ch.msg_id;
+      t_first_pub = pub;
+      t_first = t_start;
+      last_end = pub;
+    }
+    if (pub > last_end) {
+      starved_ns += pub - last_end;
+      last_end = pub;
+    }
+    away_ns += since(t_start, last_end);
+    copy_ns += since(t_end, t_start);
+    last_end = t_end;
+    t_done = t_end;
+    chunks++;
+    overlap_chunks += overlapped;
+  }
+
+  // The memcpy that hands an assembled message over, in mt_test.
+  void handed_over(uint64_t t_start, uint64_t t_end) {
+    away_ns += since(t_start, last_end);
+    copy_ns += since(t_end, t_start);
+    last_end = t_end;
+    t_done = t_end;
+  }
+};
+
 struct Message {
   Buffer buf;
+  RxTiming rt;
 };
 
 struct Partial {
@@ -159,6 +238,7 @@ struct Partial {
   int32_t tag = 0;
   int64_t bound = 0;  // receive whose buffer the chunks land in; 0: buf
   Buffer buf;
+  RxTiming rt;
 };
 
 struct SendOp {
@@ -173,6 +253,7 @@ struct SendOp {
   bool done = false;
   bool cancelled = false;
   uint32_t stalls = 0;  // consecutive pump passes that found the ring full
+  TxTiming tt;
 };
 
 // After this many consecutive passes that placed nothing in a full peer ring,
@@ -191,6 +272,7 @@ struct RecvOp {
   bool done = false;
   bool cancelled = false;
   bool size_mismatch = false;
+  RxTiming rt;
 };
 
 struct Ctx {
@@ -220,6 +302,13 @@ struct Ctx {
   uint64_t tx_ring_full = 0;
   uint64_t rx_chunks = 0;
   uint64_t rx_overlap_chunks = 0;
+  // While `timing` (mt_set_timing): ns inside circ_write, inside circ_read
+  // and the hand-over memcpy, and inside progress() with that memcpy
+  // (mt_wire_ns).  Less the two copies the last is the cost of polling.
+  bool timing = false;
+  uint64_t tx_copy_ns = 0;
+  uint64_t rx_copy_ns = 0;
+  uint64_t progress_ns = 0;
   std::string last_error;
 };
 
@@ -389,12 +478,18 @@ void abandon_partials(Ctx* ctx, int src) {
 // the buffer of the receive the message is bound to, or else into its
 // assembly buffer — one copy either way, into uninitialized storage, with
 // no lock held; an assembled message pays a second one when mt_test hands
-// it over.  `tail` is published after every chunk.
+// it over.  `tail` is published after every chunk.  While timing, a chunk's
+// copy-out is stamped at both ends and booked on its message's record,
+// which ends up on the receive (bound, or in mt_test) that takes it.
 void drain_ring(Ctx* ctx, const Ring& ring) {
+  const bool timing = ctx->timing;
   uint64_t tail = ring.idx->tail.load(std::memory_order_relaxed);
   const uint64_t limit = ring.idx->head.load(std::memory_order_acquire);
   while (tail < limit) {
     const uint64_t head_before = ring.idx->head.load(std::memory_order_acquire);
+    const uint64_t t_start = timing ? now_ns() : 0;
+    RxTiming* rt = nullptr;  // the record of this chunk's message
+    RxTiming whole;          // ... of a message that is one chunk
     ChunkHeader ch;
     circ_read(ring, tail, &ch, sizeof(ch));
     tail += sizeof(ch);
@@ -406,17 +501,22 @@ void drain_ring(Ctx* ctx, const Ring& ring) {
       abandon_partials(ctx, ch.src);
       op = posted_recv(ctx, ch.src, ch.tag, ch.total_bytes, &handle);
     }
+    RxTiming* landed = nullptr;  // where a message whole now keeps its record
     if (ch.chunk_bytes == ch.total_bytes) {  // complete in one chunk
+      rt = &whole;
       if (op != nullptr) {
         if (ch.chunk_bytes > 0) circ_read(ring, tail, op->out, ch.chunk_bytes);
         op->size = ch.total_bytes;
         op->bound = true;
         op->done = true;
+        landed = &op->rt;
         ctx->rx_direct_bytes += ch.total_bytes;
       } else {
         Buffer buf = alloc_buffer(ctx, ch.total_bytes);
         if (ch.chunk_bytes > 0) circ_read(ring, tail, buf.data.get(), ch.chunk_bytes);
-        ctx->ready[{ch.src, ch.tag}].push_back(Message{std::move(buf)});
+        auto& box = ctx->ready[{ch.src, ch.tag}];
+        box.push_back(Message{std::move(buf), RxTiming{}});
+        landed = &box.back().rt;
         ctx->rx_assembled_bytes += ch.total_bytes;
       }
     } else {
@@ -441,23 +541,35 @@ void drain_ring(Ctx* ctx, const Ring& ring) {
       if (n > 0) circ_read(ring, tail, dst + part.filled, n);
       part.filled += ch.chunk_bytes;
       part.seen++;
+      rt = &part.rt;
       if (part.filled >= part.total) {
         if (op != nullptr) {
           op->size = part.total;
           op->done = true;
+          landed = &op->rt;
           ctx->rx_direct_bytes += part.total;
         } else {
-          ctx->ready[{ch.src, part.tag}].push_back(Message{std::move(part.buf)});
+          auto& box = ctx->ready[{ch.src, part.tag}];
+          box.push_back(Message{std::move(part.buf), RxTiming{}});
+          landed = &box.back().rt;
           ctx->rx_assembled_bytes += part.total;
         }
-        ctx->partial.erase(key);
       }
     }
     tail += ch.chunk_bytes;
     ring.idx->tail.store(tail, std::memory_order_release);
     ctx->rx_chunks++;
-    if (ring.idx->head.load(std::memory_order_acquire) != head_before) {
-      ctx->rx_overlap_chunks++;
+    const bool overlapped =
+        ring.idx->head.load(std::memory_order_acquire) != head_before;
+    ctx->rx_overlap_chunks += overlapped;
+    if (timing) {
+      const uint64_t t_end = now_ns();
+      ctx->rx_copy_ns += t_end - t_start;
+      rt->chunk(ch, t_start, t_end, overlapped);
+    }
+    if (landed != nullptr) {
+      if (timing) *landed = *rt;
+      if (rt != &whole) ctx->partial.erase({ch.src, ch.msg_id});
     }
   }
 }
@@ -480,7 +592,7 @@ void drop_recv(Ctx* ctx, std::map<int64_t, RecvOp>::iterator it) {
     Buffer buf = alloc_buffer(ctx, op.cap);
     if (op.done) {
       if (op.cap > 0) std::memcpy(buf.data.get(), op.out, op.cap);
-      ctx->ready[{op.src, op.tag}].push_front(Message{std::move(buf)});
+      ctx->ready[{op.src, op.tag}].push_front(Message{std::move(buf), op.rt});
     } else {
       Partial& part = ctx->partial.at({op.src, op.msg_id});
       if (part.filled > 0) std::memcpy(buf.data.get(), op.out, part.filled);
@@ -494,8 +606,11 @@ void drop_recv(Ctx* ctx, std::map<int64_t, RecvOp>::iterator it) {
 // Place more chunks of the front send ops of each destination, at most one
 // ring's worth of bytes a destination and pass: with the owner draining
 // beside it the ring may never fill, and the caller's thread has its other
-// destinations, its inbox and its deadlines to look at.
+// destinations, its inbox and its deadlines to look at.  While timing, the
+// payload goes in first and the header, stamped with the instant, after
+// it; both lie in the ring before `head` says so either way.
 void pump_sends(Ctx* ctx) {
+  const bool timing = ctx->timing;
   for (auto& [dst, queue] : ctx->send_q) {
     uint64_t budget = UINT64_MAX;  // the ring's capacity, once it is mapped
     while (!queue.empty()) {
@@ -518,10 +633,16 @@ void pump_sends(Ctx* ctx) {
         uint64_t chunk = remaining < chunk_max ? remaining : chunk_max;
         uint64_t need = sizeof(ChunkHeader) + chunk;
         if (need > budget) break;
+        const uint64_t t_try = timing ? now_ns() : 0;
+        if (timing && op.tt.t_first == 0) op.tt.t_first = t_try;
         uint64_t used = head - ring.idx->tail.load(std::memory_order_acquire);
         if (ring.capacity - used < need) {
           ctx->tx_ring_full++;
           full = true;
+          if (timing) {
+            op.tt.refused++;
+            if (op.tt.t_refused == 0) op.tt.t_refused = t_try;
+          }
           break;
         }
         ChunkHeader ch;
@@ -532,10 +653,23 @@ void pump_sends(Ctx* ctx) {
         ch.nchunks = 0;  // informational; completion is byte-based
         ch.chunk_bytes = chunk;
         ch.total_bytes = op.len;
-        circ_write(ring, head, &ch, sizeof(ch));
+        ch.pub_ns = 0;
         if (chunk > 0) {
           circ_write(ring, head + sizeof(ch), op.data + op.written, chunk);
         }
+        if (timing) {
+          const uint64_t t_pub = now_ns();
+          ch.pub_ns = t_pub;
+          if (op.tt.t_refused != 0) {
+            op.tt.blocked_ns += t_try - op.tt.t_refused;
+            op.tt.t_refused = 0;
+          }
+          op.tt.copy_ns += t_pub - t_try;
+          op.tt.t_done = t_pub;
+          op.tt.chunks++;
+          ctx->tx_copy_ns += t_pub - t_try;
+        }
+        circ_write(ring, head, &ch, sizeof(ch));
         head += need;
         ring.idx->head.store(head, std::memory_order_release);
         budget -= need;
@@ -560,8 +694,10 @@ void pump_sends(Ctx* ctx) {
 }
 
 void progress(Ctx* ctx) {
+  const uint64_t t_in = ctx->timing ? now_ns() : 0;
   drain_inbox(ctx);
   pump_sends(ctx);
+  if (ctx->timing) ctx->progress_ns += now_ns() - t_in;
 }
 
 }  // namespace
@@ -656,7 +792,9 @@ int mt_test(void* vctx, int64_t handle) {
   if (sit != ctx->sends.end()) {
     if (sit->second.cancelled) return -1;
     if (sit->second.done) {
-      ctx->sends.erase(sit);
+      // While timing the op keeps its record for mt_op_timing until
+      // mt_release forgets it.
+      if (!ctx->timing) ctx->sends.erase(sit);
       return 1;
     }
     return 0;
@@ -675,7 +813,15 @@ int mt_test(void* vctx, int64_t handle) {
       op.size = msg.buf.len;
       return -2;
     }
+    const uint64_t t_copy = ctx->timing ? now_ns() : 0;
     if (op.cap > 0) std::memcpy(op.out, msg.buf.data.get(), op.cap);
+    if (ctx->timing) {
+      const uint64_t t_end = now_ns();
+      op.rt = msg.rt;
+      op.rt.handed_over(t_copy, t_end);
+      ctx->rx_copy_ns += t_end - t_copy;
+      ctx->progress_ns += t_end - t_copy;
+    }
     op.size = msg.buf.len;
     op.done = true;
     Buffer freed = std::move(msg.buf);
@@ -732,6 +878,65 @@ uint64_t mt_ring_counts(void* vctx, int32_t which) {
   const uint64_t counts[] = {ctx->tx_chunks, ctx->tx_ring_full, ctx->rx_chunks,
                              ctx->rx_overlap_chunks};
   return which >= 0 && which < 4 ? counts[which] : 0;
+}
+
+// The one switch of the wire's timing: on, every message keeps a record of
+// where its time went (mt_op_timing), every chunk carries the instant it
+// was published, and the endpoint its totals (mt_wire_ns); off (the
+// default) the message path reads no clock.
+void mt_set_timing(void* vctx, int32_t on) {
+  static_cast<Ctx*>(vctx)->timing = on != 0;
+}
+
+// The record of a finished op, for the caller whose mt_test saw it done
+// and before mt_release: up to kTimingWords words into `out`, and how many
+// were written; 0 without a record (timing off, a handle unknown or not
+// done).  [0] 1 a send, 2 a receive; [1] the sender's msg_id; [2] t_first
+// and [3] t_done, ns on CLOCK_MONOTONIC; [4] copy_ns; [5] blocked_ns of a
+// send, starved_ns of a receive; [6] away_ns; [7] chunks; [8] refused
+// placements of a send, overlapped chunks of a receive; [9] a receive that
+// landed in its posted buffer; [10] a receive's t_first_pub; [11] bytes.
+constexpr int32_t kTimingWords = 12;
+
+int32_t mt_op_timing(void* vctx, int64_t handle, void* vout) {
+  auto* ctx = static_cast<Ctx*>(vctx);
+  auto* out = static_cast<uint64_t*>(vout);
+  auto sit = ctx->sends.find(handle);
+  if (sit != ctx->sends.end()) {
+    const SendOp& op = sit->second;
+    const TxTiming& tt = op.tt;
+    if (!op.done || tt.t_first == 0) return 0;
+    const uint64_t busy = tt.copy_ns + tt.blocked_ns;
+    const uint64_t words[kTimingWords] = {
+        1, op.msg_id, tt.t_first, tt.t_done, tt.copy_ns, tt.blocked_ns,
+        since(tt.t_done - tt.t_first, busy), tt.chunks, tt.refused, 0,
+        tt.t_first, op.len};
+    std::memcpy(out, words, sizeof(words));
+    return kTimingWords;
+  }
+  auto rit = ctx->recvs.find(handle);
+  if (rit != ctx->recvs.end()) {
+    const RecvOp& op = rit->second;
+    const RxTiming& rt = op.rt;
+    if (!op.done || rt.chunks == 0) return 0;
+    const uint64_t words[kTimingWords] = {
+        2, rt.msg_id, rt.t_first, rt.t_done, rt.copy_ns, rt.starved_ns,
+        rt.away_ns, rt.chunks, rt.overlap_chunks, op.bound ? 1u : 0u,
+        rt.t_first_pub, op.size};
+    std::memcpy(out, words, sizeof(words));
+    return kTimingWords;
+  }
+  return 0;
+}
+
+// The endpoint's totals while timing, ns: which == 0, inside circ_write;
+// 1, inside circ_read and the memcpy that hands an assembled message
+// over; 2, inside progress() and that memcpy.  Cumulative: read as deltas.
+uint64_t mt_wire_ns(void* vctx, int32_t which) {
+  auto* ctx = static_cast<Ctx*>(vctx);
+  const uint64_t totals[] = {ctx->tx_copy_ns, ctx->rx_copy_ns,
+                             ctx->progress_ns};
+  return which >= 0 && which < 3 ? totals[which] : 0;
 }
 
 // Monotonic wall clock in seconds (the MPI_Wtime analog,
@@ -884,7 +1089,7 @@ void mt_fold_f32(const void* vown, const void* vptrs, int32_t nchildren,
 // generated _bindings.py refuses a stale .so (loud rebuild message)
 // instead of failing with a confusing missing-symbol AttributeError.
 // Keep in sync with MT_API_VERSION in gen_bindings.py.
-int64_t mt_api_version(void) { return 17003; }
+int64_t mt_api_version(void) { return 17004; }
 
 }  // extern "C"
 

@@ -20,6 +20,22 @@ half-sent is dropped when its next incarnation's first message opens.
 ``ring_counters`` says how often a sender found its ring full and how
 often the two sides were copying at once.
 
+Where a message's time went: while the span recorder records (and only
+then: the recorder's ``enabled`` at construction is the one switch, there
+is no variable and no flag of its own) the native side keeps a record a
+message on each end and stamps every chunk with the instant it was
+published.  The ``test`` that first sees a transfer of at least 1 MB done
+turns its record into one ``wire`` span, ``tx`` or ``rx``, begun and
+ended at the native stamps (this file reads no clock), whose args tile
+the flight: ``copy_ms`` (inside the ring copies), ``blocked_ms`` (``tx``:
+a full ring refused a chunk, until the ring took the next one: the owner's
+drain is the slower side) or ``starved_ms`` (``rx``: the message was
+partial and the ring empty: the sender is the slower side), and
+``away_ms`` (``tx``: the ring had room and the sender's thread was
+elsewhere; ``rx``: a chunk lay published and the owner was not copying it
+out: asleep in the back-off, in another ring, in Python, off the core).
+``wire_totals`` are the endpoint's own sums, acks and headers included.
+
 Zero-copy discipline: sends pass the numpy buffer's raw pointer to C and
 the Handle holds the array reference until completion.  A receive posted
 with a buffer *before* its message's first chunk is drained lands in that
@@ -51,6 +67,10 @@ import numpy as np
 
 from mpit_tpu.comm.transport import Handle, Transport
 from mpit_tpu.obs import metrics as _obs
+from mpit_tpu.obs import spans as _spans
+
+#: words of a native timing record (transport.cpp ``mt_op_timing``)
+_TIMING_WORDS = 12
 
 
 @functools.lru_cache(maxsize=1)
@@ -79,6 +99,12 @@ class ShmTransport(Transport):
                 f"mt_init failed for namespace={namespace!r} rank={rank}"
             )
         self._closed = False
+        # The wire's timing follows the span recorder: off, the native
+        # side reads no clock and ``test`` asks for no record.
+        self._rec = _spans.get_recorder()
+        self._record = np.zeros(_TIMING_WORDS, np.uint64)
+        if self._rec.enabled:
+            self.lib.mt_set_timing(self._ctx, 1)
         # Per-peer traffic counters (mpit_tpu.obs): rank-indexed lists,
         # null singletons when obs is disabled (no-op on the hot path).
         _reg = _obs.get_registry()
@@ -173,6 +199,8 @@ class ShmTransport(Transport):
                     int(getattr(out, "nbytes", None) or len(out or b"")))
             if handle.kind == "send":
                 handle.buf = None  # release ownership back to the caller
+            if self._rec.enabled:
+                self._wire_span(handle)
             if self._m_native:
                 now = self.wire_counts()
                 for key, counter in self._m_native.items():
@@ -222,6 +250,37 @@ class ShmTransport(Transport):
 
     def wire_counts(self) -> dict:
         return {**self.rx_path_bytes(), **self.ring_counters()}
+
+    def wire_totals(self) -> dict:
+        """Seconds this endpoint's thread has spent, so far and while
+        the recorder records (zeros otherwise): copying into its peers'
+        rings, copying out of its own (the hand-over of an assembled
+        message included), and inside the native ``progress`` altogether:
+        less the two copies, the cost of polling."""
+        return {key: self.lib.mt_wire_ns(self._ctx, which) * 1e-9
+                for which, key in enumerate(
+                    ("tx_copy", "rx_copy", "progress"))}
+
+    def _wire_span(self, handle: Handle) -> None:
+        """The native record of the transfer ``handle`` just finished,
+        as one ``wire`` span (see the module docstring)."""
+        if not self.lib.mt_op_timing(self._ctx, handle.native_id,
+                                     self._record):
+            return
+        (kind, msg_id, t_first, t_done, copy, wait, away, chunks, count,
+         direct, t_pub, nbytes) = self._record.tolist()
+        if nbytes < _spans.WIRE_SPAN_MIN_BYTES:
+            return
+        args = {"bytes": nbytes, "msg_id": msg_id, "chunks": chunks,
+                "copy_ms": copy / 1e6, "away_ms": away / 1e6}
+        if kind == 1:
+            args.update(blocked_ms=wait / 1e6, refused=count,
+                        flight_ms=(t_done - t_first) / 1e6)
+        else:
+            args.update(starved_ms=wait / 1e6, overlap_chunks=count,
+                        direct=direct, flight_ms=(t_done - t_pub) / 1e6)
+        self._rec.wire("tx" if kind == 1 else "rx", self.rank, handle.peer,
+                       handle.tag, t_first * 1e-9, t_done * 1e-9, **args)
 
     def close(self) -> None:
         if not self._closed and self._ctx:

@@ -31,7 +31,7 @@ counters.  This package is the one place the stack reports through:
   chrome://tracing next to a ``jax.profiler`` device timeline.
 - :mod:`mpit_tpu.obs.timers` — the old ``utils/timers.py``
   (``PhaseTimers``, ``trace_annotation``, ``profiler_trace``), folded
-  in; ``mpit_tpu.utils.timers`` re-exports for back-compat.
+  in; ``mpit_tpu.utils`` re-exports the three names.
 - :mod:`mpit_tpu.obs.statusd` — the **live half**: a per-rank HTTP
   introspection endpoint (``MPIT_OBS_HTTP=<base_port>``; base+rank per
   process) serving ``/metrics`` (Prometheus exposition), ``/status``

@@ -18,6 +18,9 @@ connects.  This module is the offline joiner that connects them:
    the per-channel ordinal ``n`` both recorders count (obs/spans.py):
    ``(op, client rank, server rank, "ord", n)``, exact because the
    channels are strictly sequential and nothing is retried there.
+   Beneath the ops, the shm wire's own spans (category ``wire``, one a
+   message and end) join ``tx`` to ``rx`` on the wire's identity,
+   ``(src, dst, msg_id)`` (:func:`join_wire`).
 3. **Align clocks.**  Ranks whose trace parts name the same
    ``clock_id`` (one host, one ``time.monotonic``) differ by their
    recorded ``epoch_offset``s exactly.  Otherwise cross-rank
@@ -122,8 +125,9 @@ def load_trace(path_or_obj):
     return obj.get("traceEvents", []), obj.get("otherData", {}) or {}
 
 
-def extract_spans(events) -> List[Span]:
-    """Rebuild op spans from B/E pairs, attaching the ``ps_phase`` X
+def extract_spans(events, cat: str = "ps_op") -> List[Span]:
+    """Rebuild the spans of category ``cat`` (the op spans; ``wire``
+    for the shm wire's) from B/E pairs, attaching the ``ps_phase`` X
     events that fall inside them.  Channels are protocol-sequential per
     (pid, tid), so one open-span slot per channel suffices."""
     spans: List[Span] = []
@@ -131,7 +135,7 @@ def extract_spans(events) -> List[Span]:
     for ev in events:
         ph = ev.get("ph")
         key = (ev.get("pid"), ev.get("tid"))
-        if ph == "B" and ev.get("cat") == "ps_op":
+        if ph == "B" and ev.get("cat") == cat:
             open_span[key] = Span(ev.get("pid"), ev.get("tid"),
                                   ev.get("name"), ev.get("ts", 0.0),
                                   ev.get("args"))
@@ -145,7 +149,7 @@ def extract_spans(events) -> List[Span]:
                 cpu = (ev.get("args") or {}).get("cpu_us")
                 span.phase_cpu.append(
                     float(cpu) if isinstance(cpu, (int, float)) else None)
-        elif ph == "E" and ev.get("cat") == "ps_op":
+        elif ph == "E" and ev.get("cat") == cat:
             span = open_span.pop(key, None)
             if span is not None:
                 span.t1 = float(ev.get("ts", span.t0))
@@ -246,6 +250,30 @@ def join_spans(spans: List[Span]) -> Tuple[List[Chain], List[Span]]:
         else:
             chain.servers.append(span)
     return list(chains.values()), unkeyed
+
+
+def join_wire(spans: List[Span]) -> Tuple[List[Tuple[Span, Span]],
+                                          List[Span]]:
+    """The shm wire's spans (``extract_spans(events, cat="wire")``)
+    joined end to end: ``(tx, rx)`` pairs of one message, and the spans
+    left without their other end.  The identity is the wire's own and
+    exact: the sender's rank, the receiver's, and the ``msg_id`` the
+    sender counts; where a rank came back and counts from one again,
+    its messages pair in the order they were sent."""
+    ends: Dict[Tuple, Dict[str, List[Span]]] = {}
+    for span in sorted(spans, key=lambda s: s.t0):
+        a = span.args
+        src, dst = ((a.get("rank"), a.get("peer")) if span.name == "tx"
+                    else (a.get("peer"), a.get("rank")))
+        ends.setdefault((src, dst, a.get("msg_id")),
+                        {"tx": [], "rx": []})[span.name].append(span)
+    pairs: List[Tuple[Span, Span]] = []
+    unmatched: List[Span] = []
+    for sides in ends.values():
+        n = min(len(sides["tx"]), len(sides["rx"]))
+        pairs += zip(sides["tx"][:n], sides["rx"][:n])
+        unmatched += sides["tx"][n:] + sides["rx"][n:]
+    return pairs, unmatched
 
 
 # -- clock alignment ---------------------------------------------------------

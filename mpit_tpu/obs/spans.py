@@ -26,6 +26,18 @@ kinds:
   and a device trace on one clock (docs/OBSERVABILITY.md, *One
   clock*).
 
+- **Wire spans** (:meth:`SpanRecorder.wire`): one per message of at
+  least :data:`WIRE_SPAN_MIN_BYTES` and end of the shm wire (``tx``,
+  ``rx``; category ``wire``), begun and ended at the stamps the native
+  transport took itself (``comm/shm.py``, ``comm/native/transport.cpp``
+  ``TxTiming``/``RxTiming``): the recorder takes these times as given
+  and reads no clock for them.  Their args tile the message's flight
+  into copying, blocked on a full ring (``tx``) or starved by an empty
+  one (``rx``), and away.  A :class:`WireMeter` notes, on a span that
+  covers a stretch of one endpoint's thread (the ``round`` span's
+  ``exchange``, a server's GRAD and PARAM op spans), what that thread
+  spent copying, polling and asleep in the scheduler's back-off.
+
 Every op span carries ``n``, its ordinal on its channel (``tid``).  The
 channels are strictly sequential on both sides, so the client half and
 the server half of one op share (client rank, server rank, op, ``n``)
@@ -80,8 +92,10 @@ NULL_SPAN = NullSpan()
 
 
 class OpSpan:
+    cat = "ps_op"
     __slots__ = ("_rec", "name", "tid", "t0", "t1", "marks", "args",
-                 "outcome", "cpu0", "cpu1", "cpu_marks", "cpu_us")
+                 "outcome", "cpu0", "cpu1", "cpu_marks", "cpu_us",
+                 "seen_ready")
 
     def __init__(self, rec: "SpanRecorder", name: str, tid: str,
                  args: Dict[str, object]):
@@ -93,6 +107,9 @@ class OpSpan:
         self.marks: List[Tuple[str, float]] = []
         self.args = args
         self.outcome = ""
+        #: when a thread other than the one that ends this span saw its
+        #: result ready (:meth:`SpanRecorder.seen_ready`), if one did
+        self.seen_ready: Optional[float] = None
         # CPU attribution (obs/profile.py): when profiling is enabled
         # the span stamps the stepping thread's CPU clock alongside
         # every wall stamp, so the exporter can split each phase into
@@ -136,6 +153,90 @@ class OpSpan:
         self._rec._finish(self)
 
 
+#: Messages smaller than this get no wire span (acks and headers would be
+#: thousands of events a run); their time stays in the endpoint's totals.
+WIRE_SPAN_MIN_BYTES = 1 << 20
+
+
+class WireSpan:
+    """One message on one end of the shm wire: a finished span whose
+    begin and end are the transport's own stamps (seconds on the
+    monotonic clock), with nothing of an :class:`OpSpan` but what the
+    exporter reads."""
+
+    __slots__ = ("name", "tid", "t0", "t1", "args")
+    cat = "wire"
+    marks: tuple = ()
+    cpu_marks: tuple = ()
+    cpu0 = None
+    cpu_us = None
+    outcome = "ok"
+
+    def __init__(self, name: str, tid: str, t0: float, t1: float,
+                 args: Dict[str, object]):
+        self.name = name
+        self.tid = tid
+        self.t0 = t0
+        self.t1 = t1
+        self.args = args
+
+
+class NullMeter:
+    """The disabled :class:`WireMeter`: notes nothing, reads nothing."""
+
+    __slots__ = ()
+
+    def start(self) -> None:
+        pass
+
+    def note(self, span) -> None:
+        pass
+
+
+NULL_METER = NullMeter()
+
+
+class WireMeter:
+    """What one endpoint's thread did on the wire between two looks:
+    the deltas of the transport's totals (``wire_totals``: seconds inside
+    the copies into peers' rings, inside the copies out of its own, and
+    inside the native ``progress``) and of the scheduler's measured
+    back-off sleep (``Scheduler.sleep_s``).  :meth:`note` writes them on
+    a span as ``wire_tx_copy_ms``, ``wire_rx_copy_ms``, ``wire_poll_ms``
+    (progress less the copies) and ``sched_sleep_ms``, with
+    ``wire_span_ms``, the stretch they are of: since :meth:`start` or
+    the note before.  A transport that keeps no totals (tcp, local)
+    leaves the wire's three out."""
+
+    __slots__ = ("_totals", "_sched", "_last", "_t")
+
+    def __init__(self, transport: Any, sched: Any):
+        self._totals = getattr(transport, "wire_totals", None)
+        self._sched = sched
+        self.start()
+
+    def _read(self) -> Dict[str, float]:
+        now = dict(self._totals()) if self._totals is not None else {}
+        now["sched_sleep"] = getattr(self._sched, "sleep_s", 0.0)
+        return now
+
+    def start(self) -> None:
+        self._last = self._read()
+        self._t = time.monotonic()
+
+    def note(self, span) -> None:
+        now, t = self._read(), time.monotonic()
+        d = {k: (v - self._last[k]) * 1e3 for k, v in now.items()}
+        out = {"sched_sleep_ms": d["sched_sleep"],
+               "wire_span_ms": (t - self._t) * 1e3}
+        if "progress" in d:
+            out.update(
+                wire_tx_copy_ms=d["tx_copy"], wire_rx_copy_ms=d["rx_copy"],
+                wire_poll_ms=d["progress"] - d["tx_copy"] - d["rx_copy"])
+        span.note(**out)
+        self._last, self._t = now, t
+
+
 class RoundSpan(OpSpan):
     """The parent span of one sync round (:meth:`SpanRecorder.round`)."""
 
@@ -171,9 +272,25 @@ class RoundSpan(OpSpan):
         super().end(outcome, **kw)
 
 
+def _end_no_later_than_seen(span: OpSpan) -> None:
+    """A span the waiter ends, ended at the earlier of the waiter's
+    stamp and the one of a role thread that waited on the same result
+    (never before its last mark); ``end_from`` says whose it is.  Both
+    threads call this after their own write, so whichever comes second
+    finds both stamps."""
+    seen = span.seen_ready
+    if seen is not None and span.t1 is not None and seen < span.t1:
+        span.t1 = max(seen, span.marks[-1][1] if span.marks else span.t0)
+        span.args["end_from"] = "wait_apply"
+
+
 class _ReadyWaiter(threading.Thread):
     """Ends spans when the device result they wait for is ready, so that
-    the role thread that dispatched the work never blocks on it.  Exists
+    the role thread that dispatched the work never blocks on it.  Its end
+    stamp waits for the interpreter lock, which a busy role thread holds
+    for up to a tenth of a second: where that thread waited on the same
+    result itself and says so (:meth:`SpanRecorder.seen_ready`), the span
+    ends at the earlier stamp.  Exists
     only while recording (:meth:`SpanRecorder.end_when_ready` starts it
     on first use) and ends with the recorder that started it
     (:meth:`SpanRecorder.close`, or the recorder's collection: a None
@@ -198,7 +315,8 @@ class _ReadyWaiter(threading.Thread):
                 outcome = "ready"
             except Exception:  # a donated or deleted result: no stamp
                 outcome = "lost"
-            span.end(outcome)
+            span.end(outcome, end_from="waiter")
+            _end_no_later_than_seen(span)
             self.done += 1
             del item, span, result  # a span holds its recorder
 
@@ -239,6 +357,9 @@ class SpanRecorder:
         self._waiter: Optional[_ReadyWaiter] = None
         self._end_waiter: Optional[weakref.finalize] = None
         self._handed = 0  # spans given to the waiter
+        #: where the last wire span of a track ended: the next begins no
+        #: earlier, so a track's begin/end events nest
+        self._wire_end: Dict[str, float] = {}
 
     def _begin(self, span: OpSpan) -> OpSpan:
         n = self._ordinals.get(span.tid, 0)
@@ -275,11 +396,46 @@ class SpanRecorder:
             self._handed += 1
         self._waiter.items.put((span, result))
 
+    def seen_ready(self, span: OpSpan) -> None:
+        """The calling thread has just seen ready the result that
+        ``span``, handed to :meth:`end_when_ready`, waits for: the span
+        ends no later than now (see :class:`_ReadyWaiter`)."""
+        span.seen_ready = time.monotonic()
+        _end_no_later_than_seen(span)
+
     def close(self) -> None:
         """End the waiter thread once what was handed over has drained
         (a recorder that is replaced: :func:`reset`)."""
         if self._end_waiter is not None:
             self._end_waiter()
+
+    def wire(self, name: str, rank: int, peer: int, tag: int,
+             t0: float, t1: float, **args) -> None:
+        """Record the finished wire span ``name`` (``tx`` or ``rx``) of
+        one message between ``rank`` and ``peer`` on ``tag``, from ``t0``
+        to ``t1``: the transport's own stamps, seconds on the clock of
+        :meth:`clock`.  A message taken late may end after the next one
+        on its track began to land; the later span then begins where the
+        earlier ended (``flight_ms`` in its args stays what was
+        measured)."""
+        tid = f"r{rank}:wire:{peer}:{tag}:{name}"
+        t0 = max(t0, self._wire_end.get(tid, t0))
+        self._wire_end[tid] = t1 = max(t0, t1)
+        args.update(rank=rank, peer=peer, tag=tag)
+        current = getattr(self._ctx, "round", None)
+        if current is not None:
+            args["round"] = current
+        self.spans.append(WireSpan(name, tid, t0, t1, args))
+
+    def wire_meter(self, transport: Any, sched: Any) -> WireMeter:
+        return WireMeter(transport, sched)
+
+    def sleep(self, seconds: float) -> float:
+        """Sleep, and say how long it took (the scheduler's back-off:
+        the null recorder sleeps as long and says 0.0)."""
+        t0 = time.monotonic()
+        time.sleep(seconds)
+        return time.monotonic() - t0
 
     def clock(self) -> float:
         """The clock the spans are stamped with, for a wait that has to
@@ -390,6 +546,20 @@ class NullRecorder:
 
     def end_when_ready(self, span, result) -> None:
         pass
+
+    def seen_ready(self, span) -> None:
+        pass
+
+    def wire(self, name: str, rank: int, peer: int, tag: int,
+             t0: float, t1: float, **args) -> None:
+        pass
+
+    def wire_meter(self, transport: Any, sched: Any) -> NullMeter:
+        return NULL_METER
+
+    def sleep(self, seconds: float) -> float:
+        time.sleep(seconds)
+        return 0.0
 
     def clock(self) -> float:
         return 0.0

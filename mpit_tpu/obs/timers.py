@@ -1,6 +1,6 @@
 """Per-phase wall-clock timers + jax.profiler hooks (moved here from
 ``mpit_tpu/utils/timers.py`` when observability unified under
-``mpit_tpu.obs``; that module re-exports for back-compat).
+``mpit_tpu.obs``; ``mpit_tpu.utils`` re-exports the names).
 
 The reference tracks phase times in ad-hoc tables — ``tm.feval``/
 ``tm.sync`` in the MNIST trainer (reference asyncsgd/goot.lua:20-22,

@@ -11,6 +11,11 @@ readable by Perfetto (https://ui.perfetto.dev) and chrome://tracing:
   peer, epoch, seq; end args carry the outcome and retry count) with
   their phases as nested ``X`` complete events (``GRAD.encode``,
   ``GRAD.send``, ...); task lifecycles emit one ``X`` each;
+- the shm wire's spans (category ``wire``, names ``tx`` and ``rx``, one
+  a message of 1 MB or more and end) emit a ``B``/``E`` pair on a
+  track of their own, ``r<rank>:wire:<peer>:<tag>:<tx|rx>``, at the
+  native transport's own stamps; their args tile the message's flight
+  (obs/spans.py, *Wire spans*);
 - timestamps are wall-clock microseconds (monotonic span times shifted
   by the recorder's captured epoch offset), so per-rank part files
   merge onto a single timeline.  ``otherData.ranks[<rank>]`` carries
@@ -81,7 +86,7 @@ def chrome_events(recorder, pid: int, label: str = "",
     for sp in list(recorder.spans):
         t = tid_of(sp.tid)
         events.append({
-            "ph": "B", "name": sp.name, "cat": "ps_op", "pid": pid,
+            "ph": "B", "name": sp.name, "cat": sp.cat, "pid": pid,
             "tid": t, "ts": us(sp.t0),
             "args": {k: v for k, v in sp.args.items()},
         })
@@ -107,7 +112,7 @@ def chrome_events(recorder, pid: int, label: str = "",
         if sp.cpu_us is not None:
             end_args["cpu_us"] = sp.cpu_us
         events.append({
-            "ph": "E", "name": sp.name, "cat": "ps_op", "pid": pid,
+            "ph": "E", "name": sp.name, "cat": sp.cat, "pid": pid,
             "tid": t, "ts": us(sp.t1), "args": end_args,
         })
     for name, t0, t1, state, cpu_us in list(recorder.tasks):

@@ -4,7 +4,11 @@
 (a gradient or a delta) goes device → host mirror → servers, fresh
 parameters come servers → host mirror → device.  :func:`push_pull` is
 that round, recorded as one ``round`` span whose phases tile it
-(docs/OBSERVABILITY.md, *The round's span tree*):
+(docs/OBSERVABILITY.md, *The round's span tree*; while recording the
+span also carries what the client's thread did on the wire during
+``exchange``: ``wire_tx_copy_ms``, ``wire_rx_copy_ms``, ``wire_poll_ms``
+and ``sched_sleep_ms``, and what is left of the phase is the
+interpreter's):
 
 ``wait_backward`` → ``d2h`` → ``stage`` → ``exchange`` → ``h2d`` →
 ``telemetry``
@@ -114,11 +118,14 @@ def _exchange(opt: Any, span: Any) -> None:
     span.mark("exchange")
     plain = not opt._spans.enabled  # obs off: a plain timer at this boundary
     t0 = time.monotonic() if plain else 0.0
+    meter = opt._wire_meter
+    meter.start()
     opt.pc.async_send_grad()
     opt.pc.async_recv_param()
     opt.pc.wait()
     if plain:
         opt.sync_seconds += time.monotonic() - t0
+    meter.note(span)  # what the client's thread did on the wire meanwhile
     span.mark("h2d")
 
 
@@ -339,6 +346,10 @@ def attach(opt: Any) -> None:
     stream.bind(cut or [_Whole(0, opt.grad_host.size)])
     stream.m_streamed = get_registry().counter(
         "mpit_round_streamed_total", rank=getattr(opt.pc, "rank", None))
+    # What the client's one thread does on the wire during ``exchange``
+    # (obs/spans.py ``WireMeter``; the null one while obs is off).
+    opt._wire_meter = opt._spans.wire_meter(
+        getattr(opt.pc, "transport", None), getattr(opt.pc, "sched", None))
 
 
 def note_stats(opt: Any, span: Any, stats: Dict[str, jnp.ndarray]) -> None:

@@ -388,6 +388,13 @@ class ParamServer:
         # recorder when obs is off: no clock reads).
         self.metrics = registry_or_local()
         self._spans = get_recorder()
+        # While recording: what this server's thread did on the wire and
+        # asleep, noted on each GRAD and PARAM op span as it closes
+        # (obs/spans.py ``WireMeter``), and the ``apply_exec`` span last
+        # handed to the recorder's waiter (the null span while obs is off,
+        # when nothing reads it).
+        self._wire_meter = self._spans.wire_meter(self.transport, self.sched)
+        self._exec_pending: Any = None
         _m, _r = self.metrics, rank
         self._m_grads = _m.counter("mpit_ps_grads_applied_total", rank=_r)
         self._m_inplace = _m.counter("mpit_ps_apply_inplace_total", rank=_r)
@@ -613,10 +620,15 @@ class ParamServer:
         its own (``wait_apply``) before the PARAM span's ``snapshot``,
         which would otherwise hide that wait inside its host copy.  The
         snapshot blocks on the same result a moment later, so nothing
-        is served later for it."""
+        is served later for it.  The wait's end is also the end of that
+        apply's ``apply_exec`` span, stamped on time: the waiter's own
+        stamp waits for the interpreter lock this thread holds."""
         if self._spans.enabled:
             span.mark("wait_apply")
             jax.block_until_ready(self.param)
+            if self._exec_pending is not None:
+                self._spans.seen_ready(self._exec_pending)
+                self._exec_pending = None
 
     # -- codec + FT negotiation ---------------------------------------------
 
@@ -1363,6 +1375,7 @@ class ParamServer:
                 if self._spans.enabled:
                     self._spans.end_when_ready(exec_span,
                                                self._apply_token())
+                    self._exec_pending = exec_span
                 self._m_grads.inc()
                 self._committed()
             if not self.live.on:
@@ -1743,6 +1756,7 @@ class ParamServer:
                 # next apply (:meth:`_release_snapshot`).
                 del snapshot
                 self._m_served.inc()
+                self._wire_meter.note(span)
                 span.end("served")
                 continue
             epoch, seq = int(req[0]), int(req[1])
@@ -1786,6 +1800,7 @@ class ParamServer:
                 abort=self._svc_abort(crank, gen),
             )
             self._m_served.inc()
+            self._wire_meter.note(span)
             span.end("served")
 
     # -- serving tier: READ-ONLY readers + admission control (§8) ------------
@@ -2328,6 +2343,7 @@ class ParamServer:
                 self._m_inplace.inc(int(inplace))
                 exec_span.note(inplace=int(inplace))
             self._spans.end_when_ready(exec_span, token)
+            self._exec_pending = exec_span
             self._m_grads.inc()
             self._committed()
             if not self.live.on:
@@ -2342,6 +2358,7 @@ class ParamServer:
                     self.transport, tags.EMPTY, crank, tags.GRAD_ACK,
                     live=self.live, abort=self._svc_abort(crank, gen),
                 )
+            self._wire_meter.note(span)
             span.end("applied")
 
     # -- shardctl services: shard-addressed ops over the versioned map -------

@@ -344,3 +344,60 @@ class TestAioRecvPostsEarly:
         assert calls == (["irecv", "isend"] if with_out
                          else ["isend", "irecv"])
         assert bytes(recv.result) == bytes(range(4))
+
+
+def idle_rounds(sched, passes):
+    """``passes`` passes over a queue whose one task never finishes:
+    each ends in the back-off sleep."""
+
+    def forever():
+        while True:
+            yield EXEC
+
+    sched.spawn(forever())
+    for _ in range(passes):
+        assert not sched.ping_pass()
+
+
+class TestBackoffIsMeasured:
+    """``ping_pass``'s back-off sleep is timed by the span recorder and
+    only while it records (PR 34): ``Scheduler.sleep_s`` and
+    ``mpit_aio_idle_seconds_total`` are what the sleeps took, not the
+    ``idle_usec`` they were asked for."""
+
+    def test_recording_counts_what_the_sleeps_took(self):
+        from mpit_tpu import obs
+
+        obs.configure(enabled=True, reset=True)
+        try:
+            sched = Scheduler(idle_usec=2000)
+            t0 = time.monotonic()
+            idle_rounds(sched, 10)
+            wall = time.monotonic() - t0
+            # a sleep is never shorter than asked and the passes
+            # themselves are next to nothing
+            assert 10 * 2000e-6 <= sched.sleep_s <= wall
+            assert sched.sleep_s >= 0.9 * wall
+            counter = obs.get_registry().counter(
+                "mpit_aio_idle_seconds_total")
+            assert counter.value == pytest.approx(sched.sleep_s)
+            assert sched._idle_accum == pytest.approx(sched.sleep_s)
+        finally:
+            obs.configure(enabled=None, reset=True)
+
+    def test_off_sleeps_as_long_and_reads_no_clock(self, monkeypatch):
+        from mpit_tpu.aio import scheduler as scheduler_mod
+        from mpit_tpu.obs import spans as obs_spans
+
+        sched = Scheduler(idle_usec=2000)
+        assert sched._rec is obs_spans.NULL_RECORDER
+        reads = []
+        real = time.monotonic
+        slept = []
+        monkeypatch.setattr(obs_spans.time, "monotonic",
+                            lambda: reads.append(1) or real())
+        monkeypatch.setattr(scheduler_mod.time, "sleep", slept.append)
+        idle_rounds(sched, 10)
+        assert slept == [2000e-6] * 10
+        assert not reads and sched.sleep_s == 0.0
+        assert sched._idle_accum == 0.0

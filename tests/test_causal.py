@@ -592,3 +592,101 @@ class TestTopColumns:
         assert row["send_queue"] == 7
         table = obs_top.render_table([row])
         assert "p99ms" in table and "sendq" in table
+
+
+# ---------------------------------------------------------------------------
+# the shm wire's own spans: tx joined to rx by (src, dst, msg_id)
+
+
+class TestWireJoin:
+    """``join_wire`` on real shm endpoints (PR 34): the wire's identity
+    is exact whichever way a message was received."""
+
+    RING = 1 << 20
+    BIG = 6 << 20
+
+    @pytest.fixture
+    def wires(self, obs_on):
+        import os
+
+        from mpit_tpu.comm.shm import ShmTransport
+
+        ns = f"t_wj_{os.getpid()}"
+        ends = [ShmTransport(ns, r, 3, ring_bytes=self.RING)
+                for r in range(3)]
+        yield ends
+        for t in ends:
+            t.close()
+
+    @staticmethod
+    def move(src, dst, tag, seed, receive):
+        data = np.random.default_rng(seed).integers(
+            0, 256, TestWireJoin.BIG, dtype=np.uint8)
+        send = src.isend(data, dst.rank, tag)
+        out = receive(src, dst, tag, send)
+        np.testing.assert_array_equal(out, data)
+
+    @staticmethod
+    def posted(src, dst, tag, send):
+        out = np.zeros(TestWireJoin.BIG, np.uint8)
+        recv = dst.irecv(src.rank, tag, out=out)
+        while not all([src.test(send), dst.test(recv)]):  # poll both
+            pass
+        return out
+
+    @staticmethod
+    def assembled(src, dst, tag, send):
+        while not dst.iprobe(src.rank, tag):  # whole before anyone asks
+            src.test(send)
+        return TestWireJoin.posted(src, dst, tag, send)
+
+    @staticmethod
+    def cancelled_then_reposted(src, dst, tag, send):
+        first = dst.irecv(src.rank, tag,
+                          out=np.zeros(TestWireJoin.BIG, np.uint8))
+        for _ in range(2):  # part of it lands in the first buffer
+            src.test(send)
+            assert not dst.test(first)
+        dst.cancel(first)
+        return TestWireJoin.posted(src, dst, tag, send)
+
+    def test_every_way_of_receiving_joins_end_to_end(self, wires, tmp_path):
+        a, b, c = wires
+        ways = [(a, b, self.posted, 1), (a, b, self.assembled, 0),
+                (b, a, self.cancelled_then_reposted, 0),
+                (c, a, self.posted, 1), (a, c, self.assembled, 0)]
+        for seed, (src, dst, receive, _direct) in enumerate(ways):
+            self.move(src, dst, 7, seed, receive)
+        path = str(tmp_path / "wire.json")
+        obs_trace.write_rank_trace(path, rank=0, role="gang")
+        stats = obs_trace.validate_trace(path)
+        assert stats["ops"] == 2 * len(ways)
+        events = obs_causal.load_trace(path)[0]
+        assert obs_causal.extract_spans(events) == []  # no op span is one
+        spans = obs_causal.extract_spans(events, cat="wire")
+        pairs, loose = obs_causal.join_wire(spans)
+        assert not loose and len(pairs) == len(ways)
+        # rank a sent three messages, b and c one each: msg_id alone
+        # would confuse them, (src, dst, msg_id) cannot
+        got = sorted((tx.args["rank"], rx.args["rank"], tx.args["msg_id"],
+                      rx.args["direct"]) for tx, rx in pairs)
+        assert got == sorted(
+            (src.rank, dst.rank, n, direct)
+            for (src, dst, _r, direct), n in zip(ways, (1, 2, 1, 1, 3)))
+        for tx, rx in pairs:
+            assert (tx.name, rx.name) == ("tx", "rx")
+            assert tx.args["msg_id"] == rx.args["msg_id"]
+            assert tx.args["bytes"] == rx.args["bytes"] == self.BIG
+            # published by the sender before the receiver had it whole
+            assert tx.t0 <= rx.t1 and tx.t1 <= rx.t1
+
+    def test_an_end_without_its_other_end_is_left_over(self, wires):
+        a, b, _c = wires
+        self.move(a, b, 7, 9, self.posted)
+        rec = obs.get_recorder()
+        lone = [sp for sp in rec.spans if sp.name == "tx"]
+        events = obs_trace.chrome_events(rec, pid=0)
+        spans = obs_causal.extract_spans(events, cat="wire")
+        pairs, loose = obs_causal.join_wire(
+            [s for s in spans if s.name == "tx"])
+        assert not pairs and len(loose) == len(lone) == 1

@@ -3,13 +3,16 @@ chunking path, and real multi-process runs (the mpirun-analog shape).
 """
 
 import os
+import struct
 import subprocess
 import sys
 import textwrap
+import time
 
 import numpy as np
 import pytest
 
+from mpit_tpu import obs
 from mpit_tpu.comm.shm import ShmTransport
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -710,6 +713,234 @@ class TestCopiesOverlap:
             assert counts["rx_chunks"] == 4 * 96 + 1
             assert counts["rx_overlap_chunks"] >= counts["rx_chunks"] // 4, counts
         finally:
+            b.close()
+
+
+RING = 1 << 20
+TIMED_BYTES = 8 * RING
+#: transport.cpp ``ChunkHeader``: src, tag, msg_id, chunk_idx, nchunks,
+#: chunk_bytes, total_bytes, pub_ns
+CHUNK_HEADER = struct.Struct("<iiQIIQQQ")
+
+
+def headers_in_ring(ns, owner, src, nranks=2, ring=RING):
+    """The headers of the chunks that lie published and undrained in the
+    ring ``src`` writes at ``owner``, read from the segment's file."""
+    with open(f"/dev/shm/mt_{ns}_r{owner}", "rb") as fh:
+        seg = fh.read()
+    index = 64 + src * 128  # kIndexOffset, one RingIndex a sender
+    head, = struct.unpack_from("<Q", seg, index)
+    tail, = struct.unpack_from("<Q", seg, index + 64)
+    data = ((64 + nranks * 128 + 4095) & ~4095) + src * ring
+    out = []
+    while tail < head:
+        raw = bytes(seg[data + (tail + i) % ring]
+                    for i in range(CHUNK_HEADER.size))
+        out.append(CHUNK_HEADER.unpack(raw))
+        tail += CHUNK_HEADER.size + out[-1][5]
+    return out
+
+
+@pytest.fixture
+def obs_on():
+    obs.configure(enabled=True, reset=True)
+    try:
+        yield obs.get_recorder()
+    finally:
+        obs.configure(enabled=None, reset=True)
+
+
+def wire_spans(rec):
+    return {sp.name: sp for sp in rec.spans if sp.cat == "wire"}
+
+
+def tiles(span, waited):
+    """A wire span's three parts over its flight."""
+    a = span.args
+    return (a["copy_ms"] + a[waited] + a["away_ms"]) / a["flight_ms"]
+
+
+class TestWireTiming:
+    """Where a message's time went (transport.cpp ``TxTiming`` /
+    ``RxTiming``, comm/shm.py ``_wire_span``): kept only while the span
+    recorder records, a record a message and end, tiled into copying,
+    blocked on a full ring or starved by an empty one, and away."""
+
+    def test_off_the_wire_reads_no_clock(self):
+        """Obs off (the default): every chunk's ``pub_ns`` is 0, a
+        finished op has no record, the endpoint's totals stay 0 and no
+        span is made."""
+        ns = f"t_toff_{os.getpid()}"
+        a, b = pair(ns, RING)
+        try:
+            data = noise(90, TIMED_BYTES)
+            out = np.zeros_like(data)
+            recv = b.lib.mt_irecv(b._ctx, 0, 4, out, out.nbytes)
+            send = a.lib.mt_isend(a._ctx, 1, 4, data, data.nbytes)
+            heads = headers_in_ring(ns, owner=1, src=0)
+            assert [h[3] for h in heads] == [0, 1, 2, 3]  # the ring, full
+            assert all(h[6] == TIMED_BYTES and h[7] == 0 for h in heads)
+            spin(lambda: a.lib.mt_test(a._ctx, send) == 1,
+                 lambda: b.lib.mt_test(b._ctx, recv) == 1)
+            np.testing.assert_array_equal(out, data)
+            record = np.full(12, 7, np.uint64)
+            assert a.lib.mt_op_timing(a._ctx, send, record) == 0
+            assert b.lib.mt_op_timing(b._ctx, recv, record) == 0
+            assert (record == 7).all()
+            zero = dict.fromkeys(("tx_copy", "rx_copy", "progress"), 0.0)
+            assert a.wire_totals() == b.wire_totals() == zero
+            assert not a._rec.enabled and a._rec.spans == ()
+        finally:
+            a.close()
+            b.close()
+
+    def test_on_both_ends_tile_the_flight(self, obs_on):
+        """An 8 MB message through a 1 MB ring: one ``tx`` and one ``rx``
+        span with the wire's own identity, every header stamped, and the
+        three parts of each end sum to its flight."""
+        ns = f"t_ton_{os.getpid()}"
+        a, b = pair(ns, RING)
+        try:
+            data = noise(91, TIMED_BYTES)
+            out = np.zeros_like(data)
+            hr = b.irecv(0, 4, out=out)
+            t0 = time.monotonic()
+            hs = a.isend(data, 1, 4)
+            heads = headers_in_ring(ns, owner=1, src=0)
+            assert heads and all(h[7] > t0 * 1e9 for h in heads)
+            assert [h[7] for h in heads] == sorted(h[7] for h in heads)
+            spin(lambda: a.test(hs), lambda: b.test(hr))
+            t1 = time.monotonic()
+            np.testing.assert_array_equal(out, data)
+            spans = wire_spans(obs_on)
+            tx, rx = spans["tx"], spans["rx"]
+            assert len(obs_on.spans) == 2
+            assert tx.tid == "r0:wire:1:4:tx" and rx.tid == "r1:wire:0:4:rx"
+            for span, me, peer in ((tx, 0, 1), (rx, 1, 0)):
+                args = span.args
+                assert (args["rank"], args["peer"], args["tag"]) == (
+                    me, peer, 4)
+                assert args["bytes"] == TIMED_BYTES and args["chunks"] == 33
+                assert t0 <= span.t0 <= span.t1 <= t1
+            assert tx.args["msg_id"] == rx.args["msg_id"] == 1
+            assert rx.args["direct"] == 1
+            assert tiles(tx, "blocked_ms") == pytest.approx(1.0, rel=0.01)
+            assert tiles(rx, "starved_ms") == pytest.approx(1.0, rel=0.01)
+            assert tx.args["flight_ms"] == pytest.approx(
+                (tx.t1 - tx.t0) * 1e3, rel=1e-6)
+            # first chunk published to message whole: the rx span opens
+            # when its copy-out begins, inside that flight
+            assert rx.args["flight_ms"] >= (rx.t1 - rx.t0) * 1e3
+            # the endpoint's totals are the same copies
+            assert a.wire_totals()["tx_copy"] == pytest.approx(
+                tx.args["copy_ms"] / 1e3, rel=1e-6)
+            assert b.wire_totals()["rx_copy"] == pytest.approx(
+                rx.args["copy_ms"] / 1e3, rel=1e-6)
+            for wire in (a, b):
+                totals = wire.wire_totals()
+                assert totals["progress"] >= (totals["tx_copy"]
+                                              + totals["rx_copy"])
+        finally:
+            a.close()
+            b.close()
+
+    def test_a_receiver_that_pauses_blocks_the_sender(self, obs_on):
+        """The ring fills, the owner sleeps 60 ms, then drains: the sender
+        reads that as ``blocked_ms`` (and counts the refused polls), the
+        owner as its own ``away_ms``; nobody starved."""
+        a, b = pair(f"t_tblk_{os.getpid()}", RING)
+        try:
+            data = noise(92, TIMED_BYTES)
+            out = np.zeros_like(data)
+            hr = b.irecv(0, 4, out=out)
+            hs = a.isend(data, 1, 4)
+            for _ in range(3):
+                assert not a.test(hs)
+            time.sleep(0.06)
+            spin(lambda: a.test(hs), lambda: b.test(hr))
+            spans = wire_spans(obs_on)
+            tx, rx = spans["tx"].args, spans["rx"].args
+            assert tx["blocked_ms"] >= 55 and tx["refused"] >= 3
+            assert rx["away_ms"] >= 55
+            assert tx["away_ms"] < 55 and rx["starved_ms"] < 55
+            assert a.ring_counters()["tx_ring_full"] >= tx["refused"]
+            assert tiles(spans["tx"], "blocked_ms") == pytest.approx(
+                1.0, rel=0.01)
+            assert tiles(spans["rx"], "starved_ms") == pytest.approx(
+                1.0, rel=0.01)
+        finally:
+            a.close()
+            b.close()
+
+    def test_a_sender_that_pauses_starves_the_receiver(self, obs_on):
+        """The owner drains what the ring held, the sender sleeps 60 ms
+        with the message partial and the ring empty: ``starved_ms`` on
+        the receiver, ``away_ms`` on the sender; the ring was never
+        found full after that."""
+        a, b = pair(f"t_tstv_{os.getpid()}", RING)
+        try:
+            data = noise(93, TIMED_BYTES)
+            out = np.zeros_like(data)
+            hr = b.irecv(0, 4, out=out)
+            hs = a.isend(data, 1, 4)
+            for _ in range(3):
+                assert not b.test(hr)  # the ring is empty after the first
+            time.sleep(0.06)
+            spin(lambda: a.test(hs), lambda: b.test(hr))
+            spans = wire_spans(obs_on)
+            tx, rx = spans["tx"].args, spans["rx"].args
+            assert rx["starved_ms"] >= 55 and tx["away_ms"] >= 55
+            assert tx["blocked_ms"] < 55 and rx["away_ms"] < 55
+            assert tiles(spans["tx"], "blocked_ms") == pytest.approx(
+                1.0, rel=0.01)
+            assert tiles(spans["rx"], "starved_ms") == pytest.approx(
+                1.0, rel=0.01)
+        finally:
+            a.close()
+            b.close()
+
+    @pytest.mark.parametrize("nbytes,spans", [((1 << 20) - 1, 0),
+                                              (1 << 20, 2)])
+    def test_only_messages_of_a_megabyte_get_a_span(self, obs_on, nbytes,
+                                                    spans):
+        """Acks and headers would be thousands of events a run: their
+        time stays in the endpoint's totals."""
+        a, b = pair(f"t_tmin_{nbytes}_{os.getpid()}", 16 * RING)
+        try:
+            data = noise(94, nbytes)
+            out = np.zeros_like(data)
+            hr = b.irecv(0, 4, out=out)
+            hs = a.isend(data, 1, 4)
+            spin(lambda: a.test(hs), lambda: b.test(hr))
+            assert len(obs_on.spans) == spans
+            assert a.wire_totals()["tx_copy"] > 0
+            assert b.wire_totals()["rx_copy"] > 0
+        finally:
+            a.close()
+            b.close()
+
+    def test_an_assembled_message_counts_its_hand_over(self, obs_on):
+        """No receive posted when the message arrives: it is assembled,
+        waits for its taker (``away_ms``), and the ``memcpy`` that hands
+        it over is copying too; ``direct`` says which way it went."""
+        a, b = pair(f"t_tasm_{os.getpid()}", RING)
+        try:
+            data = noise(95, TIMED_BYTES)
+            hs = a.isend(data, 1, 4)
+            spin(lambda: a.test(hs) or b.iprobe(0, 4),
+                 lambda: b.iprobe(0, 4))
+            time.sleep(0.06)
+            out = np.zeros_like(data)
+            hr = b.irecv(0, 4, out=out)
+            spin(lambda: a.test(hs), lambda: b.test(hr))
+            np.testing.assert_array_equal(out, data)
+            rx = wire_spans(obs_on)["rx"]
+            assert rx.args["direct"] == 0 and rx.args["away_ms"] >= 55
+            assert tiles(rx, "starved_ms") == pytest.approx(1.0, rel=0.01)
+            assert b.wire_totals()["rx_copy"] == pytest.approx(
+                rx.args["copy_ms"] / 1e3, rel=1e-6)
+        finally:
+            a.close()
             b.close()
 
 

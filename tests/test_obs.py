@@ -148,6 +148,7 @@ class TestDisabledPath:
         assert rec.op("GRAD", peer=1) is obs_spans.NULL_SPAN
         assert rec.task_begin("t") is None
         assert rec.open_ops() == []
+        assert rec.wire("rx", 0, 1, 2, 0.0, 1.0) is None and rec.spans == ()
         # the flight recorder is the shared null object too
         fl = obs_flight.get_flight()
         assert fl is obs_flight.NULL_FLIGHT
@@ -197,9 +198,17 @@ class TestDisabledPath:
         for _ in range(20_000):
             prof.step("t", prof.cpu_now())
             prof.sample(0)
+        # the wire's null paths (PR 34): no span, no meter, no record
+        meter = rec.wire_meter(None, None)
+        assert meter is obs_spans.NULL_METER and rec.sleep(0.0) == 0.0
+        for _ in range(20_000):
+            rec.wire("tx", 0, 1, 2, 0.0, 0.0, bytes=1 << 20)
+            meter.start()
+            meter.note(sp)
+            rec.seen_ready(sp)
         elapsed = time.perf_counter() - t0
         assert elapsed < 1.2, (
-            f"disabled-path overhead {elapsed:.3f}s for 260k ops — the "
+            f"disabled-path overhead {elapsed:.3f}s for 340k ops — the "
             "null objects are no longer no-ops")
 
     def test_configure_flips_and_restores(self):
@@ -303,11 +312,10 @@ class TestTimersFold:
     def test_utils_reexports_are_the_obs_objects(self):
         from mpit_tpu import utils
         from mpit_tpu.obs import timers as obs_timers
-        from mpit_tpu.utils import timers as utils_timers
 
-        assert utils_timers.PhaseTimers is obs_timers.PhaseTimers
+        assert utils.PhaseTimers is obs_timers.PhaseTimers
         assert utils.trace_annotation is obs_timers.trace_annotation
-        assert utils_timers.profiler_trace is obs_timers.profiler_trace
+        assert utils.profiler_trace is obs_timers.profiler_trace
         assert obs.PhaseTimers is obs_timers.PhaseTimers
 
     def test_phase_timers_still_work(self):

@@ -707,3 +707,278 @@ def test_anchor_maps_monotonic_stamps_to_the_microsecond(traced_run):
     assert spantree.to_profiler_ns(two, 7.9) == pytest.approx(
         2_900_002_000.0, abs=1)
     assert spantree.drift_us(two) == pytest.approx(2.0)
+
+
+# -- apply_exec's end stamp (PR 34) --------------------------------------------
+
+
+@pytest.mark.parametrize("seen_first", [True, False])
+def test_apply_exec_ends_at_the_earlier_of_waiter_and_wait_apply(obs_on,
+                                                                  seen_first):
+    """The waiter's end stamp waits for the interpreter lock; a role
+    thread that waited on the same result says when it saw it ready, and
+    the span ends at the earlier of the two, whichever thread writes
+    last; ``end_from`` names the stamp that stands."""
+    rec = obs_on
+    span = rec.op("apply_exec", peer=1, side="server", rank=0)
+    span.mark("queued")
+    if seen_first:
+        # the role thread's stamp is there when the waiter ends the span
+        rec.end_when_ready(span, SlowResult(0, 0.2))
+        time.sleep(0.05)
+        rec.seen_ready(span)
+        seen = span.seen_ready
+        assert span.t1 is None
+        assert rec.drain(timeout=10)
+        assert span.args["end_from"] == "wait_apply"
+        # never before the span's last mark (``exec``, the waiter's own)
+        assert span.t1 == max(seen, span.marks[-1][1])
+        assert span.t1 - span.t0 < 0.15
+    else:
+        # the waiter was on time: a later look changes nothing
+        rec.end_when_ready(span, SlowResult(0, 0.01))
+        assert rec.drain(timeout=10)
+        ended = span.t1
+        rec.seen_ready(span)
+        assert span.t1 == ended and span.args["end_from"] == "waiter"
+    assert [p for p, _t in span.marks] == ["queued", "exec"]
+
+
+def test_a_pull_that_waits_on_the_apply_stamps_its_end(obs_on):
+    """The server thread's ``wait_apply`` ends when the apply is ready:
+    that instant is also ``apply_exec``'s end (``seen_ready``), so the
+    span cannot outlast the ``snapshot`` mark that follows the wait."""
+    rec = obs_on
+    with gang(1, 1) as ((_server,), (pc,)):
+        pc.start(np.zeros(SIZE, np.float32), np.zeros(SIZE, np.float32))
+        for _ in range(3):
+            pc.grad[:] = 1.0
+            pc.async_send_grad()
+            pc.async_recv_param()
+            pc.wait()
+        assert rec.drain(timeout=10)
+        pc.stop()
+    execs = [s for s in rec.spans if s.name == "apply_exec"]
+    pulls = [s for s in rec.spans if s.name == "PARAM"
+             and s.args["side"] == "server"]
+    assert len(execs) == len(pulls) == 3
+    for exec_span, pull in zip(execs, pulls):
+        assert exec_span.args["end_from"] in ("waiter", "wait_apply")
+        waited = dict(pull.marks)
+        assert exec_span.seen_ready is not None
+        assert waited["wait_apply"] <= exec_span.seen_ready <= (
+            waited["snapshot"])
+        assert exec_span.t1 <= waited["snapshot"]
+
+
+# -- the wire meter (PR 34) ----------------------------------------------------
+
+
+def test_wire_meter_notes_what_an_endpoint_did_between_two_looks(obs_on):
+    import os
+
+    from mpit_tpu.aio import EXEC, Scheduler
+    from mpit_tpu.comm.shm import ShmTransport
+
+    ns = f"t_meter_{os.getpid()}"
+    a, b = (ShmTransport(ns, r, 2, ring_bytes=1 << 20) for r in (0, 1))
+    try:
+        sched = Scheduler(idle_usec=1000)
+        meter = obs_on.wire_meter(a, sched)
+        data = np.arange(1 << 20, dtype=np.float32)
+        out = np.zeros_like(data)
+        recv, send = b.irecv(0, 4, out=out), a.isend(data, 1, 4)
+
+        def until_sent():
+            while not all([a.test(send), b.test(recv)]):
+                yield EXEC
+
+        def other():  # keeps the queue busy, so a pass backs off
+            while not send.done:
+                yield EXEC
+
+        sched.spawn(other())
+        task = sched.spawn(until_sent())
+        meter.start()
+        sched.wait_for(task)
+        span = obs_on.op("GRAD", peer=1, side="client", rank=0)
+        meter.note(span)
+        span.end()
+    finally:
+        a.close()
+        b.close()
+    got = {k: v for k, v in span.args.items()
+           if k.startswith(("wire_", "sched_"))}
+    assert sorted(got) == ["sched_sleep_ms", "wire_poll_ms",
+                           "wire_rx_copy_ms", "wire_span_ms",
+                           "wire_tx_copy_ms"]
+    assert got["wire_tx_copy_ms"] > 0 and got["wire_rx_copy_ms"] == 0
+    assert got["wire_poll_ms"] >= 0
+    assert got["sched_sleep_ms"] == pytest.approx(sched.sleep_s * 1e3)
+    assert got["wire_tx_copy_ms"] + got["wire_poll_ms"] + (
+        got["sched_sleep_ms"]) <= got["wire_span_ms"]
+    # a second note covers only what came after the first
+    meter.note(span)
+    assert span.args["wire_tx_copy_ms"] == 0
+    assert span.args["wire_span_ms"] < got["wire_span_ms"]
+
+
+def test_wire_meter_on_a_wire_without_totals_and_with_obs_off():
+    from mpit_tpu.aio import Scheduler
+    from mpit_tpu.obs import spans as obs_spans
+
+    assert obs_spans.NULL_RECORDER.wire_meter(None, None) is (
+        obs_spans.NULL_METER)
+    obs_spans.NULL_METER.start()
+    obs_spans.NULL_METER.note(obs_spans.NULL_SPAN)
+    obs.configure(enabled=True, reset=True)
+    try:
+        rec = obs.get_recorder()
+        meter = rec.wire_meter(LocalRouter(2).endpoint(0), Scheduler())
+        span = rec.op("GRAD", peer=1, side="server", rank=0)
+        meter.note(span)
+        assert span.args["sched_sleep_ms"] == 0.0
+        assert "wire_poll_ms" not in span.args
+        span.end()
+    finally:
+        obs.configure(enabled=None, reset=True)
+
+
+# -- a two-server gang over shm: the wire's spans and their readers (PR 34) ----
+
+WIRE_METRICS = ("wire_copy_ms_p50", "push_blocked_ms_p50",
+                "server_away_ms_p50", "pull_blocked_ms_p50",
+                "client_away_ms_p50")
+GANG_STEPS = 6
+
+
+@pytest.fixture(scope="module")
+def wire_gang_run(tmp_path_factory):
+    """One process gang on the CPU, servers 0 and 2 and worker 1 over
+    the shm wire, a 9.2 MB vector (4.99 and 4.23 MB shards), with
+    ``MPIT_OBS_TRACE``: the ``run`` a reader is given, windowed from the
+    second round on."""
+    import os
+
+    from mpit_tpu.train.launch import LAUNCH_DEFAULTS, launch_processes
+
+    tmp = tmp_path_factory.mktemp("wire_gang")
+    path = str(tmp / "obs_trace.json")
+    # The ranks' compiled programs go to a cache of the gang's own: what
+    # an LM gang leaves in the checkout's cache aborts jax's dispatch in
+    # tests/test_mesh_launch.py (.claude/skills/verify, Gotchas).
+    before = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    os.environ["JAX_COMPILATION_CACHE_DIR"] = str(tmp / "jax_cache")
+    os.environ["MPIT_OBS_TRACE"] = path
+    try:
+        cfg = LAUNCH_DEFAULTS.merged(
+            np=3, lm=1, lm_d_model=128, lm_heads=4, lm_layers=1, lm_seq=64,
+            lm_vocab=8192, batch=2, lm_steps=GANG_STEPS,
+            lm_eval_every=GANG_STEPS, opt="rmsprop", lr=1e-3,
+            device_policy="cpu")
+        results = launch_processes(cfg, timeout=600)
+    finally:
+        del os.environ["MPIT_OBS_TRACE"]
+        if before is None:
+            del os.environ["JAX_COMPILATION_CACHE_DIR"]
+        else:
+            os.environ["JAX_COMPILATION_CACHE_DIR"] = before
+    assert set(results) == {0, 1, 2}
+    run = {"obs_trace": path,
+           "results": {r: {"chipbench": {"marks": {}}} for r in results},
+           "summary": {"window": [0.0, float("inf")], "worker_ranks": [1]},
+           "reduction": {}}
+    tree = spantree.load(dict(run))
+    second = sorted(tree.rounds(), key=lambda r: r.t0)[1]
+    run["summary"]["window"][0] = tree.mono(second, second.t0) - 1e-6
+    return run
+
+
+def test_gang_every_windowed_round_has_its_eight_wire_spans(wire_gang_run):
+    from chipbench.layers import wiretree
+
+    obs_trace.validate_trace(wire_gang_run["obs_trace"])
+    wire = wiretree.load(wire_gang_run)
+    tree = wire.tree
+    assert len(wire.rounds) == GANG_STEPS - 1
+    assert wire.unmatched == 0
+    assert wiretree.tile_error_pct(wire) < 1.0
+    assert len(wire.messages) == 4 * len(wire.rounds)  # 8 ends a round
+    halves = {(c.name, c.args["round"], c.args["peer"]): (c, s)
+              for c, s, _a in spantree.round_ops(tree)}
+    eps = 1e-4  # s: the op's own marks are Python's, the wire's native
+    for op, k, tx, rx in wire.messages:
+        server = rx.pid if op == "GRAD" else tx.pid
+        client_op, server_op = halves[(op, k, server)]
+        mine, theirs = (tx, rx) if op == "GRAD" else (rx, tx)
+        assert mine.pid == 1 and theirs.pid == server
+        # the client's end lies inside the client's op span
+        assert tree.mono(client_op, client_op.t0) - eps <= (
+            tree.mono(mine, mine.t0))
+        assert tree.mono(mine, mine.t1) <= (
+            tree.mono(client_op, client_op.t1) + eps)
+        if op == "GRAD":
+            # the server has the frame before its GRAD span opens
+            assert tree.mono(theirs, theirs.t1) <= (
+                tree.mono(server_op, server_op.t0) + eps)
+        else:
+            # the server's copy into its ring is its ``send`` phase
+            send = next(ts for name, ts, _d in server_op.phases
+                        if name == "send")
+            assert tree.mono(server_op, send) - eps <= (
+                tree.mono(theirs, theirs.t0))
+            assert tree.mono(theirs, theirs.t1) <= (
+                tree.mono(server_op, server_op.t1) + eps)
+    by_round = {}
+    for _op, k, _tx, _rx in wire.messages:
+        by_round[k] = by_round.get(k, 0) + 2
+    assert set(by_round.values()) == {8}
+
+
+def test_gang_round_args_tile_the_exchange(wire_gang_run):
+    from chipbench.layers import wiretree
+
+    wire = wiretree.load(wire_gang_run)
+    for r in wire.rounds:
+        exchange = spantree.phase_ms(r, "exchange")
+        parts = [r.args[key] for key in wiretree.ROUND_ARGS]
+        assert all(p >= 0 for p in parts)
+        assert r.args["wire_tx_copy_ms"] > 0 and r.args["wire_rx_copy_ms"] > 0
+        assert sum(parts) <= exchange
+        assert r.args["wire_span_ms"] == pytest.approx(exchange, abs=0.5)
+    parts = wiretree.exchange_parts(wire)
+    assert parts["interpreter_ms"] >= 0
+    # the servers note the same deltas on their op spans
+    noted = [s for s in wire.tree.spans if s.side == "server"
+             and s.name in ("GRAD", "PARAM") and wire.tree.in_window(s)]
+    assert len(noted) == 4 * len(wire.rounds)
+    for s in noted:
+        assert sum(s.args[key] for key in wiretree.ROUND_ARGS) <= (
+            s.args["wire_span_ms"])
+
+
+@pytest.mark.parametrize("name", WIRE_METRICS)
+def test_gang_wire_reader_returns_a_number(wire_gang_run, name, capsys):
+    value = reader(name)(wire_gang_run)
+    assert isinstance(value, float) and value >= 0
+    printed = capsys.readouterr().out
+    if name == "wire_copy_ms_p50":
+        assert value > 0
+        lines = [ln for ln in printed.splitlines()
+                 if ln.startswith("chipbench: wire: ")]
+        # two ops x two servers x two ends, two servers x two ops, the
+        # client's exchange, and the check of the tracing itself
+        assert len(lines) == 8 + 4 + 1 + 1
+        assert "client exchange" in lines[-2] and "interpreter" in lines[-2]
+        assert "0 wire spans of the window without their other end" in (
+            lines[-1])
+    else:
+        assert printed == ""
+
+
+@pytest.mark.parametrize("name", WIRE_METRICS)
+def test_wire_reader_gives_none_where_no_transport_ran(traced_run, name):
+    """A local cell has no transport and the parent of PR 34 no wire
+    span: a merged trace without one, and no merged trace at all."""
+    assert reader(name)(dict(traced_run)) is None
+    assert reader(name)({**traced_run, "obs_trace": None}) is None

@@ -3,6 +3,7 @@
 import numpy as np
 import pytest
 
+from mpit_tpu.obs.timers import PhaseTimers
 from mpit_tpu.utils.config import Config
 from mpit_tpu.utils.serialize import (
     decode,
@@ -10,7 +11,6 @@ from mpit_tpu.utils.serialize import (
     encode_array,
     encode_object,
 )
-from mpit_tpu.utils.timers import PhaseTimers
 
 
 class TestConfig:
