@@ -35,6 +35,7 @@ ARCHS = ("gpt2", "olmoe", "mellum", "lfm2")
 # ``mpit_<name>``
 MOE_STATS = {"moe_load": "moe_load_max_over_mean",
              "moe_held": "moe_held_rows_share",
+             "moe_compact": "moe_compact_share",
              "moe_flips": "moe_bias_flips_share"}
 
 
@@ -50,7 +51,8 @@ class LmModel(NamedTuple):
     #: olmoe, mellum, lfm2: (w, tokens) -> ((loss, {name: device
     #: array}), grad), the same step with the block's own telemetry as
     #: an auxiliary output (``moe_load_max_over_mean``; from a block
-    #: that holds a share of its experts ``moe_held_rows_share``; from
+    #: that holds a share of its experts ``moe_held_rows_share`` and
+    #: ``moe_compact_share``; from
     #: one whose router has a selection bias ``moe_bias_flips_share``;
     #: one number a sparse layer each), which the optimizer fetches only
     #: while obs is on; None for a block that has none

@@ -251,9 +251,8 @@ class OlmoeBlock(nn.Module):
         wg = self.param("experts_gate", _INIT, (e, d, f))
         wu = self.param("experts_up", _INIT, (e, d, f))
         wd = self.param("experts_down", _INIT, (e, f, d))
-        y = moe.dispatch_top_k(
-            h, weights, experts, e,
-            lambda rows, sizes: moe.swiglu_experts(rows, sizes, wg, wu, wd))
+        y = moe.dispatch_top_k(h, weights, experts, e, moe.swiglu_experts,
+                               wg, wu, wd)
         return x + y.reshape(b, l, d)
 
 
@@ -406,8 +405,9 @@ def sparse_mlp(x: jnp.ndarray, norm: jnp.ndarray, router: jnp.ndarray,
     bias, over all ``n_experts`` whether held or not), and the held
     experts' part of the weighted sum by the sorted dropless dispatch
     (``parallel/moe.py``).  Returns the branch's output and its
-    statistics: the load's max over mean, the held rows' share, then
-    ``route``'s own.  Pure in its arguments, so a block wraps it in
+    statistics: the load's max over mean, the held rows' share, whether
+    the dispatch was done in one window of the held run (1.0 or 0.0),
+    then ``route``'s own.  Pure in its arguments, so a block wraps it in
     ``jax.checkpoint``."""
     b, l, d = x.shape
     wg, wu, wd = experts
@@ -416,11 +416,12 @@ def sparse_mlp(x: jnp.ndarray, norm: jnp.ndarray, router: jnp.ndarray,
         logits = jnp.matmul(h, router, precision=ROUTER_PRECISION)
         weights, chosen, extra = route(logits.astype(jnp.float32))
         stats = (moe.load_max_over_mean(chosen, n_experts),
-                 moe.held_rows_share(chosen, first, held), *extra)
+                 moe.held_rows_share(chosen, first, held),
+                 moe.takes_window(chosen, first, held, n_experts,
+                                  d).astype(jnp.float32), *extra)
     y = moe.dispatch_top_k(
-        h, weights, chosen, n_experts,
-        lambda rows, sizes: moe.swiglu_experts(
-            rows, sizes, wg, wu, wd, first if held < n_experts else None))
+        h, weights, chosen, n_experts, moe.swiglu_experts, wg, wu, wd,
+        held=(first, held) if held < n_experts else None)
     return y.reshape(b, l, d), stats
 
 
@@ -483,11 +484,15 @@ class MellumBlock(nn.Module):
         wd = self.param("experts_down", _INIT, (held, f, d))
         k_tok, eps = self.experts_per_tok, self.norm_eps
 
-        # The dropless dispatch has k T rows whatever the share holds;
-        # kept for the backward pass in every layer they would not fit
-        # beside the model at the sequence this block trains at, so the
-        # branch is computed again there and one layer's rows live at a
-        # time.
+        # A share's dispatch moves the held run of the k T sorted rows a
+        # window at a time, twice what uniform routing sends here, and
+        # keeps only its arguments for the backward pass, which walks
+        # the windows again (parallel/moe.py, *A window over the held
+        # run*).  The branch is still computed again as a whole: kept
+        # for the backward pass in every layer, the normed stream, the
+        # router's scores and, where a block holds half its experts and
+        # more, all k T rows would not fit beside the model at the
+        # sequence this block trains at.
         @jax.checkpoint
         def sparse(x, norm, router, wg, wu, wd):
             # top-k over all the experts, the k weights renormalised
@@ -499,11 +504,12 @@ class MellumBlock(nn.Module):
                     jax.nn.softmax(logits, axis=-1), k_tok,
                     renormalise=True), ()))
 
-        y, (load, share) = sparse(x, norm, router, wg, wu, wd)
+        y, (load, share, compact) = sparse(x, norm, router, wg, wu, wd)
         # telemetry, read only where the caller makes ``intermediates``
         # mutable (lm/model.py stats)
         self.sow("intermediates", "moe_load", load)
         self.sow("intermediates", "moe_held", share)
+        self.sow("intermediates", "moe_compact", compact)
         return x + y
 
 
@@ -680,7 +686,8 @@ class Lfm2Block(nn.Module):
         wd = self.param("experts_down", _INIT, (held, f, d))
 
         # recomputed in the backward pass, as Mellum's and for its
-        # reason: the dropless dispatch's k T rows a layer
+        # reason (the dispatch itself keeps only its arguments and
+        # walks its windows again)
         @jax.checkpoint
         def sparse(x, norm, router, bias, wg, wu, wd):
             def route(logits):
@@ -696,11 +703,13 @@ class Lfm2Block(nn.Module):
                 eps=self.norm_eps, n_experts=e, first=self.experts_first,
                 held=held)
 
-        y, (load, share, flips) = sparse(x, norm, router, bias, wg, wu, wd)
+        y, (load, share, compact, flips) = sparse(x, norm, router, bias,
+                                                  wg, wu, wd)
         # telemetry, read only where the caller makes ``intermediates``
         # mutable (lm/model.py stats)
         self.sow("intermediates", "moe_load", load)
         self.sow("intermediates", "moe_held", share)
+        self.sow("intermediates", "moe_compact", compact)
         self.sow("intermediates", "moe_flips", flips)
         return y
 

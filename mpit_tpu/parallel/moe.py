@@ -1,6 +1,6 @@
 """Mixture of experts: sorted dropless top-k dispatch on one device
-(:func:`dispatch_top_k`, what the OLMoE block of
-``models/transformer.py`` runs), the dense float32 oracle it is tested
+(:func:`dispatch_top_k`, what the sparse blocks of
+``models/transformer.py`` run), the dense float32 oracle it is tested
 against (:func:`moe_dense_reference`), and a Switch-style top-1 layer
 over an ``ep`` mesh axis (:func:`ep_moe`, with its own oracle
 :func:`moe_reference`).
@@ -13,20 +13,52 @@ only, ``k / E`` of the dense form's).  The group sizes are the router's
 counts over all ``E`` experts and the row count is ``k T``, static: no
 capacity, so no token ever loses an expert however uneven the routing.
 The results are scaled by the router's weights and summed back per
-token.  Both permutations are gathers in both directions (a
-permutation's transpose is its inverse), so the backward pass has no
-scatter.
+token.  With every expert here all ``k T`` rows are moved, and both
+permutations are gathers in both directions (a permutation's transpose
+is its inverse), so the backward pass has no scatter.
 
 **A share of the experts** (``held``).  Where the experts are spread
 over chips, one chip's layer is told which contiguous range it holds
 (``held = (first, count)``) and is handed those experts' matrices only.
 The router still scores and ranks all ``E``; the sort still orders all
-``k T`` rows by expert, so the held experts' rows are one run of them;
-the grouped product starts at group ``first`` and visits ``count``
-groups, so the rows of absent experts are computed by nobody, read as
-zero and pass zero back (:func:`grouped_dot`).  With everything held the
-groups sum to exactly ``k T``; with a share, to less.  Nothing here
-stands in for the absent chips or the exchange between them.
+``k T`` assignments by expert, so the held experts' rows are one run
+``[lo, hi)`` of them; the grouped product starts at a group offset and
+visits ``count`` groups, so the rows of absent experts are computed by
+nobody, read as zero and pass zero back (:func:`grouped_dot`).  With
+everything held the groups sum to exactly ``k T``; with a share, to
+less.  Nothing here stands in for the absent chips or the exchange
+between them.
+
+**A window over the held run.**  Of the ``k T`` sorted rows a share of
+``count / E`` holds about that share, so moving them all (gathered,
+rounded to bf16, gated, zeroed, unsorted and summed, forward, once more
+under the block's ``jax.checkpoint`` and backward) is memory traffic
+over rows that are zero by construction.  A chip that holds a share
+moves a static window of ``C`` rows at a time instead
+(:func:`held_window`: twice what uniform routing sends it, from the
+shapes alone, in whole row tiles of the kernels).  The integers are
+sorted as before; window ``i`` starts at ``min(lo + i C, k T - C)``:
+``x[order[start:start + C] // k]`` is gathered, the grouped products
+see the window's own ``count + 2`` groups (the rows before its piece of
+the run, each held expert's rows inside the piece, the rows after it)
+from group offset 1, so they visit the held row tiles and no others,
+and each row, scaled by its weight, is added to its token (a
+scatter-add of ``C`` rows; the gather's transpose is the same
+scatter-add and the sum's a gather of ``C``).  What bounds the window
+is the shapes, not the routing: the held run can be as long as ``k T``,
+so the number of windows, ``ceil((hi - lo) / C)``, is worked out on the
+device, a loop a layer (:func:`_held_dispatch`): one window wherever the
+routing is anywhere near even, four at worst at a share of an eighth,
+none where nothing is held.  Every held assignment is in exactly one
+window's piece, at the same precision whichever, so the dispatch stays
+dropless exactly and nothing on this path has ``k T`` rows but the
+integers; :func:`takes_window` says whether one window was enough.
+Where ``C >= k T`` (everything held, or a share of a half and more)
+there is no window and all rows are moved at once.  A loop whose length
+the device decides has no transpose: the vjp keeps the layer's
+arguments and walks the windows again, each through its own forward and
+backward, which is also what the block's ``jax.checkpoint`` would have
+it do; that stays around the router and the norm.
 
 **``ep_moe``** (not in the reference, SURVEY §2: EP absent).  TPU-native
 shape:
@@ -288,19 +320,10 @@ def swiglu_experts(rows: jnp.ndarray, group_sizes: jnp.ndarray,
     return grouped_dot(jax.nn.silu(gate) * up, wd, group_sizes, first)
 
 
-def dispatch_top_k(x: jnp.ndarray, weights: jnp.ndarray,
-                   experts: jnp.ndarray, n_experts: int,
-                   expert_fn: Callable[[jnp.ndarray, jnp.ndarray],
-                                       jnp.ndarray]) -> jnp.ndarray:
-    """``sum_j weights[t, j] * expert_{experts[t, j]}(x[t])`` for every
-    token, dropless.  ``x (T, d)``; ``weights``, ``experts (T, k)``;
-    ``expert_fn(rows, group_sizes)`` maps the ``T k`` rows sorted by
-    expert to their outputs (:func:`swiglu_experts`; one that holds a
-    share of the experts returns zero rows for the others, so those
-    assignments add nothing, forward and backward).  The sort, the
-    gathers and the weighted sum run under the scope ``dispatch``, the
-    experts under ``experts`` (flat, never nested: a trace books an
-    operation under the one scope of its name stack)."""
+def _sorted_dispatch(x, weights, experts, n_experts, expert_fn):
+    """All ``k T`` assignments sorted by expert, their rows gathered,
+    handed to ``expert_fn(rows, group_sizes)`` and summed back per
+    token."""
     t, k = experts.shape
     with jax.named_scope("dispatch"):
         order, inverse, group_sizes = sort_by_expert(experts, n_experts)
@@ -310,6 +333,170 @@ def dispatch_top_k(x: jnp.ndarray, weights: jnp.ndarray,
     with jax.named_scope("dispatch"):
         out = _unsort(out, order, inverse).reshape(t, k, -1)
         return jnp.einsum("tkd,tk->td", out, weights.astype(out.dtype))
+
+
+# -- a window over the held run ------------------------------------------------
+
+WINDOW_OVER_UNIFORM = 2  # the window's rows over the uniform expectation's
+
+
+def held_window(rows: int, width: int, count: int, n_experts: int) -> int:
+    """The rows of the window that a chip holding ``count`` of
+    ``n_experts`` experts moves of the ``rows = k T`` sorted assignments
+    of ``width`` floats: :data:`WINDOW_OVER_UNIFORM` times what uniform
+    routing sends it, in whole row tiles of the Pallas kernels wherever
+    they take ``rows`` (so they take the window too: :func:`pallas_fits`)
+    and in multiples of 8 elsewhere; ``rows`` where that is no fewer
+    (everything held, or a share of a half and more: no window)."""
+    tile = GMM_TILE_M if pallas_fits(rows, width, width) else 8
+    uniform = -(-rows * count // n_experts)
+    return min(rows, -(-WINDOW_OVER_UNIFORM * uniform // tile) * tile)
+
+
+def takes_window(experts: jnp.ndarray, first: int, count: int,
+                 n_experts: int, width: int) -> jnp.ndarray:
+    """Bool scalar: whether :func:`dispatch_top_k` with ``held=(first,
+    count)`` is done with these ``(T, k)`` assignments in one window:
+    there is one (:func:`held_window`) and the held experts' rows fit
+    in it."""
+    window = held_window(experts.size, width, count, n_experts)
+    if window >= experts.size:
+        return jnp.zeros((), bool)
+    held = (experts >= first) & (experts < first + count)
+    return jnp.sum(held) <= window
+
+
+def _held_run(n_experts, held, x, weights, experts):
+    """The held run of the sorted assignments of ``experts (T, k)`` cut
+    into windows: ``(n, take)``.  ``n`` windows cover the run, none where
+    nothing is held.  ``take(i) -> (tokens, assigned, sizes, rows,
+    scale)`` is window ``i``: the sorted assignments from ``min(lo + i
+    C, k T - C)`` on, their tokens, rows of ``x`` and weights, and
+    their ``count + 2`` groups: the rows before the window's piece of
+    the run (another expert's, or a piece an earlier window took), each
+    held expert's rows inside the piece, the rows after it."""
+    first, count = held
+    k = experts.shape[1]
+    flat = experts.reshape(-1)
+    window = held_window(flat.size, x.shape[-1], count, n_experts)
+    with jax.named_scope("dispatch"):
+        order = jnp.argsort(flat, stable=True)
+        # where each held expert's rows start among the sorted ones and
+        # where the last one's end: the assignments on experts before it
+        bounds = jnp.sum(
+            flat[:, None] < jnp.arange(first, first + count + 1), axis=0,
+            dtype=jnp.int32)
+        starts, ends = bounds[:-1], bounds[1:]
+        lo, hi = bounds[0], bounds[-1]
+
+    def take(i):
+        with jax.named_scope("dispatch"):
+            piece_lo = lo + i * window
+            piece_hi = jnp.minimum(hi, piece_lo + window)
+            start = jnp.minimum(piece_lo, flat.size - window)
+            inside = jnp.maximum(0, jnp.minimum(ends, piece_hi)
+                                 - jnp.maximum(starts, piece_lo))
+            sizes = jnp.concatenate([(piece_lo - start)[None], inside,
+                                     (start + window - piece_hi)[None]])
+            assigned = jax.lax.dynamic_slice(order, (start,), (window,))
+            tokens = assigned // k
+            return (tokens, assigned, sizes, x[tokens],
+                    weights.reshape(-1)[assigned])
+
+    return (hi - lo + window - 1) // window, take
+
+
+def _held_terms(expert_fn, rows, scale, sizes, params):
+    """A window's rows through the held experts (groups 1 to ``count``
+    of its ``count + 2``; the other rows come back zero), each scaled by
+    its assignment's weight."""
+    with jax.named_scope("experts"):
+        out = expert_fn(rows, sizes, *params, 1)
+    with jax.named_scope("dispatch"):
+        return out * scale[:, None].astype(out.dtype)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _held_dispatch(n_experts, expert_fn, held, x, weights, experts, params):
+    """The held experts' part of the sum, a window of the sorted
+    assignments at a time: each window's rows are gathered, go through
+    ``expert_fn(rows, group_sizes, *params, 1)`` and are added, scaled,
+    to their tokens, in a loop of as many windows as the held run takes
+    (one, wherever the routing is anywhere near even).  Nothing but the
+    sort of the integers has ``k T`` rows.  A loop of a length the
+    device decides has no transpose, so the vjp keeps the arguments and
+    walks the windows again, each through its own forward and
+    backward."""
+    n, take = _held_run(n_experts, held, x, weights, experts)
+
+    def add(i, y):
+        tokens, _assigned, sizes, rows, scale = take(i)
+        terms = _held_terms(expert_fn, rows, scale, sizes, params)
+        with jax.named_scope("dispatch"):
+            return y.at[tokens].add(terms)
+
+    return jax.lax.fori_loop(0, n, add, jnp.zeros(x.shape, jnp.float32))
+
+
+def _held_dispatch_fwd(n_experts, expert_fn, held, x, weights, experts,
+                       params):
+    return (_held_dispatch(n_experts, expert_fn, held, x, weights, experts,
+                           params), (x, weights, experts, params))
+
+
+def _held_dispatch_bwd(n_experts, expert_fn, held, res, g):
+    x, weights, experts, params = res
+    n, take = _held_run(n_experts, held, x, weights, experts)
+
+    def add(i, grads):
+        d_x, d_weights, d_params = grads
+        tokens, assigned, sizes, rows, scale = take(i)
+        _terms, back = jax.vjp(
+            lambda rows, scale, params: _held_terms(
+                expert_fn, rows, scale, sizes, params), rows, scale, params)
+        with jax.named_scope("dispatch"):
+            g_rows = g[tokens]
+        d_rows, d_scale, d_window = back(g_rows)  # under its own scopes
+        with jax.named_scope("dispatch"):
+            return (d_x.at[tokens].add(d_rows),
+                    d_weights.at[assigned].add(d_scale, unique_indices=True),
+                    jax.tree.map(jnp.add, d_params, d_window))
+
+    zeros = jax.tree.map(jnp.zeros_like, (x, weights.reshape(-1), params))
+    d_x, d_weights, d_params = jax.lax.fori_loop(0, n, add, zeros)
+    return d_x, d_weights.reshape(weights.shape), None, d_params
+
+
+_held_dispatch.defvjp(_held_dispatch_fwd, _held_dispatch_bwd)
+
+
+def dispatch_top_k(x: jnp.ndarray, weights: jnp.ndarray,
+                   experts: jnp.ndarray, n_experts: int,
+                   expert_fn: Callable[..., jnp.ndarray], *params,
+                   held: Optional[Tuple[int, int]] = None) -> jnp.ndarray:
+    """``sum_j weights[t, j] * expert_{experts[t, j]}(x[t])`` for every
+    token, dropless.  ``x (T, d)``; ``weights``, ``experts (T, k)``;
+    ``expert_fn(rows, group_sizes, *params)`` maps rows sorted by expert
+    to their outputs (:func:`swiglu_experts` with its three matrices as
+    ``params``).  ``held`` None: every expert is here, all ``T k`` rows
+    are moved.  ``held = (first, count)``: ``params`` hold the experts
+    ``first .. first + count - 1`` only, ``expert_fn(rows, group_sizes,
+    *params, at)`` takes the group its matrices start at and returns
+    zero rows for the other groups, and the sum is the held experts'
+    part, worked out a window of the sorted rows at a time where the
+    shapes give a window, from all of them at once otherwise (the
+    module's docstring, *A window over the held run*).  The sort, the
+    gathers and the weighted sum run under the scope ``dispatch``, the
+    experts under ``experts`` (flat, never nested: a trace books an
+    operation under the one scope of its name stack)."""
+    if held is not None and held_window(
+            experts.size, x.shape[-1], held[1], n_experts) < experts.size:
+        return _held_dispatch(n_experts, expert_fn, tuple(held), x, weights,
+                              experts, params)
+    at = () if held is None else (held[0],)
+    return _sorted_dispatch(
+        x, weights, experts, n_experts,
+        lambda rows, sizes: expert_fn(rows, sizes, *params, *at))
 
 
 def load_max_over_mean(experts: jnp.ndarray, n_experts: int) -> jnp.ndarray:

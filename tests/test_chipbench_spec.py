@@ -272,7 +272,7 @@ def test_mellums_readers_find_nothing_in_a_run_without_the_block():
            "summary": {"worker_ranks": [1], "window": [0.0, 1.0]}}
     for name in ("attn_window_ms_per_step", "attn_window_roofline",
                  "held_experts_ms_per_step", "held_experts_roofline",
-                 "held_rows_share_pct"):
+                 "held_rows_share_pct", "compact_dispatch_pct"):
         reader = spec_mod.load_reader(cell.root, cell.bench, name)
         assert reader is not None and reader(dict(run)) is None
 
@@ -437,6 +437,38 @@ def test_lfm2s_readers_find_nothing_in_a_run_without_the_block():
                        "conv_mix_roofline", "router_bias_flips_pct"):
             reader = spec_mod.load_reader(cell.root, cell.bench, metric)
             assert reader is not None and reader(dict(run)) is None
+
+
+@pytest.mark.parametrize("rounds,want", [
+    ([[1.0] * 4] * 3, 100.0),                      # every layer, every step
+    ([[1.0] * 4, [1.0, 0.0, 1.0, 1.0], [0.0] * 4], 100.0 * 7 / 12),
+    ([[]] * 3, None),                   # a block that holds everything
+    ([None] * 3, None)])                # a program that has no such counter
+def test_compact_dispatch_pct_is_the_mean_over_layers_and_rounds(rounds,
+                                                                 want):
+    """In both cells that list it: the share of (layer, step) pairs that
+    went through the window; None where the rounds carry no such arg, as
+    the parent's do."""
+    from chipbench.layers import spantree
+
+    class Round:
+        def __init__(self, compact):
+            self.args = {"moe_held_rows_share": [0.125] * 4}
+            if compact is not None:
+                self.args["moe_compact_share"] = compact
+
+    class Tree:
+        def rounds(self):
+            return [Round(r) for r in rounds]
+
+    for name in (MELLUM_CELL, LFM2_CELL):
+        cell = spec_mod.load_cell(name)
+        assert "compact_dispatch_pct" in [
+            m["name"] for m in cell.metrics("per_layer")]
+        got = spec_mod.load_reader(cell.root, cell.bench,
+                                   "compact_dispatch_pct")(
+            {"cell": cell, spantree.CACHE_KEY: Tree()})
+        assert got == (want if want is None else pytest.approx(want))
 
 
 def test_lfm2s_readers_read_a_hand_made_run(monkeypatch):

@@ -581,6 +581,21 @@ LAUNCH = dict(
 SPARSE_LAYERS = 4
 
 
+def _tiny_window(batch):
+    """The window's share of the ``k T`` rows at the ``tiny`` size."""
+    rows = CONFIG["num_experts_per_tok"] * batch * CONFIG["train_seq"]
+    return moe.held_window(rows, CONFIG["hidden_size"], CONFIG["num_experts"],
+                           CONFIG["router_experts"]) / rows
+
+
+@pytest.mark.parametrize("batch", [1, 2])
+def test_the_tiny_size_has_a_window_smaller_than_its_rows(batch):
+    """Twice the uniform quarter: the tests of this file compile and
+    differentiate the dispatch's loop over windows through the whole
+    block, and a held run can outgrow one."""
+    assert _tiny_window(batch) == 0.5
+
+
 @pytest.fixture
 def obs_on():
     obs.configure(enabled=True, reset=True)
@@ -597,8 +612,12 @@ def _counters_on_round_spans(recorder):
         load = span.args["moe_load_max_over_mean"]
         share = span.args["moe_held_rows_share"]
         flips = span.args["moe_bias_flips_share"]
+        compact = span.args["moe_compact_share"]
         # one entry a sparse layer: the dense layer has none
-        assert len(load) == len(share) == len(flips) == SPARSE_LAYERS
+        assert len(load) == len(share) == len(flips) == len(compact) \
+            == SPARSE_LAYERS
+        # in one window exactly where the held run fits in it
+        assert compact == [float(x <= _tiny_window(2)) for x in share]
         assert all(1.0 <= x <= 4.0 for x in load)     # 8 experts, 2 a token
         assert all(0.0 < x < 1.0 for x in share)
         assert all(0.0 < x < 1.0 for x in flips)

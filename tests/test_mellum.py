@@ -534,6 +534,21 @@ def test_the_grouped_products_tiling(k, n, tiling):
     assert k % tiling[1] == 0 and n % tiling[2] == 0
 
 
+def _tiny_window(batch):
+    """The window's share of the ``k T`` rows at the ``tiny`` size."""
+    rows = CONFIG["num_experts_per_tok"] * batch * CONFIG["train_seq"]
+    return moe.held_window(rows, CONFIG["hidden_size"], CONFIG["num_experts"],
+                           CONFIG["router_experts"]) / rows
+
+
+@pytest.mark.parametrize("batch", [1, 2])
+def test_the_tiny_size_has_a_window_smaller_than_its_rows(batch):
+    """Twice the uniform quarter: the tests of this file compile and
+    differentiate the dispatch's loop over windows through the whole
+    block, and a held run can outgrow one."""
+    assert _tiny_window(batch) == 0.5
+
+
 def test_pallas_fits_wants_whole_row_tiles_and_lanes():
     assert not moe.pallas_fits(100, 2304, 896)
     assert not moe.pallas_fits(256, 2300, 896)
@@ -564,9 +579,12 @@ def _counters_on_round_spans(recorder, layers):
     for span in rounds:
         load = span.args["moe_load_max_over_mean"]
         share = span.args["moe_held_rows_share"]
-        assert len(load) == len(share) == layers
+        compact = span.args["moe_compact_share"]
+        assert len(load) == len(share) == len(compact) == layers
         assert all(1.0 <= x <= 4.0 for x in load)     # 8 experts, 2 a token
         assert all(0.0 < x < 1.0 for x in share)
+        # in one window exactly where the held run fits in it
+        assert compact == [float(x <= _tiny_window(2)) for x in share]
     reg = obs.get_registry()
     assert reg.gauge("mpit_moe_held_rows_share", layer=layers - 1).value == \
         rounds[-1].args["moe_held_rows_share"][-1]

@@ -145,3 +145,202 @@ def test_the_grouped_product_is_each_groups_rows_times_its_matrix(sizes):
     (256, 2300, 896, False)])
 def test_pallas_takes_whole_tiles_only(m, k, n, fits):
     assert moe.pallas_fits(m, k, n) is fits
+
+
+# -- a window over the held run (PR 35) -----------------------------------------
+#
+# One chip's share of the experts: 2 of 16, top-2 of 32 tokens, so 64
+# sorted assignments of which uniform routing sends 8 here and a window
+# takes 16: a held run is done in one window to four.  The routing is
+# made by hand, so that the run has exactly the length a case names.
+
+W_T, W_K, W_E, W_HELD, W_D = 32, 2, 16, 2, 16
+W_ROWS = W_T * W_K
+W_C = 16
+
+
+def forced_probs(first, n_held, seed=0):
+    """Router probabilities whose top-2 put exactly ``n_held`` of the 64
+    assignments on the experts ``first, first + 1``: the first tokens
+    choose both, then one token one of them, the rest neither."""
+    rs = np.random.RandomState(seed)
+    logits = rs.uniform(-1.0, 1.0, (W_T, W_E))
+    others = [e for e in range(W_E) if not first <= e < first + W_HELD]
+    for t in range(W_T):
+        n = min(2, n_held - 2 * t) if n_held > 2 * t else 0
+        want = [first, first + 1][:n] + list(
+            rs.permutation(others)[:W_K - n])
+        logits[t, want] += 10.0
+        logits[t, [first, first + 1][n:]] -= 10.0
+    return jax.nn.softmax(jnp.asarray(logits, jnp.float32), axis=-1)
+
+
+def held_layer(first, seed=5):
+    """Inputs and all sixteen experts' matrices; ``cut`` are the held."""
+    x = jax.random.normal(jax.random.PRNGKey(seed), (W_T, W_D))
+    return x, weights(seed, d=W_D, e=W_E), slice(first, first + W_HELD)
+
+
+def held_system(x, probs, mats, first, windowed=True):
+    """The share's part of the layer: a window at a time where
+    ``windowed``, else by the path a caller without windows takes (the
+    experts' function bound to its offset, ``held`` not given: all ``k
+    T`` rows at once)."""
+    w, chosen = moe.route_top_k(probs, W_K)
+    if windowed:
+        return moe.dispatch_top_k(x, w, chosen, W_E, moe.swiglu_experts,
+                                  *mats, held=(first, W_HELD))
+    return moe.dispatch_top_k(
+        x, w, chosen, W_E,
+        lambda rows, sizes: moe.swiglu_experts(rows, sizes, *mats, first))
+
+
+def held_oracle(x, probs, wg, wu, wd, first):
+    """The dense oracle with every absent expert's output zero."""
+    keep = (jnp.arange(W_E) >= first) & (jnp.arange(W_E) < first + W_HELD)
+    return moe.moe_dense_reference(
+        x, probs, wg, wu, jnp.where(keep[:, None, None], wd, 0.0), W_K)
+
+
+# the held run's length: well under the window, the window exactly, one
+# row more (a second window for one row), three windows with the split
+# between the two experts inside the second, every assignment (four
+# windows, nothing lost), none (no window at all)
+RUNS = {"under": 5, "full": W_C, "over": W_C + 1, "three": 2 * W_C + 7,
+        "all": W_ROWS, "none": 0}
+
+
+def test_the_window_is_twice_the_uniform_expectation_in_whole_tiles():
+    assert moe.held_window(W_ROWS, W_D, W_HELD, W_E) == W_C
+    # the two cells' shapes: whole row tiles of the Pallas kernels
+    assert moe.held_window(8 * 8192, 2304, 8, 64) == 16384
+    assert moe.held_window(4 * 8192, 2048, 8, 64) == 8192
+    assert moe.pallas_fits(16384, 2304, 896)
+    # rounded up to a tile where the kernels take the rows, to 8 elsewhere
+    assert moe.held_window(4096, 128, 3, 64) == 512
+    assert moe.held_window(4096, 100, 3, 64) == 384
+    # everything held, or a half and more: no window
+    assert moe.held_window(W_ROWS, W_D, W_E, W_E) == W_ROWS
+    assert moe.held_window(W_ROWS, W_D, 8, W_E) == W_ROWS
+
+
+@pytest.mark.parametrize("run", sorted(RUNS))
+@pytest.mark.parametrize("first", [0, 7, 14])
+def test_windowed_dispatch_equals_the_dense_oracle(first, run):
+    """Forward, for a held range first, in the middle and last among
+    the experts (the last clamps a window's start), in one window and
+    in several; the counter says which."""
+    probs = forced_probs(first, RUNS[run], seed=first)
+    x, (wg, wu, wd), cut = held_layer(first)
+    _w, chosen = moe.route_top_k(probs, W_K)
+    _order, _inv, sizes = moe.sort_by_expert(chosen, W_E)
+    assert int(sizes[cut].sum()) == RUNS[run]
+    assert bool(moe.takes_window(chosen, first, W_HELD, W_E, W_D)) is (
+        RUNS[run] <= W_C)
+    mats = (wg[cut], wu[cut], wd[cut])
+    got = jax.jit(held_system, static_argnums=(3, 4))(x, probs, mats, first)
+    np.testing.assert_allclose(
+        got, held_oracle(x, probs, wg, wu, wd, first), atol=ATOL)
+    np.testing.assert_allclose(
+        got, held_system(x, probs, mats, first, windowed=False), atol=ATOL)
+    if run == "none":
+        assert not np.asarray(got).any()
+
+
+@pytest.mark.parametrize("run", sorted(RUNS))
+@pytest.mark.parametrize("first", [0, 7, 14])
+def test_windowed_dispatch_gradients_equal_the_dense_oracles(first, run):
+    """Of the tokens, the router's probabilities and the held matrices,
+    against the oracle and against the path without windows."""
+    probs = forced_probs(first, RUNS[run], seed=first)
+    x, (wg, wu, wd), cut = held_layer(first)
+    cot = jax.random.normal(jax.random.PRNGKey(8), (W_T, W_D))
+
+    def grads(fn):
+        return jax.jit(jax.grad(
+            lambda x, probs, mats: jnp.sum(cot * fn(x, probs, mats)),
+            argnums=(0, 1, 2)))(x, probs, (wg[cut], wu[cut], wd[cut]))
+
+    def oracle(x, probs, mats):
+        whole = [w.at[cut].set(m) for w, m in zip((wg, wu, wd), mats)]
+        return held_oracle(x, probs, *whole, first)
+
+    got = jax.tree.leaves(grads(
+        lambda x, probs, mats: held_system(x, probs, mats, first)))
+    plain = jax.tree.leaves(grads(
+        lambda x, probs, mats: held_system(x, probs, mats, first, False)))
+    for g, p, want in zip(got, plain, jax.tree.leaves(grads(oracle))):
+        scale = float(jnp.max(jnp.abs(want))) or 1.0
+        np.testing.assert_allclose(g / scale, want / scale, atol=ATOL)
+        np.testing.assert_allclose(g / scale, p / scale, atol=ATOL)
+
+
+def _equations(jaxpr):
+    """Every equation of ``jaxpr`` and of what it calls."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _equations(sub)
+
+
+def test_a_share_moves_no_array_of_all_the_sorted_rows():
+    """Differentiated under the block's ``jax.checkpoint``, the share's
+    layer holds no float array of ``k T`` rows anywhere, forward or
+    backward (the sort's integers are all that long), and the compiled
+    step has one loop over the windows a pass (the recomputation's,
+    whose result nothing reads, is gone)."""
+    probs = forced_probs(7, 5)
+    x, (wg, wu, wd), cut = held_layer(7)
+    args = (x, probs, (wg[cut], wu[cut], wd[cut]))
+
+    def loss(x, probs, mats):
+        return jnp.sum(held_system(x, probs, mats, 7) ** 2)
+
+    grad = jax.grad(jax.checkpoint(loss), argnums=(0, 2))
+    eqns = list(_equations(jax.make_jaxpr(grad)(*args).jaxpr))
+    assert sum(e.primitive.name == "while" for e in eqns) == 3
+    wide = [v.aval.shape for e in eqns for v in e.outvars
+            if getattr(v.aval, "ndim", 0) >= 2 and v.aval.shape[0] == W_ROWS
+            and jnp.issubdtype(v.aval.dtype, jnp.floating)]
+    assert wide == []
+    compiled = jax.jit(grad).lower(*args).compile().as_text()
+    assert compiled.count(" while(") == 2
+
+
+def test_a_share_of_a_half_has_no_window():
+    """Where the window would be no smaller than ``k T`` all the rows
+    are moved at once, as without a share: no loop."""
+    probs = random_probs(1, W_T, W_E)
+    x, (wg, wu, wd), _cut = held_layer(0)
+    w, chosen = moe.route_top_k(probs, W_K)
+    jaxpr = jax.make_jaxpr(lambda x: moe.dispatch_top_k(
+        x, w, chosen, W_E, moe.swiglu_experts, wg[:8], wu[:8], wd[:8],
+        held=(0, 8)))(x).jaxpr
+    assert not any(e.primitive.name == "while" for e in _equations(jaxpr))
+    assert not bool(moe.takes_window(chosen, 0, 8, W_E, W_D))
+
+
+def test_the_windows_scopes_stay_flat_forward_and_backward():
+    """No operation of the share's layer is under both ``dispatch`` and
+    ``experts``: the benchmark books a kernel under the one model scope
+    of its name stack, and one under two counts for no family."""
+    probs = forced_probs(7, 5)
+    x, (wg, wu, wd), cut = held_layer(7)
+
+    def loss(x, probs, mats):
+        return jnp.sum(held_system(x, probs, mats, 7) ** 2)
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 2)))(
+        x, probs, (wg[cut], wu[cut], wd[cut])).jaxpr
+
+    def stacks(jaxpr, outer=""):
+        for eqn in jaxpr.eqns:
+            stack = f"{outer}/{eqn.source_info.name_stack}"
+            yield stack
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from stacks(sub, stack)
+
+    seen = set(stacks(jaxpr))
+    assert any("experts" in s for s in seen)
+    assert any("dispatch" in s for s in seen)
+    assert not [s for s in seen if "experts" in s and "dispatch" in s]
