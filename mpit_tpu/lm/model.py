@@ -3,11 +3,13 @@
 Assembles one of the decoders of :mod:`mpit_tpu.models.transformer`
 (``arch``: ``gpt2`` is :class:`TinyDecoder`, ``olmoe``
 :class:`OlmoeDecoder`, ``mellum`` :class:`MellumDecoder`, ``lfm2``
-:class:`Lfm2Decoder`; each one's attention is the ``ops/`` flash kernel
+:class:`Lfm2Decoder`, ``ouro`` :class:`OuroDecoder`; each one's
+attention is the ``ops/`` flash kernel
 on TPU and the jnp reference — which differentiates without a recompute
 pass — elsewhere) into the flat-vector calling convention the parameter server shards: a
 :class:`~mpit_tpu.models.flat.FlatModel` plus a next-token NLL over
-packed token grids, and the params+optimizer pytree
+packed token grids (``ouro`` closes its own loss over its passes' heads
+and exit gates), and the params+optimizer pytree
 (:func:`train_state_tree`) that :mod:`mpit_tpu.lm.plan` drives the
 partition rules over.
 """
@@ -25,11 +27,12 @@ from mpit_tpu.models.transformer import (
     Lfm2Decoder,
     MellumDecoder,
     OlmoeDecoder,
+    OuroDecoder,
     TinyDecoder,
     default_attn,
 )
 
-ARCHS = ("gpt2", "olmoe", "mellum", "lfm2")
+ARCHS = ("gpt2", "olmoe", "mellum", "lfm2", "ouro")
 # what a sparse layer ``sow``s, and the name of each in the step's
 # telemetry (``value_grad_stats``), on the round span and as the gauge
 # ``mpit_<name>``
@@ -55,7 +58,10 @@ class LmModel(NamedTuple):
     #: ``moe_compact_share``; from
     #: one whose router has a selection bias ``moe_bias_flips_share``;
     #: one number a sparse layer each), which the optimizer fetches only
-    #: while obs is on; None for a block that has none
+    #: while obs is on; ouro: the loop's three counters
+    #: (``loop_exit_step_mean``, ``loop_loss_drop``,
+    #: ``loop_exit_entropy``, one number each); None for a block that
+    #: has none
     value_grad_stats: Optional[Callable[..., Any]] = None
 
 
@@ -85,6 +91,9 @@ MELLUM_KEYS = ("kv_heads", "head_dim", "experts_first", "experts_held",
 # lfm2's own
 LFM2_KEYS = ("layer_types", "dense_layers", "dense_width", "conv_kernel",
              "route_scale")
+# ouro's own (it takes ``kv_heads``, ``head_dim`` and ``dense_width``
+# too)
+OURO_KEYS = ("loop_steps", "exit_beta", "exit_bias")
 
 
 def build_kw(cfg: Any) -> dict:
@@ -95,7 +104,7 @@ def build_kw(cfg: Any) -> dict:
     kw = {key: cfg[key] for key in (
         "arch", "d_model", "n_heads", "n_layers", "seq_len", "seed",
         "n_experts", "experts_per_tok", "expert_width", "rope_theta",
-        "norm_eps", *MELLUM_KEYS, *LFM2_KEYS)}
+        "norm_eps", *MELLUM_KEYS, *LFM2_KEYS, *OURO_KEYS)}
     if int(cfg.vocab):
         kw["vocab"] = int(cfg.vocab)
     return kw
@@ -113,7 +122,9 @@ def build(*, arch: str = "gpt2", vocab: int = 256, d_model: int = 64,
           yarn_beta_slow: float = 1.0,
           yarn_attn_factor: float = 1.0, layer_types: str = "",
           dense_layers: int = 0, dense_width: int = 0,
-          conv_kernel: int = 3, route_scale: float = 1.0) -> LmModel:
+          conv_kernel: int = 3, route_scale: float = 1.0,
+          loop_steps: int = 4, exit_beta: float = 0.1,
+          exit_bias: float = 0.0) -> LmModel:
     """Build the decoder, flatten its params, and close over the
     next-token NLL.  ``arch`` chooses the block; the expert, rotary and
     norm sizes are the sparse blocks' alone, and those from
@@ -128,12 +139,32 @@ def build(*, arch: str = "gpt2", vocab: int = 256, d_model: int = 64,
     or ``full_attention``, comma-separated; how many of them, the
     first, have the dense MLP of ``dense_width`` and not the sparse one;
     the short convolution's taps; the router's
-    ``routed_scaling_factor``.  For ``gpt2`` ``max_len`` is pinned to ``seq_len``
+    ``routed_scaling_factor``.  ``ouro`` takes ``kv_heads``,
+    ``head_dim`` and ``dense_width`` (its one MLP's) as they do, and on
+    its own ``loop_steps``, how often the ``n_layers`` layers are applied
+    with the same weights, ``exit_beta``, the weight of the exit
+    distribution's entropy in its loss, and ``exit_bias``, the value the
+    exit gate's bias is seeded at (0: a gate of a half; negative: the
+    loop starts nearer to running every pass); the loss is the block's own
+    (:class:`OuroDecoder`), not the next-token NLL of one head.  For
+    ``gpt2`` ``max_len`` is pinned to ``seq_len``
     — the packed stream always fills full sequences, and an exact fit
     keeps the position table out of the sharding slack (the other
     blocks' positions are rotary: no table)."""
     if arch not in ARCHS:
         raise ValueError(f"unknown LM arch {arch!r}; have {ARCHS}")
+    if arch == "ouro":
+        if loop_steps < 1:
+            raise ValueError(f"loop_steps {loop_steps}: at least one pass")
+        module: Any = OuroDecoder(
+            vocab=vocab, d_model=d_model, n_heads=n_heads,
+            kv_heads=kv_heads or n_heads,
+            head_dim=head_dim or d_model // n_heads, n_layers=n_layers,
+            dense_width=dense_width, loop_steps=loop_steps,
+            exit_beta=float(exit_beta), exit_bias=float(exit_bias),
+            rope_theta=rope_theta,
+            norm_eps=norm_eps, attn_fn=_resolve_attn(use_flash))
+        return _own_loss(module, seed, seq_len, vocab)
     if arch in ("mellum", "lfm2"):
         held = experts_held or n_experts
         if experts_first + held > n_experts:
@@ -146,7 +177,7 @@ def build(*, arch: str = "gpt2", vocab: int = 256, d_model: int = 64,
         if len(kinds) != n_layers:
             raise ValueError(f"layer_types names {len(kinds)} layers "
                              f"({layer_types!r}), n_layers is {n_layers}")
-        module: Any = Lfm2Decoder(
+        module = Lfm2Decoder(
             vocab=vocab, d_model=d_model, n_heads=n_heads,
             kv_heads=kv_heads or n_heads,
             head_dim=head_dim or d_model // n_heads, layer_types=kinds,
@@ -227,6 +258,29 @@ def build(*, arch: str = "gpt2", vocab: int = 256, d_model: int = 64,
                    value_and_grad=jax.value_and_grad(loss),
                    seq_len=seq_len, vocab=vocab,
                    value_grad_stats=value_grad_stats)
+
+
+def _own_loss(module: Any, seed: int, seq_len: int, vocab: int) -> LmModel:
+    """The :class:`LmModel` of a decoder that closes its own loss:
+    ``module(inputs, targets) -> (loss, {name: device scalar})``.  The
+    statistics are the step's telemetry as they come; initialised on 16
+    positions, as the other rotary blocks."""
+    sample = jnp.zeros((1, 16), jnp.int32)
+    fm = FlatModel(module, module.init(jax.random.PRNGKey(seed), sample,
+                                       sample)["params"])
+
+    def loss_and_stats(w, tokens):
+        # tokens: (B, seq_len + 1) int32 — packed, every cell real.
+        return fm.apply_flat(w, tokens[:, :-1], tokens[:, 1:])
+
+    def loss(w, tokens):
+        return loss_and_stats(w, tokens)[0]
+
+    return LmModel(module=module, flat=fm, loss=loss,
+                   value_and_grad=jax.value_and_grad(loss),
+                   seq_len=seq_len, vocab=vocab,
+                   value_grad_stats=jax.value_and_grad(loss_and_stats,
+                                                       has_aux=True))
 
 
 def train_state_tree(params: Any, rule_name: str = "adam") -> Any:

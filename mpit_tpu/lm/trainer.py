@@ -82,6 +82,13 @@ LM_DEFAULTS = Config(
     dense_width=0,
     conv_kernel=3,
     route_scale=1.0,
+    # ouro's own (it takes kv_heads, head_dim and dense_width too): how
+    # often the stack of layers is applied with the same weights, the
+    # weight of the exit distribution's entropy in its loss, and the
+    # value the exit gate's bias is seeded at
+    loop_steps=4,
+    exit_beta=0.1,
+    exit_bias=0.0,
     # -1 auto (flash on TPU, jnp reference elsewhere) | 0 reference |
     # 1 the Mosaic-compiled kernel or an error (lm/model.py _resolve_attn)
     use_flash=-1,

@@ -15,7 +15,7 @@ long-context showcase built on the framework's own kernels:
   batched over (B, L);
 - pre-LN blocks, learned positional embeddings, causal by default.
 
-Four decoders: :class:`TinyDecoder` (GPT-2's: LayerNorm, learned
+Five decoders: :class:`TinyDecoder` (GPT-2's: LayerNorm, learned
 positions, GELU MLP), :class:`OlmoeDecoder` (OLMoE's: RMSNorm, rotary
 positions, query/key norm, top-k of E gated experts),
 :class:`MellumDecoder` (Mellum 2's: grouped KV heads of their own
@@ -25,8 +25,10 @@ experts) and :class:`Lfm2Decoder` (LFM2's: a layer's token mixer a
 gated short convolution or grouped-head attention with a per-head
 query/key norm, its MLP dense or a share of sparse experts behind a
 sigmoid router with a selection bias, both read from the
-configuration layer by layer), chosen by ``lm/model.py``
-``build(arch=...)``.
+configuration layer by layer) and :class:`OuroDecoder` (Ouro's: the
+whole stack applied several times with the same weights, sandwich
+norms, a head and an exit gate at every pass, and a loss of its own
+over them), chosen by ``lm/model.py`` ``build(arch=...)``.
 """
 
 from __future__ import annotations
@@ -348,6 +350,13 @@ def yarn_inv_freq(head: int, theta: float, factor: float, original: int,
     return ((1.0 - ramp) * plain + ramp * plain / factor).astype(np.float32)
 
 
+def plain_inv_freq(head: int, theta: float) -> np.ndarray:
+    """The plain rotary table's per-pair inverse frequencies,
+    ``theta^(-2j/head)`` for pair ``j`` of ``head / 2``."""
+    return (theta ** (-np.arange(0, head, 2, dtype=np.float64) / head)
+            ).astype(np.float32)
+
+
 def rope_by(x: jnp.ndarray, inv_freq: np.ndarray,
             scale: float = 1.0) -> jnp.ndarray:
     """:func:`rope` with the inverse frequencies given and ``cos``,
@@ -451,9 +460,7 @@ class MellumBlock(nn.Module):
         attn = self.attn_fn if self.attn_fn is not None else default_attn()
         ones = nn.initializers.ones
         if self.window or self.yarn is None:  # the plain rotary table
-            inv_freq, scale = (self.rope_theta ** (
-                -np.arange(0, hd, 2, dtype=np.float64) / hd)
-            ).astype(np.float32), 1.0
+            inv_freq, scale = plain_inv_freq(hd, self.rope_theta), 1.0
         else:
             inv_freq = yarn_inv_freq(hd, self.rope_theta, *self.yarn[:4])
             scale = float(self.yarn[4])
@@ -657,8 +664,7 @@ class Lfm2Block(nn.Module):
         d, hq, hkv, hd = (self.d_model, self.n_heads, self.kv_heads,
                           self.head_dim)
         attn = self.attn_fn if self.attn_fn is not None else default_attn()
-        inv_freq = (self.rope_theta ** (
-            -np.arange(0, hd, 2, dtype=np.float64) / hd)).astype(np.float32)
+        inv_freq = plain_inv_freq(hd, self.rope_theta)
         ones = nn.initializers.ones
         with jax.named_scope("attn"):
             return x + grouped_attention(
@@ -767,3 +773,213 @@ class Lfm2Decoder(nn.Module):
                                        (d,)), self.norm_eps)
             logits = x @ self.param("head", _INIT, (d, self.vocab))
             return nn.log_softmax(logits)
+
+
+# ---------------------------------------------------------------------------
+# The looped block (Ouro, ByteDance; ``model_type`` ``ouro``; the
+# configuration's keys are those of its ``config.json``).  The whole
+# stack of layers is applied ``loop_steps`` times **with the same
+# weights**: a pass ends in the one final RMSNorm, and its output goes to
+# the head, to an **exit gate** and on into the next pass.  Every layer
+# is a **sandwich**: an RMSNorm before the sublayer and another on its
+# output, ``u = u + rms(op(rms(u)))``, for the attention (as many KV as
+# query heads, rotary, no query/key norm, no bias) and for the SiLU-gated
+# MLP alike.  The block closes its own loss: each pass's next-token NLL
+# weighted by the probability of leaving at that pass, less ``exit_beta``
+# times the entropy of that distribution (:func:`exit_distribution`).
+# The passes are one ``lax.scan`` body in the program, the parameters
+# closed over, so the step is compiled once and not ``loop_steps`` times
+# and the backward pass sums a weight's gradient over its applications
+# inside a ``while``.  The plain float32 reference it is held to is
+# ``chipbench/reference/ouro_plain.py``, which shares no code with this
+# file (tests/test_ouro.py).
+# ---------------------------------------------------------------------------
+
+
+def exit_distribution(lam: jnp.ndarray) -> jnp.ndarray:
+    """The probability of leaving at each of ``R`` passes from the
+    ``R - 1`` gates ``lam (R - 1, ...)``, in float32: ``p_1 = lam_1``,
+    ``p_t = lam_t prod_{j<t} (1 - lam_j)``, and the last pass takes what
+    is left, ``p_R = prod_{j<R} (1 - lam_j)``; ``(R, ...)``, summing to
+    one over the passes.  No gate: ``p = (1)``."""
+    ones = jnp.ones((1,) + lam.shape[1:], lam.dtype)
+    # stay[t]: past the gates of the passes before pass t + 1
+    stay = jnp.concatenate([ones, jnp.cumprod(1.0 - lam, axis=0)], axis=0)
+    return jnp.concatenate([lam * stay[:-1], stay[-1:]], axis=0)
+
+
+def exit_entropy(p: jnp.ndarray) -> jnp.ndarray:
+    """``H(p) = -sum_t p_t log p_t`` over the leading axis.  A gate that
+    saturates gives a ``p_t`` of exactly 0, whose term and whose
+    gradient are taken as 0 (``xlogy``'s gradient there is ``0 x
+    -inf``)."""
+    live = p > 0
+    return -jnp.sum(jnp.where(live, p * jnp.log(jnp.where(live, p, 1.0)),
+                              0.0), axis=0)
+
+
+class OuroBlock(nn.Module):
+    """One layer's parameters, and the layer as a pure function of
+    them (:meth:`apply_weights`): the decoder applies it inside
+    ``lax.scan`` and ``jax.checkpoint``, where no module may be
+    called."""
+
+    d_model: int
+    n_heads: int
+    kv_heads: int
+    head_dim: int
+    dense_width: int
+    rope_theta: float = 1000000.0
+    norm_eps: float = 1e-6
+    attn_fn: Optional[AttnFn] = None
+
+    def setup(self):
+        d, f = self.d_model, self.dense_width
+        hq, hkv = self.n_heads * self.head_dim, self.kv_heads * self.head_dim
+        ones = nn.initializers.ones
+        self.weights = {name: self.param(name, init, shape) for
+                        name, init, shape in (
+            ("attn_norm", ones, (d,)), ("wq", _INIT, (d, hq)),
+            ("wk", _INIT, (d, hkv)), ("wv", _INIT, (d, hkv)),
+            ("wo", _INIT, (hq, d)), ("attn_out_norm", ones, (d,)),
+            ("mlp_norm", ones, (d,)), ("w_gate", _INIT, (d, f)),
+            ("w_up", _INIT, (d, f)), ("w_down", _INIT, (f, d)),
+            ("mlp_out_norm", ones, (d,)))}
+
+    def apply_weights(self, u: jnp.ndarray, p: dict) -> jnp.ndarray:
+        """The layer on the stream ``u (B, L, d)`` with the weights
+        ``p``; pure in both.  Products one bf16 pass on a TPU (the
+        scores are O(1) without a query/key norm, as Mellum's), norms
+        in float32."""
+        eps, hd = self.norm_eps, self.head_dim
+        attn = self.attn_fn if self.attn_fn is not None else default_attn()
+        with jax.named_scope("attn"):
+            a = grouped_attention(
+                rms_norm(u, p["attn_norm"], eps), p["wq"], p["wk"], p["wv"],
+                p["wo"], heads=self.n_heads, kv_heads=self.kv_heads,
+                head_dim=hd, attn=attn,
+                inv_freq=plain_inv_freq(hd, self.rope_theta))
+            u = u + rms_norm(a, p["attn_out_norm"], eps)
+        with jax.named_scope("mlp"):
+            b = rms_norm(u, p["mlp_norm"], eps)
+            m = (jax.nn.silu(b @ p["w_gate"]) * (b @ p["w_up"])) @ p["w_down"]
+            return u + rms_norm(m, p["mlp_out_norm"], eps)
+
+
+class OuroDecoder(nn.Module):
+    """Causal LM of :class:`OuroBlock` layers run ``loop_steps`` times
+    with the same weights: a token table, the passes, and at the end of
+    every pass the one final RMSNorm, the one untied head and the exit
+    gate (``Linear(d_model -> 1)`` and a sigmoid; the last pass has
+    none: it takes what is left).  Unlike the other decoders it is
+    called with the targets and returns its own loss, a scalar, with
+    the loop's statistics (``lm/model.py`` closes over it):
+
+    - ``loss``: the mean over positions of ``sum_t p_t nll^t - exit_beta
+      H(p)``, ``p`` the exit distribution and ``nll^t`` pass ``t``'s
+      next-token negative log-likelihood;
+    - ``loop_exit_step_mean``: the mean of ``sum_t t p_t`` (``loop_steps``
+      when every position runs all passes);
+    - ``loop_loss_drop``: mean ``nll^1`` less mean ``nll^R``, in nats:
+      what the later passes buy;
+    - ``loop_exit_entropy``: the mean of ``H(p)``, at most ``ln R``.
+
+    ``exit_bias`` is what the gate's bias is seeded at (its weight at
+    std 0.02): 0 starts every gate at a half, ``p = (1/2, 1/4, 1/8,
+    1/8)`` of four passes; a negative one starts the loop nearer to
+    running every pass, with a gate that moves slower by ``lam (1 -
+    lam)``.  Under plain SGD the gate's logit moves by ``lr * |h|^2 =
+    lr * d_model`` a unit of difference between the passes' losses, so a
+    gate seeded at a half saturates onto the first pass before the later
+    ones have learnt anything (PERF.md section 6, PR 36).
+
+    ``scan`` and ``remat`` are the tests' alone: no caller in the
+    program sets them.  ``scan`` False unrolls the passes and ``remat``
+    False keeps every activation for the backward pass, the twin that
+    tests/test_ouro.py holds the scanned, recomputing decoder to (the
+    same numbers to float32 rounding), at a memory and compile time
+    that the cell's sizes do not have.  With ``remat`` each layer
+    application and each pass's norm, head and loss is computed again in
+    the backward pass: kept are the layers' inputs and the passes'
+    outputs, never a ``(positions, vocab)`` array of an earlier pass."""
+
+    vocab: int = 256
+    d_model: int = 64
+    n_heads: int = 4
+    kv_heads: int = 4
+    head_dim: int = 16
+    n_layers: int = 2
+    dense_width: int = 128
+    loop_steps: int = 4
+    exit_beta: float = 0.1
+    exit_bias: float = 0.0
+    rope_theta: float = 1000000.0
+    norm_eps: float = 1e-6
+    attn_fn: Optional[AttnFn] = None
+    scan: bool = True
+    remat: bool = True
+
+    @nn.compact
+    def __call__(self, tokens: jnp.ndarray, targets: jnp.ndarray):
+        d, eps, steps = self.d_model, self.norm_eps, self.loop_steps
+        embed = self.param("embed", _INIT, (self.vocab, d))
+        blocks = [OuroBlock(
+            d_model=d, n_heads=self.n_heads, kv_heads=self.kv_heads,
+            head_dim=self.head_dim, dense_width=self.dense_width,
+            rope_theta=self.rope_theta, norm_eps=eps, attn_fn=self.attn_fn)
+            for _ in range(self.n_layers)]
+        layers = [(block.apply_weights, block.weights) for block in blocks]
+        final_norm = self.param("final_norm", nn.initializers.ones, (d,))
+        head = self.param("head", _INIT, (d, self.vocab))
+        # named to sort last among the leaves: the bias's one element is
+        # then the flat vector's tail, and every other leaf starts on a
+        # whole lane as in the other blocks
+        gate_w = self.param("loop_gate", _INIT, (d,))
+        gate_b = self.param("loop_gate_bias",
+                            nn.initializers.constant(self.exit_bias), (1,))
+        keep = jax.checkpoint if self.remat else (lambda fn: fn)
+
+        def pass_end(u, final_norm, head):
+            with jax.named_scope("head_loss"):
+                h = rms_norm(u, final_norm, eps)
+                logp = nn.log_softmax(h @ head)
+                nll = -jnp.take_along_axis(logp, targets[..., None],
+                                           axis=-1)[..., 0]
+            return h, nll
+
+        def one_pass(h, _):
+            for layer, weights in layers:
+                h = keep(layer)(h, weights)
+            h, nll = keep(pass_end)(h, final_norm, head)
+            return h, (h, nll)
+
+        with jax.named_scope("embed"):
+            h = embed[tokens]
+        if self.scan:
+            _, (ends, nll) = jax.lax.scan(one_pass, h, None, length=steps)
+        else:
+            rows = []
+            for _ in range(steps):
+                h, row = one_pass(h, None)
+                rows.append(row)
+            ends, nll = (jnp.stack(x) for x in zip(*rows))
+        with jax.named_scope("exit_gate"):
+            # The gate reads the passes' outputs after the loop, which
+            # keeps them for the backward pass anyway: its product is
+            # then this scope's own operation and not a corner of the
+            # head's fusions.  2048 multiply-adds a position and pass,
+            # elementwise in float32, no MXU pass; the last pass has no
+            # gate.
+            lam = jax.nn.sigmoid(jnp.sum(ends[:-1] * gate_w, axis=-1)
+                                 + gate_b)               # (R - 1, B, L)
+            p = exit_distribution(lam)                   # (R, B, L)
+            entropy = exit_entropy(p)
+            loss = jnp.mean(jnp.sum(p * nll, axis=0)
+                            - self.exit_beta * entropy)
+            at = jnp.arange(1, steps + 1, dtype=jnp.float32)
+            stats = {
+                "loop_exit_step_mean": jnp.mean(
+                    jnp.tensordot(at, p, axes=1)),
+                "loop_loss_drop": jnp.mean(nll[0]) - jnp.mean(nll[-1]),
+                "loop_exit_entropy": jnp.mean(entropy)}
+        return loss, stats

@@ -73,6 +73,10 @@ def _row_spec(block_rows: int):
     return pl.BlockSpec((block_rows, LANE), lambda i: (i, 0), memory_space=pltpu.VMEM)
 
 
+def _flat_spec(block: int):
+    return pl.BlockSpec((block,), lambda i: (i,), memory_space=pltpu.VMEM)
+
+
 # ---------------------------------------------------------------------------
 # Nesterov commit (msgd phase 2)
 # ---------------------------------------------------------------------------
@@ -132,32 +136,35 @@ def fused_nesterov_commit(
         # The grid's last block may overhang the rows: what it reads
         # past the end is unspecified and what it writes there is
         # dropped, and the kernel is elementwise.
-        def rows(x):
-            return x.reshape(n // LANE, LANE)
+        view, block, spec = (n // LANE, LANE), br, _row_spec(br)
     else:
-        def rows(x):
-            return as_rows(x, br)[0]
-    w2, vt2, g2 = rows(w), rows(vt), rows(g)
-    grid = (pl.cdiv(w2.shape[0], br),)
+        # No whole number of lanes: no (rows, 128) view exists, and a
+        # slice of the aligned prefix would be a copy of each operand as
+        # whole as the pad's.  The same kernel sweeps the vector as it
+        # is, in 1-D blocks of as many elements; the last one overhangs
+        # the tail of under a lane as above, so nothing is copied and
+        # no vector changes length.
+        view, block, spec = (n,), br * LANE, _flat_spec(br * LANE)
     retract = sug is not None
-    operands = [_scalar(clr, w2.dtype), w2, vt2, g2]
-    in_specs = [_scalar_spec(), _row_spec(br), _row_spec(br), _row_spec(br)]
+    vectors = [x.reshape(view) for x in (w, vt, g)]
+    operands = [_scalar(clr, w.dtype), *vectors]
+    in_specs = [_scalar_spec(), spec, spec, spec]
     if retract:
-        operands.append(rows(sug))
-        in_specs.append(_row_spec(br))
+        operands.append(sug.reshape(view))
+        in_specs.append(spec)
     w_new, vt_new = pl.pallas_call(
         functools.partial(_nesterov_kernel, l2wd=float(l2wd), retract=retract),
-        grid=grid,
+        grid=(pl.cdiv(view[0], block),),
         in_specs=in_specs,
-        out_specs=(_row_spec(br), _row_spec(br)),
+        out_specs=(spec, spec),
         out_shape=(
-            jax.ShapeDtypeStruct(w2.shape, w2.dtype),
-            jax.ShapeDtypeStruct(vt2.shape, vt2.dtype),
+            jax.ShapeDtypeStruct(view, w.dtype),
+            jax.ShapeDtypeStruct(view, vt.dtype),
         ),
         input_output_aliases={1: 0, 2: 1},
         interpret=_interpret(interpret),
     )(*operands)
-    return from_rows(w_new, n), from_rows(vt_new, n)
+    return w_new.reshape(n), vt_new.reshape(n)
 
 
 # ---------------------------------------------------------------------------

@@ -215,10 +215,15 @@ LAUNCH_DEFAULTS = TRAINER_DEFAULTS.merged(
     # width, window and full attention mixed, top-k renormalised, a
     # share of the experts) | lfm2 (a gated short convolution or
     # grouped-head attention with a per-head q/k norm by layer, a dense
-    # or a sparse MLP by layer, a sigmoid router with a selection bias).
+    # or a sparse MLP by layer, a sigmoid router with a selection bias)
+    # | ouro (the stack of layers run lm_loop_steps times with the same
+    # weights, sandwich norms, a head and an exit gate at every pass and
+    # a loss of its own over them; it takes lm_kv_heads, lm_head_dim and
+    # lm_dense_width too).
     # lm_experts .. lm_norm_eps are the sparse blocks'; lm_kv_heads ..
     # lm_yarn_attn_factor are mellum's own, of which lfm2 takes the
-    # first four; lm_layer_types .. lm_route_scale are lfm2's own
+    # first four; lm_layer_types .. lm_route_scale are lfm2's own,
+    # lm_loop_steps, lm_exit_beta and lm_exit_bias ouro's
     # (lm/model.py build has each one's meaning and what 0 stands for)
     lm_arch="gpt2",
     lm_experts=8,
@@ -242,6 +247,9 @@ LAUNCH_DEFAULTS = TRAINER_DEFAULTS.merged(
     lm_dense_width=0,
     lm_conv_kernel=3,
     lm_route_scale=1.0,
+    lm_loop_steps=4,
+    lm_exit_beta=0.1,
+    lm_exit_bias=0.0,
     lm_d_model=64,
     lm_heads=4,
     lm_layers=2,
@@ -361,12 +369,12 @@ def lm_trainer_cfg(cfg: Config) -> Config:
     """The :data:`mpit_tpu.lm.trainer.LM_DEFAULTS`-shaped config for one
     launch config: shared optimizer/loop knobs carried over verbatim,
     lm_* knobs mapped onto the trainer's names."""
-    from mpit_tpu.lm.model import LFM2_KEYS, MELLUM_KEYS
+    from mpit_tpu.lm.model import LFM2_KEYS, MELLUM_KEYS, OURO_KEYS
     from mpit_tpu.lm.trainer import LM_DEFAULTS
 
     return Config(
         **{key: type(LM_DEFAULTS[key])(cfg.get(f"lm_{key}", LM_DEFAULTS[key]))
-           for key in MELLUM_KEYS + LFM2_KEYS},
+           for key in MELLUM_KEYS + LFM2_KEYS + OURO_KEYS},
         arch=str(cfg.get("lm_arch", "gpt2")),
         n_experts=int(cfg.get("lm_experts", 8)),
         experts_per_tok=int(cfg.get("lm_experts_per_tok", 2)),

@@ -54,7 +54,8 @@ def test_scopes_come_from_the_cells_configuration():
 def test_every_cells_vocabulary_reaches_the_program_through_the_launcher():
     from chipbench import run as runner
 
-    want = {"gpt2": 50257, "olmoe": 50304, "mellum": 12288, "lfm2_moe": 8192}
+    want = {"gpt2": 50257, "olmoe": 50304, "mellum": 12288, "lfm2_moe": 8192,
+            "ouro": 49152}
     for name in CELLS:
         cell = spec_mod.load_cell(name)
         cfg = runner.launch_config(cell, seed=5)
@@ -72,7 +73,7 @@ def test_scopes_come_from_every_committed_configuration():
     # lists, in order of first mention
     assert spantree.model_scopes({}) == [
         "embed", "attn", "mlp", "head_loss", "update", "router", "dispatch",
-        "experts", "attn_window", "conv", "conv_mix"]
+        "experts", "attn_window", "conv", "conv_mix", "exit_gate"]
 
 
 def olmoe_cases():
@@ -531,6 +532,170 @@ def test_the_parent_fails_the_new_cell_at_once():
     cell on the parent needs."""
     bench = spec_mod.load_bench()
     names = [w["name"] for w in bench["workloads"]]
-    assert names[-1] == LFM2_CELL and len(names) == 6
-    with pytest.raises(spec_mod.SpecError, match="no workload"):
-        spec_mod.load_cell("lfm2-l5e8-locals")
+    assert names[-2:] == [LFM2_CELL, OURO_CELL] and len(names) == 7
+    for missing in ("lfm2-l5e8-locals", "ouro-l6-locals"):
+        with pytest.raises(spec_mod.SpecError, match="no workload"):
+            spec_mod.load_cell(missing)
+
+
+# -- the Ouro configuration (PR 36) -----------------------------------------------
+
+OURO_CELL = "ouro-l6-local"
+OURO_METRICS = ("exit_gate_ms_per_step", "exit_step_mean",
+                "loop_loss_drop_nats")
+
+
+def test_ouro_file_has_the_catalogs_keys_and_cuts_depth_alone():
+    """Every number of the catalog's entry under its own key (the
+    model-configs guide's ``architectures.jsonl``, read where it is
+    installed; the hand-copied numbers below where it is not),
+    ``layer_types`` whole; only the depth differs, with the published
+    count beside it; the vocabulary is whole and no width is cut."""
+    import pathlib
+
+    cell = spec_mod.load_cell(OURO_CELL)
+    config = cell.config
+    catalog = {
+        "head_dim": 128, "hidden_act": "silu", "hidden_size": 2048,
+        "intermediate_size": 5632, "max_position_embeddings": 65536,
+        "max_window_layers": 48, "model_type": "ouro",
+        "num_attention_heads": 16, "num_hidden_layers": 48,
+        "num_key_value_heads": 16, "rms_norm_eps": 1e-06,
+        "rope_scaling": None, "rope_theta": 1000000, "sliding_window": None,
+        "tie_word_embeddings": False, "total_ut_steps": 4,
+        "early_exit_threshold": 1, "use_sliding_window": False,
+        "vocab_size": 49152}
+    path = pathlib.Path(
+        "/opt/skills/guides/model-configs/architectures.jsonl")
+    if path.exists():
+        rows = [json.loads(line) for line in path.read_text().splitlines()]
+        entry = next(r for r in rows if r["name"] == "Ouro-2.6B")
+        assert {k: v for k, v in entry["config"].items()
+                if not isinstance(v, (list, dict))} == catalog
+        assert entry["source_url"] == config["source"]
+        catalog = entry["config"]
+        assert config["layer_types"] == catalog["layer_types"]
+    differ = sorted(k for k, v in catalog.items() if config.get(k, "?") != v)
+    assert differ == config["reduced"] == ["num_hidden_layers"]
+    assert config["published"] == {"num_hidden_layers": 48}
+    assert config["num_hidden_layers"] == 6 and len(config["layer_types"]) == 48
+    entry = next(c for c in cell.bench["configs"]
+                 if c["name"] == cell.config_name)
+    assert entry["reduced"] == differ and entry["source"] == config["source"]
+    assert (cell.chips, cell.traffic_name) == (1, "local-msgd-s4k-ouro")
+    assert (cell.traffic["batch"], cell.traffic["su"],
+            cell.traffic["launcher"]["opt"]) == (1, 1, "msgd")
+    assert config["train_seq"] == 4096 and config["exit_entropy_beta"] == 0.1
+    assert ["embed", "attn", "mlp", "exit_gate", "head_loss",
+            "update"] == config["scopes"]
+    assert all(key in config for key in config["launcher_from"].values())
+    assert all(key in config for key in config["tiny"])
+    assert config["tiny"]["total_ut_steps"] >= 2 <= \
+        config["tiny"]["num_hidden_layers"]
+    assert len(config["assumed"]) >= 6 and config["deployment"]
+    assert cell.arithmetic().param_count(config) == 509_661_185
+
+
+def test_the_launcher_builds_the_looped_block_from_the_cells_files():
+    from chipbench import run as runner
+    from mpit_tpu.lm.model import build_kw
+    from mpit_tpu.train.launch import lm_trainer_cfg
+
+    cell = spec_mod.load_cell(OURO_CELL)
+    kw = build_kw(lm_trainer_cfg(runner.launch_config(cell, seed=5)))
+    assert (kw["arch"], kw["loop_steps"], kw["exit_beta"]) == ("ouro", 4, 0.1)
+    assert kw["exit_bias"] == cell.config["exit_gate_bias_init"] == -4.0
+    assert (kw["d_model"], kw["n_heads"], kw["kv_heads"], kw["head_dim"],
+            kw["n_layers"], kw["dense_width"], kw["seq_len"], kw["vocab"]) \
+        == (2048, 16, 16, 128, 6, 5632, 4096, 49152)
+    assert (kw["rope_theta"], kw["norm_eps"]) == (1000000.0, 1e-6)
+
+
+def test_ouros_mix_keeps_to_the_traffic_its_issue_fixed():
+    """ISSUE 36 fixed the mix before any code was written: the rate one
+    of three, the budget a fifth to a half of the 85 micro-steps a 51 s
+    run makes (whole sequences of 4096), the learning floor half of what
+    the seeds learnt by the budget (5.30..5.53 nats), momentum 0.9, and
+    the counters of what the loop learns move the loss, not the rate."""
+    cell = spec_mod.load_cell(OURO_CELL)
+    mix = cell.traffic
+    steps, rest = divmod(mix["token_budget"],
+                         mix["batch"] * cell.config["train_seq"])
+    assert rest == 0 and 17 <= steps <= 42
+    assert mix["lr"] in (0.003, 0.01, 0.03)
+    assert 2.0 <= mix["min_learning_nats"] <= 2.8
+    assert (mix["launcher"]["mom"], mix["warmup_rounds"]) == (0.9, 2)
+    moves = {m["name"]: m["moves"] for m in cell.metrics("per_layer")}
+    assert moves["exit_step_mean"] == moves["loop_loss_drop_nats"] \
+        == "loss_at_budget"
+    assert moves["exit_gate_ms_per_step"] == "tokens_per_s"
+
+
+def test_ouros_readers_find_nothing_in_a_run_without_the_block():
+    """What the parent's traced run hands them: a cell whose
+    configuration lists no such scope, a program that recorded no such
+    counter, no merged trace: None, no raise."""
+    for name in ("lfm2-l5e8-local", "c111m-local"):
+        cell = spec_mod.load_cell(name)
+        run = {"cell": cell, "reduction": {"step_module": "jit__lambda"},
+               "obs_trace": None, "peaks": None, "results": {},
+               "summary": {"worker_ranks": [0], "window": [0.0, 1.0]}}
+        for metric in OURO_METRICS:
+            reader = spec_mod.load_reader(cell.root, cell.bench, metric)
+            assert reader is not None and reader(dict(run)) is None
+
+
+def test_ouros_readers_read_a_hand_made_run(monkeypatch):
+    """The three readers, and the ten metrics without a ``workloads``
+    list that the cell has to report, on a scope table and a span tree
+    made by hand: 3 ms under ``exit_gate`` and 80 under ``head_loss`` a
+    step; three rounds that leave at 1.9, 2.1 and 2.4 and whose later
+    passes buy 0.2, 0.3 and 0.1 nats."""
+    from chipbench import flops
+    from chipbench.layers import spantree
+
+    cell = spec_mod.load_cell(OURO_CELL)
+    listed = [m["name"] for m in cell.metrics("per_layer")]
+    assert set(OURO_METRICS) <= set(listed)
+    unlisted = [m["name"] for m in cell.bench["per_layer"]
+                if "workloads" not in m]
+    assert len(unlisted) == 10 and set(unlisted) <= set(listed)
+
+    class Round:
+        def __init__(self, k, exit_step, drop):
+            self.args = {"round": k, "loop_exit_step_mean": [exit_step],
+                         "loop_loss_drop": [drop],
+                         "loop_exit_entropy": [1.2]}
+
+    class Tree:
+        def rounds(self):
+            return [Round(7, 1.9, 0.2), Round(8, 2.4, 0.3),
+                    Round(9, 2.1, 0.1)]
+
+    table = {"step": 1000.0, "attn": 400.0, "mlp": 300.0, "exit_gate": 3.0,
+             "head_loss": 80.0, "update": 30.0}
+    monkeypatch.setattr(spantree, "scope_ms_per_step", lambda run: table)
+    monkeypatch.setattr(spantree, "xplane_path", lambda run: None)
+    run = {"cell": cell, "peaks": flops.load_peaks("TPU v5 lite"),
+           spantree.CACHE_KEY: Tree(),
+           "summary": {"tokens_per_s": 4000.0, "worker_ranks": [0]},
+           "reduction": {"step_module_runs": 2, "mosaic_by_scope": {
+               "attn": (144, 0.400), "update": (2, 0.030)}}}
+
+    def read(name):
+        return spec_mod.load_reader(cell.root, cell.bench, name)(run)
+
+    assert read("exit_gate_ms_per_step") == pytest.approx(3.0)
+    assert read("exit_step_mean") == pytest.approx(2.1)
+    assert read("loop_loss_drop_nats") == pytest.approx(0.2)
+    assert read("head_loss_ms_per_step") == pytest.approx(80.0)
+    # 72 flash calls a step (24 forward, 24 recomputed, 24 backward)
+    assert read("flash_ms_per_step") == pytest.approx(200.0)
+    family = cell.arithmetic().kernels(cell.config, 1)["attn"]
+    assert read("flash_roofline") == pytest.approx(
+        100 * family["flops"] / 197e12 / 0.200)
+    assert read("mfu_pct") == pytest.approx(
+        100 * 11_022_925_824 * 4000.0 / 197e12)
+    for metric in cell.metrics("per_layer"):
+        assert spec_mod.load_reader(cell.root, cell.bench,
+                                    metric["name"]) is not None

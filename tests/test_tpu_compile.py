@@ -4,8 +4,8 @@ on-chip-measurement guide, section 2): the Pallas grouped product of
 the flash kernels at LFM2's attention shape (32 query over 8 KV heads of
 64 at 8192) and at Mellum's sliding layer's (32 over 4 heads of 128
 under a window of 1024); and the msgd commit over LFM2's vector, whose length is
-whole lanes and no whole number of blocks, with ``w`` and ``vt``
-donated.  What interpret mode cannot show: that the tiles fit the chip's fast
+whole lanes and no whole number of blocks, and over Ouro's, which is no
+whole number of lanes, with ``w`` and ``vt`` donated.  What interpret mode cannot show: that the tiles fit the chip's fast
 memory and the kernels lower.  A compile that passes is not a chip run
 and says nothing about time.
 
@@ -99,3 +99,25 @@ def test_the_donated_commit_keeps_no_copy_of_lfm2s_vector(one_chip):
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= 2 * 4 * n   # both, to the tile
     assert mem.temp_size_in_bytes < 4 * n // 8
+
+
+def test_the_donated_commit_keeps_no_copy_of_ouros_vector(one_chip):
+    """509,661,185 elements are one over a whole number of lanes: the
+    commit sweeps the vector as it is, in 1-D blocks with an overhanging
+    last one, so with ``w`` and ``vt`` donated the program holds no
+    third vector (a pad, or a slice of the aligned prefix, would copy
+    each operand whole)."""
+    from mpit_tpu.ops.fused_update import fused_nesterov_commit
+
+    n = 509_661_185
+    assert n % 128 == 1
+    vec = jax.ShapeDtypeStruct((n,), jnp.float32, sharding=one_chip)
+    compiled = jax.jit(
+        lambda w, vt, g: fused_nesterov_commit(w, vt, g, 0.03,
+                                               interpret=False),
+        donate_argnums=(0, 1)).lower(vec, vec, vec).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * 4 * n   # both, to the tile
+    assert mem.temp_size_in_bytes < 4 * n // 8
+    assert compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"') == 1
