@@ -63,6 +63,10 @@ class LmModel(NamedTuple):
     #: ``loop_exit_entropy``, one number each); None for a block that
     #: has none
     value_grad_stats: Optional[Callable[..., Any]] = None
+    #: ouro: the bytes a sequence that the decoder's checkpoints keep by
+    #: name for the backward pass (``OuroDecoder.kept_residual_bytes``);
+    #: 0 for a decoder that names none
+    kept_residual_bytes: int = 0
 
 
 def _resolve_attn(use_flash: Optional[bool],
@@ -75,12 +79,15 @@ def _resolve_attn(use_flash: Optional[bool],
     lowering, never the interpreter because the process quietly came up
     on another backend.  False pins the reference.  ``precision`` is the
     attention products' (``default_attn``)."""
-    if use_flash is None:
-        return default_attn(causal=True, precision=precision,
-                            use_flash=jax.default_backend() == "tpu")
-    return default_attn(causal=True, use_flash=bool(use_flash),
+    return default_attn(causal=True, use_flash=_uses_flash(use_flash),
                         interpret=False if use_flash else None,
                         precision=precision)
+
+
+def _uses_flash(use_flash: Optional[bool]) -> bool:
+    """Whether :func:`_resolve_attn` gives the kernel."""
+    return (jax.default_backend() == "tpu" if use_flash is None
+            else bool(use_flash))
 
 
 # mellum's own sizes (``build``'s keywords, ``LM_DEFAULTS``' names);
@@ -164,7 +171,9 @@ def build(*, arch: str = "gpt2", vocab: int = 256, d_model: int = 64,
             exit_beta=float(exit_beta), exit_bias=float(exit_bias),
             rope_theta=rope_theta,
             norm_eps=norm_eps, attn_fn=_resolve_attn(use_flash))
-        return _own_loss(module, seed, seq_len, vocab)
+        return _own_loss(module, seed, seq_len, vocab)._replace(
+            kept_residual_bytes=module.kept_residual_bytes(
+                seq_len, _uses_flash(use_flash)))
     if arch in ("mellum", "lfm2"):
         held = experts_held or n_experts
         if experts_first + held > n_experts:

@@ -154,6 +154,8 @@ class LmTrainer:
         self._m_loss = _reg.gauge("mpit_lm_loss", rank=rank)
         self._m_eval = _reg.gauge("mpit_lm_eval_loss", rank=rank)
         self._m_tps = _reg.gauge("mpit_lm_tokens_per_s", rank=rank)
+        _reg.gauge("mpit_lm_kept_residual_bytes", rank=rank).set(
+            cfg.batch * self.model.kept_residual_bytes)
         self._optimizer = None  # lazy: eval-only roles never need one
 
     @property

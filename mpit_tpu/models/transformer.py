@@ -42,8 +42,11 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 
-from mpit_tpu.ops.flash_attention import attention_reference, flash_attention
+from mpit_tpu.ops.flash_attention import (
+    FLASH_LSE, FLASH_OUT, attention_reference, flash_attention,
+)
 from mpit_tpu.ops.short_conv import causal_depthwise_conv
 from mpit_tpu.parallel import moe
 
@@ -818,6 +821,38 @@ def exit_entropy(p: jnp.ndarray) -> jnp.ndarray:
                               0.0), axis=0)
 
 
+# What :class:`OuroDecoder`'s checkpoints keep for the backward pass
+# beside their inputs, by ``checkpoint_name``: the flash rule's two, the
+# MLP's output of a layer application and the head's row log-sum-exp of
+# a pass (the decoder's docstring says what each costs and saves).  A
+# constant of the module: what a decoder can afford to keep follows from
+# its own sizes.
+MLP_OUT, HEAD_LSE = "mlp_out", "head_lse"
+OURO_KEPT = (FLASH_OUT, FLASH_LSE, MLP_OUT, HEAD_LSE)
+
+
+@jax.custom_vjp
+def row_lse(z: jnp.ndarray) -> jnp.ndarray:
+    """``logsumexp`` over the last axis, with a backward rule that reads
+    the logits and the result alone (``g exp(z - lse)``): a checkpoint
+    that keeps the result by name computes the logits again and not the
+    reductions over them."""
+    return jax.nn.logsumexp(z, axis=-1)
+
+
+def _row_lse_fwd(z):
+    lse = checkpoint_name(jax.nn.logsumexp(z, axis=-1), HEAD_LSE)
+    return lse, (z, lse)
+
+
+def _row_lse_bwd(res, g):
+    z, lse = res
+    return ((g[..., None] * jnp.exp(z - lse[..., None])).astype(z.dtype),)
+
+
+row_lse.defvjp(_row_lse_fwd, _row_lse_bwd)
+
+
 class OuroBlock(nn.Module):
     """One layer's parameters, and the layer as a pure function of
     them (:meth:`apply_weights`): the decoder applies it inside
@@ -863,7 +898,8 @@ class OuroBlock(nn.Module):
         with jax.named_scope("mlp"):
             b = rms_norm(u, p["mlp_norm"], eps)
             m = (jax.nn.silu(b @ p["w_gate"]) * (b @ p["w_up"])) @ p["w_down"]
-            return u + rms_norm(m, p["mlp_out_norm"], eps)
+            return u + rms_norm(checkpoint_name(m, MLP_OUT),
+                                p["mlp_out_norm"], eps)
 
 
 class OuroDecoder(nn.Module):
@@ -900,8 +936,30 @@ class OuroDecoder(nn.Module):
     same numbers to float32 rounding), at a memory and compile time
     that the cell's sizes do not have.  With ``remat`` each layer
     application and each pass's norm, head and loss is computed again in
-    the backward pass: kept are the layers' inputs and the passes'
-    outputs, never a ``(positions, vocab)`` array of an earlier pass."""
+    the backward pass, but for what is dear to compute twice and cheap
+    to hold.  Kept are the layers' inputs, the passes' outputs and, by
+    name (:data:`OURO_KEPT`, a ``save_only_these_names`` policy on every
+    checkpoint; float32 as computed, on the device), of a layer
+    application at ``T`` positions
+
+    - the flash kernel's output and row log-sum-exp, the custom VJP's
+      own residuals (``ops/flash_attention.py``: ``T x heads x
+      head_dim`` and ``T x heads`` floats), so the backward pass calls
+      no forward kernel: a third of the step's flash time;
+    - the MLP's output before its out-norm (``T x d_model``), so the
+      down product is not run again;
+
+    and of a pass's end the head's row log-sum-exp (``T`` floats,
+    :func:`row_lse`), so the backward pass runs the head's product again
+    but no reduction over ``(positions, vocab)``, and never holds such
+    an array of an earlier pass.  Not kept: the attention branch's
+    output (the ``wo`` product it saves is a quarter of the down one,
+    and the compiled step was slower with it than without); ``q``, ``k``
+    and ``v`` (three times the flash output's bytes) and the MLP's gate
+    and up products (``2 T x dense_width``, five times), which at the
+    cell's sizes do not fit beside the step: the backward pass makes
+    them again from the layer's input.  What each is worth on the chip:
+    PERF.md section 5, after PR 37."""
 
     vocab: int = 256
     d_model: int = 64
@@ -937,14 +995,18 @@ class OuroDecoder(nn.Module):
         gate_w = self.param("loop_gate", _INIT, (d,))
         gate_b = self.param("loop_gate_bias",
                             nn.initializers.constant(self.exit_bias), (1,))
-        keep = jax.checkpoint if self.remat else (lambda fn: fn)
+        # a layer's input and a pass's outputs, and the named values
+        # the backward pass would pay most to make again (the docstring)
+        policy = jax.checkpoint_policies.save_only_these_names(*OURO_KEPT)
+        keep = (partial(jax.checkpoint, policy=policy) if self.remat
+                else (lambda fn: fn))
 
         def pass_end(u, final_norm, head):
             with jax.named_scope("head_loss"):
                 h = rms_norm(u, final_norm, eps)
-                logp = nn.log_softmax(h @ head)
-                nll = -jnp.take_along_axis(logp, targets[..., None],
-                                           axis=-1)[..., 0]
+                z = h @ head
+                nll = row_lse(z) - jnp.take_along_axis(
+                    z, targets[..., None], axis=-1)[..., 0]
             return h, nll
 
         def one_pass(h, _):
@@ -983,3 +1045,17 @@ class OuroDecoder(nn.Module):
                 "loop_loss_drop": jnp.mean(nll[0]) - jnp.mean(nll[-1]),
                 "loop_exit_entropy": jnp.mean(entropy)}
         return loss, stats
+
+    def kept_residual_bytes(self, positions: int, flash: bool) -> int:
+        """The bytes :data:`OURO_KEPT` holds from the forward pass to
+        the backward one in a step over ``positions`` (batch times
+        sequence), from the named values' shapes, float32 as the stream
+        is.  The flash rule's two exist only where ``attn_fn`` is the
+        kernel (``flash``)."""
+        if not self.remat:
+            return 0
+        layer = self.d_model                                  # mlp_out
+        if flash:                                # flash_out, flash_lse
+            layer += self.n_heads * (self.head_dim + 1)
+        # those a layer application, and head_lse's one float a pass
+        return 4 * positions * self.loop_steps * (self.n_layers * layer + 1)

@@ -60,6 +60,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -69,6 +70,10 @@ from mpit_tpu.ops.tiles import (
 )
 
 NEG_INF = float("-inf")
+
+# ``checkpoint_name``s of what :func:`flash_attention`'s forward rule
+# keeps for its backward one: the output and the rows' log-sum-exp.
+FLASH_OUT, FLASH_LSE = "flash_out", "flash_lse"
 
 # In-kernel running-max sentinel.  A FINITE very-negative value instead
 # of -inf: every `isneginf` guard in the hot loop disappears (exp of
@@ -1256,8 +1261,14 @@ def _make_flash(causal, sm_scale, block_q, block_k, interpret, precision,
             kv_offset=kv_offset, block_q=block_q, block_k=block_k,
             interpret=interpret, precision=precision, window=window,
         )
-        o = finalize_partials(acc, l, dtype=q.dtype)
-        lse = _lse_of(m, l)
+        # Named where the rule makes them: a checkpoint whose policy
+        # saves these two names keeps the forward kernel's results and
+        # does not run it again for the backward pass (``lse`` exists
+        # nowhere outside this rule).  Outside such a policy a name
+        # lowers to nothing.
+        o = checkpoint_name(finalize_partials(acc, l, dtype=q.dtype),
+                            FLASH_OUT)
+        lse = checkpoint_name(_lse_of(m, l), FLASH_LSE)
         return o, (q, k, v, o, lse, q_offset, kv_offset)
 
     def bwd(res, g):

@@ -187,6 +187,46 @@ def test_flash_grad_matches_reference(rng, fa_backward_path):
     _assert_flash_grads_match(*_qkv(rng, (24, 16)))
 
 
+def test_the_residuals_names_change_nothing_outside_a_checkpoint(
+        rng, monkeypatch):
+    """The forward rule names its output and row log-sum-exp for a
+    checkpoint's policy to keep (``FLASH_OUT``, ``FLASH_LSE``).  Outside
+    any checkpoint they are nothing: the same output, the same gradient
+    and the same lowered text as a rule that names neither."""
+    import re
+    import sys
+
+    fa = sys.modules["mpit_tpu.ops.flash_attention"]
+    q, k, v = _qkv(rng, (2, 24, 16))
+
+    def read():
+        fa._make_flash.cache_clear()
+        jax.clear_caches()
+        call = lambda q, k, v: flash_attention(q, k, v, causal=True,
+                                               block_q=8, block_k=128)
+        grad = jax.jit(jax.grad(lambda *x: jnp.sum(call(*x) ** 2),
+                                argnums=(0, 1, 2)))
+        # the lowering numbers its private functions (``@_pad_70``) from
+        # a counter that is not the program's
+        text = re.sub(r"@(\w+?)_\d+\b", r"@\1",
+                      grad.lower(q, k, v).as_text())
+        return call(q, k, v), grad(q, k, v), text
+
+    named = read()
+    assert str(jax.make_jaxpr(jax.grad(lambda q: jnp.sum(
+        flash_attention(q, k, v, causal=True))))(q)).count(fa.FLASH_LSE) == 1
+    monkeypatch.setattr(fa, "checkpoint_name", lambda x, name: x)
+    try:
+        bare = read()
+    finally:
+        fa._make_flash.cache_clear()
+        jax.clear_caches()
+    np.testing.assert_array_equal(np.asarray(named[0]), np.asarray(bare[0]))
+    for a, b in zip(named[1], bare[1]):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert named[2] == bare[2]
+
+
 def test_fwd_long_bq_block_routing(monkeypatch):
     """Length-aware forward default (from a July 2026 sweep the ledger
     has not reproduced): block_q

@@ -18,9 +18,11 @@ gradient's norm and of a nat as measured here.  The limits are 1e-5.
 What they must refuse, each tried below on the reference itself with one
 thing wrong, is wrong by 1e-3 or more."""
 
+import functools
 import json
 import math
 import pathlib
+import re
 import threading
 
 import jax
@@ -386,16 +388,23 @@ def test_the_counters_equal_the_references_distribution(case):
 # -- one scanned, recomputed body -------------------------------------------------
 
 
-@pytest.mark.parametrize("scan,remat", [(False, False), (False, True),
-                                        (True, False)],
-                         ids=["unrolled_kept", "unrolled_recomputed",
-                              "scanned_kept"])
-def test_scan_and_recomputation_change_no_number(case, scan, remat):
+@pytest.mark.parametrize("scan,remat,exact", [
+    (False, False, False), (False, True, False), (True, False, False),
+    (True, True, True)],
+    ids=["unrolled_kept", "unrolled_recomputed", "scanned_kept",
+         "policy_against_bare_checkpoint"])
+def test_scan_and_recomputation_change_no_number(case, scan, remat, exact,
+                                                 monkeypatch):
     """The program's scanned, recomputed passes against the same module
     unrolled and with every activation kept: the loss, the counters and
-    the flat gradient to float32 rounding."""
+    the flat gradient to float32 rounding.  And against itself under the
+    bare ``jax.checkpoint`` (a policy that saves no name saves nothing):
+    a kept value is the one the backward pass computed again, so the
+    loss and every leaf of the gradient are equal exactly."""
     model, w, tokens = case["model"], case["w"], case["tokens"]
     other = model.module.clone(scan=scan, remat=remat)
+    if exact:
+        monkeypatch.setattr(transformer, "OURO_KEPT", ())
 
     def loss(flat, tokens):
         return other.apply({"params": model.flat.unravel(flat)},
@@ -407,6 +416,13 @@ def test_scan_and_recomputation_change_no_number(case, scan, remat):
     assert errors((got, grad), case["sys"])[1] <= 1e-6
     for name, value in case["stats"].items():
         assert float(stats[name]) == pytest.approx(float(value), abs=1e-6)
+    if exact:
+        assert float(got) == float(case["sys"][0])
+        for leaf, kept in zip(
+                jax.tree_util.tree_leaves(model.flat.unravel(grad)),
+                jax.tree_util.tree_leaves(
+                    model.flat.unravel(case["sys"][1]))):
+            np.testing.assert_array_equal(np.asarray(leaf), np.asarray(kept))
 
 
 def test_the_lowered_step_holds_one_body_for_all_the_passes(case):
@@ -428,6 +444,139 @@ def test_the_lowered_step_holds_one_body_for_all_the_passes(case):
     unrolled = scanned.clone(scan=False)
     assert dots(unrolled, 4) > 1.8 * dots(unrolled, 2)
     assert dots(unrolled, 2) > 1.5 * dots(scanned, 2)
+
+
+def _equations(jaxpr, primitive):
+    """Every equation of ``primitive`` in ``jaxpr`` and the jaxprs its
+    equations hold (a scan's body, a checkpoint's, a custom rule's)."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == primitive:
+            yield eqn
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _equations(sub, primitive)
+
+
+@pytest.fixture(scope="module")
+def flash_case(case):
+    """The tiny decoder with the flash kernels (interpreted) for its
+    attention, and one layer's weights."""
+    model = case["model"]
+    module = model.module.clone(attn_fn=transformer.default_attn(
+        causal=True, use_flash=True, interpret=True))
+    params = model.flat.unravel(case["w"])
+    return module, params, case["tokens"]
+
+
+@pytest.mark.parametrize("kept,forward_calls", [
+    (None, TINY["n_layers"]), ((), 2 * TINY["n_layers"])],
+    ids=["policy", "bare_checkpoint"])
+def test_the_gradient_holds_one_flash_forward_a_layer(flash_case, kept,
+                                                      forward_calls,
+                                                      monkeypatch):
+    """The gradient's jaxpr holds the forward kernel once a layer, in
+    the scanned forward body: the backward body takes the kernel's
+    output and row statistics as kept and does not call it again.  Under
+    the bare checkpoint it holds it twice a layer."""
+    from mpit_tpu.ops.flash_attention import _fa_kernel
+
+    module, params, tokens = flash_case
+    if kept is not None:
+        monkeypatch.setattr(transformer, "OURO_KEPT", kept)
+    jaxpr = jax.make_jaxpr(jax.grad(lambda p: module.apply(
+        {"params": p}, tokens[:, :-1], tokens[:, 1:])[0]))(params)
+    kernels = [eqn.params["jaxpr"].debug_info.func_name
+               for eqn in _equations(jaxpr.jaxpr, "pallas_call")]
+    assert kernels.count(_fa_kernel.__name__) == forward_calls
+    assert len(kernels) - forward_calls >= TINY["n_layers"]   # the backward
+
+
+def _saved(capsys, fn, *args):
+    """What a checkpointed ``fn`` saves beside its arguments and
+    constants, as ``(dtype, shape)``.  The public reading is the printed
+    one: a line a residual, ``f32[2,64,64] named 'mlp_out' from ...``."""
+    jax.ad_checkpoint.print_saved_residuals(fn, *args)
+    lines = [line for line in capsys.readouterr().out.splitlines()
+             if "from the argument" not in line
+             and "from a constant" not in line]
+    return [(dtype, tuple(int(n) for n in shape.split(",")))
+            for dtype, shape in (re.match(r"(\w+)\[([\d,]*)\]", line).groups()
+                                 for line in lines)]
+
+
+def test_a_layer_and_a_pass_end_keep_the_named_values_and_nothing_wide(
+        flash_case, capsys):
+    """The saved residuals under the decoder's policy, beside the
+    arguments: of one layer application the flash kernel's output and
+    row statistics and the MLP's output, of a pass's end the head's row
+    log-sum-exp; float32, and no array with ``dense_width`` or ``vocab``
+    columns.  Their bytes are what ``kept_residual_bytes`` says."""
+    module, params, tokens = flash_case
+    block = transformer.OuroBlock(
+        d_model=TINY["d_model"], n_heads=TINY["n_heads"],
+        kv_heads=TINY["kv_heads"], head_dim=TINY["head_dim"],
+        dense_width=TINY["dense_width"], rope_theta=TINY["rope_theta"],
+        norm_eps=TINY["norm_eps"], attn_fn=module.attn_fn)
+    keep = functools.partial(
+        jax.checkpoint, policy=jax.checkpoint_policies
+        .save_only_these_names(*transformer.OURO_KEPT))
+    b, l, d = 2, TINY["seq_len"], TINY["d_model"]
+    heads, hd = TINY["n_heads"], TINY["head_dim"]
+    u = jnp.zeros((b, l, d), jnp.float32)
+    layer = _saved(capsys, keep(lambda u, p: block.apply(
+        {"params": p}, u, p, method="apply_weights")),
+        u, params["OuroBlock_0"])
+    assert sorted(layer) == sorted([
+        ("f32", (b, heads, l, hd)), ("f32", (b, heads, l)),   # o, lse
+        ("f32", (b, l, d))])                                  # m
+    end = _saved(capsys, keep(lambda h, head: transformer.row_lse(h @ head)),
+                 u, params["head"])
+    assert end == [("f32", (b, l))]
+    assert not any(shape[-1] in (TINY["dense_width"], TINY["vocab"])
+                   for _, shape in layer + end)
+    # a layer application: o 2 x 64 positions x 4 heads x 16, lse
+    # 2 x 64 x 4, m 2 x 64 x 64; 2 layers, and the head's 2 x 64, a
+    # pass; 3 passes; float32
+    assert 3 * sum(4 * math.prod(shape) for _, shape in 2 * layer + end) \
+        == module.kept_residual_bytes(b * l, flash=True) \
+        == 4 * 3 * 2 * 64 * (2 * (64 + 4 + 64) + 1)
+
+
+def test_the_heads_row_lse_is_logsumexp_with_its_gradient():
+    """``row_lse``'s own backward rule, ``g exp(z - lse)``, against the
+    derivative JAX takes of ``logsumexp``."""
+    z = jnp.asarray(np.random.RandomState(5).randn(3, 7, 50) * 4, jnp.float32)
+    g = jnp.asarray(np.random.RandomState(6).randn(3, 7), jnp.float32)
+    want, pull = jax.vjp(lambda z: jax.nn.logsumexp(z, axis=-1), z)
+    got, pull_own = jax.vjp(transformer.row_lse, z)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    np.testing.assert_allclose(np.asarray(pull_own(g)[0]),
+                               np.asarray(pull(g)[0]), rtol=1e-5, atol=1e-7)
+
+
+@pytest.mark.parametrize("arch,want", [
+    # on the reference attention: m of 2 layers, 2 x 64 positions x 64,
+    # and the head's 2 x 64, a pass; 3 passes; float32
+    ("ouro", 4 * 3 * 2 * 64 * (2 * 64 + 1)), ("gpt2", 0)])
+def test_the_kept_bytes_gauge_reads_the_hand_worked_bytes(arch, want,
+                                                          obs_on):
+    """``mpit_lm_kept_residual_bytes``, set where the trainer builds its
+    model: the named values' bytes a step at the tiny size (the flash
+    rule's two are not among them on the reference attention), 0 for a
+    decoder that names none."""
+    from mpit_tpu.lm.trainer import LM_DEFAULTS, LmTrainer
+
+    assert (TINY["d_model"], TINY["seq_len"], TINY["n_layers"],
+            TINY["loop_steps"]) == (64, 64, 2, 3)
+    shared = {"d_model", "n_heads", "n_layers", "seq_len", "vocab"}
+    sizes = {key: value for key, value in TINY.items()
+             if arch == "ouro" or key in shared}
+    LmTrainer(LM_DEFAULTS.merged(arch=arch, batch=2, seed=3, use_flash=0,
+                                 **sizes), rank=7)
+    assert obs.get_registry().gauge("mpit_lm_kept_residual_bytes",
+                                    rank=7).value == want
 
 
 # -- the configuration, the arithmetic and the switches -----------------------------
