@@ -3,13 +3,15 @@
 Assembles one of the decoders of :mod:`mpit_tpu.models.transformer`
 (``arch``: ``gpt2`` is :class:`TinyDecoder`, ``olmoe``
 :class:`OlmoeDecoder`, ``mellum`` :class:`MellumDecoder`, ``lfm2``
-:class:`Lfm2Decoder`, ``ouro`` :class:`OuroDecoder`; each one's
+:class:`Lfm2Decoder`, ``ouro`` :class:`OuroDecoder`, ``joyai``
+:class:`JoyaiDecoder`; each one's
 attention is the ``ops/`` flash kernel
 on TPU and the jnp reference — which differentiates without a recompute
 pass — elsewhere) into the flat-vector calling convention the parameter server shards: a
 :class:`~mpit_tpu.models.flat.FlatModel` plus a next-token NLL over
 packed token grids (``ouro`` closes its own loss over its passes' heads
-and exit gates), and the params+optimizer pytree
+and exit gates, ``joyai`` over its main and its multi-token-prediction
+head), and the params+optimizer pytree
 (:func:`train_state_tree`) that :mod:`mpit_tpu.lm.plan` drives the
 partition rules over.
 """
@@ -24,6 +26,7 @@ import jax.numpy as jnp
 from mpit_tpu.models.flat import FlatModel, flatten_module
 from mpit_tpu.models import transformer
 from mpit_tpu.models.transformer import (
+    JoyaiDecoder,
     Lfm2Decoder,
     MellumDecoder,
     OlmoeDecoder,
@@ -32,7 +35,7 @@ from mpit_tpu.models.transformer import (
     default_attn,
 )
 
-ARCHS = ("gpt2", "olmoe", "mellum", "lfm2", "ouro")
+ARCHS = ("gpt2", "olmoe", "mellum", "lfm2", "ouro", "joyai")
 # what a sparse layer ``sow``s, and the name of each in the step's
 # telemetry (``value_grad_stats``), on the round span and as the gauge
 # ``mpit_<name>``
@@ -60,8 +63,10 @@ class LmModel(NamedTuple):
     #: one number a sparse layer each), which the optimizer fetches only
     #: while obs is on; ouro: the loop's three counters
     #: (``loop_exit_step_mean``, ``loop_loss_drop``,
-    #: ``loop_exit_entropy``, one number each); None for a block that
-    #: has none
+    #: ``loop_exit_entropy``, one number each); joyai: its two heads'
+    #: NLLs (``lm_main_nll``, ``lm_mtp_nll``) and the four routing
+    #: counters, one entry a sparse layer, the MTP module's last; None
+    #: for a block that has none
     value_grad_stats: Optional[Callable[..., Any]] = None
     #: ouro: the bytes a sequence that the decoder's checkpoints keep by
     #: name for the backward pass (``OuroDecoder.kept_residual_bytes``);
@@ -101,6 +106,10 @@ LFM2_KEYS = ("layer_types", "dense_layers", "dense_width", "conv_kernel",
 # ouro's own (it takes ``kv_heads``, ``head_dim`` and ``dense_width``
 # too)
 OURO_KEYS = ("loop_steps", "exit_beta", "exit_bias")
+# joyai's own (it takes the share's two, ``dense_layers``,
+# ``dense_width`` and ``route_scale`` too)
+JOYAI_KEYS = ("q_rank", "kv_rank", "qk_nope", "qk_rope", "v_head",
+              "shared_experts", "mtp_layers", "mtp_weight")
 
 
 def build_kw(cfg: Any) -> dict:
@@ -111,7 +120,7 @@ def build_kw(cfg: Any) -> dict:
     kw = {key: cfg[key] for key in (
         "arch", "d_model", "n_heads", "n_layers", "seq_len", "seed",
         "n_experts", "experts_per_tok", "expert_width", "rope_theta",
-        "norm_eps", *MELLUM_KEYS, *LFM2_KEYS, *OURO_KEYS)}
+        "norm_eps", *MELLUM_KEYS, *LFM2_KEYS, *OURO_KEYS, *JOYAI_KEYS)}
     if int(cfg.vocab):
         kw["vocab"] = int(cfg.vocab)
     return kw
@@ -131,7 +140,10 @@ def build(*, arch: str = "gpt2", vocab: int = 256, d_model: int = 64,
           dense_layers: int = 0, dense_width: int = 0,
           conv_kernel: int = 3, route_scale: float = 1.0,
           loop_steps: int = 4, exit_beta: float = 0.1,
-          exit_bias: float = 0.0) -> LmModel:
+          exit_bias: float = 0.0, q_rank: int = 0, kv_rank: int = 0,
+          qk_nope: int = 0, qk_rope: int = 0, v_head: int = 0,
+          shared_experts: int = 1, mtp_layers: int = 1,
+          mtp_weight: float = 0.3) -> LmModel:
     """Build the decoder, flatten its params, and close over the
     next-token NLL.  ``arch`` chooses the block; the expert, rotary and
     norm sizes are the sparse blocks' alone, and those from
@@ -153,7 +165,16 @@ def build(*, arch: str = "gpt2", vocab: int = 256, d_model: int = 64,
     distribution's entropy in its loss, and ``exit_bias``, the value the
     exit gate's bias is seeded at (0: a gate of a half; negative: the
     loop starts nearer to running every pass); the loss is the block's own
-    (:class:`OuroDecoder`), not the next-token NLL of one head.  For
+    (:class:`OuroDecoder`), not the next-token NLL of one head.
+    ``joyai`` takes the share, ``dense_layers``, ``dense_width`` and
+    ``route_scale`` as ``lfm2`` does, and on its own the latent
+    attention's sizes (``q_rank`` and ``kv_rank``, the low-rank
+    products' inner widths; ``qk_nope`` and ``qk_rope``, the two parts
+    of a head's query and key; ``v_head``, a head's value), how many
+    ``shared_experts`` of ``expert_width`` every token takes beside the
+    routed ones, ``mtp_layers`` (0 or 1: the multi-token-prediction
+    module) and ``mtp_weight``, its loss's weight in the block's own
+    objective (:class:`JoyaiDecoder`).  For
     ``gpt2`` ``max_len`` is pinned to ``seq_len``
     — the packed stream always fills full sequences, and an exact fit
     keeps the position table out of the sharding slack (the other
@@ -174,12 +195,28 @@ def build(*, arch: str = "gpt2", vocab: int = 256, d_model: int = 64,
         return _own_loss(module, seed, seq_len, vocab)._replace(
             kept_residual_bytes=module.kept_residual_bytes(
                 seq_len, _uses_flash(use_flash)))
-    if arch in ("mellum", "lfm2"):
+    if arch in ("mellum", "lfm2", "joyai"):
         held = experts_held or n_experts
         if experts_first + held > n_experts:
             raise ValueError(f"experts {experts_first}.."
                              f"{experts_first + held - 1} held of {n_experts}")
         attn_fn = _resolve_attn(use_flash)
+    if arch == "joyai":
+        if min(q_rank, kv_rank, qk_nope, qk_rope, v_head) < 1 or qk_rope % 2:
+            raise ValueError(
+                f"joyai needs q_rank, kv_rank, qk_nope, qk_rope (even) and "
+                f"v_head: {(q_rank, kv_rank, qk_nope, qk_rope, v_head)}")
+        module = JoyaiDecoder(
+            vocab=vocab, d_model=d_model, n_heads=n_heads, q_rank=q_rank,
+            kv_rank=kv_rank, qk_nope=qk_nope, qk_rope=qk_rope,
+            v_head=v_head, n_layers=n_layers, dense_layers=dense_layers,
+            dense_width=dense_width, n_experts=n_experts,
+            experts_per_tok=experts_per_tok, expert_width=expert_width,
+            experts_first=experts_first, experts_held=experts_held,
+            shared_experts=shared_experts, route_scale=float(route_scale),
+            mtp_layers=mtp_layers, mtp_weight=float(mtp_weight),
+            rope_theta=rope_theta, norm_eps=norm_eps, attn_fn=attn_fn)
+        return _own_loss(module, seed, seq_len, vocab)
     if arch == "lfm2":
         kinds = tuple(kind.strip() for kind in layer_types.split(",")
                       if kind.strip())

@@ -89,6 +89,19 @@ LM_DEFAULTS = Config(
     loop_steps=4,
     exit_beta=0.1,
     exit_bias=0.0,
+    # joyai's own (it takes the share, dense_layers, dense_width and
+    # route_scale too): the latent attention's low-rank widths, the two
+    # parts of a head's query and key and a head's value, the shared
+    # experts every token takes, the multi-token-prediction module (0 or
+    # 1) and its loss's weight
+    q_rank=0,
+    kv_rank=0,
+    qk_nope=0,
+    qk_rope=0,
+    v_head=0,
+    shared_experts=1,
+    mtp_layers=1,
+    mtp_weight=0.3,
     # -1 auto (flash on TPU, jnp reference elsewhere) | 0 reference |
     # 1 the Mosaic-compiled kernel or an error (lm/model.py _resolve_attn)
     use_flash=-1,

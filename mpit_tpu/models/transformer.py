@@ -15,7 +15,7 @@ long-context showcase built on the framework's own kernels:
   batched over (B, L);
 - pre-LN blocks, learned positional embeddings, causal by default.
 
-Five decoders: :class:`TinyDecoder` (GPT-2's: LayerNorm, learned
+Six decoders: :class:`TinyDecoder` (GPT-2's: LayerNorm, learned
 positions, GELU MLP), :class:`OlmoeDecoder` (OLMoE's: RMSNorm, rotary
 positions, query/key norm, top-k of E gated experts),
 :class:`MellumDecoder` (Mellum 2's: grouped KV heads of their own
@@ -28,7 +28,11 @@ sigmoid router with a selection bias, both read from the
 configuration layer by layer) and :class:`OuroDecoder` (Ouro's: the
 whole stack applied several times with the same weights, sandwich
 norms, a head and an exit gate at every pass, and a loss of its own
-over them), chosen by ``lm/model.py`` ``build(arch=...)``.
+over them) and :class:`JoyaiDecoder` (JoyAI-LLM-Flash's: latent
+attention with keys wider than values, a shared expert beside a share of
+the routed ones, and a multi-token-prediction module whose loss it
+closes with the main head's), chosen by ``lm/model.py``
+``build(arch=...)``.
 """
 
 from __future__ import annotations
@@ -1059,3 +1063,316 @@ class OuroDecoder(nn.Module):
             layer += self.n_heads * (self.head_dim + 1)
         # those a layer application, and head_lse's one float a pass
         return 4 * positions * self.loop_steps * (self.n_layers * layer + 1)
+
+
+# ---------------------------------------------------------------------------
+# The latent-attention block (JoyAI-LLM-Flash, JD; ``model_type``
+# ``joyai_llm_flash``, which follows DeepSeek-V3's equations key for key;
+# the configuration's keys are those of its ``config.json``).  Attention
+# is **multi-head latent attention**: queries and keys/values go through
+# low-rank products with an RMSNorm between them (``q_rank``,
+# ``kv_rank``); a head's query and key have a part without positions
+# (``qk_nope``) and a rotary part (``qk_rope``, interleaved pairs), and
+# the rotary key is **one head shared by all query heads**; the values
+# are ``v_head`` wide, narrower than the ``qk_nope + qk_rope`` of the
+# keys, which is what ``ops/flash_attention.py``'s two widths are for.
+# The MLP is dense on the leading layers and else a sigmoid router with
+# a selection bias over all ``n_experts`` (LFM2's ``noaux_tc``), this
+# chip's share of the routed experts, **and a shared expert that every
+# token takes**, added to the routed sum.  A **multi-token-prediction
+# module** after the last layer (one more sparse layer on the projected
+# pair of the next token's embedding and the stack's last hidden state)
+# predicts the token after next through the same table and head, and the
+# decoder closes its own loss over both heads.  The plain float32
+# reference it is held to is ``chipbench/reference/joyai_plain.py``,
+# which shares no code with this file (tests/test_joyai.py).
+# ---------------------------------------------------------------------------
+
+#: ``norm_topk_prob``'s guard against an all-zero top-k, as published
+#: (DeepSeek-V3's ``1e-20``: nothing in float32 beside a sum of sigmoids)
+JOYAI_ROUTE_EPS = 1e-20
+# What :class:`JoyaiBlock`'s attention checkpoint keeps beside its
+# input: the flash rule's own two residuals, so the backward pass runs
+# the low-rank products again and not the forward kernel.
+JOYAI_ATTN_KEPT = (FLASH_OUT, FLASH_LSE)
+#: what a sparse layer's branch counts (``sparse_mlp``'s statistics and
+#: the route's own), by the names the step's telemetry, the ``round``
+#: span and the gauges ``mpit_<name>`` give them (``lm/model.py``
+#: ``MOE_STATS`` has the same for the blocks that ``sow``)
+JOYAI_MOE_STATS = ("moe_load_max_over_mean", "moe_held_rows_share",
+                   "moe_compact_share", "moe_bias_flips_share")
+
+
+def rope_interleaved(x: jnp.ndarray, inv_freq: np.ndarray) -> jnp.ndarray:
+    """Rotary embedding over **interleaved** pairs ``(2j, 2j + 1)`` of
+    ``x (B, L, H, D)`` (``rope_interleave``): the pairs are gathered
+    into halves, evens then odds, and rotated as :func:`rope_by` rotates
+    halves.  The result stays in that order, the same permutation of a
+    head's rotary dimensions on queries and keys alike, which no score
+    ``q . k`` sees."""
+    pairs = x.reshape(*x.shape[:-1], x.shape[-1] // 2, 2)
+    return rope_by(jnp.concatenate([pairs[..., 0], pairs[..., 1]], axis=-1),
+                   inv_freq)
+
+
+def latent_attention(x: jnp.ndarray, p: dict, *, heads: int, qk_nope: int,
+                     qk_rope: int, v_head: int, inv_freq: np.ndarray,
+                     eps: float, attn: AttnFn) -> jnp.ndarray:
+    """Multi-head latent attention on the stream ``x (B, L, d)`` with
+    the weights ``p``, projected back to ``(B, L, d)``; pure in both.
+    Every product one bf16 pass on a TPU, as Mellum's: the inner norms
+    hold the scores at O(1) (PERF.md section 6, PR 38)."""
+    b, l, _ = x.shape
+    with jax.named_scope("mla_proj"):
+        h = rms_norm(x, p["attn_norm"], eps)
+        q = rms_norm(h @ p["wq_a"], p["q_a_norm"], eps) @ p["wq_b"]
+        q = q.reshape(b, l, heads, qk_nope + qk_rope)
+        kv_a = h @ p["wkv_a"]                       # (B, L, kv_rank + rope)
+        kv_rank = kv_a.shape[-1] - qk_rope
+        kv = rms_norm(kv_a[..., :kv_rank], p["kv_a_norm"], eps) @ p["wkv_b"]
+        kv = kv.reshape(b, l, heads, qk_nope + v_head)
+        # the rotary key: one head, used by every query head
+        k_rope = rope_interleaved(kv_a[..., None, kv_rank:], inv_freq)
+        q = jnp.concatenate(
+            [q[..., :qk_nope], rope_interleaved(q[..., qk_nope:], inv_freq)],
+            axis=-1)
+        k = jnp.concatenate(
+            [kv[..., :qk_nope],
+             jnp.broadcast_to(k_rope, (b, l, heads, qk_rope))], axis=-1)
+        v = kv[..., qk_nope:]
+    with jax.named_scope("attn"):
+        return attn(q, k, v).reshape(b, l, heads * v_head) @ p["wo"]
+
+
+def swiglu(h: jnp.ndarray, w_gate: jnp.ndarray, w_up: jnp.ndarray,
+           w_down: jnp.ndarray) -> jnp.ndarray:
+    return (jax.nn.silu(h @ w_gate) * (h @ w_up)) @ w_down
+
+
+class JoyaiBlock(nn.Module):
+    d_model: int
+    n_heads: int
+    q_rank: int
+    kv_rank: int
+    qk_nope: int
+    qk_rope: int
+    v_head: int
+    sparse: bool             # the MLP: routed and shared experts, else dense
+    dense_width: int
+    n_experts: int           # the router's width: every expert there is
+    experts_per_tok: int
+    expert_width: int
+    experts_first: int = 0   # the share held here: a contiguous range
+    experts_held: int = 0    # 0: all of them
+    shared_experts: int = 1  # shared experts, each ``expert_width`` wide
+    route_scale: float = 1.0
+    rope_theta: float = 10000.0
+    norm_eps: float = 1e-6
+    attn_fn: Optional[AttnFn] = None
+
+    @nn.compact
+    def __call__(self, x: jnp.ndarray):
+        """``(the stream after the layer, the sparse branch's statistics
+        in :data:`JOYAI_MOE_STATS`' order)``; a dense layer has none,
+        ``()``."""
+        d, eps, hq = self.d_model, self.norm_eps, self.n_heads
+        qk = self.qk_nope + self.qk_rope
+        ones = nn.initializers.ones
+        attn_p = {name: self.param(name, init, shape) for name, init, shape in (
+            ("attn_norm", ones, (d,)),
+            ("wq_a", _INIT, (d, self.q_rank)),
+            ("q_a_norm", ones, (self.q_rank,)),
+            ("wq_b", _INIT, (self.q_rank, hq * qk)),
+            ("wkv_a", _INIT, (d, self.kv_rank + self.qk_rope)),
+            ("kv_a_norm", ones, (self.kv_rank,)),
+            ("wkv_b", _INIT, (self.kv_rank,
+                              hq * (self.qk_nope + self.v_head))),
+            ("wo", _INIT, (hq * self.v_head, d)))}
+        attend = partial(
+            latent_attention, heads=hq, qk_nope=self.qk_nope,
+            qk_rope=self.qk_rope, v_head=self.v_head, eps=eps,
+            inv_freq=plain_inv_freq(self.qk_rope, self.rope_theta),
+            attn=self.attn_fn if self.attn_fn is not None else default_attn())
+        # Kept for the backward pass: the layer's input and the flash
+        # rule's two.  q and k (T x heads x 192 floats each), v and the
+        # latents are made again from the input: five products of which
+        # the largest is 1536 x 6144, a twentieth of the layer's
+        # attention kernels at 8192 positions, for 0.8 GB a layer that
+        # the step does not have (PERF.md section 4, the compile's row).
+        x = x + jax.checkpoint(
+            attend, policy=jax.checkpoint_policies.save_only_these_names(
+                *JOYAI_ATTN_KEPT))(x, attn_p)
+
+        mlp_norm = self.param("mlp_norm", ones, (d,))
+        if not self.sparse:
+            with jax.named_scope("mlp"):
+                return x + swiglu(
+                    rms_norm(x, mlp_norm, eps),
+                    self.param("w_gate", _INIT, (d, self.dense_width)),
+                    self.param("w_up", _INIT, (d, self.dense_width)),
+                    self.param("w_down", _INIT, (self.dense_width, d))), ()
+        y, stats = self.sparse_experts(x, mlp_norm)
+        return x + y, stats
+
+    def sparse_experts(self, x, norm):
+        d, e, f = self.d_model, self.n_experts, self.expert_width
+        held, shared = self.experts_held or e, self.shared_experts * f
+        router = self.param("router", _INIT, (d, e))
+        # the selection bias (``noaux_tc``'s ``e_score_correction_bias``):
+        # as LFM2's, part of the vector, seeded away from zero, reached
+        # by no gradient and updated by no rule
+        bias = self.param("router_bias", _INIT, (e,))
+        routed = tuple(self.param(f"experts_{name}", _INIT, shape)
+                       for name, shape in (("gate", (held, d, f)),
+                                           ("up", (held, d, f)),
+                                           ("down", (held, f, d))))
+        shared_w = tuple(self.param(f"shared_{name}", _INIT, shape)
+                         for name, shape in (("gate", (d, shared)),
+                                             ("up", (d, shared)),
+                                             ("down", (shared, d)))
+                         ) if shared else ()
+
+        # recomputed in the backward pass, as Mellum's and LFM2's and for
+        # their reason; the shared expert with it (three products 768
+        # wide: a hundredth of the step)
+        @jax.checkpoint
+        def branch(x, norm, router, bias, routed, shared_w):
+            def route(logits):
+                scores = jax.nn.sigmoid(logits)
+                weights, chosen = moe.route_top_k(
+                    scores, self.experts_per_tok, renormalise=True,
+                    bias=bias, eps=JOYAI_ROUTE_EPS, scale=self.route_scale)
+                return weights, chosen, (
+                    moe.bias_flips_share(scores, chosen),)
+
+            y, stats = sparse_mlp(
+                x, norm, router, routed, route=route, eps=self.norm_eps,
+                n_experts=e, first=self.experts_first, held=held)
+            if shared_w:
+                with jax.named_scope("shared_expert"):
+                    # every token, whole on every share: counted once
+                    y = y + swiglu(rms_norm(x, norm, self.norm_eps),
+                                   *shared_w)
+            return y, stats
+
+        return branch(x, norm, router, bias, routed, shared_w)
+
+
+class JoyaiDecoder(nn.Module):
+    """Causal LM of :class:`JoyaiBlock` layers with a multi-token-
+    prediction module: a token table (at :data:`MELLUM_EMBED_INIT`'s
+    scale, for its reason: a share of the experts is held), the layers
+    (layer ``i``'s MLP is dense iff ``i < dense_layers``), a final
+    RMSNorm and an untied head; and, where ``mtp_layers`` is 1, the MTP
+    module on the stack's last hidden state ``x_L`` before the final
+    norm: ``z_i = [RMSNorm(Emb(t_{i+1})) | RMSNorm(x_L,i)] W_eh``
+    (embedding first), one sparse layer on ``z``, a final RMSNorm of its
+    own, **the main model's table and head**; it predicts ``t_{i+2}`` at
+    position ``i``.  Like :class:`OuroDecoder` it is called with the
+    targets and returns its own loss with its statistics
+    (``lm/model.py`` closes over it):
+
+    - ``loss``: ``NLL_main + mtp_weight * NLL_mtp``, the main head's
+      mean next-token NLL and the MTP head's mean NLL of the token after
+      next over the positions that have one (every row's last position
+      has none and is masked out of the mean);
+    - ``lm_main_nll``, ``lm_mtp_nll``: the two terms, unweighted;
+    - the routing counters of every sparse layer, the MTP module's
+      last, under ``lm/model.py`` ``MOE_STATS``' names.
+
+    Each head's product, norm and loss is under ``jax.checkpoint``,
+    keeping the rows' log-sum-exp by name (:func:`row_lse`), as
+    :class:`OuroDecoder`'s: the backward pass runs the head's product
+    again and never holds a ``(positions, vocab)`` array of the other
+    head."""
+
+    vocab: int = 256
+    d_model: int = 64
+    n_heads: int = 4
+    q_rank: int = 48
+    kv_rank: int = 32
+    qk_nope: int = 16
+    qk_rope: int = 8
+    v_head: int = 16
+    n_layers: int = 2
+    dense_layers: int = 1
+    dense_width: int = 128
+    n_experts: int = 8
+    experts_per_tok: int = 2
+    expert_width: int = 32
+    experts_first: int = 0
+    experts_held: int = 0
+    shared_experts: int = 1
+    route_scale: float = 1.0
+    mtp_layers: int = 1
+    mtp_weight: float = 0.3
+    rope_theta: float = 10000.0
+    norm_eps: float = 1e-6
+    attn_fn: Optional[AttnFn] = None
+
+    @nn.compact
+    def __call__(self, tokens: jnp.ndarray, targets: jnp.ndarray):
+        if self.mtp_layers not in (0, 1):
+            raise ValueError(f"mtp_layers {self.mtp_layers}: 0 or 1")
+        d, eps = self.d_model, self.norm_eps
+        ones = nn.initializers.ones
+        embed = self.param("embed", MELLUM_EMBED_INIT, (self.vocab, d))
+        head = self.param("head", _INIT, (d, self.vocab))
+
+        def block(sparse, name):
+            return JoyaiBlock(
+                d_model=d, n_heads=self.n_heads, q_rank=self.q_rank,
+                kv_rank=self.kv_rank, qk_nope=self.qk_nope,
+                qk_rope=self.qk_rope, v_head=self.v_head, sparse=sparse,
+                dense_width=self.dense_width, n_experts=self.n_experts,
+                experts_per_tok=self.experts_per_tok,
+                expert_width=self.expert_width,
+                experts_first=self.experts_first,
+                experts_held=self.experts_held,
+                shared_experts=self.shared_experts,
+                route_scale=self.route_scale, rope_theta=self.rope_theta,
+                norm_eps=eps, attn_fn=self.attn_fn, name=name)
+
+        @partial(jax.checkpoint,
+                 policy=jax.checkpoint_policies.save_only_these_names(
+                     HEAD_LSE))
+        def head_nll(u, norm, head, targets):
+            with jax.named_scope("head_loss"):
+                z = rms_norm(u, norm, eps) @ head
+                return row_lse(z) - jnp.take_along_axis(
+                    z, targets[..., None], axis=-1)[..., 0]
+
+        routing = []  # a sparse layer's statistics each, in order
+        with jax.named_scope("embed"):
+            x = embed[tokens]
+        for i in range(self.n_layers):
+            x, counted = block(i >= self.dense_layers, f"JoyaiBlock_{i}")(x)
+            routing += [counted] if counted else []
+        main_nll = head_nll(
+            x, self.param("final_norm", ones, (d,)), head, targets)
+        with jax.named_scope("head_loss"):
+            main = jnp.mean(main_nll)
+        stats = {"lm_main_nll": main[None]}
+        loss = main
+        if self.mtp_layers:
+            with jax.named_scope("mtp"):
+                # position i: the next token's embedding beside the
+                # stack's hidden state; its target the token after next
+                z = jnp.concatenate(
+                    [rms_norm(embed[targets],
+                              self.param("mtp_embed_norm", ones, (d,)), eps),
+                     rms_norm(x, self.param("mtp_hidden_norm", ones, (d,)),
+                              eps)], axis=-1
+                ) @ self.param("mtp_proj", _INIT, (2 * d, d))
+                z, counted = block(True, "mtp_block")(z)
+                routing.append(counted)
+                after_next = jnp.roll(targets, -1, axis=1)
+                nll = head_nll(z, self.param("mtp_final_norm", ones, (d,)),
+                               head, after_next)
+                # a row's last position has no token after next
+                mtp = jnp.mean(nll[:, :-1])
+            loss = main + self.mtp_weight * mtp
+            stats["lm_mtp_nll"] = mtp[None]
+        if routing:
+            stats.update(zip(JOYAI_MOE_STATS, map(jnp.stack, zip(*routing))))
+        return loss, stats

@@ -22,6 +22,12 @@ showcase the rebuild adds on top of capability parity.  Design:
   ``k`` and ``v`` are read where they lie (no repeat is materialised)
   and ``dk``, ``dv`` are summed over the group in the backward kernel's
   own accumulator.  The head counts travel in the shapes.
+- **Two head widths**: ``v`` may be narrower (or wider) than ``q`` and
+  ``k`` (latent attention: 192-wide keys over 128-wide values).  Each
+  is padded to its own lane multiple: the score products and ``dq``,
+  ``dk`` run at the keys' lanes, PV, ``o``, ``do`` and ``dv`` at the
+  values', so a narrow value pays for no lane it does not have.  Equal
+  widths lower to the program they always did.
 - **A sliding window** (``window``, causal): a block wholly outside
   ``[i - window + 1, i]`` is dead like a block above the diagonal.
 - **The grids walk live blocks only** (:class:`_Walk`): for an outer
@@ -672,12 +678,15 @@ def _fold(x, lq_p, d_p):
         g * lq_p, d_p)
 
 
-def _unfold(x, like, lq_p):
+def _unfold(x, like, lq_p, width=None):
     """The inverse of :func:`_fold` on a kernel's row output: back to
-    ``like``'s leading shape, true rows and true width."""
+    ``like``'s leading shape, true rows and true width (``like``'s own,
+    or ``width`` where the output has the values' and not the queries':
+    ``o`` under unequal head widths)."""
+    d = like.shape[-1] if width is None else width
     if like.ndim == 2:
-        return x[:like.shape[0], :like.shape[1]]
-    g, lq, d = like.shape
+        return x[:like.shape[0], :d]
+    g, lq, _ = like.shape
     return x.reshape(g, lq_p, -1)[:, :lq, :d]
 
 
@@ -740,28 +749,32 @@ def _fa_2d(q, k, v, q_offset, kv_offset, *, causal, sm_scale, block_q,
     heads are folded into the rows, so ``k`` and ``v`` are read where
     they lie and never repeated."""
     lq, d = q.shape[-2:]
-    lk = k.shape[0]
+    lk, dv = v.shape
     groups = q.shape[0] if q.ndim == 3 else 1
     scale, bq, bk, lq_p, lk_p, d_p = _tile_dims(
         lq, lk, d, block_q, block_k, sm_scale, q.dtype, fwd_long_bq=True
     )
+    # the values' width, and the output's: the keys' own unless the
+    # heads are of two widths (latent attention: 192-wide keys over
+    # 128-wide values), where PV and ``o`` stay at the values' lanes
+    dv_p = _round_up(dv, LANE)
     qp = _fold(q, lq_p, d_p)
     kp = jnp.pad(k, ((0, lk_p - lk), (0, d_p - d)))
-    vp = jnp.pad(v, ((0, lk_p - lk), (0, d_p - d)))
+    vp = jnp.pad(v, ((0, lk_p - lk), (0, dv_p - dv)))
     rows = groups * lq_p
     walk = _Walk(False, causal, window, bq, bk, lq_p // bq, lk_p // bk,
                  groups)
     held, walked = _walk_specs(walk, d_p)
     if partial:
-        out_specs = (held(), held(LANE), held(LANE))
+        out_specs = (held(dv_p), held(LANE), held(LANE))
         out_shape = (
-            jax.ShapeDtypeStruct((rows, d_p), jnp.float32),
+            jax.ShapeDtypeStruct((rows, dv_p), jnp.float32),
             jax.ShapeDtypeStruct((rows, LANE), jnp.float32),
             jax.ShapeDtypeStruct((rows, LANE), jnp.float32),
         )
     else:
-        out_specs = held()
-        out_shape = jax.ShapeDtypeStruct((rows, d_p), q.dtype)
+        out_specs = held(dv_p)
+        out_shape = jax.ShapeDtypeStruct((rows, dv_p), q.dtype)
     res = pl.pallas_call(
         functools.partial(
             _fa_kernel, walk=walk, scale=scale, partial=partial,
@@ -770,10 +783,10 @@ def _fa_2d(q, k, v, q_offset, kv_offset, *, causal, sm_scale, block_q,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
             grid=walk.grid,
-            in_specs=[held(), walked(), walked()],
+            in_specs=[held(), walked(), walked(dv_p)],
             out_specs=out_specs,
             scratch_shapes=[
-                pltpu.VMEM((bq, d_p), jnp.float32),
+                pltpu.VMEM((bq, dv_p), jnp.float32),
                 pltpu.VMEM((bq, LANE), jnp.float32),
                 pltpu.VMEM((bq, LANE), jnp.float32),
             ],
@@ -784,9 +797,9 @@ def _fa_2d(q, k, v, q_offset, kv_offset, *, causal, sm_scale, block_q,
     )(*_prefetch(walk, q_offset, kv_offset, lk), qp, kp, vp)
     if partial:
         acc, m, l = res
-        return (_unfold(acc, q, lq_p), _unfold_stat(m, q, lq_p),
+        return (_unfold(acc, q, lq_p, dv), _unfold_stat(m, q, lq_p),
                 _unfold_stat(l, q, lq_p))
-    return _unfold(res, q, lq_p)
+    return _unfold(res, q, lq_p, dv)
 
 
 def flash_attention_partial(
@@ -994,7 +1007,7 @@ def _fa_2d_bwd(q, k, v, do, lse, delta, q_offset, kv_offset, *, causal,
     the group's heads inside the kernel, in the same VMEM accumulator.
     """
     lq, d = q.shape[-2:]
-    lk = k.shape[0]
+    lk, dv = v.shape
     groups = q.shape[0] if q.ndim == 3 else 1
     # bwd_long_bk only under the fused schedule: the 32k sweep measured
     # the win THERE (the halved dQ-partials transient is most of it);
@@ -1004,10 +1017,12 @@ def _fa_2d_bwd(q, k, v, do, lse, delta, q_offset, kv_offset, *, causal,
     scale, bq, bk, lq_p, lk_p, d_p = _tile_dims(
         lq, lk, d, block_q, block_k, sm_scale, q.dtype, bwd_long_bk=fused
     )
+    # q, k, dq, dk at the keys' width; v, do, dv at the values'
+    dv_p = _round_up(dv, LANE)
     qp = _fold(q, lq_p, d_p)
     kp = jnp.pad(k, ((0, lk_p - lk), (0, d_p - d)))
-    vp = jnp.pad(v, ((0, lk_p - lk), (0, d_p - d)))
-    dop = _fold(do, lq_p, d_p)
+    vp = jnp.pad(v, ((0, lk_p - lk), (0, dv_p - dv)))
+    dop = _fold(do, lq_p, dv_p)
     lse_r = _rows_to_lanes(lse, lq_p)
     delta_r = _rows_to_lanes(delta, lq_p)
     rows = groups * lq_p
@@ -1027,26 +1042,26 @@ def _fa_2d_bwd(q, k, v, do, lse, delta, q_offset, kv_offset, *, causal,
     walk = _Walk(True, causal, window, bq, bk, lq_p // bq, lk_p // bk,
                  groups)
     held, walked = _walk_specs(walk, d_p)
-    out_specs = [held(), held()]
+    out_specs = [held(), held(dv_p)]
     out_shape = [jax.ShapeDtypeStruct((lk_p, d_p), k.dtype),
-                 jax.ShapeDtypeStruct((lk_p, d_p), v.dtype)]
+                 jax.ShapeDtypeStruct((lk_p, dv_p), v.dtype)]
     if fused:
         out_specs.append(pl.BlockSpec(
             (1, bq, d_p), lambda j, t, *s: (j, walk.fetch(j, t, *s), 0),
             memory_space=pltpu.VMEM))
         out_shape.append(
             jax.ShapeDtypeStruct((walk.kv_blocks, rows, d_p), jnp.float32))
-    dk, dv, *dq_part = pl.pallas_call(
+    dk, dv_out, *dq_part = pl.pallas_call(
         functools.partial(_fa_bwd_kv_kernel, walk=walk, fused=fused, **kw),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
             grid=walk.grid,
-            in_specs=[held(), held(), walked(), walked(), walked(LANE),
-                      walked(LANE)],
+            in_specs=[held(), held(dv_p), walked(), walked(dv_p),
+                      walked(LANE), walked(LANE)],
             out_specs=tuple(out_specs),
             scratch_shapes=[
                 pltpu.VMEM((bk, d_p), jnp.float32),
-                pltpu.VMEM((bk, d_p), jnp.float32),
+                pltpu.VMEM((bk, dv_p), jnp.float32),
             ],
         ),
         out_shape=tuple(out_shape),
@@ -1067,8 +1082,8 @@ def _fa_2d_bwd(q, k, v, do, lse, delta, q_offset, kv_offset, *, causal,
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=5,
                 grid=walk.grid,
-                in_specs=[held(), held(), held(LANE), held(LANE), walked(),
-                          walked()],
+                in_specs=[held(), held(dv_p), held(LANE), held(LANE),
+                          walked(), walked(dv_p)],
                 out_specs=held(),
                 scratch_shapes=[pltpu.VMEM((bq, d_p), jnp.float32)],
             ),
@@ -1077,7 +1092,7 @@ def _fa_2d_bwd(q, k, v, do, lse, delta, q_offset, kv_offset, *, causal,
         )(*_prefetch(walk, q_offset, kv_offset, lk), qp, dop, lse_r,
           delta_r, kp, vp)
 
-    return _unfold(dq, q, lq_p), dk[:lk, :d], dv[:lk, :d]
+    return _unfold(dq, q, lq_p), dk[:lk, :d], dv_out[:lk, :dv]
 
 
 def _use_fused_bwd(q_shape, k_shape, d, dtype, sm_scale, block_q, block_k,
@@ -1310,6 +1325,10 @@ def flash_attention(
     are read where they lie (no repeat is materialised) and ``dk``,
     ``dv`` are summed over the group inside the backward kernel.
 
+    **Two head widths**: ``v (..., Lk, Dv)`` beside ``q, k (..., L,
+    D)``; the output is ``(..., Lq, Dv)`` and the default scale ``1 /
+    sqrt(D)``, the keys' width.
+
     ``window`` (causal only): query ``i`` sees key ``j`` iff ``0 <= i -
     j < window``.  A block wholly outside the window, like one above
     the diagonal or beyond the keys' length, is neither fetched nor
@@ -1339,4 +1358,4 @@ def flash_attention(
                      _interpret(interpret), precision, window)
     out = fa(_group_queries(q, k), k, v, jnp.asarray(q_offset, jnp.int32),
              jnp.asarray(kv_offset, jnp.int32))
-    return out.reshape(q.shape)
+    return out.reshape(*q.shape[:-1], v.shape[-1])

@@ -219,11 +219,16 @@ LAUNCH_DEFAULTS = TRAINER_DEFAULTS.merged(
     # | ouro (the stack of layers run lm_loop_steps times with the same
     # weights, sandwich norms, a head and an exit gate at every pass and
     # a loss of its own over them; it takes lm_kv_heads, lm_head_dim and
-    # lm_dense_width too).
+    # lm_dense_width too) | joyai (latent attention with keys wider than
+    # values, a shared expert beside a share of the routed ones behind
+    # lfm2's router, a multi-token-prediction module and a loss of its
+    # own over both heads; it takes the share, lm_dense_layers,
+    # lm_dense_width and lm_route_scale too).
     # lm_experts .. lm_norm_eps are the sparse blocks'; lm_kv_heads ..
     # lm_yarn_attn_factor are mellum's own, of which lfm2 takes the
     # first four; lm_layer_types .. lm_route_scale are lfm2's own,
-    # lm_loop_steps, lm_exit_beta and lm_exit_bias ouro's
+    # lm_loop_steps, lm_exit_beta and lm_exit_bias ouro's, lm_q_rank ..
+    # lm_mtp_weight joyai's
     # (lm/model.py build has each one's meaning and what 0 stands for)
     lm_arch="gpt2",
     lm_experts=8,
@@ -250,6 +255,14 @@ LAUNCH_DEFAULTS = TRAINER_DEFAULTS.merged(
     lm_loop_steps=4,
     lm_exit_beta=0.1,
     lm_exit_bias=0.0,
+    lm_q_rank=0,
+    lm_kv_rank=0,
+    lm_qk_nope=0,
+    lm_qk_rope=0,
+    lm_v_head=0,
+    lm_shared_experts=1,
+    lm_mtp_layers=1,
+    lm_mtp_weight=0.3,
     lm_d_model=64,
     lm_heads=4,
     lm_layers=2,
@@ -369,12 +382,14 @@ def lm_trainer_cfg(cfg: Config) -> Config:
     """The :data:`mpit_tpu.lm.trainer.LM_DEFAULTS`-shaped config for one
     launch config: shared optimizer/loop knobs carried over verbatim,
     lm_* knobs mapped onto the trainer's names."""
-    from mpit_tpu.lm.model import LFM2_KEYS, MELLUM_KEYS, OURO_KEYS
+    from mpit_tpu.lm.model import (
+        JOYAI_KEYS, LFM2_KEYS, MELLUM_KEYS, OURO_KEYS,
+    )
     from mpit_tpu.lm.trainer import LM_DEFAULTS
 
     return Config(
         **{key: type(LM_DEFAULTS[key])(cfg.get(f"lm_{key}", LM_DEFAULTS[key]))
-           for key in MELLUM_KEYS + LFM2_KEYS + OURO_KEYS},
+           for key in MELLUM_KEYS + LFM2_KEYS + OURO_KEYS + JOYAI_KEYS},
         arch=str(cfg.get("lm_arch", "gpt2")),
         n_experts=int(cfg.get("lm_experts", 8)),
         experts_per_tok=int(cfg.get("lm_experts_per_tok", 2)),

@@ -55,7 +55,7 @@ def test_every_cells_vocabulary_reaches_the_program_through_the_launcher():
     from chipbench import run as runner
 
     want = {"gpt2": 50257, "olmoe": 50304, "mellum": 12288, "lfm2_moe": 8192,
-            "ouro": 49152}
+            "ouro": 49152, "joyai_llm_flash": 16160}
     for name in CELLS:
         cell = spec_mod.load_cell(name)
         cfg = runner.launch_config(cell, seed=5)
@@ -73,7 +73,8 @@ def test_scopes_come_from_every_committed_configuration():
     # lists, in order of first mention
     assert spantree.model_scopes({}) == [
         "embed", "attn", "mlp", "head_loss", "update", "router", "dispatch",
-        "experts", "attn_window", "conv", "conv_mix", "exit_gate"]
+        "experts", "attn_window", "conv", "conv_mix", "exit_gate", "mla_proj",
+        "shared_expert"]
 
 
 def olmoe_cases():
@@ -532,8 +533,10 @@ def test_the_parent_fails_the_new_cell_at_once():
     cell on the parent needs."""
     bench = spec_mod.load_bench()
     names = [w["name"] for w in bench["workloads"]]
-    assert names[-2:] == [LFM2_CELL, OURO_CELL] and len(names) == 7
-    for missing in ("lfm2-l5e8-locals", "ouro-l6-locals"):
+    assert names[-3:] == [LFM2_CELL, OURO_CELL, JOYAI_CELL] \
+        and len(names) == 8
+    for missing in ("lfm2-l5e8-locals", "ouro-l6-locals",
+                    "joyai-l5e8-locals"):
         with pytest.raises(spec_mod.SpecError, match="no workload"):
             spec_mod.load_cell(missing)
 
@@ -699,3 +702,251 @@ def test_ouros_readers_read_a_hand_made_run(monkeypatch):
     for metric in cell.metrics("per_layer"):
         assert spec_mod.load_reader(cell.root, cell.bench,
                                     metric["name"]) is not None
+
+
+# -- the JoyAI-LLM-Flash configuration (PR 38) -----------------------------------
+
+JOYAI_CELL = "joyai-l5e8-local"
+JOYAI_METRICS = ("mla_proj_ms_per_step", "shared_expert_ms_per_step",
+                 "mtp_ms_per_step", "mtp_nll_gap_nats")
+JOYAI_APPENDED = ("dispatch_ms_per_step", "expert_load_max_over_mean",
+                  "held_experts_ms_per_step", "held_experts_roofline",
+                  "held_rows_share_pct", "router_bias_flips_pct",
+                  "compact_dispatch_pct")
+
+
+def test_joyai_file_has_the_catalogs_keys_and_the_three_floor_cuts():
+    """Every number of the catalog's entry under its own key (the
+    model-configs guide's ``architectures.jsonl``, read where it is
+    installed; the hand-copied numbers below where it is not); only the
+    depth, the experts held and the vocabulary differ, each at the
+    guide's floor, with the published counts beside them; no width is
+    cut."""
+    import pathlib
+
+    cell = spec_mod.load_cell(JOYAI_CELL)
+    config = cell.config
+    catalog = {
+        "attention_bias": False, "ep_size": 1, "first_k_dense_replace": 1,
+        "head_dim": 64, "hidden_act": "silu", "hidden_size": 2048,
+        "intermediate_size": 7168, "kv_lora_rank": 512,
+        "max_position_embeddings": 131072, "model_type": "joyai_llm_flash",
+        "moe_intermediate_size": 768, "moe_layer_freq": 1, "n_group": 1,
+        "n_routed_experts": 256, "n_shared_experts": 1,
+        "norm_topk_prob": True, "num_attention_heads": 32,
+        "num_experts_per_tok": 8, "num_hidden_layers": 40,
+        "num_key_value_heads": 32, "num_nextn_predict_layers": 1,
+        "q_lora_rank": 1536, "qk_head_dim": 192, "qk_nope_head_dim": 128,
+        "qk_rope_head_dim": 64, "rms_norm_eps": 1e-06,
+        "rope_interleave": True, "rope_scaling": None,
+        "rope_theta": 32000000, "routed_scaling_factor": 2.5,
+        "scoring_func": "sigmoid", "tie_word_embeddings": False,
+        "topk_group": 1, "topk_method": "noaux_tc", "v_head_dim": 128,
+        "vocab_size": 129280}
+    path = pathlib.Path(
+        "/opt/skills/guides/model-configs/architectures.jsonl")
+    if path.exists():
+        rows = [json.loads(line) for line in path.read_text().splitlines()]
+        entry = next(r for r in rows if r["name"] == "JoyAI-LLM-Flash")
+        assert entry["config"] == catalog
+        assert entry["source_url"] == config["source"]
+    assert all(key in config for key in catalog)
+    differ = sorted(k for k, v in catalog.items() if config[k] != v)
+    assert differ == sorted(config["reduced"]) == [
+        "n_routed_experts", "num_hidden_layers", "vocab_size"]
+    assert config["published"] == {k: catalog[k] for k in config["reduced"]}
+    # the floors: the dense layer and four sparse layers after it (one
+    # layer is the period), 8 experts, an eighth of the vocabulary
+    assert (config["num_hidden_layers"], config["n_routed_experts"],
+            config["vocab_size"]) == (5, 8, 129280 // 8)
+    assert config["router_experts"] == 256   # the router keeps its width
+    assert config["num_experts"] == config["n_routed_experts"]
+    assert (config["train_seq"], config["mtp_loss_weight"]) == (8192, 0.3)
+    entry = next(c for c in cell.bench["configs"]
+                 if c["name"] == cell.config_name)
+    assert sorted(entry["reduced"]) == differ
+    assert entry["source"] == config["source"]
+    assert (cell.chips, cell.traffic_name) == (1, "local-msgd-s8k-joyai")
+    assert ["embed", "mla_proj", "attn", "mlp", "router", "dispatch",
+            "experts", "shared_expert", "head_loss",
+            "update"] == config["scopes"]
+    assert all(key in config for key in config["launcher_from"].values())
+    assert all(key in config for key in config["tiny"])
+    assert len(config["assumed"]) >= 8 and "32 v5e chips" in \
+        config["deployment"]
+    assert cell.arithmetic().param_count(config) == 491_697_408
+
+
+def test_the_launcher_builds_the_latent_block_from_the_cells_files():
+    from chipbench import run as runner
+    from mpit_tpu.lm.model import build_kw
+    from mpit_tpu.train.launch import lm_trainer_cfg
+
+    cell = spec_mod.load_cell(JOYAI_CELL)
+    kw = build_kw(lm_trainer_cfg(runner.launch_config(cell, seed=5)))
+    assert (kw["arch"], kw["d_model"], kw["n_heads"], kw["n_layers"],
+            kw["seq_len"], kw["vocab"]) == ("joyai", 2048, 32, 5, 8192, 16160)
+    assert (kw["q_rank"], kw["kv_rank"], kw["qk_nope"], kw["qk_rope"],
+            kw["v_head"]) == (1536, 512, 128, 64, 128)
+    assert (kw["dense_layers"], kw["dense_width"], kw["n_experts"],
+            kw["experts_held"], kw["experts_first"], kw["experts_per_tok"],
+            kw["expert_width"], kw["shared_experts"]) \
+        == (1, 7168, 256, 8, 0, 8, 768, 1)
+    assert (kw["route_scale"], kw["mtp_layers"], kw["mtp_weight"],
+            kw["rope_theta"], kw["norm_eps"]) == (2.5, 1, 0.3, 32e6, 1e-6)
+
+
+def test_joyais_mix_keeps_to_the_traffic_its_issue_fixed():
+    """ISSUE 38 fixed the mix before any code was written: the rate one
+    of three, the budget a fifth to a half of the micro-steps a 51 s run
+    makes (whole sequences of 8192), momentum 0.9, two rounds of
+    warm-up, closed loop in one process; the four new metrics and the
+    seven appended ones are the cell's, and the MTP gap moves the loss,
+    not the rate."""
+    cell = spec_mod.load_cell(JOYAI_CELL)
+    mix = cell.traffic
+    steps, rest = divmod(mix["token_budget"],
+                         mix["batch"] * cell.config["train_seq"])
+    assert rest == 0 and steps >= 8
+    assert mix["lr"] in (0.003, 0.01, 0.03)
+    assert (mix["launcher"]["mom"], mix["warmup_rounds"], mix["su"],
+            mix["batch"], mix["launcher"]["np"]) == (0.9, 2, 1, 1, 1)
+    moves = {m["name"]: m["moves"] for m in cell.metrics("per_layer")}
+    assert set(JOYAI_METRICS + JOYAI_APPENDED) <= set(moves)
+    assert moves["mtp_nll_gap_nats"] == "loss_at_budget"
+    assert {moves[m] for m in JOYAI_METRICS[:3]} == {"tokens_per_s"}
+    for metric in cell.bench["per_layer"]:
+        if metric["name"] in JOYAI_METRICS:
+            assert metric["workloads"] == [JOYAI_CELL]
+        elif metric["name"] in JOYAI_APPENDED:
+            assert metric["workloads"][-1] == JOYAI_CELL
+
+
+def test_joyais_readers_find_nothing_in_a_run_without_the_block():
+    """What the parent's traced run hands them: a cell whose
+    configuration lists no such scope, a program that recorded no such
+    counter, no device trace, no merged trace: None, no raise."""
+    for name in ("lfm2-l5e8-local", "c111m-local"):
+        cell = spec_mod.load_cell(name)
+        run = {"cell": cell, "reduction": {"step_module": "jit__lambda"},
+               "obs_trace": None, "peaks": None, "results": {},
+               "summary": {"worker_ranks": [0], "window": [0.0, 1.0]}}
+        for metric in JOYAI_METRICS:
+            reader = spec_mod.load_reader(cell.root, cell.bench, metric)
+            assert reader is not None and reader(dict(run)) is None
+
+
+def test_joyais_readers_read_a_hand_made_run(monkeypatch):
+    """The four readers, the seven shared ones and the metrics without a
+    ``workloads`` list that the cell has to report, on a scope table, a
+    chip's operations and a span tree made by hand."""
+    from chipbench import flops
+    from chipbench.layers import spantree
+
+    cell = spec_mod.load_cell(JOYAI_CELL)
+    listed = [m["name"] for m in cell.metrics("per_layer")]
+    unlisted = [m["name"] for m in cell.bench["per_layer"]
+                if "workloads" not in m]
+    assert len(unlisted) == 10 and set(unlisted) <= set(listed)
+
+    class Round:
+        def __init__(self, k, main, mtp):
+            self.args = {"round": k, "lm_main_nll": [main],
+                         "lm_mtp_nll": [mtp],
+                         "moe_held_rows_share": [0.03125] * 5,
+                         "moe_load_max_over_mean": [2.0, 2.5, 2.25, 2.0, 3.0],
+                         "moe_compact_share": [1.0] * 5,
+                         "moe_bias_flips_share": [0.1] * 5}
+
+    class Tree:
+        def rounds(self):
+            return [Round(7, 3.0, 3.5), Round(8, 2.5, 3.25),
+                    Round(9, 2.0, 3.0)]
+
+    table = {"step": 400.0, "mla_proj": 40.0, "attn": 200.0, "mlp": 20.0,
+             "router": 6.0, "dispatch": 9.0, "experts": 12.0,
+             "shared_expert": 5.0, "head_loss": 30.0, "update": 25.0}
+    # two runs of the step's program; of four operations one outside
+    # them and three inside, two of these under ``mtp``
+    chip = {"plane": "/device:TPU:0", "lo": 0, "hi": 10_000_000,
+            "modules": [("jit__lambda(1)", 1_000_000, 2_000_000),
+                        ("jit__lambda(1)", 5_000_000, 2_000_000)],
+            "ops": [("a", 1_100_000, 300_000), ("b", 1_500_000, 200_000),
+                    ("c", 5_100_000, 500_000), ("a", 8_000_000, 900_000)]}
+    stacks = {"a": "jit(f)/jvp(JoyaiDecoder)/mtp/mtp_block/attn/dot",
+              "b": "jit(f)/jvp(JoyaiDecoder)/JoyaiBlock_1/attn/dot",
+              "c": "jit(f)/transpose(jvp(JoyaiDecoder))/mtp/head_loss/dot"}
+    monkeypatch.setattr(spantree, "scope_ms_per_step", lambda run: table)
+    monkeypatch.setattr(spantree, "xplane_path", lambda run: None)
+    monkeypatch.setattr(spantree, "traced_chip", lambda run: chip)
+    monkeypatch.setattr(spantree, "op_scopes", lambda path, plane: stacks)
+    run = {"cell": cell, "peaks": flops.load_peaks("TPU v5 lite"),
+           spantree.CACHE_KEY: Tree(),
+           "summary": {"tokens_per_s": 20000.0, "worker_ranks": [0]},
+           "reduction": {"step_module": "jit__lambda", "step_module_runs": 2,
+                         "mosaic_by_scope": {
+                             "attn": (36, 0.360), "experts": (90, 0.020),
+                             "update": (2, 0.030)}}}
+
+    def read(name):
+        return spec_mod.load_reader(cell.root, cell.bench, name)(run)
+
+    assert read("mla_proj_ms_per_step") == pytest.approx(40.0)
+    assert read("shared_expert_ms_per_step") == pytest.approx(5.0)
+    # (0.3 + 0.5) ms over two runs of the step
+    assert read("mtp_ms_per_step") == pytest.approx(0.4)
+    assert read("mtp_nll_gap_nats") == pytest.approx(0.75)
+    assert read("dispatch_ms_per_step") == pytest.approx(15.0)
+    assert read("held_experts_ms_per_step") == pytest.approx(12.0)
+    assert read("held_rows_share_pct") == pytest.approx(3.125)
+    assert read("expert_load_max_over_mean") == pytest.approx(3.0)
+    assert read("router_bias_flips_pct") == pytest.approx(10.0)
+    assert read("compact_dispatch_pct") == pytest.approx(100.0)
+    assert read("head_loss_ms_per_step") == pytest.approx(30.0)
+    assert read("flash_ms_per_step") == pytest.approx(180.0)
+    family = cell.arithmetic().kernels(cell.config, 1)["attn"]
+    assert read("flash_roofline") == pytest.approx(
+        100 * family["flops"] / 197e12 / 0.180)
+    experts = cell.arithmetic().experts_cost(cell.config, 1)
+    assert read("held_experts_roofline") == pytest.approx(
+        100 * max(experts["flops"] / 197e12, experts["bytes"] / 819e9)
+        / 0.010)
+    assert read("mfu_pct") == pytest.approx(
+        100 * 3_362_967_552 * 20000.0 / 197e12)
+    for metric in cell.metrics("per_layer"):
+        assert spec_mod.load_reader(cell.root, cell.bench,
+                                    metric["name"]) is not None
+
+
+def joyai_cases():
+    return spec_mod.load_cell(JOYAI_CELL).arithmetic().hand_worked()
+
+
+@pytest.mark.parametrize("what,got,want", joyai_cases(),
+                         ids=[c[0] for c in joyai_cases()])
+def test_joyai_arithmetic_by_hand_through_the_cell(what, got, want):
+    assert got == want, what
+
+
+def _one_line_fields():
+    bench = json.loads((spec_mod.ROOT / "BENCHMARK.json").read_text())
+    out = [("command", " ".join(bench["command"]))]
+    for entry in bench["configs"]:
+        out += [(f"configs.{entry['name']}.why", entry["why"]),
+                (f"configs.{entry['name']}.source", entry["source"])]
+    out += [(f"workloads.{w['name']}.why", w["why"])
+            for w in bench["workloads"]]
+    out += [(f"per_layer.{m['name']}.layer", m["layer"])
+            for m in bench["per_layer"]]
+    return out
+
+
+@pytest.mark.parametrize("where,text", _one_line_fields(),
+                         ids=[f[0] for f in _one_line_fields()])
+def test_a_line_of_the_benchmark_file_keeps_to_200_printable_characters(
+        where, text):
+    # The driver refuses BENCHMARK.json before any run for a `why`, a
+    # `layer` or a `source` outside 1 to 200 characters on one line (PR
+    # 38's first check: the new cell's `why` had 220).
+    assert 1 <= len(text) <= 200, (where, len(text))
+    assert text.isprintable(), where
