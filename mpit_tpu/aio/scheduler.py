@@ -341,6 +341,7 @@ def aio_send(
     cb: Optional[Callable[[Any], None]] = None,
     deadline: Optional[float] = None,
     abort: Optional[Callable[[], bool]] = None,
+    ready: Optional[Callable[[], int]] = None,
 ) -> Generator[str, None, None]:
     """Nonblocking send: post, then poll-test until complete.
 
@@ -354,8 +355,20 @@ def aio_send(
     ``abort`` is polled between steps; returning True cancels the send
     and returns None (the lease-eviction path: a server must stop waiting
     on a peer its lease registry has declared dead).
+
+    ``ready``: ``data`` is still being written, front to back, and
+    ``ready()`` says how many of its bytes are whole (never fewer than it
+    said before).  The send is posted with that mark and every poll moves
+    the mark to what ``ready()`` says now, so the bytes leave as they
+    become whole; for a transport that can hold such a send (``extend``,
+    ``comm/transport.py``).  If ``ready()`` raises (whoever writes the
+    rest has failed) the send is cancelled part-way and the error is the
+    task's: the peer never takes the message for whole.
     """
-    handle = transport.isend(data, dst, tag)
+    if ready is None:
+        handle = transport.isend(data, dst, tag)
+    else:
+        handle = transport.isend(data, dst, tag, ready=ready())
     while not transport.test(handle):
         if live is not None and not live.io:
             transport.cancel(handle)
@@ -367,6 +380,12 @@ def aio_send(
             transport.cancel(handle)
             raise DeadlineExceeded("send", dst, tag, time.monotonic() - deadline)
         yield EXEC
+        if ready is not None:
+            try:
+                transport.extend(handle, ready())
+            except BaseException:
+                transport.cancel(handle)
+                raise
     if cb is not None:
         cb(handle)
 

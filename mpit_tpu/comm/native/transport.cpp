@@ -44,6 +44,13 @@
 //    receive and the cancelled buffer is not written again.
 //  * Per-destination FIFO send queues give MPI-style non-overtaking order
 //    between any (src, dst) pair.
+//  * A send may be posted before its bytes are whole: it carries a ready
+//    mark, the bytes of its buffer that may be read, which the caller
+//    moves forward (mt_send_extend) as the rest is written.  Chunks are
+//    placed up to the mark and no further, the op is done only at its
+//    length, and a later send to the same rank waits behind it as behind a
+//    full ring.  The receiver sees the header, the `total_bytes` and the
+//    bytes it always saw; only the instants differ.
 //  * All progress happens inside mt_iprobe/mt_test calls from the caller's
 //    cooperative scheduler — single-threaded per process, like the
 //    reference's coroutine polling (reference init.lua:147-185).
@@ -169,13 +176,17 @@ uint64_t since(uint64_t later, uint64_t earlier) {
 
 // Where a sent message's time went, first attempt to place a chunk to last
 // chunk published.  What is neither `copy_ns` nor `blocked_ns` of that is
-// the sender's time away: the ring had room and its thread was elsewhere.
+// the sender's time away: the ring had room and its thread was elsewhere,
+// or (`unready_ns`, a part of it) here with no byte under the op's mark left.
 struct TxTiming {
   uint64_t t_first = 0;
   uint64_t t_done = 0;
   uint64_t copy_ns = 0;     // inside circ_write
   uint64_t blocked_ns = 0;  // a refused placement to the next accepted one
   uint64_t t_refused = 0;   // the refusal still waited out; 0: none
+  uint64_t unready_ns = 0;  // a pass that found the mark reached, to the
+                            // next attempt with a byte to place
+  uint64_t t_unready = 0;   // that pass, still waited out; 0: none
   uint32_t refused = 0;     // the polls, as tx_ring_full counts them
   uint32_t chunks = 0;
 };
@@ -246,7 +257,9 @@ struct SendOp {
   int tag = 0;
   const uint8_t* data = nullptr;
   uint64_t len = 0;
+  uint64_t ready = 0;    // bytes of `data` that may be read (<= len): the mark
   uint64_t written = 0;  // payload bytes already placed in the ring
+  uint64_t early_bytes = 0;  // those placed while the mark was short of len
   uint64_t msg_id = 0;
   uint32_t nchunks = 0;
   uint32_t next_chunk = 0;
@@ -297,11 +310,13 @@ struct Ctx {
   // Chunks placed in a peer's ring, placements refused by a full ring (the
   // sender waited for the owner), chunks copied out of an own ring, and
   // those of them during whose copy the ring's head moved (the sender was
-  // copying into the ring at the same time) (mt_ring_counts).
+  // copying into the ring at the same time), and payload bytes placed while
+  // their op's ready mark was short of its length (mt_ring_counts).
   uint64_t tx_chunks = 0;
   uint64_t tx_ring_full = 0;
   uint64_t rx_chunks = 0;
   uint64_t rx_overlap_chunks = 0;
+  uint64_t tx_early_bytes = 0;
   // While `timing` (mt_set_timing): ns inside circ_write, inside circ_read
   // and the hand-over memcpy, and inside progress() with that memcpy
   // (mt_wire_ns).  Less the two copies the last is the cost of polling.
@@ -606,9 +621,12 @@ void drop_recv(Ctx* ctx, std::map<int64_t, RecvOp>::iterator it) {
 // Place more chunks of the front send ops of each destination, at most one
 // ring's worth of bytes a destination and pass: with the owner draining
 // beside it the ring may never fill, and the caller's thread has its other
-// destinations, its inbox and its deadlines to look at.  While timing, the
-// payload goes in first and the header, stamped with the instant, after
-// it; both lie in the ring before `head` says so either way.
+// destinations, its inbox and its deadlines to look at.  A chunk ends at
+// the op's ready mark at the latest, and is cut short of a whole one only
+// where nothing more is ready; an op at its mark and short of its length
+// stays at the front of its queue, like one before a full ring.  While
+// timing, the payload goes in first and the header, stamped with the
+// instant, after it; both lie in the ring before `head` says so either way.
 void pump_sends(Ctx* ctx) {
   const bool timing = ctx->timing;
   for (auto& [dst, queue] : ctx->send_q) {
@@ -629,12 +647,20 @@ void pump_sends(Ctx* ctx) {
       uint64_t head = ring.idx->head.load(std::memory_order_relaxed);
       bool full = false;
       while (!op.done) {
-        uint64_t remaining = op.len - op.written;
+        uint64_t remaining = op.ready - op.written;
         uint64_t chunk = remaining < chunk_max ? remaining : chunk_max;
         uint64_t need = sizeof(ChunkHeader) + chunk;
         if (need > budget) break;
         const uint64_t t_try = timing ? now_ns() : 0;
         if (timing && op.tt.t_first == 0) op.tt.t_first = t_try;
+        if (chunk == 0 && op.len > 0) {  // at the mark: the caller's to move
+          if (timing && op.tt.t_unready == 0) op.tt.t_unready = t_try;
+          break;
+        }
+        if (timing && op.tt.t_unready != 0) {
+          op.tt.unready_ns += t_try - op.tt.t_unready;
+          op.tt.t_unready = 0;
+        }
         uint64_t used = head - ring.idx->tail.load(std::memory_order_acquire);
         if (ring.capacity - used < need) {
           ctx->tx_ring_full++;
@@ -674,6 +700,10 @@ void pump_sends(Ctx* ctx) {
         ring.idx->head.store(head, std::memory_order_release);
         budget -= need;
         ctx->tx_chunks++;
+        if (op.ready < op.len) {
+          op.early_bytes += chunk;
+          ctx->tx_early_bytes += chunk;
+        }
         op.written += chunk;
         op.next_chunk++;
         op.stalls = 0;
@@ -740,7 +770,10 @@ void mt_finalize(void* vctx) {
 int mt_rank(void* vctx) { return static_cast<Ctx*>(vctx)->rank; }
 int mt_nranks(void* vctx) { return static_cast<Ctx*>(vctx)->nranks; }
 
-int64_t mt_isend(void* vctx, int dst, int tag, const void* data, uint64_t len) {
+// A send of which only the first `ready` bytes of `data` may be read yet
+// (`len` or more: all of them); mt_send_extend moves the mark.
+int64_t mt_isend_marked(void* vctx, int dst, int tag, const void* data,
+                        uint64_t len, uint64_t ready) {
   auto* ctx = static_cast<Ctx*>(vctx);
   if (dst < 0 || dst >= ctx->nranks) return -1;
   SendOp op;
@@ -748,12 +781,31 @@ int64_t mt_isend(void* vctx, int dst, int tag, const void* data, uint64_t len) {
   op.tag = tag;
   op.data = static_cast<const uint8_t*>(data);
   op.len = len;
+  op.ready = ready < len ? ready : len;
   op.msg_id = ctx->next_msg_id++;
   int64_t handle = ctx->next_handle++;
   ctx->sends[handle] = op;
   ctx->send_q[dst].push_back(handle);
   progress(ctx);
   return handle;
+}
+
+int64_t mt_isend(void* vctx, int dst, int tag, const void* data, uint64_t len) {
+  return mt_isend_marked(vctx, dst, tag, data, len, len);
+}
+
+// Move a pending send's ready mark to `ready` bytes: forward only, and to
+// its length at most.  Returns the mark after the call, or -1 for a handle
+// that is no pending send (unknown, cancelled, or done and forgotten).  The
+// next call that makes progress places what became ready.
+int64_t mt_send_extend(void* vctx, int64_t handle, uint64_t ready) {
+  auto* ctx = static_cast<Ctx*>(vctx);
+  auto sit = ctx->sends.find(handle);
+  if (sit == ctx->sends.end() || sit->second.cancelled) return -1;
+  SendOp& op = sit->second;
+  if (ready > op.len) ready = op.len;
+  if (ready > op.ready) op.ready = ready;
+  return (int64_t)op.ready;
 }
 
 int64_t mt_irecv(void* vctx, int src, int tag, void* out, uint64_t cap) {
@@ -872,12 +924,13 @@ uint64_t mt_rx_bytes(void* vctx, int32_t which) {
 // in its peers' rings; 1, placements a full ring refused (the sender waited
 // for the owner); 2, chunks copied out of the own rings; 3, those of them
 // during whose copy the ring's head moved: sender and owner were copying
-// at the same time.
+// at the same time; 4, payload bytes placed while their op's ready mark was
+// short of its length.
 uint64_t mt_ring_counts(void* vctx, int32_t which) {
   auto* ctx = static_cast<Ctx*>(vctx);
   const uint64_t counts[] = {ctx->tx_chunks, ctx->tx_ring_full, ctx->rx_chunks,
-                             ctx->rx_overlap_chunks};
-  return which >= 0 && which < 4 ? counts[which] : 0;
+                             ctx->rx_overlap_chunks, ctx->tx_early_bytes};
+  return which >= 0 && which < 5 ? counts[which] : 0;
 }
 
 // The one switch of the wire's timing: on, every message keeps a record of
@@ -895,8 +948,9 @@ void mt_set_timing(void* vctx, int32_t on) {
 // and [3] t_done, ns on CLOCK_MONOTONIC; [4] copy_ns; [5] blocked_ns of a
 // send, starved_ns of a receive; [6] away_ns; [7] chunks; [8] refused
 // placements of a send, overlapped chunks of a receive; [9] a receive that
-// landed in its posted buffer; [10] a receive's t_first_pub; [11] bytes.
-constexpr int32_t kTimingWords = 12;
+// landed in its posted buffer; [10] a receive's t_first_pub; [11] bytes;
+// [12] a send's early_bytes and [13] its unready_ns (a part of [6]).
+constexpr int32_t kTimingWords = 14;
 
 int32_t mt_op_timing(void* vctx, int64_t handle, void* vout) {
   auto* ctx = static_cast<Ctx*>(vctx);
@@ -910,7 +964,7 @@ int32_t mt_op_timing(void* vctx, int64_t handle, void* vout) {
     const uint64_t words[kTimingWords] = {
         1, op.msg_id, tt.t_first, tt.t_done, tt.copy_ns, tt.blocked_ns,
         since(tt.t_done - tt.t_first, busy), tt.chunks, tt.refused, 0,
-        tt.t_first, op.len};
+        tt.t_first, op.len, op.early_bytes, tt.unready_ns};
     std::memcpy(out, words, sizeof(words));
     return kTimingWords;
   }
@@ -922,7 +976,7 @@ int32_t mt_op_timing(void* vctx, int64_t handle, void* vout) {
     const uint64_t words[kTimingWords] = {
         2, rt.msg_id, rt.t_first, rt.t_done, rt.copy_ns, rt.starved_ns,
         rt.away_ns, rt.chunks, rt.overlap_chunks, op.bound ? 1u : 0u,
-        rt.t_first_pub, op.size};
+        rt.t_first_pub, op.size, 0, 0};
     std::memcpy(out, words, sizeof(words));
     return kTimingWords;
   }
@@ -1089,7 +1143,7 @@ void mt_fold_f32(const void* vown, const void* vptrs, int32_t nchildren,
 // generated _bindings.py refuses a stale .so (loud rebuild message)
 // instead of failing with a confusing missing-symbol AttributeError.
 // Keep in sync with MT_API_VERSION in gen_bindings.py.
-int64_t mt_api_version(void) { return 17004; }
+int64_t mt_api_version(void) { return 17005; }
 
 }  // extern "C"
 

@@ -36,6 +36,21 @@ elsewhere; ``rx``: a chunk lay published and the owner was not copying it
 out: asleep in the back-off, in another ring, in Python, off the core).
 ``wire_totals`` are the endpoint's own sums, acks and headers included.
 
+A send whose bytes become ready while it is on the wire (the optional
+capability of :class:`~mpit_tpu.comm.transport.Transport`): ``isend(...,
+ready=n)`` posts a send of which only the first ``n`` bytes may be read
+yet, ``extend(handle, n)`` moves that mark forward as the caller writes
+the rest, and ``test`` is true only once the whole length has been
+placed.  The peer receives the message it always received (same header,
+same chunks in the same order); a later ``isend`` to the same rank waits
+behind the unfinished one.  A ``tx`` span then also says ``early_bytes``
+(placed while the mark was short of the length) and ``unready_ms``: the
+ring had room, the thread was here and no byte under the mark was left.
+That wait is the caller's own staging, so it is a part of ``away_ms``,
+noted beside it, and never ``blocked_ms``; the receiver sees it as
+``starved_ms``.  ``tx_early_bytes`` in ``ring_counters`` is the sum of
+``early_bytes`` over all sends, counted with obs off too.
+
 Zero-copy discipline: sends pass the numpy buffer's raw pointer to C and
 the Handle holds the array reference until completion.  A receive posted
 with a buffer *before* its message's first chunk is drained lands in that
@@ -70,7 +85,7 @@ from mpit_tpu.obs import metrics as _obs
 from mpit_tpu.obs import spans as _spans
 
 #: words of a native timing record (transport.cpp ``mt_op_timing``)
-_TIMING_WORDS = 12
+_TIMING_WORDS = 14
 
 
 @functools.lru_cache(maxsize=1)
@@ -136,15 +151,31 @@ class ShmTransport(Transport):
 
     # -- Transport ----------------------------------------------------------
 
-    def isend(self, data: Any, dst: int, tag: int) -> Handle:
+    def isend(self, data: Any, dst: int, tag: int,
+              ready: Optional[int] = None) -> Handle:
+        """``ready``: the bytes of ``data`` that may be read now, where
+        the caller is still writing the rest (see :meth:`extend`); absent,
+        all of them."""
         buf = self._sendable(data)
         nbytes = buf.nbytes if isinstance(buf, np.ndarray) else len(buf)
-        native = self.lib.mt_isend(self._ctx, dst, tag, buf, nbytes)
+        native = self.lib.mt_isend_marked(
+            self._ctx, dst, tag, buf, nbytes,
+            nbytes if ready is None else ready)
         if native < 0:
             raise ValueError(f"isend to invalid rank {dst}")
         self._m_tx_msgs[dst].inc()
         self._m_tx_bytes[dst].inc(nbytes)
         return Handle(kind="send", peer=dst, tag=tag, buf=buf, native_id=native)
+
+    def extend(self, handle: Handle, ready: int) -> int:
+        """Move the ready mark of the pending send ``handle`` to
+        ``ready`` bytes: forward only, and to the send's length at most.
+        Returns the mark after the call (-1: the send is no longer
+        pending).  The next ``test`` places what became ready."""
+        if handle.done or handle.cancelled:
+            return -1
+        return int(self.lib.mt_send_extend(self._ctx, handle.native_id,
+                                           ready))
 
     def irecv(self, src: int, tag: int, out: Any | None = None) -> Handle:
         if out is None:
@@ -242,11 +273,12 @@ class ShmTransport(Transport):
         its peers' rings and placements a full ring refused (it waited for
         the owner's drain); chunks it copied out of its own rings and those
         of them during whose copy the sender moved the ring's head (both
-        sides were copying at once)."""
+        sides were copying at once); payload bytes it placed while their
+        send's ready mark was short of its length."""
         return {key: int(self.lib.mt_ring_counts(self._ctx, which))
                 for which, key in enumerate((
                     "tx_chunks", "tx_ring_full", "rx_chunks",
-                    "rx_overlap_chunks"))}
+                    "rx_overlap_chunks", "tx_early_bytes"))}
 
     def wire_counts(self) -> dict:
         return {**self.rx_path_bytes(), **self.ring_counters()}
@@ -268,13 +300,14 @@ class ShmTransport(Transport):
                                      self._record):
             return
         (kind, msg_id, t_first, t_done, copy, wait, away, chunks, count,
-         direct, t_pub, nbytes) = self._record.tolist()
+         direct, t_pub, nbytes, early, unready) = self._record.tolist()
         if nbytes < _spans.WIRE_SPAN_MIN_BYTES:
             return
         args = {"bytes": nbytes, "msg_id": msg_id, "chunks": chunks,
                 "copy_ms": copy / 1e6, "away_ms": away / 1e6}
         if kind == 1:
             args.update(blocked_ms=wait / 1e6, refused=count,
+                        early_bytes=early, unready_ms=unready / 1e6,
                         flight_ms=(t_done - t_first) / 1e6)
         else:
             args.update(starved_ms=wait / 1e6, overlap_chunks=count,

@@ -11,6 +11,16 @@ Buffer discipline (the reference's zero-copy rule, lua-mpi.h:70-78): the
 caller passes numpy arrays / memoryviews; the transport reads from or
 writes into them directly.  A send buffer must stay alive and unmodified
 until ``test`` returns True; handles hold a reference to enforce liveness.
+
+An optional capability, which a transport has if it has the method
+``extend`` (``comm/shm.py`` has; ``tcp`` and ``local`` have not, and
+callers test for it by name): a send whose bytes become ready while it is
+on the wire.  ``isend(data, dst, tag, ready=n)`` posts a send of which
+only the first ``n`` bytes may be read yet; the caller goes on writing the
+rest and says how far it is with ``extend(handle, n)`` (forward only,
+clamped to the length); ``test`` is true once the whole length has left.
+Unmodified then means: the bytes under the mark.  The peer cannot tell
+such a send from any other.
 """
 
 from __future__ import annotations

@@ -8,7 +8,8 @@ that round, recorded as one ``round`` span whose phases tile it
 span also carries what the client's thread did on the wire during
 ``exchange``: ``wire_tx_copy_ms``, ``wire_rx_copy_ms``, ``wire_poll_ms``
 and ``sched_sleep_ms``, and what is left of the phase is the
-interpreter's):
+interpreter's; and what the stream's thread did while it staged the
+payload, :data:`STAGE_PARTS`: it paces the push):
 
 ``wait_backward`` → ``d2h`` → ``stage`` → ``exchange`` → ``h2d`` →
 ``telemetry``
@@ -23,9 +24,15 @@ and out of the transport) and the servers.  So the payload leaves the
 device in pieces of :data:`PIECE_BYTES`, in the client's shard order,
 a few in flight at a time, each staged into its slice of ``grad_host``
 by the stream's thread as it lands; shard ``s``'s GRAD op begins once
-shard ``s`` is whole in the mirror (the client asks the *gate*,
-:meth:`ShardStream.staged`, before it touches the slice) while the
-pieces of shard ``s + 1`` are still crossing; and when server ``s``'s
+the first piece of shard ``s`` is in the mirror and sends no byte beyond
+those that are (the client asks the *gate*, :meth:`ShardStream.staged`,
+how many bytes of the shard are staged, before it touches the slice and
+again at every poll of its send: a send whose bytes become ready while
+it is on the wire, ``comm/transport.py``), so a push ends about a piece
+after its shard's staging does and not a whole push later; where the
+payload is not the slice itself (a codec, the framed or the chunked
+wire) or the transport cannot hold such a send, the client waits for
+the whole shard as it did; and when server ``s``'s
 PARAM op completes the client calls the *sink*,
 :meth:`ShardStream.landed`, and the same thread sends that slice of
 ``w_host`` back up, piece by piece into one donated device buffer,
@@ -38,8 +45,9 @@ pieces gain, they gain without any overlap).  So there is one round,
 not two.  A client that gives no cut (the tests' simulators, shardctl,
 the device plane's front) is one shard, the whole vector, with no hook
 on it: its payload goes down in the same pieces, the three calls run
-once the vector is whole in the mirror, and the shell sinks the shard
-itself after ``wait``.  With one shard nothing moves beside anything
+once the vector is whole in the mirror (nobody gates them), and the
+shell sinks the shard itself after ``wait``.  With one shard nothing
+moves beside anything
 (``shards_streamed`` 0); the pieces are what is left of the gain.
 
 With obs off every span site is a call on ``NULL_SPAN``: no fence is
@@ -57,9 +65,11 @@ step) are read there too, and never with obs off.
 
 The phases name what the worker *waited for with nothing else going
 on*: ``d2h`` until the first piece is on the host,
-``stage`` from there until the first shard is whole in the mirror,
+``stage`` nothing where the client gates its own ops (and from there
+until the vector is whole in the mirror where it does not),
 ``exchange`` from the first ``async_*`` call to the return of ``wait``
-(the later shards' d2h and the earlier shards' h2d run inside it),
+(the staging of every shard but its first piece, and the earlier
+shards' h2d, run inside it),
 ``h2d`` from there until the parameters are whole on the device.
 
 EASGD's round has the same parts in another order (pull, then push) and
@@ -129,6 +139,15 @@ def _exchange(opt: Any, span: Any) -> None:
     span.mark("h2d")
 
 
+#: What the stream's thread did while it staged a round's payload, noted
+#: on the ``round`` span while recording (ms, summed over the pieces).
+STAGE_PARTS = ("stage_wait_ms", "stage_copy_ms", "stage_issue_ms")
+
+
+def _no_clock() -> float:
+    return 0.0
+
+
 class _Whole(NamedTuple):
     """The cut of a client that gives none: one shard, all of it."""
 
@@ -139,22 +158,31 @@ class _Whole(NamedTuple):
 class _Copies:
     """The copies of one round, run off the client's thread (on the
     stream's): every piece of the payload to its place in ``grad_host``
-    (setting a shard's flag when its last piece is there), then each
-    shard of ``w_host`` back to the device as it is sunk.  The waits
+    (a shard's pieces in order; ``staged[shard]`` says how many of its
+    bytes are whole there and moves only after a piece's copy has
+    returned), then each shard of ``w_host`` back to the device as it
+    is sunk.  The waits
     for the DMA engine and the host copies release the interpreter
     lock, so the client's thread keeps pumping beside them."""
 
     def __init__(self, stream: "ShardStream", payload: jnp.ndarray,
-                 consume: bool):
+                 consume: bool, timed: bool):
         self.stream = stream
         self.payload = payload
         self.consume = consume
+        # Where the staging went (zeros, and no clock read, unless
+        # recording): waiting for a piece's DMA, copying it into the
+        # mirror, and freeing it and cutting the next.  It paces the push.
+        self.now = time.monotonic if timed else _no_clock
+        self.spent = dict.fromkeys(STAGE_PARTS, 0.0)
         self.first_piece = threading.Event()
-        self.staged = [threading.Event() for _ in stream.cut]
+        self.staged = [0] * len(stream.cut)  # bytes whole in the mirror
+        self.whole = threading.Event()  # all of them, or the thread ended
         self.landed: "queue.SimpleQueue[Optional[int]]" = queue.SimpleQueue()
         self.sunk: Set[int] = set()  # shards handed to ``landed``
         self.w: Optional[jnp.ndarray] = None
         self.error: Optional[BaseException] = None
+        self.failure: Optional[RuntimeError] = None  # ``error``, as raised
         self.quit = False  # the round failed: stop copying
         self.done = threading.Event()
 
@@ -167,15 +195,18 @@ class _Copies:
         finally:
             self.payload = None
             self.first_piece.set()
-            for flag in self.staged:
-                flag.set()  # after ``error``: a waiter sees both
+            self.whole.set()  # after ``error``: a waiter sees both
             self.done.set()
 
     def check(self) -> None:
-        """Raise what stopped the copies, if anything did."""
+        """Raise what stopped the copies, if anything did: the same
+        exception to everyone who asks (the client raises it once)."""
         if self.error is not None:
-            raise RuntimeError(
-                "the round's copying thread failed") from self.error
+            if self.failure is None:
+                self.failure = RuntimeError(
+                    "the round's copying thread failed")
+                self.failure.__cause__ = self.error
+            raise self.failure
 
     def sink(self, shard: int) -> None:
         self.sunk.add(shard)
@@ -183,31 +214,40 @@ class _Copies:
 
     def _stage(self) -> None:
         stream, payload = self.stream, self.payload
-        todo = iter(stream.pieces)
+        todo = iter(zip(stream.pieces, stream.starts))
         flight: deque = deque()
 
         def issue() -> None:
-            piece = next(todo, None)
+            piece, start = next(todo, (None, None))
             if piece is not None:
                 _shard, lo, hi = piece
-                part = _cut(payload, lo, size=hi - lo)
+                part = _cut(payload, start, size=hi - lo)
                 part.copy_to_host_async()
                 flight.append((piece, part))
 
         for _ in range(IN_FLIGHT):
             issue()
+        now, spent = self.now, self.spent
         while flight and not self.quit:
             (shard, lo, hi), part = flight.popleft()
+            t_pop = now()
             host = np.asarray(part)
             self.first_piece.set()
+            t_host = now()
             np.copyto(stream.grad_host[lo:hi], host)
+            self.staged[shard] = (
+                hi - stream.cut[shard].offset) * stream.grad_host.itemsize
+            t_staged = now()
             del host  # on the CPU backend a view of the buffer freed next
             part.delete()
             issue()
-            if hi == stream.cut[shard].end:
-                self.staged[shard].set()
-        if self.consume and not flight:  # every cut has run
-            payload.delete()
+            spent["stage_wait_ms"] += (t_host - t_pop) * 1e3
+            spent["stage_copy_ms"] += (t_staged - t_host) * 1e3
+            spent["stage_issue_ms"] += (now() - t_staged) * 1e3
+        if not flight:  # every cut has run
+            self.whole.set()
+            if self.consume:
+                payload.delete()
 
     def _upload(self) -> None:
         stream = self.stream
@@ -241,8 +281,9 @@ class ShardStream:
     thread that makes every round's copies, from the first round until
     :meth:`close` (a thread a round would leave each round's host
     pieces behind in an allocator arena of its own).  Between rounds the
-    gate is open and the sink does nothing, so ops issued outside
-    :func:`push_pull` run as before.  The thread ends with the stream:
+    gate is open (every shard whole) and the sink does nothing, so ops
+    issued outside :func:`push_pull` run as before.  The thread ends
+    with the stream:
     at :meth:`close`, or when the last owner (the shell, and a client
     that took the gate and the sink) lets go of it unclosed."""
 
@@ -251,7 +292,13 @@ class ShardStream:
         self.cut: List[Any] = []
         self.parts: List[List[Tuple[int, int]]] = []  # (lo, hi), by shard
         self.pieces: List[Tuple[int, int, int]] = []  # (shard, lo, hi), all
+        #: every piece's ``lo`` on the device: a cut dispatched with a
+        #: Python integer sends it up first, and the dispatch is serial
+        #: work of the thread that paces the push (0.35 against 0.20 ms a
+        #: piece on a v5e: PERF.md section 6, PR 40)
+        self.starts: List[jnp.ndarray] = []
         self.shards_streamed = 0  # shards that move beside each other
+        self.gated = False  # the client took the gate and asks it itself
         self.m_streamed: Any = None  # mpit_round_streamed_total
         self._index: Dict[int, int] = {}  # a shard's offset -> its number
         self._worker: Optional[_Copies] = None  # this round's, in a round
@@ -272,18 +319,25 @@ class ShardStream:
             for shard in cut]
         self.pieces = [(i, lo, hi) for i, parts in enumerate(self.parts)
                        for lo, hi in parts]
+        self.starts = [jnp.asarray(lo, jnp.int32)
+                       for _shard, lo, _hi in self.pieces]
         self.shards_streamed = len(cut) if len(cut) > 1 else 0
 
     # -- the hooks (on the client's thread; neither blocks) ------------------
 
-    def staged(self, shard: Any) -> bool:
+    def staged(self, shard: Any) -> int:
+        """The bytes of ``shard``'s slice of ``grad_host`` that are
+        whole, from its front; all of them between rounds.  Short of
+        all, raises what stopped the copies, if anything did: the rest
+        will never come, and nothing half staged is taken for whole."""
+        whole = (shard.end - shard.offset) * self.grad_host.itemsize
         worker = self._worker
         if worker is None:
-            return True
-        if not worker.staged[self._index[shard.offset]].is_set():
-            return False
-        worker.check()
-        return True
+            return whole
+        staged = worker.staged[self._index[shard.offset]]
+        if staged < whole:
+            worker.check()
+        return staged
 
     def landed(self, shard: Any) -> None:
         worker = self._worker
@@ -307,13 +361,15 @@ class ShardStream:
             self._thread = threading.Thread(
                 target=self._serve, name="mpit-round-stream", daemon=True)
             self._thread.start()
-        worker = self._worker = _Copies(self, payload, consume)
+        worker = self._worker = _Copies(self, payload, consume,
+                                        opt._spans.enabled)
         self._rounds.put(worker)
         try:
             worker.first_piece.wait()
             span.mark("stage")
-            worker.staged[0].wait()
-            worker.check()  # nothing half staged leaves ungated
+            if not self.gated:
+                worker.whole.wait()  # nothing half staged leaves ungated
+            worker.check()
             _exchange(opt, span)
             # What the client did not sink goes up now: it took no
             # hooks, or a read was aborted at shutdown.
@@ -328,6 +384,7 @@ class ShardStream:
             worker.done.wait()
             self._worker = None
         worker.check()
+        span.note(**worker.spent)  # the null span's, with obs off
         return worker.w
 
 
@@ -343,6 +400,7 @@ def attach(opt: Any) -> None:
     # that forwards to its client (the benchmark's timing proxy).
     install = getattr(opt.pc, "stream_shards", None)
     cut = install(stream.staged, stream.landed) if install else None
+    stream.gated = bool(cut)
     stream.bind(cut or [_Whole(0, opt.grad_host.size)])
     stream.m_streamed = get_registry().counter(
         "mpit_round_streamed_total", rank=getattr(opt.pc, "rank", None))

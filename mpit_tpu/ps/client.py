@@ -58,6 +58,7 @@ from mpit_tpu.aio import (
     DeadlineExceeded,
     LiveFlag,
     Scheduler,
+    TaskError,
     aio_recv,
     aio_send,
     aio_sleep,
@@ -304,7 +305,7 @@ class ParamClient:
         # The streamed round's per-shard gate and sink (stream_shards):
         # None, and no instruction beyond the test for it, unless a
         # shell installed them.
-        self._staged: Optional[Callable[[Shard], bool]] = None
+        self._staged: Optional[Callable[[Shard], int]] = None
         self._landed: Optional[Callable[[Shard], None]] = None
 
     # -- lifecycle ----------------------------------------------------------
@@ -952,11 +953,22 @@ class ParamClient:
         the per-server staging frame at ship time; the int8 residual is
         folded in and refreshed by the same pass.  Framed mode stamps
         [epoch, seq] and retries the staged bytes on deadline.  Gated
-        (:meth:`stream_shards`): nothing here reads the slice before
-        the shell has staged it, and the wait lies before the span."""
+        (:meth:`stream_shards`): nothing here reads a byte of the slice
+        before the shell has staged it, and the wait lies before the
+        span.  Where the payload is the slice itself (identity codec,
+        unframed, unchunked) and the transport can hold a send that is
+        not yet whole, the wait is for the shard's first staged byte and
+        the send follows the staging (``aio_send(ready=...)``); everywhere
+        else something reads the whole slice first, so the wait is for
+        the whole shard."""
+        wire = self._grad_wire.get(srank)
+        follow = (self._staged is not None and wire is None
+                  and not self.ft.framed and not self._chunked
+                  and hasattr(self.transport, "extend"))
         gated_ms = None
         if self._staged is not None:
-            gated_ms = yield from self._gate(shard)
+            gated_ms = yield from self._gate(
+                shard, 1 if follow else shard.size * self.grad.itemsize)
         if self._chunked:
             yield from self._chunked_write(srank, shard, tags.GRAD,
                                            tags.GRAD_ACK, "GRAD", gated_ms)
@@ -966,14 +978,15 @@ class ParamClient:
         if gated_ms is not None:
             span.note(gated_ms=gated_ms)
         view = self.grad[shard.offset : shard.end]
-        wire = self._grad_wire.get(srank)
         span.mark("encode")
         payload = self._encode(view, wire, residual=self._residual.get(srank))
         span.note(bytes=payload.nbytes)
         if not self.ft.framed:
             span.mark("send")
-            yield from aio_send(self.transport, payload, srank, tags.GRAD,
-                                live=self.live, deadline=self._op_deadline())
+            yield from aio_send(
+                self.transport, payload, srank, tags.GRAD, live=self.live,
+                deadline=self._op_deadline(),
+                ready=(lambda: self._staged(shard)) if follow else None)
             span.mark("ack")
             yield from aio_recv(self.transport, srank, tags.GRAD_ACK,
                                 live=self.live, deadline=self._op_deadline())
@@ -1489,18 +1502,22 @@ class ParamClient:
 
     def stream_shards(
         self,
-        staged: Callable[[Shard], bool],
+        staged: Callable[[Shard], int],
         landed: Callable[[Shard], None],
     ) -> Optional[List[Shard]]:
         """An optional extension of ``ParamClientAPI``
         (optim/client_api.py; shells test for it by name): install a
         shell's per-shard gate and sink and return the cut they will be
         called with, one shard a server in channel order.  From
-        then on a GRAD op asks ``staged(shard)`` before it touches its
-        slice of ``grad``, yielding to the other channels until the
-        answer is True, and a PARAM op calls ``landed(shard)`` once its
-        slice of ``param`` is whole.  Both run on this client's thread
-        and must not block.  The wire does not change: each server still
+        then on a GRAD op asks ``staged(shard)``, how many bytes of its
+        slice of ``grad`` are staged from the front, before it touches
+        the slice, yielding to the other channels until the answer is
+        enough: the whole slice, or its first byte where the send can
+        follow the staging (:meth:`_send_grad`), which then asks again
+        at every poll and reads no byte beyond the answer.  A PARAM op
+        calls ``landed(shard)`` once its slice of ``param`` is whole.
+        Both run on this client's thread and must not block.  The wire
+        does not change: each server still
         sees its GRAD and then its PARAM request, in that order
         (docs/PROTOCOL.md §1, pairing rules).  Under shardctl the ops
         address shards that move between owners, not channels: nothing
@@ -1511,12 +1528,13 @@ class ParamClient:
         self._staged, self._landed = staged, landed
         return list(self.shards)
 
-    def _gate(self, shard: Shard):
-        """Yield until the shell has staged ``shard``; returns the
-        milliseconds that took by the recorder's clock (0.0 with obs
-        off), which the GRAD span opened next carries as ``gated_ms``."""
+    def _gate(self, shard: Shard, nbytes: int):
+        """Yield until the shell has staged ``nbytes`` of ``shard``;
+        returns the milliseconds that took by the recorder's clock (0.0
+        with obs off), which the GRAD span opened next carries as
+        ``gated_ms``."""
         t0 = self._spans.clock()
-        while not self._staged(shard):
+        while self._staged(shard) < nbytes:
             yield EXEC
         return (self._spans.clock() - t0) * 1e3
 
@@ -1605,9 +1623,21 @@ class ParamClient:
                 self._drain_clock_echoes()
                 self.sched.ping_pass()
             if self.sched.errors:
-                raise self.sched.errors.pop(0)
+                self._raise_once(self.sched.errors.pop(0))
             return
-        self.sched.wait()
+        try:
+            self.sched.wait()
+        except TaskError as first:
+            self._raise_once(first)
+
+    def _raise_once(self, first: TaskError) -> None:
+        """Raise ``first``, and forget the errors of the ops that failed
+        with the very same exception: one cause met by several channels
+        (a staging that died under two shards' gates) is raised once, so
+        the next ``wait`` does not fail for the last round's reason."""
+        self.sched.errors = [err for err in self.sched.errors
+                             if err.cause is not first.cause]
+        raise first
 
     # -- shutdown (reference pclient.lua:153-164) ---------------------------
 

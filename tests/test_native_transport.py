@@ -634,7 +634,7 @@ class TestPairRings:
         try:
             a, b = wires[1], wires[0]
             zero = dict.fromkeys(("tx_chunks", "tx_ring_full", "rx_chunks",
-                                  "rx_overlap_chunks"), 0)
+                                  "rx_overlap_chunks", "tx_early_bytes"), 0)
             assert a.ring_counters() == b.ring_counters() == zero
             # One-chunk messages to a receiver that has nothing else to do:
             # ten chunks each side, no ring ever full, and no chunk copied

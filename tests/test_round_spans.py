@@ -236,7 +236,8 @@ def test_gated_ms_lies_outside_the_grad_span(obs_on):
             if shard.offset != second.offset:
                 return stream.staged(shard)
             asked.append(time.monotonic())
-            return stream.staged(shard) and asked[-1] - asked[0] >= hold
+            held = asked[-1] - asked[0] < hold
+            return 0 if held else stream.staged(shard)
 
         pc.stream_shards(slow_gate, stream.landed)
         w, _loss = opt.step(w, TARGET)
@@ -982,3 +983,61 @@ def test_wire_reader_gives_none_where_no_transport_ran(traced_run, name):
     span: a merged trace without one, and no merged trace at all."""
     assert reader(name)(dict(traced_run)) is None
     assert reader(name)({**traced_run, "obs_trace": None}) is None
+
+
+# -- the push that follows the staging (PR 40): ``push_early_pct`` ------------
+
+
+def test_gang_push_early_reader_gives_the_early_share(wire_gang_run, capsys):
+    """The gang's GRADs are the slices themselves over shm, so their
+    sends follow the staging: every windowed round's ``tx`` spans carry
+    ``early_bytes`` within their ``bytes`` and ``unready_ms`` within
+    ``away_ms``, and the reader gives the median round's share."""
+    from chipbench.layers import wiretree
+
+    wire = wiretree.load(dict(wire_gang_run))
+    pushes = [tx for op, _k, tx, _rx in wire.messages if op == "GRAD"]
+    assert len(pushes) == 2 * len(wire.rounds)
+    for tx in pushes:
+        assert 0 <= tx.args["early_bytes"] <= tx.args["bytes"]
+        assert 0.0 <= tx.args["unready_ms"] <= tx.args["away_ms"] + 1e-9
+    value = reader("push_early_pct")(wire_gang_run)
+    by_round = {}
+    for op, k, tx, _rx in wire.messages:
+        if op == "GRAD":
+            early, total = by_round.get(k, (0, 0))
+            by_round[k] = (early + tx.args["early_bytes"],
+                           total + tx.args["bytes"])
+    import statistics
+
+    assert value == pytest.approx(statistics.median(
+        100.0 * early / total for early, total in by_round.values()))
+    assert 0.0 <= value <= 100.0
+    assert capsys.readouterr().out == ""
+    # the servers' sends have no mark, and say so
+    for op, _k, tx, _rx in wire.messages:
+        if op == "PARAM":
+            assert tx.args["early_bytes"] == 0
+            assert tx.args["unready_ms"] == 0.0
+
+
+def test_push_early_reader_gives_none_without_the_spans(traced_run,
+                                                        wire_gang_run,
+                                                        tmp_path):
+    """No transport, no merged trace, and a program from before PR 40,
+    whose ``tx`` spans say nothing of ``early_bytes``: None each time,
+    and nothing raised."""
+    import json
+
+    read = reader("push_early_pct")
+    assert read(dict(traced_run)) is None
+    assert read({**traced_run, "obs_trace": None}) is None
+    with open(wire_gang_run["obs_trace"]) as fh:
+        trace = json.load(fh)
+    for event in trace["traceEvents"]:
+        for key in ("early_bytes", "unready_ms"):
+            event.get("args", {}).pop(key, None)
+    older = tmp_path / "older.json"
+    older.write_text(json.dumps(trace))
+    run = {k: v for k, v in wire_gang_run.items() if not k.startswith("_")}
+    assert read({**run, "obs_trace": str(older)}) is None

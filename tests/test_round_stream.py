@@ -239,17 +239,19 @@ def test_server_0_is_acked_while_shard_1_is_held_and_its_slice_unread(obs_on):
                        for s in rec.spans[base:])  # finished spans only
 
         def held(shard):
-            if shard.offset != second.offset or not stream.staged(shard):
+            if shard.offset != second.offset:
                 return stream.staged(shard)
+            if stream.staged(shard) < view.nbytes:
+                return 0
             if state["saved"] is None:  # staged for real: poison it
                 state["saved"] = view.copy()
                 view[:] = np.nan
             if not acked_by_server_0(opt.rounds):
-                return False
+                return 0
             view[:] = state["saved"]
             state["saved"] = None
             opened.append((opt.rounds, time.monotonic()))
-            return True
+            return view.nbytes
 
         assert pc.stream_shards(held, stream.landed) == stream.cut
         for _ in range(rounds):
@@ -296,9 +298,9 @@ def test_shard_0_goes_up_before_server_1_has_answered(obs_on, monkeypatch):
         def held(shard):
             # channel 1 stays busy until shard 0 is on its way up: if
             # the uploads waited for ``wait`` this would never open
-            if shard.offset == second.offset:
-                return stream.staged(shard) and any(
-                    lo < second.offset for lo, _t in pastes)
+            if shard.offset == second.offset and not any(
+                    lo < second.offset for lo, _t in pastes):
+                return 0
             return stream.staged(shard)
 
         pc.stream_shards(held, stream.landed)
