@@ -3,14 +3,18 @@
 The reference trains on a single flat tensor aliasing all model weights
 (reference goot.lua:33-36); the PS protocol shards that vector by offset
 (reference pclient.lua:111-129).  JAX arrays are immutable, so instead of
-aliasing we carry the ``unravel`` closure from ``ravel_pytree`` and
-re-materialize the pytree inside jit — XLA fuses the reshapes away, so the
-flat view costs nothing at runtime.
+aliasing we cut the vector into the module's leaves inside jit
+(:func:`leaf_unravel`) and assemble the gradient from the leaves'.  That
+is not free on the chip: the gradient's concatenation is a sweep of the
+vector (11.6 ms of a 243 ms step at 486M elements) and, until PR 41, the
+TPU compiler re-laid the *whole* vector as ``[N/64, 64]`` three times a
+step for ten leaves 64 wide (27.6 ms of the same step; PERF.md section
+5, ``lfm2-l5e8-local``).
 """
 
 from __future__ import annotations
 
-from typing import Any, Callable, Tuple
+from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
@@ -18,54 +22,59 @@ import numpy as np
 from jax.flatten_util import ravel_pytree
 
 
-def sliced_unravel(params: Any) -> Callable[[jnp.ndarray], Any]:
+def leaf_unravel(params: Any) -> Callable[[jnp.ndarray], Any]:
     """``ravel_pytree``'s ``unravel`` for ``params`` (same leaf order,
-    same offsets; one dtype throughout) with each leaf's 1-D slice
-    behind an ``optimization_barrier`` before its reshape.  Without the
+    same offsets; one dtype throughout) as a ``jax.custom_vjp``.
+
+    Forward: every leaf is split off the vector and its 1-D piece goes
+    through an ``optimization_barrier`` before its reshape.  Without the
     barrier XLA's TPU compiler turns "slice, then reshape" into "reshape
     the whole vector into the leaf's tiled 2-D layout, then slice": a
-    copy of the *whole* vector for each distinct trailing width, forward
-    and again for the backward pass (at OLMoE's one layer, a 2.5 GB
-    vector: 9.64 GB of temporaries without, 4.16 GB with; the compile
-    for a described v5e, PERF.md section 6, PR 26).  The barrier is the
-    identity and differentiates as one."""
+    copy of the whole vector for each distinct trailing width, and for a
+    width under the chip's 128 lanes one that costs twice a wide one's.
+    The rule reads nothing of the tree: no leaf's shape, no vector's
+    size.
+
+    Backward: the leaves' cotangents concatenated, each element written
+    once.  It is what ``ravel_pytree``'s transpose simplifies to; a
+    barrier's own transpose would keep one whole-vector ``pad`` a leaf
+    apart instead (what every leaf behind a barrier cost the 598 MB
+    vector's local step at PR 26: 171.4 -> 209.3 ms; with this backward
+    the same step is 157.2 ms against 159.9 with no barrier at all;
+    PERF.md section 6, PR 41)."""
     leaves, treedef = jax.tree_util.tree_flatten(params)
+    dtypes = {str(jnp.result_type(leaf)) for leaf in leaves}
+    if len(dtypes) > 1:
+        raise TypeError(f"leaf_unravel needs one dtype, got {sorted(dtypes)}")
     shapes = [np.shape(leaf) for leaf in leaves]
     sizes = [int(np.prod(shape)) for shape in shapes]
-    offsets = np.concatenate([[0], np.cumsum(sizes)])
 
+    @jax.custom_vjp
     def unravel(w: jnp.ndarray) -> Any:
-        pieces = [
-            jax.lax.optimization_barrier(
-                w[int(offsets[i]):int(offsets[i + 1])]).reshape(shape)
-            for i, shape in enumerate(shapes)]
-        return jax.tree_util.tree_unflatten(treedef, pieces)
+        return jax.tree_util.tree_unflatten(treedef, [
+            jax.lax.optimization_barrier(piece).reshape(shape)
+            for piece, shape in zip(jax.lax.split(w, sizes), shapes)])
 
+    def forward(w):
+        return unravel(w), None
+
+    def backward(_, ct):
+        return (jnp.concatenate(
+            [leaf.reshape(-1) for leaf in jax.tree_util.tree_leaves(ct)]),)
+
+    unravel.defvjp(forward, backward)
     return unravel
 
 
-# From this many elements on (2 GiB of float32) the leaves are cut behind
-# the barrier.  Each whole-vector copy it saves costs a vector of memory,
-# and the barrier costs time: on the v5e it makes the 598 MB vector's
-# local step 22% slower (171.4 -> 209.3 ms) and the 598 MB and 1.6 GB
-# vectors' PS rounds 5.0% and 4.2% slower, while the 2.5 GB vector's step
-# fits the chip only with it (PERF.md section 6, PR 26).
-BARRIER_FROM = 1 << 29
-
-
 class FlatModel:
-    """A Flax module + flat-parameter calling convention.  A vector of
-    :data:`BARRIER_FROM` elements or more takes :func:`sliced_unravel`
-    in place of ``ravel_pytree``'s (the same function of ``w``, another
-    program)."""
+    """A Flax module + flat-parameter calling convention.  Every vector
+    is cut by :func:`leaf_unravel`."""
 
     def __init__(self, module: Any, params: Any):
         self.module = module
-        flat, unravel = ravel_pytree(params)
-        self.w0 = flat
-        self.size = int(flat.shape[0])
-        self.unravel = (sliced_unravel(params) if self.size >= BARRIER_FROM
-                        else unravel)
+        self.w0 = ravel_pytree(params)[0]
+        self.size = int(self.w0.shape[0])
+        self.unravel = leaf_unravel(params)
 
     def apply_flat(self, w: jnp.ndarray, *args: Any, **kwargs: Any):
         return self.module.apply({"params": self.unravel(w)}, *args, **kwargs)

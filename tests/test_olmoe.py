@@ -209,17 +209,17 @@ def test_the_vector_at_published_widths_is_625_616_896_elements():
 
 
 def test_the_barriered_unravel_is_ravel_pytrees(case):
-    """Values and gradient: the barrier is the identity.  It is taken
-    from the vector's size on, which the tiny model is far under."""
+    """Values and gradient: the barrier on every leaf's piece is the
+    identity, and the hand-written backward is ``ravel_pytree``'s
+    transpose.  The model's own unravel is that function."""
     from jax.flatten_util import ravel_pytree
 
     from mpit_tpu.models import flat as flat_mod
 
     model = case["model"]
-    assert model.flat.size < flat_mod.BARRIER_FROM <= 625_616_896
     params = model.flat.unravel(case["w"])
     flat, plain = ravel_pytree(params)
-    sliced = flat_mod.sliced_unravel(params)
+    sliced = flat_mod.leaf_unravel(params)
     np.testing.assert_array_equal(flat, case["w"])
 
     def through(unravel):
@@ -231,8 +231,9 @@ def test_the_barriered_unravel_is_ravel_pytrees(case):
     for a, b in zip(jax.tree_util.tree_leaves(plain(case["w"])),
                     jax.tree_util.tree_leaves(sliced(case["w"]))):
         np.testing.assert_array_equal(a, b)
-    np.testing.assert_array_equal(jax.grad(through(sliced))(case["w"]),
-                                  jax.grad(through(plain))(case["w"]))
+    for unravel in (sliced, model.flat.unravel):
+        np.testing.assert_array_equal(jax.grad(through(unravel))(case["w"]),
+                                      jax.grad(through(plain))(case["w"]))
 
 
 def test_gpt2_is_still_the_default_block_with_no_statistics():
