@@ -32,11 +32,6 @@ class Queue(Generic[T]):
             return None
         return self._items.popleft()
 
-    def peek(self) -> Optional[T]:
-        if not self._items:
-            return None
-        return self._items[0]
-
     def __len__(self) -> int:
         return len(self._items)
 

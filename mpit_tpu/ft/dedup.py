@@ -106,14 +106,6 @@ class DedupTable:
         cur_epoch, cur_seq = cur
         return epoch < cur_epoch or (epoch == cur_epoch and seq <= cur_seq)
 
-    def drop_partial(self, crank: int, tag: int) -> None:
-        """Forget the in-flight chunk set on one channel (the assembly
-        paths own their bytes; a server that discards them — e.g. a
-        PUSH whose staging is never checkpointed — must discard the
-        admissions with them, or resent chunks would dedup into a
-        hole)."""
-        self._partial.pop((crank, tag), None)
-
     def last(self, crank: int, tag: int) -> "Tuple[int, int] | None":
         return self._last.get((crank, tag))
 

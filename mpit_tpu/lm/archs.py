@@ -1,0 +1,250 @@
+"""The table of blocks: what sizes each decoder takes, and how it is
+made from them.  One entry a size (``SIZES``: its one name, its one
+default, whose type is its type, and what it means) and one a block
+(``BLOCKS``).  The launcher's ``lm_*`` switches (``train/launch.py``),
+the trainer's config (``lm/trainer.py`` ``LM_DEFAULTS``) and
+``lm/model.py`` ``build`` are derived from here; a new block is its
+decoder (``models/transformer.py``) and its entry here.
+
+The data is plain Python: this file imports neither jax nor flax, and a
+decoder is imported when its maker is called (the gang's parent reads
+the launcher's defaults and must load nothing for it).
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Any, Callable, Dict, Mapping, NamedTuple, Tuple
+
+
+class Size(NamedTuple):
+    default: Any  # its type is the size's type wherever it is taken
+    doc: str
+
+
+SIZES: Dict[str, Size] = {
+    # every block's
+    "d_model": Size(64, "the residual stream's width"),
+    "n_heads": Size(4, "query heads"),
+    "n_layers": Size(2, "the layers held here"),
+    "seq_len": Size(128, "positions of a packed sequence; gpt2's position "
+                    "table is exactly this long (the stream fills whole "
+                    "sequences, and an exact fit keeps the table out of the "
+                    "sharding slack); the others' positions are rotary"),
+    "vocab": Size(0, "rows of the token table and the head; 0: the byte "
+                  "stream's 256, whose ids index a larger table's first rows"),
+    # the sparse MLP's, and the dense one beside it
+    "n_experts": Size(8, "the router's width"),
+    "experts_per_tok": Size(2, "experts a token takes"),
+    "expert_width": Size(32, "one expert's inner width"),
+    "experts_first": Size(0, "the first expert of the share held here"),
+    "experts_held": Size(0, "0: all n_experts, else the contiguous share from "
+                         "experts_first that this chip holds of the router's"),
+    "route_scale": Size(1.0, "the router's routed_scaling_factor"),
+    "dense_layers": Size(0, "how many layers, the first, have the dense MLP "
+                         "of dense_width and not the sparse one"),
+    "dense_width": Size(0, "the dense MLP's inner width (ouro's one MLP)"),
+    # rotary positions, RMSNorm, grouped heads
+    "rope_theta": Size(10000.0, "the rotary base"),
+    "norm_eps": Size(1e-5, "the RMSNorm epsilon"),
+    "kv_heads": Size(0, "key/value heads; 0: as many as query heads"),
+    "head_dim": Size(0, "a head's width; 0: d_model / n_heads"),
+    # mellum's window and YaRN
+    "window": Size(0, "the sliding window; 0: every layer full"),
+    "full_every": Size(4, "every full_every-th layer is full"),
+    "yarn_factor": Size(0.0, "YaRN on the full layers; 0: the plain rotary "
+                        "table on them too"),
+    "yarn_orig": Size(0, "YaRN's original_max_position_embeddings"),
+    "yarn_beta_fast": Size(32.0, "YaRN's beta_fast"),
+    "yarn_beta_slow": Size(1.0, "YaRN's beta_slow"),
+    "yarn_attn_factor": Size(1.0, "YaRN's attention_factor"),
+    # lfm2's mixers
+    "layer_types": Size("", "each held layer's token mixer, conv or "
+                        "full_attention, comma-separated, n_layers of them"),
+    "conv_kernel": Size(3, "the short convolution's taps"),
+    # ouro's loop
+    "loop_steps": Size(4, "how often the layers are applied, same weights"),
+    "exit_beta": Size(0.1, "the exit distribution's entropy's weight in the "
+                      "block's loss"),
+    "exit_bias": Size(0.0, "what the exit gate's bias is seeded at (0: a gate "
+                      "of a half; negative: nearer to running every pass)"),
+    # joyai's latent attention, shared expert and second head
+    "q_rank": Size(0, "the queries' low-rank product's inner width"),
+    "kv_rank": Size(0, "the keys' and values' low-rank product's inner width"),
+    "qk_nope": Size(0, "a head's query and key without positions"),
+    "qk_rope": Size(0, "the rotary part of a head's query and key (even)"),
+    "v_head": Size(0, "a head's value width"),
+    "shared_experts": Size(1, "experts of expert_width every token takes "
+                           "beside the routed ones"),
+    "mtp_layers": Size(1, "0 or 1: the multi-token-prediction module"),
+    "mtp_weight": Size(0.3, "the MTP loss's weight in the block's objective"),
+}
+
+DEFAULTS = {name: size.default for name, size in SIZES.items()}
+#: the launcher's switch for a size is ``lm_<size>`` but for these
+SWITCH_ALIASES = {"n_experts": "lm_experts", "n_heads": "lm_heads",
+                  "n_layers": "lm_layers", "seq_len": "lm_seq"}
+#: size -> its launcher switch
+SWITCHES = {name: SWITCH_ALIASES.get(name, f"lm_{name}") for name in SIZES}
+
+# the two loss conventions (``lm/model.py``): the head's next-token NLL
+# closed over log-probs, or the block's own, ``module(inputs, targets) ->
+# (loss, {name: device scalar})``
+HEAD_NLL, OWN_LOSS = "head_nll", "own_loss"
+
+
+class Block(NamedTuple):
+    sizes: Tuple[str, ...]  # what it takes beyond ``SHARED``
+    #: (its sizes by name, ``attn(precision=None) -> attn_fn``) -> the
+    #: flax module, after checking what the sizes must satisfy
+    make: Callable[[Dict[str, Any], Callable[..., Any]], Any]
+    loss: str = HEAD_NLL
+    #: HEAD_NLL: the step returns what the sparse layers ``sow`` beside
+    #: the loss (``lm/model.py`` ``MOE_STATS``)
+    sown_stats: bool = False
+    #: positions of the initialisation's sample; 0: ``seq_len``.  With
+    #: rotary positions no parameter depends on it, and a host role's
+    #: forward pass with the materialised reference attention at a
+    #: training sequence takes minutes and tens of GB (``lm_layout`` on
+    #: a server rank): short but for the two oldest, which keep theirs.
+    sample_len: int = 16
+    #: the module has ``kept_residual_bytes(seq_len, flash)``: what its
+    #: checkpoints keep by name for the backward pass, a sequence
+    kept_residuals: bool = False
+
+
+SHARED = ("d_model", "n_heads", "n_layers", "seq_len", "vocab")
+_ROTARY = ("rope_theta", "norm_eps")
+_GROUPED = ("kv_heads", "head_dim")
+_SPARSE = ("n_experts", "experts_per_tok", "expert_width")
+_SHARE = ("experts_first", "experts_held")
+
+
+def _transformer() -> Any:
+    from mpit_tpu.models import transformer  # when a maker runs, not before
+
+    return transformer
+
+
+def _module(decoder: str, sizes: Mapping[str, Any], attn_fn: Any,
+            **derived: Any) -> Any:
+    """The decoder of that name with every size that is a field of it
+    under the size's name, ``derived`` laid over them."""
+    cls = getattr(_transformer(), decoder)
+    fields = {field.name for field in dataclasses.fields(cls)}
+    return cls(**{**{name: value for name, value in sizes.items()
+                     if name in fields}, **derived}, attn_fn=attn_fn)
+
+
+def _heads(s: Mapping[str, Any]) -> Dict[str, int]:
+    return {"kv_heads": s["kv_heads"] or s["n_heads"],
+            "head_dim": s["head_dim"] or s["d_model"] // s["n_heads"]}
+
+
+def _check_share(s: Mapping[str, Any]) -> None:
+    first, held = s["experts_first"], s["experts_held"] or s["n_experts"]
+    if first + held > s["n_experts"]:
+        raise ValueError(f"experts {first}..{first + held - 1} held of "
+                         f"{s['n_experts']}")
+
+
+def _gpt2(s, attn):
+    return _module("TinyDecoder", s, attn(), max_len=s["seq_len"])
+
+
+def _olmoe(s, attn):
+    # read at build time: the probe of the reference's tolerances tries
+    # other precisions (chipbench/reference/probe_olmoe.py)
+    return _module("OlmoeDecoder", s,
+                   attn(_transformer().ATTN_KERNEL_PRECISION))
+
+
+def _mellum(s, attn):
+    _check_share(s)
+    yarn = (s["yarn_factor"], s["yarn_orig"], s["yarn_beta_fast"],
+            s["yarn_beta_slow"], s["yarn_attn_factor"]
+            ) if s["yarn_factor"] else None
+    return _module("MellumDecoder", s, attn(), yarn=yarn, **_heads(s))
+
+
+def _lfm2(s, attn):
+    _check_share(s)
+    kinds = tuple(kind.strip() for kind in s["layer_types"].split(",")
+                  if kind.strip())
+    if len(kinds) != s["n_layers"]:
+        raise ValueError(f"layer_types names {len(kinds)} layers "
+                         f"({s['layer_types']!r}), n_layers is "
+                         f"{s['n_layers']}")
+    return _module("Lfm2Decoder", s, attn(), layer_types=kinds, **_heads(s))
+
+
+def _ouro(s, attn):
+    if s["loop_steps"] < 1:
+        raise ValueError(f"loop_steps {s['loop_steps']}: at least one pass")
+    return _module("OuroDecoder", s, attn(), **_heads(s))
+
+
+def _joyai(s, attn):
+    _check_share(s)
+    latent = tuple(s[name] for name in (
+        "q_rank", "kv_rank", "qk_nope", "qk_rope", "v_head"))
+    if min(latent) < 1 or s["qk_rope"] % 2:
+        raise ValueError(f"joyai needs q_rank, kv_rank, qk_nope, qk_rope "
+                         f"(even) and v_head: {latent}")
+    return _module("JoyaiDecoder", s, attn())
+
+
+# what each block is: its decoder's docstring (``models/transformer.py``)
+BLOCKS: Dict[str, Block] = {
+    "gpt2": Block((), _gpt2, sample_len=0),
+    "olmoe": Block(_SPARSE + _ROTARY, _olmoe, sown_stats=True, sample_len=0),
+    "mellum": Block(
+        _GROUPED + _SPARSE + _SHARE + _ROTARY + (
+            "window", "full_every", "yarn_factor", "yarn_orig",
+            "yarn_beta_fast", "yarn_beta_slow", "yarn_attn_factor"),
+        _mellum, sown_stats=True),
+    "lfm2": Block(
+        _GROUPED + _SPARSE + _SHARE + _ROTARY + (
+            "layer_types", "dense_layers", "dense_width", "conv_kernel",
+            "route_scale"),
+        _lfm2, sown_stats=True),
+    "ouro": Block(
+        _GROUPED + _ROTARY + ("dense_width", "loop_steps", "exit_beta",
+                              "exit_bias"),
+        _ouro, loss=OWN_LOSS, kept_residuals=True),
+    "joyai": Block(
+        _SPARSE + _SHARE + _ROTARY + (
+            "dense_layers", "dense_width", "route_scale", "q_rank",
+            "kv_rank", "qk_nope", "qk_rope", "v_head", "shared_experts",
+            "mtp_layers", "mtp_weight"),
+        _joyai, loss=OWN_LOSS),
+}
+ARCHS = tuple(BLOCKS)
+
+
+def block(arch: str) -> Block:
+    """The table's lookup."""
+    try:
+        return BLOCKS[arch]
+    except KeyError:
+        raise ValueError(f"unknown LM arch {arch!r}; have {ARCHS}") from None
+
+
+def sizes_of(arch: str) -> Tuple[str, ...]:
+    """The sizes ``arch`` takes by name: every block's, then its own."""
+    return SHARED + block(arch).sizes
+
+
+def resolve(arch: str, given: Mapping[str, Any]) -> Dict[str, Any]:
+    """Every size ``arch`` takes, by name: ``given`` where it names one,
+    else the size's default, each in the size's type.  A size the block
+    does not take is refused by naming the ones it does."""
+    names = sizes_of(arch)
+    stray = sorted(set(given) - set(names))
+    if stray:
+        raise TypeError(f"{arch} takes no {', '.join(stray)}; its sizes are "
+                        f"{', '.join(names)}")
+    sizes = {name: type(DEFAULTS[name])(given.get(name, DEFAULTS[name]))
+             for name in names}
+    sizes["vocab"] = sizes["vocab"] or 256
+    return sizes

@@ -24,6 +24,7 @@ tools/lm_smoke.py).
 
 from __future__ import annotations
 
+import functools
 import time
 from typing import Any, Dict, Optional
 
@@ -31,6 +32,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from mpit_tpu.lm import archs
 from mpit_tpu.lm.data import PackedStream
 from mpit_tpu.lm.model import build, build_kw
 from mpit_tpu.obs import PhaseTimers, get_registry, profiler_trace
@@ -40,68 +42,10 @@ from mpit_tpu.utils.config import Config
 from mpit_tpu.utils.logging import get_logger
 
 LM_DEFAULTS = Config(
-    # model: the block (lm/model.py ARCHS) and its sizes
+    # model: the block and every block's sizes under their own names
+    # (lm/archs.py has each one's meaning, and which block takes which)
     arch="gpt2",
-    d_model=64,
-    n_heads=4,
-    n_layers=2,
-    seq_len=128,
-    # rows of the token table and the head (--lm_vocab); 0 = build's own
-    # keyword default, the byte stream's 256, whose ids index the first
-    # rows of a larger table
-    vocab=0,
-    # olmoe's own sizes: experts, experts a token, one expert's width,
-    # the rotary base and the RMSNorm epsilon
-    n_experts=8,
-    experts_per_tok=2,
-    expert_width=32,
-    rope_theta=10000.0,
-    norm_eps=1e-5,
-    # mellum's own sizes (lm/model.py build): KV heads and the heads'
-    # width (0: as olmoe's), the share of the experts held (0: all), the
-    # sliding window (0: none) and which layers are full, YaRN on those
-    # (factor 0: none)
-    kv_heads=0,
-    head_dim=0,
-    experts_first=0,
-    experts_held=0,
-    window=0,
-    full_every=4,
-    yarn_factor=0.0,
-    yarn_orig=0,
-    yarn_beta_fast=32.0,
-    yarn_beta_slow=1.0,
-    yarn_attn_factor=1.0,
-    # lfm2's own sizes (lm/model.py build; it shares kv_heads, head_dim
-    # and the share with mellum): each held layer's token mixer, "conv"
-    # or "full_attention", comma-separated; how many leading layers have
-    # the dense MLP and its width; the short convolution's taps; the
-    # router's scale
-    layer_types="",
-    dense_layers=0,
-    dense_width=0,
-    conv_kernel=3,
-    route_scale=1.0,
-    # ouro's own (it takes kv_heads, head_dim and dense_width too): how
-    # often the stack of layers is applied with the same weights, the
-    # weight of the exit distribution's entropy in its loss, and the
-    # value the exit gate's bias is seeded at
-    loop_steps=4,
-    exit_beta=0.1,
-    exit_bias=0.0,
-    # joyai's own (it takes the share, dense_layers, dense_width and
-    # route_scale too): the latent attention's low-rank widths, the two
-    # parts of a head's query and key and a head's value, the shared
-    # experts every token takes, the multi-token-prediction module (0 or
-    # 1) and its loss's weight
-    q_rank=0,
-    kv_rank=0,
-    qk_nope=0,
-    qk_rope=0,
-    v_head=0,
-    shared_experts=1,
-    mtp_layers=1,
-    mtp_weight=0.3,
+    **archs.DEFAULTS,
     # -1 auto (flash on TPU, jnp reference elsewhere) | 0 reference |
     # 1 the Mosaic-compiled kernel or an error (lm/model.py _resolve_attn)
     use_flash=-1,
@@ -169,28 +113,23 @@ class LmTrainer:
         self._m_tps = _reg.gauge("mpit_lm_tokens_per_s", rank=rank)
         _reg.gauge("mpit_lm_kept_residual_bytes", rank=rank).set(
             cfg.batch * self.model.kept_residual_bytes)
-        self._optimizer = None  # lazy: eval-only roles never need one
 
-    @property
+    @functools.cached_property
     def optimizer(self):
-        if self._optimizer is None:
-            self._optimizer = self._make_optimizer()
-        return self._optimizer
-
-    def _make_optimizer(self):
+        """Built at first use: eval-only roles never need one."""
         cfg = self.cfg
         name = cfg.opt
         if name not in self.KNOWN_OPTS:
             raise ValueError(f"unknown optimizer {name!r}; have {self.KNOWN_OPTS}")
+        # a block with telemetry of its own returns it beside the loss,
+        # to the local step and to the server-stateful rules' shell
+        has_aux = self.model.value_grad_stats is not None
+        step = self.model.value_grad_stats if has_aux else self._vgf
         if name in ("sgd", "msgd"):
             mcfg = MSGDConfig(lr=cfg.lr, lrd=cfg.lrd, lrp=cfg.lrp,
                               mom=cfg.mom, mommax=cfg.mommax,
                               momdecay=cfg.momdecay, l2wd=cfg.l2wd)
-            # a block with telemetry of its own returns it beside the
-            # loss, here as under the shells below
-            stats_step = self.model.value_grad_stats
-            return MSGD(mcfg, stats_step or self._vgf,
-                        has_aux=stats_step is not None)
+            return MSGD(mcfg, step, has_aux=has_aux)
         if self.pc is None:
             raise ValueError(
                 f"optimizer {name!r} needs a parameter client "
@@ -205,10 +144,8 @@ class LmTrainer:
                           mva=cfg.mva, su=cfg.su)
         # Server-stateful rules: the launcher configures the matching
         # server rule; the client ships raw gradients.
-        # a block with telemetry of its own returns it beside the loss
-        stats_step = self.model.value_grad_stats
-        return RuleShell(stats_step or self._vgf, self.pc, su=cfg.su,
-                         mode="global", has_aux=stats_step is not None)
+        return RuleShell(step, self.pc, su=cfg.su, mode="global",
+                         has_aux=has_aux)
 
     # -- evaluation -----------------------------------------------------------
 

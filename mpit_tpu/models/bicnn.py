@@ -118,9 +118,6 @@ class BiCNN(nn.Module):
     def embed(self, tokens: jnp.ndarray, lengths: jnp.ndarray) -> jnp.ndarray:
         return self.tower(tokens, lengths)
 
-    def score_pair(self, q, q_len, a, a_len) -> jnp.ndarray:
-        return gesd(self.tower(q, q_len), self.tower(a, a_len))
-
     def __call__(self, q, q_len, a_pos, a_pos_len, a_neg, a_neg_len):
         """-> (sim(q, a+), sim(q, a-)), each (B,)."""
         eq = self.tower(q, q_len)

@@ -15,24 +15,9 @@ long-context showcase built on the framework's own kernels:
   batched over (B, L);
 - pre-LN blocks, learned positional embeddings, causal by default.
 
-Six decoders: :class:`TinyDecoder` (GPT-2's: LayerNorm, learned
-positions, GELU MLP), :class:`OlmoeDecoder` (OLMoE's: RMSNorm, rotary
-positions, query/key norm, top-k of E gated experts),
-:class:`MellumDecoder` (Mellum 2's: grouped KV heads of their own
-width, sliding-window and full attention mixed by layer with a rotary
-table per layer type, top-k renormalised, and a chip's share of the
-experts) and :class:`Lfm2Decoder` (LFM2's: a layer's token mixer a
-gated short convolution or grouped-head attention with a per-head
-query/key norm, its MLP dense or a share of sparse experts behind a
-sigmoid router with a selection bias, both read from the
-configuration layer by layer) and :class:`OuroDecoder` (Ouro's: the
-whole stack applied several times with the same weights, sandwich
-norms, a head and an exit gate at every pass, and a loss of its own
-over them) and :class:`JoyaiDecoder` (JoyAI-LLM-Flash's: latent
-attention with keys wider than values, a shared expert beside a share of
-the routed ones, and a multi-token-prediction module whose loss it
-closes with the main head's), chosen by ``lm/model.py``
-``build(arch=...)``.
+Six decoders, each described where it is defined; ``lm/archs.py``
+``BLOCKS`` names them with the sizes they take, and ``lm/model.py``
+``build(arch=...)`` chooses one.
 """
 
 from __future__ import annotations
@@ -167,8 +152,9 @@ class TinyDecoder(nn.Module):
 # (``parallel/moe.py``), an untied head.  Parameters are float32 and
 # named by hand, the experts stacked on a leading expert axis (which
 # ``lm/plan.py`` may cut between experts).  The plain float32 reference
-# it is held to is ``lm/olmoe_reference.py``; that file shares no code
-# with this one.
+# it is held to is the benchmark's, ``chipbench/reference/olmoe_plain.py``
+# (here in ``tests/test_olmoe.py``, on the chip in every run); that file
+# shares no code with this one.
 # ---------------------------------------------------------------------------
 
 _INIT = nn.initializers.normal(stddev=0.02)
@@ -313,8 +299,10 @@ class OlmoeDecoder(nn.Module):
 # contiguous range it holds (``experts_first``, ``experts_held``), routes
 # over all ``n_experts`` and computes its own experts' part
 # (``parallel/moe.py``, *A share of the experts*).  The plain float32
-# reference it is held to is ``lm/mellum_reference.py``; that file
-# shares no code with this one.
+# reference it is held to is the benchmark's,
+# ``chipbench/reference/mellum_plain.py`` (here in
+# ``tests/test_mellum.py``, on the chip in every run); that file shares
+# no code with this one.
 # ---------------------------------------------------------------------------
 
 #: The token table's own scale: what keeps the routing of a share of

@@ -152,9 +152,6 @@ class ShardMap:
         """Distinct owning ranks, ascending."""
         return sorted({e.owner for e in self.entries})
 
-    def max_shard_size(self) -> int:
-        return max(e.shard.size for e in self.entries)
-
     def __eq__(self, other) -> bool:
         return (isinstance(other, ShardMap)
                 and self.version == other.version

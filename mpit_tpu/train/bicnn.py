@@ -33,6 +33,7 @@ trajectory — SURVEY.md section 7):
 
 from __future__ import annotations
 
+import functools
 import pathlib
 import time
 from typing import Any, Dict, List, Optional, Tuple
@@ -295,7 +296,6 @@ class BiCNNTrainer:
         self._pool_cache: Dict[str, tuple] = {}
         self._pool_score = jax.jit(_pool_score)
         self._vgf = self._build_vgf()
-        self._optimizer = None
         # loss-print accumulators (bicnn.lua:283, :414-418).  A running
         # *device* scalar sum, fetched only at report time — a float()
         # per step would fence the dispatch pipeline on every batch, and
@@ -436,13 +436,9 @@ class BiCNNTrainer:
 
     KNOWN_OPTS = ("sgd", "downpour", "eamsgd", "easgd") + _GLOBAL + tuple(_SINGLE)
 
-    @property
+    @functools.cached_property
     def optimizer(self):
-        if self._optimizer is None:
-            self._optimizer = self._make_optimizer()
-        return self._optimizer
-
-    def _make_optimizer(self):
+        """Built at first use: eval-only roles never need one."""
         cfg = self.cfg
         name = cfg.optimization
         if name not in self.KNOWN_OPTS:

@@ -28,6 +28,7 @@ import sys
 import time
 from typing import Any, Dict, List, Optional, Tuple
 
+from mpit_tpu.lm import archs as _lm_archs  # plain data: loads no jax
 from mpit_tpu.optim import rules as rules_mod
 from mpit_tpu.ps import ParamClient, ParamServer
 from mpit_tpu.train.trainer import TRAINER_DEFAULTS, MnistTrainer
@@ -209,67 +210,12 @@ LAUNCH_DEFAULTS = TRAINER_DEFAULTS.merged(
     # the equal split — lm_weights skews it ("3,1" = server 0 aims at
     # 3/4 of the vector), empty = balanced cut on parameter boundaries.
     lm=0,
-    # the block: gpt2 (LayerNorm, learned positions, GELU MLP) | olmoe
-    # (RMSNorm, rotary positions, q/k norm, top-k of E gated experts by
-    # sorted dropless dispatch) | mellum (grouped KV heads of their own
-    # width, window and full attention mixed, top-k renormalised, a
-    # share of the experts) | lfm2 (a gated short convolution or
-    # grouped-head attention with a per-head q/k norm by layer, a dense
-    # or a sparse MLP by layer, a sigmoid router with a selection bias)
-    # | ouro (the stack of layers run lm_loop_steps times with the same
-    # weights, sandwich norms, a head and an exit gate at every pass and
-    # a loss of its own over them; it takes lm_kv_heads, lm_head_dim and
-    # lm_dense_width too) | joyai (latent attention with keys wider than
-    # values, a shared expert beside a share of the routed ones behind
-    # lfm2's router, a multi-token-prediction module and a loss of its
-    # own over both heads; it takes the share, lm_dense_layers,
-    # lm_dense_width and lm_route_scale too).
-    # lm_experts .. lm_norm_eps are the sparse blocks'; lm_kv_heads ..
-    # lm_yarn_attn_factor are mellum's own, of which lfm2 takes the
-    # first four; lm_layer_types .. lm_route_scale are lfm2's own,
-    # lm_loop_steps, lm_exit_beta and lm_exit_bias ouro's, lm_q_rank ..
-    # lm_mtp_weight joyai's
-    # (lm/model.py build has each one's meaning and what 0 stands for)
+    # the block (lm/archs.py BLOCKS) and every block's sizes, one switch
+    # each: the table there has each one's name, default and meaning,
+    # and which block takes which
     lm_arch="gpt2",
-    lm_experts=8,
-    lm_experts_per_tok=2,
-    lm_expert_width=32,
-    lm_rope_theta=10000.0,
-    lm_norm_eps=1e-5,
-    lm_kv_heads=0,
-    lm_head_dim=0,
-    lm_experts_first=0,
-    lm_experts_held=0,
-    lm_window=0,
-    lm_full_every=4,
-    lm_yarn_factor=0.0,
-    lm_yarn_orig=0,
-    lm_yarn_beta_fast=32.0,
-    lm_yarn_beta_slow=1.0,
-    lm_yarn_attn_factor=1.0,
-    lm_layer_types="",
-    lm_dense_layers=0,
-    lm_dense_width=0,
-    lm_conv_kernel=3,
-    lm_route_scale=1.0,
-    lm_loop_steps=4,
-    lm_exit_beta=0.1,
-    lm_exit_bias=0.0,
-    lm_q_rank=0,
-    lm_kv_rank=0,
-    lm_qk_nope=0,
-    lm_qk_rope=0,
-    lm_v_head=0,
-    lm_shared_experts=1,
-    lm_mtp_layers=1,
-    lm_mtp_weight=0.3,
-    lm_d_model=64,
-    lm_heads=4,
-    lm_layers=2,
-    lm_seq=128,
-    # rows of the token table and the head; 0 = lm.model.build's own
-    # keyword default (the byte stream's 256)
-    lm_vocab=0,
+    **{switch: _lm_archs.DEFAULTS[name]
+       for name, switch in _lm_archs.SWITCHES.items()},
     lm_steps=200,
     lm_eval_every=50,
     # -1 auto (flash on TPU) | 0 jnp reference | 1 the Pallas kernel
@@ -381,34 +327,31 @@ def serve_cfg_for(cfg: Config):
 def lm_trainer_cfg(cfg: Config) -> Config:
     """The :data:`mpit_tpu.lm.trainer.LM_DEFAULTS`-shaped config for one
     launch config: shared optimizer/loop knobs carried over verbatim,
-    lm_* knobs mapped onto the trainer's names."""
-    from mpit_tpu.lm.model import (
-        JOYAI_KEYS, LFM2_KEYS, MELLUM_KEYS, OURO_KEYS,
-    )
-    from mpit_tpu.lm.trainer import LM_DEFAULTS
+    lm_* knobs mapped onto the trainer's names, each in the type of its
+    launch default and at that default where ``cfg`` has none."""
+    def knob(key: str) -> Any:
+        default = LAUNCH_DEFAULTS[key]
+        return type(default)(cfg.get(key, default))
 
     return Config(
-        **{key: type(LM_DEFAULTS[key])(cfg.get(f"lm_{key}", LM_DEFAULTS[key]))
-           for key in MELLUM_KEYS + LFM2_KEYS + OURO_KEYS + JOYAI_KEYS},
-        arch=str(cfg.get("lm_arch", "gpt2")),
-        n_experts=int(cfg.get("lm_experts", 8)),
-        experts_per_tok=int(cfg.get("lm_experts_per_tok", 2)),
-        expert_width=int(cfg.get("lm_expert_width", 32)),
-        rope_theta=float(cfg.get("lm_rope_theta", 10000.0)),
-        norm_eps=float(cfg.get("lm_norm_eps", 1e-5)),
-        d_model=int(cfg.get("lm_d_model", 64)),
-        n_heads=int(cfg.get("lm_heads", 4)),
-        n_layers=int(cfg.get("lm_layers", 2)),
-        seq_len=int(cfg.get("lm_seq", 128)),
-        vocab=int(cfg.get("lm_vocab", 0)),
-        steps=int(cfg.get("lm_steps", 200)),
-        eval_every=int(cfg.get("lm_eval_every", 50)),
-        use_flash=int(cfg.get("lm_use_flash", -1)),
+        **{name: knob(switch)
+           for name, switch in _lm_archs.SWITCHES.items()},
+        arch=knob("lm_arch"), steps=knob("lm_steps"),
+        eval_every=knob("lm_eval_every"), use_flash=knob("lm_use_flash"),
         opt=cfg.opt, lr=cfg.lr, lrd=cfg.lrd, lrp=cfg.lrp, mom=cfg.mom,
         mommax=cfg.mommax, momdecay=cfg.momdecay, l2wd=cfg.l2wd,
         mva=cfg.mva, su=cfg.su, batch=cfg.batch, seed=cfg.seed,
         dtype=cfg.dtype, profile_dir=cfg.get("profile_dir", ""),
     )
+
+
+def _lm_shapes(cfg: Config):
+    """The LM as a worker builds it, for its parameters' shapes: they do
+    not depend on the attention implementation, so a host role's never
+    touches the accelerator kernels."""
+    from mpit_tpu.lm.model import build, build_kw
+
+    return build(use_flash=False, **build_kw(lm_trainer_cfg(cfg)))
 
 
 def lm_layout(cfg: Config, n_servers: int):
@@ -417,12 +360,9 @@ def lm_layout(cfg: Config, n_servers: int):
     must announce identically.  ``lm_weights`` ("3,1") skews the
     targets; empty keeps balanced targets (still boundary-aligned, so
     it differs from the raw equal split)."""
-    from mpit_tpu.lm import build, plan
-    from mpit_tpu.lm.model import build_kw
+    from mpit_tpu.lm import plan
 
-    # Param *shapes* don't depend on the attention implementation, so
-    # layout derivation never touches the accelerator kernels.
-    model = build(use_flash=False, **build_kw(lm_trainer_cfg(cfg)))
+    model = _lm_shapes(cfg)
     params = model.flat.unravel(model.flat.w0)
     spec = str(cfg.get("lm_weights", "") or "")
     weights = ([float(x) for x in spec.split(",") if x.strip() != ""]
@@ -448,11 +388,7 @@ def _serve_vec_len(cfg: Config, rank: int) -> int:
 
     full = TRAINER_DEFAULTS.merged(cfg.to_dict())
     if int(cfg.get("lm", 0)):
-        from mpit_tpu.lm import build
-        from mpit_tpu.lm.model import build_kw
-
-        model = build(use_flash=False, **build_kw(lm_trainer_cfg(cfg)))
-        return int(model.flat.size)
+        return int(_lm_shapes(cfg).flat.size)
     x_train = load_mnist(side=full.side)[0][0]
     if full.model == "cnn":
         module = MnistCNN(num_classes=10, side=full.side)

@@ -17,6 +17,7 @@ loop with sequential unshuffled batches (:129-146), and per-phase timers
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict, Optional
 
 import jax
@@ -101,14 +102,6 @@ class MnistTrainer:
             return jnp.mean((jnp.argmax(logp, axis=1) != yb).astype(jnp.float32))
 
         self._err = jax.jit(err_fn)
-        self._optimizer = None  # built lazily: eval-only roles (the tester,
-        # reference bicnn.lua:580-596) never need one
-
-    @property
-    def optimizer(self):
-        if self._optimizer is None:
-            self._optimizer = self._make_optimizer()
-        return self._optimizer
 
     # -- optimizer dispatch (reference goot.lua:66-89, bicnn.lua:127-252) ----
 
@@ -119,7 +112,10 @@ class MnistTrainer:
         "adagrad-single", "adadelta-single",
     )
 
-    def _make_optimizer(self):
+    @functools.cached_property
+    def optimizer(self):
+        """Built at first use: eval-only roles (the tester, reference
+        bicnn.lua:580-596) never need one."""
         cfg = self.cfg
         name = cfg.opt
         if name not in self.KNOWN_OPTS:
@@ -160,9 +156,6 @@ class MnistTrainer:
 
     def test_error(self, w: Optional[jnp.ndarray] = None) -> float:
         return float(self._err(self.w if w is None else w, self.x_test, self.y_test))
-
-    def train_error(self, w: Optional[jnp.ndarray] = None) -> float:
-        return float(self._err(self.w if w is None else w, self.x_train, self.y_train))
 
     # -- the epoch loop (reference goot.lua:129-146) -------------------------
 

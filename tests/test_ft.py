@@ -467,9 +467,12 @@ class TestHeartbeatLeaseEviction:
             t.start()
         join_all(starters)
         # the lease arms on c2's first delivered beat (not at INIT —
-        # arming before the seeding phase would evict mid-seed)
+        # arming before the seeding phase would evict mid-seed); wait for
+        # c2's own, not for two beats of anyone's: on a loaded machine
+        # both may be c1's, and a lease never armed never runs out
         deadline = time.monotonic() + 10
-        while servers[0].heartbeats_seen < 2 and time.monotonic() < deadline:
+        while (not servers[0].leases.armed(c2.rank)
+               and time.monotonic() < deadline):
             c2.ping()
             c2.wait()
             time.sleep(0.005)

@@ -3,8 +3,9 @@
 with grouped KV heads, sliding-window and full attention mixed, a
 rotary table per layer type, a renormalised top-k and a share of the
 experts by ``parallel/moe.py``'s sorted dropless dispatch) against its
-plain float32 reference (``lm/mellum_reference.py``: dense over the held
-experts, a materialised mask, no code shared), at the benchmark
+plain float32 reference (``chipbench/reference/mellum_plain.py``, the
+benchmark's: dense over the held experts, a materialised mask, no code
+shared), at the benchmark
 configuration's ``tiny`` size on seeded weights; the flash kernel's
 grouped heads and window against ``attention_reference``; and the share
 of the experts against the uncut layer.
@@ -24,8 +25,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from chipbench.reference import mellum_plain as ref
 from mpit_tpu import obs
-from mpit_tpu.lm import mellum_reference as ref
 from mpit_tpu.lm.model import build, build_kw
 from mpit_tpu.ops.flash_attention import attention_reference, flash_attention
 from mpit_tpu.parallel import moe

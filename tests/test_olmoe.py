@@ -1,9 +1,10 @@
 """The OLMoE block on the normal path (``lm/model.py``
 ``build(arch="olmoe")``: ``models/transformer.py`` ``OlmoeDecoder`` with
 ``parallel/moe.py``'s sorted dropless dispatch) against its plain
-float32 reference (``lm/olmoe_reference.py``: dense over experts, dense
-masked attention, no code shared), at the benchmark configuration's
-``tiny`` size on seeded weights.
+float32 reference (``chipbench/reference/olmoe_plain.py``, the
+benchmark's: dense over experts, dense masked attention, no code
+shared), at the benchmark configuration's ``tiny`` size on seeded
+weights.
 
 Tolerances.  On the CPU both sides multiply in full float32, so they
 differ by the rounding of sums taken in another order: 5e-7 of the
@@ -19,7 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from mpit_tpu.lm import olmoe_reference as ref
+from chipbench.reference import olmoe_plain as ref
 from mpit_tpu.lm.model import build
 from mpit_tpu.models import transformer
 

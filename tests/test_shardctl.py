@@ -8,6 +8,7 @@ retries admit at-most-once on the new owner, and lockstep turns pin the
 cross-client apply order (same discipline as tests/test_ft.py).
 """
 
+import dataclasses
 import threading
 import tempfile
 
@@ -297,9 +298,23 @@ class TestShardctlGang:
 
     def test_live_migration_under_drop_dup_plans_stays_bitwise(self):
         """The acceptance matrix, shardctl edition: client data drops +
-        dups, server reply drops, a migration mid-run — still bitwise."""
+        dups, server reply drops, a migration mid-run — still bitwise.
+
+        Two things of its own keep it so under a loaded machine (six
+        xdist workers).  The rebalance policy is off: after the
+        migration one server owns both shards, and once its busy
+        seconds pass the policy's floor, which a loaded machine and the
+        duplicates' work see to, the last ``pump`` proposes the move
+        back to a server whose thread has already stopped and waits out
+        the controller's 60 s for its DONE; the one migration under
+        test is the hook's.  And an op deadline twice ``FAST_FT``'s
+        with two retries more: a retry that a slow turn causes, on top
+        of the plans' own, is deduplicated, ten in a row are a
+        failure."""
         w0, gtab = self._tables()
         static, *_ = self._run(w0, gtab, 6)
+        patient = dataclasses.replace(FAST_FT, op_deadline_s=0.6,
+                                      max_retries=12)
 
         def hook(r, ctl, servers, threads):
             if r == 2:
@@ -312,10 +327,13 @@ class TestShardctlGang:
         server_plan = FaultPlan(seed=9, drop_every=3, tags=REPLY_TAGS)
         faulty, servers, clients, ctl = self._run(
             w0, gtab, 6, hook=hook,
-            client_plans=client_plans, server_plan=server_plan)
+            client_plans=client_plans, server_plan=server_plan,
+            client_ft=patient, server_ft=patient,
+            ctl_kwargs={"policy": RebalancePolicy(enabled=False)})
         np.testing.assert_array_equal(static, faulty)
         assert sum(int(s.dup_ops) for s in servers) > 0, \
             "no duplicate was ever admitted — the plan never bit"
+        assert ctl.smap.version == 1 and ctl.smap.owner(0) == 1
 
     def test_migration_preserves_int8_error_feedback(self):
         """Quantized gang: the residual telescope survives a migration
