@@ -45,7 +45,8 @@ SIZES: Dict[str, Size] = {
                          "of dense_width and not the sparse one"),
     "dense_width": Size(0, "the dense MLP's inner width (ouro's one MLP)"),
     # rotary positions, RMSNorm, grouped heads
-    "rope_theta": Size(10000.0, "the rotary base"),
+    "rope_theta": Size(10000.0, "the rotary base; kimi's 0: no rotary "
+                       "embedding in its latent attention"),
     "norm_eps": Size(1e-5, "the RMSNorm epsilon"),
     "kv_heads": Size(0, "key/value heads; 0: as many as query heads"),
     "head_dim": Size(0, "a head's width; 0: d_model / n_heads"),
@@ -59,9 +60,11 @@ SIZES: Dict[str, Size] = {
     "yarn_beta_slow": Size(1.0, "YaRN's beta_slow"),
     "yarn_attn_factor": Size(1.0, "YaRN's attention_factor"),
     # lfm2's mixers
-    "layer_types": Size("", "each held layer's token mixer, conv or "
-                        "full_attention, comma-separated, n_layers of them"),
-    "conv_kernel": Size(3, "the short convolution's taps"),
+    "layer_types": Size("", "each held layer's token mixer, comma-separated, "
+                        "n_layers of them: lfm2's conv or full_attention, "
+                        "kimi's kda or full_attention"),
+    "conv_kernel": Size(3, "the taps of a short causal depthwise convolution "
+                        "(lfm2's gated one; kimi's on q, k and v: 4)"),
     # ouro's loop
     "loop_steps": Size(4, "how often the layers are applied, same weights"),
     "exit_beta": Size(0.1, "the exit distribution's entropy's weight in the "
@@ -69,7 +72,8 @@ SIZES: Dict[str, Size] = {
     "exit_bias": Size(0.0, "what the exit gate's bias is seeded at (0: a gate "
                       "of a half; negative: nearer to running every pass)"),
     # joyai's latent attention, shared expert and second head
-    "q_rank": Size(0, "the queries' low-rank product's inner width"),
+    "q_rank": Size(0, "the queries' low-rank product's inner width; kimi's "
+                   "0: no query latent, one product"),
     "kv_rank": Size(0, "the keys' and values' low-rank product's inner width"),
     "qk_nope": Size(0, "a head's query and key without positions"),
     "qk_rope": Size(0, "the rotary part of a head's query and key (even)"),
@@ -78,6 +82,9 @@ SIZES: Dict[str, Size] = {
                            "beside the routed ones"),
     "mtp_layers": Size(1, "0 or 1: the multi-token-prediction module"),
     "mtp_weight": Size(0.3, "the MTP loss's weight in the block's objective"),
+    # kimi's delta attention
+    "kda_heads": Size(0, "heads of the delta attention's state"),
+    "kda_head_dim": Size(0, "a state's side: a head's keys and values"),
 }
 
 DEFAULTS = {name: size.default for name, size in SIZES.items()}
@@ -167,15 +174,20 @@ def _mellum(s, attn):
     return _module("MellumDecoder", s, attn(), yarn=yarn, **_heads(s))
 
 
-def _lfm2(s, attn):
-    _check_share(s)
+def _layer_kinds(s: Mapping[str, Any]) -> Tuple[str, ...]:
     kinds = tuple(kind.strip() for kind in s["layer_types"].split(",")
                   if kind.strip())
     if len(kinds) != s["n_layers"]:
         raise ValueError(f"layer_types names {len(kinds)} layers "
                          f"({s['layer_types']!r}), n_layers is "
                          f"{s['n_layers']}")
-    return _module("Lfm2Decoder", s, attn(), layer_types=kinds, **_heads(s))
+    return kinds
+
+
+def _lfm2(s, attn):
+    _check_share(s)
+    return _module("Lfm2Decoder", s, attn(), layer_types=_layer_kinds(s),
+                   **_heads(s))
 
 
 def _ouro(s, attn):
@@ -192,6 +204,19 @@ def _joyai(s, attn):
         raise ValueError(f"joyai needs q_rank, kv_rank, qk_nope, qk_rope "
                          f"(even) and v_head: {latent}")
     return _module("JoyaiDecoder", s, attn())
+
+
+def _kimi(s, attn):
+    _check_share(s)
+    kinds = _layer_kinds(s)
+    latent = tuple(s[name] for name in (
+        "kv_rank", "qk_nope", "qk_rope", "v_head", "kda_heads",
+        "kda_head_dim"))
+    if min(latent) < 1 or s["q_rank"] < 0 or s["qk_rope"] % 2:
+        raise ValueError(f"kimi needs kv_rank, qk_nope, qk_rope (even), "
+                         f"v_head, kda_heads and kda_head_dim: {latent}; "
+                         f"q_rank {s['q_rank']} (0: no query latent)")
+    return _module("KimiDecoder", s, attn(), layer_types=kinds)
 
 
 # what each block is: its decoder's docstring (``models/transformer.py``)
@@ -218,6 +243,12 @@ BLOCKS: Dict[str, Block] = {
             "kv_rank", "qk_nope", "qk_rope", "v_head", "shared_experts",
             "mtp_layers", "mtp_weight"),
         _joyai, loss=OWN_LOSS),
+    "kimi": Block(
+        _SPARSE + _SHARE + _ROTARY + (
+            "layer_types", "conv_kernel", "kda_heads", "kda_head_dim",
+            "dense_layers", "dense_width", "route_scale", "q_rank",
+            "kv_rank", "qk_nope", "qk_rope", "v_head", "shared_experts"),
+        _kimi, loss=OWN_LOSS),
 }
 ARCHS = tuple(BLOCKS)
 

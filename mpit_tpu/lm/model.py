@@ -153,8 +153,12 @@ def _head_nll(module: Any, key: Any, sample: Any, sown_stats: bool) -> Closed:
 def _own_loss(module: Any, key: Any, sample: Any) -> Closed:
     """A decoder that closes its own loss: ``module(inputs, targets) ->
     (loss, {name: device scalar})``.  The statistics are the step's
-    telemetry as they come."""
-    fm = FlatModel(module, module.init(key, sample, sample)["params"])
+    telemetry as they come.  The seeding is one compiled program: run
+    eagerly, ``init`` compiles every operation of the forward pass on
+    its own, 350 of them and two minutes on a chip for a delta-attention
+    block (ROADMAP S7), to throw the result away."""
+    fm = FlatModel(
+        module, jax.jit(module.init)(key, sample, sample)["params"])
 
     def loss_and_stats(w, tokens):
         # tokens: (B, seq_len + 1) int32 — packed, every cell real.

@@ -15,7 +15,7 @@ long-context showcase built on the framework's own kernels:
   batched over (B, L);
 - pre-LN blocks, learned positional embeddings, causal by default.
 
-Six decoders, each described where it is defined; ``lm/archs.py``
+Seven decoders, each described where it is defined; ``lm/archs.py``
 ``BLOCKS`` names them with the sizes they take, and ``lm/model.py``
 ``build(arch=...)`` chooses one.
 """
@@ -33,6 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
+from mpit_tpu.ops.delta_rule import KDA_OUT, kda_scan
 from mpit_tpu.ops.flash_attention import (
     FLASH_LSE, FLASH_OUT, attention_reference, flash_attention,
 )
@@ -1104,26 +1105,36 @@ def rope_interleaved(x: jnp.ndarray, inv_freq: np.ndarray) -> jnp.ndarray:
 
 
 def latent_attention(x: jnp.ndarray, p: dict, *, heads: int, qk_nope: int,
-                     qk_rope: int, v_head: int, inv_freq: np.ndarray,
-                     eps: float, attn: AttnFn) -> jnp.ndarray:
+                     qk_rope: int, v_head: int,
+                     inv_freq: Optional[np.ndarray], eps: float,
+                     attn: AttnFn) -> jnp.ndarray:
     """Multi-head latent attention on the stream ``x (B, L, d)`` with
     the weights ``p``, projected back to ``(B, L, d)``; pure in both.
     Every product one bf16 pass on a TPU, as Mellum's: the inner norms
-    hold the scores at O(1) (PERF.md section 6, PR 38)."""
+    hold the scores at O(1) (PERF.md section 6, PR 38).  **No query
+    latent** where ``p`` has ``wq`` and not the pair ``wq_a``, ``wq_b``
+    with the norm between them (``q_lora_rank`` null: one product).
+    **No positions** where ``inv_freq`` is None (``mla_use_nope``): the
+    ``qk_rope`` dimensions of query and key are kept, the key's still
+    one head repeated into every head's, and nothing is rotated."""
     b, l, _ = x.shape
+
+    def turned(part):
+        return part if inv_freq is None else rope_interleaved(part, inv_freq)
+
     with jax.named_scope("mla_proj"):
         h = rms_norm(x, p["attn_norm"], eps)
-        q = rms_norm(h @ p["wq_a"], p["q_a_norm"], eps) @ p["wq_b"]
+        q = h @ p["wq"] if "wq" in p else rms_norm(
+            h @ p["wq_a"], p["q_a_norm"], eps) @ p["wq_b"]
         q = q.reshape(b, l, heads, qk_nope + qk_rope)
         kv_a = h @ p["wkv_a"]                       # (B, L, kv_rank + rope)
         kv_rank = kv_a.shape[-1] - qk_rope
         kv = rms_norm(kv_a[..., :kv_rank], p["kv_a_norm"], eps) @ p["wkv_b"]
         kv = kv.reshape(b, l, heads, qk_nope + v_head)
         # the rotary key: one head, used by every query head
-        k_rope = rope_interleaved(kv_a[..., None, kv_rank:], inv_freq)
+        k_rope = turned(kv_a[..., None, kv_rank:])
         q = jnp.concatenate(
-            [q[..., :qk_nope], rope_interleaved(q[..., qk_nope:], inv_freq)],
-            axis=-1)
+            [q[..., :qk_nope], turned(q[..., qk_nope:])], axis=-1)
         k = jnp.concatenate(
             [kv[..., :qk_nope],
              jnp.broadcast_to(k_rope, (b, l, heads, qk_rope))], axis=-1)
@@ -1132,9 +1143,94 @@ def latent_attention(x: jnp.ndarray, p: dict, *, heads: int, qk_nope: int,
         return attn(q, k, v).reshape(b, l, heads * v_head) @ p["wo"]
 
 
+def latent_mixer(block, x):
+    """The latent attention of ``block`` (:class:`JoyaiBlock`,
+    :class:`KimiBlock`) on the stream ``x``: its parameters made in the
+    block's own scope, the query's low-rank pair where ``q_rank`` is not
+    0 and one product where it is, rotary positions where ``rope_theta``
+    is not 0.
+
+    Kept for the backward pass: the layer's input and the flash rule's
+    two.  q and k (T x heads x 192 floats each), v and the latents are
+    made again from the input: five products of which the largest is
+    1536 x 6144, a twentieth of the layer's attention kernels at 8192
+    positions, for 0.8 GB a layer that the step does not have (PERF.md
+    section 4, the compile's row)."""
+    d, hq = block.d_model, block.n_heads
+    qk, ones = block.qk_nope + block.qk_rope, nn.initializers.ones
+    query = ((("wq_a", _INIT, (d, block.q_rank)),
+              ("q_a_norm", ones, (block.q_rank,)),
+              ("wq_b", _INIT, (block.q_rank, hq * qk)))
+             if block.q_rank else (("wq", _INIT, (d, hq * qk)),))
+    p = {name: block.param(name, init, shape) for name, init, shape in (
+        ("attn_norm", ones, (d,)), *query,
+        ("wkv_a", _INIT, (d, block.kv_rank + block.qk_rope)),
+        ("kv_a_norm", ones, (block.kv_rank,)),
+        ("wkv_b", _INIT, (block.kv_rank,
+                          hq * (block.qk_nope + block.v_head))),
+        ("wo", _INIT, (hq * block.v_head, d)))}
+    attend = partial(
+        latent_attention, heads=hq, qk_nope=block.qk_nope,
+        qk_rope=block.qk_rope, v_head=block.v_head, eps=block.norm_eps,
+        inv_freq=plain_inv_freq(block.qk_rope, block.rope_theta)
+        if block.rope_theta else None,
+        attn=block.attn_fn if block.attn_fn is not None else default_attn())
+    return jax.checkpoint(
+        attend, policy=jax.checkpoint_policies.save_only_these_names(
+            *JOYAI_ATTN_KEPT))(x, p)
+
+
 def swiglu(h: jnp.ndarray, w_gate: jnp.ndarray, w_up: jnp.ndarray,
            w_down: jnp.ndarray) -> jnp.ndarray:
     return (jax.nn.silu(h @ w_gate) * (h @ w_up)) @ w_down
+
+
+def shared_sparse_experts(block, x, norm):
+    """The sparse MLP of ``block`` with a shared expert
+    (:class:`JoyaiBlock`, :class:`KimiBlock`): its parameters made in the
+    block's own scope, ``(output, statistics in
+    :data:`JOYAI_MOE_STATS`' order)``."""
+    d, e, f = block.d_model, block.n_experts, block.expert_width
+    held, shared = block.experts_held or e, block.shared_experts * f
+    router = block.param("router", _INIT, (d, e))
+    # the selection bias (``noaux_tc``'s ``e_score_correction_bias``):
+    # as LFM2's, part of the vector, seeded away from zero, reached
+    # by no gradient and updated by no rule
+    bias = block.param("router_bias", _INIT, (e,))
+    routed = tuple(block.param(f"experts_{name}", _INIT, shape)
+                   for name, shape in (("gate", (held, d, f)),
+                                       ("up", (held, d, f)),
+                                       ("down", (held, f, d))))
+    shared_w = tuple(block.param(f"shared_{name}", _INIT, shape)
+                     for name, shape in (("gate", (d, shared)),
+                                         ("up", (d, shared)),
+                                         ("down", (shared, d)))
+                     ) if shared else ()
+
+    # recomputed in the backward pass, as Mellum's and LFM2's and for
+    # their reason; the shared expert with it (three products 768
+    # wide: a hundredth of the step)
+    @jax.checkpoint
+    def branch(x, norm, router, bias, routed, shared_w):
+        def route(logits):
+            scores = jax.nn.sigmoid(logits)
+            weights, chosen = moe.route_top_k(
+                scores, block.experts_per_tok, renormalise=True,
+                bias=bias, eps=JOYAI_ROUTE_EPS, scale=block.route_scale)
+            return weights, chosen, (
+                moe.bias_flips_share(scores, chosen),)
+
+        y, stats = sparse_mlp(
+            x, norm, router, routed, route=route, eps=block.norm_eps,
+            n_experts=e, first=block.experts_first, held=held)
+        if shared_w:
+            with jax.named_scope("shared_expert"):
+                # every token, whole on every share: counted once
+                y = y + swiglu(rms_norm(x, norm, block.norm_eps),
+                               *shared_w)
+        return y, stats
+
+    return branch(x, norm, router, bias, routed, shared_w)
 
 
 class JoyaiBlock(nn.Module):
@@ -1163,33 +1259,9 @@ class JoyaiBlock(nn.Module):
         """``(the stream after the layer, the sparse branch's statistics
         in :data:`JOYAI_MOE_STATS`' order)``; a dense layer has none,
         ``()``."""
-        d, eps, hq = self.d_model, self.norm_eps, self.n_heads
-        qk = self.qk_nope + self.qk_rope
+        d, eps = self.d_model, self.norm_eps
         ones = nn.initializers.ones
-        attn_p = {name: self.param(name, init, shape) for name, init, shape in (
-            ("attn_norm", ones, (d,)),
-            ("wq_a", _INIT, (d, self.q_rank)),
-            ("q_a_norm", ones, (self.q_rank,)),
-            ("wq_b", _INIT, (self.q_rank, hq * qk)),
-            ("wkv_a", _INIT, (d, self.kv_rank + self.qk_rope)),
-            ("kv_a_norm", ones, (self.kv_rank,)),
-            ("wkv_b", _INIT, (self.kv_rank,
-                              hq * (self.qk_nope + self.v_head))),
-            ("wo", _INIT, (hq * self.v_head, d)))}
-        attend = partial(
-            latent_attention, heads=hq, qk_nope=self.qk_nope,
-            qk_rope=self.qk_rope, v_head=self.v_head, eps=eps,
-            inv_freq=plain_inv_freq(self.qk_rope, self.rope_theta),
-            attn=self.attn_fn if self.attn_fn is not None else default_attn())
-        # Kept for the backward pass: the layer's input and the flash
-        # rule's two.  q and k (T x heads x 192 floats each), v and the
-        # latents are made again from the input: five products of which
-        # the largest is 1536 x 6144, a twentieth of the layer's
-        # attention kernels at 8192 positions, for 0.8 GB a layer that
-        # the step does not have (PERF.md section 4, the compile's row).
-        x = x + jax.checkpoint(
-            attend, policy=jax.checkpoint_policies.save_only_these_names(
-                *JOYAI_ATTN_KEPT))(x, attn_p)
+        x = x + latent_mixer(self, x)
 
         mlp_norm = self.param("mlp_norm", ones, (d,))
         if not self.sparse:
@@ -1199,51 +1271,8 @@ class JoyaiBlock(nn.Module):
                     self.param("w_gate", _INIT, (d, self.dense_width)),
                     self.param("w_up", _INIT, (d, self.dense_width)),
                     self.param("w_down", _INIT, (self.dense_width, d))), ()
-        y, stats = self.sparse_experts(x, mlp_norm)
+        y, stats = shared_sparse_experts(self, x, mlp_norm)
         return x + y, stats
-
-    def sparse_experts(self, x, norm):
-        d, e, f = self.d_model, self.n_experts, self.expert_width
-        held, shared = self.experts_held or e, self.shared_experts * f
-        router = self.param("router", _INIT, (d, e))
-        # the selection bias (``noaux_tc``'s ``e_score_correction_bias``):
-        # as LFM2's, part of the vector, seeded away from zero, reached
-        # by no gradient and updated by no rule
-        bias = self.param("router_bias", _INIT, (e,))
-        routed = tuple(self.param(f"experts_{name}", _INIT, shape)
-                       for name, shape in (("gate", (held, d, f)),
-                                           ("up", (held, d, f)),
-                                           ("down", (held, f, d))))
-        shared_w = tuple(self.param(f"shared_{name}", _INIT, shape)
-                         for name, shape in (("gate", (d, shared)),
-                                             ("up", (d, shared)),
-                                             ("down", (shared, d)))
-                         ) if shared else ()
-
-        # recomputed in the backward pass, as Mellum's and LFM2's and for
-        # their reason; the shared expert with it (three products 768
-        # wide: a hundredth of the step)
-        @jax.checkpoint
-        def branch(x, norm, router, bias, routed, shared_w):
-            def route(logits):
-                scores = jax.nn.sigmoid(logits)
-                weights, chosen = moe.route_top_k(
-                    scores, self.experts_per_tok, renormalise=True,
-                    bias=bias, eps=JOYAI_ROUTE_EPS, scale=self.route_scale)
-                return weights, chosen, (
-                    moe.bias_flips_share(scores, chosen),)
-
-            y, stats = sparse_mlp(
-                x, norm, router, routed, route=route, eps=self.norm_eps,
-                n_experts=e, first=self.experts_first, held=held)
-            if shared_w:
-                with jax.named_scope("shared_expert"):
-                    # every token, whole on every share: counted once
-                    y = y + swiglu(rms_norm(x, norm, self.norm_eps),
-                                   *shared_w)
-            return y, stats
-
-        return branch(x, norm, router, bias, routed, shared_w)
 
 
 class JoyaiDecoder(nn.Module):
@@ -1361,6 +1390,271 @@ class JoyaiDecoder(nn.Module):
                 mtp = jnp.mean(nll[:, :-1])
             loss = main + self.mtp_weight * mtp
             stats["lm_mtp_nll"] = mtp[None]
+        if routing:
+            stats.update(zip(JOYAI_MOE_STATS, map(jnp.stack, zip(*routing))))
+        return loss, stats
+
+
+# ---------------------------------------------------------------------------
+# The linear-attention hybrid (Kimi-Linear, Moonshot; ``model_type``
+# ``kimi_linear``; the configuration's keys are those of its
+# ``config.json``, the equations the Kimi Linear report's,
+# arXiv:2510.26692).  A layer's token mixer is one of two
+# (``layer_types``).  ``kda``, **Kimi Delta Attention**: a gated delta
+# rule whose state, a ``kda_head_dim x kda_head_dim`` matrix a head, is
+# carried along the sequence, decayed **per key channel** and corrected
+# by a rank-one term at every position (``ops/delta_rule.py`` has the
+# recurrence and its chunked form); queries, keys and values each go
+# through a depthwise causal convolution of ``conv_kernel`` taps and a
+# SiLU, queries and keys are L2-normalised a head, the log-decay and the
+# output gate are low-rank products of the layer's input, and the
+# result is RMSNormed a head and gated before ``W_o``.
+# ``full_attention``: JoyAI's latent attention **without a query
+# latent** (``q_rank`` 0) **and without positions** (``rope_theta`` 0:
+# the hybrid leaves order to the KDA layers).  The MLP is dense on the
+# leading layers and else JoyAI's: a sigmoid router with a selection
+# bias over all ``n_experts``, this chip's share of the routed experts
+# and a shared expert.  The plain float32 reference it is held to is
+# ``chipbench/reference/kimi_plain.py``, which shares no code with this
+# file and computes the recurrence token by token (tests/test_kimi.py).
+# ---------------------------------------------------------------------------
+
+#: the kinds of token mixer ``layer_types`` may name
+KIMI_MIXERS = ("kda", "full_attention")
+#: the guard of the heads' L2 norm (``q / sqrt(sum q^2 + eps)``)
+KDA_L2_EPS = 1e-6
+#: What a ``kda`` mixer's checkpoint keeps beside its input: the scan's
+#: result (``T x heads x head_dim`` floats a layer), so that the
+#: backward pass makes q, k, v, the decay and the gates again, six
+#: products and three convolutions, and runs the scan's own backward
+#: rule, which computes the chunks again, once and not twice.
+KDA_KEPT = (KDA_OUT,)
+#: the name of the decay's mean in the step's telemetry (gauge
+#: ``mpit_lm_kda_decay_mean``, one entry a ``kda`` layer)
+KDA_DECAY_MEAN = "lm_kda_decay_mean"
+
+
+def kda_a_log_init(key, shape, dtype=jnp.float32):
+    """``A_log = log U(1, 16)``, a head."""
+    return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
+
+
+def kda_dt_bias_init(key, shape, dtype=jnp.float32):
+    """The inverse softplus of a step drawn log-uniformly from ``[1e-3,
+    0.1]``: with :func:`kda_a_log_init` the decay ``alpha = exp(-A
+    softplus(dt_bias))`` lies in about 0.2-0.999 at the seed, so a
+    state's memory is neither none nor everything."""
+    step = jnp.exp(jax.random.uniform(
+        key, shape, dtype, math.log(1e-3), math.log(0.1)))
+    return step + jnp.log(-jnp.expm1(-step))
+
+
+def l2_norm(x: jnp.ndarray) -> jnp.ndarray:
+    return x * jax.lax.rsqrt(
+        jnp.sum(x * x, axis=-1, keepdims=True) + KDA_L2_EPS)
+
+
+def delta_attention(x: jnp.ndarray, p: dict, *, heads: int, head_dim: int,
+                    eps: float):
+    """Kimi Delta Attention on the stream ``x (B, L, d)`` with the
+    weights ``p``, projected back to ``(B, L, d)``, and the mean of the
+    decay ``alpha`` over positions, heads and channels; pure in both.
+    Three scopes: ``kda_proj`` (the norm, the six products, the
+    convolutions, the heads' norms, the decay and ``beta``), ``kda_scan``
+    (the chunked state, ``ops/delta_rule.py``) and ``kda_out`` (the
+    heads' RMSNorm, the output gate and ``W_o``)."""
+    b, l, _ = x.shape
+    split = (b, l, heads, head_dim)
+    with jax.named_scope("kda_proj"):
+        h = rms_norm(x, p["attn_norm"], eps)
+
+        def mixed(w, taps):
+            return jax.nn.silu(causal_depthwise_conv(h @ w, taps)
+                               ).reshape(split)
+
+        q = l2_norm(mixed(p["wq"], p["conv_q"])) * head_dim ** -0.5
+        k = l2_norm(mixed(p["wk"], p["conv_k"]))
+        v = mixed(p["wv"], p["conv_v"])
+        # the log-decay, a head and key channel: never positive
+        g = -jnp.exp(p["a_log"])[:, None] * jax.nn.softplus(
+            (h @ p["wf_a"]) @ p["wf_b"] + p["dt_bias"]).reshape(split)
+        beta = jax.nn.sigmoid(h @ p["w_beta"])
+        decay_mean = jnp.mean(jnp.exp(jax.lax.stop_gradient(g)))
+    with jax.named_scope("kda_scan"):
+        o = kda_scan(q, k, v, g, beta)
+    with jax.named_scope("kda_out"):
+        gate = jax.nn.sigmoid((h @ p["wg_a"]) @ p["wg_b"]).reshape(split)
+        o = rms_norm(o, p["o_norm"], eps) * gate
+        return o.reshape(b, l, heads * head_dim) @ p["wo"], decay_mean
+
+
+class KimiBlock(nn.Module):
+    d_model: int
+    mixer: str               # of KIMI_MIXERS
+    sparse: bool             # the MLP: routed and shared experts, else dense
+    n_heads: int             # the latent attention's
+    kda_heads: int
+    kda_head_dim: int
+    q_rank: int              # 0: no query latent
+    kv_rank: int
+    qk_nope: int
+    qk_rope: int
+    v_head: int
+    dense_width: int
+    n_experts: int           # the router's width: every expert there is
+    experts_per_tok: int
+    expert_width: int
+    experts_first: int = 0   # the share held here: a contiguous range
+    experts_held: int = 0    # 0: all of them
+    shared_experts: int = 1
+    conv_kernel: int = 4
+    route_scale: float = 1.0
+    rope_theta: float = 0.0  # 0: no positions in the latent attention
+    norm_eps: float = 1e-5
+    attn_fn: Optional[AttnFn] = None
+
+    @nn.compact
+    def __call__(self, x: jnp.ndarray):
+        """``(the stream after the layer, the ``kda`` mixer's mean decay
+        or None, the sparse branch's statistics in
+        :data:`JOYAI_MOE_STATS`' order or ``()``)``."""
+        if self.mixer not in KIMI_MIXERS:
+            raise ValueError(f"layer type {self.mixer!r}; have {KIMI_MIXERS}")
+        d, eps = self.d_model, self.norm_eps
+        ones = nn.initializers.ones
+        decay = None
+        if self.mixer == "kda":
+            y, decay = self.delta(x)
+        else:
+            y = latent_mixer(self, x)
+        x = x + y
+        mlp_norm = self.param("mlp_norm", ones, (d,))
+        if not self.sparse:
+            # recomputed in the backward pass (its input alone is kept):
+            # what a SiLU-gated MLP keeps is six arrays ``dense_width``
+            # wide, 1.8 GB at 8192 x 9216, for one more forward pass of
+            # three products
+            @jax.checkpoint
+            def dense(x, norm, w_gate, w_up, w_down):
+                with jax.named_scope("mlp"):
+                    return swiglu(rms_norm(x, norm, eps), w_gate, w_up,
+                                  w_down)
+
+            return x + dense(
+                x, mlp_norm,
+                self.param("w_gate", _INIT, (d, self.dense_width)),
+                self.param("w_up", _INIT, (d, self.dense_width)),
+                self.param("w_down", _INIT, (self.dense_width, d))
+            ), decay, ()
+        y, stats = shared_sparse_experts(self, x, mlp_norm)
+        return x + y, decay, stats
+
+    def delta(self, x):
+        d, h, hd = self.d_model, self.kda_heads, self.kda_head_dim
+        wide, ones = h * hd, nn.initializers.ones
+        p = {name: self.param(name, init, shape) for name, init, shape in (
+            ("attn_norm", ones, (d,)),
+            ("wq", _INIT, (d, wide)), ("wk", _INIT, (d, wide)),
+            ("wv", _INIT, (d, wide)),
+            # at LFM2's scale and for its reason: at 0.02 the taps'
+            # gradients are lost in the norm of the whole
+            ("conv_q", LFM2_TAPS_INIT, (self.conv_kernel, wide)),
+            ("conv_k", LFM2_TAPS_INIT, (self.conv_kernel, wide)),
+            ("conv_v", LFM2_TAPS_INIT, (self.conv_kernel, wide)),
+            ("wf_a", _INIT, (d, hd)), ("wf_b", _INIT, (hd, wide)),
+            ("a_log", kda_a_log_init, (h,)),
+            ("dt_bias", kda_dt_bias_init, (wide,)),
+            ("w_beta", _INIT, (d, h)),
+            ("wg_a", _INIT, (d, hd)), ("wg_b", _INIT, (hd, wide)),
+            ("o_norm", ones, (hd,)),
+            ("wo", _INIT, (wide, d)))}
+        return jax.checkpoint(
+            partial(delta_attention, heads=h, head_dim=hd,
+                    eps=self.norm_eps),
+            policy=jax.checkpoint_policies.save_only_these_names(*KDA_KEPT)
+        )(x, p)
+
+
+class KimiDecoder(nn.Module):
+    """Causal LM of :class:`KimiBlock` layers: a token table (at
+    :data:`MELLUM_EMBED_INIT`'s scale, for its reason: a share of the
+    experts is held), the layers (layer ``i``'s mixer is
+    ``layer_types[i]``, its MLP dense iff ``i < dense_layers``), a final
+    RMSNorm and an untied head.  Like :class:`JoyaiDecoder` it is called
+    with the targets and returns its own loss, the head's mean
+    next-token NLL (no second head: ``num_nextn_predict_layers`` 0),
+    with its statistics (``lm/model.py`` closes over it):
+
+    - :data:`KDA_DECAY_MEAN`: the mean of the decay ``alpha`` over
+      positions, heads and channels, one entry a ``kda`` layer (at 0 the
+      layer has no memory, at 1 it is an undecayed delta rule);
+    - the routing counters of every sparse layer under ``lm/model.py``
+      ``MOE_STATS``' names.
+
+    The head's product, norm and loss is under ``jax.checkpoint``,
+    keeping the rows' log-sum-exp by name (:func:`row_lse`)."""
+
+    vocab: int = 256
+    d_model: int = 64
+    n_heads: int = 4
+    kda_heads: int = 4
+    kda_head_dim: int = 16
+    layer_types: tuple = ("kda", "kda", "kda", "full_attention")
+    q_rank: int = 0
+    kv_rank: int = 32
+    qk_nope: int = 16
+    qk_rope: int = 8
+    v_head: int = 16
+    dense_layers: int = 1
+    dense_width: int = 128
+    n_experts: int = 8
+    experts_per_tok: int = 2
+    expert_width: int = 32
+    experts_first: int = 0
+    experts_held: int = 0
+    shared_experts: int = 1
+    conv_kernel: int = 4
+    route_scale: float = 1.0
+    rope_theta: float = 0.0
+    norm_eps: float = 1e-5
+    attn_fn: Optional[AttnFn] = None
+
+    @nn.compact
+    def __call__(self, tokens: jnp.ndarray, targets: jnp.ndarray):
+        d, eps = self.d_model, self.norm_eps
+        sizes = {field: getattr(self, field) for field in (
+            "d_model", "n_heads", "kda_heads", "kda_head_dim", "q_rank",
+            "kv_rank", "qk_nope", "qk_rope", "v_head", "dense_width",
+            "n_experts", "experts_per_tok", "expert_width", "experts_first",
+            "experts_held", "shared_experts", "conv_kernel", "route_scale",
+            "rope_theta", "norm_eps", "attn_fn")}
+
+        @partial(jax.checkpoint,
+                 policy=jax.checkpoint_policies.save_only_these_names(
+                     HEAD_LSE))
+        def head_nll(u, norm, head, targets):
+            with jax.named_scope("head_loss"):
+                z = rms_norm(u, norm, eps) @ head
+                return row_lse(z) - jnp.take_along_axis(
+                    z, targets[..., None], axis=-1)[..., 0]
+
+        decays, routing = [], []
+        with jax.named_scope("embed"):
+            x = self.param("embed", MELLUM_EMBED_INIT,
+                           (self.vocab, d))[tokens]
+        for i, mixer in enumerate(self.layer_types):
+            x, decay, counted = KimiBlock(
+                mixer=mixer, sparse=i >= self.dense_layers, **sizes)(x)
+            decays += [] if decay is None else [decay]
+            routing += [counted] if counted else []
+        nll = head_nll(
+            x, self.param("final_norm", nn.initializers.ones, (d,)),
+            self.param("head", _INIT, (d, self.vocab)), targets)
+        with jax.named_scope("head_loss"):
+            loss = jnp.mean(nll)
+        stats = {}
+        if decays:
+            stats[KDA_DECAY_MEAN] = jnp.stack(decays)
         if routing:
             stats.update(zip(JOYAI_MOE_STATS, map(jnp.stack, zip(*routing))))
         return loss, stats

@@ -1,10 +1,12 @@
-"""The short causal depthwise convolution of a gated-convolution token
-mixer (LFM2's ``conv`` layers: ``models/transformer.py`` ``Lfm2Block``).
+"""The short causal depthwise convolution of a token mixer: LFM2's gated
+``conv`` layers (``models/transformer.py`` ``Lfm2Block``, three taps)
+and the three on q, k and v before Kimi's delta attention
+(``delta_attention``, four taps).
 
 ``c[t] = sum_j taps[j] * u[t - (K - 1) + j]`` per channel, ``u`` zero
 before the sequence: the last tap multiplies the current position and
 nothing later is seen (torch's ``Conv1d(groups=d, padding=K - 1)`` cut
-to the sequence's length).  ``K`` is a handful (3), so the convolution
+to the sequence's length).  ``K`` is a handful (3 or 4), so the convolution
 is ``K`` shifted elementwise products that XLA fuses with the gates
 around it; its transpose is the same shifts the other way, so the
 backward pass has no scatter.  No state crosses sequences: a packed

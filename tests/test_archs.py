@@ -35,7 +35,10 @@ OTHER = dict(
     layer_types="conv,full_attention,conv", conv_kernel=4,
     loop_steps=2, exit_beta=0.05, exit_bias=-2.0,
     q_rank=24, kv_rank=16, qk_nope=8, qk_rope=4, v_head=8,
-    shared_experts=2, mtp_layers=0, mtp_weight=0.125)
+    shared_experts=2, mtp_layers=0, mtp_weight=0.125,
+    kda_heads=3, kda_head_dim=8)
+# where a block cannot take ``OTHER``'s value of a size: its own
+OTHER_OF = {"kimi": {"layer_types": "kda,full_attention,kda"}}
 
 # a size that is no field of its name on the module: where the maker put it
 TRANSLATED = {
@@ -74,12 +77,13 @@ def test_every_size_reaches_its_blocks_module_from_its_switch(arch):
     (or where ``TRANSLATED`` says), and no other block's size is among
     ``build``'s keywords."""
     names = archs.sizes_of(arch)
+    other = {**OTHER, **OTHER_OF.get(arch, {})}
     cfg = LAUNCH_DEFAULTS.merged(
         lm_arch=arch, seed=11,
-        **{archs.SWITCHES[name]: OTHER[name] for name in names})
+        **{archs.SWITCHES[name]: other[name] for name in names})
     kw = build_kw(lm_trainer_cfg(cfg))
     assert kw == {"arch": arch, "seed": 11,
-                  **{name: OTHER[name] for name in names}}
+                  **{name: other[name] for name in names}}
     model = build(use_flash=False, **kw)
     for name in names:
         if hasattr(model.module, name):
@@ -88,9 +92,9 @@ def test_every_size_reaches_its_blocks_module_from_its_switch(arch):
             reached = TRANSLATED[name](model)
         if name == "layer_types":
             reached = ",".join(reached)
-        assert reached == OTHER[name], name
-        assert type(reached) is type(OTHER[name]), name
-    assert model.vocab == OTHER["vocab"]
+        assert reached == other[name], name
+        assert type(reached) is type(other[name]), name
+    assert model.vocab == other["vocab"]
 
 
 @pytest.mark.parametrize("path", CONFIGS, ids=lambda path: path.stem)
@@ -113,6 +117,7 @@ def test_a_configuration_names_only_its_own_blocks_switches(path):
 @pytest.mark.parametrize("arch, stray", [
     ("gpt2", "n_experts"), ("olmoe", "kv_heads"), ("lfm2", "window"),
     ("ouro", "n_experts"), ("joyai", "kv_heads"), ("mellum", "nonsense"),
+    ("kimi", "mtp_layers"),
 ])
 def test_build_refuses_a_size_the_block_does_not_take(arch, stray):
     with pytest.raises(TypeError) as refused:
@@ -137,6 +142,32 @@ def test_the_launchers_path_and_the_trainers_give_the_same_model(arch):
     assert archs.resolve(arch, {}) == archs.resolve(
         arch, {k: v for k, v in launched.items()
                if k not in ("arch", "seed")})
+
+
+@pytest.mark.parametrize("arch", [
+    name for name in archs.ARCHS
+    if archs.block(name).loss == archs.OWN_LOSS])
+def test_a_block_with_its_own_loss_is_seeded_by_one_compiled_program(arch):
+    """``build`` seeds such a block under ``jit``, where the forward
+    pass that ``init`` traces is dead code: the vector is the eager
+    ``init``'s to the last bit but one (the compiler may fold an
+    initialiser's two scalings into one), leaf for leaf."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    sizes = {name: {**OTHER, **OTHER_OF.get(arch, {})}[name]
+             for name in archs.sizes_of(arch)}
+    model = build(arch=arch, use_flash=False, seed=5, **sizes)
+    sample = jnp.zeros(
+        (1, archs.block(arch).sample_len or sizes["seq_len"]), jnp.int32)
+    eager = model.module.init(jax.random.PRNGKey(5), sample, sample)
+    mine = model.flat.unravel(model.flat.w0)
+    assert jax.tree_util.tree_structure(eager["params"]) \
+        == jax.tree_util.tree_structure(mine)
+    for plain, got in zip(jax.tree_util.tree_leaves(eager["params"]),
+                          jax.tree_util.tree_leaves(mine)):
+        np.testing.assert_allclose(got, plain, rtol=1e-6, atol=1e-7)
 
 
 def test_the_launchers_defaults_load_no_model_and_no_trainer():

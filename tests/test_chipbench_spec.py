@@ -55,7 +55,7 @@ def test_every_cells_vocabulary_reaches_the_program_through_the_launcher():
     from chipbench import run as runner
 
     want = {"gpt2": 50257, "olmoe": 50304, "mellum": 12288, "lfm2_moe": 8192,
-            "ouro": 49152, "joyai_llm_flash": 16160}
+            "ouro": 49152, "joyai_llm_flash": 16160, "kimi_linear": 20480}
     for name in CELLS:
         cell = spec_mod.load_cell(name)
         cfg = runner.launch_config(cell, seed=5)
@@ -74,7 +74,7 @@ def test_scopes_come_from_every_committed_configuration():
     assert spantree.model_scopes({}) == [
         "embed", "attn", "mlp", "head_loss", "update", "router", "dispatch",
         "experts", "attn_window", "conv", "conv_mix", "exit_gate", "mla_proj",
-        "shared_expert"]
+        "shared_expert", "kda_proj", "kda_scan", "kda_out"]
 
 
 def olmoe_cases():
@@ -475,10 +475,10 @@ def test_the_parent_fails_the_new_cell_at_once():
     cell on the parent needs."""
     bench = spec_mod.load_bench()
     names = [w["name"] for w in bench["workloads"]]
-    assert names[-3:] == [LFM2_CELL, OURO_CELL, JOYAI_CELL] \
-        and len(names) == 8
+    assert names[-4:] == [LFM2_CELL, OURO_CELL, JOYAI_CELL, KIMI_CELL] \
+        and len(names) == 9
     for missing in ("lfm2-l5e8-locals", "ouro-l6-locals",
-                    "joyai-l5e8-locals"):
+                    "joyai-l5e8-locals", "kimi-linear-l5e8-locals"):
         with pytest.raises(spec_mod.SpecError, match="no workload"):
             spec_mod.load_cell(missing)
 
@@ -758,10 +758,11 @@ def test_joyais_mix_keeps_to_the_traffic_its_issue_fixed():
     assert moves["mtp_nll_gap_nats"] == "loss_at_budget"
     assert {moves[m] for m in JOYAI_METRICS[:3]} == {"tokens_per_s"}
     for metric in cell.bench["per_layer"]:
-        if metric["name"] in JOYAI_METRICS:
+        if metric["name"] in JOYAI_METRICS[2:]:
             assert metric["workloads"] == [JOYAI_CELL]
-        elif metric["name"] in JOYAI_APPENDED:
-            assert metric["workloads"][-1] == JOYAI_CELL
+        elif metric["name"] in JOYAI_APPENDED + JOYAI_METRICS[:2]:
+            # the cell of PR 43, which has these layers too, follows it
+            assert JOYAI_CELL in metric["workloads"][-2:]
 
 
 def test_joyais_readers_find_nothing_in_a_run_without_the_block():
@@ -867,6 +868,246 @@ def joyai_cases():
 @pytest.mark.parametrize("what,got,want", joyai_cases(),
                          ids=[c[0] for c in joyai_cases()])
 def test_joyai_arithmetic_by_hand_through_the_cell(what, got, want):
+    assert got == want, what
+
+# -- the Kimi-Linear configuration (PR 43) ----------------------------------------
+
+KIMI_CELL = "kimi-linear-l5e8-local"
+KIMI_METRICS = ("kda_ms_per_step", "kda_scan_ms_per_step",
+                "kda_scan_roofline", "kda_decay_mean")
+KIMI_APPENDED = JOYAI_APPENDED + ("mla_proj_ms_per_step",
+                                  "shared_expert_ms_per_step")
+
+
+def test_kimi_file_has_the_catalogs_keys_and_the_floor_cuts():
+    """Every key of the catalog's entry under its own name (the
+    model-configs guide's ``architectures.jsonl``, read where it is
+    installed; the hand-copied values below where it is not); only the
+    depth, the experts held, the vocabulary and, with the depth, the two
+    lists of layer numbers inside ``linear_attn_config`` differ, each at
+    the guide's floor, with the published values beside them; no width
+    is cut, inside the group or outside."""
+    import pathlib
+
+    cell = spec_mod.load_cell(KIMI_CELL)
+    config = cell.config
+    catalog = {
+        "first_k_dense_replace": 1, "head_dim": 72, "hidden_act": "silu",
+        "hidden_size": 2304, "intermediate_size": 9216, "kv_lora_rank": 512,
+        "linear_attn_config": {
+            "full_attn_layers": [4, 8, 12, 16, 20, 24, 27], "head_dim": 128,
+            "kda_layers": [1, 2, 3, 5, 6, 7, 9, 10, 11, 13, 14, 15, 17, 18,
+                           19, 21, 22, 23, 25, 26],
+            "num_heads": 32, "short_conv_kernel_size": 4},
+        "mla_use_nope": True, "model_max_length": 1048576,
+        "model_type": "kimi_linear", "moe_intermediate_size": 1024,
+        "moe_layer_freq": 1, "moe_renormalize": True,
+        "moe_router_activation_func": "sigmoid", "num_attention_heads": 32,
+        "num_expert_group": 1, "num_experts": 256,
+        "num_experts_per_token": 8, "num_hidden_layers": 27,
+        "num_key_value_heads": 32, "num_nextn_predict_layers": 0,
+        "num_shared_experts": 1, "q_lora_rank": None,
+        "qk_nope_head_dim": 128, "qk_rope_head_dim": 64,
+        "rms_norm_eps": 1e-05, "rope_scaling": None, "rope_theta": 10000,
+        "routed_scaling_factor": 2.446, "tie_word_embeddings": False,
+        "topk_group": 1, "use_grouped_topk": True, "v_head_dim": 128,
+        "vocab_size": 163840}
+    path = pathlib.Path(
+        "/opt/skills/guides/model-configs/architectures.jsonl")
+    if path.exists():
+        rows = [json.loads(line) for line in path.read_text().splitlines()]
+        entry = next(r for r in rows
+                     if r["name"] == "Kimi-Linear-48B-A3B-Instruct")
+        assert entry["config"] == catalog
+        assert entry["source_url"] == config["source"]
+    assert all(key in config for key in catalog)
+    differ = sorted(k for k, v in catalog.items() if config[k] != v)
+    assert differ == sorted(config["reduced"]) == [
+        "linear_attn_config", "num_experts", "num_hidden_layers",
+        "vocab_size"]
+    assert config["published"] == {k: catalog[k] for k in config["reduced"]}
+    # inside the group the widths stand and the lists are the published
+    # ones cut to the layers held
+    group, whole = config["linear_attn_config"], catalog["linear_attn_config"]
+    assert {k: group[k] for k in ("head_dim", "num_heads",
+                                  "short_conv_kernel_size")} \
+        == {k: whole[k] for k in ("head_dim", "num_heads",
+                                  "short_conv_kernel_size")}
+    held = range(1, config["num_hidden_layers"] + 1)
+    for name in ("kda_layers", "full_attn_layers"):
+        assert group[name] == [n for n in whole[name] if n in held]
+    # the floors: the dense layer and the four that follow (a whole
+    # period among them), 8 experts, an eighth of the vocabulary
+    assert (config["num_hidden_layers"], config["num_experts"],
+            config["vocab_size"]) == (5, 8, 163840 // 8)
+    assert group["kda_layers"] == [1, 2, 3, 5] and \
+        group["full_attn_layers"] == [4]
+    assert config["router_experts"] == 256   # the router keeps its width
+    assert (config["train_seq"], config["kda_chunk"]) == (8192, 64)
+    entry = next(c for c in cell.bench["configs"]
+                 if c["name"] == cell.config_name)
+    assert sorted(entry["reduced"]) == differ
+    assert entry["source"] == config["source"]
+    assert (cell.chips, cell.traffic_name) == (1, "local-msgd-s8k-kimi")
+    assert ["embed", "kda_proj", "kda_scan", "kda_out", "mla_proj", "attn",
+            "mlp", "router", "dispatch", "experts", "shared_expert",
+            "head_loss", "update"] == config["scopes"]
+    assert all(key in config for key in config["launcher_from"].values())
+    assert all(key in config for key in config["tiny"])
+    assert len(config["assumed"]) >= 8 and "32 v5e chips" in \
+        config["deployment"]
+    assert cell.arithmetic().param_count(config) == 602_434_432
+
+
+def test_the_launcher_builds_the_hybrid_block_from_the_cells_files():
+    from chipbench import run as runner
+    from mpit_tpu.lm.model import build_kw
+    from mpit_tpu.train.launch import lm_trainer_cfg
+
+    cell = spec_mod.load_cell(KIMI_CELL)
+    kw = build_kw(lm_trainer_cfg(runner.launch_config(cell, seed=5)))
+    assert (kw["arch"], kw["d_model"], kw["n_heads"], kw["n_layers"],
+            kw["seq_len"], kw["vocab"]) == ("kimi", 2304, 32, 5, 8192, 20480)
+    assert (kw["layer_types"], kw["kda_heads"], kw["kda_head_dim"],
+            kw["conv_kernel"]) == ("kda,kda,kda,full_attention,kda", 32,
+                                   128, 4)
+    assert (kw["q_rank"], kw["kv_rank"], kw["qk_nope"], kw["qk_rope"],
+            kw["v_head"], kw["rope_theta"]) == (0, 512, 128, 64, 128, 0.0)
+    assert (kw["dense_layers"], kw["dense_width"], kw["n_experts"],
+            kw["experts_held"], kw["experts_first"], kw["experts_per_tok"],
+            kw["expert_width"], kw["shared_experts"]) \
+        == (1, 9216, 256, 8, 0, 8, 1024, 1)
+    assert (kw["route_scale"], kw["norm_eps"]) == (2.446, 1e-5)
+
+
+def test_kimis_mix_keeps_to_the_traffic_its_issue_fixed():
+    """ISSUE 43 fixed the mix before any code was written: the rate one
+    of three, the budget a whole number of micro-steps (whole sequences
+    of 8192, or of 4096 by the one permitted departure), momentum 0.9,
+    two rounds of warm-up, closed loop in one process; the four new
+    metrics and the nine appended ones are the cell's, and the decay's
+    mean moves the loss, not the rate."""
+    cell = spec_mod.load_cell(KIMI_CELL)
+    mix = cell.traffic
+    assert cell.config["train_seq"] in (8192, 4096)
+    steps, rest = divmod(mix["token_budget"],
+                         mix["batch"] * cell.config["train_seq"])
+    assert rest == 0 and steps >= 8
+    assert mix["lr"] in (0.003, 0.01, 0.03)
+    assert (mix["launcher"]["mom"], mix["warmup_rounds"], mix["su"],
+            mix["batch"], mix["launcher"]["np"]) == (0.9, 2, 1, 1, 1)
+    moves = {m["name"]: m["moves"] for m in cell.metrics("per_layer")}
+    assert set(KIMI_METRICS + KIMI_APPENDED) <= set(moves)
+    assert moves["kda_decay_mean"] == "loss_at_budget"
+    assert {moves[m] for m in KIMI_METRICS[:3]} == {"tokens_per_s"}
+    layers = {m["name"]: m["layer"] for m in cell.bench["per_layer"]}
+    assert layers["kda_scan_roofline"] == layers["flash_roofline"]
+    assert layers["kda_ms_per_step"] == layers["mla_proj_ms_per_step"]
+    for metric in cell.bench["per_layer"]:
+        if metric["name"] in KIMI_METRICS:
+            assert metric["workloads"] == [KIMI_CELL]
+        elif metric["name"] in KIMI_APPENDED:
+            assert metric["workloads"][-1] == KIMI_CELL
+        elif "workloads" in metric:
+            assert KIMI_CELL not in metric["workloads"], metric["name"]
+
+
+def test_kimis_readers_find_nothing_in_a_run_without_the_block():
+    """What the parent's traced run hands them: a cell whose
+    configuration lists no such scope, a program that recorded no such
+    counter, no device trace, no merged trace: None, no raise."""
+    for name in ("joyai-l5e8-local", "c111m-local"):
+        cell = spec_mod.load_cell(name)
+        run = {"cell": cell, "reduction": {"step_module": "jit__lambda"},
+               "obs_trace": None, "peaks": None, "results": {},
+               "summary": {"worker_ranks": [0], "window": [0.0, 1.0]}}
+        for metric in KIMI_METRICS:
+            reader = spec_mod.load_reader(cell.root, cell.bench, metric)
+            assert reader is not None and reader(dict(run)) is None
+
+
+def test_kimis_readers_read_a_hand_made_run(monkeypatch):
+    """The four readers, the nine shared ones and the metrics without a
+    ``workloads`` list that the cell has to report, on a scope table and
+    a span tree made by hand."""
+    from chipbench import flops
+    from chipbench.layers import spantree
+
+    cell = spec_mod.load_cell(KIMI_CELL)
+    listed = [m["name"] for m in cell.metrics("per_layer")]
+    unlisted = [m["name"] for m in cell.bench["per_layer"]
+                if "workloads" not in m]
+    assert len(unlisted) == 10 and set(unlisted) <= set(listed)
+
+    class Round:
+        def __init__(self, k, decay):
+            self.args = {"round": k, "lm_kda_decay_mean": decay,
+                         "moe_held_rows_share": [0.03125] * 4,
+                         "moe_load_max_over_mean": [2.0, 2.5, 2.25, 3.0],
+                         "moe_compact_share": [1.0] * 4,
+                         "moe_bias_flips_share": [0.1] * 4}
+
+    class Tree:
+        def rounds(self):
+            return [Round(7, [0.8, 0.9, 0.8, 0.9]),
+                    Round(8, [0.7, 0.9, 0.8, 0.8]),
+                    Round(9, [0.6, 0.8, 0.8, 0.6])]
+
+    table = {"step": 600.0, "kda_proj": 90.0, "kda_scan": 120.0,
+             "kda_out": 30.0, "mla_proj": 8.0, "attn": 60.0, "mlp": 20.0,
+             "router": 6.0, "dispatch": 9.0, "experts": 12.0,
+             "shared_expert": 5.0, "head_loss": 30.0, "update": 25.0}
+    monkeypatch.setattr(spantree, "scope_ms_per_step", lambda run: table)
+    monkeypatch.setattr(spantree, "xplane_path", lambda run: None)
+    run = {"cell": cell, "peaks": flops.load_peaks("TPU v5 lite"),
+           spantree.CACHE_KEY: Tree(),
+           "summary": {"tokens_per_s": 12000.0, "worker_ranks": [0]},
+           "reduction": {"step_module": "jit__lambda", "step_module_runs": 2,
+                         "mosaic_by_scope": {
+                             "attn": (6, 0.080), "experts": (72, 0.020),
+                             "update": (2, 0.030)}}}
+
+    def read(name):
+        return spec_mod.load_reader(cell.root, cell.bench, name)(run)
+
+    assert read("kda_ms_per_step") == pytest.approx(240.0)
+    assert read("kda_scan_ms_per_step") == pytest.approx(120.0)
+    cost = cell.arithmetic().kda_scan_cost(cell.config, 1)
+    assert cost["bytes"] / 819e9 > cost["flops"] / 197e12   # memory binds
+    assert read("kda_scan_roofline") == pytest.approx(
+        100 * cost["bytes"] / 819e9 / 0.120)
+    assert read("kda_decay_mean") == pytest.approx(0.8)
+    assert read("mla_proj_ms_per_step") == pytest.approx(8.0)
+    assert read("shared_expert_ms_per_step") == pytest.approx(5.0)
+    assert read("dispatch_ms_per_step") == pytest.approx(15.0)
+    assert read("held_experts_ms_per_step") == pytest.approx(12.0)
+    assert read("held_rows_share_pct") == pytest.approx(3.125)
+    assert read("expert_load_max_over_mean") == pytest.approx(3.0)
+    assert read("router_bias_flips_pct") == pytest.approx(10.0)
+    assert read("compact_dispatch_pct") == pytest.approx(100.0)
+    assert read("head_loss_ms_per_step") == pytest.approx(30.0)
+    assert read("flash_ms_per_step") == pytest.approx(40.0)
+    family = cell.arithmetic().kernels(cell.config, 1)["attn"]
+    assert read("flash_roofline") == pytest.approx(
+        100 * family["flops"] / 197e12 / 0.040)
+    experts = cell.arithmetic().experts_cost(cell.config, 1)
+    assert read("held_experts_roofline") == pytest.approx(
+        100 * max(experts["flops"] / 197e12, experts["bytes"] / 819e9)
+        / 0.010)
+    assert read("mfu_pct") == pytest.approx(
+        100 * 2_318_727_168 * 12000.0 / 197e12)
+    for metric in cell.metrics("per_layer"):
+        assert spec_mod.load_reader(cell.root, cell.bench,
+                                    metric["name"]) is not None
+
+
+def kimi_cases():
+    return spec_mod.load_cell(KIMI_CELL).arithmetic().hand_worked()
+
+
+@pytest.mark.parametrize("what,got,want", kimi_cases(),
+                         ids=[c[0] for c in kimi_cases()])
+def test_kimi_arithmetic_by_hand_through_the_cell(what, got, want):
     assert got == want, what
 
 

@@ -22,7 +22,8 @@ from mpit_tpu.models import flat as flat_mod
 # a cell of each block the launcher builds (chipbench/configs/*.json)
 CELLS = {"gpt2": "c111m-local", "olmoe": "olmoe-l1-ps1w-su1",
          "mellum": "mellum2-l4e8-local", "lfm2": "lfm2-l5e8-local",
-         "ouro": "ouro-l6-local", "joyai": "joyai-l5e8-local"}
+         "ouro": "ouro-l6-local", "joyai": "joyai-l5e8-local",
+         "kimi": "kimi-linear-l5e8-local"}
 TREES = tuple(CELLS) + ("hand_made", "gpt2_wide")
 _built = {}
 
@@ -189,7 +190,7 @@ def test_the_blocks_own_step_has_ravel_pytrees_gradient(name):
     sparse branches, JoyAI's second loss."""
     params, flat = tree_of(name)
     plain = ravel_pytree(params)[1]
-    own_loss = name in ("ouro", "joyai")  # decoders called with the targets
+    own_loss = name in ("ouro", "joyai", "kimi")  # called with the targets
     tokens = jnp.asarray(np.random.RandomState(2).randint(0, 256, (2, 33)),
                          jnp.int32)
 
