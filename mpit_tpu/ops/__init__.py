@@ -10,6 +10,10 @@ HBM read/write sweep with buffer donation (no param-sized temporaries).
 :mod:`mpit_tpu.ops.flash_attention` adds the blockwise-attention kernel
 that backs sequence-parallel ring attention
 (:mod:`mpit_tpu.parallel.ring_attention`).
+:mod:`mpit_tpu.ops.delta_rule` is the gated delta rule's chunked scan
+(Kimi Delta Attention): three Mosaic kernels at head widths of whole
+lanes (forward, and the backward rule's two), XLA's fusions and products
+at every other width; :mod:`mpit_tpu.ops.short_conv` is XLA's fusions.
 
 Every op has a jnp reference implementation (``*_reference``) used for
 testing and as a CPU fallback; kernels run in pallas interpret mode off-TPU

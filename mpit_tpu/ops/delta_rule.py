@@ -35,21 +35,46 @@ and a scan over the ``L / C`` chunk states of four small products each.
 **Every decay is the ``exp`` of a difference of summed log-decays that
 is not positive.**  ``A = (K * exp(G)) (K * exp(-G))^T`` would be one
 product, and ``exp(-G)`` overflows float32 within a chunk at the seeded
-decays (``alpha`` down to 0.2: ``exp(1.6 x 64)``).  So a chunk is cut
-into sub-blocks of :data:`SUB` positions: between two sub-blocks the
-decay goes through the later one's first boundary (``G_t - G_ref <= 0``
-on the rows, ``G_ref - G_s <= 0`` on the columns: a product whose
-operands are each at most 1 in size), and inside a sub-block the
-``SUB x SUB x d_k`` differences are taken one by one.  A product that
-underflows is of a pair whose true weight underflows too.
+decays (``alpha`` down to 0.2: ``exp(1.6 x 64)``).  So the decay from
+``s`` to ``t`` always goes through a position between them, ``exp(G_t -
+G_ref) exp(G_ref - G_s)``: a product whose operands are each at most 1
+in size, and one that underflows is of a pair whose true weight
+underflows too.  The XLA form cuts a chunk into sub-blocks of
+:data:`SUB` positions (between two sub-blocks the later one's first
+boundary is the reference; inside one the ``SUB x SUB x d_k``
+differences are taken one by one); the kernels halve the chunk level by
+level (the comment above :data:`LEVELS`).
+
+**Two ways to compile the one algorithm, chosen by the shapes**
+(:func:`kda_scan`).  Head widths of whole lanes (``d_k`` and ``d_v``
+multiples of 128: every configuration's) run as **three Mosaic
+kernels** in which a chunk's matrices are made, used and dropped in
+VMEM: a grid step is one chunk of :data:`HEADS_A_STEP` heads, read as
+the block ``(CHUNK, heads x d)`` of the row-major ``(L, H d)`` view
+where the projections leave it (no transpose into a heads-major layout
+of chunks and none back), the state a VMEM scratch carried along the
+chunk axis of the grid; the heads of a grid step are written stage by
+stage, not head by head, because the kernel's compiler runs the small
+products in the order they are written (:func:`_in_step`).  The forward
+kernel reads the five inputs and writes ``o``.  Every other width runs the chunked form as XLA's fusions
+and batched products (:func:`kda_scan_xla`), the kernels' second oracle
+beside the recurrence.
 
 **The backward pass is the operator's own rule** (``jax.custom_vjp``):
 it keeps ``q, k, v, g, beta`` and nothing of the forward pass, computes
 the chunks' matrices and the chunk states again (``L / C`` states of
 ``d_k x d_v``, never ``L``), and differentiates that: the solve by its
 own rule (``dM = -T^T dT T^T``, two products, not the doublings'
-transposes), the sub-blocks' differences computed again and not kept.
-The result is named :data:`KDA_OUT` for a caller's checkpoint policy
+transposes), the decays computed again and not kept.  In the kernels
+the rule is two calls: the forward kernel again, which now also writes
+every chunk's starting state and solve (``L / C`` of ``d_v x d_k`` and
+of ``C x C`` a head, alive inside the rule only), and a kernel that
+walks the chunks from the last to the first with the state's cotangent
+in VMEM, makes the chunk's other matrices again and writes the five
+gradients, the log-decays' as the sums' tables transposed (``G``'s is
+the reverse cumulative sum), not the transpose of the forward graph.
+The XLA form's rule is ``jax.vjp`` of its parts.  The result is named
+:data:`KDA_OUT` for a caller's checkpoint policy
 (``jax.ad_checkpoint.checkpoint_name``), as the flash rule names its
 two.
 
@@ -57,9 +82,11 @@ Shapes: ``q, k, g (B, L, H, d_k)``, ``v (B, L, H, d_v)``, ``beta (B, L,
 H)``; the result ``(B, L, H, d_v)``.  Any ``L``: a last chunk that is
 not whole is filled with positions that neither decay nor write
 (``g = 0``, ``beta = 0``, ``k = 0``).  No state crosses the batch axis.
-XLA's fusions and products, no Mosaic kernel: ``chipbench/arithmetic/
-kimi.py`` ``kda_scan_cost`` counts what the algorithm needs, and
-``kda_scan_roofline`` holds the scope's device time to it.
+``chipbench/arithmetic/kimi.py`` ``kda_scan_cost`` counts what the
+algorithm needs (five inputs read, ``o`` written; the same again and
+the gradients in the rule), and ``kda_scan_roofline`` holds the scope's
+device time to it, whichever form runs under the scope.  Off a TPU the
+kernels run in Pallas interpret mode, on float32 operands.
 """
 
 from __future__ import annotations
@@ -68,15 +95,21 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.ad_checkpoint import checkpoint_name
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from mpit_tpu.ops.tiles import LANE, round_up, use_interpret
 
 #: the name of the scan's result for a checkpoint policy
 KDA_OUT = "kda_out"
 #: positions of a chunk, and of a sub-block inside it
 CHUNK, SUB = 64, 16
-#: heads whose chunks' matrices are made, and transposed in the backward
-#: pass, at a time: what is alive meanwhile is a dozen arrays of a
-#: head's ``L x d`` each (the cell's compiled step at 8192 positions: 4
+#: the XLA form's (narrow head widths): heads whose chunks' matrices are
+#: made, and transposed in the backward pass, at a time: what is alive
+#: meanwhile is a dozen arrays of a head's ``L x d`` each (at 128-wide
+#: heads and 8192 positions, before the kernels took those widths: 4
 #: heads 14.81 GB, 8 14.62, 16 13.95 before its last repairs, 32 14.56)
 HEAD_GROUP = 8
 #: the solve's products: the inverse's entries are sums of products of
@@ -137,7 +170,7 @@ def _inverse_bwd(inverse, ct):
 unit_lower_inverse.defvjp(_inverse_fwd, _inverse_bwd)
 
 
-# -- a chunk's matrices --------------------------------------------------------
+# -- a chunk's matrices, the XLA form -------------------------------------------
 
 
 @jax.checkpoint
@@ -239,8 +272,9 @@ def _head_group(heads: int) -> int:
 
 
 def _mapped(fn, *xs):
-    """``fn`` over the leading axis (the groups of heads), one group
-    after another and the results stacked.  Unrolled, not a
+    """The XLA form's (narrow head widths): ``fn`` over the leading
+    axis (the groups of heads), one group after another and the results
+    stacked.  Unrolled, not a
     ``lax.map``: inside a ``while`` the compiler gives every group's
     temporaries a place of their own for the whole loop, and the
     donated step of the five-layer cell read 3.3 GB more (PERF.md
@@ -276,12 +310,436 @@ def _chunks_bwd(kept, ct):
 _chunks_out.defvjp(_chunks_fwd, _chunks_bwd)
 
 
-def kda_scan(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, g: jnp.ndarray,
-             beta: jnp.ndarray) -> jnp.ndarray:
-    """The recurrence of the module's docstring in chunks of
-    :data:`CHUNK` positions, with its own backward rule.  The chunks'
-    products are at the backend's default precision (one bf16 pass on a
-    TPU), the solve at :data:`SOLVE_PRECISION`, the decays in float32."""
+# -- the chunk as Mosaic kernels (head widths of whole lanes) -------------------
+#
+# One grid step is one chunk of :data:`HEADS_A_STEP` heads: the block
+# ``(CHUNK, heads x d)`` of the row-major ``(L, H x d)`` view, lane-aligned
+# as the projections leave it.  Everything a chunk needs is a value inside
+# the step; the state (transposed, ``d_v x d_k``, so that a channel's decay
+# scales lanes) is a scratch carried along the grid's last axis.
+#
+# The pair matrices by halving: positions ``s < t`` of a chunk first part
+# ways at one bit of their index, level ``l`` (halves of ``2^l``), and
+# there the decay from ``s`` to ``t`` goes through the last position of
+# the lower half: ``exp(G_t - G_mid) exp(G_mid - G_s)``, two exponents
+# that are sums of log-decays and so never positive.  A level is one
+# ``exp`` of a ``(C, d_k)`` array (every row is in one half or the
+# other) and one product masked to the level's pairs; the sums come from
+# one product of ``g`` with a table of 0s and 1s (:func:`_tables`), the
+# summed log-decay ``G`` among them.
+
+#: heads a grid step holds: their chains of small products are
+#: independent and written stage by stage (:func:`_in_step`), so that
+#: one head's waits are filled with another's products (the three
+#: kernels of a layer at 1, 2, 4, 8 heads: 36.8, 24.8, 22.4, 21.6 ms;
+#: at 16 the walk back does not fit the scoped VMEM)
+HEADS_A_STEP = 4
+#: levels of the halving: ``log2(CHUNK)``; ``lev == LEVELS`` on the diagonal
+LEVELS = CHUNK.bit_length() - 1
+
+
+def _tables(one_pass: bool = False):
+    """``(sums, sums_t, lev)``.  ``sums ((LEVELS + 1) C, C)`` of 0s and
+    1s, whose product with a chunk's ``g`` is ``G`` (rows ``0..C``: the
+    sum from the chunk's start to ``t``) and below it a level's
+    exponents (from the middle to ``t`` in an upper half, from ``t`` to
+    the middle in a lower one); ``sums_t`` its transpose, for the
+    log-decays' gradient; ``lev (C, C)``: the level at which ``s < t``
+    part ways, :data:`LEVELS` on the diagonal, -1 above it.  With
+    ``one_pass`` the two tables are in bf16, where they are exact, three
+    times side by side: :meth:`_Chunk.table`."""
+    at = np.arange(CHUNK)
+    t, u = at[:, None], at[None, :]
+    sums = [u <= t]
+    for level in range(LEVELS):
+        half = 1 << level
+        mid = (t >> (level + 1) << (level + 1)) + half - 1
+        upper = (t >> level) & 1 == 1
+        sums.append(np.where(upper, (mid < u) & (u <= t),
+                             (t < u) & (u <= mid)))
+    sums = np.concatenate(sums).astype(np.float32)
+    lev = np.full((CHUNK, CHUNK), -1, np.int32)
+    below = t > u
+    lev[below] = np.floor(np.log2((t ^ u)[below])).astype(np.int32)
+    lev[at, at] = LEVELS
+    tables = (sums, sums.T)
+    if one_pass:
+        tables = (jnp.asarray(np.tile(table, (1, 3)), jnp.bfloat16)
+                  for table in tables)
+    return (*map(jnp.asarray, tables), jnp.asarray(lev))
+
+
+def _dot(a, b, dims, exact=False):
+    return jax.lax.dot_general(
+        a, b, (dims, ((), ())), preferred_element_type=jnp.float32,
+        precision=SOLVE_PRECISION if exact else None)
+
+
+_nn = partial(_dot, dims=((1,), (0,)))      # a b
+_nt = partial(_dot, dims=((1,), (1,)))      # a b^T
+_tn = partial(_dot, dims=((0,), (0,)))      # a^T b
+
+
+class _Chunk:
+    """The arithmetic of one chunk and head on values, shared by the
+    three kernels.  ``one_pass``: the chunk's products take their
+    operands in bf16 (one MXU pass, float32 sums: what the backend's
+    default precision is to the XLA form); the solve's products and the
+    tables' are exact either way."""
+
+    def __init__(self, sums, sums_t, lev, one_pass):
+        self.sums, self.sums_t, self.lev = sums, sums_t, lev
+        self.one_pass = one_pass
+        self.eye = (lev == LEVELS).astype(jnp.float32)
+
+    def low(self, x):
+        return x.astype(jnp.bfloat16) if self.one_pass else x
+
+    def table(self, table, x):
+        """A table's product at float32's precision: the table is exact
+        in bf16, so ``x`` is cut in three bf16 parts, stacked along the
+        contraction against the table three times side by side
+        (:func:`_tables` lays it so): one product, one sum."""
+        if not self.one_pass:
+            return _nn(table, x)
+        parts = []
+        for _ in range(3):
+            parts.append(x.astype(jnp.bfloat16))
+            x = x - parts[-1].astype(jnp.float32)
+        return _nn(table, jnp.concatenate(parts, axis=0))
+
+    # The three methods below are generators: each ``yield`` stands
+    # between two products of which the second needs the first, and
+    # :func:`_in_step` takes every head of a grid step to the same
+    # ``yield`` before any goes on.  The compiler schedules the products
+    # in the order they are written, so written head by head one head's
+    # chain of small products runs alone, each waiting on the last;
+    # written stage by stage one head's product runs while another's
+    # drains (the operator alone: the forward kernel 11.1 -> 6.7 ms, the
+    # walk back 11.5 -> 8.6; PERF.md section 6, PR 44).
+
+    def pairs(self, q, k, g):
+        """``G``, ``A`` (strictly lower), ``B`` (weakly lower) and each
+        level's ``(decay, k decayed, q decayed)``."""
+        sums = self.table(self.sums, g)
+        yield
+        a = jnp.zeros((CHUNK, CHUNK), jnp.float32)
+        b = self.eye * jnp.sum(q * k, axis=1, keepdims=True)
+        levels = []
+        for level in range(LEVELS):
+            decay = jnp.exp(sums[(level + 1) * CHUNK:(level + 2) * CHUNK])
+            ke, qe = k * decay, q * decay
+            both = _nt(self.low(jnp.concatenate([ke, qe], axis=0)),
+                       self.low(ke))
+            here = self.lev == level
+            a = a + jnp.where(here, both[:CHUNK], 0.0)
+            b = b + jnp.where(here, both[CHUNK:], 0.0)
+            levels.append((decay, ke, qe))
+        return sums[:CHUNK], a, b, levels
+
+    def solve(self, n):
+        """:func:`unit_lower_inverse` on one ``(C, C)`` value."""
+        inverse = self.eye - n
+        power, reach = _nn(n, n, exact=True), 2
+        while reach < CHUNK:
+            yield
+            inverse = inverse + _nn(inverse, power, exact=True)
+            reach *= 2
+            if reach < CHUNK:
+                power = _nn(power, power, exact=True)
+        return inverse
+
+    def forward(self, q, k, v, g, beta, state, t=None):
+        """``(o, the next state)`` and what the backward pass uses again;
+        ``state (d_v, d_k)`` is the chunk's starting state transposed,
+        ``t`` the solve where it is at hand."""
+        low = self.low
+        gsum, a, b, levels = yield from self.pairs(q, k, g)
+        yield
+        if t is None:
+            t = yield from self.solve(beta * a)
+            yield
+        into = jnp.exp(gsum)
+        kg, qg = k * into, q * into
+        rhs = beta * jnp.concatenate([v, kg], axis=1)
+        solved = _nn(low(t), low(rhs))
+        yield
+        u, wk = solved[:, :v.shape[1]], solved[:, v.shape[1]:]
+        seen = _nt(low(jnp.concatenate([wk, qg], axis=0)), low(state))
+        yield
+        w = u - seen[:CHUNK]
+        o = seen[CHUNK:] + _nn(low(b), low(w))
+        last = gsum[CHUNK - 1:]                       # (1, d_k)
+        out_of = jnp.exp(last - gsum)
+        kend = k * out_of
+        after = state * jnp.exp(last) + _tn(low(w), low(kend))
+        return o, after, dict(
+            a=a, b=b, levels=levels, t=t, into=into, kg=kg, qg=qg, rhs=rhs,
+            wk=wk, w=w, last=last, out_of=out_of, kend=kend)
+
+    def backward(self, q, k, v, g, beta, state, t, do, dafter):
+        """The chunk's matrices again but for the solve ``t``, then the
+        cotangents of the five inputs and of the starting state from
+        ``do`` and the next state's ``dafter``: the solve by ``dM = -T^T
+        dT T^T``, the pair matrices level by level, the log-decays' by
+        the tables' transposes (``G``'s is the reverse cumulative
+        sum)."""
+        low, dv = self.low, v.shape[1]
+        _, _, f = yield from self.forward(q, k, v, g, beta, state, t)
+        a, b, w, wk, kg, qg, kend = (f[name] for name in (
+            "a", "b", "w", "wk", "kg", "qg", "kend"))
+        dw = _tn(low(b), low(do)) + _nt(low(kend), low(dafter))
+        db = _nt(low(do), low(w))
+        dkend = _nn(low(w), low(dafter))
+        yield
+        through = _nn(low(jnp.concatenate([do, dw], axis=0)), low(state))
+        dstate = dafter * jnp.exp(f["last"]) + _tn(
+            low(jnp.concatenate([do, -dw], axis=0)),
+            low(jnp.concatenate([qg, wk], axis=0)))
+        yield
+        dqg, dwk = through[:CHUNK], -through[CHUNK:]
+        dsolved = jnp.concatenate([dw, dwk], axis=1)
+        dt = _nt(low(dsolved), low(f["rhs"]))
+        drhs = _tn(low(t), low(dsolved))
+        yield
+        half = _tn(t, dt, exact=True)
+        yield
+        dn = -_nt(half, t, exact=True)
+        yield
+        da = beta * dn
+        dbeta = jnp.sum(dn * a, axis=1, keepdims=True) + jnp.sum(
+            drhs * jnp.concatenate([v, kg], axis=1), axis=1, keepdims=True)
+        dkg = beta * drhs[:, dv:]
+        on_diagonal = jnp.sum(self.eye * db, axis=1, keepdims=True)
+        d_k = dkg * f["into"] + dkend * f["out_of"] + on_diagonal * q
+        d_q = dqg * f["into"] + on_diagonal * k
+        leaving = dkend * kend
+        dlast = jnp.exp(f["last"]) * jnp.sum(
+            state * dafter, axis=0, keepdims=True) + jnp.sum(
+                leaving, axis=0, keepdims=True)
+        row = jax.lax.broadcasted_iota(jnp.int32, leaving.shape, 0)
+        dsums = [dkg * kg + dqg * qg - leaving
+                 + jnp.where(row == CHUNK - 1, dlast, 0.0)]
+        for level, (decay, ke, qe) in enumerate(f["levels"]):
+            here = self.lev == level
+            cut = jnp.concatenate([jnp.where(here, da, 0.0),
+                                   jnp.where(here, db, 0.0)], axis=0)
+            along = _nn(low(cut), low(ke))
+            dke = along[:CHUNK] + _tn(
+                low(cut), low(jnp.concatenate([ke, qe], axis=0)))
+            dke, dqe = dke * decay, along[CHUNK:] * decay
+            d_k, d_q = d_k + dke, d_q + dqe
+            dsums.append(dke * k + dqe * q)
+        yield
+        d_g = self.table(self.sums_t, jnp.concatenate(dsums, axis=0))
+        return d_q, d_k, beta * drhs[:, :dv], d_g, dbeta, dstate
+
+
+def _in_step(heads):
+    """The results of the generators ``heads``, each taken to its next
+    ``yield`` in turn until all have returned."""
+    results, live = [None] * len(heads), dict(enumerate(heads))
+    while live:
+        for j, head in list(live.items()):
+            try:
+                next(head)
+            except StopIteration as done:
+                results[j] = done.value
+                del live[j]
+    return results
+
+
+def _heads_a_step(heads: int) -> int:
+    return max(n for n in range(1, HEADS_A_STEP + 1) if heads % n == 0)
+
+
+def _beta_of(beta_ref, head):
+    """A head's ``beta`` as a column ``(C, 1)`` of the block ``(C, H)``."""
+    block = beta_ref[0]
+    lane = jax.lax.broadcasted_iota(jnp.int32, block.shape, 1)
+    return jnp.sum(jnp.where(lane == head, block, 0.0), axis=1,
+                   keepdims=True)
+
+
+def _forward_kernel(sums_ref, lev_ref, q_ref, k_ref, v_ref, g_ref, beta_ref,
+                    o_ref, *rest, group, dk, dv, one_pass):
+    """``rest``: where the rule asks for them, every chunk's starting
+    state (``(1, group, 1, d_v, d_k)``) and solve (``(1, group, 1, C,
+    C)``); then the state's scratch."""
+    *kept_refs, state_ref = rest
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        state_ref[...] = jnp.zeros_like(state_ref)
+
+    chunk = _Chunk(sums_ref[...], None, lev_ref[...], one_pass)
+    states = [state_ref[j] for j in range(group)]
+    made = _in_step([chunk.forward(
+        q_ref[0, :, j * dk:(j + 1) * dk], k_ref[0, :, j * dk:(j + 1) * dk],
+        v_ref[0, :, j * dv:(j + 1) * dv], g_ref[0, :, j * dk:(j + 1) * dk],
+        _beta_of(beta_ref, pl.program_id(1) * group + j), states[j])
+        for j in range(group)])
+    for j, (o, after, kept) in enumerate(made):
+        o_ref[0, :, j * dv:(j + 1) * dv] = o
+        state_ref[j] = after
+        if kept_refs:
+            kept_refs[0][0, j, 0], kept_refs[1][0, j, 0] = states[j], kept["t"]
+
+
+def _backward_kernel(sums_ref, sums_t_ref, lev_ref, q_ref, k_ref, v_ref,
+                     g_ref, beta_ref, do_ref, starts_ref, solves_ref, dq_ref,
+                     dk_ref, dv_ref, dg_ref, dbeta_ref, dstate_ref, *, group,
+                     dk, dv, one_pass):
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        dstate_ref[...] = jnp.zeros_like(dstate_ref)
+
+    chunk = _Chunk(sums_ref[...], sums_t_ref[...], lev_ref[...], one_pass)
+    keys = [slice(j * dk, (j + 1) * dk) for j in range(group)]
+    values = [slice(j * dv, (j + 1) * dv) for j in range(group)]
+    made = _in_step([chunk.backward(
+        q_ref[0, :, keys[j]], k_ref[0, :, keys[j]], v_ref[0, :, values[j]],
+        g_ref[0, :, keys[j]], _beta_of(beta_ref, pl.program_id(1) * group + j),
+        starts_ref[0, j, 0], solves_ref[0, j, 0], do_ref[0, :, values[j]],
+        dstate_ref[j]) for j in range(group)])
+    lane = jax.lax.broadcasted_iota(jnp.int32, (CHUNK, group), 1)
+    dbeta = jnp.zeros((CHUNK, group), jnp.float32)
+    for j, (d_q, d_k, d_v, d_g, d_beta, dstate) in enumerate(made):
+        dq_ref[0, :, keys[j]], dk_ref[0, :, keys[j]] = d_q, d_k
+        dv_ref[0, :, values[j]], dg_ref[0, :, keys[j]] = d_v, d_g
+        dbeta = jnp.where(lane == j, d_beta, dbeta)
+        dstate_ref[j] = dstate
+    dbeta_ref[0, 0] = dbeta
+
+
+class _Calls:
+    """What the three calls share: the sizes, the tables, and the block
+    specs of one walk over the chunks (``back``: from the last to the
+    first)."""
+
+    def __init__(self, q, v, back, interpret):
+        self.b, self.length, self.h, self.dk = q.shape
+        self.dv, n = v.shape[-1], q.shape[1] // CHUNK
+        self.n, self.group = n, _heads_a_step(self.h)
+        interpret = use_interpret(interpret)
+        self.sums, self.sums_t, self.lev = _tables(one_pass=not interpret)
+        self.at = (lambda c: n - 1 - c) if back else (lambda c: c)
+        self.static = dict(group=self.group, dk=self.dk, dv=self.dv,
+                           one_pass=not interpret)
+        self.call = dict(
+            grid=(self.b, self.h // self.group, n), interpret=interpret,
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary")))
+
+    def rows(self, width):     # a chunk of a group's heads, row-major
+        return pl.BlockSpec((1, CHUNK, self.group * width),
+                            lambda i, j, c: (i, self.at(c), j))
+
+    def heads_rows(self):      # beta: a chunk of every head
+        return pl.BlockSpec((1, CHUNK, self.h),
+                            lambda i, j, c: (i, self.at(c), 0))
+
+    def kept(self, *shape):    # a chunk's and head's state or solve
+        return pl.BlockSpec((1, self.group, 1) + shape,
+                            lambda i, j, c: (i, j, self.at(c), 0, 0))
+
+    def dbeta(self):           # a chunk of a group's heads, a lane each
+        return pl.BlockSpec((1, 1, CHUNK, self.group),
+                            lambda i, j, c: (i, j, self.at(c), 0))
+
+    @staticmethod
+    def whole(table):          # held for the whole grid
+        return pl.BlockSpec(table.shape, lambda i, j, c: (0,) * table.ndim)
+
+
+def _f32(*shape):
+    return jax.ShapeDtypeStruct(shape, jnp.float32)
+
+
+def _flat(x):
+    """``(B, L, H, d)`` as its row-major ``(B, L, H d)`` view."""
+    return x.reshape(x.shape[0], x.shape[1], -1)
+
+
+def _kernel_forward(q, k, v, g, beta, keep=False, interpret=None):
+    """``o (B, L, H, d_v)``, and with ``keep`` every chunk's starting
+    state ``(B, H, n, d_v, d_k)`` and solve ``(B, H, n, C, C)``; ``L``
+    whole chunks."""
+    c = _Calls(q, v, False, interpret)
+    kept = ((c.dv, c.dk), (CHUNK, CHUNK)) if keep else ()
+    o, *rest = pl.pallas_call(
+        partial(_forward_kernel, **c.static),
+        in_specs=[c.whole(c.sums), c.whole(c.lev), c.rows(c.dk),
+                  c.rows(c.dk), c.rows(c.dv), c.rows(c.dk), c.heads_rows()],
+        out_specs=[c.rows(c.dv)] + [c.kept(*shape) for shape in kept],
+        out_shape=[_f32(c.b, c.length, c.h * c.dv)] + [
+            _f32(c.b, c.h, c.n, *shape) for shape in kept],
+        scratch_shapes=[pltpu.VMEM((c.group, c.dv, c.dk), jnp.float32)],
+        **c.call,
+    )(c.sums, c.lev, _flat(q), _flat(k), _flat(v), _flat(g), beta)
+    o = o.reshape(v.shape)
+    return (o, *rest) if keep else o
+
+
+def _kernel_backward(q, k, v, g, beta, starts, solves, do, interpret=None):
+    """The five cotangents, the chunks walked from the last to the first
+    with the state's cotangent in VMEM."""
+    c = _Calls(q, v, True, interpret)
+    keys, values = c.rows(c.dk), c.rows(c.dv)
+    d_keys, d_values = (_f32(c.b, c.length, c.h * d) for d in (c.dk, c.dv))
+    d_q, d_k, d_v, d_g, d_beta = pl.pallas_call(
+        partial(_backward_kernel, **c.static),
+        in_specs=[c.whole(c.sums), c.whole(c.sums_t), c.whole(c.lev),
+                  keys, keys, values, keys, c.heads_rows(), values,
+                  c.kept(c.dv, c.dk), c.kept(CHUNK, CHUNK)],
+        out_specs=[keys, keys, values, keys, c.dbeta()],
+        out_shape=[d_keys, d_keys, d_values, d_keys,
+                   _f32(c.b, c.h // c.group, c.length, c.group)],
+        scratch_shapes=[pltpu.VMEM((c.group, c.dv, c.dk), jnp.float32)],
+        **c.call,
+    )(c.sums, c.sums_t, c.lev, _flat(q), _flat(k), _flat(v), _flat(g), beta,
+      _flat(do), starts, solves)
+    return (d_q.reshape(q.shape), d_k.reshape(k.shape), d_v.reshape(v.shape),
+            d_g.reshape(g.shape),
+            d_beta.transpose(0, 2, 1, 3).reshape(beta.shape))
+
+
+def _whole_chunks(x):
+    """``x (B, L, ...)`` filled to whole chunks with positions that
+    neither decay nor write (zeros)."""
+    pad = round_up(x.shape[1], CHUNK) - x.shape[1]
+    return jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2)) \
+        if pad else x
+
+
+@jax.custom_vjp
+def _kernels_out(q, k, v, g, beta):
+    """The chunked form as Mosaic kernels; head widths of whole lanes."""
+    length = q.shape[1]
+    return _kernel_forward(*map(_whole_chunks, (q, k, v, g, beta))
+                           )[:, :length]
+
+
+def _kernels_fwd(q, k, v, g, beta):
+    return _kernels_out(q, k, v, g, beta), (q, k, v, g, beta)
+
+
+def _kernels_bwd(kept, ct):
+    kept_whole = tuple(map(_whole_chunks, kept))
+    _, starts, solves = _kernel_forward(*kept_whole, keep=True)
+    grads = _kernel_backward(*kept_whole, starts, solves, _whole_chunks(ct))
+    length = ct.shape[1]
+    return tuple(x[:, :length] for x in grads)
+
+
+_kernels_out.defvjp(_kernels_fwd, _kernels_bwd)
+
+
+def kda_scan_xla(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
+                 g: jnp.ndarray, beta: jnp.ndarray) -> jnp.ndarray:
+    """The chunked form as XLA's fusions and batched products, any head
+    width: what :func:`kda_scan` runs where a width is no whole number
+    of lanes, and the kernels' second oracle beside the recurrence."""
     b, length, h, _ = q.shape
     chunk = min(CHUNK, -(-length // SUB) * SUB)  # a short sequence: one chunk
     n = -(-length // chunk)
@@ -293,5 +751,20 @@ def kda_scan(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, g: jnp.ndarray,
                          ).transpose(3, 0, 4, 1, 2, 5)
 
     out = _chunks_out(*map(chunks, (q, k, v, g, beta[..., None])))
-    return checkpoint_name(out.transpose(1, 3, 4, 0, 2, 5).reshape(
-        b, n * chunk, h, out.shape[-1])[:, :length], KDA_OUT)
+    return out.transpose(1, 3, 4, 0, 2, 5).reshape(
+        b, n * chunk, h, out.shape[-1])[:, :length]
+
+
+def kda_scan(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, g: jnp.ndarray,
+             beta: jnp.ndarray) -> jnp.ndarray:
+    """The recurrence of the module's docstring in chunks of
+    :data:`CHUNK` positions, with its own backward rule.  Which form
+    runs is read off the shapes: head widths of whole lanes (``d_k`` and
+    ``d_v`` multiples of 128) take the Mosaic kernels, every other width
+    :func:`kda_scan_xla`.  The chunks' products take one bf16 pass on a
+    TPU (the backend's default precision in the XLA form, bf16 operands
+    in the kernels), the solve :data:`SOLVE_PRECISION`, the decays and
+    their sums float32."""
+    lanes = q.shape[-1] % LANE == 0 and v.shape[-1] % LANE == 0
+    return checkpoint_name(
+        (_kernels_out if lanes else kda_scan_xla)(q, k, v, g, beta), KDA_OUT)

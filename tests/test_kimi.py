@@ -140,31 +140,55 @@ SCANS = [
 ]
 
 
-@pytest.mark.parametrize("what,length,alpha,heads,g_tol", SCANS,
-                         ids=[s[0] for s in SCANS])
-def test_the_chunked_scan_is_the_recurrence_forward_and_backward(
-        what, length, alpha, heads, g_tol):
-    args = scan_inputs(length, *alpha, heads=heads)
-    ct = jax.random.normal(jax.random.PRNGKey(9), args[2].shape)
-    out, grads = both(delta_rule.kda_scan, args, ct)
-    want, want_grads = both(delta_rule.kda_scan_reference, args, ct)
+# the head widths: narrow ones take the XLA form, whole lanes the kernels
+NARROW, LANES = (16, 8), (128, 128)
+# each row of SCANS at both; "two groups of heads" on the kernels is two
+# grid steps' worth of heads (6: two groups of 3), not the XLA form's 12
+SCAN_CASES = [(*scan, *NARROW) for scan in SCANS] + [
+    (f"{what}, on the kernels", length, alpha, min(heads, 6), g_tol, *LANES)
+    for what, length, alpha, heads, g_tol in SCANS] + [
+    # the state is d_v x d_k: keys of two lanes' worth over values of one
+    ("keys twice as wide as values, on the kernels", 100, (0.2, 0.999), 2,
+     SCAN_TOL, 256, 128)]
+
+
+def _same(what, got, wanted, g_tol):
+    """Output and five gradients of one form against another's."""
+    (out, grads), (want, want_grads) = got, wanted
     assert bool(jnp.all(jnp.isfinite(out))), what
     assert relative(out, want) < SCAN_TOL, what
-    for name, got, wanted in zip("qkvgb", grads, want_grads):
-        assert bool(jnp.all(jnp.isfinite(got))), (what, name)
+    for name, mine, theirs in zip("qkvgb", grads, want_grads):
+        assert bool(jnp.all(jnp.isfinite(mine))), (what, name)
         if name != "g":
-            assert relative(got, wanted) < SCAN_TOL, (what, name)
+            assert relative(mine, theirs) < SCAN_TOL, (what, name)
         elif g_tol is not None:
-            assert relative(got, wanted) < g_tol, (what, name)
+            assert relative(mine, theirs) < g_tol, (what, name)
         else:
             # near 0 the state is gone before it is read: the gradient
             # is nothing beside the others', on both sides
             scale = float(jnp.linalg.norm(want_grads[2]))
-            assert float(jnp.linalg.norm(got - wanted)) < 1e-6 * scale
+            assert float(jnp.linalg.norm(mine - theirs)) < 1e-6 * scale
 
 
-def test_no_state_crosses_the_sequences_of_a_batch():
-    args = scan_inputs(90, 0.5, 0.999, batch=2)
+@pytest.mark.parametrize("what,length,alpha,heads,g_tol,dk,dv", SCAN_CASES,
+                         ids=[s[0] for s in SCAN_CASES])
+def test_the_chunked_scan_is_the_recurrence_forward_and_backward(
+        what, length, alpha, heads, g_tol, dk, dv):
+    """At a narrow head the XLA form, at a head of whole lanes the
+    Mosaic kernels (interpreted here): each against the recurrence
+    token by token, and the kernels against the XLA form on the same
+    inputs too."""
+    args = scan_inputs(length, *alpha, heads=heads, dk=dk, dv=dv)
+    ct = jax.random.normal(jax.random.PRNGKey(9), args[2].shape)
+    got = both(delta_rule.kda_scan, args, ct)
+    _same(what, got, both(delta_rule.kda_scan_reference, args, ct), g_tol)
+    if dk % 128 == 0:
+        _same(what, got, both(delta_rule.kda_scan_xla, args, ct), g_tol)
+
+
+@pytest.mark.parametrize("dk,dv", [NARROW, LANES], ids=["xla", "kernels"])
+def test_no_state_crosses_the_sequences_of_a_batch(dk, dv):
+    args = scan_inputs(90, 0.5, 0.999, batch=2, heads=2, dk=dk, dv=dv)
     scan = jax.jit(delta_rule.kda_scan)
     whole = scan(*args)
     for row in range(2):
@@ -173,15 +197,82 @@ def test_no_state_crosses_the_sequences_of_a_batch():
                            rtol=0, atol=1e-6)
 
 
-def test_the_state_carries_what_a_chunk_saw_into_the_next():
+@pytest.mark.parametrize("dk,dv", [NARROW, LANES], ids=["xla", "kernels"])
+def test_the_state_carries_what_a_chunk_saw_into_the_next(dk, dv):
     """A value written in the first chunk is read in the third (no
     decay, one key): the scan over chunk states, not the chunks alone."""
-    length, dk, dv = 3 * delta_rule.CHUNK, 16, 8
+    length = 3 * delta_rule.CHUNK
     k = jnp.zeros((1, length, 1, dk)).at[:, :, :, 0].set(1.0)
     v = jnp.zeros((1, length, 1, dv)).at[:, 0].set(1.0)
     beta = jnp.zeros((1, length, 1)).at[:, 0].set(1.0)
     out = jax.jit(delta_rule.kda_scan)(k, k, v, jnp.zeros_like(k), beta)
     assert np.allclose(np.asarray(out[0, :, 0]), 1.0)
+
+
+def test_a_chunks_pairs_part_ways_at_one_level_each():
+    """The kernels' tables: every pair ``s < t`` of a chunk belongs to
+    one level, and there the two exponents' sums are the log-decays
+    from ``s`` to ``t``, each once: ``G_t - G_s``."""
+    sums, sums_t, lev = map(np.asarray, delta_rule._tables())
+    assert np.array_equal(sums_t, sums.T)
+    chunk, levels = delta_rule.CHUNK, delta_rule.LEVELS
+    assert sums.shape == ((levels + 1) * chunk, chunk)
+    assert np.array_equal(sums[:chunk], np.tril(np.ones((chunk, chunk))))
+    t, s = np.tril_indices(chunk, -1)
+    assert set(lev[t, s]) == set(range(levels))
+    assert np.all(lev[np.triu_indices(chunk, 1)] == -1)
+    assert np.all(np.diag(lev) == levels)
+    g = np.random.RandomState(0).rand(chunk)
+    through = (sums @ g).reshape(levels + 1, chunk)
+    assert np.allclose(through[1 + lev[t, s], t] + through[1 + lev[t, s], s],
+                       through[0, t] - through[0, s])
+
+
+def test_a_chunk_on_bf16_operands_is_the_float32_one_to_their_rounding():
+    """What the kernels compute on the chip and interpret mode does not
+    run: the chunk's products on bf16 operands, the tables' on three
+    bf16 parts of the other operand stacked along the contraction.  On
+    plain values, no kernel: forward and backward within bf16's rounding
+    of the float32 arithmetic, and the tables' products within
+    float32's."""
+    q, k, v, g, beta = (x[0, :, 0] for x in scan_inputs(
+        64, 0.2, 0.999, batch=1, heads=1, dk=128, dv=128))
+    keys = jax.random.split(jax.random.PRNGKey(4), 3)
+    state, do, dafter = (jax.random.normal(key, shape) for key, shape in zip(
+        keys, ((128, 128), (64, 128), (128, 128))))
+    exact, rounded = (delta_rule._Chunk(*delta_rule._tables(one_pass), one_pass)
+                      for one_pass in (False, True))
+    assert rounded.sums.dtype == jnp.bfloat16
+    for table, x in ((0, g), (1, jnp.tile(g, (delta_rule.LEVELS + 1, 1)))):
+        tables = [(c.sums, c.sums_t)[table] for c in (exact, rounded)]
+        want, got = (c.table(t, x) for c, t in zip((exact, rounded), tables))
+        assert relative(got, want) < 1e-6
+    args = (q, k, v, g, beta[:, None], state)
+    (o, after, made), (o2, after2, _) = delta_rule._in_step(
+        [c.forward(*args) for c in (exact, rounded)])
+    assert 1e-5 < relative(o2, o) < 1e-2 > relative(after2, after)
+    want, got = delta_rule._in_step(
+        [c.backward(*args, made["t"], do, dafter) for c in (exact, rounded)])
+    for mine, theirs in zip(got, want):
+        assert 1e-5 < relative(mine, theirs) < 2e-2
+
+
+def test_the_heads_of_a_grid_step_go_stage_by_stage():
+    """``_in_step`` takes every generator to its next ``yield`` in turn:
+    the order in which the heads' products are written, which is the
+    order the kernel's compiler runs them in."""
+    order = []
+
+    def head(name, stages):
+        for stage in range(stages):
+            order.append((name, stage))
+            yield
+        return name.upper()
+
+    assert delta_rule._in_step([head("a", 3), head("b", 1), head("c", 2)]) \
+        == ["A", "B", "C"]
+    assert order == [("a", 0), ("b", 0), ("c", 0), ("a", 1), ("c", 1),
+                     ("a", 2)]
 
 
 def test_the_triangular_solve_is_the_inverse_and_its_rule_the_inverses():
@@ -226,6 +317,70 @@ def test_the_backward_rule_keeps_the_five_inputs_and_no_chunk_state():
     assert len(kept) == 5
     assert sum(int(np.prod(s.shape)) for s in kept) <= sum(
         256 * x.size // 200 for x in args)
+
+
+def test_the_kernels_rule_keeps_the_five_inputs_as_they_are_handed():
+    """At a head of whole lanes: the five arguments themselves, row-major
+    and unpadded; no chunk state, no chunk matrix."""
+    from jax._src.ad_checkpoint import saved_residuals
+
+    args = scan_inputs(200, 0.5, 0.9, batch=1, heads=2, dk=128, dv=128)
+    kept = saved_residuals(delta_rule.kda_scan, *args)
+    assert sorted(shape.shape for shape, _ in kept) == sorted(
+        x.shape for x in args)
+    assert all("argument" in why for _, why in kept)
+
+
+def _equations(jaxpr, primitive, above=""):
+    """Every equation of ``primitive`` in ``jaxpr`` and the jaxprs its
+    equations hold (a checkpoint's, a custom rule's), each with its
+    whole name stack: an inner jaxpr's stacks start at its equation's."""
+    for eqn in jaxpr.eqns:
+        stack = f"{above}/{eqn.source_info.name_stack}"
+        if eqn.primitive.name == primitive:
+            yield eqn, stack
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _equations(sub, primitive, stack)
+
+
+@pytest.mark.parametrize("head_dim,kernels", [
+    (128, ["_backward_kernel", "_forward_kernel", "_forward_kernel"]),
+    (16, [])], ids=["the_cells_width", "a_16_wide_head"])
+def test_a_kda_layers_step_holds_the_scans_three_kernels(head_dim, kernels):
+    """The counter that says the kernels engage is static, the choice
+    being made while the step is traced: the gradient of a ``KimiBlock``
+    with a ``kda`` mixer at the cell's KDA width holds, under the scope
+    ``kda_scan``, the forward kernel once (the mixer's checkpoint keeps
+    its result) and the rule's two, the forward that writes the chunks'
+    starting states and solves and the walk back; at a 16-wide head
+    none."""
+    kw = {name: TINY[name] for name in (
+        "d_model", "n_heads", "q_rank", "kv_rank", "qk_nope", "qk_rope",
+        "v_head", "dense_width", "n_experts", "experts_per_tok",
+        "expert_width", "conv_kernel", "route_scale", "norm_eps")}
+    block = transformer.KimiBlock(
+        **kw, mixer="kda", sparse=False, kda_heads=2, kda_head_dim=head_dim,
+        attn_fn=transformer.default_attn(use_flash=False))
+    x = jnp.zeros((1, 80, TINY["d_model"]))
+    params = jax.eval_shape(block.init, jax.random.PRNGKey(0), x)["params"]
+    params = jax.tree_util.tree_map(
+        lambda leaf: jnp.zeros(leaf.shape, leaf.dtype), params)
+    jaxpr = jax.make_jaxpr(jax.grad(lambda p: jnp.sum(
+        block.apply({"params": p}, x)[0])))(params)
+    calls = list(_equations(jaxpr.jaxpr, "pallas_call"))
+    assert sorted(eqn.params["jaxpr"].debug_info.func_name
+                  for eqn, _ in calls) == kernels
+    for _, stack in calls:
+        assert "kda_scan" in stack, stack
+    kept = [[out.aval.shape for out in eqn.outvars] for eqn, _ in calls
+            if len(eqn.outvars) == 3]
+    if kernels:   # the rule's forward alone writes the chunks' states
+        #           and solves, for the walk back and no longer
+        assert kept == [[(1, 128, 2 * 128), (1, 2, 2, 128, 128),
+                         (1, 2, 2, 64, 64)]]
 
 
 # -- (b) the decoder against the plain reference ---------------------------------

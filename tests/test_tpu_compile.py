@@ -3,7 +3,8 @@ on-chip-measurement guide, section 2): the Pallas grouped product of
 ``parallel/moe.py`` at OLMoE's published shapes, forward and backward;
 the flash kernels at LFM2's attention shape (32 query over 8 KV heads of
 64 at 8192) and at Mellum's sliding layer's (32 over 4 heads of 128
-under a window of 1024); and the msgd commit over LFM2's vector, whose length is
+under a window of 1024); the delta rule's three kernels at Kimi's KDA
+shape (32 heads of 128 at 8192); and the msgd commit over LFM2's vector, whose length is
 whole lanes and no whole number of blocks, and over Ouro's, which is no
 whole number of lanes, with ``w`` and ``vt`` donated.  What interpret mode cannot show: that the tiles fit the chip's fast
 memory and the kernels lower.  A compile that passes is not a chip run
@@ -81,6 +82,34 @@ def test_flash_attention_compiles_at(one_chip, kv_heads, width, window):
     # forward, and the fused (no window) or the two backward kernels
     assert calls >= (2 if window is None else 3)
     dq, dk, dv = compiled.output_shardings
+
+
+def test_the_delta_rules_kernels_compile_at_kimis_shape(one_chip, monkeypatch):
+    """Kimi's KDA layer (PR 44): 32 heads of 128 at 8192, the forward
+    kernel and the rule's two (the forward that writes the 128 chunk
+    states and solves a head, the walk back), on the row-major inputs: blocks of
+    ``(64, 4 x 128)`` and the tables fit the chip's fast memory and every
+    product lowers, the solve's at full float32 precision among them.
+    The choice of interpret mode asks for the backend, which is the CPU
+    here: steered in the test."""
+    from mpit_tpu.ops import delta_rule
+
+    monkeypatch.setattr(delta_rule, "use_interpret", lambda flag: False)
+    wide = jax.ShapeDtypeStruct((1, 8192, 32, 128), jnp.float32,
+                                sharding=one_chip)
+    beta = jax.ShapeDtypeStruct((1, 8192, 32), jnp.float32,
+                                sharding=one_chip)
+
+    def loss(q, k, v, g, beta):
+        return jnp.sum(delta_rule.kda_scan(q, k, v, g, beta) ** 2)
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        wide, wide, wide, wide, beta).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    assert "f32[1,32,128,128,128]" in text   # the chunks' starting states
+    assert "f32[1,32,128,64,64]" in text     # and their solves
+    assert len(compiled.output_shardings) == 5
 
 
 def test_the_donated_commit_keeps_no_copy_of_lfm2s_vector(one_chip):
