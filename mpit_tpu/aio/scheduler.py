@@ -17,7 +17,7 @@ from __future__ import annotations
 
 import os
 import time
-from typing import Any, Callable, Generator, Optional
+from typing import Any, Callable, Generator, List, Optional
 
 from mpit_tpu.aio.queue import Queue
 from mpit_tpu.obs import flight as _obs_flight
@@ -341,7 +341,7 @@ def aio_send(
     cb: Optional[Callable[[Any], None]] = None,
     deadline: Optional[float] = None,
     abort: Optional[Callable[[], bool]] = None,
-    ready: Optional[Callable[[], int]] = None,
+    pieces: Optional[Callable[[int], List[Any]]] = None,
 ) -> Generator[str, None, None]:
     """Nonblocking send: post, then poll-test until complete.
 
@@ -356,20 +356,31 @@ def aio_send(
     and returns None (the lease-eviction path: a server must stop waiting
     on a peer its lease registry has declared dead).
 
-    ``ready``: ``data`` is still being written, front to back, and
-    ``ready()`` says how many of its bytes are whole (never fewer than it
-    said before).  The send is posted with that mark and every poll moves
-    the mark to what ``ready()`` says now, so the bytes leave as they
-    become whole; for a transport that can hold such a send (``extend``,
-    ``comm/transport.py``).  If ``ready()`` raises (whoever writes the
-    rest has failed) the send is cancelled part-way and the error is the
+    ``pieces``: the message is not whole yet and arrives in pieces;
+    ``data`` is then its length in bytes.  ``pieces(written)`` is asked at
+    the post and at every poll: told how many of the message's bytes the
+    transport has placed by now (every piece that ends there or before is
+    the caller's again), it returns the arrays that became whole since it
+    was last asked, in order, and the send reads each where it lies; for a
+    transport that can hold such a send (``append``,
+    ``comm/transport.py``).  If ``pieces`` raises (whoever makes the rest
+    has failed) the send is cancelled part-way and the error is the
     task's: the peer never takes the message for whole.
     """
-    if ready is None:
+    if pieces is None:
         handle = transport.isend(data, dst, tag)
     else:
-        handle = transport.isend(data, dst, tag, ready=ready())
-    while not transport.test(handle):
+        handle = transport.isend_pieces(data, dst, tag)
+    while True:
+        if pieces is not None:
+            try:
+                for piece in pieces(transport.written(handle)):
+                    transport.append(handle, piece)
+            except BaseException:
+                transport.cancel(handle)
+                raise
+        if transport.test(handle):
+            break
         if live is not None and not live.io:
             transport.cancel(handle)
             return
@@ -380,12 +391,8 @@ def aio_send(
             transport.cancel(handle)
             raise DeadlineExceeded("send", dst, tag, time.monotonic() - deadline)
         yield EXEC
-        if ready is not None:
-            try:
-                transport.extend(handle, ready())
-            except BaseException:
-                transport.cancel(handle)
-                raise
+    if pieces is not None:
+        pieces(transport.written(handle))  # all of them: none is held now
     if cb is not None:
         cb(handle)
 

@@ -51,7 +51,7 @@ def _as_pointer(obj):
 #: lockstep with transport.cpp (see kApiVersion comment there) whenever
 #: the exported C surface changes, so a stale .so fails loudly with a
 #: rebuild message instead of a confusing missing-symbol AttributeError.
-MT_API_VERSION = 17005
+MT_API_VERSION = 17006
 
 
 class NativeTransportLib:
@@ -97,8 +97,8 @@ class NativeTransportLib:
         self._lib.mt_irecv.argtypes = [ctypes.c_void_p, ctypes.c_int32, ctypes.c_int32, ctypes.c_void_p, ctypes.c_uint64]
         self._lib.mt_isend.restype = ctypes.c_int64
         self._lib.mt_isend.argtypes = [ctypes.c_void_p, ctypes.c_int32, ctypes.c_int32, ctypes.c_void_p, ctypes.c_uint64]
-        self._lib.mt_isend_marked.restype = ctypes.c_int64
-        self._lib.mt_isend_marked.argtypes = [ctypes.c_void_p, ctypes.c_int32, ctypes.c_int32, ctypes.c_void_p, ctypes.c_uint64, ctypes.c_uint64]
+        self._lib.mt_isend_pieces.restype = ctypes.c_int64
+        self._lib.mt_isend_pieces.argtypes = [ctypes.c_void_p, ctypes.c_int32, ctypes.c_int32, ctypes.c_uint64]
         self._lib.mt_nranks.restype = ctypes.c_int32
         self._lib.mt_nranks.argtypes = [ctypes.c_void_p]
         self._lib.mt_op_timing.restype = ctypes.c_int32
@@ -133,8 +133,10 @@ class NativeTransportLib:
         self._lib.mt_ring_counts.argtypes = [ctypes.c_void_p, ctypes.c_int32]
         self._lib.mt_rx_bytes.restype = ctypes.c_uint64
         self._lib.mt_rx_bytes.argtypes = [ctypes.c_void_p, ctypes.c_int32]
-        self._lib.mt_send_extend.restype = ctypes.c_int64
-        self._lib.mt_send_extend.argtypes = [ctypes.c_void_p, ctypes.c_int64, ctypes.c_uint64]
+        self._lib.mt_send_append.restype = ctypes.c_int64
+        self._lib.mt_send_append.argtypes = [ctypes.c_void_p, ctypes.c_int64, ctypes.c_void_p, ctypes.c_uint64]
+        self._lib.mt_send_written.restype = ctypes.c_int64
+        self._lib.mt_send_written.argtypes = [ctypes.c_void_p, ctypes.c_int64]
         self._lib.mt_set_timing.restype = None
         self._lib.mt_set_timing.argtypes = [ctypes.c_void_p, ctypes.c_int32]
         self._lib.mt_test.restype = ctypes.c_int32
@@ -211,10 +213,9 @@ class NativeTransportLib:
         data, _data_len = _as_pointer(data)
         return self._lib.mt_isend(ctx, dst, tag, data, len)
 
-    def mt_isend_marked(self, ctx, dst, tag, data, len, ready):
-        """mt_isend of a buffer that is still being written: only the first ready bytes of data may be read yet (len or more: all). Chunks are placed up to the mark, mt_send_extend moves it, and the op is done only at len."""
-        data, _data_len = _as_pointer(data)
-        return self._lib.mt_isend_marked(ctx, dst, tag, data, len, ready)
+    def mt_isend_pieces(self, ctx, dst, tag, len):
+        """A send of len bytes that is not yet whole: it takes its place in the destination's queue with no piece appended. mt_send_append adds each piece where it lies, chunks are cut across the pieces up to the last appended byte, and the op is done only at len."""
+        return self._lib.mt_isend_pieces(ctx, dst, tag, len)
 
     def mt_nranks(self, ctx):
         """World size."""
@@ -282,16 +283,21 @@ class NativeTransportLib:
         return self._lib.mt_release(ctx, handle)
 
     def mt_ring_counts(self, ctx, which):
-        """How the rings were used so far: which == 0, chunks this endpoint placed in its peers' rings; 1, placements a full ring refused (the sender waited for the owner); 2, chunks copied out of the own rings; 3, those of them during whose copy the ring's head moved (sender and owner were copying at the same time); 4, payload bytes placed while their op's ready mark was short of its length."""
+        """How the rings were used so far: which == 0, chunks this endpoint placed in its peers' rings; 1, placements a full ring refused (the sender waited for the owner); 2, chunks copied out of the own rings; 3, those of them during whose copy the ring's head moved (sender and owner were copying at the same time); 4, payload bytes placed while their op's pieces were short of its length."""
         return self._lib.mt_ring_counts(ctx, which)
 
     def mt_rx_bytes(self, ctx, which):
         """Bytes of the messages received whole so far: which == 0, those that landed in the buffer of a receive posted before they arrived; 1, those assembled in a buffer of the transport's own."""
         return self._lib.mt_rx_bytes(ctx, which)
 
-    def mt_send_extend(self, ctx, handle, ready):
-        """Move a pending send's ready mark to ready bytes: forward only, clamped to its length. Returns the mark after the call, or -1 for a handle that is no pending send. The next call that makes progress places what became ready."""
-        return self._lib.mt_send_extend(ctx, handle, ready)
+    def mt_send_append(self, ctx, handle, data, n):
+        """Append the next n bytes of a pending send, which lie at data and must stay there unchanged until they are in the ring (mt_send_written). Returns the bytes appended so far, -1 for a handle that is no pending send, -2 (nothing appended) if the pieces would pass the send's length. The next call that makes progress places what was appended."""
+        data, _data_len = _as_pointer(data)
+        return self._lib.mt_send_append(ctx, handle, data, n)
+
+    def mt_send_written(self, ctx, handle):
+        """Payload bytes of a pending send that are in the ring: a piece that ends at or before that byte has been read for the last time. -1 for a handle that is no pending send."""
+        return self._lib.mt_send_written(ctx, handle)
 
     def mt_set_timing(self, ctx, on):
         """The one switch of the wire's timing: on, every message keeps a record of where its time went (mt_op_timing), every chunk carries the instant it was published and the endpoint its totals (mt_wire_ns); off (the default) the message path reads no clock."""

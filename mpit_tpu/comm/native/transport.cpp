@@ -44,13 +44,19 @@
 //    receive and the cancelled buffer is not written again.
 //  * Per-destination FIFO send queues give MPI-style non-overtaking order
 //    between any (src, dst) pair.
-//  * A send may be posted before its bytes are whole: it carries a ready
-//    mark, the bytes of its buffer that may be read, which the caller
-//    moves forward (mt_send_extend) as the rest is written.  Chunks are
-//    placed up to the mark and no further, the op is done only at its
+//  * A send is a list of pieces, (pointer, length), placed in order: the
+//    message is their bytes end to end.  mt_isend posts the list of one, a
+//    whole buffer.  A send may also be posted before its bytes are whole
+//    (mt_isend_pieces: a length and no piece yet), and the caller appends
+//    each piece where it lies as it becomes whole (mt_send_append): no
+//    byte is copied together first.  Chunks are cut across the pieces, up
+//    to the last appended byte and no further, the op is done only at its
 //    length, and a later send to the same rank waits behind it as behind a
-//    full ring.  The receiver sees the header, the `total_bytes` and the
-//    bytes it always saw; only the instants differ.
+//    full ring.  A piece is read until its last byte is in the ring and
+//    not after (mt_send_written says how far that is).  The receiver sees
+//    the header, the `total_bytes` and the bytes it always saw, one
+//    message; only the instants differ.  This is the one form of a send
+//    that is not yet whole.
 //  * All progress happens inside mt_iprobe/mt_test calls from the caller's
 //    cooperative scheduler — single-threaded per process, like the
 //    reference's coroutine polling (reference init.lua:147-185).
@@ -177,15 +183,15 @@ uint64_t since(uint64_t later, uint64_t earlier) {
 // Where a sent message's time went, first attempt to place a chunk to last
 // chunk published.  What is neither `copy_ns` nor `blocked_ns` of that is
 // the sender's time away: the ring had room and its thread was elsewhere,
-// or (`unready_ns`, a part of it) here with no byte under the op's mark left.
+// or (`unready_ns`, a part of it) here with no appended byte of the op left.
 struct TxTiming {
   uint64_t t_first = 0;
   uint64_t t_done = 0;
   uint64_t copy_ns = 0;     // inside circ_write
   uint64_t blocked_ns = 0;  // a refused placement to the next accepted one
   uint64_t t_refused = 0;   // the refusal still waited out; 0: none
-  uint64_t unready_ns = 0;  // a pass that found the mark reached, to the
-                            // next attempt with a byte to place
+  uint64_t unready_ns = 0;  // a pass that found every appended byte placed,
+                            // to the next attempt with a byte to place
   uint64_t t_unready = 0;   // that pass, still waited out; 0: none
   uint32_t refused = 0;     // the polls, as tx_ring_full counts them
   uint32_t chunks = 0;
@@ -252,14 +258,21 @@ struct Partial {
   RxTiming rt;
 };
 
+// A run of a send's bytes, where the caller keeps them.
+struct Piece {
+  const uint8_t* data = nullptr;
+  uint64_t len = 0;
+};
+
 struct SendOp {
   int dst = -1;
   int tag = 0;
-  const uint8_t* data = nullptr;
+  std::deque<Piece> pieces;  // appended and not yet wholly placed, in order
+  uint64_t piece_off = 0;    // bytes of the front piece already placed
   uint64_t len = 0;
-  uint64_t ready = 0;    // bytes of `data` that may be read (<= len): the mark
-  uint64_t written = 0;  // payload bytes already placed in the ring
-  uint64_t early_bytes = 0;  // those placed while the mark was short of len
+  uint64_t appended = 0;  // bytes of all the pieces appended so far (<= len)
+  uint64_t written = 0;   // payload bytes already placed in the ring
+  uint64_t early_bytes = 0;  // those placed while `appended` was short of len
   uint64_t msg_id = 0;
   uint32_t nchunks = 0;
   uint32_t next_chunk = 0;
@@ -311,7 +324,7 @@ struct Ctx {
   // sender waited for the owner), chunks copied out of an own ring, and
   // those of them during whose copy the ring's head moved (the sender was
   // copying into the ring at the same time), and payload bytes placed while
-  // their op's ready mark was short of its length (mt_ring_counts).
+  // their op's pieces were short of its length (mt_ring_counts).
   uint64_t tx_chunks = 0;
   uint64_t tx_ring_full = 0;
   uint64_t rx_chunks = 0;
@@ -621,10 +634,11 @@ void drop_recv(Ctx* ctx, std::map<int64_t, RecvOp>::iterator it) {
 // Place more chunks of the front send ops of each destination, at most one
 // ring's worth of bytes a destination and pass: with the owner draining
 // beside it the ring may never fill, and the caller's thread has its other
-// destinations, its inbox and its deadlines to look at.  A chunk ends at
-// the op's ready mark at the latest, and is cut short of a whole one only
-// where nothing more is ready; an op at its mark and short of its length
-// stays at the front of its queue, like one before a full ring.  While
+// destinations, its inbox and its deadlines to look at.  A chunk is cut
+// across the op's pieces in order, ends at the last appended byte at the
+// latest, and is cut short of a whole one only where nothing more is
+// appended; an op at its last appended byte and short of its length stays
+// at the front of its queue, like one before a full ring.  While
 // timing, the payload goes in first and the header, stamped with the
 // instant, after it; both lie in the ring before `head` says so either way.
 void pump_sends(Ctx* ctx) {
@@ -647,13 +661,13 @@ void pump_sends(Ctx* ctx) {
       uint64_t head = ring.idx->head.load(std::memory_order_relaxed);
       bool full = false;
       while (!op.done) {
-        uint64_t remaining = op.ready - op.written;
+        uint64_t remaining = op.appended - op.written;
         uint64_t chunk = remaining < chunk_max ? remaining : chunk_max;
         uint64_t need = sizeof(ChunkHeader) + chunk;
         if (need > budget) break;
         const uint64_t t_try = timing ? now_ns() : 0;
         if (timing && op.tt.t_first == 0) op.tt.t_first = t_try;
-        if (chunk == 0 && op.len > 0) {  // at the mark: the caller's to move
+        if (chunk == 0 && op.len > 0) {  // nothing appended is left: the caller's
           if (timing && op.tt.t_unready == 0) op.tt.t_unready = t_try;
           break;
         }
@@ -680,8 +694,18 @@ void pump_sends(Ctx* ctx) {
         ch.chunk_bytes = chunk;
         ch.total_bytes = op.len;
         ch.pub_ns = 0;
-        if (chunk > 0) {
-          circ_write(ring, head + sizeof(ch), op.data + op.written, chunk);
+        for (uint64_t placed = 0; placed < chunk;) {
+          Piece& piece = op.pieces.front();
+          uint64_t n = piece.len - op.piece_off;
+          if (n > chunk - placed) n = chunk - placed;
+          circ_write(ring, head + sizeof(ch) + placed,
+                     piece.data + op.piece_off, n);
+          placed += n;
+          op.piece_off += n;
+          if (op.piece_off == piece.len) {  // its last byte is in the ring
+            op.pieces.pop_front();
+            op.piece_off = 0;
+          }
         }
         if (timing) {
           const uint64_t t_pub = now_ns();
@@ -700,7 +724,7 @@ void pump_sends(Ctx* ctx) {
         ring.idx->head.store(head, std::memory_order_release);
         budget -= need;
         ctx->tx_chunks++;
-        if (op.ready < op.len) {
+        if (op.appended < op.len) {
           op.early_bytes += chunk;
           ctx->tx_early_bytes += chunk;
         }
@@ -770,42 +794,61 @@ void mt_finalize(void* vctx) {
 int mt_rank(void* vctx) { return static_cast<Ctx*>(vctx)->rank; }
 int mt_nranks(void* vctx) { return static_cast<Ctx*>(vctx)->nranks; }
 
-// A send of which only the first `ready` bytes of `data` may be read yet
-// (`len` or more: all of them); mt_send_extend moves the mark.
-int64_t mt_isend_marked(void* vctx, int dst, int tag, const void* data,
-                        uint64_t len, uint64_t ready) {
+// A send of `len` bytes of which no piece has been appended yet
+// (mt_send_append): the op takes its place in the destination's queue and
+// its message id now, and its bytes leave as they are appended.
+int64_t mt_isend_pieces(void* vctx, int dst, int tag, uint64_t len) {
   auto* ctx = static_cast<Ctx*>(vctx);
   if (dst < 0 || dst >= ctx->nranks) return -1;
   SendOp op;
   op.dst = dst;
   op.tag = tag;
-  op.data = static_cast<const uint8_t*>(data);
   op.len = len;
-  op.ready = ready < len ? ready : len;
   op.msg_id = ctx->next_msg_id++;
   int64_t handle = ctx->next_handle++;
   ctx->sends[handle] = op;
   ctx->send_q[dst].push_back(handle);
-  progress(ctx);
   return handle;
 }
 
-int64_t mt_isend(void* vctx, int dst, int tag, const void* data, uint64_t len) {
-  return mt_isend_marked(vctx, dst, tag, data, len, len);
-}
-
-// Move a pending send's ready mark to `ready` bytes: forward only, and to
-// its length at most.  Returns the mark after the call, or -1 for a handle
-// that is no pending send (unknown, cancelled, or done and forgotten).  The
-// next call that makes progress places what became ready.
-int64_t mt_send_extend(void* vctx, int64_t handle, uint64_t ready) {
+// Append the next `n` bytes of a pending send, which lie at `data` and stay
+// there unchanged until they are in the ring (mt_send_written).  Returns the
+// bytes appended so far after the call; -1 for a handle that is no pending
+// send (unknown, cancelled, or done and forgotten); -2, and nothing is
+// appended, if the pieces would pass the send's length.  The next call that
+// makes progress places what was appended.
+int64_t mt_send_append(void* vctx, int64_t handle, const void* data,
+                       uint64_t n) {
   auto* ctx = static_cast<Ctx*>(vctx);
   auto sit = ctx->sends.find(handle);
   if (sit == ctx->sends.end() || sit->second.cancelled) return -1;
   SendOp& op = sit->second;
-  if (ready > op.len) ready = op.len;
-  if (ready > op.ready) op.ready = ready;
-  return (int64_t)op.ready;
+  if (n > op.len - op.appended) return -2;
+  if (n > 0) {
+    op.pieces.push_back(Piece{static_cast<const uint8_t*>(data), n});
+    op.appended += n;
+  }
+  return (int64_t)op.appended;
+}
+
+// The whole send: the list of one piece.
+int64_t mt_isend(void* vctx, int dst, int tag, const void* data, uint64_t len) {
+  int64_t handle = mt_isend_pieces(vctx, dst, tag, len);
+  if (handle < 0) return handle;
+  mt_send_append(vctx, handle, data, len);
+  progress(static_cast<Ctx*>(vctx));
+  return handle;
+}
+
+// Payload bytes of a pending send that are in the ring: every piece that
+// ends at or before that byte has been read for the last time.  -1 for a
+// handle that is no pending send; a finished one whose record is kept
+// (timing) says its length.
+int64_t mt_send_written(void* vctx, int64_t handle) {
+  auto* ctx = static_cast<Ctx*>(vctx);
+  auto sit = ctx->sends.find(handle);
+  if (sit == ctx->sends.end() || sit->second.cancelled) return -1;
+  return (int64_t)sit->second.written;
 }
 
 int64_t mt_irecv(void* vctx, int src, int tag, void* out, uint64_t cap) {
@@ -924,7 +967,7 @@ uint64_t mt_rx_bytes(void* vctx, int32_t which) {
 // in its peers' rings; 1, placements a full ring refused (the sender waited
 // for the owner); 2, chunks copied out of the own rings; 3, those of them
 // during whose copy the ring's head moved: sender and owner were copying
-// at the same time; 4, payload bytes placed while their op's ready mark was
+// at the same time; 4, payload bytes placed while their op's pieces were
 // short of its length.
 uint64_t mt_ring_counts(void* vctx, int32_t which) {
   auto* ctx = static_cast<Ctx*>(vctx);
@@ -1143,7 +1186,7 @@ void mt_fold_f32(const void* vown, const void* vptrs, int32_t nchildren,
 // generated _bindings.py refuses a stale .so (loud rebuild message)
 // instead of failing with a confusing missing-symbol AttributeError.
 // Keep in sync with MT_API_VERSION in gen_bindings.py.
-int64_t mt_api_version(void) { return 17005; }
+int64_t mt_api_version(void) { return 17006; }
 
 }  // extern "C"
 

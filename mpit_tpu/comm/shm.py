@@ -36,20 +36,24 @@ elsewhere; ``rx``: a chunk lay published and the owner was not copying it
 out: asleep in the back-off, in another ring, in Python, off the core).
 ``wire_totals`` are the endpoint's own sums, acks and headers included.
 
-A send whose bytes become ready while it is on the wire (the optional
-capability of :class:`~mpit_tpu.comm.transport.Transport`): ``isend(...,
-ready=n)`` posts a send of which only the first ``n`` bytes may be read
-yet, ``extend(handle, n)`` moves that mark forward as the caller writes
-the rest, and ``test`` is true only once the whole length has been
-placed.  The peer receives the message it always received (same header,
-same chunks in the same order); a later ``isend`` to the same rank waits
-behind the unfinished one.  A ``tx`` span then also says ``early_bytes``
-(placed while the mark was short of the length) and ``unready_ms``: the
-ring had room, the thread was here and no byte under the mark was left.
-That wait is the caller's own staging, so it is a part of ``away_ms``,
-noted beside it, and never ``blocked_ms``; the receiver sees it as
-``starved_ms``.  ``tx_early_bytes`` in ``ring_counters`` is the sum of
-``early_bytes`` over all sends, counted with obs off too.
+A send that is not yet whole (the optional capability of
+:class:`~mpit_tpu.comm.transport.Transport`, and its one form here): a
+send is a list of pieces placed in order, and ``isend`` posts the list of
+one.  ``isend_pieces(nbytes, dst, tag)`` posts a send of ``nbytes`` with
+no piece yet, ``append(handle, piece)`` adds the next run of its bytes
+where it lies (nothing is copied together first), and ``test`` is true
+only once the whole length has been placed.  ``written(handle)`` says how
+many of the message's bytes are in the ring; the handle keeps every piece
+alive until its last byte is, and lets go of it then.  The peer receives
+the message it always received (same header, same bytes in the same
+order, one message); a later ``isend`` to the same rank waits behind the
+unfinished one.  A ``tx`` span then also says ``early_bytes`` (placed
+while the pieces were short of the length) and ``unready_ms``: the ring
+had room, the thread was here and no appended byte was left.  That wait
+is the caller's own staging, so it is a part of ``away_ms``, noted beside
+it, and never ``blocked_ms``; the receiver sees it as ``starved_ms``.
+``tx_early_bytes`` in ``ring_counters`` is the sum of ``early_bytes`` over
+all sends, counted with obs off too.
 
 Zero-copy discipline: sends pass the numpy buffer's raw pointer to C and
 the Handle holds the array reference until completion.  A receive posted
@@ -76,7 +80,8 @@ from __future__ import annotations
 import atexit
 import functools
 import os
-from typing import Any, Optional
+from collections import deque
+from typing import Any
 
 import numpy as np
 
@@ -151,31 +156,62 @@ class ShmTransport(Transport):
 
     # -- Transport ----------------------------------------------------------
 
-    def isend(self, data: Any, dst: int, tag: int,
-              ready: Optional[int] = None) -> Handle:
-        """``ready``: the bytes of ``data`` that may be read now, where
-        the caller is still writing the rest (see :meth:`extend`); absent,
-        all of them."""
+    def isend(self, data: Any, dst: int, tag: int) -> Handle:
         buf = self._sendable(data)
         nbytes = buf.nbytes if isinstance(buf, np.ndarray) else len(buf)
-        native = self.lib.mt_isend_marked(
-            self._ctx, dst, tag, buf, nbytes,
-            nbytes if ready is None else ready)
+        native = self.lib.mt_isend(self._ctx, dst, tag, buf, nbytes)
+        return self._posted_send(native, dst, tag, nbytes, buf)
+
+    def isend_pieces(self, nbytes: int, dst: int, tag: int) -> Handle:
+        """Post a send of ``nbytes`` of which no piece is there yet (see
+        :meth:`append`).  It holds its place in front of every later send
+        to ``dst`` until its whole length has been appended and placed."""
+        native = self.lib.mt_isend_pieces(self._ctx, dst, tag, nbytes)
+        handle = self._posted_send(native, dst, tag, nbytes, deque())
+        handle.meta["nbytes"] = nbytes
+        handle.meta["appended"] = 0
+        return handle
+
+    def _posted_send(self, native: int, dst: int, tag: int, nbytes: int,
+                     buf: Any) -> Handle:
         if native < 0:
             raise ValueError(f"isend to invalid rank {dst}")
         self._m_tx_msgs[dst].inc()
         self._m_tx_bytes[dst].inc(nbytes)
         return Handle(kind="send", peer=dst, tag=tag, buf=buf, native_id=native)
 
-    def extend(self, handle: Handle, ready: int) -> int:
-        """Move the ready mark of the pending send ``handle`` to
-        ``ready`` bytes: forward only, and to the send's length at most.
-        Returns the mark after the call (-1: the send is no longer
-        pending).  The next ``test`` places what became ready."""
-        if handle.done or handle.cancelled:
-            return -1
-        return int(self.lib.mt_send_extend(self._ctx, handle.native_id,
-                                           ready))
+    def append(self, handle: Handle, piece: np.ndarray) -> None:
+        """The next bytes of the pending send ``handle`` (of
+        :meth:`isend_pieces`) are ``piece``'s: they are read where they
+        lie, so ``piece`` stays unmodified until :meth:`written` has
+        passed its end, and the handle keeps it alive that long.  The
+        next ``test`` places them.  More bytes than the send has left is
+        a ``ValueError`` and appends nothing."""
+        if not piece.flags["C_CONTIGUOUS"]:
+            raise ValueError("a piece must be C-contiguous (zero-copy rule)")
+        end = int(self.lib.mt_send_append(self._ctx, handle.native_id, piece,
+                                          piece.nbytes))
+        if end == -2:
+            raise ValueError(
+                f"append of {piece.nbytes}B passes the send's length "
+                f"({handle.meta['appended']}B of {handle.meta['nbytes']}B "
+                "appended)")
+        if end < 0:
+            raise RuntimeError(f"append to a send that is not pending: {handle}")
+        handle.meta["appended"] = end
+        handle.buf.append((end, piece))
+
+    def written(self, handle: Handle) -> int:
+        """Bytes of the send ``handle`` that are in the peer's ring.  The
+        pieces that end at or before that byte have been read for the
+        last time, and the handle lets go of them here."""
+        if handle.done:
+            return handle.meta["nbytes"]
+        done = int(self.lib.mt_send_written(self._ctx, handle.native_id))
+        held = handle.buf
+        while held and held[0][0] <= done:
+            held.popleft()
+        return done
 
     def irecv(self, src: int, tag: int, out: Any | None = None) -> Handle:
         if out is None:
@@ -274,7 +310,7 @@ class ShmTransport(Transport):
         the owner's drain); chunks it copied out of its own rings and those
         of them during whose copy the sender moved the ring's head (both
         sides were copying at once); payload bytes it placed while their
-        send's ready mark was short of its length."""
+        send's pieces were short of its length."""
         return {key: int(self.lib.mt_ring_counts(self._ctx, which))
                 for which, key in enumerate((
                     "tx_chunks", "tx_ring_full", "rx_chunks",

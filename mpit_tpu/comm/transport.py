@@ -13,14 +13,18 @@ writes into them directly.  A send buffer must stay alive and unmodified
 until ``test`` returns True; handles hold a reference to enforce liveness.
 
 An optional capability, which a transport has if it has the method
-``extend`` (``comm/shm.py`` has; ``tcp`` and ``local`` have not, and
-callers test for it by name): a send whose bytes become ready while it is
-on the wire.  ``isend(data, dst, tag, ready=n)`` posts a send of which
-only the first ``n`` bytes may be read yet; the caller goes on writing the
-rest and says how far it is with ``extend(handle, n)`` (forward only,
-clamped to the length); ``test`` is true once the whole length has left.
-Unmodified then means: the bytes under the mark.  The peer cannot tell
-such a send from any other.
+``append`` (``comm/shm.py`` has; ``tcp`` and ``local`` have not, and
+callers test for it by name): a send that is not yet whole, made of
+pieces.  ``isend_pieces(nbytes, dst, tag)`` posts a send of ``nbytes``
+with none of them there yet; the caller hands over each run of its bytes
+where it lies, in order, with ``append(handle, piece)``, and nothing is
+copied together first; ``written(handle)`` says how many of the bytes the
+transport has read for the last time; ``test`` is true once the whole
+length has left.  Unmodified then means: each piece, until ``written``
+has passed its end.  The peer cannot tell such a send from any other: it
+is one message of the same bytes.  There is no other form of a send that
+is not yet whole (a buffer that fills from the front is a send of its
+slices).
 """
 
 from __future__ import annotations

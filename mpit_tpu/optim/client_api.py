@@ -12,9 +12,12 @@ the client slices per server shard (numpy views = the zero-copy analog of
 
 Two optional extensions: ``sync_device`` (:class:`DeviceSyncAPI`, below)
 and ``stream_shards(staged, landed)``, by which a client tells the sync
-round how the vector is cut and takes its per-shard gate and sink
-(described on :meth:`mpit_tpu.ps.client.ParamClient.stream_shards`; used
-by :mod:`mpit_tpu.optim.sync`, which tests for it by name, because
+round how the vector is cut and takes its per-shard gate and sink, with
+its second half ``stream_pieces(pieces)``, by which it says which shards'
+GRAD sends read the payload's pieces where they land and takes their feed
+(described on :meth:`mpit_tpu.ps.client.ParamClient.stream_shards` and
+:meth:`~mpit_tpu.ps.client.ParamClient.stream_pieces`; used
+by :mod:`mpit_tpu.optim.sync`, which tests for them by name, because
 ``isinstance`` on a protocol does not see through a front that forwards
 with ``__getattr__``).
 """

@@ -8,8 +8,9 @@ that round, recorded as one ``round`` span whose phases tile it
 span also carries what the client's thread did on the wire during
 ``exchange``: ``wire_tx_copy_ms``, ``wire_rx_copy_ms``, ``wire_poll_ms``
 and ``sched_sleep_ms``, and what is left of the phase is the
-interpreter's; and what the stream's thread did while it staged the
-payload, :data:`STAGE_PARTS`: it paces the push):
+interpreter's; what the stream's thread did while it staged the
+payload, :data:`STAGE_PARTS`; and ``direct_bytes``, the payload bytes
+the push read from the pieces and not from the mirror):
 
 ``wait_backward`` → ``d2h`` → ``stage`` → ``exchange`` → ``h2d`` →
 ``telemetry``
@@ -22,13 +23,24 @@ Three resources take part in a round and none needs the others' turn:
 the chip's DMA engine (d2h, h2d), the client's thread (its copies into
 and out of the transport) and the servers.  So the payload leaves the
 device in pieces of :data:`PIECE_BYTES`, in the client's shard order,
-a few in flight at a time, each staged into its slice of ``grad_host``
-by the stream's thread as it lands; shard ``s``'s GRAD op begins once
-the first piece of shard ``s`` is in the mirror and sends no byte beyond
+a few in flight at a time, and the stream's thread takes each as it
+lands.  Where the client says a shard's GRAD payload is the slice itself
+and its transport can send from anywhere (``stream_pieces``: identity
+codec, unframed, unchunked, the shm wire), the piece is *handed to the
+send where the DMA left it*: the client's thread copies it from there
+into the server's ring and ``grad_host`` is not written at all (the
+``round`` span's ``direct_bytes``; a mirror nobody else reads cost the
+thread that paces the push most of its work, PERF.md section 6, PR 45);
+the handle keeps the piece alive until its bytes are in the ring, and the
+stream cuts no further piece while :data:`HELD_BYTES` are handed over and
+not yet there.  Every other shard's piece is copied into its slice of
+``grad_host`` as before.  Shard ``s``'s GRAD op begins once
+the first piece of shard ``s`` is on the host and sends no byte beyond
 those that are (the client asks the *gate*, :meth:`ShardStream.staged`,
-how many bytes of the shard are staged, before it touches the slice and
-again at every poll of its send: a send whose bytes become ready while
-it is on the wire, ``comm/transport.py``), so a push ends about a piece
+how many bytes of the shard are staged, before it touches the slice, and
+a followed shard's *feed*, :meth:`ShardStream.feed`, at every poll of
+its send: a send made of pieces, ``comm/transport.py``), so a push ends
+about a piece
 after its shard's staging does and not a whole push later; where the
 payload is not the slice itself (a codec, the framed or the chunked
 wire) or the transport cannot hold such a send, the client waits for
@@ -104,6 +116,17 @@ PIECE_BYTES = 8 << 20
 #: what the device holds beside the payload, and enough that the DMA
 #: engine never waits for the host (2 is slower, 8 no faster).
 IN_FLIGHT = 4
+#: Bytes handed to the client's sends as pieces and not yet in a server's
+#: ring at which the stream cuts no further piece: what the pieces may
+#: hold of the host's memory beside the :data:`IN_FLIGHT` that are
+#: landing, so that a slow client does not find a whole 2.5 GB vector
+#: staged ahead of it.  Eight rings' worth (``comm/shm.py``, 64 MB), and
+#: not one: the client places up to a ring's worth a pass and says how far
+#: it is only between passes, so at one ring's worth the DMA and the
+#: client's copy took turns and the push gained nothing (OLMoE on a v5e:
+#: 7.0-7.3k tokens/s at 16 and 64 MB as at the parent, 7.9k at 256 MB,
+#: 8.0-8.2k at 512 MB and with no bound: PERF.md section 6, PR 45).
+HELD_BYTES = 512 << 20
 
 
 @jax.jit
@@ -140,8 +163,13 @@ def _exchange(opt: Any, span: Any) -> None:
 
 
 #: What the stream's thread did while it staged a round's payload, noted
-#: on the ``round`` span while recording (ms, summed over the pieces).
-STAGE_PARTS = ("stage_wait_ms", "stage_copy_ms", "stage_issue_ms")
+#: on the ``round`` span while recording (ms, summed over the pieces):
+#: waiting for a piece's DMA, copying it into the mirror (a piece handed
+#: to its send is not copied: about 0 where every shard is followed),
+#: standing at :data:`HELD_BYTES` until the client had placed more, and
+#: freeing the piece and cutting the next.
+STAGE_PARTS = ("stage_wait_ms", "stage_copy_ms", "stage_held_ms",
+               "stage_issue_ms")
 
 
 def _no_clock() -> float:
@@ -157,12 +185,14 @@ class _Whole(NamedTuple):
 
 class _Copies:
     """The copies of one round, run off the client's thread (on the
-    stream's): every piece of the payload to its place in ``grad_host``
-    (a shard's pieces in order; ``staged[shard]`` says how many of its
-    bytes are whole there and moves only after a piece's copy has
-    returned), then each shard of ``w_host`` back to the device as it
-    is sunk.  The waits
-    for the DMA engine and the host copies release the interpreter
+    stream's): every piece of the payload to the host, a shard's pieces
+    in order, and there either into the hands of the shard's send
+    (``handed[shard]``, a followed shard: :meth:`feed` gives them to the
+    client's thread) or to its place in ``grad_host``; ``staged[shard]``
+    says how many of the shard's bytes are whole on the host either way
+    and moves only after a piece's hand-over or copy has returned.  Then
+    each shard of ``w_host`` back to the device as it is sunk.  The
+    waits for the DMA engine and the host copies release the interpreter
     lock, so the client's thread keeps pumping beside them."""
 
     def __init__(self, stream: "ShardStream", payload: jnp.ndarray,
@@ -171,12 +201,18 @@ class _Copies:
         self.payload = payload
         self.consume = consume
         # Where the staging went (zeros, and no clock read, unless
-        # recording): waiting for a piece's DMA, copying it into the
-        # mirror, and freeing it and cutting the next.  It paces the push.
+        # recording): :data:`STAGE_PARTS`.  It paces the push.
         self.now = time.monotonic if timed else _no_clock
         self.spent = dict.fromkeys(STAGE_PARTS, 0.0)
         self.first_piece = threading.Event()
-        self.staged = [0] * len(stream.cut)  # bytes whole in the mirror
+        self.staged = [0] * len(stream.cut)  # bytes whole on the host
+        # The followed shards' pieces: landed and not yet taken by the
+        # client, and the bytes the client says are in the ring (the
+        # client thread's to write).
+        self.handed: List[deque] = [deque() for _ in stream.cut]
+        self.wrote = [0] * len(stream.cut)
+        self.direct_bytes = 0  # handed over, all shards
+        self.room = threading.Event()  # ``wrote`` moved, or the round quit
         self.whole = threading.Event()  # all of them, or the thread ended
         self.landed: "queue.SimpleQueue[Optional[int]]" = queue.SimpleQueue()
         self.sunk: Set[int] = set()  # shards handed to ``landed``
@@ -208,14 +244,52 @@ class _Copies:
                 self.failure.__cause__ = self.error
             raise self.failure
 
+    def stop(self) -> None:
+        """No copy after a failed exchange, and no wait for room."""
+        self.quit = True
+        self.room.set()
+
     def sink(self, shard: int) -> None:
         self.sunk.add(shard)
         self.landed.put(shard)
+
+    def feed(self, shard: int, written: int) -> List[np.ndarray]:
+        """On the client's thread, at every poll of followed shard
+        ``shard``'s send: ``written`` of its bytes are in the ring; the
+        pieces that landed since the last call, in order.  With none to
+        give and the shard short, raises what stopped the copies, if
+        anything did: the rest will never come."""
+        if written > self.wrote[shard]:
+            self.wrote[shard] = written
+            self.room.set()
+        landed = self.handed[shard]
+        pieces = []
+        while landed:
+            pieces.append(landed.popleft())
+        if not pieces and self.staged[shard] < self.stream.nbytes[shard]:
+            self.check()
+        return pieces
+
+    def _no_room(self) -> bool:
+        return (self.direct_bytes - sum(self.wrote) > HELD_BYTES
+                and not self.quit)
+
+    def _wait_for_room(self) -> None:
+        """Stand while more than :data:`HELD_BYTES` are handed over and
+        not yet in a ring (and the round has not quit)."""
+        while self._no_room():
+            self.room.clear()
+            if self._no_room():  # still, now that a ``set`` cannot be lost
+                self.room.wait()
 
     def _stage(self) -> None:
         stream, payload = self.stream, self.payload
         todo = iter(zip(stream.pieces, stream.starts))
         flight: deque = deque()
+        # On the CPU backend the host array is a view of the device
+        # piece's buffer: a piece that is handed over goes when the send
+        # lets go of the view, not here.
+        aliased = jax.default_backend() == "cpu"
 
         def issue() -> None:
             piece, start = next(todo, (None, None))
@@ -234,16 +308,26 @@ class _Copies:
             host = np.asarray(part)
             self.first_piece.set()
             t_host = now()
-            np.copyto(stream.grad_host[lo:hi], host)
+            if stream.follow[shard]:
+                self.direct_bytes += host.nbytes
+                self.handed[shard].append(host)
+            else:
+                np.copyto(stream.grad_host[lo:hi], host)
             self.staged[shard] = (
                 hi - stream.cut[shard].offset) * stream.grad_host.itemsize
             t_staged = now()
-            del host  # on the CPU backend a view of the buffer freed next
-            part.delete()
+            if not (aliased and stream.follow[shard]):
+                del host  # on the CPU backend a view of the buffer freed next
+                part.delete()
+            t_freed = now()
+            self._wait_for_room()
+            t_room = now()
             issue()
             spent["stage_wait_ms"] += (t_host - t_pop) * 1e3
             spent["stage_copy_ms"] += (t_staged - t_host) * 1e3
-            spent["stage_issue_ms"] += (now() - t_staged) * 1e3
+            spent["stage_held_ms"] += (t_room - t_freed) * 1e3
+            spent["stage_issue_ms"] += (
+                t_freed - t_staged + now() - t_room) * 1e3
         if not flight:  # every cut has run
             self.whole.set()
             if self.consume:
@@ -276,8 +360,8 @@ def _serve(rounds: "queue.SimpleQueue[Optional[_Copies]]") -> None:
 
 
 class ShardStream:
-    """A shell's side of the round: the cut it moves by, the gate and
-    the sink it offers the client once (:func:`attach`), and the one
+    """A shell's side of the round: the cut it moves by, the gate, the
+    sink and the feed it offers the client once (:func:`attach`), and the one
     thread that makes every round's copies, from the first round until
     :meth:`close` (a thread a round would leave each round's host
     pieces behind in an allocator arena of its own).  Between rounds the
@@ -290,6 +374,8 @@ class ShardStream:
     def __init__(self, grad_host: np.ndarray, w_host: np.ndarray):
         self.grad_host, self.w_host = grad_host, w_host
         self.cut: List[Any] = []
+        self.follow: List[bool] = []  # by shard: its send reads the pieces
+        self.nbytes: List[int] = []  # by shard
         self.parts: List[List[Tuple[int, int]]] = []  # (lo, hi), by shard
         self.pieces: List[Tuple[int, int, int]] = []  # (shard, lo, hi), all
         #: every piece's ``lo`` on the device: a cut dispatched with a
@@ -308,10 +394,16 @@ class ShardStream:
         self._serve = partial(_serve, self._rounds)  # the thread's target
         weakref.finalize(self, self._rounds.put, None)
 
-    def bind(self, cut: List[Any]) -> None:
-        """Take the cut; a piece never crosses a shard."""
-        step = max(PIECE_BYTES // self.grad_host.dtype.itemsize, 1)
+    def bind(self, cut: List[Any],
+             follow: Optional[List[bool]] = None) -> None:
+        """Take the cut; a piece never crosses a shard.  ``follow``:
+        which shards' sends read the pieces where they land (none, where
+        the client does not say)."""
+        itemsize = self.grad_host.dtype.itemsize
+        step = max(PIECE_BYTES // itemsize, 1)
         self.cut = list(cut)
+        self.follow = list(follow) if follow else [False] * len(cut)
+        self.nbytes = [(shard.end - shard.offset) * itemsize for shard in cut]
         self._index = {shard.offset: i for i, shard in enumerate(cut)}
         self.parts = [
             [(lo, min(lo + step, shard.end))
@@ -326,18 +418,31 @@ class ShardStream:
     # -- the hooks (on the client's thread; neither blocks) ------------------
 
     def staged(self, shard: Any) -> int:
-        """The bytes of ``shard``'s slice of ``grad_host`` that are
-        whole, from its front; all of them between rounds.  Short of
-        all, raises what stopped the copies, if anything did: the rest
-        will never come, and nothing half staged is taken for whole."""
-        whole = (shard.end - shard.offset) * self.grad_host.itemsize
+        """The bytes of ``shard`` that are whole on the host, from its
+        front: in its slice of ``grad_host``, or in the pieces of
+        :meth:`feed` where its send reads those; all of them, in the
+        slice, between rounds.  Short of all, raises what stopped the
+        copies, if anything did: the rest will never come, and nothing
+        half staged is taken for whole."""
+        index = self._index[shard.offset]
         worker = self._worker
         if worker is None:
-            return whole
-        staged = worker.staged[self._index[shard.offset]]
-        if staged < whole:
+            return self.nbytes[index]
+        staged = worker.staged[index]
+        if staged < self.nbytes[index]:
             worker.check()
         return staged
+
+    def feed(self, shard: Any) -> Optional[Any]:
+        """This round's feed of ``shard`` (``feed(written) -> pieces``,
+        :meth:`_Copies.feed`), where its send reads the pieces; None
+        where the shard is staged into ``grad_host``, and between
+        rounds, when the slice is whole and is the payload."""
+        index = self._index[shard.offset]
+        worker = self._worker
+        if worker is None or not self.follow[index]:
+            return None
+        return partial(worker.feed, index)
 
     def landed(self, shard: Any) -> None:
         worker = self._worker
@@ -377,14 +482,15 @@ class ShardStream:
                 if shard not in worker.sunk:
                     worker.sink(shard)
         except BaseException:
-            worker.quit = True  # no copy after a failed exchange
+            worker.stop()  # no copy after a failed exchange
             raise
         finally:
             worker.landed.put(None)
             worker.done.wait()
             self._worker = None
         worker.check()
-        span.note(**worker.spent)  # the null span's, with obs off
+        # (the null span's, with obs off)
+        span.note(direct_bytes=worker.direct_bytes, **worker.spent)
         return worker.w
 
 
@@ -392,7 +498,9 @@ def attach(opt: Any) -> None:
     """Called by a shell's ``start`` once its client has started: bind
     the round's stream, ``opt._stream``, to the client's cut and give
     the client the gate and the sink, if it takes them
-    (``stream_shards``); if not, to one shard with no hook on it."""
+    (``stream_shards``), and then the feed (``stream_pieces``: the client
+    says which shards' sends read the pieces); if not, to one shard with
+    no hook on it."""
     opt.rounds_streamed = 0  # rounds in which two or more shards streamed
     stream = opt._stream = ShardStream(opt.grad_host, opt.w_host)
     # Tested by name: ``isinstance`` on a protocol looks past
@@ -401,7 +509,9 @@ def attach(opt: Any) -> None:
     install = getattr(opt.pc, "stream_shards", None)
     cut = install(stream.staged, stream.landed) if install else None
     stream.gated = bool(cut)
-    stream.bind(cut or [_Whole(0, opt.grad_host.size)])
+    follow = getattr(opt.pc, "stream_pieces", None) if cut else None
+    stream.bind(cut or [_Whole(0, opt.grad_host.size)],
+                follow(stream.feed) if follow else None)
     stream.m_streamed = get_registry().counter(
         "mpit_round_streamed_total", rank=getattr(opt.pc, "rank", None))
     # What the client's one thread does on the wire during ``exchange``

@@ -307,6 +307,9 @@ class ParamClient:
         # shell installed them.
         self._staged: Optional[Callable[[Shard], int]] = None
         self._landed: Optional[Callable[[Shard], None]] = None
+        # Where a followed shard's GRAD send gets its pieces
+        # (stream_pieces): None unless a shell installed it.
+        self._pieces: Optional[Callable[[Shard], Optional[Callable]]] = None
 
     # -- lifecycle ----------------------------------------------------------
 
@@ -955,20 +958,24 @@ class ParamClient:
         [epoch, seq] and retries the staged bytes on deadline.  Gated
         (:meth:`stream_shards`): nothing here reads a byte of the slice
         before the shell has staged it, and the wait lies before the
-        span.  Where the payload is the slice itself (identity codec,
-        unframed, unchunked) and the transport can hold a send that is
-        not yet whole, the wait is for the shard's first staged byte and
-        the send follows the staging (``aio_send(ready=...)``); everywhere
-        else something reads the whole slice first, so the wait is for
-        the whole shard."""
+        span.  Where the payload is the slice itself (:meth:`_follows`)
+        the wait is for the shard's first staged byte and the send is
+        made of the shell's pieces, read where the d2h left them, as they
+        land (``aio_send(pieces=...)``): the slice is not read at all;
+        everywhere else something reads the whole slice first, so the
+        wait is for the whole shard."""
         wire = self._grad_wire.get(srank)
-        follow = (self._staged is not None and wire is None
-                  and not self.ft.framed and not self._chunked
-                  and hasattr(self.transport, "extend"))
+        feed = None
         gated_ms = None
         if self._staged is not None:
-            gated_ms = yield from self._gate(
-                shard, 1 if follow else shard.size * self.grad.itemsize)
+            whole = shard.size * self.grad.itemsize
+            follow = self._pieces is not None and self._follows(srank)
+            gated_ms = yield from self._gate(shard, 1 if follow else whole)
+            feed = self._pieces(shard) if follow else None
+            if follow and feed is None:
+                # No feed (between rounds): the slice is the payload, and
+                # nothing of it leaves before it is whole.
+                yield from self._gate(shard, whole)
         if self._chunked:
             yield from self._chunked_write(srank, shard, tags.GRAD,
                                            tags.GRAD_ACK, "GRAD", gated_ms)
@@ -984,9 +991,9 @@ class ParamClient:
         if not self.ft.framed:
             span.mark("send")
             yield from aio_send(
-                self.transport, payload, srank, tags.GRAD, live=self.live,
-                deadline=self._op_deadline(),
-                ready=(lambda: self._staged(shard)) if follow else None)
+                self.transport, payload if feed is None else payload.nbytes,
+                srank, tags.GRAD, live=self.live,
+                deadline=self._op_deadline(), pieces=feed)
             span.mark("ack")
             yield from aio_recv(self.transport, srank, tags.GRAD_ACK,
                                 live=self.live, deadline=self._op_deadline())
@@ -1512,9 +1519,9 @@ class ParamClient:
         then on a GRAD op asks ``staged(shard)``, how many bytes of its
         slice of ``grad`` are staged from the front, before it touches
         the slice, yielding to the other channels until the answer is
-        enough: the whole slice, or its first byte where the send can
-        follow the staging (:meth:`_send_grad`), which then asks again
-        at every poll and reads no byte beyond the answer.  A PARAM op
+        enough: the whole slice, or its first byte where the send reads
+        the shell's pieces as they land (:meth:`stream_pieces`,
+        :meth:`_send_grad`) and never the slice.  A PARAM op
         calls ``landed(shard)`` once its slice of ``param`` is whole.
         Both run on this client's thread and must not block.  The wire
         does not change: each server still
@@ -1527,6 +1534,36 @@ class ParamClient:
             return None
         self._staged, self._landed = staged, landed
         return list(self.shards)
+
+    def stream_pieces(
+        self,
+        pieces: Callable[[Shard], Optional[Callable[[int], List[np.ndarray]]]],
+    ) -> List[bool]:
+        """The second half of the extension, for a client that gave its
+        cut (:meth:`stream_shards`): say which shards' GRAD sends read the
+        shell's pieces where they lie, one answer a shard of the cut, and
+        take the hook that hands them over.  Such a shard need not be
+        staged into ``grad`` at all: its op waits for its first staged
+        byte, asks ``pieces(shard)`` for this round's feed and sends what
+        the feed gives, piece by piece (``aio_send(pieces=...)``: the feed
+        is told how many bytes are in the ring and returns the arrays that
+        landed since, on this client's thread; it must not block).
+        Between rounds ``pieces(shard)`` is None and the op sends its
+        slice of ``grad`` whole.  A shard is followed where the payload is
+        the slice itself and the transport can hold a send made of pieces
+        (:meth:`_follows`); every other shard answers False and is staged
+        into ``grad`` and gated whole, as before."""
+        self._pieces = pieces
+        return [self._follows(srank) for srank in self.sranks]
+
+    def _follows(self, srank: int) -> bool:
+        """Whether server ``srank``'s GRAD payload is its slice of
+        ``grad`` byte for byte and may therefore be sent from anywhere
+        those bytes lie: identity codec (a codec encodes from the slice),
+        unframed (the framed wire stamps and retries the staged bytes),
+        unchunked, and a transport that can hold a send made of pieces."""
+        return (self._grad_wire.get(srank) is None and not self.ft.framed
+                and not self._chunked and hasattr(self.transport, "append"))
 
     def _gate(self, shard: Shard, nbytes: int):
         """Yield until the shell has staged ``nbytes`` of ``shard``;

@@ -1,19 +1,23 @@
-"""A send whose bytes become ready while it is on the wire, and the
-round that uses it (``comm/native/transport.cpp`` ``mt_isend_marked`` /
-``mt_send_extend``, ``comm/shm.py`` ``isend(ready=)`` / ``extend``,
-``aio_send(ready=)``, ``ps/client.py`` ``_send_grad``, ``optim/sync.py``).
+"""A send that is not yet whole, made of pieces, and the round that uses
+it (``comm/native/transport.cpp`` ``mt_isend_pieces`` / ``mt_send_append``
+/ ``mt_send_written``, ``comm/shm.py`` ``isend_pieces`` / ``append`` /
+``written``, ``aio_send(pieces=)``, ``ps/client.py`` ``_send_grad``,
+``optim/sync.py``).
 
-The wire, native, two endpoints in one process: a marked send delivers
-no byte beyond its mark, is done only at its length, gives the receiver
-the bytes an unmarked send gives, keeps its place in front of a later
-send, can be cancelled half-ready, and counts what it placed early.
+The wire, native, two endpoints in one process: a send delivers no byte
+beyond its last appended one, is done only at its length, gives the
+receiver the bytes a whole send gives (one message, whatever the pieces:
+across a chunk's edge, smaller than a chunk, a buffer's own slices), keeps
+its place in front of a later send, can be cancelled half-made, counts
+what it placed early, and holds a piece until its bytes are in the ring
+and not after.
 
-The round, two servers on threads over shm: with the early gate every
-server receives the messages it receives with the whole-shard gate, byte
-for byte and in the same order; a staging that dies after its first
-piece fails the round, completes no GRAD and leaves the next round
-sound; and every client whose payload is not the slice itself keeps the
-whole-shard gate.
+The round, two servers on threads over shm: with the pieces handed to
+the sends every server receives the messages it receives with the mirror
+and the whole-shard gate, byte for byte and in the same order; a staging
+that dies after its first piece fails the round, completes no GRAD and
+leaves the next round sound; and every client whose payload is not the
+slice itself keeps the mirror and the whole-shard gate.
 """
 
 import contextlib
@@ -89,6 +93,23 @@ def settle(*wires, passes=50):
             wire.iprobe(wire.rank, 999)
 
 
+def marked(wire, data, dst, tag, ready):
+    """A send of ``data`` of which the first ``ready`` bytes are there:
+    the buffer that fills from the front, as a send of its slices."""
+    handle = wire.isend_pieces(data.nbytes, dst, tag)
+    handle.meta["of"] = data
+    extend(wire, handle, ready)
+    return handle
+
+
+def extend(wire, handle, ready):
+    """The bytes of the buffer up to ``ready`` are there now."""
+    data, at = handle.meta["of"], handle.meta["appended"]
+    if ready > at:
+        wire.append(handle, data[at:ready])
+    return handle.meta["appended"]
+
+
 @contextlib.contextmanager
 def pair(name):
     ns = f"t_ep_{name}_{os.getpid()}"
@@ -113,13 +134,13 @@ def test_a_marked_send_delivers_up_to_its_mark_and_is_done_at_its_length(mark):
     with pair(f"mark{ready}") as (a, b):
         out = np.full(BIG, SENTINEL, np.uint8)
         hr = b.irecv(0, 4, out=out)
-        hs = a.isend(data, 1, 4, ready=ready)
+        hs = marked(a, data, 1, 4, ready)
         settle(a, b)
         assert not a.test(hs) and not b.test(hr)
         np.testing.assert_array_equal(out[:ready], data[:ready])
         assert (out[ready:] == SENTINEL).all()  # no byte beyond the mark
         assert a.ring_counters()["tx_early_bytes"] == ready
-        assert a.extend(hs, BIG) == BIG
+        assert extend(a, hs, BIG) == BIG
         spin(lambda: a.test(hs), lambda: b.test(hr))
         np.testing.assert_array_equal(out, data)
         assert b.rx_path_bytes() == {"rx_direct_bytes": BIG,
@@ -145,11 +166,11 @@ def test_a_mark_that_moves_piece_by_piece_gives_the_same_bytes():
     with pair("steps") as (a, b):
         out = np.full(BIG, SENTINEL, np.uint8)
         hr = b.irecv(0, 4, out=out)
-        hs = a.isend(staging, 1, 4, ready=0)
+        hs = marked(a, staging, 1, 4, 0)
         for hi in range(step, BIG + step, step):
             hi = min(hi, BIG)
             staging[:hi] = data[:hi]
-            a.extend(hs, hi)
+            extend(a, hs, hi)
             settle(a, b, passes=4)
             np.testing.assert_array_equal(out[:hi], data[:hi])
             assert (out[hi:] == SENTINEL).all()
@@ -159,35 +180,41 @@ def test_a_mark_that_moves_piece_by_piece_gives_the_same_bytes():
         assert BIG - step <= early < BIG  # all but the last step's bytes
 
 
-def test_extend_moves_forward_only_and_no_further_than_the_length():
+def test_append_goes_no_further_than_the_length_and_only_while_pending():
     data = noise(3, BIG)
     with pair("mono") as (a, b):
-        hs = a.isend(data, 1, 4, ready=500)
-        assert a.extend(hs, 100) == 500       # never back
-        assert a.extend(hs, 700) == 700
-        assert a.extend(hs, 10**12) == BIG    # clamped
-        assert a.extend(hs, 0) == BIG
+        hs = marked(a, data, 1, 4, 500)
+        assert extend(a, hs, 700) == 700
+        with pytest.raises(ValueError, match="passes the send's length"):
+            a.append(hs, noise(0, BIG))   # 700 + BIG: refused whole
+        assert hs.meta["appended"] == 700 and a.written(hs) <= 700
+        assert extend(a, hs, BIG) == BIG
+        with pytest.raises(ValueError, match="passes the send's length"):
+            a.append(hs, data[:1])
         out = np.zeros_like(data)
         hr = b.irecv(0, 4, out=out)
         spin(lambda: a.test(hs), lambda: b.test(hr))
         np.testing.assert_array_equal(out, data)
-        assert a.extend(hs, BIG) == -1        # done: nothing to move
-        # a plain send has its mark at its length from the start
-        hs = a.isend(data, 1, 4)
-        assert a.extend(hs, 5) == BIG
+        assert a.written(hs) == BIG and hs.buf is None  # done: all let go
+        with pytest.raises(RuntimeError, match="not pending"):
+            a.append(hs, data[:1])
+        hs = marked(a, data, 1, 4, 5)
         a.cancel(hs)
-        assert a.extend(hs, BIG) == -1
+        with pytest.raises(RuntimeError, match="not pending"):
+            a.append(hs, data[5:6])
+        with pytest.raises(ValueError, match="C-contiguous"):
+            a.append(marked(a, data, 1, 4, 0), data[::2])
 
 
 def test_a_later_send_to_the_same_rank_waits_behind_the_marked_one():
     first, second = noise(4, BIG), noise(5, 1000)
     with pair("fifo") as (a, b):
-        h1 = a.isend(first, 1, 4, ready=CHUNK // 2)
+        h1 = marked(a, first, 1, 4, CHUNK // 2)
         h2 = a.isend(second, 1, 6)  # another tag, the same destination
         settle(a, b)
         assert not a.test(h1) and not a.test(h2)
         assert not b.iprobe(0, 6)  # it has not overtaken
-        a.extend(h1, BIG)
+        extend(a, h1, BIG)
         spin(lambda: a.test(h1), lambda: a.test(h2),
              lambda: b.iprobe(0, 4) and b.iprobe(0, 6))
         np.testing.assert_array_equal(
@@ -201,12 +228,12 @@ def test_cancel_of_a_half_ready_send_leaves_the_next_message_whole():
     with pair("torn") as (a, b):
         out = np.full(BIG, SENTINEL, np.uint8)
         hr = b.irecv(0, 4, out=out)
-        hs = a.isend(torn, 1, 4, ready=CHUNK + 17)
+        hs = marked(a, torn, 1, 4, CHUNK + 17)
         settle(a, b)
         np.testing.assert_array_equal(out[:CHUNK + 17], torn[:CHUNK + 17])
         assert not b.test(hr) and not a.test(hs)
         a.cancel(hs)
-        assert a.extend(hs, BIG) == -1
+        assert hs.buf is None  # the pieces are the caller's again
         settle(a, b)
         assert not b.test(hr)  # the torn message is never taken for whole
         hs = a.isend(retry, 1, 4)
@@ -221,30 +248,186 @@ def test_the_tx_span_says_what_was_early_and_how_long_it_was_unready(obs_on):
     with pair("span") as (a, b):
         out = np.zeros_like(data)
         hr = b.irecv(0, 4, out=out)
-        hs = a.isend(data, 1, 4, ready=CHUNK)
+        hs = marked(a, data, 1, 4, CHUNK)
         settle(a, b)
-        time.sleep(0.05)  # at its mark, the ring empty, the thread here
+        time.sleep(0.05)  # at its last byte, the ring empty, the thread here
         settle(a, b)
-        a.extend(hs, nbytes)
+        extend(a, hs, nbytes)
         spin(lambda: a.test(hs), lambda: b.test(hr))
         # an unmarked one beside it
         hr = b.irecv(0, 4, out=out)
         hs = a.isend(data, 1, 4)
         spin(lambda: a.test(hs), lambda: b.test(hr))
-    marked, plain = [s for s in obs_on.spans
-                     if getattr(s, "cat", "wire") == "wire" and s.name == "tx"
-                     and "early_bytes" in s.args]
-    assert marked.args["early_bytes"] == CHUNK
-    assert marked.args["unready_ms"] >= 50.0
+    early, plain = [s for s in obs_on.spans
+                    if getattr(s, "cat", "wire") == "wire" and s.name == "tx"
+                    and "early_bytes" in s.args]
+    assert early.args["early_bytes"] == CHUNK
+    assert early.args["unready_ms"] >= 50.0
     # a part of the time away, never of the time blocked
-    assert marked.args["unready_ms"] <= marked.args["away_ms"]
-    assert marked.args["blocked_ms"] < 50.0
-    parts = sum(marked.args[k] for k in ("copy_ms", "blocked_ms", "away_ms"))
-    assert parts == pytest.approx(marked.args["flight_ms"], rel=1e-9)
+    assert early.args["unready_ms"] <= early.args["away_ms"]
+    assert early.args["blocked_ms"] < 50.0
+    parts = sum(early.args[k] for k in ("copy_ms", "blocked_ms", "away_ms"))
+    assert parts == pytest.approx(early.args["flight_ms"], rel=1e-9)
     assert plain.args["early_bytes"] == 0 and plain.args["unready_ms"] == 0.0
     # the receiver sees the same wait as starvation
     (rx, _rx2) = [s for s in obs_on.spans if s.name == "rx"]
     assert rx.args["starved_ms"] >= 50.0
+
+
+# -- a send made of pieces that lie apart --------------------------------------
+
+PIECES = {
+    # a chunk's edge falls inside the second piece, and the third's too
+    "across_a_chunks_edge": [CHUNK - 1000, CHUNK + 700, BIG - 2 * CHUNK + 300],
+    # a chunk is cut across a dozen pieces, down to one of a single byte
+    "smaller_than_a_chunk": [70_001] * (BIG // 70_001) + [1, BIG % 70_001 - 1],
+    "one_piece": [BIG],
+}
+
+
+def apart(seed, sizes):
+    """Pieces of ``sizes`` bytes, each an array of its own."""
+    assert sum(sizes) == BIG
+    return [noise(seed + i, n) for i, n in enumerate(sizes)]
+
+
+@pytest.mark.parametrize("case", sorted(PIECES))
+@pytest.mark.parametrize("pace", ["all_at_once", "one_a_pass"])
+def test_appended_pieces_arrive_as_one_message_bit_for_bit(case, pace):
+    pieces = apart(10, PIECES[case])
+    want = np.concatenate(pieces)
+    with pair(f"pcs_{case}_{pace}") as (a, b):
+        out = np.full(BIG, SENTINEL, np.uint8)
+        hr = b.irecv(0, 4, out=out)
+        hs = a.isend_pieces(BIG, 1, 4)
+        sent = 0
+        for piece in pieces:
+            a.append(hs, piece)
+            sent += piece.nbytes
+            if pace == "one_a_pass":
+                settle(a, b, passes=3)
+                np.testing.assert_array_equal(out[:sent], want[:sent])
+                assert (out[sent:] == SENTINEL).all()
+                assert a.written(hs) == sent or a.test(hs)
+        spin(lambda: a.test(hs), lambda: b.test(hr))
+        np.testing.assert_array_equal(out, want)
+        # one message, landed where it was asked for
+        assert b.rx_path_bytes() == {"rx_direct_bytes": BIG,
+                                     "rx_assembled_bytes": 0}
+        if pace == "all_at_once":
+            # the chunks are cut across the pieces: three whole and a tail
+            assert a.ring_counters()["tx_chunks"] == 4
+        # a whole send of the same bytes gives the receiver the same
+        again = np.full(BIG, SENTINEL, np.uint8)
+        hr = b.irecv(0, 4, out=again)
+        hs = a.isend(want, 1, 4)
+        spin(lambda: a.test(hs), lambda: b.test(hr))
+        np.testing.assert_array_equal(again, out)
+
+
+def test_a_send_with_nothing_appended_holds_its_queue():
+    pieces, second = apart(20, PIECES["across_a_chunks_edge"]), noise(5, 1000)
+    with pair("empty") as (a, b):
+        h1 = a.isend_pieces(BIG, 1, 4)
+        h2 = a.isend(second, 1, 6)  # whole, another tag, the same peer
+        settle(a, b)
+        assert not a.test(h1) and not a.test(h2)
+        assert a.written(h1) == 0
+        assert not b.iprobe(0, 6) and not b.iprobe(0, 4)
+        assert a.ring_counters()["tx_chunks"] == 0  # not a byte, not a header
+        for piece in pieces[:-1]:
+            a.append(h1, piece)
+        settle(a, b)
+        assert not a.test(h1) and not a.test(h2) and not b.iprobe(0, 6)
+        a.append(h1, pieces[-1])
+        spin(lambda: a.test(h1), lambda: a.test(h2),
+             lambda: b.iprobe(0, 4) and b.iprobe(0, 6))
+        np.testing.assert_array_equal(
+            np.frombuffer(b.recv(0, 4), np.uint8), np.concatenate(pieces))
+        np.testing.assert_array_equal(
+            np.frombuffer(b.recv(0, 6), np.uint8), second)
+
+
+def test_a_piece_is_held_until_its_bytes_are_in_the_ring_and_not_after():
+    """The ring takes a megabyte and nobody drains it: the first piece is
+    in it whole, the second in part, the third not at all."""
+    third = 600_000
+    pieces = [noise(30 + i, third) for i in range(3)]
+    want = np.concatenate(pieces)
+    with pair("held") as (a, b):
+        hs = a.isend_pieces(3 * third, 1, 4)
+        for piece in pieces:
+            a.append(hs, piece)
+        assert len(hs.buf) == 3 and all(
+            p is q for (_end, p), q in zip(hs.buf, pieces))
+        for _ in range(20):
+            assert not a.test(hs)
+        written = a.written(hs)
+        assert third <= written < 2 * third
+        held = [p for _end, p in hs.buf]
+        assert len(held) == 2 and held[0] is pieces[1] and \
+            held[1] is pieces[2]
+        # the first piece is the caller's again: what it writes there now
+        # changes nothing the peer receives
+        pieces[0][:] = SENTINEL
+        out = np.zeros(3 * third, np.uint8)
+        hr = b.irecv(0, 4, out=out)
+        spin(lambda: a.test(hs), lambda: b.test(hr))
+        np.testing.assert_array_equal(out, want)
+        assert hs.buf is None and a.written(hs) == 3 * third
+
+
+def test_aio_send_tells_the_feed_what_is_written_and_sends_what_it_gives():
+    from mpit_tpu.aio import Scheduler, aio_send
+
+    pieces = apart(40, PIECES["smaller_than_a_chunk"])
+    want = np.concatenate(pieces)
+    todo, told = list(pieces), []
+
+    def feed(written):
+        told.append(written)
+        return [todo.pop(0)] if todo else []
+
+    with pair("aio") as (a, b):
+        out = np.zeros(BIG, np.uint8)
+        hr = b.irecv(0, 4, out=out)
+        sched = Scheduler()
+        sched.spawn(aio_send(a, BIG, 1, 4, pieces=feed), name="send")
+        while sched.queue:
+            sched.ping_pass(0.0)
+            b.test(hr)
+        assert sched.errors == []
+        spin(lambda: b.test(hr))
+    np.testing.assert_array_equal(out, want)
+    assert told == sorted(told) and told[0] == 0 and told[-1] == BIG
+
+
+def test_a_feed_that_raises_cancels_the_send_part_way():
+    from mpit_tpu.aio import Scheduler, aio_send
+
+    pieces = apart(50, PIECES["across_a_chunks_edge"])
+    todo = list(pieces[:1])
+
+    def feed(_written):
+        if not todo:
+            raise OSError("the d2h broke")
+        return [todo.pop(0)]
+
+    with pair("aio_err") as (a, b):
+        out = np.full(BIG, SENTINEL, np.uint8)
+        hr = b.irecv(0, 4, out=out)
+        sched = Scheduler()
+        sched.spawn(aio_send(a, BIG, 1, 4, pieces=feed), name="send")
+        while sched.queue:
+            sched.ping_pass(0.0)
+        (err,) = sched.errors
+        assert isinstance(err.cause, OSError)
+        settle(a, b)
+        assert not b.test(hr)  # never taken for whole
+        retry = noise(51, BIG)
+        hs = a.isend(retry, 1, 4)
+        spin(lambda: a.test(hs), lambda: b.test(hr))
+        np.testing.assert_array_equal(out, retry)
 
 
 # -- the round ----------------------------------------------------------------
@@ -327,6 +510,16 @@ class Plain(Transport):
         return self.inner.payload(handle)
 
 
+class Withheld:
+    """A client's ``ParamClientAPI`` and nothing more: it takes no gate."""
+
+    def __init__(self, inner):
+        self.rank = inner.rank
+        for name in ("start", "reset", "async_send_grad", "async_recv_param",
+                     "async_send_param", "ping", "wait", "stop"):
+            setattr(self, name, getattr(inner, name))
+
+
 @contextlib.contextmanager
 def shm_gang(name, rule="adam", codec=None, ft=None, plain=False):
     """Servers 0 and 1 on threads, the client (rank 2) driven by the
@@ -354,17 +547,21 @@ def shm_gang(name, rule="adam", codec=None, ft=None, plain=False):
             wire.close()
 
 
-def train(name, rounds, **gang_kw):
+def train(name, rounds, withheld=False, mirrors=None, **gang_kw):
     """``rounds`` rounds; the final parameters, what each server
-    received, each server's shard and the client's early bytes."""
+    received, each server's shard and the client's early bytes.
+    ``mirrors``: a list that takes the gradient mirror as each round
+    left it."""
     with shm_gang(name, **gang_kw) as (servers, pc, wire):
-        opt = RuleShell(quad, pc, su=1)
+        opt = RuleShell(quad, Withheld(pc) if withheld else pc, su=1)
         w = opt.start(jnp.zeros(SIZE) + 0.25)
         for _ in range(rounds):
             w, _loss = opt.step(w, TARGET)
+            if mirrors is not None:
+                mirrors.append(opt.grad_host.copy())
         out = np.array(w)
         opt.stop()
-        assert opt.rounds_streamed == rounds
+        assert opt.rounds_streamed == (0 if withheld else rounds)
         # (a STOP is taken whenever its server gets to it: not compared)
         tapes = [[m for m in s.transport.tape if m[1] != tags.STOP]
                  for s in servers]
@@ -415,7 +612,13 @@ def test_a_stager_that_dies_after_its_first_piece_fails_the_round_only(
                 raise OSError("the d2h broke")
             return slow_cut(x, start, size=size)
 
+        def feed(shard):
+            gate(shard)
+            return stream.feed(shard)
+
+        assert stream.follow == [True, True]  # the sends read the pieces
         pc.stream_shards(gate, stream.landed)
+        pc.stream_pieces(feed)
         monkeypatch.setattr(sync, "_cut", broken)
         with pytest.raises(TaskError) as err:
             opt.step(w, TARGET)
@@ -468,6 +671,123 @@ def test_a_payload_that_is_not_the_slice_keeps_the_whole_shard_gate(
     assert all(s.args["gated_ms"] >= 60.0 for s in grads)
 
 
+KEEPS_THE_MIRROR = dict(WHOLE_GATE, no_gate=dict(withheld=True))
+
+
+@pytest.mark.parametrize("case", sorted(KEEPS_THE_MIRROR))
+def test_every_other_path_keeps_the_mirror_and_reads_no_piece(case, obs_on):
+    rounds, mirrors = 2, []
+    w, _tapes, _shards, early = train(f"km_{case}", rounds, mirrors=mirrors,
+                                      rule=RULES.get(case, "adam"),
+                                      **KEEPS_THE_MIRROR[case])
+    assert early == 0 and np.isfinite(w).all()
+    spans = [s for s in obs_on.spans if s.name == "round"]
+    assert [s.args["direct_bytes"] for s in spans] == [0] * rounds
+    assert all(s.args["stage_copy_ms"] > 0.0 for s in spans)
+    # the mirror holds each round's whole gradient: w - target at the
+    # parameters the round began with
+    np.testing.assert_allclose(
+        mirrors[0], np.full(SIZE, 0.25, np.float32) - np.asarray(TARGET),
+        rtol=1e-6, atol=1e-7)
+    assert not np.array_equal(mirrors[0], mirrors[1])
+    assert np.count_nonzero(mirrors[1]) > 0.99 * SIZE  # both shards'
+
+
+def test_a_followed_round_never_writes_the_mirror_and_gives_the_same_bits(
+        obs_on):
+    """Three rounds with every shard's send reading the pieces, and the
+    same three with the mirror (a wire that cannot send pieces)."""
+    rounds, followed, mirrored = 3, [], []
+    w_f, tapes_f, shards_f, early_f = train("f3", rounds, mirrors=followed)
+    base = len(obs_on.spans)
+    w_m, tapes_m, shards_m, early_m = train("m3", rounds, mirrors=mirrored,
+                                            plain=True)
+    np.testing.assert_array_equal(w_f, w_m)
+    for got, want in zip(shards_f, shards_m):
+        np.testing.assert_array_equal(got, want)
+    assert tapes_f == tapes_m  # every message, byte for byte, in order
+    assert not np.array_equal(w_f, np.full(SIZE, 0.25, np.float32))
+    direct = [[s.args["direct_bytes"] for s in spans if s.name == "round"]
+              for spans in (obs_on.spans[:base], obs_on.spans[base:])]
+    assert direct == [[SIZE * 4] * rounds, [0] * rounds]
+    assert all(not mirror.any() for mirror in followed)  # never written
+    assert all(mirror.any() for mirror in mirrored)
+    assert early_m == 0
+
+
+def test_the_stream_learns_shard_by_shard_whose_send_reads_pieces():
+    """The predicate is the client's, a shard at a time: a codec on one
+    server's channel keeps that shard in the mirror and no other."""
+    with shm_gang("mixed", rule="add") as (servers, pc, wire):
+        opt = RuleShell(quad, pc, su=1)
+        w = opt.start(jnp.zeros(SIZE) + 0.25)
+        stream = opt._stream
+        assert stream.follow == [True, True]
+        assert pc.stream_pieces(stream.feed) == [True, True]
+        # between rounds there is no feed: the slice is the payload
+        assert [stream.feed(shard) for shard in stream.cut] == [None, None]
+        stream.follow[1] = False
+        real = pc._follows
+        pc._follows = lambda srank: srank == 0 and real(srank)
+        w, _loss = opt.step(w, TARGET)
+        grad = np.full(SIZE, 0.25, np.float32) - np.asarray(TARGET)
+        first, second = stream.cut
+        assert not opt.grad_host[first.offset:first.end].any()
+        np.testing.assert_allclose(opt.grad_host[second.offset:second.end],
+                                   grad[second.offset:second.end], rtol=1e-6)
+        np.testing.assert_allclose(np.array(w), 0.25 + grad, rtol=1e-6)
+        opt.stop()
+
+
+def test_the_stager_stands_at_the_bound_until_the_client_has_placed_more(
+        monkeypatch, obs_on):
+    """A bound of one piece: no piece is cut while more than that is
+    handed over and not in a ring, and the round still ends, equal."""
+    want, _tapes, want_shards, _early = train("unbound", 2)
+    monkeypatch.setattr(sync, "HELD_BYTES", PIECE)
+    monkeypatch.setattr(sync, "IN_FLIGHT", 1)
+    most = []
+    real = sync._Copies._no_room
+
+    def watched(self):
+        most.append(self.direct_bytes - sum(self.wrote))
+        return real(self)
+
+    monkeypatch.setattr(sync._Copies, "_no_room", watched)
+    got, _tapes, shards, _early = train("bound", 2)
+    np.testing.assert_array_equal(got, want)
+    for a, b in zip(shards, want_shards):
+        np.testing.assert_array_equal(a, b)
+    # one piece in flight: at most the bound and the piece just landed
+    assert most and max(most) <= 2 * PIECE
+    assert any(held > PIECE for held in most)  # it did stand there
+
+
+def test_many_small_pieces_under_a_tight_bound_and_a_short_switch_interval(
+        monkeypatch):
+    """The stager and the client's thread share the pieces, the count of
+    bytes written and the wake-up: with 157 pieces a round, a bound of
+    two and the interpreter switching threads every 10 us, a lost piece,
+    a piece out of order or a lost wake-up would show as other bits or
+    as the test's time limit."""
+    import sys
+
+    rounds = 12
+    want, _tapes, want_shards, _early = train("calm", rounds, plain=True)
+    monkeypatch.setattr(sync, "PIECE_BYTES", 128)
+    monkeypatch.setattr(sync, "HELD_BYTES", 256)
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        got, _tapes, shards, early = train("stress", rounds)
+    finally:
+        sys.setswitchinterval(interval)
+    np.testing.assert_array_equal(got, want)
+    for a, b in zip(shards, want_shards):
+        np.testing.assert_array_equal(a, b)
+    assert early > 0
+
+
 def test_the_slice_itself_over_shm_follows_the_staging(slow_staging, obs_on):
     rounds = 2
     _w, _tapes, _shards, early = train("follow", rounds)
@@ -494,13 +814,17 @@ def test_the_round_span_says_where_the_staging_threads_time_went(
     spans = [s for s in obs_on.spans if s.name == "round"]
     assert len(spans) == rounds
     for span in spans:
-        wait, copy, issue = (span.args[key] for key in sync.STAGE_PARTS)
+        wait, copy, held, issue = (span.args[key]
+                                   for key in sync.STAGE_PARTS)
         # ten pieces, one in flight: nine are cut inside the loop at 20 ms
-        assert issue >= 9 * 20.0 and wait >= 0.0 and copy > 0.0
+        assert issue >= 9 * 20.0 and wait >= 0.0 and copy >= 0.0
+        assert held >= 0.0
         staging = 1e3 * (span.phase_seconds("d2h")
                          + span.phase_seconds("stage")
                          + span.phase_seconds("exchange"))
-        assert wait + copy + issue <= staging
+        assert wait + copy + held + issue <= staging
+        # every shard's send read the pieces: nothing went by the mirror
+        assert span.args["direct_bytes"] == SIZE * 4
 
 
 def test_obs_off_the_staging_thread_reads_no_clock(slow_staging, monkeypatch):

@@ -487,6 +487,7 @@ HAND_WORKED = {
     "server_apply_ms_p50": 0.23,
     "idle_unnamed_pct": 100 * 140 / 790,
     "head_loss_ms_per_step": 0.15,
+    "push_direct_pct": 75.0,  # 375 of the GRAD's 500 bytes read from pieces
 }
 
 
@@ -581,7 +582,7 @@ def traced_run(tmp_path):
     span(WORKER, 1, "round", 0.20,
          [("wait_backward", 0.20), ("d2h", 0.25), ("stage", 0.30),
           ("exchange", 0.35), ("h2d", 0.85), ("telemetry", 0.90)], 0.95,
-         side="worker", round=4, rank=WORKER, n=4)
+         side="worker", round=4, rank=WORKER, n=4, direct_bytes=375)
     span(WORKER, 2, "GRAD", 0.35,
          [("encode", 0.35), ("send", 0.36), ("ack", 0.38)], 0.60,
          side="client", rank=WORKER, peer=SERVER, round=4, n=4, bytes=500)
@@ -748,7 +749,11 @@ def test_apply_exec_ends_at_the_earlier_of_waiter_and_wait_apply(obs_on,
 def test_a_pull_that_waits_on_the_apply_stamps_its_end(obs_on):
     """The server thread's ``wait_apply`` ends when the apply is ready:
     that instant is also ``apply_exec``'s end (``seen_ready``), so the
-    span cannot outlast the ``snapshot`` mark that follows the wait."""
+    span cannot outlast the ``snapshot`` mark that follows the wait,
+    unless its own last mark does: ``exec`` is the waiter thread's, which
+    stamps it when it gets the interpreter lock, under load after the
+    pull has gone on, and a span never ends before its last mark (it
+    then ends at that mark, an ``exec`` phase of no length)."""
     rec = obs_on
     with gang(1, 1) as ((_server,), (pc,)):
         pc.start(np.zeros(SIZE, np.float32), np.zeros(SIZE, np.float32))
@@ -769,7 +774,12 @@ def test_a_pull_that_waits_on_the_apply_stamps_its_end(obs_on):
         assert exec_span.seen_ready is not None
         assert waited["wait_apply"] <= exec_span.seen_ready <= (
             waited["snapshot"])
-        assert exec_span.t1 <= waited["snapshot"]
+        began = dict(exec_span.marks)["exec"]
+        if began <= waited["snapshot"]:
+            assert exec_span.t1 <= waited["snapshot"]
+        else:  # the waiter came late: the span ends where ``exec`` began
+            assert exec_span.t1 == began
+            assert exec_span.args["end_from"] == "wait_apply"
 
 
 # -- the wire meter (PR 34) ----------------------------------------------------
@@ -1041,3 +1051,50 @@ def test_push_early_reader_gives_none_without_the_spans(traced_run,
     older.write_text(json.dumps(trace))
     run = {k: v for k, v in wire_gang_run.items() if not k.startswith("_")}
     assert read({**run, "obs_trace": str(older)}) is None
+
+
+# -- the push that reads the pieces (PR 45): ``push_direct_pct`` ---------------
+
+
+def test_gang_push_direct_reader_says_the_whole_push_read_the_pieces(
+        wire_gang_run, capsys):
+    """The gang's GRADs are the slices themselves over shm, so both
+    shards' sends read the pieces where they landed: every windowed
+    round's ``direct_bytes`` is the sum of its two GRADs' ``bytes``, the
+    stream's thread copied nothing, and the reader says 100."""
+    tree = spantree.load(dict(wire_gang_run))
+    rounds = tree.rounds()
+    assert len(rounds) >= 3
+    for r in rounds:
+        pushed = sum(s.args["bytes"] for s in tree.named("GRAD", "client")
+                     if s.args.get("round") == r.args["round"])
+        assert r.args["direct_bytes"] == pushed > 0
+        assert 0.0 <= r.args["stage_copy_ms"] < 1.0
+        assert r.args["stage_held_ms"] >= 0.0
+    assert reader("push_direct_pct")(wire_gang_run) == 100.0
+    assert capsys.readouterr().out == ""
+
+
+def test_push_direct_reader_gives_none_for_a_program_without_the_counter(
+        traced_run, wire_gang_run, tmp_path):
+    """No merged trace, and a program from before PR 45, whose ``round``
+    spans say nothing of ``direct_bytes``: None each time, nothing
+    raised; a round whose push went by the mirror reads 0."""
+    import json
+
+    read = reader("push_direct_pct")
+    assert read({**traced_run, "obs_trace": None}) is None
+    with open(wire_gang_run["obs_trace"]) as fh:
+        trace = json.load(fh)
+    for event in trace["traceEvents"]:
+        event.get("args", {}).pop("direct_bytes", None)
+    older = tmp_path / "older.json"
+    older.write_text(json.dumps(trace))
+    run = {k: v for k, v in wire_gang_run.items() if not k.startswith("_")}
+    assert read({**run, "obs_trace": str(older)}) is None
+    for event in trace["traceEvents"]:
+        if event.get("name") == "round" and event.get("ph") in ("B", "E"):
+            event.setdefault("args", {})["direct_bytes"] = 0
+    mirror = tmp_path / "mirror.json"
+    mirror.write_text(json.dumps(trace))
+    assert read({**run, "obs_trace": str(mirror)}) == 0.0

@@ -5,12 +5,16 @@ its own staging and for nothing else, a landed shard goes back up while
 the next is on the wire, the clients that give no cut move the vector as
 one shard in the same pieces, and an error on either side of the gate
 surfaces from ``wait`` and leaves no thread behind once the shell stops.
-In-process thread gangs over the local transport; every test runs under
-a time limit of its own.
+Where the wire can send pieces (shm) and the payload is the slice itself,
+the push reads each piece where it landed and the gradient mirror is not
+written (PR 45); the local wire cannot, and keeps the mirror.
+In-process thread gangs over the local transport (over shm where a test
+says so); every test runs under a time limit of its own.
 """
 
 import contextlib
 import gc
+import os
 import signal
 import threading
 import time
@@ -23,6 +27,7 @@ import pytest
 from mpit_tpu import obs
 from mpit_tpu.aio import TaskError
 from mpit_tpu.comm.local import LocalRouter
+from mpit_tpu.comm.shm import ShmTransport
 from mpit_tpu.ft import FTConfig
 from mpit_tpu.optim import sync
 from mpit_tpu.optim.client_api import ParamClientAPI
@@ -77,17 +82,25 @@ def obs_on():
 
 
 @contextlib.contextmanager
-def gang(nservers, rule="add", codec=None, ft=None, **client_kw):
-    """Servers on threads, one client driven by the caller."""
-    router = LocalRouter(nservers + 1)
+def gang(nservers, rule="add", codec=None, ft=None, shm=None, **client_kw):
+    """Servers on threads, one client driven by the caller; ``shm``: a
+    name, and the gang talks over the shm wire under it."""
+    if shm is None:
+        wires = []
+        endpoint = LocalRouter(nservers + 1).endpoint
+    else:
+        wires = [ShmTransport(f"t_rs_{shm}_{os.getpid()}", r, nservers + 1,
+                              ring_bytes=1 << 20)
+                 for r in range(nservers + 1)]
+        endpoint = wires.__getitem__
     sranks, crank = list(range(nservers)), nservers
-    servers = [ParamServer(r, [crank], router.endpoint(r), rule=rule, ft=ft)
+    servers = [ParamServer(r, [crank], endpoint(r), rule=rule, ft=ft)
                for r in sranks]
     threads = [threading.Thread(target=s.start, daemon=True)
                for s in servers]
     for t in threads:
         t.start()
-    client = ParamClient(crank, sranks, router.endpoint(crank),
+    client = ParamClient(crank, sranks, endpoint(crank),
                          seed_servers=True, codec=codec, ft=ft, **client_kw)
     try:
         yield servers, client
@@ -97,6 +110,8 @@ def gang(nservers, rule="add", codec=None, ft=None, **client_kw):
         for t in threads:
             t.join(10)
             assert not t.is_alive(), "server thread did not stop"
+        for wire in wires:
+            wire.close()
 
 
 class Withheld:
@@ -119,11 +134,11 @@ SHELLS = {  # name -> (factory, micro-steps a round, the payload is consumed)
 }
 
 
-def train(shell, rounds, codec, ft, stream, rule="adam"):
+def train(shell, rounds, codec, ft, stream, rule="adam", shm=None):
     """``rounds`` sync rounds against two servers; returns the final
     parameters, both servers' shards and the shell."""
     make, su, _consume = SHELLS[shell]
-    with gang(2, rule=rule, codec=codec, ft=ft) as (servers, pc):
+    with gang(2, rule=rule, codec=codec, ft=ft, shm=shm) as (servers, pc):
         opt = make(pc if stream else Withheld(pc))
         w = opt.start(jnp.zeros(SIZE) + 0.25)
         for _ in range(rounds * su):
@@ -169,12 +184,50 @@ def test_streamed_rounds_equal_whole_vector_rounds_bitwise(shell, codec, ft):
     assert not stream_threads()
 
 
+@pytest.mark.parametrize("shell", sorted(SHELLS))
+def test_rounds_that_read_the_pieces_equal_rounds_by_the_mirror_bitwise(
+        shell, obs_on):
+    """Over shm the sends of both shards read the pieces (RuleShell and
+    Downpour, a payload that is consumed and one that is not); the same
+    rounds through a client that takes no gate go by the mirror."""
+    rounds = 3
+    w_d, shards_d, opt_d = train(shell, rounds, "none", None, stream=True,
+                                 shm=f"d{shell}")
+    base = len(obs_on.spans)
+    w_m, shards_m, opt_m = train(shell, rounds, "none", None, stream=False,
+                                 shm=f"m{shell}")
+    assert opt_d._stream.follow == [True, True]
+    assert opt_m._stream.follow == [False]
+    np.testing.assert_array_equal(w_d, w_m)
+    for got, want in zip(shards_d, shards_m):
+        np.testing.assert_array_equal(got, want)
+    assert np.isfinite(w_d).all() and not np.array_equal(
+        w_d, np.full(SIZE, 0.25, np.float32))  # it did train
+    direct = [[s.args["direct_bytes"] for s in spans if s.name == "round"]
+              for spans in (obs_on.spans[:base], obs_on.spans[base:])]
+    assert direct == [[SIZE * 4] * rounds, [0] * rounds]
+    assert not opt_d.grad_host.any()  # the mirror was never written
+    assert opt_m.grad_host.any()
+    assert not stream_threads()
+
+
+def test_over_the_local_wire_every_shard_keeps_the_mirror(obs_on):
+    """A transport that cannot send pieces: the client says so shard by
+    shard, and the stream copies every piece into the mirror."""
+    _w, _shards, opt = train("rule-su1", 2, "none", None, stream=True)
+    assert opt._stream.follow == [False, False] and opt._stream.gated
+    assert [s.args["direct_bytes"] for s in obs_on.spans
+            if s.name == "round"] == [0, 0]
+    assert opt.grad_host.any()
+
+
 def test_a_real_client_has_the_extension_and_a_withheld_one_has_not():
     router = LocalRouter(2)
     pc = ParamClient(1, [0], router.endpoint(1))
-    assert callable(pc.stream_shards)
+    assert callable(pc.stream_shards) and callable(pc.stream_pieces)
     assert isinstance(Withheld(pc), ParamClientAPI)
     assert not hasattr(Withheld(pc), "stream_shards")
+    assert not hasattr(Withheld(pc), "stream_pieces")
 
 
 class Forwarding:
