@@ -85,6 +85,14 @@ SIZES: Dict[str, Size] = {
     # kimi's delta attention
     "kda_heads": Size(0, "heads of the delta attention's state"),
     "kda_head_dim": Size(0, "a state's side: a head's keys and values"),
+    # keye's indexer: the learned selection of keys
+    "index_heads": Size(0, "the indexer's query heads, over its one key "
+                        "head"),
+    "index_head_dim": Size(0, "an indexer head's width (even: all of it "
+                           "is rotated)"),
+    "index_topk": Size(0, "keys a query attends: the positions its "
+                       "indexer scores highest, all of them where fewer "
+                       "came before"),
 }
 
 DEFAULTS = {name: size.default for name, size in SIZES.items()}
@@ -219,6 +227,16 @@ def _kimi(s, attn):
     return _module("KimiDecoder", s, attn(), layer_types=kinds)
 
 
+def _keye(s, attn):
+    _check_share(s)
+    index = tuple(s[name] for name in (
+        "index_heads", "index_head_dim", "index_topk"))
+    if min(index) < 1 or s["index_head_dim"] % 2:
+        raise ValueError(f"keye needs index_heads, index_head_dim (even) "
+                         f"and index_topk: {index}")
+    return _module("KeyeDecoder", s, attn(), **_heads(s))
+
+
 # what each block is: its decoder's docstring (``models/transformer.py``)
 BLOCKS: Dict[str, Block] = {
     "gpt2": Block((), _gpt2, sample_len=0),
@@ -249,6 +267,10 @@ BLOCKS: Dict[str, Block] = {
             "dense_layers", "dense_width", "route_scale", "q_rank",
             "kv_rank", "qk_nope", "qk_rope", "v_head", "shared_experts"),
         _kimi, loss=OWN_LOSS),
+    "keye": Block(
+        _GROUPED + _SPARSE + _SHARE + _ROTARY + (
+            "index_heads", "index_head_dim", "index_topk"),
+        _keye, loss=OWN_LOSS),
 }
 ARCHS = tuple(BLOCKS)
 

@@ -15,7 +15,7 @@ long-context showcase built on the framework's own kernels:
   batched over (B, L);
 - pre-LN blocks, learned positional embeddings, causal by default.
 
-Seven decoders, each described where it is defined; ``lm/archs.py``
+Eight decoders, each described where it is defined; ``lm/archs.py``
 ``BLOCKS`` names them with the sizes they take, and ``lm/model.py``
 ``build(arch=...)`` chooses one.
 """
@@ -37,35 +37,45 @@ from mpit_tpu.ops.delta_rule import KDA_OUT, kda_scan
 from mpit_tpu.ops.flash_attention import (
     FLASH_LSE, FLASH_OUT, attention_reference, flash_attention,
 )
+from mpit_tpu.ops.index_select import index_select
 from mpit_tpu.ops.short_conv import causal_depthwise_conv
 from mpit_tpu.parallel import moe
 
-#: ``fn(q, k, v, window=None) -> out``.  A block that never passes a
-#: window may be handed a callable of three arguments (ring attention).
+#: ``fn(q, k, v, window=None, select=None) -> out``.  ``select`` is a
+#: learned selection of keys, one set a query for all its heads, as the
+#: bits of ``ops/select_bits.py`` ``(B, L, words)``; only a block with
+#: an indexer passes it (:func:`selected_attention`).  A block that
+#: passes neither keyword may be handed a callable of three arguments
+#: (ring attention).
 AttnFn = Callable[..., jnp.ndarray]
 
 
 def default_attn(causal: bool = True, use_flash: bool = True,
                  interpret: Optional[bool] = None,
                  precision: Optional[str] = None) -> AttnFn:
-    """Single-device attention ``fn(q, k, v, window=None)`` over ``q (B,
-    L, Hq, D)`` and ``k, v (B, L, Hkv, D)``: flash kernel or the jnp
-    reference (the latter differentiates without a recompute pass).
-    Fewer KV heads than query heads are grouped (query head ``g`` on KV
-    head ``g // (Hq // Hkv)``) and ``window`` is the sliding causal
-    window, both as ``ops/flash_attention.py`` has them: one callable
-    serves a model's full and windowed layers.
+    """Single-device attention ``fn(q, k, v, window=None, select=None)``
+    over ``q (B, L, Hq, D)`` and ``k, v (B, L, Hkv, D)``: flash kernel
+    or the jnp reference (the latter differentiates without a recompute
+    pass).  Fewer KV heads than query heads are grouped (query head
+    ``g`` on KV head ``g // (Hq // Hkv)``) and ``window`` is the sliding
+    causal window, both as ``ops/flash_attention.py`` has them: one
+    callable serves a model's full and windowed layers.  ``select (B,
+    L, words)`` is a chosen set of keys a query (``ops/index_select.py``
+    makes it, :func:`selected_attention` passes it): a pair outside it
+    is masked in the kernel and in the reference alike.
     ``interpret`` reaches ``pallas_call``: None interprets everywhere
     but on a TPU (ops/tiles.py), False pins the Mosaic-compiled kernel.
     ``precision`` is the MXU input precision of the two attention
     products, forward and backward (``"highest"``: float32 inputs);
     None is the backend's default, one bf16 pass on a TPU."""
 
-    def fn(q, k, v, window=None):
+    def fn(q, k, v, window=None, select=None):
         qh, kh, vh = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
-        # the window is a keyword only where there is one: the plain
-        # causal call is the call it was
+        # the window and the selection are keywords only where there is
+        # one: the plain causal call is the call it was
         kw = {} if window is None else {"window": window}
+        if select is not None:
+            kw["select"] = select
         if use_flash:
             out = flash_attention(qh, kh, vh, causal=causal,
                                   interpret=interpret, precision=precision,
@@ -1657,4 +1667,240 @@ class KimiDecoder(nn.Module):
             stats[KDA_DECAY_MEAN] = jnp.stack(decays)
         if routing:
             stats.update(zip(JOYAI_MOE_STATS, map(jnp.stack, zip(*routing))))
+        return loss, stats
+
+
+# ---------------------------------------------------------------------------
+# The learned-sparse-attention block (Keye-VL-2.0's language model,
+# Kwai-Keye; ``model_type`` ``KeyeVL2``; the block is Qwen3-MoE's key
+# for key, the indexer DeepSeek-V3.2's lightning indexer at the config's
+# ``sa_config`` sizes).  Every layer's attention is over grouped KV
+# heads with an RMSNorm over each head's width on queries and keys
+# (LFM2's) and plain rotary positions, **but a query attends only the
+# ``index_topk`` earlier positions a small scorer ranks highest**: the
+# indexer projects the layer's normed input to ``index_heads`` narrow
+# query heads, one key head (LayerNormed) and a weight a head, scores
+# every earlier position ``I[t, j] = sum_h w[t, h] ReLU(qI[t, h] .
+# kI[j])`` and keeps the largest ``index_topk`` a query, one set for all
+# heads (``ops/index_select.py``); the flash kernels mask by that set
+# (``ops/flash_attention.py``, *A selection*).  The set is piecewise
+# constant, so under the head's NLL no gradient reaches the indexer:
+# its leaves are in the vector and stay at their seed (the alignment
+# loss that trains one is the recipe's, ROADMAP).  Every MLP is sparse:
+# Mellum's softmax router, renormalised, this chip's share of the
+# experts, no shared expert.  The plain float32 reference it is held to
+# is ``chipbench/reference/keye_plain.py``, which shares no code with
+# this file (tests/test_keye.py).
+# ---------------------------------------------------------------------------
+
+#: the selection's ``checkpoint_name``: the bits the backward kernels
+#: mask by are the forward's own, and the indexer is not run again
+DSA_SELECT = "dsa_select"
+# What a Keye layer's attention checkpoint keeps beside its input: the
+# flash rule's two and the selection (8 MB a layer at 8192 positions).
+KEYE_ATTN_KEPT = (FLASH_OUT, FLASH_LSE, DSA_SELECT)
+#: the selection's two counters in the step's telemetry, one entry a
+#: layer (gauges ``mpit_<name>``): chosen pairs over causal pairs (1.0:
+#: nothing is left out), and over the rows that have a choice the share
+#: of chosen positions among the row's ``index_topk`` most recent (1.0:
+#: the indexer is a sliding window)
+KEYE_DSA_STATS = ("lm_dsa_kept_share", "lm_dsa_window_overlap")
+
+
+def layer_norm(x: jnp.ndarray, weight: jnp.ndarray, bias: jnp.ndarray,
+               eps: float) -> jnp.ndarray:
+    """LayerNorm over the last axis, in float32."""
+    x = x.astype(jnp.float32)
+    centred = x - jnp.mean(x, axis=-1, keepdims=True)
+    var = jnp.mean(jnp.square(centred), axis=-1, keepdims=True)
+    return centred * jax.lax.rsqrt(var + eps) * weight + bias
+
+
+def indexer_select(h: jnp.ndarray, p: dict, *, index_heads: int,
+                   index_head_dim: int, topk: int, theta: float, eps: float):
+    """The lightning indexer on the layer's normed input ``h (B, L,
+    d)``: ``(the selection's bits, the chosen pairs' share, the window
+    overlap)`` (``ops/index_select.py`` ``index_select``).  Its three
+    products at full float32 precision, its key's LayerNorm, the
+    rotation of all ``index_head_dim`` dimensions at ``theta``."""
+    b, l, _ = h.shape
+    full = partial(jnp.matmul, precision=ROUTER_PRECISION)
+    turn = plain_inv_freq(index_head_dim, theta)
+    qi = rope_by(full(h, p["index_wq"]).reshape(
+        b, l, index_heads, index_head_dim), turn)
+    ki = rope_by(layer_norm(
+        full(h, p["index_wk"]), p["index_k_norm"], p["index_k_bias"],
+        eps)[:, :, None, :], turn)[:, :, 0, :]
+    return index_select(qi, ki, full(h, p["index_ww"]), topk)
+
+
+def selected_attention(x: jnp.ndarray, p: dict, *, heads: int,
+                       kv_heads: int, head_dim: int, index_heads: int,
+                       index_head_dim: int, topk: int, theta: float,
+                       eps: float, attn: AttnFn):
+    """Grouped attention over a learned selection of keys on the stream
+    ``x (B, L, d)`` with the weights ``p``, projected back to ``(B, L,
+    d)``, and the selection's two counters (:data:`KEYE_DSA_STATS`);
+    pure in both.  Two scopes: ``index`` (:func:`indexer_select`: the
+    indexer's products, the scores and the exact top ``topk`` a query)
+    and ``attn`` (the norm before the layer, :func:`grouped_attention`
+    with the per-head query/key norm at the main heads' ``theta``,
+    ``attn`` handed the selection)."""
+    with jax.named_scope("attn"):
+        h = rms_norm(x, p["attn_norm"], eps)
+    with jax.named_scope("index"):
+        select, kept, overlap = indexer_select(
+            h, p, index_heads=index_heads, index_head_dim=index_head_dim,
+            topk=topk, theta=theta, eps=eps)
+        select = checkpoint_name(select, DSA_SELECT)
+    with jax.named_scope("attn"):
+        y = grouped_attention(
+            h, p["wq"], p["wk"], p["wv"], p["wo"], heads=heads,
+            kv_heads=kv_heads, head_dim=head_dim,
+            inv_freq=plain_inv_freq(head_dim, theta),
+            attn=partial(attn, select=select),
+            qk_norm=(p["q_norm"], p["k_norm"], eps))
+    return y, (kept, overlap)
+
+
+class KeyeBlock(nn.Module):
+    d_model: int
+    n_heads: int
+    kv_heads: int
+    head_dim: int
+    index_heads: int
+    index_head_dim: int
+    index_topk: int
+    n_experts: int           # the router's width: every expert there is
+    experts_per_tok: int
+    expert_width: int
+    experts_first: int = 0   # the share held here: a contiguous range
+    experts_held: int = 0    # 0: all of them
+    rope_theta: float = 10000000.0
+    norm_eps: float = 1e-6
+    attn_fn: Optional[AttnFn] = None
+
+    @nn.compact
+    def __call__(self, x: jnp.ndarray):
+        """``(the stream after the layer, the selection's counters in
+        :data:`KEYE_DSA_STATS`' order, the sparse branch's statistics in
+        :data:`JOYAI_MOE_STATS`' order)``."""
+        d, hq, hkv, hd = (self.d_model, self.n_heads, self.kv_heads,
+                          self.head_dim)
+        hi, di = self.index_heads, self.index_head_dim
+        e, f = self.n_experts, self.expert_width
+        held, ones = self.experts_held or e, nn.initializers.ones
+        p = {name: self.param(name, init, shape) for name, init, shape in (
+            ("attn_norm", ones, (d,)),
+            ("wq", _INIT, (d, hq * hd)), ("wk", _INIT, (d, hkv * hd)),
+            ("wv", _INIT, (d, hkv * hd)), ("wo", _INIT, (hq * hd, d)),
+            ("q_norm", ones, (hd,)), ("k_norm", ones, (hd,)),
+            ("index_wq", _INIT, (d, hi * di)), ("index_wk", _INIT, (d, di)),
+            ("index_ww", _INIT, (d, hi)), ("index_k_norm", ones, (di,)),
+            ("index_k_bias", nn.initializers.zeros, (di,)))}
+        # Kept for the backward pass: the layer's input, the flash
+        # rule's two and the selection; q, k, v are made again from the
+        # input (four products, a tenth of the layer's kernels at 8192
+        # positions), the indexer is not (nothing that needs a gradient
+        # depends on more of it than the kept bits).
+        y, dsa = jax.checkpoint(
+            partial(selected_attention, heads=hq, kv_heads=hkv, head_dim=hd,
+                    index_heads=hi, index_head_dim=di, topk=self.index_topk,
+                    theta=self.rope_theta, eps=self.norm_eps,
+                    attn=self.attn_fn if self.attn_fn is not None
+                    else default_attn()),
+            policy=jax.checkpoint_policies.save_only_these_names(
+                *KEYE_ATTN_KEPT))(x, p)
+        x = x + y
+
+        norm = self.param("mlp_norm", ones, (d,))
+        router = self.param("router", _INIT, (d, e))
+        experts = tuple(self.param(f"experts_{name}", _INIT, shape)
+                        for name, shape in (("gate", (held, d, f)),
+                                            ("up", (held, d, f)),
+                                            ("down", (held, f, d))))
+
+        # recomputed in the backward pass, as Mellum's and for its
+        # reason; Mellum's router: a softmax over all the experts, the k
+        # largest renormalised, held or not
+        @jax.checkpoint
+        def sparse(x, norm, router, experts):
+            return sparse_mlp(
+                x, norm, router, experts, eps=self.norm_eps, n_experts=e,
+                first=self.experts_first, held=held,
+                route=lambda logits: (*moe.route_top_k(
+                    jax.nn.softmax(logits, axis=-1), self.experts_per_tok,
+                    renormalise=True), ()))
+
+        y, stats = sparse(x, norm, router, experts)
+        return x + y, dsa, stats
+
+
+class KeyeDecoder(nn.Module):
+    """Causal LM of :class:`KeyeBlock` layers: a token table (at
+    :data:`MELLUM_EMBED_INIT`'s scale, for its reason: a share of the
+    experts is held), the layers, every one alike, a final RMSNorm and
+    an untied head.  Like :class:`KimiDecoder` it is called with the
+    targets and returns its own loss, the head's mean next-token NLL,
+    with its statistics (``lm/model.py`` closes over it):
+
+    - :data:`KEYE_DSA_STATS`: the selection's two counters, one entry a
+      layer;
+    - the routing counters of every layer under ``lm/model.py``
+      ``MOE_STATS``' names.
+
+    The head's product, norm and loss is under ``jax.checkpoint``,
+    keeping the rows' log-sum-exp by name (:func:`row_lse`)."""
+
+    vocab: int = 256
+    d_model: int = 64
+    n_heads: int = 4
+    kv_heads: int = 2
+    head_dim: int = 16
+    index_heads: int = 2
+    index_head_dim: int = 8
+    index_topk: int = 16
+    n_layers: int = 2
+    n_experts: int = 8
+    experts_per_tok: int = 2
+    expert_width: int = 32
+    experts_first: int = 0
+    experts_held: int = 0
+    rope_theta: float = 10000000.0
+    norm_eps: float = 1e-6
+    attn_fn: Optional[AttnFn] = None
+
+    @nn.compact
+    def __call__(self, tokens: jnp.ndarray, targets: jnp.ndarray):
+        d, eps = self.d_model, self.norm_eps
+        sizes = {field: getattr(self, field) for field in (
+            "d_model", "n_heads", "kv_heads", "head_dim", "index_heads",
+            "index_head_dim", "index_topk", "n_experts", "experts_per_tok",
+            "expert_width", "experts_first", "experts_held", "rope_theta",
+            "norm_eps", "attn_fn")}
+
+        @partial(jax.checkpoint,
+                 policy=jax.checkpoint_policies.save_only_these_names(
+                     HEAD_LSE))
+        def head_nll(u, norm, head, targets):
+            with jax.named_scope("head_loss"):
+                z = rms_norm(u, norm, eps) @ head
+                return row_lse(z) - jnp.take_along_axis(
+                    z, targets[..., None], axis=-1)[..., 0]
+
+        chosen, routing = [], []
+        with jax.named_scope("embed"):
+            x = self.param("embed", MELLUM_EMBED_INIT,
+                           (self.vocab, d))[tokens]
+        for _ in range(self.n_layers):
+            x, dsa, counted = KeyeBlock(**sizes)(x)
+            chosen.append(dsa)
+            routing.append(counted)
+        nll = head_nll(
+            x, self.param("final_norm", nn.initializers.ones, (d,)),
+            self.param("head", _INIT, (d, self.vocab)), targets)
+        with jax.named_scope("head_loss"):
+            loss = jnp.mean(nll)
+        stats = dict(zip(KEYE_DSA_STATS, map(jnp.stack, zip(*chosen))))
+        stats.update(zip(JOYAI_MOE_STATS, map(jnp.stack, zip(*routing))))
         return loss, stats

@@ -14,6 +14,10 @@ that backs sequence-parallel ring attention
 (Kimi Delta Attention): three Mosaic kernels at head widths of whole
 lanes (forward, and the backward rule's two), XLA's fusions and products
 at every other width; :mod:`mpit_tpu.ops.short_conv` is XLA's fusions.
+:mod:`mpit_tpu.ops.index_select` is a learned selection of keys (an
+indexer's scores and an exact top-k a query): XLA's products and
+fusions too; :mod:`mpit_tpu.ops.select_bits` is the format its set
+travels in, the bits the flash kernels mask by.
 
 Every op has a jnp reference implementation (``*_reference``) used for
 testing and as a CPU fallback; kernels run in pallas interpret mode off-TPU

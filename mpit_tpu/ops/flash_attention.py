@@ -42,6 +42,22 @@ showcase the rebuild adds on top of capability parity.  Design:
   forward and in every backward kernel; one function holds the bounds
   for index maps and bodies alike.  :func:`flash_step_counts` says how
   many steps a call visits and how many are live.
+- **A selection** (``select``): beside the live set that is geometry
+  (two positions decide: causal, a window, a padded length), one that is
+  **data**: a (query, key) pair is live iff a set computed elsewhere
+  holds it (learned sparse attention: ``ops/index_select.py``, the top
+  ``k`` keys a query by an indexer's scores, one set for all heads).
+  The set travels as bits, ``(B, Lq, words)`` int32
+  (``ops/select_bits.py`` owns the layout, which the tile here
+  dictates), indexed **by position, not by the kernel's row** (a
+  group's folded query heads read the same words): a ``(block_q,
+  block_k)`` tile reads one ``(block_q, 128)`` block of words and its
+  ``block_k / 128`` bits are whole-lane shifts, no lane moved.  The
+  walk stays the geometric one; within it a tile with no chosen pair
+  runs no product (its words are all it reads to know), forward and in
+  both backward kernels, and every other tile masks its scores by the
+  bits (and by the geometry where it is an edge tile).  The backward
+  under a selection is the two-kernel schedule, as under a window.
 
 :func:`flash_attention` is the user op (normalized output, custom VJP:
 pallas backward in the standard flash schedule — P is recomputed
@@ -71,6 +87,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from mpit_tpu.obs.metrics import get_registry
+from mpit_tpu.ops.select_bits import SUPER, unpack as _unpack_select
 from mpit_tpu.ops.tiles import (
     LANE, round_up as _round_up, use_interpret as _interpret,
 )
@@ -198,11 +215,13 @@ def attention_reference(
     q_offset=0,
     kv_offset=0,
     window: int | None = None,
+    select: jnp.ndarray | None = None,
 ) -> jnp.ndarray:
     """Plain softmax attention over the last two axes; leading axes batch.
     Rows with no valid key return zeros (matches the ring/partial path).
     ``window`` as in :func:`_mask`; fewer KV heads than query heads
-    (axis -3) are repeated over their groups, materialised."""
+    (axis -3) are repeated over their groups, materialised; ``select``
+    as :func:`flash_attention` takes it, unpacked to a whole mask."""
     window = _check_window(window, causal)
     if q.ndim >= 3 and q.shape[-3] != k.shape[-3]:
         groups = q.shape[-3] // k.shape[-3]
@@ -211,6 +230,12 @@ def attention_reference(
     s = jnp.einsum("...qd,...kd->...qk", q, k).astype(jnp.float32) * scale
     valid = _mask(q.shape[-2], k.shape[-2], q_offset, kv_offset,
                   k.shape[-2], causal, window)
+    if select is not None:
+        chosen = _unpack_select(select, k.shape[-2])
+        # a sequence's, the first of ``s``'s axes: every head's alike
+        valid = valid & chosen.reshape(
+            *chosen.shape[:-2], *(1,) * (s.ndim - chosen.ndim),
+            *chosen.shape[-2:])
     s = jnp.where(valid, s, NEG_INF)
     m = jnp.max(s, axis=-1, keepdims=True)
     m_safe = jnp.where(jnp.isneginf(m), 0.0, m)
@@ -363,6 +388,9 @@ class _Walk:
     q_blocks: int   # q blocks of one head
     kv_blocks: int
     groups: int = 1
+    #: a selection's bits ride along (``_chosen``): a tile's words are
+    #: ``fetch_select``'s block, and no tile takes the mask-free path
+    select: bool = False
 
     @property
     def inner_blocks(self) -> int:
@@ -442,6 +470,18 @@ class _Walk:
                             self.inner_blocks - 1)
         return (head + held).v
 
+    def fetch_select(self, outer, t, *prefetched):
+        """Index-map half of a selection: the ``(block_q, 128)`` block
+        of words of the tile at inner step ``t``: the rows of its
+        **position** block (a folded head's rows are its positions') and
+        the 128-word group that holds its kv block's bits."""
+        held, per = _Int(self.fetch(outer, t, *prefetched)), SUPER // self.block_k
+        if self.kv_outer:
+            rows = held % self.q_blocks if self.groups > 1 else held
+            return rows.v, (_Int(outer) // per).v
+        rows = _Int(outer) % self.q_blocks if self.groups > 1 else outer
+        return _Int.of(rows).v, (held // per).v
+
     def tile(self, lo_ref, hi_ref, qoff_ref, kvoff_ref, kvlen_ref):
         """Body half, for this grid step: ``(q_lo, j, live, full, first,
         last)``: the global position of the tile's first query row, its
@@ -509,6 +549,39 @@ def _valid(q_lo, j, kvoff_ref, kvlen_ref, shape, walk):
     return valid
 
 
+def _first_bit(j, walk):
+    """Kv block ``j``'s tile has ``block_k / 128`` bits of every word of
+    its group: the first of them."""
+    return jax.lax.rem(j, SUPER // walk.block_k) * (walk.block_k // LANE)
+
+
+def _any_chosen(sel_ref, j, walk):
+    """Whether the tile has a chosen pair at all: a scalar, from the
+    words alone."""
+    lanes = walk.block_k // LANE
+    mask = jax.lax.shift_left(
+        jnp.int32(-1 if lanes == 32 else (1 << lanes) - 1),
+        _first_bit(j, walk))
+    return jnp.max(jnp.where((sel_ref[:] & mask) != 0, 1, 0)) > 0
+
+
+def _chosen(sel_ref, j, walk):
+    """The tile's selection ``(block_q, block_k)``: lane group ``b`` of
+    the keys is bit ``first + b`` of the words."""
+    words, first = sel_ref[:], _first_bit(j, walk)
+    bits = [jax.lax.shift_right_logical(
+        words, jnp.full(words.shape, first + b, jnp.int32)) & 1
+        for b in range(walk.block_k // LANE)]
+    return (bits[0] if len(bits) == 1
+            else jnp.concatenate(bits, axis=1)) != 0
+
+
+def _take_select(walk, refs):
+    """``(the selection's ref or None, the refs after it)``: it follows
+    the kernel's other inputs where the walk has one."""
+    return (refs[0], refs[1:]) if walk.select else (None, refs)
+
+
 def _run_tile(live, full, block):
     """The triage of :meth:`_Walk.tile` carried out: a dead tile runs
     nothing, a full one ``block(masked=False)``, an edge one the masked
@@ -520,7 +593,8 @@ def _run_tile(live, full, block):
 
 
 def _fa_kernel(lo_ref, hi_ref, qoff_ref, kvoff_ref, kvlen_ref, q_ref, k_ref,
-               v_ref, o_ref, *rest, walk, scale, partial, precision):
+               v_ref, *rest, walk, scale, partial, precision):
+    sel_ref, (o_ref, *rest) = _take_select(walk, rest)
     if partial:
         m_out, l_out, acc_scr, m_scr, l_scr = rest
     else:
@@ -530,6 +604,8 @@ def _fa_kernel(lo_ref, hi_ref, qoff_ref, kvoff_ref, kvlen_ref, q_ref, k_ref,
     # dependent chain, not the MXU.
     q_lo, j, live, full, first, last = walk.tile(
         lo_ref, hi_ref, qoff_ref, kvoff_ref, kvlen_ref)
+    if walk.select:
+        live = jnp.logical_and(live, _any_chosen(sel_ref, j, walk))
 
     @pl.when(first)
     def _init():
@@ -545,6 +621,10 @@ def _fa_kernel(lo_ref, hi_ref, qoff_ref, kvoff_ref, kvlen_ref, q_ref, k_ref,
 
         if masked:
             valid = _valid(q_lo, j, kvoff_ref, kvlen_ref, s.shape, walk)
+        if walk.select:
+            chosen = _chosen(sel_ref, j, walk)
+            valid, masked = (valid & chosen if masked else chosen), True
+        if masked:
             s = jnp.where(valid, s, _BIG_NEG)
 
         # Finite sentinel algebra: m_new >= any valid score, so
@@ -719,6 +799,25 @@ def _walk_specs(walk, d_p):
     return held, walked
 
 
+def _select_operand(walk, select, lq_p, lk_p):
+    """``(in_specs, operands)`` of a walk's selection, each empty where
+    it has none: the words padded to the tiles' rows (a padded row
+    chooses nothing) and to whole 128-word groups over the padded keys,
+    a ``(block_q, 128)`` block a tile (:meth:`_Walk.fetch_select`)."""
+    if select is None:
+        return [], []
+    if SUPER % walk.block_k:
+        raise ValueError(f"a selection's bits come {SUPER} keys a group of "
+                         f"words: block_k {walk.block_k} must divide it")
+    lq, words = select.shape
+    padded = jnp.pad(select.astype(jnp.int32), (
+        (0, lq_p - lq), (0, max(_round_up(lk_p, SUPER) // 32 - words, 0))))
+    spec = pl.BlockSpec((walk.block_q, LANE),
+                        lambda o, t, *s: walk.fetch_select(o, t, *s),
+                        memory_space=pltpu.VMEM)
+    return [spec], [padded]
+
+
 def _prefetch(walk, q_offset, kv_offset, kv_len):
     """What every kernel prefetches (SMEM, ahead of the grid, so that
     the index maps read it, traced or not): each outer block's live
@@ -741,7 +840,8 @@ def _prefetch(walk, q_offset, kv_offset, kv_len):
 
 
 def _fa_2d(q, k, v, q_offset, kv_offset, *, causal, sm_scale, block_q,
-           block_k, interpret, partial=False, precision=None, window=None):
+           block_k, interpret, partial=False, precision=None, window=None,
+           select=None):
     """Core call on (Lq, D) x (Lk, D); pads to tiles.  Returns the
     normalized (Lq, D) output, or with ``partial`` the unnormalized
     ``(acc, m, l)`` triple (f32) for cross-chunk merging.  ``q`` of
@@ -763,8 +863,9 @@ def _fa_2d(q, k, v, q_offset, kv_offset, *, causal, sm_scale, block_q,
     vp = jnp.pad(v, ((0, lk_p - lk), (0, dv_p - dv)))
     rows = groups * lq_p
     walk = _Walk(False, causal, window, bq, bk, lq_p // bq, lk_p // bk,
-                 groups)
+                 groups, select is not None)
     held, walked = _walk_specs(walk, d_p)
+    sel_specs, sel = _select_operand(walk, select, lq_p, lk_p)
     if partial:
         out_specs = (held(dv_p), held(LANE), held(LANE))
         out_shape = (
@@ -783,7 +884,7 @@ def _fa_2d(q, k, v, q_offset, kv_offset, *, causal, sm_scale, block_q,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
             grid=walk.grid,
-            in_specs=[held(), walked(), walked(dv_p)],
+            in_specs=[held(), walked(), walked(dv_p), *sel_specs],
             out_specs=out_specs,
             scratch_shapes=[
                 pltpu.VMEM((bq, dv_p), jnp.float32),
@@ -794,12 +895,31 @@ def _fa_2d(q, k, v, q_offset, kv_offset, *, causal, sm_scale, block_q,
         out_shape=out_shape,
         interpret=_interpret(interpret),
         compiler_params=_fa_compiler_params(_vmem_auto(bq, bk)),
-    )(*_prefetch(walk, q_offset, kv_offset, lk), qp, kp, vp)
+    )(*_prefetch(walk, q_offset, kv_offset, lk), qp, kp, vp, *sel)
     if partial:
         acc, m, l = res
         return (_unfold(acc, q, lq_p, dv), _unfold_stat(m, q, lq_p),
                 _unfold_stat(l, q, lq_p))
     return _unfold(res, q, lq_p, dv)
+
+
+def _over_leading(f, k, select=None):
+    """``f(q2, k2, v2, ...)`` vmapped over ``k``'s leading axes.  A
+    selection ``(B, Lq, words)`` is ``f``'s last argument: it has the
+    outermost of those axes, a sequence's and never a head's, and goes
+    whole to the others."""
+    if select is not None and (select.ndim != 3 or k.ndim < 3):
+        raise ValueError(f"a selection is (B, Lq, words) beside k (B, ..., "
+                         f"Lk, D): got {select.shape} beside {k.shape}")
+
+    def with_select(f, axis):
+        return lambda *a: jax.vmap(
+            f, in_axes=(0,) * (len(a) - 1) + (axis,))(*a)
+
+    for level in reversed(range(k.ndim - 2)):  # the innermost axis first
+        f = jax.vmap(f) if select is None else with_select(
+            f, 0 if level == 0 else None)
+    return f
 
 
 def flash_attention_partial(
@@ -816,24 +936,25 @@ def flash_attention_partial(
     interpret: bool | None = None,
     precision: str | None = None,
     window: int | None = None,
+    select: jnp.ndarray | None = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Pallas twin of :func:`block_attention_partial`: unnormalized
     ``(acc, m, l)`` over ``(..., L, D)``.  Forward-only — ring attention
     pairs it with :func:`flash_attention_bwd_pair` under a custom VJP at
     the ring level
     (:mod:`mpit_tpu.parallel.ring_attention`).  ``q`` one rank above
-    ``k`` is grouped (:func:`_group_queries`)."""
+    ``k`` is grouped (:func:`_group_queries`); ``select`` as
+    :func:`flash_attention` takes it."""
     _note_steps(("fwd",), q, k, causal=causal, window=window,
                 block_q=block_q, block_k=block_k, q_offset=q_offset,
                 kv_offset=kv_offset)
-    f = lambda q2, k2, v2: _fa_2d(
+    f = lambda q2, k2, v2, *sel: _fa_2d(
         q2, k2, v2, q_offset, kv_offset, causal=causal, sm_scale=sm_scale,
         block_q=block_q, block_k=block_k, interpret=interpret, partial=True,
-        precision=precision, window=window,
+        precision=precision, window=window, select=sel[0] if sel else None,
     )
-    for _ in range(k.ndim - 2):
-        f = jax.vmap(f)
-    return f(q, k, v)
+    return _over_leading(f, k, select)(
+        q, k, v, *(() if select is None else (select,)))
 
 
 # ---------------------------------------------------------------------------
@@ -854,7 +975,7 @@ def flash_attention_partial(
 
 def _bwd_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
               kvoff_ref, kvlen_ref, q_lo, j, *, walk, scale, precision,
-              masked):
+              masked, sel_ref=None):
     """Shared block math: recompute P and dS for the tile whose first
     query row is at ``q_lo`` over kv block ``j``.
     Matmul inputs stay in their native dtype (bf16 runs the MXU at full
@@ -868,6 +989,10 @@ def _bwd_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     if masked:
         valid = _valid(q_lo, j, kvoff_ref, kvlen_ref, s.shape, walk)
+    if walk.select:
+        chosen = _chosen(sel_ref, j, walk)
+        valid, masked = (valid & chosen if masked else chosen), True
+    if masked:
         # exp(s - lse) is only read where valid; all-masked rows have
         # lse = -inf and no valid element, so the inf branch is never
         # taken.
@@ -885,11 +1010,13 @@ def _bwd_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _fa_bwd_dq_kernel(lo_ref, hi_ref, qoff_ref, kvoff_ref, kvlen_ref, q_ref,
-                      do_ref, lse_ref, delta_ref, k_ref, v_ref, dq_ref,
-                      dq_scr, *,
+                      do_ref, lse_ref, delta_ref, k_ref, v_ref, *rest,
                       walk, scale, precision):
+    sel_ref, (dq_ref, dq_scr) = _take_select(walk, rest)
     q_lo, j, live, full, first, last = walk.tile(
         lo_ref, hi_ref, qoff_ref, kvoff_ref, kvlen_ref)
+    if walk.select:
+        live = jnp.logical_and(live, _any_chosen(sel_ref, j, walk))
 
     @pl.when(first)
     def _init():
@@ -900,6 +1027,7 @@ def _fa_bwd_dq_kernel(lo_ref, hi_ref, qoff_ref, kvoff_ref, kvlen_ref, q_ref,
             q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             kvoff_ref, kvlen_ref, q_lo, j,
             walk=walk, scale=scale, precision=precision, masked=masked,
+            sel_ref=sel_ref,
         )
         dq_scr[:] = dq_scr[:] + scale * jax.lax.dot_general(
             ds.astype(k_ref.dtype), k_ref[:], (((1,), (0,)), ((), ())),
@@ -915,8 +1043,7 @@ def _fa_bwd_dq_kernel(lo_ref, hi_ref, qoff_ref, kvoff_ref, kvlen_ref, q_ref,
 
 def _fa_bwd_kv_kernel(lo_ref, hi_ref, qoff_ref, kvoff_ref, kvlen_ref, k_ref,
                       v_ref, q_ref, do_ref, lse_ref, delta_ref,
-                      dk_ref, dv_ref, *rest, walk, scale, precision,
-                      fused):
+                      *rest, walk, scale, precision, fused):
     """The kv-outer backward sweep: dK/dV accumulated in VMEM scratch
     over the q blocks of the kv block's live range (over every head of a
     group in turn).  The two-kernel schedule's second kernel as it is;
@@ -926,12 +1053,15 @@ def _fa_bwd_kv_kernel(lo_ref, hi_ref, qoff_ref, kvoff_ref, kvlen_ref, k_ref,
     kernel's s/P/dS recomputation folds away, 5 matmuls per tile pair
     instead of 7.  A dead pair's slot is never visited and holds
     garbage: the caller sums the visited ones only."""
+    sel_ref, (dk_ref, dv_ref, *rest) = _take_select(walk, rest)
     if fused:
         dqp_ref, dk_scr, dv_scr = rest
     else:
         dk_scr, dv_scr = rest
     q_lo, j, live, full, first, last = walk.tile(
         lo_ref, hi_ref, qoff_ref, kvoff_ref, kvlen_ref)
+    if walk.select:
+        live = jnp.logical_and(live, _any_chosen(sel_ref, j, walk))
 
     @pl.when(first)
     def _init():
@@ -943,6 +1073,7 @@ def _fa_bwd_kv_kernel(lo_ref, hi_ref, qoff_ref, kvoff_ref, kvlen_ref, k_ref,
             q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             kvoff_ref, kvlen_ref, q_lo, j,
             walk=walk, scale=scale, precision=precision, masked=masked,
+            sel_ref=sel_ref,
         )
         dv_scr[:] = dv_scr[:] + jax.lax.dot_general(
             p.astype(do_ref.dtype), do_ref[:], (((0,), (0,)), ((), ())),
@@ -995,7 +1126,7 @@ def _sum_visited(dq_part, walk, q_offset, kv_offset, kv_len):
 
 def _fa_2d_bwd(q, k, v, do, lse, delta, q_offset, kv_offset, *, causal,
                sm_scale, block_q, block_k, interpret, precision,
-               fused=True, window=None):
+               fused=True, window=None, select=None):
     """Backward core on (Lq, D) x (Lk, D): returns (dq, dk, dv).
 
     ``lse``/``delta`` are per-q-row f32 vectors (log-sum-exp from the
@@ -1040,8 +1171,9 @@ def _fa_2d_bwd(q, k, v, do, lse, delta, q_offset, kv_offset, *, causal,
     # HBM budget) lives in _use_fused_bwd; this function only executes
     # the chosen schedule.
     walk = _Walk(True, causal, window, bq, bk, lq_p // bq, lk_p // bk,
-                 groups)
+                 groups, select is not None)
     held, walked = _walk_specs(walk, d_p)
+    sel_specs, sel = _select_operand(walk, select, lq_p, lk_p)
     out_specs = [held(), held(dv_p)]
     out_shape = [jax.ShapeDtypeStruct((lk_p, d_p), k.dtype),
                  jax.ShapeDtypeStruct((lk_p, dv_p), v.dtype)]
@@ -1057,7 +1189,7 @@ def _fa_2d_bwd(q, k, v, do, lse, delta, q_offset, kv_offset, *, causal,
             num_scalar_prefetch=5,
             grid=walk.grid,
             in_specs=[held(), held(dv_p), walked(), walked(dv_p),
-                      walked(LANE), walked(LANE)],
+                      walked(LANE), walked(LANE), *sel_specs],
             out_specs=tuple(out_specs),
             scratch_shapes=[
                 pltpu.VMEM((bk, d_p), jnp.float32),
@@ -1067,7 +1199,7 @@ def _fa_2d_bwd(q, k, v, do, lse, delta, q_offset, kv_offset, *, causal,
         out_shape=tuple(out_shape),
         **call,
     )(*_prefetch(walk, q_offset, kv_offset, lk), kp, vp, qp, dop, lse_r,
-      delta_r)
+      delta_r, *sel)
 
     if fused:
         dq = _sum_visited(dq_part[0], walk, q_offset, kv_offset,
@@ -1077,26 +1209,27 @@ def _fa_2d_bwd(q, k, v, do, lse, delta, q_offset, kv_offset, *, causal,
         # kv blocks of its live range.
         walk = dataclasses.replace(walk, kv_outer=False)
         held, walked = _walk_specs(walk, d_p)
+        sel_specs, sel = _select_operand(walk, select, lq_p, lk_p)
         dq = pl.pallas_call(
             functools.partial(_fa_bwd_dq_kernel, walk=walk, **kw),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=5,
                 grid=walk.grid,
                 in_specs=[held(), held(dv_p), held(LANE), held(LANE),
-                          walked(), walked(dv_p)],
+                          walked(), walked(dv_p), *sel_specs],
                 out_specs=held(),
                 scratch_shapes=[pltpu.VMEM((bq, d_p), jnp.float32)],
             ),
             out_shape=jax.ShapeDtypeStruct((rows, d_p), q.dtype),
             **call,
         )(*_prefetch(walk, q_offset, kv_offset, lk), qp, dop, lse_r,
-          delta_r, kp, vp)
+          delta_r, kp, vp, *sel)
 
     return _unfold(dq, q, lq_p), dk[:lk, :d], dv_out[:lk, :dv]
 
 
 def _use_fused_bwd(q_shape, k_shape, d, dtype, sm_scale, block_q, block_k,
-                   window=None):
+                   window=None, select=False):
     """Backward-schedule choice (the ONE decision point, made where the
     full vmapped batch shape is visible).
 
@@ -1133,7 +1266,14 @@ def _use_fused_bwd(q_shape, k_shape, d, dtype, sm_scale, block_q, block_k,
     does not need.  Fused under a window, with the partials laid out by
     a pair's place in its range so that the transient shrinks with the
     window, is UNMEASURED (``MPIT_FA_FUSED_BWD=1`` still forces the
-    fused sweep, on the slot layout)."""
+    fused sweep, on the slot layout).
+
+    With a selection it is the two-kernel schedule whatever the lever
+    says: a tile with no chosen pair is skipped, its slot of the fused
+    sweep's partial never written, and which tiles those are is data the
+    caller's sum of the visited slots does not have."""
+    if select:
+        return False
     mode = os.environ.get("MPIT_FA_FUSED_BWD", "auto") or "auto"
     if mode == "0":
         return False
@@ -1221,7 +1361,7 @@ def _note_steps(kernels, q, k, **kw):
 def flash_attention_bwd_pair(q, k, v, do, lse, *, causal=False, sm_scale=None,
                              q_offset=0, kv_offset=0, delta=None, o=None,
                              block_q=None, block_k=None, interpret=None,
-                             precision=None, window=None):
+                             precision=None, window=None, select=None):
     """Pallas flash backward for one (Q chunk, KV chunk) pair over
     ``(..., L, D)``: returns ``(dq, dk, dv)`` given the forward's row
     ``lse`` (shape ``(..., Lq)``) and either ``delta = rowsum(dO*O)`` or
@@ -1235,19 +1375,19 @@ def flash_attention_bwd_pair(q, k, v, do, lse, *, causal=False, sm_scale=None,
             raise ValueError("flash_attention_bwd_pair needs delta or o")
         delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), -1)
     fused = _use_fused_bwd(q.shape, k.shape, q.shape[-1], q.dtype,
-                           sm_scale, block_q, block_k, window)
+                           sm_scale, block_q, block_k, window,
+                           select is not None)
     _note_steps(("fused",) if fused else ("dq", "dkdv"), q, k,
                 causal=causal, window=window, block_q=block_q,
                 block_k=block_k, q_offset=q_offset, kv_offset=kv_offset)
-    f = lambda q2, k2, v2, do2, lse2, delta2: _fa_2d_bwd(
+    f = lambda q2, k2, v2, do2, lse2, delta2, *sel: _fa_2d_bwd(
         q2, k2, v2, do2, lse2, delta2, q_offset, kv_offset, causal=causal,
         sm_scale=sm_scale, block_q=block_q, block_k=block_k,
         interpret=interpret, precision=precision, fused=fused,
-        window=window,
+        window=window, select=sel[0] if sel else None,
     )
-    for _ in range(k.ndim - 2):
-        f = jax.vmap(f)
-    return f(q, k, v, do, lse, delta)
+    return _over_leading(f, k, select)(
+        q, k, v, do, lse, delta, *(() if select is None else (select,)))
 
 
 @functools.lru_cache(maxsize=64)
@@ -1255,26 +1395,28 @@ def _make_flash(causal, sm_scale, block_q, block_k, interpret, precision,
                 window=None):
     """Differentiable flash op for fixed static config: pallas forward,
     pallas backward (flash schedule, O(block) memory — the forward's
-    partial outputs provide the LSE residual)."""
+    partial outputs provide the LSE residual).  ``sel`` is the
+    selection's words where the call has one, else nothing: an integer
+    operand, with no cotangent."""
 
     @jax.custom_vjp
-    def fa(q, k, v, q_offset, kv_offset):
+    def fa(q, k, v, q_offset, kv_offset, *sel):
         _note_steps(("fwd",), q, k, causal=causal, window=window,
                     block_q=block_q, block_k=block_k)
-        f = lambda q2, k2, v2: _fa_2d(
+        f = lambda q2, k2, v2, *sel2: _fa_2d(
             q2, k2, v2, q_offset, kv_offset, causal=causal,
             sm_scale=sm_scale, block_q=block_q, block_k=block_k,
             interpret=interpret, precision=precision, window=window,
+            select=sel2[0] if sel2 else None,
         )
-        for _ in range(k.ndim - 2):
-            f = jax.vmap(f)
-        return f(q, k, v)
+        return _over_leading(f, k, *sel)(q, k, v, *sel)
 
-    def fwd(q, k, v, q_offset, kv_offset):
+    def fwd(q, k, v, q_offset, kv_offset, *sel):
         acc, m, l = flash_attention_partial(
             q, k, v, causal=causal, sm_scale=sm_scale, q_offset=q_offset,
             kv_offset=kv_offset, block_q=block_q, block_k=block_k,
             interpret=interpret, precision=precision, window=window,
+            select=sel[0] if sel else None,
         )
         # Named where the rule makes them: a checkpoint whose policy
         # saves these two names keeps the forward kernel's results and
@@ -1284,17 +1426,18 @@ def _make_flash(causal, sm_scale, block_q, block_k, interpret, precision,
         o = checkpoint_name(finalize_partials(acc, l, dtype=q.dtype),
                             FLASH_OUT)
         lse = checkpoint_name(_lse_of(m, l), FLASH_LSE)
-        return o, (q, k, v, o, lse, q_offset, kv_offset)
+        return o, (q, k, v, o, lse, q_offset, kv_offset, *sel)
 
     def bwd(res, g):
-        q, k, v, o, lse, q_offset, kv_offset = res
+        q, k, v, o, lse, q_offset, kv_offset, *sel = res
         dq, dk, dv = flash_attention_bwd_pair(
             q, k, v, g, lse, causal=causal, sm_scale=sm_scale,
             q_offset=q_offset, kv_offset=kv_offset, o=o,
             block_q=block_q, block_k=block_k, interpret=interpret,
             precision=precision, window=window,
+            select=sel[0] if sel else None,
         )
-        return dq, dk, dv, None, None
+        return (dq, dk, dv, None, None, *(None for _ in sel))
 
     fa.defvjp(fwd, bwd)
     return fa
@@ -1314,6 +1457,7 @@ def flash_attention(
     interpret: bool | None = None,
     precision: str | None = None,
     window: int | None = None,
+    select: jnp.ndarray | None = None,
 ) -> jnp.ndarray:
     """Flash attention over ``(..., L, D)`` with global-offset causal
     masking.  Leading axes are batched (vmapped); offsets may be traced.
@@ -1337,6 +1481,13 @@ def flash_attention(
     window, not the sequence.  The backward under a window is the
     two-kernel schedule (:func:`_use_fused_bwd`).
 
+    ``select``: a chosen set of keys a query, as the bits of
+    ``ops/select_bits.py`` ``pack``: ``(B, Lq, words)`` int32 beside ``k
+    (B, Hkv, Lk, D)``, a sequence's, the same for every head.  Query ``i``
+    sees key ``j`` iff the other rules let it and its bit is set; a tile
+    with no bit set runs no product, forward or backward; a row with
+    none returns zeros.  Integer: it has no gradient.
+
     Default blocks are 1024x1024, growing to 2048x1024 at L >= 16384
     (defaults from a July 2026 sweep on a v5e the ledger has not
     reproduced; MPIT_FA_LONG_BQ=0 pins 1024 — the kernel auto-raises
@@ -1357,5 +1508,6 @@ def flash_attention(
                      None if block_k is None else int(block_k),
                      _interpret(interpret), precision, window)
     out = fa(_group_queries(q, k), k, v, jnp.asarray(q_offset, jnp.int32),
-             jnp.asarray(kv_offset, jnp.int32))
+             jnp.asarray(kv_offset, jnp.int32),
+             *(() if select is None else (select,)))
     return out.reshape(*q.shape[:-1], v.shape[-1])

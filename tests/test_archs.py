@@ -36,7 +36,8 @@ OTHER = dict(
     loop_steps=2, exit_beta=0.05, exit_bias=-2.0,
     q_rank=24, kv_rank=16, qk_nope=8, qk_rope=4, v_head=8,
     shared_experts=2, mtp_layers=0, mtp_weight=0.125,
-    kda_heads=3, kda_head_dim=8)
+    kda_heads=3, kda_head_dim=8,
+    index_heads=3, index_head_dim=4, index_topk=8)
 # where a block cannot take ``OTHER``'s value of a size: its own
 OTHER_OF = {"kimi": {"layer_types": "kda,full_attention,kda"}}
 

@@ -55,7 +55,8 @@ def test_every_cells_vocabulary_reaches_the_program_through_the_launcher():
     from chipbench import run as runner
 
     want = {"gpt2": 50257, "olmoe": 50304, "mellum": 12288, "lfm2_moe": 8192,
-            "ouro": 49152, "joyai_llm_flash": 16160, "kimi_linear": 20480}
+            "ouro": 49152, "joyai_llm_flash": 16160, "kimi_linear": 20480,
+            "KeyeVL2": 18992}
     for name in CELLS:
         cell = spec_mod.load_cell(name)
         cfg = runner.launch_config(cell, seed=5)
@@ -74,7 +75,7 @@ def test_scopes_come_from_every_committed_configuration():
     assert spantree.model_scopes({}) == [
         "embed", "attn", "mlp", "head_loss", "update", "router", "dispatch",
         "experts", "attn_window", "conv", "conv_mix", "exit_gate", "mla_proj",
-        "shared_expert", "kda_proj", "kda_scan", "kda_out"]
+        "shared_expert", "kda_proj", "kda_scan", "kda_out", "index"]
 
 
 def olmoe_cases():
@@ -475,10 +476,11 @@ def test_the_parent_fails_the_new_cell_at_once():
     cell on the parent needs."""
     bench = spec_mod.load_bench()
     names = [w["name"] for w in bench["workloads"]]
-    assert names[-4:] == [LFM2_CELL, OURO_CELL, JOYAI_CELL, KIMI_CELL] \
-        and len(names) == 9
+    assert names[-5:] == [LFM2_CELL, OURO_CELL, JOYAI_CELL, KIMI_CELL,
+                          KEYE_CELL] and len(names) == 10
     for missing in ("lfm2-l5e8-locals", "ouro-l6-locals",
-                    "joyai-l5e8-locals", "kimi-linear-l5e8-locals"):
+                    "joyai-l5e8-locals", "kimi-linear-l5e8-locals",
+                    "keye-l6e8-locals"):
         with pytest.raises(spec_mod.SpecError, match="no workload"):
             spec_mod.load_cell(missing)
 
@@ -761,8 +763,9 @@ def test_joyais_mix_keeps_to_the_traffic_its_issue_fixed():
         if metric["name"] in JOYAI_METRICS[2:]:
             assert metric["workloads"] == [JOYAI_CELL]
         elif metric["name"] in JOYAI_APPENDED + JOYAI_METRICS[:2]:
-            # the cell of PR 43, which has these layers too, follows it
-            assert JOYAI_CELL in metric["workloads"][-2:]
+            # the cells of PR 43 and PR 46, which have some of these
+            # layers too, follow it
+            assert JOYAI_CELL in metric["workloads"][-3:]
 
 
 def test_joyais_readers_find_nothing_in_a_run_without_the_block():
@@ -1007,7 +1010,8 @@ def test_kimis_mix_keeps_to_the_traffic_its_issue_fixed():
         if metric["name"] in KIMI_METRICS:
             assert metric["workloads"] == [KIMI_CELL]
         elif metric["name"] in KIMI_APPENDED:
-            assert metric["workloads"][-1] == KIMI_CELL
+            # the cell of PR 46 follows it where it has the layer
+            assert KIMI_CELL in metric["workloads"][-2:]
         elif "workloads" in metric:
             assert KIMI_CELL not in metric["workloads"], metric["name"]
 
@@ -1108,6 +1112,232 @@ def kimi_cases():
 @pytest.mark.parametrize("what,got,want", kimi_cases(),
                          ids=[c[0] for c in kimi_cases()])
 def test_kimi_arithmetic_by_hand_through_the_cell(what, got, want):
+    assert got == want, what
+
+
+# -- the Keye configuration (PR 46) -----------------------------------------------
+
+KEYE_CELL = "keye-l6e8-local"
+KEYE_METRICS = ("dsa_ms_per_step", "dsa_index_ms_per_step",
+                "dsa_index_roofline", "dsa_kept_pct",
+                "dsa_window_overlap_pct")
+KEYE_APPENDED = ("dispatch_ms_per_step", "expert_load_max_over_mean",
+                 "held_experts_ms_per_step", "held_experts_roofline",
+                 "held_rows_share_pct", "compact_dispatch_pct")
+
+
+def test_keye_file_has_the_catalogs_keys_and_the_floor_cuts():
+    """Every key of the catalog's entry under its own name (the
+    model-configs guide's ``architectures.jsonl``, read where it is
+    installed; the hand-copied values below where it is not); only the
+    depth, the experts held and the vocabulary differ, each at the
+    guide's floor, with the published values beside them; no width is
+    cut, inside ``sa_config`` or outside."""
+    import pathlib
+
+    cell = spec_mod.load_cell(KEYE_CELL)
+    config = cell.config
+    catalog = {
+        "attention_bias": False, "decoder_sparse_step": 1, "head_dim": 128,
+        "hidden_act": "silu", "hidden_size": 2048,
+        "intermediate_size": 6144, "max_position_embeddings": 262144,
+        "max_window_layers": 48, "mlp_only_layers": [],
+        "model_type": "KeyeVL2", "moe_intermediate_size": 768,
+        "norm_topk_prob": True, "num_attention_heads": 32,
+        "num_experts": 128, "num_experts_per_tok": 8,
+        "num_hidden_layers": 48, "num_key_value_heads": 4,
+        "num_local_experts": 128, "rms_norm_eps": 1e-06,
+        "rope_scaling": {"mrope_section": [16, 24, 24],
+                         "rope_type": "default", "type": "default"},
+        "rope_theta": 10000000,
+        "sa_config": {"indexer_head_dim": 64, "indexer_num_heads": 16,
+                      "indexer_num_kv_heads": 1, "kv_chunk_size": 512,
+                      "q_chunk_size": 512, "topk": 2048},
+        "sliding_window": None, "tie_word_embeddings": False,
+        "use_sliding_window": False, "vocab_size": 151936}
+    path = pathlib.Path(
+        "/opt/skills/guides/model-configs/architectures.jsonl")
+    if path.exists():
+        rows = [json.loads(line) for line in path.read_text().splitlines()]
+        entry = next(r for r in rows if r["name"] == "Keye-VL-2.0-30B-A3B")
+        assert entry["config"] == catalog
+        assert entry["source_url"] == config["source"]
+    assert all(key in config for key in catalog)
+    differ = sorted(k for k, v in catalog.items() if config[k] != v)
+    assert differ == sorted(config["reduced"]) == [
+        "num_experts", "num_hidden_layers", "vocab_size"]
+    assert config["published"] == {k: catalog[k] for k in config["reduced"]}
+    # the floors: six layers (one is the period), 8 experts, an eighth
+    # of the vocabulary
+    assert (config["num_hidden_layers"], config["num_experts"],
+            config["vocab_size"]) == (6, 8, 151936 // 8)
+    assert config["router_experts"] == 128   # the router keeps its width
+    assert config["train_seq"] == 8192
+    sa = config["sa_config"]
+    assert (config["index_heads"], config["index_head_dim"],
+            config["index_topk"]) == (sa["indexer_num_heads"],
+                                      sa["indexer_head_dim"], sa["topk"])
+    entry = next(c for c in cell.bench["configs"]
+                 if c["name"] == cell.config_name)
+    assert sorted(entry["reduced"]) == differ
+    assert entry["source"] == config["source"]
+    assert (cell.chips, cell.traffic_name) == (1, "local-msgd-s8k-keye")
+    assert ["embed", "index", "attn", "router", "dispatch", "experts",
+            "head_loss", "update"] == config["scopes"]
+    assert all(key in config for key in config["launcher_from"].values())
+    assert all(key in config for key in config["tiny"])
+    assert len(config["assumed"]) >= 8 and "16 v5e chips" in \
+        config["deployment"]
+    assert cell.arithmetic().param_count(config) == 432_697_600
+
+
+def test_the_launcher_builds_the_selecting_block_from_the_cells_files():
+    from chipbench import run as runner
+    from mpit_tpu.lm.model import build_kw
+    from mpit_tpu.train.launch import lm_trainer_cfg
+
+    cell = spec_mod.load_cell(KEYE_CELL)
+    kw = build_kw(lm_trainer_cfg(runner.launch_config(cell, seed=5)))
+    assert (kw["arch"], kw["d_model"], kw["n_heads"], kw["kv_heads"],
+            kw["head_dim"], kw["n_layers"], kw["seq_len"], kw["vocab"]) \
+        == ("keye", 2048, 32, 4, 128, 6, 8192, 18992)
+    assert (kw["index_heads"], kw["index_head_dim"], kw["index_topk"]) \
+        == (16, 64, 2048)
+    assert (kw["n_experts"], kw["experts_held"], kw["experts_first"],
+            kw["experts_per_tok"], kw["expert_width"]) == (128, 8, 0, 8, 768)
+    assert (kw["rope_theta"], kw["norm_eps"]) == (1e7, 1e-6)
+
+
+def test_keyes_mix_keeps_to_the_traffic_its_issue_fixed():
+    """ISSUE 46 fixed the mix before any code was written: the rate one
+    of three, the budget a whole number of micro-steps of 8192, momentum
+    0.9, two rounds of warm-up, closed loop in one process; the five new
+    metrics and the six appended ones are the cell's, and the
+    selection's two counters move the loss, not the rate."""
+    cell = spec_mod.load_cell(KEYE_CELL)
+    mix = cell.traffic
+    assert cell.config["train_seq"] == 8192
+    steps, rest = divmod(mix["token_budget"],
+                         mix["batch"] * cell.config["train_seq"])
+    assert rest == 0 and steps >= 8
+    assert mix["lr"] in (0.003, 0.01, 0.03)
+    assert (mix["launcher"]["mom"], mix["warmup_rounds"], mix["su"],
+            mix["batch"], mix["launcher"]["np"],
+            mix["launcher"]["lm_use_flash"]) == (0.9, 2, 1, 1, 1, 1)
+    moves = {m["name"]: m["moves"] for m in cell.metrics("per_layer")}
+    assert set(KEYE_METRICS + KEYE_APPENDED) <= set(moves)
+    assert {moves[m] for m in KEYE_METRICS[3:]} == {"loss_at_budget"}
+    assert {moves[m] for m in KEYE_METRICS[:3]} == {"tokens_per_s"}
+    layers = {m["name"]: m["layer"] for m in cell.bench["per_layer"]}
+    assert layers["dsa_index_roofline"] == layers["flash_roofline"]
+    assert layers["dsa_ms_per_step"] == layers["mla_proj_ms_per_step"]
+    for metric in cell.bench["per_layer"]:
+        if metric["name"] in KEYE_METRICS:
+            assert metric["workloads"] == [KEYE_CELL]
+        elif metric["name"] in KEYE_APPENDED:
+            assert metric["workloads"][-1] == KEYE_CELL
+        elif "workloads" in metric:
+            assert KEYE_CELL not in metric["workloads"], metric["name"]
+    assert cell.bench["per_layer"][-5:] == [
+        m for m in cell.bench["per_layer"] if m["name"] in KEYE_METRICS]
+    assert (cell.bench["configs"][-1]["name"],
+            cell.bench["workloads"][-1]["name"]) == (cell.config_name,
+                                                     KEYE_CELL)
+
+
+def test_keyes_readers_find_nothing_in_a_run_without_the_block():
+    """What the parent's traced run hands them: a cell whose
+    configuration lists no such scope, a program that recorded no such
+    counter, no device trace, no merged trace: None, no raise."""
+    for name in ("mellum2-l4e8-local", "c111m-local"):
+        cell = spec_mod.load_cell(name)
+        run = {"cell": cell, "reduction": {"step_module": "jit__lambda"},
+               "obs_trace": None, "peaks": None, "results": {},
+               "summary": {"worker_ranks": [0], "window": [0.0, 1.0]}}
+        for metric in KEYE_METRICS:
+            reader = spec_mod.load_reader(cell.root, cell.bench, metric)
+            assert reader is not None and reader(dict(run)) is None
+
+
+def test_keyes_readers_read_a_hand_made_run(monkeypatch):
+    """The five readers, the six shared ones and the metrics without a
+    ``workloads`` list that the cell has to report, on a scope table and
+    a span tree made by hand."""
+    from chipbench import flops
+    from chipbench.layers import spantree
+
+    cell = spec_mod.load_cell(KEYE_CELL)
+    listed = [m["name"] for m in cell.metrics("per_layer")]
+    unlisted = [m["name"] for m in cell.bench["per_layer"]
+                if "workloads" not in m]
+    assert len(unlisted) == 10 and set(unlisted) <= set(listed)
+
+    class Round:
+        def __init__(self, k, overlap):
+            self.args = {"round": k, "lm_dsa_kept_share": [0.4375] * 6,
+                         "lm_dsa_window_overlap": overlap,
+                         "moe_held_rows_share": [0.0625] * 6,
+                         "moe_load_max_over_mean": [2.0, 2.5, 2.25, 3.0, 2.0,
+                                                    2.0],
+                         "moe_compact_share": [1.0] * 6}
+
+    class Tree:
+        def rounds(self):
+            return [Round(7, [0.5] * 6), Round(8, [0.4, 0.6] * 3),
+                    Round(9, [0.3] * 6)]
+
+    table = {"step": 500.0, "index": 120.0, "attn": 180.0, "router": 6.0,
+             "dispatch": 9.0, "experts": 12.0, "head_loss": 30.0,
+             "update": 25.0}
+    monkeypatch.setattr(spantree, "scope_ms_per_step", lambda run: table)
+    monkeypatch.setattr(spantree, "xplane_path", lambda run: None)
+    run = {"cell": cell, "peaks": flops.load_peaks("TPU v5 lite"),
+           spantree.CACHE_KEY: Tree(),
+           "summary": {"tokens_per_s": 15000.0, "worker_ranks": [0]},
+           "reduction": {"step_module": "jit__lambda", "step_module_runs": 2,
+                         "mosaic_by_scope": {
+                             "attn": (36, 0.240), "experts": (72, 0.020),
+                             "update": (2, 0.030)}}}
+
+    def read(name):
+        return spec_mod.load_reader(cell.root, cell.bench, name)(run)
+
+    assert read("dsa_ms_per_step") == pytest.approx(300.0)
+    assert read("dsa_index_ms_per_step") == pytest.approx(120.0)
+    cost = cell.arithmetic().index_cost(cell.config, 1)
+    assert cost["flops"] / 197e12 > cost["bytes"] / 819e9   # compute binds
+    assert read("dsa_index_roofline") == pytest.approx(
+        100 * cost["flops"] / 197e12 / 0.120)
+    assert read("dsa_kept_pct") == pytest.approx(43.75)
+    assert read("dsa_window_overlap_pct") == pytest.approx(50.0)
+    assert read("dispatch_ms_per_step") == pytest.approx(15.0)
+    assert read("held_experts_ms_per_step") == pytest.approx(12.0)
+    assert read("held_rows_share_pct") == pytest.approx(6.25)
+    assert read("expert_load_max_over_mean") == pytest.approx(3.0)
+    assert read("compact_dispatch_pct") == pytest.approx(100.0)
+    assert read("head_loss_ms_per_step") == pytest.approx(30.0)
+    assert read("flash_ms_per_step") == pytest.approx(120.0)
+    family = cell.arithmetic().kernels(cell.config, 1)["attn"]
+    assert read("flash_roofline") == pytest.approx(
+        100 * family["flops"] / 197e12 / 0.120)
+    experts = cell.arithmetic().experts_cost(cell.config, 1)
+    assert read("held_experts_roofline") == pytest.approx(
+        100 * max(experts["flops"] / 197e12, experts["bytes"] / 819e9)
+        / 0.010)
+    assert read("mfu_pct") == pytest.approx(
+        100 * 1_613_211_648 * 15000.0 / 197e12)
+    for metric in cell.metrics("per_layer"):
+        assert spec_mod.load_reader(cell.root, cell.bench,
+                                    metric["name"]) is not None
+
+
+def keye_cases():
+    return spec_mod.load_cell(KEYE_CELL).arithmetic().hand_worked()
+
+
+@pytest.mark.parametrize("what,got,want", keye_cases(),
+                         ids=[c[0] for c in keye_cases()])
+def test_keye_arithmetic_by_hand_through_the_cell(what, got, want):
     assert got == want, what
 
 
