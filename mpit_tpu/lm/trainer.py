@@ -37,7 +37,7 @@ from mpit_tpu.lm.data import PackedStream
 from mpit_tpu.lm.model import build, build_kw
 from mpit_tpu.obs import PhaseTimers, get_registry, profiler_trace
 from mpit_tpu.optim import EAMSGD, MSGD, Downpour, RuleShell
-from mpit_tpu.optim.msgd import MSGDConfig
+from mpit_tpu.optim.msgd import MSGDConfig, committed
 from mpit_tpu.utils.config import Config
 from mpit_tpu.utils.logging import get_logger
 
@@ -149,10 +149,14 @@ class LmTrainer:
 
     # -- evaluation -----------------------------------------------------------
 
+    # the vector to evaluate or save (``self.w`` is the optimizer's to
+    # hand back)
+    params = property(committed)
+
     def eval_loss(self, w: Optional[jnp.ndarray] = None) -> float:
         """Mean NLL over ``eval_batches`` fixed batches of the disjoint
         eval stream — a pure read of ``w`` (or the live params)."""
-        w = self.w if w is None else w
+        w = self.params if w is None else w
         losses = [
             float(self._loss(w, jnp.asarray(self.eval_stream.batch_at(i))))
             for i in range(max(self.cfg.eval_batches, 1))

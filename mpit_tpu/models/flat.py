@@ -6,10 +6,15 @@ The reference trains on a single flat tensor aliasing all model weights
 aliasing we cut the vector into the module's leaves inside jit
 (:func:`leaf_unravel`) and assemble the gradient from the leaves'.  That
 is not free on the chip: the gradient's concatenation is a sweep of the
-vector (11.6 ms of a 243 ms step at 486M elements) and, until PR 41, the
+vector (11.9 ms of a 190 ms step at 486M elements) and, until PR 41, the
 TPU compiler re-laid the *whole* vector as ``[N/64, 64]`` three times a
-step for ten leaves 64 wide (27.6 ms of the same step; PERF.md section
-5, ``lfm2-l5e8-local``).
+step for ten leaves 64 wide (27.6 ms of what was a 243 ms step; PERF.md
+section 5, ``lfm2-l5e8-local``).  Outside the model a local msgd step
+now sweeps the vector twice: that concatenation, and the optimizer's one
+kernel (14.9 ms there), which since PR 47 writes the next step's
+lookahead with the commit (``optim/msgd.py``; the separate lookahead
+pass was a third sweep, 11.8 ms).  A commit that reads the leaves'
+gradients where they lie would leave one.
 """
 
 from __future__ import annotations

@@ -7,7 +7,9 @@ reference asyncsgd/ptest.lua:3) is read and written exactly once:
 
 - :func:`fused_nesterov_commit` — the msgd commit phase
   (reference asyncsgd/optim-msgd.lua:31-39): ``w -= clr*g; vt -= clr*g``
-  with optional fused L2.
+  with optional fused L2 and, given the next step's momentum, that
+  step's lookahead on the same block (``vt *= mom; w += vt``, reference
+  :24-29), so a local step sweeps ``w`` and ``vt`` once.
 - :func:`fused_adam` — the server-side Adam shard rule
   (reference BiCNN/pserver.lua:140-155): moment updates + step in one pass.
 - :func:`fused_elastic` — the EASGD elastic exchange's elementwise half
@@ -16,7 +18,10 @@ reference asyncsgd/ptest.lua:3) is read and written exactly once:
 
 Semantics match :mod:`mpit_tpu.optim.msgd` / :mod:`mpit_tpu.optim.rules`
 bit-for-bit in f32; the ``*_reference`` twins are the contract (and the
-CPU fallback — kernels run in interpret mode off-TPU).
+CPU fallback — kernels run in interpret mode off-TPU).  The Nesterov
+kernel sweeps its operands as they are, in 1-D blocks of any length
+(``_flat_spec``); the Adam and elastic kernels pad theirs to whole
+``(rows, 128)`` blocks (``_row_spec``).
 """
 
 from __future__ import annotations
@@ -65,8 +70,8 @@ def _scalar(x, dtype) -> jnp.ndarray:
     return jnp.asarray(x, dtype).reshape(1, 1)
 
 
-def _scalar_spec():
-    return pl.BlockSpec((1, 1), lambda i: (0, 0), memory_space=pltpu.SMEM)
+def _scalar_spec(count: int = 1):
+    return pl.BlockSpec((1, count), lambda i: (0, 0), memory_space=pltpu.SMEM)
 
 
 def _row_spec(block_rows: int):
@@ -82,7 +87,7 @@ def _flat_spec(block: int):
 # ---------------------------------------------------------------------------
 
 
-def _nesterov_kernel(clr_ref, w_ref, vt_ref, g_ref, *rest, l2wd, retract):
+def _nesterov_kernel(scalars, w_ref, vt_ref, g_ref, *rest, l2wd, retract, fold):
     if retract:
         sug_ref, w_out, vt_out = rest
     else:
@@ -90,23 +95,31 @@ def _nesterov_kernel(clr_ref, w_ref, vt_ref, g_ref, *rest, l2wd, retract):
     g = g_ref[:]
     if l2wd != 0.0:
         g = g + l2wd * w_ref[:]
-    step = clr_ref[0, 0] * g
+    step = scalars[0, 0] * g
     w = w_ref[:] - step
     if retract:
         w = w - sug_ref[:]
+    vt = vt_ref[:] - step
+    if fold:
+        vt = scalars[0, 1] * vt
+        w = w + vt
     w_out[:] = w
-    vt_out[:] = vt_ref[:] - step
+    vt_out[:] = vt
 
 
 def fused_nesterov_commit_reference(w, vt, g, clr, *, l2wd: float = 0.0,
-                                    sug=None):
+                                    sug=None, mom_next=None):
     if l2wd != 0.0:
         g = g + l2wd * w
     step = jnp.asarray(clr, w.dtype) * g
     w_new = w - step
     if sug is not None:
         w_new = w_new - sug
-    return w_new, vt - step
+    vt_new = vt - step
+    if mom_next is not None:
+        vt_new = jnp.asarray(mom_next, w.dtype) * vt_new
+        w_new = w_new + vt_new
+    return w_new, vt_new
 
 
 def fused_nesterov_commit(
@@ -117,6 +130,7 @@ def fused_nesterov_commit(
     *,
     l2wd: float = 0.0,
     sug: jnp.ndarray | None = None,
+    mom_next=None,
     interpret: bool | None = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """One-sweep msgd commit: ``(w - clr*g_eff, vt - clr*g_eff)`` where
@@ -125,46 +139,47 @@ def fused_nesterov_commit(
     With ``sug`` the elastic retract of the EASGD sync round rides the
     same sweep — ``w - clr*g_eff - sug`` — so commit + retract cost one
     HBM pass instead of two (reference optim-eamsgd.lua:66 applies the
-    retract right after its localupdate)."""
+    retract right after its localupdate).
+
+    With ``mom_next`` (the next step's momentum, traced or not) that
+    step's lookahead rides it too: the committed pair ``(w, vt)`` above
+    becomes ``vt' = mom_next*vt`` and ``w + vt'``, the displaced point
+    and the scaled velocity a local step starts from
+    (:func:`mpit_tpu.optim.msgd.msgd_step`), in the order the two phases
+    compute them.
+
+    The vector is swept as it is, in 1-D blocks, whatever its length:
+    no padded copy and no other view of it is made, which is what lets
+    a caller's donated ``w`` and ``vt`` be updated where they lie (a
+    pad copies each operand whole and the slice back each result).  The
+    grid's last block may overhang the end: what it reads past it is
+    unspecified and what it writes there is dropped, and the kernel is
+    elementwise."""
     n = w.shape[0]
-    br = block_rows_for(n)
-    if n % LANE == 0:
-        # Whole lanes: the vector is its own (rows, 128) view and no
-        # padded copy of it is made, which is what lets a caller's
-        # donated ``w`` and ``vt`` be updated where they lie (a pad
-        # copies each operand whole and the slice back each result).
-        # The grid's last block may overhang the rows: what it reads
-        # past the end is unspecified and what it writes there is
-        # dropped, and the kernel is elementwise.
-        view, block, spec = (n // LANE, LANE), br, _row_spec(br)
-    else:
-        # No whole number of lanes: no (rows, 128) view exists, and a
-        # slice of the aligned prefix would be a copy of each operand as
-        # whole as the pad's.  The same kernel sweeps the vector as it
-        # is, in 1-D blocks of as many elements; the last one overhangs
-        # the tail of under a lane as above, so nothing is copied and
-        # no vector changes length.
-        view, block, spec = (n,), br * LANE, _flat_spec(br * LANE)
-    retract = sug is not None
-    vectors = [x.reshape(view) for x in (w, vt, g)]
-    operands = [_scalar(clr, w.dtype), *vectors]
-    in_specs = [_scalar_spec(), spec, spec, spec]
+    block = block_rows_for(n) * LANE
+    spec = _flat_spec(block)
+    retract, fold = sug is not None, mom_next is not None
+    scalars = jnp.concatenate(
+        [_scalar(x, w.dtype) for x in ((clr, mom_next) if fold else (clr,))],
+        axis=1)
+    operands = [scalars, w, vt, g]
+    in_specs = [_scalar_spec(scalars.shape[1]), spec, spec, spec]
     if retract:
-        operands.append(sug.reshape(view))
+        operands.append(sug)
         in_specs.append(spec)
-    w_new, vt_new = pl.pallas_call(
-        functools.partial(_nesterov_kernel, l2wd=float(l2wd), retract=retract),
-        grid=(pl.cdiv(view[0], block),),
+    return pl.pallas_call(
+        functools.partial(_nesterov_kernel, l2wd=float(l2wd), retract=retract,
+                          fold=fold),
+        grid=(pl.cdiv(n, block),),
         in_specs=in_specs,
         out_specs=(spec, spec),
         out_shape=(
-            jax.ShapeDtypeStruct(view, w.dtype),
-            jax.ShapeDtypeStruct(view, vt.dtype),
+            jax.ShapeDtypeStruct((n,), w.dtype),
+            jax.ShapeDtypeStruct((n,), vt.dtype),
         ),
         input_output_aliases={1: 0, 2: 1},
         interpret=_interpret(interpret),
     )(*operands)
-    return w_new.reshape(n), vt_new.reshape(n)
 
 
 # ---------------------------------------------------------------------------

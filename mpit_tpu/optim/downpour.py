@@ -94,6 +94,12 @@ class Downpour:
         self._started = True
         return w
 
+    def params(self, w: jnp.ndarray) -> jnp.ndarray:
+        """The vector to evaluate or save behind the ``w`` that
+        :meth:`step` returned: ``w`` itself (:class:`mpit_tpu.optim.MSGD`
+        is the optimizer whose may differ)."""
+        return w
+
     def step(self, w: jnp.ndarray, *fn_args: Any) -> Tuple[jnp.ndarray, jnp.ndarray]:
         assert self._started, "call start(w) first"
         k = jnp.asarray(self.k, jnp.int32)

@@ -11,11 +11,26 @@ Semantics follow reference asyncsgd/optim-msgd.lua exactly:
 4. lr decay ``clr = lr/(1 + k*lrd)^lrp`` (reference :33-35);
 5. ``w -= clr*g; vt -= clr*g`` (reference :36-39), step counter ``k += 1``.
 
-TPU-native shape: the whole step — lookahead, loss/grad, commit — is one
-pure function suitable for ``jax.jit`` and ``lax.scan`` over minibatches.
-The lookahead/commit halves are also exported separately because the
-EASGD/EAMSGD wrapper interleaves parameter-server traffic between them
-(reference optim-eamsgd.lua:24-45 embeds the same local update).
+TPU-native shape: the whole step is one pure function suitable for
+``jax.jit`` and ``lax.scan`` over minibatches.  On a flat vector under
+the fused build it sweeps the vector once: :func:`msgd_step` does step
+k's 3-5 and then step k+1's 2 on the block the commit kernel holds (with
+``mom_{k+1}``: the ramp keeps its schedule), so between steps ``w`` is
+the *displaced* point ``w + mom*vt`` and ``state["vt"]`` the scaled
+velocity ``mom*vt``.  From :func:`msgd_init`'s zero velocity the
+displaced point is the seeded vector, so the first step is no special
+case.  Anything else (a pytree, the unfused build, ``mom <= 0``) runs
+the five in the reference's order and keeps the reference's pair.  The
+losses are taken where the reference takes them either way, and what
+the pair holds between steps is the step's own business:
+:func:`msgd_params` reads the committed vector out of it for whoever
+evaluates, saves or ships it.
+
+The lookahead/commit halves are exported separately because the
+EASGD/EAMSGD wrapper and the mesh trainers interleave an exchange
+between them (reference optim-eamsgd.lua:24-45 embeds the same local
+update).  Their state is the reference's: ``w`` committed and ``vt``
+unscaled between steps.
 """
 
 from __future__ import annotations
@@ -26,7 +41,10 @@ import jax
 import jax.numpy as jnp
 
 from mpit_tpu.obs import get_recorder
-from mpit_tpu.ops.fused_update import fused_enabled as _fused_enabled
+from mpit_tpu.ops.fused_update import (
+    fused_enabled as _fused_enabled,
+    fused_nesterov_commit,
+)
 
 
 class MSGDConfig(NamedTuple):
@@ -44,6 +62,10 @@ class MSGDConfig(NamedTuple):
 
 
 def msgd_init(w: Any) -> dict:
+    """Step counter and zero velocity: the start of either form (the
+    module text: on the kernel's path :func:`msgd_step` keeps ``vt``
+    scaled by the coming step's momentum, :func:`msgd_lookahead` /
+    :func:`msgd_commit` keep it unscaled)."""
     return {
         "k": jnp.zeros((), jnp.int32),
         "vt": jax.tree_util.tree_map(jnp.zeros_like, w),
@@ -76,6 +98,13 @@ def msgd_lookahead(w: Any, state: dict, cfg: MSGDConfig) -> Tuple[Any, dict]:
     return w, {"k": state["k"], "vt": vt}
 
 
+def _takes_kernel(w: Any, cfg: MSGDConfig) -> bool:
+    """Flat 1-D params with momentum take the fused pallas sweep when
+    enabled (on a TPU, unless ``cfg.use_fused`` says otherwise)."""
+    return (cfg.mom > 0 and isinstance(w, jnp.ndarray) and w.ndim == 1
+            and _fused_enabled(cfg.use_fused))
+
+
 def msgd_commit(w: Any, grad: Any, state: dict, cfg: MSGDConfig) -> Tuple[Any, dict]:
     """Phase 2: weight-decay, decayed-lr descent, velocity update (:31-40).
 
@@ -83,14 +112,7 @@ def msgd_commit(w: Any, grad: Any, state: dict, cfg: MSGDConfig) -> Tuple[Any, d
     (:func:`mpit_tpu.ops.fused_update.fused_nesterov_commit`) when enabled
     — one HBM read/write of (w, vt, g) instead of several."""
     clr = _effective_lr(cfg, state["k"])
-    if (
-        cfg.mom > 0
-        and isinstance(w, jnp.ndarray)
-        and w.ndim == 1
-        and _fused_enabled(cfg.use_fused)
-    ):
-        from mpit_tpu.ops.fused_update import fused_nesterov_commit
-
+    if _takes_kernel(w, cfg):
         w_new, vt = fused_nesterov_commit(
             w, state["vt"], grad, clr, l2wd=float(cfg.l2wd)
         )
@@ -113,17 +135,53 @@ def msgd_step(
 ) -> Tuple[Any, dict, jnp.ndarray]:
     """One full msgd step: lookahead -> grad at displaced w -> commit.
 
+    What it returns is what the next call takes, and no more is promised
+    of the pair; :func:`msgd_params` reads the committed vector out of
+    it.  A flat vector that takes the commit kernel (``_takes_kernel``:
+    ``w.ndim``, ``mom`` and the backend, as :func:`msgd_commit` chooses)
+    is swept once: the gradient is taken at ``w`` as handed in, which is
+    the displaced point, and the kernel commits and then writes the next
+    step's lookahead over its operands (the module text).  Anything else
+    runs the two phases round the gradient and keeps their pair.
+
     ``value_and_grad_fn(w, *fn_args) -> (loss, grad)`` is the feval closure
     analog (reference goot.lua:101-126).  Pure; jit the caller
     (:class:`MSGD` does, and donates ``w`` and ``state`` to the jitted
     step: there the caller's arrays are consumed).
     """
+    if not _takes_kernel(w, cfg):
+        with jax.named_scope("update"):
+            w_la, state = msgd_lookahead(w, state, cfg)
+        loss, grad = value_and_grad_fn(w_la, *fn_args)
+        with jax.named_scope("update"):
+            w_new, state = msgd_commit(w_la, grad, state, cfg)
+        return w_new, state, loss
+    loss, grad = value_and_grad_fn(w, *fn_args)
+    k = state["k"]
     with jax.named_scope("update"):
-        w_la, state = msgd_lookahead(w, state, cfg)
-    loss, grad = value_and_grad_fn(w_la, *fn_args)
-    with jax.named_scope("update"):
-        w_new, state = msgd_commit(w_la, grad, state, cfg)
-    return w_new, state, loss
+        w_new, vt = fused_nesterov_commit(
+            w, state["vt"], grad, _effective_lr(cfg, k), l2wd=float(cfg.l2wd),
+            mom_next=_effective_momentum(cfg, k + 1))
+    return w_new, {"k": k + 1, "vt": vt}, loss
+
+
+def msgd_params(w: Any, state: dict, cfg: MSGDConfig) -> Any:
+    """The committed vector behind :func:`msgd_step`'s pair: what to
+    evaluate, save or ship.  On the kernel's path that is ``w - vt``,
+    within one rounding of the vector the two phases would have
+    committed (``(w + vt) - vt``) and ``w`` itself while the velocity is
+    zero (no step yet); on the phases' path it is ``w``."""
+    return w - state["vt"] if _takes_kernel(w, cfg) else w
+
+
+def committed(trainer: Any) -> Any:
+    """The vector a trainer evaluates or saves: its optimizer's
+    ``params`` of its ``w`` (every optimizer of this package has one,
+    the identity but for :func:`msgd_step`'s), and ``w`` itself while
+    the trainer's ``optimizer``, a cached property, is not built: an
+    eval-only role never builds one."""
+    opt = vars(trainer).get("optimizer")
+    return trainer.w if opt is None else opt.params(trainer.w)
 
 
 class MSGD:
@@ -139,8 +197,12 @@ class MSGD:
         server's shells take it (optim/shells.py).  Each step is then a
         ``round`` span while obs records (phases ``step`` and
         ``telemetry``), with the statistics noted on it, set on their
-        gauges and kept as ``stats_last``; with obs off they are never
-        fetched and no span exists."""
+        gauges and kept as ``stats_last``; the first of them also says
+        what every step does with the vector, a constant of the run
+        (``commit``: ``kernel`` or ``xla``; ``lookahead``: ``folded``
+        into the commit's sweep, a ``pass`` of its own, or ``none`` at
+        ``mom <= 0``); with obs off they are never fetched and no span
+        exists."""
         self.cfg = cfg
         # ``w`` and ``state`` are donated: the step writes the new
         # vector and momentum where the old ones lay, so it holds each
@@ -155,14 +217,24 @@ class MSGD:
         self._spans = get_recorder()
         self.rounds = 0  # steps done: the ``round`` of the spans
         self.stats_last: dict = {}  # name -> the last recorded step's values
+        self._sweep_noted = False
+
+    def params(self, w: Any) -> Any:
+        """The committed vector behind the ``w`` that :meth:`step`
+        returned (:func:`msgd_params`): what to evaluate or save."""
+        return w if self.state is None else msgd_params(w, self.state, self.cfg)
 
     def step(self, w: Any, *fn_args: Any) -> Tuple[Any, jnp.ndarray]:
-        """One step; returns the new ``w`` and the loss.  The ``w``
-        passed in is donated to the step and unreadable afterwards:
-        keep only what this returns.  The first call's is the
-        exception: a trainer starts from its model's seeded vector
-        (``flat.w0``, which ``LmTrainer.w`` aliases and the benchmark
-        reads again after warm-up), so that call steps on a copy."""
+        """One step; returns the new ``w`` and the loss.  ``w`` is
+        :func:`msgd_step`'s, to be handed back as it is: on the kernel's
+        path the point the next step's gradient is taken at
+        (:meth:`params` reads the committed vector).  The ``w`` passed
+        in is donated to the step
+        and unreadable afterwards: keep only what this returns.  The
+        first call's is the exception: a trainer starts from its model's
+        seeded vector (``flat.w0``, which ``LmTrainer.w`` aliases and
+        the benchmark reads again after warm-up), so that call steps on
+        a copy."""
         if self.state is None:
             self.state = msgd_init(w)
             w = jax.tree_util.tree_map(jnp.copy, w)
@@ -178,6 +250,13 @@ class MSGD:
             jax.block_until_ready(w)
             span.mark("telemetry")
             note_stats(self, span, stats)
+            if not self._sweep_noted:
+                kernel = _takes_kernel(w, self.cfg)
+                span.note(
+                    commit="kernel" if kernel else "xla",
+                    lookahead="folded" if kernel
+                    else "pass" if self.cfg.mom > 0 else "none")
+                self._sweep_noted = True
         span.end()
         self.rounds += 1
         return w, loss

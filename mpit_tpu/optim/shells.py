@@ -39,7 +39,7 @@ import numpy as np
 from mpit_tpu.obs import get_recorder, get_registry
 from mpit_tpu.optim import rules as rules_mod
 from mpit_tpu.optim.client_api import ParamClientAPI
-from mpit_tpu.optim.msgd import MSGDConfig, msgd_init, msgd_step
+from mpit_tpu.optim.msgd import MSGDConfig, msgd_init, msgd_params, msgd_step
 from mpit_tpu.optim.sync import attach, push_pull
 
 
@@ -130,6 +130,12 @@ class RuleShell:
         self._started = True
         return w
 
+    def params(self, w: jnp.ndarray) -> jnp.ndarray:
+        """The vector to evaluate or save behind the ``w`` that
+        :meth:`step` returned: ``w`` itself (:class:`mpit_tpu.optim.MSGD`
+        is the optimizer whose may differ)."""
+        return w
+
     def step(self, w: jnp.ndarray, *fn_args: Any) -> Tuple[jnp.ndarray, jnp.ndarray]:
         assert self._started, "call start(w) first"
         if self.mode == "global":
@@ -178,8 +184,10 @@ class SingleWorker:
     ):
         self.pc = pclient
         self._started = False
+        self.state = None
+        self._msgd_cfg = None
         if rule == "msgd":
-            cfg = MSGDConfig(**hyperparams)
+            self._msgd_cfg = cfg = MSGDConfig(**hyperparams)
 
             def _step(w, state, *args):
                 return msgd_step(value_and_grad_fn, w, state, cfg, *args)
@@ -207,11 +215,20 @@ class SingleWorker:
         self._started = True
         return w
 
+    def params(self, w: jnp.ndarray) -> jnp.ndarray:
+        """The committed vector behind the ``w`` that :meth:`step`
+        returned: ``w`` itself but under ``rule="msgd"``, whose step may
+        return the point its next gradient is taken at
+        (:func:`mpit_tpu.optim.msgd.msgd_params`)."""
+        if self._msgd_cfg is None or self.state is None:
+            return w
+        return msgd_params(w, self.state, self._msgd_cfg)
+
     def step(self, w: jnp.ndarray, *fn_args: Any) -> Tuple[jnp.ndarray, jnp.ndarray]:
         assert self._started, "call start(w) first"
         w, self.state, loss = self._step_fn(w, self.state, *fn_args)
         # Push the whole parameter vector (reference optim-adam-single.lua:35-36).
-        np.copyto(self.w_host, np.asarray(w))
+        np.copyto(self.w_host, np.asarray(self.params(w)))
         self.pc.async_send_param()
         self.pc.wait()
         return w, loss

@@ -47,7 +47,7 @@ from mpit_tpu.models.bicnn import BiCNN, gesd, margin_ranking_loss
 from mpit_tpu.models.flat import FlatModel
 from mpit_tpu.optim import EAMSGD, MSGD, Downpour, RuleShell, SingleWorker
 from mpit_tpu.optim import rules as rules_mod
-from mpit_tpu.optim.msgd import MSGDConfig
+from mpit_tpu.optim.msgd import MSGDConfig, committed
 from mpit_tpu.utils.checkpoint import load_flat, save_flat
 from mpit_tpu.utils.config import Config
 from mpit_tpu.utils.logging import get_logger
@@ -513,13 +513,17 @@ class BiCNNTrainer:
         self._pool_cache[name] = (eval_set,) + tables
         return tables
 
+    # the vector to evaluate or save (``self.w`` is the optimizer's to
+    # hand back)
+    params = property(committed)
+
     def evaluate(
         self, eval_set: EvalSet, name: str, w=None, ans_emb: Optional[np.ndarray] = None
     ) -> float:
         """Pool-restricted answer selection accuracy for one dataset —
         one leg of test3 (bicnn.lua:465-510).  ``ans_emb`` lets test3
         embed the answer space once for all three datasets."""
-        w = self.w if w is None else w
+        w = self.params if w is None else w
         data = self.data
         with self.tm.phase("test"):
             if ans_emb is None:
@@ -542,7 +546,7 @@ class BiCNNTrainer:
         """Evaluate valid + test1 + test2 (bicnn.lua:465-571, :589).
         The answer space is embedded once and shared across the three
         datasets (the reference re-embeds it per dataset, :467-470)."""
-        w_eval = self.w if w is None else w
+        w_eval = self.params if w is None else w
         with self.tm.phase("test"):
             ans_emb = self._embed_chunked(
                 w_eval, self.data.answer_tokens, self.data.answer_len
@@ -562,7 +566,7 @@ class BiCNNTrainer:
         runtime = self.tm.elapsed() + float(self.cfg.prevtime)
         save_flat(
             path.parent if path.parent != pathlib.Path("") else pathlib.Path("."),
-            self.w,
+            self.params,
             {"runtime": runtime, "epoch": self.epoch, "best": dict(self.best)},
             prefix=path.name,
         )

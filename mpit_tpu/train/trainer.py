@@ -27,7 +27,7 @@ import numpy as np
 from mpit_tpu.data.mnist import load_mnist
 from mpit_tpu.models import MnistCNN, MnistLinear, MnistMLP, flatten_module
 from mpit_tpu.optim import EAMSGD, MSGD, Downpour, RuleShell, SingleWorker
-from mpit_tpu.optim.msgd import MSGDConfig
+from mpit_tpu.optim.msgd import MSGDConfig, committed
 from mpit_tpu.utils.config import Config
 from mpit_tpu.utils.logging import get_logger
 from mpit_tpu.obs import PhaseTimers, profiler_trace
@@ -154,8 +154,13 @@ class MnistTrainer:
 
     # -- evaluation ----------------------------------------------------------
 
+    # the vector to evaluate or save (``self.w`` is the optimizer's to
+    # hand back)
+    params = property(committed)
+
     def test_error(self, w: Optional[jnp.ndarray] = None) -> float:
-        return float(self._err(self.w if w is None else w, self.x_test, self.y_test))
+        w = self.params if w is None else w
+        return float(self._err(w, self.x_test, self.y_test))
 
     # -- the epoch loop (reference goot.lua:129-146) -------------------------
 

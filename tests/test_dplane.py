@@ -399,6 +399,7 @@ def _run_msgd(dplane, steps=5, size=32):
     w = opt.start(w)
     for _ in range(steps):
         w, _loss = opt.step(w)
+    w = opt.params(w)  # the committed vector: what the pushes carry
     opt.stop()
     join_all(threads)
     return np.asarray(w), np.concatenate(

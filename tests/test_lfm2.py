@@ -520,24 +520,44 @@ def test_the_seeded_weights_do_not_depend_on_the_samples_length():
 # -- donation: the local step consumes its vectors ------------------------------------
 
 
-def test_two_donated_steps_are_the_undonated_steps_bit_for_bit():
+@pytest.mark.parametrize("fused", [False, True], ids=["phases", "kernel"])
+def test_two_donated_steps_are_the_undonated_steps_bit_for_bit(fused):
     """``MSGD.step`` donates ``w`` and its state to the jitted step; the
     numbers are ``msgd_step``'s own.  The first call steps on a copy, so
     the model's seeded vector stays readable; what a later call is
-    handed is consumed."""
-    from mpit_tpu.optim.msgd import MSGD, MSGDConfig, msgd_init, msgd_step
+    handed is consumed.  Against ``msgd_lookahead`` / ``msgd_commit``
+    in turn the losses and ``msgd_params`` are the two phases': their
+    program to the bit off the kernel's path, and on it, where the step
+    hands back the point its next gradient is taken at, to rounding
+    (the CPU's compiler contracts the two programs' multiply-adds
+    differently; tests/test_optim_rules.py holds the arithmetic to the
+    bit)."""
+    from mpit_tpu.optim.msgd import (MSGD, MSGDConfig, msgd_commit,
+                                     msgd_init, msgd_lookahead, msgd_params,
+                                     msgd_step)
 
     model = build(arch="lfm2", seed=4, use_flash=False, **TINY)
     flat = model.flat
     rs = np.random.RandomState(1)
     batches = [jnp.asarray(rs.randint(0, 256, (2, TINY["seq_len"] + 1)),
                            jnp.int32) for _ in range(2)]
-    cfg = MSGDConfig(lr=0.1, mom=0.9)
+    cfg = MSGDConfig(lr=0.1, mom=0.9, use_fused=fused)
     plain = jax.jit(lambda w, state, tok: msgd_step(
         model.value_and_grad, w, state, cfg, tok))
     want, state = flat.w0, msgd_init(flat.w0)
     for tokens in batches:
         want, state, want_loss = plain(want, state, tokens)
+
+    @jax.jit
+    def phases(w, state, tok):
+        w_la, state = msgd_lookahead(w, state, cfg)
+        loss, grad = model.value_and_grad(w_la, tok)
+        return (*msgd_commit(w_la, grad, state, cfg), loss)
+
+    committed, phase_state = flat.w0, msgd_init(flat.w0)
+    for tokens in batches:
+        committed, phase_state, phase_loss = phases(committed, phase_state,
+                                                    tokens)
 
     opt = MSGD(cfg, model.value_and_grad)
     w1, _ = opt.step(flat.w0, batches[0])
@@ -546,6 +566,17 @@ def test_two_donated_steps_are_the_undonated_steps_bit_for_bit():
     np.testing.assert_array_equal(np.asarray(opt.state["vt"]),
                                   np.asarray(state["vt"]))
     assert float(loss) == float(want_loss)
+    np.testing.assert_array_equal(np.asarray(opt.params(w2)),
+                                  np.asarray(msgd_params(want, state, cfg)))
+    if fused:
+        np.testing.assert_allclose(float(loss), float(phase_loss), rtol=1e-6)
+        np.testing.assert_allclose(np.asarray(opt.params(w2)),
+                                   np.asarray(committed), rtol=1e-5, atol=1e-6)
+        assert not np.allclose(np.asarray(w2), np.asarray(committed), atol=1e-4)
+    else:
+        assert float(loss) == float(phase_loss)
+        np.testing.assert_array_equal(np.asarray(w2), np.asarray(committed))
+        assert opt.params(w2) is w2
     assert not flat.w0.is_deleted() and int(flat.w0.size) == flat.size
     assert float(jnp.sum(flat.w0)) == float(jnp.sum(flat.w0))  # readable
     assert w1.is_deleted()           # the old w is not
@@ -569,6 +600,30 @@ def test_the_trainers_vector_survives_the_models_own():
         tr.w, _ = tr.optimizer.step(tr.w, jnp.asarray(tr.stream.batch_at(k)))
     np.testing.assert_array_equal(np.asarray(tr.model.flat.w0), seeded)
     assert not np.array_equal(np.asarray(tr.w), seeded)
+
+
+def test_the_trainer_evaluates_the_committed_vector(monkeypatch):
+    """Between local steps on the kernel's path ``tr.w`` is the point
+    the next gradient is taken at; ``eval_loss`` reads the committed
+    vector out of it, and a trainer that never stepped builds no
+    optimizer to ask."""
+    from mpit_tpu.lm import LmTrainer
+    from mpit_tpu.optim.msgd import msgd_params
+    from mpit_tpu.train import launch
+
+    cfg = launch.LAUNCH_DEFAULTS.merged(
+        lm=1, lm_d_model=32, lm_heads=2, lm_layers=1, lm_seq=16, opt="msgd",
+        mom=0.9, lr=0.1, lm_use_flash=0, batch=2)
+    monkeypatch.setenv("MPIT_FUSED", "1")  # the chip's default, here
+    tr = LmTrainer(launch.lm_trainer_cfg(cfg))
+    assert tr.eval_loss() == tr.eval_loss(tr.w)
+    assert "optimizer" not in tr.__dict__
+    for k in range(3):
+        tr.w, _ = tr.optimizer.step(tr.w, jnp.asarray(tr.stream.batch_at(k)))
+    committed = msgd_params(tr.w, tr.optimizer.state, tr.optimizer.cfg)
+    np.testing.assert_array_equal(np.asarray(tr.params), np.asarray(committed))
+    assert tr.eval_loss() == tr.eval_loss(committed)
+    assert tr.eval_loss() != tr.eval_loss(tr.w)
 
 
 # -- through the launcher: a gang of three and a run of one ------------------------

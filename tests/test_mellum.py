@@ -682,6 +682,35 @@ def test_the_local_step_fetches_no_statistic_with_obs_off():
         obs.configure(enabled=None, reset=True)
 
 
+@pytest.mark.parametrize("mom,fused,want", [
+    (0.9, True, {"commit": "kernel", "lookahead": "folded"}),
+    (0.9, False, {"commit": "xla", "lookahead": "pass"}),
+    (0.0, True, {"commit": "xla", "lookahead": "none"}),
+], ids=["kernel_folded", "xla_pass", "no_momentum"])
+def test_the_round_span_says_what_the_step_does_with_the_vector(obs_on, mom,
+                                                                fused, want):
+    """With obs on the first local step's ``round`` span carries, beside
+    the block's statistics, whether the commit is the kernel's sweep and
+    whether the next step's lookahead rides it: a constant of the run,
+    said once."""
+    from mpit_tpu.optim.msgd import MSGD, MSGDConfig
+
+    def step(w, target):
+        return (jnp.sum((w - target) ** 2), {"load": jnp.ones(2)}), \
+            2 * (w - target)
+
+    opt = MSGD(MSGDConfig(lr=0.1, mom=mom, use_fused=fused), step,
+               has_aux=True)
+    w = jnp.zeros(300)
+    for _ in range(2):
+        w, _loss = opt.step(w, jnp.ones(300))
+    first, second = [s for s in obs_on.spans if s.name == "round"]
+    assert {k: first.args[k] for k in want} == want
+    assert not set(want) & set(second.args)
+    for span in (first, second):
+        assert span.args["load"] == [1.0, 1.0]
+
+
 def test_a_block_without_statistics_takes_the_plain_local_step():
     """gpt2 under ``--opt msgd`` is the program it was: no auxiliary
     output, no span."""

@@ -47,6 +47,33 @@ def test_fused_nesterov(rng, n, l2wd):
     np.testing.assert_allclose(np.asarray(vt1), np.asarray(vt2), rtol=1e-5, atol=1e-6)
 
 
+@pytest.mark.parametrize("n", [128 * 300, 128 * 300 + 77, 1000],
+                         ids=["whole_lanes", "no_whole_lanes", "under_a_block"])
+@pytest.mark.parametrize("l2wd", [0.0, 0.01])
+def test_the_commit_writes_the_next_steps_displaced_point(rng, n, l2wd):
+    """With ``mom_next`` the kernel does the commit and then the next
+    step's lookahead on the block it holds: the reference's arithmetic
+    in the reference's order, to the bit, at every length (one view,
+    the last block overhanging)."""
+    w, vt, g = (jnp.asarray(rng.normal(size=(n,)), jnp.float32) for _ in range(3))
+    kernel = jax.jit(lambda w, vt, g, clr, mom: fused_nesterov_commit(
+        w, vt, g, clr, l2wd=l2wd, mom_next=mom))
+    plain = jax.jit(lambda w, vt, g, clr, mom: fused_nesterov_commit_reference(
+        w, vt, g, clr, l2wd=l2wd, mom_next=mom))
+    clr, mom = jnp.float32(0.05), jnp.float32(0.9)
+    got, want = kernel(w, vt, g, clr, mom), plain(w, vt, g, clr, mom)
+    for a, b in zip(got, want):
+        assert a.shape == (n,)
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    # and it is the two phases: commit, then ``vt *= mom; w += vt``
+    w_c, vt_c = fused_nesterov_commit_reference(w, vt, g, clr, l2wd=l2wd)
+    np.testing.assert_allclose(np.asarray(want[1]), 0.9 * np.asarray(vt_c),
+                               rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(want[0]),
+                               np.asarray(w_c) + 0.9 * np.asarray(vt_c),
+                               rtol=1e-5, atol=1e-6)
+
+
 def test_fused_nesterov_jit_traced_lr(rng):
     w, vt, g = (jnp.asarray(rng.normal(size=(500,)), jnp.float32) for _ in range(3))
 
@@ -712,7 +739,8 @@ class TestFusedRouting:
         np.testing.assert_allclose(outs[0], outs[1], rtol=1e-5, atol=1e-6)
 
     def test_msgd_fused_matches(self, rng):
-        from mpit_tpu.optim.msgd import MSGDConfig, msgd_init, msgd_step
+        from mpit_tpu.optim.msgd import (MSGDConfig, msgd_init, msgd_params,
+                                         msgd_step)
 
         w0 = jnp.asarray(rng.normal(size=(257,)), jnp.float32)
         xs = [jnp.asarray(rng.normal(size=(257,)), jnp.float32) for _ in range(4)]
@@ -726,7 +754,8 @@ class TestFusedRouting:
             w, st = w0, msgd_init(w0)
             for t in xs:
                 w, st, _ = msgd_step(vgf, w, st, cfg, t)
-            outs.append(np.asarray(w))
+            # the committed vector: the fused step hands back another point
+            outs.append(np.asarray(msgd_params(w, st, cfg)))
         np.testing.assert_allclose(outs[0], outs[1], rtol=1e-5, atol=1e-6)
 
     def test_resolution_order(self, monkeypatch):

@@ -11,7 +11,8 @@ import numpy as np
 import pytest
 
 from mpit_tpu.optim import rules
-from mpit_tpu.optim.msgd import MSGDConfig, msgd_init, msgd_step
+from mpit_tpu.optim.msgd import (
+    MSGDConfig, msgd_commit, msgd_init, msgd_lookahead, msgd_params, msgd_step)
 
 RTOL = 1e-5
 
@@ -234,7 +235,81 @@ class TestMSGD:
             clr = cfg.lr / (1 + k * cfg.lrd) ** cfg.lrp
             ref = ref - clr * g
             vt = vt - clr * g
-        np.testing.assert_allclose(np.asarray(w), ref, rtol=1e-4)
+        np.testing.assert_allclose(np.asarray(msgd_params(w, state, cfg)), ref,
+                                   rtol=1e-4)
+
+    @pytest.mark.parametrize("momdecay", [0.0, 10.0],
+                             ids=["constant_momentum", "momentum_ramp"])
+    @pytest.mark.parametrize("form", ["folded", "kernel", "flat", "pytree"])
+    def test_twenty_steps_are_the_two_phases_in_turn(self, momdecay, form,
+                                                     monkeypatch):
+        """``msgd_step`` against ``msgd_lookahead`` / ``msgd_commit``
+        called in turn.  On the kernel's path it carries the displaced
+        point and the scaled velocity where the phases carry the
+        committed vector: the gradients are taken at the same points by
+        the same arithmetic, so the losses are equal to the bit, and
+        ``msgd_params`` is the committed vector to one rounding
+        (``(w + vt) - vt``); the ramp keeps its schedule.  Off it (the
+        unfused build, a pytree) it is the phases, pair and all.  Not
+        jitted: operation by operation the arithmetic is IEEE's, where
+        the CPU's compiler contracts a fused program's multiply-adds as
+        it likes, the interpreted kernel's among them: ``folded`` runs
+        the kernel's path on the kernel's contract
+        (``fused_nesterov_commit_reference``) and is held to the bit,
+        ``kernel`` runs the kernel and is held to a rounding a step."""
+        from mpit_tpu.optim import msgd
+        from mpit_tpu.ops.fused_update import fused_nesterov_commit_reference
+
+        exact = form != "kernel"
+        kernel, tree = form in ("folded", "kernel"), form == "pytree"
+        if form == "folded":
+            monkeypatch.setattr(msgd, "fused_nesterov_commit",
+                                fused_nesterov_commit_reference)
+        cfg = MSGDConfig(lr=0.05, lrd=0.01, lrp=1.0, mom=0.9, mommax=0.95,
+                         momdecay=momdecay, l2wd=1e-3, use_fused=kernel)
+        rs = np.random.RandomState(7)
+        scale = jnp.asarray(rs.uniform(0.5, 2.0, 300), jnp.float32)
+        target = jnp.asarray(rs.randn(300), jnp.float32)
+        w0 = jnp.asarray(rs.randn(300), jnp.float32)
+        pack = (lambda v: {"a": v[:100], "b": {"c": v[100:]}}) if tree else (
+            lambda v: v)
+        unpack = (lambda t: np.concatenate([t["a"], t["b"]["c"]])) if tree else (
+            np.asarray)
+
+        def vgf(w, target):
+            def loss(w):
+                flat = jnp.concatenate([w["a"], w["b"]["c"]]) if tree else w
+                return 0.5 * jnp.sum(scale * (flat - target) ** 2)
+            return jax.value_and_grad(loss)(w)
+
+        phases = cfg._replace(use_fused=False)
+        w, state = pack(w0), msgd_init(pack(w0))
+        ref, ref_state = pack(w0), msgd_init(pack(w0))
+        first = float(vgf(w, target)[0])
+        for k in range(20):
+            ref_la, ref_state = msgd_lookahead(ref, ref_state, phases)
+            if exact:
+                np.testing.assert_array_equal(
+                    unpack(w), unpack(ref_la if kernel else ref))
+            ref_loss, g = vgf(ref_la, target)
+            ref, ref_state = msgd_commit(ref_la, g, ref_state, phases)
+            w, state, loss = msgd_step(vgf, w, state, cfg, target)
+            if exact:
+                assert float(loss) == float(ref_loss), k
+            else:
+                np.testing.assert_allclose(float(loss), float(ref_loss),
+                                           rtol=1e-5)
+            got, want = unpack(msgd_params(w, state, cfg)), unpack(ref)
+            if kernel:
+                ulp = np.spacing(np.maximum(np.abs(want), np.abs(unpack(w))))
+                slack = 1 if exact else 4 * (k + 1)
+                assert np.all(np.abs(got - want) <= slack * ulp), k
+                if k:  # momentum in: the point handed back is not that vector
+                    assert not np.array_equal(unpack(w), want)
+            else:
+                np.testing.assert_array_equal(got, want)
+        assert int(state["k"]) == int(ref_state["k"]) == 20
+        assert float(loss) < 0.05 * first
 
     def test_momentum_ramp_capped(self):
         from mpit_tpu.optim.msgd import _effective_momentum

@@ -271,9 +271,29 @@ class TestSingleWorker:
         # Server mirror tracks local params exactly.
         np.testing.assert_allclose(pc.center, np.asarray(w), rtol=1e-5)
 
-    def test_msgd_single(self, w0, target):
+    @pytest.mark.parametrize("fused", [False, True], ids=["phases", "kernel"])
+    def test_msgd_single(self, w0, target, fused):
+        """What is pushed is the committed vector, the one the two
+        phases commit, whatever the step hands back: on the kernel's
+        path that is ``msgd_step``'s displaced point."""
+        from mpit_tpu.optim.msgd import (
+            MSGDConfig, msgd_commit, msgd_init, msgd_lookahead)
+
         pc = FakeClient()
-        opt = SingleWorker(quadratic_vgf, pc, rule="msgd", lr=0.1, mom=0.9)
-        w = opt.start(jnp.asarray(w0))
-        w, _ = opt.step(w, target)
-        np.testing.assert_allclose(pc.center, np.asarray(w), rtol=1e-5)
+        opt = SingleWorker(quadratic_vgf, pc, rule="msgd", lr=0.1, mom=0.9,
+                           use_fused=fused)
+        seeded = jnp.asarray(w0)
+        assert opt.params(seeded) is seeded  # no state before start()
+        w = opt.start(seeded)
+        cfg = MSGDConfig(lr=0.1, mom=0.9)
+        ref, state = jnp.asarray(w0), msgd_init(jnp.asarray(w0))
+        for _ in range(3):
+            w, _ = opt.step(w, target)
+            ref_la, state = msgd_lookahead(ref, state, cfg)
+            ref, state = msgd_commit(ref_la, quadratic_vgf(ref_la, target)[1],
+                                     state, cfg)
+            np.testing.assert_array_equal(pc.center, np.asarray(opt.params(w)))
+            np.testing.assert_allclose(pc.center, np.asarray(ref), rtol=1e-5,
+                                       atol=1e-6)
+        # three steps of momentum in: the kernel's point is not that vector
+        assert np.allclose(np.asarray(w), pc.center, rtol=1e-3) is not fused

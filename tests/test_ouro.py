@@ -786,9 +786,14 @@ def test_the_commit_sweeps_a_vector_of_any_length_where_it_lies(length, sug):
 
 def test_two_donated_steps_on_an_odd_vector_are_the_undonated_steps():
     """The tiny model's vector is one over a whole number of lanes, as
-    the cell's: two steps of ``MSGD`` (donated, the fused commit pinned)
-    against the same two steps taken undonated by ``msgd_step``."""
-    from mpit_tpu.optim.msgd import MSGD, MSGDConfig, msgd_init, msgd_step
+    the cell's: two steps of ``MSGD`` (donated, the fused commit pinned,
+    the next step's lookahead inside it) against the same two steps
+    taken undonated by ``msgd_step``, and against ``msgd_lookahead`` /
+    ``msgd_commit`` in turn, whose losses and committed vector
+    ``msgd_params`` gives back (to rounding here: the CPU's compiler
+    contracts the two programs' multiply-adds differently)."""
+    from mpit_tpu.optim.msgd import (MSGD, MSGDConfig, msgd_commit,
+                                     msgd_init, msgd_lookahead, msgd_step)
 
     model = build(arch="ouro", seed=3, use_flash=False, **TINY)
     assert model.flat.size % 128 == 1
@@ -798,7 +803,7 @@ def test_two_donated_steps_on_an_odd_vector_are_the_undonated_steps():
     opt = MSGD(cfg, model.value_and_grad)
     w = model.flat.w0
     for tokens in batches:
-        w, _loss = opt.step(w, tokens)
+        w, loss = opt.step(w, tokens)
     plain, state = model.flat.w0, msgd_init(model.flat.w0)
     step = jax.jit(lambda w, s, t: msgd_step(model.value_and_grad, w, s,
                                              cfg, t))
@@ -806,3 +811,17 @@ def test_two_donated_steps_on_an_odd_vector_are_the_undonated_steps():
         plain, state, _loss = step(plain, state, tokens)
     np.testing.assert_array_equal(np.asarray(w), np.asarray(plain))
     assert np.asarray(model.flat.w0).shape == (model.flat.size,)  # not donated
+
+    @jax.jit
+    def phases(w, state, tok):
+        w_la, state = msgd_lookahead(w, state, cfg)
+        phase_loss, grad = model.value_and_grad(w_la, tok)
+        return (*msgd_commit(w_la, grad, state, cfg), phase_loss)
+
+    committed, state = model.flat.w0, msgd_init(model.flat.w0)
+    for tokens in batches:
+        committed, state, phase_loss = phases(committed, state, tokens)
+    np.testing.assert_allclose(float(loss), float(phase_loss), rtol=1e-6)
+    np.testing.assert_allclose(np.asarray(opt.params(w)),
+                               np.asarray(committed), rtol=1e-5, atol=1e-6)
+    assert not np.allclose(np.asarray(w), np.asarray(committed), atol=1e-4)

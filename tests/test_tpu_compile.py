@@ -112,41 +112,62 @@ def test_the_delta_rules_kernels_compile_at_kimis_shape(one_chip, monkeypatch):
     assert len(compiled.output_shardings) == 5
 
 
-def test_the_donated_commit_keeps_no_copy_of_lfm2s_vector(one_chip):
-    """486,062,464 elements are whole lanes and 14,833.45 blocks: the
-    commit sweeps the vector where it lies with an overhanging last
-    block, so with ``w`` and ``vt`` donated the program holds no third
-    vector (a padded copy of each operand would be three)."""
+@pytest.mark.parametrize("n", [486_062_464, 509_661_185],
+                         ids=["lfm2s_whole_lanes", "ouros_one_over"])
+@pytest.mark.parametrize("mom_next", [None, 0.9],
+                         ids=["commit", "commit_and_lookahead"])
+def test_the_donated_commit_keeps_no_copy_of_the_vector(one_chip, n, mom_next):
+    """486,062,464 elements are whole lanes and 14,833.45 blocks,
+    509,661,185 are one over a whole number of lanes: the commit sweeps
+    either where it lies, in 1-D blocks with an overhanging last one
+    and under no other view, so with ``w`` and ``vt`` donated the
+    program holds no third vector (a pad, or a slice of the aligned
+    prefix, would copy each operand whole), with the next step's
+    lookahead on the same blocks or without."""
     from mpit_tpu.ops.fused_update import fused_nesterov_commit
 
-    n = 486_062_464
     vec = jax.ShapeDtypeStruct((n,), jnp.float32, sharding=one_chip)
     compiled = jax.jit(
         lambda w, vt, g: fused_nesterov_commit(w, vt, g, 0.03,
+                                               mom_next=mom_next,
                                                interpret=False),
         donate_argnums=(0, 1)).lower(vec, vec, vec).compile()
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= 2 * 4 * n   # both, to the tile
     assert mem.temp_size_in_bytes < 4 * n // 8
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert f"f32[{n // 128},128]" not in text
 
 
-def test_the_donated_commit_keeps_no_copy_of_ouros_vector(one_chip):
-    """509,661,185 elements are one over a whole number of lanes: the
-    commit sweeps the vector as it is, in 1-D blocks with an overhanging
-    last one, so with ``w`` and ``vt`` donated the program holds no
-    third vector (a pad, or a slice of the aligned prefix, would copy
-    each operand whole)."""
-    from mpit_tpu.ops.fused_update import fused_nesterov_commit
+@pytest.mark.parametrize("n", [486_062_464, 509_661_185],
+                         ids=["lfm2s_whole_lanes", "ouros_one_over"])
+def test_the_local_step_sweeps_its_vector_once(one_chip, monkeypatch, n):
+    """``MSGD``'s program is still ``jit__lambda`` (the benchmark finds
+    the step by that name), and outside the model it is one kernel over
+    the vector: no fusion writes two arrays of its length (the lookahead
+    pass did: the scaled velocity and the displaced point)."""
+    from mpit_tpu.optim.msgd import MSGD, MSGDConfig
 
-    n = 509_661_185
-    assert n % 128 == 1
+    def vgf(w, target):
+        return 0.5 * jnp.sum((w - target) ** 2), w - target
+
+    # the described chip is not the default backend: take its branch
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    opt = MSGD(MSGDConfig(lr=0.01, mom=0.9), vgf)
     vec = jax.ShapeDtypeStruct((n,), jnp.float32, sharding=one_chip)
-    compiled = jax.jit(
-        lambda w, vt, g: fused_nesterov_commit(w, vt, g, 0.03,
-                                               interpret=False),
-        donate_argnums=(0, 1)).lower(vec, vec, vec).compile()
+    state = {"k": jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
+             "vt": vec}
+    lowered = opt._step.lower(vec, state, vec)
+    assert "module @jit__lambda" in lowered.as_text()
+    assert lowered.as_text().count("tpu_custom_call") == 1
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    results = [line.split(" fusion(")[0] for line in text.splitlines()
+               if " fusion(" in line]
+    assert results and all(r.count(f"f32[{n}]") <= 1 for r in results)
+    assert f"f32[{n // 128},128]" not in text
     mem = compiled.memory_analysis()
-    assert mem.alias_size_in_bytes >= 2 * 4 * n   # both, to the tile
-    assert mem.temp_size_in_bytes < 4 * n // 8
-    assert compiled.as_text().count(
-        'custom_call_target="tpu_custom_call"') == 1
+    assert mem.alias_size_in_bytes >= 2 * 4 * n
+    assert mem.temp_size_in_bytes < 4 * n + 4 * n // 8   # the gradient
