@@ -164,6 +164,12 @@ class Scheduler:
         #: seconds inside the back-off sleeps, as the recorder measured
         #: them: 0.0 and no clock read while it does not record
         self.sleep_s = 0.0
+        #: the owner's name for a back-off sleep, asked after each one
+        #: while the recorder measures them (``why() -> str``: the PS
+        #: client says what its pending ops waited for), and ``sleep_s``
+        #: by that name; None and empty unless an owner installs them
+        self.why: Optional[Callable[[], str]] = None
+        self.sleep_by: dict[str, float] = {}
         _reg = _obs_metrics.get_registry()
         self._m_steps = _reg.counter("mpit_aio_steps_total")
         self._m_idle = _reg.counter("mpit_aio_idle_seconds_total")
@@ -222,6 +228,9 @@ class Scheduler:
             # is the recorder's to say: 0.0 with obs off.
             slept = self._rec.sleep(self.idle_usec * 1e-6)
             self.sleep_s += slept
+            if slept and self.why is not None:
+                name = self.why()
+                self.sleep_by[name] = self.sleep_by.get(name, 0.0) + slept
             self._m_idle.inc(slept)
             self._idle_accum += slept
             if (self._flight.enabled and self.stall_s > 0

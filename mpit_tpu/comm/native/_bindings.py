@@ -51,7 +51,7 @@ def _as_pointer(obj):
 #: lockstep with transport.cpp (see kApiVersion comment there) whenever
 #: the exported C surface changes, so a stale .so fails loudly with a
 #: rebuild message instead of a confusing missing-symbol AttributeError.
-MT_API_VERSION = 17006
+MT_API_VERSION = 17007
 
 
 class NativeTransportLib:
@@ -101,6 +101,8 @@ class NativeTransportLib:
         self._lib.mt_isend_pieces.argtypes = [ctypes.c_void_p, ctypes.c_int32, ctypes.c_int32, ctypes.c_uint64]
         self._lib.mt_nranks.restype = ctypes.c_int32
         self._lib.mt_nranks.argtypes = [ctypes.c_void_p]
+        self._lib.mt_op_intervals.restype = ctypes.c_int32
+        self._lib.mt_op_intervals.argtypes = [ctypes.c_void_p, ctypes.c_int64, ctypes.c_void_p]
         self._lib.mt_op_timing.restype = ctypes.c_int32
         self._lib.mt_op_timing.argtypes = [ctypes.c_void_p, ctypes.c_int64, ctypes.c_void_p]
         self._lib.mt_pool_busy_seconds.restype = ctypes.c_double
@@ -143,6 +145,8 @@ class NativeTransportLib:
         self._lib.mt_test.argtypes = [ctypes.c_void_p, ctypes.c_int64]
         self._lib.mt_time.restype = ctypes.c_double
         self._lib.mt_time.argtypes = []
+        self._lib.mt_waiting.restype = ctypes.c_int32
+        self._lib.mt_waiting.argtypes = [ctypes.c_void_p]
         self._lib.mt_wire_ns.restype = ctypes.c_uint64
         self._lib.mt_wire_ns.argtypes = [ctypes.c_void_p, ctypes.c_int32]
         self._lib.mt_xor_bytes.restype = None
@@ -221,8 +225,13 @@ class NativeTransportLib:
         """World size."""
         return self._lib.mt_nranks(ctx)
 
+    def mt_op_intervals(self, ctx, handle, out):
+        """The copy intervals of a finished op, at the same time as mt_op_timing: when this end's thread was inside the ring copies, one interval a run of chunks copied back to back in one pass, at most 64 (past that the two with the smallest gap between them were merged). Three u64 words an interval into out (room for 192): begin ns, end ns on CLOCK_MONOTONIC, bytes; returns how many intervals, 0 without a record (timing off)."""
+        out, _out_len = _as_pointer(out)
+        return self._lib.mt_op_intervals(ctx, handle, out)
+
     def mt_op_timing(self, ctx, handle, out):
-        """The record of a finished op, after mt_test saw it done and before mt_release: 14 u64 words into out and 14 returned, or 0 without a record (timing off). [0] 1 send / 2 receive, [1] msg_id, [2] t_first ns, [3] t_done ns, [4] copy_ns, [5] blocked_ns (send) or starved_ns (receive), [6] away_ns, [7] chunks, [8] refused placements (send) or overlapped chunks (receive), [9] receive landed in its posted buffer, [10] receive's t_first_pub ns, [11] bytes, [12] a send's early_bytes (placed while its ready mark was short of its length), [13] a send's unready_ns (at its mark with room in the ring: a part of [6])."""
+        """The record of a finished op, after mt_test saw it done and before mt_release: 12 u64 words into out and 12 returned, or 0 without a record (timing off). [0] 1 send / 2 receive, [1] msg_id, [2] t_first ns, [3] t_done ns, [4] copy_ns, [5] blocked_ns (send) or starved_ns (receive), [6] away_ns, [7] receive's t_first_pub ns, [8] bytes, [9] a send's early_bytes (placed while its pieces were short of its length), [10] a send's unready_ns (every appended byte placed, with room in the ring: a part of [6]), [11] copy intervals merged over a gap (mt_op_intervals)."""
         out, _out_len = _as_pointer(out)
         return self._lib.mt_op_timing(ctx, handle, out)
 
@@ -283,7 +292,7 @@ class NativeTransportLib:
         return self._lib.mt_release(ctx, handle)
 
     def mt_ring_counts(self, ctx, which):
-        """How the rings were used so far: which == 0, chunks this endpoint placed in its peers' rings; 1, placements a full ring refused (the sender waited for the owner); 2, chunks copied out of the own rings; 3, those of them during whose copy the ring's head moved (sender and owner were copying at the same time); 4, payload bytes placed while their op's pieces were short of its length."""
+        """How the rings were used so far: which == 0, chunks this endpoint placed in its peers' rings; 1, placements a full ring refused (the sender waited for the owner); 2, chunks copied out of the own rings; 3, those of them during whose copy the ring's head moved (sender and owner were copying at the same time); 4, payload bytes placed while their op's pieces were short of its length; 5, buffers of copy intervals allocated (none while the timing is off)."""
         return self._lib.mt_ring_counts(ctx, which)
 
     def mt_rx_bytes(self, ctx, which):
@@ -310,6 +319,10 @@ class NativeTransportLib:
     def mt_time(self):
         """Monotonic seconds (MPI_Wtime analog, reference mpifuncs.c:2500)."""
         return self._lib.mt_time()
+
+    def mt_waiting(self, ctx):
+        """What this endpoint's unfinished transfers stand before after the last pass of progress, as bits (no clock is read): 1 a front send with every appended byte placed and short of its length (unready), 2 a front send with bytes left and no room for them this pass (blocked), 4 a receive posted with a buffer that no message has begun to land in, 8 a receive whose message is landing and not whole."""
+        return self._lib.mt_waiting(ctx)
 
     def mt_wire_ns(self, ctx, which):
         """The endpoint's totals while timing, ns, cumulative: which == 0, inside the copies into peers' rings; 1, inside the copies out of the own rings and the memcpy that hands an assembled message over; 2, inside progress() and that memcpy (less the two copies: the cost of polling)."""

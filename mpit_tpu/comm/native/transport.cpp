@@ -64,10 +64,14 @@
 //    switch is on (mt_set_timing; comm/shm.py sets it from the span
 //    recorder): a record a message on each end (TxTiming, RxTiming), the
 //    instant a chunk was published in its header (`pub_ns`), and three
-//    endpoint totals (mt_wire_ns).  Off, no clock is read on the message
-//    path, `pub_ns` is 0 and mt_op_timing gives nothing.  Both ends read
-//    CLOCK_MONOTONIC of one host, so the owner's subtraction from a
-//    sender's stamp is exact.
+//    endpoint totals (mt_wire_ns).  A record also keeps the message's copy
+//    intervals (CopyRuns; mt_op_intervals): when this end's thread was
+//    inside the ring copies and how many bytes each stretch moved, one
+//    interval a run of back-to-back chunks, at most kMaxRuns of them.
+//    Off, no clock is read on the message path, `pub_ns` is 0, no interval
+//    is kept or allocated and mt_op_timing gives nothing.  Both ends read
+//    CLOCK_MONOTONIC of one host (the clock Python's time.monotonic reads
+//    too), so the owner's subtraction from a sender's stamp is exact.
 //
 // Exported C API (ctypes bindings are generated from specs/*.json by
 // gen_bindings.py, mirroring the reference's readspec.py codegen).
@@ -180,6 +184,59 @@ uint64_t since(uint64_t later, uint64_t earlier) {
   return later > earlier ? later - earlier : 0;
 }
 
+// When one end of a message was inside the ring copies: a run of chunks
+// copied back to back in one pass of progress() is one interval (a refusal,
+// an empty ring, a send short of appended bytes or the end of the pass's
+// budget ends the pass for that message, and with it the run), so a shard
+// of some hundred MB through a 64 MB ring is a handful of them, not one a
+// chunk.  At most kMaxRuns are kept: past that the two neighbours with the
+// smallest gap between them become one, gap included, and `merged` counts
+// how often.  Nothing is allocated before the first timed chunk.
+struct CopyRun {
+  uint64_t begin = 0;
+  uint64_t end = 0;
+  uint64_t bytes = 0;
+};
+
+constexpr size_t kMaxRuns = 64;
+
+struct CopyRuns {
+  std::vector<CopyRun> runs;
+  uint64_t pass = 0;  // the pass of progress() that copied the last chunk
+  uint32_t merged = 0;
+
+  // `bytes` copied between `begin` and `end` in pass `pass_no`; `fresh`: a
+  // copy that is no chunk of that pass (the hand-over of an assembled
+  // message) never continues a run.  `allocs` counts the buffers made.
+  void add(uint64_t pass_no, uint64_t begin, uint64_t end, uint64_t bytes,
+           bool fresh, uint64_t* allocs) {
+    if (!runs.empty() && !fresh && pass == pass_no) {
+      runs.back().end = end;
+      runs.back().bytes += bytes;
+      return;
+    }
+    if (runs.capacity() == 0) {
+      runs.reserve(kMaxRuns);
+      ++*allocs;
+    }
+    if (runs.size() == kMaxRuns) {
+      size_t at = 0;
+      for (size_t i = 1; i + 1 < runs.size(); ++i) {
+        if (runs[i + 1].begin - runs[i].end <
+            runs[at + 1].begin - runs[at].end) {
+          at = i;
+        }
+      }
+      runs[at].end = runs[at + 1].end;
+      runs[at].bytes += runs[at + 1].bytes;
+      runs.erase(runs.begin() + (ptrdiff_t)at + 1);
+      merged++;
+    }
+    runs.push_back(CopyRun{begin, end, bytes});
+    pass = fresh ? 0 : pass_no;
+  }
+};
+
 // Where a sent message's time went, first attempt to place a chunk to last
 // chunk published.  What is neither `copy_ns` nor `blocked_ns` of that is
 // the sender's time away: the ring had room and its thread was elsewhere,
@@ -193,8 +250,7 @@ struct TxTiming {
   uint64_t unready_ns = 0;  // a pass that found every appended byte placed,
                             // to the next attempt with a byte to place
   uint64_t t_unready = 0;   // that pass, still waited out; 0: none
-  uint32_t refused = 0;     // the polls, as tx_ring_full counts them
-  uint32_t chunks = 0;
+  CopyRuns runs;            // when the thread was inside circ_write
 };
 
 // Where a received message's time went, first chunk published to message
@@ -209,11 +265,10 @@ struct RxTiming {
   uint64_t starved_ns = 0;   // ring empty, message partial: the sender's
   uint64_t away_ns = 0;      // a chunk lay published and was not being copied
   uint32_t chunks = 0;
-  uint32_t overlap_chunks = 0;
+  CopyRuns runs;  // when the thread was inside circ_read or the hand-over
 
   // One chunk copied out between `t_start` and `t_end`.
-  void chunk(const ChunkHeader& ch, uint64_t t_start, uint64_t t_end,
-             bool overlapped) {
+  void chunk(const ChunkHeader& ch, uint64_t t_start, uint64_t t_end) {
     // A sender that keeps no time says nothing of when it published.
     uint64_t pub = ch.pub_ns != 0 && ch.pub_ns < t_start ? ch.pub_ns : t_start;
     if (chunks == 0) {
@@ -231,7 +286,6 @@ struct RxTiming {
     last_end = t_end;
     t_done = t_end;
     chunks++;
-    overlap_chunks += overlapped;
   }
 
   // The memcpy that hands an assembled message over, in mt_test.
@@ -337,6 +391,10 @@ struct Ctx {
   uint64_t tx_copy_ns = 0;
   uint64_t rx_copy_ns = 0;
   uint64_t progress_ns = 0;
+  // While `timing`: the passes of progress() so far (a message's copy runs
+  // are cut by it) and the interval buffers allocated (mt_ring_counts 5).
+  uint64_t pass_no = 0;
+  uint64_t run_buffers = 0;
   std::string last_error;
 };
 
@@ -593,10 +651,12 @@ void drain_ring(Ctx* ctx, const Ring& ring) {
     if (timing) {
       const uint64_t t_end = now_ns();
       ctx->rx_copy_ns += t_end - t_start;
-      rt->chunk(ch, t_start, t_end, overlapped);
+      rt->chunk(ch, t_start, t_end);
+      rt->runs.add(ctx->pass_no, t_start, t_end, ch.chunk_bytes,
+                   /*fresh=*/false, &ctx->run_buffers);
     }
     if (landed != nullptr) {
-      if (timing) *landed = *rt;
+      if (timing) *landed = std::move(*rt);
       if (rt != &whole) ctx->partial.erase({ch.src, ch.msg_id});
     }
   }
@@ -679,10 +739,7 @@ void pump_sends(Ctx* ctx) {
         if (ring.capacity - used < need) {
           ctx->tx_ring_full++;
           full = true;
-          if (timing) {
-            op.tt.refused++;
-            if (op.tt.t_refused == 0) op.tt.t_refused = t_try;
-          }
+          if (timing && op.tt.t_refused == 0) op.tt.t_refused = t_try;
           break;
         }
         ChunkHeader ch;
@@ -716,7 +773,8 @@ void pump_sends(Ctx* ctx) {
           }
           op.tt.copy_ns += t_pub - t_try;
           op.tt.t_done = t_pub;
-          op.tt.chunks++;
+          op.tt.runs.add(ctx->pass_no, t_try, t_pub, chunk, /*fresh=*/false,
+                         &ctx->run_buffers);
           ctx->tx_copy_ns += t_pub - t_try;
         }
         circ_write(ring, head, &ch, sizeof(ch));
@@ -749,6 +807,7 @@ void pump_sends(Ctx* ctx) {
 
 void progress(Ctx* ctx) {
   const uint64_t t_in = ctx->timing ? now_ns() : 0;
+  if (ctx->timing) ctx->pass_no++;
   drain_inbox(ctx);
   pump_sends(ctx);
   if (ctx->timing) ctx->progress_ns += now_ns() - t_in;
@@ -912,8 +971,10 @@ int mt_test(void* vctx, int64_t handle) {
     if (op.cap > 0) std::memcpy(op.out, msg.buf.data.get(), op.cap);
     if (ctx->timing) {
       const uint64_t t_end = now_ns();
-      op.rt = msg.rt;
+      op.rt = std::move(msg.rt);
       op.rt.handed_over(t_copy, t_end);
+      op.rt.runs.add(ctx->pass_no, t_copy, t_end, op.cap, /*fresh=*/true,
+                     &ctx->run_buffers);
       ctx->rx_copy_ns += t_end - t_copy;
       ctx->progress_ns += t_end - t_copy;
     }
@@ -968,12 +1029,43 @@ uint64_t mt_rx_bytes(void* vctx, int32_t which) {
 // for the owner); 2, chunks copied out of the own rings; 3, those of them
 // during whose copy the ring's head moved: sender and owner were copying
 // at the same time; 4, payload bytes placed while their op's pieces were
-// short of its length.
+// short of its length; 5, buffers of copy intervals allocated (none while
+// the timing is off).
 uint64_t mt_ring_counts(void* vctx, int32_t which) {
   auto* ctx = static_cast<Ctx*>(vctx);
-  const uint64_t counts[] = {ctx->tx_chunks, ctx->tx_ring_full, ctx->rx_chunks,
-                             ctx->rx_overlap_chunks, ctx->tx_early_bytes};
-  return which >= 0 && which < 5 ? counts[which] : 0;
+  const uint64_t counts[] = {ctx->tx_chunks,      ctx->tx_ring_full,
+                             ctx->rx_chunks,      ctx->rx_overlap_chunks,
+                             ctx->tx_early_bytes, ctx->run_buffers};
+  return which >= 0 && which < 6 ? counts[which] : 0;
+}
+
+// What this endpoint's unfinished transfers stand before, as bits, from the
+// state the last pass of progress() left (no clock is read): 1, a send at
+// the front of its queue with every appended byte placed and short of its
+// length (its caller has staged no more: `unready`); 2, a front send with
+// bytes left that the ring has no room for this pass (the owner's drain:
+// `blocked`); 4, a receive posted with a buffer that no message has begun
+// to land in (the peer has not begun to send); 8, a receive whose message
+// is landing and is not whole (the sender's next chunks are not published).
+int32_t mt_waiting(void* vctx) {
+  auto* ctx = static_cast<Ctx*>(vctx);
+  int32_t bits = 0;
+  for (auto& [dst, queue] : ctx->send_q) {
+    for (int64_t handle : queue) {
+      auto it = ctx->sends.find(handle);
+      if (it == ctx->sends.end() || it->second.cancelled || it->second.done) {
+        continue;
+      }
+      const SendOp& op = it->second;
+      bits |= op.appended == op.written && op.len > 0 ? 1 : 2;
+      break;  // the front one: those behind it wait for it
+    }
+  }
+  for (auto& [handle, op] : ctx->recvs) {
+    if (op.done || op.cancelled) continue;
+    bits |= op.bound ? 8 : 4;
+  }
+  return bits;
 }
 
 // The one switch of the wire's timing: on, every message keeps a record of
@@ -989,11 +1081,10 @@ void mt_set_timing(void* vctx, int32_t on) {
 // were written; 0 without a record (timing off, a handle unknown or not
 // done).  [0] 1 a send, 2 a receive; [1] the sender's msg_id; [2] t_first
 // and [3] t_done, ns on CLOCK_MONOTONIC; [4] copy_ns; [5] blocked_ns of a
-// send, starved_ns of a receive; [6] away_ns; [7] chunks; [8] refused
-// placements of a send, overlapped chunks of a receive; [9] a receive that
-// landed in its posted buffer; [10] a receive's t_first_pub; [11] bytes;
-// [12] a send's early_bytes and [13] its unready_ns (a part of [6]).
-constexpr int32_t kTimingWords = 14;
+// send, starved_ns of a receive; [6] away_ns; [7] a receive's t_first_pub;
+// [8] bytes; [9] a send's early_bytes and [10] its unready_ns (a part of
+// [6]); [11] copy intervals that were merged over a gap (mt_op_intervals).
+constexpr int32_t kTimingWords = 12;
 
 int32_t mt_op_timing(void* vctx, int64_t handle, void* vout) {
   auto* ctx = static_cast<Ctx*>(vctx);
@@ -1006,8 +1097,8 @@ int32_t mt_op_timing(void* vctx, int64_t handle, void* vout) {
     const uint64_t busy = tt.copy_ns + tt.blocked_ns;
     const uint64_t words[kTimingWords] = {
         1, op.msg_id, tt.t_first, tt.t_done, tt.copy_ns, tt.blocked_ns,
-        since(tt.t_done - tt.t_first, busy), tt.chunks, tt.refused, 0,
-        tt.t_first, op.len, op.early_bytes, tt.unready_ns};
+        since(tt.t_done - tt.t_first, busy), tt.t_first, op.len,
+        op.early_bytes, tt.unready_ns, tt.runs.merged};
     std::memcpy(out, words, sizeof(words));
     return kTimingWords;
   }
@@ -1018,12 +1109,36 @@ int32_t mt_op_timing(void* vctx, int64_t handle, void* vout) {
     if (!op.done || rt.chunks == 0) return 0;
     const uint64_t words[kTimingWords] = {
         2, rt.msg_id, rt.t_first, rt.t_done, rt.copy_ns, rt.starved_ns,
-        rt.away_ns, rt.chunks, rt.overlap_chunks, op.bound ? 1u : 0u,
-        rt.t_first_pub, op.size, 0, 0};
+        rt.away_ns, rt.t_first_pub, op.size, 0, 0, rt.runs.merged};
     std::memcpy(out, words, sizeof(words));
     return kTimingWords;
   }
   return 0;
+}
+
+// The copy intervals of a finished op, for the same caller at the same
+// time as mt_op_timing: (begin ns, end ns, bytes) of each, in order and
+// apart, three words an interval into `out` (room for 3 * 64), and how
+// many intervals were written; 0 without a record.  Their lengths sum to
+// the record's copy_ns and what lay between the chunks of a run.
+int32_t mt_op_intervals(void* vctx, int64_t handle, void* vout) {
+  auto* ctx = static_cast<Ctx*>(vctx);
+  auto* out = static_cast<uint64_t*>(vout);
+  const CopyRuns* runs = nullptr;
+  auto sit = ctx->sends.find(handle);
+  auto rit = ctx->recvs.find(handle);
+  if (sit != ctx->sends.end() && sit->second.done) {
+    runs = &sit->second.tt.runs;
+  } else if (rit != ctx->recvs.end() && rit->second.done) {
+    runs = &rit->second.rt.runs;
+  }
+  if (runs == nullptr) return 0;
+  for (const CopyRun& run : runs->runs) {
+    *out++ = run.begin;
+    *out++ = run.end;
+    *out++ = run.bytes;
+  }
+  return (int32_t)runs->runs.size();
 }
 
 // The endpoint's totals while timing, ns: which == 0, inside circ_write;
@@ -1186,7 +1301,7 @@ void mt_fold_f32(const void* vown, const void* vptrs, int32_t nchildren,
 // generated _bindings.py refuses a stale .so (loud rebuild message)
 // instead of failing with a confusing missing-symbol AttributeError.
 // Keep in sync with MT_API_VERSION in gen_bindings.py.
-int64_t mt_api_version(void) { return 17006; }
+int64_t mt_api_version(void) { return 17007; }
 
 }  // extern "C"
 

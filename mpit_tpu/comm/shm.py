@@ -34,6 +34,12 @@ partial and the ring empty: the sender is the slower side), and
 ``away_ms`` (``tx``: the ring had room and the sender's thread was
 elsewhere; ``rx``: a chunk lay published and the owner was not copying it
 out: asleep in the back-off, in another ring, in Python, off the core).
+``copies`` says when the copying was: ``[begin_ns, end_ns, bytes]`` of every
+run of chunks this end's thread copied back to back, on the monotonic
+clock, in order and apart, at most 64 a message (``copies_merged``: how
+often two were made one over the gap between them).  A ``tx``'s are a pass
+``ring_in`` over the host's memory, an ``rx``'s ``ring_out``
+(``obs/copies.py``).
 ``wire_totals`` are the endpoint's own sums, acks and headers included.
 
 A send that is not yet whole (the optional capability of
@@ -89,8 +95,12 @@ from mpit_tpu.comm.transport import Handle, Transport
 from mpit_tpu.obs import metrics as _obs
 from mpit_tpu.obs import spans as _spans
 
-#: words of a native timing record (transport.cpp ``mt_op_timing``)
-_TIMING_WORDS = 14
+#: words of a native timing record (transport.cpp ``mt_op_timing``), and
+#: of the most copy intervals a record keeps (``mt_op_intervals``)
+_TIMING_WORDS = 12
+_RUN_WORDS = 3 * 64
+#: what :meth:`ShmTransport.waiting` names, by bit of ``mt_waiting``
+_WAITING = ((1, "unready"), (2, "blocked"), (4, "unanswered"), (8, "partial"))
 
 
 @functools.lru_cache(maxsize=1)
@@ -123,8 +133,10 @@ class ShmTransport(Transport):
         # side reads no clock and ``test`` asks for no record.
         self._rec = _spans.get_recorder()
         self._record = np.zeros(_TIMING_WORDS, np.uint64)
+        self._runs = None  # a record's copy intervals, while recording
         if self._rec.enabled:
             self.lib.mt_set_timing(self._ctx, 1)
+            self._runs = np.zeros(_RUN_WORDS, np.uint64)
         # Per-peer traffic counters (mpit_tpu.obs): rank-indexed lists,
         # null singletons when obs is disabled (no-op on the hot path).
         _reg = _obs.get_registry()
@@ -329,25 +341,42 @@ class ShmTransport(Transport):
                 for which, key in enumerate(
                     ("tx_copy", "rx_copy", "progress"))}
 
+    def waiting(self) -> tuple:
+        """What this endpoint's unfinished transfers stood before when it
+        last made progress, from the native side's own state (no clock):
+        ``unready`` (a send with every appended piece placed and short of
+        its length: its caller has staged no more), ``blocked`` (a send
+        with bytes left and a ring with no room for them: the owner's
+        drain), ``unanswered`` (a receive posted with a buffer that no
+        message has begun to land in: the peer has not begun to send),
+        ``partial`` (a receive whose message is landing: its next chunks
+        are not published).  For a caller that names its idle time."""
+        bits = self.lib.mt_waiting(self._ctx)
+        return tuple(name for bit, name in _WAITING if bits & bit)
+
     def _wire_span(self, handle: Handle) -> None:
         """The native record of the transfer ``handle`` just finished,
         as one ``wire`` span (see the module docstring)."""
         if not self.lib.mt_op_timing(self._ctx, handle.native_id,
                                      self._record):
             return
-        (kind, msg_id, t_first, t_done, copy, wait, away, chunks, count,
-         direct, t_pub, nbytes, early, unready) = self._record.tolist()
+        (kind, msg_id, t_first, t_done, copy, wait, away, t_pub, nbytes,
+         early, unready, merged) = self._record.tolist()
         if nbytes < _spans.WIRE_SPAN_MIN_BYTES:
             return
-        args = {"bytes": nbytes, "msg_id": msg_id, "chunks": chunks,
-                "copy_ms": copy / 1e6, "away_ms": away / 1e6}
+        runs = self.lib.mt_op_intervals(self._ctx, handle.native_id,
+                                        self._runs)
+        args = {"bytes": nbytes, "msg_id": msg_id,
+                "copy_ms": copy / 1e6, "away_ms": away / 1e6,
+                "copies": self._runs[:3 * runs].reshape(-1, 3).tolist(),
+                "copies_merged": merged}
         if kind == 1:
-            args.update(blocked_ms=wait / 1e6, refused=count,
+            args.update(blocked_ms=wait / 1e6,
                         early_bytes=early, unready_ms=unready / 1e6,
                         flight_ms=(t_done - t_first) / 1e6)
         else:
-            args.update(starved_ms=wait / 1e6, overlap_chunks=count,
-                        direct=direct, flight_ms=(t_done - t_pub) / 1e6)
+            args.update(starved_ms=wait / 1e6,
+                        flight_ms=(t_done - t_pub) / 1e6)
         self._rec.wire("tx" if kind == 1 else "rx", self.rank, handle.peer,
                        handle.tag, t_first * 1e-9, t_done * 1e-9, **args)
 

@@ -20,7 +20,12 @@ Subcommands:
   chains, align rank clocks, decompose per-op latency onto the phase
   taxonomy and report the critical path (obs/causal.py).  Exit 1 on
   negative phase durations beyond clock uncertainty or a join rate
-  below ``--min-join`` — the CI obs-trace job gates on both.
+  below ``--min-join`` — the CI obs-trace job gates on both.  A trace
+  of a streamed PS round also gets the host copies' account
+  (obs/copies.py): the passes a byte of the vector makes over the
+  host's memory a round, the rate while any copier ran, the rate with
+  one, two, and three or more copiers at work at once, the stream's
+  thread piece by piece and the client's sleeps by name.
 - ``profile <trace.json> [--json] [--top N] [--require-counters]`` —
   CPU/utilization attribution (obs/profile.py): per-rank core use,
   the on/off-CPU split of every marked phase, pool overlap efficiency

@@ -60,6 +60,7 @@ import json
 import os
 from typing import Dict, List, Optional, Tuple
 
+from mpit_tpu.obs import copies as _copies
 from mpit_tpu.obs.clock import PeerClock
 
 #: the phase taxonomy, in causal order.  ``retry`` holds the time spent
@@ -760,6 +761,9 @@ def analyze(path_or_obj, min_join: float = 0.0) -> dict:
         "streaming": streaming,
         "aggregation": aggregation_section(agg_rows),
         "cpu_attribution": cpu_section,
+        # The round's passes over the host's memory (obs/copies.py):
+        # None for a trace without the stream thread's copy spans.
+        "host_copies": _copies.section(events, other),
         "slowest": slowest,
         "violations": violations,
         "chains": decomposed,
@@ -892,6 +896,8 @@ def render_report(report: dict, top: int = 5) -> str:
                     f"{e['wall_us'] / 1000.0:.3f}ms")
             if parts:
                 lines.append(f"  {key}: " + "  ".join(parts))
+    if report.get("host_copies"):
+        lines += _copies.render(report["host_copies"])
     for d in report["slowest"][:top]:
         decomp = "  ".join(f"{phase}={d['phases'][phase] / 1000.0:.3f}"
                            for phase in PHASES if d["phases"][phase] > 0)

@@ -38,6 +38,14 @@ kinds:
   ``exchange``, a server's GRAD and PARAM op spans), what that thread
   spent copying, polling and asleep in the scheduler's back-off.
 
+- **Copy spans** (:meth:`SpanRecorder.copy`): one per piece of the
+  vector that the round's stream thread moves between the device and the
+  host (category ``copy``, names ``d2h`` and ``h2d``, and ``h2d_shard``
+  that closes a shard's upload), finished when recorded, at stamps the
+  caller took.  With the wire spans' ``copies`` and ``apply_exec``'s
+  ``bytes_moved`` they are the round's passes over the host's memory
+  (obs/copies.py).
+
 Every op span carries ``n``, its ordinal on its channel (``tid``).  The
 channels are strictly sequential on both sides, so the client half and
 the server half of one op share (client rank, server rank, op, ``n``)
@@ -181,6 +189,19 @@ class WireSpan:
         self.args = args
 
 
+class CopySpan(WireSpan):
+    """One pass of a piece of the vector over the host's memory, as the
+    thread that made it timed it: a finished span with phases."""
+
+    __slots__ = ("marks",)
+    cat = "copy"
+
+    def __init__(self, name: str, tid: str, t0: float, t1: float,
+                 marks: tuple, args: Dict[str, object]):
+        super().__init__(name, tid, t0, t1, args)
+        self.marks = marks
+
+
 class NullMeter:
     """The disabled :class:`WireMeter`: notes nothing, reads nothing."""
 
@@ -189,7 +210,7 @@ class NullMeter:
     def start(self) -> None:
         pass
 
-    def note(self, span) -> None:
+    def note(self, span, stretch: bool = True) -> None:
         pass
 
 
@@ -205,8 +226,13 @@ class WireMeter:
     a span as ``wire_tx_copy_ms``, ``wire_rx_copy_ms``, ``wire_poll_ms``
     (progress less the copies) and ``sched_sleep_ms``, with
     ``wire_span_ms``, the stretch they are of: since :meth:`start` or
-    the note before.  A transport that keeps no totals (tcp, local)
-    leaves the wire's three out."""
+    the note before (left out where the span has a phase of that very
+    stretch, as the ``round`` span's ``exchange``: ``stretch=False``).
+    A transport that keeps no totals (tcp, local)
+    leaves the wire's three out.  Where the scheduler's owner names its
+    sleeps (``Scheduler.sleep_by``: the PS client does, by what its
+    pending ops waited for), each name's share goes beside them as
+    ``sleep_<name>_ms``; they sum to ``sched_sleep_ms``."""
 
     __slots__ = ("_totals", "_sched", "_last", "_t")
 
@@ -218,17 +244,21 @@ class WireMeter:
     def _read(self) -> Dict[str, float]:
         now = dict(self._totals()) if self._totals is not None else {}
         now["sched_sleep"] = getattr(self._sched, "sleep_s", 0.0)
+        for name, slept in getattr(self._sched, "sleep_by", {}).items():
+            now[f"sleep_{name}"] = slept
         return now
 
     def start(self) -> None:
         self._last = self._read()
         self._t = time.monotonic()
 
-    def note(self, span) -> None:
+    def note(self, span, stretch: bool = True) -> None:
         now, t = self._read(), time.monotonic()
-        d = {k: (v - self._last[k]) * 1e3 for k, v in now.items()}
-        out = {"sched_sleep_ms": d["sched_sleep"],
-               "wire_span_ms": (t - self._t) * 1e3}
+        d = {k: (v - self._last.get(k, 0.0)) * 1e3 for k, v in now.items()}
+        out = {f"{k}_ms": v for k, v in d.items() if k.startswith("sleep_")}
+        out["sched_sleep_ms"] = d["sched_sleep"]
+        if stretch:
+            out["wire_span_ms"] = (t - self._t) * 1e3
         if "progress" in d:
             out.update(
                 wire_tx_copy_ms=d["tx_copy"], wire_rx_copy_ms=d["rx_copy"],
@@ -427,6 +457,19 @@ class SpanRecorder:
             args["round"] = current
         self.spans.append(WireSpan(name, tid, t0, t1, args))
 
+    def copy(self, name: str, rank: object, thread: str, t0: float,
+             t1: float, marks: tuple = (), track: str = "", **args) -> None:
+        """Record the finished copy span ``name`` of ``rank``'s thread
+        ``thread``, from ``t0`` to ``t1`` with the phases ``marks``
+        (``(phase, begin)`` in order, the first at ``t0``): the caller's
+        own stamps, seconds on the clock of :meth:`clock`.  One thread's
+        spans follow each other on one track; ``track`` names another
+        for spans that lie across them."""
+        prefix = f"r{rank}:" if rank is not None else ""
+        args.update(rank=rank, thread=thread)
+        self.spans.append(CopySpan(
+            name, f"{prefix}copy:{thread}{track}", t0, t1, marks, args))
+
     def wire_meter(self, transport: Any, sched: Any) -> WireMeter:
         return WireMeter(transport, sched)
 
@@ -552,6 +595,10 @@ class NullRecorder:
 
     def wire(self, name: str, rank: int, peer: int, tag: int,
              t0: float, t1: float, **args) -> None:
+        pass
+
+    def copy(self, name: str, rank: object, thread: str, t0: float,
+             t1: float, marks: tuple = (), track: str = "", **args) -> None:
         pass
 
     def wire_meter(self, transport: Any, sched: Any) -> NullMeter:

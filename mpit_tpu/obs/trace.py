@@ -22,7 +22,10 @@ readable by Perfetto (https://ui.perfetto.dev) and chrome://tracing:
   that rank's ``epoch_offset`` (seconds) and ``clock_id``: subtracting
   the offset takes an exported timestamp back to the rank's monotonic
   clock, and ranks with one ``clock_id`` (one host) share that clock
-  exactly.
+  exactly: ``CLOCK_MONOTONIC``, which ``time.monotonic`` and the native
+  transport's stamps (the ``wire`` spans' begin, end and ``copies``)
+  both read.  The merge refuses parts whose ``clock_id`` differ: every
+  reader of a merged trace subtracts across ranks as if they were one.
 - a concurrently captured ``jax.profiler`` trace is on the profiler's
   own clock, not on this one.  The two are joined explicitly: every
   ``round`` span enters a ``TraceAnnotation("mpit.round", round=k,
@@ -181,7 +184,10 @@ def maybe_write_rank_trace(rank: int, role: str = "") -> Optional[str]:
 
 def merge_traces(out_path: str, parts: List[str]) -> int:
     """Concatenate per-rank part files (each already stamped with its
-    own pid) into one merged trace; returns the merged event count."""
+    own pid) into one merged trace; returns the merged event count.
+    Raises ``ValueError`` where two ranks name different ``clock_id``:
+    their monotonic clocks are not one, and the readers of a merged
+    trace (the wire's join, the host copies' sweep) assume they are."""
     events: List[dict] = []
     ranks: Dict[str, dict] = {}
     clock: Dict[str, dict] = {}
@@ -192,6 +198,12 @@ def merge_traces(out_path: str, parts: List[str]) -> int:
         other = obj.get("otherData") or {}
         ranks.update(other.get("ranks", {}))
         clock.update(other.get("clock", {}))
+    clocks = {info["clock_id"]: rank for rank, info in sorted(ranks.items())
+              if isinstance(info, dict) and info.get("clock_id")}
+    if len(clocks) > 1:
+        raise ValueError(
+            "ranks of different monotonic clocks cannot be merged onto one "
+            f"timeline: clock_id {clocks} (one rank of each)")
     events.sort(key=lambda e: e.get("ts", -1.0))
     with open(out_path, "w") as fh:
         json.dump({"traceEvents": events, "displayTimeUnit": "ms",

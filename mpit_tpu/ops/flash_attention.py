@@ -86,7 +86,6 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from mpit_tpu.obs.metrics import get_registry
 from mpit_tpu.ops.select_bits import SUPER, unpack as _unpack_select
 from mpit_tpu.ops.tiles import (
     LANE, round_up as _round_up, use_interpret as _interpret,
@@ -945,9 +944,6 @@ def flash_attention_partial(
     (:mod:`mpit_tpu.parallel.ring_attention`).  ``q`` one rank above
     ``k`` is grouped (:func:`_group_queries`); ``select`` as
     :func:`flash_attention` takes it."""
-    _note_steps(("fwd",), q, k, causal=causal, window=window,
-                block_q=block_q, block_k=block_k, q_offset=q_offset,
-                kv_offset=kv_offset)
     f = lambda q2, k2, v2, *sel: _fa_2d(
         q2, k2, v2, q_offset, kv_offset, causal=causal, sm_scale=sm_scale,
         block_q=block_q, block_k=block_k, interpret=interpret, partial=True,
@@ -1343,21 +1339,6 @@ def flash_step_counts(kernel, q_shape, k_shape, dtype, *, causal=False,
     return {name: n * calls for name, n in one.items()}
 
 
-def _note_steps(kernels, q, k, **kw):
-    """While obs records: the step counts of the kernels this lowering
-    holds, on ``mpit_fa_steps_{visited,live,rect}_total`` by ``kernel``.
-    Runs where a call is traced, once a lowering, never in the step;
-    with obs off the registry is the null one and nothing is counted."""
-    registry = get_registry()
-    if not registry.enabled:
-        return
-    for kernel in kernels:
-        counts = flash_step_counts(kernel, q.shape, k.shape, q.dtype, **kw)
-        for name, n in counts.items():
-            registry.counter(f"mpit_fa_steps_{name}_total",
-                             kernel=kernel).inc(n)
-
-
 def flash_attention_bwd_pair(q, k, v, do, lse, *, causal=False, sm_scale=None,
                              q_offset=0, kv_offset=0, delta=None, o=None,
                              block_q=None, block_k=None, interpret=None,
@@ -1377,9 +1358,6 @@ def flash_attention_bwd_pair(q, k, v, do, lse, *, causal=False, sm_scale=None,
     fused = _use_fused_bwd(q.shape, k.shape, q.shape[-1], q.dtype,
                            sm_scale, block_q, block_k, window,
                            select is not None)
-    _note_steps(("fused",) if fused else ("dq", "dkdv"), q, k,
-                causal=causal, window=window, block_q=block_q,
-                block_k=block_k, q_offset=q_offset, kv_offset=kv_offset)
     f = lambda q2, k2, v2, do2, lse2, delta2, *sel: _fa_2d_bwd(
         q2, k2, v2, do2, lse2, delta2, q_offset, kv_offset, causal=causal,
         sm_scale=sm_scale, block_q=block_q, block_k=block_k,
@@ -1401,8 +1379,6 @@ def _make_flash(causal, sm_scale, block_q, block_k, interpret, precision,
 
     @jax.custom_vjp
     def fa(q, k, v, q_offset, kv_offset, *sel):
-        _note_steps(("fwd",), q, k, causal=causal, window=window,
-                    block_q=block_q, block_k=block_k)
         f = lambda q2, k2, v2, *sel2: _fa_2d(
             q2, k2, v2, q_offset, kv_offset, causal=causal,
             sm_scale=sm_scale, block_q=block_q, block_k=block_k,

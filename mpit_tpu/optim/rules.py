@@ -266,6 +266,17 @@ def state_slots(name: str) -> int:
             f"unknown rule {name!r}; have {sorted(_RULES)}") from None
 
 
+def streams(state: State, size: int) -> int:
+    """Passes over a shard's worth of memory that one ``apply`` to a shard
+    of ``size`` elements makes, from the state it carries: it reads the
+    shard, the gradient and every vector-shaped state array and writes the
+    shard and those arrays back (a scalar step counter is free): 3 for
+    plain add, 7 for Adam, ``3 + 2 * STATE_SLOTS[rule]``.  What the
+    server's ``apply_exec`` span says it moved (``bytes_moved``)."""
+    return 3 + 2 * sum(1 for leaf in state.values()
+                       if getattr(leaf, "size", 0) == size)
+
+
 def names() -> Tuple[str, ...]:
     return tuple(_RULES)
 

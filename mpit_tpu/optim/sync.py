@@ -8,9 +8,10 @@ that round, recorded as one ``round`` span whose phases tile it
 span also carries what the client's thread did on the wire during
 ``exchange``: ``wire_tx_copy_ms``, ``wire_rx_copy_ms``, ``wire_poll_ms``
 and ``sched_sleep_ms``, and what is left of the phase is the
-interpreter's; what the stream's thread did while it staged the
-payload, :data:`STAGE_PARTS`; and ``direct_bytes``, the payload bytes
-the push read from the pieces and not from the mirror):
+interpreter's, with its back-off sleeps by what the pending ops waited
+for, ``sleep_<reason>_ms``; and ``direct_bytes``, the payload bytes the
+push read from the pieces and not from the mirror.  What the stream's
+thread did is a ``copy`` span a piece, :data:`STAGE_PHASES`):
 
 ``wait_backward`` → ``d2h`` → ``stage`` → ``exchange`` → ``h2d`` →
 ``telemetry``
@@ -59,8 +60,7 @@ the device plane's front) is one shard, the whole vector, with no hook
 on it: its payload goes down in the same pieces, the three calls run
 once the vector is whole in the mirror (nobody gates them), and the
 shell sinks the shard itself after ``wait``.  With one shard nothing
-moves beside anything
-(``shards_streamed`` 0); the pieces are what is left of the gain.
+moves beside anything; the pieces are what is left of the gain.
 
 With obs off every span site is a call on ``NULL_SPAN``: no fence is
 taken and no telemetry is computed, and the only clock reads are the
@@ -158,18 +158,34 @@ def _exchange(opt: Any, span: Any) -> None:
     opt.pc.wait()
     if plain:
         opt.sync_seconds += time.monotonic() - t0
-    meter.note(span)  # what the client's thread did on the wire meanwhile
+    # what the client's thread did on the wire during the phase
+    meter.note(span, stretch=False)
     span.mark("h2d")
 
 
-#: What the stream's thread did while it staged a round's payload, noted
-#: on the ``round`` span while recording (ms, summed over the pieces):
-#: waiting for a piece's DMA, copying it into the mirror (a piece handed
-#: to its send is not copied: about 0 where every shard is followed),
-#: standing at :data:`HELD_BYTES` until the client had placed more, and
-#: freeing the piece and cutting the next.
-STAGE_PARTS = ("stage_wait_ms", "stage_copy_ms", "stage_held_ms",
-               "stage_issue_ms")
+#: The phases of a piece's ``d2h`` copy span (category ``copy``, one a
+#: piece while recording, on the stream's thread; they tile it, and the
+#: spans tile the thread's time from before the first cut until the
+#: payload is whole on the host): waiting for the piece's DMA
+#: (``np.asarray`` returning), handing it to its send or copying it into
+#: the mirror (a piece handed over is not copied: about 0 where every
+#: shard is followed), standing at :data:`HELD_BYTES` until the client
+#: had placed more, and the thread's own work between them: freeing the
+#: piece, cutting the next (before the first piece, the first
+#: :data:`IN_FLIGHT` cuts).  The span's args: ``pass`` ``d2h``,
+#: ``round``, ``shard``, ``lo`` (the piece's first element), ``bytes``,
+#: ``streams`` (passes over the host's memory: 1, the DMA's landing; 3
+#: where the piece was copied into the mirror as well), ``in_flight``
+#: (cuts outstanding when this one was popped, itself included) and
+#: ``issued_ms`` (how long before the span's ``wait`` its cut was
+#: dispatched).  On the way up a piece's ``h2d`` span is the dispatch of
+#: its ``device_put`` and paste (``pass`` ``h2d``), and one ``h2d_shard``
+#: span a shard closes them: from its first piece to its last dispatch
+#: (``ready`` 0: a transfer completes after the call returns and nothing
+#: waits for it) or, the round's last, to the instant the shell found
+#: the parameters whole on the device (``ready`` 1: the fence it takes
+#: while recording anyway, the end of the ``h2d`` phase).
+STAGE_PHASES = ("wait", "hand", "held", "issue")
 
 
 def _no_clock() -> float:
@@ -196,14 +212,19 @@ class _Copies:
     lock, so the client's thread keeps pumping beside them."""
 
     def __init__(self, stream: "ShardStream", payload: jnp.ndarray,
-                 consume: bool, timed: bool):
+                 consume: bool, rec: Any, k: int, rank: object):
         self.stream = stream
         self.payload = payload
         self.consume = consume
-        # Where the staging went (zeros, and no clock read, unless
-        # recording): :data:`STAGE_PARTS`.  It paces the push.
-        self.now = time.monotonic if timed else _no_clock
-        self.spent = dict.fromkeys(STAGE_PARTS, 0.0)
+        # Where the staging went: a copy span a piece (no span, no clock
+        # read and nothing made for one unless recording).  It paces the
+        # push.
+        self.rec = rec if rec.enabled else None
+        self.now = time.monotonic if rec.enabled else _no_clock
+        self.k, self.rank = k, rank  # the round, the worker
+        #: the last sunk shard's ``h2d_shard`` span, not yet recorded:
+        #: ``(rank, begin, end of dispatch, args)``
+        self.closing: Optional[tuple] = None
         self.first_piece = threading.Event()
         self.staged = [0] * len(stream.cut)  # bytes whole on the host
         # The followed shards' pieces: landed and not yet taken by the
@@ -291,25 +312,29 @@ class _Copies:
         # lets go of the view, not here.
         aliased = jax.default_backend() == "cpu"
 
+        now, rec = self.now, self.rec
+
         def issue() -> None:
             piece, start = next(todo, (None, None))
             if piece is not None:
                 _shard, lo, hi = piece
                 part = _cut(payload, start, size=hi - lo)
                 part.copy_to_host_async()
-                flight.append((piece, part))
+                flight.append((piece, part, now()))
 
+        t_end = now()  # the first span begins before the first cuts
         for _ in range(IN_FLIGHT):
             issue()
-        now, spent = self.now, self.spent
         while flight and not self.quit:
-            (shard, lo, hi), part = flight.popleft()
+            t_begin, in_flight = t_end, len(flight)
+            (shard, lo, hi), part, t_issued = flight.popleft()
             t_pop = now()
             host = np.asarray(part)
             self.first_piece.set()
             t_host = now()
+            nbytes = host.nbytes
             if stream.follow[shard]:
-                self.direct_bytes += host.nbytes
+                self.direct_bytes += nbytes
                 self.handed[shard].append(host)
             else:
                 np.copyto(stream.grad_host[lo:hi], host)
@@ -323,11 +348,17 @@ class _Copies:
             self._wait_for_room()
             t_room = now()
             issue()
-            spent["stage_wait_ms"] += (t_host - t_pop) * 1e3
-            spent["stage_copy_ms"] += (t_staged - t_host) * 1e3
-            spent["stage_held_ms"] += (t_room - t_freed) * 1e3
-            spent["stage_issue_ms"] += (
-                t_freed - t_staged + now() - t_room) * 1e3
+            t_end = now()
+            if rec is not None:
+                rec.copy(
+                    "d2h", self.rank, "stream", t_begin, t_end,
+                    (("issue", t_begin), ("wait", t_pop), ("hand", t_host),
+                     ("issue", t_staged), ("held", t_freed),
+                     ("issue", t_room)),
+                    round=self.k, shard=shard, lo=lo,
+                    bytes=nbytes, streams=1 if stream.follow[shard] else 3,
+                    in_flight=in_flight,
+                    issued_ms=(t_pop - t_issued) * 1e3, **{"pass": "d2h"})
         if not flight:  # every cut has run
             self.whole.set()
             if self.consume:
@@ -338,16 +369,45 @@ class _Copies:
         # On the CPU backend a put may alias host memory, and the
         # mirror is overwritten by the next round's PARAM.
         private = jax.default_backend() == "cpu"
+        now, rec = self.now, self.rec
         while True:
             shard = self.landed.get()
+            if self.closing is not None and shard is not None:
+                # not the round's last: its dispatch alone
+                _close_upload(rec, self.closing, None)
+                self.closing = None
             if shard is None or self.quit:
                 return
             if self.w is None:
                 self.w = jnp.zeros(stream.w_host.shape, stream.w_host.dtype)
+            t_first = t0 = now()
             for lo, hi in stream.parts[shard]:
                 view = stream.w_host[lo:hi]
                 part = jax.device_put(view.copy() if private else view)
                 self.w = _paste(self.w, part, lo)
+                if rec is not None:
+                    t1 = now()
+                    rec.copy("h2d", self.rank, "stream", t0, t1,
+                             round=self.k, shard=shard, lo=lo,
+                             bytes=view.nbytes, streams=1, **{"pass": "h2d"})
+                    t0 = t1
+            if rec is not None:
+                self.closing = (self.rank, t_first, t0, {
+                    "round": self.k, "shard": shard,
+                    "bytes": stream.nbytes[shard],
+                    "pieces": len(stream.parts[shard])})
+
+
+def _close_upload(rec: Any, closing: tuple,
+                  ready_at: Optional[float]) -> None:
+    """Record a shard's ``h2d_shard`` span (``closing``:
+    :attr:`_Copies.closing`): to ``ready_at``, the instant its pieces
+    were seen whole on the device, or (None) to the return of its last
+    dispatch."""
+    rank, t0, t1, args = closing
+    rec.copy("h2d_shard", rank, "stream", t0,
+             t1 if ready_at is None else max(t1, ready_at),
+             track=":shards", ready=int(ready_at is not None), **args)
 
 
 def _serve(rounds: "queue.SimpleQueue[Optional[_Copies]]") -> None:
@@ -383,11 +443,11 @@ class ShardStream:
         #: work of the thread that paces the push (0.35 against 0.20 ms a
         #: piece on a v5e: PERF.md section 6, PR 40)
         self.starts: List[jnp.ndarray] = []
-        self.shards_streamed = 0  # shards that move beside each other
         self.gated = False  # the client took the gate and asks it itself
-        self.m_streamed: Any = None  # mpit_round_streamed_total
         self._index: Dict[int, int] = {}  # a shard's offset -> its number
         self._worker: Optional[_Copies] = None  # this round's, in a round
+        #: the last round's last ``h2d_shard`` span, until it is recorded
+        self.closing: Optional[tuple] = None
         self._rounds: "queue.SimpleQueue[Optional[_Copies]]" = (
             queue.SimpleQueue())
         self._thread: Optional[threading.Thread] = None
@@ -413,7 +473,6 @@ class ShardStream:
                        for lo, hi in parts]
         self.starts = [jnp.asarray(lo, jnp.int32)
                        for _shard, lo, _hi in self.pieces]
-        self.shards_streamed = len(cut) if len(cut) > 1 else 0
 
     # -- the hooks (on the client's thread; neither blocks) ------------------
 
@@ -466,8 +525,9 @@ class ShardStream:
             self._thread = threading.Thread(
                 target=self._serve, name="mpit-round-stream", daemon=True)
             self._thread.start()
-        worker = self._worker = _Copies(self, payload, consume,
-                                        opt._spans.enabled)
+        worker = self._worker = _Copies(
+            self, payload, consume, opt._spans, opt.rounds,
+            getattr(opt.pc, "rank", None))
         self._rounds.put(worker)
         try:
             worker.first_piece.wait()
@@ -489,9 +549,17 @@ class ShardStream:
             worker.done.wait()
             self._worker = None
         worker.check()
-        # (the null span's, with obs off)
-        span.note(direct_bytes=worker.direct_bytes, **worker.spent)
+        span.note(direct_bytes=worker.direct_bytes)  # (the null span's, off)
+        self.closing = worker.closing
         return worker.w
+
+    def uploaded(self, opt: Any, ready_at: float) -> None:
+        """While recording: the shell saw the round's parameters whole
+        on the device at ``ready_at``, which ends the last sunk shard's
+        ``h2d_shard`` span."""
+        if self.closing is not None:
+            _close_upload(opt._spans, self.closing, ready_at)
+            self.closing = None
 
 
 def attach(opt: Any) -> None:
@@ -501,7 +569,6 @@ def attach(opt: Any) -> None:
     (``stream_shards``), and then the feed (``stream_pieces``: the client
     says which shards' sends read the pieces); if not, to one shard with
     no hook on it."""
-    opt.rounds_streamed = 0  # rounds in which two or more shards streamed
     stream = opt._stream = ShardStream(opt.grad_host, opt.w_host)
     # Tested by name: ``isinstance`` on a protocol looks past
     # ``__getattr__``, so it would not see the extension behind a front
@@ -512,8 +579,6 @@ def attach(opt: Any) -> None:
     follow = getattr(opt.pc, "stream_pieces", None) if cut else None
     stream.bind(cut or [_Whole(0, opt.grad_host.size)],
                 follow(stream.feed) if follow else None)
-    stream.m_streamed = get_registry().counter(
-        "mpit_round_streamed_total", rank=getattr(opt.pc, "rank", None))
     # What the client's one thread does on the wire during ``exchange``
     # (obs/spans.py ``WireMeter``; the null one while obs is off).
     opt._wire_meter = opt._spans.wire_meter(
@@ -561,14 +626,10 @@ def push_pull(opt: Any, payload: jnp.ndarray,
         unorm = shipped_norm(payload)  # dispatched; read under telemetry
     stream = opt._stream
     w = stream.round(opt, span, payload, consume)
-    span.note(pieces=len(stream.pieces),
-              shards_streamed=stream.shards_streamed)
-    if stream.shards_streamed:
-        opt.rounds_streamed += 1
-        stream.m_streamed.inc()
     if rec.enabled:
         jax.block_until_ready(w)
         span.mark("telemetry")
+        stream.uploaded(opt, span.marks[-1][1])
         opt._m_unorm.set(float(unorm))
         if loss is not None:
             opt._m_loss.set(float(loss))

@@ -115,6 +115,11 @@ from mpit_tpu.shardctl import shardmap as _shardmap
 from mpit_tpu.shardctl import wire as _scwire
 from mpit_tpu.utils.logging import get_logger
 
+#: What a back-off sleep of the client's scheduler inside ``exchange`` was
+#: a wait for (:meth:`ParamClient._why_asleep`): the ``round`` span's
+#: ``sleep_<reason>_ms``, which sum to its ``sched_sleep_ms``.
+SLEEP_REASONS = ("staging", "apply", "drain", "pull")
+
 
 class ParamClient:
     def __init__(
@@ -310,6 +315,15 @@ class ParamClient:
         # Where a followed shard's GRAD send gets its pieces
         # (stream_pieces): None unless a shell installed it.
         self._pieces: Optional[Callable[[Shard], Optional[Callable]]] = None
+        # While recording: the scheduler's back-off sleeps by what the
+        # pending ops waited for (:meth:`_why_asleep`), which the shell's
+        # wire meter notes on the ``round`` span; ``_gating`` counts the
+        # GRAD ops standing at the gate.
+        self._gating = 0
+        self._waiting = getattr(self.transport, "waiting", None)
+        if self._spans.enabled:
+            self.sched.why = self._why_asleep
+            self.sched.sleep_by = dict.fromkeys(SLEEP_REASONS, 0.0)
 
     # -- lifecycle ----------------------------------------------------------
 
@@ -1571,9 +1585,33 @@ class ParamClient:
         with obs off), which the GRAD span opened next carries as
         ``gated_ms``."""
         t0 = self._spans.clock()
-        while self._staged(shard) < nbytes:
-            yield EXEC
+        self._gating += 1
+        try:
+            while self._staged(shard) < nbytes:
+                yield EXEC
+        finally:
+            self._gating -= 1
         return (self._spans.clock() - t0) * 1e3
+
+    def _why_asleep(self) -> str:
+        """What the pending ops waited for when the scheduler backed off
+        (one of :data:`SLEEP_REASONS`; asked while recording only), from
+        what this client and its transport already hold: ``staging``, a
+        GRAD op stands at the gate or its send has placed every piece
+        the shell has staged; ``apply``, a PARAM's receive is posted and
+        its server has not begun to send; ``drain``, a send has bytes
+        left that its server's ring has no room for; ``pull``, a PARAM is
+        landing and its next chunks are not published.  Several at once
+        count as the first of that order; none of them (an ack awaited:
+        the server is still taking the message in) as ``drain``."""
+        waits = self._waiting() if self._waiting is not None else ()
+        if self._gating or "unready" in waits:
+            return "staging"
+        if "unanswered" in waits:
+            return "apply"
+        if "partial" in waits and "blocked" not in waits:
+            return "pull"
+        return "drain"
 
     # -- public async API (reference pclient.lua:84-109) --------------------
 

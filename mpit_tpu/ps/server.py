@@ -121,6 +121,7 @@ from mpit_tpu.obs import (
 )
 from mpit_tpu.obs import clock as obs_clock
 from mpit_tpu.optim.rules import ShardRule, make as make_rule
+from mpit_tpu.optim.rules import streams as rule_streams
 from mpit_tpu.ps import serve as _psserve
 from mpit_tpu.ps import tags
 from mpit_tpu.shardctl import migrate as _scmigrate
@@ -598,11 +599,19 @@ class ParamServer:
         its phases are ``queued`` (behind the apply dispatched before
         it) and ``exec``.  ``grad_n`` is the GRAD span's ordinal: a dup
         or stale frame opens a GRAD span and no apply, so the two
-        ordinals may part."""
+        ordinals may part.  ``bytes_moved`` is what the sweep reads and
+        writes: the shard's bytes times the rule's streams
+        (``optim/rules.py`` ``streams``), which makes ``exec`` a pass
+        ``apply`` over the host's memory (``obs/copies.py``)."""
         if not self._spans.enabled:
             return NULL_SPAN
-        span = self._spans.op("apply_exec", peer=crank, side="server",
-                              rank=self.rank, grad_n=grad_span.args["n"])
+        state = (self._hbm.rule_state if self._hbm is not None
+                 else self.rule_state)
+        span = self._spans.op(
+            "apply_exec", peer=crank, side="server", rank=self.rank,
+            grad_n=grad_span.args["n"],
+            bytes_moved=self.param.nbytes * rule_streams(
+                state or {}, self.param.size))
         span.mark("queued")
         return span
 

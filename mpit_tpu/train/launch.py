@@ -892,9 +892,7 @@ def _run_role(rank: int, size: int, cfg: Config, transport: Any,
     else:
         trainer = MnistTrainer(cfg, pclient=pclient, data=data, rank=rank)
     log.info("worker with servers %s", sranks)
-    return {"role": "worker", **trainer.run(),
-            "rounds_streamed": getattr(trainer.optimizer,
-                                       "rounds_streamed", 0)}
+    return {"role": "worker", **trainer.run()}
 
 
 # -- process-mode launcher (the mpirun analog) -------------------------------
@@ -1128,7 +1126,7 @@ def _summarize(result: Dict[str, Any]) -> Dict[str, Any]:
             "best_test_err",
             "reads", "monotone", "busy_honored",
             "final_loss", "final_eval_loss", "tokens_per_s", "tokens_total",
-            "steps", "rounds_streamed", "rx_direct_bytes",
+            "steps", "rx_direct_bytes",
             "rx_assembled_bytes", "tx_chunks", "tx_ring_full", "rx_chunks",
             "rx_overlap_chunks", "tx_early_bytes", "train_seconds",
             "first_step_seconds", "mosaic_calls",

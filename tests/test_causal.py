@@ -668,11 +668,17 @@ class TestWireJoin:
         assert not loose and len(pairs) == len(ways)
         # rank a sent three messages, b and c one each: msg_id alone
         # would confuse them, (src, dst, msg_id) cannot
-        got = sorted((tx.args["rank"], rx.args["rank"], tx.args["msg_id"],
-                      rx.args["direct"]) for tx, rx in pairs)
+        got = sorted((tx.args["rank"], rx.args["rank"], tx.args["msg_id"])
+                     for tx, rx in pairs)
         assert got == sorted(
-            (src.rank, dst.rank, n, direct)
-            for (src, dst, _r, direct), n in zip(ways, (1, 2, 1, 1, 3)))
+            (src.rank, dst.rank, n)
+            for (src, dst, _r, _direct), n in zip(ways, (1, 2, 1, 1, 3)))
+        # which way each went is the endpoints' own count, not the span's
+        direct = {r.rank: r.rx_path_bytes()["rx_direct_bytes"]
+                  for r in wires}
+        assert direct == {
+            r.rank: self.BIG * sum(d for _s, dst, _r, d in ways
+                                   if dst is r) for r in wires}
         for tx, rx in pairs:
             assert (tx.name, rx.name) == ("tx", "rx")
             assert tx.args["msg_id"] == rx.args["msg_id"]

@@ -1238,8 +1238,10 @@ def test_keyes_mix_keeps_to_the_traffic_its_issue_fixed():
             assert metric["workloads"][-1] == KEYE_CELL
         elif "workloads" in metric:
             assert KEYE_CELL not in metric["workloads"], metric["name"]
-    assert cell.bench["per_layer"][-5:] == [
-        m for m in cell.bench["per_layer"] if m["name"] in KEYE_METRICS]
+    # added together and in order (later PRs' entries follow them)
+    keye = [m for m in cell.bench["per_layer"] if m["name"] in KEYE_METRICS]
+    at = cell.bench["per_layer"].index(keye[0])
+    assert cell.bench["per_layer"][at:at + 5] == keye
     assert (cell.bench["configs"][-1]["name"],
             cell.bench["workloads"][-1]["name"]) == (cell.config_name,
                                                      KEYE_CELL)
@@ -1363,3 +1365,58 @@ def test_a_line_of_the_benchmark_file_keeps_to_200_printable_characters(
     # 38's first check: the new cell's `why` had 220).
     assert 1 <= len(text) <= 200, (where, len(text))
     assert text.isprintable(), where
+
+
+# -- the host copies' readers on a recorded trace (PR 48) ----------------------
+
+COPIES_FIXTURE = spec_mod.ROOT / "chipbench" / "fixtures" / "copies2.obs_trace.json"
+COPIES_EXPECTED = json.loads(
+    (spec_mod.ROOT / "chipbench" / "fixtures" / "copies2.expected.json").read_text())
+PS_CELLS = ["c111m-ps1w-su1", "c1.3b-ps1w-su8", "olmoe-l1-ps1w-su1"]
+
+
+def copies_run():
+    """The ``run`` a reader is given, for the two rounds cut out of a
+    traced run of ``c111m-ps1w-su1`` on the chip
+    (``chipbench/fixtures/trim_obs_trace.py``)."""
+    fixture = json.loads(COPIES_FIXTURE.read_text())["otherData"]["fixture"]
+    return {"obs_trace": str(COPIES_FIXTURE), "results": {}, "reduction": {},
+            "summary": {"window": fixture["window"],
+                        "worker_ranks": fixture["worker_ranks"]}}
+
+
+@pytest.mark.parametrize("name", sorted(COPIES_EXPECTED["numbers"]))
+def test_a_host_copies_reader_keeps_its_number_on_the_recorded_rounds(name):
+    """Every new metric's reader runs on the committed cut and gives
+    what it gave when the cut was made: the arithmetic of the yardstick
+    is held by a recorded trace, not only by a hand-made one.  (The cut
+    brings no device trace, so the idle time's reader finds nothing.)"""
+    bench = spec_mod.load_bench(spec_mod.ROOT)
+    entry = next(m for m in bench["per_layer"] if m["name"] == name)
+    assert entry["workloads"] == PS_CELLS
+    perf = (spec_mod.ROOT / "PERF.md").read_text()
+    layers = perf[perf.index("## 3. Layers"):perf.index("## 4. Cells")]
+    assert f"| {entry['layer']} |" in layers and f"`{name}`" in layers
+    value = spec_mod.load_reader(spec_mod.ROOT, bench, name)(copies_run())
+    want = COPIES_EXPECTED["numbers"][name]
+    if want is None:
+        assert value is None and name == "idle_by_host_pass_pct"
+    else:
+        assert value == pytest.approx(want, rel=1e-9)
+
+
+def test_the_recorded_rounds_are_two_whole_rounds_of_the_c111m_cell():
+    from chipbench.layers import copytree
+    from mpit_tpu.obs import trace as obs_trace
+
+    obs_trace.validate_trace(str(COPIES_FIXTURE))
+    copies = copytree.load(copies_run())
+    assert [r.args["round"] for r, _m, _p in copies.rounds] == (
+        COPIES_EXPECTED["rounds"])
+    cell = spec_mod.load_cell(PS_CELLS[0])
+    vector = 4 * cell.arithmetic().param_count(cell.config)
+    for _r, mine, pieces in copies.rounds:
+        assert copies.prog.vector_bytes(1, mine) == vector
+        assert sum(c.moved for c in mine) == 17 * vector
+        assert sum(s.args["bytes"] for s in pieces
+                   if s.name == "h2d") == vector

@@ -343,40 +343,6 @@ def test_the_walks_step_counts_at_published_shapes(shape, kernel):
         assert visited == heads_kv * outer * per_row
 
 
-def _fa_counters():
-    return {name: value for name, value
-            in obs.get_registry().snapshot().items()
-            if name.startswith("mpit_fa_steps_")}
-
-
-def test_the_step_counters_move_at_lowering_only(obs_on):
-    """``mpit_fa_steps_*_total`` by ``kernel``: counted where a call is
-    traced, once a lowering; running the compiled step again counts
-    nothing, and a window's backward is the two kernels."""
-    q = jnp.ones((1, 8, 256, 32))
-    k = jnp.ones((1, 2, 256, 32))
-
-    @jax.jit
-    def step(q, k, v):
-        return jax.grad(lambda q: jnp.sum(flash_attention(
-            q, k, v, causal=True, window=100, block_q=64, block_k=128,
-            interpret=True)))(q)
-
-    assert _fa_counters() == {}
-    step(q, k, k)
-    first = _fa_counters()
-    # 2 KV heads x (4 heads x 4 q blocks) x 2 kv blocks a rectangle; a
-    # window of 100 over 64 x 128 blocks keeps both kv blocks in a row's
-    # bound and 2 + 4 of a head's 8 pairs live
-    for kernel in ("fwd", "dq", "dkdv"):
-        assert first[f'mpit_fa_steps_rect_total{{kernel="{kernel}"}}'] == 64
-        assert first[f'mpit_fa_steps_visited_total{{kernel="{kernel}"}}'] == 64
-        assert first[f'mpit_fa_steps_live_total{{kernel="{kernel}"}}'] == 48
-    assert not any("fused" in name for name in first)
-    step(q + 1.0, k, k)
-    assert _fa_counters() == first
-
-
 def test_with_obs_off_a_lowering_touches_no_registry(monkeypatch):
     def no_counter(*_a, **_k):
         raise AssertionError("a counter was asked for with obs off")

@@ -119,7 +119,7 @@ def test_the_device_pieces_of_a_consumed_payload_are_gone_after_the_round(
         opt._vgf = lambda w, t: held.append(quad(w, t)) or held[-1]
         w = opt.start(jnp.zeros(SIZE))
         w, _loss = opt.step(w, TARGET)
-        assert opt.rounds_streamed == 1
+        assert opt.rounds == 1 and len(opt._stream.cut) == 2
         assert len(cuts) == len(parts) == len(opt._stream.pieces) == 8
         assert all(piece.is_deleted() for piece in cuts)
         assert held[0][1].is_deleted()  # the gradient itself
