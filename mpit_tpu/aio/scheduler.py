@@ -416,6 +416,7 @@ def aio_recv(
     deadline: Optional[float] = None,
     abort: Optional[Callable[[], bool]] = None,
     request: Optional[Generator[str, None, Any]] = None,
+    landing: Optional[Callable[[int], None]] = None,
 ) -> Generator[str, None, Any]:
     """Nonblocking receive.  Returns the payload, or None if it gave up.
 
@@ -432,6 +433,15 @@ def aio_recv(
     assembled elsewhere and copied, and a quick peer beats a slow
     requester often enough to matter (the round's PARAM did in one pull
     of ten when the request went first: PERF.md section 6, PR 29).
+
+    ``landing``, with ``out`` and a transport that can say how far a
+    posted receive's buffer is filled (``follow``, ``comm/transport.py``):
+    told whenever that has moved, and once more when the message is whole,
+    how many bytes of ``out`` are the message's for good, from its front
+    (negative: the message that had begun to land was abandoned and
+    ``out`` fills anew), so that a reader may follow the landing.  The
+    transport tells it from whichever call made the progress, this
+    receive's own polls or another task's.  It must not block.
 
     ``deadline`` (absolute monotonic seconds) raises
     :class:`DeadlineExceeded` if the message is not whole in time;
@@ -460,6 +470,8 @@ def aio_recv(
                 return None
             yield EXEC
     handle = transport.irecv(src, tag, out=out)
+    if landing is not None:
+        transport.follow(handle, landing)
     whole = False
     try:
         if out is not None:

@@ -51,7 +51,7 @@ def _as_pointer(obj):
 #: lockstep with transport.cpp (see kApiVersion comment there) whenever
 #: the exported C surface changes, so a stale .so fails loudly with a
 #: rebuild message instead of a confusing missing-symbol AttributeError.
-MT_API_VERSION = 17007
+MT_API_VERSION = 17008
 
 
 class NativeTransportLib:
@@ -127,6 +127,8 @@ class NativeTransportLib:
         self._lib.mt_probe_size.argtypes = [ctypes.c_void_p, ctypes.c_int32, ctypes.c_int32]
         self._lib.mt_rank.restype = ctypes.c_int32
         self._lib.mt_rank.argtypes = [ctypes.c_void_p]
+        self._lib.mt_recv_filled.restype = ctypes.c_int64
+        self._lib.mt_recv_filled.argtypes = [ctypes.c_void_p, ctypes.c_int64]
         self._lib.mt_recv_size.restype = ctypes.c_int64
         self._lib.mt_recv_size.argtypes = [ctypes.c_void_p, ctypes.c_int64]
         self._lib.mt_release.restype = None
@@ -282,6 +284,10 @@ class NativeTransportLib:
     def mt_rank(self, ctx):
         """This endpoint's rank."""
         return self._lib.mt_rank(ctx)
+
+    def mt_recv_filled(self, ctx, handle):
+        """Bytes of a posted receive that lie in the caller's buffer from its front and are the message's for good: the whole size once done, 0 while no message is bound to it or its message goes through an assembly buffer. -1 for an unknown handle and for a receive whose buffer holds the front of a message its sender abandoned."""
+        return self._lib.mt_recv_filled(ctx, handle)
 
     def mt_recv_size(self, ctx, handle):
         """Actual byte size of a completed receive."""

@@ -41,7 +41,9 @@
 //    reports -2 and the message stays), a message queued ahead.
 //    Cancelling a bound receive moves what has landed into an assembly
 //    buffer, where the rest follows: the message stays whole for the next
-//    receive and the cancelled buffer is not written again.
+//    receive and the cancelled buffer is not written again.  How far a
+//    bound receive's buffer is filled from its front is the caller's to
+//    read between calls (mt_recv_filled: the chunks land in order).
 //  * Per-destination FIFO send queues give MPI-style non-overtaking order
 //    between any (src, dst) pair.
 //  * A send is a list of pieces, (pointer, length), placed in order: the
@@ -76,6 +78,7 @@
 // Exported C API (ctypes bindings are generated from specs/*.json by
 // gen_bindings.py, mirroring the reference's readspec.py codegen).
 
+#include <algorithm>
 #include <atomic>
 #include <cerrno>
 #include <cmath>
@@ -349,6 +352,7 @@ struct RecvOp {
   uint64_t size = 0;
   uint64_t msg_id = 0;  // the sender's message landing in `out`, once bound
   bool bound = false;
+  bool torn = false;  // a message had begun to land in `out` and was abandoned
   bool done = false;
   bool cancelled = false;
   bool size_mismatch = false;
@@ -551,6 +555,7 @@ void abandon_partials(Ctx* ctx, int src) {
       RecvOp& op = ctx->recvs.at(part.bound);
       op.bound = false;
       op.msg_id = 0;
+      op.torn = op.torn || part.filled > 0;
     } else {
       recycle_buffer(ctx, std::move(part.buf));
     }
@@ -988,6 +993,27 @@ int mt_test(void* vctx, int64_t handle) {
   return -1;
 }
 
+// Bytes of a posted receive that lie in the caller's buffer, from its
+// front, and are the message's for good (mt_send_written's mirror): what
+// the drain has copied out of the ring of the message bound to the receive
+// (Partial.filled moves after the copy has returned), the whole size once
+// the receive is done, 0 while no message is bound to it or its message
+// goes through an assembly buffer.  -1 for an unknown handle, and for a
+// receive whose buffer holds the front of a message its sender abandoned
+// (abandon_partials): the next message fills it from the front again, so
+// what was said before no longer holds, and nothing is said until whoever
+// reads has taken the buffer whole.
+int64_t mt_recv_filled(void* vctx, int64_t handle) {
+  auto* ctx = static_cast<Ctx*>(vctx);
+  auto rit = ctx->recvs.find(handle);
+  if (rit == ctx->recvs.end() || rit->second.torn) return -1;
+  const RecvOp& op = rit->second;
+  if (op.done) return (int64_t)op.size;
+  if (!op.bound) return 0;
+  const Partial& part = ctx->partial.at({op.src, op.msg_id});
+  return (int64_t)std::min(part.filled, part.total);
+}
+
 int64_t mt_recv_size(void* vctx, int64_t handle) {
   auto* ctx = static_cast<Ctx*>(vctx);
   auto rit = ctx->recvs.find(handle);
@@ -1301,7 +1327,7 @@ void mt_fold_f32(const void* vown, const void* vptrs, int32_t nchildren,
 // generated _bindings.py refuses a stale .so (loud rebuild message)
 // instead of failing with a confusing missing-symbol AttributeError.
 // Keep in sync with MT_API_VERSION in gen_bindings.py.
-int64_t mt_api_version(void) { return 17007; }
+int64_t mt_api_version(void) { return 17008; }
 
 }  // extern "C"
 

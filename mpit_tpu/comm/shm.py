@@ -76,6 +76,18 @@ A buffer of the wrong size raises the size mismatch and the message stays.
 ``cancel`` of a receive whose message has begun to land moves what has
 landed into an assembly buffer, the rest follows it there, and the next
 receive gets the message whole; the cancelled buffer is not written again.
+``filled(handle)`` says how far a posted receive's buffer is filled from
+its front (the optional capability of
+:class:`~mpit_tpu.comm.transport.Transport`, the mirror of ``written``):
+the chunks land in order, so every byte below that is the message's for
+good and a reader may take it before the message is whole.
+``follow(handle, told)`` has the endpoint say so itself, ``told(filled)``
+whenever the mark has moved, after *every* call that made progress and
+not only the receive's own ``test``: any call drains every ring, and an
+op that sends a request or takes an ack makes several in a row, a ring's
+worth of another receive's landing each, before its generator yields
+(the PARAM of one server stood 37 ms without a poll of its own beside
+the other server's ack and request: PERF.md section 6, PR 49).
 ``rx_path_bytes`` says how many bytes went which way.  Completed native
 handles are freed test-once style (like MPI requests); the Python Handle
 caches completion so repeated ``test`` stays idempotent.
@@ -164,6 +176,9 @@ class ShmTransport(Transport):
         # the drain, so they live until the receive is done or cancelled
         # even if the caller lets go of the Handle.
         self._posted: dict = {}
+        # Followed receives (:meth:`follow`), by native handle:
+        # ``[handle, told, the mark last told]``.
+        self._followed: dict = {}
         atexit.register(self.close)
 
     # -- Transport ----------------------------------------------------------
@@ -172,6 +187,8 @@ class ShmTransport(Transport):
         buf = self._sendable(data)
         nbytes = buf.nbytes if isinstance(buf, np.ndarray) else len(buf)
         native = self.lib.mt_isend(self._ctx, dst, tag, buf, nbytes)
+        if self._followed:
+            self._tell()
         return self._posted_send(native, dst, tag, nbytes, buf)
 
     def isend_pieces(self, nbytes: int, dst: int, tag: int) -> Handle:
@@ -228,6 +245,8 @@ class ShmTransport(Transport):
     def irecv(self, src: int, tag: int, out: Any | None = None) -> Handle:
         if out is None:
             size = self.lib.mt_probe_size(self._ctx, src, tag)
+            if self._followed:
+                self._tell()
             if size < 0:
                 raise RuntimeError(
                     "irecv without a buffer requires a probed message "
@@ -256,21 +275,66 @@ class ShmTransport(Transport):
         self._posted[native] = out
         return Handle(kind="recv", peer=src, tag=tag, out=out, native_id=native)
 
+    def filled(self, handle: Handle) -> int:
+        """Bytes of the posted receive ``handle`` that lie in its buffer,
+        from the front, and are its message's for good: what the drain has
+        copied out of the ring so far where the message lands in the buffer
+        itself, the whole size once ``test`` is true, 0 while no message
+        has begun to land or the message goes through an assembly buffer.
+        Negative once a message that had begun to land was abandoned by its
+        sender (the next one fills the buffer from its front again, so the
+        earlier answers no longer hold; it stays negative when the receive
+        is done: the reader takes the buffer whole), and for a receive that
+        was cancelled.  One native read, no progress made."""
+        if not handle.done:
+            handle.meta["filled"] = int(
+                self.lib.mt_recv_filled(self._ctx, handle.native_id))
+        return handle.meta["filled"]
+
+    def follow(self, handle: Handle, told: Any) -> None:
+        """From now until the posted receive ``handle`` is done or
+        cancelled, call ``told(filled)`` (:meth:`filled`) whenever the
+        mark has moved, on the thread and at the end of whichever call of
+        this endpoint made the progress (``test`` of any handle,
+        ``iprobe``, ``isend``, ``irecv`` of a probed message), and once
+        more, with the size (or the negative of a receive that was torn),
+        from the ``test`` that finds it done.  ``told`` must not block
+        and must not call the endpoint."""
+        self._followed[handle.native_id] = [handle, told, 0]
+
+    def _tell(self) -> None:
+        """Tell the followed receives' marks that moved."""
+        for entry in self._followed.values():
+            mark = self.filled(entry[0])
+            if mark != entry[2]:
+                entry[2] = mark
+                entry[1](mark)
+
     def iprobe(self, src: int, tag: int) -> bool:
-        return bool(self.lib.mt_iprobe(self._ctx, src, tag))
+        there = bool(self.lib.mt_iprobe(self._ctx, src, tag))
+        if self._followed:
+            self._tell()
+        return there
 
     def test(self, handle: Handle) -> bool:
         if handle.done or handle.cancelled:
             return handle.done
         code = self.lib.mt_test(self._ctx, handle.native_id)
         if code == 0:
+            if self._followed:
+                self._tell()
             return False
         self._posted.pop(handle.native_id, None)  # every other code is final
+        if code != 1:
+            self._followed.pop(handle.native_id, None)
         if code == 1:
             handle.done = True
             if handle.kind == "recv" and handle.meta.get("as_bytes"):
                 handle.payload = handle.out.tobytes()
                 handle.out = None
+            elif handle.kind == "recv":  # its last word, for ``filled``
+                handle.meta["filled"] = int(
+                    self.lib.mt_recv_filled(self._ctx, handle.native_id))
             if handle.kind == "recv":
                 out = handle.out if handle.out is not None else handle.payload
                 self._m_rx_msgs[handle.peer].inc()
@@ -278,6 +342,9 @@ class ShmTransport(Transport):
                     int(getattr(out, "nbytes", None) or len(out or b"")))
             if handle.kind == "send":
                 handle.buf = None  # release ownership back to the caller
+            if self._followed:
+                self._tell()  # this one's last word, the others' marks
+                self._followed.pop(handle.native_id, None)
             if self._rec.enabled:
                 self._wire_span(handle)
             if self._m_native:
@@ -304,6 +371,7 @@ class ShmTransport(Transport):
         if not handle.done:
             self.lib.mt_cancel(self._ctx, handle.native_id)
             self._posted.pop(handle.native_id, None)
+            self._followed.pop(handle.native_id, None)
         handle.cancelled = True
         handle.buf = None
 

@@ -25,6 +25,22 @@ has passed its end.  The peer cannot tell such a send from any other: it
 is one message of the same bytes.  There is no other form of a send that
 is not yet whole (a buffer that fills from the front is a send of its
 slices).
+
+Its mirror on the receiving side, a second optional capability (a
+transport has it if it has the method ``follow``; ``comm/shm.py`` has):
+``filled(handle)`` says how many bytes of a receive posted with ``out``
+lie in ``out`` from its front and will not be written again, so a reader
+may take them before ``test`` is true; all of them once it is, 0 while
+the transport assembles the message elsewhere, and a negative number if
+what it said before no longer holds (a message that had begun to land was
+abandoned: the reader starts over and takes the buffer whole).  It makes
+no progress and never blocks.  ``follow(handle, told)`` has the transport
+say it unasked: ``told(filled)`` whenever the mark has moved, from
+whichever call of the endpoint made the progress (a receive's own polls
+are not the only ones that move its mark) and once more from the ``test``
+that finds the receive done; this is what ``aio_recv(landing=)`` uses and
+what callers test for by name.  A transport without them says nothing
+before ``test`` is true, and its callers wait for that.
 """
 
 from __future__ import annotations

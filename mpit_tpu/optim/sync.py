@@ -45,11 +45,23 @@ about a piece
 after its shard's staging does and not a whole push later; where the
 payload is not the slice itself (a codec, the framed or the chunked
 wire) or the transport cannot hold such a send, the client waits for
-the whole shard as it did; and when server ``s``'s
-PARAM op completes the client calls the *sink*,
-:meth:`ShardStream.landed`, and the same thread sends that slice of
-``w_host`` back up, piece by piece into one donated device buffer,
-while the other servers' PARAM ops are still receiving.  Every server
+the whole shard as it did; and as server ``s``'s
+PARAM lands the client tells the *sink*, :meth:`ShardStream.landed`,
+how many bytes of that slice of ``w_host`` are whole from its front, and
+the same thread sends every piece below that mark back up, into one
+donated device buffer, while the rest of the shard and the other
+servers' PARAMs are still landing.  Where the receive lands in the slice
+itself and the transport says how far (identity codec, unframed,
+unchunked, the shm wire: ``ParamClient._lands``) the mark moves with
+the landing, so a shard's upload ends about a piece after its pull does
+and not a whole upload later (PERF.md section 6, PR 49); everywhere
+else (a codec decodes into the slice, the framed or the chunked wire, a
+transport that cannot say) the sink hears of the shard once, whole, and
+its pieces go up then, through the same code.  No piece goes up before
+its last byte is below the mark, and a mark that falls (the message
+that had begun to land was abandoned) or a PARAM op that is aborted
+sends the shard up again from its front once it is whole or the round
+ends.  Every server
 sees the frames it saw, in the order it saw them.  No whole-vector host
 array is made on the way up, and the pieces land in memory the
 allocator hands out again (a host array of the whole vector is fresh
@@ -96,7 +108,7 @@ import time
 import weakref
 from collections import deque
 from functools import partial
-from typing import Any, Dict, List, NamedTuple, Optional, Set, Tuple
+from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -179,7 +191,9 @@ def _exchange(opt: Any, span: Any) -> None:
 #: (cuts outstanding when this one was popped, itself included) and
 #: ``issued_ms`` (how long before the span's ``wait`` its cut was
 #: dispatched).  On the way up a piece's ``h2d`` span is the dispatch of
-#: its ``device_put`` and paste (``pass`` ``h2d``), and one ``h2d_shard``
+#: its ``device_put`` and paste (``pass`` ``h2d``; it begins when the
+#: thread has heard that the piece is whole on the host, so the spans of
+#: a shard that goes up as it lands lie apart), and one ``h2d_shard``
 #: span a shard closes them: from its first piece to its last dispatch
 #: (``ready`` 0: a transfer completes after the call returns and nothing
 #: waits for it) or, the round's last, to the instant the shell found
@@ -235,8 +249,13 @@ class _Copies:
         self.direct_bytes = 0  # handed over, all shards
         self.room = threading.Event()  # ``wrote`` moved, or the round quit
         self.whole = threading.Event()  # all of them, or the thread ended
+        # The way up.  ``marks[shard]``: the bytes of the shard, from its
+        # front, that the sink last handed to the stream's thread as
+        # whole in ``w_host`` (the client thread's to write); ``landed``
+        # carries each hand-over as one integer, mark and shard
+        # (:meth:`sink`), and a None at the round's end.
+        self.marks = [0] * len(stream.cut)
         self.landed: "queue.SimpleQueue[Optional[int]]" = queue.SimpleQueue()
-        self.sunk: Set[int] = set()  # shards handed to ``landed``
         self.w: Optional[jnp.ndarray] = None
         self.error: Optional[BaseException] = None
         self.failure: Optional[RuntimeError] = None  # ``error``, as raised
@@ -270,9 +289,23 @@ class _Copies:
         self.quit = True
         self.room.set()
 
-    def sink(self, shard: int) -> None:
-        self.sunk.add(shard)
-        self.landed.put(shard)
+    def sink(self, shard: int, upto: int) -> None:
+        """On the client's thread: ``upto`` bytes of ``shard`` are whole
+        in ``w_host``, from the shard's front.  The stream's thread hears
+        of it when the mark has passed another piece's end; a mark no
+        higher than the last one handed over (it fell, or the slice was
+        read again) voids what was said of the shard before, and its
+        pieces go up again from the first.  Nothing is made for the
+        hand-over but the integer."""
+        mark = self.marks[shard]
+        if 0 < mark >= upto:
+            self.landed.put(shard)  # the mark 0: the shard starts over
+            mark = self.marks[shard] = 0
+        step = self.stream.piece_bytes
+        ahead = min((mark // step + 1) * step, self.stream.nbytes[shard])
+        if mark < ahead <= upto:
+            self.marks[shard] = upto
+            self.landed.put(upto * len(self.marks) + shard)
 
     def feed(self, shard: int, written: int) -> List[np.ndarray]:
         """On the client's thread, at every poll of followed shard
@@ -365,37 +398,59 @@ class _Copies:
                 payload.delete()
 
     def _upload(self) -> None:
+        """Every piece of ``w_host`` to the device, each as soon as the
+        sink's mark of its shard (:meth:`sink`) has reached its end and
+        never before: a shard's pieces in order, the shards in the order
+        their marks move.  A mark that fell sends its shard's pieces up
+        again from the first."""
         stream = self.stream
         # On the CPU backend a put may alias host memory, and the
         # mirror is overwritten by the next round's PARAM.
         private = jax.default_backend() == "cpu"
         now, rec = self.now, self.rec
+        nshards = len(stream.cut)
+        itemsize = stream.w_host.itemsize
+        todo = [0] * nshards  # by shard: the next piece of ``parts`` to go
+        heard = [0] * nshards  # ... the mark last heard
+        begun = [0.0] * nshards  # ... when its first piece's dispatch began
         while True:
-            shard = self.landed.get()
-            if self.closing is not None and shard is not None:
-                # not the round's last: its dispatch alone
-                _close_upload(rec, self.closing, None)
-                self.closing = None
-            if shard is None or self.quit:
+            item = self.landed.get()
+            if item is None or self.quit:
                 return
-            if self.w is None:
-                self.w = jnp.zeros(stream.w_host.shape, stream.w_host.dtype)
-            t_first = t0 = now()
-            for lo, hi in stream.parts[shard]:
+            upto, shard = divmod(item, nshards)
+            if upto < heard[shard]:
+                todo[shard] = 0
+            heard[shard] = upto
+            parts = stream.parts[shard]
+            top = stream.cut[shard].offset + upto // itemsize
+            t0 = now()
+            while todo[shard] < len(parts) and parts[todo[shard]][1] <= top:
+                lo, hi = parts[todo[shard]]
+                if self.closing is not None:
+                    # not the round's last dispatch: its own alone
+                    _close_upload(rec, self.closing, None)
+                    self.closing = None
+                if self.w is None:
+                    self.w = jnp.zeros(stream.w_host.shape,
+                                       stream.w_host.dtype)
+                    t0 = now()
+                if todo[shard] == 0:
+                    begun[shard] = t0
                 view = stream.w_host[lo:hi]
                 part = jax.device_put(view.copy() if private else view)
                 self.w = _paste(self.w, part, lo)
+                todo[shard] += 1
                 if rec is not None:
                     t1 = now()
                     rec.copy("h2d", self.rank, "stream", t0, t1,
                              round=self.k, shard=shard, lo=lo,
                              bytes=view.nbytes, streams=1, **{"pass": "h2d"})
                     t0 = t1
-            if rec is not None:
-                self.closing = (self.rank, t_first, t0, {
-                    "round": self.k, "shard": shard,
-                    "bytes": stream.nbytes[shard],
-                    "pieces": len(stream.parts[shard])})
+                    if todo[shard] == len(parts):
+                        self.closing = (self.rank, begun[shard], t1, {
+                            "round": self.k, "shard": shard,
+                            "bytes": stream.nbytes[shard],
+                            "pieces": len(parts)})
 
 
 def _close_upload(rec: Any, closing: tuple,
@@ -405,9 +460,12 @@ def _close_upload(rec: Any, closing: tuple,
     were seen whole on the device, or (None) to the return of its last
     dispatch."""
     rank, t0, t1, args = closing
+    # a track a shard: two shards that go up as they land lie across
+    # each other
     rec.copy("h2d_shard", rank, "stream", t0,
              t1 if ready_at is None else max(t1, ready_at),
-             track=":shards", ready=int(ready_at is not None), **args)
+             track=f":shard{args['shard']}",
+             ready=int(ready_at is not None), **args)
 
 
 def _serve(rounds: "queue.SimpleQueue[Optional[_Copies]]") -> None:
@@ -437,6 +495,7 @@ class ShardStream:
         self.follow: List[bool] = []  # by shard: its send reads the pieces
         self.nbytes: List[int] = []  # by shard
         self.parts: List[List[Tuple[int, int]]] = []  # (lo, hi), by shard
+        self.piece_bytes = PIECE_BYTES  # of every piece but a shard's last
         self.pieces: List[Tuple[int, int, int]] = []  # (shard, lo, hi), all
         #: every piece's ``lo`` on the device: a cut dispatched with a
         #: Python integer sends it up first, and the dispatch is serial
@@ -462,6 +521,7 @@ class ShardStream:
         itemsize = self.grad_host.dtype.itemsize
         step = max(PIECE_BYTES // itemsize, 1)
         self.cut = list(cut)
+        self.piece_bytes = step * itemsize
         self.follow = list(follow) if follow else [False] * len(cut)
         self.nbytes = [(shard.end - shard.offset) * itemsize for shard in cut]
         self._index = {shard.offset: i for i, shard in enumerate(cut)}
@@ -503,10 +563,14 @@ class ShardStream:
             return None
         return partial(worker.feed, index)
 
-    def landed(self, shard: Any) -> None:
+    def landed(self, shard: Any, nbytes: int) -> None:
+        """``nbytes`` of ``shard``'s slice of ``w_host`` are whole, from
+        its front: all of them once its PARAM op is done, fewer while
+        the op's receive is landing in the slice and says how far
+        (:meth:`_Copies.sink`).  Between rounds, nothing."""
         worker = self._worker
         if worker is not None:
-            worker.sink(self._index[shard.offset])
+            worker.sink(self._index[shard.offset], nbytes)
 
     # -- the thread, and one round -------------------------------------------
 
@@ -536,11 +600,13 @@ class ShardStream:
                 worker.whole.wait()  # nothing half staged leaves ungated
             worker.check()
             _exchange(opt, span)
-            # What the client did not sink goes up now: it took no
-            # hooks, or a read was aborted at shutdown.
-            for shard in range(len(self.cut)):
-                if shard not in worker.sunk:
-                    worker.sink(shard)
+            # What the client did not sink whole goes up now, from its
+            # shard's front: it took no hooks, or a read was aborted (at
+            # shutdown), and what that read had said of its shard is void.
+            for shard, nbytes in enumerate(self.nbytes):
+                if worker.marks[shard] < nbytes:
+                    worker.sink(shard, 0)
+                    worker.sink(shard, nbytes)
         except BaseException:
             worker.stop()  # no copy after a failed exchange
             raise

@@ -311,7 +311,7 @@ class ParamClient:
         # None, and no instruction beyond the test for it, unless a
         # shell installed them.
         self._staged: Optional[Callable[[Shard], int]] = None
-        self._landed: Optional[Callable[[Shard], None]] = None
+        self._landed: Optional[Callable[[Shard, int], None]] = None
         # Where a followed shard's GRAD send gets its pieces
         # (stream_pieces): None unless a shell installed it.
         self._pieces: Optional[Callable[[Shard], Optional[Callable]]] = None
@@ -1031,12 +1031,37 @@ class ParamClient:
     def _recv_param(self, srank: int, shard: Shard):
         """Read this server's shard into the param slice and, once the
         slice is whole (decoded, where a codec or the framed wire is
-        on), hand it to the shell's sink (:meth:`stream_shards`)."""
+        on), say so to the shell's sink (:meth:`stream_shards`).  Where
+        the slice is the receive's own buffer the sink has heard of the
+        shard's front before (:meth:`_mark`)."""
         whole = yield from (self._chunked_read(srank, shard)
                             if self._chunked
                             else self._read_shard(srank, shard))
         if whole and self._landed is not None:
-            self._landed(shard)
+            self._landed(shard, shard.size * self.param.itemsize)
+
+    def _mark(self, srank: int, shard: Shard
+              ) -> Optional[Callable[[int], None]]:
+        """What follows a PARAM receive of ``shard``
+        (``aio_recv(landing=...)``), where the slice is the receive's own
+        buffer (:meth:`_lands`) and a shell took the sink: told how many
+        bytes of the slice are the message's for good, from its front, it
+        tells the sink as long as the slice is not yet
+        whole (that is :meth:`_recv_param`'s to say, once).  A receive
+        that starts over (a negative answer: the message that had begun
+        to land was abandoned) takes the mark back to 0: nothing of the
+        slice is whole until all of it is.  None everywhere else: the
+        sink hears of the shard once, whole."""
+        if self._landed is None or not self._lands(srank):
+            return None
+        landed = self._landed
+        whole = shard.size * self.param.itemsize
+
+        def mark(filled: int) -> None:
+            if filled < whole:
+                landed(shard, max(filled, 0))
+
+        return mark
 
     def _read_shard(self, srank: int, shard: Shard):
         """Request-to-read header, then receive into the param slice
@@ -1066,6 +1091,7 @@ class ParamClient:
                 self.transport, srank, tags.PARAM, live=self.live,
                 out=out if wire is None else wire,
                 deadline=self._op_deadline(), request=ask(),
+                landing=self._mark(srank, shard),
             )
             if got is not None and wire is not None:
                 span.mark("decode")
@@ -1524,7 +1550,7 @@ class ParamClient:
     def stream_shards(
         self,
         staged: Callable[[Shard], int],
-        landed: Callable[[Shard], None],
+        landed: Callable[[Shard, int], None],
     ) -> Optional[List[Shard]]:
         """An optional extension of ``ParamClientAPI``
         (optim/client_api.py; shells test for it by name): install a
@@ -1536,7 +1562,14 @@ class ParamClient:
         enough: the whole slice, or its first byte where the send reads
         the shell's pieces as they land (:meth:`stream_pieces`,
         :meth:`_send_grad`) and never the slice.  A PARAM op
-        calls ``landed(shard)`` once its slice of ``param`` is whole.
+        calls ``landed(shard, nbytes)`` with the bytes of its slice of
+        ``param`` that are whole, from the slice's front: once, with all
+        of them, when the slice is whole; and before that, where the
+        receive lands in the slice itself and the transport says how far
+        (:meth:`_lands`), whenever the mark has moved (a mark
+        lower than the last takes the earlier ones back: a message that
+        had begun to land was abandoned).  An op that is aborted says
+        nothing more, and what it said of its shard is void.
         Both run on this client's thread and must not block.  The wire
         does not change: each server still
         sees its GRAD and then its PARAM request, in that order
@@ -1570,14 +1603,26 @@ class ParamClient:
         self._pieces = pieces
         return [self._follows(srank) for srank in self.sranks]
 
-    def _follows(self, srank: int) -> bool:
-        """Whether server ``srank``'s GRAD payload is its slice of
-        ``grad`` byte for byte and may therefore be sent from anywhere
-        those bytes lie: identity codec (a codec encodes from the slice),
-        unframed (the framed wire stamps and retries the staged bytes),
-        unchunked, and a transport that can hold a send made of pieces."""
+    def _bare(self, srank: int) -> bool:
+        """Whether server ``srank``'s GRAD and PARAM payloads are its
+        slices of ``grad`` and ``param`` byte for byte: identity codec (a
+        codec encodes from the slice and decodes into it), unframed (the
+        framed wire stamps and retries staged bytes and receives into a
+        frame) and unchunked."""
         return (self._grad_wire.get(srank) is None and not self.ft.framed
-                and not self._chunked and hasattr(self.transport, "append"))
+                and not self._chunked)
+
+    def _follows(self, srank: int) -> bool:
+        """Whether server ``srank``'s GRAD may be sent from anywhere its
+        bytes lie: the payload is the slice itself (:meth:`_bare`) and the
+        transport can hold a send made of pieces."""
+        return self._bare(srank) and hasattr(self.transport, "append")
+
+    def _lands(self, srank: int) -> bool:
+        """Whether server ``srank``'s PARAM may be read as it lands: the
+        receive's buffer is the slice itself (:meth:`_bare`) and the
+        transport says how far it is filled as that moves."""
+        return self._bare(srank) and hasattr(self.transport, "follow")
 
     def _gate(self, shard: Shard, nbytes: int):
         """Yield until the shell has staged ``nbytes`` of ``shard``;

@@ -1420,3 +1420,74 @@ def test_the_recorded_rounds_are_two_whole_rounds_of_the_c111m_cell():
         assert sum(c.moved for c in mine) == 17 * vector
         assert sum(s.args["bytes"] for s in pieces
                    if s.name == "h2d") == vector
+
+
+# -- the upload that follows its landing (PR 49) -------------------------------
+
+def _pull_early(run):
+    bench = spec_mod.load_bench(spec_mod.ROOT)
+    return spec_mod.load_reader(spec_mod.ROOT, bench, "pull_early_pct")(run)
+
+
+def test_pull_early_pct_is_entered_for_the_ps_cells_under_a_layer_of_perf_md():
+    bench = spec_mod.load_bench(spec_mod.ROOT)
+    entry = next(m for m in bench["per_layer"] if m["name"] == "pull_early_pct")
+    assert entry == {"name": "pull_early_pct", "unit": "%", "better": "higher",
+                     "source": "program_span", "layer": "L3 shell + client",
+                     "moves": "tokens_per_s", "workloads": PS_CELLS}
+    assert bench["per_layer"][-1] is entry  # appended, nothing moved
+    perf = (spec_mod.ROOT / "PERF.md").read_text()
+    layers = perf[perf.index("## 3. Layers"):perf.index("## 4. Cells")]
+    assert f"| {entry['layer']} |" in layers and "`pull_early_pct`" in layers
+
+
+def test_pull_early_pct_reads_0_on_the_rounds_recorded_before_the_change():
+    """The committed cut is of PR 48's program: each shard went up after
+    its PARAM op was done (round 69: shard 0's ``rx`` ends at ..142.0 ms
+    and its first piece is dispatched at ..154.2), so no byte of either
+    round was early."""
+    assert _pull_early(copies_run()) == 0.0
+
+
+@pytest.mark.parametrize("early", [(0, 0), (10, 5), (34, 0), (34, 38)],
+                         ids=["none", "some_of_each", "all_of_shard_0",
+                              "every_piece"])
+def test_pull_early_pct_reads_the_hand_computed_share(early, tmp_path):
+    """The same two rounds with the dispatch of the first ``early[s]``
+    pieces of shard ``s`` moved to just before the end of that shard's
+    own PARAM ``rx`` span on the worker (servers 0 and 2 are shards 0 and
+    1): those pieces' bytes over the round's 598,468,608, by hand."""
+    trace = json.loads(COPIES_FIXTURE.read_text())
+    events = trace["traceEvents"]
+    ends, open_rx = {}, {}
+    for ev in events:
+        if ev.get("cat") == "wire" and ev["name"] == "rx" and ev["pid"] == 1:
+            if ev["ph"] == "B":
+                open_rx[ev["tid"]] = (ev["args"]["round"], ev["args"]["peer"])
+            else:
+                ends[open_rx.pop(ev["tid"])] = ev["ts"]
+    assert sorted(ends) == [(69, 0), (69, 2), (70, 0), (70, 2)]
+    moved, counts, begun = 0, {}, None
+    for ev in events:
+        if ev.get("cat") != "copy" or ev["name"] != "h2d":
+            continue
+        if ev["ph"] == "B":
+            k, shard = ev["args"]["round"], ev["args"]["shard"]
+            n = counts[k, shard] = counts.get((k, shard), 0) + 1
+            begun = None
+            if n <= early[shard]:
+                begun = ev["ts"] = ends[k, 2 * shard] - 1000.0 + n
+                moved += ev["args"]["bytes"]
+        elif begun is not None:
+            ev["ts"] = begun + 0.5
+    assert set(counts.values()) == {34, 38}  # pieces a shard
+    path = tmp_path / "moved.obs_trace.json"
+    path.write_text(json.dumps(trace))
+    run = dict(copies_run(), obs_trace=str(path))
+    by_hand = 100.0 * (moved / 2) / 598_468_608  # the same in both rounds
+    assert moved / 2 == sum(
+        n * 8_388_608 for n in early) - (1_820_672 if early[0] == 34 else 0) - (
+        3_690_496 if early[1] == 38 else 0)  # a shard's last piece is short
+    assert _pull_early(run) == pytest.approx(by_hand, rel=1e-12)
+    if early == (34, 38):
+        assert by_hand == 100.0

@@ -18,6 +18,17 @@ and the whole-shard gate, byte for byte and in the same order; a staging
 that dies after its first piece fails the round, completes no GRAD and
 leaves the next round sound; and every client whose payload is not the
 slice itself keeps the mirror and the whole-shard gate.
+
+The pull's twin (``ShmTransport.filled`` / ``follow``, ``aio_recv(landing=)``,
+``ps/client.py`` ``_mark``, ``optim/sync.py`` ``_Copies.sink`` /
+``_upload``), the same gang with a client's endpoint that lands a PARAM
+in three steps and says so (``follow``): a shard's pieces go up as the
+mark passes them and never above it, each once, to the bits of the
+whole-shard path; a codec, the framed wire and a transport that cannot
+say how far a receive is filled hear of the shard once, whole, through
+the same code; a read aborted
+mid-shard sends the shard up again whole; an upload that fails fails the
+round once.
 """
 
 import contextlib
@@ -521,12 +532,14 @@ class Withheld:
 
 
 @contextlib.contextmanager
-def shm_gang(name, rule="adam", codec=None, ft=None, plain=False):
-    """Servers 0 and 1 on threads, the client (rank 2) driven by the
-    caller, all over the shm wire; yields servers, client and the
-    client's own shm endpoint."""
+def shm_gang(name, rule="adam", codec=None, ft=None, plain=False,
+             endpoint=Taped):
+    """Servers 0 and 1 on threads, the client (rank 2, on an
+    ``endpoint``) driven by the caller, all over the shm wire; yields
+    servers, client and the client's own shm endpoint."""
     ns = f"t_ep_{name}_{os.getpid()}"
-    wires = [Taped(ns, r, 3, ring_bytes=RING) for r in range(3)]
+    wires = [(endpoint if r == 2 else Taped)(ns, r, 3, ring_bytes=RING)
+             for r in range(3)]
     servers = [ParamServer(r, [2], wires[r], rule=rule, ft=ft)
                for r in (0, 1)]
     threads = [threading.Thread(target=s.start, daemon=True)
@@ -768,23 +781,30 @@ def test_the_stager_stands_at_the_bound_until_the_client_has_placed_more(
     assert any(held > PIECE for held in most)  # it did stand there
 
 
+@pytest.mark.parametrize("lands", ["at_once", "in_steps"])
 def test_many_small_pieces_under_a_tight_bound_and_a_short_switch_interval(
-        monkeypatch):
+        lands, monkeypatch):
     """The stager and the client's thread share the pieces, the count of
     bytes written and the wake-up: with 157 pieces a round, a bound of
     two and the interpreter switching threads every 10 us, a lost piece,
     a piece out of order or a lost wake-up would show as other bits or
-    as the test's time limit."""
+    as the test's time limit.  On the way up they share the marks
+    (``in_steps``: a PARAM lands a third at a poll, :class:`Stepped`, with
+    the upload racing the landing): a piece gone up short would show as
+    other bits too."""
     import sys
 
     rounds = 12
-    want, _tapes, want_shards, _early = train("calm", rounds, plain=True)
+    endpoint = {"at_once": Taped, "in_steps": Stepped}[lands]
+    want, _tapes, want_shards, _early = train(f"calm_{lands}", rounds,
+                                              plain=True)
     monkeypatch.setattr(sync, "PIECE_BYTES", 128)
     monkeypatch.setattr(sync, "HELD_BYTES", 256)
     interval = sys.getswitchinterval()
     sys.setswitchinterval(1e-5)
     try:
-        got, _tapes, shards, early = train("stress", rounds)
+        got, _tapes, shards, early = train(f"stress_{lands}", rounds,
+                                           endpoint=endpoint)
     finally:
         sys.setswitchinterval(interval)
     np.testing.assert_array_equal(got, want)
@@ -857,3 +877,256 @@ def test_obs_off_the_staging_thread_reads_no_clock(slow_staging, monkeypatch):
     monkeypatch.setattr(time, "monotonic", counted)
     train("noclock", 2)
     assert reads == []
+
+
+# -- the pull follows its landing ---------------------------------------------
+
+STEPS = 3
+
+
+class Stepped(Taped):
+    """The client's endpoint, whose PARAM receives land in the caller's
+    buffer a third at a poll once the message is whole in a buffer of
+    this endpoint's own, and say how far they are (``follow``).  ``may``,
+    where a test sets it: ``may(server, step) -> bool``, asked before a
+    step is taken."""
+
+    def __init__(self, *args, **kw):
+        super().__init__(*args, **kw)
+        self.may = None
+        self.asked = 0  # calls of ``follow``
+
+    def irecv(self, src, tag, out=None):
+        if tag != tags.PARAM or out is None:
+            return super().irecv(src, tag, out=out)
+        handle = super().irecv(src, tag, out=np.empty_like(out))
+        handle.meta.update(shown=out, step=0)
+        return handle
+
+    def test(self, handle):
+        whole = super().test(handle)
+        shown = handle.meta.get("shown")
+        if shown is None or not whole:
+            return whole
+        step = handle.meta["step"]
+        if step < STEPS and (self.may is None or self.may(handle.peer, step)):
+            step = handle.meta["step"] = step + 1
+            upto = shown.nbytes * step // STEPS
+            shown.view(np.uint8)[:upto] = handle.out.view(np.uint8)[:upto]
+            if "told" in handle.meta:
+                handle.meta["told"](upto)
+        return step == STEPS
+
+    def follow(self, handle, told):
+        self.asked += 1
+        assert "shown" in handle.meta  # only a PARAM into its slice
+        handle.meta["told"] = told
+
+
+def watch_uploads(monkeypatch, log):
+    """Every dispatch of a piece into the device's vector, as ``(round,
+    shard, lo, hi, mark, whole)``: ``lo`` and ``hi`` in elements from the
+    shard's front, ``mark`` the bytes of the shard the sink had handed
+    over as whole by then, ``whole`` the piece's host bytes at that
+    instant."""
+    real = sync._paste
+
+    def logged(whole, piece, start):
+        worker = next(t for t in threading.enumerate()
+                      if t.name == "mpit-round-stream")
+        copies = worker.copies
+        cut = copies.stream.cut
+        shard = next(i for i, c in enumerate(cut)
+                     if c.offset <= int(start) < c.end)
+        lo = int(start) - cut[shard].offset
+        log.append((copies.k, shard, lo, lo + piece.shape[0],
+                    copies.marks[shard], np.asarray(piece).tobytes()))
+        return real(whole, piece, start)
+
+    monkeypatch.setattr(sync, "_paste", logged)
+    real_run = sync._Copies.run
+
+    def run(self):
+        threading.current_thread().copies = self
+        try:
+            real_run(self)
+        finally:
+            threading.current_thread().copies = None
+
+    monkeypatch.setattr(sync._Copies, "run", run)
+
+
+def lockstep(wire, log, rounds_done):
+    """Hold a shard's next step until every piece under its mark has gone
+    up: the stream's thread uploads while the shard is landing, or the
+    test meets its time limit."""
+    ends = [600, 1200, 1800, 2400, 2500]  # a shard's pieces, in elements
+
+    def may(server, step):
+        mark = (SIZE // 2 * 4) * step // STEPS
+        due = sum(1 for hi in ends if hi * 4 <= mark)
+        k = rounds_done()
+        return sum(1 for r, shard, *_ in log
+                   if r == k and shard == server) >= due
+
+    wire.may = may
+
+
+LANDS = {
+    "identity": dict(),
+    "codec": dict(codec="int8"),
+    "framed": dict(ft=FRAMED),
+    "no_capability": dict(plain=True),
+}
+THIRDS = [SIZE // 2 * 4 * step // STEPS for step in (1, 2, 3)]
+
+
+@pytest.mark.parametrize("case", sorted(LANDS))
+def test_a_shards_pieces_go_up_under_the_mark_each_once_to_the_same_bits(
+        case, monkeypatch):
+    rounds, log = 3, []
+    want, _tapes, want_shards, _early = train(f"pw_{case}", rounds,
+                                              **dict(LANDS[case], plain=True))
+    watch_uploads(monkeypatch, log)
+    with shm_gang(f"pl_{case}", endpoint=Stepped, **LANDS[case]) as (
+            servers, pc, wire):
+        opt = RuleShell(quad, pc, su=1)
+        w = opt.start(jnp.zeros(SIZE) + 0.25)
+        if case == "identity":
+            lockstep(wire, log, lambda: opt.rounds)
+        finals = []
+        for _ in range(rounds):
+            w, _loss = opt.step(w, TARGET)
+            finals.append(opt.w_host.tobytes())
+        got = np.array(w)
+        shards = [np.array(s.param) for s in servers]
+        opt.stop()
+    # the same parameters on the device and the servers, to the bit
+    np.testing.assert_array_equal(got, want)
+    for a, b in zip(shards, want_shards):
+        np.testing.assert_array_equal(a, b)
+    assert not np.array_equal(got, np.full(SIZE, 0.25, np.float32))
+    half = SIZE // 2
+    for k in range(rounds):
+        for shard in (0, 1):
+            mine = [(lo, hi, mark, data) for r, s, lo, hi, mark, data in log
+                    if (r, s) == (k, shard)]
+            # every piece exactly once, in order
+            assert [(lo, hi) for lo, hi, _m, _d in mine] == [
+                (0, 600), (600, 1200), (1200, 1800), (1800, 2400),
+                (2400, 2500)]
+            # none above the mark, and each whole on the host when it went
+            whole = finals[k][shard * half * 4:(shard + 1) * half * 4]
+            for lo, hi, mark, data in mine:
+                assert hi * 4 <= mark
+                assert data == whole[lo * 4:hi * 4]
+            marks = [mark for _lo, _hi, mark, _d in mine]
+            if case == "identity":
+                # a piece a third, and the rest at the whole
+                assert marks == [THIRDS[0], THIRDS[1]] + [THIRDS[2]] * 3
+            else:
+                assert marks == [THIRDS[2]] * 5
+    # the transport was asked to say how far only where the slice is its
+    # buffer
+    assert (wire.asked > 0) == (case == "identity")
+
+
+def test_a_read_aborted_mid_shard_sends_the_shard_up_again_whole(
+        monkeypatch):
+    log = []
+    watch_uploads(monkeypatch, log)
+    with shm_gang("abort", rule="add", endpoint=Stepped) as (
+            servers, pc, wire):
+        opt = RuleShell(quad, pc, su=1)
+        w = opt.start(jnp.zeros(SIZE) + 0.25)
+        lockstep(wire, log, lambda: opt.rounds)
+        w, _loss = opt.step(w, TARGET)  # a sound round
+        held = wire.may
+
+        def may(server, step):
+            # shard 1's first piece is up (a third of it has landed):
+            # the gang shuts down under the read
+            if server == 1 and step == 1 and held(server, step):
+                pc.live.io = False
+                return False
+            return held(server, step)
+
+        wire.may = may
+        w, _loss = opt.step(w, TARGET)
+        got = np.array(w)
+        mine = [(lo, hi, mark) for r, s, lo, hi, mark, _d in log
+                if (r, s) == (1, 1)]
+        # its first piece went up under the first third, and then, the
+        # read aborted, every piece again once the round was over
+        whole = SIZE // 2 * 4
+        assert mine == [(0, 600, THIRDS[0]), (0, 600, whole),
+                        (600, 1200, whole), (1200, 1800, whole),
+                        (1800, 2400, whole), (2400, 2500, whole)]
+        # the device holds what the mirror holds, whatever the read left
+        np.testing.assert_array_equal(got, opt.w_host)
+        second = opt._stream.cut[1]
+        landed = THIRDS[0] // 4
+        assert not np.array_equal(
+            got[second.offset:second.offset + landed],
+            np.array(opt.w_host)[second.end - landed:second.end])
+        assert opt._stream._worker is None
+        opt._stream.close()
+    assert not [t for t in threading.enumerate()
+                if t.name == "mpit-round-stream"]
+
+
+def test_an_upload_that_fails_fails_the_round_once_and_the_next_is_sound(
+        monkeypatch):
+    real = sync._paste
+    with shm_gang("up_fail", rule="add", endpoint=Stepped) as (
+            servers, pc, wire):
+        opt = RuleShell(quad, pc, su=1)
+        w0 = opt.start(jnp.zeros(SIZE) + 0.25)
+        first = opt._stream.cut[0]
+
+        def broken(whole, piece, start):
+            if int(start) >= first.offset + 600:  # shard 0's second piece
+                raise OSError("the h2d broke")
+            return real(whole, piece, start)
+
+        monkeypatch.setattr(sync, "_paste", broken)
+        with pytest.raises(RuntimeError, match="copying thread failed") as err:
+            opt.step(w0, TARGET)
+        assert isinstance(err.value.__cause__, OSError)
+        assert pc.sched.errors == []  # raised once, and not by the client
+        assert opt._stream._worker is None
+        # the exchange itself was sound: both servers took their GRADs
+        assert [s.grads_applied for s in servers] == [1, 1]
+        monkeypatch.setattr(sync, "_paste", real)
+        w, _loss = opt.step(w0, TARGET)
+        # the same gradient, added by the servers a second time
+        grad = np.full(SIZE, 0.25, np.float32) - np.asarray(TARGET)
+        np.testing.assert_array_equal(
+            np.array(w), (np.float32(0.25) + grad) + grad)
+        opt.stop()
+    assert not [t for t in threading.enumerate()
+                if t.name == "mpit-round-stream"]
+
+
+def test_obs_off_the_upload_reads_no_clock_while_it_follows_the_landing(
+        monkeypatch):
+    log = []
+    watch_uploads(monkeypatch, log)
+    real = time.monotonic
+
+    def guarded():
+        if threading.current_thread().name == "mpit-round-stream":
+            raise AssertionError("the stream's thread read the clock")
+        return real()
+
+    with shm_gang("up_noclock", endpoint=Stepped) as (servers, pc, wire):
+        opt = RuleShell(quad, pc, su=1)
+        w = opt.start(jnp.zeros(SIZE) + 0.25)
+        lockstep(wire, log, lambda: opt.rounds)
+        monkeypatch.setattr(time, "monotonic", guarded)
+        for _ in range(2):
+            w, _loss = opt.step(w, TARGET)
+        monkeypatch.setattr(time, "monotonic", real)
+        opt.stop()
+    # the early path ran: pieces went up under a third of their shard
+    assert sum(1 for *_x, mark, _d in log if mark == THIRDS[0]) == 4

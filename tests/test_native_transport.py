@@ -493,6 +493,272 @@ class TestPostedRecvLandsInPlace:
             b.close()
 
 
+class TestFilledFollowsTheLanding:
+    """``filled(handle)``: how far a posted receive's buffer is filled
+    from its front (transport.cpp ``mt_recv_filled``, the mirror of
+    ``written``): it moves with the chunks, in order, where the message
+    lands in the buffer itself, says nothing of a message that is
+    assembled elsewhere until it is whole, and goes negative where what
+    it said no longer holds.  Messages are five 1 MB rings long."""
+
+    RING = 1 << 20
+    BIG = 5 << 20
+
+    def pair(self, name):
+        ns = f"t_fl_{name}_{os.getpid()}"
+        return [ShmTransport(ns, r, 2, ring_bytes=self.RING)
+                for r in range(2)]
+
+    def test_it_moves_in_order_with_the_senders_chunks(self):
+        a, b = self.pair("moves")
+        try:
+            data = noise(21, self.BIG)
+            out = np.zeros_like(data)
+            hr = b.irecv(0, 4, out=out)
+            assert b.filled(hr) == 0  # no message is bound to it yet
+            hs = a.isend(data, 1, 4)
+            marks = [0]
+            for _ in range(10**6):
+                a.test(hs)
+                done = b.test(hr)
+                mark = b.filled(hr)
+                assert mark >= marks[-1]  # never back
+                assert b.filled(hr) == mark  # a read, no progress
+                # every byte below the mark is the message's, for good
+                np.testing.assert_array_equal(out[marks[-1]:mark],
+                                              data[marks[-1]:mark])
+                marks.append(mark)
+                if done:
+                    break
+            assert marks[-1] == self.BIG == b.filled(hr)
+            # it said so on the way: the message is five rings long
+            assert len({m for m in marks if 0 < m < self.BIG}) >= 3
+            np.testing.assert_array_equal(out, data)
+        finally:
+            a.close()
+            b.close()
+
+    @pytest.mark.parametrize("nbytes", [1, 1000])
+    def test_a_message_of_one_chunk_is_all_there_or_not_at_all(self, nbytes):
+        a, b = self.pair(f"one_{nbytes}")
+        try:
+            data = noise(22, nbytes)
+            out = np.zeros_like(data)
+            hr = b.irecv(0, 4, out=out)
+            assert b.filled(hr) == 0
+            a.send(data, 1, 4)
+            spin(lambda: b.test(hr))
+            assert b.filled(hr) == nbytes
+        finally:
+            a.close()
+            b.close()
+
+    def test_an_assembled_message_reads_0_until_it_is_whole(self):
+        """Posted half-way through the message's arrival: it goes by the
+        assembly buffer, and nothing of it is in ``out`` before the
+        ``test`` that hands it over."""
+        a, b = self.pair("assembled")
+        try:
+            data = noise(23, self.BIG)
+            hs = a.isend(data, 1, 4)
+            assert not b.iprobe(0, 4)  # drains the first ring
+            out = np.zeros_like(data)
+            hr = b.irecv(0, 4, out=out)
+            polls = 0
+            while not b.test(hr):
+                a.test(hs)
+                assert b.filled(hr) == 0 and not out.any()
+                polls += 1
+                assert polls < 10**6
+            assert polls > 0
+            assert b.filled(hr) == self.BIG
+            np.testing.assert_array_equal(out, data)
+            assert b.rx_path_bytes()["rx_assembled_bytes"] == self.BIG
+        finally:
+            a.close()
+            b.close()
+
+    def test_it_survives_cancel(self):
+        """A receive cancelled with one ring of its message landed: the
+        message moves to an assembly buffer, so the cancelled handle
+        says nothing (negative) and the next receive reads 0 until the
+        message is whole in its buffer."""
+        a, b = self.pair("cancel")
+        try:
+            data = noise(24, self.BIG)
+            out = np.zeros_like(data)
+            hr = b.irecv(0, 4, out=out)
+            hs = a.isend(data, 1, 4)
+            assert not b.test(hr)
+            landed = b.filled(hr)
+            assert 0 < landed < self.BIG
+            np.testing.assert_array_equal(out[:landed], data[:landed])
+            b.cancel(hr)
+            assert b.filled(hr) < 0
+            again = np.zeros_like(data)
+            hr2 = b.irecv(0, 4, out=again)
+            while not b.test(hr2):
+                a.test(hs)
+                assert b.filled(hr2) == 0 and not again.any()
+            assert b.filled(hr2) == self.BIG
+            np.testing.assert_array_equal(again, data)
+        finally:
+            a.close()
+            b.close()
+
+    @pytest.mark.parametrize("asked_on_the_way", [True, False])
+    def test_a_torn_message_takes_back_what_was_said(self, asked_on_the_way):
+        """The sender gives a message up half-placed and sends another:
+        the receive bound to the torn one takes the next from the front
+        of the same buffer, so the bytes below the earlier mark were not
+        the message's after all.  ``filled`` says so by going negative,
+        and stays so when the receive is done, however late it is asked:
+        whoever followed the landing takes the buffer whole."""
+        a, b = self.pair(f"torn_{asked_on_the_way}")
+        try:
+            torn, retry = noise(25, self.BIG), noise(26, self.BIG)
+            out = np.zeros_like(retry)
+            hr = b.irecv(0, 4, out=out)
+            hs = a.isend(torn, 1, 4)
+            assert not b.test(hr) and not a.test(hs)
+            said = b.filled(hr)
+            assert 0 < said < self.BIG
+            a.cancel(hs)
+            hs = a.isend(retry, 1, 4)
+            marks = []
+            while not b.test(hr):
+                a.test(hs)
+                if asked_on_the_way:
+                    marks.append(b.filled(hr))
+            # the torn message's mark (what of it lay in the ring still
+            # lands) while its bytes lie there untouched, then, from the
+            # drain that began to write over them, negative
+            back = marks.index(-1) if marks else 0
+            assert marks[:back] == sorted(marks[:back])
+            assert all(said <= m < self.BIG for m in marks[:back])
+            assert marks[back:] == [-1] * (len(marks) - back)
+            assert not asked_on_the_way or len(marks) > back
+            assert b.filled(hr) < 0
+            np.testing.assert_array_equal(out, retry)
+        finally:
+            a.close()
+            b.close()
+
+    def test_a_receive_torn_before_a_byte_landed_says_what_it_always_said(
+            self):
+        """Nothing had been said of the buffer, so nothing is taken back."""
+        a, b = self.pair("untorn")
+        try:
+            data = noise(27, self.BIG)
+            out = np.zeros_like(data)
+            hr = b.irecv(0, 4, out=out)
+            first = a.isend_pieces(self.BIG, 1, 4)  # a header, no byte
+            a.test(first)
+            a.cancel(first)
+            hs = a.isend(data, 1, 4)
+            spin(lambda: a.test(hs), lambda: b.test(hr))
+            assert b.filled(hr) == self.BIG
+            np.testing.assert_array_equal(out, data)
+        finally:
+            a.close()
+            b.close()
+
+
+class TestFollowTellsTheMark:
+    """``follow(handle, told)``: the endpoint says a posted receive's mark
+    itself, whenever it has moved, from whichever call made the progress
+    (a receive's own polls are not the only calls that drain its ring),
+    and once more from the ``test`` that finds it done."""
+
+    RING = 1 << 20
+    BIG = 5 << 20
+
+    def pair(self, name):
+        ns = f"t_fo_{name}_{os.getpid()}"
+        return [ShmTransport(ns, r, 2, ring_bytes=self.RING)
+                for r in range(2)]
+
+    @pytest.mark.parametrize("by", ["its_own_test", "another_handles_test",
+                                    "a_probe", "a_send"])
+    def test_told_from_whichever_call_made_the_progress(self, by):
+        a, b = self.pair(f"by_{by}")
+        try:
+            data = noise(31, self.BIG)
+            out = np.zeros_like(data)
+            hr = b.irecv(0, 4, out=out)
+            told = []
+            b.follow(hr, told.append)
+            other = b.irecv(0, 7, out=np.zeros(8, np.uint8))  # never comes
+            hs = a.isend(data, 1, 4)
+            step = {
+                "its_own_test": lambda: b.test(hr),
+                "another_handles_test": lambda: b.test(other),
+                "a_probe": lambda: b.iprobe(0, 9),
+                "a_send": lambda: b.test(b.isend(b"x", 0, 9)),
+            }[by]
+            for _ in range(10**6):
+                a.test(hs)
+                step()
+                if told and told[-1] == self.BIG:
+                    break
+                if by != "its_own_test" and b.filled(hr) == self.BIG:
+                    assert b.test(hr)  # the one that finds it done says so
+                # every byte below what was told is the message's
+                upto = told[-1] if told else 0
+                np.testing.assert_array_equal(out[:upto], data[:upto])
+            assert told == sorted(set(told))  # only when it moved, never back
+            assert told[-1] == self.BIG and len(told) >= 4
+            assert b.test(hr) and b._followed == {}
+            np.testing.assert_array_equal(out, data)
+            b.cancel(other)
+        finally:
+            a.close()
+            b.close()
+
+    def test_nothing_is_told_after_cancel(self):
+        a, b = self.pair("cancel")
+        try:
+            data = noise(32, self.BIG)
+            out = np.zeros_like(data)
+            hr = b.irecv(0, 4, out=out)
+            told = []
+            b.follow(hr, told.append)
+            hs = a.isend(data, 1, 4)
+            assert not b.test(hr)
+            assert told and 0 < told[-1] < self.BIG
+            b.cancel(hr)
+            said = list(told)
+            again = np.zeros_like(data)
+            hr2 = b.irecv(0, 4, out=again)
+            spin(lambda: a.test(hs), lambda: b.test(hr2))
+            assert told == said and b._followed == {}
+            np.testing.assert_array_equal(again, data)
+        finally:
+            a.close()
+            b.close()
+
+    def test_a_torn_message_is_told_once_as_negative(self):
+        a, b = self.pair("torn")
+        try:
+            torn, retry = noise(33, self.BIG), noise(34, self.BIG)
+            out = np.zeros_like(retry)
+            hr = b.irecv(0, 4, out=out)
+            told = []
+            b.follow(hr, told.append)
+            hs = a.isend(torn, 1, 4)
+            assert not b.test(hr) and not a.test(hs)
+            a.cancel(hs)
+            hs = a.isend(retry, 1, 4)
+            spin(lambda: a.test(hs), lambda: b.test(hr))
+            back = told.index(-1)
+            assert back > 0 and told[back:] == [-1]  # and never the size
+            assert told[:back] == sorted(told[:back])
+            np.testing.assert_array_equal(out, retry)
+        finally:
+            a.close()
+            b.close()
+
+
 SEND_PEER = textwrap.dedent(
     """
     import sys, numpy as np
