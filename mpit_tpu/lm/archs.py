@@ -30,7 +30,9 @@ SIZES: Dict[str, Size] = {
     "seq_len": Size(128, "positions of a packed sequence; gpt2's position "
                     "table is exactly this long (the stream fills whole "
                     "sequences, and an exact fit keeps the table out of the "
-                    "sharding slack); the others' positions are rotary"),
+                    "sharding slack); the others' positions are rotary.  It "
+                    "counts tokens, not rows: sdar's layers see 2 x seq_len "
+                    "rows, a noised and a clean copy of every sequence"),
     "vocab": Size(0, "rows of the token table and the head; 0: the byte "
                   "stream's 256, whose ids index a larger table's first rows"),
     # the sparse MLP's, and the dense one beside it
@@ -93,6 +95,14 @@ SIZES: Dict[str, Size] = {
     "index_topk": Size(0, "keys a query attends: the positions its "
                        "indexer scores highest, all of them where fewer "
                        "came before"),
+    # sdar's block-diffusion pass
+    "block_len": Size(4, "positions of a diffusion block: a noised block "
+                      "attends itself both ways and the clean blocks before "
+                      "it (a power of two up to 16 that divides seq_len)"),
+    "mask_id": Size(-1, "the id a noised position carries; -1: the table's "
+                    "last row"),
+    "noise_seed": Size(0, "keys the noise with the row's own ids: which "
+                       "positions of which blocks are masked"),
 }
 
 DEFAULTS = {name: size.default for name, size in SIZES.items()}
@@ -104,8 +114,16 @@ SWITCHES = {name: SWITCH_ALIASES.get(name, f"lm_{name}") for name in SIZES}
 
 # the two loss conventions (``lm/model.py``): the head's next-token NLL
 # closed over log-probs, or the block's own, ``module(inputs, targets) ->
-# (loss, {name: device scalar})``
+# (loss, {name: device scalar})``.  A block's own need be no NLL of the
+# next token: sdar's is the block-diffusion bound, the cross-entropy of
+# masked positions with their own ids over a noised and a clean copy of
+# the inputs (the targets are read by nothing), and ``seq_len`` counts
+# the tokens of a sequence, not the rows its layers see
 HEAD_NLL, OWN_LOSS = "head_nll", "own_loss"
+
+
+#: positions of the initialisation's sample where a block sets none
+SAMPLE_LEN = 16
 
 
 class Block(NamedTuple):
@@ -122,7 +140,7 @@ class Block(NamedTuple):
     #: forward pass with the materialised reference attention at a
     #: training sequence takes minutes and tens of GB (``lm_layout`` on
     #: a server rank): short but for the two oldest, which keep theirs.
-    sample_len: int = 16
+    sample_len: int = SAMPLE_LEN
     #: the module has ``kept_residual_bytes(seq_len, flash)``: what its
     #: checkpoints keep by name for the backward pass, a sequence
     kept_residuals: bool = False
@@ -237,6 +255,21 @@ def _keye(s, attn):
     return _module("KeyeDecoder", s, attn(), **_heads(s))
 
 
+def _sdar(s, attn):
+    _check_share(s)
+    block, seq = s["block_len"], s["seq_len"]
+    # whole blocks in the training sequence and in the initialisation's
+    # sample alike
+    if (block < 1 or block & (block - 1) or SAMPLE_LEN % block
+            or seq % block):
+        raise ValueError(f"sdar needs block_len a power of two up to "
+                         f"{SAMPLE_LEN} that divides seq_len {seq}: {block}")
+    if not -1 <= s["mask_id"] < s["vocab"]:
+        raise ValueError(f"mask_id {s['mask_id']} is no row of a table of "
+                         f"{s['vocab']} (-1: the last)")
+    return _module("SdarDecoder", s, attn(), **_heads(s))
+
+
 # what each block is: its decoder's docstring (``models/transformer.py``)
 BLOCKS: Dict[str, Block] = {
     "gpt2": Block((), _gpt2, sample_len=0),
@@ -271,6 +304,10 @@ BLOCKS: Dict[str, Block] = {
         _GROUPED + _SPARSE + _SHARE + _ROTARY + (
             "index_heads", "index_head_dim", "index_topk"),
         _keye, loss=OWN_LOSS),
+    "sdar": Block(
+        _GROUPED + _SPARSE + _SHARE + _ROTARY + (
+            "block_len", "mask_id", "noise_seed"),
+        _sdar, loss=OWN_LOSS),
 }
 ARCHS = tuple(BLOCKS)
 

@@ -7,7 +7,10 @@ is the ``ops/`` flash kernel on TPU and the jnp reference — which
 differentiates without a recompute pass — elsewhere), into the
 flat-vector calling convention the parameter server shards: a
 :class:`~mpit_tpu.models.flat.FlatModel` plus a loss over packed token
-grids (the head's next-token NLL, or the block's own), and the
+grids (the head's next-token NLL, or the block's own, which need be no
+NLL of the next token: sdar's is the block-diffusion bound over a noised
+and a clean copy of the grid's inputs, and ``seq_len`` counts the tokens
+of a sequence, not the rows the layers see), and the
 params+optimizer pytree
 (:func:`train_state_tree`) that :mod:`mpit_tpu.lm.plan` drives the
 partition rules over.

@@ -15,7 +15,7 @@ long-context showcase built on the framework's own kernels:
   batched over (B, L);
 - pre-LN blocks, learned positional embeddings, causal by default.
 
-Eight decoders, each described where it is defined; ``lm/archs.py``
+Nine decoders, each described where it is defined; ``lm/archs.py``
 ``BLOCKS`` names them with the sizes they take, and ``lm/model.py``
 ``build(arch=...)`` chooses one.
 """
@@ -36,25 +36,28 @@ from jax.ad_checkpoint import checkpoint_name
 from mpit_tpu.ops.delta_rule import KDA_OUT, kda_scan
 from mpit_tpu.ops.flash_attention import (
     FLASH_LSE, FLASH_OUT, attention_reference, flash_attention,
+    flash_call_counts,
 )
 from mpit_tpu.ops.index_select import index_select
 from mpit_tpu.ops.short_conv import causal_depthwise_conv
 from mpit_tpu.parallel import moe
 
-#: ``fn(q, k, v, window=None, select=None) -> out``.  ``select`` is a
-#: learned selection of keys, one set a query for all its heads, as the
-#: bits of ``ops/select_bits.py`` ``(B, L, words)``; only a block with
-#: an indexer passes it (:func:`selected_attention`).  A block that
-#: passes neither keyword may be handed a callable of three arguments
-#: (ring attention).
+#: ``fn(q, k, v, window=None, select=None, blockdiff=None) -> out``.
+#: ``select`` is a learned selection of keys, one set a query for all
+#: its heads, as the bits of ``ops/select_bits.py`` ``(B, L, words)``;
+#: only a block with an indexer passes it (:func:`selected_attention`).
+#: ``blockdiff (half, block)`` is the block-diffusion pass's mask over a
+#: noised and a clean copy of a sequence, in place of the causal one;
+#: only :class:`SdarBlock` passes it.  A block that passes no keyword
+#: may be handed a callable of three arguments (ring attention).
 AttnFn = Callable[..., jnp.ndarray]
 
 
 def default_attn(causal: bool = True, use_flash: bool = True,
                  interpret: Optional[bool] = None,
                  precision: Optional[str] = None) -> AttnFn:
-    """Single-device attention ``fn(q, k, v, window=None, select=None)``
-    over ``q (B, L, Hq, D)`` and ``k, v (B, L, Hkv, D)``: flash kernel
+    """Single-device attention ``fn(q, k, v, window=None, select=None,
+    blockdiff=None)`` over ``q (B, L, Hq, D)`` and ``k, v (B, L, Hkv, D)``: flash kernel
     or the jnp reference (the latter differentiates without a recompute
     pass).  Fewer KV heads than query heads are grouped (query head
     ``g`` on KV head ``g // (Hq // Hkv)``) and ``window`` is the sliding
@@ -62,29 +65,37 @@ def default_attn(causal: bool = True, use_flash: bool = True,
     callable serves a model's full and windowed layers.  ``select (B,
     L, words)`` is a chosen set of keys a query (``ops/index_select.py``
     makes it, :func:`selected_attention` passes it): a pair outside it
-    is masked in the kernel and in the reference alike.
+    is masked in the kernel and in the reference alike.  ``blockdiff
+    (half, block)`` replaces the causal mask by the block-diffusion
+    pass's (``ops/flash_attention.py``), in both alike; ``fn.flash``
+    says whether the callable is the kernel.
     ``interpret`` reaches ``pallas_call``: None interprets everywhere
     but on a TPU (ops/tiles.py), False pins the Mosaic-compiled kernel.
     ``precision`` is the MXU input precision of the two attention
     products, forward and backward (``"highest"``: float32 inputs);
     None is the backend's default, one bf16 pass on a TPU."""
 
-    def fn(q, k, v, window=None, select=None):
+    def fn(q, k, v, window=None, select=None, blockdiff=None):
         qh, kh, vh = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
-        # the window and the selection are keywords only where there is
-        # one: the plain causal call is the call it was
+        # the window, the selection and the block-diffusion mask are
+        # keywords only where there is one: the plain causal call is the
+        # call it was
         kw = {} if window is None else {"window": window}
         if select is not None:
             kw["select"] = select
+        if blockdiff is not None:  # the whole mask, in the causal one's place
+            kw["blockdiff"] = blockdiff
+        masked = causal and blockdiff is None
         if use_flash:
-            out = flash_attention(qh, kh, vh, causal=causal,
+            out = flash_attention(qh, kh, vh, causal=masked,
                                   interpret=interpret, precision=precision,
                                   **kw)
         else:
             with jax.default_matmul_precision(precision or "default"):
-                out = attention_reference(qh, kh, vh, causal=causal, **kw)
+                out = attention_reference(qh, kh, vh, causal=masked, **kw)
         return out.transpose(0, 2, 1, 3)
 
+    fn.flash = use_flash
     return fn
 
 
@@ -363,15 +374,20 @@ def plain_inv_freq(head: int, theta: float) -> np.ndarray:
             ).astype(np.float32)
 
 
-def rope_by(x: jnp.ndarray, inv_freq: np.ndarray,
-            scale: float = 1.0) -> jnp.ndarray:
+def rope_by(x: jnp.ndarray, inv_freq: np.ndarray, scale: float = 1.0,
+            period: int = 0) -> jnp.ndarray:
     """:func:`rope` with the inverse frequencies given and ``cos``,
-    ``sin`` multiplied by ``scale`` (YaRN's ``attention_factor``)."""
-    l = x.shape[1]
+    ``sin`` multiplied by ``scale`` (YaRN's ``attention_factor``).
+    ``period``: row ``r`` is at position ``r mod period`` (the rows are
+    whole copies of one sequence, one after another); 0: at ``r``."""
+    l = period or x.shape[1]
     angles = (jnp.arange(l, dtype=jnp.float32)[:, None]
               * jnp.asarray(inv_freq)[None, :])
     cos = (jnp.cos(angles) * scale)[None, :, None, :]
     sin = (jnp.sin(angles) * scale)[None, :, None, :]
+    if period:
+        cos, sin = (jnp.tile(t, (1, x.shape[1] // period, 1, 1))
+                    for t in (cos, sin))
     x1, x2 = jnp.split(x, 2, axis=-1)
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
@@ -380,14 +396,16 @@ def grouped_attention(h: jnp.ndarray, wq: jnp.ndarray, wk: jnp.ndarray,
                       wv: jnp.ndarray, wo: jnp.ndarray, *, heads: int,
                       kv_heads: int, head_dim: int, inv_freq: np.ndarray,
                       attn: AttnFn, scale: float = 1.0, window: int = 0,
-                      qk_norm: Optional[tuple] = None) -> jnp.ndarray:
+                      qk_norm: Optional[tuple] = None,
+                      period: int = 0) -> jnp.ndarray:
     """Attention over grouped KV heads of their own width on the normed
     stream ``h (B, L, d)``, projected back to ``(B, L, d)``: bias-free
     projections to ``heads`` query and ``kv_heads`` key and value heads
     of ``head_dim``, rotary positions by ``inv_freq`` (:func:`rope_by`),
     ``attn`` with the ``window`` where there is one.  ``qk_norm``
     ``(q weight, k weight, eps)``: an RMSNorm over each head's width on
-    the queries and the keys, before the rotary embedding.  Every
+    the queries and the keys, before the rotary embedding.  ``period``
+    as :func:`rope_by` takes it.  Every
     product at the backend's default precision, one bf16 pass on a TPU:
     Mellum's scores are O(1) without a norm, and LFM2's with its per-head
     norm read the same gradient error against the float32 reference with
@@ -400,7 +418,7 @@ def grouped_attention(h: jnp.ndarray, wq: jnp.ndarray, wk: jnp.ndarray,
         x = x.reshape(b, l, count, head_dim)
         if qk_norm is not None:
             x = rms_norm(x, weight, qk_norm[2])
-        return rope_by(x, inv_freq, scale)
+        return rope_by(x, inv_freq, scale, period)
 
     q = heads_of(q, heads, qk_norm and qk_norm[0])
     k = heads_of(k, kv_heads, qk_norm and qk_norm[1])
@@ -1902,5 +1920,266 @@ class KeyeDecoder(nn.Module):
         with jax.named_scope("head_loss"):
             loss = jnp.mean(nll)
         stats = dict(zip(KEYE_DSA_STATS, map(jnp.stack, zip(*chosen))))
+        stats.update(zip(JOYAI_MOE_STATS, map(jnp.stack, zip(*routing))))
+        return loss, stats
+
+
+# ---------------------------------------------------------------------------
+# The block-diffusion block (SDAR-30B-A3B-Chat, JetLM; ``model_type``
+# ``sdar_moe``; the configuration's keys are those of its
+# ``config.json``).  The layer is Qwen3-MoE's, as Keye's main heads and
+# sparse MLP are: grouped KV heads of their own width with an RMSNorm
+# over each head's width on the queries and the keys, rotary positions,
+# Mellum's softmax router, renormalised, this chip's share of the
+# experts, no shared expert.  What is new is the pass it is trained by:
+# **the objective is no next-token NLL**.  A sequence of ``L`` ids is cut
+# in blocks of ``block_len``; a seeded subset of every block is replaced
+# by the mask id (:func:`block_noise`); the layers see the noised copy
+# **and** the clean copy, ``2 L`` rows that share ``L`` rotary positions,
+# under a mask that is neither causal nor inside the causal triangle
+# (``ops/flash_attention.py`` ``blockdiff``: a noised row sees its own
+# noised block, both ways, and the clean blocks strictly before it; a
+# clean row the clean blocks up to its own); the head reads the noised
+# half alone and the loss is the cross-entropy of the masked positions
+# with their **own** ids (no shift), each block's weighted by one over
+# its count of masked positions.  The plain float32 reference it is held
+# to is ``chipbench/reference/sdar_plain.py``, which shares no code with
+# this file (tests/test_sdar.py).
+# ---------------------------------------------------------------------------
+
+#: one layer's share of the step's attention tiles, constants of the
+#: lowered calls (``ops/flash_attention.py`` ``flash_call_counts``): the
+#: tiles the forward and backward kernels run a product on, and those of
+#: them in which the mask has a live pair
+SDAR_TILE_STATS = ("attn_tiles_visited", "attn_tiles_live")
+_GOLDEN, _MIX_A, _MIX_B = 0x9E3779B9, 0x7FEB352D, 0x846CA68B
+_BLOCK_SALT, _SPOT_SALT = 0x85EBCA6B, 0xC2B2AE35
+
+
+def mix32(x: jnp.ndarray) -> jnp.ndarray:
+    """A 32-bit integer mix (two multiply-xorshift rounds) on ``uint32``,
+    wrapping: what numpy computes to the bit with the same lines."""
+    x = x ^ (x >> 16)
+    x = x * jnp.uint32(_MIX_A)
+    x = x ^ (x >> 15)
+    x = x * jnp.uint32(_MIX_B)
+    return x ^ (x >> 16)
+
+
+def block_noise(ids: jnp.ndarray, seed: int, block: int):
+    """The block-diffusion pass's noise on ``ids (B, L)``: ``(masked (B,
+    L) bool, count (B, L) int32)``, a pure function of the row's ids and
+    ``seed`` in 32-bit integer arithmetic (no ``jax.random``, whose
+    implementation is a flag of the process), which the reference
+    repeats in numpy.  A row's checksum ``h = mix(seed ^ sum_i mix(id_i
+    + golden (i + 1)))`` keys everything.  Block ``b`` gets the key
+    ``mix(h ^ mix(b ^ salt))`` and the count ``1 + rank_b mod block``,
+    ``rank_b`` its place among the row's blocks by key (a tie to the
+    lower block): a seeded permutation, so ``L / block`` a multiple of
+    ``block`` gives every count to exactly as many blocks.  Position
+    ``i`` gets the key ``mix(h ^ mix(i ^ salt'))`` and is masked iff
+    fewer than its block's count of its block's positions come before
+    it by key (a tie to the lower position): a uniform set of that
+    size.  ``count`` is the block's count at each of its positions."""
+    b, l = ids.shape
+    n = l // block
+    u32 = lambda x: jnp.asarray(x, jnp.uint32)
+    at = jnp.arange(l, dtype=jnp.uint32)
+    h = mix32(u32(seed) ^ jnp.sum(
+        mix32(ids.astype(jnp.uint32) + u32(_GOLDEN) * (at + u32(1))),
+        axis=1, dtype=jnp.uint32))[:, None]
+    block_key = mix32(h ^ mix32(jnp.arange(n, dtype=jnp.uint32)
+                                ^ u32(_BLOCK_SALT)))
+    rank = jnp.argsort(jnp.argsort(block_key, axis=1, stable=True), axis=1,
+                       stable=True)
+    count = (1 + rank % block).astype(jnp.int32)
+    key = mix32(h ^ mix32(at ^ u32(_SPOT_SALT))).reshape(b, n, block)
+    mine, other = key[..., :, None], key[..., None, :]
+    spot = jnp.arange(block)
+    before = (other < mine) | ((other == mine) & (spot[None, :] < spot[:, None]))
+    masked = jnp.sum(before, axis=-1) < count[..., None]
+    return masked.reshape(b, l), jnp.repeat(count, block, axis=1)
+
+
+def blockdiff_attention(x: jnp.ndarray, p: dict, *, heads: int,
+                        kv_heads: int, head_dim: int, block: int,
+                        theta: float, eps: float, attn: AttnFn) -> jnp.ndarray:
+    """Grouped attention of the block-diffusion pass on the stream ``x
+    (B, 2 L, d)``, a noised copy of the sequence and then its clean one,
+    with the weights ``p``: the norm before the layer,
+    :func:`grouped_attention` with the per-head query/key norm, row
+    ``r`` at rotary position ``r mod L``, ``attn`` handed the mask
+    ``blockdiff=(L, block)``."""
+    half = x.shape[1] // 2
+    with jax.named_scope("attn"):
+        return grouped_attention(
+            rms_norm(x, p["attn_norm"], eps), p["wq"], p["wk"], p["wv"],
+            p["wo"], heads=heads, kv_heads=kv_heads, head_dim=head_dim,
+            inv_freq=plain_inv_freq(head_dim, theta),
+            attn=partial(attn, blockdiff=(half, block)),
+            qk_norm=(p["q_norm"], p["k_norm"], eps), period=half)
+
+
+class SdarBlock(nn.Module):
+    d_model: int
+    n_heads: int
+    kv_heads: int
+    head_dim: int
+    n_experts: int           # the router's width: every expert there is
+    experts_per_tok: int
+    expert_width: int
+    experts_first: int = 0   # the share held here: a contiguous range
+    experts_held: int = 0    # 0: all of them
+    block_len: int = 4
+    rope_theta: float = 1000000.0
+    norm_eps: float = 1e-6
+    attn_fn: Optional[AttnFn] = None
+
+    @nn.compact
+    def __call__(self, x: jnp.ndarray):
+        """``(the stream (B, 2 L, d) after the layer, the sparse
+        branch's statistics in :data:`JOYAI_MOE_STATS`' order)``."""
+        d, hq, hkv, hd = (self.d_model, self.n_heads, self.kv_heads,
+                          self.head_dim)
+        e, f = self.n_experts, self.expert_width
+        held, ones = self.experts_held or e, nn.initializers.ones
+        p = {name: self.param(name, init, shape) for name, init, shape in (
+            ("attn_norm", ones, (d,)),
+            ("wq", _INIT, (d, hq * hd)), ("wk", _INIT, (d, hkv * hd)),
+            ("wv", _INIT, (d, hkv * hd)), ("wo", _INIT, (hq * hd, d)),
+            ("q_norm", ones, (hd,)), ("k_norm", ones, (hd,)))}
+        # kept for the backward pass: the layer's input and the flash
+        # rule's two; q, k, v are made again from the input, as Keye's
+        x = x + jax.checkpoint(
+            partial(blockdiff_attention, heads=hq, kv_heads=hkv, head_dim=hd,
+                    block=self.block_len, theta=self.rope_theta,
+                    eps=self.norm_eps,
+                    attn=self.attn_fn if self.attn_fn is not None
+                    else default_attn()),
+            policy=jax.checkpoint_policies.save_only_these_names(
+                *JOYAI_ATTN_KEPT))(x, p)
+
+        norm = self.param("mlp_norm", ones, (d,))
+        router = self.param("router", _INIT, (d, e))
+        experts = tuple(self.param(f"experts_{name}", _INIT, shape)
+                        for name, shape in (("gate", (held, d, f)),
+                                            ("up", (held, d, f)),
+                                            ("down", (held, f, d))))
+
+        # recomputed in the backward pass, as Mellum's and for its
+        # reason; Mellum's router over all 2 L rows, noised and clean
+        @jax.checkpoint
+        def sparse(x, norm, router, experts):
+            return sparse_mlp(
+                x, norm, router, experts, eps=self.norm_eps, n_experts=e,
+                first=self.experts_first, held=held,
+                route=lambda logits: (*moe.route_top_k(
+                    jax.nn.softmax(logits, axis=-1), self.experts_per_tok,
+                    renormalise=True), ()))
+
+        y, stats = sparse(x, norm, router, experts)
+        return x + y, stats
+
+
+class SdarDecoder(nn.Module):
+    """Block-diffusion LM of :class:`SdarBlock` layers: a token table
+    (at :data:`MELLUM_EMBED_INIT`'s scale, for its reason: a share of
+    the experts is held), the layers, every one alike, a final RMSNorm
+    and an untied head.  Called like the decoders that close their own
+    loss (``lm/model.py``), with the packed grid's inputs ``x0 (B, L)``;
+    the targets are read by nothing, the objective is no next-token
+    NLL.  The noise makes ``xt`` from ``x0`` (:func:`block_noise`, ``mask_id``
+    at the masked positions); the layers run on ``[xt ; x0]``, ``2 L``
+    rows for ``L`` counted tokens; the head on the noised half alone;
+    ``loss = mean over the batch of (1 / n) sum_b (1 / c_b) sum_{i
+    masked in b} -log softmax(z_i)[x0_i]`` over the ``n = L /
+    block_len`` blocks, ``c_b`` block ``b``'s count of masked positions
+    (the block-diffusion bound under the linear schedule in its count
+    form: ``chipbench/reference/sdar_plain.py`` has the derivation).
+    Its statistics:
+
+    - ``diff_masked_share``: masked positions over ``L`` (``(block_len
+      + 1) / (2 block_len)`` by construction: the guard on the noise);
+    - ``diff_nll_c1`` .. ``diff_nll_c<block_len>``: the mean NLL of the
+      masked positions whose block has that count (``c = block_len``
+      sees the clean past alone, ``c = 1`` its block's other positions
+      too);
+    - :data:`SDAR_TILE_STATS`, where the attention is the flash kernel:
+      constants of the lowered calls, one entry a layer;
+    - the routing counters of every layer under ``lm/model.py``
+      ``MOE_STATS``' names.
+
+    The head's product, norm and loss is under ``jax.checkpoint``,
+    keeping the rows' log-sum-exp by name (:func:`row_lse`)."""
+
+    vocab: int = 256
+    d_model: int = 64
+    n_heads: int = 4
+    kv_heads: int = 2
+    head_dim: int = 16
+    n_layers: int = 2
+    n_experts: int = 8
+    experts_per_tok: int = 2
+    expert_width: int = 32
+    experts_first: int = 0
+    experts_held: int = 0
+    block_len: int = 4
+    mask_id: int = -1        # -1: the table's last row
+    noise_seed: int = 0
+    rope_theta: float = 1000000.0
+    norm_eps: float = 1e-6
+    attn_fn: Optional[AttnFn] = None
+
+    @nn.compact
+    def __call__(self, tokens: jnp.ndarray, targets: jnp.ndarray):
+        d, eps, block = self.d_model, self.norm_eps, self.block_len
+        b, l = tokens.shape
+        if l % block:
+            raise ValueError(f"{l} positions are no whole blocks of {block}")
+        sizes = {field: getattr(self, field) for field in (
+            "d_model", "n_heads", "kv_heads", "head_dim", "n_experts",
+            "experts_per_tok", "expert_width", "experts_first",
+            "experts_held", "block_len", "rope_theta", "norm_eps",
+            "attn_fn")}
+
+        @partial(jax.checkpoint,
+                 policy=jax.checkpoint_policies.save_only_these_names(
+                     HEAD_LSE))
+        def head_nll(u, norm, head, ids):
+            with jax.named_scope("head_loss"):
+                z = rms_norm(u, norm, eps) @ head
+                return row_lse(z) - jnp.take_along_axis(
+                    z, ids[..., None], axis=-1)[..., 0]
+
+        with jax.named_scope("noise"):
+            masked, count = block_noise(tokens, self.noise_seed, block)
+            noised = jnp.where(masked, self.mask_id % self.vocab, tokens)
+        with jax.named_scope("embed"):
+            x = self.param("embed", MELLUM_EMBED_INIT, (self.vocab, d))[
+                jnp.concatenate([noised, tokens], axis=1)]
+        routing = []
+        for _ in range(self.n_layers):
+            x, counted = SdarBlock(**sizes)(x)
+            routing.append(counted)
+        nll = head_nll(
+            x[:, :l], self.param("final_norm", nn.initializers.ones, (d,)),
+            self.param("head", _INIT, (d, self.vocab)), tokens)
+        with jax.named_scope("head_loss"):
+            weight = masked / count.astype(jnp.float32)
+            loss = jnp.mean(jnp.sum(weight * nll, axis=1)) / (l // block)
+            stats = {"diff_masked_share": jnp.mean(
+                masked.astype(jnp.float32))}
+            for c in range(1, block + 1):
+                of_c = (masked & (count == c)).astype(jnp.float32)
+                stats[f"diff_nll_c{c}"] = jnp.sum(of_c * nll) / jnp.maximum(
+                    jnp.sum(of_c), 1.0)
+        attn = self.attn_fn
+        if attn is None or getattr(attn, "flash", False):
+            steps = flash_call_counts(
+                (b, self.n_heads, 2 * l, self.head_dim),
+                (b, self.kv_heads, 2 * l, self.head_dim), x.dtype,
+                blockdiff=(l, block))
+            for name, key in zip(SDAR_TILE_STATS, ("live", "nonempty")):
+                stats[name] = jnp.full((self.n_layers,), float(steps[key]))
         stats.update(zip(JOYAI_MOE_STATS, map(jnp.stack, zip(*routing))))
         return loss, stats

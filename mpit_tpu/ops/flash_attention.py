@@ -58,6 +58,23 @@ showcase the rebuild adds on top of capability parity.  Design:
   both backward kernels, and every other tile masks its scores by the
   bits (and by the geometry where it is an edge tile).  The backward
   under a selection is the two-kernel schedule, as under a window.
+- **The block-diffusion pass** (``blockdiff=(half, block)``): the one
+  mask that is neither causal nor inside the causal triangle.  The
+  ``2 * half`` rows are a noised copy of a sequence and then its clean
+  copy, cut in blocks of ``block`` positions; a noised row sees its own
+  noised block (both directions) and the clean blocks strictly before
+  it, a clean row the clean blocks up to its own
+  (:func:`_blockdiff_live`, the one copy of the rule).  An outer
+  block's live inner blocks are then **no one range** (a noised q
+  block's are its own diagonal tile and a run of the clean half; a
+  clean kv block is seen from a run of each half), so the walk reads
+  them from a table made at trace time from the rule itself
+  (:func:`_blockdiff_tiles`): inner step ``t`` visits the ``t``-th live
+  block, whichever it is, and the inner axis is the longest row's count.
+  No ``(2 half, 2 half)`` mask, bias or bit set exists anywhere: an
+  edge tile computes its mask from two iotas, a full one takes the
+  mask-free path, a dead one (the whole clean-to-noised quadrant among
+  them) is neither fetched nor computed, forward and backward.
 
 :func:`flash_attention` is the user op (normalized output, custom VJP:
 pallas backward in the standard flash schedule — P is recomputed
@@ -166,11 +183,35 @@ def _long_blocks_fit_vmem(bq: int, bk: int) -> bool:
 # ---------------------------------------------------------------------------
 
 
+def _blockdiff_live(qi, kj, half: int, block: int, xp=jnp):
+    """The block-diffusion pass's mask, the ONE copy of its rule: rows
+    and keys ``[0, half)`` are the noised copy of a sequence, ``[half, 2
+    half)`` its clean copy, a position's block is ``(p mod half) //
+    block`` (``block`` a power of two: a shift).  A noised row sees the noised keys of its own block and the
+    clean keys of the blocks strictly before it; a clean row the clean
+    keys of the blocks up to and including its own; nothing else (no
+    clean row sees a noised key, a noised block never its own clean
+    copy).  ``xp`` is ``jnp`` (the reference's whole mask, an edge
+    tile's inside a kernel) or ``numpy`` (the walk's table at trace
+    time: :func:`_blockdiff_tiles`)."""
+    shift = block.bit_length() - 1
+    noised_q, clean_k = qi < half, kj >= half
+    qb = xp.where(noised_q, qi, qi - half) >> shift
+    kb = xp.where(clean_k, kj - half, kj) >> shift
+    # in and-or form (Mosaic selects no booleans): a clean key of an
+    # earlier block is seen from either half; a key of the row's own
+    # block iff exactly one of "the key is clean" and "the row is
+    # noised" holds (a noised row's own noised block, a clean row's own
+    # clean block)
+    return (clean_k & (kb < qb)) | ((kb == qb) & (clean_k ^ noised_q))
+
+
 def _mask(sh_q: int, sh_k: int, q_offset, kv_offset, kv_len, causal: bool,
-          window: int | None = None):
+          window: int | None = None, blockdiff: tuple | None = None):
     """Boolean (Lq, Lk) validity mask in *global* coordinates.  With a
     ``window`` (causal only) query ``i`` sees key ``j`` iff ``0 <= i - j
-    < window``: its own position and the ``window - 1`` before it."""
+    < window``: its own position and the ``window - 1`` before it.
+    ``blockdiff``: :func:`_blockdiff_live`'s rule."""
     qi = q_offset + jnp.arange(sh_q)[:, None]
     kj = kv_offset + jnp.arange(sh_k)[None, :]
     valid = (kj - kv_offset) < kv_len
@@ -178,7 +219,49 @@ def _mask(sh_q: int, sh_k: int, q_offset, kv_offset, kv_len, causal: bool,
         valid = valid & (qi >= kj)
     if window is not None:
         valid = valid & (qi - kj < window)
+    if blockdiff is not None:
+        valid = valid & _blockdiff_live(qi, kj, *blockdiff)
     return valid
+
+
+def _check_blockdiff(blockdiff, lq: int, lk: int, causal: bool, window,
+                     select) -> tuple | None:
+    """``(half, block)`` of a block-diffusion pass: the noised and the
+    clean copy are the call's whole rows and keys, in whole blocks, and
+    the mask is all of the call's geometry."""
+    if blockdiff is None:
+        return None
+    half, block = (int(x) for x in blockdiff)
+    if causal or window is not None or select is not None:
+        raise ValueError("blockdiff is the whole mask: no causal, window "
+                         "or select beside it")
+    if (block < 1 or block & (block - 1) or half % block
+            or lq != 2 * half or lk != 2 * half):
+        raise ValueError(f"blockdiff {(half, block)}: {lq} rows over {lk} "
+                         f"keys are not a noised and a clean copy of "
+                         f"{half} positions in blocks of {block}, a power "
+                         f"of two")
+    return half, block
+
+
+@functools.lru_cache(maxsize=64)
+def _blockdiff_tiles(half: int, block: int, bq: int, bk: int,
+                     q_blocks: int, kv_blocks: int):
+    """``(live, full)``: for every ``(bq, bk)`` tile of the padded ``(q
+    blocks, kv blocks)`` rectangle, whether :func:`_blockdiff_live` has
+    any true entry in it and whether every entry is true (a key past ``2
+    half`` is no key).  Worked out at trace time from the rule itself, a
+    strip of ``bq`` rows at a time in numpy: nothing of the square ever
+    reaches the program."""
+    kj = np.arange(kv_blocks * bk, dtype=np.int32)[None, :]
+    live = np.zeros((q_blocks, kv_blocks), bool)
+    full = np.zeros((q_blocks, kv_blocks), bool)
+    for i in range(q_blocks):
+        qi = (i * bq + np.arange(bq, dtype=np.int32))[:, None]
+        seen = _blockdiff_live(qi, kj, half, block, np) & (kj < 2 * half)
+        count = seen.reshape(bq, kv_blocks, bk).sum(axis=(0, 2))
+        live[i], full[i] = count > 0, count == bq * bk
+    return live, full
 
 
 def _check_window(window, causal: bool) -> int | None:
@@ -215,20 +298,25 @@ def attention_reference(
     kv_offset=0,
     window: int | None = None,
     select: jnp.ndarray | None = None,
+    blockdiff: tuple | None = None,
 ) -> jnp.ndarray:
     """Plain softmax attention over the last two axes; leading axes batch.
     Rows with no valid key return zeros (matches the ring/partial path).
     ``window`` as in :func:`_mask`; fewer KV heads than query heads
     (axis -3) are repeated over their groups, materialised; ``select``
-    as :func:`flash_attention` takes it, unpacked to a whole mask."""
+    as :func:`flash_attention` takes it, unpacked to a whole mask;
+    ``blockdiff`` as :func:`flash_attention` takes it, the whole mask
+    materialised."""
     window = _check_window(window, causal)
+    blockdiff = _check_blockdiff(blockdiff, q.shape[-2], k.shape[-2],
+                                 causal, window, select)
     if q.ndim >= 3 and q.shape[-3] != k.shape[-3]:
         groups = q.shape[-3] // k.shape[-3]
         k, v = (jnp.repeat(x, groups, axis=-3) for x in (k, v))
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(q.shape[-1])
     s = jnp.einsum("...qd,...kd->...qk", q, k).astype(jnp.float32) * scale
     valid = _mask(q.shape[-2], k.shape[-2], q_offset, kv_offset,
-                  k.shape[-2], causal, window)
+                  k.shape[-2], causal, window, blockdiff)
     if select is not None:
         chosen = _unpack_select(select, k.shape[-2])
         # a sequence's, the first of ``s``'s axes: every head's alike
@@ -377,7 +465,19 @@ class _Walk:
     one after another along the rows, ``q_blocks`` blocks each
     (:func:`_fold`): a q-outer row starts its positions over at every
     head, and a kv-outer row walks its range once a head, ``extent``
-    steps each."""
+    steps each.
+
+    Under ``blockdiff`` (the block-diffusion pass: :func:`_blockdiff_live`)
+    an outer block's live inner blocks are not one range (two, most of
+    them: a piece of each half), and the offsets are 0: the walk reads
+    them from a **table** made at trace time from the rule
+    (:meth:`table`).  The two prefetched vectors then hold, in place of
+    ``lo`` and ``hi``, the table's rows laid end to end (entry ``t`` of
+    a row: its ``t``-th live inner block, twice, plus 1 where the tile
+    is full; past the row's count the last live block again, which the
+    pipeline holds) and the rows' counts; :attr:`extent` is the longest
+    row's count.  :meth:`fetch`, :meth:`tile` and :meth:`steps` read
+    those, and :meth:`live_range` is not asked."""
 
     kv_outer: bool
     causal: bool
@@ -390,17 +490,48 @@ class _Walk:
     #: a selection's bits ride along (``_chosen``): a tile's words are
     #: ``fetch_select``'s block, and no tile takes the mask-free path
     select: bool = False
+    #: ``(half, block)``: the block-diffusion pass's mask is the call's
+    #: geometry, walked by :meth:`table`
+    blockdiff: tuple | None = None
 
     @property
     def inner_blocks(self) -> int:
         return self.q_blocks if self.kv_outer else self.kv_blocks
+
+    def tiles(self):
+        """``(live, full)`` of the block-diffusion pass, ``(q blocks, kv
+        blocks)`` each (:func:`_blockdiff_tiles`)."""
+        return _blockdiff_tiles(*self.blockdiff, self.block_q, self.block_k,
+                                self.q_blocks, self.kv_blocks)
+
+    @functools.lru_cache(maxsize=64)
+    def table(self):
+        """``(entries (rows, extent), counts (rows,))`` int32, a row an
+        outer block (of one head): the walk of the block-diffusion pass
+        as the class's docstring lays it out.  Made once a walk (the
+        class is frozen, so equal walks share it)."""
+        live, full = self.tiles()
+        if self.kv_outer:
+            live, full = live.T, full.T
+        counts = live.sum(axis=1).astype(np.int32)
+        entries = np.zeros((live.shape[0], max(1, int(counts.max()))),
+                           np.int32)
+        for row, (seen, whole) in enumerate(zip(live, full)):
+            inner = np.flatnonzero(seen)
+            if inner.size:
+                entries[row, :inner.size] = 2 * inner + whole[inner]
+                entries[row, inner.size:] = 2 * inner[-1]
+        return entries, counts
 
     @property
     def extent(self) -> int:
         """Inner steps of one range: the static bound on its length.
         ``window + b_outer - 1`` consecutive positions see an outer
         block, and ``n`` of them touch at most ``ceil((n - 1) / b) + 1``
-        inner blocks, whatever the offsets."""
+        inner blocks, whatever the offsets.  Under ``blockdiff``, the
+        longest row of the table."""
+        if self.blockdiff is not None:
+            return self.table()[0].shape[1]
         if self.window is None:
             return self.inner_blocks
         b_outer, b_inner = ((self.block_k, self.block_q) if self.kv_outer
@@ -461,6 +592,9 @@ class _Walk:
         folded row block under ``kv_outer``): the walk's block, held at
         the range's end past it; an empty range holds any valid block.
         The ranges are the prefetched ones (:func:`_prefetch`)."""
+        if self.blockdiff is not None:
+            head, _row, _t, entry = self._entry(outer, t, lo_ref)
+            return (head + entry // 2).v
         t, lo, hi = _Int(t), _Int(lo_ref[outer]), _Int(hi_ref[outer])
         head = 0
         if self.kv_outer and self.groups > 1:
@@ -468,6 +602,18 @@ class _Walk:
         held = _Int.minimum(_Int.maximum(_Int.minimum(lo + t, hi), 0),
                             self.inner_blocks - 1)
         return (head + held).v
+
+    def _entry(self, outer, t, table_ref):
+        """Inner step ``t`` of grid row ``outer`` under ``blockdiff``:
+        ``(the folded head's first row block, the table's row, the step
+        within it, the table's entry)``."""
+        row, t, head = _Int(outer), _Int(t), 0
+        if self.groups > 1:
+            if self.kv_outer:
+                head, t = t // self.extent * self.q_blocks, t % self.extent
+            else:
+                row = row % self.q_blocks
+        return head, row, t, _Int(table_ref[(row * self.extent + t).v])
 
     def fetch_select(self, outer, t, *prefetched):
         """Index-map half of a selection: the ``(block_q, 128)`` block
@@ -490,6 +636,15 @@ class _Walk:
         walk's first or last (init, finalize)."""
         outer, t = pl.program_id(0), _Int(pl.program_id(1))
         first, last = t <= 0, t >= pl.num_programs(1) - 1
+        if self.blockdiff is not None:
+            _head, row, t, entry = self._entry(outer, t.v, lo_ref)
+            # the table's row is the outer block (of one head), its
+            # entry the inner one
+            head_i, j = (entry // 2, row) if self.kv_outer else (
+                row, entry // 2)
+            live, full = t < _Int(hi_ref[row.v]), entry % 2 > 0
+            return tuple(x.v for x in (head_i * self.block_q, j, live, full,
+                                       first, last))
         q_off, kv_off, kv_len = (_Int(ref[0]) for ref in
                                  (qoff_ref, kvoff_ref, kvlen_ref))
         lo, hi, outer = _Int(lo_ref[outer]), _Int(hi_ref[outer]), _Int(outer)
@@ -519,6 +674,17 @@ class _Walk:
         ``live`` (tiles computed) and ``rect`` (the whole rectangle, the
         grid before the walk), from :meth:`live_range` on concrete
         offsets."""
+        if self.blockdiff is not None:
+            # ``live`` is the walk's own (the table's counts: the tiles
+            # it runs a product on), ``nonempty`` the rule's (the tiles
+            # in which it has a true entry): equal while the walk visits
+            # nothing dead
+            return {
+                "visited": self.grid[0] * self.grid[1],
+                "live": int(self.table()[1].sum()) * self.groups,
+                "rect": self.groups * self.q_blocks * self.kv_blocks,
+                "nonempty": int(self.tiles()[0].sum()) * self.groups,
+            }
         kv_len = self.kv_blocks * self.block_k if kv_len is None else kv_len
         outer = np.arange(self.grid[0])
         lo, hi = self.live_range(outer, int(q_off), int(kv_off),
@@ -541,6 +707,8 @@ def _valid(q_lo, j, kvoff_ref, kvlen_ref, shape, walk):
     kj_local = (j * walk.block_k
                 + jax.lax.broadcasted_iota(jnp.int32, shape, 1))
     valid = kj_local < kvlen_ref[0]
+    if walk.blockdiff is not None:
+        valid = valid & _blockdiff_live(qi, kj_local, *walk.blockdiff)
     if walk.causal:
         valid = valid & (qi >= kvoff_ref[0] + kj_local)
     if walk.window is not None:
@@ -829,6 +997,11 @@ def _prefetch(walk, q_offset, kv_offset, kv_len):
     model's warm start-up (PERF.md section 6, PR 33)."""
     q_off, kv_off, kv_len = (jnp.asarray(x, jnp.int32)
                              for x in (q_offset, kv_offset, kv_len))
+    if walk.blockdiff is not None:  # the table's rows and their counts
+        entries, counts = walk.table()
+        zero = jnp.zeros((1,), jnp.int32)
+        return (jnp.asarray(entries.reshape(-1)), jnp.asarray(counts), zero,
+                zero, kv_len.reshape(1))
     n_outer = walk.grid[0]
     lo, hi = walk.live_range(_Int(jnp.arange(n_outer, dtype=jnp.int32)),
                              _Int(q_off), _Int(kv_off), _Int(kv_len))
@@ -840,7 +1013,7 @@ def _prefetch(walk, q_offset, kv_offset, kv_len):
 
 def _fa_2d(q, k, v, q_offset, kv_offset, *, causal, sm_scale, block_q,
            block_k, interpret, partial=False, precision=None, window=None,
-           select=None):
+           select=None, blockdiff=None):
     """Core call on (Lq, D) x (Lk, D); pads to tiles.  Returns the
     normalized (Lq, D) output, or with ``partial`` the unnormalized
     ``(acc, m, l)`` triple (f32) for cross-chunk merging.  ``q`` of
@@ -862,7 +1035,7 @@ def _fa_2d(q, k, v, q_offset, kv_offset, *, causal, sm_scale, block_q,
     vp = jnp.pad(v, ((0, lk_p - lk), (0, dv_p - dv)))
     rows = groups * lq_p
     walk = _Walk(False, causal, window, bq, bk, lq_p // bq, lk_p // bk,
-                 groups, select is not None)
+                 groups, select is not None, blockdiff)
     held, walked = _walk_specs(walk, d_p)
     sel_specs, sel = _select_operand(walk, select, lq_p, lk_p)
     if partial:
@@ -936,6 +1109,7 @@ def flash_attention_partial(
     precision: str | None = None,
     window: int | None = None,
     select: jnp.ndarray | None = None,
+    blockdiff: tuple | None = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Pallas twin of :func:`block_attention_partial`: unnormalized
     ``(acc, m, l)`` over ``(..., L, D)``.  Forward-only — ring attention
@@ -948,6 +1122,7 @@ def flash_attention_partial(
         q2, k2, v2, q_offset, kv_offset, causal=causal, sm_scale=sm_scale,
         block_q=block_q, block_k=block_k, interpret=interpret, partial=True,
         precision=precision, window=window, select=sel[0] if sel else None,
+        blockdiff=blockdiff,
     )
     return _over_leading(f, k, select)(
         q, k, v, *(() if select is None else (select,)))
@@ -1108,11 +1283,14 @@ def _sum_visited(dq_part, walk, q_offset, kv_offset, kv_len):
     in q block ``i``'s live range (the q-outer reading of the same
     rule); the others were never written and may hold anything, so they
     are selected away, not multiplied by zero."""
-    across = dataclasses.replace(walk, kv_outer=False, groups=1)
-    lo, hi = across.live_range(_Int(jnp.arange(walk.q_blocks)),
-                               _Int(q_offset), _Int(kv_offset), kv_len)
-    j = jnp.arange(walk.kv_blocks)[:, None]
-    visited = (j >= _Int.of(lo).v) & (j <= hi.v)  # (kv_blocks, q_blocks)
+    if walk.blockdiff is not None:
+        visited = walk.tiles()[0].T
+    else:
+        across = dataclasses.replace(walk, kv_outer=False, groups=1)
+        lo, hi = across.live_range(_Int(jnp.arange(walk.q_blocks)),
+                                   _Int(q_offset), _Int(kv_offset), kv_len)
+        j = jnp.arange(walk.kv_blocks)[:, None]
+        visited = (j >= _Int.of(lo).v) & (j <= hi.v)  # (kv_blocks, q_blocks)
     nj, rows, d_p = dq_part.shape
     part = dq_part.reshape(nj, walk.groups, walk.q_blocks, walk.block_q, d_p)
     return jnp.sum(
@@ -1122,7 +1300,7 @@ def _sum_visited(dq_part, walk, q_offset, kv_offset, kv_len):
 
 def _fa_2d_bwd(q, k, v, do, lse, delta, q_offset, kv_offset, *, causal,
                sm_scale, block_q, block_k, interpret, precision,
-               fused=True, window=None, select=None):
+               fused=True, window=None, select=None, blockdiff=None):
     """Backward core on (Lq, D) x (Lk, D): returns (dq, dk, dv).
 
     ``lse``/``delta`` are per-q-row f32 vectors (log-sum-exp from the
@@ -1167,7 +1345,7 @@ def _fa_2d_bwd(q, k, v, do, lse, delta, q_offset, kv_offset, *, causal,
     # HBM budget) lives in _use_fused_bwd; this function only executes
     # the chosen schedule.
     walk = _Walk(True, causal, window, bq, bk, lq_p // bq, lk_p // bk,
-                 groups, select is not None)
+                 groups, select is not None, blockdiff)
     held, walked = _walk_specs(walk, d_p)
     sel_specs, sel = _select_operand(walk, select, lq_p, lk_p)
     out_specs = [held(), held(dv_p)]
@@ -1225,7 +1403,7 @@ def _fa_2d_bwd(q, k, v, do, lse, delta, q_offset, kv_offset, *, causal,
 
 
 def _use_fused_bwd(q_shape, k_shape, d, dtype, sm_scale, block_q, block_k,
-                   window=None, select=False):
+                   window=None, select=False, blockdiff=False):
     """Backward-schedule choice (the ONE decision point, made where the
     full vmapped batch shape is visible).
 
@@ -1282,7 +1460,8 @@ def _use_fused_bwd(q_shape, k_shape, d, dtype, sm_scale, block_q, block_k,
         raise ValueError(
             f"MPIT_FA_FUSED_BWD={mode!r}: expected '0', '1', or 'auto'"
         )
-    if window is not None:
+    if window is not None or blockdiff:
+        # the block-diffusion pass likewise: 5 of 16 slots are live
         return False
     lq, lk = q_shape[-2], k_shape[-2]
     # bwd_long_bk: the gate must see the SAME bk the executed backward
@@ -1309,7 +1488,7 @@ _KERNEL_WALKS = {  # kernel -> (kv_outer, the _tile_dims flag it resolves with)
 
 def flash_step_counts(kernel, q_shape, k_shape, dtype, *, causal=False,
                       window=None, block_q=None, block_k=None, q_offset=0,
-                      kv_offset=0):
+                      kv_offset=0, blockdiff=None):
     """Grid steps of one kernel call over ``q_shape`` x ``k_shape`` (as
     :func:`flash_attention` takes them, or ``q`` already grouped one rank
     above ``k``): ``{"visited", "live", "rect"}``, summed over the
@@ -1319,7 +1498,10 @@ def flash_step_counts(kernel, q_shape, k_shape, dtype, *, causal=False,
     the grid was before it walked live ranges; live over visited is the
     walk's hit share.  The program's own numbers: the same
     :class:`_Walk` the kernels lower with, on concrete offsets (an
-    offset that is traced counts as 0)."""
+    offset that is traced counts as 0).  Under ``blockdiff`` also
+    ``nonempty``: the tiles of the rectangle in which the mask has a
+    true entry, by the rule and not by the walk, so ``live - nonempty``
+    is what the walk computes for nothing."""
     kv_outer, long_flag = _KERNEL_WALKS[kernel]
     q_shape, k_shape = tuple(q_shape), tuple(k_shape)
     if len(q_shape) == len(k_shape) and len(q_shape) >= 3:
@@ -1332,17 +1514,38 @@ def flash_step_counts(kernel, q_shape, k_shape, dtype, *, causal=False,
         lq, lk, d, block_q, block_k, None, dtype,
         **({long_flag: True} if long_flag else {}))
     walk = _Walk(kv_outer, causal, window, bq, bk, lq_p // bq, lk_p // bk,
-                 groups)
+                 groups, blockdiff=_check_blockdiff(blockdiff, lq, lk, causal,
+                                                    window, None))
     concrete = lambda x: 0 if isinstance(x, jax.core.Tracer) else int(x)
     one = walk.steps(concrete(q_offset), concrete(kv_offset), lk)
     calls = math.prod(k_shape[:-2])
     return {name: n * calls for name, n in one.items()}
 
 
+def flash_call_counts(q_shape, k_shape, dtype, **mask):
+    """:func:`flash_step_counts` summed over the kernels one
+    differentiated :func:`flash_attention` call of these shapes lowers:
+    the forward and, by :func:`_use_fused_bwd`, the fused sweep or the
+    two-kernel backward.  ``mask``: ``causal``, ``window``, ``blockdiff``,
+    ``block_q``, ``block_k`` as the call has them."""
+    q_shape, k_shape = tuple(q_shape), tuple(k_shape)
+    fused = _use_fused_bwd(  # the batch is all it reads of the shapes
+        q_shape, k_shape, q_shape[-1], dtype, None, mask.get("block_q"),
+        mask.get("block_k"), mask.get("window"), False,
+        mask.get("blockdiff") is not None)
+    total: dict = {}
+    for kernel in ("fwd", *(("fused",) if fused else ("dq", "dkdv"))):
+        for name, n in flash_step_counts(kernel, q_shape, k_shape, dtype,
+                                         **mask).items():
+            total[name] = total.get(name, 0) + n
+    return total
+
+
 def flash_attention_bwd_pair(q, k, v, do, lse, *, causal=False, sm_scale=None,
                              q_offset=0, kv_offset=0, delta=None, o=None,
                              block_q=None, block_k=None, interpret=None,
-                             precision=None, window=None, select=None):
+                             precision=None, window=None, select=None,
+                             blockdiff=None):
     """Pallas flash backward for one (Q chunk, KV chunk) pair over
     ``(..., L, D)``: returns ``(dq, dk, dv)`` given the forward's row
     ``lse`` (shape ``(..., Lq)``) and either ``delta = rowsum(dO*O)`` or
@@ -1357,12 +1560,12 @@ def flash_attention_bwd_pair(q, k, v, do, lse, *, causal=False, sm_scale=None,
         delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), -1)
     fused = _use_fused_bwd(q.shape, k.shape, q.shape[-1], q.dtype,
                            sm_scale, block_q, block_k, window,
-                           select is not None)
+                           select is not None, blockdiff is not None)
     f = lambda q2, k2, v2, do2, lse2, delta2, *sel: _fa_2d_bwd(
         q2, k2, v2, do2, lse2, delta2, q_offset, kv_offset, causal=causal,
         sm_scale=sm_scale, block_q=block_q, block_k=block_k,
         interpret=interpret, precision=precision, fused=fused,
-        window=window, select=sel[0] if sel else None,
+        window=window, select=sel[0] if sel else None, blockdiff=blockdiff,
     )
     return _over_leading(f, k, select)(
         q, k, v, do, lse, delta, *(() if select is None else (select,)))
@@ -1370,7 +1573,7 @@ def flash_attention_bwd_pair(q, k, v, do, lse, *, causal=False, sm_scale=None,
 
 @functools.lru_cache(maxsize=64)
 def _make_flash(causal, sm_scale, block_q, block_k, interpret, precision,
-                window=None):
+                window=None, blockdiff=None):
     """Differentiable flash op for fixed static config: pallas forward,
     pallas backward (flash schedule, O(block) memory — the forward's
     partial outputs provide the LSE residual).  ``sel`` is the
@@ -1383,7 +1586,7 @@ def _make_flash(causal, sm_scale, block_q, block_k, interpret, precision,
             q2, k2, v2, q_offset, kv_offset, causal=causal,
             sm_scale=sm_scale, block_q=block_q, block_k=block_k,
             interpret=interpret, precision=precision, window=window,
-            select=sel2[0] if sel2 else None,
+            select=sel2[0] if sel2 else None, blockdiff=blockdiff,
         )
         return _over_leading(f, k, *sel)(q, k, v, *sel)
 
@@ -1392,7 +1595,7 @@ def _make_flash(causal, sm_scale, block_q, block_k, interpret, precision,
             q, k, v, causal=causal, sm_scale=sm_scale, q_offset=q_offset,
             kv_offset=kv_offset, block_q=block_q, block_k=block_k,
             interpret=interpret, precision=precision, window=window,
-            select=sel[0] if sel else None,
+            select=sel[0] if sel else None, blockdiff=blockdiff,
         )
         # Named where the rule makes them: a checkpoint whose policy
         # saves these two names keeps the forward kernel's results and
@@ -1411,7 +1614,7 @@ def _make_flash(causal, sm_scale, block_q, block_k, interpret, precision,
             q_offset=q_offset, kv_offset=kv_offset, o=o,
             block_q=block_q, block_k=block_k, interpret=interpret,
             precision=precision, window=window,
-            select=sel[0] if sel else None,
+            select=sel[0] if sel else None, blockdiff=blockdiff,
         )
         return (dq, dk, dv, None, None, *(None for _ in sel))
 
@@ -1434,6 +1637,7 @@ def flash_attention(
     precision: str | None = None,
     window: int | None = None,
     select: jnp.ndarray | None = None,
+    blockdiff: tuple | None = None,
 ) -> jnp.ndarray:
     """Flash attention over ``(..., L, D)`` with global-offset causal
     masking.  Leading axes are batched (vmapped); offsets may be traced.
@@ -1464,6 +1668,16 @@ def flash_attention(
     with no bit set runs no product, forward or backward; a row with
     none returns zeros.  Integer: it has no gradient.
 
+    ``blockdiff=(half, block)``: the block-diffusion pass.  The ``2 *
+    half`` rows (and keys) are a noised copy of a sequence followed by
+    its clean copy, in blocks of ``block`` positions; a noised row sees
+    its own noised block and the clean blocks strictly before it, a
+    clean row the clean blocks up to its own.  It is the call's whole
+    mask (no ``causal``, ``window``, ``select`` or offset beside it),
+    walked tile by live tile from a table made at trace time
+    (:class:`_Walk`); the backward is the two-kernel schedule unless
+    ``MPIT_FA_FUSED_BWD=1`` forces the fused sweep.
+
     Default blocks are 1024x1024, growing to 2048x1024 at L >= 16384
     (defaults from a July 2026 sweep on a v5e the ledger has not
     reproduced; MPIT_FA_LONG_BQ=0 pins 1024 — the kernel auto-raises
@@ -1478,11 +1692,17 @@ def flash_attention(
     # tracers with a clear error instead of leaking per-trace cache
     # entries).
     window = _check_window(window, causal)
+    blockdiff = _check_blockdiff(blockdiff, q.shape[-2], k.shape[-2], causal,
+                                 window, select)
+    if blockdiff is not None and not (
+            isinstance(q_offset, int) and isinstance(kv_offset, int)
+            and q_offset == kv_offset == 0):
+        raise ValueError("blockdiff takes no offset: its rows are the call's")
     fa = _make_flash(bool(causal),
                      None if sm_scale is None else float(sm_scale),
                      None if block_q is None else int(block_q),
                      None if block_k is None else int(block_k),
-                     _interpret(interpret), precision, window)
+                     _interpret(interpret), precision, window, blockdiff)
     out = fa(_group_queries(q, k), k, v, jnp.asarray(q_offset, jnp.int32),
              jnp.asarray(kv_offset, jnp.int32),
              *(() if select is None else (select,)))

@@ -37,7 +37,8 @@ OTHER = dict(
     q_rank=24, kv_rank=16, qk_nope=8, qk_rope=4, v_head=8,
     shared_experts=2, mtp_layers=0, mtp_weight=0.125,
     kda_heads=3, kda_head_dim=8,
-    index_heads=3, index_head_dim=4, index_topk=8)
+    index_heads=3, index_head_dim=4, index_topk=8,
+    block_len=8, mask_id=300, noise_seed=9)
 # where a block cannot take ``OTHER``'s value of a size: its own
 OTHER_OF = {"kimi": {"layer_types": "kda,full_attention,kda"}}
 

@@ -56,7 +56,7 @@ def test_every_cells_vocabulary_reaches_the_program_through_the_launcher():
 
     want = {"gpt2": 50257, "olmoe": 50304, "mellum": 12288, "lfm2_moe": 8192,
             "ouro": 49152, "joyai_llm_flash": 16160, "kimi_linear": 20480,
-            "KeyeVL2": 18992}
+            "KeyeVL2": 18992, "sdar_moe": 18992}
     for name in CELLS:
         cell = spec_mod.load_cell(name)
         cfg = runner.launch_config(cell, seed=5)
@@ -75,7 +75,7 @@ def test_scopes_come_from_every_committed_configuration():
     assert spantree.model_scopes({}) == [
         "embed", "attn", "mlp", "head_loss", "update", "router", "dispatch",
         "experts", "attn_window", "conv", "conv_mix", "exit_gate", "mla_proj",
-        "shared_expert", "kda_proj", "kda_scan", "kda_out", "index"]
+        "shared_expert", "kda_proj", "kda_scan", "kda_out", "index", "noise"]
 
 
 def olmoe_cases():
@@ -476,11 +476,11 @@ def test_the_parent_fails_the_new_cell_at_once():
     cell on the parent needs."""
     bench = spec_mod.load_bench()
     names = [w["name"] for w in bench["workloads"]]
-    assert names[-5:] == [LFM2_CELL, OURO_CELL, JOYAI_CELL, KIMI_CELL,
-                          KEYE_CELL] and len(names) == 10
+    assert names[-6:] == [LFM2_CELL, OURO_CELL, JOYAI_CELL, KIMI_CELL,
+                          KEYE_CELL, SDAR_CELL] and len(names) == 11
     for missing in ("lfm2-l5e8-locals", "ouro-l6-locals",
                     "joyai-l5e8-locals", "kimi-linear-l5e8-locals",
-                    "keye-l6e8-locals"):
+                    "keye-l6e8-locals", "sdar-l6e8-locals"):
         with pytest.raises(spec_mod.SpecError, match="no workload"):
             spec_mod.load_cell(missing)
 
@@ -763,9 +763,9 @@ def test_joyais_mix_keeps_to_the_traffic_its_issue_fixed():
         if metric["name"] in JOYAI_METRICS[2:]:
             assert metric["workloads"] == [JOYAI_CELL]
         elif metric["name"] in JOYAI_APPENDED + JOYAI_METRICS[:2]:
-            # the cells of PR 43 and PR 46, which have some of these
-            # layers too, follow it
-            assert JOYAI_CELL in metric["workloads"][-3:]
+            # the cells of PR 43, PR 46 and PR 51, which have some of
+            # these layers too, follow it
+            assert JOYAI_CELL in metric["workloads"][-4:]
 
 
 def test_joyais_readers_find_nothing_in_a_run_without_the_block():
@@ -1010,8 +1010,9 @@ def test_kimis_mix_keeps_to_the_traffic_its_issue_fixed():
         if metric["name"] in KIMI_METRICS:
             assert metric["workloads"] == [KIMI_CELL]
         elif metric["name"] in KIMI_APPENDED:
-            # the cell of PR 46 follows it where it has the layer
-            assert KIMI_CELL in metric["workloads"][-2:]
+            # the cells of PR 46 and PR 51 follow it where they have
+            # the layer
+            assert KIMI_CELL in metric["workloads"][-3:]
         elif "workloads" in metric:
             assert KIMI_CELL not in metric["workloads"], metric["name"]
 
@@ -1235,15 +1236,17 @@ def test_keyes_mix_keeps_to_the_traffic_its_issue_fixed():
         if metric["name"] in KEYE_METRICS:
             assert metric["workloads"] == [KEYE_CELL]
         elif metric["name"] in KEYE_APPENDED:
-            assert metric["workloads"][-1] == KEYE_CELL
+            # the cell of PR 51 follows it
+            assert KEYE_CELL in metric["workloads"][-2:]
         elif "workloads" in metric:
             assert KEYE_CELL not in metric["workloads"], metric["name"]
     # added together and in order (later PRs' entries follow them)
     keye = [m for m in cell.bench["per_layer"] if m["name"] in KEYE_METRICS]
     at = cell.bench["per_layer"].index(keye[0])
     assert cell.bench["per_layer"][at:at + 5] == keye
-    assert (cell.bench["configs"][-1]["name"],
-            cell.bench["workloads"][-1]["name"]) == (cell.config_name,
+    # (PR 51's configuration and cell follow them)
+    assert (cell.bench["configs"][-2]["name"],
+            cell.bench["workloads"][-2]["name"]) == (cell.config_name,
                                                      KEYE_CELL)
 
 
@@ -1343,6 +1346,220 @@ def test_keye_arithmetic_by_hand_through_the_cell(what, got, want):
     assert got == want, what
 
 
+# -- the SDAR configuration (PR 51) -----------------------------------------------
+
+SDAR_CELL = "sdar-l6e8-local"
+SDAR_METRICS = ("blockdiff_dead_tiles_pct", "noise_ms_per_step",
+                "diff_masked_pct", "diff_nll_gap_nats")
+
+
+def test_sdar_file_has_the_catalogs_keys_and_the_floor_cuts():
+    """Every key of the catalog's entry under its own name (the
+    model-configs guide's ``architectures.jsonl``, read where it is
+    installed; ``tests/test_sdar.py`` holds the hand-copied values);
+    only the depth, the experts held and the vocabulary differ, each at
+    the guide's floor, with the published values beside them; no width
+    is cut; what the catalog does not give is stated as assumed."""
+    import pathlib
+
+    cell = spec_mod.load_cell(SDAR_CELL)
+    config = cell.config
+    path = pathlib.Path(
+        "/opt/skills/guides/model-configs/architectures.jsonl")
+    if path.exists():
+        rows = [json.loads(line) for line in path.read_text().splitlines()]
+        entry = next(r for r in rows if r["name"] == "SDAR-30B-A3B-Chat")
+        catalog = entry["config"]
+        assert entry["source_url"] == config["source"]
+        assert all(key in config for key in catalog)
+        differ = sorted(k for k, v in catalog.items() if config[k] != v)
+        assert differ == sorted(config["reduced"])
+        assert config["published"] == {k: catalog[k]
+                                       for k in config["reduced"]}
+        for missing in entry["not_given"]:
+            assert missing.split()[0] in " ".join(config["assumed"])
+    assert sorted(config["reduced"]) == [
+        "num_experts", "num_hidden_layers", "vocab_size"]
+    assert (config["num_hidden_layers"], config["num_experts"],
+            config["vocab_size"]) == (6, 8, 151936 // 8)
+    assert (config["hidden_size"], config["num_attention_heads"],
+            config["num_key_value_heads"], config["head_dim"],
+            config["moe_intermediate_size"], config["num_experts_per_tok"],
+            config["rope_theta"]) == (2048, 32, 4, 128, 768, 8, 1000000)
+    assert config["router_experts"] == 128   # the router keeps its width
+    assert (config["train_seq"], config["block_length"],
+            config["mask_token_id"]) == (4096, 4, 18991)
+    entry = next(c for c in cell.bench["configs"]
+                 if c["name"] == cell.config_name)
+    assert sorted(entry["reduced"]) == sorted(config["reduced"])
+    assert entry["source"] == config["source"]
+    assert (cell.chips, cell.traffic_name) == (1, "local-msgd-s4k-sdar")
+    assert ["embed", "noise", "attn", "router", "dispatch", "experts",
+            "head_loss", "update"] == config["scopes"]
+    assert all(key in config for key in config["launcher_from"].values())
+    assert all(key in config for key in config["tiny"])
+    assert len(config["assumed"]) >= 8 and "16 v5e chips" in \
+        config["deployment"]
+    assert cell.arithmetic().param_count(config) == 419_130_880
+    assert cell.arithmetic().live_pairs(4096, 4) == 16_793_600
+    assert cell.reference().LOSS_TOL_NATS > 0 < cell.reference().GRAD_REL_TOL
+
+
+def test_the_launcher_builds_the_diffusion_block_from_the_cells_files():
+    from chipbench import run as runner
+    from mpit_tpu.lm.model import build_kw
+    from mpit_tpu.train.launch import lm_trainer_cfg
+
+    cell = spec_mod.load_cell(SDAR_CELL)
+    kw = build_kw(lm_trainer_cfg(runner.launch_config(cell, seed=5)))
+    assert (kw["arch"], kw["d_model"], kw["n_heads"], kw["kv_heads"],
+            kw["head_dim"], kw["n_layers"], kw["seq_len"], kw["vocab"]) \
+        == ("sdar", 2048, 32, 4, 128, 6, 4096, 18992)
+    assert (kw["block_len"], kw["mask_id"], kw["noise_seed"]) \
+        == (4, 18991, cell.config["noise_seed"])
+    assert (kw["n_experts"], kw["experts_held"], kw["experts_first"],
+            kw["experts_per_tok"], kw["expert_width"]) == (128, 8, 0, 8, 768)
+    assert (kw["rope_theta"], kw["norm_eps"]) == (1e6, 1e-6)
+
+
+def test_sdars_mix_keeps_to_the_traffic_its_issue_fixed():
+    """ISSUE 51 fixed the mix before any code was written: the rate one
+    of three, the budget a whole number of micro-steps of 4096 clean
+    tokens, momentum 0.9, two rounds of warm-up, closed loop in one
+    process; the four new metrics and the six appended ones are the
+    cell's; the tiles and the noise's time move the rate, the noise's
+    share and the gap the loss."""
+    cell = spec_mod.load_cell(SDAR_CELL)
+    mix = cell.traffic
+    steps, rest = divmod(mix["token_budget"],
+                         mix["batch"] * cell.config["train_seq"])
+    assert rest == 0 and steps >= 8
+    assert mix["lr"] in (0.003, 0.01, 0.03)
+    assert (mix["launcher"]["mom"], mix["warmup_rounds"], mix["su"],
+            mix["batch"], mix["launcher"]["np"],
+            mix["launcher"]["lm_use_flash"]) == (0.9, 2, 1, 1, 1, 1)
+    moves = {m["name"]: m["moves"] for m in cell.metrics("per_layer")}
+    assert set(SDAR_METRICS + KEYE_APPENDED) <= set(moves)
+    assert {moves[m] for m in SDAR_METRICS[:2]} == {"tokens_per_s"}
+    assert {moves[m] for m in SDAR_METRICS[2:]} == {"loss_at_budget"}
+    layers = {m["name"]: m["layer"] for m in cell.bench["per_layer"]}
+    assert layers["blockdiff_dead_tiles_pct"] == layers["flash_roofline"]
+    assert layers["noise_ms_per_step"] == layers["dsa_ms_per_step"]
+    for metric in cell.bench["per_layer"]:
+        if metric["name"] in SDAR_METRICS:
+            assert metric["workloads"] == [SDAR_CELL]
+        elif metric["name"] in KEYE_APPENDED:
+            assert metric["workloads"][-2:] == [KEYE_CELL, SDAR_CELL]
+        elif "workloads" in metric:
+            assert SDAR_CELL not in metric["workloads"], metric["name"]
+    # added together, in order and last
+    assert [m["name"] for m in cell.bench["per_layer"][-4:]] == list(
+        SDAR_METRICS)
+    assert (cell.bench["configs"][-1]["name"],
+            cell.bench["workloads"][-1]["name"]) == (cell.config_name,
+                                                     SDAR_CELL)
+
+
+def test_sdars_readers_find_nothing_in_a_run_without_the_block():
+    """What the parent's traced run hands them: a cell whose
+    configuration lists no such scope, a program that recorded no such
+    counter, no device trace, no merged trace: None, no raise."""
+    for name in ("keye-l6e8-local", "c111m-local"):
+        cell = spec_mod.load_cell(name)
+        run = {"cell": cell, "reduction": {"step_module": "jit__lambda"},
+               "obs_trace": None, "peaks": None, "results": {},
+               "summary": {"worker_ranks": [0], "window": [0.0, 1.0]}}
+        for metric in SDAR_METRICS:
+            reader = spec_mod.load_reader(cell.root, cell.bench, metric)
+            assert reader is not None and reader(dict(run)) is None
+
+
+def test_sdars_readers_read_a_hand_made_run(monkeypatch):
+    """The four readers, the six shared ones and the metrics without a
+    ``workloads`` list that the cell has to report, on a scope table and
+    a span tree made by hand."""
+    from chipbench import flops
+    from chipbench.layers import spantree
+
+    cell = spec_mod.load_cell(SDAR_CELL)
+    listed = [m["name"] for m in cell.metrics("per_layer")]
+    unlisted = [m["name"] for m in cell.bench["per_layer"]
+                if "workloads" not in m]
+    assert len(unlisted) == 10 and set(unlisted) <= set(listed)
+    last = cell.traffic["token_budget"] // 4096 - 1
+
+    class Round:
+        def __init__(self, k, whole, alone):
+            self.args = {"round": k, "diff_masked_share": [0.625],
+                         "diff_nll_c1": [alone], "diff_nll_c2": [3.0],
+                         "diff_nll_c3": [3.0], "diff_nll_c4": [whole],
+                         "attn_tiles_visited": [7680.0] * 6,
+                         "attn_tiles_live": [7680.0] * 5 + [3840.0],
+                         "moe_held_rows_share": [0.0625] * 6,
+                         "moe_load_max_over_mean": [2.0, 6.5, 2.25, 3.0, 2.0,
+                                                    2.0],
+                         "moe_compact_share": [1.0] * 5 + [0.0]}
+
+    class Tree:
+        def rounds(self):
+            # the budget's four steps and one after them, which the gap
+            # does not read
+            return [Round(last - 3, 4.0, 3.0), Round(last - 2, 4.0, 3.5),
+                    Round(last - 1, 4.5, 3.5), Round(last, 4.5, 4.0),
+                    Round(last + 1, 9.0, 1.0)]
+
+    table = {"step": 314.0, "noise": 0.02, "attn": 196.0, "router": 10.0,
+             "dispatch": 23.0, "experts": 14.0, "head_loss": 7.5,
+             "update": 13.0}
+    monkeypatch.setattr(spantree, "scope_ms_per_step", lambda run: table)
+    monkeypatch.setattr(spantree, "xplane_path", lambda run: None)
+    run = {"cell": cell, "peaks": flops.load_peaks("TPU v5 lite"),
+           spantree.CACHE_KEY: Tree(),
+           "summary": {"tokens_per_s": 13000.0, "worker_ranks": [0]},
+           "reduction": {"step_module": "jit__lambda", "step_module_runs": 2,
+                         "mosaic_by_scope": {
+                             "attn": (36, 0.214), "experts": (72, 0.020),
+                             "update": (2, 0.026)}}}
+
+    def read(name):
+        return spec_mod.load_reader(cell.root, cell.bench, name)(run)
+
+    # a twelfth of the visited tiles is dead in the hand-made counts
+    assert read("blockdiff_dead_tiles_pct") == pytest.approx(100.0 / 12)
+    assert read("noise_ms_per_step") == pytest.approx(0.02)
+    assert read("diff_masked_pct") == pytest.approx(62.5)
+    assert read("diff_nll_gap_nats") == pytest.approx(0.75)
+    assert read("dispatch_ms_per_step") == pytest.approx(33.0)
+    assert read("held_experts_ms_per_step") == pytest.approx(14.0)
+    assert read("held_rows_share_pct") == pytest.approx(6.25)
+    assert read("expert_load_max_over_mean") == pytest.approx(6.5)
+    assert read("compact_dispatch_pct") == pytest.approx(100.0 * 5 / 6)
+    assert read("head_loss_ms_per_step") == pytest.approx(7.5)
+    assert read("flash_ms_per_step") == pytest.approx(107.0)
+    family = cell.arithmetic().kernels(cell.config, 1)["attn"]
+    assert read("flash_roofline") == pytest.approx(
+        100 * family["flops"] / 197e12 / 0.107)
+    experts = cell.arithmetic().experts_cost(cell.config, 1)
+    assert read("held_experts_roofline") == pytest.approx(
+        100 * max(experts["flops"] / 197e12, experts["bytes"] / 819e9)
+        / 0.010)
+    assert read("mfu_pct") == pytest.approx(
+        100 * 2_990_211_072 * 13000.0 / 197e12)
+    for metric in cell.metrics("per_layer"):
+        assert spec_mod.load_reader(cell.root, cell.bench,
+                                    metric["name"]) is not None
+
+
+def sdar_cases():
+    return spec_mod.load_cell(SDAR_CELL).arithmetic().hand_worked()
+
+
+@pytest.mark.parametrize("what,got,want", sdar_cases(),
+                         ids=[c[0] for c in sdar_cases()])
+def test_sdar_arithmetic_by_hand_through_the_cell(what, got, want):
+    assert got == want, what
+
+
 def _one_line_fields():
     bench = json.loads((spec_mod.ROOT / "BENCHMARK.json").read_text())
     out = [("command", " ".join(bench["command"]))]
@@ -1435,7 +1652,8 @@ def test_pull_early_pct_is_entered_for_the_ps_cells_under_a_layer_of_perf_md():
     assert entry == {"name": "pull_early_pct", "unit": "%", "better": "higher",
                      "source": "program_span", "layer": "L3 shell + client",
                      "moves": "tokens_per_s", "workloads": PS_CELLS}
-    assert bench["per_layer"][-1] is entry  # appended, nothing moved
+    # appended, nothing moved; PR 51's four follow it
+    assert bench["per_layer"][-5] is entry
     perf = (spec_mod.ROOT / "PERF.md").read_text()
     layers = perf[perf.index("## 3. Layers"):perf.index("## 4. Cells")]
     assert f"| {entry['layer']} |" in layers and "`pull_early_pct`" in layers
