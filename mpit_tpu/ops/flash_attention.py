@@ -11,6 +11,21 @@ showcase the rebuild adds on top of capability parity.  Design:
 - Online softmax: running row-max ``m``, normalizer ``l`` and
   unnormalized accumulator carried across k-blocks in VMEM scratch —
   O(Lq·D) memory regardless of Lk.
+- **A row's statistics lie in every lane**: ``m`` and ``l`` (forward)
+  and ``lse`` and ``delta`` (backward) are ``(rows, 128)`` blocks that
+  hold the row's value in all 128 lanes, and the bodies use them whole
+  (:func:`_lanes`: the same vregs side by side against a wider
+  operand).  A grid step's body is straight-line code that the
+  kernel's compiler schedules vreg by vreg over the whole tile, MXU
+  pushes, ``exp`` and reductions of different rows side by side; what
+  it cannot hide is a dependent round trip through the cross-lane
+  unit, and a statistic read from lane 0 (``ref[:, :1]``) cost one for
+  every use: with them gone the forward body at ``(512, 512)`` float32
+  is 3,393 bundles for 4,594 (docs/tpu_compile_notes.md section 5).
+  Cutting a step into row groups written stage by stage, as the delta
+  rule's heads are (``ops/delta_rule.py`` ``_in_step``), was measured
+  and gains nothing here: the rows of a tile are independent chains
+  already, and the scheduler sees them (PERF.md section 6, PR 52).
 - **Global-offset causal masking**: ``q_offset``/``kv_offset`` (traced
   scalars) shift local indices into global sequence positions, which is
   exactly what sequence-parallel ring attention needs — each ring step
@@ -743,6 +758,19 @@ def _chosen(sel_ref, j, walk):
             else jnp.concatenate(bits, axis=1)) != 0
 
 
+def _lanes(stat, width: int):
+    """A row statistic ``(rows, LANE)`` that holds a row's value in
+    every lane, as ``(rows, width)``: the same vregs side by side, so
+    that it meets a ``width``-wide operand with no lane moved.  A
+    statistic read from lane 0 alone (``ref[:, :1]``) is spread over
+    the lanes by the cross-lane unit each time it is used (a
+    ``vperm.xlu`` a row block and use), and in the forward kernel that
+    round trip stands between a row's scores and its ``exp``: the
+    ``P V`` product then waits on it (PERF.md section 6, PR 52)."""
+    return stat if width == LANE else jnp.concatenate(
+        [stat] * (width // LANE), axis=1)
+
+
 def _take_select(walk, refs):
     """``(the selection's ref or None, the refs after it)``: it follows
     the kernel's other inputs where the walk has one."""
@@ -798,9 +826,14 @@ def _fa_kernel(lo_ref, hi_ref, qoff_ref, kvoff_ref, kvlen_ref, q_ref, k_ref,
         # exp(s - m_new) <= 1 always; rows with no valid score so far
         # keep m == _BIG_NEG and exp underflows to 0 — no isneginf
         # guards anywhere on the dependent path.
-        m_prev = m_scr[:, :1]
+        # The row statistics hold a row's value in EVERY lane of their
+        # ``(block_q, LANE)`` scratch: a row's maximum and sum come out
+        # of the cross-lane reduction in every lane already, and read
+        # back whole they meet the scores and the accumulator with no
+        # lane moved (:func:`_lanes`).
+        m_prev = m_scr[:]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
+        p = jnp.exp(s - _lanes(m_new, s.shape[1]))
         if masked:
             # A row with NO valid score keeps m_new == _BIG_NEG, making
             # exp(s - m_new) = exp(0) = 1 at its masked positions — the
@@ -808,17 +841,14 @@ def _fa_kernel(lo_ref, hi_ref, qoff_ref, kvoff_ref, kvlen_ref, q_ref, k_ref,
             # never has dead rows).
             p = jnp.where(valid, p, 0.0)
         alpha = jnp.exp(m_prev - m_new)
-        l_new = alpha * l_scr[:, :1] + jnp.sum(p, axis=-1, keepdims=True)
+        l_new = alpha * l_scr[:] + jnp.sum(p, axis=-1, keepdims=True)
         pv = jax.lax.dot_general(
             p.astype(v_ref.dtype), v_ref[:], (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32, precision=precision,
         )
-        acc_scr[:] = acc_scr[:] * alpha + pv
-        # lane-0 writes: only column 0 is ever read back (and only
-        # column 0 of the partial outputs is consumed) — broadcasting
-        # the row stats across all 128 lanes cost a full VPU pass each.
-        m_scr[:, :1] = m_new
-        l_scr[:, :1] = l_new
+        acc_scr[:] = acc_scr[:] * _lanes(alpha, acc_scr.shape[1]) + pv
+        m_scr[:] = m_new
+        l_scr[:] = l_new
 
     _run_tile(live, full, _block)
 
@@ -834,7 +864,7 @@ def _fa_kernel(lo_ref, hi_ref, qoff_ref, kvoff_ref, kvlen_ref, q_ref, k_ref,
             m_out[:] = jnp.where(m_scr[:] == _BIG_NEG, NEG_INF, m_scr[:])
             l_out[:] = l_scr[:]
         else:
-            l = l_scr[:, :1]
+            l = _lanes(l_scr[:], acc_scr.shape[1])
             o_ref[:] = (
                 acc_scr[:] / jnp.where(l == 0.0, 1.0, l)
             ).astype(o_ref.dtype)
@@ -1163,20 +1193,23 @@ def _bwd_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     if walk.select:
         chosen = _chosen(sel_ref, j, walk)
         valid, masked = (valid & chosen if masked else chosen), True
+    # ``lse`` and ``delta`` come with a row's value in every lane
+    # (:func:`_rows_to_lanes`): read whole, no lane is moved
+    lse = _lanes(lse_ref[:], s.shape[1])
     if masked:
         # exp(s - lse) is only read where valid; all-masked rows have
         # lse = -inf and no valid element, so the inf branch is never
         # taken.
-        p = jnp.where(valid, jnp.exp(s - lse_ref[:, :1]), 0.0)
+        p = jnp.where(valid, jnp.exp(s - lse), 0.0)
     else:
         # Full blocks contain no dead row (a dead row has no valid key
         # anywhere), so lse is finite and exp needs no guard.
-        p = jnp.exp(s - lse_ref[:, :1])
+        p = jnp.exp(s - lse)
     dp = jax.lax.dot_general(
         do_ref[:], v_ref[:], (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32, precision=precision,
     )  # (block_q, block_k) f32
-    ds = p * (dp - delta_ref[:, :1])
+    ds = p * (dp - _lanes(delta_ref[:], s.shape[1]))
     return p, ds
 
 
@@ -1270,7 +1303,7 @@ def _fa_bwd_kv_kernel(lo_ref, hi_ref, qoff_ref, kvoff_ref, kvlen_ref, k_ref,
 
 def _rows_to_lanes(x, length_p):
     """(L,) f32 row stats -> (L_p, LANE) with the value broadcast across
-    lanes (the layout the kernels read back as ``ref[:, :1]``); a
+    lanes (the layout the kernels read whole: :func:`_lanes`); a
     group's (G, L) -> (G * L_p, LANE), as :func:`_fold` lays the rows."""
     pad = [(0, 0)] * (x.ndim - 1) + [(0, length_p - x.shape[-1])]
     xp = jnp.pad(x.astype(jnp.float32), pad).reshape(-1)  # folded heads

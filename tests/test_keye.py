@@ -519,12 +519,15 @@ def test_the_router_takes_8_of_128_by_softmax_renormalised():
 # PR 46 printed it**: the kernels gained an operand, and a call without a
 # selection must still trace to the program it was, to the character.  A
 # PR that changes one of these blocks or the kernels on purpose records
-# the new digest here and says so.
+# the new digest here and says so.  (PR 52 did: the kernels' bodies read
+# the row statistics whole, in every lane, so every digest of this table
+# and of ``tests/test_sdar.py``'s is that PR's; the programs' numbers
+# are the parent's bit for bit on the chip, PERF.md section 6.)
 PARENTS_STEP = {
-    "mellum2-l4e8-local": "6e59151d029dbae0",
-    "lfm2-l5e8-local": "65c1bcf87eb6c96e",
-    "ouro-l6-local": "5889f27ddae5d257",
-    "joyai-l5e8-local": "e37a97967cd02ac5",
+    "mellum2-l4e8-local": "161d096142069b40",
+    "lfm2-l5e8-local": "7d92712e1bf03ecc",
+    "ouro-l6-local": "7408c6961cee718e",
+    "joyai-l5e8-local": "2be621e6f6b8f9c0",
 }
 
 

@@ -688,17 +688,18 @@ def test_the_shares_routed_parts_are_the_whole_layer_and_nothing_is_twice():
 
 # -- (g) the other blocks' steps are the parent's ------------------------------------------
 
-# As ``tests/test_keye.py`` (e), whose four digests stand: sha256 of
+# As ``tests/test_keye.py`` (e): sha256 of
 # ``str(make_jaxpr(value_and_grad(loss)))`` (addresses blanked) of each
 # cell's block at its ``tiny`` size, the interpreted flash kernels in
 # place of the reference attention, **as the parent commit of PR 51
 # printed it**: the walk gained a table and the attention a keyword, and
 # a call without the block-diffusion mask must still trace to the
-# program it was, to the character (a selection's too).
+# program it was, to the character (a selection's too).  (Since PR 52
+# the digests are that PR's, here and there: ``tests/test_keye.py``.)
 PARENTS_STEP = {
-    "keye-l6e8-local": "bcae43ebc13d4523",
-    "kimi-linear-l5e8-local": "6292e3156c4f95d3",
-    "olmoe-l1-ps1w-su1": "d08a66b825976f78",
+    "keye-l6e8-local": "1f16d66dfae5d358",
+    "kimi-linear-l5e8-local": "df19f22288d6fde3",
+    "olmoe-l1-ps1w-su1": "a967a545ea4783e9",
 }
 
 

@@ -2,8 +2,9 @@
 on-chip-measurement guide, section 2): the Pallas grouped product of
 ``parallel/moe.py`` at OLMoE's published shapes, forward and backward;
 the flash kernels at LFM2's attention shape (32 query over 8 KV heads of
-64 at 8192) and at Mellum's sliding layer's (32 over 4 heads of 128
-under a window of 1024); the delta rule's three kernels at Kimi's KDA
+64 at 8192), at Mellum's sliding layer's (32 over 4 heads of 128
+under a window of 1024) and at the default tiles of float32 and bf16
+operands under both backward schedules; the delta rule's three kernels at Kimi's KDA
 shape (32 heads of 128 at 8192); and the msgd commit over LFM2's vector, whose length is
 whole lanes and no whole number of blocks, and over Ouro's, which is no
 whole number of lanes, with ``w`` and ``vt`` donated.  What interpret mode cannot show: that the tiles fit the chip's fast
@@ -82,6 +83,41 @@ def test_flash_attention_compiles_at(one_chip, kv_heads, width, window):
     # forward, and the fused (no window) or the two backward kernels
     assert calls >= (2 if window is None else 3)
     dq, dk, dv = compiled.output_shardings
+
+
+@pytest.mark.parametrize("fused", ["1", "0"], ids=["fused", "two_kernel"])
+@pytest.mark.parametrize("dtype,block,width,dv", [
+    (jnp.float32, 512, 128, 128), (jnp.float32, 512, 192, 128),
+    (jnp.bfloat16, 1024, 128, 128)],
+    ids=["f32_512x512", "f32_512x512_keys_of_256_lanes", "bf16_1024x1024"])
+def test_the_flash_kernels_compile_at_the_default_tiles(
+        one_chip, monkeypatch, dtype, block, width, dv, fused):
+    """The forward and both backward schedules lower for the chip at
+    the default tiles, ``(512, 512)`` float32 (at keys of 128 lanes and
+    at JoyAI's 192 -> 256 over values of 128) and ``(1024, 1024)`` bf16,
+    with the row statistics read whole and laid side by side against the
+    tile (PR 52: ``_lanes``; a concatenation along the lanes at whole
+    vregs), under the scoped-VMEM budget ``_vmem_auto`` asks for at
+    those tiles, which is the stock one."""
+    import importlib
+
+    fa = importlib.import_module("mpit_tpu.ops.flash_attention")
+    monkeypatch.setenv("MPIT_FA_FUSED_BWD", fused)
+    monkeypatch.delenv("MPIT_FA_VMEM_MB", raising=False)
+    assert fa._default_blocks(dtype) == (block, block)
+    assert fa._vmem_auto(block, block) == 0.0   # no raise is asked for
+
+    def loss(q, k, v):
+        return jnp.sum(fa.flash_attention(
+            q, k, v, causal=True, interpret=False).astype(jnp.float32) ** 2)
+
+    operands = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+                for shape in ((1, 8, 4096, width), (1, 8, 4096, width),
+                              (1, 8, 4096, dv))]
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        *operands).compile()
+    calls = compiled.as_text().count('custom_call_target="tpu_custom_call"')
+    assert calls == (2 if fused == "1" else 3)
 
 
 def test_the_delta_rules_kernels_compile_at_kimis_shape(one_chip, monkeypatch):
