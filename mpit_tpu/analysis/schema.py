@@ -340,6 +340,9 @@ WIRE_MODULES: Tuple[WireModuleSpec, ...] = (
             "CHUNK_HDR_BYTES": 32, "CHUNK_ACK_WORDS": 3,
             "CHUNK_ACK_TIMING_WORDS": 6, "CHUNK_REPLY_WORDS": 5,
             "CHUNK_BLOCK": 1024,
+            # the last word of an INIT that ends in the vector's plain
+            # ranges (PROTOCOL.md section 2, the plain tail)
+            "PLAIN_TAIL": -0x504C41494E,
         },
         packers={
             "pack_header": 2, "header_frame": 2, "timed_frame": 3,

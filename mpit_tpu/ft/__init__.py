@@ -68,6 +68,8 @@ from mpit_tpu.ft.wire import (
     header_frame,
     init_v3,
     init_v5,
+    split_plain_tail,
+    with_plain_tail,
     pack_chunk_header,
     pack_chunk_reply,
     pack_header,
@@ -100,7 +102,7 @@ __all__ = [
     "chunk_elems_for", "chunk_spans", "chunk_stride", "chunk_hdr_bytes",
     "chunk_reply_hdr_bytes", "pack_chunk_header", "unpack_chunk_header",
     "pack_chunk_reply", "unpack_chunk_reply", "chunk_ack_frame",
-    "init_v5",
+    "init_v5", "split_plain_tail", "with_plain_tail",
     "ACK_TIMING_WORDS", "TIMING_TAIL_BYTES",
     "hdr_bytes", "reply_hdr_bytes",
     "pack_header", "unpack_header", "header_frame", "timed_frame",

@@ -231,6 +231,37 @@ def init_v5(offset: int, size: int, codec_id: int, epoch: int, flags: int,
                       dtype=np.int64)
 
 
+#: the last word of an INIT announcement that ends in the vector's plain
+#: ranges (no field of any INIT is negative but v4's leading -1)
+PLAIN_TAIL = -0x504C41494E  # "PLAIN"
+
+
+def with_plain_tail(cinfo: np.ndarray, plain) -> np.ndarray:
+    """``cinfo`` (an INIT announcement of the static path, any version)
+    followed by the vector's plain ranges (``models/flat.py``
+    ``plain_ranges``): ``[start_0, stop_0, ..., count, PLAIN_TAIL]``,
+    extents of the whole vector, the same to every server.  Without
+    ranges ``cinfo`` itself: the announcement every other model makes."""
+    if not plain:
+        return cinfo
+    tail = [x for pair in plain for x in pair] + [len(plain), PLAIN_TAIL]
+    return np.concatenate([cinfo, np.asarray(tail, dtype=np.int64)])
+
+
+def split_plain_tail(raw: np.ndarray):
+    """``(announcement, plain ranges)`` of a received INIT's words: the
+    inverse of :func:`with_plain_tail` (no tail: no ranges)."""
+    if raw.size < 2 or int(raw[-1]) != PLAIN_TAIL:
+        return raw, ()
+    count = int(raw[-2])
+    words = raw[-2 - 2 * count:-2]
+    if count < 1 or words.size != 2 * count:
+        raise ValueError(f"INIT ends in a plain tail of {count} ranges "
+                         f"but holds {raw.size} words")
+    return raw[:-2 - 2 * count], tuple(
+        (int(words[2 * i]), int(words[2 * i + 1])) for i in range(count))
+
+
 # -- chunked streaming (FLAG_CHUNKED, docs/PROTOCOL.md §12) ------------------
 
 #: chunk cuts land on the int8 codec's quantization-block boundaries so

@@ -64,7 +64,9 @@ SIZES: Dict[str, Size] = {
     # lfm2's mixers
     "layer_types": Size("", "each held layer's token mixer, comma-separated, "
                         "n_layers of them: lfm2's conv or full_attention, "
-                        "kimi's kda or full_attention"),
+                        "kimi's kda or full_attention, trinity's "
+                        "sliding_attention (window keys, rotary positions) "
+                        "or full_attention (no positions)"),
     "conv_kernel": Size(3, "the taps of a short causal depthwise convolution "
                         "(lfm2's gated one; kimi's on q, k and v: 4)"),
     # ouro's loop
@@ -103,6 +105,16 @@ SIZES: Dict[str, Size] = {
                     "last row"),
     "noise_seed": Size(0, "keys the noise with the row's own ids: which "
                        "positions of which blocks are masked"),
+    # trinity's balancing rule and scaled input
+    "bias_rate": Size(0.0, "the step of the rule that moves a router's "
+                      "selection bias from each pass's own expert loads "
+                      "(load_balance_coeff); over 0 the bias leaves are "
+                      "the vector's plain ranges, which no optimizer "
+                      "owns (models/flat.py plain_ranges); 0: no rule, "
+                      "every other block's value"),
+    "embed_scale": Size(1.0, "what the token table's rows are multiplied "
+                        "by on their way into the stream (mup_enabled: "
+                        "the square root of d_model)"),
 }
 
 DEFAULTS = {name: size.default for name, size in SIZES.items()}
@@ -270,6 +282,17 @@ def _sdar(s, attn):
     return _module("SdarDecoder", s, attn(), **_heads(s))
 
 
+def _trinity(s, attn):
+    _check_share(s)
+    if s["window"] < 1 or s["bias_rate"] < 0 or s["embed_scale"] <= 0:
+        raise ValueError(f"trinity needs a window, a bias_rate of 0 or "
+                         f"more and an embed_scale over 0: "
+                         f"{s['window']}, {s['bias_rate']}, "
+                         f"{s['embed_scale']}")
+    return _module("TrinityDecoder", s, attn(), layer_types=_layer_kinds(s),
+                   **_heads(s))
+
+
 # what each block is: its decoder's docstring (``models/transformer.py``)
 BLOCKS: Dict[str, Block] = {
     "gpt2": Block((), _gpt2, sample_len=0),
@@ -308,6 +331,11 @@ BLOCKS: Dict[str, Block] = {
         _GROUPED + _SPARSE + _SHARE + _ROTARY + (
             "block_len", "mask_id", "noise_seed"),
         _sdar, loss=OWN_LOSS),
+    "trinity": Block(
+        _GROUPED + _SPARSE + _SHARE + _ROTARY + (
+            "layer_types", "window", "dense_layers", "dense_width",
+            "route_scale", "shared_experts", "bias_rate", "embed_scale"),
+        _trinity, loss=OWN_LOSS),
 }
 ARCHS = tuple(BLOCKS)
 

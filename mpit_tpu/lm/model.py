@@ -25,7 +25,7 @@ import jax
 import jax.numpy as jnp
 
 from mpit_tpu.lm import archs
-from mpit_tpu.models.flat import FlatModel, flatten_module
+from mpit_tpu.models.flat import FlatModel, flatten_module, plain_ranges
 from mpit_tpu.models.transformer import default_attn
 
 # what a sparse layer ``sow``s, and the name of each in the step's
@@ -105,9 +105,16 @@ def build(*, arch: str = "gpt2", use_flash: Optional[bool] = None,
                                        sample)
     kept = module.kept_residual_bytes(
         sizes["seq_len"], _uses_flash(use_flash)) if block.kept_residuals else 0
+    value_and_grad = jax.value_and_grad(loss)
+    if sizes.get("bias_rate", 0.0) > 0:
+        # the block moves its routers' biases by a rule of its own: the
+        # vector has plain ranges, and every step handed out says where
+        # (models/flat.py plain_ranges; optim/rules.py plain_of)
+        fm.plain = plain_ranges(jax.eval_shape(fm.unravel, fm.w0))
+        for step in (value_and_grad, value_grad_stats):
+            step.plain = fm.plain
     return LmModel(
-        module=module, flat=fm, loss=loss,
-        value_and_grad=jax.value_and_grad(loss),
+        module=module, flat=fm, loss=loss, value_and_grad=value_and_grad,
         seq_len=sizes["seq_len"], vocab=sizes["vocab"],
         value_grad_stats=value_grad_stats, kept_residual_bytes=kept)
 

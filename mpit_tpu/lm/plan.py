@@ -135,7 +135,9 @@ def plan(params: Any, n_servers: int, *, rule: str = "add",
     per-element slot count prices the footprint; it does not change the
     cut (every element of one vector carries the same rule, so equal
     weights already equalize params+slots — weights exist for
-    *heterogeneous server budgets*)."""
+    *heterogeneous server budgets*; the one exception, the vector's
+    plain ranges of ``models/flat.py`` ``plain_ranges``, is a few
+    hundred elements whose slots the servers allocate and never use)."""
     if n_servers < 1:
         raise ValueError("need at least one server")
     segments = flat_segments(params, sep=sep, stacked=STACKED_LEAVES)

@@ -19,12 +19,15 @@ gradients where they lie would leave one.
 
 from __future__ import annotations
 
-from typing import Any, Callable
+import re
+from typing import Any, Callable, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.flatten_util import ravel_pytree
+
+from mpit_tpu.dplane.partition import flat_segments
 
 
 def leaf_unravel(params: Any) -> Callable[[jnp.ndarray], Any]:
@@ -71,6 +74,34 @@ def leaf_unravel(params: Any) -> Callable[[jnp.ndarray], Any]:
     return unravel
 
 
+#: The leaves that move by a rule of their own: a router's selection
+#: bias under the balancing rule (``parallel/moe.py`` ``balance_step``).
+PLAIN_LEAVES = r"(^|/)router_bias$"
+#: ``(start, stop)`` extents of the flat vector, ascending and disjoint
+Ranges = Tuple[Tuple[int, int], ...]
+
+
+def plain_ranges(params: Any, leaves: str = PLAIN_LEAVES) -> Ranges:
+    """The *plain ranges* of ``params``' raveled vector: the extents of
+    the leaves whose path matches ``leaves``, in the vector's order.
+
+    The system's premise is one flat vector on which every element
+    carries the same rule.  A plain range is the exception: its slot of
+    the flat gradient holds a step that the model has already worked out
+    (minus it, written as a gradient of rate 1), and whoever updates the
+    vector moves those elements by exactly minus what it finds there: no
+    learning rate, no momentum, no decay, no optimizer slot.  This is
+    the one place that says where they lie; the local step
+    (``optim/msgd.py``), the shells and, through the client's
+    announcement, the servers' rule (``optim/rules.py`` ``apply_at``)
+    are all handed what this returns (``lm/model.py`` ``build`` lays it
+    on the step it hands out, ``optim/rules.py`` ``plain_of`` reads it
+    there)."""
+    match = re.compile(leaves).search
+    return tuple((segment.offset, segment.end)
+                 for segment in flat_segments(params) if match(segment.name))
+
+
 class FlatModel:
     """A Flax module + flat-parameter calling convention.  Every vector
     is cut by :func:`leaf_unravel`."""
@@ -80,6 +111,10 @@ class FlatModel:
         self.w0 = ravel_pytree(params)[0]
         self.size = int(self.w0.shape[0])
         self.unravel = leaf_unravel(params)
+        #: the plain ranges of the vector (:func:`plain_ranges`); none
+        #: unless whoever builds the model says its block has a rule of
+        #: its own (``lm/model.py`` ``build``)
+        self.plain: Ranges = ()
 
     def apply_flat(self, w: jnp.ndarray, *args: Any, **kwargs: Any):
         return self.module.apply({"params": self.unravel(w)}, *args, **kwargs)

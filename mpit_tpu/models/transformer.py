@@ -15,7 +15,7 @@ long-context showcase built on the framework's own kernels:
   batched over (B, L);
 - pre-LN blocks, learned positional embeddings, causal by default.
 
-Nine decoders, each described where it is defined; ``lm/archs.py``
+Ten decoders, each described where it is defined; ``lm/archs.py``
 ``BLOCKS`` names them with the sizes they take, and ``lm/model.py``
 ``build(arch=...)`` chooses one.
 """
@@ -262,8 +262,8 @@ class OlmoeBlock(nn.Module):
             weights, experts = moe.route_top_k(probs, self.experts_per_tok)
             # routing imbalance, for telemetry: read only where the
             # caller makes ``intermediates`` mutable (lm/model.py stats)
-            self.sow("intermediates", "moe_load",
-                     moe.load_max_over_mean(experts, e))
+            self.sow("intermediates", "moe_load", moe.load_max_over_mean(
+                moe.expert_counts(experts, e), experts.size))
 
         wg = self.param("experts_gate", _INIT, (e, d, f))
         wu = self.param("experts_up", _INIT, (e, d, f))
@@ -397,15 +397,19 @@ def grouped_attention(h: jnp.ndarray, wq: jnp.ndarray, wk: jnp.ndarray,
                       kv_heads: int, head_dim: int, inv_freq: np.ndarray,
                       attn: AttnFn, scale: float = 1.0, window: int = 0,
                       qk_norm: Optional[tuple] = None,
-                      period: int = 0) -> jnp.ndarray:
+                      period: int = 0,
+                      gate: Optional[jnp.ndarray] = None) -> jnp.ndarray:
     """Attention over grouped KV heads of their own width on the normed
     stream ``h (B, L, d)``, projected back to ``(B, L, d)``: bias-free
     projections to ``heads`` query and ``kv_heads`` key and value heads
-    of ``head_dim``, rotary positions by ``inv_freq`` (:func:`rope_by`),
+    of ``head_dim``, rotary positions by ``inv_freq`` (:func:`rope_by`;
+    None: **no positional term**, nothing is rotated),
     ``attn`` with the ``window`` where there is one.  ``qk_norm``
     ``(q weight, k weight, eps)``: an RMSNorm over each head's width on
     the queries and the keys, before the rotary embedding.  ``period``
-    as :func:`rope_by` takes it.  Every
+    as :func:`rope_by` takes it.  ``gate (d, heads * head_dim)``: the
+    heads' output is multiplied elementwise by ``sigmoid(h gate)``
+    before ``wo``, under the scope ``attn_gate``.  Every
     product at the backend's default precision, one bf16 pass on a TPU:
     Mellum's scores are O(1) without a norm, and LFM2's with its per-head
     norm read the same gradient error against the float32 reference with
@@ -418,13 +422,17 @@ def grouped_attention(h: jnp.ndarray, wq: jnp.ndarray, wk: jnp.ndarray,
         x = x.reshape(b, l, count, head_dim)
         if qk_norm is not None:
             x = rms_norm(x, weight, qk_norm[2])
-        return rope_by(x, inv_freq, scale, period)
+        return x if inv_freq is None else rope_by(x, inv_freq, scale, period)
 
     q = heads_of(q, heads, qk_norm and qk_norm[0])
     k = heads_of(k, kv_heads, qk_norm and qk_norm[1])
     v = v.reshape(b, l, kv_heads, head_dim)
     out = attn(q, k, v, window=window) if window else attn(q, k, v)
-    return out.reshape(b, l, heads * head_dim) @ wo
+    out = out.reshape(b, l, heads * head_dim)
+    if gate is not None:
+        with jax.named_scope("attn_gate"):
+            out = out * jax.nn.sigmoid(h @ gate)
+    return out @ wo
 
 
 def sparse_mlp(x: jnp.ndarray, norm: jnp.ndarray, router: jnp.ndarray,
@@ -448,7 +456,8 @@ def sparse_mlp(x: jnp.ndarray, norm: jnp.ndarray, router: jnp.ndarray,
         h = rms_norm(x, norm, eps).reshape(b * l, d)
         logits = jnp.matmul(h, router, precision=ROUTER_PRECISION)
         weights, chosen, extra = route(logits.astype(jnp.float32))
-        stats = (moe.load_max_over_mean(chosen, n_experts),
+        stats = (moe.load_max_over_mean(
+                     moe.expert_counts(chosen, n_experts), chosen.size),
                  moe.held_rows_share(chosen, first, held),
                  moe.takes_window(chosen, first, held, n_experts,
                                   d).astype(jnp.float32), *extra)
@@ -708,8 +717,10 @@ class Lfm2Block(nn.Module):
         router = self.param("router", _INIT, (d, e))
         # the selection bias: part of the vector, seeded away from zero
         # so that the selection it changes is exercised; no gradient
-        # reaches it and no rule here updates it (the published
-        # balancing has no rate in the configuration)
+        # reaches it.  The rule that would move it is
+        # ``parallel/moe.py`` ``balance_step`` (``shared_sparse_experts``
+        # applies it where a block has a ``bias_rate``); this block's
+        # rate is 0, because its configuration publishes none
         bias = self.param("router_bias", _INIT, (e,))
         wg = self.param("experts_gate", _INIT, (held, d, f))
         wu = self.param("experts_up", _INIT, (held, d, f))
@@ -1118,6 +1129,11 @@ JOYAI_ATTN_KEPT = (FLASH_OUT, FLASH_LSE)
 #: ``MOE_STATS`` has the same for the blocks that ``sow``)
 JOYAI_MOE_STATS = ("moe_load_max_over_mean", "moe_held_rows_share",
                    "moe_compact_share", "moe_bias_flips_share")
+#: what a sparse layer whose bias moves by the balancing rule counts
+#: beside them: the mean of ``|bias|`` over the router's experts as the
+#: pass found it, and the share of the experts whose count is off the
+#: mean, that is whose step has a sign (1.0 unless a count sits on it)
+BIAS_RULE_STATS = ("moe_bias_abs_mean", "moe_bias_step_nonzero_share")
 
 
 def rope_interleaved(x: jnp.ndarray, inv_freq: np.ndarray) -> jnp.ndarray:
@@ -1217,14 +1233,24 @@ def shared_sparse_experts(block, x, norm):
     """The sparse MLP of ``block`` with a shared expert
     (:class:`JoyaiBlock`, :class:`KimiBlock`): its parameters made in the
     block's own scope, ``(output, statistics in
-    :data:`JOYAI_MOE_STATS`' order)``."""
+    :data:`JOYAI_MOE_STATS`' order)``, then :data:`BIAS_RULE_STATS`'
+    where the block has a ``bias_rate`` over 0."""
     d, e, f = block.d_model, block.n_experts, block.expert_width
     held, shared = block.experts_held or e, block.shared_experts * f
     router = block.param("router", _INIT, (d, e))
     # the selection bias (``noaux_tc``'s ``e_score_correction_bias``):
-    # as LFM2's, part of the vector, seeded away from zero, reached
-    # by no gradient and updated by no rule
+    # as LFM2's, part of the vector, seeded away from zero and reached
+    # by no gradient.  Where the block has a ``bias_rate`` over 0 (a
+    # configuration that publishes the balancing's rate: Trinity's
+    # ``load_balance_coeff``) the rule of ``parallel/moe.py``
+    # ``balance_step`` moves it from this pass's own counts: the bias's
+    # slot of the flat gradient carries minus the step (``carry_step``)
+    # and every optimizer moves the vector's plain ranges by exactly
+    # that (``models/flat.py`` ``plain_ranges``).  JoyAI's and Kimi's
+    # configurations publish no rate: theirs is 0, nothing moves the
+    # bias and their programs are what they were
     bias = block.param("router_bias", _INIT, (e,))
+    rate = float(getattr(block, "bias_rate", 0.0))
     routed = tuple(block.param(f"experts_{name}", _INIT, shape)
                    for name, shape in (("gate", (held, d, f)),
                                        ("up", (held, d, f)),
@@ -1245,8 +1271,18 @@ def shared_sparse_experts(block, x, norm):
             weights, chosen = moe.route_top_k(
                 scores, block.experts_per_tok, renormalise=True,
                 bias=bias, eps=JOYAI_ROUTE_EPS, scale=block.route_scale)
-            return weights, chosen, (
-                moe.bias_flips_share(scores, chosen),)
+            extra = (moe.bias_flips_share(scores, chosen),)
+            if rate > 0:
+                with jax.named_scope("bias_rule"):
+                    # this pass's counts over all the router's experts,
+                    # held or not; the selection above used the bias
+                    # before the step
+                    counts = moe.expert_counts(chosen, e)
+                    weights = moe.carry_step(weights, bias, counts, rate)
+                    off_mean = jnp.sum(counts) != e * counts
+                    extra += (jnp.mean(jnp.abs(bias)),
+                              jnp.mean(off_mean.astype(jnp.float32)))
+            return weights, chosen, extra
 
         y, stats = sparse_mlp(
             x, norm, router, routed, route=route, eps=block.norm_eps,
@@ -2182,4 +2218,215 @@ class SdarDecoder(nn.Module):
             for name, key in zip(SDAR_TILE_STATS, ("live", "nonempty")):
                 stats[name] = jnp.full((self.n_layers,), float(steps[key]))
         stats.update(zip(JOYAI_MOE_STATS, map(jnp.stack, zip(*routing))))
+        return loss, stats
+
+
+# ---------------------------------------------------------------------------
+# The balanced sparse block (Trinity-Mini, Arcee; ``model_type`` ``afmoe``;
+# the configuration's keys are those of its ``config.json``).  Every
+# layer is a **double sandwich**: an RMSNorm before each of its two
+# branches and another on each one's output, ``u = u + N2(Attn(N1(u)))``,
+# ``u = u + N4(Mlp(N3(u)))``.  Attention is over grouped KV heads of
+# their own width with an RMSNorm over each head's width on queries and
+# keys (LFM2's), and what is its own: **a window layer has rotary
+# positions and a full layer has none at all**
+# (``layer_types``: ``sliding_attention`` slides ``window`` keys,
+# rotated; ``full_attention`` is causal over everything, unrotated), and
+# the heads' output is multiplied elementwise by **a sigmoid gate**, a
+# fifth product of the layer's normed input, before ``wo``.  The MLP is
+# dense on the leading layers and else JoyAI's: a sigmoid router with a
+# selection bias over all ``n_experts``, this chip's share of the
+# routed experts and a shared expert.  **The bias moves**: the
+# configuration publishes the rate of the balancing without an
+# auxiliary loss (``load_balance_coeff``, here ``bias_rate``), and after
+# every forward pass each sparse layer's bias takes the step
+# ``parallel/moe.py`` ``balance_step`` makes of that pass's own counts
+# over all the router's experts.  No gradient and no optimizer owns that
+# leaf: its slot of the flat gradient carries minus the step and the
+# optimizers move the vector's plain ranges by it as it is
+# (``models/flat.py`` ``plain_ranges``).  The token table's rows are
+# multiplied by ``embed_scale`` (``mup_enabled``: the square root of the
+# hidden size).  The plain float32 reference it is held to is
+# ``chipbench/reference/trinity_plain.py``, which shares no code with
+# this file (tests/test_trinity.py).
+# ---------------------------------------------------------------------------
+
+#: the kinds of attention ``layer_types`` may name
+TRINITY_MIXERS = ("sliding_attention", "full_attention")
+#: the rms of a token's scaled row in the stream, Mellum's and for its
+#: reason (:data:`MELLUM_EMBED_INIT`): the table is seeded at this over
+#: ``embed_scale``
+TRINITY_EMBED_RMS = 8.0
+
+
+def gated_attention(x: jnp.ndarray, p: dict, *, heads: int, kv_heads: int,
+                    head_dim: int, window: int, theta: float, eps: float,
+                    attn: AttnFn) -> jnp.ndarray:
+    """One Trinity layer's attention on the stream ``x (B, L, d)`` with
+    the weights ``p``, before its out-norm: the norm before the layer
+    and :func:`grouped_attention` with the per-head query/key norm and
+    the sigmoid gate; ``window`` keys and rotary positions, or (0) every
+    earlier key and no positions.  Under the scope ``attn_window`` or
+    ``attn`` (Mellum's two, so that a trace tells the two kernels' time
+    apart), the gate's product and elementwise pass under
+    ``attn_gate``."""
+    with jax.named_scope("attn_window" if window else "attn"):
+        return grouped_attention(
+            rms_norm(x, p["attn_norm"], eps), p["wq"], p["wk"], p["wv"],
+            p["wo"], heads=heads, kv_heads=kv_heads, head_dim=head_dim,
+            inv_freq=plain_inv_freq(head_dim, theta) if window else None,
+            attn=attn, window=window,
+            qk_norm=(p["q_norm"], p["k_norm"], eps), gate=p["wg"])
+
+
+class TrinityBlock(nn.Module):
+    d_model: int
+    n_heads: int
+    kv_heads: int
+    head_dim: int
+    window: int              # 0: a full layer, causal, no positions
+    sparse: bool             # the MLP: routed and shared experts, else dense
+    dense_width: int
+    n_experts: int           # the router's width: every expert there is
+    experts_per_tok: int
+    expert_width: int
+    experts_first: int = 0   # the share held here: a contiguous range
+    experts_held: int = 0    # 0: all of them
+    shared_experts: int = 1
+    route_scale: float = 1.0
+    bias_rate: float = 0.0   # the balancing rule's step; 0: no rule
+    rope_theta: float = 10000.0
+    norm_eps: float = 1e-5
+    attn_fn: Optional[AttnFn] = None
+
+    @nn.compact
+    def __call__(self, x: jnp.ndarray):
+        """``(the stream after the layer, the sparse branch's statistics
+        in :data:`JOYAI_MOE_STATS`' and :data:`BIAS_RULE_STATS`' order)``;
+        a dense layer has none, ``()``."""
+        d, hq, hkv, hd = (self.d_model, self.n_heads, self.kv_heads,
+                          self.head_dim)
+        eps, ones = self.norm_eps, nn.initializers.ones
+        p = {name: self.param(name, init, shape) for name, init, shape in (
+            ("attn_norm", ones, (d,)),
+            ("wq", _INIT, (d, hq * hd)), ("wk", _INIT, (d, hkv * hd)),
+            ("wv", _INIT, (d, hkv * hd)), ("wg", _INIT, (d, hq * hd)),
+            ("wo", _INIT, (hq * hd, d)),
+            ("q_norm", ones, (hd,)), ("k_norm", ones, (hd,)))}
+        # kept for the backward pass: the layer's input and the flash
+        # rule's two; q, k, v and the gate are made again from the
+        # input, as JoyAI's and Keye's attention branches are
+        a = jax.checkpoint(
+            partial(gated_attention, heads=hq, kv_heads=hkv, head_dim=hd,
+                    window=self.window, theta=self.rope_theta, eps=eps,
+                    attn=self.attn_fn if self.attn_fn is not None
+                    else default_attn()),
+            policy=jax.checkpoint_policies.save_only_these_names(
+                *JOYAI_ATTN_KEPT))(x, p)
+        with jax.named_scope("attn_window" if self.window else "attn"):
+            x = x + rms_norm(a, self.param("attn_out_norm", ones, (d,)), eps)
+
+        mlp_norm = self.param("mlp_norm", ones, (d,))
+        out_norm = self.param("mlp_out_norm", ones, (d,))
+        if not self.sparse:
+            # recomputed in the backward pass, as Kimi's dense layer and
+            # for its reason: its input alone is kept
+            @jax.checkpoint
+            def dense(x, norm, w_gate, w_up, w_down):
+                with jax.named_scope("dense_mlp"):
+                    return swiglu(rms_norm(x, norm, eps), w_gate, w_up,
+                                  w_down)
+
+            y = dense(x, mlp_norm,
+                      self.param("w_gate", _INIT, (d, self.dense_width)),
+                      self.param("w_up", _INIT, (d, self.dense_width)),
+                      self.param("w_down", _INIT, (self.dense_width, d)))
+            with jax.named_scope("dense_mlp"):
+                return x + rms_norm(y, out_norm, eps), ()
+        y, stats = shared_sparse_experts(self, x, mlp_norm)
+        with jax.named_scope("dispatch"):  # the norm of the weighted sum
+            return x + rms_norm(y, out_norm, eps), stats
+
+
+class TrinityDecoder(nn.Module):
+    """Causal LM of :class:`TrinityBlock` layers: a token table whose
+    rows are multiplied by ``embed_scale`` (seeded at
+    :data:`TRINITY_EMBED_RMS` over it, so that the scaled row has
+    Mellum's size, for Mellum's reason: a share of the experts is held),
+    the layers (layer ``i``'s attention is ``layer_types[i]``, its MLP
+    dense iff ``i < dense_layers``), a final RMSNorm and an untied head.
+    Like :class:`KimiDecoder` it is called with the targets and returns
+    its own loss, the head's mean next-token NLL, with the routing
+    counters of every sparse layer under ``lm/model.py`` ``MOE_STATS``'
+    names and, where ``bias_rate`` is over 0, :data:`BIAS_RULE_STATS`.
+
+    The head's product, norm and loss is under ``jax.checkpoint``,
+    keeping the rows' log-sum-exp by name (:func:`row_lse`)."""
+
+    vocab: int = 256
+    d_model: int = 64
+    n_heads: int = 4
+    kv_heads: int = 2
+    head_dim: int = 16
+    layer_types: tuple = ("sliding_attention", "sliding_attention",
+                          "full_attention", "sliding_attention",
+                          "sliding_attention")
+    window: int = 16
+    dense_layers: int = 1
+    dense_width: int = 128
+    n_experts: int = 8
+    experts_per_tok: int = 2
+    expert_width: int = 32
+    experts_first: int = 0
+    experts_held: int = 0
+    shared_experts: int = 1
+    route_scale: float = 1.0
+    bias_rate: float = 0.0
+    embed_scale: float = 1.0
+    rope_theta: float = 10000.0
+    norm_eps: float = 1e-5
+    attn_fn: Optional[AttnFn] = None
+
+    @nn.compact
+    def __call__(self, tokens: jnp.ndarray, targets: jnp.ndarray):
+        d, eps = self.d_model, self.norm_eps
+        sizes = {field: getattr(self, field) for field in (
+            "d_model", "n_heads", "kv_heads", "head_dim", "dense_width",
+            "n_experts", "experts_per_tok", "expert_width", "experts_first",
+            "experts_held", "shared_experts", "route_scale", "bias_rate",
+            "rope_theta", "norm_eps", "attn_fn")}
+
+        @partial(jax.checkpoint,
+                 policy=jax.checkpoint_policies.save_only_these_names(
+                     HEAD_LSE))
+        def head_nll(u, norm, head, targets):
+            with jax.named_scope("head_loss"):
+                z = rms_norm(u, norm, eps) @ head
+                return row_lse(z) - jnp.take_along_axis(
+                    z, targets[..., None], axis=-1)[..., 0]
+
+        routing = []
+        with jax.named_scope("embed"):
+            table = self.param(
+                "embed", nn.initializers.normal(
+                    stddev=TRINITY_EMBED_RMS / self.embed_scale),
+                (self.vocab, d))
+            x = table[tokens] * self.embed_scale
+        for i, kind in enumerate(self.layer_types):
+            if kind not in TRINITY_MIXERS:
+                raise ValueError(f"layer type {kind!r}; have "
+                                 f"{TRINITY_MIXERS}")
+            x, counted = TrinityBlock(
+                window=self.window if kind == "sliding_attention" else 0,
+                sparse=i >= self.dense_layers, **sizes)(x)
+            routing += [counted] if counted else []
+        nll = head_nll(
+            x, self.param("final_norm", nn.initializers.ones, (d,)),
+            self.param("head", _INIT, (d, self.vocab)), targets)
+        with jax.named_scope("head_loss"):
+            loss = jnp.mean(nll)
+        names = JOYAI_MOE_STATS + (BIAS_RULE_STATS if self.bias_rate > 0
+                                   else ())
+        stats = dict(zip(names, map(jnp.stack, zip(*routing)))
+                     ) if routing else {}
         return loss, stats

@@ -10,6 +10,11 @@ unit tests substitute an in-process simulator.  Buffers are 1-D numpy arrays
 the client slices per server shard (numpy views = the zero-copy analog of
 ``torch.Storage(grad, offset, size)``, reference pclient.lua:50-52).
 
+``announce_plain(ranges)``, before ``start``, is asked of a client
+only by a shell whose step has plain ranges (``models/flat.py``
+``plain_ranges``; :meth:`mpit_tpu.ps.client.ParamClient.announce_plain`):
+a client without it cannot serve such a vector and fails there.
+
 Two optional extensions: ``sync_device`` (:class:`DeviceSyncAPI`, below)
 and ``stream_shards(staged, landed)``, by which a client tells the sync
 round how the vector is cut and takes its per-shard gate and sink, with

@@ -10,6 +10,11 @@ Semantics preserved exactly:
   checked *before* increment, so the first step syncs) ship the accumulated
   delta and fetch params; between syncs apply ``dfdx`` locally
   (reference :26-45).
+- The vector's plain ranges (``models/flat.py`` ``plain_ranges``) are
+  not scaled: ``dfdx`` there is minus the gradient as it is, the step
+  the model worked out itself, whatever ``lr`` and ``l2wd`` are.  The
+  servers add it like the rest, so the master copy moves by the sum of
+  the pushed steps.
 
 TPU-native changes from the reference mechanics (not semantics): the
 parameter vector, gradient, and the DOWNPOUR accumulator live in device HBM
@@ -39,6 +44,7 @@ import numpy as np
 
 from mpit_tpu.obs import get_recorder, get_registry
 from mpit_tpu.optim.client_api import ParamClientAPI
+from mpit_tpu.optim.rules import plain_of
 from mpit_tpu.optim.sync import attach, push_pull
 
 
@@ -74,12 +80,17 @@ class Downpour:
         self._m_loss = _reg.gauge("mpit_train_loss", opt="downpour")
         self._m_unorm = _reg.gauge("mpit_train_update_norm", opt="downpour")
 
+        self._plain = plain = plain_of(value_and_grad_fn)
+
         def _local(w, accum, k, *args):
-            loss, g = value_and_grad_fn(w, *args)
+            loss, raw = value_and_grad_fn(w, *args)
+            g = raw
             if l2wd != 0:
                 g = g + l2wd * w
             clr = lr / (1.0 + k.astype(jnp.float32) * lrd) if lrd != 0 else lr
             dfdx = -clr * g
+            for start, stop in plain:  # their own step, at no rate
+                dfdx = dfdx.at[start:stop].set(-raw[start:stop])
             return loss, dfdx, accum + dfdx, w + dfdx
 
         self._local = jax.jit(_local)
@@ -89,6 +100,8 @@ class Downpour:
         self.w_host = np.array(w)  # dtype-preserving host mirror
         self.grad_host = np.zeros_like(self.w_host)
         self.accum = jnp.zeros_like(w)
+        if self._plain:  # the client refuses a codec that would round them
+            self.pc.announce_plain(self._plain)
         self.pc.start(self.w_host, self.grad_host)
         attach(self)  # the round streams where the client says how it is cut
         self._started = True

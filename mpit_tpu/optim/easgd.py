@@ -42,6 +42,7 @@ import numpy as np
 from mpit_tpu.obs import get_recorder, get_registry
 from mpit_tpu.optim.client_api import ParamClientAPI
 from mpit_tpu.optim.msgd import MSGDConfig, msgd_commit, msgd_init, msgd_lookahead
+from mpit_tpu.optim.rules import plain_of
 from mpit_tpu.optim.sync import shipped_norm
 
 
@@ -84,10 +85,15 @@ class EAMSGD:
         self.cfg = cfg
         self._skip_local = lr == 0.0  # reference :25 guards localupdate on lr~=0
 
+        # the vector's plain ranges move by their own step in the local
+        # update (optim/msgd.py plain_commit) and elastically, like any
+        # element, in the exchange
+        self._plain = plain = plain_of(value_and_grad_fn)
+
         def _localupdate(w, state, *args):
             w_la, state = msgd_lookahead(w, state, cfg)
             loss, grad = value_and_grad_fn(w_la, *args)
-            w_new, state = msgd_commit(w_la, grad, state, cfg)
+            w_new, state = msgd_commit(w_la, grad, state, cfg, plain)
             return w_new, state, loss
 
         self._localupdate = jax.jit(_localupdate)
@@ -114,6 +120,8 @@ class EAMSGD:
         # (reference :49-53 allocates suw/sug and retargets the client).
         self.center_host = np.zeros_like(np.asarray(w))
         self.sug_host = np.zeros_like(self.center_host)
+        if self._plain:  # the client refuses a codec that would round them
+            self.pc.announce_plain(self._plain)
         self.pc.start(np.array(w), self.sug_host)
         self.pc.reset(self.center_host, self.sug_host)
         self._started = True

@@ -31,6 +31,16 @@ EASGD/EAMSGD wrapper and the mesh trainers interleave an exchange
 between them (reference optim-eamsgd.lua:24-45 embeds the same local
 update).  Their state is the reference's: ``w`` committed and ``vt``
 unscaled between steps.
+
+**The vector's plain ranges** (``models/flat.py`` ``plain_ranges``,
+read off the step's function by ``optim/rules.py`` ``plain_of``) are
+not this rule's: their slots of the gradient hold a step the model has
+worked out itself, and whichever form commits the rest, those elements
+move by exactly minus what lies there, at no rate, with no momentum
+(their velocity stays zero, so the displaced point is the committed one
+there) and no decay (:func:`plain_commit`: a few slices taken before the
+commit and written over its results where they lie; without ranges
+nothing is traced).
 """
 
 from __future__ import annotations
@@ -45,6 +55,7 @@ from mpit_tpu.ops.fused_update import (
     fused_enabled as _fused_enabled,
     fused_nesterov_commit,
 )
+from mpit_tpu.optim.rules import Ranges, plain_of
 
 
 class MSGDConfig(NamedTuple):
@@ -105,25 +116,63 @@ def _takes_kernel(w: Any, cfg: MSGDConfig) -> bool:
             and _fused_enabled(cfg.use_fused))
 
 
-def msgd_commit(w: Any, grad: Any, state: dict, cfg: MSGDConfig) -> Tuple[Any, dict]:
+def plain_commit(plain: Ranges, w: Any, grad: Any,
+                 commit: Callable[[Any], Tuple[Any, Any]]) -> Tuple[Any, Any]:
+    """``commit(w) -> (w_new, vt)`` with the plain ranges as their own
+    rule leaves them (the module text): ``w_new`` there is ``w`` less
+    the gradient as it is, ``vt`` zero.  ``w`` is the point the gradient
+    was taken at.  Without ranges this is ``commit(w)`` and nothing else
+    is traced.
+
+    The ranges' next values are sliced out **before** the commit, behind
+    a barrier that the commit's ``w`` comes through: the commit kernel
+    overwrites ``w`` where it lies, and a slice of ``w`` that may be
+    read after it makes XLA copy the whole vector before the kernel and
+    again after the writes (two sweeps of a 2 GB vector, 12 ms of a
+    339 ms step, and a vector more of temporaries: PERF.md section 6,
+    PR 53)."""
+    if not plain:
+        return commit(w)
+    if not (isinstance(w, jnp.ndarray) and w.ndim == 1):
+        raise TypeError("plain ranges are extents of a flat vector")
+    with jax.named_scope("bias_rule"):
+        moved = [w[start:stop] - grad[start:stop] for start, stop in plain]
+        w, moved = jax.lax.optimization_barrier((w, moved))
+    w_new, vt = commit(w)
+    with jax.named_scope("bias_rule"):
+        for (start, stop), piece in zip(plain, moved):
+            w_new = w_new.at[start:stop].set(piece)
+            vt = vt.at[start:stop].set(0.0)
+    return w_new, vt
+
+
+def msgd_commit(w: Any, grad: Any, state: dict, cfg: MSGDConfig,
+                plain: Ranges = ()) -> Tuple[Any, dict]:
     """Phase 2: weight-decay, decayed-lr descent, velocity update (:31-40).
 
     Flat 1-D params with momentum take the fused pallas sweep
     (:func:`mpit_tpu.ops.fused_update.fused_nesterov_commit`) when enabled
-    — one HBM read/write of (w, vt, g) instead of several."""
+    — one HBM read/write of (w, vt, g) instead of several.  ``plain``:
+    the vector's plain ranges (:func:`plain_commit`)."""
     clr = _effective_lr(cfg, state["k"])
     if _takes_kernel(w, cfg):
-        w_new, vt = fused_nesterov_commit(
-            w, state["vt"], grad, clr, l2wd=float(cfg.l2wd)
-        )
+        w_new, vt = plain_commit(
+            plain, w, grad, lambda w: fused_nesterov_commit(
+                w, state["vt"], grad, clr, l2wd=float(cfg.l2wd)))
         return w_new, {"k": state["k"] + 1, "vt": vt}
-    if cfg.l2wd != 0:
-        grad = jax.tree_util.tree_map(lambda g, p: g + cfg.l2wd * p, grad, w)
-    w = jax.tree_util.tree_map(lambda p, g: p - clr * g, w, grad)
-    vt = state["vt"]
-    if cfg.mom > 0:
-        vt = jax.tree_util.tree_map(lambda v, g: v - clr * g, vt, grad)
-    return w, {"k": state["k"] + 1, "vt": vt}
+
+    def two_passes(w):
+        g = grad
+        if cfg.l2wd != 0:
+            g = jax.tree_util.tree_map(lambda g, p: g + cfg.l2wd * p, g, w)
+        w_new = jax.tree_util.tree_map(lambda p, g: p - clr * g, w, g)
+        vt = state["vt"]
+        if cfg.mom > 0:
+            vt = jax.tree_util.tree_map(lambda v, g: v - clr * g, vt, g)
+        return w_new, vt
+
+    w_new, vt = plain_commit(plain, w, grad, two_passes)
+    return w_new, {"k": state["k"] + 1, "vt": vt}
 
 
 def msgd_step(
@@ -149,19 +198,22 @@ def msgd_step(
     (:class:`MSGD` does, and donates ``w`` and ``state`` to the jitted
     step: there the caller's arrays are consumed).
     """
+    plain = plain_of(value_and_grad_fn)
     if not _takes_kernel(w, cfg):
         with jax.named_scope("update"):
             w_la, state = msgd_lookahead(w, state, cfg)
         loss, grad = value_and_grad_fn(w_la, *fn_args)
         with jax.named_scope("update"):
-            w_new, state = msgd_commit(w_la, grad, state, cfg)
+            w_new, state = msgd_commit(w_la, grad, state, cfg, plain)
         return w_new, state, loss
     loss, grad = value_and_grad_fn(w, *fn_args)
     k = state["k"]
     with jax.named_scope("update"):
-        w_new, vt = fused_nesterov_commit(
-            w, state["vt"], grad, _effective_lr(cfg, k), l2wd=float(cfg.l2wd),
-            mom_next=_effective_momentum(cfg, k + 1))
+        w_new, vt = plain_commit(
+            plain, w, grad, lambda w: fused_nesterov_commit(
+                w, state["vt"], grad, _effective_lr(cfg, k),
+                l2wd=float(cfg.l2wd),
+                mom_next=_effective_momentum(cfg, k + 1)))
     return w_new, {"k": k + 1, "vt": vt}, loss
 
 

@@ -23,6 +23,18 @@ the state pytree as a traced scalar.
 The sign convention matches the reference wire protocol: clients ship either
 pre-scaled updates (``-lr*grad`` for DOWNPOUR, elastic deltas for EASGD) to
 be *plain-added*, or raw gradients for the server-side rules to consume.
+
+One rule a shard, with one exception: the vector's **plain ranges**
+(``models/flat.py`` ``plain_ranges``: a router's selection bias under
+the balancing rule of ``parallel/moe.py``).  Their slots of the gradient
+hold a step the model has worked out itself, written as a gradient of
+rate 1, and a rule that carries ``plain`` ranges moves those elements by
+exactly minus what it finds there and leaves their slots of its state
+where they were (:func:`apply_at`; a server is told the ranges by its
+clients' announcement, ``ps/server.py``).  Plain add needs nothing of
+the kind: its clients ship the step itself, and their shells lay the
+plain ranges' own into it (``optim/downpour.py``).  A rule without
+ranges is the function it always was.
 """
 
 from __future__ import annotations
@@ -34,6 +46,8 @@ from typing import Any, Callable, Dict, NamedTuple, Tuple
 import jax.numpy as jnp
 
 State = Dict[str, Any]
+#: ``(start, stop)`` extents of the whole flat vector, ascending
+Ranges = Tuple[Tuple[int, int], ...]
 
 
 class ShardRule(NamedTuple):
@@ -41,6 +55,58 @@ class ShardRule(NamedTuple):
 
     init: Callable[[jnp.ndarray], State]
     apply: Callable[[jnp.ndarray, jnp.ndarray, State], Tuple[jnp.ndarray, State]]
+    #: the vector's plain ranges (the module text); ``apply`` itself
+    #: knows nothing of them: :func:`apply_at` is the rule with them
+    plain: Ranges = ()
+
+
+def plain_of(value_and_grad_fn: Any) -> Ranges:
+    """The plain ranges of the vector a step differentiates: what
+    ``lm/model.py`` ``build`` laid on the function as ``plain``, none on
+    any other."""
+    return tuple(getattr(value_and_grad_fn, "plain", ()))
+
+
+def in_plain(plain: Ranges, at: Any, size: int) -> jnp.ndarray:
+    """``(size,)`` bool: which elements of a piece that starts at
+    element ``at`` of the vector (an int or a traced scalar) lie in
+    ``plain``."""
+    index = at + jnp.arange(size)
+    inside = jnp.zeros((size,), bool)
+    for start, stop in plain:
+        inside = inside | ((index >= start) & (index < stop))
+    return inside
+
+
+def with_plain(rule: ShardRule, plain: Ranges) -> ShardRule:
+    """``rule`` with the vector's plain ranges.  Plain add keeps none:
+    it adds whatever step its clients ship, theirs for the plain ranges
+    included."""
+    if rule.init is add_init:
+        return rule
+    return rule._replace(plain=tuple((int(a), int(b)) for a, b in plain))
+
+
+def apply_at(rule: ShardRule, at: Any = 0) -> Callable[
+        [jnp.ndarray, jnp.ndarray, State], Tuple[jnp.ndarray, State]]:
+    """``rule``'s ``apply`` for a piece of the vector whose first
+    element is element ``at`` of the whole (a shard's offset, or a
+    chunk's inside it, which may be traced).  Without plain ranges that
+    is ``rule.apply`` itself, the same object.  With them, an element
+    inside one moves by minus its gradient and its slots of the state
+    stay as they were; every other element is ``rule.apply``'s."""
+    if not rule.plain:
+        return rule.apply
+
+    def apply(p, g, state):
+        inside = in_plain(rule.plain, at, p.shape[0])
+        p_new, new = rule.apply(p, g, state)
+        return jnp.where(inside, p - g, p_new), {
+            name: jnp.where(inside, state[name], leaf)
+            if jnp.shape(leaf) == p.shape else leaf
+            for name, leaf in new.items()}
+
+    return apply
 
 
 # ---------------------------------------------------------------------------
@@ -281,8 +347,10 @@ def names() -> Tuple[str, ...]:
     return tuple(_RULES)
 
 
-def make(name: str, **hyperparams: Any) -> ShardRule:
+def make(name: str, plain: Ranges = (), **hyperparams: Any) -> ShardRule:
     """Bind hyperparameters, returning a jit-friendly (init, apply) pair.
+    ``plain``: the vector's plain ranges, for :func:`apply_at`
+    (:func:`with_plain`).
 
     Hyperparameter names are validated eagerly so a typo fails here, at the
     config site, rather than at the first jitted apply."""
@@ -303,4 +371,4 @@ def make(name: str, **hyperparams: Any) -> ShardRule:
                 f"valid: {sorted(valid)}"
             )
         apply = functools.partial(apply, **hyperparams)
-    return ShardRule(init=init, apply=apply)
+    return with_plain(ShardRule(init=init, apply=apply), plain)

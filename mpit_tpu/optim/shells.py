@@ -98,18 +98,25 @@ class RuleShell:
                                    opt=f"rule-{mode}")
         self._has_aux = has_aux
         self.stats_last: dict = {}  # name -> the last recorded round's values
+        # the vector's plain ranges (models/flat.py): in global mode the
+        # servers' rule moves them by the raw step it is shipped, so the
+        # servers are told where they lie (``start``); in local mode
+        # this side's rule does
+        self._plain = rules_mod.plain_of(value_and_grad_fn)
         if mode == "global":
             self._vgf = jax.jit(value_and_grad_fn)
 
         if mode == "local":
             # Client-side centered RMSProp producing an additive update.
             rule = rules_mod.make(
-                "rmsprop", lr=lr, decay=decay, momentum=momentum, epsilon=epsilon
+                "rmsprop", plain=self._plain, lr=lr, decay=decay,
+                momentum=momentum, epsilon=epsilon
             )
+            rule_apply = rules_mod.apply_at(rule)
 
             def _local(w, accum, rstate, *args):
                 loss, g = value_and_grad_fn(w, *args)
-                w_new, rstate = rule.apply(w, g, rstate)
+                w_new, rstate = rule_apply(w, g, rstate)
                 update = w_new - w  # the shipped quantity (reference :59-60)
                 return loss, update, accum + update, rstate
 
@@ -125,6 +132,9 @@ class RuleShell:
                       if self.su > 1 or self.mode == "local" else None)
         if self.mode == "local":
             self.rstate = self._rule.init(w)
+        elif self._plain:
+            # a client that cannot say so cannot serve this vector
+            self.pc.announce_plain(self._plain)
         self.pc.start(self.w_host, self.grad_host)
         attach(self)  # the round streams where the client says how it is cut
         self._started = True
@@ -197,11 +207,14 @@ class SingleWorker:
         else:
             # Single-worker bias correction uses the plain exponent t
             # (reference optim-adam-single.lua:28-30), hence step_div=None.
-            bound = rules_mod.make(rule, **hyperparams)
+            bound = rules_mod.make(
+                rule, plain=rules_mod.plain_of(value_and_grad_fn),
+                **hyperparams)
+            bound_apply = rules_mod.apply_at(bound)
 
             def _step(w, state, *args):
                 loss, g = value_and_grad_fn(w, *args)
-                w_new, state = bound.apply(w, g, state)
+                w_new, state = bound_apply(w, g, state)
                 return w_new, state, loss
 
             self._step_fn = jax.jit(_step)

@@ -499,11 +499,71 @@ def dispatch_top_k(x: jnp.ndarray, weights: jnp.ndarray,
         lambda rows, sizes: expert_fn(rows, sizes, *params, *at))
 
 
-def load_max_over_mean(experts: jnp.ndarray, n_experts: int) -> jnp.ndarray:
-    """Routing imbalance: the busiest expert's assignment count over the
-    mean count (1 is even; ``E / k`` is every token on the same k)."""
-    counts = jnp.bincount(experts.reshape(-1), length=n_experts)
-    return jnp.max(counts) * n_experts / experts.size
+def expert_counts(experts: jnp.ndarray, n_experts: int) -> jnp.ndarray:
+    """How many of the ``T k`` assignments ``experts (T, k)`` fell on
+    each of the router's ``n_experts`` experts, int32 ``(n_experts,)``:
+    every expert's, whether this chip holds it or not."""
+    return jnp.bincount(experts.reshape(-1),
+                        length=n_experts).astype(jnp.int32)
+
+
+def load_max_over_mean(counts: jnp.ndarray, assignments: int) -> jnp.ndarray:
+    """Routing imbalance from :func:`expert_counts` of ``assignments``
+    choices: the busiest expert's count over the mean count (1 is even;
+    ``E / k`` is every token on the same k)."""
+    return jnp.max(counts) * counts.shape[-1] / assignments
+
+
+# -- the selection bias's own rule ---------------------------------------------
+#
+# The balancing without an auxiliary loss (Wang et al., arXiv:2408.15664):
+# after a step's forward pass the bias of every expert that took fewer
+# assignments than the mean goes up by ``rate`` and that of every expert
+# that took more goes down, and the steps are centred so that the bias
+# keeps its sum.  The rule is the bias's whole update: no gradient
+# reaches the bias (:func:`route_top_k`), no learning rate, momentum or
+# decay applies to it.  It travels as a gradient all the same: the slot
+# of the bias in the flat gradient holds minus the step
+# (:func:`carry_step`), and every optimizer moves the *plain ranges* of
+# the vector (``models/flat.py`` ``plain_ranges``) by exactly minus what
+# it finds there (``optim/msgd.py``, ``optim/rules.py``).
+
+
+def balance_step(counts: jnp.ndarray, rate: float) -> jnp.ndarray:
+    """The step the rule asks of the selection bias for one forward
+    pass's ``counts (E,)`` (:func:`expert_counts`), float32 ``(E,)``:
+    ``rate * (s - mean(s))`` with ``s = sign(mean(counts) - counts)``.
+    The signs are taken in integers (``sum(counts) - E counts``), so a
+    count on the mean gives exactly 0 and no rounding decides a sign;
+    every entry is within ``2 rate`` of zero and they sum to zero."""
+    e = counts.shape[-1]
+    sign = jnp.sign(jnp.sum(counts) - e * counts)          # int32
+    mean = jnp.sum(sign).astype(jnp.float32) / e
+    return rate * (sign.astype(jnp.float32) - mean)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(3,))
+def carry_step(weights: jnp.ndarray, bias: jnp.ndarray, counts: jnp.ndarray,
+               rate: float) -> jnp.ndarray:
+    """``weights`` as they are.  In the backward pass the bias's slot is
+    handed ``-balance_step(counts, rate)``: the step the rule asks for,
+    written as a gradient of rate 1, whatever the cotangent.  The
+    router's weights carry it because they are what of the routing the
+    loss reads: ``jax.grad`` then lays the step where the bias lies in
+    the flat gradient and the exchange needs no message of its own."""
+    return weights
+
+
+def _carry_step_fwd(weights, bias, counts, rate):
+    return weights, counts
+
+
+def _carry_step_bwd(rate, counts, g):
+    with jax.named_scope("bias_rule"):
+        return g, -balance_step(counts, rate), None
+
+
+carry_step.defvjp(_carry_step_fwd, _carry_step_bwd)
 
 
 def held_rows_share(experts: jnp.ndarray, first: int,

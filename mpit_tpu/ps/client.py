@@ -89,6 +89,7 @@ from mpit_tpu.ft import (
     header_frame,
     init_v3,
     init_v5,
+    with_plain_tail,
     pack_chunk_header,
     pack_header,
     pack_tx_stamp,
@@ -161,6 +162,9 @@ class ParamClient:
         # timing and the §13 agg tree all still negotiate on.  Every
         # client and reader of one gang must pass the identical layout
         # (servers reject mismatched re-announcements).
+        #: the vector's plain ranges, which every INIT then ends in
+        #: (:meth:`announce_plain`)
+        self._plain: Tuple[Tuple[int, int], ...] = ()
         self._layout = list(layout) if layout is not None else None
         if self._layout is not None:
             if self._sc:
@@ -427,7 +431,8 @@ class ParamClient:
                     dtype=np.int64,
                 )
             self.sched.spawn(
-                aio_send(self.transport, cinfo, srank, tags.INIT,
+                aio_send(self.transport, with_plain_tail(cinfo, self._plain),
+                         srank, tags.INIT,
                          live=self.live, deadline=self._op_deadline()),
                 name=f"send_init:{srank}",
             )
@@ -440,6 +445,29 @@ class ParamClient:
         if self.seed_servers:
             self.async_send_param()
             self.wait()
+
+    def announce_plain(self, plain) -> None:
+        """Before :meth:`start`: the vector's *plain ranges*
+        (``models/flat.py`` ``plain_ranges``), elements that move by
+        exactly minus what a GRAD carries for them whatever the servers'
+        rule is.  Every INIT then ends in them
+        (``ft/wire.py`` ``with_plain_tail``) and a server's rule is
+        ``optim/rules.py`` ``apply_at`` with them.  The step must arrive
+        as it left, so a codec that rounds is refused, and the static
+        cut is the only placement that carries the tail so far."""
+        plain = tuple((int(a), int(b)) for a, b in plain)
+        if not plain:
+            return
+        if not self.codec.identity:
+            raise ValueError(
+                f"the vector has plain ranges and the codec is "
+                f"{self.codec.name!r}: a step of its own rule must reach "
+                "the servers unrounded (docs/WORKLOADS.md, a leaf with a "
+                "rule of its own)")
+        if self._sc:
+            raise ValueError("plain ranges travel in the static path's "
+                             "INIT; shardctl's v4 does not carry them yet")
+        self._plain = plain
 
     def _register(self, param: np.ndarray, grad: np.ndarray) -> None:
         # Dtype-agnostic: shards are element ranges; transports move bytes.

@@ -104,6 +104,7 @@ from mpit_tpu.ft import (
     pack_reply_stamps,
     pack_version,
     reply_hdr_bytes,
+    split_plain_tail,
     unpack_chunk_header,
     unpack_header,
     unpack_tx_stamp,
@@ -120,7 +121,9 @@ from mpit_tpu.obs import (
     registry_or_local,
 )
 from mpit_tpu.obs import clock as obs_clock
-from mpit_tpu.optim.rules import ShardRule, make as make_rule
+from mpit_tpu.optim.rules import (
+    ShardRule, apply_at, make as make_rule, with_plain,
+)
 from mpit_tpu.optim.rules import streams as rule_streams
 from mpit_tpu.ps import serve as _psserve
 from mpit_tpu.ps import tags
@@ -647,7 +650,8 @@ class ParamServer:
         framed/heartbeat flags).  Every failure here is loud — a codec
         disagreement must never reach the frame decoders, where it would
         corrupt parameters silently."""
-        raw = np.frombuffer(payload, dtype=np.int64)
+        raw, plain = split_plain_tail(np.frombuffer(payload, dtype=np.int64))
+        self._adopt_plain(crank, plain)
         epoch, flags = 0, 0
         if raw.size >= 8 and int(raw[0]) == -1:  # INIT v4 (shardctl)
             return self._negotiate_v4(crank, raw)
@@ -786,6 +790,30 @@ class ParamServer:
                                and bool(flags & FLAG_TIMING))
         self.leases.arm(crank, epoch, heartbeats=self._hb[crank])
         return codec
+
+    def _adopt_plain(self, crank: int, plain) -> None:
+        """The vector's plain ranges as ``crank``'s INIT announced them
+        (``ft/wire.py`` ``with_plain_tail``): the rule takes them
+        (``optim/rules.py`` ``with_plain``; plain add keeps none, its
+        clients ship the step itself), and every apply made from here on
+        is ``apply_at`` the shard's offset.  A second announcement must
+        agree: the ranges are the model's, not a client's."""
+        if not plain:
+            return
+        if self._sc or self._dp_cfg is not None:
+            raise ValueError(
+                f"client {crank} announced plain ranges; the static "
+                "host-resident shard is the only placement whose applies "
+                "know them so far")
+        was, self.rule = self.rule, with_plain(self.rule, plain)
+        if was.plain and was.plain != self.rule.plain:
+            raise ValueError(
+                f"client {crank} announced plain ranges "
+                f"{self.rule.plain} but server {self.rank} already holds "
+                f"{was.plain}")
+        if was.plain != self.rule.plain:
+            self._apply_cache.clear()
+            self._chunk_apply_cache.clear()
 
     def _require_splittable_rule(self, crank: int) -> None:
         """Chunked streaming applies chunk *k* before chunk *k+1* has
@@ -1020,7 +1048,8 @@ class ParamServer:
         ``mpit_ps_apply_inplace_total`` says how often it engaged."""
         fn = self._apply_cache.get(codec.name)
         if fn is None:
-            rule_apply = self.rule.apply
+            # the rule itself unless it has plain ranges (rules.apply_at)
+            rule_apply = apply_at(self.rule, self.offset)
             if codec.identity:
                 fn = jax.jit(rule_apply, donate_argnums=(0, 2))
             else:
@@ -1216,7 +1245,7 @@ class ParamServer:
         key = (codec.name if codec is not None else None, csize)
         fn = self._chunk_apply_cache.get(key)
         if fn is None:
-            rule_apply = self.rule.apply
+            rule, offset = self.rule, self.offset
 
             def _chunk_apply(param, payload, state, lo):
                 g = (payload if codec is None or codec.identity
@@ -1224,7 +1253,9 @@ class ParamServer:
                 psl = jax.lax.dynamic_slice(param, (lo,), (csize,))
                 ssl = {k: jax.lax.dynamic_slice(v, (lo,), (csize,))
                        for k, v in state.items()}
-                pn, sn = rule_apply(psl, g, ssl)
+                # the rule itself unless it has plain ranges, which are
+                # cut by the chunk where it lies (rules.apply_at)
+                pn, sn = apply_at(rule, offset + lo)(psl, g, ssl)
                 return (jax.lax.dynamic_update_slice(param, pn, (lo,)),
                         {k: jax.lax.dynamic_update_slice(state[k], sn[k],
                                                          (lo,))

@@ -38,9 +38,12 @@ OTHER = dict(
     shared_experts=2, mtp_layers=0, mtp_weight=0.125,
     kda_heads=3, kda_head_dim=8,
     index_heads=3, index_head_dim=4, index_topk=8,
-    block_len=8, mask_id=300, noise_seed=9)
+    block_len=8, mask_id=300, noise_seed=9,
+    bias_rate=0.002, embed_scale=4.0)
 # where a block cannot take ``OTHER``'s value of a size: its own
-OTHER_OF = {"kimi": {"layer_types": "kda,full_attention,kda"}}
+OTHER_OF = {"kimi": {"layer_types": "kda,full_attention,kda"},
+            "trinity": {"layer_types": "sliding_attention,full_attention,"
+                                       "sliding_attention"}}
 
 # a size that is no field of its name on the module: where the maker put it
 TRANSLATED = {

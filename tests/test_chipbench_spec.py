@@ -56,7 +56,7 @@ def test_every_cells_vocabulary_reaches_the_program_through_the_launcher():
 
     want = {"gpt2": 50257, "olmoe": 50304, "mellum": 12288, "lfm2_moe": 8192,
             "ouro": 49152, "joyai_llm_flash": 16160, "kimi_linear": 20480,
-            "KeyeVL2": 18992, "sdar_moe": 18992}
+            "KeyeVL2": 18992, "sdar_moe": 18992, "afmoe": 25024}
     for name in CELLS:
         cell = spec_mod.load_cell(name)
         cfg = runner.launch_config(cell, seed=5)
@@ -75,7 +75,8 @@ def test_scopes_come_from_every_committed_configuration():
     assert spantree.model_scopes({}) == [
         "embed", "attn", "mlp", "head_loss", "update", "router", "dispatch",
         "experts", "attn_window", "conv", "conv_mix", "exit_gate", "mla_proj",
-        "shared_expert", "kda_proj", "kda_scan", "kda_out", "index", "noise"]
+        "shared_expert", "kda_proj", "kda_scan", "kda_out", "index", "noise",
+        "attn_gate", "bias_rule", "dense_mlp"]
 
 
 def olmoe_cases():
@@ -476,11 +477,13 @@ def test_the_parent_fails_the_new_cell_at_once():
     cell on the parent needs."""
     bench = spec_mod.load_bench()
     names = [w["name"] for w in bench["workloads"]]
-    assert names[-6:] == [LFM2_CELL, OURO_CELL, JOYAI_CELL, KIMI_CELL,
-                          KEYE_CELL, SDAR_CELL] and len(names) == 11
+    assert names[-7:] == [LFM2_CELL, OURO_CELL, JOYAI_CELL, KIMI_CELL,
+                          KEYE_CELL, SDAR_CELL, TRINITY_CELL] \
+        and len(names) == 12
     for missing in ("lfm2-l5e8-locals", "ouro-l6-locals",
                     "joyai-l5e8-locals", "kimi-linear-l5e8-locals",
-                    "keye-l6e8-locals", "sdar-l6e8-locals"):
+                    "keye-l6e8-locals", "sdar-l6e8-locals",
+                    "trinity-l5e8-locals"):
         with pytest.raises(spec_mod.SpecError, match="no workload"):
             spec_mod.load_cell(missing)
 
@@ -763,9 +766,9 @@ def test_joyais_mix_keeps_to_the_traffic_its_issue_fixed():
         if metric["name"] in JOYAI_METRICS[2:]:
             assert metric["workloads"] == [JOYAI_CELL]
         elif metric["name"] in JOYAI_APPENDED + JOYAI_METRICS[:2]:
-            # the cells of PR 43, PR 46 and PR 51, which have some of
-            # these layers too, follow it
-            assert JOYAI_CELL in metric["workloads"][-4:]
+            # the cells of PR 43, PR 46, PR 51 and PR 53, which have
+            # some of these layers too, follow it
+            assert JOYAI_CELL in metric["workloads"][-5:]
 
 
 def test_joyais_readers_find_nothing_in_a_run_without_the_block():
@@ -1010,9 +1013,9 @@ def test_kimis_mix_keeps_to_the_traffic_its_issue_fixed():
         if metric["name"] in KIMI_METRICS:
             assert metric["workloads"] == [KIMI_CELL]
         elif metric["name"] in KIMI_APPENDED:
-            # the cells of PR 46 and PR 51 follow it where they have
-            # the layer
-            assert KIMI_CELL in metric["workloads"][-3:]
+            # the cells of PR 46, PR 51 and PR 53 follow it where they
+            # have the layer
+            assert KIMI_CELL in metric["workloads"][-4:]
         elif "workloads" in metric:
             assert KIMI_CELL not in metric["workloads"], metric["name"]
 
@@ -1236,17 +1239,17 @@ def test_keyes_mix_keeps_to_the_traffic_its_issue_fixed():
         if metric["name"] in KEYE_METRICS:
             assert metric["workloads"] == [KEYE_CELL]
         elif metric["name"] in KEYE_APPENDED:
-            # the cell of PR 51 follows it
-            assert KEYE_CELL in metric["workloads"][-2:]
+            # the cells of PR 51 and PR 53 follow it
+            assert KEYE_CELL in metric["workloads"][-3:]
         elif "workloads" in metric:
             assert KEYE_CELL not in metric["workloads"], metric["name"]
     # added together and in order (later PRs' entries follow them)
     keye = [m for m in cell.bench["per_layer"] if m["name"] in KEYE_METRICS]
     at = cell.bench["per_layer"].index(keye[0])
     assert cell.bench["per_layer"][at:at + 5] == keye
-    # (PR 51's configuration and cell follow them)
-    assert (cell.bench["configs"][-2]["name"],
-            cell.bench["workloads"][-2]["name"]) == (cell.config_name,
+    # (PR 51's and PR 53's configurations and cells follow them)
+    assert (cell.bench["configs"][-3]["name"],
+            cell.bench["workloads"][-3]["name"]) == (cell.config_name,
                                                      KEYE_CELL)
 
 
@@ -1449,14 +1452,16 @@ def test_sdars_mix_keeps_to_the_traffic_its_issue_fixed():
         if metric["name"] in SDAR_METRICS:
             assert metric["workloads"] == [SDAR_CELL]
         elif metric["name"] in KEYE_APPENDED:
-            assert metric["workloads"][-2:] == [KEYE_CELL, SDAR_CELL]
+            # the cell of PR 53 follows them
+            assert metric["workloads"][-3:-1] == [KEYE_CELL, SDAR_CELL]
         elif "workloads" in metric:
             assert SDAR_CELL not in metric["workloads"], metric["name"]
-    # added together, in order and last
-    assert [m["name"] for m in cell.bench["per_layer"][-4:]] == list(
+    # added together and in order (PR 53's four follow them, and its
+    # configuration and cell)
+    assert [m["name"] for m in cell.bench["per_layer"][-8:-4]] == list(
         SDAR_METRICS)
-    assert (cell.bench["configs"][-1]["name"],
-            cell.bench["workloads"][-1]["name"]) == (cell.config_name,
+    assert (cell.bench["configs"][-2]["name"],
+            cell.bench["workloads"][-2]["name"]) == (cell.config_name,
                                                      SDAR_CELL)
 
 
@@ -1560,6 +1565,253 @@ def test_sdar_arithmetic_by_hand_through_the_cell(what, got, want):
     assert got == want, what
 
 
+# -- the Trinity configuration (PR 53) --------------------------------------------
+
+TRINITY_CELL = "trinity-l5e8-local"
+TRINITY_METRICS = ("bias_rule_ms_per_step", "attn_gate_ms_per_step",
+                   "bias_rule_balance_x", "router_bias_abs_mean")
+TRINITY_APPENDED = (
+    "dispatch_ms_per_step", "expert_load_max_over_mean",
+    "held_experts_ms_per_step", "held_experts_roofline",
+    "held_rows_share_pct", "compact_dispatch_pct", "router_bias_flips_pct",
+    "shared_expert_ms_per_step", "attn_window_ms_per_step",
+    "attn_window_roofline")
+
+
+def test_trinity_file_has_the_catalogs_keys_and_the_floor_cuts():
+    """Every key of the catalog's entry under its own name (the
+    model-configs guide's ``architectures.jsonl``, read where it is
+    installed; ``tests/test_trinity.py`` holds the hand-copied values);
+    only the depth, the dense layers and the experts held, the
+    vocabulary and the held layers' kinds differ, with the published
+    values beside them; no width is cut; what the catalog does not give
+    is stated as assumed."""
+    import pathlib
+
+    cell = spec_mod.load_cell(TRINITY_CELL)
+    config = cell.config
+    path = pathlib.Path(
+        "/opt/skills/guides/model-configs/architectures.jsonl")
+    if path.exists():
+        rows = [json.loads(line) for line in path.read_text().splitlines()]
+        entry = next(r for r in rows if r["name"] == "Trinity-Mini")
+        catalog = entry["config"]
+        assert entry["source_url"] == config["source"]
+        assert all(key in config for key in catalog)
+        differ = sorted(k for k, v in catalog.items() if config[k] != v)
+        assert differ == sorted(config["reduced"])
+        assert config["published"] == {k: catalog[k]
+                                       for k in config["reduced"]}
+        assert config["layer_types"] == catalog["layer_types"][1:6]
+    assert sorted(config["reduced"]) == [
+        "layer_types", "num_dense_layers", "num_experts",
+        "num_hidden_layers", "vocab_size"]
+    assert (config["num_hidden_layers"], config["num_dense_layers"],
+            config["num_experts"], config["vocab_size"]) == (
+                5, 1, 8, 200192 // 8)
+    assert (config["hidden_size"], config["num_attention_heads"],
+            config["num_key_value_heads"], config["head_dim"],
+            config["intermediate_size"], config["moe_intermediate_size"],
+            config["num_experts_per_tok"], config["sliding_window"],
+            config["rope_theta"], config["route_scale"],
+            config["load_balance_coeff"]) == (
+                2048, 32, 4, 128, 6144, 1024, 8, 2048, 10000, 2.826, 0.001)
+    assert config["router_experts"] == 128   # the router keeps its width
+    assert config["train_seq"] == 8192
+    entry = next(c for c in cell.bench["configs"]
+                 if c["name"] == cell.config_name)
+    assert sorted(entry["reduced"]) == sorted(config["reduced"])
+    assert entry["source"] == config["source"]
+    assert (cell.chips, cell.traffic_name) == (1, "local-msgd-s8k-trinity")
+    assert ["embed", "attn", "attn_window", "attn_gate", "router",
+            "bias_rule", "dispatch", "experts", "shared_expert",
+            "dense_mlp", "head_loss", "update"] == config["scopes"]
+    assert all(key in config for key in config["launcher_from"].values())
+    assert all(key in config for key in config["tiny"])
+    assert len(config["assumed"]) >= 8 and "16 v5e chips" in \
+        config["deployment"]
+    worked = [got for _what, got, want in cell.arithmetic().hand_worked()
+              if got == want]
+    assert all(count in worked for count in (
+        504_147_712, 65_020_160, 84_156_800))
+    assert cell.arithmetic().param_count(config) == 504_147_712
+    assert cell.reference().LOSS_TOL_NATS > 0 < cell.reference().GRAD_REL_TOL
+
+
+def test_the_launcher_builds_the_balanced_block_from_the_cells_files():
+    from chipbench import run as runner
+    from mpit_tpu.lm.model import build_kw
+    from mpit_tpu.train.launch import lm_trainer_cfg
+
+    cell = spec_mod.load_cell(TRINITY_CELL)
+    kw = build_kw(lm_trainer_cfg(runner.launch_config(cell, seed=5)))
+    assert (kw["arch"], kw["d_model"], kw["n_heads"], kw["kv_heads"],
+            kw["head_dim"], kw["n_layers"], kw["seq_len"], kw["vocab"]) \
+        == ("trinity", 2048, 32, 4, 128, 5, 8192, 25024)
+    assert kw["layer_types"].split(",") == cell.config["layer_types"]
+    assert (kw["window"], kw["dense_layers"], kw["dense_width"]) == (
+        2048, 1, 6144)
+    assert (kw["n_experts"], kw["experts_held"], kw["experts_first"],
+            kw["experts_per_tok"], kw["expert_width"],
+            kw["shared_experts"]) == (128, 8, 0, 8, 1024, 1)
+    assert (kw["route_scale"], kw["bias_rate"], kw["rope_theta"],
+            kw["norm_eps"]) == (2.826, 0.001, 1e4, 1e-5)
+    assert kw["embed_scale"] == pytest.approx(2048 ** 0.5)
+
+
+def test_trinitys_mix_keeps_to_the_traffic_its_issue_fixed():
+    """ISSUE 53 fixed the mix before any code was written: the rate one
+    of three, the budget a whole number of micro-steps of 8192 tokens,
+    momentum 0.9, two rounds of warm-up, closed loop in one process; the
+    four new metrics and the ten appended ones are the cell's; the
+    rule's time, the gate's and what the rule buys move the rate, the
+    bias's size the loss."""
+    cell = spec_mod.load_cell(TRINITY_CELL)
+    mix = cell.traffic
+    steps, rest = divmod(mix["token_budget"],
+                         mix["batch"] * cell.config["train_seq"])
+    assert rest == 0 and steps >= 8
+    assert mix["lr"] in (0.003, 0.01, 0.03)
+    assert (mix["launcher"]["mom"], mix["warmup_rounds"], mix["su"],
+            mix["batch"], mix["launcher"]["np"],
+            mix["launcher"]["lm_use_flash"]) == (0.9, 2, 1, 1, 1, 1)
+    assert "lm_bias_rate" not in mix["launcher"]   # the configuration's
+    moves = {m["name"]: m["moves"] for m in cell.metrics("per_layer")}
+    assert set(TRINITY_METRICS + TRINITY_APPENDED) <= set(moves)
+    assert {moves[m] for m in TRINITY_METRICS[:3]} == {"tokens_per_s"}
+    assert moves["router_bias_abs_mean"] == "loss_at_budget"
+    layers = {m["name"]: m["layer"] for m in cell.bench["per_layer"]}
+    assert {layers[m] for m in TRINITY_METRICS} == {
+        layers["dispatch_ms_per_step"]}
+    for metric in cell.bench["per_layer"]:
+        if metric["name"] in TRINITY_METRICS:
+            assert metric["workloads"] == [TRINITY_CELL]
+        elif metric["name"] in TRINITY_APPENDED:
+            assert metric["workloads"][-1] == TRINITY_CELL
+        elif "workloads" in metric:
+            assert TRINITY_CELL not in metric["workloads"], metric["name"]
+    # added together, in order and last
+    assert [m["name"] for m in cell.bench["per_layer"][-4:]] == list(
+        TRINITY_METRICS)
+    assert (cell.bench["configs"][-1]["name"],
+            cell.bench["workloads"][-1]["name"]) == (cell.config_name,
+                                                     TRINITY_CELL)
+    assert cell.chips == 1 and len(cell.why) <= 200
+
+
+def test_trinitys_readers_find_nothing_in_a_run_without_the_block():
+    """What the parent's traced run hands them: a cell whose
+    configuration lists no such scope, a program that recorded no such
+    counter, no device trace, no merged trace: None, no raise."""
+    for name in ("joyai-l5e8-local", "c111m-local"):
+        cell = spec_mod.load_cell(name)
+        run = {"cell": cell, "reduction": {"step_module": "jit__lambda"},
+               "obs_trace": None, "peaks": None, "results": {},
+               "summary": {"worker_ranks": [0], "window": [0.0, 1.0]}}
+        for metric in TRINITY_METRICS:
+            reader = spec_mod.load_reader(cell.root, cell.bench, metric)
+            assert reader is not None and reader(dict(run)) is None
+
+
+def test_trinitys_readers_read_a_hand_made_run(monkeypatch):
+    """The four readers, the ten shared ones and the metrics without a
+    ``workloads`` list that the cell has to report, on a scope table and
+    a span tree made by hand; and a block with a bias and no rule
+    (JoyAI's rounds carry no ``moe_bias_abs_mean``) reads nothing of the
+    rule's."""
+    from chipbench import flops
+    from chipbench.layers import spantree
+
+    cell = spec_mod.load_cell(TRINITY_CELL)
+    listed = [m["name"] for m in cell.metrics("per_layer")]
+    unlisted = [m["name"] for m in cell.bench["per_layer"]
+                if "workloads" not in m]
+    assert len(unlisted) == 10 and set(unlisted) <= set(listed)
+
+    class Round:
+        def __init__(self, k, load, size, rule=True):
+            self.args = {"round": k,
+                         "moe_held_rows_share": [0.0625] * 4,
+                         "moe_load_max_over_mean": [2.0, load, 2.25, 2.0],
+                         "moe_compact_share": [1.0] * 4,
+                         "moe_bias_flips_share": [0.1, 0.2, 0.3, 0.2]}
+            if rule:
+                self.args.update(
+                    moe_bias_abs_mean=[size] * 4,
+                    moe_bias_step_nonzero_share=[1.0] * 4)
+
+    class Tree:
+        def __init__(self, rule=True):
+            self.rule = rule
+
+        def rounds(self):
+            # four uneven rounds, two between, four more even ones
+            loads = [6.0] * 4 + [5.0, 4.0] + [3.0] * 4
+            return [Round(k, load, 0.016 + 0.001 * k, self.rule)
+                    for k, load in enumerate(loads)]
+
+    table = {"step": 300.0, "attn": 40.0, "attn_window": 60.0,
+             "attn_gate": 9.0, "router": 8.0, "bias_rule": 0.25,
+             "dispatch": 20.0, "experts": 30.0, "shared_expert": 12.0,
+             "dense_mlp": 14.0, "head_loss": 11.0, "update": 13.0}
+    monkeypatch.setattr(spantree, "scope_ms_per_step", lambda run: table)
+    monkeypatch.setattr(spantree, "xplane_path", lambda run: None)
+    run = {"cell": cell, "peaks": flops.load_peaks("TPU v5 lite"),
+           spantree.CACHE_KEY: Tree(),
+           "summary": {"tokens_per_s": 27000.0, "worker_ranks": [0]},
+           "reduction": {"step_module": "jit__lambda", "step_module_runs": 2,
+                         "mosaic_by_scope": {
+                             "attn": (6, 0.070), "attn_window": (24, 0.100),
+                             "experts": (48, 0.040), "update": (2, 0.026)}}}
+
+    def read(name, run=run):
+        return spec_mod.load_reader(cell.root, cell.bench, name)(run)
+
+    assert read("bias_rule_ms_per_step") == pytest.approx(0.25)
+    assert read("attn_gate_ms_per_step") == pytest.approx(9.0)
+    assert read("bias_rule_balance_x") == pytest.approx(2.0)
+    assert read("router_bias_abs_mean") == pytest.approx(0.025)
+    assert read("dispatch_ms_per_step") == pytest.approx(28.0)
+    assert read("held_experts_ms_per_step") == pytest.approx(30.0)
+    assert read("shared_expert_ms_per_step") == pytest.approx(12.0)
+    assert read("held_rows_share_pct") == pytest.approx(6.25)
+    assert read("expert_load_max_over_mean") == pytest.approx(4.5)
+    assert read("compact_dispatch_pct") == pytest.approx(100.0)
+    assert read("router_bias_flips_pct") == pytest.approx(20.0)
+    assert read("head_loss_ms_per_step") == pytest.approx(11.0)
+    assert read("flash_ms_per_step") == pytest.approx(35.0)
+    assert read("attn_window_ms_per_step") == pytest.approx(50.0)
+    families = cell.arithmetic().kernels(cell.config, 1)
+    assert read("flash_roofline") == pytest.approx(
+        100 * families["attn"]["flops"] / 197e12 / 0.035)
+    assert read("attn_window_roofline") == pytest.approx(
+        100 * families["attn_window"]["flops"] / 197e12 / 0.050)
+    experts = cell.arithmetic().experts_cost(cell.config, 1)
+    assert read("held_experts_roofline") == pytest.approx(
+        100 * max(experts["flops"] / 197e12, experts["bytes"] / 819e9)
+        / 0.020)
+    assert read("mfu_pct") == pytest.approx(
+        100 * 2_138_357_760 * 27000.0 / 197e12)
+    for metric in cell.metrics("per_layer"):
+        assert spec_mod.load_reader(cell.root, cell.bench,
+                                    metric["name"]) is not None
+    # a block whose bias nothing moves: the rule's two read nothing
+    still = {**run, spantree.CACHE_KEY: Tree(rule=False)}
+    assert read("bias_rule_balance_x", still) is None
+    assert read("router_bias_abs_mean", still) is None
+    assert read("router_bias_flips_pct", still) == pytest.approx(20.0)
+
+
+def trinity_cases():
+    return spec_mod.load_cell(TRINITY_CELL).arithmetic().hand_worked()
+
+
+@pytest.mark.parametrize("what,got,want", trinity_cases(),
+                         ids=[c[0] for c in trinity_cases()])
+def test_trinity_arithmetic_by_hand_through_the_cell(what, got, want):
+    assert got == want, what
+
+
 def _one_line_fields():
     bench = json.loads((spec_mod.ROOT / "BENCHMARK.json").read_text())
     out = [("command", " ".join(bench["command"]))]
@@ -1652,8 +1904,8 @@ def test_pull_early_pct_is_entered_for_the_ps_cells_under_a_layer_of_perf_md():
     assert entry == {"name": "pull_early_pct", "unit": "%", "better": "higher",
                      "source": "program_span", "layer": "L3 shell + client",
                      "moves": "tokens_per_s", "workloads": PS_CELLS}
-    # appended, nothing moved; PR 51's four follow it
-    assert bench["per_layer"][-5] is entry
+    # appended, nothing moved; PR 51's four and PR 53's four follow it
+    assert bench["per_layer"][-9] is entry
     perf = (spec_mod.ROOT / "PERF.md").read_text()
     layers = perf[perf.index("## 3. Layers"):perf.index("## 4. Cells")]
     assert f"| {entry['layer']} |" in layers and "`pull_early_pct`" in layers
