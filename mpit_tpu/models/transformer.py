@@ -1103,7 +1103,11 @@ class OuroDecoder(nn.Module):
 # (``qk_nope``) and a rotary part (``qk_rope``, interleaved pairs), and
 # the rotary key is **one head shared by all query heads**; the values
 # are ``v_head`` wide, narrower than the ``qk_nope + qk_rope`` of the
-# keys, which is what ``ops/flash_attention.py``'s two widths are for.
+# keys, which is what ``ops/flash_attention.py``'s two widths are for
+# (each goes to the kernels at the width it has, 192 and 128: no lane is
+# padded in front of a call; what is left round the kernels is this
+# file's: :func:`default_attn`'s transposes to heads-major and the join
+# of the rotary and plain parts below, ROADMAP S13 (a)).
 # The MLP is dense on the leading layers and else a sigmoid router with
 # a selection bias over all ``n_experts`` (LFM2's ``noaux_tc``), this
 # chip's share of the routed experts, **and a shared expert that every

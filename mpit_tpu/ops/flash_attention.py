@@ -6,8 +6,22 @@ The reference has no attention at all (conv/pool models only — SURVEY.md
 showcase the rebuild adds on top of capability parity.  Design:
 
 - MXU-shaped: scores and the PV product are ``jnp.dot`` with
-  ``preferred_element_type=f32``; blocks are (block_q, block_k) tiles with
-  the head dim padded to a lane multiple (128).
+  ``preferred_element_type=f32``; blocks are (block_q, block_k) tiles.
+- **Every operand and result at the width it has**: rows and keys are
+  padded to whole blocks, a head's width never is.  An array whose last
+  dimension is no multiple of 128 (a head of 64, latent attention's keys
+  of 192) lies in HBM in whole ``(8, 128)`` tiles already, so a block
+  that spans its last dimension is legal as it stands, and the lanes the
+  last tile lacks are masked where the products run, in VMEM (Mosaic
+  pushes and streams a contraction's partial tile under the MXU
+  instructions' own lane mask and stores a narrow result with masked
+  stores: docs/tpu_compile_notes.md section 5).  Padding q, k, v and dO
+  to whole lanes in XLA, and slicing dQ and dK back, is a copy of each
+  through HBM a call (29 ms of a 565 ms step at 192 lanes, PERF.md
+  section 6, PR 54) that changes no number, since a zero lane adds an
+  exact zero: do not bring it back.  Only the row statistics (``m``,
+  ``l``, ``lse``, ``delta``) are 128 lanes wide whatever the head: they
+  are the kernels' own format (next item).
 - Online softmax: running row-max ``m``, normalizer ``l`` and
   unnormalized accumulator carried across k-blocks in VMEM scratch —
   O(Lq·D) memory regardless of Lk.
@@ -39,10 +53,9 @@ showcase the rebuild adds on top of capability parity.  Design:
   own accumulator.  The head counts travel in the shapes.
 - **Two head widths**: ``v`` may be narrower (or wider) than ``q`` and
   ``k`` (latent attention: 192-wide keys over 128-wide values).  Each
-  is padded to its own lane multiple: the score products and ``dq``,
-  ``dk`` run at the keys' lanes, PV, ``o``, ``do`` and ``dv`` at the
-  values', so a narrow value pays for no lane it does not have.  Equal
-  widths lower to the program they always did.
+  is blocked at its own width: the score products and ``dq``, ``dk``
+  run at the keys' lanes, PV, ``o``, ``do`` and ``dv`` at the values',
+  so a narrow value pays for no lane it does not have.
 - **A sliding window** (``window``, causal): a block wholly outside
   ``[i - window + 1, i]`` is dead like a block above the diagonal.
 - **The grids walk live blocks only** (:class:`_Walk`): for an outer
@@ -767,8 +780,11 @@ def _lanes(stat, width: int):
     ``vperm.xlu`` a row block and use), and in the forward kernel that
     round trip stands between a row's scores and its ``exp``: the
     ``P V`` product then waits on it (PERF.md section 6, PR 52)."""
-    return stat if width == LANE else jnp.concatenate(
-        [stat] * (width // LANE), axis=1)
+    whole, part = divmod(width, LANE)
+    # a width that is no whole number of tiles ends in the first lanes
+    # of one more copy
+    pieces = [stat] * whole + ([stat[:, :part]] if part else [])
+    return pieces[0] if len(pieces) == 1 else jnp.concatenate(pieces, axis=1)
 
 
 def _take_select(walk, refs):
@@ -883,7 +899,9 @@ def _default_blocks(dtype) -> Tuple[int, int]:
 def _tile_dims(lq, lk, d, block_q, block_k, sm_scale, dtype,
                fwd_long_bq=False, bwd_long_bk=False):
     """Shared forward/backward tiling contract: softmax scale, clamped
-    block sizes and padded dims.  The backward's saved-LSE rows only line
+    block sizes and padded dims (rows, keys, and the head's width in
+    whole lanes, which sizes the fused schedule's dQ partial in HBM and
+    pads no operand).  The backward's saved-LSE rows only line
     up with recomputed score tiles if both directions use exactly this
     scale/padding; block sizes themselves may differ per direction (the
     forward slices outputs back to true lq, and LSE/delta are per-row).
@@ -944,27 +962,22 @@ def _lse_of(m, l):
     return m + jnp.log(jnp.where(l == 0.0, 1.0, l))
 
 
-def _fold(x, lq_p, d_p):
-    """``(G, lq, d)`` padded to ``(G, lq_p, d_p)`` and laid out as ``(G
-    * lq_p, d_p)`` rows, head after head (see :func:`_q_row0`); a plain
-    ``(lq, d)`` is only padded."""
-    if x.ndim == 2:
-        return jnp.pad(x, ((0, lq_p - x.shape[0]), (0, d_p - x.shape[1])))
-    g, lq, d = x.shape
-    return jnp.pad(x, ((0, 0), (0, lq_p - lq), (0, d_p - d))).reshape(
-        g * lq_p, d_p)
+def _fold(x, lq_p):
+    """``(G, lq, d)`` padded to ``(G, lq_p, d)`` rows and laid out as
+    ``(G * lq_p, d)``, head after head; a plain ``(lq, d)`` is only
+    padded.  The width stays the operand's own (:func:`_pad_rows`)."""
+    x = _pad_rows(x, lq_p)
+    return x if x.ndim == 2 else x.reshape(-1, x.shape[-1])
 
 
-def _unfold(x, like, lq_p, width=None):
+def _unfold(x, like, lq_p):
     """The inverse of :func:`_fold` on a kernel's row output: back to
-    ``like``'s leading shape, true rows and true width (``like``'s own,
-    or ``width`` where the output has the values' and not the queries':
-    ``o`` under unequal head widths)."""
-    d = like.shape[-1] if width is None else width
+    ``like``'s leading shape and true rows; the width is the output's
+    own, as the kernel wrote it."""
     if like.ndim == 2:
-        return x[:like.shape[0], :d]
+        return x[:like.shape[0]]
     g, lq, _ = like.shape
-    return x.reshape(g, lq_p, -1)[:, :lq, :d]
+    return x.reshape(g, lq_p, -1)[:, :lq]
 
 
 def _unfold_stat(x, like, lq_p):
@@ -975,8 +988,19 @@ def _unfold_stat(x, like, lq_p):
     return x[:, 0].reshape(like.shape[0], lq_p)[:, :like.shape[1]]
 
 
-def _walk_specs(walk, d_p):
-    """Block specs of one walk, each a function of the block's width:
+def _pad_rows(x, rows):
+    """``(..., l, d)`` padded with zero rows to ``(..., rows, d)``.  Rows
+    and keys are padded to whole blocks; a head's width never is (the
+    module's text says why: a block that spans the last dimension is
+    legal as it stands, and the lanes its last tile lacks are masked in
+    VMEM)."""
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 2)
+                   + [(0, rows - x.shape[-2]), (0, 0)])
+
+
+def _walk_specs(walk):
+    """Block specs of one walk, each a function of the block's width
+    (the operand's own last dimension):
     ``held`` for an operand of the outer side, which stays while the
     inner axis runs, ``walked`` for one of the inner side, fetched as
     :meth:`_Walk.fetch` says.  The index maps take what is prefetched
@@ -984,11 +1008,11 @@ def _walk_specs(walk, d_p):
     b_outer, b_inner = ((walk.block_k, walk.block_q) if walk.kv_outer
                         else (walk.block_q, walk.block_k))
 
-    def held(width=d_p):
+    def held(width):
         return pl.BlockSpec((b_outer, width), lambda o, t, *s: (o, 0),
                             memory_space=pltpu.VMEM)
 
-    def walked(width=d_p):
+    def walked(width):
         return pl.BlockSpec((b_inner, width),
                             lambda o, t, *s: (walk.fetch(o, t, *s), 0),
                             memory_space=pltpu.VMEM)
@@ -1044,8 +1068,9 @@ def _prefetch(walk, q_offset, kv_offset, kv_len):
 def _fa_2d(q, k, v, q_offset, kv_offset, *, causal, sm_scale, block_q,
            block_k, interpret, partial=False, precision=None, window=None,
            select=None, blockdiff=None):
-    """Core call on (Lq, D) x (Lk, D); pads to tiles.  Returns the
-    normalized (Lq, D) output, or with ``partial`` the unnormalized
+    """Core call on (Lq, D) x (Lk, D) over values (Lk, Dv); pads rows
+    and keys to whole blocks, and no width.  Returns the
+    normalized (Lq, Dv) output, or with ``partial`` the unnormalized
     ``(acc, m, l)`` triple (f32) for cross-chunk merging.  ``q`` of
     ``(G, Lq, D)`` is a group of query heads over the one KV head: its
     heads are folded into the rows, so ``k`` and ``v`` are read where
@@ -1053,31 +1078,30 @@ def _fa_2d(q, k, v, q_offset, kv_offset, *, causal, sm_scale, block_q,
     lq, d = q.shape[-2:]
     lk, dv = v.shape
     groups = q.shape[0] if q.ndim == 3 else 1
-    scale, bq, bk, lq_p, lk_p, d_p = _tile_dims(
+    scale, bq, bk, lq_p, lk_p, _ = _tile_dims(
         lq, lk, d, block_q, block_k, sm_scale, q.dtype, fwd_long_bq=True
     )
-    # the values' width, and the output's: the keys' own unless the
-    # heads are of two widths (latent attention: 192-wide keys over
-    # 128-wide values), where PV and ``o`` stay at the values' lanes
-    dv_p = _round_up(dv, LANE)
-    qp = _fold(q, lq_p, d_p)
-    kp = jnp.pad(k, ((0, lk_p - lk), (0, d_p - d)))
-    vp = jnp.pad(v, ((0, lk_p - lk), (0, dv_p - dv)))
+    # every operand and result at its own width: ``d`` for q and k,
+    # ``dv`` for v, PV and ``o`` (the keys' own unless the heads are of
+    # two widths, latent attention's 192-wide keys over 128-wide values)
+    qp = _fold(q, lq_p)
+    kp = _pad_rows(k, lk_p)
+    vp = _pad_rows(v, lk_p)
     rows = groups * lq_p
     walk = _Walk(False, causal, window, bq, bk, lq_p // bq, lk_p // bk,
                  groups, select is not None, blockdiff)
-    held, walked = _walk_specs(walk, d_p)
+    held, walked = _walk_specs(walk)
     sel_specs, sel = _select_operand(walk, select, lq_p, lk_p)
     if partial:
-        out_specs = (held(dv_p), held(LANE), held(LANE))
+        out_specs = (held(dv), held(LANE), held(LANE))
         out_shape = (
-            jax.ShapeDtypeStruct((rows, dv_p), jnp.float32),
+            jax.ShapeDtypeStruct((rows, dv), jnp.float32),
             jax.ShapeDtypeStruct((rows, LANE), jnp.float32),
             jax.ShapeDtypeStruct((rows, LANE), jnp.float32),
         )
     else:
-        out_specs = held(dv_p)
-        out_shape = jax.ShapeDtypeStruct((rows, dv_p), q.dtype)
+        out_specs = held(dv)
+        out_shape = jax.ShapeDtypeStruct((rows, dv), q.dtype)
     res = pl.pallas_call(
         functools.partial(
             _fa_kernel, walk=walk, scale=scale, partial=partial,
@@ -1086,10 +1110,10 @@ def _fa_2d(q, k, v, q_offset, kv_offset, *, causal, sm_scale, block_q,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
             grid=walk.grid,
-            in_specs=[held(), walked(), walked(dv_p), *sel_specs],
+            in_specs=[held(d), walked(d), walked(dv), *sel_specs],
             out_specs=out_specs,
             scratch_shapes=[
-                pltpu.VMEM((bq, dv_p), jnp.float32),
+                pltpu.VMEM((bq, dv), jnp.float32),
                 pltpu.VMEM((bq, LANE), jnp.float32),
                 pltpu.VMEM((bq, LANE), jnp.float32),
             ],
@@ -1100,9 +1124,9 @@ def _fa_2d(q, k, v, q_offset, kv_offset, *, causal, sm_scale, block_q,
     )(*_prefetch(walk, q_offset, kv_offset, lk), qp, kp, vp, *sel)
     if partial:
         acc, m, l = res
-        return (_unfold(acc, q, lq_p, dv), _unfold_stat(m, q, lq_p),
+        return (_unfold(acc, q, lq_p), _unfold_stat(m, q, lq_p),
                 _unfold_stat(l, q, lq_p))
-    return _unfold(res, q, lq_p, dv)
+    return _unfold(res, q, lq_p)
 
 
 def _over_leading(f, k, select=None):
@@ -1324,11 +1348,11 @@ def _sum_visited(dq_part, walk, q_offset, kv_offset, kv_len):
                                    _Int(q_offset), _Int(kv_offset), kv_len)
         j = jnp.arange(walk.kv_blocks)[:, None]
         visited = (j >= _Int.of(lo).v) & (j <= hi.v)  # (kv_blocks, q_blocks)
-    nj, rows, d_p = dq_part.shape
-    part = dq_part.reshape(nj, walk.groups, walk.q_blocks, walk.block_q, d_p)
+    nj, rows, d = dq_part.shape
+    part = dq_part.reshape(nj, walk.groups, walk.q_blocks, walk.block_q, d)
     return jnp.sum(
         jnp.where(visited[:, None, :, None, None], part, 0.0), axis=0
-    ).reshape(rows, d_p)
+    ).reshape(rows, d)
 
 
 def _fa_2d_bwd(q, k, v, do, lse, delta, q_offset, kv_offset, *, causal,
@@ -1352,15 +1376,14 @@ def _fa_2d_bwd(q, k, v, do, lse, delta, q_offset, kv_offset, *, causal,
     # the two-kernel schedule with bk=2048 is unmeasured, so the
     # fallback keeps its flat default.  _use_fused_bwd models the fused
     # candidate with the same flag, so gate and kernel stay consistent.
-    scale, bq, bk, lq_p, lk_p, d_p = _tile_dims(
+    scale, bq, bk, lq_p, lk_p, _ = _tile_dims(
         lq, lk, d, block_q, block_k, sm_scale, q.dtype, bwd_long_bk=fused
     )
-    # q, k, dq, dk at the keys' width; v, do, dv at the values'
-    dv_p = _round_up(dv, LANE)
-    qp = _fold(q, lq_p, d_p)
-    kp = jnp.pad(k, ((0, lk_p - lk), (0, d_p - d)))
-    vp = jnp.pad(v, ((0, lk_p - lk), (0, dv_p - dv)))
-    dop = _fold(do, lq_p, dv_p)
+    # q, k, dq, dk at the keys' own width; v, do, dv at the values'
+    qp = _fold(q, lq_p)
+    kp = _pad_rows(k, lk_p)
+    vp = _pad_rows(v, lk_p)
+    dop = _fold(do, lq_p)
     lse_r = _rows_to_lanes(lse, lq_p)
     delta_r = _rows_to_lanes(delta, lq_p)
     rows = groups * lq_p
@@ -1379,28 +1402,28 @@ def _fa_2d_bwd(q, k, v, do, lse, delta, q_offset, kv_offset, *, causal,
     # the chosen schedule.
     walk = _Walk(True, causal, window, bq, bk, lq_p // bq, lk_p // bk,
                  groups, select is not None, blockdiff)
-    held, walked = _walk_specs(walk, d_p)
+    held, walked = _walk_specs(walk)
     sel_specs, sel = _select_operand(walk, select, lq_p, lk_p)
-    out_specs = [held(), held(dv_p)]
-    out_shape = [jax.ShapeDtypeStruct((lk_p, d_p), k.dtype),
-                 jax.ShapeDtypeStruct((lk_p, dv_p), v.dtype)]
+    out_specs = [held(d), held(dv)]
+    out_shape = [jax.ShapeDtypeStruct((lk_p, d), k.dtype),
+                 jax.ShapeDtypeStruct((lk_p, dv), v.dtype)]
     if fused:
         out_specs.append(pl.BlockSpec(
-            (1, bq, d_p), lambda j, t, *s: (j, walk.fetch(j, t, *s), 0),
+            (1, bq, d), lambda j, t, *s: (j, walk.fetch(j, t, *s), 0),
             memory_space=pltpu.VMEM))
         out_shape.append(
-            jax.ShapeDtypeStruct((walk.kv_blocks, rows, d_p), jnp.float32))
+            jax.ShapeDtypeStruct((walk.kv_blocks, rows, d), jnp.float32))
     dk, dv_out, *dq_part = pl.pallas_call(
         functools.partial(_fa_bwd_kv_kernel, walk=walk, fused=fused, **kw),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
             grid=walk.grid,
-            in_specs=[held(), held(dv_p), walked(), walked(dv_p),
+            in_specs=[held(d), held(dv), walked(d), walked(dv),
                       walked(LANE), walked(LANE), *sel_specs],
             out_specs=tuple(out_specs),
             scratch_shapes=[
-                pltpu.VMEM((bk, d_p), jnp.float32),
-                pltpu.VMEM((bk, dv_p), jnp.float32),
+                pltpu.VMEM((bk, d), jnp.float32),
+                pltpu.VMEM((bk, dv), jnp.float32),
             ],
         ),
         out_shape=tuple(out_shape),
@@ -1415,24 +1438,24 @@ def _fa_2d_bwd(q, k, v, do, lse, delta, q_offset, kv_offset, *, causal,
         # The two-kernel schedule's dQ: q rows outer, each walking the
         # kv blocks of its live range.
         walk = dataclasses.replace(walk, kv_outer=False)
-        held, walked = _walk_specs(walk, d_p)
+        held, walked = _walk_specs(walk)
         sel_specs, sel = _select_operand(walk, select, lq_p, lk_p)
         dq = pl.pallas_call(
             functools.partial(_fa_bwd_dq_kernel, walk=walk, **kw),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=5,
                 grid=walk.grid,
-                in_specs=[held(), held(dv_p), held(LANE), held(LANE),
-                          walked(), walked(dv_p), *sel_specs],
-                out_specs=held(),
-                scratch_shapes=[pltpu.VMEM((bq, d_p), jnp.float32)],
+                in_specs=[held(d), held(dv), held(LANE), held(LANE),
+                          walked(d), walked(dv), *sel_specs],
+                out_specs=held(d),
+                scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
             ),
-            out_shape=jax.ShapeDtypeStruct((rows, d_p), q.dtype),
+            out_shape=jax.ShapeDtypeStruct((rows, d), q.dtype),
             **call,
         )(*_prefetch(walk, q_offset, kv_offset, lk), qp, dop, lse_r,
           delta_r, kp, vp, *sel)
 
-    return _unfold(dq, q, lq_p), dk[:lk, :d], dv_out[:lk, :dv]
+    return _unfold(dq, q, lq_p), dk[:lk], dv_out[:lk]
 
 
 def _use_fused_bwd(q_shape, k_shape, d, dtype, sm_scale, block_q, block_k,
@@ -1506,6 +1529,8 @@ def _use_fused_bwd(q_shape, k_shape, d, dtype, sm_scale, block_q, block_k,
     batch = 1
     for s in q_shape[:-2]:
         batch *= int(s)
+    # at the width in whole lanes: the partial is declared ``d`` wide
+    # and lies in HBM in whole tiles, so that is what it takes there
     transient_mb = batch * (lk_p // bk) * lq_p * d_p * 4 / 2**20
     budget = float(os.environ.get("MPIT_FA_FUSED_BWD_MAX_MB", "2048"))
     return transient_mb <= budget

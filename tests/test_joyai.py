@@ -162,29 +162,28 @@ def _pallas_calls(monkeypatch, fn, *args):
     return calls
 
 
-@pytest.mark.parametrize("d,dv,want", [
-    # equal widths: every operand at the one padded width, the shapes the
-    # kernels were handed before they took a second width
-    (64, 64, {"fwd": ([(32, 128), (128, 128), (128, 128)], [(32, 128)]),
-              "dkdv": ([(128, 128), (128, 128), (32, 128), (32, 128),
-                        (32, 128), (32, 128)], [(128, 128), (128, 128)]),
-              "dq": ([(32, 128), (32, 128), (32, 128), (32, 128),
-                      (128, 128), (128, 128)], [(32, 128)])}),
-    # 192-wide keys pad to 256 lanes, 128-wide values not at all: q, k,
-    # dq, dk at 256; v, o, do, dv at 128
-    (192, 128, {"fwd": ([(32, 256), (128, 256), (128, 128)], [(32, 128)]),
-                "dkdv": ([(128, 256), (128, 128), (32, 256), (32, 128),
-                          (32, 128), (32, 128)], [(128, 256), (128, 128)]),
-                "dq": ([(32, 256), (32, 128), (32, 128), (32, 128),
-                        (128, 256), (128, 128)], [(32, 256)])}),
-])
-def test_each_operand_is_padded_to_its_own_width(d, dv, want, monkeypatch):
+def _blocks_at(d, dv):
+    """The blocks of the three calls at ``block_q`` 32 and ``block_k``
+    128 with q, k, dq, dk ``d`` wide and v, o, do, dv ``dv`` wide; the
+    rows' statistics (``lse``, ``delta``) are 128 lanes whatever the
+    head."""
+    return {"fwd": ([(32, d), (128, d), (128, dv)], [(32, dv)]),
+            "dkdv": ([(128, d), (128, dv), (32, d), (32, dv), (32, 128),
+                      (32, 128)], [(128, d), (128, dv)]),
+            "dq": ([(32, d), (32, dv), (32, 128), (32, 128), (128, d),
+                    (128, dv)], [(32, d)])}
+
+
+@pytest.mark.parametrize("d,dv", [(128, 128), (64, 64), (192, 128)])
+def test_each_operand_is_blocked_at_its_own_width(d, dv, monkeypatch):
     """The blocks ``pallas_call`` is handed, forward and in the
-    two-kernel backward: at equal widths what they always were, so the
-    four attention shapes the file already serves lower to the program
-    they lowered to; at two widths a narrow value pays for no lane it
-    does not have."""
+    two-kernel backward: every operand, result and accumulator at the
+    width it has (PR 54: a head of 64 or 192 lanes is no longer padded
+    to whole 128-lane tiles in front of a call), so at 128 lanes what
+    they always were, and at two widths a narrow value pays for no lane
+    it does not have."""
     monkeypatch.setenv("MPIT_FA_FUSED_BWD", "0")
+    want = _blocks_at(d, dv)
     q, k, v, g = _qkv(d, dv, length=128)
     q, k, v, g = (x[0, 0] for x in (q, k, v, g))
     kernel = functools.partial(flash_attention, causal=True, interpret=True,
@@ -201,22 +200,26 @@ def test_each_operand_is_padded_to_its_own_width(d, dv, want, monkeypatch):
         assert call["in"] == blocks_in, name
         assert call["out"][:len(blocks_out)] == blocks_out, name
     # the forward's accumulator is as wide as the values, the backward's
-    # dk and dv scratch each as wide as what it sums
-    assert calls[0]["scratch"][0] == (32, want["fwd"][1][0][1])
-    assert calls[1]["scratch"] == [(128, want["dkdv"][1][0][1]),
-                                   (128, want["dkdv"][1][1][1])]
+    # dk and dv scratch each as wide as what it sums, and what the calls
+    # return has no lane to cut
+    assert calls[0]["scratch"][0] == (32, dv)
+    assert calls[1]["scratch"] == [(128, d), (128, dv)]
+    assert calls[0]["shape"][0] == (128, dv)
+    assert calls[1]["shape"][:2] == [(128, d), (128, dv)]
+    assert calls[2]["shape"] == [(128, d)]
 
 
 def test_equal_widths_take_the_same_path_whichever_way_they_are_said():
-    """``v`` as wide as ``k`` is the one-width call: the same bits as the
-    two-width machinery gives when the widths happen to agree after
-    padding (24 and 16 both pad to 128 lanes), on the shared columns."""
+    """``v`` as wide as ``k`` is the one-width call: what the two-width
+    machinery gives on the shared columns, which are independent of the
+    others (to a unit in the last place: interpreted, the ``P V``
+    product is the CPU's, which tiles 24 columns otherwise than 16)."""
     q, k, v, _ = _qkv(24, 24)
     kernel = functools.partial(flash_attention, causal=True, interpret=True,
                                block_q=32, block_k=128)
     narrow = kernel(q, k, v[..., :16])
-    assert np.array_equal(np.asarray(kernel(q, k, v)[..., :16]),
-                          np.asarray(narrow))
+    np.testing.assert_allclose(np.asarray(kernel(q, k, v)[..., :16]),
+                               np.asarray(narrow), rtol=1e-6, atol=1e-6)
 
 
 # -- (b) the decoder against the plain reference ---------------------------------

@@ -522,12 +522,19 @@ def test_the_router_takes_8_of_128_by_softmax_renormalised():
 # the new digest here and says so.  (PR 52 did: the kernels' bodies read
 # the row statistics whole, in every lane, so every digest of this table
 # and of ``tests/test_sdar.py``'s is that PR's; the programs' numbers
-# are the parent's bit for bit on the chip, PERF.md section 6.)
+# are the parent's bit for bit on the chip, PERF.md section 6.  PR 54
+# did again: the tiny sizes' heads are narrower than a 128-lane tile and
+# go to the kernels at the width they have, no pad in front of a call
+# and no slice behind it, so every digest of this table, of
+# ``tests/test_sdar.py``'s and of ``tests/test_trinity.py``'s two is
+# PR 54's; a 128-wide call still traces to its parent's program
+# (``tests/test_ops.py`` ``PARENTS_128_WIDE``), and on the chip the
+# results are the parent's to the last printed digit, PERF.md section 6.)
 PARENTS_STEP = {
-    "mellum2-l4e8-local": "161d096142069b40",
-    "lfm2-l5e8-local": "7d92712e1bf03ecc",
-    "ouro-l6-local": "7408c6961cee718e",
-    "joyai-l5e8-local": "2be621e6f6b8f9c0",
+    "mellum2-l4e8-local": "ad42943aa12d42df",
+    "lfm2-l5e8-local": "b593098f661ec161",
+    "ouro-l6-local": "8dd55cff8f3be0d2",
+    "joyai-l5e8-local": "5ec1de116152ac77",
 }
 
 

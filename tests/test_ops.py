@@ -6,6 +6,9 @@ implementation with tolerances, the golden-value style the rebuild's test
 strategy mandates.
 """
 
+import hashlib
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -817,6 +820,131 @@ def test_lanes_lays_a_statistic_side_by_side():
     assert wide.shape == (16, 384)
     assert np.array_equal(wide, np.broadcast_to(np.arange(16.0)[:, None],
                                                 (16, 384)))
+
+
+@pytest.mark.parametrize("width", [64, 72, 192, 200])
+def test_lanes_lays_a_statistic_over_a_width_that_is_no_whole_tile(width):
+    """A head narrower than a tile, or one and a half of them: whole
+    copies and then the first lanes of one more."""
+    from mpit_tpu.ops.flash_attention import _lanes
+
+    stat = jnp.broadcast_to(jnp.arange(16.0)[:, None], (16, 128))
+    wide = _lanes(stat, width)
+    assert np.array_equal(wide, np.broadcast_to(np.arange(16.0)[:, None],
+                                                (16, width)))
+
+
+# -- every operand at the width it has (PR 54) --------------------------------
+
+WIDTHS = [(64, 64), (72, 72), (192, 128), (128, 128)]   # keys, values
+MASKS = ["causal", "window", "selection", "block_diffusion"]
+WIDE_L = 256
+
+
+def _mask_of(mask, rng):
+    """The call's keywords for one of the four masks at ``WIDE_L`` rows."""
+    from mpit_tpu.ops import select_bits
+
+    if mask == "causal":
+        return dict(causal=True)
+    if mask == "window":
+        return dict(causal=True, window=100)
+    if mask == "block_diffusion":
+        return dict(blockdiff=(WIDE_L // 2, 32))
+    # a third of the causal pairs, every row its own position among them
+    chosen = np.tril(rng.random((1, WIDE_L, WIDE_L)) < 1 / 3)
+    chosen |= np.eye(WIDE_L, dtype=bool)
+    return dict(causal=True, select=select_bits.pack(jnp.asarray(chosen)))
+
+
+@pytest.mark.parametrize("fa_backward_path", ["1", "0"], indirect=True,
+                         ids=["fused-bwd", "two-kernel-bwd"])
+@pytest.mark.parametrize("heads", [(2, 2), (4, 1)],
+                         ids=["plain", "group_of_four"])
+@pytest.mark.parametrize("mask", MASKS)
+@pytest.mark.parametrize("d,dv", WIDTHS,
+                         ids=[f"{d}_over_{dv}" for d, dv in WIDTHS])
+def test_the_kernels_take_every_operand_at_its_own_width(
+        rng, d, dv, mask, heads, fa_backward_path):
+    """q, k at ``d`` lanes and v, dO at ``dv``, whole tiles or not, go to
+    the kernels as they are: the forward and the three gradients are the
+    reference's, and **to the bit** those of the same call on operands
+    padded to whole 128-lane tiles by hand (what the wrapper did in XLA
+    before PR 54): a zero lane adds an exact zero to every product, so
+    the scores, ``P``, ``dS`` and every result are the padded call's.
+    (At 72 lanes to a few units in the last place: interpreted, a tile's
+    product is the CPU's, which sums a contraction of 72, no whole
+    number of its own vectors, in another order than one of 128.)"""
+    hq, hkv = heads
+    kw = _mask_of(mask, rng)
+    q = jnp.asarray(rng.normal(size=(1, hq, WIDE_L, d)) * 0.5, jnp.float32)
+    k = jnp.asarray(rng.normal(size=(1, hkv, WIDE_L, d)) * 0.5, jnp.float32)
+    v = jnp.asarray(rng.normal(size=(1, hkv, WIDE_L, dv)) * 0.5, jnp.float32)
+    g = jnp.asarray(rng.normal(size=(1, hq, WIDE_L, dv)), jnp.float32)
+    scale = 1.0 / np.sqrt(d)
+
+    def attend(q, k, v):
+        return flash_attention(q, k, v, sm_scale=scale, block_q=64,
+                               block_k=128, precision="highest", **kw)
+
+    def kernels(q, k, v, g=g):
+        return jnp.sum(g * attend(q, k, v))
+
+    def plain(q, k, v):
+        return jnp.sum(g * attention_reference(q, k, v, sm_scale=scale,
+                                               **kw))
+
+    def lanes(x):
+        pad = -x.shape[-1] % 128
+        return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
+
+    def same(a, b):
+        if d % 64:
+            np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
+        else:
+            assert np.array_equal(a, b)
+
+    with jax.default_matmul_precision("highest"):
+        got = jax.value_and_grad(kernels, (0, 1, 2))(q, k, v)
+        want = jax.value_and_grad(plain, (0, 1, 2))(q, k, v)
+        by_hand = jax.value_and_grad(kernels, (0, 1, 2))(
+            lanes(q), lanes(k), lanes(v), lanes(g))
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(a, b, atol=2e-4, rtol=2e-4)
+    # (not the loss: a sum over an array of another shape is XLA's own)
+    out, out_by_hand = attend(q, k, v), attend(lanes(q), lanes(k), lanes(v))
+    same(out, out_by_hand[..., :dv])
+    for a, b, width in zip(got[1], by_hand[1], (d, d, dv)):
+        same(a, b[..., :width])
+        assert not np.any(np.asarray(b[..., width:]))
+
+
+# sha256 of ``str(make_jaxpr(grad(loss)))`` (addresses blanked) of a
+# 128-wide call **as the parent commit of PR 54 printed it**: where the
+# keys and the values are whole tiles the operands, specs, scratch and
+# kernel bodies are what they were, to the character.  A PR that changes
+# the kernels on purpose records the new digests here and says so.
+PARENTS_128_WIDE = {
+    (None, 8, 8): "429a6434eef1f0d9", (None, 32, 4): "7abc0bf45586a306",
+    (1024, 8, 8): "e7b81775e6bb8cc9", (1024, 32, 4): "ec516e5a6fc758d4",
+}
+
+
+@pytest.mark.parametrize("window,hq,hkv", sorted(
+    PARENTS_128_WIDE, key=str), ids=lambda x: str(x))
+def test_a_128_wide_call_traces_to_the_parents_program(window, hq, hkv):
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, causal=True, window=window,
+                                       interpret=False) ** 2)
+
+    shapes = [jax.ShapeDtypeStruct((1, heads, 2048, 128), jnp.float32)
+              for heads in (hq, hkv, hkv)]
+    text = re.sub(r"0x[0-9a-f]+", "0x", str(
+        jax.make_jaxpr(jax.grad(loss, (0, 1, 2)))(*shapes)))
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == \
+        PARENTS_128_WIDE[window, hq, hkv]
 
 
 def test_flash_bwd_no_quadratic_intermediate():

@@ -694,12 +694,12 @@ def test_the_shares_routed_parts_are_the_whole_layer_and_nothing_is_twice():
 # place of the reference attention, **as the parent commit of PR 51
 # printed it**: the walk gained a table and the attention a keyword, and
 # a call without the block-diffusion mask must still trace to the
-# program it was, to the character (a selection's too).  (Since PR 52
+# program it was, to the character (a selection's too).  (Since PR 54
 # the digests are that PR's, here and there: ``tests/test_keye.py``.)
 PARENTS_STEP = {
-    "keye-l6e8-local": "1f16d66dfae5d358",
-    "kimi-linear-l5e8-local": "df19f22288d6fde3",
-    "olmoe-l1-ps1w-su1": "a967a545ea4783e9",
+    "keye-l6e8-local": "91759c8057a04b3c",
+    "kimi-linear-l5e8-local": "e3309d7eb34a2455",
+    "olmoe-l1-ps1w-su1": "c28bcbdddde256b5",
 }
 
 

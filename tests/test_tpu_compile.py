@@ -4,7 +4,8 @@ on-chip-measurement guide, section 2): the Pallas grouped product of
 the flash kernels at LFM2's attention shape (32 query over 8 KV heads of
 64 at 8192), at Mellum's sliding layer's (32 over 4 heads of 128
 under a window of 1024) and at the default tiles of float32 and bf16
-operands under both backward schedules; the delta rule's three kernels at Kimi's KDA
+operands under both backward schedules, with no pad and no slice of an
+operand's size round the calls at head widths of 64 and 192 (PR 54); the delta rule's three kernels at Kimi's KDA
 shape (32 heads of 128 at 8192); and the msgd commit over LFM2's vector, whose length is
 whole lanes and no whole number of blocks, and over Ouro's, which is no
 whole number of lanes, with ``w`` and ``vt`` donated.  What interpret mode cannot show: that the tiles fit the chip's fast
@@ -14,6 +15,9 @@ and says nothing about time.
 The topology is described inside a fixture, never at import, and all
 such tests live in this one file: only one process at a time may load
 the TPU's compiler (the guide says why)."""
+
+import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -55,18 +59,51 @@ def test_the_pallas_grouped_product_compiles_at_published_shapes(one_chip, k, n)
     assert "f32[64,%d,%d]" % (k, n) in text
 
 
+def sweeps_of(text, least):
+    """The ``pad`` and ``slice`` operations of a compiled program, inside
+    its fusions or not, whose result holds at least ``least`` elements:
+    a head padded to whole 128-lane tiles in front of a flash kernel, or
+    ``dq`` / ``dk`` cut back to the head's width behind one, is such an
+    operation (PR 54 took them out; the row statistics' ``[:, 0]`` and
+    the prefetched ranges are far smaller than any operand)."""
+    found = []
+    for line in text.splitlines():
+        op = re.search(r"= \w+\[([\d,]*)\]\S* (pad|slice)\(", line)
+        if op and math.prod(map(int, op.group(1).split(","))) >= least:
+            found.append(line.strip()[:200])
+    return found
+
+
+def block_shapes(fn, *operands):
+    """The ``(rows, width)`` of every block of every ``pallas_call``
+    that ``fn`` traces to."""
+    def calls(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from calls(sub)
+
+    return {tuple(getattr(dim, "block_size", dim)
+                  for dim in mapping.block_shape[-2:])
+            for call in calls(jax.make_jaxpr(fn)(*operands).jaxpr)
+            for mapping in call.params["grid_mapping"].block_mappings}
+
+
 @pytest.mark.parametrize("kv_heads,width,window", [(8, 64, None),
                                                    (4, 128, 1024)],
                          ids=["32_over_8_heads_of_64",
                               "32_over_4_heads_of_128_window_1024"])
 def test_flash_attention_compiles_at(one_chip, kv_heads, width, window):
     """LFM2's attention layer (PR 32): a group's four query heads folded
-    into the kernel's rows, the head width padded to the lanes; and
-    Mellum's sliding layer at its published shape (PR 33): a group of
-    eight under a window of 1024, the inner grid axis the window's
-    static bound and the index maps reading the prefetched offsets.
-    Forward and the backward kernels lower for the chip, k and v at the
-    KV heads' size."""
+    into the kernel's rows, the head 64 lanes wide and blocked as it is
+    (PR 54: no operand is padded to the lanes in front of a call and no
+    gradient cut back behind it); and Mellum's sliding layer at its
+    published shape (PR 33): a group of eight under a window of 1024,
+    the inner grid axis the window's static bound and the index maps
+    reading the prefetched offsets, every block 128 wide as it always
+    was.  Forward and the backward kernels lower for the chip, k and v
+    at the KV heads' size."""
     from mpit_tpu.ops.flash_attention import flash_attention
 
     def loss(q, k, v):
@@ -77,28 +114,34 @@ def test_flash_attention_compiles_at(one_chip, kv_heads, width, window):
                              sharding=one_chip)
     kv = jax.ShapeDtypeStruct((1, kv_heads, 8192, width), jnp.float32,
                               sharding=one_chip)
-    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
-        q, kv, kv).compile()
-    calls = compiled.as_text().count('custom_call_target="tpu_custom_call"')
+    grads = jax.grad(loss, argnums=(0, 1, 2))
+    compiled = jax.jit(grads).lower(q, kv, kv).compile()
+    text = compiled.as_text()
+    calls = text.count('custom_call_target="tpu_custom_call"')
     # forward, and the fused (no window) or the two backward kernels
     assert calls >= (2 if window is None else 3)
     dq, dk, dv = compiled.output_shardings
+    assert not sweeps_of(text, least=math.prod(kv.shape))
+    # the rows' statistics are the kernels' own format, 128 lanes
+    assert block_shapes(grads, q, kv, kv) == {(512, width), (512, 128)}
 
 
 @pytest.mark.parametrize("fused", ["1", "0"], ids=["fused", "two_kernel"])
 @pytest.mark.parametrize("dtype,block,width,dv", [
     (jnp.float32, 512, 128, 128), (jnp.float32, 512, 192, 128),
     (jnp.bfloat16, 1024, 128, 128)],
-    ids=["f32_512x512", "f32_512x512_keys_of_256_lanes", "bf16_1024x1024"])
+    ids=["f32_512x512", "f32_512x512_keys_of_192_lanes", "bf16_1024x1024"])
 def test_the_flash_kernels_compile_at_the_default_tiles(
         one_chip, monkeypatch, dtype, block, width, dv, fused):
     """The forward and both backward schedules lower for the chip at
     the default tiles, ``(512, 512)`` float32 (at keys of 128 lanes and
-    at JoyAI's 192 -> 256 over values of 128) and ``(1024, 1024)`` bf16,
-    with the row statistics read whole and laid side by side against the
-    tile (PR 52: ``_lanes``; a concatenation along the lanes at whole
-    vregs), under the scoped-VMEM budget ``_vmem_auto`` asks for at
-    those tiles, which is the stock one."""
+    at JoyAI's 192 over values of 128, blocked at 192 as they lie: PR
+    54) and ``(1024, 1024)`` bf16, with the row statistics read whole
+    and laid side by side against the tile (PR 52: ``_lanes``; a
+    concatenation along the lanes at whole vregs), under the scoped-VMEM
+    budget ``_vmem_auto`` asks for at those tiles, which is the stock
+    one.  No ``pad`` and no ``slice`` of an operand's size stands round
+    the calls, and a 128-wide call's blocks are 128 wide as before."""
     import importlib
 
     fa = importlib.import_module("mpit_tpu.ops.flash_attention")
@@ -114,10 +157,13 @@ def test_the_flash_kernels_compile_at_the_default_tiles(
     operands = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
                 for shape in ((1, 8, 4096, width), (1, 8, 4096, width),
                               (1, 8, 4096, dv))]
-    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
-        *operands).compile()
-    calls = compiled.as_text().count('custom_call_target="tpu_custom_call"')
+    grads = jax.grad(loss, argnums=(0, 1, 2))
+    text = jax.jit(grads).lower(*operands).compile().as_text()
+    calls = text.count('custom_call_target="tpu_custom_call"')
     assert calls == (2 if fused == "1" else 3)
+    assert not sweeps_of(text, least=math.prod(operands[2].shape))
+    assert block_shapes(grads, *operands) == {
+        (block, width), (block, dv), (block, 128)}
 
 
 def test_the_delta_rules_kernels_compile_at_kimis_shape(one_chip, monkeypatch):
