@@ -1,7 +1,7 @@
 """MT-Y8xx — declared concurrency disciplines, verified against the code.
 
-The concurrency spec used to be prose: "§11 read-gate/header/cache-read
-run without a scheduler yield" (docs/PROTOCOL.md §11.3), "DevicePlane is
+The concurrency spec used to be prose: "cache read and header stamp
+run without a scheduler yield" (docs/PROTOCOL.md §8), "DevicePlane is
 drained only by ``_dplane_service``" (§10), "every inbound chunk passes
 ``_chunk_owned``/``device_copy`` before a donated apply" (docs/DEVICE.md).
 This module is the schema.py move applied to that spec: the disciplines
@@ -75,7 +75,7 @@ class AtomicSection:
     """A declared no-yield window.  With ``start=None`` the whole body
     of each named function is atomic; with a start anchor the window
     runs from the first matching call to the end of the function (the
-    §11 shape: atomic from ``self._read_gate()`` onward)."""
+    §8 shape: atomic from ``self._snapshot_wire()`` onward)."""
     name: str
     file: str                  # rel-path suffix, e.g. "ps/server.py"
     fns: Tuple[str, ...]
@@ -139,25 +139,16 @@ class DonatedSlot:
 
 SECTIONS: Tuple[AtomicSection, ...] = (
     AtomicSection(
-        "ps-read-gate-window", "ps/server.py", ("_dispatch_read",),
-        start=Anchor("_read_gate"),
-        doc="§11.3: gate check, header build and cache read must see one "
-            "consistent (version, bytes) pair — no scheduler yield from "
-            "the _read_gate() call to the end of _dispatch_read."),
+        "ps-read-snapshot-window", "ps/server.py", ("_dispatch_read",),
+        start=Anchor("_snapshot_wire"),
+        doc="§8: the cached frame and the version stamped in the OK "
+            "header must be one consistent (version, bytes) pair — no "
+            "scheduler yield from the _snapshot_wire() call to the end "
+            "of _dispatch_read."),
     AtomicSection(
-        "ps-read-path-helpers", "ps/server.py",
-        ("_read_gate", "_serve_ok_header", "_snapshot_wire"),
-        doc="the read-path helpers the §11 window calls are themselves "
+        "ps-read-path-helpers", "ps/server.py", ("_snapshot_wire",),
+        doc="the read-path helper the §8 window calls is itself "
             "yield-free end to end."),
-    AtomicSection(
-        "cell-read-path-helpers", "cells/cell.py",
-        ("_read_gate", "_serve_ok_header", "_snapshot_wire"),
-        doc="cell shards serve reads under the same §11 window contract "
-            "as the PS (cells/cell.py rebinds the PS dispatcher)."),
-    AtomicSection(
-        "cell-install-atomic", "cells/cell.py", ("_install", "_apply_diff"),
-        doc="§13: installing a received frame/diff into the cell store "
-            "must be atomic w.r.t. concurrent cell reads."),
     AtomicSection(
         "agg-fold-window", "agg/client.py", ("_group_fold",),
         start=Anchor("pop", receiver="_pending_tickets"),
@@ -180,13 +171,8 @@ WRITERS: Tuple[SingleWriter, ...] = (
     SingleWriter(
         "reader-single-writer", "ps/server.py",
         Anchor("_dispatch_read"), ("_reader_dispatcher",),
-        doc="§11: read frames are dispatched only by the reader "
+        doc="§8: read frames are dispatched only by the reader "
             "dispatcher task (one reader stream per connection)."),
-    SingleWriter(
-        "cell-stream-single-writer", "ps/server.py",
-        Anchor("_cell_frame"), ("_cell_dispatcher",),
-        doc="§13: cell stream frames are applied only by the cell "
-            "dispatcher task."),
 )
 
 SINKS: Tuple[OwnedSink, ...] = (
@@ -235,12 +221,6 @@ SINKS: Tuple[OwnedSink, ...] = (
         doc="PR 17 pool seam: the chunk body a pooled scatter reads "
             "must be owned — the server's rx buffer is reused per "
             "message while the job may still be copying from it."),
-    OwnedSink(
-        "cells-xor-owned-out", "cells/wire.py", "xor_sync", 2,
-        receiver="pool",
-        doc="§11 DELTA production/install: the XOR kernel's output must "
-            "be a fresh owned buffer (np.empty) — reply tasks may still "
-            "hold zero-copy views of the old frame (copy-on-write)."),
 )
 
 PATHS: Tuple[OwnedPath, ...] = (

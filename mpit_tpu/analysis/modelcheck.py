@@ -5,7 +5,7 @@ The recv-recv deadlock shapes the FT/chunking machinery was built to
 avoid (the EASGD-lineage PS model's classic failure) were, until now,
 only caught dynamically: a wedged gang, a flight-recorder postmortem, a
 CI timeout.  This module explores every cooperative-scheduler
-interleaving of the INIT/STOP/RETIRE/PREEMPT/SUBSCRIBE handshakes that
+interleaving of the INIT/STOP/RETIRE/PREEMPT handshakes that
 :data:`mpit_tpu.analysis.schema.HANDSHAKES` declares — bounded only by
 per-channel capacity and a global state cap — and reports:
 
@@ -20,9 +20,9 @@ per-channel capacity and a global state cap — and reports:
   quiescence while some role still awaits a declared ack (``expects``
   on the send) that can no longer arrive.
 
-Transitions may declare per-hop ``drop``/``dup`` fault toggles — the
-tolerances the protocol actually claims (duplicated framed writes are
-re-acked by dedup, dropped DIFF deltas are recovered by resync).  A
+Transitions may declare a per-hop ``dup`` fault toggle — the
+tolerance the protocol actually claims (duplicated framed writes are
+re-acked by dedup).  A
 second exploration pass with faults enabled must *still* be
 deadlock-free; unacked-terminal is only judged on fault-free paths
 (retry machinery, not the handshake table, owns lost-message recovery).
@@ -69,7 +69,6 @@ class Transition:
     peer: str
     target: str
     expects: Optional[str] = None
-    drop: bool = False
     dup: bool = False
 
     def label(self) -> str:
@@ -110,7 +109,7 @@ class Machine:
                     role=role, index=len(transitions), state=state,
                     action=action, tag=tag, peer=peer, target=target,
                     expects=opts.get("expects"),
-                    drop=bool(opts.get("drop")), dup=bool(opts.get("dup"))))
+                    dup=bool(opts.get("dup"))))
         return cls(name=data["name"], doc=data.get("doc", ""),
                    channel_cap=int(data.get("channel_cap", 2)),
                    roles=roles, start=start, terminal=terminal,
@@ -197,7 +196,7 @@ def _enabled(m: Machine, state: State) -> List[Transition]:
 def _apply(m: Machine, state: State, t: Transition,
            copies: int = 1) -> State:
     """The successor state after firing ``t`` delivering ``copies``
-    messages (0 = dropped, 2 = duplicated; recv/tau ignore it)."""
+    messages (2 = duplicated; recv/tau ignore it)."""
     idx = m.roles.index(t.role)
     roles = list(state[0])
     roles[idx] = t.target
@@ -286,11 +285,8 @@ def explore(m: Machine, faults: bool, max_states: int = 200_000
         for t in enabled:
             covered.add(t.index)
             variants = [1]
-            if faults and t.action == "send":
-                if t.drop:
-                    variants.append(0)
-                if t.dup:
-                    variants.append(2)
+            if faults and t.action == "send" and t.dup:
+                variants.append(2)
             for copies in variants:
                 nxt = _apply(m, state, t, copies)
                 if nxt in seen:
@@ -299,8 +295,7 @@ def explore(m: Machine, faults: bool, max_states: int = 200_000
                     truncated = True
                     continue
                 seen.add(nxt)
-                suffix = {0: " (dropped)", 2: " (duplicated)"}.get(
-                    copies, "")
+                suffix = " (duplicated)" if copies == 2 else ""
                 parents[nxt] = (state, t.label() + suffix)
                 queue.append(nxt)
     return len(seen), truncated, covered, violations
@@ -312,7 +307,7 @@ def check_machine(m: Machine, max_states: int = 200_000) -> MachineResult:
                                      max_states=max_states)
     res.states_fault_free, res.truncated = n, trunc
     res.violations.extend(vio)
-    if any(t.drop or t.dup for t in m.transitions):
+    if any(t.dup for t in m.transitions):
         n2, trunc2, covered2, vio2 = explore(m, faults=True,
                                              max_states=max_states)
         res.states_faulty = n2

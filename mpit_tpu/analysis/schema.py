@@ -2,18 +2,18 @@
 tag, INIT version, negotiated flag bit, and frame header layout, plus
 the conformance passes (MT-S6xx) that hold the code to it.
 
-The protocol surface outgrew prose-and-pattern checking: 17 tags, INIT
-v1–v5, seven negotiated flag bits with a requires/excludes lattice, and
+The protocol surface outgrew prose-and-pattern checking: 15 tags, INIT
+v1–v5, six negotiated flag bits with a requires/excludes lattice, and
 a dozen frame layouts whose pack/unpack widths must agree across
-ps/ft/shardctl/cells/agg.  This module makes the spec *executable*:
+ps/ft/shardctl/agg.  This module makes the spec *executable*:
 
 - the **registry** below is the single source of truth.  PROTOCOL.md's
   §1 tag table and §6.0 flag/version tables are *generated* from it
   (``python -m mpit_tpu.analysis schema --emit-docs``; drift between
   the registry and the checked-in doc fails ``--check`` and CI);
 - the **conformance pass** (:func:`check`, wired into the mtlint
-  engine) parses the six wire modules (ps/tags.py, ft/wire.py,
-  shardctl/wire.py, cells/wire.py, agg/wire.py) and the negotiation
+  engine) parses the four wire modules (ps/tags.py, ft/wire.py,
+  shardctl/wire.py, agg/wire.py) and the negotiation
   code in ps/server.py / ps/client.py and reports any constant, struct
   literal, tag registration, INIT-version dispatch, or flag-lattice
   guard that contradicts the registry;
@@ -22,7 +22,7 @@ ps/ft/shardctl/cells/agg.  This module makes the spec *executable*:
   2^7 × v1–v5 matrix test drives the real ``ParamServer._negotiate``
   against it, so the registry and the server cannot quietly diverge;
 - the **handshake tables** (:data:`HANDSHAKES`) declare the
-  INIT/STOP/RETIRE/PREEMPT/SUBSCRIBE state machines the bounded
+  INIT/STOP/RETIRE/PREEMPT state machines the bounded
   interleaving model checker (mpit_tpu.analysis.modelcheck) explores.
 
 Like the rest of mpit_tpu.analysis this module is stdlib-only and never
@@ -142,24 +142,11 @@ TAGS: Tuple[TagSpec, ...] = (
         "response to `SHARD_PULL`"),
     TagSpec(
         "HEARTBEAT_ECHO", 13, "server", "client", "s→c",
-        "int64 `[epoch, seq, t_tx_echo, t_recv, t_ack]` (40 B, §6.7); to a "
-        "SUBSCRIBE cell: int64 `[epoch, seq, head_version]` (24 B, §11.3)",
+        "int64 `[epoch, seq, t_tx_echo, t_recv, t_ack]` (40 B, §6.7)",
         "— (FLAG_TIMING reply to a timed `HEARTBEAT`; **not** an ack tail — "
         "beats stay fire-and-forget and the client drains echoes "
-        "opportunistically.  The subscriber form is the head announcement "
-        "a cell's staleness admission keys on)"),
-    TagSpec(
-        "DIFF", 14, "server", "cell", "s→cell",
-        "one snapshot-diff frame of the committed version stream: int64 "
-        "`[kind, from_version, to_version, head_version, body_nbytes]` "
-        "(40 B) + body, one message (§11.2); to a FLAG_CHUNKED "
-        "subscription: self-describing 7-word chunk messages (§11.8)",
-        "— (pushed version stream; a broken chain is recovered by "
-        "`DIFF_REQ`, not retransmission)"),
-    TagSpec(
-        "DIFF_REQ", 15, "cell", "server", "cell→s",
-        "int64 `[epoch, seq, have_version]` (24 B)",
-        "answered by a `DIFF` FULL frame at the current head (§11.2)"),
+        "opportunistically)"),
+    # ids 14 and 15 are retired (§11) and not reused
     TagSpec(
         "REDUCE", 16, "client", "client", "c→c",
         "int64 `[epoch, seq, chunk_idx, chunk_count, nfold]` (40 B) + "
@@ -220,9 +207,8 @@ class FlagSpec:
     """One negotiated INIT flag bit.
 
     ``requires``: bits that must be announced alongside or the server
-    refuses loudly.  ``refused_with``: ``(other, unless)`` — announcing
-    both ``name`` and ``other`` is refused unless ``unless`` is also
-    announced (``unless=None``: unconditionally).  ``active_requires`` /
+    refuses loudly.  ``refused_with``: announcing both ``name`` and one
+    of these is refused.  ``active_requires`` /
     ``off_with``: the *effective* posture — the feature silently
     negotiates off unless every ``active_requires`` bit is present, and
     whenever any ``off_with`` bit is present (never a refusal).
@@ -233,7 +219,7 @@ class FlagSpec:
     space: str  # "v3" (INIT v3/v5 flags word) | "v4" (shardctl announce)
     meaning: str
     requires: Tuple[str, ...] = ()
-    refused_with: Tuple[Tuple[str, Optional[str]], ...] = ()
+    refused_with: Tuple[str, ...] = ()
     active_requires: Tuple[str, ...] = ()
     off_with: Tuple[str, ...] = ()
     version_only: Optional[int] = None  # bit legal only in this INIT version
@@ -263,19 +249,14 @@ FLAGS: Tuple[FlagSpec, ...] = (
         "READONLY", 16, "v3",
         "READ-ONLY attach posture of the serving tier (§8): status-framed "
         "reads, no grad/push staging; announcing rank must be an expected "
-        "reader (or cell)",
+        "reader",
         requires=("FRAMED",)),
-    FlagSpec(
-        "SUBSCRIBE", 32, "v3",
-        "replica-cell attach (§11.1): the diff stream replaces reads; "
-        "announcing rank must be an expected cell",
-        requires=("READONLY",)),
+    # bit 5 (32) is retired (§11): no row, refused from every rank
     FlagSpec(
         "CHUNKED", 64, "v3",
-        "pipelined streaming transfers (§12) — or a chunk-framed "
-        "subscription (§11.8); travels only in the 48-byte v5 "
-        "announcement, which carries the chunk cut",
-        requires=("FRAMED",), refused_with=(("READONLY", "SUBSCRIBE"),),
+        "pipelined streaming transfers (§12); travels only in the "
+        "48-byte v5 announcement, which carries the chunk cut",
+        requires=("FRAMED",), refused_with=("READONLY",),
         version_only=5),
     FlagSpec(
         "SHARDCTL", 4, "v4",
@@ -285,6 +266,9 @@ FLAGS: Tuple[FlagSpec, ...] = (
 )
 
 FLAGS_BY_NAME: Dict[str, FlagSpec] = {f.name: f for f in FLAGS}
+#: bits of the v3/v5 flags word that were assigned once: no row above,
+#: never reused, and an announcement that carries one is refused.
+RETIRED_V3_BITS = 32
 V3_FLAGS: Tuple[FlagSpec, ...] = tuple(f for f in FLAGS if f.space == "v3")
 
 #: the refusal lattice in normal form: refuse when every flag in
@@ -293,10 +277,8 @@ V3_FLAGS: Tuple[FlagSpec, ...] = tuple(f for f in FLAGS if f.space == "v3")
 #: — an extracted rule not listed here, or a listed rule not enforced
 #: there, is a finding.
 REFUSALS: Set[Tuple[frozenset, str]] = {
-    (frozenset({"SUBSCRIBE"}), "READONLY"),
     (frozenset({"READONLY"}), "FRAMED"),
     (frozenset({"CHUNKED"}), "FRAMED"),
-    (frozenset({"CHUNKED", "READONLY"}), "SUBSCRIBE"),
 }
 
 #: effective-posture algebra (silent negotiate-off, never a refusal):
@@ -333,8 +315,7 @@ WIRE_MODULES: Tuple[WireModuleSpec, ...] = (
         constants={
             "HDR_BYTES": 16, "HDR_STALE_BYTES": 24,
             "FLAG_FRAMED": 1, "FLAG_HEARTBEAT": 2, "FLAG_STALENESS": 4,
-            "FLAG_TIMING": 8, "FLAG_READONLY": 16, "FLAG_SUBSCRIBE": 32,
-            "FLAG_CHUNKED": 64,
+            "FLAG_TIMING": 8, "FLAG_READONLY": 16, "FLAG_CHUNKED": 64,
             "TIMING_TAIL_WORDS": 3, "TIMING_TAIL_BYTES": 24,
             "ACK_TIMING_WORDS": 5,
             "CHUNK_HDR_BYTES": 32, "CHUNK_ACK_WORDS": 3,
@@ -375,22 +356,6 @@ WIRE_MODULES: Tuple[WireModuleSpec, ...] = (
         },
     ),
     WireModuleSpec(
-        "cells/wire.py",
-        constants={
-            "DIFF_HDR_WORDS": 5, "DIFF_HDR_BYTES": 40,
-            "DIFF_FULL": 0, "DIFF_DELTA": 1,
-            "DIFF_REQ_WORDS": 3, "HEAD_ECHO_WORDS": 3,
-            "DIFF_CHUNK_HDR_WORDS": 7, "DIFF_CHUNK_HDR_BYTES": 56,
-        },
-        packers={
-            "pack_diff": 5, "pack_diff_chunks": 7, "diff_req": 3,
-            "head_echo": 3,
-        },
-        parsers={
-            "parse_diff": 5, "parse_diff_chunk": 7, "parse_diff_req": 3,
-        },
-    ),
-    WireModuleSpec(
         "agg/wire.py",
         constants={
             "RD_HDR_WORDS": 5, "RD_HDR_BYTES": 40, "RD_ACK_WORDS": 4,
@@ -428,7 +393,6 @@ class Outcome:
     staleness: bool = False
     timing: bool = False
     readonly: bool = False
-    subscribe: bool = False
     chunked: bool = False
     shardctl: bool = False
 
@@ -444,23 +408,22 @@ def flag_names(flags: int, space: str = "v3") -> Set[str]:
 
 
 def negotiate(version: int, flags: int = 0, *, reader_rank: bool = False,
-              cell_rank: bool = False, serves_readers: bool = False,
-              serves_cells: bool = False, sc_server: bool = False,
+              serves_readers: bool = False, sc_server: bool = False,
               splittable_rule: bool = True) -> Outcome:
     """The registry's verdict for one INIT announcement.
 
-    ``reader_rank``/``cell_rank``: the announcing rank's membership in
-    the server's expected reader/cell sets.  ``serves_readers``/
-    ``serves_cells``: whether the server is configured with a serving
-    tier at all (shardctl excludes it).  ``sc_server``: the server is
-    already shardctl (a legacy announcement is then refused).
+    ``reader_rank``: the announcing rank's membership in the server's
+    expected reader set.  ``serves_readers``: whether the server is
+    configured with a serving tier at all (shardctl excludes it).
+    ``sc_server``: the server is already shardctl (a legacy
+    announcement is then refused).
     """
 
     def refuse(reason: str) -> Outcome:
         return Outcome(False, reason)
 
     if version == 4:
-        if serves_readers or serves_cells:
+        if serves_readers:
             return refuse("shardctl excludes the serving tier")
         if not flags & FLAGS_BY_NAME["FRAMED"].bit:
             return refuse("shardctl requires FLAG_FRAMED")
@@ -475,11 +438,11 @@ def negotiate(version: int, flags: int = 0, *, reader_rank: bool = False,
     if version in (1, 2):
         if reader_rank:
             return refuse("reader rank must announce FLAG_READONLY")
-        if cell_rank:
-            return refuse("cell rank must announce FLAG_SUBSCRIBE")
         return Outcome(True)
     if version not in (3, 5):
         return refuse(f"unknown INIT version {version}")
+    if flags & RETIRED_V3_BITS:
+        return refuse("flag bit 5 is retired (§11)")
 
     names = flag_names(flags, "v3")
     # version <-> bit coupling (CHUNKED travels only in v5, which exists
@@ -495,24 +458,23 @@ def negotiate(version: int, flags: int = 0, *, reader_rank: bool = False,
                                                          r[1])):
         if ante <= names and missing not in names:
             return refuse(f"{'+'.join(sorted(ante))} requires {missing}")
+    for f in V3_FLAGS:
+        clash = names & set(f.refused_with)
+        if f.name in names and clash:
+            return refuse(f"{f.name} with {'+'.join(sorted(clash))}")
     # rank-posture membership (role model, not bit lattice)
-    ro, sub = "READONLY" in names, "SUBSCRIBE" in names
-    if sub and not cell_rank:
-        return refuse("FLAG_SUBSCRIBE from a non-cell rank")
-    if cell_rank and not sub:
-        return refuse("cell rank must announce FLAG_SUBSCRIBE")
-    if ro and not sub and not reader_rank:
+    ro = "READONLY" in names
+    if ro and not reader_rank:
         return refuse("FLAG_READONLY from a non-reader rank")
     if reader_rank and not ro:
         return refuse("reader rank must announce FLAG_READONLY")
-    if "CHUNKED" in names and not sub and not splittable_rule:
+    if "CHUNKED" in names and not splittable_rule:
         return refuse("FLAG_CHUNKED needs an element-wise (splittable) rule")
 
     out = Outcome(True)
     out.framed = "FRAMED" in names
     out.heartbeat = "HEARTBEAT" in names
     out.readonly = ro
-    out.subscribe = sub
     out.chunked = "CHUNKED" in names
     for feature, (need, off) in EFFECTIVE.items():
         active = (feature in names
@@ -749,8 +711,8 @@ def _check_tags_module(src: SourceFile) -> List[Finding]:
 
 def _flag_resolver(neg_fn: ast.AST):
     """Build a resolver mapping expressions inside ``_negotiate`` to v3
-    flag names, via the function's own aliases: ``sub = bool(flags &
-    FLAG_SUBSCRIBE)`` name aliases, ``self._framed[crank] = bool(flags &
+    flag names, via the function's own aliases: ``ro = bool(flags &
+    FLAG_READONLY)`` name aliases, ``self._framed[crank] = bool(flags &
     FLAG_FRAMED)`` attribute aliases, and direct ``flags & FLAG_X``
     tests."""
     name_alias: Dict[str, str] = {}
@@ -884,16 +846,6 @@ def _defines_param_client(tree: ast.Module) -> bool:
                for node in ast.walk(tree))
 
 
-def _declares_wire_names(spec: WireModuleSpec, src: SourceFile) -> bool:
-    """Is this file plausibly the registry's wire module — i.e. does it
-    declare any of the spec's constants or pack/parse functions?"""
-    consts = _module_consts(src.tree)
-    if any(name in consts for name in spec.constants):
-        return True
-    fns = _top_functions(src.tree)
-    return any(name in fns for name in (*spec.packers, *spec.parsers))
-
-
 def _check_negotiation(src: SourceFile) -> List[Finding]:
     """MT-S604/MT-S605 over ``ParamServer._negotiate``: the INIT length
     dispatch must accept exactly the schema's versions, and the pure
@@ -1010,13 +962,7 @@ def check(files: List[SourceFile]) -> List[Finding]:
     for src in files:
         rel = src.rel
         for spec in WIRE_MODULES:
-            # Scoped to files that declare at least one registry name:
-            # ownership-discipline fixtures reuse a wire-module path
-            # suffix (e.g. cells/wire.py) to pick up the declared pool
-            # disciplines without carrying the full frame vocabulary.
-            # The real module always declares some of them, so any
-            # single deletion/drift still fails conformance.
-            if rel.endswith(spec.suffix) and _declares_wire_names(spec, src):
+            if rel.endswith(spec.suffix):
                 findings += _check_wire_module(spec, src)
         if rel.endswith("ps/tags.py"):
             findings += _check_tags_module(src)
@@ -1075,9 +1021,7 @@ def render_flag_table() -> str:
             req.append(f"the v{f.version_only} announcement")
         if f.space == "v4":
             req.append("a v4 announcement")
-        refused = ", ".join(
-            f"`{other}`" + (f" (unless `{unless}`)" if unless else "")
-            for other, unless in f.refused_with) or "—"
+        refused = ", ".join(f"`{o}`" for o in f.refused_with) or "—"
         off = []
         for need in f.active_requires:
             off.append(f"missing `{need}`")
@@ -1141,8 +1085,8 @@ def emit_docs(doc_path, check: bool = False) -> List[str]:
 #: Transition: (state, action, tag, peer, next_state, opts) with action
 #: in {"send", "recv", "tau"} (tau transitions use tag for the label and
 #: peer "").  opts: "expects" (ack tag this send awaits before the role
-#: may rest at a terminal state), "drop"/"dup" (fault toggles the
-#: protocol claims to tolerate on this hop).  Tags are message labels in
+#: may rest at a terminal state), "dup" (a fault toggle the protocol
+#: claims to tolerate on this hop).  Tags are message labels in
 #: the model: wire tags verbatim, plus MAP_UPDATE kinds (RETIRE, DONE,
 #: RETIRED, PREEMPT) spelled out — the §7.2 directive word is what
 #: distinguishes them on the one MAP_UPDATE channel.
@@ -1274,46 +1218,6 @@ HANDSHAKES: Tuple[dict, ...] = (
                     ("awaiting", "recv", "DONE", "server", "done", {}),
                     ("deciding", "tau", "leave_to_failover", "", "done",
                      {}),
-                ],
-            },
-        },
-    },
-    {
-        "name": "subscribe",
-        "doc": "the diff stream (§11): FULL on attach, XOR deltas after "
-               "every commit (drop-tolerated — DIFF_REQ resync is the "
-               "recovery path), stop like any client",
-        "channel_cap": 2,
-        "roles": {
-            "cell": {
-                "start": "attach", "terminal": ["done"],
-                "transitions": [
-                    ("attach", "send", "INIT", "server", "syncing", {}),
-                    ("syncing", "recv", "DIFF_FULL", "server", "installed",
-                     {}),
-                    ("syncing", "recv", "DIFF_DELTA", "server", "syncing",
-                     {}),
-                    ("installed", "recv", "DIFF_DELTA", "server",
-                     "installed", {}),
-                    ("installed", "tau", "gap_detected", "", "resync", {}),
-                    ("resync", "send", "DIFF_REQ", "server", "syncing",
-                     {}),
-                    ("installed", "send", "STOP", "server", "done", {}),
-                ],
-            },
-            "server": {
-                "start": "wait", "terminal": ["done"],
-                "transitions": [
-                    ("wait", "recv", "INIT", "cell", "seeding", {}),
-                    ("seeding", "send", "DIFF_FULL", "cell", "streaming",
-                     {}),
-                    ("streaming", "tau", "commit", "", "delta_ready", {}),
-                    ("delta_ready", "send", "DIFF_DELTA", "cell",
-                     "streaming", {"drop": True}),
-                    ("streaming", "recv", "DIFF_REQ", "cell", "seeding",
-                     {}),
-                    ("streaming", "recv", "STOP", "cell", "done", {}),
-                    ("delta_ready", "recv", "STOP", "cell", "done", {}),
                 ],
             },
         },

@@ -151,8 +151,6 @@ class NativeTransportLib:
         self._lib.mt_waiting.argtypes = [ctypes.c_void_p]
         self._lib.mt_wire_ns.restype = ctypes.c_uint64
         self._lib.mt_wire_ns.argtypes = [ctypes.c_void_p, ctypes.c_int32]
-        self._lib.mt_xor_bytes.restype = None
-        self._lib.mt_xor_bytes.argtypes = [ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int64]
 
     def mt_api_version(self):
         """Build stamp of the compiled library; must equal MT_API_VERSION in the generated bindings or the loader refuses the .so with a rebuild message."""
@@ -262,7 +260,7 @@ class NativeTransportLib:
         return self._lib.mt_pool_start(nthreads)
 
     def mt_pool_submit(self, pool, kind, a, b, c, d, n, aux):
-        """Enqueue one pure job (kinds: 1 int8-enc, 2 int8-dec, 3 bf16-enc, 4 bf16-dec, 5 xor, 6 fold-f32, 7 copy); returns a handle > 0, or 0 when the pool is closing or the job is malformed. Buffers must stay alive until completion (zero-copy rule; the Job object holds the references)."""
+        """Enqueue one pure job (kinds: 1 int8-enc, 2 int8-dec, 3 bf16-enc, 4 bf16-dec, 6 fold-f32, 7 copy; 5 is retired); returns a handle > 0, or 0 when the pool is closing or the job is malformed. Buffers must stay alive until completion (zero-copy rule; the Job object holds the references)."""
         a, _a_len = _as_pointer(a)
         b, _b_len = _as_pointer(b)
         c, _c_len = _as_pointer(c)
@@ -333,10 +331,3 @@ class NativeTransportLib:
     def mt_wire_ns(self, ctx, which):
         """The endpoint's totals while timing, ns, cumulative: which == 0, inside the copies into peers' rings; 1, inside the copies out of the own rings and the memcpy that hands an assembled message over; 2, inside progress() and that memcpy (less the two copies: the cost of polling)."""
         return self._lib.mt_wire_ns(ctx, which)
-
-    def mt_xor_bytes(self, a, b, out, n):
-        """out[i] = a[i] ^ b[i] over n bytes (cells DELTA frame XOR), word-at-a-time. Bit-identical to np.bitwise_xor."""
-        a, _a_len = _as_pointer(a)
-        b, _b_len = _as_pointer(b)
-        out, _out_len = _as_pointer(out)
-        return self._lib.mt_xor_bytes(a, b, out, n)

@@ -1281,28 +1281,12 @@ void mt_codec_bf16_decode(const void* vwire, uint64_t n, void* vout) {
 
 // -- data-plane kernels for the worker pool ----------------------------------
 //
-// Byte-wise XOR delta (cells FrameHistory DELTA production and apply) and
-// the fused f32 add-fold (agg interior-node per-chunk fold).  Both are
-// single-pass replacements for multi-pass numpy pipelines; both must stay
-// bit-identical to the numpy reference (tests/test_pool.py parity suite):
-// XOR trivially is, and the fold keeps numpy's association order
+// The fused f32 add-fold (agg interior-node per-chunk fold): a
+// single-pass replacement for a multi-pass numpy pipeline that must stay
+// bit-identical to the numpy reference (tests/test_pool.py parity suite).
+// It keeps numpy's association order
 // ((own[i] + c0[i]) + c1[i]) + ... element-wise with -ffp-contract=off,
 // so no FMA ever merges an add pair the serial path keeps separate.
-
-void mt_xor_bytes(const void* va, const void* vb, void* vout, int64_t n) {
-  const uint8_t* a = static_cast<const uint8_t*>(va);
-  const uint8_t* b = static_cast<const uint8_t*>(vb);
-  uint8_t* out = static_cast<uint8_t*>(vout);
-  int64_t i = 0;
-  for (; i + 8 <= n; i += 8) {
-    uint64_t x, y;
-    memcpy(&x, a + i, 8);
-    memcpy(&y, b + i, 8);
-    x ^= y;
-    memcpy(out + i, &x, 8);
-  }
-  for (; i < n; ++i) out[i] = (uint8_t)(a[i] ^ b[i]);
-}
 
 // vptrs: uint64_t[nchildren] raw child-buffer addresses, each f32[n].
 // The serial agg fold does copyto(acc, own) then one `acc += child` pass
@@ -1333,7 +1317,7 @@ int64_t mt_api_version(void) { return 17008; }
 
 // -- worker-pool data plane --------------------------------------------------
 //
-// A persistent native thread pool so chunk encode/decode/XOR/fold runs off
+// A persistent native thread pool so chunk encode/decode/fold runs off
 // the Python critical thread (otherwise one interpreter lock caps it).  Jobs
 // are pure: owned input pointers -> owned output pointers, all regions
 // disjoint per job, per-block int8 EF state (the residual slice) carried in
@@ -1347,7 +1331,7 @@ enum PoolJobKind {
   kJobInt8Dec = 2,
   kJobBf16Enc = 3,
   kJobBf16Dec = 4,
-  kJobXor = 5,
+  // 5 is retired (it was the byte-wise XOR) and not reused
   kJobFoldF32 = 6,
   kJobCopy = 7,
 };
@@ -1357,7 +1341,7 @@ struct PoolJob {
   uint64_t handle = 0;
   int32_t kind = 0;
   const void* a = nullptr;  // primary input
-  const void* b = nullptr;  // secondary input (residual / xor rhs / ptrs)
+  const void* b = nullptr;  // secondary input (residual / ptrs)
   void* c = nullptr;        // primary output
   void* d = nullptr;        // secondary output (int8 codes)
   int64_t n = 0;
@@ -1393,9 +1377,6 @@ void pool_run(const PoolJob& job) {
       break;
     case kJobBf16Dec:
       mt_codec_bf16_decode(job.a, (uint64_t)job.n, job.c);
-      break;
-    case kJobXor:
-      mt_xor_bytes(job.a, job.b, job.c, job.n);
       break;
     case kJobFoldF32:
       mt_fold_f32(job.a, job.ptrs.data(), (int32_t)job.aux, job.c, job.n);
@@ -1479,7 +1460,6 @@ int32_t mt_pool_threads(void* vpool) {
 //   INT8_ENC  a=x f32[n], b=residual f32[n]|NULL, c=scales, d=codes
 //   INT8_DEC  a=scales, b=codes, c=out f32[n]
 //   BF16_ENC  a=x f32[n], c=wire u16[n]      BF16_DEC a=wire, c=out
-//   XOR       a, b, c = out, n bytes
 //   FOLD_F32  a=own f32[n], b=u64[aux] child addresses (copied), c=out
 //   COPY      a=src, c=dst, n bytes
 // Buffers must stay alive until the job completes (zero-copy rule; the
@@ -1488,7 +1468,10 @@ uint64_t mt_pool_submit(void* vpool, int32_t kind, const void* a,
                         const void* b, void* c, void* d, int64_t n,
                         int64_t aux) {
   auto* pool = static_cast<Pool*>(vpool);
-  if (pool == nullptr || kind <= 0 || kind >= kJobKinds || n < 0) return 0;
+  if (pool == nullptr || kind <= 0 || kind >= kJobKinds || n < 0 ||
+      kind == 5 /* retired */) {
+    return 0;
+  }
   PoolJob job;
   job.kind = kind;
   job.a = a;

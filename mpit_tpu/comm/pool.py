@@ -1,6 +1,6 @@
 """Worker-pool submission seam for the chunk data plane.
 
-The GIL cap: every per-chunk encode, decode, XOR delta and tree fold
+The GIL cap: every per-chunk encode, decode and tree fold
 ran serially on the one Python thread, so chunk k's CPU work could
 never overlap chunk k+1's wire time.  This module is
 the narrow seam between the protocol code and the native worker pool in
@@ -58,7 +58,7 @@ KIND_INT8_ENC = 1
 KIND_INT8_DEC = 2
 KIND_BF16_ENC = 3
 KIND_BF16_DEC = 4
-KIND_XOR = 5
+# 5 is retired (it was the byte-wise XOR) and not reused
 KIND_FOLD_F32 = 6
 KIND_COPY = 7
 
@@ -68,7 +68,6 @@ KIND_NAMES = {
     KIND_INT8_DEC: "int8_dec",
     KIND_BF16_ENC: "bf16_enc",
     KIND_BF16_DEC: "bf16_dec",
-    KIND_XOR: "xor",
     KIND_FOLD_F32: "fold_f32",
     KIND_COPY: "copy",
 }
@@ -230,16 +229,6 @@ class WorkerPool:
         h = self._submit(KIND_COPY, src, None, dst, None, int(src.nbytes), 0)
         return Job(self, (h,), (src, dst))
 
-    def submit_xor(self, a: np.ndarray, b: np.ndarray,
-                   out: np.ndarray) -> Job:
-        """``out = a ^ b`` byte-wise (cells DELTA production/apply)."""
-        self._check_open()
-        if self._pool is None:
-            self.xor_sync(a, b, out)
-            return _done_job()
-        h = self._submit(KIND_XOR, a, b, out, None, int(a.nbytes), 0)
-        return Job(self, (h,), (a, b, out))
-
     def submit_fold_f32(self, own: np.ndarray,
                         children: Sequence[np.ndarray],
                         out: np.ndarray) -> Job:
@@ -290,23 +279,15 @@ class WorkerPool:
 
     # -- synchronous entries (atomic sections / no-yield windows) -------------
     #
-    # These never queue: declared atomic sections (cell-install-atomic,
-    # ps-read-path-helpers) may not block on a pool condvar, so inside
-    # them the kernels run inline on the calling thread.
+    # These never queue: a declared atomic section
+    # (ps-read-path-helpers) may not block on a pool condvar, so inside
+    # it the kernels run inline on the calling thread.
 
     def encode_sync(self, codec, x, wire, residual=None) -> None:
         codec.encode_into(x, wire, residual=residual)
 
     def decode_sync(self, codec, wire, out) -> None:
         codec.decode_into(wire, out)
-
-    def xor_sync(self, a: np.ndarray, b: np.ndarray,
-                 out: np.ndarray) -> None:
-        lib = self._lib if self._lib is not None else codec_mod._native()
-        if lib is not None:
-            lib.mt_xor_bytes(a, b, out, int(a.nbytes))
-        else:
-            np.bitwise_xor(a, b, out=out)
 
     def fold_f32_sync(self, own: np.ndarray,
                       children: Sequence[np.ndarray],

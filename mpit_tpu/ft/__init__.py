@@ -52,7 +52,6 @@ from mpit_tpu.ft.wire import (
     FLAG_FRAMED,
     FLAG_HEARTBEAT,
     FLAG_READONLY,
-    FLAG_SUBSCRIBE,
     FLAG_STALENESS,
     FLAG_TIMING,
     HDR_BYTES,
@@ -96,7 +95,7 @@ __all__ = [
     "Scenario", "TrafficPhase", "TrafficEvent",
     "HDR_BYTES", "HDR_STALE_BYTES",
     "FLAG_FRAMED", "FLAG_HEARTBEAT", "FLAG_READONLY", "FLAG_STALENESS",
-    "FLAG_SUBSCRIBE", "FLAG_TIMING", "FLAG_CHUNKED",
+    "FLAG_TIMING", "FLAG_CHUNKED",
     "CHUNK_HDR_BYTES", "CHUNK_ACK_WORDS", "CHUNK_ACK_TIMING_WORDS",
     "CHUNK_REPLY_WORDS",
     "chunk_elems_for", "chunk_spans", "chunk_stride", "chunk_hdr_bytes",

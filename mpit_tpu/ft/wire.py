@@ -86,19 +86,9 @@ FLAG_TIMING = 8
 #: a rejoining incarnation.
 FLAG_READONLY = 16
 
-#: INIT v3 flags bit5: SUBSCRIBE attach (the multi-cell serving fabric,
-#: docs/PROTOCOL.md §11).  The announcing peer is a *replica cell*: a
-#: follower serving rank that will never send GRAD/PARAM_PUSH and never
-#: request PARAM reads — instead the server streams it the committed
-#: version sequence on the DIFF channel (full encoded snapshot on
-#: attach, then per-version deltas out of the snapshot cache), and the
-#: cell serves READ-ONLY reader traffic from its own installed copy
-#: under a declared staleness bound.  Extends the §8 READ-ONLY
-#: handshake: FLAG_SUBSCRIBE requires FLAG_READONLY | FLAG_FRAMED, and
-#: the subscriber's HEARTBEAT beacons are answered with a 3-word
-#: [epoch, seq, head_version] echo so its view of the head version
-#: never depends on the (possibly delayed) diff stream itself.
-FLAG_SUBSCRIBE = 32
+#: INIT v3 flags bit5 (value 32) is retired and not to be reused: it
+#: was FLAG_SUBSCRIBE of the multi-cell fabric (docs/PROTOCOL.md §11).
+#: A server refuses an announcement that carries it, from any rank.
 
 #: INIT v3 flags bit6: pipelined streaming transfers (docs/PROTOCOL.md
 #: §12).  A GRAD / PARAM / PARAM_PUSH body ships as K independent chunk

@@ -289,24 +289,6 @@ def validate_dump(path_or_obj) -> Dict[str, object]:
             raise ValueError(
                 "scheduler_stall dump resources.top_tasks must be "
                 "[name, cpu_us] pairs")
-    if reason in ("cell_failover", "cell_lag_shed"):
-        # Cell-fabric postmortems (PROTOCOL.md §11): a dead or lagging
-        # cell must leave its version window behind — which version was
-        # being served, against which head, under which bound — or the
-        # dump explains nothing about the staleness envelope crossed.
-        extra = obj.get("extra")
-        if not isinstance(extra, dict):
-            raise ValueError(f"{reason} dump has no extra payload")
-        window = extra.get("window")
-        if not isinstance(window, dict) or "version" not in window:
-            raise ValueError(
-                f"{reason} dump extra.window must be a dict carrying "
-                "the cell's version window (version key required)")
-        if reason == "cell_lag_shed" and not {"head",
-                                              "max_lag"} <= set(window):
-            raise ValueError(
-                "cell_lag_shed dump extra.window must carry head + "
-                "max_lag alongside version")
     return {
         "reason": obj["reason"],
         "rank": obj.get("rank"),

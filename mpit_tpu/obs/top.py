@@ -209,12 +209,8 @@ def _rank_row(rank: int, sample: Optional[dict],
         # Elastic membership (PROTOCOL.md §9): the controller rank
         # publishes the live server count; everyone else reads 0.
         "gang_size": int(metric_sum(m, "mpit_gang_size", role="server")),
-        # Multi-cell fabric (PROTOCOL.md §11): a cell rank publishes
-        # its serving version and lag vs the upstream head; readers
-        # attached ride the shared mpit_ps_readers gauge, and reader
-        # ranks publish their fail-over/GOODBYE reroutes.
-        "cell_version": int(metric_sum(m, "mpit_cell_version")),
-        "cell_lag": int(metric_sum(m, "mpit_cell_lag")),
+        # Serving tier (PROTOCOL.md §8, §9.4): a server publishes the
+        # readers attached to it, a reader its GOODBYE reroutes.
         "readers": int(metric_sum(m, "mpit_ps_readers")),
         "reroutes": int(metric_sum(m, "mpit_ps_reader_reroutes_total")),
         # Aggregation columns (PROTOCOL.md §13): a reducing client rank
@@ -317,7 +313,7 @@ def render_autoscale_line(section: Optional[dict]) -> str:
 _COLUMNS = ("rank", "role", "ops", "ops/s", "p99ms", "slo", "busy%",
             "sendq", "conns",
             "busy", "stale", "retry", "evict", "shards", "busy_s", "mapv",
-            "gang", "cellv", "lag", "rdrs", "rrt", "fanin", "late", "fb",
+            "gang", "rdrs", "rrt", "fanin", "late", "fb",
             "pool", "cpu%", "putl%", "runq", "infl")
 
 
@@ -348,11 +344,6 @@ def render_table(rows: List[Dict[str, object]]) -> str:
             f"{row['shard_busy_s']:.2f}" if row["shard_busy_s"] else "-",
             str(row["map_version"]) if row["map_version"] else "-",
             str(row["gang_size"]) if row.get("gang_size") else "-",
-            # Cell-fabric columns (§11): only meaningful on cell /
-            # reader rows — everyone else shows '-'.
-            (str(row["cell_version"]) if row.get("role") == "cell"
-             else "-"),
-            (str(row["cell_lag"]) if row.get("role") == "cell" else "-"),
             str(row["readers"]) if row.get("readers") else "-",
             str(row["reroutes"]) if row.get("reroutes") else "-",
             # Aggregation columns (§13): only meaningful on reducing

@@ -45,7 +45,7 @@ import os
 import time
 from collections import deque
 from dataclasses import dataclass
-from typing import Any, Deque, Dict, Generator, List, Optional, Tuple
+from typing import Deque, Dict, Generator, List, Optional, Tuple
 
 import numpy as np
 
@@ -94,23 +94,12 @@ def serve_reply(epoch: int, seq: int, status: int, word: int) -> np.ndarray:
 
 
 def parse_serve_header(payload) -> Tuple[int, int, int, int]:
-    """(epoch, seq, status, word) from a reader reply header message.
-    Accepts both the 4-word §8 form and the 5-word §11 cell form (whose
-    extra word — the serving rank's known head version — is read by
-    :func:`serve_head`)."""
+    """(epoch, seq, status, word) from a reader reply header message."""
     words = np.frombuffer(bytes(payload), np.int64)
-    if words.size not in (4, 5):
+    if words.size != 4:
         raise ValueError(
-            f"reader reply header must be 4 or 5 int64 words, got "
-            f"{words.size}")
+            f"reader reply header must be 4 int64 words, got {words.size}")
     return int(words[0]), int(words[1]), int(words[2]), int(words[3])
-
-
-def serve_head(payload) -> Optional[int]:
-    """The head-version word of a 5-word cell OK reply (None on the
-    4-word direct-server form — a training server IS the head)."""
-    words = np.frombuffer(bytes(payload), np.int64)
-    return int(words[4]) if words.size == 5 else None
 
 
 @dataclass(frozen=True)
@@ -172,13 +161,6 @@ class ReaderClient:
         scheduler: Optional[Scheduler] = None,
         codec: Optional[str] = None,
         ft: Optional[FTConfig] = None,
-        cells: "Optional[Dict[int, list]]" = None,  # fabric routing
-        #   (§11.5): {launch server rank -> its replica cell ranks}.
-        #   Reads route to one cell per shard by consistent hashing and
-        #   fail over to ring siblings instead of exhausting the retry
-        #   budget against a dead cell.
-        failover_after: int = 2,  # deadline-exceeded attempts against
-        #   one cell before failing over to the next ring sibling.
         layout: "Optional[List[Shard]]" = None,  # static weighted cut
         #   (mpit_tpu.lm): one Shard per server, identical to the cut
         #   the gang's ParamClients announced — servers reject a reader
@@ -213,38 +195,11 @@ class ReaderClient:
         self._half_pair: Dict[int, bool] = {}
         #: last snapshot version observed per server (reads must be
         #: monotone: the serving tier never goes back in time).  Keyed
-        #: by the *physical* serving rank — each cell's version stream
-        #: is monotone on its own; a fail-over lands on a fresh key.
+        #: by the *physical* serving rank: a successor's version stream
+        #: is its own.
         self.versions: Dict[int, int] = {}
-        #: per *launch server slot* (§11.5), from the last completed
-        #: read: the served snapshot version and the observed lag (the
-        #: serving rank's stamped head minus that version; 0 against a
-        #: direct server — it is the head).  The pair is the client's
-        #: staleness envelope: "I hold version v, at most ``lag``
-        #: behind what existed when it was served".
-        self.read_versions: Dict[int, int] = {}
-        self.lags: Dict[int, int] = {}
         self.monotone = True
         self.reads_done = 0
-        # Fabric routing (§11.5): a consistent-hash ring of replica
-        # cells per launch server slot.  The primary cell is the ring
-        # lookup of this reader's rank; deadline exhaustion against a
-        # cell marks it down and fails over to the next live sibling
-        # with a FRESH attempt budget — RetryExhausted is reserved for
-        # "no live cell remains".
-        self._rings: Dict[int, Any] = {}
-        self._failover_after = max(int(failover_after), 1)
-        self.failovers = 0
-        if cells:
-            from mpit_tpu.cells.ring import CellRing
-
-            for srank in self.sranks:
-                fabric = cells.get(srank)
-                if not fabric:
-                    raise ValueError(
-                        f"fabric routing needs cells for every server "
-                        f"slot; server {srank} has none")
-                self._rings[srank] = CellRing(fabric)
         # Server retirement (§9.4): a GOODBYE reply re-routes this
         # attach slot to the named successor instead of burning the
         # retry budget against a disappearing rank.  ``_route`` maps
@@ -268,10 +223,6 @@ class ReaderClient:
             "mpit_ft_retries_total", rank=rank)
         self._m_hb = self.metrics.counter(
             "mpit_ft_heartbeats_sent_total", rank=rank)
-        #: observed staleness per completed read (§11.5): stamped head
-        #: minus served version — 0 against a direct server.
-        self._m_lag = self.metrics.histogram(
-            "mpit_serve_read_lag", rank=rank)
         if obs_enabled():
             register_status_provider(f"reader{rank}", self._status_section)
         # Per-server FIFO op pumps (the ParamClient pattern): reads to
@@ -289,14 +240,9 @@ class ReaderClient:
             "codec": self.codec.name,
             "epoch": self.ft.epoch,
             "versions": {str(s): v for s, v in self.versions.items()},
-            "lags": {str(s): v for s, v in self.lags.items()},
             "monotone": self.monotone,
             "reads_done": self.reads_done,
             "busy_honored": int(self._m_busy.value),
-            "fabric": {str(s): {"route": self._route.get(s, s),
-                                "live_cells": r.live}
-                       for s, r in self._rings.items()},
-            "failovers": self.failovers,
         }
 
     @property
@@ -335,31 +281,17 @@ class ReaderClient:
         flags = FLAG_FRAMED | FLAG_READONLY | (
             FLAG_HEARTBEAT if self.ft.heartbeat_s > 0 else 0)
         self._flags = flags
-        attached = set()
         for srank, shard in zip(self.sranks, self.shards):
             self._announce[srank] = shard
             cinfo = init_v3(shard.offset, shard.size, self.codec.wire_id,
                             self.ft.epoch, flags)
-            ring = self._rings.get(srank)
-            if ring is None:
-                targets = [srank]
-            else:
-                # Fabric (§11.5): announce to EVERY replica cell of the
-                # slot — attach is one message, and it buys lazy STOP
-                # accounting plus instant fail-over (the sibling already
-                # holds our negotiation) — then route reads to the
-                # ring's pick for this reader.
-                targets = ring.members
-                self._route[srank] = ring.lookup(self.rank)
-            for target in targets:
-                self.sched.spawn(
-                    aio_send(self.transport, cinfo, target, tags.INIT,
-                             live=self.live, deadline=self._op_deadline()),
-                    name=f"send_init:{target}",
-                )
-            attached.update(targets)
+            self.sched.spawn(
+                aio_send(self.transport, cinfo, srank, tags.INIT,
+                         live=self.live, deadline=self._op_deadline()),
+                name=f"send_init:{srank}",
+            )
         self.wait()
-        self._attached = attached
+        self._attached = set(self.sranks)
         self._started = True
         self._hb_last = 0.0
 
@@ -454,7 +386,6 @@ class ReaderClient:
                         return None
                     epoch, aseq, status, word = parse_serve_header(raw)
                     if status == OK:
-                        head = serve_head(raw)
                         self._half_pair[target] = True
                         body = yield from aio_recv(
                             self.transport, target, tags.PARAM,
@@ -467,16 +398,7 @@ class ReaderClient:
                             span.mark("decode")
                             self._decode(body, out)
                             self._note_version(target, word)
-                            # Observed staleness (§11.5): the serving
-                            # rank's stamped head minus the version we
-                            # got — surfaced per read so clients can
-                            # assert their own envelope.
-                            lag = (max(head - word, 0)
-                                   if head is not None else 0)
-                            self.read_versions[srank] = word
-                            self.lags[srank] = lag
-                            self._m_lag.observe(lag)
-                            span.note(version=word, lag=lag)
+                            span.note(version=word)
                             span.end("ok")
                             return word
                         continue  # stale pair (earlier attempt): dropped
@@ -518,23 +440,10 @@ class ReaderClient:
             except (DeadlineExceeded, RuntimeError) as exc:
                 # DeadlineExceeded: the target never answered in time.
                 # RuntimeError: the transport's fail-loud raise-once on
-                # a torn link (a SIGKILLed cell) — both are the same
+                # a torn link (a killed server) — both are the same
                 # retryable fact: this target is not answering.
                 last = exc
                 attempt += 1
-                ring = self._rings.get(srank)
-                if (ring is not None and attempt >= self._failover_after
-                        and len(ring.live) > 1):
-                    # Fabric fail-over (§11.5): a dead cell must cost a
-                    # reroute, not the retry budget — mark it down,
-                    # take the next ring sibling with a FRESH attempt
-                    # budget.  Bounded: once no live sibling remains,
-                    # the ordinary exhaustion path below is the truth.
-                    target = self._route.get(srank, srank)
-                    yield from self._cell_failover(srank, target, ring)
-                    attempt = 0
-                    span.mark("reroute")
-                    continue
                 if attempt >= self._retry.attempts:
                     span.end("exhausted")
                     self._flight_dump(
@@ -566,50 +475,8 @@ class ReaderClient:
         self._m_reroutes.inc()
         self._route[srank] = succ
         self._goodbyes.add(old)
-        ring = self._rings.get(srank)
-        if ring is not None:
-            # A retiring cell leaves the ring for good; the successor
-            # may be a fresh (autoscaled) cell outside it — the route
-            # override wins either way.
-            ring.mark_down(old)
         self.log.warning("server %d retiring: re-attaching its shard "
                          "reads to server %d", old, succ)
-        if succ not in self._attached:
-            shard = self._announce[srank]
-            cinfo = init_v3(shard.offset, shard.size, self.codec.wire_id,
-                            self.ft.epoch, self._flags)
-            yield from aio_send(self.transport, cinfo, succ, tags.INIT,
-                                live=self.live,
-                                deadline=self._op_deadline())
-            self._attached.add(succ)
-
-    def _cell_failover(self, srank: int, dead: int, ring):
-        """Fail a read over to the next live ring sibling after the
-        current cell stopped answering (§11.5): mark it down, route to
-        the ring's next pick for this reader, re-announce if it never
-        saw our INIT, and leave a ``cell_failover`` postmortem naming
-        the version window we crossed it with."""
-        ring.mark_down(dead)
-        try:
-            succ = ring.lookup(self.rank)
-        except LookupError:
-            raise RetryExhausted(
-                f"cell {dead} dead and no live sibling remains for "
-                f"server slot {srank}", self.failovers, None)
-        self._m_reroutes.inc()
-        self.failovers += 1
-        self._route[srank] = succ
-        self.log.warning(
-            "cell %d stopped answering: failing shard %d reads over to "
-            "cell %d", dead, srank, succ)
-        self._flight.record("cell_failover", rank=self.rank,
-                            dead=dead, successor=succ)
-        self._flight.dump(
-            "cell_failover",
-            window={"version": self.versions.get(dead, -1),
-                    "lag": self.lags.get(srank, 0),
-                    "dead": dead, "successor": succ},
-            server_slot=srank)
         if succ not in self._attached:
             shard = self._announce[srank]
             cinfo = init_v3(shard.offset, shard.size, self.codec.wire_id,
@@ -698,9 +565,9 @@ class ReaderClient:
         return dict(self.versions)
 
     def _stop_op(self, srank: int):
-        """One best-effort STOP: a target that died (a SIGKILLed cell)
-        must not fail the reader's shutdown — the serving side's lease
-        machinery owns counting a dead reader out."""
+        """One best-effort STOP: a target that died must not fail the
+        reader's shutdown — the serving side's lease machinery owns
+        counting a dead reader out."""
         try:
             yield from aio_send(self.transport, tags.EMPTY, srank,
                                 tags.STOP, live=self.live,
@@ -710,10 +577,8 @@ class ReaderClient:
 
     def stop(self) -> None:
         # STOP goes to every rank that saw our INIT and is still
-        # serving: in fabric mode that is every replica cell (each one
-        # counts every expected reader), otherwise wherever each slot
-        # is served *now*.  A retired rank already counted us out when
-        # it said GOODBYE (§9.4).
+        # serving, and wherever each slot is served *now*.  A retired
+        # rank already counted us out when it said GOODBYE (§9.4).
         targets = (self._attached | set(self._targets())) - self._goodbyes
         for srank in sorted(targets):
             self._enqueue(srank, self._stop_op(srank), "send_stop")

@@ -76,7 +76,6 @@ from mpit_tpu.aio import (
 from mpit_tpu.comm import codec as codec_mod
 from mpit_tpu.comm import pool as comm_pool
 from mpit_tpu.comm.transport import Transport
-from mpit_tpu.cells import wire as _cellwire
 from mpit_tpu.ft import (
     ACK_TIMING_WORDS,
     CHUNK_ACK_TIMING_WORDS,
@@ -87,7 +86,6 @@ from mpit_tpu.ft import (
     FLAG_HEARTBEAT,
     FLAG_READONLY,
     FLAG_STALENESS,
-    FLAG_SUBSCRIBE,
     FLAG_TIMING,
     HDR_BYTES,
     STALE,
@@ -133,6 +131,10 @@ from mpit_tpu.shardctl.migrate import ShardSlot
 from mpit_tpu.shardctl.shardmap import ShardMap
 from mpit_tpu.utils.logging import get_logger
 
+
+#: Bit 5 of the INIT v3/v5 flags word: retired, refused from every rank
+#: (``ft/wire.py``).
+_FLAG_BIT5_RETIRED = 32
 
 #: XLA:CPU takes host memory under ``jnp.asarray`` as it stands only on
 #: this boundary; anything else it copies into a buffer of its own.
@@ -226,16 +228,6 @@ class ParamServer:
         preempt: "Optional[Any]" = None,  # ft.elastic.PreemptionNotice —
         #                                   checkpoint-on-notice + PREEMPT
         #                                   report when it fires (§9.3)
-        cell_ranks: Optional[list] = None,  # multi-cell serving fabric
-        #                          (§11): replica cells that SUBSCRIBE to
-        #                          this server's committed version stream
-        #                          and serve READ-ONLY traffic from their
-        #                          own installed copy.  Not protocol
-        #                          clients: no grads, no reads — one diff
-        #                          stream each.
-        cell_history: int = 16,  # encoded frame versions kept per codec
-        #                          for delta production; a cell further
-        #                          behind resyncs with a FULL frame.
         dplane: "Optional[_dphbm.PlaneConfig]" = None,  # device-resident
         #                          data plane (mpit_tpu.dplane): shard +
         #                          rule state live as (mesh-sharded) HBM
@@ -259,19 +251,6 @@ class ParamServer:
                 f"reader_ranks {sorted(self._reader_set & set(self.cranks))}"
                 " overlap client_ranks — a rank is a writer or a reader,"
                 " not both")
-        # Multi-cell serving fabric (§11): subscriber cells are a third
-        # role — like readers they are outside the client phases (lease
-        # slot, lazy attach, stop accounting) but they receive the
-        # pushed diff stream instead of requesting reads.
-        self.cells = list(cell_ranks or [])
-        self._cell_set = set(self.cells)
-        overlap = self._cell_set & (set(self.cranks) | self._reader_set)
-        if overlap:
-            raise ValueError(
-                f"cell_ranks {sorted(overlap)} overlap client/reader "
-                "ranks — a rank is a writer, a reader, or a cell, never "
-                "two of them")
-        self._cell_keep = int(cell_history)
         self.serve_cfg = (serve if serve is not None
                           else _psserve.ServeConfig.from_env())
         self.transport = transport
@@ -306,7 +285,7 @@ class ParamServer:
         # rejoin/eviction so stale loops abort), framed/heartbeat flags
         # from INIT v3, and the reply staging the framed paths need.
         self.ft = ft if ft is not None else FTConfig.from_env()
-        self.leases = LeaseRegistry(self.cranks + self.readers + self.cells,
+        self.leases = LeaseRegistry(self.cranks + self.readers,
                                     ttl_s=self.ft.lease_ttl_s)
         self.dedup = DedupTable()
         self._framed: Dict[int, bool] = {}
@@ -341,17 +320,9 @@ class ParamServer:
         self._chunk_rx_push: Dict[int, np.ndarray] = {}
         self._chunk_asm: Dict[int, np.ndarray] = {}
         self._chunk_apply_cache: Dict[Tuple[str, int], Callable] = {}
-        _members = self.cranks + self.readers + self.cells
+        _members = self.cranks + self.readers
         self._gen: Dict[int, int] = {c: 0 for c in _members}
         self._svc_live: Dict[int, int] = {c: 0 for c in _members}
-        # Diff-stream producer state (§11.2): SUBSCRIBE postures, the
-        # last version shipped per cell (-1 = owes a FULL frame), one
-        # in-flight push flag per cell (FIFO per channel), and the
-        # per-codec encoded frame history deltas are drawn from.
-        self._subscribe: Dict[int, bool] = {}
-        self._cell_sent: Dict[int, int] = {}
-        self._cell_push_live: Dict[int, bool] = {}
-        self._cell_hist: Dict[str, _cellwire.FrameHistory] = {}
         self._param_send: Dict[int, np.ndarray] = {}
         self._ack_send: Dict[int, np.ndarray] = {}
         self._req_buf: Dict[int, np.ndarray] = {}
@@ -413,13 +384,6 @@ class ParamServer:
         self._m_ckpts = _m.counter("mpit_ps_ckpts_written_total", rank=_r)
         self._m_busy = _m.counter("mpit_ps_busy_replies_total", rank=_r)
         self._m_readers = _m.gauge("mpit_ps_readers", rank=_r)
-        self._m_cells = _m.gauge("mpit_ps_cells", rank=_r)
-        self._m_diff_full = _m.counter("mpit_ps_diffs_sent_total",
-                                       rank=_r, kind="full")
-        self._m_diff_delta = _m.counter("mpit_ps_diffs_sent_total",
-                                        rank=_r, kind="delta")
-        self._m_diff_chunks = _m.counter("mpit_ps_diff_chunks_sent_total",
-                                         rank=_r)
         self._m_evictions = _m.counter("mpit_ft_evictions_total", rank=_r)
         self._m_sc_nacks = _m.counter("mpit_shardctl_nacks_sent_total",
                                       rank=_r)
@@ -494,13 +458,6 @@ class ParamServer:
             "map_version": getattr(self.smap, "version", None),
             "owned_shards": sorted(self._slots),
             "readers": int(self._m_readers.value),
-            "cells": {
-                str(c): {
-                    "state": self.leases.state(c),
-                    "sent_version": self._cell_sent.get(c, -1),
-                }
-                for c in self.cells
-            },
             "busy_replies": int(self._m_busy.value),
             "retired": self.retired,
             "retiring_to": self._serve_successor,
@@ -685,26 +642,13 @@ class ParamServer:
                 "must travel together (docs/PROTOCOL.md §12.1)")
         # READ-ONLY attach (serving tier, §8): the posture is a property
         # of the *rank role*, so a reader announcing as a writer (or
-        # vice versa) is a misconfiguration, caught here loudly.  The
-        # SUBSCRIBE posture (§11) extends it: a replica cell announces
-        # FLAG_READONLY | FLAG_SUBSCRIBE and receives the pushed diff
-        # stream instead of requesting reads.
+        # vice versa) is a misconfiguration, caught here loudly.
+        if flags & _FLAG_BIT5_RETIRED:
+            raise ValueError(
+                f"rank {crank} announced flag bit 5 (32), which no rank "
+                "may: it went with the multi-cell fabric (§11)")
         ro = bool(flags & FLAG_READONLY)
-        sub = bool(flags & FLAG_SUBSCRIBE)
-        if sub and not ro:
-            raise ValueError(
-                f"rank {crank} announced FLAG_SUBSCRIBE without "
-                "FLAG_READONLY — a cell is a read-only role (§11.1)")
-        if sub and crank not in self._cell_set:
-            raise ValueError(
-                f"rank {crank} announced FLAG_SUBSCRIBE but is not in "
-                f"this server's cell_ranks {sorted(self._cell_set)}")
-        if crank in self._cell_set and not sub:
-            raise ValueError(
-                f"rank {crank} is a cell rank but announced without "
-                "FLAG_SUBSCRIBE — cells attach with the subscribe "
-                "posture")
-        if ro and not sub and crank not in self._reader_set:
+        if ro and crank not in self._reader_set:
             raise ValueError(
                 f"rank {crank} announced FLAG_READONLY but is not in this "
                 f"server's reader_ranks {sorted(self._reader_set)}")
@@ -718,7 +662,6 @@ class ParamServer:
                 "FLAG_FRAMED — status-framed replies echo the request "
                 "identity")
         self._readonly[crank] = ro
-        self._subscribe[crank] = sub
         codec = codec_mod.by_wire_id(wire_id)
         if self._codec_pin is not None and codec.name != self._codec_pin:
             raise ValueError(
@@ -753,15 +696,14 @@ class ParamServer:
             )
         self._framed[crank] = bool(flags & FLAG_FRAMED)
         self._hb[crank] = bool(flags & FLAG_HEARTBEAT)
-        # Pipelined streaming (§12): a framed posture — the writer path,
-        # plus chunk-framed diff streams for SUBSCRIBE cells (§11.8).
+        # Pipelined streaming (§12): a framed posture of the writer path.
         if chunked:
-            if ro and not sub:
+            if ro:
                 raise ValueError(
                     f"rank {crank} announced FLAG_CHUNKED with the "
                     "READONLY posture — reads are served by the §8 "
                     "dispatcher; chunked streaming is the writer path "
-                    "(§12.1) or a chunk-framed subscription (§11.8)")
+                    "(§12.1)")
             if not self._framed[crank]:
                 raise ValueError(
                     f"client {crank} announced FLAG_CHUNKED without "
@@ -772,8 +714,7 @@ class ParamServer:
                     f"client {crank} announced chunk_elems={chunk_elems}; "
                     f"must be a positive multiple of {codec_mod.BLOCK} "
                     "(the codec block boundary, §12.2)")
-            if not sub:
-                self._require_splittable_rule(crank)
+            self._require_splittable_rule(crank)
         self._chunk[crank] = chunk_elems if chunked else 0
         # Staleness telemetry only rides the framed wire: the version
         # word extends the [epoch, seq] header, so a FLAG_STALENESS
@@ -841,11 +782,11 @@ class ParamServer:
         map replaces the per-pair (offset, size); owned shards become
         slots.  Shardctl implies framing — re-routable ops need the
         retry/dedup identity under them."""
-        if self.readers or self.cells:
+        if self.readers:
             raise ValueError(
-                "the serving tier (reader_ranks / cell_ranks) and "
-                "shardctl are mutually exclusive for now — readers and "
-                "cells address a static shard cut")
+                "the serving tier (reader_ranks) and shardctl are "
+                "mutually exclusive for now — readers address a static "
+                "shard cut")
         codec_id, epoch, flags, smap = _scwire.parse_init_v4(raw)
         if not (flags & FLAG_FRAMED):
             raise ValueError(
@@ -1121,7 +1062,7 @@ class ParamServer:
         of the shard, and a view still held when the donated apply is
         dispatched makes jax decline the donation.  Nothing is lost — a
         version that is about to be stale never hits again.  A view in
-        flight elsewhere (a reply task, a cell's frame history) keeps
+        flight elsewhere (a reply task) keeps
         pinning its buffer; that apply allocates, as every apply did."""
         self._snap_host = None
         self._snap_wire.clear()
@@ -1308,7 +1249,7 @@ class ParamServer:
         """Decode+apply one GRAD chunk — fused into one XLA call when
         that matches the unchunked rounding (:meth:`_chunk_fused_ok`);
         the version commits once per op (on the final chunk), so the
-        snapshot cache and diff stream keep op-granular versions.
+        snapshot cache keeps op-granular versions.
         ``span`` is the op's GRAD span: ``copy`` covers the owned copy
         (or host decode) of the chunk, ``dispatch`` the jitted call."""
         csize = hi - lo
@@ -1863,190 +1804,6 @@ class ParamServer:
         self.log.info("serving tier retiring: readers redirected to %d",
                       successor)
 
-    def _read_gate(self) -> "Optional[Tuple[int, int]]":
-        """Admission gate hook for the reader dispatcher: None grants;
-        a ``(status, word)`` pair answers the request with that status
-        instead (a lagging cell returns ``(BUSY, hint_us)``, §11.4).
-        The base server serves the head itself — never gated."""
-        return None
-
-    def _serve_ok_header(self, epoch: int, seq: int) -> np.ndarray:
-        """The OK reply header for a granted read.  A cell overrides
-        this to the 5-word form that also stamps its known head version
-        (readers derive their observed lag from it, §11.5)."""
-        return _psserve.serve_reply(epoch, seq, _scwire.OK,
-                                    self._snap_version)
-
-    # -- multi-cell serving fabric: the diff-stream producer (§11.2) ---------
-
-    def _update_cell_gauge(self) -> None:
-        live = sum(1 for c in self.cells
-                   if c in self._codecs and not self.leases.gone(c))
-        self._m_cells.set(live)
-
-    def _cell_frame(self, crank: int) -> "List[np.ndarray]":
-        """The next DIFF message sequence for one subscriber: a DELTA
-        against the last version shipped to it when the history still
-        holds that frame, else a FULL frame at the head — as ONE
-        message, or as chunk messages when the subscription negotiated
-        FLAG_CHUNKED (§11.8: a 640 MB resync must not head-of-line-
-        block the stream).  Either way the head frame comes out of (and
-        is recorded into) the same snapshot cache wire reads share — N
-        same-codec cells cost one encode and one XOR per committed
-        version, not N."""
-        codec = self._codecs[crank]
-        head = self._snap_version
-        wire = self._snapshot_wire(codec)
-        hist = self._cell_hist.get(codec.name)
-        if hist is None:
-            hist = _cellwire.FrameHistory(keep=self._cell_keep)
-            self._cell_hist[codec.name] = hist
-        hist.record(head, wire)
-        sent = self._cell_sent.get(crank, -1)
-        if 0 <= sent < head and hist.has(sent):
-            self._m_diff_delta.inc()
-            kind, from_v = _cellwire.DIFF_DELTA, sent
-            body = hist.delta(sent, head)
-        else:
-            self._m_diff_full.inc()
-            kind, from_v = _cellwire.DIFF_FULL, -1
-            body = wire
-        chunk_elems = self._chunk.get(crank, 0)
-        if chunk_elems:
-            msgs = _cellwire.pack_diff_chunks(kind, from_v, head, head,
-                                              body, 4 * chunk_elems)
-            self._m_diff_chunks.inc(len(msgs))
-            return msgs
-        return [_cellwire.pack_diff(kind, from_v, head, head, body)]
-
-    def _cell_push(self, crank: int, gen: int, frames: "List[np.ndarray]",
-                   push_live: Dict[int, bool]):
-        """One in-flight diff push to one cell (FIFO per cell: the next
-        frame waits until this one is accepted, so the stream coalesces
-        to head under backpressure instead of queueing every version).
-        A chunk-framed subscription ships the frame as its message
-        sequence on the same FIFO channel — `chunk`-marked per message.
-        A cell that dies mid-push costs this task, never the server."""
-        span = self._spans.op("DIFF", peer=crank, side="server",
-                              rank=self.rank)
-        try:
-            span.mark("send")
-            for i, frame in enumerate(frames):
-                if i:
-                    span.mark("chunk")
-                yield from aio_send(self.transport, frame, crank,
-                                    tags.DIFF, live=self.live,
-                                    abort=self._svc_abort(crank, gen))
-        except (RuntimeError, DeadlineExceeded) as exc:
-            self.log.debug("diff to cell %d dropped: %r", crank, exc)
-            span.end("aborted")
-            return
-        finally:
-            push_live[crank] = False
-        span.end("served")
-
-    def _cell_dispatcher(self):
-        """ONE task serves every subscriber cell (the §11 counterpart of
-        the reader dispatcher): probes attach/re-attach INITs, STOPs,
-        HEARTBEATs (renewing the lease and answering the 3-word head
-        echo — head knowledge must never ride the possibly-delayed DIFF
-        channel), DIFF_REQ resync requests, and pushes one diff per
-        cell whenever the committed version moved past what that cell
-        was last shipped."""
-        push_live: Dict[int, bool] = {c: False for c in self.cells}
-        self._cell_push_live = push_live
-        scan = 0
-        while self.live.on:
-            progressed = False
-            slot = scan & 7
-            for crank in self.cells:
-                attached = crank in self._codecs
-                slow_turn = (crank & 7) == slot
-                try:
-                    if ((not attached or slow_turn)
-                            and self.transport.iprobe(crank, tags.INIT)):
-                        payload = yield from self._dispatch_recv(
-                            crank, tags.INIT)
-                        codec = self._negotiate(crank, payload)
-                        self._gen[crank] += 1
-                        self.leases.rejoin(crank, self.leases.epoch(crank))
-                        self.leases.arm(crank, self.leases.epoch(crank),
-                                        heartbeats=self._hb.get(crank, False))
-                        self._alloc_client(crank, codec)
-                        self._cell_sent[crank] = -1  # owes a FULL frame
-                        self._update_cell_gauge()
-                        attached = True
-                        progressed = True
-                        self.log.info(
-                            "cell %d subscribed (epoch %d, gen %d, "
-                            "codec %s)", crank, self.leases.epoch(crank),
-                            self._gen[crank], codec.name)
-                    if not attached or self.leases.gone(crank):
-                        continue
-                    gen = self._gen[crank]
-                    if slow_turn and self.transport.iprobe(crank, tags.STOP):
-                        yield from self._dispatch_recv(crank, tags.STOP)
-                        self.leases.stop(crank)
-                        self._update_cell_gauge()
-                        progressed = True
-                        if self.leases.all_done():
-                            self.live.stop()
-                        continue
-                    while self.transport.iprobe(crank, tags.HEARTBEAT):
-                        beat = yield from self._dispatch_recv(
-                            crank, tags.HEARTBEAT, out=self._hb_buf[crank])
-                        if beat is None:
-                            break
-                        self._m_hb_seen.inc()
-                        self.leases.renew(crank, int(beat[0]))
-                        # Head echo (§11.3): the staleness bound's
-                        # ground truth rides the heartbeat channel.
-                        yield from aio_send(
-                            self.transport,
-                            _cellwire.head_echo(int(beat[0]), int(beat[1]),
-                                                self._snap_version),
-                            crank, tags.HEARTBEAT_ECHO, live=self.live,
-                            abort=self._svc_abort(crank, gen))
-                        progressed = True
-                    if self.transport.iprobe(crank, tags.DIFF_REQ):
-                        req = yield from self._dispatch_recv(
-                            crank, tags.DIFF_REQ)
-                        if req is not None:
-                            epoch, _seq, have = _cellwire.parse_diff_req(req)
-                            if epoch >= self.leases.epoch(crank):
-                                self.leases.renew(crank, epoch)
-                                # Chain broke at the cell: next push is
-                                # a FULL frame at head.
-                                self._cell_sent[crank] = -1
-                                self.log.info(
-                                    "cell %d requested resync (has "
-                                    "version %d, head %d)", crank, have,
-                                    self._snap_version)
-                        progressed = True
-                    if push_live[crank]:
-                        continue  # FIFO per cell: one diff in flight
-                    sent = self._cell_sent.get(crank, -1)
-                    if self.param is None or self._snap_version <= sent:
-                        continue
-                    frame = self._cell_frame(crank)
-                    push_live[crank] = True
-                    self._cell_sent[crank] = self._snap_version
-                    self.sched.spawn(
-                        self._cell_push(crank, gen, frame, push_live),
-                        name=f"cell_diff:{crank}")
-                    progressed = True
-                except RuntimeError:
-                    # Torn connection (fail-loud probe): the cell is
-                    # gone without a STOP — its lease evicts it, and a
-                    # restarted cell re-attaches via a fresh INIT.
-                    continue
-            scan += 1
-            if progressed:
-                yield EXEC
-            else:
-                if not (yield from aio_sleep(0.002, live=self.live)):
-                    return
-
     def _dispatch_recv(self, crank: int, tag: int, out=None):
         """Receive a message the dispatcher's probe already saw (fully
         assembled, so this completes without waiting on the peer)."""
@@ -2174,28 +1931,6 @@ class ParamServer:
             self.leases.stop(crank)
             self._update_reader_gauge()
             return
-        # Role-specific admission gate (§11.4): the base server never
-        # gates — a cell overrides this hook to shed reads while its
-        # installed version trails the head beyond max_lag (BUSY with a
-        # catch-up hint), which is what makes the staleness bound
-        # *enforced* rather than advisory.
-        # Declared atomic section `ps-read-gate-window` (MT-Y801): no
-        # scheduler yield between this gate and the stamped reply header
-        # — the (version, head) bound in the OK header is only exact
-        # because nothing can park the task inside this window.
-        gate = self._read_gate()
-        if gate is not None:
-            status, word = gate
-            self._m_busy.inc()
-            span.note(hint_us=word)
-            span.mark("send")
-            header = _psserve.serve_reply(epoch, seq, status, word)
-            reply_live[crank] = True
-            self.sched.spawn(
-                self._serve_reply(crank, gen, span, header, None, 0,
-                                  reply_live),
-                name=f"serve_gate:{crank}")
-            return
         nbytes = (self.size * np.dtype(self.dtype).itemsize
                   if codec.identity else codec.wire_nbytes(self.size))
         # An idle rank always grants (a frame larger than the whole
@@ -2216,11 +1951,14 @@ class ParamServer:
                                   reply_live),
                 name=f"serve_busy:{crank}")
             return
-        # No ``wait_apply`` split here: serving cells reuse this method
-        # verbatim (cells/cell.py) and have no apply to wait for.
+        # Declared atomic section `ps-read-snapshot-window` (MT-Y801):
+        # no scheduler yield between taking the frame and stamping the
+        # reply header — the version in the OK header is the frame's
+        # only because nothing can park the task inside this window.
         span.mark("snapshot")
         wire = self._snapshot_wire(codec)
-        header = self._serve_ok_header(epoch, seq)
+        header = _psserve.serve_reply(epoch, seq, _scwire.OK,
+                                      self._snap_version)
         self._serve_inflight_bytes += nbytes
         self._serve_inflight_reads += 1
         reply_live[crank] = True
@@ -3051,8 +2789,6 @@ class ParamServer:
                 self._release_client(crank)
                 if crank in self._reader_set:
                     self._update_reader_gauge()
-                if crank in self._cell_set:
-                    self._update_cell_gauge()
                 # Postmortem: the gang just lost a member — dump the
                 # recent-event ring + live task table (obs/flight.py;
                 # no-op when obs is disabled).
@@ -3084,8 +2820,8 @@ class ParamServer:
                 "epoch": self.leases.epoch(c),
             }
             for c in self._codecs
-            if c not in self._reader_set and c not in self._cell_set
-            # Readers and cells are excluded on purpose: they re-attach
+            if c not in self._reader_set
+            # Readers are excluded on purpose: they re-attach
             # through the perpetual listeners, so a restarted server
             # need not carry their negotiation.
         }
@@ -3370,11 +3106,6 @@ class ParamServer:
             # scheduler's task count stays O(in-flight replies).
             self.sched.spawn(self._reader_dispatcher(),
                              name="reader_dispatcher")
-        if self.cells:
-            # Multi-cell fabric (§11): ONE dispatcher pushes the diff
-            # stream to every subscriber cell.
-            self.sched.spawn(self._cell_dispatcher(),
-                             name="cell_dispatcher")
         if self.ft.server_rejoin:
             for crank in self.cranks:
                 self.sched.spawn(self._init_listener(crank),

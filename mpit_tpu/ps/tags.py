@@ -49,24 +49,8 @@ HEARTBEAT_ECHO = 13  # server -> client: int64 [epoch, seq, t_tx_echo,
 #                      client drains echoes opportunistically (iprobe in
 #                      ping/wait) to refresh its clock-offset estimator
 #                      while compute-bound; a lost echo costs nothing.
-#                      Subscriber (FLAG_SUBSCRIBE) beats get the 3-word
-#                      [epoch, seq, head_version] form instead — the
-#                      head announcement a replica cell's staleness
-#                      admission keys on (docs/PROTOCOL.md §11.3).
-DIFF = 14  # server -> cell: one snapshot-diff frame of the committed
-#            version stream (docs/PROTOCOL.md §11.2): int64
-#            [kind, from_version, to_version, head_version, body_nbytes]
-#            then the body bytes in the SAME message (message-atomic
-#            under fault injection).  kind FULL carries the whole
-#            encoded snapshot frame at to_version (attach/resync); kind
-#            DELTA carries the XOR of the to/from encoded frames — the
-#            cell reconstructs to_version's frame bit-exactly from its
-#            installed from_version copy.
-DIFF_REQ = 15  # cell -> server: int64 [epoch, seq, have_version] — the
-#                resync request.  Sent when the diff chain broke (a
-#                dropped DELTA: from_version != the installed version)
-#                or the cell fell beyond its resync horizon; the server
-#                answers with a FULL frame at the current head.
+# 14 and 15 are unassigned and not to be reused: they were DIFF and
+# DIFF_REQ of the multi-cell fabric (docs/PROTOCOL.md §11, retired).
 REDUCE = 16  # client -> client: one partial-gradient chunk frame of the
 #              hierarchical aggregation tree (docs/PROTOCOL.md §13):
 #              int64 [epoch, seq, chunk_idx, chunk_count, nfold] then
@@ -108,13 +92,6 @@ TAG_PAIRS = {
     "SHARD_PULL": ("server", "server"),
     "SHARD_STATE": ("server", "server"),
     "HEARTBEAT_ECHO": ("server", "client"),
-    # Multi-cell serving fabric (docs/PROTOCOL.md §11): a replica cell
-    # attaches to its upstream server like a client (SUBSCRIBE posture
-    # on INIT) but is its own role — the diff-stream rows live outside
-    # the binary client<->server model (like controller traffic) and
-    # are validated against this table + PROTOCOL.md (MT-P5xx).
-    "DIFF": ("server", "cell"),
-    "DIFF_REQ": ("cell", "server"),
     # Hierarchical aggregation (docs/PROTOCOL.md §13): reduction-tree
     # hops travel client<->client — like the server<->server shard
     # handoff, these rows live outside the binary client<->server role

@@ -32,7 +32,7 @@ def assign_devices(
     """Per-rank device environment, applied BEFORE a child imports jax:
     each rank of ``chip_owners`` (workers, a tester) gets a chip of its
     own and sees no other; every other rank (servers, controller,
-    readers, cells, spares) is pinned to ``JAX_PLATFORMS=cpu`` and never
+    readers, spares) is pinned to ``JAX_PLATFORMS=cpu`` and never
     initialises the TPU backend — a chip belongs to one process.  The
     map is a pure function of the roles, so a supervisor restart hands a
     worker the chip its predecessor had.

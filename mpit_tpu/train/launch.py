@@ -121,29 +121,6 @@ LAUNCH_DEFAULTS = TRAINER_DEFAULTS.merged(
     serve_interval_s=0.05,
     serve_budget_mb=64.0,
     serve_budget_reads=0,
-    # Multi-cell serving fabric (mpit_tpu.cells; docs/PROTOCOL.md §11):
-    # --cells N inserts N replica serving cells between the training
-    # roles and the readers.  Cells SUBSCRIBE to their upstream server's
-    # committed version stream (one diff stream each), serve the reader
-    # traffic under the cell_max_lag staleness bound, and readers route
-    # across the cells of each shard by consistent hashing, failing
-    # over to ring siblings on cell death (zero RetryExhausted while a
-    # sibling lives).  Requires serve_readers > 0 (someone to serve),
-    # ft_op_deadline_s > 0 and ft_heartbeat_s > 0 (cell leases + head
-    # echoes ride the beat channel), and N >= the server count (every
-    # shard needs a replica).
-    cells=0,
-    cell_max_lag=4,
-    # Cell subscription codec (ROADMAP item 3): the diff stream's XOR
-    # deltas ride the *encoded* domain, so an int8 subscription is ~4x
-    # cheaper per hop than fp32 — and bit-exact by the same induction
-    # (the cell installs the upstream's encoded frame byte-for-byte;
-    # readers decode exactly what a direct int8 read would).  Empty =
-    # default the fleet to int8; --cell_codec none opts out (e.g. a
-    # non-f32 dtype, which the quantizers refuse).  Fabric readers
-    # negotiate the same codec — a cell serves its subscription codec
-    # only (§11.1).
-    cell_codec="",
     # Elastic gangs (mpit_tpu.ft.elastic; docs/PROTOCOL.md §9): --elastic
     # composes shardctl + the supervisor into dynamic membership.
     # elastic_spares reserves that many joiner-server rank slots beyond
@@ -399,79 +376,11 @@ def _serve_vec_len(cfg: Config, rank: int) -> int:
     return int(flatten_module(module, rng, sample).w0.size)
 
 
-def cell_codec_for(cfg: Config) -> str:
-    """The cell fleet's subscription codec: ``--cell_codec`` when set,
-    else int8 — the XOR diff stream is ~4x cheaper in the int8 domain
-    and bit-exact by construction (§11.2), so compressed subscriptions
-    are the default and ``--cell_codec none`` is the opt-out.  Falls
-    back to 'none' for non-f32 dtypes (the quantizers refuse them)."""
-    from mpit_tpu.comm import codec as codec_mod
-
-    name = str(cfg.get("cell_codec", "") or "")
-    if not name:
-        dtype = str(cfg.get("dtype", "float32"))
-        name = "int8" if dtype == "float32" else "none"
-    codec_mod.get(name)  # unknown names fail at launch, not mid-gang
-    return name
-
-
-def cell_map_for(sranks: List[int], cell_ranks: List[int]) -> Dict[int, List[int]]:
-    """Round-robin assignment of replica cells to server slots: cell i
-    mirrors sranks[i % S], so every shard gets ceil(N/S) replicas and
-    siblings exist whenever N >= 2S (§11.5)."""
-    out: Dict[int, List[int]] = {s: [] for s in sranks}
-    for i, c in enumerate(cell_ranks):
-        out[sranks[i % len(sranks)]].append(c)
-    return out
-
-
-def run_cell(rank: int, sranks: List[int], cell_ranks: List[int],
-             reader_ranks: List[int], cfg: Config,
-             transport: Any) -> Dict[str, Any]:
-    """One replica serving cell (§11): subscribe to the assigned
-    upstream server's version stream, serve the fabric's readers under
-    the staleness bound, stop when every reader is terminal."""
-    from mpit_tpu.cells.cell import ServingCell
-    from mpit_tpu.shardctl import shardmap as _shardmap
-
-    log = get_logger("cell", rank)
-    cmap = cell_map_for(sranks, cell_ranks)
-    upstream = next(s for s, cs in cmap.items() if rank in cs)
-    vec_len = _serve_vec_len(cfg, rank)
-    smap = _shardmap.ShardMap.initial(vec_len, sranks)
-    shard = dict(zip(sranks, (e.shard for e in smap.entries)))[upstream]
-    cell = ServingCell(
-        rank, upstream, transport, reader_ranks,
-        offset=shard.offset, size=shard.size,
-        dtype=cfg.get("dtype", "float32"),
-        codec=cell_codec_for(cfg),
-        max_lag=int(cfg.get("cell_max_lag", 4)),
-        ft=ft_from_cfg(cfg),
-        serve=serve_cfg_for(cfg),
-    )
-    log.info("cell for upstream %d, shard (%d,%d), readers %s",
-             upstream, shard.offset, shard.size, reader_ranks)
-    cell.start()
-    return {
-        "role": "cell",
-        "upstream": upstream,
-        "version": cell.version,
-        "head": cell.head,
-        "params_served": cell.params_served,
-        "busy_replies": cell.busy_replies,
-        "diffs_installed": cell.diffs_installed,
-        "resyncs": cell.resyncs,
-        "lag_sheds": cell.lag_sheds,
-    }
-
-
 def run_reader(rank: int, sranks: List[int], cfg: Config,
-               transport: Any,
-               cell_ranks: Optional[List[int]] = None) -> Dict[str, Any]:
+               transport: Any) -> Dict[str, Any]:
     """One READ-ONLY reader rank (serve mode): attach, pull the current
     params ``serve_rounds`` times at ``serve_interval_s`` pacing, check
-    version monotonicity, stop.  With a cell fabric the reads route
-    across the replica cells instead of the training servers (§11.5)."""
+    version monotonicity, stop."""
     import numpy as np
 
     from mpit_tpu.ps import ReaderClient
@@ -479,13 +388,8 @@ def run_reader(rank: int, sranks: List[int], cfg: Config,
     log = get_logger("serve", rank)
     rc = ReaderClient(
         rank, sranks, transport,
-        # Fabric-routed readers negotiate the cells' subscription codec
-        # (a cell serves its subscription codec only, §11.1); direct
-        # readers keep the gang codec.
-        codec=(cell_codec_for(cfg) if cell_ranks
-               else str(cfg.get("codec", "") or "") or None),
+        codec=str(cfg.get("codec", "") or "") or None,
         ft=ft_from_cfg(cfg),
-        cells=(cell_map_for(sranks, cell_ranks) if cell_ranks else None),
         # --lm readers must announce the identical weighted cut the
         # writers announced (servers reject a disagreeing attach).
         layout=(lm_layout(cfg, len(sranks)) if int(cfg.get("lm", 0))
@@ -510,9 +414,6 @@ def run_reader(rank: int, sranks: List[int], cfg: Config,
         "busy_honored": rc.busy_honored,
         "retries": rc.retries,
         "versions": {str(k): v for k, v in rc.versions.items()},
-        "read_versions": {str(k): v for k, v in rc.read_versions.items()},
-        "lags": {str(k): v for k, v in rc.lags.items()},
-        "failovers": rc.failovers,
     }
 
 
@@ -635,10 +536,6 @@ def _run_role(rank: int, size: int, cfg: Config, transport: Any,
         if str(cfg.get("tester", "none")) != "none":
             raise ValueError("--lm and a tester rank are mutually "
                              "exclusive (the tester is MNIST-only)")
-        if int(cfg.get("cells", 0) or 0):
-            raise ValueError("--lm and --cells are not composed yet: the "
-                             "cell fabric derives the equal split, not "
-                             "the LM plan's weighted cut")
     # Under --elastic the transport spans the provisioned ceiling
     # (np0 + spares); roles split over the initial membership np0 and
     # ranks beyond it are joiner-server slots the controller may spawn.
@@ -648,12 +545,7 @@ def _run_role(rank: int, size: int, cfg: Config, transport: Any,
     ctl_rank: Optional[int] = None
     role_size = size
     n_readers = int(cfg.get("serve_readers", 0) or 0)
-    n_cells = int(cfg.get("cells", 0) or 0)
     reader_ranks: List[int] = []
-    cell_ranks: List[int] = []
-    if n_cells and not n_readers:
-        raise ValueError("--cells without --serve_readers: a cell fabric "
-                         "exists to serve readers")
     if n_readers:
         if sc_on:
             raise ValueError("serve_readers and shardctl are mutually "
@@ -664,18 +556,13 @@ def _run_role(rank: int, size: int, cfg: Config, transport: Any,
         if float(cfg.get("ft_op_deadline_s", 0) or 0) <= 0:
             raise ValueError("serve_readers needs --ft_op_deadline_s > 0: "
                              "BUSY recovery rides the FT retry machinery")
-        if n_cells and float(cfg.get("ft_heartbeat_s", 0) or 0) <= 0:
-            raise ValueError("--cells needs --ft_heartbeat_s > 0: cell "
-                             "leases and the head echoes ride the beat "
-                             "channel (§11.3)")
-        if size - n_readers - n_cells < 2:
+        if size - n_readers < 2:
             raise ValueError(
-                f"serve_readers={n_readers} + cells={n_cells} leave "
-                f"{size - n_readers - n_cells} role ranks; need >= 1 "
+                f"serve_readers={n_readers} leave "
+                f"{size - n_readers} role ranks; need >= 1 "
                 "server + >= 1 worker")
-        role_size = size - n_readers - n_cells
-        cell_ranks = list(range(role_size, role_size + n_cells))
-        reader_ranks = list(range(role_size + n_cells, size))
+        role_size = size - n_readers
+        reader_ranks = list(range(role_size, size))
     if sc_on:
         if str(cfg.get("tester", "none")) != "none":
             raise ValueError("shardctl and a tester rank are mutually "
@@ -692,16 +579,8 @@ def _run_role(rank: int, size: int, cfg: Config, transport: Any,
         role_size, cfg.get("master_freq", 2), cfg.get("tester", "none")
     )
     single_mode = str(cfg.opt).endswith("-single")
-    if cell_ranks and len(cell_ranks) < len(sranks):
-        raise ValueError(
-            f"cells={n_cells} < {len(sranks)} servers: every shard "
-            "needs at least one replica cell")
     if rank in reader_ranks:
-        return run_reader(rank, sranks, cfg, transport,
-                          cell_ranks=cell_ranks or None)
-    if rank in cell_ranks:
-        return run_cell(rank, sranks, cell_ranks, reader_ranks, cfg,
-                        transport)
+        return run_reader(rank, sranks, cfg, transport)
     if elastic_on and rank >= np0:
         # A spare slot the controller asked the supervisor to spawn:
         # a joiner server — no INIT rendezvous, shards arrive by
@@ -808,14 +687,8 @@ def _run_role(rank: int, size: int, cfg: Config, transport: Any,
             codec=str(cfg.get("codec", "") or "") or None,
             ft=ft,
             controller_rank=ctl_rank,
-            # With a cell fabric the readers attach to the CELLS, not
-            # here — the server's serving surface is one diff stream
-            # per assigned cell (§11.2).
-            reader_ranks=(None if cell_ranks else (reader_ranks or None)),
-            cell_ranks=(cell_map_for(sranks, cell_ranks)[rank]
-                        if cell_ranks else None),
-            serve=serve_cfg_for(cfg) if (reader_ranks and not cell_ranks)
-            else None,
+            reader_ranks=reader_ranks or None,
+            serve=serve_cfg_for(cfg) if reader_ranks else None,
             preempt=_maybe_preemption(cfg),
             dplane=(_dplane_cfg(cfg) if int(cfg.get("dplane", 0)) else None),
         )
@@ -914,14 +787,11 @@ def expected_role(rank: int, size: int, cfg: Config) -> str:
     if sc_on and rank == np0 - 1:
         return "controller"
     n_readers = int(cfg.get("serve_readers", 0) or 0)
-    n_cells = int(cfg.get("cells", 0) or 0)
     if n_readers and rank >= size - n_readers:
         return "reader"
-    if n_cells and rank >= size - n_readers - n_cells:
-        return "cell"
     try:
         sranks, _cranks, tester_rank = assign_roles(
-            np0 - 1 if sc_on else size - n_readers - n_cells,
+            np0 - 1 if sc_on else size - n_readers,
             int(cfg.get("master_freq", 2)),
             str(cfg.get("tester", "none")))
     except ValueError:
@@ -967,12 +837,11 @@ def device_env_overrides(cfg: Config, size: int) -> Dict[int, Dict[str, str]]:
     # The chip owners are the ranks that train or test.  Under shardctl
     # the last rank is the controller; under --elastic the split runs
     # over the initial membership (spare joiner slots are servers);
-    # readers and replica cells sit past the role ranks — host roles all.
+    # readers sit past the role ranks — host roles all.
     role_size = int(cfg.get("elastic_np0", 0) or 0) or size
     if bool(cfg.get("shardctl", False)) or bool(cfg.get("elastic", False)):
         role_size -= 1
     role_size -= int(cfg.get("serve_readers", 0) or 0)
-    role_size -= int(cfg.get("cells", 0) or 0)
     _sranks, cranks, tester = assign_roles(
         role_size, int(cfg.get("master_freq", 2)),
         str(cfg.get("tester", "none")))

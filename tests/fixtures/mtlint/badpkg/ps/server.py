@@ -1,7 +1,7 @@
 """Seeded yield-atomicity + ownership violations (mtlint fixture —
 parsed, never imported).  The rel-path suffix ``ps/server.py`` makes
 the declared disciplines in mpit_tpu.analysis.disciplines apply here:
-the read-gate window, the device-plane single-writer set and the
+the read window, the device-plane single-writer set and the
 chunk-apply donation seam."""
 
 import numpy as np
@@ -10,16 +10,16 @@ EXEC = "EXEC"
 
 
 class PS:
-    def _read_gate(self):
-        if self.lag > self.bound:
-            return None
-        return self.version
+    def _over_budget(self):
+        if self.inflight > self.budget:
+            return True
+        return False
 
     def _dispatch_read(self, req):
-        gate = self._read_gate()
-        # MT-Y801: scheduler yield inside the declared read-gate window.
+        wire = self._snapshot_wire()
+        # MT-Y801: scheduler yield inside the declared read window.
         yield EXEC
-        self.serve(gate, req)
+        self.serve(wire, req, self._over_budget())
 
     def steal_ticket(self):
         # MT-Y802: pops the device plane outside the declared writer set.

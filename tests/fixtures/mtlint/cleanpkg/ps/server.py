@@ -1,6 +1,6 @@
 """Clean yield-atomicity + ownership twins (mtlint fixture — zero
 findings).  Same declared-discipline surface as badpkg/ps/server.py:
-the read-gate window stays yield-free (``sched.spawn`` of a generator
+the read window stays yield-free (``sched.spawn`` of a generator
 is NOT a yield — spawn primes only the new task), the plane pop stays
 inside the single-writer closure even one helper down, and every buffer
 crossing the donation seam is provably owned."""
@@ -11,23 +11,14 @@ EXEC = "EXEC"
 
 
 class PS:
-    def _read_gate(self):
-        if self.lag > self.bound:
-            return None
-        return self.version
-
-    def _serve_ok_header(self, version):
-        return (version, len(self._wire))
-
     def _snapshot_wire(self):
         return self._wire
 
     def _dispatch_read(self, req):
-        gate = self._read_gate()
-        header = self._serve_ok_header(gate)
+        wire = self._snapshot_wire()
+        header = (self.version, len(wire))
         # spawn primes the NEW task one step; it does not yield this one.
-        self.sched.spawn(
-            self._serve_reply(req, header, self._snapshot_wire()))
+        self.sched.spawn(self._serve_reply(req, header, wire))
 
     def _serve_reply(self, req, header, wire):
         yield EXEC

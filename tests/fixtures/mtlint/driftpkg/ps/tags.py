@@ -14,7 +14,7 @@ MAP_UPDATE = 10
 SHARD_PULL = 11
 SHARD_STATE = 12
 HEARTBEAT_ECHO = 13
-DIFF = 14
+DIFF = 14  # MT-S603: a retired id (PROTOCOL.md §11) still assigned
 DIFF_REQ = 15
 REDUCE = 18  # MT-S603: schema says 16 — the id itself drifted
 REDUCE_ACK = 17
@@ -36,10 +36,10 @@ TAG_PAIRS = {
     "SHARD_PULL": ("server", "server"),
     "SHARD_STATE": ("server", "server"),
     "HEARTBEAT_ECHO": ("server", "client"),
-    "DIFF": ("server", "server"),  # MT-S603: schema says (server, cell)
+    "DIFF": ("server", "server"),  # MT-S603: a row for a retired tag
     "DIFF_REQ": ("cell", "server"),
     "REDUCE": ("client", "client"),
-    "REDUCE_ACK": ("client", "client"),
+    "REDUCE_ACK": ("client", "server"),  # MT-S603: schema says c -> c
     # MT-S603: SIDEBAND has a TAG_PAIRS row but no schema TagSpec
     "SIDEBAND": ("client", "server"),
 }

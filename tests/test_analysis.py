@@ -138,11 +138,11 @@ class TestSeededViolations:
         assert "nap_via_sched" in hits[0].message
 
     def test_atomic_section_yield_detected(self, bad):
-        # MT-Y801: a yield inside the declared read-gate window of the
+        # MT-Y801: a yield inside the declared read window of the
         # fixture ps/server.py — exactly one finding.
         hits = bad.get("MT-Y801", [])
         assert [(f.path, f.line) for f in hits] == [("ps/server.py", 21)]
-        assert "ps-read-gate-window" in hits[0].message
+        assert "ps-read-snapshot-window" in hits[0].message
 
     def test_single_writer_escape_detected(self, bad):
         # MT-Y802: steal_ticket pops the device plane outside the
@@ -155,12 +155,12 @@ class TestSeededViolations:
 
     def test_unowned_buffer_at_seam_detected(self, bad):
         # MT-D901: a frombuffer view reaches the donated chunk apply,
-        # plus the three pool-seam seeds (server scatter, client decode,
-        # cells XOR out) — one finding each, nothing else.
+        # plus the two pool-seam seeds (server scatter, client decode)
+        # — one finding each, nothing else.
         hits = bad.get("MT-D901", [])
         assert {(f.path, f.line) for f in hits} == {
             ("ps/server.py", 31), ("ps/server.py", 47),
-            ("ps/client.py", 12), ("cells/wire.py", 12)}
+            ("ps/client.py", 12)}
         assert all("frombuffer" in f.message for f in hits)
 
     def test_ownership_wrapper_dropped_detected(self, bad):
@@ -213,10 +213,13 @@ class TestSeededViolations:
         # the section.  The cleanpkg done()-under-lock and
         # join-outside-mutex twins must be silent
         # (test_clean_fixture_is_silent).
-        hits = [f for f in bad.get("MT-C204", [])
-                if f.path == "ps/server.py"]
-        assert [(f.path, f.line) for f in hits] == [("ps/server.py", 41)]
-        assert "ps-read-path-helpers" in hits[0].message
+        hits = sorted((f for f in bad.get("MT-C204", [])
+                       if f.path == "ps/server.py"), key=lambda f: f.line)
+        # the helper's own wait, and the read window that calls it
+        assert [(f.path, f.line) for f in hits] == [
+            ("ps/server.py", 19), ("ps/server.py", 41)]
+        assert "ps-read-snapshot-window" in hits[0].message
+        assert "ps-read-path-helpers" in hits[1].message
 
     def test_traced_branch_detected(self, bad):
         hits = bad.get("MT-J302", [])
@@ -400,7 +403,37 @@ class TestSchemaConformance:
         msgs = [f.message for f in drift.get("MT-S603", [])]
         assert any("REDUCE = 18" in m for m in msgs)
         assert any("SIDEBAND" in m for m in msgs)
-        assert any("TAG_PAIRS['DIFF']" in m for m in msgs)
+        assert any("TAG_PAIRS['REDUCE_ACK']" in m for m in msgs)
+        # a tree that still assigns the retired ids has drifted
+        assert any("tag DIFF = 14 is not in the schema" in m for m in msgs)
+        assert any("tag DIFF_REQ = 15 is not in the" in m for m in msgs)
+        assert any("TAG_PAIRS row 'DIFF' names a tag" in m for m in msgs)
+
+    def test_retired_surface_is_assigned_to_nothing(self):
+        # Tags 14 and 15 and bit 5 of the v3/v5 flags word went with the
+        # multi-cell fabric (PROTOCOL.md §11) and are not reused: no row
+        # in the registry, no constant in the modules it describes.
+        import ast
+
+        from mpit_tpu.analysis import schema
+
+        assert not {t.id for t in schema.TAGS} & {14, 15}
+        assert len(schema.TAGS) == 15
+        assert sorted(f.bit for f in schema.V3_FLAGS) == [
+            1, 2, 4, 8, 16, 64]
+        assert schema.RETIRED_V3_BITS == 32
+
+        def consts(rel):
+            tree = ast.parse((REPO / "mpit_tpu" / rel).read_text())
+            return {k: v for k, (v, _) in
+                    schema._module_consts(tree).items()}
+
+        assert not set(consts("ps/tags.py").values()) & {14, 15}
+        flags = {k: v for k, v in consts("ft/wire.py").items()
+                 if k.startswith("FLAG_")}
+        assert flags == {"FLAG_FRAMED": 1, "FLAG_HEARTBEAT": 2,
+                         "FLAG_STALENESS": 4, "FLAG_TIMING": 8,
+                         "FLAG_READONLY": 16, "FLAG_CHUNKED": 64}
 
     def test_clean_fixture_has_no_schema_findings(self):
         by = _by_rule(_findings(CLEANPKG))
@@ -478,10 +511,10 @@ class TestSchemaDocs:
         from mpit_tpu.analysis import schema
 
         # requires edges refuse
-        for bits, missing in ((["SUBSCRIBE"], "READONLY"),
-                              (["READONLY"], "FRAMED")):
-            out = schema.negotiate(3, schema.flag_bits(*bits),
-                                   reader_rank=True, cell_rank=True)
+        for version, bit, missing in ((3, "READONLY", "FRAMED"),
+                                      (5, "CHUNKED", "FRAMED")):
+            out = schema.negotiate(version, schema.flag_bits(bit),
+                                   reader_rank=True)
             assert not out.accepted and missing in out.reason
         # negotiate-off is silent, not a refusal
         out = schema.negotiate(3, schema.flag_bits("STALENESS"))
@@ -502,8 +535,7 @@ class TestModelCheck:
 
         results = modelcheck.check_all()
         assert {r.machine for r in results} == {
-            "init-grad-stop", "param-read", "retire", "preempt",
-            "subscribe"}
+            "init-grad-stop", "param-read", "retire", "preempt"}
         for r in results:
             assert r.clean, [v.render() for v in r.violations]
             assert r.states_fault_free > 0
@@ -544,7 +576,7 @@ class TestModelCheck:
         data = json.loads(report.read_text())
         assert data["schema"] == "mpit_modelcheck/1"
         assert data["clean"] is True
-        assert len(data["machines"]) == 5
+        assert len(data["machines"]) == 4
         assert data["total_states"] > 0
         bad = subprocess.run(
             [sys.executable, "-m", "mpit_tpu.analysis", "modelcheck",
@@ -578,15 +610,14 @@ class TestDisciplines:
         assert rep["violated"] == 0, [
             r for r in rep["disciplines"] if r["status"] == "violated"]
         assert rep["verified"] >= 6
-        # The minimum coverage the spec names: the §11 read-gate window,
+        # The minimum coverage the spec names: the §8 read window,
         # one single-writer per plane, and the donation seam.
         names = {r["name"] for r in rep["disciplines"]}
-        assert {"ps-read-gate-window", "dplane-single-writer",
+        assert {"ps-read-snapshot-window", "dplane-single-writer",
                 "aggplane-single-writer", "reader-single-writer",
-                "cell-stream-single-writer",
                 "chunk-apply-owned-seam",
-                "pool-client-decode-owned", "pool-server-scatter-owned",
-                "cells-xor-owned-out"} <= names
+                "pool-client-decode-owned",
+                "pool-server-scatter-owned"} <= names
 
     def test_cli_report_and_exit_codes(self, tmp_path):
         report = tmp_path / "disc.json"
@@ -633,13 +664,14 @@ class TestDisciplines:
         assert errs == []
         return files
 
-    def test_yield_in_read_gate_window_turns_tree_red(self, tmp_path):
+    def test_yield_in_read_window_turns_tree_red(self, tmp_path):
         from mpit_tpu.analysis import disciplines
 
         files = self._doctored(
             tmp_path, "ps/server.py",
-            "gate = self._read_gate()",
-            "gate = self._read_gate()\n        yield None")
+            "wire = self._snapshot_wire(codec)\n        header =",
+            "wire = self._snapshot_wire(codec)\n        yield None\n"
+            "        header =")
         findings = disciplines.check(files)
         assert any(f.rule == "MT-Y801" for f in findings), [
             f.render() for f in findings]
@@ -719,7 +751,7 @@ class TestDisciplines:
         files, _ = collect(CLEANPKG)
         graph = callgraph.build_graph(files)
         section = next(s for s in disciplines.SECTIONS
-                       if s.name == "ps-read-gate-window")
+                       if s.name == "ps-read-snapshot-window")
         assert disciplines.section_findings(graph, section) == []
 
 

@@ -3,7 +3,7 @@
 Two suites for the ISSUE 17 seam:
 
 * **Pooled-vs-serial bitwise parity.**  Every kernel the pool runs
-  (codec encode/decode, XOR, f32 fold, chunk gather/scatter) must
+  (codec encode/decode, f32 fold, chunk gather/scatter) must
   produce bytes identical to the serial fallback — per codec, per chunk
   geometry (BLOCK-aligned and tailed shards), per thread count, across
   seeds.  This is the determinism contract the module docstring pins:
@@ -111,22 +111,12 @@ class TestPooledSerialParity:
             pool.close()
             serial.close()
 
-    def test_xor_and_fold_bitwise(self, threads):
+    def test_fold_bitwise(self, threads):
         pool = pool_mod.WorkerPool(threads)
         serial = pool_mod.WorkerPool(0)
         try:
             for seed in SEEDS:
-                rng = np.random.default_rng(seed)
-                n = int(rng.integers(BLOCK, 4 * BLOCK))
-                a = rng.integers(0, 256, n).astype(np.uint8)
-                b = rng.integers(0, 256, n).astype(np.uint8)
-                out_p = np.empty(n, np.uint8)
-                out_s = np.empty(n, np.uint8)
-                pool.submit_xor(a, b, out_p).result()
-                serial.submit_xor(a, b, out_s).result()
-                assert out_p.tobytes() == out_s.tobytes()
-                assert out_s.tobytes() == np.bitwise_xor(a, b).tobytes()
-
+                n = int(np.random.default_rng(seed).integers(BLOCK, 4 * BLOCK))
                 own = rnd(n, seed)
                 children = [rnd(n, seed * 7 + k + 1) for k in range(3)]
                 f_p = np.empty(n, np.float32)
@@ -134,6 +124,10 @@ class TestPooledSerialParity:
                 pool.submit_fold_f32(own, children, f_p).result()
                 serial.submit_fold_f32(own, children, f_s).result()
                 assert f_p.tobytes() == f_s.tobytes()
+                ref = own.copy()
+                for child in children:  # numpy's association order
+                    ref += child
+                assert f_s.tobytes() == ref.tobytes()
         finally:
             pool.close()
             serial.close()
@@ -218,21 +212,18 @@ class TestLifecycle:
         pool = pool_mod.WorkerPool(0)
         pool.close()
         with pytest.raises(pool_mod.PoolClosedError):
-            pool.submit_xor(np.zeros(8, np.uint8), np.zeros(8, np.uint8),
-                            np.zeros(8, np.uint8))
+            pool.submit_copy(np.zeros(8, np.uint8), np.zeros(8, np.uint8))
 
     @pooled
     def test_close_drains_queued_jobs(self):
         pool = pool_mod.WorkerPool(1)
         n = 1 << 20
         a = np.random.default_rng(0).integers(0, 256, n).astype(np.uint8)
-        b = np.random.default_rng(1).integers(0, 256, n).astype(np.uint8)
         outs = [np.zeros(n, np.uint8) for _ in range(8)]
-        jobs = [pool.submit_xor(a, b, out) for out in outs]
+        jobs = [pool.submit_copy(a, out) for out in outs]
         pool.close()  # must drain, not drop
-        expect = np.bitwise_xor(a, b).tobytes()
         for out in outs:
-            assert out.tobytes() == expect
+            assert out.tobytes() == a.tobytes()
         for j in jobs:  # collecting after close is a no-op, not a hang
             j.result()
             assert j.done()
@@ -242,17 +233,16 @@ class TestLifecycle:
     @pooled
     def test_no_thread_leak_across_open_close_cycles(self):
         a = np.arange(4096, dtype=np.uint8)
-        b = a[::-1].copy()
         out = np.empty_like(a)
         # a first cycle warms lazy state (ctypes, obs registry)
         p = pool_mod.WorkerPool(2)
-        p.submit_xor(a, b, out).result()
+        p.submit_copy(a, out).result()
         p.close()
         before = _os_threads()
         for _ in range(32):
             p = pool_mod.WorkerPool(2)
             assert p.threads == 2
-            p.submit_xor(a, b, out).result()
+            p.submit_copy(a, out).result()
             p.close()
             p.close()  # idempotent
         assert _os_threads() == before
@@ -262,13 +252,12 @@ class TestLifecycle:
         pool = pool_mod.WorkerPool(1)
         try:
             n = 1 << 22
-            a = np.zeros(n, np.uint8)
-            b = np.ones(n, np.uint8)
-            out = np.empty(n, np.uint8)
-            job = pool.submit_xor(a, b, out)
+            a = np.ones(n, np.uint8)
+            out = np.zeros(n, np.uint8)
+            job = pool.submit_copy(a, out)
             while not job.done():  # scheduler-style poll, no result()
                 pass
-            assert out.tobytes() == np.bitwise_xor(a, b).tobytes()
+            assert out.tobytes() == a.tobytes()
         finally:
             pool.close()
 

@@ -163,11 +163,18 @@ def test_obs_off_makes_no_span_and_reads_only_the_plain_timer(monkeypatch,
             w, _loss = opt.step(w, TARGET)
         before = opt.sync_seconds
         reads = []
+        me = threading.get_ident()  # not a thread another test left behind
+
+        def counted(real, clock):
+            def read():
+                if threading.get_ident() == me:
+                    reads.append(clock)
+                return real()
+            return read
+
         for clock in ("monotonic", "monotonic_ns", "time", "perf_counter"):
-            real = getattr(time, clock)
-            monkeypatch.setattr(
-                time, clock,
-                lambda real=real, clock=clock: reads.append(clock) or real())
+            monkeypatch.setattr(time, clock,
+                                counted(getattr(time, clock), clock))
         for _ in range(2 * getattr(opt, "su", 1)):
             w, _loss = opt.step(w, TARGET)
         monkeypatch.undo()

@@ -1,5 +1,6 @@
 """The flag-lattice negotiation matrix (ISSUE 15): every one of the
-2^7 client flag sets × INIT v1–v5, against every server posture config,
+2^7 client flag words (six assigned bits and the retired bit 5) × INIT
+v1–v5, against every server posture config,
 checked against the wire-schema registry's negotiation oracle
 (mpit_tpu.analysis.schema.negotiate) — and one real wire op round-tripped
 for every combination the lattice declares legal.
@@ -22,13 +23,13 @@ Two layers:
 """
 
 import threading
+import time
 
 import numpy as np
 import pytest
 
 import mpit_tpu.ft.wire as ftw
 from mpit_tpu.analysis import schema
-from mpit_tpu.cells import wire as cellwire
 from mpit_tpu.comm.local import LocalRouter
 from mpit_tpu.ps import ParamServer, tags
 from mpit_tpu.shardctl import wire as scwire
@@ -42,8 +43,6 @@ CONFIGS = [
     ("plain", {}, {}),
     ("reader", {"reader_ranks": [1]}, {"reader_rank": True,
                                        "serves_readers": True}),
-    ("cell", {"cell_ranks": [1]}, {"cell_rank": True,
-                                   "serves_cells": True}),
 ]
 
 
@@ -62,13 +61,23 @@ def _announce_bytes(version: int, flags: int) -> bytes:
     raise AssertionError(version)
 
 
+def _await(cond, what="condition", deadline_s=30.0):
+    """Bounded wait — a leg that cannot proceed fails the test instead
+    of hanging it."""
+    t0 = time.monotonic()
+    while not cond():
+        assert time.monotonic() - t0 < deadline_s, \
+            f"{what} not within {deadline_s}s"
+        time.sleep(0.0005)
+
+
 def _fresh_server(server_kw, transport=None):
-    # client_ranks=[2] keeps rank 1 free for the reader/cell postures.
+    # client_ranks=[2] keeps rank 1 free for the reader posture.
     return ParamServer(0, [2], transport, rule="add", **server_kw)
 
 
 class TestNegotiationMatrix:
-    """All 2^7 flag sets × v1–v5 × 3 server postures: the real
+    """All 2^7 flag words × v1–v5 × 2 server postures: the real
     ``_negotiate`` must agree with the schema oracle cell for cell —
     refusals loud, acceptances with the exact effective posture."""
 
@@ -102,7 +111,6 @@ class TestNegotiationMatrix:
                 "staleness": server._stale_track.get(1, False),
                 "timing": server._timing.get(1, False),
                 "readonly": server._readonly.get(1, False),
-                "subscribe": server._subscribe.get(1, False),
                 "chunked": bool(server._chunk.get(1, 0)),
                 "shardctl": server._sc,
             }
@@ -113,6 +121,67 @@ class TestNegotiationMatrix:
                 mismatches.append(f"{ctx}: posture drift "
                                   f"(schema, server) = {diff}")
         assert not mismatches, "\n".join(mismatches)
+
+    @pytest.mark.parametrize("role", ["writer", "reader"])
+    @pytest.mark.parametrize("version", [3, 5])
+    def test_retired_bit5_is_refused(self, version, role):
+        """Bit 5 (32) of the v3/v5 flags word went with the multi-cell
+        fabric and is not reused: the oracle refuses every word that
+        carries it, naming the bit, and so does a running server that
+        is sent one, where it gives the same word without the bit the
+        oracle's verdict.  (The v4 word is another space — bit 2 there
+        is FLAG_SHARDCTL — and a v4 server reads nothing of it but
+        FRAMED and HEARTBEAT.)"""
+        retired = schema.RETIRED_V3_BITS
+        reader = role == "reader"
+        oracle_kw = ({"reader_rank": True, "serves_readers": True}
+                     if reader else {})
+        for flags in range(128):
+            if flags & retired:
+                want = schema.negotiate(version, flags, **oracle_kw)
+                assert not want.accepted and "bit 5" in want.reason, flags
+        base = schema.flag_bits(
+            "FRAMED", *(["READONLY"] if reader else []),
+            *(["CHUNKED"] if version == 5 else []))
+        for word in (base, base | retired):
+            want = schema.negotiate(version, word, **oracle_kw)
+            router = LocalRouter(3)
+            server = ParamServer(0, [2], router.endpoint(0), rule="add",
+                                 reader_ranks=[1] if reader else None,
+                                 admit_ranks=None if reader else [1])
+            failed = []
+
+            def run():
+                try:
+                    server.start()
+                except Exception as exc:  # noqa: BLE001 — the verdict
+                    failed.append(exc)
+
+            t = threading.Thread(target=run, daemon=True)
+            t.start()
+            try:
+                writer = router.endpoint(2)
+                writer.send(np.asarray([0, SIZE], np.int64), 0, tags.INIT)
+                writer.send(np.arange(SIZE, dtype=np.float32), 0,
+                            tags.PARAM_PUSH)
+                _recv(writer, 0, tags.PARAM_PUSH_ACK)
+                peer = router.endpoint(1)
+                peer.send(np.frombuffer(_announce_bytes(version, word),
+                                        np.int64), 0, tags.INIT)
+                if want.accepted:
+                    _await(lambda: 1 in server._codecs, "the attach")
+                    peer.send(tags.EMPTY, 0, tags.STOP)
+                else:
+                    _await(lambda: server.sched.errors or failed,
+                           "the refusal")
+                writer.send(tags.EMPTY, 0, tags.STOP)
+                _join(server, t)
+            finally:
+                server.live.stop()
+            assert bool(failed) == (not want.accepted), (word, failed)
+            if word & retired:
+                assert "ValueError" in str(failed[0])
+                assert "bit 5" in str(failed[0])
 
     def test_matrix_has_both_verdicts(self):
         """Sanity on the oracle itself: the v3 space must contain both
@@ -135,15 +204,9 @@ def _legal(version, **oracle_kw):
 
 
 def _recv(wire, src, tag, deadline_s=30.0):
-    """Bounded blocking receive returning the raw payload bytes —
-    a mis-framed leg fails the test instead of hanging it."""
-    import time
-
-    t0 = time.monotonic()
-    while not wire.iprobe(src, tag):
-        assert time.monotonic() - t0 < deadline_s, \
-            f"no message from {src} on tag {tag} within {deadline_s}s"
-        time.sleep(0.0005)
+    """Bounded blocking receive returning the raw payload bytes."""
+    _await(lambda: wire.iprobe(src, tag),
+           f"message from {src} on tag {tag}", deadline_s)
     return bytes(wire.recv(src, tag))
 
 
@@ -289,47 +352,6 @@ class TestLegalRoundTrips:
                                     np.float32)
                 np.testing.assert_array_equal(got, w0)
                 reader.send(tags.EMPTY, 0, tags.STOP)
-                writer.send(tags.EMPTY, 0, tags.STOP)
-                _join(server, t)
-            finally:
-                server.live.stop()
-
-    @pytest.mark.parametrize("version", [3, 5])
-    def test_cell_combos(self, version):
-        """SUBSCRIBE legs: the attach FULL frame of the diff stream
-        (§11.2; chunk-framed under v5, §11.8) for every legal cell flag
-        set."""
-        w0 = np.arange(SIZE, dtype=np.float32)
-        legal = _legal(version, cell_rank=True, serves_cells=True)
-        assert len(legal) == 8, (version, legal)
-        for flags in legal:
-            out = schema.negotiate(version, flags, cell_rank=True,
-                                   serves_cells=True)
-            router = LocalRouter(3)
-            server = ParamServer(0, [2], router.endpoint(0), rule="add",
-                                 cell_ranks=[1])
-            t = _run_server(server)
-            try:
-                writer = router.endpoint(2)
-                writer.send(np.asarray([0, SIZE], np.int64), 0, tags.INIT)
-                writer.send(w0, 0, tags.PARAM_PUSH)
-                _recv(writer, 0, tags.PARAM_PUSH_ACK)
-                cell = router.endpoint(1)
-                cell.send(np.frombuffer(
-                    _announce_bytes(version, flags), np.int64), 0,
-                    tags.INIT)
-                if out.chunked:
-                    (kind, _f, _to, _head, idx, cnt,
-                     body) = cellwire.parse_diff_chunk(
-                        _recv(cell, 0, tags.DIFF))
-                    assert (idx, cnt) == (0, 1)  # one block => one chunk
-                else:
-                    kind, _f, _to, _head, body = cellwire.parse_diff(
-                        _recv(cell, 0, tags.DIFF))
-                assert kind == cellwire.DIFF_FULL
-                np.testing.assert_array_equal(
-                    np.frombuffer(bytes(body), np.float32), w0)
-                cell.send(tags.EMPTY, 0, tags.STOP)
                 writer.send(tags.EMPTY, 0, tags.STOP)
                 _join(server, t)
             finally:
