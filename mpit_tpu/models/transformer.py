@@ -36,7 +36,7 @@ from jax.ad_checkpoint import checkpoint_name
 from mpit_tpu.ops.delta_rule import KDA_OUT, kda_scan
 from mpit_tpu.ops.flash_attention import (
     FLASH_LSE, FLASH_OUT, attention_reference, flash_attention,
-    flash_call_counts,
+    flash_call_counts, operand_dtype,
 )
 from mpit_tpu.ops.index_select import index_select
 from mpit_tpu.ops.short_conv import causal_depthwise_conv
@@ -68,12 +68,16 @@ def default_attn(causal: bool = True, use_flash: bool = True,
     is masked in the kernel and in the reference alike.  ``blockdiff
     (half, block)`` replaces the causal mask by the block-diffusion
     pass's (``ops/flash_attention.py``), in both alike; ``fn.flash``
-    says whether the callable is the kernel.
+    says whether the callable is the kernel and ``fn.precision`` what
+    it was made with.
     ``interpret`` reaches ``pallas_call``: None interprets everywhere
     but on a TPU (ops/tiles.py), False pins the Mosaic-compiled kernel.
     ``precision`` is the MXU input precision of the two attention
     products, forward and backward (``"highest"``: float32 inputs);
-    None is the backend's default, one bf16 pass on a TPU."""
+    None is the backend's default, one bf16 pass on a TPU, for which
+    the kernel's rules round q, k, v and dO to bf16 themselves
+    (``ops/flash_attention.py`` ``operand_dtype``): XLA folds that into
+    the transposes below and into the projections' outputs."""
 
     def fn(q, k, v, window=None, select=None, blockdiff=None):
         qh, kh, vh = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
@@ -95,7 +99,7 @@ def default_attn(causal: bool = True, use_flash: bool = True,
                 out = attention_reference(qh, kh, vh, causal=masked, **kw)
         return out.transpose(0, 2, 1, 3)
 
-    fn.flash = use_flash
+    fn.flash, fn.precision = use_flash, precision
     return fn
 
 
@@ -2217,7 +2221,8 @@ class SdarDecoder(nn.Module):
         if attn is None or getattr(attn, "flash", False):
             steps = flash_call_counts(
                 (b, self.n_heads, 2 * l, self.head_dim),
-                (b, self.kv_heads, 2 * l, self.head_dim), x.dtype,
+                (b, self.kv_heads, 2 * l, self.head_dim),
+                operand_dtype(x.dtype, getattr(attn, "precision", None)),
                 blockdiff=(l, block))
             for name, key in zip(SDAR_TILE_STATS, ("live", "nonempty")):
                 stats[name] = jnp.full((self.n_layers,), float(steps[key]))

@@ -7,6 +7,12 @@ showcase the rebuild adds on top of capability parity.  Design:
 
 - MXU-shaped: scores and the PV product are ``jnp.dot`` with
   ``preferred_element_type=f32``; blocks are (block_q, block_k) tiles.
+  At the default precision the operands are bf16 whatever the caller's
+  arrays are (:func:`operand_dtype`): a float32 operand is rounded to
+  bf16 by the MXU on its way in, every time a tile pair loads it, at
+  half the rows a push and twice the bytes a load; rounded once where
+  it is made it is the same number (the kernels' results agree to the
+  bit at equal tiles on a v5e: PERF.md section 6, PR 57).
 - **Every operand and result at the width it has**: rows and keys are
   padded to whole blocks, a head's width never is.  An array whose last
   dimension is no multiple of 128 (a head of 64, latent attention's keys
@@ -886,18 +892,43 @@ def _fa_kernel(lo_ref, hi_ref, qoff_ref, kvoff_ref, kvlen_ref, q_ref, k_ref,
             ).astype(o_ref.dtype)
 
 
-def _default_blocks(dtype) -> Tuple[int, int]:
-    """Dtype-aware default tiles, from a July 2026 on-chip sweep the
-    ledger has not reproduced: 1024x1024 for <=2-byte inputs (2.7x
-    faster than the old 256x512 there); 512x512 for f32 — the f32
-    backward at 1024-blocks sits at the scoped-VMEM edge and crashes
-    the TPU compiler inside larger programs
-    (docs/tpu_compile_notes.md)."""
-    return (1024, 1024) if jnp.dtype(dtype).itemsize <= 2 else (512, 512)
+def _default_blocks(dtype, width: int = LANE,
+                    banded: bool = False) -> Tuple[int, int]:
+    """Default tiles, from what a call can see: its operands' dtype, the
+    keys' width and whether its mask is a band (a window, the
+    block-diffusion pass: most live tiles are edge tiles, and a taller
+    or wider tile reaches further outside the band).
+
+    float32 operands (a caller that named a ``precision``): ``(512,
+    512)``; the float32 backward at 1024-blocks sits at the scoped-VMEM
+    edge and crashes the TPU compiler inside larger programs
+    (docs/tpu_compile_notes.md section 1).
+
+    Operands of 2 bytes, which is every call at the default precision
+    (:func:`operand_dtype`), as priced and timed at PR 57
+    (docs/tpu_compile_notes.md section 5 has the bundle counts and the
+    chip's milliseconds, PERF.md section 6 the cells): under a band
+    ``(512, 512)``, the tile whose geometry the float32 bodies had (at
+    a window of 1024 a ``(1024, 512)`` tile computes 5 units of area a
+    512 rows for 3, and measured 3% over the float32 kernels where
+    ``(512, 512)`` is 9% under); elsewhere 1024 rows, over 1024 keys
+    where the keys are one lane tile wide or less and over 512 where
+    they are wider (latent attention's 192: at ``(1024, 1024)`` the
+    fused sweep's blocks overflow the stock scoped VMEM, and 1024 keys
+    a block would halve the fused sweep's transient into its budget and
+    flip a two-kernel backward to a 2 GB one: :func:`_use_fused_bwd`).
+    By the static schedule all four bf16 tiles price within 4% of each
+    other a unit of area and within 10% of the float32 body; the chip
+    separates them (Keye's shape, forward and backward: 34.8 ms float32,
+    33.4 / 29.5 / 25.9 ms at ``(512, 512)`` / ``(1024, 512)`` / ``(1024,
+    1024)``)."""
+    if jnp.dtype(dtype).itemsize > 2 or banded:
+        return 512, 512
+    return 1024, (1024 if width <= LANE else 512)
 
 
 def _tile_dims(lq, lk, d, block_q, block_k, sm_scale, dtype,
-               fwd_long_bq=False, bwd_long_bk=False):
+               fwd_long_bq=False, bwd_long_bk=False, banded=False):
     """Shared forward/backward tiling contract: softmax scale, clamped
     block sizes and padded dims (rows, keys, and the head's width in
     whole lanes, which sizes the fused schedule's dQ partial in HBM and
@@ -905,7 +936,9 @@ def _tile_dims(lq, lk, d, block_q, block_k, sm_scale, dtype,
     up with recomputed score tiles if both directions use exactly this
     scale/padding; block sizes themselves may differ per direction (the
     forward slices outputs back to true lq, and LSE/delta are per-row).
-    ``block_q``/``block_k`` of None resolve to the dtype default.
+    ``block_q``/``block_k`` of None resolve to the default of the
+    operands' dtype, the keys' width and ``banded``
+    (:func:`_default_blocks`).
 
     Both length-aware defaults below came from July 2026 on-chip
     sweeps the ledger has not reproduced.
@@ -936,7 +969,7 @@ def _tile_dims(lq, lk, d, block_q, block_k, sm_scale, dtype,
     the 64 MB floor — notably ``=0``, the stock-budget A/B control —
     keeps the flat defaults rather than resolving an uncompilable
     geometry."""
-    dq, dk = _default_blocks(dtype)
+    dq, dk = _default_blocks(dtype, d, banded)
     if (fwd_long_bq and block_q is None and lq >= 16384
             and jnp.dtype(dtype).itemsize <= 2
             and os.environ.get("MPIT_FA_LONG_BQ", "1") != "0"
@@ -1067,10 +1100,11 @@ def _prefetch(walk, q_offset, kv_offset, kv_len):
 
 def _fa_2d(q, k, v, q_offset, kv_offset, *, causal, sm_scale, block_q,
            block_k, interpret, partial=False, precision=None, window=None,
-           select=None, blockdiff=None):
+           select=None, blockdiff=None, out_dtype=None):
     """Core call on (Lq, D) x (Lk, D) over values (Lk, Dv); pads rows
     and keys to whole blocks, and no width.  Returns the
-    normalized (Lq, Dv) output, or with ``partial`` the unnormalized
+    normalized (Lq, Dv) output in ``out_dtype`` (None: the operands'),
+    or with ``partial`` the unnormalized
     ``(acc, m, l)`` triple (f32) for cross-chunk merging.  ``q`` of
     ``(G, Lq, D)`` is a group of query heads over the one KV head: its
     heads are folded into the rows, so ``k`` and ``v`` are read where
@@ -1079,7 +1113,8 @@ def _fa_2d(q, k, v, q_offset, kv_offset, *, causal, sm_scale, block_q,
     lk, dv = v.shape
     groups = q.shape[0] if q.ndim == 3 else 1
     scale, bq, bk, lq_p, lk_p, _ = _tile_dims(
-        lq, lk, d, block_q, block_k, sm_scale, q.dtype, fwd_long_bq=True
+        lq, lk, d, block_q, block_k, sm_scale, q.dtype, fwd_long_bq=True,
+        banded=window is not None or blockdiff is not None,
     )
     # every operand and result at its own width: ``d`` for q and k,
     # ``dv`` for v, PV and ``o`` (the keys' own unless the heads are of
@@ -1101,7 +1136,7 @@ def _fa_2d(q, k, v, q_offset, kv_offset, *, causal, sm_scale, block_q,
         )
     else:
         out_specs = held(dv)
-        out_shape = jax.ShapeDtypeStruct((rows, dv), q.dtype)
+        out_shape = jax.ShapeDtypeStruct((rows, dv), out_dtype or q.dtype)
     res = pl.pallas_call(
         functools.partial(
             _fa_kernel, walk=walk, scale=scale, partial=partial,
@@ -1203,10 +1238,12 @@ def _bwd_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
               masked, sel_ref=None):
     """Shared block math: recompute P and dS for the tile whose first
     query row is at ``q_lo`` over kv block ``j``.
-    Matmul inputs stay in their native dtype (bf16 runs the MXU at full
-    rate); softmax/derivative algebra is f32.  ``masked=False`` is the
-    interior-block fast path: every element is valid by construction, so
-    the iota/compare/where mask algebra is skipped entirely."""
+    Matmul inputs stay in the dtype they come in (bf16 at the default
+    precision: :func:`operand_dtype`), ``p`` and ``dS`` are rounded to
+    it before their products; softmax/derivative algebra is f32.
+    ``masked=False`` is the interior-block fast path: every element is
+    valid by construction, so the iota/compare/where mask algebra is
+    skipped entirely."""
     s = jax.lax.dot_general(
         q_ref[:], k_ref[:], (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32, precision=precision,
@@ -1357,8 +1394,10 @@ def _sum_visited(dq_part, walk, q_offset, kv_offset, kv_len):
 
 def _fa_2d_bwd(q, k, v, do, lse, delta, q_offset, kv_offset, *, causal,
                sm_scale, block_q, block_k, interpret, precision,
-               fused=True, window=None, select=None, blockdiff=None):
-    """Backward core on (Lq, D) x (Lk, D): returns (dq, dk, dv).
+               fused=True, window=None, select=None, blockdiff=None,
+               out_dtype=None):
+    """Backward core on (Lq, D) x (Lk, D): returns (dq, dk, dv), in
+    ``out_dtype`` (None: the operands').
 
     ``lse``/``delta`` are per-q-row f32 vectors (log-sum-exp from the
     forward; rowsum(dO*O)).  Padded q rows carry dO = 0 so their P/dS
@@ -1371,13 +1410,15 @@ def _fa_2d_bwd(q, k, v, do, lse, delta, q_offset, kv_offset, *, causal,
     lq, d = q.shape[-2:]
     lk, dv = v.shape
     groups = q.shape[0] if q.ndim == 3 else 1
+    out_dtype = out_dtype or q.dtype
     # bwd_long_bk only under the fused schedule: the 32k sweep measured
     # the win THERE (the halved dQ-partials transient is most of it);
     # the two-kernel schedule with bk=2048 is unmeasured, so the
     # fallback keeps its flat default.  _use_fused_bwd models the fused
     # candidate with the same flag, so gate and kernel stay consistent.
     scale, bq, bk, lq_p, lk_p, _ = _tile_dims(
-        lq, lk, d, block_q, block_k, sm_scale, q.dtype, bwd_long_bk=fused
+        lq, lk, d, block_q, block_k, sm_scale, q.dtype, bwd_long_bk=fused,
+        banded=window is not None or blockdiff is not None,
     )
     # q, k, dq, dk at the keys' own width; v, do, dv at the values'
     qp = _fold(q, lq_p)
@@ -1405,8 +1446,8 @@ def _fa_2d_bwd(q, k, v, do, lse, delta, q_offset, kv_offset, *, causal,
     held, walked = _walk_specs(walk)
     sel_specs, sel = _select_operand(walk, select, lq_p, lk_p)
     out_specs = [held(d), held(dv)]
-    out_shape = [jax.ShapeDtypeStruct((lk_p, d), k.dtype),
-                 jax.ShapeDtypeStruct((lk_p, dv), v.dtype)]
+    out_shape = [jax.ShapeDtypeStruct((lk_p, d), out_dtype),
+                 jax.ShapeDtypeStruct((lk_p, dv), out_dtype)]
     if fused:
         out_specs.append(pl.BlockSpec(
             (1, bq, d), lambda j, t, *s: (j, walk.fetch(j, t, *s), 0),
@@ -1433,7 +1474,7 @@ def _fa_2d_bwd(q, k, v, do, lse, delta, q_offset, kv_offset, *, causal,
 
     if fused:
         dq = _sum_visited(dq_part[0], walk, q_offset, kv_offset,
-                          lk).astype(q.dtype)
+                          lk).astype(out_dtype)
     else:
         # The two-kernel schedule's dQ: q rows outer, each walking the
         # kv blocks of its live range.
@@ -1450,7 +1491,7 @@ def _fa_2d_bwd(q, k, v, do, lse, delta, q_offset, kv_offset, *, causal,
                 out_specs=held(d),
                 scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
             ),
-            out_shape=jax.ShapeDtypeStruct((rows, d), q.dtype),
+            out_shape=jax.ShapeDtypeStruct((rows, d), out_dtype),
             **call,
         )(*_prefetch(walk, q_offset, kv_offset, lk), qp, dop, lse_r,
           delta_r, kp, vp, *sel)
@@ -1551,10 +1592,12 @@ def flash_step_counts(kernel, q_shape, k_shape, dtype, *, causal=False,
     :func:`flash_attention` takes them, or ``q`` already grouped one rank
     above ``k``): ``{"visited", "live", "rect"}``, summed over the
     leading (batch, KV head) axes.  ``kernel`` is ``fwd``, ``dq``,
-    ``dkdv`` or ``fused``.  ``visited`` is what the grid's shape says,
-    ``live`` the tiles that run a product, ``rect`` the whole rectangle
-    the grid was before it walked live ranges; live over visited is the
-    walk's hit share.  The program's own numbers: the same
+    ``dkdv`` or ``fused``; ``dtype`` the operands' as the kernels take
+    them (:func:`operand_dtype` of the caller's).  ``visited`` is what
+    the grid's shape says, ``live`` the tiles that run a product,
+    ``rect`` the whole rectangle the grid was before it walked live
+    ranges; live over visited is the walk's hit share.  The program's
+    own numbers: the same
     :class:`_Walk` the kernels lower with, on concrete offsets (an
     offset that is traced counts as 0).  Under ``blockdiff`` also
     ``nonempty``: the tiles of the rectangle in which the mask has a
@@ -1570,6 +1613,7 @@ def flash_step_counts(kernel, q_shape, k_shape, dtype, *, causal=False,
     lk = k_shape[-2]
     _, bq, bk, lq_p, lk_p, _ = _tile_dims(
         lq, lk, d, block_q, block_k, None, dtype,
+        banded=window is not None or blockdiff is not None,
         **({long_flag: True} if long_flag else {}))
     walk = _Walk(kv_outer, causal, window, bq, bk, lq_p // bq, lk_p // bk,
                  groups, blockdiff=_check_blockdiff(blockdiff, lq, lk, causal,
@@ -1603,14 +1647,15 @@ def flash_attention_bwd_pair(q, k, v, do, lse, *, causal=False, sm_scale=None,
                              q_offset=0, kv_offset=0, delta=None, o=None,
                              block_q=None, block_k=None, interpret=None,
                              precision=None, window=None, select=None,
-                             blockdiff=None):
+                             blockdiff=None, out_dtype=None):
     """Pallas flash backward for one (Q chunk, KV chunk) pair over
     ``(..., L, D)``: returns ``(dq, dk, dv)`` given the forward's row
     ``lse`` (shape ``(..., Lq)``) and either ``delta = rowsum(dO*O)`` or
     ``o`` to compute it from.  This is the per-ring-step backward op of
     :mod:`mpit_tpu.parallel.ring_attention` — O(block) extra memory.
     ``q``, ``do`` (and ``lse``) one rank above ``k`` are grouped
-    (:func:`_group_queries`).
+    (:func:`_group_queries`).  The operands go to the kernels as they
+    come; the gradients are ``out_dtype`` (None: ``q``'s).
     """
     if delta is None:
         if o is None:
@@ -1624,9 +1669,25 @@ def flash_attention_bwd_pair(q, k, v, do, lse, *, causal=False, sm_scale=None,
         sm_scale=sm_scale, block_q=block_q, block_k=block_k,
         interpret=interpret, precision=precision, fused=fused,
         window=window, select=sel[0] if sel else None, blockdiff=blockdiff,
+        out_dtype=out_dtype,
     )
     return _over_leading(f, k, select)(
         q, k, v, do, lse, delta, *(() if select is None else (select,)))
+
+
+def operand_dtype(dtype, precision):
+    """The dtype :func:`flash_attention`'s kernels take ``q``, ``k``,
+    ``v`` and ``dO`` in, the ONE copy of the rule: at the default
+    ``precision`` the products are one bf16 MXU pass with float32
+    accumulation, and an operand wider than that is rounded to bf16
+    once, where it is made, instead of by the MXU every time a tile
+    pair loads it: the same product of the same roundings, on half the
+    pushes and half the bytes (docs/tpu_compile_notes.md section 5).  A
+    caller that names a ``precision`` asked for more than one pass of
+    the operands it has, and they stay as they are."""
+    dtype = jnp.dtype(dtype)
+    wide = jnp.issubdtype(dtype, jnp.floating) and dtype.itemsize > 2
+    return jnp.dtype(jnp.bfloat16) if precision is None and wide else dtype
 
 
 @functools.lru_cache(maxsize=64)
@@ -1636,25 +1697,34 @@ def _make_flash(causal, sm_scale, block_q, block_k, interpret, precision,
     pallas backward (flash schedule, O(block) memory — the forward's
     partial outputs provide the LSE residual).  ``sel`` is the
     selection's words where the call has one, else nothing: an integer
-    operand, with no cotangent."""
+    operand, with no cotangent.
+
+    The rules own the operands' rounding (:func:`operand_dtype`): the
+    casts stand at the head of each rule, behind whatever made ``q``,
+    ``k``, ``v`` and ``dO``, so XLA writes the rounded operand from the
+    fusion that made the wide one and no pass over ``(B, H, L, D)`` is
+    added; the forward keeps the rounded ``q``, ``k``, ``v`` for the
+    backward.  What comes out (``o``, ``dq``, ``dk``, ``dv``) is the
+    caller's dtype, and ``lse`` and ``delta`` are float32."""
+    kw = dict(causal=causal, sm_scale=sm_scale, block_q=block_q,
+              block_k=block_k, interpret=interpret, precision=precision,
+              window=window, blockdiff=blockdiff)
+
+    def rounded(*xs):
+        return tuple(x.astype(operand_dtype(x.dtype, precision)) for x in xs)
 
     @jax.custom_vjp
     def fa(q, k, v, q_offset, kv_offset, *sel):
         f = lambda q2, k2, v2, *sel2: _fa_2d(
-            q2, k2, v2, q_offset, kv_offset, causal=causal,
-            sm_scale=sm_scale, block_q=block_q, block_k=block_k,
-            interpret=interpret, precision=precision, window=window,
-            select=sel2[0] if sel2 else None, blockdiff=blockdiff,
-        )
-        return _over_leading(f, k, *sel)(q, k, v, *sel)
+            q2, k2, v2, q_offset, kv_offset, out_dtype=q.dtype,
+            select=sel2[0] if sel2 else None, **kw)
+        return _over_leading(f, k, *sel)(*rounded(q, k, v), *sel)
 
     def fwd(q, k, v, q_offset, kv_offset, *sel):
+        qkv = rounded(q, k, v)
         acc, m, l = flash_attention_partial(
-            q, k, v, causal=causal, sm_scale=sm_scale, q_offset=q_offset,
-            kv_offset=kv_offset, block_q=block_q, block_k=block_k,
-            interpret=interpret, precision=precision, window=window,
-            select=sel[0] if sel else None, blockdiff=blockdiff,
-        )
+            *qkv, q_offset=q_offset, kv_offset=kv_offset,
+            select=sel[0] if sel else None, **kw)
         # Named where the rule makes them: a checkpoint whose policy
         # saves these two names keeps the forward kernel's results and
         # does not run it again for the backward pass (``lse`` exists
@@ -1663,17 +1733,17 @@ def _make_flash(causal, sm_scale, block_q, block_k, interpret, precision,
         o = checkpoint_name(finalize_partials(acc, l, dtype=q.dtype),
                             FLASH_OUT)
         lse = checkpoint_name(_lse_of(m, l), FLASH_LSE)
-        return o, (q, k, v, o, lse, q_offset, kv_offset, *sel)
+        return o, (*qkv, o, lse, q_offset, kv_offset, *sel)
 
     def bwd(res, g):
         q, k, v, o, lse, q_offset, kv_offset, *sel = res
+        # ``delta`` is taken inside, from the rounded ``dO`` the kernels
+        # multiply: ``rowsum(P * dP)`` is ``dO . O`` for that ``dO``, and
+        # the float32 one then has no reader and is never written
         dq, dk, dv = flash_attention_bwd_pair(
-            q, k, v, g, lse, causal=causal, sm_scale=sm_scale,
-            q_offset=q_offset, kv_offset=kv_offset, o=o,
-            block_q=block_q, block_k=block_k, interpret=interpret,
-            precision=precision, window=window,
-            select=sel[0] if sel else None, blockdiff=blockdiff,
-        )
+            q, k, v, *rounded(g), lse, q_offset=q_offset,
+            kv_offset=kv_offset, o=o, out_dtype=o.dtype,
+            select=sel[0] if sel else None, **kw)
         return (dq, dk, dv, None, None, *(None for _ in sel))
 
     fa.defvjp(fwd, bwd)
@@ -1736,15 +1806,21 @@ def flash_attention(
     (:class:`_Walk`); the backward is the two-kernel schedule unless
     ``MPIT_FA_FUSED_BWD=1`` forces the fused sweep.
 
-    Default blocks are 1024x1024, growing to 2048x1024 at L >= 16384
-    (defaults from a July 2026 sweep on a v5e the ledger has not
-    reproduced; MPIT_FA_LONG_BQ=0 pins 1024 — the kernel auto-raises
+    Default blocks come from the operands' dtype, the keys' width and
+    the mask (:func:`_default_blocks`), ``block_q`` growing to 2048 at
+    L >= 16384 (a July 2026 sweep on a v5e the ledger has not
+    reproduced; MPIT_FA_LONG_BQ=0 pins it — the kernel auto-raises
     its scoped-VMEM budget for the bigger score tile).  ``_tile_dims``
     clamps blocks for short sequences, so the default is safe at any L.
 
     ``precision``: MXU input precision for the two block matmuls (e.g.
-    ``"highest"`` for full-f32 inputs); None uses the backend default —
-    bf16 MXU passes on TPU, the standard flash-attention trade."""
+    ``"highest"`` for full-f32 inputs), on the operands as they come.
+    None is the backend default, one bf16 MXU pass with float32
+    accumulation, the standard flash-attention trade: ``q``, ``k``,
+    ``v`` and, backward, ``dO`` then go to the kernels rounded to bf16
+    (:func:`operand_dtype`: once, by the fusion that makes them, where
+    the MXU rounded a float32 operand every time a tile pair loaded it),
+    and the output and the gradients are the caller's dtype as before."""
     # sm_scale is a cache key and closed over as a compile-time constant —
     # it must be a static float, not a traced value (float() rejects
     # tracers with a clear error instead of leaking per-trace cache

@@ -123,8 +123,10 @@ def test_the_kernel_at_two_widths_is_the_materialised_attention(
     output has the values' width, the scale is the keys'."""
     monkeypatch.setenv("MPIT_FA_FUSED_BWD", fused)
     q, k, v, g = _qkv(d, dv, hq=4 if d == 24 else 2)
+    # float32 operands, which a named precision keeps (the default's
+    # bf16 operands at these widths: tests/test_ops.py, PR 57)
     kernel = functools.partial(flash_attention, causal=True, interpret=True,
-                               block_q=32, block_k=128)
+                               block_q=32, block_k=128, precision="highest")
     plain = functools.partial(attention_reference, causal=True)
     with jax.default_matmul_precision("highest"):
         out, vjp = jax.vjp(kernel, q, k, v)

@@ -529,12 +529,18 @@ def test_the_router_takes_8_of_128_by_softmax_renormalised():
 # ``tests/test_sdar.py``'s and of ``tests/test_trinity.py``'s two is
 # PR 54's; a 128-wide call still traces to its parent's program
 # (``tests/test_ops.py`` ``PARENTS_128_WIDE``), and on the chip the
-# results are the parent's to the last printed digit, PERF.md section 6.)
+# results are the parent's to the last printed digit, PERF.md section 6.
+# PR 57 did a third time: a call at the default precision rounds q, k, v
+# and dO to bf16 at the head of the op's rules and tiles by that dtype,
+# so every digest of the three tables is PR 57's; a call that names a
+# precision still traces to the parent's program
+# (``tests/test_ops.py`` ``PARENTS_128_WIDE``), and on the chip the
+# bf16 operands give the float32 operands' bits at the same tile.)
 PARENTS_STEP = {
-    "mellum2-l4e8-local": "ad42943aa12d42df",
-    "lfm2-l5e8-local": "b593098f661ec161",
-    "ouro-l6-local": "8dd55cff8f3be0d2",
-    "joyai-l5e8-local": "5ec1de116152ac77",
+    "mellum2-l4e8-local": "0ec308be12de951d",
+    "lfm2-l5e8-local": "56d30a65f216f0fb",
+    "ouro-l6-local": "b8d78e1f61d17001",
+    "joyai-l5e8-local": "a50c60fbad1cf7fd",
 }
 
 

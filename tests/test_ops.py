@@ -6,6 +6,7 @@ implementation with tolerances, the golden-value style the rebuild's test
 strategy mandates.
 """
 
+import functools
 import hashlib
 import re
 
@@ -28,6 +29,7 @@ from mpit_tpu.ops import (
     fused_nesterov_commit_reference,
 )
 from mpit_tpu.ops.flash_attention import finalize_partials, merge_partials
+from mpit_tpu.ops.select_bits import pack as pack_bits
 from mpit_tpu.optim.rules import adam_apply, adam_init
 
 
@@ -130,6 +132,14 @@ def test_fused_elastic(rng):
 # ---------------------------------------------------------------------------
 
 
+# The algorithm's tests hold the kernels to the float32 reference at
+# float32's tolerances, so they name a precision: the operands then stay
+# float32 (``operand_dtype``; in the interpreter a product is exact at
+# either).  What a call at the default precision does, bf16 operands
+# and float32 results, has its own cases below (PR 57).
+flash_f32 = functools.partial(flash_attention, precision="highest")
+
+
 def _qkv(rng, shape):
     return tuple(
         jnp.asarray(rng.normal(size=shape) * 0.5, jnp.float32) for _ in range(3)
@@ -140,7 +150,7 @@ def _qkv(rng, shape):
 @pytest.mark.parametrize("shape", [(64, 16), (2, 3, 40, 24)])
 def test_flash_matches_reference(rng, causal, shape):
     q, k, v = _qkv(rng, shape)
-    out = flash_attention(q, k, v, causal=causal, block_q=16, block_k=128)
+    out = flash_f32(q, k, v, causal=causal, block_q=16, block_k=128)
     ref = attention_reference(q, k, v, causal=causal)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
@@ -174,7 +184,7 @@ def test_flash_offsets_pallas(rng):
     L, D, C = 32, 16, 16
     q, k, v = _qkv(rng, (L, D))
     full = attention_reference(q, k, v, causal=True)
-    out = flash_attention(
+    out = flash_f32(
         q[C:], k, v, causal=True, q_offset=jnp.int32(C), block_q=16, block_k=128
     )
     np.testing.assert_allclose(np.asarray(out), np.asarray(full[C:]), atol=2e-5)
@@ -185,9 +195,7 @@ def _assert_flash_grads_match(q, k, v, fa=None, atol=3e-5):
     dense reference, 3e-5 atol (the ONE place the loss/tolerance live).
     ``fa`` overrides the attention callable (default: tiny blocks)."""
     if fa is None:
-        import functools
-
-        fa = functools.partial(flash_attention, block_q=8, block_k=128)
+        fa = functools.partial(flash_f32, block_q=8, block_k=128)
     fa_loss = lambda q, k, v: jnp.sum(fa(q, k, v, causal=True) ** 2)
     ref = lambda q, k, v: jnp.sum(
         attention_reference(q, k, v, causal=True) ** 2
@@ -360,10 +368,7 @@ def test_flash_grad_matches_reference_wide_bk(rng, blocks, fa_backward_path):
     L = 4096
     q, k, v = _qkv(rng, (L, 64))
 
-    import functools
-    from mpit_tpu.ops import flash_attention
-
-    fa = functools.partial(flash_attention, block_q=bq, block_k=bk)
+    fa = functools.partial(flash_f32, block_q=bq, block_k=bk)
     _assert_flash_grads_match(q, k, v, fa=fa)
 
 
@@ -451,7 +456,7 @@ def test_flash_ragged_lengths(rng):
     """Non-block-multiple Lq/Lk/D are padded and masked correctly."""
     q, k, v = _qkv(rng, (19, 12))
     k2, v2 = k[:13], v[:13]
-    out = flash_attention(q, k2, v2, block_q=8, block_k=128)
+    out = flash_f32(q, k2, v2, block_q=8, block_k=128)
     ref = attention_reference(q, k2, v2)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
@@ -464,7 +469,7 @@ def test_flash_bwd_ragged_offset_pair(rng, fa_backward_path):
     q = _qkv(rng, (2, 19, 12))[0]
     k, v = (x[:, :13] for x in _qkv(rng, (2, 29, 12))[:2])
     g = jnp.asarray(rng.normal(size=(2, 19, 12)), jnp.float32)
-    fa = lambda q, k, v: flash_attention(
+    fa = lambda q, k, v: flash_f32(
         q, k, v, causal=True, q_offset=26, kv_offset=13,
         block_q=8, block_k=128,
     )
@@ -684,8 +689,6 @@ def test_traced_offsets_give_what_concrete_ones_give(window,
         o = finalize_partials(acc, l)
         return (acc, m, l) + flash_attention_bwd_pair(
             q, k, v, do, _lse_of(m, l), o=o, **kw)
-
-    import functools
 
     for offsets in [(0, 0), (140, 75), (0, 130)]:
         concrete = jax.jit(functools.partial(pair, *offsets))()
@@ -922,29 +925,144 @@ def test_the_kernels_take_every_operand_at_its_own_width(
 
 
 # sha256 of ``str(make_jaxpr(grad(loss)))`` (addresses blanked) of a
-# 128-wide call **as the parent commit of PR 54 printed it**: where the
-# keys and the values are whole tiles the operands, specs, scratch and
-# kernel bodies are what they were, to the character.  A PR that changes
-# the kernels on purpose records the new digests here and says so.
+# 128-wide call.  With a ``precision`` named, **as the parent commit of
+# PR 57 printed it**: the float32 operands, specs, scratch and kernel
+# bodies behind ``precision=`` are what they were, to the character
+# (OLMoE's path).  At the default precision, **as PR 57 prints it**: the
+# rules round q, k, v and dO to bf16, the tiles are that dtype's and
+# the results float32 (the digests before it were PR 54's parent's).  A
+# PR that changes the kernels on purpose records the new digests here
+# and says so.
 PARENTS_128_WIDE = {
-    (None, 8, 8): "429a6434eef1f0d9", (None, 32, 4): "7abc0bf45586a306",
-    (1024, 8, 8): "e7b81775e6bb8cc9", (1024, 32, 4): "ec516e5a6fc758d4",
+    ("highest", None, 8, 8): "6403152374f75525",
+    ("highest", None, 32, 4): "3d638c4da492255b",
+    ("highest", 1024, 8, 8): "7932ac0eb8040ea6",
+    ("highest", 1024, 32, 4): "7746f5b61cd391ee",
+    (None, None, 8, 8): "edbb1493a4c4c6d8",
+    (None, None, 32, 4): "1a196ec84df32818",
+    (None, 1024, 8, 8): "dca1f07bcd644e60",
+    (None, 1024, 32, 4): "80f9dd923fa4069d",
 }
 
 
-@pytest.mark.parametrize("window,hq,hkv", sorted(
+@pytest.mark.parametrize("precision,window,hq,hkv", sorted(
     PARENTS_128_WIDE, key=str), ids=lambda x: str(x))
-def test_a_128_wide_call_traces_to_the_parents_program(window, hq, hkv):
+def test_a_128_wide_call_traces_to_the_parents_program(precision, window,
+                                                       hq, hkv):
     def loss(q, k, v):
         return jnp.sum(flash_attention(q, k, v, causal=True, window=window,
-                                       interpret=False) ** 2)
+                                       interpret=False,
+                                       precision=precision) ** 2)
 
     shapes = [jax.ShapeDtypeStruct((1, heads, 2048, 128), jnp.float32)
               for heads in (hq, hkv, hkv)]
     text = re.sub(r"0x[0-9a-f]+", "0x", str(
         jax.make_jaxpr(jax.grad(loss, (0, 1, 2)))(*shapes)))
     assert hashlib.sha256(text.encode()).hexdigest()[:16] == \
-        PARENTS_128_WIDE[window, hq, hkv]
+        PARENTS_128_WIDE[precision, window, hq, hkv]
+
+
+# -- bf16 operands at the default precision (PR 57) ---------------------------
+#
+# A call that names no ``precision`` hands its kernels q, k, v and dO
+# rounded to bf16 and gets float32 back.  On inputs that are bf16
+# numbers already the float32-operand kernels (``precision=`` named)
+# multiply the same numbers, so the two differ only where a body rounds
+# ``p`` and ``dS`` to the operands' dtype before their products (on the
+# chip the MXU does that to float32 operands itself, and the two agree to
+# the bit: PERF.md section 6, PR 57): two bf16 ulps of the largest entry.
+
+OPERAND_MASKS = {
+    "causal": lambda rows: dict(causal=True),
+    "window": lambda rows: dict(causal=True, window=80),
+    "select": lambda rows: dict(causal=True, select=pack_bits(
+        (jnp.arange(rows)[:, None] * 7 + jnp.arange(rows)[None, :] * 3)
+        % 5 < 3)[None]),
+    "blockdiff": lambda rows: dict(blockdiff=(rows // 2, 4)),
+}
+#: body -> (MPIT_FA_FUSED_BWD, which of (o, dq, dk, dv) it writes)
+OPERAND_BODIES = {"forward": ("0", (0,)), "dq": ("0", (1,)),
+                  "dkdv": ("0", (2, 3)), "fused": ("1", (1, 2, 3))}
+
+
+@pytest.mark.parametrize("d,dv", [(64, 64), (128, 128), (192, 128)],
+                         ids=["64", "128", "192_over_128"])
+@pytest.mark.parametrize("body,mask", [
+    (body, mask) for body in sorted(OPERAND_BODIES)
+    for mask in sorted(OPERAND_MASKS)
+    # a selection's backward is the two-kernel schedule whatever is asked
+    if (body, mask) != ("fused", "select")])
+def test_bf16_operands_give_what_float32_operands_give_on_rounded_inputs(
+        body, mask, d, dv, monkeypatch):
+    schedule, written = OPERAND_BODIES[body]
+    monkeypatch.setenv("MPIT_FA_FUSED_BWD", schedule)
+    rows = 256
+    keys = jax.random.split(jax.random.PRNGKey(d + len(mask)), 4)
+    rounded = lambda key, shape: jax.random.normal(key, shape).astype(
+        jnp.bfloat16).astype(jnp.float32)
+    q = rounded(keys[0], (1, 4, rows, d))      # two query heads a KV head
+    k = rounded(keys[1], (1, 2, rows, d))
+    v = rounded(keys[2], (1, 2, rows, dv))
+    g = rounded(keys[3], (1, 4, rows, dv))
+    call = functools.partial(flash_attention, block_q=64, block_k=128,
+                             **OPERAND_MASKS[mask](rows))
+
+    def results(precision):
+        fn = functools.partial(call, precision=precision)
+        if body == "forward":
+            return (fn(q, k, v),)
+        out, vjp = jax.vjp(fn, q, k, v)
+        return (out, *vjp(g))
+
+    got, want = results(None), results("highest")
+    for i in written:
+        assert got[i].dtype == jnp.float32 and got[i].shape == want[i].shape
+        scale = max(1.0, float(jnp.max(jnp.abs(want[i]))))
+        assert float(jnp.max(jnp.abs(got[i] - want[i]))) < 2 ** -7 * scale, i
+
+
+def _kernel_operands(fn, *args):
+    """``(operand dtypes, result dtypes)`` of every ``pallas_call`` that
+    ``fn`` traces to, the floating ones."""
+    def calls(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from calls(sub)
+
+    floats = lambda vs: [v.aval.dtype for v in vs
+                         if jnp.issubdtype(v.aval.dtype, jnp.floating)]
+    return [(floats(eqn.invars), floats(eqn.outvars))
+            for eqn in calls(jax.make_jaxpr(fn)(*args).jaxpr)]
+
+
+@pytest.mark.parametrize("precision,operand", [
+    (None, jnp.bfloat16), ("highest", jnp.float32), ("default", jnp.float32)])
+def test_a_named_precision_keeps_float32_operands(precision, operand):
+    """The rule reads the argument the kernels already have: no
+    ``precision``, bf16 operands (q, k, v, and dO in the backward
+    kernels; ``lse`` and ``delta`` stay float32); any named one, the
+    float32 arrays as they came.  Results are float32 either way, and
+    bf16 arrays are passed through with bf16 results, as before."""
+    from mpit_tpu.ops.flash_attention import operand_dtype
+
+    q = jnp.zeros((1, 2, 256, 64), jnp.float32)
+    loss = lambda q, k, v: jnp.sum(flash_attention(
+        q, k, v, causal=True, precision=precision) ** 2)
+    kernels = _kernel_operands(jax.grad(loss, (0, 1, 2)), q, q, q)
+    assert len(kernels) >= 2
+    assert operand_dtype(jnp.float32, precision) == operand
+    for n, (operands, results) in enumerate(kernels):
+        wide = 0 if n == 0 else 2   # the backward kernels' lse and delta
+        narrow = [d for d in operands if d != jnp.float32]
+        assert narrow == ([] if operand == jnp.float32 else
+                          [jnp.bfloat16] * (len(operands) - wide))
+        assert all(r == jnp.float32 for r in results)
+    half = q.astype(jnp.bfloat16)
+    assert operand_dtype(jnp.bfloat16, precision) == jnp.bfloat16
+    assert flash_attention(half, half, half, causal=True,
+                           precision=precision).dtype == jnp.bfloat16
 
 
 def test_flash_bwd_no_quadratic_intermediate():

@@ -694,12 +694,12 @@ def test_the_shares_routed_parts_are_the_whole_layer_and_nothing_is_twice():
 # place of the reference attention, **as the parent commit of PR 51
 # printed it**: the walk gained a table and the attention a keyword, and
 # a call without the block-diffusion mask must still trace to the
-# program it was, to the character (a selection's too).  (Since PR 54
+# program it was, to the character (a selection's too).  (Since PR 57
 # the digests are that PR's, here and there: ``tests/test_keye.py``.)
 PARENTS_STEP = {
-    "keye-l6e8-local": "91759c8057a04b3c",
-    "kimi-linear-l5e8-local": "e3309d7eb34a2455",
-    "olmoe-l1-ps1w-su1": "c28bcbdddde256b5",
+    "keye-l6e8-local": "5bed1b64ef7097a1",
+    "kimi-linear-l5e8-local": "8a0acdd925172b3e",
+    "olmoe-l1-ps1w-su1": "38c59ae0597d4cb8",
 }
 
 
@@ -728,6 +728,34 @@ def test_a_block_without_the_mask_lowers_to_the_parents_step(cell_name):
     assert "pallas_call" in text
     assert hashlib.sha256(text.encode()).hexdigest()[:16] == \
         PARENTS_STEP[cell_name]
+
+
+def test_olmoes_step_at_its_own_precision_is_the_parents():
+    """OLMoE's block hands its attention a precision
+    (``transformer.ATTN_KERNEL_PRECISION``: float32 operands at more than
+    one pass), and a call that names one keeps the operands it has (PR
+    57: every other call's are rounded to bf16 by the op).  With that
+    attention its tiny step traces to the program it was **as the parent
+    commit of PR 57 printed it**, and nothing in it is bf16."""
+    cell = spec_mod.load_cell("olmoe-l1-ps1w-su1")
+    cell.config.update(cell.config["tiny"])
+    model = runner.build_model(cell, seed=1, lm_use_flash=0)
+    module = model.module.clone(attn_fn=transformer.default_attn(
+        causal=True, use_flash=True, interpret=True,
+        precision=transformer.ATTN_KERNEL_PRECISION))
+    tokens = jnp.zeros((2, model.seq_len + 1), jnp.int32)
+
+    def loss(w):
+        logp = module.apply({"params": model.flat.unravel(w)},
+                            tokens[:, :-1])
+        return -jnp.mean(jnp.take_along_axis(logp, tokens[:, 1:, None],
+                                             axis=-1))
+
+    text = re.sub(r"0x[0-9a-f]+", "0x", str(
+        jax.make_jaxpr(jax.value_and_grad(loss))(model.flat.w0)))
+    assert "pallas_call" in text and "bf16" not in text
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == \
+        "cdb491eafc95f7f3"
 
 
 # -- (h) the file, the vector, the seeding, the scopes, what is kept ----------------------

@@ -5,7 +5,9 @@ the flash kernels at LFM2's attention shape (32 query over 8 KV heads of
 64 at 8192), at Mellum's sliding layer's (32 over 4 heads of 128
 under a window of 1024) and at the default tiles of float32 and bf16
 operands under both backward schedules, with no pad and no slice of an
-operand's size round the calls at head widths of 64 and 192 (PR 54); the delta rule's three kernels at Kimi's KDA
+operand's size round the calls at head widths of 64 and 192 (PR 54), and a dense and a
+latent-attention block whose q, k, v and dO are rounded to bf16 by the
+fusions that make them, not by a pass of their own (PR 57); the delta rule's three kernels at Kimi's KDA
 shape (32 heads of 128 at 8192); and the msgd commit over LFM2's vector, whose length is
 whole lanes and no whole number of blocks, and over Ouro's, which is no
 whole number of lanes, with ``w`` and ``vt`` donated.  What interpret mode cannot show: that the tiles fit the chip's fast
@@ -122,21 +124,33 @@ def test_flash_attention_compiles_at(one_chip, kv_heads, width, window):
     assert calls >= (2 if window is None else 3)
     dq, dk, dv = compiled.output_shardings
     assert not sweeps_of(text, least=math.prod(kv.shape))
-    # the rows' statistics are the kernels' own format, 128 lanes
-    assert block_shapes(grads, q, kv, kv) == {(512, width), (512, 128)}
+    # the rows' statistics are the kernels' own format, 128 lanes; the
+    # operands are bf16 (PR 57): 1024 rows a tile, 512 under a window
+    block = 1024 if window is None else 512
+    assert block_shapes(grads, q, kv, kv) == {(block, width), (block, 128)}
 
 
 @pytest.mark.parametrize("fused", ["1", "0"], ids=["fused", "two_kernel"])
-@pytest.mark.parametrize("dtype,block,width,dv", [
-    (jnp.float32, 512, 128, 128), (jnp.float32, 512, 192, 128),
-    (jnp.bfloat16, 1024, 128, 128)],
-    ids=["f32_512x512", "f32_512x512_keys_of_192_lanes", "bf16_1024x1024"])
+@pytest.mark.parametrize("dtype,precision,blocks,width,dv", [
+    (jnp.float32, "highest", (512, 512), 128, 128),
+    (jnp.float32, "highest", (512, 512), 192, 128),
+    (jnp.float32, None, (1024, 1024), 128, 128),
+    (jnp.float32, None, (1024, 512), 192, 128),
+    (jnp.bfloat16, None, (1024, 1024), 128, 128)],
+    ids=["f32_512x512", "f32_512x512_keys_of_192_lanes",
+         "f32_in_bf16_operands_1024x1024",
+         "f32_in_bf16_operands_1024x512_keys_of_192_lanes",
+         "bf16_1024x1024"])
 def test_the_flash_kernels_compile_at_the_default_tiles(
-        one_chip, monkeypatch, dtype, block, width, dv, fused):
+        one_chip, monkeypatch, dtype, precision, blocks, width, dv, fused):
     """The forward and both backward schedules lower for the chip at
-    the default tiles, ``(512, 512)`` float32 (at keys of 128 lanes and
-    at JoyAI's 192 over values of 128, blocked at 192 as they lie: PR
-    54) and ``(1024, 1024)`` bf16, with the row statistics read whole
+    the default tiles: ``(512, 512)`` on float32 operands, which a
+    caller keeps by naming a ``precision`` (at keys of 128 lanes and at
+    JoyAI's 192 over values of 128, blocked at 192 as they lie: PR 54),
+    and on the bf16 operands every other call has (PR 57: float32
+    arrays at the default precision are rounded by the op, the results
+    stay float32) ``(1024, 1024)``, ``(1024, 512)`` where the keys are
+    wider than a lane tile; with the row statistics read whole
     and laid side by side against the tile (PR 52: ``_lanes``; a
     concatenation along the lanes at whole vregs), under the scoped-VMEM
     budget ``_vmem_auto`` asks for at those tiles, which is the stock
@@ -147,12 +161,15 @@ def test_the_flash_kernels_compile_at_the_default_tiles(
     fa = importlib.import_module("mpit_tpu.ops.flash_attention")
     monkeypatch.setenv("MPIT_FA_FUSED_BWD", fused)
     monkeypatch.delenv("MPIT_FA_VMEM_MB", raising=False)
-    assert fa._default_blocks(dtype) == (block, block)
-    assert fa._vmem_auto(block, block) == 0.0   # no raise is asked for
+    operand = fa.operand_dtype(dtype, precision)
+    assert operand == (jnp.float32 if precision else jnp.bfloat16)
+    assert fa._default_blocks(operand, width) == blocks
+    assert fa._vmem_auto(*blocks) == 0.0   # no raise is asked for
 
     def loss(q, k, v):
         return jnp.sum(fa.flash_attention(
-            q, k, v, causal=True, interpret=False).astype(jnp.float32) ** 2)
+            q, k, v, causal=True, interpret=False,
+            precision=precision).astype(jnp.float32) ** 2)
 
     operands = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
                 for shape in ((1, 8, 4096, width), (1, 8, 4096, width),
@@ -162,8 +179,83 @@ def test_the_flash_kernels_compile_at_the_default_tiles(
     calls = text.count('custom_call_target="tpu_custom_call"')
     assert calls == (2 if fused == "1" else 3)
     assert not sweeps_of(text, least=math.prod(operands[2].shape))
+    bq, bk = blocks
     assert block_shapes(grads, *operands) == {
-        (block, width), (block, dv), (block, 128)}
+        (bq, width), (bq, dv), (bq, 128), (bk, width), (bk, dv)}
+
+
+def lone_converts(text, least):
+    """The fusions of a compiled program that do nothing but round an
+    array of at least ``least`` elements to bf16: a ``convert`` over
+    parameters and bitcasts alone (a bitcast moves nothing: a fusion
+    that transposes holds a ``copy`` or a ``transpose``), or such a
+    ``convert`` outside any fusion.  The flash kernels' operands are
+    rounded at the head of the op's rules (``operand_dtype``), and XLA
+    is to fold that into whatever writes the operand (the transposes to
+    heads-major, a projection's output); a fusion like these would be a
+    pass over ``(B, H, L, D)`` that the float32 kernels did not have."""
+    found = []
+    for body in re.split(r"\n(?=%?[\w.]+ \(.*\) -> .*\{\n|ENTRY )", text):
+        lines = [l.strip() for l in body.splitlines()[1:] if " = " in l]
+        rounds = [l for l in lines if (op := re.search(
+            r"= bf16\[([\d,]+)\]\S* convert\(", l)) and math.prod(
+                map(int, op.group(1).split(","))) >= least]
+        if body.startswith("ENTRY"):
+            found += [l[:160] for l in rounds]
+        elif rounds and not [l for l in lines if l not in rounds
+                             and " parameter(" not in l
+                             and " bitcast(" not in l]:
+            found.append(rounds[0][:160])
+    return found
+
+
+@pytest.mark.parametrize("block", ["dense", "latent"])
+def test_the_operands_rounding_rides_on_the_fusions_that_make_them(
+        one_chip, block):
+    """PR 57: at the default precision the flash kernels take q, k, v
+    and dO in bf16 (``ops/flash_attention.py`` ``operand_dtype``) and
+    write float32.  In the compiled step of a dense block and of a
+    latent-attention block (keys of 192 lanes over values of 128) every
+    kernel call reads bf16 operands, and no fusion stands in front of
+    one that only rounds an operand: the ``convert`` sits in the fusion
+    that transposes to heads-major (or in the projection's own output),
+    which writes half the bytes it wrote."""
+    from mpit_tpu.models import transformer
+
+    attn = transformer.default_attn(causal=True, use_flash=True,
+                                    interpret=False)
+    b, l, d, heads = 1, 2048, 512, 4
+    if block == "dense":
+        module = transformer.DecoderBlock(d_model=d, n_heads=heads,
+                                          attn_fn=attn)
+        widths = {(b, heads, l, d // heads)}
+    else:
+        module = transformer.JoyaiBlock(
+            d_model=d, n_heads=heads, q_rank=192, kv_rank=128, qk_nope=128,
+            qk_rope=64, v_head=128, sparse=False, dense_width=1024,
+            n_experts=0, experts_per_tok=0, expert_width=0, attn_fn=attn)
+        widths = {(b, heads, l, 192), (b, heads, l, 128)}
+    x = jax.ShapeDtypeStruct((b, l, d), jnp.float32, sharding=one_chip)
+    params = jax.tree.map(
+        lambda p: jax.ShapeDtypeStruct(p.shape, p.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: module.init(jax.random.PRNGKey(0),
+                                           jnp.zeros(x.shape))))
+
+    def loss(p, x):
+        out = module.apply(p, x)
+        return jnp.sum((out[0] if isinstance(out, tuple) else out) ** 2)
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, x).compile().as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) >= 2
+    for call in calls:  # what a kernel writes is float32
+        assert "bf16[" not in call.split(" custom-call(")[0], call[:200]
+    for shape in widths:  # what it reads is bf16 (the batch of 1 squeezed)
+        dims = ",".join(map(str, shape[1:]))
+        assert re.search(r"bf16\[(%d,)?%s\]" % (b, dims), text), shape
+    assert not lone_converts(text, least=b * heads * l * 64)
 
 
 def test_the_delta_rules_kernels_compile_at_kimis_shape(one_chip, monkeypatch):

@@ -921,13 +921,13 @@ def test_the_router_takes_8_of_128_by_sigmoid_plus_bias_scaled():
 # ``tests/test_keye.py`` and ``tests/test_sdar.py`` hold seven cells'
 # tiny steps to their parents' digests; here the eighth block's (sha256
 # of the same text, as the parent commit of PR 53 printed it; since PR
-# 54 that PR's, as ``tests/test_keye.py`` says), the local
+# 57 that PR's, as ``tests/test_keye.py`` says), the local
 # step that commits them, and the servers' applies.
 PARENTS_STEP = {
-    "sdar-l6e8-local": "ad7474d0e91aa421",
+    "sdar-l6e8-local": "32a5060c8aaa8cc9",
 }
 PARENTS_MSGD_STEP = {
-    "joyai-l5e8-local": "7f136e6cce0c3104",
+    "joyai-l5e8-local": "1cf44eeef5aa13d1",
 }
 
 
