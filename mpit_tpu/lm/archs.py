@@ -66,9 +66,11 @@ SIZES: Dict[str, Size] = {
                         "n_layers of them: lfm2's conv or full_attention, "
                         "kimi's kda or full_attention, trinity's "
                         "sliding_attention (window keys, rotary positions) "
-                        "or full_attention (no positions)"),
+                        "or full_attention (no positions), nemotron's "
+                        "mamba, moe or attention: one branch a layer"),
     "conv_kernel": Size(3, "the taps of a short causal depthwise convolution "
-                        "(lfm2's gated one; kimi's on q, k and v: 4)"),
+                        "(lfm2's gated one; kimi's on q, k and v: 4; "
+                        "nemotron's on x, B and C together, with a bias: 4)"),
     # ouro's loop
     "loop_steps": Size(4, "how often the layers are applied, same weights"),
     "exit_beta": Size(0.1, "the exit distribution's entropy's weight in the "
@@ -115,6 +117,20 @@ SIZES: Dict[str, Size] = {
     "embed_scale": Size(1.0, "what the token table's rows are multiplied "
                         "by on their way into the stream (mup_enabled: "
                         "the square root of d_model)"),
+    # nemotron's state-space mixer, its shared expert and its seeding
+    "ssm_heads": Size(0, "heads of the state-space mixer's state"),
+    "ssm_head_dim": Size(0, "a state-space head's width (the state's "
+                         "rows)"),
+    "ssm_groups": Size(1, "groups of heads that share B and C, and the "
+                       "groups of the gated RMSNorm (divides ssm_heads)"),
+    "ssm_state": Size(0, "the state's columns: B's and C's width a group"),
+    "ssm_chunk": Size(128, "positions of a chunk of the state's scan"),
+    "shared_width": Size(0, "the shared expert's own inner width; 0: "
+                         "shared_experts x expert_width"),
+    "init_depth": Size(0, "the depth whose square root divides the seeded "
+                       "std of the mixers' output projections "
+                       "(rescale_prenorm_residual: the published layer "
+                       "count); 0: seeded as the rest"),
 }
 
 DEFAULTS = {name: size.default for name, size in SIZES.items()}
@@ -293,6 +309,22 @@ def _trinity(s, attn):
                    **_heads(s))
 
 
+def _nemotron(s, attn):
+    _check_share(s)
+    kinds = _layer_kinds(s)
+    state = tuple(s[name] for name in (
+        "ssm_heads", "ssm_head_dim", "ssm_groups", "ssm_state", "ssm_chunk"))
+    if min(state) < 1 or s["ssm_heads"] % s["ssm_groups"] \
+            or s["init_depth"] < 0 or s["shared_width"] < 0:
+        raise ValueError(f"nemotron needs ssm_heads, ssm_head_dim, "
+                         f"ssm_groups (dividing the heads), ssm_state and "
+                         f"ssm_chunk: {state}; init_depth "
+                         f"{s['init_depth']} and shared_width "
+                         f"{s['shared_width']} of 0 or more")
+    return _module("NemotronDecoder", s, attn(), layer_types=kinds,
+                   **_heads(s))
+
+
 # what each block is: its decoder's docstring (``models/transformer.py``)
 BLOCKS: Dict[str, Block] = {
     "gpt2": Block((), _gpt2, sample_len=0),
@@ -336,6 +368,12 @@ BLOCKS: Dict[str, Block] = {
             "layer_types", "window", "dense_layers", "dense_width",
             "route_scale", "shared_experts", "bias_rate", "embed_scale"),
         _trinity, loss=OWN_LOSS),
+    "nemotron": Block(
+        _GROUPED + _SPARSE + _SHARE + (
+            "norm_eps", "layer_types", "conv_kernel", "ssm_heads",
+            "ssm_head_dim", "ssm_groups", "ssm_state", "ssm_chunk",
+            "route_scale", "shared_experts", "shared_width", "init_depth"),
+        _nemotron, loss=OWN_LOSS),
 }
 ARCHS = tuple(BLOCKS)
 

@@ -39,7 +39,8 @@ from mpit_tpu.ops.flash_attention import (
     flash_call_counts, operand_dtype,
 )
 from mpit_tpu.ops.index_select import index_select
-from mpit_tpu.ops.short_conv import causal_depthwise_conv
+from mpit_tpu.ops.short_conv import causal_conv_silu, causal_depthwise_conv
+from mpit_tpu.ops.ssd_scan import CHUNK as SSD_CHUNK, SSD_OUT, ssd_scan
 from mpit_tpu.parallel import moe
 
 #: ``fn(q, k, v, window=None, select=None, blockdiff=None) -> out``.
@@ -441,7 +442,8 @@ def grouped_attention(h: jnp.ndarray, wq: jnp.ndarray, wk: jnp.ndarray,
 
 def sparse_mlp(x: jnp.ndarray, norm: jnp.ndarray, router: jnp.ndarray,
                experts: tuple, *, route: Callable, eps: float,
-               n_experts: int, first: int, held: int):
+               n_experts: int, first: int, held: int,
+               expert_fn: Callable = moe.swiglu_experts):
     """The sparse MLP of a block that holds ``held`` of its ``n_experts``
     experts from ``first`` on, on the stream ``x (B, L, d)``: RMSNorm,
     the router's product over all the experts in float32 at full
@@ -449,13 +451,15 @@ def sparse_mlp(x: jnp.ndarray, norm: jnp.ndarray, router: jnp.ndarray,
     (the block's own scoring: a softmax or a sigmoid with a selection
     bias, over all ``n_experts`` whether held or not), and the held
     experts' part of the weighted sum by the sorted dropless dispatch
-    (``parallel/moe.py``).  Returns the branch's output and its
+    (``parallel/moe.py``) through ``expert_fn`` on the ``experts``'
+    stacked matrices (:func:`~mpit_tpu.parallel.moe.swiglu_experts` on
+    gate, up and down; :func:`~mpit_tpu.parallel.moe.relu2_experts` on
+    up and down).  Returns the branch's output and its
     statistics: the load's max over mean, the held rows' share, whether
     the dispatch was done in one window of the held run (1.0 or 0.0),
     then ``route``'s own.  Pure in its arguments, so a block wraps it in
     ``jax.checkpoint``."""
     b, l, d = x.shape
-    wg, wu, wd = experts
     with jax.named_scope("router"):
         h = rms_norm(x, norm, eps).reshape(b * l, d)
         logits = jnp.matmul(h, router, precision=ROUTER_PRECISION)
@@ -466,7 +470,7 @@ def sparse_mlp(x: jnp.ndarray, norm: jnp.ndarray, router: jnp.ndarray,
                  moe.takes_window(chosen, first, held, n_experts,
                                   d).astype(jnp.float32), *extra)
     y = moe.dispatch_top_k(
-        h, weights, chosen, n_experts, moe.swiglu_experts, wg, wu, wd,
+        h, weights, chosen, n_experts, expert_fn, *experts,
         held=(first, held) if held < n_experts else None)
     return y.reshape(b, l, d), stats
 
@@ -1237,14 +1241,35 @@ def swiglu(h: jnp.ndarray, w_gate: jnp.ndarray, w_up: jnp.ndarray,
     return (jax.nn.silu(h @ w_gate) * (h @ w_up)) @ w_down
 
 
+def relu2_mlp(h: jnp.ndarray, w_up: jnp.ndarray,
+              w_down: jnp.ndarray) -> jnp.ndarray:
+    return jnp.square(jax.nn.relu(h @ w_up)) @ w_down
+
+
+#: an expert's form by ``expert_act``: its matrices' names in the order
+#: its function takes them, the routed experts' grouped function and the
+#: shared expert's dense one
+EXPERT_FORMS = {
+    "swiglu": (("gate", "up", "down"), moe.swiglu_experts, swiglu),
+    "relu2": (("up", "down"), moe.relu2_experts, relu2_mlp),
+}
+
+
 def shared_sparse_experts(block, x, norm):
     """The sparse MLP of ``block`` with a shared expert
     (:class:`JoyaiBlock`, :class:`KimiBlock`): its parameters made in the
     block's own scope, ``(output, statistics in
     :data:`JOYAI_MOE_STATS`' order)``, then :data:`BIAS_RULE_STATS`'
-    where the block has a ``bias_rate`` over 0."""
+    where the block has a ``bias_rate`` over 0.  A block with an
+    ``expert_act`` of ``relu2`` (:class:`NemotronBlock`) has experts of
+    two matrices and no gate (:data:`EXPERT_FORMS`), and one with a
+    ``shared_width`` a shared expert of that inner width, not
+    ``shared_experts`` times the routed experts'."""
     d, e, f = block.d_model, block.n_experts, block.expert_width
-    held, shared = block.experts_held or e, block.shared_experts * f
+    held = block.experts_held or e
+    shared = getattr(block, "shared_width", 0) or block.shared_experts * f
+    names, routed_fn, shared_fn = EXPERT_FORMS[
+        getattr(block, "expert_act", "swiglu")]
     router = block.param("router", _INIT, (d, e))
     # the selection bias (``noaux_tc``'s ``e_score_correction_bias``):
     # as LFM2's, part of the vector, seeded away from zero and reached
@@ -1254,20 +1279,20 @@ def shared_sparse_experts(block, x, norm):
     # ``balance_step`` moves it from this pass's own counts: the bias's
     # slot of the flat gradient carries minus the step (``carry_step``)
     # and every optimizer moves the vector's plain ranges by exactly
-    # that (``models/flat.py`` ``plain_ranges``).  JoyAI's and Kimi's
-    # configurations publish no rate: theirs is 0, nothing moves the
-    # bias and their programs are what they were
+    # that (``models/flat.py`` ``plain_ranges``).  JoyAI's, Kimi's and
+    # Nemotron's configurations publish no rate: theirs is 0, nothing
+    # moves the bias and their programs are what they were
     bias = block.param("router_bias", _INIT, (e,))
     rate = float(getattr(block, "bias_rate", 0.0))
-    routed = tuple(block.param(f"experts_{name}", _INIT, shape)
-                   for name, shape in (("gate", (held, d, f)),
-                                       ("up", (held, d, f)),
-                                       ("down", (held, f, d))))
-    shared_w = tuple(block.param(f"shared_{name}", _INIT, shape)
-                     for name, shape in (("gate", (d, shared)),
-                                         ("up", (d, shared)),
-                                         ("down", (shared, d)))
-                     ) if shared else ()
+
+    def shape(name, width):
+        return (width, d) if name == "down" else (d, width)
+
+    routed = tuple(block.param(f"experts_{name}", _INIT,
+                               (held,) + shape(name, f)) for name in names)
+    shared_w = tuple(block.param(f"shared_{name}", _INIT,
+                                 shape(name, shared))
+                     for name in names) if shared else ()
 
     # recomputed in the backward pass, as Mellum's and LFM2's and for
     # their reason; the shared expert with it (three products 768
@@ -1294,12 +1319,13 @@ def shared_sparse_experts(block, x, norm):
 
         y, stats = sparse_mlp(
             x, norm, router, routed, route=route, eps=block.norm_eps,
-            n_experts=e, first=block.experts_first, held=held)
+            n_experts=e, first=block.experts_first, held=held,
+            expert_fn=routed_fn)
         if shared_w:
             with jax.named_scope("shared_expert"):
                 # every token, whole on every share: counted once
-                y = y + swiglu(rms_norm(x, norm, block.norm_eps),
-                               *shared_w)
+                y = y + shared_fn(rms_norm(x, norm, block.norm_eps),
+                                  *shared_w)
         return y, stats
 
     return branch(x, norm, router, bias, routed, shared_w)
@@ -2438,4 +2464,268 @@ class TrinityDecoder(nn.Module):
                                    else ())
         stats = dict(zip(names, map(jnp.stack, zip(*routing)))
                      ) if routing else {}
+        return loss, stats
+
+
+# ---------------------------------------------------------------------------
+# The state-space hybrid (NVIDIA Nemotron-3-Nano, ``model_type``
+# ``nemotron_h``; the configuration's keys are those of its
+# ``config.json``, the mixer's equations Mamba-2's, arXiv:2405.21060).
+# **A layer is one branch**, ``u = u + Branch(RMSNorm(u))``, of three
+# kinds (``layer_types``): every other decoder of this file pairs a
+# token mixer with an MLP in each layer.  ``mamba``: a Mamba-2 mixer.
+# One product of the normed input gives the gate ``z``, the triple
+# ``x | B | C`` and a step ``dt`` a head; the triple goes through one
+# depthwise causal convolution of ``conv_kernel`` taps with a bias and a
+# SiLU (``ops/short_conv.py``); the state, ``ssm_head_dim x ssm_state``
+# a head, decays by the scalar ``exp(softplus(dt + dt_bias) A)`` and is
+# written by ``x (x) B``, ``B`` and ``C`` shared by the heads of a group
+# (``ops/ssd_scan.py`` has the recurrence and its chunked form); the
+# read-out plus ``D x`` is gated by ``SiLU(z)``, RMSNormed over each
+# group's channels and projected back.  ``attention``: grouped-head
+# attention with **no positional term**, no norm on queries or keys and
+# no gate (order comes from the state-space layers).  ``moe``: JoyAI's
+# router (a sigmoid with a selection bias over all ``n_experts``,
+# renormalised, scaled) over this chip's share of experts **of two
+# matrices and no gate**, ``relu(h W_up)^2 W_down``, whose inner width
+# need be no whole lane tile (``parallel/moe.py`` ``relu2_experts``),
+# beside a shared expert of the same form and its own width.  The plain
+# float32 reference it is held to is
+# ``chipbench/reference/nemotron_plain.py``, which shares no code with
+# this file and steps the state a position at a time
+# (tests/test_nemotron.py).
+# ---------------------------------------------------------------------------
+
+#: the kinds of layer ``layer_types`` may name
+NEMOTRON_LAYERS = ("mamba", "moe", "attention")
+#: What a ``mamba`` mixer's checkpoint keeps beside its input: the
+#: scan's result (``T x heads x head_dim`` floats a layer), so that the
+#: backward pass makes z, x, B, C and the step again (one product and a
+#: convolution) and runs the scan's own backward rule, which makes the
+#: chunks again, once and not twice.
+SSM_KEPT = (SSD_OUT,)
+#: the name of the decay's mean in the step's telemetry (gauge
+#: ``mpit_lm_ssm_decay_mean``, one entry a ``mamba`` layer)
+SSM_DECAY_MEAN = "lm_ssm_decay_mean"
+
+
+def ssm_a_log_init(key, shape, dtype=jnp.float32):
+    """``A_log = log(1 .. heads)``, a head (Mamba-2's ``A_init_range``
+    at whole numbers, as the public ``nemotron_h`` module seeds it): no
+    draw."""
+    del key
+    return jnp.log(jnp.arange(1, shape[0] + 1, dtype=dtype))
+
+
+def state_space_mixer(x: jnp.ndarray, p: dict, *, heads: int, head_dim: int,
+                      groups: int, state: int, chunk: int, eps: float):
+    """A Mamba-2 mixer on the stream ``x (B, L, d)`` with the weights
+    ``p``, projected back to ``(B, L, d)``, and the mean of the decay
+    ``a_t`` over positions and heads; pure in both.  Four scopes:
+    ``ssm_proj`` (the norm before the layer, ``W_in`` and ``W_out``),
+    ``ssm_conv`` (the convolution over x, B and C with its bias and
+    SiLU), ``ssd_scan`` (the step, the chunked state and the skip ``D
+    x``) and ``ssm_norm`` (the gate and the groups' RMSNorm)."""
+    b, l, _ = x.shape
+    inner, shared = heads * head_dim, groups * state
+    with jax.named_scope("ssm_proj"):
+        projected = rms_norm(x, p["norm"], eps) @ p["w_in"]
+        z = projected[..., :inner]
+        xbc = projected[..., inner:2 * inner + 2 * shared]
+        dt = projected[..., 2 * inner + 2 * shared:]
+    with jax.named_scope("ssm_conv"):
+        xbc = causal_conv_silu(xbc, p["conv_w"], p["conv_b"])
+    with jax.named_scope("ssd_scan"):
+        xs = xbc[..., :inner].reshape(b, l, heads, head_dim)
+        bs = xbc[..., inner:inner + shared].reshape(b, l, groups, state)
+        cs = xbc[..., inner + shared:].reshape(b, l, groups, state)
+        step = jax.nn.softplus(dt + p["dt_bias"])     # no clamp
+        rate = -jnp.exp(p["a_log"])
+        decay_mean = jnp.mean(jnp.exp(jax.lax.stop_gradient(step * rate)))
+        y = ssd_scan(xs, step, rate, bs, cs, chunk) \
+            + p["d_skip"][:, None] * xs
+    with jax.named_scope("ssm_norm"):
+        # the gate before the norm; the mean square over a group's
+        # channels, one weight a channel
+        y = y.reshape(b, l, inner) * jax.nn.silu(z)
+        y = rms_norm(y.reshape(b, l, groups, inner // groups),
+                     p["ssm_norm"].reshape(groups, inner // groups), eps)
+    with jax.named_scope("ssm_proj"):
+        return y.reshape(b, l, inner) @ p["w_out"], decay_mean
+
+
+def plain_attention(x: jnp.ndarray, p: dict, *, heads: int, kv_heads: int,
+                    head_dim: int, eps: float, attn: AttnFn) -> jnp.ndarray:
+    """One position-free attention branch on the stream ``x (B, L, d)``:
+    the norm before the layer and :func:`grouped_attention` with no
+    rotation, no norm on queries or keys, no gate; under ``attn``."""
+    with jax.named_scope("attn"):
+        return grouped_attention(
+            rms_norm(x, p["norm"], eps), p["wq"], p["wk"], p["wv"], p["wo"],
+            heads=heads, kv_heads=kv_heads, head_dim=head_dim,
+            inv_freq=None, attn=attn)
+
+
+class NemotronBlock(nn.Module):
+    d_model: int
+    kind: str                # of NEMOTRON_LAYERS
+    n_heads: int
+    kv_heads: int
+    head_dim: int
+    ssm_heads: int
+    ssm_head_dim: int
+    ssm_groups: int
+    ssm_state: int
+    n_experts: int           # the router's width: every expert there is
+    experts_per_tok: int
+    expert_width: int
+    shared_width: int        # the shared expert's own inner width
+    ssm_chunk: int = SSD_CHUNK
+    conv_kernel: int = 4
+    experts_first: int = 0   # the share held here: a contiguous range
+    experts_held: int = 0    # 0: all of them
+    shared_experts: int = 1
+    route_scale: float = 1.0
+    init_depth: int = 0      # 0: the output projections seeded as the rest
+    norm_eps: float = 1e-5
+    attn_fn: Optional[AttnFn] = None
+    expert_act = "relu2"     # :func:`shared_sparse_experts`' form
+
+    @nn.compact
+    def __call__(self, x: jnp.ndarray):
+        """``(the stream after the layer, the ``mamba`` mixer's mean
+        decay or None, the sparse branch's statistics in
+        :data:`JOYAI_MOE_STATS`' order or ``()``)``."""
+        d, eps, ones = self.d_model, self.norm_eps, nn.initializers.ones
+        # ``rescale_prenorm_residual``: the mixers' output projections
+        # are seeded at the std over the square root of the published
+        # depth
+        out_init = nn.initializers.normal(
+            stddev=0.02 / math.sqrt(self.init_depth or 1))
+        if self.kind == "moe":
+            y, stats = shared_sparse_experts(
+                self, x, self.param("norm", ones, (d,)))
+            return x + y, None, stats
+        if self.kind == "attention":
+            hq, hkv, hd = self.n_heads, self.kv_heads, self.head_dim
+            p = {name: self.param(name, init, shape)
+                 for name, init, shape in (
+                     ("norm", ones, (d,)),
+                     ("wq", _INIT, (d, hq * hd)), ("wk", _INIT, (d, hkv * hd)),
+                     ("wv", _INIT, (d, hkv * hd)),
+                     ("wo", out_init, (hq * hd, d)))}
+            # kept: the layer's input and the flash rule's two, as
+            # Trinity's attention branch
+            y = jax.checkpoint(
+                partial(plain_attention, heads=hq, kv_heads=hkv, head_dim=hd,
+                        eps=eps, attn=self.attn_fn if self.attn_fn is not None
+                        else default_attn()),
+                policy=jax.checkpoint_policies.save_only_these_names(
+                    *JOYAI_ATTN_KEPT))(x, p)
+            return x + y, None, ()
+        if self.kind != "mamba":
+            raise ValueError(f"layer type {self.kind!r}; have "
+                             f"{NEMOTRON_LAYERS}")
+        h, hd, g, n = (self.ssm_heads, self.ssm_head_dim, self.ssm_groups,
+                       self.ssm_state)
+        inner, mixed = h * hd, h * hd + 2 * g * n
+        p = {name: self.param(name, init, shape) for name, init, shape in (
+            ("norm", ones, (d,)),
+            ("w_in", _INIT, (d, inner + mixed + h)),
+            # at LFM2's scale and for its reason: at 0.02 the taps'
+            # gradients are lost in the norm of the whole
+            ("conv_w", LFM2_TAPS_INIT, (self.conv_kernel, mixed)),
+            ("conv_b", _INIT, (mixed,)),
+            ("dt_bias", kda_dt_bias_init, (h,)),
+            ("a_log", ssm_a_log_init, (h,)),
+            ("d_skip", ones, (h,)),
+            ("ssm_norm", ones, (inner,)),
+            ("w_out", out_init, (inner, d)))}
+        y, decay = jax.checkpoint(
+            partial(state_space_mixer, heads=h, head_dim=hd, groups=g,
+                    state=n, chunk=self.ssm_chunk, eps=eps),
+            policy=jax.checkpoint_policies.save_only_these_names(*SSM_KEPT)
+        )(x, p)
+        return x + y, decay, ()
+
+
+class NemotronDecoder(nn.Module):
+    """Causal LM of :class:`NemotronBlock` layers: a token table (at
+    :data:`MELLUM_EMBED_INIT`'s scale, for its reason: a share of the
+    experts is held), the layers (layer ``i`` is the one branch
+    ``layer_types[i]`` names), a final RMSNorm and an untied head.  Like
+    :class:`KimiDecoder` it is called with the targets and returns its
+    own loss, the head's mean next-token NLL, with its statistics
+    (``lm/model.py`` closes over it):
+
+    - :data:`SSM_DECAY_MEAN`: the mean of the decay ``a_t`` over
+      positions and heads, one entry a ``mamba`` layer (at 0 the layer
+      has no memory, at 1 its state only grows);
+    - the routing counters of every ``moe`` layer under ``lm/model.py``
+      ``MOE_STATS``' names.
+
+    The head's product, norm and loss is under ``jax.checkpoint``,
+    keeping the rows' log-sum-exp by name (:func:`row_lse`)."""
+
+    vocab: int = 256
+    d_model: int = 64
+    n_heads: int = 4
+    kv_heads: int = 2
+    head_dim: int = 16
+    ssm_heads: int = 4
+    ssm_head_dim: int = 16
+    ssm_groups: int = 2
+    ssm_state: int = 16
+    ssm_chunk: int = SSD_CHUNK
+    layer_types: tuple = ("mamba", "moe", "mamba", "attention", "moe")
+    n_experts: int = 8
+    experts_per_tok: int = 2
+    expert_width: int = 32
+    shared_width: int = 64
+    experts_first: int = 0
+    experts_held: int = 0
+    shared_experts: int = 1
+    conv_kernel: int = 4
+    route_scale: float = 1.0
+    init_depth: int = 0
+    norm_eps: float = 1e-5
+    attn_fn: Optional[AttnFn] = None
+
+    @nn.compact
+    def __call__(self, tokens: jnp.ndarray, targets: jnp.ndarray):
+        d, eps = self.d_model, self.norm_eps
+        sizes = {field: getattr(self, field) for field in (
+            "d_model", "n_heads", "kv_heads", "head_dim", "ssm_heads",
+            "ssm_head_dim", "ssm_groups", "ssm_state", "ssm_chunk",
+            "n_experts", "experts_per_tok", "expert_width", "shared_width",
+            "experts_first", "experts_held", "shared_experts", "conv_kernel",
+            "route_scale", "init_depth", "norm_eps", "attn_fn")}
+
+        @partial(jax.checkpoint,
+                 policy=jax.checkpoint_policies.save_only_these_names(
+                     HEAD_LSE))
+        def head_nll(u, norm, head, targets):
+            with jax.named_scope("head_loss"):
+                z = rms_norm(u, norm, eps) @ head
+                return row_lse(z) - jnp.take_along_axis(
+                    z, targets[..., None], axis=-1)[..., 0]
+
+        decays, routing = [], []
+        with jax.named_scope("embed"):
+            x = self.param("embed", MELLUM_EMBED_INIT,
+                           (self.vocab, d))[tokens]
+        for kind in self.layer_types:
+            x, decay, counted = NemotronBlock(kind=kind, **sizes)(x)
+            decays += [] if decay is None else [decay]
+            routing += [counted] if counted else []
+        nll = head_nll(
+            x, self.param("final_norm", nn.initializers.ones, (d,)),
+            self.param("head", _INIT, (d, self.vocab)), targets)
+        with jax.named_scope("head_loss"):
+            loss = jnp.mean(nll)
+        stats = {}
+        if decays:
+            stats[SSM_DECAY_MEAN] = jnp.stack(decays)
+        if routing:
+            stats.update(zip(JOYAI_MOE_STATS, map(jnp.stack, zip(*routing))))
         return loss, stats

@@ -13,7 +13,10 @@ that backs sequence-parallel ring attention
 :mod:`mpit_tpu.ops.delta_rule` is the gated delta rule's chunked scan
 (Kimi Delta Attention): three Mosaic kernels at head widths of whole
 lanes (forward, and the backward rule's two), XLA's fusions and products
-at every other width; :mod:`mpit_tpu.ops.short_conv` is XLA's fusions.
+at every other width; :mod:`mpit_tpu.ops.ssd_scan` is the scalar-decay
+state-space scan (Mamba-2) in chunks with a backward rule of its own,
+XLA's products and fusions; :mod:`mpit_tpu.ops.short_conv` is XLA's
+fusions.
 :mod:`mpit_tpu.ops.index_select` is a learned selection of keys (an
 indexer's scores and an exact top-k a query): XLA's products and
 fusions too; :mod:`mpit_tpu.ops.select_bits` is the format its set

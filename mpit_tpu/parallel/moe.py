@@ -188,7 +188,11 @@ def sort_by_expert(experts: jnp.ndarray, n_experts: int):
 #   returns a bf16 operand's gradient in bf16).  On the v5e at OLMoE's
 #   shapes (32,768 rows x 2048 x 1024, 64 groups) it is 1.5 times
 #   ``ragged_dot`` forward and backward (PERF.md section 6, PR 26).  TPU
-#   only, shapes in whole tiles only (:func:`pallas_fits`).
+#   only, shapes in whole tiles only but for a long dimension's masked
+#   last tile (:func:`pallas_fits`).
+#
+# Two expert forms over it: :func:`swiglu_experts` (gate, up, down) and
+# :func:`relu2_experts` (up, down; no gate).
 
 GMM_TILE_M = 256   # rows a tile; the row count must be a multiple
 GMM_TILE = 1024    # the most columns a tile takes, on both dimensions
@@ -198,17 +202,34 @@ def _gmm_tile(x: int) -> int:
     """The tile of a matrix dimension ``x``, a multiple of 128: the
     whole of it up to :data:`GMM_TILE`, else the largest multiple of
     128 that divides it and is no more than :data:`GMM_TILE` (1024 for
-    2048; 768 for 2304)."""
+    2048; 768 for 2304).  A dimension that no multiple of 128 divides
+    (:func:`pallas_fits` takes those of whole half-tiles: 1856, 14.5
+    lane tiles) ends in a tile the kernels mask: the tile that rounds
+    it up least, the largest of those (640 for 1856: three tiles, 1920
+    columns computed for 1856)."""
     if x <= GMM_TILE:
         return x
-    return max(t for t in range(128, GMM_TILE + 1, 128) if x % t == 0)
+    tiles = range(128, GMM_TILE + 1, 128)
+    if x % 128:
+        # the least columns computed; of equals the largest tile
+        return min(tiles, key=lambda t: (-(-x // t) * t, -t))
+    return max(t for t in tiles if x % t == 0)
+
+
+def _lanes_fit(x: int) -> bool:
+    """A matrix dimension the kernels take: whole 128-lane tiles, or,
+    past one tile of :data:`GMM_TILE`, whole half-tiles of 64, the last
+    tile masked (the megablox kernels mask a contraction's remainder
+    themselves and the grid clips a result's)."""
+    return x % 128 == 0 or (x > GMM_TILE and x % 64 == 0)
 
 
 def pallas_fits(m: int, k: int, n: int) -> bool:
     """Whether the Pallas kernels take ``(m, k) x (E, k, n)``: whole row
     tiles, and each matrix dimension a multiple of 128 (so whole tiles
-    of :func:`_gmm_tile`)."""
-    return m % GMM_TILE_M == 0 and k % 128 == 0 and n % 128 == 0
+    of :func:`_gmm_tile`) or one :func:`_lanes_fit` lets end in a masked
+    tile."""
+    return m % GMM_TILE_M == 0 and _lanes_fit(k) and _lanes_fit(n)
 
 
 def _gmm_tiling(k: int, n: int) -> Tuple[int, int, int]:
@@ -318,6 +339,19 @@ def swiglu_experts(rows: jnp.ndarray, group_sizes: jnp.ndarray,
     gate = grouped_dot(rows, wg, group_sizes, first)
     up = grouped_dot(rows, wu, group_sizes, first)
     return grouped_dot(jax.nn.silu(gate) * up, wd, group_sizes, first)
+
+
+def relu2_experts(rows: jnp.ndarray, group_sizes: jnp.ndarray,
+                  wu: jnp.ndarray, wd: jnp.ndarray,
+                  first: Optional[int] = None) -> jnp.ndarray:
+    """``relu(rows Wu_e)^2 Wd_e`` for rows sorted by expert: the second
+    expert form, two grouped products (:func:`grouped_dot`) over ``wu
+    (E, d, f)`` and ``wd (E, f, d)``, no gate and no bias
+    (``mlp_hidden_act`` ``relu2``); with ``first``, over the held
+    experts' matrices only.  ``f`` need be no whole lane tile
+    (:func:`pallas_fits`): no column is padded into the matrices."""
+    up = grouped_dot(rows, wu, group_sizes, first)
+    return grouped_dot(jnp.square(jax.nn.relu(up)), wd, group_sizes, first)
 
 
 def _sorted_dispatch(x, weights, experts, n_experts, expert_fn):

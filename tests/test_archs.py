@@ -39,9 +39,12 @@ OTHER = dict(
     kda_heads=3, kda_head_dim=8,
     index_heads=3, index_head_dim=4, index_topk=8,
     block_len=8, mask_id=300, noise_seed=9,
-    bias_rate=0.002, embed_scale=4.0)
+    bias_rate=0.002, embed_scale=4.0,
+    ssm_heads=6, ssm_head_dim=4, ssm_groups=3, ssm_state=8, ssm_chunk=16,
+    shared_width=24, init_depth=7)
 # where a block cannot take ``OTHER``'s value of a size: its own
 OTHER_OF = {"kimi": {"layer_types": "kda,full_attention,kda"},
+            "nemotron": {"layer_types": "mamba,attention,moe"},
             "trinity": {"layer_types": "sliding_attention,full_attention,"
                                        "sliding_attention"}}
 

@@ -56,7 +56,8 @@ def test_every_cells_vocabulary_reaches_the_program_through_the_launcher():
 
     want = {"gpt2": 50257, "olmoe": 50304, "mellum": 12288, "lfm2_moe": 8192,
             "ouro": 49152, "joyai_llm_flash": 16160, "kimi_linear": 20480,
-            "KeyeVL2": 18992, "sdar_moe": 18992, "afmoe": 25024}
+            "KeyeVL2": 18992, "sdar_moe": 18992, "afmoe": 25024,
+            "nemotron_h": 16384}
     for name in CELLS:
         cell = spec_mod.load_cell(name)
         cfg = runner.launch_config(cell, seed=5)
@@ -76,7 +77,8 @@ def test_scopes_come_from_every_committed_configuration():
         "embed", "attn", "mlp", "head_loss", "update", "router", "dispatch",
         "experts", "attn_window", "conv", "conv_mix", "exit_gate", "mla_proj",
         "shared_expert", "kda_proj", "kda_scan", "kda_out", "index", "noise",
-        "attn_gate", "bias_rule", "dense_mlp"]
+        "attn_gate", "bias_rule", "dense_mlp", "ssm_proj", "ssm_conv",
+        "ssd_scan", "ssm_norm"]
 
 
 def olmoe_cases():
@@ -303,6 +305,7 @@ def test_mellums_readers_read_a_hand_made_run(monkeypatch):
 # -- the LFM2 configuration (PR 32) -----------------------------------------------
 
 LFM2_CELL = "lfm2-l5e8-local"
+NEMOTRON_CELL = "nemotron3-l9e8-local"
 
 
 def lfm2_cases():
@@ -477,13 +480,14 @@ def test_the_parent_fails_the_new_cell_at_once():
     cell on the parent needs."""
     bench = spec_mod.load_bench()
     names = [w["name"] for w in bench["workloads"]]
-    assert names[-7:] == [LFM2_CELL, OURO_CELL, JOYAI_CELL, KIMI_CELL,
-                          KEYE_CELL, SDAR_CELL, TRINITY_CELL] \
-        and len(names) == 12
+    assert names[-8:] == [LFM2_CELL, OURO_CELL, JOYAI_CELL, KIMI_CELL,
+                          KEYE_CELL, SDAR_CELL, TRINITY_CELL,
+                          NEMOTRON_CELL] \
+        and len(names) == 13
     for missing in ("lfm2-l5e8-locals", "ouro-l6-locals",
                     "joyai-l5e8-locals", "kimi-linear-l5e8-locals",
                     "keye-l6e8-locals", "sdar-l6e8-locals",
-                    "trinity-l5e8-locals"):
+                    "trinity-l5e8-locals", "nemotron3-l9e8-locals"):
         with pytest.raises(spec_mod.SpecError, match="no workload"):
             spec_mod.load_cell(missing)
 
@@ -768,7 +772,7 @@ def test_joyais_mix_keeps_to_the_traffic_its_issue_fixed():
         elif metric["name"] in JOYAI_APPENDED + JOYAI_METRICS[:2]:
             # the cells of PR 43, PR 46, PR 51 and PR 53, which have
             # some of these layers too, follow it
-            assert JOYAI_CELL in metric["workloads"][-5:]
+            assert JOYAI_CELL in metric["workloads"][-6:]
 
 
 def test_joyais_readers_find_nothing_in_a_run_without_the_block():
@@ -1015,7 +1019,7 @@ def test_kimis_mix_keeps_to_the_traffic_its_issue_fixed():
         elif metric["name"] in KIMI_APPENDED:
             # the cells of PR 46, PR 51 and PR 53 follow it where they
             # have the layer
-            assert KIMI_CELL in metric["workloads"][-4:]
+            assert KIMI_CELL in metric["workloads"][-5:]
         elif "workloads" in metric:
             assert KIMI_CELL not in metric["workloads"], metric["name"]
 
@@ -1240,7 +1244,7 @@ def test_keyes_mix_keeps_to_the_traffic_its_issue_fixed():
             assert metric["workloads"] == [KEYE_CELL]
         elif metric["name"] in KEYE_APPENDED:
             # the cells of PR 51 and PR 53 follow it
-            assert KEYE_CELL in metric["workloads"][-3:]
+            assert KEYE_CELL in metric["workloads"][-4:]
         elif "workloads" in metric:
             assert KEYE_CELL not in metric["workloads"], metric["name"]
     # added together and in order (later PRs' entries follow them)
@@ -1248,8 +1252,8 @@ def test_keyes_mix_keeps_to_the_traffic_its_issue_fixed():
     at = cell.bench["per_layer"].index(keye[0])
     assert cell.bench["per_layer"][at:at + 5] == keye
     # (PR 51's and PR 53's configurations and cells follow them)
-    assert (cell.bench["configs"][-3]["name"],
-            cell.bench["workloads"][-3]["name"]) == (cell.config_name,
+    assert (cell.bench["configs"][-4]["name"],
+            cell.bench["workloads"][-4]["name"]) == (cell.config_name,
                                                      KEYE_CELL)
 
 
@@ -1453,15 +1457,16 @@ def test_sdars_mix_keeps_to_the_traffic_its_issue_fixed():
             assert metric["workloads"] == [SDAR_CELL]
         elif metric["name"] in KEYE_APPENDED:
             # the cell of PR 53 follows them
-            assert metric["workloads"][-3:-1] == [KEYE_CELL, SDAR_CELL]
+            assert [c for c in metric["workloads"]
+                    if c in (KEYE_CELL, SDAR_CELL)] == [KEYE_CELL, SDAR_CELL]
         elif "workloads" in metric:
             assert SDAR_CELL not in metric["workloads"], metric["name"]
     # added together and in order (PR 53's four follow them, and its
     # configuration and cell)
-    assert [m["name"] for m in cell.bench["per_layer"][-8:-4]] == list(
+    assert [m["name"] for m in cell.bench["per_layer"][-13:-9]] == list(
         SDAR_METRICS)
-    assert (cell.bench["configs"][-2]["name"],
-            cell.bench["workloads"][-2]["name"]) == (cell.config_name,
+    assert (cell.bench["configs"][-3]["name"],
+            cell.bench["workloads"][-3]["name"]) == (cell.config_name,
                                                      SDAR_CELL)
 
 
@@ -1687,14 +1692,14 @@ def test_trinitys_mix_keeps_to_the_traffic_its_issue_fixed():
         if metric["name"] in TRINITY_METRICS:
             assert metric["workloads"] == [TRINITY_CELL]
         elif metric["name"] in TRINITY_APPENDED:
-            assert metric["workloads"][-1] == TRINITY_CELL
+            assert TRINITY_CELL in metric["workloads"][-2:]
         elif "workloads" in metric:
             assert TRINITY_CELL not in metric["workloads"], metric["name"]
     # added together, in order and last
-    assert [m["name"] for m in cell.bench["per_layer"][-4:]] == list(
+    assert [m["name"] for m in cell.bench["per_layer"][-9:-5]] == list(
         TRINITY_METRICS)
-    assert (cell.bench["configs"][-1]["name"],
-            cell.bench["workloads"][-1]["name"]) == (cell.config_name,
+    assert (cell.bench["configs"][-2]["name"],
+            cell.bench["workloads"][-2]["name"]) == (cell.config_name,
                                                      TRINITY_CELL)
     assert cell.chips == 1 and len(cell.why) <= 200
 
@@ -1812,6 +1817,261 @@ def test_trinity_arithmetic_by_hand_through_the_cell(what, got, want):
     assert got == want, what
 
 
+# -- the Nemotron-3-Nano configuration (PR 58) ------------------------------------
+
+NEMOTRON_METRICS = ("ssm_ms_per_step", "ssd_scan_ms_per_step",
+                    "ssd_scan_roofline", "ssm_conv_ms_per_step",
+                    "ssm_decay_mean")
+NEMOTRON_APPENDED = (
+    "dispatch_ms_per_step", "expert_load_max_over_mean",
+    "held_experts_ms_per_step", "held_experts_roofline",
+    "held_rows_share_pct", "compact_dispatch_pct",
+    "shared_expert_ms_per_step", "router_bias_flips_pct")
+
+
+def test_nemotron_file_has_the_catalogs_keys_and_the_floor_cuts():
+    """Every key of the catalog's row under its own name and at its
+    published value but the four cut: the depth, the pattern cut to it,
+    the experts held and the vocabulary, with the published values
+    beside them; no width is cut; what the row does not give is stated
+    as assumed."""
+    import pathlib
+
+    cell = spec_mod.load_cell(NEMOTRON_CELL)
+    config = cell.config
+    path = pathlib.Path(
+        "/opt/skills/guides/model-configs/architectures.jsonl")
+    if path.exists():
+        rows = [json.loads(line) for line in path.read_text().splitlines()]
+        entry = next(r for r in rows
+                     if r["name"] == "NVIDIA-Nemotron-3-Nano-30B-A3B-BF16")
+        catalog = entry["config"]
+        assert entry["source_url"] == config["source"]
+        assert all(key in config for key in catalog)
+        differ = sorted(k for k, v in catalog.items() if config[k] != v)
+        assert differ == sorted(config["reduced"])
+        assert config["published"] == {k: catalog[k]
+                                       for k in config["reduced"]}
+        assert catalog["hybrid_override_pattern"].startswith(
+            config["hybrid_override_pattern"])
+    assert sorted(config["reduced"]) == [
+        "hybrid_override_pattern", "n_routed_experts", "num_hidden_layers",
+        "vocab_size"]
+    # the floors: a whole period of nine, 8 experts, an eighth of the rows
+    assert (config["num_hidden_layers"], config["hybrid_override_pattern"],
+            config["n_routed_experts"], config["vocab_size"]) == (
+                9, "MEMEM*EME", 8, 131072 // 8)
+    assert (config["hidden_size"], config["num_attention_heads"],
+            config["num_key_value_heads"], config["head_dim"],
+            config["mamba_num_heads"], config["mamba_head_dim"],
+            config["n_groups"], config["ssm_state_size"],
+            config["conv_kernel"], config["chunk_size"],
+            config["moe_intermediate_size"],
+            config["moe_shared_expert_intermediate_size"],
+            config["num_experts_per_tok"], config["routed_scaling_factor"],
+            config["norm_eps"], config["layer_norm_epsilon"]) == (
+                2688, 32, 2, 128, 64, 64, 8, 128, 4, 128, 1856, 3712, 6,
+                2.5, 1e-5, 1e-5)
+    assert config["router_experts"] == 128   # the router keeps its width
+    assert config["num_experts"] == config["n_routed_experts"]
+    assert (config["train_seq"], config["experts_first"],
+            config["rescale_depth"]) == (8192, 0, 52)
+    entry = next(c for c in cell.bench["configs"]
+                 if c["name"] == cell.config_name)
+    assert sorted(entry["reduced"]) == sorted(config["reduced"])
+    assert entry["source"] == config["source"]
+    assert (cell.chips, cell.traffic_name) == (
+        1, "local-msgd-s8k-nemotron3")
+    assert ["embed", "ssm_proj", "ssm_conv", "ssd_scan", "ssm_norm", "attn",
+            "router", "dispatch", "experts", "shared_expert", "head_loss",
+            "update"] == config["scopes"]
+    assert all(key in config for key in config["launcher_from"].values())
+    assert all(key in config for key in config["tiny"])
+    assert len(config["assumed"]) >= 8 and "16 v5e chips" in \
+        config["deployment"]
+    worked = [got for _what, got, want in cell.arithmetic().hand_worked()
+              if got == want]
+    # a Mamba layer, an attention layer, a sparse layer, the vector
+    assert all(count in worked for count in (
+        38_744_896, 23_399_040, 100_125_440, 666_963_456))
+    assert cell.arithmetic().param_count(config) == 666_963_456
+    assert cell.reference().LOSS_TOL_NATS > 0 < cell.reference().GRAD_REL_TOL
+
+
+def test_the_launcher_builds_the_state_space_block_from_the_cells_files():
+    from chipbench import run as runner
+    from mpit_tpu.lm.model import build_kw
+    from mpit_tpu.train.launch import lm_trainer_cfg
+
+    cell = spec_mod.load_cell(NEMOTRON_CELL)
+    kw = build_kw(lm_trainer_cfg(runner.launch_config(cell, seed=5)))
+    assert (kw["arch"], kw["d_model"], kw["n_heads"], kw["kv_heads"],
+            kw["head_dim"], kw["n_layers"], kw["seq_len"], kw["vocab"]) \
+        == ("nemotron", 2688, 32, 2, 128, 9, 8192, 16384)
+    assert kw["layer_types"] == cell.arithmetic().layer_types(cell.config)
+    assert kw["layer_types"].split(",").count("mamba") == 4
+    assert (kw["ssm_heads"], kw["ssm_head_dim"], kw["ssm_groups"],
+            kw["ssm_state"], kw["ssm_chunk"], kw["conv_kernel"]) == (
+                64, 64, 8, 128, 128, 4)
+    assert (kw["n_experts"], kw["experts_held"], kw["experts_first"],
+            kw["experts_per_tok"], kw["expert_width"], kw["shared_experts"],
+            kw["shared_width"]) == (128, 8, 0, 6, 1856, 1, 3712)
+    assert (kw["route_scale"], kw["norm_eps"], kw["init_depth"]) == (
+        2.5, 1e-5, 52)
+
+
+def test_nemotrons_mix_keeps_to_the_traffic_its_issue_fixed():
+    """ISSUE 58 fixed the mix before any code was written: the rate one
+    of four, the budget a whole number of micro-steps of 8192 tokens
+    between a tenth and four fifths of a window's, momentum 0.9, two
+    rounds of warm-up, closed loop in one process; the five new metrics
+    and the eight appended ones are the cell's, every one moving the
+    rate, and the budget's table is in the mix's own file."""
+    cell = spec_mod.load_cell(NEMOTRON_CELL)
+    mix = cell.traffic
+    steps, rest = divmod(mix["token_budget"],
+                         mix["batch"] * cell.config["train_seq"])
+    assert rest == 0 and steps >= 8
+    assert mix["lr"] in (0.003, 0.01, 0.03, 0.1)
+    assert (mix["launcher"]["mom"], mix["warmup_rounds"], mix["su"],
+            mix["batch"], mix["launcher"]["np"],
+            mix["launcher"]["lm_use_flash"]) == (0.9, 2, 1, 1, 1, 1)
+    assert "quartile distance" in mix["chosen_because"]
+    moves = {m["name"]: m["moves"] for m in cell.metrics("per_layer")}
+    assert set(NEMOTRON_METRICS + NEMOTRON_APPENDED) <= set(moves)
+    assert {moves[m] for m in NEMOTRON_METRICS} == {"tokens_per_s"}
+    layers = {m["name"]: m["layer"] for m in cell.bench["per_layer"]}
+    assert layers["ssd_scan_roofline"] == layers["kda_scan_roofline"]
+    assert layers["ssm_ms_per_step"] == layers["kda_ms_per_step"]
+    units = {m["name"]: (m["unit"], m["better"])
+             for m in cell.bench["per_layer"]}
+    assert units["ssd_scan_roofline"] == ("%", "higher")
+    assert units["ssm_decay_mean"] == ("share", "lower")
+    for metric in cell.bench["per_layer"]:
+        if metric["name"] in NEMOTRON_METRICS:
+            assert metric["workloads"] == [NEMOTRON_CELL]
+        elif metric["name"] in NEMOTRON_APPENDED:
+            assert metric["workloads"][-1] == NEMOTRON_CELL
+        elif "workloads" in metric:
+            assert NEMOTRON_CELL not in metric["workloads"], metric["name"]
+    # added together, in order and last
+    assert [m["name"] for m in cell.bench["per_layer"][-5:]] == list(
+        NEMOTRON_METRICS)
+    assert (cell.bench["configs"][-1]["name"],
+            cell.bench["workloads"][-1]["name"]) == (cell.config_name,
+                                                     NEMOTRON_CELL)
+    assert cell.chips == 1 and len(cell.why) <= 200
+
+
+def test_nemotrons_readers_find_nothing_in_a_run_without_the_block():
+    """What the parent's traced run hands them: a cell whose
+    configuration lists no such scope, a program that recorded no such
+    counter, no device trace, no merged trace: None, no raise."""
+    for name in ("kimi-linear-l5e8-local", "c111m-local"):
+        cell = spec_mod.load_cell(name)
+        run = {"cell": cell, "reduction": {"step_module": "jit__lambda"},
+               "obs_trace": None, "peaks": None, "results": {},
+               "summary": {"worker_ranks": [0], "window": [0.0, 1.0]}}
+        for metric in NEMOTRON_METRICS:
+            reader = spec_mod.load_reader(cell.root, cell.bench, metric)
+            assert reader is not None and reader(dict(run)) is None
+
+
+def test_nemotrons_readers_read_a_hand_made_run(monkeypatch):
+    """The five readers, the eight shared ones and the metrics without a
+    ``workloads`` list that the cell has to report, on a scope table and
+    a span tree made by hand."""
+    from chipbench import flops
+    from chipbench.layers import spantree
+
+    cell = spec_mod.load_cell(NEMOTRON_CELL)
+    listed = [m["name"] for m in cell.metrics("per_layer")]
+    unlisted = [m["name"] for m in cell.bench["per_layer"]
+                if "workloads" not in m]
+    assert len(unlisted) == 10 and set(unlisted) <= set(listed)
+
+    class Round:
+        def __init__(self, k, decay):
+            self.args = {"round": k,
+                         "lm_ssm_decay_mean": [decay, decay + 0.02,
+                                               decay - 0.02, decay],
+                         "moe_held_rows_share": [0.0625] * 4,
+                         "moe_load_max_over_mean": [2.0, 3.0, 2.25, 2.0],
+                         "moe_compact_share": [1.0] * 4,
+                         "moe_bias_flips_share": [0.1, 0.2, 0.3, 0.2]}
+
+    class Tree:
+        def rounds(self):
+            return [Round(k, 0.88 + 0.01 * k) for k in range(5)]
+
+    table = {"step": 400.0, "ssm_proj": 60.0, "ssm_conv": 12.0,
+             "ssd_scan": 50.0, "ssm_norm": 6.0, "attn": 40.0,
+             "router": 8.0, "dispatch": 20.0, "experts": 30.0,
+             "shared_expert": 24.0, "head_loss": 11.0, "update": 13.0}
+    monkeypatch.setattr(spantree, "scope_ms_per_step", lambda run: table)
+    monkeypatch.setattr(spantree, "xplane_path", lambda run: None)
+    run = {"cell": cell, "peaks": flops.load_peaks("TPU v5 lite"),
+           spantree.CACHE_KEY: Tree(),
+           "summary": {"tokens_per_s": 20000.0, "worker_ranks": [0]},
+           "reduction": {"step_module": "jit__lambda", "step_module_runs": 2,
+                         "mosaic_by_scope": {
+                             "attn": (6, 0.070), "experts": (48, 0.040),
+                             "update": (2, 0.026)}}}
+
+    def read(name, run=run):
+        return spec_mod.load_reader(cell.root, cell.bench, name)(run)
+
+    assert read("ssm_ms_per_step") == pytest.approx(128.0)
+    assert read("ssd_scan_ms_per_step") == pytest.approx(50.0)
+    assert read("ssm_conv_ms_per_step") == pytest.approx(12.0)
+    assert read("ssm_decay_mean") == pytest.approx(0.90)
+    scan = cell.arithmetic().ssd_scan_cost(cell.config, 1)
+    assert read("ssd_scan_roofline") == pytest.approx(
+        100 * max(scan["flops"] / 197e12, scan["bytes"] / 819e9) / 0.050)
+    assert scan["bytes"] / 819e9 > scan["flops"] / 197e12   # memory binds
+    assert read("dispatch_ms_per_step") == pytest.approx(28.0)
+    assert read("held_experts_ms_per_step") == pytest.approx(30.0)
+    assert read("shared_expert_ms_per_step") == pytest.approx(24.0)
+    assert read("held_rows_share_pct") == pytest.approx(6.25)
+    assert read("expert_load_max_over_mean") == pytest.approx(3.0)
+    assert read("compact_dispatch_pct") == pytest.approx(100.0)
+    assert read("router_bias_flips_pct") == pytest.approx(20.0)
+    assert read("head_loss_ms_per_step") == pytest.approx(11.0)
+    assert read("flash_ms_per_step") == pytest.approx(35.0)
+    families = cell.arithmetic().kernels(cell.config, 1)
+    assert read("flash_roofline") == pytest.approx(
+        100 * families["attn"]["flops"] / 197e12 / 0.035)
+    experts = cell.arithmetic().experts_cost(cell.config, 1)
+    assert read("held_experts_roofline") == pytest.approx(
+        100 * max(experts["flops"] / 197e12, experts["bytes"] / 819e9)
+        / 0.020)
+    assert read("mfu_pct") == pytest.approx(
+        100 * 2_144_968_704 * 20000.0 / 197e12)
+    for metric in cell.metrics("per_layer"):
+        assert spec_mod.load_reader(cell.root, cell.bench,
+                                    metric["name"]) is not None
+    # a block with no state-space layer carries no decay: nothing read
+    class Bare:
+        def rounds(self):
+            rounds = Tree().rounds()
+            for r in rounds:
+                del r.args["lm_ssm_decay_mean"]
+            return rounds
+
+    assert read("ssm_decay_mean", {**run, spantree.CACHE_KEY: Bare()}) \
+        is None
+
+
+def nemotron_cases():
+    return spec_mod.load_cell(NEMOTRON_CELL).arithmetic().hand_worked()
+
+
+@pytest.mark.parametrize("what,got,want", nemotron_cases(),
+                         ids=[c[0] for c in nemotron_cases()])
+def test_nemotron_arithmetic_by_hand_through_the_cell(what, got, want):
+    assert got == want, what
+
+
 def _one_line_fields():
     bench = json.loads((spec_mod.ROOT / "BENCHMARK.json").read_text())
     out = [("command", " ".join(bench["command"]))]
@@ -1905,7 +2165,7 @@ def test_pull_early_pct_is_entered_for_the_ps_cells_under_a_layer_of_perf_md():
                      "source": "program_span", "layer": "L3 shell + client",
                      "moves": "tokens_per_s", "workloads": PS_CELLS}
     # appended, nothing moved; PR 51's four and PR 53's four follow it
-    assert bench["per_layer"][-9] is entry
+    assert bench["per_layer"][-14] is entry
     perf = (spec_mod.ROOT / "PERF.md").read_text()
     layers = perf[perf.index("## 3. Layers"):perf.index("## 4. Cells")]
     assert f"| {entry['layer']} |" in layers and "`pull_early_pct`" in layers

@@ -382,3 +382,63 @@ def test_plain_ranges_cost_the_local_step_no_copy_of_the_vector(
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= 2 * 4 * n
     assert mem.temp_size_in_bytes < 4 * n + 4 * n // 8   # the gradient
+
+
+# -- the state-space hybrid (PR 58) ---------------------------------------------
+
+
+@pytest.mark.parametrize("k,n", [(2688, 1856), (1856, 2688)],
+                         ids=["up", "down"])
+def test_the_grouped_product_compiles_at_an_inner_width_of_14_5_lane_tiles(
+        one_chip, k, n):
+    """Nemotron's experts: 1856 columns inside, which no multiple of 128
+    divides.  The kernels take it as it is, its last tile masked (640 x
+    3 = 1920 computed): forward, the rows' gradient and the weights'
+    compile for the chip over a window of 6144 rows and 8 held experts
+    from a group offset, no operand is padded to whole lanes in front of
+    a call and no gradient cut back behind one."""
+    from mpit_tpu.parallel import moe
+
+    rows, held, groups = 6144, 8, 10
+    assert moe.pallas_fits(rows, k, n)
+    assert 1856 in (k, n) and moe._gmm_tile(1856) == 640
+
+    def loss(x, w, sizes):
+        return jnp.sum(moe.grouped_dot(x, w, sizes, 1) ** 2)
+
+    args = (jax.ShapeDtypeStruct((rows, k), jnp.float32, sharding=one_chip),
+            jax.ShapeDtypeStruct((held, k, n), jnp.float32,
+                                 sharding=one_chip),
+            jax.ShapeDtypeStruct((groups,), jnp.int32, sharding=one_chip))
+    real = jax.default_backend
+    jax.default_backend = lambda: "tpu"    # ``grouped_dot`` asks
+    try:
+        compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+            *args).compile()
+    finally:
+        jax.default_backend = real
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    assert "f32[8,%d,%d]" % (k, n) in text
+    assert not sweeps_of(text, held * k * n)
+
+
+def test_the_state_space_scan_compiles_at_the_published_shape(one_chip):
+    """64 heads of 64 over 8 groups' B and C of 128 at 8192 positions in
+    chunks of 128, forward and the operator's own rule: XLA's products
+    and fusions for the chip, no Mosaic call, inside a fifth of the
+    chip's memory."""
+    from mpit_tpu.ops.ssd_scan import ssd_scan
+
+    def loss(x, dt, a, b, c):
+        return jnp.sum(ssd_scan(x, dt, a, b, c) ** 2)
+
+    def of(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    args = (of(1, 8192, 64, 64), of(1, 8192, 64), of(64,),
+            of(1, 8192, 8, 128), of(1, 8192, 8, 128))
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        *args).compile()
+    assert "tpu_custom_call" not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 3.4e9
