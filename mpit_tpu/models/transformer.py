@@ -210,6 +210,25 @@ def rms_norm(x: jnp.ndarray, weight: jnp.ndarray, eps: float) -> jnp.ndarray:
     return x * jax.lax.rsqrt(var + eps) * weight
 
 
+def group_rms_norm(x: jnp.ndarray, weight: jnp.ndarray, groups: int,
+                   eps: float) -> jnp.ndarray:
+    """:func:`rms_norm` over each of ``groups`` equal runs of the last
+    axis, one weight a channel, on the array as it lies: a group's mean
+    square and its way back to the channels are two products with the
+    groups' membership (0s and 1s) at full float32 precision, so the
+    channels stay the minor axis throughout (as a reduction over
+    ``(..., groups, width)`` the compiler lays the positions minor, and
+    a neighbour that wants the channels there, a kernel's result, pays
+    a relayout each way)."""
+    x = x.astype(jnp.float32)
+    width = x.shape[-1] // groups
+    member = (jnp.arange(x.shape[-1])[:, None] // width
+              == jnp.arange(groups)[None, :]).astype(jnp.float32)
+    exact = partial(jnp.matmul, precision=jax.lax.Precision.HIGHEST)
+    var = exact(jnp.square(x), member) / width
+    return x * exact(jax.lax.rsqrt(var + eps), member.T) * weight
+
+
 def rope(x: jnp.ndarray, theta: float) -> jnp.ndarray:
     """Rotary position embedding over the full head width of ``x (B, L,
     H, D)``, rotate-half convention: with the head split into halves
@@ -2502,7 +2521,8 @@ NEMOTRON_LAYERS = ("mamba", "moe", "attention")
 #: scan's result (``T x heads x head_dim`` floats a layer), so that the
 #: backward pass makes z, x, B, C and the step again (one product and a
 #: convolution) and runs the scan's own backward rule, which makes the
-#: chunks again, once and not twice.
+#: chunk-start states again (the contributions and the carry) and,
+#: walking back, each chunk's matrices: the chunks once and not twice.
 SSM_KEPT = (SSD_OUT,)
 #: the name of the decay's mean in the step's telemetry (gauge
 #: ``mpit_lm_ssm_decay_mean``, one entry a ``mamba`` layer)
@@ -2542,16 +2562,14 @@ def state_space_mixer(x: jnp.ndarray, p: dict, *, heads: int, head_dim: int,
         step = jax.nn.softplus(dt + p["dt_bias"])     # no clamp
         rate = -jnp.exp(p["a_log"])
         decay_mean = jnp.mean(jnp.exp(jax.lax.stop_gradient(step * rate)))
-        y = ssd_scan(xs, step, rate, bs, cs, chunk) \
-            + p["d_skip"][:, None] * xs
+        y = ssd_scan(xs, step, rate, bs, cs, chunk, skip=p["d_skip"])
     with jax.named_scope("ssm_norm"):
         # the gate before the norm; the mean square over a group's
         # channels, one weight a channel
-        y = y.reshape(b, l, inner) * jax.nn.silu(z)
-        y = rms_norm(y.reshape(b, l, groups, inner // groups),
-                     p["ssm_norm"].reshape(groups, inner // groups), eps)
+        y = group_rms_norm(y.reshape(b, l, inner) * jax.nn.silu(z),
+                           p["ssm_norm"], groups, eps)
     with jax.named_scope("ssm_proj"):
-        return y.reshape(b, l, inner) @ p["w_out"], decay_mean
+        return y @ p["w_out"], decay_mean
 
 
 def plain_attention(x: jnp.ndarray, p: dict, *, heads: int, kv_heads: int,

@@ -14,8 +14,11 @@ that backs sequence-parallel ring attention
 (Kimi Delta Attention): three Mosaic kernels at head widths of whole
 lanes (forward, and the backward rule's two), XLA's fusions and products
 at every other width; :mod:`mpit_tpu.ops.ssd_scan` is the scalar-decay
-state-space scan (Mamba-2) in chunks with a backward rule of its own,
-XLA's products and fusions; :mod:`mpit_tpu.ops.short_conv` is XLA's
+state-space scan (Mamba-2) in chunks with a backward rule of its own:
+three Mosaic kernels at widths of whole lanes (forward, and the rule's
+walk that makes the chunk-start states again and its walk back), the
+state a VMEM scratch along the chunk axis, XLA's products and fusions
+at every other shape; :mod:`mpit_tpu.ops.short_conv` is XLA's
 fusions.
 :mod:`mpit_tpu.ops.index_select` is a learned selection of keys (an
 indexer's scores and an exact top-k a query): XLA's products and

@@ -186,6 +186,117 @@ def test_the_log_decays_are_summed_in_float32_and_a_lower_sum_shows(
     assert 1e-4 < relative(low, want) < 5e-2
 
 
+# The same recurrence as the Mosaic kernels (``ops/ssd_scan.py``: widths
+# of whole lanes, chunks of 128), in Pallas interpret mode here: against
+# the recurrence and against the XLA form, which the shapes above keep
+# taking.  At chunks of 128 a decay near 0 sums log-decays into the
+# thousands, so the rates' gradient agrees with the recurrence to a
+# hundredth and with the XLA form, which sums the same, far closer.
+KERNEL_SHAPE = dict(heads=4, p=64, groups=2, n=128)
+KERNEL_SCANS = [
+    # what, length, the decay's range, the shape, the rates' tolerance
+    ("whole chunks", 256, (0.5, 0.999), KERNEL_SHAPE, 2e-4),
+    ("a last chunk that is not whole", 200, (0.5, 0.999), KERNEL_SHAPE,
+     2e-4),
+    ("one chunk", 128, (0.5, 0.999), KERNEL_SHAPE, 2e-4),
+    ("one chunk longer than the row", 100, (0.5, 0.999), KERNEL_SHAPE, 2e-4),
+    ("a decay near 1", 256, (0.9999, 0.999999), KERNEL_SHAPE, 2e-4),
+    ("a decay near 0", 256, (1e-9, 1e-3), KERNEL_SHAPE, 3e-2),
+    ("a head a lane tile", 256, (0.5, 0.999),
+     dict(heads=2, p=128, groups=2, n=128), 2e-4),
+    ("four heads a lane tile", 256, (0.5, 0.999),
+     dict(heads=8, p=32, groups=2, n=128), 2e-4),
+]
+
+
+def runs_kernels(fn, *args) -> bool:
+    return "pallas_call" in str(jax.make_jaxpr(fn)(*args))
+
+
+@pytest.mark.parametrize("what,length,decay,shape,a_tol", KERNEL_SCANS,
+                         ids=[s[0] for s in KERNEL_SCANS])
+def test_the_scan_kernels_are_the_recurrence_and_the_xla_form(
+        what, length, decay, shape, a_tol):
+    args = scan_inputs(length, *decay, **shape)
+    # the skip ``D x`` rides in the kernels: a sixth input and gradient
+    args += (jax.random.normal(jax.random.PRNGKey(5), (shape["heads"],)),)
+    ct = jax.random.normal(jax.random.PRNGKey(9), args[0].shape)
+
+    def skipped(scan):
+        return lambda *a: scan(*a[:5]) + a[5][:, None] * a[0]
+
+    assert ssd_scan.takes_kernels(args[0], args[3], ssd_scan.CHUNK)
+    assert runs_kernels(jax.grad(lambda *a: jnp.sum(ssd_scan.ssd_scan(*a))),
+                        *args[:5])
+    got, got_grads = both(
+        lambda *a: ssd_scan.ssd_scan(*a[:5], skip=a[5]), args, ct)
+    want, want_grads = both(skipped(ssd_scan.ssd_scan_reference), args, ct)
+    xla, xla_grads = both(skipped(functools.partial(
+        ssd_scan.ssd_chunked, chunk=ssd_scan.CHUNK)), args, ct)
+    assert bool(jnp.all(jnp.isfinite(got)))
+    assert relative(got, want) < SCAN_TOL
+    assert relative(got, xla) < SCAN_TOL
+    for name, g, w, x in zip("x dt a b c skip".split(), got_grads,
+                             want_grads, xla_grads):
+        assert bool(jnp.all(jnp.isfinite(g))), name
+        assert relative(g, w) < (a_tol if name == "a" else 2e-4), name
+        assert relative(g, x) < 2e-4, name
+
+
+def kernel_rows_do_not_see_each_other(_):
+    x, dt, a, b, c = scan_inputs(200, 0.5, 0.999, **KERNEL_SHAPE)
+    both_rows = ssd_scan.ssd_scan(x, dt, a, b, c)
+    alone = ssd_scan.ssd_scan(x[1:], dt[1:], a, b[1:], c[1:])
+    np.testing.assert_allclose(both_rows[1:], alone, atol=1e-5)
+
+
+def kernel_positions_see_no_later_one(_):
+    x, dt, a, b, c = scan_inputs(384, 0.99, 0.9999, **KERNEL_SHAPE)
+    y = ssd_scan.ssd_scan(x, dt, a, b, c)
+    other = ssd_scan.ssd_scan(x.at[:, 130].add(1.0), dt, a, b, c)
+    changed = jnp.max(jnp.abs(other - y), axis=(0, 2, 3))
+    assert float(jnp.max(changed[:130])) == 0.0     # causal
+    # its own chunk, the next one and the last position
+    assert float(jnp.min(changed[jnp.asarray([130, 200, 300, 383])])) > 1e-6
+
+
+def kernel_sums_are_float32_and_a_lower_sum_shows(monkeypatch):
+    args = scan_inputs(256, 0.9, 0.999, **KERNEL_SHAPE)
+    ct = jnp.ones(args[0].shape)
+    want, want_grads = both(ssd_scan.ssd_scan, args, ct)
+    assert ssd_scan.SUM_DTYPE == jnp.float32
+    monkeypatch.setattr(ssd_scan, "SUM_DTYPE", jnp.bfloat16)
+    low, low_grads = both(ssd_scan.ssd_scan, args, ct)
+    assert 1e-4 < relative(low, want) < 5e-2
+    assert 1e-4 < relative(low_grads[1], want_grads[1]) < 2e-1   # the step's
+
+
+def narrow_widths_and_other_chunks_take_the_xla_form(_):
+    loss = jax.grad(lambda *a, **kw: jnp.sum(ssd_scan.ssd_scan(*a, **kw)))
+    wide = scan_inputs(256, 0.5, 0.999, **KERNEL_SHAPE)
+    assert runs_kernels(loss, *wide)
+    assert not runs_kernels(functools.partial(loss, chunk=64), *wide)
+    for shape in (dict(), dict(heads=4, p=16, groups=2, n=128),
+                  dict(heads=4, p=64, groups=2, n=16),
+                  dict(heads=2, p=64, groups=2, n=128)):
+        narrow = scan_inputs(256, 0.5, 0.999, **shape)
+        assert not ssd_scan.takes_kernels(narrow[0], narrow[3],
+                                          ssd_scan.CHUNK), shape
+        assert not runs_kernels(loss, *narrow), shape
+
+
+KERNEL_PROPERTIES = [kernel_rows_do_not_see_each_other,
+                     kernel_positions_see_no_later_one,
+                     kernel_sums_are_float32_and_a_lower_sum_shows,
+                     narrow_widths_and_other_chunks_take_the_xla_form]
+
+
+@pytest.mark.parametrize("check", KERNEL_PROPERTIES,
+                         ids=[c.__name__ for c in KERNEL_PROPERTIES])
+def test_the_scan_kernels_keep_what_the_scan_promises(check, monkeypatch):
+    check(monkeypatch)
+
+
 def test_mismatched_shapes_are_refused():
     x, dt, a, b, c = scan_inputs(16, 0.5, 0.9, heads=4, groups=2)
     with pytest.raises(ValueError):
@@ -259,6 +370,31 @@ def test_the_gated_norm_is_over_each_groups_channels_gate_first():
     # the gate comes first: with z = 0 everywhere SiLU(z) = 0 gates all
     closed = {**p, "w_in": p["w_in"].at[:, :heads * hd].set(0)}
     assert float(jnp.max(jnp.abs(mixer(x, closed)[0]))) < 1e-6
+
+
+@pytest.mark.parametrize("groups", [1, 2, 8])
+def test_the_group_norm_as_products_is_the_norm_over_each_groups_channels(
+        groups):
+    """``group_rms_norm`` takes a group's mean square and its way back
+    as two products with the groups' membership, named exact whatever
+    the ambient precision: value and both gradients are ``rms_norm``'s
+    over the ``(..., groups, width)`` view."""
+    rs = np.random.RandomState(groups)
+    x = jnp.asarray(3.0 * rs.randn(2, 24, 64), jnp.float32)
+    w = jnp.asarray(1 + 0.3 * rs.randn(64), jnp.float32)
+    ct = jnp.asarray(rs.randn(2, 24, 64), jnp.float32)
+
+    def viewed(x, w):
+        return transformer.rms_norm(
+            x.reshape(2, 24, groups, -1), w.reshape(groups, -1), 1e-5
+        ).reshape(x.shape)
+
+    got, back = jax.vjp(
+        lambda x, w: transformer.group_rms_norm(x, w, groups, 1e-5), x, w)
+    want, want_back = jax.vjp(viewed, x, w)
+    assert relative(got, want) < 1e-6
+    for g, v in zip(back(ct), want_back(ct)):
+        assert relative(g, v) < 1e-5
 
 
 # -- (c) experts of two matrices at a width that is no whole tile ----------------
@@ -596,8 +732,9 @@ def test_the_steps_operations_carry_the_blocks_scopes(case):
 def test_a_mamba_layer_keeps_its_input_and_the_scans_output_alone():
     """The mixer's checkpoint: beside the layer's input and its
     parameters, the one array kept for the backward pass is the scan's
-    output (``T x heads x head_dim``); z, x, B, C and the step are made
-    again."""
+    output (``T x heads x head_dim`` floats, as the row-major view the
+    kernels write, with the skip in it); z, x, B, C and the step are
+    made again."""
     from jax._src.ad_checkpoint import saved_residuals
 
     d, heads, hd, groups, n = 32, 4, 8, 2, 8
@@ -611,7 +748,7 @@ def test_a_mamba_layer_keeps_its_input_and_the_scans_output_alone():
             *transformer.SSM_KEPT))
     kept = saved_residuals(lambda x, p: mixer(x, p)[0], x, p)
     made = [shape.shape for shape, why in kept if "argument" not in why]
-    assert made == [(2, 48, heads, hd)]
+    assert made == [(2, 48, heads * hd)]
     assert transformer.SSM_KEPT == (ssd_scan.SSD_OUT,)
 
 
