@@ -21,9 +21,11 @@ state a VMEM scratch along the chunk axis, XLA's products and fusions
 at every other shape; :mod:`mpit_tpu.ops.short_conv` is XLA's
 fusions.
 :mod:`mpit_tpu.ops.index_select` is a learned selection of keys (an
-indexer's scores and an exact top-k a query): XLA's products and
-fusions too; :mod:`mpit_tpu.ops.select_bits` is the format its set
-travels in, the bits the flash kernels mask by.
+indexer's scores and an exact top-k a query): one Mosaic kernel a call,
+a block of rows' scores made over the causal columns alone, kept as
+integer keys in VMEM, bisected and packed there;
+:mod:`mpit_tpu.ops.select_bits` is the format its set travels in, the
+bits the flash kernels mask by.
 
 Every op has a jnp reference implementation (``*_reference``) used for
 testing and as a CPU fallback; kernels run in pallas interpret mode off-TPU

@@ -118,6 +118,13 @@ SELECT_CASES = [
     ("a length that is no whole number of row blocks", 1, 100, 24, 32, True),
     ("topk over the length: every earlier position", 2, 48, 64, 16, False),
     ("topk one", 1, 40, 1, 8, True),
+    ("a row block that straddles topk", 1, 64, 24, 16, False),
+    ("a length over one column tile that is no whole number of tiles", 1, 70,
+     8, 16, False),
+    ("ties at the threshold whose equals lie in different column tiles", 1,
+     96, 20, 8, True),
+    ("two sequences with different keys in one call", 2, 80, 12, 16, False),
+    ("a length past one group of 4096 keys' words", 1, 4224, 300, 256, False),
 ]
 
 
@@ -134,6 +141,8 @@ def test_the_selection_is_the_stable_sorts(what, batch, length, topk, rows,
     got = np.asarray(select_bits.unpack(words, length))
     want = np.asarray(index_select.index_select_reference(qi, ki, w, topk))
     assert np.array_equal(got, want), what
+    if "different column tiles" in what:
+        assert equals_across_tiles(qi[0], ki[0], w[0], want[0], rows)
     per_row = np.minimum(np.arange(length) + 1, topk)
     assert np.array_equal(got.sum(-1), np.broadcast_to(per_row, got.shape[:2]))
     assert not np.triu(got, 1).any()   # nothing above the diagonal
@@ -146,6 +155,104 @@ def test_the_selection_is_the_stable_sorts(what, batch, length, topk, rows,
     if late.any():
         assert float(overlap) == pytest.approx(
             (got & near)[:, late].sum() / got[:, late].sum(), rel=1e-6)
+
+
+def equals_across_tiles(qi, ki, w, chosen, tile):
+    """Whether some row's lowest chosen score has equals among the row's
+    candidates that are not all taken and lie in different tiles of
+    ``tile`` columns: the case the ties' second bisection exists for."""
+    scores = np.asarray(index_select.index_scores(qi, ki, w))
+    for t, row in enumerate(chosen):
+        equals = np.flatnonzero(
+            scores[t, :t + 1] == scores[t, :t + 1][row[:t + 1]].min())
+        if not row[equals].all() and len(set(equals // tile)) > 1:
+            return True
+    return False
+
+
+def test_the_kernels_tiles_of_scores_are_index_scores_to_the_bit():
+    """A tile of the kernel's scores, made inside a Pallas body from
+    refs (interpreted), against ``index_scores`` of the same keys, on
+    random float32 inputs: one function, one order of the heads' sum, so
+    the sets of the tests' reference and of the kernel are made from
+    the same numbers.  (Tile by tile and both compiled: how the CPU's
+    own product sums 8 terms depends on the operands' shapes, and a
+    compiled multiply-add rounds once where two operations round twice,
+    each in the last bit.)"""
+    from jax.experimental import pallas as pl
+
+    length, tile, heads = 96, 32, 5
+    qi, ki, w = (x[0] for x in index_inputs(1, length, heads=heads, seed=4))
+
+    def body(q_ref, k_ref, w_ref, out_ref):
+        out_ref[...] = index_select._tile_scores(
+            k_ref[...], heads, lambda h: (q_ref[h], w_ref[h:h + 1, :]),
+            index_select.SCORE_PRECISION)
+
+    tiles = pl.pallas_call(
+        body, grid=(length // tile,),
+        in_specs=[pl.BlockSpec((heads, 8, length), lambda t: (0, 0, 0)),
+                  pl.BlockSpec((tile, 8), lambda t: (t, 0)),
+                  pl.BlockSpec((heads, length), lambda t: (0, 0))],
+        out_specs=pl.BlockSpec((tile, length), lambda t: (t, 0)),
+        out_shape=jax.ShapeDtypeStruct((length, length), jnp.float32),
+        interpret=True)(qi.transpose(1, 2, 0), ki, w.T)
+    bits = lambda x: np.asarray(x).view(np.uint32)
+    for t in range(length // tile):
+        keys = slice(t * tile, (t + 1) * tile)
+        assert np.array_equal(
+            bits(tiles[keys].T),
+            bits(jax.jit(index_select.index_scores)(qi, ki[keys], w))), t
+    # and the sum over the heads is taken in their order, in float32
+    by_hand = sum(np.maximum(np.asarray(qi)[:, h] @ np.asarray(ki).T, 0)
+                  * np.asarray(w)[:, h:h + 1] for h in range(heads))
+    np.testing.assert_allclose(np.asarray(tiles).T, by_hand, rtol=1e-5,
+                               atol=1e-5)
+
+
+def test_a_lowered_score_precision_is_another_trace(monkeypatch):
+    """``chipbench/reference/probe_keye.py`` plants its fault by setting
+    ``SCORE_PRECISION`` after a sound run in the same process at the
+    same shapes: the precision is part of the key of the kernel's own
+    ``jit``, so the second build's products are the planted ones and
+    not the first trace's."""
+    monkeypatch.setattr(index_select, "ROWS", 16)
+    qi, ki, w = index_inputs(1, 32)
+
+    def products():
+        text = str(jax.make_jaxpr(
+            lambda *a: index_select.index_select(*a, 8))(qi, ki, w))
+        return {p for p in ("HIGHEST", "DEFAULT")
+                if f"precision=(Precision.{p}" in text}
+
+    assert products() == {"HIGHEST"}
+    monkeypatch.setattr(index_select, "SCORE_PRECISION",
+                        jax.lax.Precision.DEFAULT)
+    assert products() == {"DEFAULT"}
+    monkeypatch.undo()
+    monkeypatch.setattr(index_select, "ROWS", 16)
+    assert products() == {"HIGHEST"}
+
+
+def test_a_sequence_past_the_chips_vmem_is_refused():
+    """The keys of a block and the whole key head live in VMEM: past
+    the chip's 128 MiB the call says so before Mosaic does, and the
+    longest sequence the docstring names is let through."""
+    def shapes(length):
+        return (jax.ShapeDtypeStruct((1, length, 16, 64), jnp.float32),
+                jax.ShapeDtypeStruct((1, length, 64), jnp.float32),
+                jax.ShapeDtypeStruct((1, length, 16), jnp.float32))
+
+    select = lambda *a: index_select.index_select(*a, 2048)
+    assert jax.eval_shape(select, *shapes(54272))[0].shape == (
+        1, 54272, select_bits.words_of(54272))
+    with pytest.raises(ValueError, match="MiB of VMEM"):
+        jax.eval_shape(select, *shapes(54272 + 256))
+    # the cell's shape: the keys 8, the key head twice 8, the other
+    # blocks twice 2.5 and the stock 16 MiB
+    assert index_select._vmem_bytes(
+        16, 8192, 64, 256, select_bits.words_of(8192)) == pytest.approx(
+            34.5 * 2**20, rel=0.01)
 
 
 def test_all_scores_equal_is_the_lowest_positions(monkeypatch):
@@ -692,9 +799,10 @@ def test_a_layer_keeps_its_input_the_kernels_two_and_the_bits_alone():
     ])
     jaxpr = str(jax.make_jaxpr(jax.grad(
         lambda x, p: jnp.sum(attend(x, p)[0]), argnums=(0, 1)))(x, p))
-    # the indexer once, forward only: its map over blocks of rows and,
-    # inside it, the two bisections
-    assert jaxpr.count("scan[") == 3
+    # the indexer once, forward only: one Mosaic call and, inside it,
+    # the two bisections (no map over blocks of rows is left)
+    assert jaxpr.count("name=_select_blocks") == 1
+    assert jaxpr.count("scan[") == 2
 
 
 # -- the counters on the round spans ----------------------------------------------

@@ -695,9 +695,11 @@ def test_the_shares_routed_parts_are_the_whole_layer_and_nothing_is_twice():
 # printed it**: the walk gained a table and the attention a keyword, and
 # a call without the block-diffusion mask must still trace to the
 # program it was, to the character (a selection's too).  (Since PR 57
-# the digests are that PR's, here and there: ``tests/test_keye.py``.)
+# the digests are that PR's, here and there: ``tests/test_keye.py``;
+# Keye's is PR 60's, which made the indexer one Mosaic kernel on
+# purpose.)
 PARENTS_STEP = {
-    "keye-l6e8-local": "5bed1b64ef7097a1",
+    "keye-l6e8-local": "ce0ac9dd614a14d3",
     "kimi-linear-l5e8-local": "8a0acdd925172b3e",
     "olmoe-l1-ps1w-su1": "38c59ae0597d4cb8",
 }

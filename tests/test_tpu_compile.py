@@ -455,3 +455,31 @@ def test_the_state_space_scan_compiles_at_the_published_shape(one_chip):
     assert not re.findall(r"f32\[[0-9,]*128,128\]", text)
     assert "f32[1,8,64,128,512]" in text       # the chunk-start states
     assert compiled.memory_analysis().temp_size_in_bytes < 1e9
+
+
+def test_the_indexer_compiles_at_the_published_shape(one_chip):
+    """16 heads of 64 against one key head at 8192 positions, the top
+    2048 a query: the selection is one Mosaic call for the chip whose
+    VMEM holds a block's ``8192 x 256`` keys (8 MB) beside its operands,
+    no array of the program holds a row block's scores, and what leaves
+    is the bits and two counts a row."""
+    from mpit_tpu.ops import index_select
+
+    def of(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    real = jax.default_backend
+    jax.default_backend = lambda: "tpu"    # ``use_interpret`` asks
+    try:
+        compiled = jax.jit(
+            lambda *a: index_select.index_select(*a, 2048)).lower(
+                of(1, 8192, 16, 64), of(1, 8192, 64),
+                of(1, 8192, 16)).compile()
+    finally:
+        jax.default_backend = real
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "s32[1,8192,256]" in text                  # the bits
+    assert not re.findall(r"[fsu]32\[(1,)?256,8192\]", text)  # no scores
+    assert "while" not in text and "conditional" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 80e6
