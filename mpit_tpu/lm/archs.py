@@ -67,10 +67,12 @@ SIZES: Dict[str, Size] = {
                         "kimi's kda or full_attention, trinity's "
                         "sliding_attention (window keys, rotary positions) "
                         "or full_attention (no positions), nemotron's "
-                        "mamba, moe or attention: one branch a layer"),
+                        "mamba, moe or attention: one branch a layer; "
+                        "qwen3next's linear_attention or full_attention"),
     "conv_kernel": Size(3, "the taps of a short causal depthwise convolution "
                         "(lfm2's gated one; kimi's on q, k and v: 4; "
-                        "nemotron's on x, B and C together, with a bias: 4)"),
+                        "nemotron's on x, B and C together, with a bias: 4; "
+                        "qwen3next's on q, k and v together, no bias: 4)"),
     # ouro's loop
     "loop_steps": Size(4, "how often the layers are applied, same weights"),
     "exit_beta": Size(0.1, "the exit distribution's entropy's weight in the "
@@ -131,6 +133,17 @@ SIZES: Dict[str, Size] = {
                        "std of the mixers' output projections "
                        "(rescale_prenorm_residual: the published layer "
                        "count); 0: seeded as the rest"),
+    # qwen3next's gated delta rule and its partly rotated heads
+    "gdn_key_heads": Size(0, "key (and query) heads of the gated delta "
+                          "rule's state"),
+    "gdn_value_heads": Size(0, "its value heads, a multiple of the key "
+                            "heads: key head j serves value heads r j .. "
+                            "r j + r - 1"),
+    "gdn_key_dim": Size(0, "a key head's width: the state's rows"),
+    "gdn_value_dim": Size(0, "a value head's width: the state's columns"),
+    "rotary_factor": Size(1.0, "the share of a head's width that is "
+                          "rotated, from its first dimension on "
+                          "(partial_rotary_factor); 1: the whole head"),
 }
 
 DEFAULTS = {name: size.default for name, size in SIZES.items()}
@@ -325,6 +338,25 @@ def _nemotron(s, attn):
                    **_heads(s))
 
 
+def _qwen3next(s, attn):
+    _check_share(s)
+    kinds = _layer_kinds(s)
+    state = tuple(s[name] for name in (
+        "gdn_key_heads", "gdn_value_heads", "gdn_key_dim", "gdn_value_dim"))
+    rotary = (s["head_dim"] or s["d_model"] // s["n_heads"]
+              ) * s["rotary_factor"]
+    if min(state) < 1 or s["gdn_value_heads"] % s["gdn_key_heads"] \
+            or s["shared_width"] < 0 or rotary < 2 or rotary % 2:
+        raise ValueError(f"qwen3next needs gdn_key_heads, gdn_value_heads "
+                         f"(a multiple of them), gdn_key_dim and "
+                         f"gdn_value_dim: {state}; shared_width "
+                         f"{s['shared_width']} of 0 or more; rotary_factor "
+                         f"{s['rotary_factor']} of the head an even count "
+                         f"of dimensions: {rotary}")
+    return _module("Qwen3NextDecoder", s, attn(), layer_types=kinds,
+                   **_heads(s))
+
+
 # what each block is: its decoder's docstring (``models/transformer.py``)
 BLOCKS: Dict[str, Block] = {
     "gpt2": Block((), _gpt2, sample_len=0),
@@ -374,6 +406,12 @@ BLOCKS: Dict[str, Block] = {
             "ssm_head_dim", "ssm_groups", "ssm_state", "ssm_chunk",
             "route_scale", "shared_experts", "shared_width", "init_depth"),
         _nemotron, loss=OWN_LOSS),
+    "qwen3next": Block(
+        _GROUPED + _SPARSE + _SHARE + _ROTARY + (
+            "layer_types", "conv_kernel", "gdn_key_heads",
+            "gdn_value_heads", "gdn_key_dim", "gdn_value_dim",
+            "rotary_factor", "shared_experts", "shared_width"),
+        _qwen3next, loss=OWN_LOSS),
 }
 ARCHS = tuple(BLOCKS)
 

@@ -33,7 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
-from mpit_tpu.ops.delta_rule import KDA_OUT, kda_scan
+from mpit_tpu.ops.delta_rule import GDN_OUT, KDA_OUT, gdn_scan, kda_scan
 from mpit_tpu.ops.flash_attention import (
     FLASH_LSE, FLASH_OUT, attention_reference, flash_attention,
     flash_call_counts, operand_dtype,
@@ -422,7 +422,8 @@ def grouped_attention(h: jnp.ndarray, wq: jnp.ndarray, wk: jnp.ndarray,
                       attn: AttnFn, scale: float = 1.0, window: int = 0,
                       qk_norm: Optional[tuple] = None,
                       period: int = 0,
-                      gate: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+                      gate: Optional[jnp.ndarray] = None,
+                      rotary: int = 0) -> jnp.ndarray:
     """Attention over grouped KV heads of their own width on the normed
     stream ``h (B, L, d)``, projected back to ``(B, L, d)``: bias-free
     projections to ``heads`` query and ``kv_heads`` key and value heads
@@ -433,7 +434,13 @@ def grouped_attention(h: jnp.ndarray, wq: jnp.ndarray, wk: jnp.ndarray,
     the queries and the keys, before the rotary embedding.  ``period``
     as :func:`rope_by` takes it.  ``gate (d, heads * head_dim)``: the
     heads' output is multiplied elementwise by ``sigmoid(h gate)``
-    before ``wo``, under the scope ``attn_gate``.  Every
+    before ``wo``, under the scope ``attn_gate``.  ``rotary``: **the
+    first ``rotary`` dimensions of every query and key head are rotated**
+    (``partial_rotary_factor`` x ``head_dim``; ``inv_freq`` is then that
+    part's table, ``rotary / 2`` pairs in :func:`rope_by`'s half-split
+    pairing over those dimensions alone) and the rest pass as they are;
+    0, or ``head_dim``: the whole head, :func:`rope_by`'s result to the
+    bit.  Every
     product at the backend's default precision, one bf16 pass on a TPU:
     Mellum's scores are O(1) without a norm, and LFM2's with its per-head
     norm read the same gradient error against the float32 reference with
@@ -446,6 +453,10 @@ def grouped_attention(h: jnp.ndarray, wq: jnp.ndarray, wk: jnp.ndarray,
         x = x.reshape(b, l, count, head_dim)
         if qk_norm is not None:
             x = rms_norm(x, weight, qk_norm[2])
+        if inv_freq is not None and rotary not in (0, head_dim):
+            return jnp.concatenate(
+                [rope_by(x[..., :rotary], inv_freq, scale, period),
+                 x[..., rotary:]], axis=-1)
         return x if inv_freq is None else rope_by(x, inv_freq, scale, period)
 
     q = heads_of(q, heads, qk_norm and qk_norm[0])
@@ -1283,7 +1294,15 @@ def shared_sparse_experts(block, x, norm):
     ``expert_act`` of ``relu2`` (:class:`NemotronBlock`) has experts of
     two matrices and no gate (:data:`EXPERT_FORMS`), and one with a
     ``shared_width`` a shared expert of that inner width, not
-    ``shared_experts`` times the routed experts'."""
+    ``shared_experts`` times the routed experts'.  A block with a
+    ``router_act`` of ``softmax`` (:class:`Qwen3NextBlock`) scores with a
+    softmax over all the experts, renormalised over the chosen, **with
+    no selection bias**: it has no ``router_bias`` leaf and counts
+    :data:`JOYAI_MOE_STATS`' first three.  One with ``shared_gated``
+    multiplies the shared expert's output by **a gate of its own**,
+    ``sigmoid(h w_s)``, a scalar a token (the leaf
+    ``shared_expert_gate (d, 1)``), and the statistics end in the
+    gate's mean."""
     d, e, f = block.d_model, block.n_experts, block.expert_width
     held = block.experts_held or e
     shared = getattr(block, "shared_width", 0) or block.shared_experts * f
@@ -1301,7 +1320,8 @@ def shared_sparse_experts(block, x, norm):
     # that (``models/flat.py`` ``plain_ranges``).  JoyAI's, Kimi's and
     # Nemotron's configurations publish no rate: theirs is 0, nothing
     # moves the bias and their programs are what they were
-    bias = block.param("router_bias", _INIT, (e,))
+    soft = getattr(block, "router_act", "sigmoid") == "softmax"
+    bias = None if soft else block.param("router_bias", _INIT, (e,))
     rate = float(getattr(block, "bias_rate", 0.0))
 
     def shape(name, width):
@@ -1312,13 +1332,19 @@ def shared_sparse_experts(block, x, norm):
     shared_w = tuple(block.param(f"shared_{name}", _INIT,
                                  shape(name, shared))
                      for name in names) if shared else ()
+    gate_w = block.param("shared_expert_gate", _INIT, (d, 1)) \
+        if shared and getattr(block, "shared_gated", False) else None
 
     # recomputed in the backward pass, as Mellum's and LFM2's and for
     # their reason; the shared expert with it (three products 768
     # wide: a hundredth of the step)
     @jax.checkpoint
-    def branch(x, norm, router, bias, routed, shared_w):
+    def branch(x, norm, router, bias, routed, shared_w, gate_w):
         def route(logits):
+            if soft:
+                return (*moe.route_top_k(
+                    jax.nn.softmax(logits, axis=-1), block.experts_per_tok,
+                    renormalise=True), ())
             scores = jax.nn.sigmoid(logits)
             weights, chosen = moe.route_top_k(
                 scores, block.experts_per_tok, renormalise=True,
@@ -1343,11 +1369,16 @@ def shared_sparse_experts(block, x, norm):
         if shared_w:
             with jax.named_scope("shared_expert"):
                 # every token, whole on every share: counted once
-                y = y + shared_fn(rms_norm(x, norm, block.norm_eps),
-                                  *shared_w)
+                h = rms_norm(x, norm, block.norm_eps)
+                out = shared_fn(h, *shared_w)
+                if gate_w is not None:
+                    gate = jax.nn.sigmoid(h @ gate_w)        # (B, L, 1)
+                    out = gate * out
+                    stats += (jnp.mean(jax.lax.stop_gradient(gate)),)
+                y = y + out
         return y, stats
 
-    return branch(x, norm, router, bias, routed, shared_w)
+    return branch(x, norm, router, bias, routed, shared_w, gate_w)
 
 
 class JoyaiBlock(nn.Module):
@@ -2746,4 +2777,275 @@ class NemotronDecoder(nn.Module):
             stats[SSM_DECAY_MEAN] = jnp.stack(decays)
         if routing:
             stats.update(zip(JOYAI_MOE_STATS, map(jnp.stack, zip(*routing))))
+        return loss, stats
+
+
+# ---------------------------------------------------------------------------
+# The gated-delta hybrid (Qwen3-Next, ``model_type`` ``qwen3_next``; the
+# configuration's keys are those of its ``config.json``, the linear
+# layers' equations Gated DeltaNet's, arXiv:2412.06464, as the public
+# ``qwen3_next`` module has them).  Three layers of every four are
+# **Gated DeltaNet**: one product of the normed input gives q, k, v and
+# the output gate ``z``, a narrow one ``beta`` and the decay's step;
+# **one** depthwise causal convolution with no bias and a SiLU runs over
+# q, k and v together; the state, ``d_k x d_v`` a value head, decays by
+# **one scalar a head** and is corrected by the delta rule, **``H_k`` key
+# heads serving ``H_v = r H_k`` value heads** (``ops/delta_rule.py``
+# ``gdn_scan``); the read-out is RMSNormed a head **and then** gated by
+# ``SiLU(z)``.  The fourth is grouped attention with the per-head
+# query/key norm, **the first ``rotary_factor`` of each head rotated**
+# and the rest passed, and a sigmoid gate cut from the query's product
+# (held as a leaf of its own, as Trinity's).  Every MLP is sparse: a
+# softmax router with no bias over all ``n_experts``, renormalised over
+# the chosen, this chip's share of the routed experts, and a shared
+# expert **with a gate of its own**, ``sigmoid(h w_s)``.  **Every norm
+# on the stream and on the attention's heads stores its weight as an
+# offset from one**, ``x / rms(x) (1 + w)`` with ``w`` seeded at 0; the
+# delta layers' head norm is plain.  The plain float32 reference it is
+# held to is ``chipbench/reference/qwen3next_plain.py``, which shares no
+# code with this file and steps the state a position at a time
+# (tests/test_qwen3next.py).
+# ---------------------------------------------------------------------------
+
+#: the kinds of token mixer ``layer_types`` may name
+QWEN3NEXT_MIXERS = ("linear_attention", "full_attention")
+#: What a ``linear_attention`` mixer's checkpoint keeps beside its
+#: input: the scan's result (``T x value heads x d_v`` floats a layer),
+#: so that the backward pass makes q, k, v, z, the decay and ``beta``
+#: again (two products and a convolution) and runs the scan's own
+#: backward rule, which computes the chunks again, once and not twice.
+GDN_KEPT = (GDN_OUT,)
+#: the names of the decay's mean and of the shared expert's gate's mean
+#: in the step's telemetry (gauges ``mpit_lm_gdn_decay_mean``, one entry
+#: a ``linear_attention`` layer, and ``mpit_lm_shared_gate_mean``, one a
+#: layer)
+GDN_DECAY_MEAN = "lm_gdn_decay_mean"
+SHARED_GATE_MEAN = "lm_shared_gate_mean"
+#: the sparse layers' counters: no bias, so nothing flips a choice
+QWEN3NEXT_MOE_STATS = JOYAI_MOE_STATS[:3]
+
+
+def gated_delta_mixer(x: jnp.ndarray, p: dict, *, key_heads: int,
+                      value_heads: int, key_dim: int, value_dim: int,
+                      eps: float):
+    """A Gated DeltaNet mixer on the stream ``x (B, L, d)`` with the
+    weights ``p``, projected back to ``(B, L, d)``, and the mean of the
+    decay ``alpha`` over positions and value heads; pure in both.  Four
+    scopes: ``gdn_proj`` (the norm before the layer, ``W_qkvz``,
+    ``W_ba``, the heads' L2 norms, the decay and ``beta``), ``gdn_conv``
+    (the one convolution over q, k and v with its SiLU), ``gdn_scan``
+    (the chunked state) and ``gdn_out`` (the heads' RMSNorm, **then**
+    the gate ``SiLU(z)``, and ``W_out``)."""
+    b, l, _ = x.shape
+    keys, values = key_heads * key_dim, value_heads * value_dim
+    mixed = 2 * keys + values
+    with jax.named_scope("gdn_proj"):
+        h = rms_norm(x, 1.0 + p["attn_norm"], eps)
+        qkvz, ba = h @ p["w_qkvz"], h @ p["w_ba"]
+    with jax.named_scope("gdn_conv"):
+        qkv = jax.nn.silu(causal_depthwise_conv(qkvz[..., :mixed],
+                                                p["conv"]))
+    with jax.named_scope("gdn_proj"):
+        q = l2_norm(qkv[..., :keys].reshape(b, l, key_heads, key_dim)
+                    ) * key_dim ** -0.5
+        k = l2_norm(qkv[..., keys:2 * keys].reshape(b, l, key_heads, key_dim))
+        v = qkv[..., 2 * keys:].reshape(b, l, value_heads, value_dim)
+        beta = jax.nn.sigmoid(ba[..., :value_heads])
+        # the log-decay, one number a value head: never positive
+        g = -jnp.exp(p["a_log"]) * jax.nn.softplus(
+            ba[..., value_heads:] + p["dt_bias"])
+        decay_mean = jnp.mean(jnp.exp(jax.lax.stop_gradient(g)))
+    with jax.named_scope("gdn_scan"):
+        o = gdn_scan(q, k, v, g, beta)
+    with jax.named_scope("gdn_out"):
+        z = qkvz[..., mixed:].reshape(b, l, value_heads, value_dim)
+        o = rms_norm(o, p["o_norm"], eps) * jax.nn.silu(z)
+        return o.reshape(b, l, values) @ p["wo"], decay_mean
+
+
+def partly_rotated_attention(x: jnp.ndarray, p: dict, *, heads: int,
+                             kv_heads: int, head_dim: int, rotary: int,
+                             theta: float, eps: float,
+                             attn: AttnFn) -> jnp.ndarray:
+    """One ``full_attention`` layer's branch on the stream ``x (B, L,
+    d)``: the offset norm before the layer and :func:`grouped_attention`
+    with the per-head offset norms on queries and keys, the first
+    ``rotary`` dimensions of a head rotated and the sigmoid gate; under
+    ``attn``, the gate's product and elementwise pass under
+    ``attn_gate``."""
+    with jax.named_scope("attn"):
+        return grouped_attention(
+            rms_norm(x, 1.0 + p["attn_norm"], eps), p["wq"], p["wk"],
+            p["wv"], p["wo"], heads=heads, kv_heads=kv_heads,
+            head_dim=head_dim, inv_freq=plain_inv_freq(rotary, theta),
+            attn=attn, qk_norm=(1.0 + p["q_norm"], 1.0 + p["k_norm"], eps),
+            gate=p["wg"], rotary=rotary)
+
+
+class Qwen3NextBlock(nn.Module):
+    d_model: int
+    mixer: str               # of QWEN3NEXT_MIXERS
+    n_heads: int
+    kv_heads: int
+    head_dim: int
+    rotary: int              # the rotated dimensions of a head, from 0 on
+    gdn_key_heads: int
+    gdn_value_heads: int
+    gdn_key_dim: int
+    gdn_value_dim: int
+    n_experts: int           # the router's width: every expert there is
+    experts_per_tok: int
+    expert_width: int
+    shared_width: int        # the shared expert's own inner width
+    experts_first: int = 0   # the share held here: a contiguous range
+    experts_held: int = 0    # 0: all of them
+    shared_experts: int = 1
+    conv_kernel: int = 4
+    rope_theta: float = 1e7
+    norm_eps: float = 1e-6
+    attn_fn: Optional[AttnFn] = None
+    router_act = "softmax"   # :func:`shared_sparse_experts`' router
+    shared_gated = True      # and its shared expert's own gate
+
+    @nn.compact
+    def __call__(self, x: jnp.ndarray):
+        """``(the stream after the layer, the ``linear_attention``
+        mixer's mean decay or None, the sparse branch's statistics in
+        :data:`QWEN3NEXT_MOE_STATS`' order and the shared gate's
+        mean)``."""
+        d, eps = self.d_model, self.norm_eps
+        ones, zeros = nn.initializers.ones, nn.initializers.zeros
+        decay = None
+        if self.mixer == "linear_attention":
+            hk, hv = self.gdn_key_heads, self.gdn_value_heads
+            dk, dv = self.gdn_key_dim, self.gdn_value_dim
+            mixed = 2 * hk * dk + hv * dv
+            p = {name: self.param(name, init, shape)
+                 for name, init, shape in (
+                     ("attn_norm", zeros, (d,)),
+                     ("w_qkvz", _INIT, (d, mixed + hv * dv)),
+                     ("w_ba", _INIT, (d, 2 * hv)),
+                     # at LFM2's scale and for its reason: at 0.02 the
+                     # taps' gradients are lost in the norm of the whole
+                     ("conv", LFM2_TAPS_INIT, (self.conv_kernel, mixed)),
+                     ("a_log", kda_a_log_init, (hv,)),
+                     ("dt_bias", kda_dt_bias_init, (hv,)),
+                     ("o_norm", ones, (dv,)),
+                     ("wo", _INIT, (hv * dv, d)))}
+            y, decay = jax.checkpoint(
+                partial(gated_delta_mixer, key_heads=hk, value_heads=hv,
+                        key_dim=dk, value_dim=dv, eps=eps),
+                policy=jax.checkpoint_policies.save_only_these_names(
+                    *GDN_KEPT))(x, p)
+        elif self.mixer == "full_attention":
+            hq, hkv, hd = self.n_heads, self.kv_heads, self.head_dim
+            p = {name: self.param(name, init, shape)
+                 for name, init, shape in (
+                     ("attn_norm", zeros, (d,)),
+                     ("wq", _INIT, (d, hq * hd)), ("wk", _INIT, (d, hkv * hd)),
+                     ("wv", _INIT, (d, hkv * hd)), ("wg", _INIT, (d, hq * hd)),
+                     ("wo", _INIT, (hq * hd, d)),
+                     ("q_norm", zeros, (hd,)), ("k_norm", zeros, (hd,)))}
+            # kept: the layer's input and the flash rule's two, as
+            # Trinity's attention branch
+            y = jax.checkpoint(
+                partial(partly_rotated_attention, heads=hq, kv_heads=hkv,
+                        head_dim=hd, rotary=self.rotary,
+                        theta=self.rope_theta, eps=eps,
+                        attn=self.attn_fn if self.attn_fn is not None
+                        else default_attn()),
+                policy=jax.checkpoint_policies.save_only_these_names(
+                    *JOYAI_ATTN_KEPT))(x, p)
+        else:
+            raise ValueError(f"layer type {self.mixer!r}; have "
+                             f"{QWEN3NEXT_MIXERS}")
+        x = x + y
+        y, stats = shared_sparse_experts(
+            self, x, 1.0 + self.param("mlp_norm", zeros, (d,)))
+        return x + y, decay, stats
+
+
+class Qwen3NextDecoder(nn.Module):
+    """Causal LM of :class:`Qwen3NextBlock` layers: a token table (at
+    :data:`MELLUM_EMBED_INIT`'s scale, for its reason: a share of the
+    experts is held), the layers (layer ``i``'s mixer is
+    ``layer_types[i]``, every MLP sparse), a final offset RMSNorm and an
+    untied head.  Like :class:`KimiDecoder` it is called with the targets
+    and returns its own loss, the head's mean next-token NLL, with its
+    statistics (``lm/model.py`` closes over it):
+
+    - :data:`GDN_DECAY_MEAN`: the mean of the decay ``alpha`` over
+      positions and value heads, one entry a ``linear_attention`` layer
+      (at 0 the layer has no memory, at 1 it is an undecayed delta rule);
+    - :data:`SHARED_GATE_MEAN`: the mean of ``sigmoid(h w_s)`` over the
+      tokens, one entry a layer (at 0 the shared expert is off, at 1 it
+      is ungated);
+    - the routing counters of every layer under ``lm/model.py``
+      ``MOE_STATS``' first three names.
+
+    The head's product, norm and loss is under ``jax.checkpoint``,
+    keeping the rows' log-sum-exp by name (:func:`row_lse`)."""
+
+    vocab: int = 256
+    d_model: int = 64
+    n_heads: int = 4
+    kv_heads: int = 2
+    head_dim: int = 16
+    rotary_factor: float = 0.25
+    gdn_key_heads: int = 2
+    gdn_value_heads: int = 4
+    gdn_key_dim: int = 16
+    gdn_value_dim: int = 16
+    layer_types: tuple = ("linear_attention", "linear_attention",
+                          "linear_attention", "full_attention")
+    n_experts: int = 8
+    experts_per_tok: int = 2
+    expert_width: int = 32
+    shared_width: int = 32
+    experts_first: int = 0
+    experts_held: int = 0
+    shared_experts: int = 1
+    conv_kernel: int = 4
+    rope_theta: float = 1e7
+    norm_eps: float = 1e-6
+    attn_fn: Optional[AttnFn] = None
+
+    @nn.compact
+    def __call__(self, tokens: jnp.ndarray, targets: jnp.ndarray):
+        d, eps = self.d_model, self.norm_eps
+        sizes = {field: getattr(self, field) for field in (
+            "d_model", "n_heads", "kv_heads", "head_dim", "gdn_key_heads",
+            "gdn_value_heads", "gdn_key_dim", "gdn_value_dim", "n_experts",
+            "experts_per_tok", "expert_width", "shared_width",
+            "experts_first", "experts_held", "shared_experts", "conv_kernel",
+            "rope_theta", "norm_eps", "attn_fn")}
+        rotary = int(self.head_dim * self.rotary_factor)
+
+        @partial(jax.checkpoint,
+                 policy=jax.checkpoint_policies.save_only_these_names(
+                     HEAD_LSE))
+        def head_nll(u, norm, head, targets):
+            with jax.named_scope("head_loss"):
+                z = rms_norm(u, 1.0 + norm, eps) @ head
+                return row_lse(z) - jnp.take_along_axis(
+                    z, targets[..., None], axis=-1)[..., 0]
+
+        decays, counted = [], []
+        with jax.named_scope("embed"):
+            x = self.param("embed", MELLUM_EMBED_INIT,
+                           (self.vocab, d))[tokens]
+        for mixer in self.layer_types:
+            x, decay, stats = Qwen3NextBlock(
+                mixer=mixer, rotary=rotary, **sizes)(x)
+            decays += [] if decay is None else [decay]
+            counted.append(stats)
+        nll = head_nll(
+            x, self.param("final_norm", nn.initializers.zeros, (d,)),
+            self.param("head", _INIT, (d, self.vocab)), targets)
+        with jax.named_scope("head_loss"):
+            loss = jnp.mean(nll)
+        stats = dict(zip(QWEN3NEXT_MOE_STATS + (SHARED_GATE_MEAN,),
+                         map(jnp.stack, zip(*counted))))
+        if decays:
+            stats[GDN_DECAY_MEAN] = jnp.stack(decays)
         return loss, stats

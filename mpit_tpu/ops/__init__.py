@@ -13,7 +13,9 @@ that backs sequence-parallel ring attention
 :mod:`mpit_tpu.ops.delta_rule` is the gated delta rule's chunked scan
 (Kimi Delta Attention): three Mosaic kernels at head widths of whole
 lanes (forward, and the backward rule's two), XLA's fusions and products
-at every other width; :mod:`mpit_tpu.ops.ssd_scan` is the scalar-decay
+at every other width, and its entry for one scalar decay a head with
+fewer key heads than value heads (Gated DeltaNet, ``gdn_scan``);
+:mod:`mpit_tpu.ops.ssd_scan` is the scalar-decay
 state-space scan (Mamba-2) in chunks with a backward rule of its own:
 three Mosaic kernels at widths of whole lanes (forward, and the rule's
 walk that makes the chunk-start states again and its walk back), the

@@ -78,6 +78,12 @@ The XLA form's rule is ``jax.vjp`` of its parts.  The result is named
 (``jax.ad_checkpoint.checkpoint_name``), as the flash rule names its
 two.
 
+**One scalar decay a head, key heads shared by value heads**
+(:func:`gdn_scan`, Gated DeltaNet: ``models/transformer.py``
+``Qwen3NextBlock``'s ``linear_attention`` mixer) is the same recurrence
+with ``alpha_t`` one number a head and ``H_k`` key heads under ``H_v``
+value heads: the section at the module's end.
+
 Shapes: ``q, k, g (B, L, H, d_k)``, ``v (B, L, H, d_v)``, ``beta (B, L,
 H)``; the result ``(B, L, H, d_v)``.  Any ``L``: a last chunk that is
 not whole is filled with positions that neither decay nor write
@@ -228,13 +234,31 @@ def _pair_matrices(q, k, gsum):
     return a, b
 
 
+def _scalar_pairs(q, k, gsum):
+    """:func:`_pair_matrices` where the decay is one number a head and
+    position (``gsum (..., C, 1)``, the gated delta rule of
+    :func:`gdn_scan`): every channel's difference of sums is the same
+    number, so a pair matrix is **one product under a ``C x C`` decay
+    matrix**, ``A_ts = (k_t . k_s) exp(G_t - G_s)``, each decay still
+    the ``exp`` of a difference that is not positive."""
+    chunk, gs = q.shape[-2], gsum[..., 0]
+    decay = jnp.exp(jnp.minimum(gs[..., :, None] - gs[..., None, :], 0.0))
+    at, k_t = jnp.arange(chunk), jnp.swapaxes(k, -1, -2)
+    a = jnp.where(at[:, None] > at[None, :], (k @ k_t) * decay, 0.0)
+    b = jnp.where(at[:, None] >= at[None, :], (q @ k_t) * decay, 0.0)
+    return a, b
+
+
 def _prepare(q, k, v, g, beta):
     """What a chunk is before its state is known, every chunk side by
     side: ``(U, W_k, K * exp(G_C - G), exp(G_C), Q * exp(G), B)`` from
-    ``q, k, v, g (..., n, C, d)`` and ``beta (..., n, C, 1)``."""
+    ``q, k, v, g (..., n, C, d)`` and ``beta (..., n, C, 1)``; a ``g``
+    of one column is a scalar decay a head (:func:`_scalar_pairs`) and
+    broadcasts over the keys' channels everywhere else."""
     dv = v.shape[-1]
     gsum = jnp.cumsum(g, axis=-2)                    # from the chunk's start
-    a, pairs = _pair_matrices(q, k, gsum)
+    a, pairs = (_scalar_pairs if g.shape[-1] == 1 and k.shape[-1] > 1
+                else _pair_matrices)(q, k, gsum)
     solve = unit_lower_inverse(beta * a)             # (..., n, C, C)
     into = jnp.exp(gsum)                             # from the start to t
     solved = solve @ (beta * jnp.concatenate([v, k * into], axis=-1))
@@ -768,3 +792,96 @@ def kda_scan(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, g: jnp.ndarray,
     lanes = q.shape[-1] % LANE == 0 and v.shape[-1] % LANE == 0
     return checkpoint_name(
         (_kernels_out if lanes else kda_scan_xla)(q, k, v, g, beta), KDA_OUT)
+
+
+# -- a scalar decay a head, key heads shared by value heads ----------------------
+#
+# The gated delta rule of Gated DeltaNet (arXiv:2412.06464; the ``qwen3_next``
+# module's ``linear_attention`` layers): the recurrence of this module's
+# docstring with ``alpha_t`` **one number a head** and ``H_k`` key heads
+# under ``H_v = r H_k`` value heads, key head ``j`` serving value heads ``r j
+# .. r j + r - 1``.
+
+#: the name of :func:`gdn_scan`'s result for a checkpoint policy
+GDN_OUT = "gdn_out"
+#: the dtype the log-decays' sums from a chunk's start are held in:
+#: float32.  Read at every call of :func:`gdn_scan`, so that the probe of
+#: the reference's tolerances can lower it for one build
+#: (``chipbench/reference/probe_qwen3next.py``: a sum of up to 64
+#: log-decays then carries three digits, and every decay inside a chunk
+#: is the ``exp`` of a difference of two such sums)
+GDN_SUM_DTYPE = jnp.float32
+
+
+def _sums_held_in(g: jnp.ndarray, dtype) -> jnp.ndarray:
+    """``g (B, L, H)`` changed so that its sums from each chunk's start
+    are the true sums rounded to ``dtype``: what a scan that holds them
+    in ``dtype`` decays by, whichever form then sums them in float32
+    (the differences of neighbouring rounded sums add up to the rounded
+    sum exactly)."""
+    b, length, h = g.shape
+    whole = jnp.pad(g, ((0, 0), (0, -length % CHUNK), (0, 0)))
+    sums = jnp.cumsum(whole.reshape(b, -1, CHUNK, h), axis=2)
+    sums = sums.astype(dtype).astype(jnp.float32)
+    return jnp.diff(sums, axis=2, prepend=0.0).reshape(b, -1, h)[:, :length]
+
+
+def gdn_scan_reference(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
+                       g: jnp.ndarray, beta: jnp.ndarray) -> jnp.ndarray:
+    """The recurrence as it is defined, one position a step: ``q, k (B,
+    L, H_k, d_k)``, ``v (B, L, H_v, d_v)``, ``g, beta (B, L, H_v)``; no
+    key is repeated and no decay broadcast."""
+    b, length, hk, dk = q.shape
+    hv, dv = v.shape[2:]
+    r = hv // hk
+
+    def step(state, at):                     # state (B, H_k, r, d_k, d_v)
+        q_t, k_t, v_t, g_t, beta_t = at
+        state = state * jnp.exp(g_t)[..., None, None]
+        seen = jnp.einsum("bjk,bjrkv->bjrv", k_t, state)
+        state = state + jnp.einsum(
+            "bjk,bjrv->bjrkv", k_t, beta_t[..., None] * (v_t - seen))
+        return state, jnp.einsum("bjk,bjrkv->bjrv", q_t, state)
+
+    along = (jnp.moveaxis(q, 1, 0), jnp.moveaxis(k, 1, 0),
+             jnp.moveaxis(v, 1, 0).reshape(length, b, hk, r, dv),
+             jnp.moveaxis(g, 1, 0).reshape(length, b, hk, r),
+             jnp.moveaxis(beta, 1, 0).reshape(length, b, hk, r))
+    state = jnp.zeros((b, hk, r, dk, dv), jnp.float32)
+    out = jax.lax.scan(step, state, along)[1]
+    return jnp.moveaxis(out, 0, 1).reshape(b, length, hv, dv)
+
+
+def gdn_scan(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, g: jnp.ndarray,
+             beta: jnp.ndarray) -> jnp.ndarray:
+    """The gated delta rule with a scalar decay a head in chunks of
+    :data:`CHUNK` positions: ``q, k (B, L, H_k, d_k)``, ``v (B, L, H_v,
+    d_v)``, ``g, beta (B, L, H_v)`` with ``H_v`` a multiple of ``H_k``;
+    the result ``(B, L, H_v, d_v)``, named :data:`GDN_OUT`.  The solve,
+    the carry and the backward rule are the module's own.  Which form
+    runs is read off the shapes, as :func:`kda_scan`'s: any narrow head
+    width takes the XLA form with the chunk's pair matrices one product
+    each under a ``C x C`` decay matrix (:func:`_scalar_pairs`); head
+    widths of whole lanes take **the channel-wise Mosaic kernels on the
+    decay broadcast over the keys' channels and the keys repeated** ``H_v
+    / H_k`` times, which is exact (every channel's difference of sums is
+    the same number) and moves ``d_k`` times the decay and ``r`` times
+    the queries and keys the algorithm needs
+    (``chipbench/arithmetic/qwen3next.py`` ``gdn_scan_cost`` counts the
+    algorithm, not the broadcast; kernels of the scalar rule's own are
+    ROADMAP's).  The repeat's and the broadcast's transposes are sums:
+    the gradients come back at the operands' own shapes."""
+    hk, hv = k.shape[2], v.shape[2]
+    if hv % hk or g.shape != v.shape[:3] or beta.shape != v.shape[:3]:
+        raise ValueError(f"gdn_scan: {hv} value heads over {hk} key heads, "
+                         f"g {g.shape}, beta {beta.shape}, v {v.shape}")
+    if hv != hk:
+        q, k = (jnp.repeat(x, hv // hk, axis=2) for x in (q, k))
+    if GDN_SUM_DTYPE != jnp.float32:
+        g = _sums_held_in(g, GDN_SUM_DTYPE)
+    if q.shape[-1] % LANE == 0 and v.shape[-1] % LANE == 0:
+        out = _kernels_out(q, k, v, jnp.broadcast_to(g[..., None], q.shape),
+                           beta)
+    else:
+        out = kda_scan_xla(q, k, v, g[..., None], beta)
+    return checkpoint_name(out, GDN_OUT)

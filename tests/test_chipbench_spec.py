@@ -57,7 +57,7 @@ def test_every_cells_vocabulary_reaches_the_program_through_the_launcher():
     want = {"gpt2": 50257, "olmoe": 50304, "mellum": 12288, "lfm2_moe": 8192,
             "ouro": 49152, "joyai_llm_flash": 16160, "kimi_linear": 20480,
             "KeyeVL2": 18992, "sdar_moe": 18992, "afmoe": 25024,
-            "nemotron_h": 16384}
+            "nemotron_h": 16384, "qwen3_next": 18992}
     for name in CELLS:
         cell = spec_mod.load_cell(name)
         cfg = runner.launch_config(cell, seed=5)
@@ -78,7 +78,8 @@ def test_scopes_come_from_every_committed_configuration():
         "experts", "attn_window", "conv", "conv_mix", "exit_gate", "mla_proj",
         "shared_expert", "kda_proj", "kda_scan", "kda_out", "index", "noise",
         "attn_gate", "bias_rule", "dense_mlp", "ssm_proj", "ssm_conv",
-        "ssd_scan", "ssm_norm"]
+        "ssd_scan", "ssm_norm", "gdn_proj", "gdn_conv", "gdn_scan",
+        "gdn_out"]
 
 
 def olmoe_cases():
@@ -306,6 +307,7 @@ def test_mellums_readers_read_a_hand_made_run(monkeypatch):
 
 LFM2_CELL = "lfm2-l5e8-local"
 NEMOTRON_CELL = "nemotron3-l9e8-local"
+QWEN3NEXT_CELL = "qwen3next-l4e32-local"
 
 
 def lfm2_cases():
@@ -480,14 +482,15 @@ def test_the_parent_fails_the_new_cell_at_once():
     cell on the parent needs."""
     bench = spec_mod.load_bench()
     names = [w["name"] for w in bench["workloads"]]
-    assert names[-8:] == [LFM2_CELL, OURO_CELL, JOYAI_CELL, KIMI_CELL,
+    assert names[-9:] == [LFM2_CELL, OURO_CELL, JOYAI_CELL, KIMI_CELL,
                           KEYE_CELL, SDAR_CELL, TRINITY_CELL,
-                          NEMOTRON_CELL] \
-        and len(names) == 13
+                          NEMOTRON_CELL, QWEN3NEXT_CELL] \
+        and len(names) == 14
     for missing in ("lfm2-l5e8-locals", "ouro-l6-locals",
                     "joyai-l5e8-locals", "kimi-linear-l5e8-locals",
                     "keye-l6e8-locals", "sdar-l6e8-locals",
-                    "trinity-l5e8-locals", "nemotron3-l9e8-locals"):
+                    "trinity-l5e8-locals", "nemotron3-l9e8-locals",
+                    "qwen3next-l4e32-locals"):
         with pytest.raises(spec_mod.SpecError, match="no workload"):
             spec_mod.load_cell(missing)
 
@@ -770,9 +773,9 @@ def test_joyais_mix_keeps_to_the_traffic_its_issue_fixed():
         if metric["name"] in JOYAI_METRICS[2:]:
             assert metric["workloads"] == [JOYAI_CELL]
         elif metric["name"] in JOYAI_APPENDED + JOYAI_METRICS[:2]:
-            # the cells of PR 43, PR 46, PR 51 and PR 53, which have
-            # some of these layers too, follow it
-            assert JOYAI_CELL in metric["workloads"][-6:]
+            # the cells of PR 43, PR 46, PR 51, PR 53, PR 58 and PR 61,
+            # which have some of these layers too, follow it
+            assert JOYAI_CELL in metric["workloads"][-7:]
 
 
 def test_joyais_readers_find_nothing_in_a_run_without_the_block():
@@ -1019,7 +1022,7 @@ def test_kimis_mix_keeps_to_the_traffic_its_issue_fixed():
         elif metric["name"] in KIMI_APPENDED:
             # the cells of PR 46, PR 51 and PR 53 follow it where they
             # have the layer
-            assert KIMI_CELL in metric["workloads"][-5:]
+            assert KIMI_CELL in metric["workloads"][-6:]
         elif "workloads" in metric:
             assert KIMI_CELL not in metric["workloads"], metric["name"]
 
@@ -1244,7 +1247,7 @@ def test_keyes_mix_keeps_to_the_traffic_its_issue_fixed():
             assert metric["workloads"] == [KEYE_CELL]
         elif metric["name"] in KEYE_APPENDED:
             # the cells of PR 51 and PR 53 follow it
-            assert KEYE_CELL in metric["workloads"][-4:]
+            assert KEYE_CELL in metric["workloads"][-5:]
         elif "workloads" in metric:
             assert KEYE_CELL not in metric["workloads"], metric["name"]
     # added together and in order (later PRs' entries follow them)
@@ -1252,8 +1255,8 @@ def test_keyes_mix_keeps_to_the_traffic_its_issue_fixed():
     at = cell.bench["per_layer"].index(keye[0])
     assert cell.bench["per_layer"][at:at + 5] == keye
     # (PR 51's and PR 53's configurations and cells follow them)
-    assert (cell.bench["configs"][-4]["name"],
-            cell.bench["workloads"][-4]["name"]) == (cell.config_name,
+    assert (cell.bench["configs"][-5]["name"],
+            cell.bench["workloads"][-5]["name"]) == (cell.config_name,
                                                      KEYE_CELL)
 
 
@@ -1463,10 +1466,10 @@ def test_sdars_mix_keeps_to_the_traffic_its_issue_fixed():
             assert SDAR_CELL not in metric["workloads"], metric["name"]
     # added together and in order (PR 53's four follow them, and its
     # configuration and cell)
-    assert [m["name"] for m in cell.bench["per_layer"][-13:-9]] == list(
+    assert [m["name"] for m in cell.bench["per_layer"][-19:-15]] == list(
         SDAR_METRICS)
-    assert (cell.bench["configs"][-3]["name"],
-            cell.bench["workloads"][-3]["name"]) == (cell.config_name,
+    assert (cell.bench["configs"][-4]["name"],
+            cell.bench["workloads"][-4]["name"]) == (cell.config_name,
                                                      SDAR_CELL)
 
 
@@ -1689,17 +1692,20 @@ def test_trinitys_mix_keeps_to_the_traffic_its_issue_fixed():
     assert {layers[m] for m in TRINITY_METRICS} == {
         layers["dispatch_ms_per_step"]}
     for metric in cell.bench["per_layer"]:
-        if metric["name"] in TRINITY_METRICS:
+        if metric["name"] == "attn_gate_ms_per_step":
+            # PR 61's cell has a gated attention too
+            assert metric["workloads"] == [TRINITY_CELL, QWEN3NEXT_CELL]
+        elif metric["name"] in TRINITY_METRICS:
             assert metric["workloads"] == [TRINITY_CELL]
         elif metric["name"] in TRINITY_APPENDED:
-            assert TRINITY_CELL in metric["workloads"][-2:]
+            assert TRINITY_CELL in metric["workloads"][-3:]
         elif "workloads" in metric:
             assert TRINITY_CELL not in metric["workloads"], metric["name"]
     # added together, in order and last
-    assert [m["name"] for m in cell.bench["per_layer"][-9:-5]] == list(
+    assert [m["name"] for m in cell.bench["per_layer"][-15:-11]] == list(
         TRINITY_METRICS)
-    assert (cell.bench["configs"][-2]["name"],
-            cell.bench["workloads"][-2]["name"]) == (cell.config_name,
+    assert (cell.bench["configs"][-3]["name"],
+            cell.bench["workloads"][-3]["name"]) == (cell.config_name,
                                                      TRINITY_CELL)
     assert cell.chips == 1 and len(cell.why) <= 200
 
@@ -1951,14 +1957,15 @@ def test_nemotrons_mix_keeps_to_the_traffic_its_issue_fixed():
         if metric["name"] in NEMOTRON_METRICS:
             assert metric["workloads"] == [NEMOTRON_CELL]
         elif metric["name"] in NEMOTRON_APPENDED:
-            assert metric["workloads"][-1] == NEMOTRON_CELL
+            assert NEMOTRON_CELL in metric["workloads"][-2:]
         elif "workloads" in metric:
             assert NEMOTRON_CELL not in metric["workloads"], metric["name"]
-    # added together, in order and last
-    assert [m["name"] for m in cell.bench["per_layer"][-5:]] == list(
+    # added together and in order (PR 61's six follow them, and its
+    # configuration and cell)
+    assert [m["name"] for m in cell.bench["per_layer"][-11:-6]] == list(
         NEMOTRON_METRICS)
-    assert (cell.bench["configs"][-1]["name"],
-            cell.bench["workloads"][-1]["name"]) == (cell.config_name,
+    assert (cell.bench["configs"][-2]["name"],
+            cell.bench["workloads"][-2]["name"]) == (cell.config_name,
                                                      NEMOTRON_CELL)
     assert cell.chips == 1 and len(cell.why) <= 200
 
@@ -2072,6 +2079,266 @@ def test_nemotron_arithmetic_by_hand_through_the_cell(what, got, want):
     assert got == want, what
 
 
+# -- the Qwen3-Next configuration (PR 61) -----------------------------------------
+
+QWEN3NEXT_METRICS = ("gdn_ms_per_step", "gdn_scan_ms_per_step",
+                     "gdn_scan_roofline", "gdn_conv_ms_per_step",
+                     "gdn_decay_mean", "shared_gate_mean")
+QWEN3NEXT_APPENDED = (
+    "dispatch_ms_per_step", "expert_load_max_over_mean",
+    "held_experts_ms_per_step", "held_experts_roofline",
+    "held_rows_share_pct", "compact_dispatch_pct",
+    "shared_expert_ms_per_step", "attn_gate_ms_per_step")
+
+
+def test_qwen3next_file_has_the_catalogs_keys_and_the_floor_cuts():
+    """Every key of the catalog's row under its own name and at its
+    published value but the three cut: the depth, the experts held and
+    the vocabulary, with the published values beside them; no width is
+    cut; what the row does not give is stated as assumed."""
+    import pathlib
+
+    cell = spec_mod.load_cell(QWEN3NEXT_CELL)
+    config = cell.config
+    path = pathlib.Path(
+        "/opt/skills/guides/model-configs/architectures.jsonl")
+    if path.exists():
+        rows = [json.loads(line) for line in path.read_text().splitlines()]
+        entry = next(r for r in rows
+                     if r["name"] == "Qwen3-Next-80B-A3B-Instruct")
+        catalog = entry["config"]
+        assert entry["source_url"] == config["source"]
+        assert all(key in config for key in catalog)
+        differ = sorted(k for k, v in catalog.items() if config[k] != v)
+        assert differ == sorted(config["reduced"])
+        assert config["published"] == {k: catalog[k]
+                                       for k in config["reduced"]}
+    assert sorted(config["reduced"]) == ["num_experts", "num_hidden_layers",
+                                         "vocab_size"]
+    # the floors: one whole period of four, 32 experts, an eighth of the rows
+    assert (config["num_hidden_layers"], config["num_experts"],
+            config["vocab_size"], config["full_attention_interval"]) == (
+                4, 32, 151936 // 8, 4)
+    assert (config["hidden_size"], config["num_attention_heads"],
+            config["num_key_value_heads"], config["head_dim"],
+            config["linear_num_key_heads"], config["linear_num_value_heads"],
+            config["linear_key_head_dim"], config["linear_value_head_dim"],
+            config["linear_conv_kernel_dim"], config["moe_intermediate_size"],
+            config["shared_expert_intermediate_size"],
+            config["num_experts_per_tok"], config["partial_rotary_factor"],
+            config["rope_theta"], config["rms_norm_eps"],
+            config["norm_topk_prob"]) == (
+                2048, 16, 2, 256, 16, 32, 128, 128, 4, 512, 512, 10, 0.25,
+                10_000_000, 1e-6, True)
+    assert config["router_experts"] == 512   # the router keeps its width
+    assert (config["train_seq"], config["experts_first"],
+            config["gdn_chunk"]) == (8192, 0, 64)
+    entry = next(c for c in cell.bench["configs"]
+                 if c["name"] == cell.config_name)
+    assert sorted(entry["reduced"]) == sorted(config["reduced"])
+    assert entry["source"] == config["source"]
+    assert (cell.chips, cell.traffic_name) == (
+        1, "local-msgd-s8k-qwen3next")
+    assert ["embed", "gdn_proj", "gdn_conv", "gdn_scan", "gdn_out", "attn",
+            "attn_gate", "router", "dispatch", "experts", "shared_expert",
+            "head_loss", "update"] == config["scopes"]
+    assert all(key in config for key in config["launcher_from"].values())
+    assert all(key in config for key in config["tiny"])
+    assert len(config["assumed"]) >= 8 and "16 v5e chips" in \
+        config["deployment"]
+    worked = [got for _what, got, want in cell.arithmetic().hand_worked()
+              if got == want]
+    # a delta mixer, the attention, a sparse MLP, the vector
+    assert all(count in worked for count in (
+        33_720_512, 27_265_536, 104_861_696, 625_667_136))
+    assert cell.arithmetic().param_count(config) == 625_667_136
+    assert cell.reference().LOSS_TOL_NATS > 0 < cell.reference().GRAD_REL_TOL
+
+
+def test_the_launcher_builds_the_gated_delta_block_from_the_cells_files():
+    from chipbench import run as runner
+    from mpit_tpu.lm.model import build_kw
+    from mpit_tpu.train.launch import lm_trainer_cfg
+
+    cell = spec_mod.load_cell(QWEN3NEXT_CELL)
+    kw = build_kw(lm_trainer_cfg(runner.launch_config(cell, seed=5)))
+    assert (kw["arch"], kw["d_model"], kw["n_heads"], kw["kv_heads"],
+            kw["head_dim"], kw["n_layers"], kw["seq_len"], kw["vocab"]) \
+        == ("qwen3next", 2048, 16, 2, 256, 4, 8192, 18992)
+    assert kw["layer_types"] == cell.arithmetic().layer_types(cell.config)
+    assert kw["layer_types"].split(",") == ["linear_attention"] * 3 + [
+        "full_attention"]
+    assert (kw["gdn_key_heads"], kw["gdn_value_heads"], kw["gdn_key_dim"],
+            kw["gdn_value_dim"], kw["conv_kernel"], kw["rotary_factor"]) == (
+                16, 32, 128, 128, 4, 0.25)
+    assert (kw["n_experts"], kw["experts_held"], kw["experts_first"],
+            kw["experts_per_tok"], kw["expert_width"],
+            kw["shared_width"]) == (512, 32, 0, 10, 512, 512)
+    assert (kw["rope_theta"], kw["norm_eps"]) == (1e7, 1e-6)
+
+
+def test_qwen3nexts_mix_keeps_to_the_traffic_its_issue_fixed():
+    """ISSUE 61 fixed the mix before any code was written: the rate one
+    of four, the budget a whole number of micro-steps of 8192 tokens,
+    momentum 0.9, two rounds of warm-up, closed loop in one process; the
+    six new metrics and the eight appended ones are the cell's, and the
+    budget's table is in the mix's own file."""
+    cell = spec_mod.load_cell(QWEN3NEXT_CELL)
+    mix = cell.traffic
+    steps, rest = divmod(mix["token_budget"],
+                         mix["batch"] * cell.config["train_seq"])
+    assert rest == 0 and steps >= 8
+    assert mix["lr"] in (0.003, 0.01, 0.03, 0.1)
+    assert (mix["launcher"]["mom"], mix["warmup_rounds"], mix["su"],
+            mix["batch"], mix["launcher"]["np"],
+            mix["launcher"]["lm_use_flash"]) == (0.9, 2, 1, 1, 1, 1)
+    assert "quartile distance" in mix["chosen_because"]
+    moves = {m["name"]: m["moves"] for m in cell.metrics("per_layer")}
+    assert set(QWEN3NEXT_METRICS + QWEN3NEXT_APPENDED) <= set(moves)
+    assert {moves[m] for m in QWEN3NEXT_METRICS[:4]} == {"tokens_per_s"}
+    assert {moves[m] for m in QWEN3NEXT_METRICS[4:]} == {"loss_at_budget"}
+    layers = {m["name"]: m["layer"] for m in cell.bench["per_layer"]}
+    assert layers["gdn_scan_roofline"] == layers["kda_scan_roofline"]
+    assert layers["gdn_ms_per_step"] == layers["kda_ms_per_step"]
+    units = {m["name"]: (m["unit"], m["better"])
+             for m in cell.bench["per_layer"]}
+    assert units["gdn_scan_roofline"] == ("%", "higher")
+    assert units["gdn_decay_mean"] == units["shared_gate_mean"] == (
+        "share", "lower")
+    for metric in cell.bench["per_layer"]:
+        if metric["name"] in QWEN3NEXT_METRICS:
+            assert metric["workloads"] == [QWEN3NEXT_CELL]
+        elif metric["name"] in QWEN3NEXT_APPENDED:
+            assert metric["workloads"][-1] == QWEN3NEXT_CELL
+        elif "workloads" in metric:
+            assert QWEN3NEXT_CELL not in metric["workloads"], metric["name"]
+    # added together, in order and last
+    assert [m["name"] for m in cell.bench["per_layer"][-6:]] == list(
+        QWEN3NEXT_METRICS)
+    assert (cell.bench["configs"][-1]["name"],
+            cell.bench["workloads"][-1]["name"]) == (cell.config_name,
+                                                     QWEN3NEXT_CELL)
+    assert cell.chips == 1 and len(cell.why) <= 200
+    assert len(cell.bench["configs"]) == 13 and len(
+        cell.bench["workloads"]) == 14
+
+
+def test_qwen3nexts_readers_find_nothing_in_a_run_without_the_block():
+    """What the parent's traced run hands them: a cell whose
+    configuration lists no such scope, a program that recorded no such
+    counter, no device trace, no merged trace: None, no raise."""
+    for name in ("kimi-linear-l5e8-local", "c111m-local"):
+        cell = spec_mod.load_cell(name)
+        run = {"cell": cell, "reduction": {"step_module": "jit__lambda"},
+               "obs_trace": None, "peaks": None, "results": {},
+               "summary": {"worker_ranks": [0], "window": [0.0, 1.0]}}
+        for metric in QWEN3NEXT_METRICS:
+            reader = spec_mod.load_reader(cell.root, cell.bench, metric)
+            assert reader is not None and reader(dict(run)) is None
+
+
+def test_qwen3nexts_readers_read_a_hand_made_run(monkeypatch):
+    """The six readers, the eight shared ones and the metrics without a
+    ``workloads`` list that the cell has to report, on a scope table and
+    a span tree made by hand."""
+    from chipbench import flops
+    from chipbench.layers import spantree
+
+    cell = spec_mod.load_cell(QWEN3NEXT_CELL)
+    listed = [m["name"] for m in cell.metrics("per_layer")]
+    unlisted = [m["name"] for m in cell.bench["per_layer"]
+                if "workloads" not in m]
+    assert len(unlisted) == 10 and set(unlisted) <= set(listed)
+
+    class Round:
+        def __init__(self, k, decay):
+            self.args = {"round": k,
+                         "lm_gdn_decay_mean": [decay, decay + 0.03,
+                                               decay - 0.03],
+                         "lm_shared_gate_mean": [0.5, 0.52, 0.48, 0.5],
+                         "moe_held_rows_share": [0.0625] * 4,
+                         "moe_load_max_over_mean": [2.0, 3.0, 2.25, 2.0],
+                         "moe_compact_share": [1.0] * 4}
+
+    class Tree:
+        def rounds(self):
+            return [Round(k, 0.83 + 0.01 * k) for k in range(5)]
+
+    table = {"step": 300.0, "gdn_proj": 50.0, "gdn_conv": 15.0,
+             "gdn_scan": 45.0, "gdn_out": 20.0, "attn": 40.0,
+             "attn_gate": 3.0, "router": 8.0, "dispatch": 20.0,
+             "experts": 30.0, "shared_expert": 9.0, "head_loss": 11.0,
+             "update": 60.0}
+    monkeypatch.setattr(spantree, "scope_ms_per_step", lambda run: table)
+    monkeypatch.setattr(spantree, "xplane_path", lambda run: None)
+    run = {"cell": cell, "peaks": flops.load_peaks("TPU v5 lite"),
+           spantree.CACHE_KEY: Tree(),
+           "summary": {"tokens_per_s": 27000.0, "worker_ranks": [0]},
+           "reduction": {"step_module": "jit__lambda", "step_module_runs": 2,
+                         "mosaic_by_scope": {
+                             "attn": (6, 0.070), "experts": (48, 0.040),
+                             "gdn_scan": (18, 0.080), "update": (2, 0.026)}}}
+
+    def read(name, run=run):
+        return spec_mod.load_reader(cell.root, cell.bench, name)(run)
+
+    assert read("gdn_ms_per_step") == pytest.approx(130.0)
+    assert read("gdn_scan_ms_per_step") == pytest.approx(45.0)
+    assert read("gdn_conv_ms_per_step") == pytest.approx(15.0)
+    assert read("gdn_decay_mean") == pytest.approx(0.85)
+    assert read("shared_gate_mean") == pytest.approx(0.5)
+    scan = cell.arithmetic().gdn_scan_cost(cell.config, 1)
+    assert read("gdn_scan_roofline") == pytest.approx(
+        100 * max(scan["flops"] / 197e12, scan["bytes"] / 819e9) / 0.045)
+    # the algorithm's bytes: q and k at 16 heads, the decay a float a head
+    assert scan["bytes"] == 3 * (6 * 67_108_864 + 5 * 134_217_728
+                                 + 6 * 1_048_576)
+    assert read("dispatch_ms_per_step") == pytest.approx(28.0)
+    assert read("held_experts_ms_per_step") == pytest.approx(30.0)
+    assert read("shared_expert_ms_per_step") == pytest.approx(9.0)
+    assert read("attn_gate_ms_per_step") == pytest.approx(3.0)
+    assert read("held_rows_share_pct") == pytest.approx(6.25)
+    assert read("expert_load_max_over_mean") == pytest.approx(3.0)
+    assert read("compact_dispatch_pct") == pytest.approx(100.0)
+    assert read("head_loss_ms_per_step") == pytest.approx(11.0)
+    assert read("flash_ms_per_step") == pytest.approx(35.0)
+    families = cell.arithmetic().kernels(cell.config, 1)
+    assert read("flash_roofline") == pytest.approx(
+        100 * families["attn"]["flops"] / 197e12 / 0.035)
+    experts = cell.arithmetic().experts_cost(cell.config, 1)
+    assert read("held_experts_roofline") == pytest.approx(
+        100 * max(experts["flops"] / 197e12, experts["bytes"] / 819e9)
+        / 0.020)
+    assert read("mfu_pct") == pytest.approx(
+        100 * 1_390_288_896 * 27000.0 / 197e12)
+    for metric in cell.metrics("per_layer"):
+        assert spec_mod.load_reader(cell.root, cell.bench,
+                                    metric["name"]) is not None
+
+    # a block with no delta layer and no gated shared expert: nothing read
+    class Bare:
+        def rounds(self):
+            rounds = Tree().rounds()
+            for r in rounds:
+                del r.args["lm_gdn_decay_mean"]
+                del r.args["lm_shared_gate_mean"]
+            return rounds
+
+    bare = {**run, spantree.CACHE_KEY: Bare()}
+    assert read("gdn_decay_mean", bare) is None
+    assert read("shared_gate_mean", bare) is None
+
+
+def qwen3next_cases():
+    return spec_mod.load_cell(QWEN3NEXT_CELL).arithmetic().hand_worked()
+
+
+@pytest.mark.parametrize("what,got,want", qwen3next_cases(),
+                         ids=[c[0] for c in qwen3next_cases()])
+def test_qwen3next_arithmetic_by_hand_through_the_cell(what, got, want):
+    assert got == want, what
+
+
 def _one_line_fields():
     bench = json.loads((spec_mod.ROOT / "BENCHMARK.json").read_text())
     out = [("command", " ".join(bench["command"]))]
@@ -2164,8 +2431,9 @@ def test_pull_early_pct_is_entered_for_the_ps_cells_under_a_layer_of_perf_md():
     assert entry == {"name": "pull_early_pct", "unit": "%", "better": "higher",
                      "source": "program_span", "layer": "L3 shell + client",
                      "moves": "tokens_per_s", "workloads": PS_CELLS}
-    # appended, nothing moved; PR 51's four and PR 53's four follow it
-    assert bench["per_layer"][-14] is entry
+    # appended, nothing moved; PR 51's four, PR 53's four, PR 58's five
+    # and PR 61's six follow it
+    assert bench["per_layer"][-20] is entry
     perf = (spec_mod.ROOT / "PERF.md").read_text()
     layers = perf[perf.index("## 3. Layers"):perf.index("## 4. Cells")]
     assert f"| {entry['layer']} |" in layers and "`pull_early_pct`" in layers

@@ -483,3 +483,104 @@ def test_the_indexer_compiles_at_the_published_shape(one_chip):
     assert not re.findall(r"[fsu]32\[(1,)?256,8192\]", text)  # no scores
     assert "while" not in text and "conditional" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 80e6
+
+
+# -- the gated-delta hybrid (PR 61) ----------------------------------------------
+
+
+def test_flash_attention_compiles_at_16_over_2_heads_of_256(one_chip):
+    """Qwen3-Next's full layer: a group's eight query heads folded into
+    the kernel's rows (as Keye's), keys **and values** 256 wide, two
+    lane tiles each, which no kernel here had run on the value side
+    (JoyAI's keys are 192 -> 256 lanes, its values 128).  The tiles
+    ``_default_blocks`` gives a width past one lane tile hold in the
+    chip's fast memory in the forward and the backward bodies, k and v
+    at the KV heads' size, nothing padded or cut round the calls."""
+    from mpit_tpu.ops.flash_attention import flash_attention
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, causal=True,
+                                       interpret=False) ** 2)
+
+    q = jax.ShapeDtypeStruct((1, 16, 8192, 256), jnp.float32,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, 2, 8192, 256), jnp.float32,
+                              sharding=one_chip)
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') >= 2
+    assert len(compiled.output_shardings) == 3
+    assert "f32[1,2,8192,256]" in text         # dk, dv at the KV heads
+    assert not sweeps_of(text, least=math.prod(kv.shape))
+
+
+@pytest.mark.parametrize("k,n", [(2048, 512), (512, 2048)],
+                         ids=["up", "down"])
+def test_the_grouped_product_compiles_at_32_held_experts_of_512(
+        one_chip, k, n):
+    """Qwen3-Next's experts: a router 512 wide and 10 a token are 81,920
+    sorted assignments a layer at 8192 tokens, of which the held window
+    moves twice the uniform share; 32 held experts whose inner width is
+    one tile of four lanes see 160 rows each on average, **less than a
+    row tile**, so most row tiles span two experts or three.  Forward,
+    the rows' gradient and the weights' compile for the chip over the
+    window's rows and 32 held experts from a group offset among the 512
+    group sizes."""
+    from mpit_tpu.parallel import moe
+
+    held, groups, assignments = 32, 512, 81_920
+    rows = moe.held_window(assignments, 2048, held, groups)
+    assert rows == 10_240                  # twice the uniform 5,120
+    assert moe.pallas_fits(rows, k, n)
+
+    def loss(x, w, sizes):
+        return jnp.sum(moe.grouped_dot(x, w, sizes, 7) ** 2)
+
+    args = (jax.ShapeDtypeStruct((rows, k), jnp.float32, sharding=one_chip),
+            jax.ShapeDtypeStruct((held, k, n), jnp.float32,
+                                 sharding=one_chip),
+            jax.ShapeDtypeStruct((groups,), jnp.int32, sharding=one_chip))
+    real = jax.default_backend
+    jax.default_backend = lambda: "tpu"    # ``grouped_dot`` asks
+    try:
+        compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+            *args).compile()
+    finally:
+        jax.default_backend = real
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    assert "f32[32,%d,%d]" % (k, n) in text
+    assert not sweeps_of(text, held * k * n)
+
+
+def test_the_gated_delta_scan_compiles_at_the_published_shape(
+        one_chip, monkeypatch):
+    """Qwen3-Next's Gated DeltaNet layer: 16 key heads under 32 value
+    heads of 128 at 8192, one scalar decay a value head.  The entry's
+    first form repeats the keys and broadcasts the decay in front of
+    Kimi's three kernels; the five gradients come back at the operands'
+    own shapes (q and k at 16 heads, g and beta a float a head)."""
+    from mpit_tpu.ops import delta_rule
+
+    monkeypatch.setattr(delta_rule, "use_interpret", lambda flag: False)
+
+    def of(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    keys, values, small = of(1, 8192, 16, 128), of(1, 8192, 32, 128), \
+        of(1, 8192, 32)
+
+    def loss(q, k, v, g, beta):
+        return jnp.sum(delta_rule.gdn_scan(q, k, v, g, beta) ** 2)
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        keys, keys, values, small, small).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    assert "f32[1,32,128,128,128]" in text   # the chunks' starting states
+    shapes = [tuple(s.shape) for s in jax.eval_shape(
+        jax.grad(loss, argnums=(0, 1, 2, 3, 4)),
+        keys, keys, values, small, small)]
+    assert shapes == [keys.shape, keys.shape, values.shape, small.shape,
+                      small.shape]
