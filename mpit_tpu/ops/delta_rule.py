@@ -56,9 +56,10 @@ of chunks and none back), the state a VMEM scratch carried along the
 chunk axis of the grid; the heads of a grid step are written stage by
 stage, not head by head, because the kernel's compiler runs the small
 products in the order they are written (:func:`_in_step`).  The forward
-kernel reads the five inputs and writes ``o``.  Every other width runs the chunked form as XLA's fusions
-and batched products (:func:`kda_scan_xla`), the kernels' second oracle
-beside the recurrence.
+kernel reads the five inputs and writes ``o``.  Every other width runs
+the chunked form as XLA's fusions and batched products
+(:func:`kda_scan_xla`), the kernels' second oracle beside the
+recurrence.
 
 **The backward pass is the operator's own rule** (``jax.custom_vjp``):
 it keeps ``q, k, v, g, beta`` and nothing of the forward pass, computes
@@ -81,8 +82,25 @@ two.
 **One scalar decay a head, key heads shared by value heads**
 (:func:`gdn_scan`, Gated DeltaNet: ``models/transformer.py``
 ``Qwen3NextBlock``'s ``linear_attention`` mixer) is the same recurrence
-with ``alpha_t`` one number a head and ``H_k`` key heads under ``H_v``
-value heads: the section at the module's end.
+with ``alpha_t`` one number a head and ``H_k`` key heads under ``H_v =
+r H_k`` value heads.  Every channel's difference of sums is then the
+same number, so the halving has nothing to do: ``A = (K K^T) * D`` and
+``B = (Q K^T) * D`` with ``D_ts = exp(min(G_t - G_s, 0))``, **one
+product a key head under a ``C x C`` decay matrix a value head**, each
+decay still the ``exp`` of a difference that is not positive, and
+``exp(G)``, ``exp(G_C - G)``, ``exp(G_C)`` columns and a scalar.  At
+head widths of whole lanes that is **three Mosaic kernels of its own**
+(:class:`_ScalarChunk`, the section at the module's end) with the
+solve, the stage-by-stage writing, the block specs and the rule's shape
+of the channel-wise ones: the grid runs over key heads, a step reads a
+key head's ``q`` and ``k`` once for its ``r`` value heads, ``g`` as
+``beta`` a float a head, and no array of a call is wider than the
+operand the caller made (no key repeated, no decay broadcast); in the
+walk back the log-decay's gradient is row and column sums of the decay
+matrix's cotangent times the matrix, then the reverse cumulative sum,
+and ``dq``, ``dk`` are summed over a key head's value heads inside the
+step.  Narrow head widths run the XLA form with the pair matrices of
+:func:`_scalar_pairs`.
 
 Shapes: ``q, k, g (B, L, H, d_k)``, ``v (B, L, H, d_v)``, ``beta (B, L,
 H)``; the result ``(B, L, H, d_v)``.  Any ``L``: a last chunk that is
@@ -501,13 +519,13 @@ class _Chunk:
             a=a, b=b, levels=levels, t=t, into=into, kg=kg, qg=qg, rhs=rhs,
             wk=wk, w=w, last=last, out_of=out_of, kend=kend)
 
-    def backward(self, q, k, v, g, beta, state, t, do, dafter):
-        """The chunk's matrices again but for the solve ``t``, then the
-        cotangents of the five inputs and of the starting state from
-        ``do`` and the next state's ``dafter``: the solve by ``dM = -T^T
-        dT T^T``, the pair matrices level by level, the log-decays' by
-        the tables' transposes (``G``'s is the reverse cumulative
-        sum)."""
+    def to_the_pairs(self, q, k, v, g, beta, state, t, do, dafter):
+        """The walk back as far as the decay's kind does not matter: the
+        chunk's matrices again but for the solve ``t``, then, from
+        ``do`` and the next state's ``dafter``, what :meth:`forward`
+        kept and the cotangents ``(dA, dB, d(k exp(G)), d(q exp(G)),
+        d(k exp(G_C - G)), d rhs, dbeta, dstate)``: the solve by ``dM =
+        -T^T dT T^T``."""
         low, dv = self.low, v.shape[1]
         _, _, f = yield from self.forward(q, k, v, g, beta, state, t)
         a, b, w, wk, kg, qg, kend = (f[name] for name in (
@@ -534,6 +552,18 @@ class _Chunk:
         dbeta = jnp.sum(dn * a, axis=1, keepdims=True) + jnp.sum(
             drhs * jnp.concatenate([v, kg], axis=1), axis=1, keepdims=True)
         dkg = beta * drhs[:, dv:]
+        return f, da, db, dkg, dqg, dkend, drhs, dbeta, dstate
+
+    def backward(self, q, k, v, g, beta, state, t, do, dafter):
+        """The cotangents of the five inputs and of the starting state
+        (:meth:`to_the_pairs`), the pair matrices' level by level, the
+        log-decays' by the tables' transposes (``G``'s is the reverse
+        cumulative sum)."""
+        low, dv = self.low, v.shape[1]
+        f, da, db, dkg, dqg, dkend, drhs, dbeta, dstate = \
+            yield from self.to_the_pairs(q, k, v, g, beta, state, t, do,
+                                         dafter)
+        kg, qg, kend = f["kg"], f["qg"], f["kend"]
         on_diagonal = jnp.sum(self.eye * db, axis=1, keepdims=True)
         d_k = dkg * f["into"] + dkend * f["out_of"] + on_diagonal * q
         d_q = dqg * f["into"] + on_diagonal * k
@@ -573,16 +603,42 @@ def _in_step(heads):
     return results
 
 
-def _heads_a_step(heads: int) -> int:
-    return max(n for n in range(1, HEADS_A_STEP + 1) if heads % n == 0)
+def _heads_a_step(keys: int, per: int = 1, most: int = HEADS_A_STEP) -> int:
+    """Value heads a grid step holds: whole key heads of ``per`` value
+    heads each (1 where every head has its own keys), as many as divide
+    ``keys`` and come to ``most`` heads or fewer, and one key head at
+    the least."""
+    return per * max(n for n in range(1, max(most // per, 1) + 1)
+                     if keys % n == 0)
+
+
+def _column_of(block, head):
+    """A head's column ``(C, 1)`` of the block ``(C, H)``."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, block.shape, 1)
+    return jnp.sum(jnp.where(lane == head, block, 0.0), axis=1,
+                   keepdims=True)
 
 
 def _beta_of(beta_ref, head):
     """A head's ``beta`` as a column ``(C, 1)`` of the block ``(C, H)``."""
-    block = beta_ref[0]
-    lane = jax.lax.broadcasted_iota(jnp.int32, block.shape, 1)
-    return jnp.sum(jnp.where(lane == head, block, 0.0), axis=1,
-                   keepdims=True)
+    return _column_of(beta_ref[0], head)
+
+
+def _zero_at_the_first(state_ref):
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        state_ref[...] = jnp.zeros_like(state_ref)
+
+
+def _write_forward(made, states, o_ref, state_ref, kept_refs, dv):
+    """What a forward kernel writes of its heads' ``(o, the next state,
+    what was kept)``: ``o``, the state for the next chunk and, where the
+    rule asks, the state the chunk started from and its solve."""
+    for j, (o, after, kept) in enumerate(made):
+        o_ref[0, :, j * dv:(j + 1) * dv] = o
+        state_ref[j] = after
+        if kept_refs:
+            kept_refs[0][0, j, 0], kept_refs[1][0, j, 0] = states[j], kept["t"]
 
 
 def _forward_kernel(sums_ref, lev_ref, q_ref, k_ref, v_ref, g_ref, beta_ref,
@@ -591,11 +647,7 @@ def _forward_kernel(sums_ref, lev_ref, q_ref, k_ref, v_ref, g_ref, beta_ref,
     state (``(1, group, 1, d_v, d_k)``) and solve (``(1, group, 1, C,
     C)``); then the state's scratch."""
     *kept_refs, state_ref = rest
-
-    @pl.when(pl.program_id(2) == 0)
-    def _():
-        state_ref[...] = jnp.zeros_like(state_ref)
-
+    _zero_at_the_first(state_ref)
     chunk = _Chunk(sums_ref[...], None, lev_ref[...], one_pass)
     states = [state_ref[j] for j in range(group)]
     made = _in_step([chunk.forward(
@@ -603,21 +655,14 @@ def _forward_kernel(sums_ref, lev_ref, q_ref, k_ref, v_ref, g_ref, beta_ref,
         v_ref[0, :, j * dv:(j + 1) * dv], g_ref[0, :, j * dk:(j + 1) * dk],
         _beta_of(beta_ref, pl.program_id(1) * group + j), states[j])
         for j in range(group)])
-    for j, (o, after, kept) in enumerate(made):
-        o_ref[0, :, j * dv:(j + 1) * dv] = o
-        state_ref[j] = after
-        if kept_refs:
-            kept_refs[0][0, j, 0], kept_refs[1][0, j, 0] = states[j], kept["t"]
+    _write_forward(made, states, o_ref, state_ref, kept_refs, dv)
 
 
 def _backward_kernel(sums_ref, sums_t_ref, lev_ref, q_ref, k_ref, v_ref,
                      g_ref, beta_ref, do_ref, starts_ref, solves_ref, dq_ref,
                      dk_ref, dv_ref, dg_ref, dbeta_ref, dstate_ref, *, group,
                      dk, dv, one_pass):
-    @pl.when(pl.program_id(2) == 0)
-    def _():
-        dstate_ref[...] = jnp.zeros_like(dstate_ref)
-
+    _zero_at_the_first(dstate_ref)
     chunk = _Chunk(sums_ref[...], sums_t_ref[...], lev_ref[...], one_pass)
     keys = [slice(j * dk, (j + 1) * dk) for j in range(group)]
     values = [slice(j * dv, (j + 1) * dv) for j in range(group)]
@@ -637,16 +682,19 @@ def _backward_kernel(sums_ref, sums_t_ref, lev_ref, q_ref, k_ref, v_ref,
 
 
 class _Calls:
-    """What the three calls share: the sizes, the tables, and the block
-    specs of one walk over the chunks (``back``: from the last to the
-    first)."""
+    """What the three calls share: the sizes and the block specs of one
+    walk over the chunks (``back``: from the last to the first).  A grid
+    step holds ``group`` value heads, whole key heads of ``per`` value
+    heads each (1 in the channel-wise kernels, where ``q`` has ``v``'s
+    heads)."""
 
-    def __init__(self, q, v, back, interpret):
-        self.b, self.length, self.h, self.dk = q.shape
-        self.dv, n = v.shape[-1], q.shape[1] // CHUNK
-        self.n, self.group = n, _heads_a_step(self.h)
+    def __init__(self, q, v, back, interpret, most=HEADS_A_STEP):
+        self.b, self.length, keys, self.dk = q.shape
+        self.h, self.dv = v.shape[2:]
+        n = q.shape[1] // CHUNK
+        self.per = self.h // keys
+        self.n, self.group = n, _heads_a_step(keys, self.per, most)
         interpret = use_interpret(interpret)
-        self.sums, self.sums_t, self.lev = _tables(one_pass=not interpret)
         self.at = (lambda c: n - 1 - c) if back else (lambda c: c)
         self.static = dict(group=self.group, dk=self.dk, dv=self.dv,
                            one_pass=not interpret)
@@ -655,9 +703,14 @@ class _Calls:
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")))
 
-    def rows(self, width):     # a chunk of a group's heads, row-major
-        return pl.BlockSpec((1, CHUNK, self.group * width),
-                            lambda i, j, c: (i, self.at(c), j))
+    def tables(self):          # the channel-wise kernels' (:func:`_tables`)
+        return _tables(one_pass=self.static["one_pass"])
+
+    def rows(self, width, heads=None):
+        # a chunk of a group's heads (or of its ``heads`` key heads), row-major
+        return pl.BlockSpec(
+            (1, CHUNK, (self.group if heads is None else heads) * width),
+            lambda i, j, c: (i, self.at(c), j))
 
     def heads_rows(self):      # beta: a chunk of every head
         return pl.BlockSpec((1, CHUNK, self.h),
@@ -670,6 +723,9 @@ class _Calls:
     def dbeta(self):           # a chunk of a group's heads, a lane each
         return pl.BlockSpec((1, 1, CHUNK, self.group),
                             lambda i, j, c: (i, j, self.at(c), 0))
+
+    def by_position(self, x):  # what ``dbeta`` blocks, as ``(B, L, H)``
+        return x.transpose(0, 2, 1, 3).reshape(self.b, self.length, self.h)
 
     @staticmethod
     def whole(table):          # held for the whole grid
@@ -690,17 +746,18 @@ def _kernel_forward(q, k, v, g, beta, keep=False, interpret=None):
     state ``(B, H, n, d_v, d_k)`` and solve ``(B, H, n, C, C)``; ``L``
     whole chunks."""
     c = _Calls(q, v, False, interpret)
+    sums, _, lev = c.tables()
     kept = ((c.dv, c.dk), (CHUNK, CHUNK)) if keep else ()
     o, *rest = pl.pallas_call(
         partial(_forward_kernel, **c.static),
-        in_specs=[c.whole(c.sums), c.whole(c.lev), c.rows(c.dk),
+        in_specs=[c.whole(sums), c.whole(lev), c.rows(c.dk),
                   c.rows(c.dk), c.rows(c.dv), c.rows(c.dk), c.heads_rows()],
         out_specs=[c.rows(c.dv)] + [c.kept(*shape) for shape in kept],
         out_shape=[_f32(c.b, c.length, c.h * c.dv)] + [
             _f32(c.b, c.h, c.n, *shape) for shape in kept],
         scratch_shapes=[pltpu.VMEM((c.group, c.dv, c.dk), jnp.float32)],
         **c.call,
-    )(c.sums, c.lev, _flat(q), _flat(k), _flat(v), _flat(g), beta)
+    )(sums, lev, _flat(q), _flat(k), _flat(v), _flat(g), beta)
     o = o.reshape(v.shape)
     return (o, *rest) if keep else o
 
@@ -709,11 +766,12 @@ def _kernel_backward(q, k, v, g, beta, starts, solves, do, interpret=None):
     """The five cotangents, the chunks walked from the last to the first
     with the state's cotangent in VMEM."""
     c = _Calls(q, v, True, interpret)
+    sums, sums_t, lev = c.tables()
     keys, values = c.rows(c.dk), c.rows(c.dv)
     d_keys, d_values = (_f32(c.b, c.length, c.h * d) for d in (c.dk, c.dv))
     d_q, d_k, d_v, d_g, d_beta = pl.pallas_call(
         partial(_backward_kernel, **c.static),
-        in_specs=[c.whole(c.sums), c.whole(c.sums_t), c.whole(c.lev),
+        in_specs=[c.whole(sums), c.whole(sums_t), c.whole(lev),
                   keys, keys, values, keys, c.heads_rows(), values,
                   c.kept(c.dv, c.dk), c.kept(CHUNK, CHUNK)],
         out_specs=[keys, keys, values, keys, c.dbeta()],
@@ -721,11 +779,10 @@ def _kernel_backward(q, k, v, g, beta, starts, solves, do, interpret=None):
                    _f32(c.b, c.h // c.group, c.length, c.group)],
         scratch_shapes=[pltpu.VMEM((c.group, c.dv, c.dk), jnp.float32)],
         **c.call,
-    )(c.sums, c.sums_t, c.lev, _flat(q), _flat(k), _flat(v), _flat(g), beta,
+    )(sums, sums_t, lev, _flat(q), _flat(k), _flat(v), _flat(g), beta,
       _flat(do), starts, solves)
     return (d_q.reshape(q.shape), d_k.reshape(k.shape), d_v.reshape(v.shape),
-            d_g.reshape(g.shape),
-            d_beta.transpose(0, 2, 1, 3).reshape(beta.shape))
+            d_g.reshape(g.shape), c.by_position(d_beta))
 
 
 def _whole_chunks(x):
@@ -801,6 +858,18 @@ def kda_scan(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, g: jnp.ndarray,
 # docstring with ``alpha_t`` **one number a head** and ``H_k`` key heads
 # under ``H_v = r H_k`` value heads, key head ``j`` serving value heads ``r j
 # .. r j + r - 1``.
+#
+# The kernels (head widths of whole lanes): a grid step is one chunk of
+# whole key heads with their value heads side by side.  What one step
+# makes once: the summed log-decays of every head, as columns and as rows,
+# by two thin exact products of the ``(C, H_v)`` block of ``g`` with a
+# triangle of ones (:meth:`_ScalarChunk.sums`), and ``[k; q] k^T`` a key
+# head (one bf16 pass; ``B``'s diagonal, ``q_t . k_t`` under no decay, a
+# float32 sum).  A value head's decay matrix is its column minus
+# its row under one ``exp``, and multiplies the float32 product: nothing
+# is rounded that the channel-wise form did not round.  From there on a
+# head is :meth:`_Chunk.forward` as it stands, the decays broadcasting
+# over lanes where the channel-wise ones multiply them.
 
 #: the name of :func:`gdn_scan`'s result for a checkpoint policy
 GDN_OUT = "gdn_out"
@@ -852,36 +921,279 @@ def gdn_scan_reference(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     return jnp.moveaxis(out, 0, 1).reshape(b, length, hv, dv)
 
 
+#: value heads a grid step of the scalar kernels holds at the most
+#: (:func:`_heads_a_step`): the bodies keep no levels, so twice
+#: :data:`HEADS_A_STEP` fit the scoped VMEM (16 key heads under 32 value
+#: heads of 128, the three kernels of a layer at 2, 4, 8 value heads a
+#: step: 13.1, 9.9, 8.5 ms; at 16 the walk back does not fit; PERF.md
+#: section 6, PR 62)
+SCALAR_HEADS_A_STEP = 8
+
+
+class _ScalarChunk(_Chunk):
+    """:class:`_Chunk` where the decay is one number a head and
+    position: the summed log-decays are a column, every place a decay
+    is applied a column or a scalar broadcast over lanes, and a chunk's
+    pair matrices **one product a key head** (:meth:`products`) under a
+    ``C x C`` decay matrix a value head (:meth:`pairs`).  No levels and
+    no tables: the masks are comparisons of iotas and the sums two thin
+    exact products of a grid step's ``g`` (:meth:`sums`).  Where
+    :class:`_Chunk`'s methods take a head's ``g``, this class's take
+    what :meth:`decays` returns."""
+
+    def __init__(self, one_pass):
+        self.one_pass = one_pass
+        t = jax.lax.broadcasted_iota(jnp.int32, (CHUNK, CHUNK), 0)
+        s = jax.lax.broadcasted_iota(jnp.int32, (CHUNK, CHUNK), 1)
+        self.before, self.upto = s < t, s <= t
+        self.eye = (s == t).astype(jnp.float32)
+        self.since = (s >= t).astype(jnp.float32)
+
+    def sums(self, g):
+        """The log-decays ``g (C, H)`` of every head summed from the
+        chunk's start: positions by heads, and heads by positions (a
+        decay matrix is a column minus a row)."""
+        return (_nn(self.upto.astype(jnp.float32), g, exact=True),
+                _tn(g, self.since, exact=True))
+
+    def products(self, q, k):
+        """A key head's ``[k; q] k^T``, ``(2 C, C)``, and ``q_t . k_t`` as
+        a column: ``B``'s diagonal, which no decay touches, is taken in
+        float32 as the channel-wise form takes it."""
+        return (_nt(self.low(jnp.concatenate([k, q], axis=0)), self.low(k)),
+                jnp.sum(q * k, axis=1, keepdims=True))
+
+    @staticmethod
+    def decays(sums, head, products):
+        """What the methods below take as a value head's ``g``: its
+        summed log-decays as a column ``(C, 1)`` and as a row ``(1,
+        C)``, and its key head's :meth:`products`."""
+        columns, rows = sums
+        at = jax.lax.broadcasted_iota(jnp.int32, rows.shape, 0)
+        return (_column_of(columns, head),
+                jnp.sum(jnp.where(at == head, rows, 0.0), axis=0,
+                        keepdims=True), products)
+
+    def pairs(self, q, k, g):
+        """``G`` (a column), ``A``, ``B`` and, in the levels' place, the
+        decay matrix ``exp(min(G_t - G_s, 0))``."""
+        gsum, across, (products, diagonal) = g
+        decay = jnp.exp(jnp.minimum(gsum - across, 0.0))
+        yield
+        a = jnp.where(self.before, products[:CHUNK] * decay, 0.0)
+        b = jnp.where(self.before, products[CHUNK:] * decay, 0.0
+                      ) + self.eye * diagonal
+        return gsum, a, b, decay
+
+    def backward(self, q, k, v, g, beta, state, t, do, dafter):
+        """As :meth:`_Chunk.backward`, but for what a key head's value
+        heads share: ``(dq, dk`` but for the pair products' part, the
+        cotangent of :meth:`products`, ``dv``, the summed log-decays'
+        cotangent as a column and what is to be taken from it as a row,
+        ``dbeta, dstate)``.  The decay matrix's part is row and column
+        sums of its cotangent times the matrix."""
+        dv = v.shape[1]
+        f, da, db, dkg, dqg, dkend, drhs, dbeta, dstate = \
+            yield from self.to_the_pairs(q, k, v, g, beta, state, t, do,
+                                         dafter)
+        decay = f["levels"]
+        d_products = jnp.concatenate(
+            [jnp.where(self.before, d * decay, 0.0) for d in (da, db)],
+            axis=0)
+        on_diagonal = jnp.sum(self.eye * db, axis=1, keepdims=True)
+        leaving = dkend * f["kend"]
+        dlast = jnp.exp(f["last"]) * jnp.sum(
+            state * dafter, keepdims=True) + jnp.sum(leaving, keepdims=True)
+        moved = da * f["a"] + db * f["b"]             # d G_t - d G_s
+        row = jax.lax.broadcasted_iota(jnp.int32, (CHUNK, 1), 0)
+        d_gsum = jnp.sum(moved, axis=1, keepdims=True) + jnp.sum(
+            dkg * f["kg"] + dqg * f["qg"] - leaving, axis=1, keepdims=True
+        ) + jnp.where(row == CHUNK - 1, dlast, 0.0)
+        return (dqg * f["into"] + on_diagonal * k,
+                dkg * f["into"] + dkend * f["out_of"] + on_diagonal * q,
+                d_products, beta * drhs[:, :dv], d_gsum,
+                jnp.sum(moved, axis=0, keepdims=True), dbeta, dstate)
+
+
+def _scalar_heads(chunk, q_ref, k_ref, v_ref, g_ref, beta_ref, group, per, dk,
+                  dv):
+    """A grid step's key heads ``[(q, k)]`` and, a value head, the
+    leading arguments of :class:`_ScalarChunk`'s ``forward`` and
+    ``backward``: ``(q, k, v, decays, beta)``."""
+    first = pl.program_id(1) * group                 # the step's value heads
+    sums = chunk.sums(g_ref[0])
+    keys = [tuple(ref[0, :, i * dk:(i + 1) * dk] for ref in (q_ref, k_ref))
+            for i in range(group // per)]
+    products = [chunk.products(q, k) for q, k in keys]
+    return keys, [
+        (*keys[j // per], v_ref[0, :, j * dv:(j + 1) * dv],
+         chunk.decays(sums, first + j, products[j // per]),
+         _beta_of(beta_ref, first + j)) for j in range(group)]
+
+
+def _scalar_forward_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, *rest,
+                           group, per, dk, dv, one_pass):
+    """:func:`_forward_kernel` on ``per`` value heads a key head: ``q``
+    and ``k`` blocks of ``group / per`` heads, ``g`` a block of every
+    head as ``beta``."""
+    *kept_refs, state_ref = rest
+    _zero_at_the_first(state_ref)
+    chunk = _ScalarChunk(one_pass)
+    _, heads = _scalar_heads(chunk, q_ref, k_ref, v_ref, g_ref, beta_ref,
+                             group, per, dk, dv)
+    states = [state_ref[j] for j in range(group)]
+    made = _in_step([chunk.forward(*heads[j], states[j])
+                     for j in range(group)])
+    _write_forward(made, states, o_ref, state_ref, kept_refs, dv)
+
+
+def _scalar_backward_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, do_ref,
+                            starts_ref, solves_ref, dq_ref, dk_ref, dv_ref,
+                            dg_ref, dbeta_ref, dstate_ref, *, group, per, dk,
+                            dv, one_pass):
+    """:func:`_backward_kernel` on ``per`` value heads a key head:
+    ``dq`` and ``dk`` summed over them and written once, the pair
+    products' part one product each way a key head; ``dg`` a lane a
+    head as ``dbeta``, the reverse cumulative sum of the summed
+    log-decays' cotangent."""
+    _zero_at_the_first(dstate_ref)
+    chunk = _ScalarChunk(one_pass)
+    keys, heads = _scalar_heads(chunk, q_ref, k_ref, v_ref, g_ref, beta_ref,
+                                group, per, dk, dv)
+    made = _in_step([chunk.backward(
+        *heads[j], starts_ref[0, j, 0], solves_ref[0, j, 0],
+        do_ref[0, :, j * dv:(j + 1) * dv], dstate_ref[j])
+        for j in range(group)])
+    lane = jax.lax.broadcasted_iota(jnp.int32, (CHUNK, group), 1)
+    at = jax.lax.broadcasted_iota(jnp.int32, (group, CHUNK), 0)
+    dbeta = d_gsum = jnp.zeros((CHUNK, group), jnp.float32)
+    taken = jnp.zeros((group, CHUNK), jnp.float32)
+    for j, (_, _, _, d_v, d_column, d_row, d_beta, dstate) in enumerate(made):
+        dv_ref[0, :, j * dv:(j + 1) * dv] = d_v
+        dbeta = jnp.where(lane == j, d_beta, dbeta)
+        d_gsum = jnp.where(lane == j, d_column, d_gsum)
+        taken = jnp.where(at == j, d_row, taken)
+        dstate_ref[j] = dstate
+    dbeta_ref[0, 0] = dbeta
+    dg_ref[0, 0] = _nn(chunk.since, d_gsum, exact=True) - _nt(
+        chunk.since, taken, exact=True)
+    shared = [tuple(sum(made[j][part] for j in range(i * per, (i + 1) * per))
+                    for part in range(3)) for i in range(group // per)]
+    along = [_nn(chunk.low(d_products), chunk.low(k))
+             for (_, k), (_, _, d_products) in zip(keys, shared)]
+    for i, ((q, k), (d_q, d_k, d_products)) in enumerate(zip(keys, shared)):
+        mine = slice(i * dk, (i + 1) * dk)
+        dq_ref[0, :, mine] = d_q + along[i][CHUNK:]
+        dk_ref[0, :, mine] = d_k + along[i][:CHUNK] + _tn(
+            chunk.low(d_products),
+            chunk.low(jnp.concatenate([k, q], axis=0)))
+
+
+# The two calls are ``jax.jit``s that the step's trace takes **inline**: a
+# body is traced once a process and shape, not once a layer and program,
+# and every layer still lowers to a Mosaic call of its own, which is what
+# the benchmark counts (``chipbench/child.py``: ``tpu_custom_call``s in the
+# lowered text).  What a trace reads is in its key: ``most`` is
+# :data:`SCALAR_HEADS_A_STEP` as the rule finds it when it is called.
+
+
+@partial(jax.jit, static_argnames=("keep", "most", "interpret"), inline=True)
+def _scalar_forward(q, k, v, g, beta, keep, most, interpret):
+    """:func:`_kernel_forward` for ``q, k (B, L, H_k, d_k)`` under ``v
+    (B, L, H_v, d_v)`` and ``g, beta (B, L, H_v)``: no array of the call
+    is wider than its operand."""
+    c = _Calls(q, v, False, interpret, most)
+    keys = c.rows(c.dk, c.group // c.per)
+    kept = ((c.dv, c.dk), (CHUNK, CHUNK)) if keep else ()
+    o, *rest = pl.pallas_call(
+        partial(_scalar_forward_kernel, per=c.per, **c.static),
+        in_specs=[keys, keys, c.rows(c.dv), c.heads_rows(), c.heads_rows()],
+        out_specs=[c.rows(c.dv)] + [c.kept(*shape) for shape in kept],
+        out_shape=[_f32(c.b, c.length, c.h * c.dv)] + [
+            _f32(c.b, c.h, c.n, *shape) for shape in kept],
+        scratch_shapes=[pltpu.VMEM((c.group, c.dv, c.dk), jnp.float32)],
+        **c.call,
+    )(_flat(q), _flat(k), _flat(v), g, beta)
+    o = o.reshape(v.shape)
+    return (o, *rest) if keep else o
+
+
+@partial(jax.jit, static_argnames=("most", "interpret"), inline=True)
+def _scalar_backward(q, k, v, g, beta, starts, solves, do, most, interpret):
+    """:func:`_kernel_backward` at the operands' own shapes."""
+    c = _Calls(q, v, True, interpret, most)
+    keys, values = c.rows(c.dk, c.group // c.per), c.rows(c.dv)
+    small = _f32(c.b, c.h // c.group, c.length, c.group)
+    d_keys = _f32(c.b, c.length, q.shape[2] * c.dk)
+    d_q, d_k, d_v, d_g, d_beta = pl.pallas_call(
+        partial(_scalar_backward_kernel, per=c.per, **c.static),
+        in_specs=[keys, keys, values, c.heads_rows(), c.heads_rows(), values,
+                  c.kept(c.dv, c.dk), c.kept(CHUNK, CHUNK)],
+        out_specs=[keys, keys, values, c.dbeta(), c.dbeta()],
+        out_shape=[d_keys, d_keys, _f32(c.b, c.length, c.h * c.dv), small,
+                   small],
+        scratch_shapes=[pltpu.VMEM((c.group, c.dv, c.dk), jnp.float32)],
+        **c.call,
+    )(_flat(q), _flat(k), _flat(v), g, beta, _flat(do), starts, solves)
+    return (d_q.reshape(q.shape), d_k.reshape(k.shape), d_v.reshape(v.shape),
+            c.by_position(d_g), c.by_position(d_beta))
+
+
+@jax.custom_vjp
+def _scalar_kernels_out(q, k, v, g, beta):
+    """The chunked form as the scalar rule's Mosaic kernels; head widths
+    of whole lanes."""
+    length = q.shape[1]
+    return _scalar_forward(*map(_whole_chunks, (q, k, v, g, beta)),
+                           keep=False, most=SCALAR_HEADS_A_STEP,
+                           interpret=use_interpret(None))[:, :length]
+
+
+def _scalar_kernels_fwd(q, k, v, g, beta):
+    return _scalar_kernels_out(q, k, v, g, beta), (q, k, v, g, beta)
+
+
+def _scalar_kernels_bwd(kept, ct):
+    kept_whole = tuple(map(_whole_chunks, kept))
+    how = dict(most=SCALAR_HEADS_A_STEP, interpret=use_interpret(None))
+    _, starts, solves = _scalar_forward(*kept_whole, keep=True, **how)
+    grads = _scalar_backward(*kept_whole, starts, solves, _whole_chunks(ct),
+                             **how)
+    length = ct.shape[1]
+    return tuple(x[:, :length] for x in grads)
+
+
+_scalar_kernels_out.defvjp(_scalar_kernels_fwd, _scalar_kernels_bwd)
+
+
 def gdn_scan(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, g: jnp.ndarray,
              beta: jnp.ndarray) -> jnp.ndarray:
     """The gated delta rule with a scalar decay a head in chunks of
     :data:`CHUNK` positions: ``q, k (B, L, H_k, d_k)``, ``v (B, L, H_v,
     d_v)``, ``g, beta (B, L, H_v)`` with ``H_v`` a multiple of ``H_k``;
     the result ``(B, L, H_v, d_v)``, named :data:`GDN_OUT`.  The solve,
-    the carry and the backward rule are the module's own.  Which form
-    runs is read off the shapes, as :func:`kda_scan`'s: any narrow head
-    width takes the XLA form with the chunk's pair matrices one product
-    each under a ``C x C`` decay matrix (:func:`_scalar_pairs`); head
-    widths of whole lanes take **the channel-wise Mosaic kernels on the
-    decay broadcast over the keys' channels and the keys repeated** ``H_v
-    / H_k`` times, which is exact (every channel's difference of sums is
-    the same number) and moves ``d_k`` times the decay and ``r`` times
-    the queries and keys the algorithm needs
-    (``chipbench/arithmetic/qwen3next.py`` ``gdn_scan_cost`` counts the
-    algorithm, not the broadcast; kernels of the scalar rule's own are
-    ROADMAP's).  The repeat's and the broadcast's transposes are sums:
-    the gradients come back at the operands' own shapes."""
+    the carry and the backward rule's shape are the module's own, a
+    chunk's pair matrices one product each under a ``C x C`` decay
+    matrix.  Which form runs is read off the shapes, as
+    :func:`kda_scan`'s: head widths of whole lanes take **the scalar
+    rule's three Mosaic kernels** (the forward, the forward again
+    keeping every chunk's starting state and solve, the walk back:
+    :class:`_ScalarChunk`) on the five operands as they are handed
+    over, the gradients written at the operands' own shapes; any narrow
+    head width takes the XLA form on the keys repeated for their value
+    heads (:func:`_scalar_pairs`; the repeat's transpose is a sum).
+    ``chipbench/arithmetic/qwen3next.py`` ``gdn_scan_cost`` counts the
+    algorithm at the operands' sizes, whichever runs."""
     hk, hv = k.shape[2], v.shape[2]
     if hv % hk or g.shape != v.shape[:3] or beta.shape != v.shape[:3]:
         raise ValueError(f"gdn_scan: {hv} value heads over {hk} key heads, "
                          f"g {g.shape}, beta {beta.shape}, v {v.shape}")
-    if hv != hk:
-        q, k = (jnp.repeat(x, hv // hk, axis=2) for x in (q, k))
     if GDN_SUM_DTYPE != jnp.float32:
         g = _sums_held_in(g, GDN_SUM_DTYPE)
     if q.shape[-1] % LANE == 0 and v.shape[-1] % LANE == 0:
-        out = _kernels_out(q, k, v, jnp.broadcast_to(g[..., None], q.shape),
-                           beta)
+        out = _scalar_kernels_out(q, k, v, g, beta)
     else:
+        if hv != hk:
+            q, k = (jnp.repeat(x, hv // hk, axis=2) for x in (q, k))
         out = kda_scan_xla(q, k, v, g[..., None], beta)
     return checkpoint_name(out, GDN_OUT)

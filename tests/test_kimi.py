@@ -383,6 +383,46 @@ def test_a_kda_layers_step_holds_the_scans_three_kernels(head_dim, kernels):
                          (1, 2, 2, 64, 64)]]
 
 
+# sha256 of ``str(make_jaxpr(value_and_grad(loss)))`` (addresses blanked)
+# of ``kda_scan`` at the cell's KDA shape (32 heads of 128, one sequence),
+# **as the parent commit of PR 62 printed it**: that PR gave the scalar
+# decay of ``gdn_scan`` Mosaic kernels of its own beside these, and
+# ``_Chunk.solve``, ``_in_step``, ``_Calls`` and ``_whole_chunks`` serve
+# both; the channel-wise program must still trace to what it was, to the
+# character, as the chip compiles it (``use_interpret`` steered off) and
+# interpreted, on whole chunks and with a ragged end.  A PR that changes
+# the channel-wise kernels on purpose records the new digests here and
+# says so.
+PARENTS_KDA = {
+    ("compiled", 8192): "0d07be5013763523",
+    ("compiled", 8150): "55918b446ba9af20",
+    ("interpreted", 8192): "aea0249fde720cea",
+    ("interpreted", 8150): "425b5d97b2ffb359",
+}
+
+
+@pytest.mark.parametrize("how,length", sorted(PARENTS_KDA))
+def test_the_channel_wise_scan_traces_to_the_parents_program(
+        how, length, monkeypatch):
+    import hashlib
+    import re
+
+    monkeypatch.setattr(delta_rule, "use_interpret",
+                        lambda flag: how == "interpreted")
+    wide = jax.ShapeDtypeStruct((1, length, 32, 128), jnp.float32)
+    beta = jax.ShapeDtypeStruct((1, length, 32), jnp.float32)
+
+    def loss(q, k, v, g, beta):
+        return jnp.sum(delta_rule.kda_scan(q, k, v, g, beta) ** 2)
+
+    text = re.sub(r"0x[0-9a-f]+", "0x", str(jax.make_jaxpr(
+        jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4)))(
+            wide, wide, wide, wide, beta)))
+    assert text.count("pallas_call") >= 3
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == \
+        PARENTS_KDA[how, length]
+
+
 # -- (b) the decoder against the plain reference ---------------------------------
 
 

@@ -138,11 +138,18 @@ SCANS = [
 ]
 
 
+# narrow heads take the XLA form, heads of whole lanes the scalar rule's
+# three Mosaic kernels (interpreted here): both are held to the recurrence
+WIDTHS = {"narrow": dict(dk=16, dv=8), "whole_lanes": dict(dk=128, dv=128)}
+widths = pytest.mark.parametrize("width", sorted(WIDTHS))
+
+
+@widths
 @pytest.mark.parametrize("what,length,hk,hv,lo,hi,tol", SCANS,
                          ids=[s[0] for s in SCANS])
 def test_the_chunked_entry_is_the_recurrence_forward_and_backward(
-        what, length, hk, hv, lo, hi, tol):
-    args = scan_inputs(length, lo, hi, hk=hk, hv=hv)
+        what, length, hk, hv, lo, hi, tol, width):
+    args = scan_inputs(length, lo, hi, hk=hk, hv=hv, **WIDTHS[width])
     ct = jax.random.normal(jax.random.PRNGKey(9), args[2].shape)
     got, got_grads = both(delta_rule.gdn_scan, args, ct)
     want, want_grads = both(delta_rule.gdn_scan_reference, args, ct)
@@ -153,15 +160,18 @@ def test_the_chunked_entry_is_the_recurrence_forward_and_backward(
         assert relative(g, w) < 10 * tol, (what, name)
 
 
+@widths
 @pytest.mark.parametrize("hk,hv", [(2, 4), (2, 2)])
 @pytest.mark.parametrize("length", [128, 100])
 def test_the_entry_agrees_with_the_channel_wise_scan_on_the_broadcast_decay(
-        hk, hv, length):
+        hk, hv, length, width):
     """A scalar decay a head broadcast over the keys' channels, the keys
     repeated for their value heads, is exact: ``kda_scan`` on them and
     ``gdn_scan`` agree to rounding, forward and in the gradients summed
-    back to the operands' shapes."""
-    args = scan_inputs(length, 0.3, 1.0, hk=hk, hv=hv)
+    back to the operands' shapes.  At whole lanes the two are kernels of
+    their own: the channel-wise ones' halving against one product under
+    a decay matrix."""
+    args = scan_inputs(length, 0.3, 1.0, hk=hk, hv=hv, **WIDTHS[width])
     ct = jax.random.normal(jax.random.PRNGKey(3), args[2].shape)
 
     def broadcast(q, k, v, g, beta):
@@ -176,43 +186,117 @@ def test_the_entry_agrees_with_the_channel_wise_scan_on_the_broadcast_decay(
         assert relative(g, w) < 1e-4
 
 
-def test_whole_lane_head_widths_take_the_kernels_on_the_broadcast():
-    """Head widths of 128 run Kimi's three Mosaic kernels (interpreted
-    here) on the repeated keys and the broadcast decay; they are the
-    recurrence at 1 key head under 2 value heads."""
-    args = scan_inputs(128, 0.5, 1.0, hk=1, hv=2, dk=128, dv=128, batch=1)
+def _pallas_calls(jaxpr):
+    """Every ``pallas_call`` equation of a jaxpr and of the jaxprs its
+    equations close."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append(eqn)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _pallas_calls(sub)
+    return found
+
+
+def test_whole_lane_head_widths_take_the_kernels_at_the_operands_own_widths():
+    """Head widths of whole lanes run the scalar rule's three Mosaic
+    kernels (interpreted here), one forward and two in the rule, and no
+    array of a call is wider than the entry's own argument: q and k at
+    the key heads, the log-decay and ``beta`` a float a value head; no
+    key repeated, no decay broadcast.  Keys of 128 under values of 256,
+    so that a width says whose it is."""
+    hk, hv, dk, dv, length = 1, 2, 128, 256, 128
+    args = scan_inputs(length, 0.5, 1.0, hk=hk, hv=hv, dk=dk, dv=dv, batch=1)
     ct = jax.random.normal(jax.random.PRNGKey(4), args[2].shape)
-    text = str(jax.make_jaxpr(delta_rule.gdn_scan)(*args))
-    assert "pallas_call" in text
+    own = {hk * dk, hv * dv, hv}
+
+    def rows(avals):     # the arrays with a position a row
+        return [a.shape for a in avals
+                if len(a.shape) == 3 and a.shape[1] == length]
+
+    forward = _pallas_calls(jax.make_jaxpr(delta_rule.gdn_scan)(*args).jaxpr)
+    assert len(forward) == 1
+    assert sorted(rows(v.aval for v in forward[0].invars)) == sorted(
+        [(1, length, hk * dk)] * 2 + [(1, length, hv * dv)]
+        + [(1, length, hv)] * 2)
+    rule = _pallas_calls(jax.make_jaxpr(
+        lambda *a: jax.vjp(delta_rule.gdn_scan, *a)[1](ct))(*args).jaxpr)
+    assert len(rule) == 3               # the forward, it again, the walk back
+    for call in rule:
+        for shape in rows(v.aval for v in call.invars + call.outvars):
+            assert shape[-1] in own, shape
     narrow = scan_inputs(128, 0.5, 1.0)
-    assert "pallas_call" not in str(
-        jax.make_jaxpr(delta_rule.gdn_scan)(*narrow))
+    assert not _pallas_calls(
+        jax.make_jaxpr(delta_rule.gdn_scan)(*narrow).jaxpr)
     got, got_grads = both(delta_rule.gdn_scan, args, ct)
     want, want_grads = both(delta_rule.gdn_scan_reference, args, ct)
     assert relative(got, want) < 2e-5
     for g, w in zip(got_grads, want_grads):
+        assert g.shape == w.shape
         assert relative(g, w) < 2e-4
 
 
-def test_no_state_crosses_the_rows_of_a_batch():
-    q, k, v, g, beta = scan_inputs(96, 0.5, 1.0)
+@pytest.mark.parametrize("hk,hv,most,held", [
+    (16, 32, 8, 8), (16, 32, 4, 4), (16, 16, 8, 8), (2, 8, 8, 8),
+    (3, 12, 8, 4), (1, 16, 8, 16), (6, 6, 8, 6), (5, 10, 8, 2)])
+def test_a_grid_step_holds_whole_key_heads(hk, hv, most, held):
+    """The value heads of a grid step: whole key heads, as many as
+    divide their count and come to ``most`` value heads or fewer, one
+    key head at the least; the channel-wise kernels' rule where every
+    head has its own keys."""
+    per = hv // hk
+    assert delta_rule._heads_a_step(hk, per, most) == held
+    assert held % per == 0 and hv % held == 0
+    assert delta_rule._heads_a_step(hv) == max(
+        n for n in range(1, delta_rule.HEADS_A_STEP + 1) if hv % n == 0)
+
+
+def test_the_heads_a_step_are_read_at_every_call(monkeypatch):
+    """The kernels' calls are ``jit``s taken inline, traced once a
+    shape: what a trace reads of the module is in its key, so a changed
+    :data:`SCALAR_HEADS_A_STEP` is another trace and another grid."""
+    args = scan_inputs(128, 0.5, 1.0, hk=2, hv=4, dk=128, dv=128, batch=1)
+
+    def grids():
+        jaxpr = jax.make_jaxpr(
+            lambda *a: jax.vjp(delta_rule.gdn_scan, *a)[1](a[2]))(*args)
+        return [call.params["grid_mapping"].grid
+                for call in _pallas_calls(jaxpr.jaxpr)]
+
+    assert grids() == [(1, 1, 2)] * 3       # four value heads in one step
+    monkeypatch.setattr(delta_rule, "SCALAR_HEADS_A_STEP", 2)
+    assert grids() == [(1, 2, 2)] * 3       # a key head a step
+
+
+@widths
+def test_no_state_crosses_the_rows_of_a_batch(width):
+    q, k, v, g, beta = scan_inputs(96, 0.5, 1.0, **WIDTHS[width])
     whole = delta_rule.gdn_scan(q, k, v, g, beta)
     alone = delta_rule.gdn_scan(q[1:], k[1:], v[1:], g[1:], beta[1:])
     np.testing.assert_allclose(whole[1:], alone, atol=1e-6)
 
 
-def test_the_backward_rule_keeps_the_five_inputs_and_no_chunk_state():
+@widths
+def test_the_backward_rule_keeps_the_five_inputs_and_no_chunk_state(width):
     from jax._src.ad_checkpoint import saved_residuals
 
-    args = scan_inputs(256, 0.5, 1.0)
+    args = scan_inputs(256, 0.5, 1.0, **WIDTHS[width])
     kept = saved_residuals(delta_rule.gdn_scan, *args)
+    dk, dv = WIDTHS[width]["dk"], WIDTHS[width]["dv"]
     batch, length, heads = args[4].shape
-    largest = batch * length * heads * 16    # a key repeated for its heads
+    # the kernels keep the inputs as they are; the XLA form a key
+    # repeated for its heads, cut in chunks
+    largest = max(x.size for x in args) if width == "whole_lanes" else \
+        batch * length * heads * dk
     for shape, why in kept:
-        # the inputs as they are, repeated or cut in chunks, never a state
-        # ``d_k x d_v`` a chunk or a position, nor a chunk's ``C x C``
+        # never a state ``d_k x d_v`` a chunk or a position, nor a chunk's
+        # ``C x C``
         assert math.prod(shape.shape) <= largest, (shape, why)
-        assert shape.shape[-2:] not in ((16, 8), (64, 64)), (shape, why)
+        assert shape.shape[-2:] not in ((dk, dv), (dv, dk), (64, 64)), (
+            shape, why)
+    if width == "whole_lanes":
+        assert sorted(s.shape for s, _ in kept) == sorted(
+            x.shape for x in args)
 
 
 def test_mismatched_heads_are_refused():
@@ -224,11 +308,13 @@ def test_mismatched_heads_are_refused():
         delta_rule.gdn_scan(q, k, v, g[..., :2], beta)
 
 
+@widths
 def test_the_sums_dtype_is_read_at_every_call_and_a_lower_one_shows(
-        monkeypatch):
+        monkeypatch, width):
     """The log-decays are summed in float32; the probe's knob holds the
-    sums from a chunk's start in bf16, and that shows."""
-    args = scan_inputs(150, 0.5, 1.0)
+    sums from a chunk's start in bf16, and that shows, in the XLA form
+    and in the kernels."""
+    args = scan_inputs(150, 0.5, 1.0, **WIDTHS[width])
     sound = delta_rule.gdn_scan(*args)
     monkeypatch.setattr(delta_rule, "GDN_SUM_DTYPE", jnp.bfloat16)
     low = delta_rule.gdn_scan(*args)

@@ -557,10 +557,14 @@ def test_the_grouped_product_compiles_at_32_held_experts_of_512(
 def test_the_gated_delta_scan_compiles_at_the_published_shape(
         one_chip, monkeypatch):
     """Qwen3-Next's Gated DeltaNet layer: 16 key heads under 32 value
-    heads of 128 at 8192, one scalar decay a value head.  The entry's
-    first form repeats the keys and broadcasts the decay in front of
-    Kimi's three kernels; the five gradients come back at the operands'
-    own shapes (q and k at 16 heads, g and beta a float a head)."""
+    heads of 128 at 8192, one scalar decay a value head, as the scalar
+    rule's own three kernels (PR 62): the forward, the forward again
+    writing the 128 chunk states and solves a value head, the walk
+    back.  Every call reads q and k at ``(8192, 16 x 128)``, v at
+    ``(8192, 32 x 128)`` and the log-decay and ``beta`` at ``(8192,
+    32)``: no key is repeated and no decay broadcast to ``(1, 8192, 32,
+    128)`` anywhere in the program, and the five gradients come back at
+    the operands' own shapes."""
     from mpit_tpu.ops import delta_rule
 
     monkeypatch.setattr(delta_rule, "use_interpret", lambda flag: False)
@@ -579,6 +583,19 @@ def test_the_gated_delta_scan_compiles_at_the_published_shape(
     text = compiled.as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 3
     assert "f32[1,32,128,128,128]" in text   # the chunks' starting states
+    assert "f32[1,32,128,64,64]" in text     # and their solves
+    read = re.findall(
+        r"operand_layout_constraints=\{((?:[^{}]|\{[^}]*\})*)\}", text)
+    assert len(read) == 3
+    for operands in read:     # what a call reads first: q, k, v, g, beta
+        assert re.findall(r"f32\[([\d,]+)\]", operands)[:5] == [
+            "1,8192,2048", "1,8192,2048", "1,8192,4096", "1,8192,32",
+            "1,8192,32"]
+    # the first form's decay over the keys' channels and its keys
+    # repeated for their value heads: a ``broadcast_in_dim`` each, by
+    # whatever instruction the compiler made of it
+    assert not re.search(r"= f32\[1,8192,[\d,]*(4096|128)\]\S* \w+\("
+                         r"[^\n]*broadcast_in_dim", text)
     shapes = [tuple(s.shape) for s in jax.eval_shape(
         jax.grad(loss, argnums=(0, 1, 2, 3, 4)),
         keys, keys, values, small, small)]
