@@ -45,7 +45,8 @@ SIZES: Dict[str, Size] = {
     "route_scale": Size(1.0, "the router's routed_scaling_factor"),
     "dense_layers": Size(0, "how many layers, the first, have the dense MLP "
                          "of dense_width and not the sparse one"),
-    "dense_width": Size(0, "the dense MLP's inner width (ouro's one MLP)"),
+    "dense_width": Size(0, "the dense MLP's inner width (ouro's one MLP; "
+                        "granite's gated one beside every mixer)"),
     # rotary positions, RMSNorm, grouped heads
     "rope_theta": Size(10000.0, "the rotary base; kimi's 0: no rotary "
                        "embedding in its latent attention"),
@@ -68,11 +69,14 @@ SIZES: Dict[str, Size] = {
                         "sliding_attention (window keys, rotary positions) "
                         "or full_attention (no positions), nemotron's "
                         "mamba, moe or attention: one branch a layer; "
-                        "qwen3next's linear_attention or full_attention"),
+                        "qwen3next's linear_attention or full_attention; "
+                        "granite's mamba or attention, each before a "
+                        "gated MLP"),
     "conv_kernel": Size(3, "the taps of a short causal depthwise convolution "
                         "(lfm2's gated one; kimi's on q, k and v: 4; "
                         "nemotron's on x, B and C together, with a bias: 4; "
-                        "qwen3next's on q, k and v together, no bias: 4)"),
+                        "qwen3next's on q, k and v together, no bias: 4; "
+                        "granite's as nemotron's: 4)"),
     # ouro's loop
     "loop_steps": Size(4, "how often the layers are applied, same weights"),
     "exit_beta": Size(0.1, "the exit distribution's entropy's weight in the "
@@ -144,6 +148,14 @@ SIZES: Dict[str, Size] = {
     "rotary_factor": Size(1.0, "the share of a head's width that is "
                           "rotated, from its first dimension on "
                           "(partial_rotary_factor); 1: the whole head"),
+    # granite's multipliers (embed_scale is its embedding_multiplier)
+    "residual_scale": Size(1.0, "what every branch is multiplied by before "
+                           "it joins the stream (residual_multiplier)"),
+    "attn_scale": Size(0.0, "what the attention's scores are multiplied "
+                       "by in place of 1 / sqrt(head_dim) "
+                       "(attention_multiplier); 0: that"),
+    "logits_scale": Size(1.0, "what the logits are divided by "
+                         "(logits_scaling)"),
 }
 
 DEFAULTS = {name: size.default for name, size in SIZES.items()}
@@ -357,6 +369,25 @@ def _qwen3next(s, attn):
                    **_heads(s))
 
 
+def _granite(s, attn):
+    kinds = _layer_kinds(s)
+    state = tuple(s[name] for name in (
+        "ssm_heads", "ssm_head_dim", "ssm_groups", "ssm_state", "ssm_chunk",
+        "dense_width"))
+    scales = tuple(s[name] for name in (
+        "embed_scale", "residual_scale", "logits_scale"))
+    if min(state) < 1 or s["ssm_heads"] % s["ssm_groups"] \
+            or min(scales) <= 0 or s["attn_scale"] < 0:
+        raise ValueError(f"granite needs ssm_heads, ssm_head_dim, "
+                         f"ssm_groups (dividing the heads), ssm_state, "
+                         f"ssm_chunk and dense_width: {state}; embed_scale, "
+                         f"residual_scale and logits_scale over 0: "
+                         f"{scales}; attn_scale {s['attn_scale']} of 0 "
+                         f"or more")
+    return _module("GraniteDecoder", s, attn(), layer_types=kinds,
+                   **_heads(s))
+
+
 # what each block is: its decoder's docstring (``models/transformer.py``)
 BLOCKS: Dict[str, Block] = {
     "gpt2": Block((), _gpt2, sample_len=0),
@@ -412,6 +443,13 @@ BLOCKS: Dict[str, Block] = {
             "gdn_value_heads", "gdn_key_dim", "gdn_value_dim",
             "rotary_factor", "shared_experts", "shared_width"),
         _qwen3next, loss=OWN_LOSS),
+    "granite": Block(
+        _GROUPED + (
+            "norm_eps", "layer_types", "conv_kernel", "ssm_heads",
+            "ssm_head_dim", "ssm_groups", "ssm_state", "ssm_chunk",
+            "dense_width", "embed_scale", "residual_scale", "attn_scale",
+            "logits_scale"),
+        _granite, loss=OWN_LOSS),
 }
 ARCHS = tuple(BLOCKS)
 

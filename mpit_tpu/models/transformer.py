@@ -423,7 +423,8 @@ def grouped_attention(h: jnp.ndarray, wq: jnp.ndarray, wk: jnp.ndarray,
                       qk_norm: Optional[tuple] = None,
                       period: int = 0,
                       gate: Optional[jnp.ndarray] = None,
-                      rotary: int = 0) -> jnp.ndarray:
+                      rotary: int = 0,
+                      query_scale: float = 1.0) -> jnp.ndarray:
     """Attention over grouped KV heads of their own width on the normed
     stream ``h (B, L, d)``, projected back to ``(B, L, d)``: bias-free
     projections to ``heads`` query and ``kv_heads`` key and value heads
@@ -440,7 +441,10 @@ def grouped_attention(h: jnp.ndarray, wq: jnp.ndarray, wk: jnp.ndarray,
     part's table, ``rotary / 2`` pairs in :func:`rope_by`'s half-split
     pairing over those dimensions alone) and the rest pass as they are;
     0, or ``head_dim``: the whole head, :func:`rope_by`'s result to the
-    bit.  Every
+    bit.  ``query_scale``: the query heads are multiplied by it in front
+    of ``attn``, which scales the scores by ``1 / sqrt(head_dim)``: a
+    block whose softmax scale is another ``m`` hands over ``m
+    sqrt(head_dim)`` (Granite's); 1: nothing is multiplied.  Every
     product at the backend's default precision, one bf16 pass on a TPU:
     Mellum's scores are O(1) without a norm, and LFM2's with its per-head
     norm read the same gradient error against the float32 reference with
@@ -460,6 +464,8 @@ def grouped_attention(h: jnp.ndarray, wq: jnp.ndarray, wk: jnp.ndarray,
         return x if inv_freq is None else rope_by(x, inv_freq, scale, period)
 
     q = heads_of(q, heads, qk_norm and qk_norm[0])
+    if query_scale != 1.0:
+        q = q * query_scale
     k = heads_of(k, kv_heads, qk_norm and qk_norm[1])
     v = v.reshape(b, l, kv_heads, head_dim)
     out = attn(q, k, v, window=window) if window else attn(q, k, v)
@@ -2603,16 +2609,53 @@ def state_space_mixer(x: jnp.ndarray, p: dict, *, heads: int, head_dim: int,
         return y @ p["w_out"], decay_mean
 
 
+def state_space_leaves(d: int, heads: int, head_dim: int, groups: int,
+                       state: int, taps: int, out_init) -> tuple:
+    """``(name, initialiser, shape)`` of every leaf
+    :func:`state_space_mixer` reads: the layer's norm, ``W_in`` to z,
+    x | B | C and a step a head, the convolution's taps and bias, the
+    step's bias, ``A_log``, the skip, the gated norm's weight and
+    ``W_out`` (seeded by ``out_init``)."""
+    ones = nn.initializers.ones
+    inner = heads * head_dim
+    mixed = inner + 2 * groups * state
+    return (
+        ("norm", ones, (d,)),
+        ("w_in", _INIT, (d, inner + mixed + heads)),
+        # at LFM2's scale and for its reason: at 0.02 the taps'
+        # gradients are lost in the norm of the whole
+        ("conv_w", LFM2_TAPS_INIT, (taps, mixed)),
+        ("conv_b", _INIT, (mixed,)),
+        ("dt_bias", kda_dt_bias_init, (heads,)),
+        ("a_log", ssm_a_log_init, (heads,)),
+        ("d_skip", ones, (heads,)),
+        ("ssm_norm", ones, (inner,)),
+        ("w_out", out_init, (inner, d)))
+
+
+def plain_attention_leaves(d: int, heads: int, kv_heads: int, head_dim: int,
+                           out_init) -> tuple:
+    """``(name, initialiser, shape)`` of every leaf
+    :func:`plain_attention` reads; ``wo`` is seeded by ``out_init``."""
+    return (("norm", nn.initializers.ones, (d,)),
+            ("wq", _INIT, (d, heads * head_dim)),
+            ("wk", _INIT, (d, kv_heads * head_dim)),
+            ("wv", _INIT, (d, kv_heads * head_dim)),
+            ("wo", out_init, (heads * head_dim, d)))
+
+
 def plain_attention(x: jnp.ndarray, p: dict, *, heads: int, kv_heads: int,
-                    head_dim: int, eps: float, attn: AttnFn) -> jnp.ndarray:
+                    head_dim: int, eps: float, attn: AttnFn,
+                    query_scale: float = 1.0) -> jnp.ndarray:
     """One position-free attention branch on the stream ``x (B, L, d)``:
     the norm before the layer and :func:`grouped_attention` with no
-    rotation, no norm on queries or keys, no gate; under ``attn``."""
+    rotation, no norm on queries or keys, no gate; under ``attn``.
+    ``query_scale`` as :func:`grouped_attention` takes it."""
     with jax.named_scope("attn"):
         return grouped_attention(
             rms_norm(x, p["norm"], eps), p["wq"], p["wk"], p["wv"], p["wo"],
             heads=heads, kv_heads=kv_heads, head_dim=head_dim,
-            inv_freq=None, attn=attn)
+            inv_freq=None, attn=attn, query_scale=query_scale)
 
 
 class NemotronBlock(nn.Module):
@@ -2658,11 +2701,8 @@ class NemotronBlock(nn.Module):
         if self.kind == "attention":
             hq, hkv, hd = self.n_heads, self.kv_heads, self.head_dim
             p = {name: self.param(name, init, shape)
-                 for name, init, shape in (
-                     ("norm", ones, (d,)),
-                     ("wq", _INIT, (d, hq * hd)), ("wk", _INIT, (d, hkv * hd)),
-                     ("wv", _INIT, (d, hkv * hd)),
-                     ("wo", out_init, (hq * hd, d)))}
+                 for name, init, shape in plain_attention_leaves(
+                     d, hq, hkv, hd, out_init)}
             # kept: the layer's input and the flash rule's two, as
             # Trinity's attention branch
             y = jax.checkpoint(
@@ -2677,19 +2717,9 @@ class NemotronBlock(nn.Module):
                              f"{NEMOTRON_LAYERS}")
         h, hd, g, n = (self.ssm_heads, self.ssm_head_dim, self.ssm_groups,
                        self.ssm_state)
-        inner, mixed = h * hd, h * hd + 2 * g * n
-        p = {name: self.param(name, init, shape) for name, init, shape in (
-            ("norm", ones, (d,)),
-            ("w_in", _INIT, (d, inner + mixed + h)),
-            # at LFM2's scale and for its reason: at 0.02 the taps'
-            # gradients are lost in the norm of the whole
-            ("conv_w", LFM2_TAPS_INIT, (self.conv_kernel, mixed)),
-            ("conv_b", _INIT, (mixed,)),
-            ("dt_bias", kda_dt_bias_init, (h,)),
-            ("a_log", ssm_a_log_init, (h,)),
-            ("d_skip", ones, (h,)),
-            ("ssm_norm", ones, (inner,)),
-            ("w_out", out_init, (inner, d)))}
+        p = {name: self.param(name, init, shape)
+             for name, init, shape in state_space_leaves(
+                 d, h, hd, g, n, self.conv_kernel, out_init)}
         y, decay = jax.checkpoint(
             partial(state_space_mixer, heads=h, head_dim=hd, groups=g,
                     state=n, chunk=self.ssm_chunk, eps=eps),
@@ -3048,4 +3078,200 @@ class Qwen3NextDecoder(nn.Module):
                          map(jnp.stack, zip(*counted))))
         if decays:
             stats[GDN_DECAY_MEAN] = jnp.stack(decays)
+        return loss, stats
+
+
+# ---------------------------------------------------------------------------
+# The dense state-space hybrid (IBM Granite-4.0-H-Micro, ``model_type``
+# ``granitemoehybrid`` with ``num_local_experts`` 0; the configuration's
+# keys are those of its ``config.json``, the mixer's equations Mamba-2's
+# as the public ``granitemoehybrid`` module has them).  **A layer is two
+# sublayers**: a token mixer by ``layer_types`` (``mamba``:
+# :func:`state_space_mixer`, here with **all the heads in one group**,
+# so ``B`` and ``C`` are shared by every head and the gated RMSNorm's
+# mean square is over all the inner channels; ``attention``:
+# :func:`plain_attention`, grouped heads with no positional term), then a
+# gated SiLU MLP whose two input matrices are one leaf, ``[W_a | W_b]``.
+# **Four multipliers scale the stream**: the looked-up rows are
+# multiplied by ``embed_scale`` (``embedding_multiplier``), every
+# branch by ``residual_scale`` (``residual_multiplier``) before it joins
+# the stream, the attention's scores by ``attn_scale``
+# (``attention_multiplier``) **in place of** ``1 / sqrt(head_dim)``, and
+# the logits are divided by ``logits_scale`` (``logits_scaling``).
+# **The head is tied**: the logits are the normed stream against the
+# token table transposed, and the table's gradient has two sources, the
+# look-up and the head.  The plain float32 reference it is held to is
+# ``chipbench/reference/granite_plain.py``, which shares no code with
+# this file and steps the state a position at a time
+# (tests/test_granite.py).
+# ---------------------------------------------------------------------------
+
+#: the kinds of token mixer ``layer_types`` may name
+GRANITE_MIXERS = ("mamba", "attention")
+#: the name of the stream's root mean square on its way into the final
+#: norm in the step's telemetry (gauge ``mpit_lm_stream_rms``, one
+#: entry): what ``embed_scale`` and ``residual_scale`` set
+STREAM_RMS = "lm_stream_rms"
+
+
+def gated_mlp(x: jnp.ndarray, p: dict, *, eps: float) -> jnp.ndarray:
+    """A gated SiLU MLP on the stream ``x (B, L, d)`` behind its norm:
+    ``(SiLU(h W_a) * (h W_b)) W_o`` with ``[W_a | W_b]`` one matrix
+    ``mlp_in (d, 2 f)``; under the scope ``mlp``."""
+    with jax.named_scope("mlp"):
+        both = rms_norm(x, p["mlp_norm"], eps) @ p["mlp_in"]
+        width = both.shape[-1] // 2
+        return (jax.nn.silu(both[..., :width]) * both[..., width:]
+                ) @ p["mlp_out"]
+
+
+class GraniteBlock(nn.Module):
+    d_model: int
+    mixer: str               # of GRANITE_MIXERS
+    n_heads: int
+    kv_heads: int
+    head_dim: int
+    ssm_heads: int
+    ssm_head_dim: int
+    ssm_groups: int
+    ssm_state: int
+    dense_width: int         # the MLP's inner width
+    ssm_chunk: int = SSD_CHUNK
+    conv_kernel: int = 4
+    residual_scale: float = 1.0
+    attn_scale: float = 0.0  # 0: 1 / sqrt(head_dim)
+    norm_eps: float = 1e-5
+    attn_fn: Optional[AttnFn] = None
+
+    @nn.compact
+    def __call__(self, x: jnp.ndarray):
+        """``(the stream after the layer, the ``mamba`` mixer's mean
+        decay or None)``.  Each sublayer is under ``jax.checkpoint``: a
+        ``mamba`` mixer keeps its input and the scan's result
+        (:data:`SSM_KEPT`), the attention its input and the flash rule's
+        two, the MLP its input alone (``h W_a`` and ``h W_b``, ``T x 2
+        f`` floats, are made again)."""
+        d, eps, ones = self.d_model, self.norm_eps, nn.initializers.ones
+        r, decay = self.residual_scale, None
+        if self.mixer == "attention":
+            hq, hkv, hd = self.n_heads, self.kv_heads, self.head_dim
+            p = {name: self.param(name, init, shape)
+                 for name, init, shape in plain_attention_leaves(
+                     d, hq, hkv, hd, _INIT)}
+            # the flash kernels and both plain forms scale the scores by
+            # 1 / sqrt(head_dim): the queries are multiplied by
+            # ``attn_scale sqrt(head_dim)`` in front of the call, which
+            # is the same scores (the published 1/64 at heads of 64: by
+            # 1/8, a power of two, exact at any precision)
+            y = jax.checkpoint(
+                partial(plain_attention, heads=hq, kv_heads=hkv, head_dim=hd,
+                        eps=eps, attn=self.attn_fn if self.attn_fn is not None
+                        else default_attn(),
+                        query_scale=self.attn_scale * math.sqrt(hd)
+                        if self.attn_scale else 1.0),
+                policy=jax.checkpoint_policies.save_only_these_names(
+                    *JOYAI_ATTN_KEPT))(x, p)
+            with jax.named_scope("attn"):
+                x = x + r * y
+        elif self.mixer == "mamba":
+            h, hd, g, n = (self.ssm_heads, self.ssm_head_dim, self.ssm_groups,
+                           self.ssm_state)
+            p = {name: self.param(name, init, shape)
+                 for name, init, shape in state_space_leaves(
+                     d, h, hd, g, n, self.conv_kernel, _INIT)}
+            y, decay = jax.checkpoint(
+                partial(state_space_mixer, heads=h, head_dim=hd, groups=g,
+                        state=n, chunk=self.ssm_chunk, eps=eps),
+                policy=jax.checkpoint_policies.save_only_these_names(
+                    *SSM_KEPT))(x, p)
+            with jax.named_scope("ssm_proj"):
+                x = x + r * y
+        else:
+            raise ValueError(f"layer type {self.mixer!r}; have "
+                             f"{GRANITE_MIXERS}")
+        f = self.dense_width
+        y = jax.checkpoint(partial(gated_mlp, eps=eps))(x, {
+            "mlp_norm": self.param("mlp_norm", ones, (d,)),
+            "mlp_in": self.param("mlp_in", _INIT, (d, 2 * f)),
+            "mlp_out": self.param("mlp_out", _INIT, (f, d))})
+        with jax.named_scope("mlp"):
+            return x + r * y, decay
+
+
+class GraniteDecoder(nn.Module):
+    """Causal LM of :class:`GraniteBlock` layers: a token table whose
+    rows are multiplied by ``embed_scale`` (seeded at the std of every
+    other matrix: the multiplier is what sets a row's size beside the
+    branches), the layers (layer ``i``'s mixer is ``layer_types[i]``,
+    every MLP dense and gated), a final RMSNorm and **the table again as
+    the head**, the logits divided by ``logits_scale``.  Like
+    :class:`KimiDecoder` it is called with the targets and returns its
+    own loss, the head's mean next-token NLL, with its statistics
+    (``lm/model.py`` closes over it):
+
+    - :data:`SSM_DECAY_MEAN`: the mean of the decay ``a_t`` over
+      positions and heads, one entry a ``mamba`` layer;
+    - :data:`STREAM_RMS`: the root mean square of the stream entering
+      the final norm, one entry.
+
+    The head's norm, product and loss is under ``jax.checkpoint``,
+    keeping the rows' log-sum-exp by name (:func:`row_lse`)."""
+
+    vocab: int = 256
+    d_model: int = 64
+    n_heads: int = 4
+    kv_heads: int = 2
+    head_dim: int = 16
+    ssm_heads: int = 4
+    ssm_head_dim: int = 16
+    ssm_groups: int = 1
+    ssm_state: int = 16
+    ssm_chunk: int = SSD_CHUNK
+    layer_types: tuple = ("mamba", "mamba", "attention", "mamba")
+    dense_width: int = 96
+    conv_kernel: int = 4
+    embed_scale: float = 1.0
+    residual_scale: float = 1.0
+    attn_scale: float = 0.0
+    logits_scale: float = 1.0
+    norm_eps: float = 1e-5
+    attn_fn: Optional[AttnFn] = None
+
+    @nn.compact
+    def __call__(self, tokens: jnp.ndarray, targets: jnp.ndarray):
+        d, eps = self.d_model, self.norm_eps
+        sizes = {field: getattr(self, field) for field in (
+            "d_model", "n_heads", "kv_heads", "head_dim", "ssm_heads",
+            "ssm_head_dim", "ssm_groups", "ssm_state", "ssm_chunk",
+            "dense_width", "conv_kernel", "residual_scale", "attn_scale",
+            "norm_eps", "attn_fn")}
+
+        @partial(jax.checkpoint,
+                 policy=jax.checkpoint_policies.save_only_these_names(
+                     HEAD_LSE))
+        def head_nll(u, norm, table, targets):
+            with jax.named_scope("head_loss"):
+                # the table's rows against the normed stream: its leaf
+                # as it lies, contracted over the stream's width
+                z = jnp.einsum("bld,vd->blv", rms_norm(u, norm, eps),
+                               table) / self.logits_scale
+                return row_lse(z) - jnp.take_along_axis(
+                    z, targets[..., None], axis=-1)[..., 0]
+
+        decays = []
+        with jax.named_scope("embed"):
+            table = self.param("embed", _INIT, (self.vocab, d))
+            x = table[tokens] * self.embed_scale
+        for mixer in self.layer_types:
+            x, decay = GraniteBlock(mixer=mixer, **sizes)(x)
+            decays += [] if decay is None else [decay]
+        nll = head_nll(
+            x, self.param("final_norm", nn.initializers.ones, (d,)), table,
+            targets)
+        with jax.named_scope("head_loss"):
+            loss = jnp.mean(nll)
+            stats = {STREAM_RMS: jnp.sqrt(jnp.mean(jnp.square(
+                jax.lax.stop_gradient(x))))[None]}
+        if decays:
+            stats[SSM_DECAY_MEAN] = jnp.stack(decays)
         return loss, stats

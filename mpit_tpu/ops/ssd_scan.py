@@ -46,19 +46,27 @@ seeds give).  The sums are float32.
 number of lane tiles or a whole number of heads a tile, chunks of 128:
 the published configuration's) run as **three Mosaic kernels** in which
 a chunk's matrices are made, used and dropped in VMEM.  The grid is
-``(batch, groups, chunks)``, the last axis walked in order; a grid step
-is one chunk of one group: ``x`` as the block ``(128, per P)`` of the
-row-major ``(B, L, H P)`` view, ``B`` and ``C`` as ``(128, N)`` blocks of
-``(B, L, G N)``, the step and the summed log-decays a head as columns
-(and the sums as rows too: a decay matrix is a column minus a row).
-``C B^T`` is made once a group, a head's decay matrix from the masked
-difference of sums, their product applied to ``dt x``; the read-out of
-the state and the chunk's contribution are one wide product each for the
-group's heads, against the group's states side by side and transposed
-(``N x per P``, float32): **a VMEM scratch carried along the chunk
-axis**, zeroed at a row's first chunk, ``S <- exp(cum_Q) S + added``
-elementwise in float32, which is the recurrence itself a chunk at a
-time: no product over the chunks and no decay matrix over them.  Every
+``(batch, head blocks, chunks)``, the last axis walked in order; a grid
+step is one chunk of one **head block**: the whole of a group of up to
+:data:`HEAD_BLOCK` heads (Nemotron's 8 groups of 8), or ``per`` heads
+of a wider one (Granite's one group of 64 is eight blocks of 8: a
+step's decay matrices, masked products and state are a block's, not
+the group's, which would not fit VMEM), the blocks of a group one
+after another on the grid's second axis.  ``x`` comes as the block
+``(128, per P)`` of the row-major ``(B, L, H P)`` view, ``B`` and ``C``
+as ``(128, N)`` blocks of ``(B, L, G N)`` **indexed by the block's
+group**, the step and the summed log-decays a head as columns (and the
+sums as rows too: a decay matrix is a column minus a row).  ``C B^T``
+is made once a step (once a group where the group is one block; again
+a head block where it is not, ``Q^2 N`` of a block's ``per (Q^2 P + 4 Q
+P N)``), a head's decay matrix from the masked difference of sums,
+their product applied to ``dt x``; the read-out of the state and the
+chunk's contribution are one wide product each for the block's heads,
+against the block's states side by side and transposed (``N x per P``,
+float32): **a VMEM scratch carried along the chunk axis**, zeroed at a
+row's first chunk, ``S <- exp(cum_Q) S + added`` elementwise in
+float32, which is the recurrence itself a chunk at a time: no product
+over the chunks and no decay matrix over them.  Every
 other shape (a narrow head, a narrow state, another chunk size) runs
 :func:`ssd_chunked`, the same algorithm as XLA's products and fusions,
 which carries the state by :data:`CARRY_PRECISION`'s product: the
@@ -105,8 +113,11 @@ over a group's heads where a head's term meets ``B`` or ``C``)::
     d cum_Q = the same + sum_s e_s sum_p (B dS)_s * X_s
               + exp(cum_Q) sum (S_0 * dS)
 
-``B`` and ``C`` of a group are whole in one grid step, so nothing is
-summed across steps but the state's cotangent.  What is one number a
+Where a group is one head block, ``B`` and ``C`` of a group are whole
+in one grid step and nothing is summed across steps but the state's
+cotangent; where it is several, each block writes its heads' part of
+``dB`` and ``dC`` (``dG`` summed over the block's heads) and the wrapper
+adds the parts of a group.  What is one number a
 head and position stays XLA's, in the wrapper: ``cum`` is the cumulative
 sum of ``dt A`` inside a chunk in :data:`SUM_DTYPE`, and its transpose
 (the reverse sum of ``d cum`` inside a chunk to ``d (dt A)``, then ``d
@@ -124,8 +135,9 @@ forward kernel adds it where ``x`` and ``y`` are both in VMEM).  Any
 ``L``: a last chunk that is not whole is filled with positions that
 neither decay nor write (``dt = 0``).  No state crosses the batch axis,
 and none is reset inside a row.
-``chipbench/arithmetic/nemotron.py`` ``ssd_scan_cost`` counts
-what the chunked algorithm needs and ``ssd_scan_roofline`` holds the
+``chipbench/arithmetic/nemotron.py`` and ``granite.py``
+``ssd_scan_cost`` count what the chunked algorithm needs (``C B^T`` once
+a group and chunk, however many head blocks make it) and ``ssd_scan_roofline`` holds the
 scope's device time to it, whichever form runs under the scope.
 """
 
@@ -150,6 +162,13 @@ CHUNK = 128
 #: the products that carry a state from chunk to chunk: exact, and a
 #: five-hundredth of the operator's work
 CARRY_PRECISION = jax.lax.Precision.HIGHEST
+#: Heads of a group in one grid step of the kernels, where a group has
+#: more (:func:`heads_a_step`): Nemotron's group, whose bodies are known
+#: to fit scoped VMEM (64 would hold 4 MB of decay matrices and as much
+#: again of masked products at once).  Read at every call of
+#: :func:`ssd_scan` and handed on as a static argument, as
+#: :data:`SUM_DTYPE` is.
+HEAD_BLOCK = 8
 #: what the log-decays are summed in.  Read at every call of
 #: :func:`ssd_scan` and handed on as a static argument: the probe of the
 #: reference's tolerances lowers it for one build
@@ -240,16 +259,18 @@ def ssd_chunked(x: jnp.ndarray, dt: jnp.ndarray, a: jnp.ndarray,
 
 # -- the chunk as Mosaic kernels (widths of whole lanes) ------------------------
 #
-# One grid step is one chunk of one group: x as the block ``(CHUNK, per x
-# P)`` of the row-major ``(B, L, H P)`` view, B and C as ``(CHUNK, N)``
-# blocks of ``(B, L, G N)``, where the convolution's slices leave them (no
-# transpose into chunks and none back).  What is one number a head and
-# position (the step, the summed log-decays) comes a group at a time, as
-# columns ``(CHUNK, per)`` and, the sums, as rows ``(per, CHUNK)`` too: a
-# decay matrix is a column minus a row.  The group's states, transposed
-# and side by side (``N x per P``: a head's decay scales its lanes, and
-# the read-out and the contribution are one wide product each for the
-# group), are a VMEM scratch carried along the grid's last axis.  A lane
+# One grid step is one chunk of one head block (``per`` heads of one
+# group: the whole group where it has no more than HEAD_BLOCK): x as the
+# block ``(CHUNK, per x P)`` of the row-major ``(B, L, H P)`` view, B and
+# C as ``(CHUNK, N)`` blocks of ``(B, L, G N)``, where the convolution's
+# slices leave them (no transpose into chunks and none back).  What is
+# one number a head and position (the step, the summed log-decays) comes
+# a block at a time, as columns ``(CHUNK, per)`` and, the sums, as rows
+# ``(per, CHUNK)`` too: a decay matrix is a column minus a row.  The
+# block's states, transposed and side by side (``N x per P``: a head's
+# decay scales its lanes, and the read-out and the contribution are one
+# wide product each for the block), are a VMEM scratch carried along the
+# grid's last axis.  A lane
 # tile of 128 holds ``128 / P`` heads where a head is narrower: a product
 # a head is taken against the whole tile and its lanes kept, so no value
 # is cut or joined inside a tile.  The heads of a step are written stage
@@ -260,8 +281,8 @@ def ssd_chunked(x: jnp.ndarray, dt: jnp.ndarray, a: jnp.ndarray,
 
 
 class _Group:
-    """The arithmetic of one chunk and group on values, shared by the
-    three kernels.  ``one_pass``: the products take their operands in
+    """The arithmetic of one chunk and head block (``per`` heads of one
+    group) on values, shared by the three kernels.  ``one_pass``: the products take their operands in
     bf16 (one MXU pass, float32 sums: what the backend's default
     precision is to the XLA form, rounded where it rounds); everything
     else is float32 either way."""
@@ -451,22 +472,29 @@ class _Calls:
     walk over the chunks (``back``: from the last to the first), and the
     views the kernels read."""
 
-    def __init__(self, x, b, interpret, back=False):
+    def __init__(self, x, b, interpret, per, back=False):
         self.b, self.length, self.h, self.p = x.shape
-        self.g, self.n = b.shape[2:]
-        self.per, count = self.h // self.g, self.length // CHUNK
+        groups, self.n = b.shape[2:]
+        # ``g`` head blocks of ``per`` heads, ``self.blocks`` a group
+        self.per, count = per, self.length // CHUNK
+        self.g, self.blocks = self.h // per, self.h // groups // per
         self.count = count
         at = (lambda c: count - 1 - c) if back else (lambda c: c)
+        blocks = self.blocks
+        group_of = (lambda j: j) if blocks == 1 else (lambda j: j // blocks)
         self.static = dict(per=self.per, p=self.p, one_pass=not interpret)
         self.call = dict(
             grid=(self.b, self.g, count), interpret=interpret,
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")))
-        # a chunk of a group: its heads' x, its B or C, row-major
+        # a chunk of a head block: its heads' x, its group's B or C,
+        # row-major; a block's part of dB or dC, the blocks side by side
         self.wide = pl.BlockSpec((1, CHUNK, self.per * self.p),
                                  lambda i, j, c: (i, at(c), j))
         self.shared = pl.BlockSpec((1, CHUNK, self.n),
-                                   lambda i, j, c: (i, at(c), j))
+                                   lambda i, j, c: (i, at(c), group_of(j)))
+        self.parts = pl.BlockSpec((1, CHUNK, self.n),
+                                  lambda i, j, c: (i, at(c), j))
         # a number a head and position: positions by heads, heads by
         # positions
         self.columns = pl.BlockSpec((1, 1, CHUNK, self.per),
@@ -475,20 +503,28 @@ class _Calls:
                                  lambda i, j, c: (i, j, 0, at(c)))
         self.starts = pl.BlockSpec((1, 1, 1, self.n, self.per * self.p),
                                    lambda i, j, c: (i, j, at(c), 0, 0))
-        # a number a head: the skip a group, its gradient a row and group
+        # a number a head: the skip a block, its gradient a row and block
         self.skip = pl.BlockSpec((1, 1, self.per), lambda i, j, c: (j, 0, 0))
         self.dskip = pl.BlockSpec((1, 1, 1, self.per),
                                   lambda i, j, c: (i, j, 0, 0))
         self.scratch = [pltpu.VMEM((self.n, self.per * self.p), jnp.float32)]
 
     def by_group(self, x):
-        """``(B, L, H)`` as ``(B, G, L, per)``."""
+        """``(B, L, H)`` as ``(B, head blocks, L, per)``."""
         return x.reshape(self.b, self.length, self.g, self.per
                          ).transpose(0, 2, 1, 3)
 
     def by_position(self, x):
-        """``(B, G, L, per)`` as ``(B, L, H)``."""
+        """``(B, head blocks, L, per)`` as ``(B, L, H)``."""
         return x.transpose(0, 2, 1, 3).reshape(self.b, self.length, self.h)
+
+    def of_group(self, parts, like):
+        """A group's ``dB`` or ``dC`` from its head blocks' parts ``(B, L,
+        head blocks x N)``, in the shape of ``like (B, L, G, N)``."""
+        if self.blocks == 1:
+            return parts.reshape(like.shape)
+        return jnp.sum(parts.reshape(like.shape[:3] + (self.blocks, self.n)),
+                       axis=3)
 
     def f32(self, *shape):
         return jax.ShapeDtypeStruct((self.b,) + shape, jnp.float32)
@@ -498,11 +534,12 @@ class _Calls:
 # trace and one lowering of each kernel, not one a layer and program.
 
 
-@partial(jax.jit, static_argnames="interpret")
-def _kernel_forward(x, dt, cum, b, c, skip, interpret):
+@partial(jax.jit, static_argnames=("interpret", "per"))
+def _kernel_forward(x, dt, cum, b, c, skip, interpret, per):
     """``y + skip x (B, L, H P)``; ``L`` whole chunks, ``cum (B, L, H)``
-    the log-decays summed from each chunk's start, ``skip (H,)``."""
-    k = _Calls(x, b, interpret)
+    the log-decays summed from each chunk's start, ``skip (H,)``, ``per``
+    heads a grid step."""
+    k = _Calls(x, b, interpret, per)
     cum = k.by_group(cum)
     return pl.pallas_call(
         partial(_forward_kernel, **k.static),
@@ -514,11 +551,11 @@ def _kernel_forward(x, dt, cum, b, c, skip, interpret):
       _flat(c), skip.reshape(k.g, 1, k.per))
 
 
-@partial(jax.jit, static_argnames="interpret")
-def _kernel_states(x, dt, cum, b, interpret):
-    """The state every chunk starts from, transposed and a group's side
-    by side: ``(B, G, L / CHUNK, N, per P)``."""
-    k = _Calls(x, b, interpret)
+@partial(jax.jit, static_argnames=("interpret", "per"))
+def _kernel_states(x, dt, cum, b, interpret, per):
+    """The state every chunk starts from, transposed and a head block's
+    side by side: ``(B, head blocks, L / CHUNK, N, per P)``."""
+    k = _Calls(x, b, interpret, per)
     return pl.pallas_call(
         partial(_states_kernel, **k.static),
         in_specs=[k.wide, k.columns, k.columns, k.shared],
@@ -528,19 +565,20 @@ def _kernel_states(x, dt, cum, b, interpret):
     )(_flat(x), k.by_group(dt), k.by_group(cum), _flat(b))
 
 
-@partial(jax.jit, static_argnames="interpret")
-def _kernel_backward(x, dt, cum, b, c, skip, starts, dy, interpret):
+@partial(jax.jit, static_argnames=("interpret", "per"))
+def _kernel_backward(x, dt, cum, b, c, skip, starts, dy, interpret, per):
     """``(dx, d dt, d cum, dB, dC, d skip)`` from ``dy (B, L, H P)``,
     the chunks walked from the last to the first with the state's
-    cotangent in VMEM."""
-    k = _Calls(x, b, interpret, back=True)
+    cotangent in VMEM; ``dB`` and ``dC`` a head block's part a step,
+    added a group."""
+    k = _Calls(x, b, interpret, per, back=True)
     cum = k.by_group(cum)
     columns, rows = k.f32(k.g, k.length, k.per), k.f32(k.g, k.per, k.length)
     dx, ddt, dcum, dcum_rows, db, dc, dskip = pl.pallas_call(
         partial(_backward_kernel, **k.static),
         in_specs=[k.wide, k.columns, k.columns, k.rows, k.shared, k.shared,
                   k.skip, k.wide, k.starts],
-        out_specs=[k.wide, k.columns, k.columns, k.rows, k.shared, k.shared,
+        out_specs=[k.wide, k.columns, k.columns, k.rows, k.parts, k.parts,
                    k.dskip],
         out_shape=[k.f32(k.length, k.h * k.p), columns, columns, rows,
                    k.f32(k.length, k.g * k.n), k.f32(k.length, k.g * k.n),
@@ -550,7 +588,7 @@ def _kernel_backward(x, dt, cum, b, c, skip, starts, dy, interpret):
       _flat(c), skip.reshape(k.g, 1, k.per), dy, starts)
     return (dx.reshape(x.shape), k.by_position(ddt),
             k.by_position(dcum + dcum_rows.transpose(0, 1, 3, 2)),
-            db.reshape(b.shape), dc.reshape(c.shape),
+            k.of_group(db, b), k.of_group(dc, c),
             jnp.sum(dskip, axis=0).reshape(k.h))
 
 
@@ -582,25 +620,25 @@ def _no_skip(x):
     return jnp.zeros((x.shape[2],), jnp.float32)
 
 
-def _kernels_forward(x, dt, a, b, c, skip, sum_dtype):
+def _kernels_forward(x, dt, a, b, c, skip, sum_dtype, per):
     length = x.shape[1]
     x, dt, b, c = map(_whole_chunks, (x, dt, b, c))
     return _kernel_forward(x, dt, _sums(dt, a, sum_dtype), b, c,
                            _no_skip(x) if skip is None else skip,
-                           use_interpret(None))[:, :length]
+                           use_interpret(None), per)[:, :length]
 
 
-def _kernels_backward(x, dt, a, b, c, skip, sum_dtype, g):
+def _kernels_backward(x, dt, a, b, c, skip, sum_dtype, per, g):
     length = x.shape[1]
     x, dt, b, c, g = map(_whole_chunks, (x, dt, b, c, g))
     # the sums and their transposes (the reverse sum inside a chunk, the
     # rates' as a sum) are XLA's: a number a head and position
     cum, sums_back = jax.vjp(partial(_sums, sum_dtype=sum_dtype), dt, a)
     interpret = use_interpret(None)
-    starts = _kernel_states(x, dt, cum, b, interpret)
+    starts = _kernel_states(x, dt, cum, b, interpret, per)
     dx, ddt, dcum, db, dc, dskip = _kernel_backward(
         x, dt, cum, b, c, _no_skip(x) if skip is None else skip, starts, g,
-        interpret)
+        interpret, per)
     through_sums, da = sums_back(dcum)
     return (dx[:, :length], (ddt + through_sums)[:, :length], da,
             db[:, :length], dc[:, :length], None if skip is None else dskip)
@@ -616,32 +654,46 @@ def takes_kernels(x, b, chunk) -> bool:
             and (p % LANE == 0 or LANE % p == 0))
 
 
+def heads_a_step(x, b, most: int) -> int:
+    """Heads of a group in one grid step of the kernels: the whole group
+    where it has no more than ``most``, else the largest part of it up
+    to ``most`` that divides it in whole lane tiles (the whole group
+    where no part does)."""
+    (heads, p), groups = x.shape[2:], b.shape[2]
+    per = heads // groups
+    return next((k for k in range(min(per, most), 0, -1)
+                 if per % k == 0 and k * p % LANE == 0), per)
+
+
 def _xla_form(x, dt, a, b, c, skip, chunk, sum_dtype):
     y = ssd_chunked(x, dt, a, b, c, chunk, sum_dtype)
     return _flat(y if skip is None else y + skip[:, None] * x)
 
 
-def _forward(x, dt, a, b, c, skip, chunk, sum_dtype):
+def _forward(x, dt, a, b, c, skip, chunk, sum_dtype, most):
     """The result as its row-major ``(B, L, H P)`` view: what the
     kernels write, and what a block's checkpoint keeps by name (a head
     narrower than a lane tile would be kept in a layout of its own, a
-    relayout each way)."""
+    relayout each way).  ``most``: :data:`HEAD_BLOCK` as the call read
+    it."""
     if takes_kernels(x, b, chunk):
-        return _kernels_forward(x, dt, a, b, c, skip, sum_dtype)
+        return _kernels_forward(x, dt, a, b, c, skip, sum_dtype,
+                                heads_a_step(x, b, most))
     return _xla_form(x, dt, a, b, c, skip, chunk, sum_dtype)
 
 
-_ssd_scan = jax.custom_vjp(_forward, nondiff_argnums=(6, 7))
+_ssd_scan = jax.custom_vjp(_forward, nondiff_argnums=(6, 7, 8))
 
 
-def _ssd_scan_fwd(x, dt, a, b, c, skip, chunk, sum_dtype):
-    return (_forward(x, dt, a, b, c, skip, chunk, sum_dtype),
+def _ssd_scan_fwd(x, dt, a, b, c, skip, chunk, sum_dtype, most):
+    return (_forward(x, dt, a, b, c, skip, chunk, sum_dtype, most),
             (x, dt, a, b, c, skip))
 
 
-def _ssd_scan_bwd(chunk, sum_dtype, kept, g):
+def _ssd_scan_bwd(chunk, sum_dtype, most, kept, g):
     if takes_kernels(kept[0], kept[3], chunk):
-        return _kernels_backward(*kept, sum_dtype, g)
+        return _kernels_backward(*kept, sum_dtype,
+                                 heads_a_step(kept[0], kept[3], most), g)
     # the chunks and the chunk-start states again, and their transposes
     _, back = jax.vjp(partial(_xla_form, chunk=chunk, sum_dtype=sum_dtype),
                       *kept)
@@ -659,7 +711,8 @@ def ssd_scan(x: jnp.ndarray, dt: jnp.ndarray, a: jnp.ndarray,
     given (Mamba-2's ``D``: the kernels add it where ``x`` and ``y`` are
     both in VMEM); the module's docstring has the shapes, the algorithm,
     the two forms and the rule.  Which form runs is read off the shapes
-    (:func:`takes_kernels`)."""
+    (:func:`takes_kernels`), and so is how many heads of a group a grid
+    step of the kernels holds (:func:`heads_a_step`)."""
     heads, groups = x.shape[2], b.shape[2]
     if heads % groups or b.shape != c.shape or dt.shape != x.shape[:3] or (
             skip is not None and skip.shape != (heads,)):
@@ -667,5 +720,6 @@ def ssd_scan(x: jnp.ndarray, dt: jnp.ndarray, a: jnp.ndarray,
                          f"{b.shape}, c {c.shape}"
                          + ("" if skip is None else f", skip {skip.shape}"))
     return checkpoint_name(
-        _ssd_scan(x, dt, a, b, c, skip, int(chunk), SUM_DTYPE), SSD_OUT
+        _ssd_scan(x, dt, a, b, c, skip, int(chunk), SUM_DTYPE, HEAD_BLOCK),
+        SSD_OUT
     ).reshape(x.shape)

@@ -43,9 +43,11 @@ OTHER = dict(
     ssm_heads=6, ssm_head_dim=4, ssm_groups=3, ssm_state=8, ssm_chunk=16,
     shared_width=24, init_depth=7,
     gdn_key_heads=3, gdn_value_heads=6, gdn_key_dim=8, gdn_value_dim=4,
-    rotary_factor=0.5)
+    rotary_factor=0.5,
+    residual_scale=0.5, attn_scale=0.25, logits_scale=2.0)
 # where a block cannot take ``OTHER``'s value of a size: its own
-OTHER_OF = {"kimi": {"layer_types": "kda,full_attention,kda"},
+OTHER_OF = {"granite": {"layer_types": "mamba,attention,mamba"},
+            "kimi": {"layer_types": "kda,full_attention,kda"},
             "nemotron": {"layer_types": "mamba,attention,moe"},
             "qwen3next": {"layer_types": "linear_attention,full_attention,"
                                          "linear_attention"},
@@ -130,6 +132,7 @@ def test_a_configuration_names_only_its_own_blocks_switches(path):
     ("gpt2", "n_experts"), ("olmoe", "kv_heads"), ("lfm2", "window"),
     ("ouro", "n_experts"), ("joyai", "kv_heads"), ("mellum", "nonsense"),
     ("kimi", "mtp_layers"), ("qwen3next", "kda_heads"),
+    ("granite", "n_experts"),
 ])
 def test_build_refuses_a_size_the_block_does_not_take(arch, stray):
     with pytest.raises(TypeError) as refused:

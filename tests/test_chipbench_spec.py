@@ -57,7 +57,8 @@ def test_every_cells_vocabulary_reaches_the_program_through_the_launcher():
     want = {"gpt2": 50257, "olmoe": 50304, "mellum": 12288, "lfm2_moe": 8192,
             "ouro": 49152, "joyai_llm_flash": 16160, "kimi_linear": 20480,
             "KeyeVL2": 18992, "sdar_moe": 18992, "afmoe": 25024,
-            "nemotron_h": 16384, "qwen3_next": 18992}
+            "nemotron_h": 16384, "qwen3_next": 18992,
+            "granitemoehybrid": 12544}
     for name in CELLS:
         cell = spec_mod.load_cell(name)
         cfg = runner.launch_config(cell, seed=5)
@@ -307,6 +308,7 @@ def test_mellums_readers_read_a_hand_made_run(monkeypatch):
 
 LFM2_CELL = "lfm2-l5e8-local"
 NEMOTRON_CELL = "nemotron3-l9e8-local"
+GRANITE_CELL = "granite4h-l10-local"
 QWEN3NEXT_CELL = "qwen3next-l4e32-local"
 
 
@@ -482,15 +484,15 @@ def test_the_parent_fails_the_new_cell_at_once():
     cell on the parent needs."""
     bench = spec_mod.load_bench()
     names = [w["name"] for w in bench["workloads"]]
-    assert names[-9:] == [LFM2_CELL, OURO_CELL, JOYAI_CELL, KIMI_CELL,
-                          KEYE_CELL, SDAR_CELL, TRINITY_CELL,
-                          NEMOTRON_CELL, QWEN3NEXT_CELL] \
-        and len(names) == 14
+    assert names[-10:] == [LFM2_CELL, OURO_CELL, JOYAI_CELL, KIMI_CELL,
+                           KEYE_CELL, SDAR_CELL, TRINITY_CELL,
+                           NEMOTRON_CELL, QWEN3NEXT_CELL, GRANITE_CELL] \
+        and len(names) == 15
     for missing in ("lfm2-l5e8-locals", "ouro-l6-locals",
                     "joyai-l5e8-locals", "kimi-linear-l5e8-locals",
                     "keye-l6e8-locals", "sdar-l6e8-locals",
                     "trinity-l5e8-locals", "nemotron3-l9e8-locals",
-                    "qwen3next-l4e32-locals"):
+                    "qwen3next-l4e32-locals", "granite4h-l10-locals"):
         with pytest.raises(spec_mod.SpecError, match="no workload"):
             spec_mod.load_cell(missing)
 
@@ -1255,8 +1257,8 @@ def test_keyes_mix_keeps_to_the_traffic_its_issue_fixed():
     at = cell.bench["per_layer"].index(keye[0])
     assert cell.bench["per_layer"][at:at + 5] == keye
     # (PR 51's and PR 53's configurations and cells follow them)
-    assert (cell.bench["configs"][-5]["name"],
-            cell.bench["workloads"][-5]["name"]) == (cell.config_name,
+    assert (cell.bench["configs"][-6]["name"],
+            cell.bench["workloads"][-6]["name"]) == (cell.config_name,
                                                      KEYE_CELL)
 
 
@@ -1466,10 +1468,10 @@ def test_sdars_mix_keeps_to_the_traffic_its_issue_fixed():
             assert SDAR_CELL not in metric["workloads"], metric["name"]
     # added together and in order (PR 53's four follow them, and its
     # configuration and cell)
-    assert [m["name"] for m in cell.bench["per_layer"][-19:-15]] == list(
+    assert [m["name"] for m in cell.bench["per_layer"][-22:-18]] == list(
         SDAR_METRICS)
-    assert (cell.bench["configs"][-4]["name"],
-            cell.bench["workloads"][-4]["name"]) == (cell.config_name,
+    assert (cell.bench["configs"][-5]["name"],
+            cell.bench["workloads"][-5]["name"]) == (cell.config_name,
                                                      SDAR_CELL)
 
 
@@ -1702,10 +1704,10 @@ def test_trinitys_mix_keeps_to_the_traffic_its_issue_fixed():
         elif "workloads" in metric:
             assert TRINITY_CELL not in metric["workloads"], metric["name"]
     # added together, in order and last
-    assert [m["name"] for m in cell.bench["per_layer"][-15:-11]] == list(
+    assert [m["name"] for m in cell.bench["per_layer"][-18:-14]] == list(
         TRINITY_METRICS)
-    assert (cell.bench["configs"][-3]["name"],
-            cell.bench["workloads"][-3]["name"]) == (cell.config_name,
+    assert (cell.bench["configs"][-4]["name"],
+            cell.bench["workloads"][-4]["name"]) == (cell.config_name,
                                                      TRINITY_CELL)
     assert cell.chips == 1 and len(cell.why) <= 200
 
@@ -1955,17 +1957,18 @@ def test_nemotrons_mix_keeps_to_the_traffic_its_issue_fixed():
     assert units["ssm_decay_mean"] == ("share", "lower")
     for metric in cell.bench["per_layer"]:
         if metric["name"] in NEMOTRON_METRICS:
-            assert metric["workloads"] == [NEMOTRON_CELL]
+            # PR 65's cell, the second with state-space layers, follows
+            assert metric["workloads"] == [NEMOTRON_CELL, GRANITE_CELL]
         elif metric["name"] in NEMOTRON_APPENDED:
             assert NEMOTRON_CELL in metric["workloads"][-2:]
         elif "workloads" in metric:
             assert NEMOTRON_CELL not in metric["workloads"], metric["name"]
-    # added together and in order (PR 61's six follow them, and its
-    # configuration and cell)
-    assert [m["name"] for m in cell.bench["per_layer"][-11:-6]] == list(
+    # added together and in order (PR 61's six and PR 65's three
+    # follow them, and their configurations and cells)
+    assert [m["name"] for m in cell.bench["per_layer"][-14:-9]] == list(
         NEMOTRON_METRICS)
-    assert (cell.bench["configs"][-2]["name"],
-            cell.bench["workloads"][-2]["name"]) == (cell.config_name,
+    assert (cell.bench["configs"][-3]["name"],
+            cell.bench["workloads"][-3]["name"]) == (cell.config_name,
                                                      NEMOTRON_CELL)
     assert cell.chips == 1 and len(cell.why) <= 200
 
@@ -2212,15 +2215,16 @@ def test_qwen3nexts_mix_keeps_to_the_traffic_its_issue_fixed():
             assert metric["workloads"][-1] == QWEN3NEXT_CELL
         elif "workloads" in metric:
             assert QWEN3NEXT_CELL not in metric["workloads"], metric["name"]
-    # added together, in order and last
-    assert [m["name"] for m in cell.bench["per_layer"][-6:]] == list(
+    # added together and in order (PR 65's three follow them, and its
+    # configuration and cell)
+    assert [m["name"] for m in cell.bench["per_layer"][-9:-3]] == list(
         QWEN3NEXT_METRICS)
-    assert (cell.bench["configs"][-1]["name"],
-            cell.bench["workloads"][-1]["name"]) == (cell.config_name,
+    assert (cell.bench["configs"][-2]["name"],
+            cell.bench["workloads"][-2]["name"]) == (cell.config_name,
                                                      QWEN3NEXT_CELL)
     assert cell.chips == 1 and len(cell.why) <= 200
-    assert len(cell.bench["configs"]) == 13 and len(
-        cell.bench["workloads"]) == 14
+    assert len(cell.bench["configs"]) == 14 and len(
+        cell.bench["workloads"]) == 15
 
 
 def test_qwen3nexts_readers_find_nothing_in_a_run_without_the_block():
@@ -2339,6 +2343,259 @@ def test_qwen3next_arithmetic_by_hand_through_the_cell(what, got, want):
     assert got == want, what
 
 
+# -- the Granite-4.0-H-Micro configuration (PR 65) ---------------------------------
+
+GRANITE_METRICS = ("mlp_ms_per_step", "ssm_share_pct", "stream_rms_final")
+GRANITE_APPENDED = NEMOTRON_METRICS
+
+
+def test_granite_file_has_the_catalogs_keys_and_the_floor_cuts():
+    """Every key of the catalog's row under its own name and at its
+    published value but the three cut: the depth, the layers' types cut
+    to it and the vocabulary, with the published values beside them; no
+    width is cut; what the row does not give is stated as assumed."""
+    import pathlib
+
+    cell = spec_mod.load_cell(GRANITE_CELL)
+    config = cell.config
+    path = pathlib.Path(
+        "/opt/skills/guides/model-configs/architectures.jsonl")
+    if path.exists():
+        rows = [json.loads(line) for line in path.read_text().splitlines()]
+        entry = next(r for r in rows if r["name"] == "granite-4.0-h-micro")
+        catalog = entry["config"]
+        assert entry["source_url"] == config["source"]
+        assert all(key in config for key in catalog)
+        differ = sorted(k for k, v in catalog.items() if config[k] != v)
+        assert differ == sorted(config["reduced"])
+        assert config["published"] == {k: catalog[k]
+                                       for k in config["reduced"]}
+        assert catalog["layer_types"][:10] == config["layer_types"]
+    assert config["reduced"] == ["num_hidden_layers", "layer_types",
+                                 "vocab_size"]
+    # the floors: one whole period of ten, an eighth of the rows
+    assert (config["num_hidden_layers"], config["layer_types"],
+            config["vocab_size"]) == (
+                10, ["mamba"] * 5 + ["attention"] + ["mamba"] * 4,
+                100352 // 8)
+    assert (config["hidden_size"], config["num_attention_heads"],
+            config["num_key_value_heads"], config["mamba_n_heads"],
+            config["mamba_d_head"], config["mamba_n_groups"],
+            config["mamba_d_state"], config["mamba_d_conv"],
+            config["intermediate_size"], config["shared_intermediate_size"],
+            config["embedding_multiplier"], config["residual_multiplier"],
+            config["attention_multiplier"], config["logits_scaling"],
+            config["tie_word_embeddings"], config["rms_norm_eps"],
+            config["mamba_chunk_size"], config["num_local_experts"]) == (
+                2048, 32, 8, 64, 64, 1, 128, 4, 8192, 8192, 12, 0.22,
+                0.015625, 8, True, 1e-5, 256, 0)
+    assert (config["train_seq"], config["scan_chunk"],
+            config["layer_types_here"]) == (
+                4096, 128, ",".join(config["layer_types"]))
+    entry = next(c for c in cell.bench["configs"]
+                 if c["name"] == cell.config_name)
+    assert entry["reduced"] == config["reduced"]
+    assert entry["source"] == config["source"]
+    assert (cell.chips, cell.traffic_name) == (1, "local-msgd-s4k-granite4h")
+    assert ["embed", "ssm_proj", "ssm_conv", "ssd_scan", "ssm_norm", "attn",
+            "mlp", "head_loss", "update"] == config["scopes"]
+    assert (config["reference"], config["arithmetic"]) == (
+        "granite_plain", "granite")
+    assert all(key in config for key in config["launcher_from"].values())
+    assert all(key in config for key in config["tiny"])
+    assert len(config["assumed"]) >= 8
+    assert "share 0 of stage 0" in config["deployment"] and \
+        "Four stages of ten layers" in config["deployment"]
+    assert set(config["limits"]) == {"LOSS_TOL_NATS", "GRAD_REL_TOL"}
+    worked = [got for _what, got, want in cell.arithmetic().hand_worked()
+              if got == want]
+    # a mamba layer, an attention layer, the vector
+    assert all(count in worked for count in (
+        76_182_976, 60_821_504, 772_160_448))
+    assert cell.arithmetic().param_count(config) == 772_160_448
+    assert cell.reference().LOSS_TOL_NATS > 0 < cell.reference().GRAD_REL_TOL
+    # the tiny size: one group, and every multiplier off 1
+    small = config["tiny"]
+    assert small["mamba_n_groups"] == 1 and small["layer_types"] == [
+        "mamba", "mamba", "attention", "mamba"]
+    assert all(small[key] != 1 for key in (
+        "embedding_multiplier", "residual_multiplier",
+        "attention_multiplier", "logits_scaling"))
+
+
+def test_the_launcher_builds_the_dense_hybrid_from_the_cells_files():
+    from chipbench import run as runner
+    from mpit_tpu.lm.model import build_kw
+    from mpit_tpu.train.launch import lm_trainer_cfg
+
+    cell = spec_mod.load_cell(GRANITE_CELL)
+    kw = build_kw(lm_trainer_cfg(runner.launch_config(cell, seed=5)))
+    assert (kw["arch"], kw["d_model"], kw["n_heads"], kw["kv_heads"],
+            kw["head_dim"], kw["n_layers"], kw["seq_len"], kw["vocab"]) \
+        == ("granite", 2048, 32, 8, 0, 10, 4096, 12544)
+    assert kw["layer_types"] == cell.arithmetic().layer_types(cell.config)
+    assert kw["layer_types"].split(",").count("mamba") == 9
+    assert (kw["ssm_heads"], kw["ssm_head_dim"], kw["ssm_groups"],
+            kw["ssm_state"], kw["ssm_chunk"], kw["conv_kernel"],
+            kw["dense_width"]) == (64, 64, 1, 128, 128, 4, 8192)
+    assert (kw["embed_scale"], kw["residual_scale"], kw["attn_scale"],
+            kw["logits_scale"], kw["norm_eps"]) == (
+                12.0, 0.22, 0.015625, 8.0, 1e-5)
+
+
+def test_granites_mix_keeps_to_the_traffic_its_issue_fixed():
+    """ISSUE 65 fixed the mix before any code was written: the rate one
+    of four, the budget a whole number of micro-steps of 4096 tokens,
+    momentum 0.9, two rounds of warm-up, closed loop in one process; the
+    three new metrics list the cell alone, the five state-space ones
+    have it appended, and the budget's table is in the mix's own file."""
+    cell = spec_mod.load_cell(GRANITE_CELL)
+    mix = cell.traffic
+    steps, rest = divmod(mix["token_budget"],
+                         mix["batch"] * cell.config["train_seq"])
+    assert rest == 0 and steps >= 8
+    assert mix["lr"] in (0.003, 0.01, 0.03, 0.1)
+    assert (mix["launcher"]["mom"], mix["warmup_rounds"], mix["su"],
+            mix["batch"], mix["launcher"]["np"], mix["launcher"]["opt"],
+            mix["launcher"]["lm_use_flash"]) == (0.9, 2, 1, 1, 1, "msgd", 1)
+    assert "quartile distance" in mix["chosen_because"]
+    moves = {m["name"]: m["moves"] for m in cell.metrics("per_layer")}
+    assert set(GRANITE_METRICS + GRANITE_APPENDED) <= set(moves)
+    assert [moves[m] for m in GRANITE_METRICS] == [
+        "tokens_per_s", "tokens_per_s", "loss_at_budget"]
+    layers = {m["name"]: m["layer"] for m in cell.bench["per_layer"]}
+    assert {layers[m] for m in GRANITE_METRICS} == {layers["ssm_ms_per_step"]}
+    units = {m["name"]: (m["unit"], m["better"], m["source"])
+             for m in cell.bench["per_layer"]}
+    assert units["mlp_ms_per_step"] == ("ms", "lower", "device_trace")
+    assert units["ssm_share_pct"] == ("%", "higher", "device_trace")
+    assert units["stream_rms_final"] == ("rms", "lower", "program_counter")
+    for metric in cell.bench["per_layer"]:
+        if metric["name"] in GRANITE_METRICS:
+            assert metric["workloads"] == [GRANITE_CELL]
+        elif metric["name"] in GRANITE_APPENDED:
+            assert metric["workloads"][-1] == GRANITE_CELL
+        elif "workloads" in metric:
+            assert GRANITE_CELL not in metric["workloads"], metric["name"]
+    # added together, in order and last
+    assert [m["name"] for m in cell.bench["per_layer"][-3:]] == list(
+        GRANITE_METRICS)
+    assert (cell.bench["configs"][-1]["name"],
+            cell.bench["workloads"][-1]["name"]) == (cell.config_name,
+                                                     GRANITE_CELL)
+    assert cell.chips == 1 and len(cell.why) <= 200
+    assert "One stage of four" in cell.why and "1 layer of 10" in cell.why
+    assert len(cell.bench["configs"]) == 14 and len(
+        cell.bench["workloads"]) == 15 and len(
+            cell.bench["per_layer"]) == 90
+    # one cell in four may take four chips, and none does
+    assert sum(w["chips"] == 4 for w in cell.bench["workloads"]) == 0
+
+
+def test_granites_readers_find_nothing_in_a_run_without_the_block():
+    """What the parent's traced run hands them: a cell whose
+    configuration lists no such scope, a program that recorded no such
+    counter, no device trace, no merged trace: None, no raise."""
+    for name in ("kimi-linear-l5e8-local", "c111m-local"):
+        cell = spec_mod.load_cell(name)
+        run = {"cell": cell, "reduction": {"step_module": "jit__lambda"},
+               "obs_trace": None, "peaks": None, "results": {},
+               "summary": {"worker_ranks": [0], "window": [0.0, 1.0]}}
+        for metric in GRANITE_METRICS:
+            reader = spec_mod.load_reader(cell.root, cell.bench, metric)
+            assert reader is not None and reader(dict(run)) is None
+
+
+def test_granites_readers_read_a_hand_made_run(monkeypatch):
+    """The three readers, the five shared state-space ones and the
+    metrics without a ``workloads`` list that the cell has to report, on
+    a scope table and a span tree made by hand."""
+    from chipbench import flops
+    from chipbench.layers import spantree
+
+    cell = spec_mod.load_cell(GRANITE_CELL)
+    listed = [m["name"] for m in cell.metrics("per_layer")]
+    unlisted = [m["name"] for m in cell.bench["per_layer"]
+                if "workloads" not in m]
+    assert len(unlisted) == 10 and set(unlisted) <= set(listed)
+    assert len(listed) == 18
+
+    class Round:
+        def __init__(self, k, decay):
+            self.args = {"round": k, "lm_stream_rms": [0.5 + 0.1 * k],
+                         "lm_ssm_decay_mean": [decay + 0.01 * j
+                                               for j in range(-4, 5)]}
+
+    class Tree:
+        def rounds(self):
+            return [Round(k, 0.88 + 0.01 * k) for k in range(5)]
+
+    table = {"step": 250.0, "embed": 1.0, "ssm_proj": 60.0, "ssm_conv": 12.0,
+             "ssd_scan": 22.0, "ssm_norm": 6.0, "attn": 15.0, "mlp": 110.0,
+             "head_loss": 11.0, "update": 10.0, "unscoped": 3.0}
+    monkeypatch.setattr(spantree, "scope_ms_per_step", lambda run: table)
+    monkeypatch.setattr(spantree, "xplane_path", lambda run: None)
+    run = {"cell": cell, "peaks": flops.load_peaks("TPU v5 lite"),
+           spantree.CACHE_KEY: Tree(),
+           "summary": {"tokens_per_s": 12000.0, "worker_ranks": [0]},
+           "reduction": {"step_module": "jit__lambda", "step_module_runs": 2,
+                         "mosaic_by_scope": {
+                             "attn": (6, 0.024), "ssd_scan": (54, 0.040),
+                             "update": (2, 0.020)}}}
+
+    def read(name, run=run):
+        return spec_mod.load_reader(cell.root, cell.bench, name)(run)
+
+    assert read("mlp_ms_per_step") == pytest.approx(110.0)
+    assert read("ssm_ms_per_step") == pytest.approx(100.0)
+    assert read("ssm_share_pct") == pytest.approx(100 * 100.0 / 250.0)
+    assert read("stream_rms_final") == pytest.approx(0.7)
+    assert read("ssd_scan_ms_per_step") == pytest.approx(22.0)
+    assert read("ssm_conv_ms_per_step") == pytest.approx(12.0)
+    assert read("ssm_decay_mean") == pytest.approx(0.90)
+    scan = cell.arithmetic().ssd_scan_cost(cell.config, 1)
+    assert read("ssd_scan_roofline") == pytest.approx(
+        100 * max(scan["flops"] / 197e12, scan["bytes"] / 819e9) / 0.022)
+    # one group: C B^T once, so the yardstick is bound by memory
+    assert scan["bytes"] / 819e9 > scan["flops"] / 197e12
+    assert read("head_loss_ms_per_step") == pytest.approx(11.0)
+    assert read("flash_ms_per_step") == pytest.approx(12.0)
+    families = cell.arithmetic().kernels(cell.config, 1)
+    assert read("flash_roofline") == pytest.approx(
+        100 * families["attn"]["flops"] / 197e12 / 0.012)
+    assert read("mfu_pct") == pytest.approx(
+        100 * 4_752_863_232 * 12000.0 / 197e12)
+    for metric in cell.metrics("per_layer"):
+        assert spec_mod.load_reader(cell.root, cell.bench,
+                                    metric["name"]) is not None
+
+    # a block that records no stream rms, and a run with no mixer scope
+    class Bare:
+        def rounds(self):
+            rounds = Tree().rounds()
+            for r in rounds:
+                del r.args["lm_stream_rms"]
+            return rounds
+
+    assert read("stream_rms_final", {**run, spantree.CACHE_KEY: Bare()}) \
+        is None
+    monkeypatch.setattr(spantree, "scope_ms_per_step",
+                        lambda run: {"step": 130.0, "attn": 15.0,
+                                     "mlp": 110.0})
+    assert read("ssm_share_pct") is None
+    assert read("mlp_ms_per_step") == pytest.approx(110.0)
+
+
+def granite_cases():
+    return spec_mod.load_cell(GRANITE_CELL).arithmetic().hand_worked()
+
+
+@pytest.mark.parametrize("what,got,want", granite_cases(),
+                         ids=[c[0] for c in granite_cases()])
+def test_granite_arithmetic_by_hand_through_the_cell(what, got, want):
+    assert got == want, what
+
+
 def _one_line_fields():
     bench = json.loads((spec_mod.ROOT / "BENCHMARK.json").read_text())
     out = [("command", " ".join(bench["command"]))]
@@ -2431,9 +2688,9 @@ def test_pull_early_pct_is_entered_for_the_ps_cells_under_a_layer_of_perf_md():
     assert entry == {"name": "pull_early_pct", "unit": "%", "better": "higher",
                      "source": "program_span", "layer": "L3 shell + client",
                      "moves": "tokens_per_s", "workloads": PS_CELLS}
-    # appended, nothing moved; PR 51's four, PR 53's four, PR 58's five
-    # and PR 61's six follow it
-    assert bench["per_layer"][-20] is entry
+    # appended, nothing moved; PR 51's four, PR 53's four, PR 58's five,
+    # PR 61's six and PR 65's three follow it
+    assert bench["per_layer"][-23] is entry
     perf = (spec_mod.ROOT / "PERF.md").read_text()
     layers = perf[perf.index("## 3. Layers"):perf.index("## 4. Cells")]
     assert f"| {entry['layer']} |" in layers and "`pull_early_pct`" in layers

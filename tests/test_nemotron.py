@@ -297,6 +297,51 @@ def test_the_scan_kernels_keep_what_the_scan_promises(check, monkeypatch):
     check(monkeypatch)
 
 
+# sha256 of ``str(make_jaxpr(value_and_grad(loss)))`` (addresses blanked)
+# of ``ssd_scan`` at the cell's shape (64 heads of 64 in 8 groups, state
+# 128, one sequence, with the skip), **as the parent commit of PR 65
+# printed it**: that PR gave the kernels a part of a group a grid step
+# (``heads_a_step``: Granite's one group of 64 heads is eight blocks of
+# 8) and ``_Calls`` serves both; a group of 8 is one block, and the
+# program must still trace to what it was, to the character, as the chip
+# compiles it (``use_interpret`` steered off) and interpreted, on whole
+# chunks and with a ragged end.  A PR that changes these kernels on
+# purpose records the new digests here and says so.
+PARENTS_SSD = {
+    ("compiled", 8192): "f42e50765c893e33",
+    ("compiled", 8150): "92ff9bbb9bf353f0",
+    ("interpreted", 8192): "407f10bf161be671",
+    ("interpreted", 8150): "bc4866407e7088f6",
+}
+
+
+@pytest.mark.parametrize("how,length", sorted(PARENTS_SSD))
+def test_the_scan_at_eight_groups_traces_to_the_parents_program(
+        how, length, monkeypatch):
+    import hashlib
+    import re
+
+    monkeypatch.setattr(ssd_scan, "use_interpret",
+                        lambda flag: how == "interpreted")
+    jax.clear_caches()   # the calls are jits: no trace of another mode
+    wide = jax.ShapeDtypeStruct((1, length, 64, 64), jnp.float32)
+    step = jax.ShapeDtypeStruct((1, length, 64), jnp.float32)
+    head = jax.ShapeDtypeStruct((64,), jnp.float32)
+    shared = jax.ShapeDtypeStruct((1, length, 8, 128), jnp.float32)
+    assert ssd_scan.heads_a_step(wide, shared, ssd_scan.HEAD_BLOCK) == 8
+
+    def loss(x, dt, a, b, c, d):
+        return jnp.sum(ssd_scan.ssd_scan(x, dt, a, b, c, skip=d) ** 2)
+
+    text = re.sub(r"0x[0-9a-f]+", "0x", str(jax.make_jaxpr(
+        jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4, 5)))(
+            wide, step, head, shared, shared, head)))
+    assert text.count("pallas_call") >= 3
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == \
+        PARENTS_SSD[how, length]
+    jax.clear_caches()
+
+
 def test_mismatched_shapes_are_refused():
     x, dt, a, b, c = scan_inputs(16, 0.5, 0.9, heads=4, groups=2)
     with pytest.raises(ValueError):

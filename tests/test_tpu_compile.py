@@ -8,7 +8,9 @@ operands under both backward schedules, with no pad and no slice of an
 operand's size round the calls at head widths of 64 and 192 (PR 54), and a dense and a
 latent-attention block whose q, k, v and dO are rounded to bf16 by the
 fusions that make them, not by a pass of their own (PR 57); the delta rule's three kernels at Kimi's KDA
-shape (32 heads of 128 at 8192); and the msgd commit over LFM2's vector, whose length is
+shape (32 heads of 128 at 8192); the state-space scan's three at Nemotron's shape (8 groups of 8 heads)
+and at Granite's (ONE group of 64 heads, eight a grid step), and the whole donated step of Granite's cell
+under its stated size (PR 65); and the msgd commit over LFM2's vector, whose length is
 whole lanes and no whole number of blocks, and over Ouro's, which is no
 whole number of lanes, with ``w`` and ``vt`` donated.  What interpret mode cannot show: that the tiles fit the chip's fast
 memory and the kernels lower.  A compile that passes is not a chip run
@@ -455,6 +457,68 @@ def test_the_state_space_scan_compiles_at_the_published_shape(one_chip):
     assert not re.findall(r"f32\[[0-9,]*128,128\]", text)
     assert "f32[1,8,64,128,512]" in text       # the chunk-start states
     assert compiled.memory_analysis().temp_size_in_bytes < 1e9
+
+
+def test_the_state_space_scan_compiles_at_one_group_of_64_heads(one_chip):
+    """Granite's shape: 64 heads of 64 under ONE group's B and C of 128
+    at 4096 positions.  A grid step holds eight of the group's heads
+    (``heads_a_step``), so the three calls lower within scoped VMEM as
+    Nemotron's bodies do (the whole group a step would hold 4 MB of
+    decay matrices and as much of masked products at once), the
+    chunk-start states are eight blocks' side by side, and ``dB`` and
+    ``dC`` leave the walk back as eight parts a position."""
+    from mpit_tpu.ops import ssd_scan
+
+    def loss(x, dt, a, b, c, d):
+        return jnp.sum(ssd_scan.ssd_scan(x, dt, a, b, c, skip=d) ** 2)
+
+    def of(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    args = (of(1, 4096, 64, 64), of(1, 4096, 64), of(64,),
+            of(1, 4096, 1, 128), of(1, 4096, 1, 128), of(64,))
+    assert ssd_scan.takes_kernels(args[0], args[3], ssd_scan.CHUNK)
+    assert ssd_scan.heads_a_step(args[0], args[3], ssd_scan.HEAD_BLOCK) == 8
+    real = jax.default_backend
+    jax.default_backend = lambda: "tpu"    # ``use_interpret`` asks
+    try:
+        compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4, 5))).lower(
+            *args).compile()
+    finally:
+        jax.default_backend = real
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    assert not re.findall(r"f32\[[0-9,]*128,128\]", text)
+    assert "f32[1,8,32,128,512]" in text       # the chunk-start states
+    assert "f32[1,4096,1024]" in text          # dB, dC: eight parts of 128
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.6e9
+
+
+def test_the_granite_cells_step_is_under_the_stated_size(one_chip):
+    """``chipbench/rehearse_compile_granite.py``: the donated step of
+    ``granite4h-l10-local`` at its real shapes (772,160,448 elements,
+    4096 positions), no weight made here, compiled for the described
+    chip: with the seeded vector beside it at most the script's target,
+    and the scan's three bodies, the attention's pair and the commit
+    kernel among its Mosaic calls."""
+    import jax as _jax
+
+    from chipbench import rehearse_compile_granite as script
+
+    lowerings, vector_gb = script.programs()
+    name, beside, lower = lowerings[0]
+    assert (name, beside) == ("msgd_step, donated", 1)
+    assert vector_gb == 772_160_448 * 4 / 1e9
+    real = _jax.default_backend
+    _jax.default_backend = lambda: "tpu"
+    try:
+        lowered = lower()
+        assert lowered.as_text().count("tpu_custom_call") >= 3 + 2 + 1
+        alone = script._size(lowered.compile())
+    finally:
+        _jax.default_backend = real
+    assert alone + beside * vector_gb <= script.TARGET_GB
+    assert alone > 3 * vector_gb       # the vector, its momentum, the gradient
 
 
 def test_the_indexer_compiles_at_the_published_shape(one_chip):
