@@ -51,7 +51,7 @@ def _as_pointer(obj):
 #: lockstep with transport.cpp (see kApiVersion comment there) whenever
 #: the exported C surface changes, so a stale .so fails loudly with a
 #: rebuild message instead of a confusing missing-symbol AttributeError.
-MT_API_VERSION = 17008
+MT_API_VERSION = 17009
 
 
 class NativeTransportLib:
@@ -85,6 +85,8 @@ class NativeTransportLib:
         self._lib.mt_codec_int8_decode.argtypes = [ctypes.c_void_p, ctypes.c_void_p, ctypes.c_uint64, ctypes.c_void_p]
         self._lib.mt_codec_int8_encode.restype = None
         self._lib.mt_codec_int8_encode.argtypes = [ctypes.c_void_p, ctypes.c_void_p, ctypes.c_uint64, ctypes.c_void_p, ctypes.c_void_p]
+        self._lib.mt_copy_helpers.restype = ctypes.c_int32
+        self._lib.mt_copy_helpers.argtypes = [ctypes.c_void_p, ctypes.c_int32, ctypes.c_uint64, ctypes.c_uint64]
         self._lib.mt_finalize.restype = None
         self._lib.mt_finalize.argtypes = [ctypes.c_void_p]
         self._lib.mt_fold_f32.restype = None
@@ -187,6 +189,10 @@ class NativeTransportLib:
         codes, _codes_len = _as_pointer(codes)
         return self._lib.mt_codec_int8_encode(x, residual, n, scales, codes)
 
+    def mt_copy_helpers(self, ctx, n, min_bytes, spin_ns):
+        """Give the endpoint n helper threads that copy a ring copy of at least min_bytes in parts with the caller, fork and join inside the call that makes the progress; a helper waits spinning for spin_ns after a part and asleep from then on. Once, before the first message; n <= 0 changes nothing. Returns the helpers started."""
+        return self._lib.mt_copy_helpers(ctx, n, min_bytes, spin_ns)
+
     def mt_finalize(self, ctx):
         """Unmap rings, unlink the own segment, free the context."""
         return self._lib.mt_finalize(ctx)
@@ -231,7 +237,7 @@ class NativeTransportLib:
         return self._lib.mt_op_intervals(ctx, handle, out)
 
     def mt_op_timing(self, ctx, handle, out):
-        """The record of a finished op, after mt_test saw it done and before mt_release: 12 u64 words into out and 12 returned, or 0 without a record (timing off). [0] 1 send / 2 receive, [1] msg_id, [2] t_first ns, [3] t_done ns, [4] copy_ns, [5] blocked_ns (send) or starved_ns (receive), [6] away_ns, [7] receive's t_first_pub ns, [8] bytes, [9] a send's early_bytes (placed while its pieces were short of its length), [10] a send's unready_ns (every appended byte placed, with room in the ring: a part of [6]), [11] copy intervals merged over a gap (mt_op_intervals)."""
+        """The record of a finished op, after mt_test saw it done and before mt_release: 13 u64 words into out and 13 returned, or 0 without a record (timing off). [0] 1 send / 2 receive, [1] msg_id, [2] t_first ns, [3] t_done ns, [4] copy_ns, [5] blocked_ns (send) or starved_ns (receive), [6] away_ns, [7] receive's t_first_pub ns, [8] bytes, [9] a send's early_bytes (placed while its pieces were short of its length), [10] a send's unready_ns (every appended byte placed, with room in the ring: a part of [6]), [11] copy intervals merged over a gap (mt_op_intervals), [12] payload bytes copied in parts by the caller and the helpers."""
         out, _out_len = _as_pointer(out)
         return self._lib.mt_op_timing(ctx, handle, out)
 
@@ -296,7 +302,7 @@ class NativeTransportLib:
         return self._lib.mt_release(ctx, handle)
 
     def mt_ring_counts(self, ctx, which):
-        """How the rings were used so far: which == 0, chunks this endpoint placed in its peers' rings; 1, placements a full ring refused (the sender waited for the owner); 2, chunks copied out of the own rings; 3, those of them during whose copy the ring's head moved (sender and owner were copying at the same time); 4, payload bytes placed while their op's pieces were short of its length; 5, buffers of copy intervals allocated (none while the timing is off)."""
+        """How the rings were used so far: which == 0, chunks this endpoint placed in its peers' rings; 1, placements a full ring refused (the sender waited for the owner); 2, chunks copied out of the own rings; 3, those of them during whose copy the ring's head moved (sender and owner were copying at the same time); 4, payload bytes placed while their op's pieces were short of its length; 5, buffers of copy intervals allocated (none while the timing is off); 6, payload bytes placed in parts that the caller and the helpers copied at once (mt_copy_helpers); 7, those copied out of the own rings so."""
         return self._lib.mt_ring_counts(ctx, which)
 
     def mt_rx_bytes(self, ctx, which):

@@ -59,9 +59,26 @@
 //    the header, the `total_bytes` and the bytes it always saw, one
 //    message; only the instants differ.  This is the one form of a send
 //    that is not yet whole.
-//  * All progress happens inside mt_iprobe/mt_test calls from the caller's
-//    cooperative scheduler — single-threaded per process, like the
-//    reference's coroutine polling (reference init.lua:147-185).
+//  * All progress happens inside mt_iprobe/mt_test/mt_isend calls from the
+//    caller's cooperative scheduler, like the reference's coroutine polling
+//    (reference init.lua:147-185): one thread a process reads and writes
+//    the ring indices, matches messages and holds the handles.  An endpoint
+//    may have helper threads beside it (mt_copy_helpers; Crew), and they do
+//    one thing: a ring copy of a large chunk (circ_write, circ_read) is cut
+//    into parts on cache lines, the caller and the helpers copy the parts
+//    at once, and the caller goes on only when every part is copied, fork
+//    and join inside the call that makes the progress.  A helper is handed
+//    a destination, a source and a length and says when it is done: it
+//    reads no ring index, matches no message, holds no handle and touches
+//    no Python.  `head` and `tail` are published where they always were,
+//    after the join, so a chunk is visible to its peer only whole: a sender
+//    killed inside a chunk, be it inside a part, has still published
+//    nothing, and abandon_partials, the remap after a stall,
+//    mt_recv_filled's in-order mark, mt_send_written, a bound receive's
+//    cancel and the assembly path see what they saw.  Without helpers (the
+//    default, and what a host with no core to spare gets) every copy is one
+//    memcpy on the caller's thread.  mt_ring_counts 6 and 7 say how many
+//    payload bytes went in and out in parts.
 //  * Where a message's time went is kept only while the endpoint's one
 //    switch is on (mt_set_timing; comm/shm.py sets it from the span
 //    recorder): a record a message on each end (TxTiming, RxTiming), the
@@ -253,6 +270,7 @@ struct TxTiming {
   uint64_t unready_ns = 0;  // a pass that found every appended byte placed,
                             // to the next attempt with a byte to place
   uint64_t t_unready = 0;   // that pass, still waited out; 0: none
+  uint64_t split_bytes = 0;  // payload bytes copied in parts (the crew)
   CopyRuns runs;            // when the thread was inside circ_write
 };
 
@@ -268,6 +286,7 @@ struct RxTiming {
   uint64_t starved_ns = 0;   // ring empty, message partial: the sender's
   uint64_t away_ns = 0;      // a chunk lay published and was not being copied
   uint32_t chunks = 0;
+  uint64_t split_bytes = 0;  // payload bytes copied in parts (the crew)
   CopyRuns runs;  // when the thread was inside circ_read or the hand-over
 
   // One chunk copied out between `t_start` and `t_end`.
@@ -359,6 +378,8 @@ struct RecvOp {
   RxTiming rt;
 };
 
+struct Crew;  // the endpoint's helper threads, below
+
 struct Ctx {
   std::string ns;
   int rank = -1;
@@ -388,6 +409,12 @@ struct Ctx {
   uint64_t rx_chunks = 0;
   uint64_t rx_overlap_chunks = 0;
   uint64_t tx_early_bytes = 0;
+  // Payload bytes that went into a peer's ring, and out of an own ring, in
+  // parts copied at once (mt_ring_counts 6, 7); `crew` copies them with the
+  // caller, and without one (the default) every copy is one memcpy here.
+  uint64_t tx_split_bytes = 0;
+  uint64_t rx_split_bytes = 0;
+  Crew* crew = nullptr;
   // While `timing` (mt_set_timing): ns inside circ_write, inside circ_read
   // and the hand-over memcpy, and inside progress() with that memcpy
   // (mt_wire_ns).  Less the two copies the last is the cost of polling.
@@ -476,23 +503,161 @@ bool map_segment(const std::string& name, uint64_t nrings, uint64_t ring_bytes,
   return true;
 }
 
-void circ_write(const Ring& ring, uint64_t pos, const void* src, uint64_t n) {
-  uint64_t off = pos % ring.capacity;
-  uint64_t first = (off + n <= ring.capacity) ? n : ring.capacity - off;
-  std::memcpy(ring.data + off, src, first);
-  if (first < n) {
-    std::memcpy(ring.data, reinterpret_cast<const uint8_t*>(src) + first,
-                n - first);
+// -- the copy crew ------------------------------------------------------------
+//
+// The helper threads of one endpoint (mt_copy_helpers; none by default).  A
+// ring copy of at least `min_bytes` is cut into parts on the destination's
+// cache lines, twice as many as there are threads to copy them, and the
+// caller and the helpers take part after part off `open` until none is
+// left; the caller then waits for the parts the helpers took and returns:
+// fork and join inside the one memcpy's place, so whoever called sees a
+// copy that is whole, as before.  A helper knows nothing but the job: it
+// reads no ring index and no message, and it is told a destination, a
+// source and a length.  The job's fields are plain: they are written before
+// `open` is stored, read only by whoever took a part off `open`, and not
+// written again before every part taken has been counted in `copied`.  A
+// helper that is late (asleep, or off its core) finds `open` at 0 and the
+// caller has copied its share: nobody waits for a thread that has not
+// begun.  A helper spins for `spin_ns` after the last part it saw (the next
+// chunk of a large message follows within microseconds, and a futex wake
+// costs a good part of a chunk's half) and then sleeps on the condition
+// variable: it costs a core only while large messages move.
+struct Crew {
+  std::vector<std::thread> threads;
+  uint64_t min_bytes = 0;
+  uint64_t spin_ns = 0;
+  uint8_t* dst = nullptr;
+  const uint8_t* src = nullptr;
+  uint64_t len = 0;
+  uint64_t lead = 0;  // bytes of `dst` before its first whole cache line
+  uint64_t part = 0;  // bytes a part, whole cache lines
+  alignas(64) std::atomic<uint32_t> open{0};    // parts nobody has taken yet
+  alignas(64) std::atomic<uint32_t> copied{0};  // parts the helpers finished
+  alignas(64) std::atomic<uint32_t> asleep{0};
+  std::atomic<bool> closing{false};
+  std::mutex mu;
+  std::condition_variable cv;
+};
+
+void cpu_relax() {
+#if defined(__x86_64__) || defined(__i386__)
+  __builtin_ia32_pause();
+#elif defined(__aarch64__)
+  asm volatile("yield");
+#endif
+}
+
+// Part `idx` of the open job: [lead + idx * part, lead + (idx + 1) * part),
+// the first from 0 and the last to the end.
+void crew_copy_part(const Crew* crew, uint32_t idx) {
+  uint64_t lo = idx == 0 ? 0 : crew->lead + idx * crew->part;
+  uint64_t hi = std::min(crew->len, crew->lead + (idx + 1) * crew->part);
+  std::memcpy(crew->dst + lo, crew->src + lo, hi - lo);
+}
+
+// Take one part off `open`; false when none is left.
+bool crew_take(Crew* crew, uint32_t* idx) {
+  uint32_t left = crew->open.load(std::memory_order_acquire);
+  while (left > 0) {
+    if (crew->open.compare_exchange_weak(left, left - 1,
+                                         std::memory_order_acq_rel)) {
+      *idx = left - 1;
+      return true;
+    }
+  }
+  return false;
+}
+
+void crew_helper(Crew* crew) {
+  uint64_t idle_since = 0;  // first look at the clock since the last part
+  for (uint32_t spins = 0;; ++spins) {
+    uint32_t idx;
+    if (crew_take(crew, &idx)) {
+      crew_copy_part(crew, idx);
+      crew->copied.fetch_add(1, std::memory_order_release);
+      idle_since = 0;
+      continue;
+    }
+    if (crew->closing.load(std::memory_order_acquire)) return;
+    cpu_relax();
+    if (spins % 128 != 0) continue;
+    const uint64_t now = now_ns();
+    if (idle_since == 0) idle_since = now;
+    if (now - idle_since < crew->spin_ns) continue;
+    std::unique_lock<std::mutex> lk(crew->mu);
+    crew->asleep.fetch_add(1, std::memory_order_seq_cst);
+    crew->cv.wait(lk, [crew] {
+      return crew->open.load(std::memory_order_seq_cst) > 0 ||
+             crew->closing.load(std::memory_order_acquire);
+    });
+    crew->asleep.fetch_sub(1, std::memory_order_seq_cst);
+    idle_since = 0;
   }
 }
 
-void circ_read(const Ring& ring, uint64_t pos, void* dst, uint64_t n) {
+// Copy `n` bytes; those of them that were copied in parts (all or none).
+uint64_t copy_bytes(Crew* crew, void* dst, const void* src, uint64_t n) {
+  if (crew == nullptr || n < crew->min_bytes) {
+    std::memcpy(dst, src, n);
+    return 0;
+  }
+  const uint64_t threads = crew->threads.size() + 1;
+  crew->dst = static_cast<uint8_t*>(dst);
+  crew->src = static_cast<const uint8_t*>(src);
+  crew->len = n;
+  crew->lead = (64 - reinterpret_cast<uintptr_t>(dst) % 64) % 64;
+  crew->part = (n / (2 * threads) + 63) & ~63ull;
+  const uint32_t nparts =
+      (uint32_t)((n - crew->lead + crew->part - 1) / crew->part);
+  crew->copied.store(0, std::memory_order_relaxed);
+  crew->open.store(nparts, std::memory_order_seq_cst);
+  if (crew->asleep.load(std::memory_order_seq_cst) > 0) {
+    { std::lock_guard<std::mutex> lk(crew->mu); }  // it is waiting, or awake
+    crew->cv.notify_all();
+  }
+  uint32_t mine = 0;
+  for (uint32_t idx; crew_take(crew, &idx); ++mine) crew_copy_part(crew, idx);
+  while (crew->copied.load(std::memory_order_acquire) + mine < nparts) {
+    cpu_relax();
+  }
+  return n;
+}
+
+void crew_close(Crew* crew) {
+  if (crew == nullptr) return;
+  {
+    std::lock_guard<std::mutex> lk(crew->mu);
+    crew->closing.store(true, std::memory_order_release);
+  }
+  crew->cv.notify_all();
+  for (auto& t : crew->threads) t.join();
+  delete crew;
+}
+
+// Both return the bytes that were copied in parts.
+uint64_t circ_write(Crew* crew, const Ring& ring, uint64_t pos,
+                    const void* src, uint64_t n) {
   uint64_t off = pos % ring.capacity;
   uint64_t first = (off + n <= ring.capacity) ? n : ring.capacity - off;
-  std::memcpy(dst, ring.data + off, first);
+  uint64_t split = copy_bytes(crew, ring.data + off, src, first);
   if (first < n) {
-    std::memcpy(reinterpret_cast<uint8_t*>(dst) + first, ring.data, n - first);
+    split += copy_bytes(crew, ring.data,
+                        reinterpret_cast<const uint8_t*>(src) + first,
+                        n - first);
   }
+  return split;
+}
+
+uint64_t circ_read(Crew* crew, const Ring& ring, uint64_t pos, void* dst,
+                   uint64_t n) {
+  uint64_t off = pos % ring.capacity;
+  uint64_t first = (off + n <= ring.capacity) ? n : ring.capacity - off;
+  uint64_t split = copy_bytes(crew, dst, ring.data + off, first);
+  if (first < n) {
+    split += copy_bytes(crew, reinterpret_cast<uint8_t*>(dst) + first,
+                        ring.data, n - first);
+  }
+  return split;
 }
 
 void unmap_peer(Ctx* ctx, int dst) {
@@ -582,8 +747,9 @@ void drain_ring(Ctx* ctx, const Ring& ring) {
     RxTiming* rt = nullptr;  // the record of this chunk's message
     RxTiming whole;          // ... of a message that is one chunk
     ChunkHeader ch;
-    circ_read(ring, tail, &ch, sizeof(ch));
+    circ_read(ctx->crew, ring, tail, &ch, sizeof(ch));
     tail += sizeof(ch);
+    uint64_t split = 0;  // bytes of this chunk copied in parts
     // A first chunk opens a message: it lands in the receive posted for
     // it, if there is one, and in an assembly buffer otherwise.
     RecvOp* op = nullptr;
@@ -596,7 +762,9 @@ void drain_ring(Ctx* ctx, const Ring& ring) {
     if (ch.chunk_bytes == ch.total_bytes) {  // complete in one chunk
       rt = &whole;
       if (op != nullptr) {
-        if (ch.chunk_bytes > 0) circ_read(ring, tail, op->out, ch.chunk_bytes);
+        if (ch.chunk_bytes > 0) {
+          split = circ_read(ctx->crew, ring, tail, op->out, ch.chunk_bytes);
+        }
         op->size = ch.total_bytes;
         op->bound = true;
         op->done = true;
@@ -604,7 +772,10 @@ void drain_ring(Ctx* ctx, const Ring& ring) {
         ctx->rx_direct_bytes += ch.total_bytes;
       } else {
         Buffer buf = alloc_buffer(ctx, ch.total_bytes);
-        if (ch.chunk_bytes > 0) circ_read(ring, tail, buf.data.get(), ch.chunk_bytes);
+        if (ch.chunk_bytes > 0) {
+          split = circ_read(ctx->crew, ring, tail, buf.data.get(),
+                            ch.chunk_bytes);
+        }
         auto& box = ctx->ready[{ch.src, ch.tag}];
         box.push_back(Message{std::move(buf), RxTiming{}});
         landed = &box.back().rt;
@@ -629,7 +800,7 @@ void drain_ring(Ctx* ctx, const Ring& ring) {
       uint8_t* dst = op != nullptr ? op->out : part.buf.data.get();
       uint64_t n = ch.chunk_bytes;  // clamp defensively; completion is byte-based
       if (part.filled + n > part.total) n = part.total - part.filled;
-      if (n > 0) circ_read(ring, tail, dst + part.filled, n);
+      if (n > 0) split = circ_read(ctx->crew, ring, tail, dst + part.filled, n);
       part.filled += ch.chunk_bytes;
       part.seen++;
       rt = &part.rt;
@@ -650,6 +821,7 @@ void drain_ring(Ctx* ctx, const Ring& ring) {
     tail += ch.chunk_bytes;
     ring.idx->tail.store(tail, std::memory_order_release);
     ctx->rx_chunks++;
+    ctx->rx_split_bytes += split;
     const bool overlapped =
         ring.idx->head.load(std::memory_order_acquire) != head_before;
     ctx->rx_overlap_chunks += overlapped;
@@ -657,6 +829,7 @@ void drain_ring(Ctx* ctx, const Ring& ring) {
       const uint64_t t_end = now_ns();
       ctx->rx_copy_ns += t_end - t_start;
       rt->chunk(ch, t_start, t_end);
+      rt->split_bytes += split;
       rt->runs.add(ctx->pass_no, t_start, t_end, ch.chunk_bytes,
                    /*fresh=*/false, &ctx->run_buffers);
     }
@@ -756,12 +929,13 @@ void pump_sends(Ctx* ctx) {
         ch.chunk_bytes = chunk;
         ch.total_bytes = op.len;
         ch.pub_ns = 0;
+        uint64_t split = 0;  // bytes of this chunk copied in parts
         for (uint64_t placed = 0; placed < chunk;) {
           Piece& piece = op.pieces.front();
           uint64_t n = piece.len - op.piece_off;
           if (n > chunk - placed) n = chunk - placed;
-          circ_write(ring, head + sizeof(ch) + placed,
-                     piece.data + op.piece_off, n);
+          split += circ_write(ctx->crew, ring, head + sizeof(ch) + placed,
+                              piece.data + op.piece_off, n);
           placed += n;
           op.piece_off += n;
           if (op.piece_off == piece.len) {  // its last byte is in the ring
@@ -778,15 +952,17 @@ void pump_sends(Ctx* ctx) {
           }
           op.tt.copy_ns += t_pub - t_try;
           op.tt.t_done = t_pub;
+          op.tt.split_bytes += split;
           op.tt.runs.add(ctx->pass_no, t_try, t_pub, chunk, /*fresh=*/false,
                          &ctx->run_buffers);
           ctx->tx_copy_ns += t_pub - t_try;
         }
-        circ_write(ring, head, &ch, sizeof(ch));
+        circ_write(ctx->crew, ring, head, &ch, sizeof(ch));
         head += need;
         ring.idx->head.store(head, std::memory_order_release);
         budget -= need;
         ctx->tx_chunks++;
+        ctx->tx_split_bytes += split;
         if (op.appended < op.len) {
           op.early_bytes += chunk;
           ctx->tx_early_bytes += chunk;
@@ -844,9 +1020,27 @@ void* mt_init(const char* ns, int rank, int nranks, uint64_t ring_bytes) {
   return ctx;
 }
 
+// Give the endpoint `n` helper threads that copy a ring copy of at least
+// `min_bytes` in parts with the caller (Crew); they wait spinning for
+// `spin_ns` after a part and asleep from then on.  Once, before the first
+// message; n <= 0 changes nothing.  Returns the helpers started.
+int32_t mt_copy_helpers(void* vctx, int32_t n, uint64_t min_bytes,
+                        uint64_t spin_ns) {
+  auto* ctx = static_cast<Ctx*>(vctx);
+  if (n <= 0 || ctx->crew != nullptr) return 0;
+  auto* crew = new Crew();
+  crew->min_bytes = std::max<uint64_t>(min_bytes, 4096);
+  crew->spin_ns = spin_ns;
+  crew->threads.reserve((size_t)n);
+  for (int32_t i = 0; i < n; ++i) crew->threads.emplace_back(crew_helper, crew);
+  ctx->crew = crew;
+  return n;
+}
+
 void mt_finalize(void* vctx) {
   auto* ctx = static_cast<Ctx*>(vctx);
   if (ctx == nullptr) return;
+  crew_close(ctx->crew);
   if (ctx->own.hdr != nullptr) {
     munmap(ctx->own.hdr, ctx->own.map_bytes);
     shm_unlink(shm_name(ctx->ns, ctx->rank).c_str());
@@ -1056,13 +1250,15 @@ uint64_t mt_rx_bytes(void* vctx, int32_t which) {
 // during whose copy the ring's head moved: sender and owner were copying
 // at the same time; 4, payload bytes placed while their op's pieces were
 // short of its length; 5, buffers of copy intervals allocated (none while
-// the timing is off).
+// the timing is off); 6, payload bytes placed in parts copied at once by
+// the caller and the helpers; 7, those copied out so (none without helpers).
 uint64_t mt_ring_counts(void* vctx, int32_t which) {
   auto* ctx = static_cast<Ctx*>(vctx);
   const uint64_t counts[] = {ctx->tx_chunks,      ctx->tx_ring_full,
                              ctx->rx_chunks,      ctx->rx_overlap_chunks,
-                             ctx->tx_early_bytes, ctx->run_buffers};
-  return which >= 0 && which < 6 ? counts[which] : 0;
+                             ctx->tx_early_bytes, ctx->run_buffers,
+                             ctx->tx_split_bytes, ctx->rx_split_bytes};
+  return which >= 0 && which < 8 ? counts[which] : 0;
 }
 
 // What this endpoint's unfinished transfers stand before, as bits, from the
@@ -1109,8 +1305,9 @@ void mt_set_timing(void* vctx, int32_t on) {
 // and [3] t_done, ns on CLOCK_MONOTONIC; [4] copy_ns; [5] blocked_ns of a
 // send, starved_ns of a receive; [6] away_ns; [7] a receive's t_first_pub;
 // [8] bytes; [9] a send's early_bytes and [10] its unready_ns (a part of
-// [6]); [11] copy intervals that were merged over a gap (mt_op_intervals).
-constexpr int32_t kTimingWords = 12;
+// [6]); [11] copy intervals that were merged over a gap (mt_op_intervals);
+// [12] payload bytes copied in parts by the caller and the helpers.
+constexpr int32_t kTimingWords = 13;
 
 int32_t mt_op_timing(void* vctx, int64_t handle, void* vout) {
   auto* ctx = static_cast<Ctx*>(vctx);
@@ -1124,7 +1321,7 @@ int32_t mt_op_timing(void* vctx, int64_t handle, void* vout) {
     const uint64_t words[kTimingWords] = {
         1, op.msg_id, tt.t_first, tt.t_done, tt.copy_ns, tt.blocked_ns,
         since(tt.t_done - tt.t_first, busy), tt.t_first, op.len,
-        op.early_bytes, tt.unready_ns, tt.runs.merged};
+        op.early_bytes, tt.unready_ns, tt.runs.merged, tt.split_bytes};
     std::memcpy(out, words, sizeof(words));
     return kTimingWords;
   }
@@ -1135,7 +1332,8 @@ int32_t mt_op_timing(void* vctx, int64_t handle, void* vout) {
     if (!op.done || rt.chunks == 0) return 0;
     const uint64_t words[kTimingWords] = {
         2, rt.msg_id, rt.t_first, rt.t_done, rt.copy_ns, rt.starved_ns,
-        rt.away_ns, rt.t_first_pub, op.size, 0, 0, rt.runs.merged};
+        rt.away_ns, rt.t_first_pub, op.size, 0, 0, rt.runs.merged,
+        rt.split_bytes};
     std::memcpy(out, words, sizeof(words));
     return kTimingWords;
   }
@@ -1311,7 +1509,7 @@ void mt_fold_f32(const void* vown, const void* vptrs, int32_t nchildren,
 // generated _bindings.py refuses a stale .so (loud rebuild message)
 // instead of failing with a confusing missing-symbol AttributeError.
 // Keep in sync with MT_API_VERSION in gen_bindings.py.
-int64_t mt_api_version(void) { return 17008; }
+int64_t mt_api_version(void) { return 17009; }
 
 }  // extern "C"
 

@@ -20,6 +20,29 @@ half-sent is dropped when its next incarnation's first message opens.
 ``ring_counters`` says how often a sender found its ring full and how
 often the two sides were copying at once.
 
+Who copies: one thread a process makes all the progress (reads and
+writes the ring indices, matches messages, holds the handles), from
+inside ``test``, ``iprobe`` and ``isend``.  Where the host has cores to
+spare (:func:`copy_helpers`: worked out from the cores this process may
+run on and the ranks that share them, no variable and no flag) the
+endpoint starts that many helper threads in the native library, and a
+ring copy of ``SPLIT_MIN_BYTES`` or more is cut into parts on cache
+lines that the calling thread and the helpers copy at once, fork and
+join inside the one ``memcpy``'s place.  A helper is handed a
+destination, a source and a length and says when it is done: it reads
+no ring index, matches no message, holds no handle and touches no
+Python.  ``head`` and ``tail`` are published after the join, where they
+always were, so a chunk is visible to its peer only whole and every
+recovery rule holds as it stood: a sender killed inside a chunk (inside
+a part) has published nothing, ``filled`` moves in order and by whole
+chunks, ``written`` says what it said, a cancelled receive's message
+stays whole.  Acks, headers, heartbeats and every message under the
+threshold are one ``memcpy`` on the caller's thread at the latency they
+had, and with no helper every copy is.  ``ring_counters`` says how many
+payload bytes went in and out in parts (``tx_split_bytes``,
+``rx_split_bytes``, counted with obs off too), and a ``wire`` span its
+message's (``split_bytes`` beside ``bytes``).
+
 Where a message's time went: while the span recorder records (and only
 then: the recorder's ``enabled`` at construction is the one switch, there
 is no variable and no flag of its own) the native side keeps a record a
@@ -109,10 +132,48 @@ from mpit_tpu.obs import spans as _spans
 
 #: words of a native timing record (transport.cpp ``mt_op_timing``), and
 #: of the most copy intervals a record keeps (``mt_op_intervals``)
-_TIMING_WORDS = 12
+_TIMING_WORDS = 13
 _RUN_WORDS = 3 * 64
 #: what :meth:`ShmTransport.waiting` names, by bit of ``mt_waiting``
 _WAITING = ((1, "unready"), (2, "blocked"), (4, "unanswered"), (8, "partial"))
+#: :meth:`ShmTransport.ring_counters`' names, by ``mt_ring_counts``' index
+_RING_COUNTS = ((0, "tx_chunks"), (1, "tx_ring_full"), (2, "rx_chunks"),
+                (3, "rx_overlap_chunks"), (4, "tx_early_bytes"),
+                (6, "tx_split_bytes"), (7, "rx_split_bytes"))
+#: a ring copy of at least this many bytes is cut into parts
+#: (:func:`copy_helpers` has the timings), and a helper waits spinning
+#: this long after a part before it sleeps
+SPLIT_MIN_BYTES = 1 << 20
+_HELPER_SPIN_NS = 1_000_000
+
+
+def copy_helpers(cores: int, ranks: int) -> int:
+    """How many helper threads an endpoint starts for its ring copies, on
+    a host where this process may run on ``cores`` cores
+    (``os.sched_getaffinity``) that ``ranks`` ranks share (the
+    endpoint's ``nranks``): of a rank's share of the cores one is its
+    own thread's and one its other threads' (a worker's stream thread,
+    a server's sweep), and half of what is left, two at most, copy
+    beside it; 0 where nothing is left, and the copies are one
+    ``memcpy`` on the caller's thread as they always were.
+
+    The timings that chose it (PERF.md section 5, "After PR 66": my chip
+    runs, PR 66, the chip's host, 13 cores of a KVM guest).  The bare
+    wire, ``benchmarks/ptest.py`` over shm with one client and two
+    servers, MB/s both ways with 0 / 1 / 2 / 3 helpers an endpoint:
+    727-729 / 1,329-1,347 / 1,679-1,739 / 1,991-2,038 at 600 MB and
+    773-790 / 1,458-1,468 / 1,881-1,897 / 2,196-2,228 at 1,650 MB: one
+    helper gives 1.85 times a lone copy, the second 0.55 more, the
+    third 0.4 more.  Under a round of ``c111m-ps1w-su1`` (13 cores, 3
+    ranks, the servers' sweeps and the stream's thread beside the
+    copies; one run a count) 38,004 / 42,245 / 40,374 / 42,018
+    tokens/s: the first helper is the gain and the others are inside
+    the cell's spread, so a rank with four cores gets one.  A helper
+    that slept between parts (no spinning) gave a pair of endpoints
+    11.0-11.7 GB/s of payload where one spinning for a millisecond
+    gave 13.3-14.6 and a lone copy 9.6-10.2, hence ``_HELPER_SPIN_NS``;
+    ``SPLIT_MIN_BYTES``: see PERF.md, the same section."""
+    return max(0, min(2, (cores // ranks - 2) // 2))
 
 
 @functools.lru_cache(maxsize=1)
@@ -141,6 +202,9 @@ class ShmTransport(Transport):
                 f"mt_init failed for namespace={namespace!r} rank={rank}"
             )
         self._closed = False
+        self.lib.mt_copy_helpers(
+            self._ctx, copy_helpers(len(os.sched_getaffinity(0)), nranks),
+            SPLIT_MIN_BYTES, _HELPER_SPIN_NS)
         # The wire's timing follows the span recorder: off, the native
         # side reads no clock and ``test`` asks for no record.
         self._rec = _spans.get_recorder()
@@ -390,11 +454,11 @@ class ShmTransport(Transport):
         the owner's drain); chunks it copied out of its own rings and those
         of them during whose copy the sender moved the ring's head (both
         sides were copying at once); payload bytes it placed while their
-        send's pieces were short of its length."""
+        send's pieces were short of its length; payload bytes it placed,
+        and copied out, in parts that its thread and its helpers copied
+        at once (:func:`copy_helpers`)."""
         return {key: int(self.lib.mt_ring_counts(self._ctx, which))
-                for which, key in enumerate((
-                    "tx_chunks", "tx_ring_full", "rx_chunks",
-                    "rx_overlap_chunks", "tx_early_bytes"))}
+                for which, key in _RING_COUNTS}
 
     def wire_counts(self) -> dict:
         return {**self.rx_path_bytes(), **self.ring_counters()}
@@ -429,12 +493,12 @@ class ShmTransport(Transport):
                                      self._record):
             return
         (kind, msg_id, t_first, t_done, copy, wait, away, t_pub, nbytes,
-         early, unready, merged) = self._record.tolist()
+         early, unready, merged, split) = self._record.tolist()
         if nbytes < _spans.WIRE_SPAN_MIN_BYTES:
             return
         runs = self.lib.mt_op_intervals(self._ctx, handle.native_id,
                                         self._runs)
-        args = {"bytes": nbytes, "msg_id": msg_id,
+        args = {"bytes": nbytes, "split_bytes": split, "msg_id": msg_id,
                 "copy_ms": copy / 1e6, "away_ms": away / 1e6,
                 "copies": self._runs[:3 * runs].reshape(-1, 3).tolist(),
                 "copies_merged": merged}

@@ -503,7 +503,8 @@ def run_rank(
     the transport's word on which way the bytes it received went
     (``rx_direct_bytes`` / ``rx_assembled_bytes`` on the shm wire) and on
     how its rings were used (``tx_chunks``, ``tx_ring_full``, ``rx_chunks``,
-    ``rx_overlap_chunks``, ``tx_early_bytes``)."""
+    ``rx_overlap_chunks``, ``tx_early_bytes``, ``tx_split_bytes``,
+    ``rx_split_bytes``)."""
     result = _run_role(rank, size, cfg, transport, data)
     if transport is not None:
         result = {**result, **transport.wire_counts()}
@@ -997,7 +998,8 @@ def _summarize(result: Dict[str, Any]) -> Dict[str, Any]:
             "final_loss", "final_eval_loss", "tokens_per_s", "tokens_total",
             "steps", "rx_direct_bytes",
             "rx_assembled_bytes", "tx_chunks", "tx_ring_full", "rx_chunks",
-            "rx_overlap_chunks", "tx_early_bytes", "train_seconds",
+            "rx_overlap_chunks", "tx_early_bytes", "tx_split_bytes",
+            "rx_split_bytes", "train_seconds",
             "first_step_seconds", "mosaic_calls",
             "moe_load_max_over_mean",
             "platform", "device_kind", "device_count", "device_ids",

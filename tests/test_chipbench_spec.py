@@ -1468,7 +1468,7 @@ def test_sdars_mix_keeps_to_the_traffic_its_issue_fixed():
             assert SDAR_CELL not in metric["workloads"], metric["name"]
     # added together and in order (PR 53's four follow them, and its
     # configuration and cell)
-    assert [m["name"] for m in cell.bench["per_layer"][-22:-18]] == list(
+    assert [m["name"] for m in cell.bench["per_layer"][-23:-19]] == list(
         SDAR_METRICS)
     assert (cell.bench["configs"][-5]["name"],
             cell.bench["workloads"][-5]["name"]) == (cell.config_name,
@@ -1704,7 +1704,7 @@ def test_trinitys_mix_keeps_to_the_traffic_its_issue_fixed():
         elif "workloads" in metric:
             assert TRINITY_CELL not in metric["workloads"], metric["name"]
     # added together, in order and last
-    assert [m["name"] for m in cell.bench["per_layer"][-18:-14]] == list(
+    assert [m["name"] for m in cell.bench["per_layer"][-19:-15]] == list(
         TRINITY_METRICS)
     assert (cell.bench["configs"][-4]["name"],
             cell.bench["workloads"][-4]["name"]) == (cell.config_name,
@@ -1965,7 +1965,7 @@ def test_nemotrons_mix_keeps_to_the_traffic_its_issue_fixed():
             assert NEMOTRON_CELL not in metric["workloads"], metric["name"]
     # added together and in order (PR 61's six and PR 65's three
     # follow them, and their configurations and cells)
-    assert [m["name"] for m in cell.bench["per_layer"][-14:-9]] == list(
+    assert [m["name"] for m in cell.bench["per_layer"][-15:-10]] == list(
         NEMOTRON_METRICS)
     assert (cell.bench["configs"][-3]["name"],
             cell.bench["workloads"][-3]["name"]) == (cell.config_name,
@@ -2217,7 +2217,7 @@ def test_qwen3nexts_mix_keeps_to_the_traffic_its_issue_fixed():
             assert QWEN3NEXT_CELL not in metric["workloads"], metric["name"]
     # added together and in order (PR 65's three follow them, and its
     # configuration and cell)
-    assert [m["name"] for m in cell.bench["per_layer"][-9:-3]] == list(
+    assert [m["name"] for m in cell.bench["per_layer"][-10:-4]] == list(
         QWEN3NEXT_METRICS)
     assert (cell.bench["configs"][-2]["name"],
             cell.bench["workloads"][-2]["name"]) == (cell.config_name,
@@ -2477,8 +2477,8 @@ def test_granites_mix_keeps_to_the_traffic_its_issue_fixed():
             assert metric["workloads"][-1] == GRANITE_CELL
         elif "workloads" in metric:
             assert GRANITE_CELL not in metric["workloads"], metric["name"]
-    # added together, in order and last
-    assert [m["name"] for m in cell.bench["per_layer"][-3:]] == list(
+    # added together and in order (PR 66's one follows them)
+    assert [m["name"] for m in cell.bench["per_layer"][-4:-1]] == list(
         GRANITE_METRICS)
     assert (cell.bench["configs"][-1]["name"],
             cell.bench["workloads"][-1]["name"]) == (cell.config_name,
@@ -2487,7 +2487,7 @@ def test_granites_mix_keeps_to_the_traffic_its_issue_fixed():
     assert "One stage of four" in cell.why and "1 layer of 10" in cell.why
     assert len(cell.bench["configs"]) == 14 and len(
         cell.bench["workloads"]) == 15 and len(
-            cell.bench["per_layer"]) == 90
+            cell.bench["per_layer"]) == 91
     # one cell in four may take four chips, and none does
     assert sum(w["chips"] == 4 for w in cell.bench["workloads"]) == 0
 
@@ -2689,8 +2689,8 @@ def test_pull_early_pct_is_entered_for_the_ps_cells_under_a_layer_of_perf_md():
                      "source": "program_span", "layer": "L3 shell + client",
                      "moves": "tokens_per_s", "workloads": PS_CELLS}
     # appended, nothing moved; PR 51's four, PR 53's four, PR 58's five,
-    # PR 61's six and PR 65's three follow it
-    assert bench["per_layer"][-23] is entry
+    # PR 61's six, PR 65's three and PR 66's one follow it
+    assert bench["per_layer"][-24] is entry
     perf = (spec_mod.ROOT / "PERF.md").read_text()
     layers = perf[perf.index("## 3. Layers"):perf.index("## 4. Cells")]
     assert f"| {entry['layer']} |" in layers and "`pull_early_pct`" in layers
@@ -2746,3 +2746,52 @@ def test_pull_early_pct_reads_the_hand_computed_share(early, tmp_path):
     assert _pull_early(run) == pytest.approx(by_hand, rel=1e-12)
     if early == (34, 38):
         assert by_hand == 100.0
+
+
+# -- the ring copies split over threads (PR 66) --------------------------------
+
+def _copy_split(run):
+    bench = spec_mod.load_bench(spec_mod.ROOT)
+    return spec_mod.load_reader(spec_mod.ROOT, bench, "copy_split_pct")(run)
+
+
+def test_copy_split_pct_is_entered_last_for_the_ps_cells_under_a_layer_of_perf_md():
+    bench = spec_mod.load_bench(spec_mod.ROOT)
+    entry = bench["per_layer"][-1]
+    assert entry == {"name": "copy_split_pct", "unit": "%", "better": "higher",
+                     "source": "program_span", "layer": "L2 servers + wire",
+                     "moves": "tokens_per_s", "workloads": PS_CELLS}
+    perf = (spec_mod.ROOT / "PERF.md").read_text()
+    layers = perf[perf.index("## 3. Layers"):perf.index("## 4. Cells")]
+    assert f"| {entry['layer']} |" in layers and "`copy_split_pct`" in layers
+
+
+def test_copy_split_pct_reads_nothing_on_the_rounds_recorded_before_the_change():
+    """The committed cut is of PR 48's program, whose ``wire`` spans say
+    nothing of parts: the reader returns None and does not raise, as it
+    must on this PR's parent."""
+    assert _copy_split(copies_run()) is None
+
+
+@pytest.mark.parametrize("ends,share", [((), 0.0), (("tx",), 0.5),
+                                        (("tx", "rx"), 1.0)],
+                         ids=["no_helper", "one_end", "both_ends"])
+def test_copy_split_pct_reads_the_hand_computed_share(ends, share, tmp_path):
+    """The same two rounds with ``split_bytes`` written on every ``wire``
+    span (0, or all but a last chunk of 1,000,000 bytes on the named
+    ends): each message has a ``tx`` and an ``rx`` of the same bytes, so
+    the named ends' share of the round's bytes less the short chunks, by
+    hand."""
+    trace = json.loads(COPIES_FIXTURE.read_text())
+    spans = [ev for ev in trace["traceEvents"]
+             if ev.get("cat") == "wire" and ev["ph"] == "B"]
+    assert len(spans) == 16  # two rounds x (GRAD, PARAM) x two servers x ends
+    for ev in spans:
+        ev["args"]["split_bytes"] = (
+            ev["args"]["bytes"] - 1_000_000 if ev["name"] in ends else 0)
+    assert sum(ev["args"]["bytes"] for ev in spans) == 2 * 4 * 598_468_608
+    path = tmp_path / "split.obs_trace.json"
+    path.write_text(json.dumps(trace))
+    by_hand = 100.0 * (share - len(ends) * 4 * 1_000_000 / (4 * 598_468_608))
+    assert _copy_split(dict(copies_run(), obs_trace=str(path))) == (
+        pytest.approx(by_hand, rel=1e-12))

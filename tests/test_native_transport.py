@@ -13,6 +13,7 @@ import numpy as np
 import pytest
 
 from mpit_tpu import obs
+from mpit_tpu.comm import shm
 from mpit_tpu.comm.shm import ShmTransport
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -900,7 +901,8 @@ class TestPairRings:
         try:
             a, b = wires[1], wires[0]
             zero = dict.fromkeys(("tx_chunks", "tx_ring_full", "rx_chunks",
-                                  "rx_overlap_chunks", "tx_early_bytes"), 0)
+                                  "rx_overlap_chunks", "tx_early_bytes",
+                                  "tx_split_bytes", "rx_split_bytes"), 0)
             assert a.ring_counters() == b.ring_counters() == zero
             # One-chunk messages to a receiver that has nothing else to do:
             # ten chunks each side, no ring ever full, and no chunk copied
@@ -1357,3 +1359,249 @@ class TestBuild:
         assert out != str(lib) and os.path.dirname(out) == str(lib.parent)
         assert not os.path.exists(out)  # renamed into place
         assert build.STAMP.read_text().strip() == build.source_hash()
+
+
+#: (cores the process may run on, ranks of the gang, helpers an endpoint)
+RULE_CASES = [(1, 3, 0), (8, 3, 0), (13, 3, 1), (30, 6, 1), (20, 3, 2),
+              (64, 3, 2)]
+
+SPLIT_PEER = textwrap.dedent(
+    """
+    import sys, numpy as np
+    sys.path.insert(0, {repo!r})
+    from mpit_tpu.comm import shm
+    shm.copy_helpers = lambda cores, ranks: {helpers}
+    t = shm.ShmTransport({ns!r}, {rank}, 2, ring_bytes={ring})
+    data = np.random.default_rng({seed}).integers(0, 256, {nbytes}, dtype=np.uint8)
+    t.send(data, {dst}, 4)
+    t.close()
+    """
+)
+
+
+class TestSplitCopies:
+    """A ring copy of ``shm.SPLIT_MIN_BYTES`` or more is cut into parts
+    that the calling thread and the endpoint's helper threads copy at
+    once (transport.cpp ``Crew``, ``copy_bytes``), fork and join inside
+    ``circ_write`` / ``circ_read``: the bytes, the order of the chunks,
+    ``filled`` and what a killed sender leaves behind are what they were,
+    and the counters say how much was copied so.  The helpers' count is
+    ``shm.copy_helpers(cores, ranks)``; the tests name it themselves.  A
+    ring of 8 MB cuts chunks of 2 MB less a header, twice the threshold."""
+
+    RING = 8 << 20
+    CHUNK = RING // 4 - CHUNK_HEADER.size
+    MIN = shm.SPLIT_MIN_BYTES
+
+    @pytest.fixture(params=[0, 1, 3], ids=lambda n: f"helpers{n}")
+    def helpers(self, request, monkeypatch):
+        monkeypatch.setattr(shm, "copy_helpers",
+                            lambda cores, ranks: request.param)
+        return request.param
+
+    @pytest.fixture
+    def one_helper(self, monkeypatch):
+        monkeypatch.setattr(shm, "copy_helpers", lambda cores, ranks: 1)
+
+    def pair(self, name, ring=RING):
+        ns = f"t_sp_{name}_{os.getpid()}"
+        return [ShmTransport(ns, r, 2, ring_bytes=ring) for r in range(2)]
+
+    @pytest.mark.parametrize("nbytes", [MIN - 1, MIN, MIN + 1, CHUNK + 1,
+                                        3 * RING + 17],
+                             ids=["under", "at", "over", "two_chunks",
+                                  "three_rings"])
+    def test_the_bytes_are_the_senders(self, helpers, nbytes):
+        """Sizes on both sides of the threshold, and one that laps the
+        ring three times, its chunks straddling the ring's end: a bound
+        receive and, posted behind it, an assembled one."""
+        a, b = self.pair(f"bytes_{helpers}_{nbytes}")
+        try:
+            first, second = noise(1, nbytes), noise(2, nbytes)
+            out = np.zeros_like(first)
+            hr = b.irecv(0, 4, out=out)
+            sends = [a.isend(first, 1, 4), a.isend(second, 1, 4)]
+            spin(lambda: a.test(sends[0]), lambda: b.test(hr))
+            np.testing.assert_array_equal(out, first)
+            while not b.iprobe(0, 4):  # the second is assembled meanwhile
+                a.test(sends[1])
+            late = np.zeros_like(second)
+            b.recv(0, 4, out=late)
+            np.testing.assert_array_equal(late, second)
+            assert a.test(sends[1])
+            assert b.rx_path_bytes() == {"rx_direct_bytes": nbytes,
+                                         "rx_assembled_bytes": nbytes}
+            tx = a.ring_counters()["tx_split_bytes"]
+            rx = b.ring_counters()["rx_split_bytes"]
+            if helpers == 0 or nbytes < self.MIN:
+                assert tx == rx == 0
+            else:  # all of it but a chunk's short end or a short chunk
+                assert 2 * nbytes - 8 * self.MIN <= tx <= 2 * nbytes
+                assert 2 * nbytes - 8 * self.MIN <= rx <= 2 * nbytes
+                assert tx > 0 and rx > 0
+            assert (a.ring_counters()["rx_split_bytes"]
+                    == b.ring_counters()["tx_split_bytes"] == 0)
+        finally:
+            a.close()
+            b.close()
+
+    def test_a_send_of_pieces_whose_piece_ends_inside_a_chunk(self, helpers):
+        """Pieces of 1.5 MB and 3 bytes, 2.7 MB, 300 kB and the rest,
+        appended as the sender goes: a chunk is cut across them, so a
+        chunk's copies are some over and some under the threshold."""
+        a, b = self.pair(f"pieces_{helpers}")
+        try:
+            data = noise(3, 9 << 20)
+            cuts = [0, (3 << 19) + 3, 4_400_000, 4_700_000, data.nbytes]
+            out = np.zeros_like(data)
+            hr = b.irecv(0, 4, out=out)
+            hs = a.isend_pieces(data.nbytes, 1, 4)
+            for lo, hi in zip(cuts, cuts[1:]):
+                a.append(hs, data[lo:hi])
+                for _ in range(3):
+                    a.test(hs)
+                    b.test(hr)
+                assert b.filled(hr) <= hi
+                np.testing.assert_array_equal(out[:b.filled(hr)],
+                                              data[:b.filled(hr)])
+            spin(lambda: a.test(hs), lambda: b.test(hr))
+            np.testing.assert_array_equal(out, data)
+            split = a.ring_counters()["tx_split_bytes"]
+            assert split == 0 if helpers == 0 else 0 < split < data.nbytes
+        finally:
+            a.close()
+            b.close()
+
+    def test_filled_moves_in_order_and_by_whole_chunks(self, helpers):
+        a, b = self.pair(f"filled_{helpers}")
+        try:
+            data = noise(4, 4 * self.CHUNK + 12_345)
+            out = np.zeros_like(data)
+            hr = b.irecv(0, 4, out=out)
+            told = []
+            b.follow(hr, told.append)
+            hs = a.isend(data, 1, 4)
+            spin(lambda: a.test(hs), lambda: b.test(hr))
+            assert told == sorted(told) and told[-1] == data.nbytes
+            assert set(told) <= {k * self.CHUNK for k in range(1, 5)} | {
+                data.nbytes}
+            np.testing.assert_array_equal(out, data)
+        finally:
+            a.close()
+            b.close()
+
+    def test_the_counters_are_the_large_messages_payload(self, one_helper):
+        """Through a ring that nothing wraps in: every chunk of the two
+        large messages is 4 MB or the rest, 1 MB at the least, and the
+        small ones, the one under the threshold among them, are one
+        ``memcpy`` on the caller's thread."""
+        a, b = self.pair("counters", ring=64 << 20)
+        try:
+            sizes = [1, 1000, self.MIN - 1, 9 << 20, (4 << 20) + self.MIN]
+            for seed, nbytes in enumerate(sizes):
+                data, out = noise(seed, nbytes), np.zeros(nbytes, np.uint8)
+                hr = b.irecv(0, 4, out=out)
+                hs = a.isend(data, 1, 4)
+                spin(lambda: a.test(hs), lambda: b.test(hr))
+                np.testing.assert_array_equal(out, data)
+            large = sum(n for n in sizes if n >= self.MIN)
+            assert a.ring_counters()["tx_split_bytes"] == large
+            assert b.ring_counters()["rx_split_bytes"] == large
+            assert a.wire_counts()["tx_split_bytes"] == large
+        finally:
+            a.close()
+            b.close()
+
+    def test_a_wire_span_says_how_much_of_it_was_split(self, one_helper,
+                                                       obs_on):
+        a, b = self.pair("span", ring=64 << 20)
+        try:
+            for seed, nbytes in ((1, (8 << 20) + 1000), (2, self.MIN + 5)):
+                data, out = noise(seed, nbytes), np.zeros(nbytes, np.uint8)
+                hr = b.irecv(0, 4, out=out)
+                hs = a.isend(data, 1, 4)
+                spin(lambda: a.test(hs), lambda: b.test(hr))
+            spans = [sp for sp in obs_on.spans if sp.cat == "wire"]
+            assert sorted((sp.name, sp.args["bytes"], sp.args["split_bytes"])
+                          for sp in spans) == sorted(
+                (end, nbytes, split) for end in ("tx", "rx")
+                for nbytes, split in (((8 << 20) + 1000, 8 << 20),
+                                      (self.MIN + 5, self.MIN + 5)))
+        finally:
+            a.close()
+            b.close()
+
+    @pytest.mark.parametrize("helpers", [1, 3])
+    def test_killed_inside_a_split_copy_has_published_nothing(
+            self, helpers, monkeypatch):
+        """The killed-producer case with both ends copying in parts: the
+        sender dies while it streams 2 MB chunks with room in the ring
+        (so inside a copy, as far as a signal can be aimed), and what the
+        owner then finds published is whole chunks of the sender's bytes,
+        no part of a chunk whose other parts never came; the dead one's
+        next incarnation takes the receive the torn message was bound
+        to."""
+        monkeypatch.setattr(shm, "copy_helpers", lambda cores, ranks: helpers)
+        ns = f"t_sp_kill_{helpers}_{os.getpid()}"
+        owner = ShmTransport(ns, 0, 2, ring_bytes=self.RING)
+        try:
+            nbytes = 12 * self.RING
+            peer = dict(repo=REPO, ns=ns, rank=1, ring=self.RING, dst=0,
+                        nbytes=nbytes, helpers=helpers)
+
+            def run(seed):
+                return subprocess.Popen(
+                    [sys.executable, "-c",
+                     SPLIT_PEER.format(seed=seed, **peer)],
+                    env={**os.environ, "JAX_PLATFORMS": "cpu"})
+
+            out = np.zeros(nbytes, np.uint8)
+            hr = owner.irecv(1, 4, out=out)
+            doomed = run(40)
+            spin(lambda: owner.test(hr) or owner.filled(hr) >= 3 * self.RING,
+                 limit=10**8)
+            doomed.kill()
+            doomed.wait(60)
+            for _ in range(8):  # drain whatever it had published
+                assert not owner.test(hr)
+            landed = owner.filled(hr)
+            assert 3 * self.RING <= landed < nbytes
+            assert landed % self.CHUNK == 0  # whole chunks, nothing else
+            np.testing.assert_array_equal(out[:landed],
+                                          noise(40, nbytes)[:landed])
+            assert headers_in_ring(ns, 0, 1, ring=self.RING) == []
+            again = run(42)
+            spin(lambda: owner.test(hr), limit=10**8)
+            assert again.wait(60) == 0
+            np.testing.assert_array_equal(out, noise(42, nbytes))
+            assert owner.filled(hr) < 0  # it was torn on the way
+        finally:
+            owner.close()
+
+    def test_more_helpers_than_cores_copy_every_byte_once(self, monkeypatch):
+        """Both directions at once with twice as many helpers an endpoint
+        as the host has cores, so that helpers are late, asleep or off
+        their core in the middle of a part: a part taken twice, lost or
+        published before it was whole would show in the bytes."""
+        crowd = 2 * len(os.sched_getaffinity(0))
+        monkeypatch.setattr(shm, "copy_helpers", lambda cores, ranks: crowd)
+        a, b = self.pair("crowd")
+        try:
+            there, back = noise(5, 6 * self.RING + 3), noise(6, 5 * self.RING)
+            got_there, got_back = np.zeros_like(there), np.zeros_like(back)
+            recvs = [(b, b.irecv(0, 4, out=got_there)),
+                     (a, a.irecv(1, 4, out=got_back))]
+            sends = [(a, a.isend(there, 1, 4)), (b, b.isend(back, 0, 4))]
+            spin(*[lambda t=t, h=h: t.test(h) for t, h in sends + recvs],
+                 limit=10**5)
+            np.testing.assert_array_equal(got_there, there)
+            np.testing.assert_array_equal(got_back, back)
+            assert a.ring_counters()["tx_split_bytes"] > 5 * self.RING
+        finally:
+            a.close()
+            b.close()
+
+    @pytest.mark.parametrize("cores,ranks,count", RULE_CASES)
+    def test_the_count_is_worked_out_from_the_cores_and_the_ranks(
+            self, cores, ranks, count):
+        assert shm.copy_helpers(cores, ranks) == count
