@@ -51,7 +51,7 @@ def _as_pointer(obj):
 #: lockstep with transport.cpp (see kApiVersion comment there) whenever
 #: the exported C surface changes, so a stale .so fails loudly with a
 #: rebuild message instead of a confusing missing-symbol AttributeError.
-MT_API_VERSION = 17009
+MT_API_VERSION = 17010
 
 
 class NativeTransportLib:
@@ -335,5 +335,5 @@ class NativeTransportLib:
         return self._lib.mt_waiting(ctx)
 
     def mt_wire_ns(self, ctx, which):
-        """The endpoint's totals while timing, ns, cumulative: which == 0, inside the copies into peers' rings; 1, inside the copies out of the own rings and the memcpy that hands an assembled message over; 2, inside progress() and that memcpy (less the two copies: the cost of polling)."""
+        """The endpoint's totals while timing, ns, cumulative: which == 0, inside the copies into peers' rings; 1, inside the copies out of the own rings and the memcpy that hands an assembled message over; 2, inside progress() and that memcpy (less the two copies: the cost of polling); 3, the helper threads' inside the parts they copied (mt_copy_helpers); 4, the helper threads' spinning with no part to take, none of it asleep (3 and 4 together: the helpers' time on a core)."""
         return self._lib.mt_wire_ns(ctx, which)

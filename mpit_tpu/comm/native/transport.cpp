@@ -113,6 +113,7 @@
 #include <vector>
 
 #include <fcntl.h>
+#include <pthread.h>
 #include <sys/mman.h>
 #include <sys/stat.h>
 #include <time.h>
@@ -521,7 +522,10 @@ bool map_segment(const std::string& name, uint64_t nrings, uint64_t ring_bytes,
 // begun.  A helper spins for `spin_ns` after the last part it saw (the next
 // chunk of a large message follows within microseconds, and a futex wake
 // costs a good part of a chunk's half) and then sleeps on the condition
-// variable: it costs a core only while large messages move.
+// variable: it costs a core only while large messages move.  While `timed`
+// (mt_set_timing) a helper adds up, by its own readings of the clock, the ns
+// it spent inside its parts' copies and the ns it spun with no part to take
+// (mt_wire_ns 3, 4): together they are its time on a core.
 struct Crew {
   std::vector<std::thread> threads;
   uint64_t min_bytes = 0;
@@ -535,6 +539,9 @@ struct Crew {
   alignas(64) std::atomic<uint32_t> copied{0};  // parts the helpers finished
   alignas(64) std::atomic<uint32_t> asleep{0};
   std::atomic<bool> closing{false};
+  std::atomic<bool> timed{false};
+  std::atomic<uint64_t> copy_ns{0};
+  std::atomic<uint64_t> spun_ns{0};
   std::mutex mu;
   std::condition_variable cv;
 };
@@ -569,12 +576,26 @@ bool crew_take(Crew* crew, uint32_t* idx) {
 }
 
 void crew_helper(Crew* crew) {
+#if defined(__linux__)
+  // Once a thread, before any job: the name a rank's census of its threads
+  // counts a helper under (obs/profile.py thread_census).
+  pthread_setname_np(pthread_self(), "mpit-crew");
+#endif
   uint64_t idle_since = 0;  // first look at the clock since the last part
   for (uint32_t spins = 0;; ++spins) {
     uint32_t idx;
     if (crew_take(crew, &idx)) {
+      const uint64_t t_part =
+          crew->timed.load(std::memory_order_relaxed) ? now_ns() : 0;
       crew_copy_part(crew, idx);
       crew->copied.fetch_add(1, std::memory_order_release);
+      if (t_part != 0) {
+        crew->copy_ns.fetch_add(now_ns() - t_part, std::memory_order_relaxed);
+        if (idle_since != 0) {
+          crew->spun_ns.fetch_add(t_part - idle_since,
+                                  std::memory_order_relaxed);
+        }
+      }
       idle_since = 0;
       continue;
     }
@@ -584,6 +605,9 @@ void crew_helper(Crew* crew) {
     const uint64_t now = now_ns();
     if (idle_since == 0) idle_since = now;
     if (now - idle_since < crew->spin_ns) continue;
+    if (crew->timed.load(std::memory_order_relaxed)) {
+      crew->spun_ns.fetch_add(now - idle_since, std::memory_order_relaxed);
+    }
     std::unique_lock<std::mutex> lk(crew->mu);
     crew->asleep.fetch_add(1, std::memory_order_seq_cst);
     crew->cv.wait(lk, [crew] {
@@ -1031,6 +1055,7 @@ int32_t mt_copy_helpers(void* vctx, int32_t n, uint64_t min_bytes,
   auto* crew = new Crew();
   crew->min_bytes = std::max<uint64_t>(min_bytes, 4096);
   crew->spin_ns = spin_ns;
+  crew->timed.store(ctx->timing, std::memory_order_relaxed);
   crew->threads.reserve((size_t)n);
   for (int32_t i = 0; i < n; ++i) crew->threads.emplace_back(crew_helper, crew);
   ctx->crew = crew;
@@ -1295,7 +1320,11 @@ int32_t mt_waiting(void* vctx) {
 // was published, and the endpoint its totals (mt_wire_ns); off (the
 // default) the message path reads no clock.
 void mt_set_timing(void* vctx, int32_t on) {
-  static_cast<Ctx*>(vctx)->timing = on != 0;
+  auto* ctx = static_cast<Ctx*>(vctx);
+  ctx->timing = on != 0;
+  if (ctx->crew != nullptr) {
+    ctx->crew->timed.store(ctx->timing, std::memory_order_relaxed);
+  }
 }
 
 // The record of a finished op, for the caller whose mt_test saw it done
@@ -1367,12 +1396,18 @@ int32_t mt_op_intervals(void* vctx, int64_t handle, void* vout) {
 
 // The endpoint's totals while timing, ns: which == 0, inside circ_write;
 // 1, inside circ_read and the memcpy that hands an assembled message
-// over; 2, inside progress() and that memcpy.  Cumulative: read as deltas.
+// over; 2, inside progress() and that memcpy; 3, the helpers' inside the
+// parts they copied; 4, the helpers' spinning with no part to take (the
+// wait for a chunk's next half, and `spin_ns` after the last: none of it
+// asleep).  Cumulative: read as deltas.
 uint64_t mt_wire_ns(void* vctx, int32_t which) {
   auto* ctx = static_cast<Ctx*>(vctx);
-  const uint64_t totals[] = {ctx->tx_copy_ns, ctx->rx_copy_ns,
-                             ctx->progress_ns};
-  return which >= 0 && which < 3 ? totals[which] : 0;
+  const Crew* crew = ctx->crew;
+  const uint64_t totals[] = {
+      ctx->tx_copy_ns, ctx->rx_copy_ns, ctx->progress_ns,
+      crew ? crew->copy_ns.load(std::memory_order_relaxed) : 0,
+      crew ? crew->spun_ns.load(std::memory_order_relaxed) : 0};
+  return which >= 0 && which < 5 ? totals[which] : 0;
 }
 
 // Monotonic wall clock in seconds (the MPI_Wtime analog,
@@ -1509,7 +1544,7 @@ void mt_fold_f32(const void* vown, const void* vptrs, int32_t nchildren,
 // generated _bindings.py refuses a stale .so (loud rebuild message)
 // instead of failing with a confusing missing-symbol AttributeError.
 // Keep in sync with MT_API_VERSION in gen_bindings.py.
-int64_t mt_api_version(void) { return 17009; }
+int64_t mt_api_version(void) { return 17010; }
 
 }  // extern "C"
 

@@ -468,10 +468,14 @@ class ShmTransport(Transport):
         the recorder records (zeros otherwise): copying into its peers'
         rings, copying out of its own (the hand-over of an assembled
         message included), and inside the native ``progress`` altogether:
-        less the two copies, the cost of polling."""
+        less the two copies, the cost of polling.  And its helper
+        threads' (:func:`copy_helpers`; zeros with none), by their own
+        readings of the clock: inside the parts they copied, and
+        spinning with no part to take: together their time on a core."""
         return {key: self.lib.mt_wire_ns(self._ctx, which) * 1e-9
                 for which, key in enumerate(
-                    ("tx_copy", "rx_copy", "progress"))}
+                    ("tx_copy", "rx_copy", "progress",
+                     "crew_copy", "crew_spin"))}
 
     def waiting(self) -> tuple:
         """What this endpoint's unfinished transfers stood before when it

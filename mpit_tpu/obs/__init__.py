@@ -60,6 +60,10 @@ counters.  This package is the one place the stack reports through:
   pool_depth / sched_runq / task_cpu) sampled into the trace, and
   ``python -m mpit_tpu.obs profile`` — per-rank core utilization,
   on/off-CPU phase split, pool overlap efficiency, top tasks by CPU.
+  While spans are recorded, a server's ``apply_exec`` and every
+  stretch of the wire's meter also carry ``cpu_ms``, the process's own
+  CPU over them (all threads, exact), and a rank's trace says once
+  whose threads they were (``otherData`` ``cores``).
 
 Enablement: ``MPIT_OBS=1`` (or ``MPIT_OBS_TRACE=<path>``, which implies
 it) turns the global registry + recorder on; :func:`configure` does the

@@ -22,6 +22,17 @@ the attribution plane that makes those answerable:
   ``sched_runq``, ``task_cpu``) — one set per rank (counters are
   keyed per pid), merging and rendering under the existing B/E spans
   in Perfetto.
+- **The process's cores, exactly** (:func:`process_cpu`): while a rank
+  records spans, two of them are stamped with the CPU seconds of *all*
+  threads of the process at their ends, beside the wall stamps: the
+  ``exec`` phase of a server's ``apply_exec`` (by the recorder's
+  waiter) and every stretch the wire's meter measures (the worker's
+  ``exchange``, a server's GRAD and PARAM ops); ``cpu_ms`` over the
+  stretch is the cores the process had, with no sampling and no
+  thread.  :func:`thread_census` says once, when a rank writes its
+  trace, whose threads they were: the threads by OS name with the CPU
+  each name ran since the process began.  This module is the only
+  reader of ``/proc`` and of ``time.process_time`` in the tree.
 - **Overlap-efficiency reporting**: ``python -m mpit_tpu.obs profile
   <trace>`` computes per-rank core utilization (pool busy-seconds ÷
   wall × threads), the per-phase on-CPU vs off-CPU split (non-negative
@@ -221,6 +232,59 @@ def reset() -> None:
     global _GLOBAL, _FORCED
     _GLOBAL = None
     _FORCED = None
+
+
+def process_cpu() -> float:
+    """The CPU seconds of all threads of the process so far
+    (``time.process_time``): the span recorder stamps a stretch with it
+    at both ends (obs/spans.py: the waiter's ``exec``, the wire's
+    meter), and only while it records."""
+    return time.process_time()
+
+
+TASK_DIR = "/proc/self/task"
+#: so many names a census keeps, those that ran the most CPU
+CENSUS_NAMES = 12
+
+
+def thread_census() -> Dict[str, object]:
+    """This process's threads by OS name (``/proc/self/task/<tid>/stat``:
+    a Python thread keeps the interpreter's, a native one what its
+    library gave it, ``tf_XLAEigen`` XLA:CPU's pool, ``mpit-crew`` a
+    ring copy's helper; a trailing index is cut), each name with its
+    live threads and the CPU milliseconds (``utime + stime``, in the
+    kernel's clock ticks) they ran since they began, the
+    :data:`CENSUS_NAMES` that ran most; with the affinity set's size.
+    One listing and one read a thread: for a rank's exit (the trace
+    exporter calls it after the last span), never a timed path.  Threads
+    that have ended are not in it.  Empty where there is no ``/proc``."""
+    tick_ms = 1e3 / os.sysconf("SC_CLK_TCK")
+    names: Dict[str, List[float]] = {}
+    try:
+        tids = os.listdir(TASK_DIR)
+    except OSError:
+        return {}
+    for tid in tids:
+        try:
+            with open(f"{TASK_DIR}/{tid}/stat", "rb") as fh:
+                raw = fh.read()
+            name = raw[raw.index(b"(") + 1:raw.rindex(b")")].decode(
+                "utf-8", "replace").rstrip("0123456789-_/:")
+            rest = raw[raw.rindex(b")") + 2:].split()
+            cpu_ms = (int(rest[11]) + int(rest[12])) * tick_ms
+        except (OSError, ValueError, IndexError):
+            continue  # ended meanwhile
+        row = names.setdefault(name, [0, 0.0])
+        row[0] += 1
+        row[1] += cpu_ms
+    top = sorted(names.items(), key=lambda kv: (-kv[1][1], kv[0]))
+    return {
+        "affinity": len(os.sched_getaffinity(0)),
+        "threads": sum(n for n, _ms in names.values()),
+        "clock_tick_ms": tick_ms,
+        "by_name": {name: {"threads": n, "cpu_ms": ms}
+                    for name, (n, ms) in top[:CENSUS_NAMES]},
+    }
 
 
 def resource_snapshot() -> Dict[str, object]:

@@ -103,7 +103,7 @@ class OpSpan:
     cat = "ps_op"
     __slots__ = ("_rec", "name", "tid", "t0", "t1", "marks", "args",
                  "outcome", "cpu0", "cpu1", "cpu_marks", "cpu_us",
-                 "seen_ready")
+                 "seen_ready", "exec_cpu")
 
     def __init__(self, rec: "SpanRecorder", name: str, tid: str,
                  args: Dict[str, object]):
@@ -118,6 +118,10 @@ class OpSpan:
         #: when a thread other than the one that ends this span saw its
         #: result ready (:meth:`SpanRecorder.seen_ready`), if one did
         self.seen_ready: Optional[float] = None
+        #: on a span handed to the waiter (:meth:`end_when_ready`): the
+        #: process's CPU seconds at the waiter's ``exec`` mark, at its
+        #: end stamp and at ``seen_ready``'s (None: not stamped)
+        self.exec_cpu: Optional[List[Optional[float]]] = None
         # CPU attribution (obs/profile.py): when profiling is enabled
         # the span stamps the stepping thread's CPU clock alongside
         # every wall stamp, so the exporter can split each phase into
@@ -232,7 +236,12 @@ class WireMeter:
     leaves the wire's three out.  Where the scheduler's owner names its
     sleeps (``Scheduler.sleep_by``: the PS client does, by what its
     pending ops waited for), each name's share goes beside them as
-    ``sleep_<name>_ms``; they sum to ``sched_sleep_ms``."""
+    ``sleep_<name>_ms``; they sum to ``sched_sleep_ms``.  Beside them
+    ``cpu_ms``, the CPU of all threads of the process over the same
+    stretch (``obs/profile.py`` ``process_cpu``: the stretch's cores are
+    ``cpu_ms`` over its length), and, from a transport that keeps them
+    (shm), ``crew_copy_ms`` and ``crew_spin_ms``: what the endpoint's
+    copy helpers spent inside their parts and spinning with none."""
 
     __slots__ = ("_totals", "_sched", "_last", "_t")
 
@@ -244,12 +253,18 @@ class WireMeter:
     def _read(self) -> Dict[str, float]:
         now = dict(self._totals()) if self._totals is not None else {}
         now["sched_sleep"] = getattr(self._sched, "sleep_s", 0.0)
+        now["cpu"] = _profile.process_cpu()
         for name, slept in getattr(self._sched, "sleep_by", {}).items():
             now[f"sleep_{name}"] = slept
         return now
 
     def start(self) -> None:
-        self._last = self._read()
+        now = self._read()
+        # a helper spins on past the last copy of a stretch: the crew's
+        # two run from note to note, whatever a start leaves out
+        now.update({k: v for k, v in getattr(self, "_last", {}).items()
+                    if k.startswith("crew_")})
+        self._last = now
         self._t = time.monotonic()
 
     def note(self, span, stretch: bool = True) -> None:
@@ -257,12 +272,16 @@ class WireMeter:
         d = {k: (v - self._last.get(k, 0.0)) * 1e3 for k, v in now.items()}
         out = {f"{k}_ms": v for k, v in d.items() if k.startswith("sleep_")}
         out["sched_sleep_ms"] = d["sched_sleep"]
+        out["cpu_ms"] = d["cpu"]
         if stretch:
             out["wire_span_ms"] = (t - self._t) * 1e3
         if "progress" in d:
             out.update(
                 wire_tx_copy_ms=d["tx_copy"], wire_rx_copy_ms=d["rx_copy"],
                 wire_poll_ms=d["progress"] - d["tx_copy"] - d["rx_copy"])
+        if "crew_copy" in d:
+            out.update(crew_copy_ms=d["crew_copy"],
+                       crew_spin_ms=d["crew_spin"])
         span.note(**out)
         self._last, self._t = now, t
 
@@ -307,11 +326,21 @@ def _end_no_later_than_seen(span: OpSpan) -> None:
     stamp and the one of a role thread that waited on the same result
     (never before its last mark); ``end_from`` says whose it is.  Both
     threads call this after their own write, so whichever comes second
-    finds both stamps."""
+    finds both stamps.  ``cpu_ms`` is the process's CPU from the
+    ``exec`` mark to the stamp the span ends at: every thread's, so the
+    phase's cores are ``cpu_ms`` over its length.  Where the role
+    thread's stamp ends the span, ``waiter_late_ms`` says how much later
+    the waiter's own came: what ``exec`` overstates by wherever no role
+    thread waited on the result."""
     seen = span.seen_ready
+    cpu0, cpu_end, cpu_seen = span.exec_cpu or (None, None, None)
     if seen is not None and span.t1 is not None and seen < span.t1:
+        span.args["waiter_late_ms"] = (span.t1 - seen) * 1e3
         span.t1 = max(seen, span.marks[-1][1] if span.marks else span.t0)
         span.args["end_from"] = "wait_apply"
+        cpu_end = cpu_seen
+    if cpu0 is not None and cpu_end is not None and span.t1 is not None:
+        span.args["cpu_ms"] = max(cpu_end - cpu0, 0.0) * 1e3
 
 
 class _ReadyWaiter(threading.Thread):
@@ -340,11 +369,13 @@ class _ReadyWaiter(threading.Thread):
         while (item := self.items.get()) is not None:
             span, result = item
             span.mark("exec")
+            span.exec_cpu[0] = _profile.process_cpu()
             try:
                 jax.block_until_ready(result)
                 outcome = "ready"
             except Exception:  # a donated or deleted result: no stamp
                 outcome = "lost"
+            span.exec_cpu[1] = _profile.process_cpu()
             span.end(outcome, end_from="waiter")
             _end_no_later_than_seen(span)
             self.done += 1
@@ -416,7 +447,8 @@ class SpanRecorder:
         not the shard): a deleted array cannot be waited on, and its
         span ends ``lost`` at once.  The caller must not touch the span
         again."""
-        span.cpu0 = None  # another thread ends it: no CPU attribution
+        span.cpu0 = None  # no one thread's CPU: the waiter notes ``cpu_ms``
+        span.exec_cpu = [None, None, None]
         with self._hist_lock:
             if self._waiter is None:
                 self._waiter = _ReadyWaiter()
@@ -431,6 +463,8 @@ class SpanRecorder:
         ``span``, handed to :meth:`end_when_ready`, waits for: the span
         ends no later than now (see :class:`_ReadyWaiter`)."""
         span.seen_ready = time.monotonic()
+        if span.exec_cpu is not None:
+            span.exec_cpu[2] = _profile.process_cpu()
         _end_no_later_than_seen(span)
 
     def close(self) -> None:

@@ -157,7 +157,10 @@ def write_rank_trace(path: str, rank: int, role: str = "",
             "ranks": {str(rank): {
                 "role": role, "metrics": reg.snapshot(),
                 "epoch_offset": rec.epoch_offset,
-                "clock_id": _clock.clock_id()}},
+                "clock_id": _clock.clock_id(),
+                # once a part, after its last span: the affinity set
+                # and the threads by name with the CPU each name ran
+                "cores": _profile.thread_census()}},
             # Per-peer clock-offset estimates (obs/clock.py): the causal
             # joiner aligns ranks from these instead of re-deriving
             # offsets from span pairs (obs/causal.py).

@@ -1468,7 +1468,7 @@ def test_sdars_mix_keeps_to_the_traffic_its_issue_fixed():
             assert SDAR_CELL not in metric["workloads"], metric["name"]
     # added together and in order (PR 53's four follow them, and its
     # configuration and cell)
-    assert [m["name"] for m in cell.bench["per_layer"][-23:-19]] == list(
+    assert [m["name"] for m in cell.bench["per_layer"][-26:-22]] == list(
         SDAR_METRICS)
     assert (cell.bench["configs"][-5]["name"],
             cell.bench["workloads"][-5]["name"]) == (cell.config_name,
@@ -1704,7 +1704,7 @@ def test_trinitys_mix_keeps_to_the_traffic_its_issue_fixed():
         elif "workloads" in metric:
             assert TRINITY_CELL not in metric["workloads"], metric["name"]
     # added together, in order and last
-    assert [m["name"] for m in cell.bench["per_layer"][-19:-15]] == list(
+    assert [m["name"] for m in cell.bench["per_layer"][-22:-18]] == list(
         TRINITY_METRICS)
     assert (cell.bench["configs"][-4]["name"],
             cell.bench["workloads"][-4]["name"]) == (cell.config_name,
@@ -1965,7 +1965,7 @@ def test_nemotrons_mix_keeps_to_the_traffic_its_issue_fixed():
             assert NEMOTRON_CELL not in metric["workloads"], metric["name"]
     # added together and in order (PR 61's six and PR 65's three
     # follow them, and their configurations and cells)
-    assert [m["name"] for m in cell.bench["per_layer"][-15:-10]] == list(
+    assert [m["name"] for m in cell.bench["per_layer"][-18:-13]] == list(
         NEMOTRON_METRICS)
     assert (cell.bench["configs"][-3]["name"],
             cell.bench["workloads"][-3]["name"]) == (cell.config_name,
@@ -2217,7 +2217,7 @@ def test_qwen3nexts_mix_keeps_to_the_traffic_its_issue_fixed():
             assert QWEN3NEXT_CELL not in metric["workloads"], metric["name"]
     # added together and in order (PR 65's three follow them, and its
     # configuration and cell)
-    assert [m["name"] for m in cell.bench["per_layer"][-10:-4]] == list(
+    assert [m["name"] for m in cell.bench["per_layer"][-13:-7]] == list(
         QWEN3NEXT_METRICS)
     assert (cell.bench["configs"][-2]["name"],
             cell.bench["workloads"][-2]["name"]) == (cell.config_name,
@@ -2477,8 +2477,8 @@ def test_granites_mix_keeps_to_the_traffic_its_issue_fixed():
             assert metric["workloads"][-1] == GRANITE_CELL
         elif "workloads" in metric:
             assert GRANITE_CELL not in metric["workloads"], metric["name"]
-    # added together and in order (PR 66's one follows them)
-    assert [m["name"] for m in cell.bench["per_layer"][-4:-1]] == list(
+    # added together and in order (PR 66's one and PR 67's three follow)
+    assert [m["name"] for m in cell.bench["per_layer"][-7:-4]] == list(
         GRANITE_METRICS)
     assert (cell.bench["configs"][-1]["name"],
             cell.bench["workloads"][-1]["name"]) == (cell.config_name,
@@ -2487,7 +2487,7 @@ def test_granites_mix_keeps_to_the_traffic_its_issue_fixed():
     assert "One stage of four" in cell.why and "1 layer of 10" in cell.why
     assert len(cell.bench["configs"]) == 14 and len(
         cell.bench["workloads"]) == 15 and len(
-            cell.bench["per_layer"]) == 91
+            cell.bench["per_layer"]) == 94
     # one cell in four may take four chips, and none does
     assert sum(w["chips"] == 4 for w in cell.bench["workloads"]) == 0
 
@@ -2690,7 +2690,7 @@ def test_pull_early_pct_is_entered_for_the_ps_cells_under_a_layer_of_perf_md():
                      "moves": "tokens_per_s", "workloads": PS_CELLS}
     # appended, nothing moved; PR 51's four, PR 53's four, PR 58's five,
     # PR 61's six, PR 65's three and PR 66's one follow it
-    assert bench["per_layer"][-24] is entry
+    assert bench["per_layer"][-27] is entry
     perf = (spec_mod.ROOT / "PERF.md").read_text()
     layers = perf[perf.index("## 3. Layers"):perf.index("## 4. Cells")]
     assert f"| {entry['layer']} |" in layers and "`pull_early_pct`" in layers
@@ -2757,7 +2757,7 @@ def _copy_split(run):
 
 def test_copy_split_pct_is_entered_last_for_the_ps_cells_under_a_layer_of_perf_md():
     bench = spec_mod.load_bench(spec_mod.ROOT)
-    entry = bench["per_layer"][-1]
+    entry = bench["per_layer"][-4]  # PR 67 appended three
     assert entry == {"name": "copy_split_pct", "unit": "%", "better": "higher",
                      "source": "program_span", "layer": "L2 servers + wire",
                      "moves": "tokens_per_s", "workloads": PS_CELLS}

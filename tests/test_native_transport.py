@@ -1061,7 +1061,8 @@ class TestWireTiming:
             assert a._runs is None and b._runs is None
             assert a.lib.mt_ring_counts(a._ctx, 5) == 0
             assert b.lib.mt_ring_counts(b._ctx, 5) == 0
-            zero = dict.fromkeys(("tx_copy", "rx_copy", "progress"), 0.0)
+            zero = dict.fromkeys(("tx_copy", "rx_copy", "progress",
+                                  "crew_copy", "crew_spin"), 0.0)
             assert a.wire_totals() == b.wire_totals() == zero
             assert not a._rec.enabled and a._rec.spans == ()
         finally:
@@ -1597,6 +1598,43 @@ class TestSplitCopies:
             np.testing.assert_array_equal(got_there, there)
             np.testing.assert_array_equal(got_back, back)
             assert a.ring_counters()["tx_split_bytes"] > 5 * self.RING
+        finally:
+            a.close()
+            b.close()
+
+    @pytest.mark.parametrize("timed", [False, True], ids=["off", "timed"])
+    def test_a_helper_keeps_its_own_time_only_while_the_wire_is_timed(
+            self, one_helper, timed):
+        """``mt_wire_ns`` 3 and 4 (``wire_totals`` ``crew_copy`` and
+        ``crew_spin``): what the helper spent inside its parts and
+        spinning with none, by its own readings of the clock; with the
+        timing off (obs off) it reads no clock for them and both stay 0,
+        and neither falls."""
+        import time
+
+        a, b = self.pair(f"crewns_{int(timed)}")
+        try:
+            for wire in (a, b):
+                wire.lib.mt_set_timing(wire._ctx, int(timed))
+            data = noise(9, 6 * self.RING)
+            out = np.zeros_like(data)
+            hr, hs = b.irecv(0, 4, out=out), a.isend(data, 1, 4)
+            spin(lambda: a.test(hs), lambda: b.test(hr))
+            np.testing.assert_array_equal(out, data)
+            assert a.ring_counters()["tx_split_bytes"] > 0
+            first = a.wire_totals()
+            deadline = time.monotonic() + 10.0
+            while timed and time.monotonic() < deadline and (
+                    a.wire_totals()["crew_spin"] == first["crew_spin"]):
+                time.sleep(0.005)  # it spins on past the last part
+            after = a.wire_totals()
+            if timed:
+                assert first["crew_copy"] > 0.0
+                assert after["crew_spin"] > 0.0
+                assert after["crew_copy"] >= first["crew_copy"]
+            else:
+                assert first["crew_copy"] == first["crew_spin"] == 0.0
+                assert after == first
         finally:
             a.close()
             b.close()

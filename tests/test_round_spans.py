@@ -826,12 +826,18 @@ def test_wire_meter_notes_what_an_endpoint_did_between_two_looks(obs_on):
         sched.wait_for(task)
         span = obs_on.op("GRAD", peer=1, side="client", rank=0)
         meter.note(span)
+        got = {k: v for k, v in span.args.items()
+               if k.startswith(("wire_", "sched_"))}
+        # a second note covers only what came after the first: taken at
+        # once, before the endpoints close (a close joins the helper
+        # threads and unmaps the rings, which under load outlasts the
+        # 4 MB send the first note covers)
+        meter.note(span)
+        again = dict(span.args)
         span.end()
     finally:
         a.close()
         b.close()
-    got = {k: v for k, v in span.args.items()
-           if k.startswith(("wire_", "sched_"))}
     assert sorted(got) == ["sched_sleep_ms", "wire_poll_ms",
                            "wire_rx_copy_ms", "wire_span_ms",
                            "wire_tx_copy_ms"]
@@ -840,10 +846,8 @@ def test_wire_meter_notes_what_an_endpoint_did_between_two_looks(obs_on):
     assert got["sched_sleep_ms"] == pytest.approx(sched.sleep_s * 1e3)
     assert got["wire_tx_copy_ms"] + got["wire_poll_ms"] + (
         got["sched_sleep_ms"]) <= got["wire_span_ms"]
-    # a second note covers only what came after the first
-    meter.note(span)
-    assert span.args["wire_tx_copy_ms"] == 0
-    assert span.args["wire_span_ms"] < got["wire_span_ms"]
+    assert again["wire_tx_copy_ms"] == 0
+    assert again["wire_span_ms"] < got["wire_span_ms"]
 
 
 def test_wire_meter_on_a_wire_without_totals_and_with_obs_off():
@@ -997,6 +1001,55 @@ def test_gang_wire_reader_returns_a_number(wire_gang_run, name, capsys):
             lines[-1])
     else:
         assert printed == ""
+
+
+def test_gang_cores_stamps_pass_their_own_check(wire_gang_run, capsys):
+    """PR 67 on the program's own trace: every windowed round carries the
+    worker's ``cpu_ms``, the servers' metered stretches tile their time
+    and each ``exec`` lies in two of them, every rank's part says whose
+    threads it had, and the table's last line is the check."""
+    from chipbench.layers import coretree
+
+    run = dict(wire_gang_run)
+    cores = coretree.load(run)
+    assert len(cores.rounds) == GANG_STEPS - 1
+    assert coretree.check_faults(cores) == []
+    assert sorted(cores.census) == [0, 1, 2]
+    for census in cores.census.values():
+        assert census["threads"] >= 1 and census["by_name"]
+    rows = coretree.round_rows(cores)
+    for row in rows:
+        assert row["worker_ms"] >= 0.0
+        # a push's ack comes in the middle of the exchange; a reply is
+        # noted as the worker has it, a moment before or after
+        ended = sorted((st.pid, st.op) for st in row["stretches"])
+        assert {(0, "GRAD"), (2, "GRAD")} <= set(ended) <= {
+            (0, "GRAD"), (0, "PARAM"), (2, "GRAD"), (2, "PARAM")}
+        assert len(ended) == len(set(ended))
+    assert sum(len(r["stretches"]) for r in rows) >= 4 * len(rows) - 1
+    applied = [a for a in coretree.applies(cores) if a["round"] is not None]
+    assert len(applied) == 2 * len(cores.rounds)
+    assert all(a["cpu_ms"] is not None and a["end_from"] in (
+        "waiter", "wait_apply") for a in applied)
+    assert all((a["waiter_late_ms"] is not None)
+               == (a["end_from"] == "wait_apply") for a in applied)
+    assert coretree.print_table(cores)
+    lines = capsys.readouterr().out.splitlines()
+    assert all(ln.startswith("chipbench: cores: ") for ln in lines)
+    assert lines[-1].startswith("chipbench: cores: check passes")
+
+
+@pytest.mark.parametrize("name", ["apply_cores_p50", "exchange_cores_p50",
+                                  "crew_spin_pct"])
+def test_gang_cores_reader_returns_a_number(wire_gang_run, name):
+    from chipbench.layers import coretree
+
+    value = reader(name)(dict(wire_gang_run))
+    crew = coretree.crew_rows(coretree.load(dict(wire_gang_run)))
+    if name == "crew_spin_pct" and not any(c > 0 for c, _s in crew.values()):
+        assert value is None  # this host leaves a gang of three no helper
+    else:
+        assert isinstance(value, float) and value >= 0
 
 
 @pytest.mark.parametrize("name", WIRE_METRICS)
